@@ -337,20 +337,17 @@ def bench_lanes(
 
 def check_engine_parity(
     n_elems: int = 1 << 14, lanes: int = 2, timeout: float = 60.0
-) -> Optional[bool]:
+) -> bool:
     """Bitwise engine parity on live rings: the SAME deterministic payload
     allreduced by a 2-rank py-engine pair and a 2-rank native-engine pair
     (f32 raw, bf16 wire, and the int8 codec) must produce IDENTICAL bits —
     the contract that lets "auto" switch engines without a numerics review.
-    Returns None when the native engine is unavailable (nothing to
-    compare), else the parity verdict.  The exhaustive topology x codec x
+    The exhaustive topology x codec x
     lanes matrix lives in tests/test_ring_engine.py; this is the live
     artifact-level pin."""
-    from torchft_tpu._native import StoreServer, ring_engine_available
+    from torchft_tpu._native import StoreServer
     from torchft_tpu.collectives import TCPCollective
 
-    if not ring_engine_available():
-        return None
     rng = np.random.default_rng(1234)
     data = [
         (rng.standard_normal(n_elems) * (r + 1)).astype(np.float32)
@@ -421,24 +418,16 @@ def run_engine_quick(
     cell, threads): one py cell, one native cell, plus the live bitwise
     parity pin.  Wired into
     tests/test_bench_contract.py::test_ring_engine_quick_smoke."""
-    from torchft_tpu._native import ring_engine_available
-
     cells = [
         bench_lanes(payload_mb=payload_mb, lanes=lanes, mbps=0.0, rtt_ms=0.0,
                     n_buckets=4, timeout=120.0, procs=False, trials=trials,
-                    engine="py")
+                    engine=engine)
+        for engine in ("py", "native")
     ]
-    native_available = ring_engine_available()
-    if native_available:
-        cells.append(
-            bench_lanes(payload_mb=payload_mb, lanes=lanes, mbps=0.0,
-                        rtt_ms=0.0, n_buckets=4, timeout=120.0, procs=False,
-                        trials=trials, engine="native")
-        )
     by_engine = {c["engine"]: c for c in cells}
     out: Dict[str, Any] = {
         "section": "ring_engine",
-        "native_available": native_available,
+        "native_available": True,
         "cells": cells,
         "parity_bitwise": check_engine_parity(),
     }
@@ -527,18 +516,16 @@ def check_transport_parity(
 def check_multi_stripe(
     n_elems: int = 1 << 16, lanes: int = 2, chunk_bytes: int = 32 << 10,
     ops: int = 4, timeout: float = 60.0,
-) -> Optional[Dict[str, Any]]:
+) -> Dict[str, Any]:
     """Pins the one-call native multi-stripe entry: a striped allreduce
     (many stripes per op at this chunk size) must cross the C API ONCE per
     op (``tf_ring_pass_multi``), not once per stripe — the per-stripe
     ctypes round-trips were pure Python overhead the batch entry removed.
     Counts ``RingEngine.pass_calls`` on rank 0 across ``ops`` back-to-back
-    allreduces.  None when the native engine is unavailable."""
-    from torchft_tpu._native import StoreServer, ring_engine_available
+    allreduces."""
+    from torchft_tpu._native import StoreServer
     from torchft_tpu.collectives import TCPCollective
 
-    if not ring_engine_available():
-        return None
     nstripes = max(1, (n_elems * 4 + chunk_bytes - 1) // chunk_bytes)
     store = StoreServer(bind="127.0.0.1:0")
     counts: Dict[int, int] = {}
@@ -636,11 +623,8 @@ def bench_engine_threads(
     curve flattens.  On a 1-core container BOTH flatten (nothing to run
     parallel on); the record carries ``cpu_count`` so readers can tell
     "GIL-bound" from "core-bound" honestly."""
-    from torchft_tpu._native import ring_engine_available
-
     cells: List[Dict[str, Any]] = []
-    engines = ["py"] + (["native"] if ring_engine_available() else [])
-    for eng in engines:
+    for eng in ("py", "native"):
         for lanes in lane_counts:
             r = bench_lanes(payload_mb=payload_mb, lanes=lanes, mbps=0.0,
                             rtt_ms=0.0, n_buckets=4, timeout=120.0,

@@ -1,6 +1,6 @@
 """Shared mechanics for the example trainers.
 
-Only the non-instructive plumbing lives here (platform pinning, compile
+Only the non-instructive plumbing lives here (virtual devices, compile
 cache, Manager wiring, the FINAL digest); each example keeps its own train
 loop inline so it still reads as a tutorial for its parallelism style.
 """
@@ -13,17 +13,20 @@ import time
 from typing import Any, Callable, Optional
 
 
-def pin_platform_and_cache(virtual_devices: Optional[int] = None) -> None:
-    """Applies the environment contract every example shares, BEFORE the
-    first touch of the JAX backend:
+def prepare_jax_env(virtual_devices: Optional[int] = None) -> None:
+    """Applies the environment contract every example shares.  Call it
+    BEFORE the first ``import jax``: JAX reads both settings at import.
 
-    - ``virtual_devices``: simulate one multi-device slice per process
-      (demo only; real hardware drops this).
-    - ``TPUFT_JAX_PLATFORM``: explicit platform pin — env JAX_PLATFORMS
-      alone can be overridden by site hooks after launch, and multi-process
-      drives must not share a single TPU chip.
-    - ``TPUFT_COMPILE_CACHE``: persistent compile cache so a restarted
-      replica re-JITs from disk, shrinking the recovery window.
+    - ``virtual_devices``: simulate one multi-device slice per process on
+      the CPU backend (demo only; real hardware drops this).
+    - compile cache: placed by ``torchft_tpu.launch.export_compile_cache``
+      (``JAX_COMPILATION_CACHE_DIR`` if set, else ``<repo>/.jax_cache``) so
+      a restarted replica re-JITs from disk, shrinking the recovery window.
+
+    The platform is JAX's own ``JAX_PLATFORMS``.  A chip belongs to one
+    process: anything that runs several processes on one host sets
+    ``JAX_PLATFORMS=cpu`` in the child environment, or gives each child its
+    own chip (``Launcher(group_env=...)``, see ``chip_smoke.py``).
     """
     if virtual_devices is not None:
         flags = os.environ.get("XLA_FLAGS", "")
@@ -32,15 +35,9 @@ def pin_platform_and_cache(virtual_devices: Optional[int] = None) -> None:
                 flags + f" --xla_force_host_platform_device_count={virtual_devices}"
             ).strip()
 
-    import jax
+    from torchft_tpu.launch import export_compile_cache
 
-    forced = os.environ.get("TPUFT_JAX_PLATFORM")
-    if forced:
-        jax.config.update("jax_platforms", forced)
-    cache_dir = os.environ.get("TPUFT_COMPILE_CACHE")
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    export_compile_cache()
 
 
 def replica_env() -> tuple:
@@ -48,18 +45,27 @@ def replica_env() -> tuple:
 
     Hot-spare mode: when the supervisor started this process as a SPARE
     (``TPUFT_SPARE_FILE`` set, no ``REPLICA_GROUP_ID``), finish the
-    expensive initialization NOW — force the JAX backend up — and block
-    until the supervisor assigns a replica group by writing the go-file.
-    Adoption then skips the process-spawn + runtime-init floor that
-    dominates a cold restart's dead window (measured ~7 s of the ~7.5 s
-    downtime on the kill bench)."""
+    expensive initialization NOW and block until the supervisor assigns a
+    replica group by writing the go-file.  Adoption then skips the
+    process-spawn + runtime-init floor that dominates a cold restart's dead
+    window (measured ~7 s of the ~7.5 s downtime on the CPU kill bench).
+
+    What "initialization" may cover depends on the platform.  A CPU backend
+    is not exclusive, so under ``JAX_PLATFORMS=cpu`` the spare brings it up
+    while idle.  A chip belongs to one process: anywhere else the spare
+    stops at the imports and takes the chip only once it owns a group —
+    on a one-chip host that is after the group it replaces has died."""
     gid = os.environ.get("REPLICA_GROUP_ID")
     spare = os.environ.get("TPUFT_SPARE_FILE")
     if gid is None and spare:
         import jax
 
-        jax.devices()  # backend init happens while idling, not after a death
-        print(f"[spare] ready (backend up), waiting at {spare}", flush=True)
+        if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+            jax.devices()  # backend init happens while idling, not after a death
+            ready = "backend up"
+        else:
+            ready = "imports done, no chip taken"
+        print(f"[spare] ready ({ready}), waiting at {spare}", flush=True)
         while not os.path.exists(spare):
             time.sleep(0.05)
         with open(spare) as f:

@@ -39,7 +39,7 @@ from _common import (
     make_manager,
     maybe_straggle,
     params_digest,
-    pin_platform_and_cache,
+    prepare_jax_env,
     replica_env,
 )
 
@@ -77,7 +77,7 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    pin_platform_and_cache()
+    prepare_jax_env()
 
     import jax
     import numpy as np

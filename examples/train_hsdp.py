@@ -32,7 +32,7 @@ from _common import (
     TrainGate,
     make_manager,
     params_digest,
-    pin_platform_and_cache,
+    prepare_jax_env,
     replica_env,
 )
 
@@ -65,7 +65,7 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    pin_platform_and_cache(virtual_devices=args.devices)
+    prepare_jax_env(virtual_devices=args.devices)
 
     import jax
     import jax.numpy as jnp

@@ -13,10 +13,10 @@ PIPELINE-SHARDED state in place (NamedShardings restored onto its own
 mesh) from a healthy peer.
 
 Run (two supervised groups; each simulates a pipeline x data slice on
-CPU — pin TPUFT_JAX_PLATFORM=cpu when a TPU is attached, it cannot be
-shared by two processes)::
+virtual CPU devices — JAX_PLATFORMS=cpu because a chip belongs to one
+process and these two would both take it)::
 
-    TPUFT_JAX_PLATFORM=cpu python -m torchft_tpu.launch --groups 2 \
+    JAX_PLATFORMS=cpu python -m torchft_tpu.launch --groups 2 \
         --max-restarts 3 -- python examples/train_pipeline.py --steps 200
 """
 
@@ -33,7 +33,7 @@ from _common import (
     TrainGate,
     make_manager,
     params_digest,
-    pin_platform_and_cache,
+    prepare_jax_env,
     replica_env,
 )
 
@@ -82,7 +82,7 @@ def main() -> None:
             f"then into --microbatches {args.microbatches}"
         )
 
-    pin_platform_and_cache(virtual_devices=args.devices)
+    prepare_jax_env(virtual_devices=args.devices)
 
     import jax
     import jax.numpy as jnp

@@ -11,10 +11,11 @@ reference has neither sequence parallelism nor this composition
 (SURVEY.md §2.3); the FT mechanics mirror its DDP recovery story
 (torchft/manager_integ_test.py:281).
 
-Run (two supervised groups; pin TPUFT_JAX_PLATFORM=cpu when a TPU is
-attached — one chip cannot be shared by two processes)::
+Run (two supervised groups, each simulating a slice on virtual CPU devices;
+JAX_PLATFORMS=cpu because a chip belongs to one process and these two would
+both take it)::
 
-    TPUFT_JAX_PLATFORM=cpu python -m torchft_tpu.launch --groups 2 \
+    JAX_PLATFORMS=cpu python -m torchft_tpu.launch --groups 2 \
         --max-restarts 3 -- python examples/train_ring.py --steps 200 \
         --layout zigzag
 """
@@ -32,7 +33,7 @@ from _common import (
     TrainGate,
     make_manager,
     params_digest,
-    pin_platform_and_cache,
+    prepare_jax_env,
     replica_env,
 )
 
@@ -72,7 +73,7 @@ def main() -> None:
             f"--devices {args.devices} not divisible by --sequence {args.sequence}"
         )
 
-    pin_platform_and_cache(virtual_devices=args.devices)
+    prepare_jax_env(virtual_devices=args.devices)
 
     import jax
     import jax.numpy as jnp

@@ -2,14 +2,16 @@
 """Protoc replacement for toolchain-less containers (no protoc/cmake/ninja).
 
 Parses proto/tpuft.proto (the simple proto3 subset this repo uses) and emits:
-  - /tmp/tpuftpb/tpuft.pb.h         C++ classes speaking REAL protobuf wire
+  - <gen_dir>/tpuft.pb.h            C++ classes speaking REAL protobuf wire
                                     format, no libprotobuf dependency
+                                    (gen_dir: the one argument, default
+                                    native/build-g++/gen in this checkout)
   - torchft_tpu/proto/tpuft_pb2.py  descriptor-pool-based real protobuf classes
 
 With these, the native library rebuilds with nothing but g++:
 
     python native/gen_pb_local.py
-    g++ -std=c++17 -O3 -fPIC -shared -I native/src -I /tmp/tpuftpb \
+    g++ -std=c++17 -O3 -fPIC -shared -I native/src -I native/build-g++/gen \
         native/src/wire.cc native/src/http.cc native/src/flight.cc \
         native/src/lighthouse.cc native/src/manager.cc native/src/store.cc \
         native/src/ring.cc native/src/capi.cc \
@@ -372,12 +374,18 @@ def main():
                 )
     names = [n for n, _ in msgs]
     sys.stderr.write("messages: %s\n" % ", ".join(names))
-    os.makedirs("/tmp/tpuftpb", exist_ok=True)
-    with open("/tmp/tpuftpb/tpuft.pb.h", "w") as f:
+    gen_dir = (
+        sys.argv[1]
+        if len(sys.argv) > 1
+        else os.path.join(REPO, "native", "build-g++", "gen")
+    )
+    os.makedirs(gen_dir, exist_ok=True)
+    header = os.path.join(gen_dir, "tpuft.pb.h")
+    with open(header, "w") as f:
         f.write(gen_cpp(msgs))
     with open(os.path.join(REPO, "torchft_tpu", "proto", "tpuft_pb2.py"), "w") as f:
         f.write(gen_py(msgs))
-    print("wrote /tmp/tpuftpb/tpuft.pb.h and torchft_tpu/proto/tpuft_pb2.py")
+    print(f"wrote {header} and torchft_tpu/proto/tpuft_pb2.py")
 
 
 if __name__ == "__main__":

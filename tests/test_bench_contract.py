@@ -190,11 +190,6 @@ def test_ring_engine_quick_smoke() -> None:
         import bench_allreduce
     finally:
         sys.path.pop(0)
-    from torchft_tpu._native import ring_engine_available
-
-    if not ring_engine_available():
-        pytest.skip("libtpuft.so lacks the ring engine symbols")
-
     payload = bench_allreduce.run_engine_quick(
         payload_mb=4.0, lanes=2, trials=2
     )
@@ -602,6 +597,36 @@ def test_elastic_quick_smoke() -> None:
     assert artifact["ok"] is True
 
 
+def test_bench_chip_paths_refuse_anything_but_a_known_tpu() -> None:
+    """The chip measurements never run on the CPU under a device's name, and
+    an MFU is never computed against a guessed peak: no TPU is an error (in
+    the child that owns the chip, so `main` fails with it), and so is a
+    device kind the peaks table does not hold."""
+    sys.path.insert(0, REPO)
+    try:
+        import bench
+    finally:
+        sys.path.pop(0)
+
+    with pytest.raises(RuntimeError, match="measures the chip"):
+        bench.tpu_device()
+
+    class Device:
+        device_kind = "TPU v5 lite"
+
+    assert bench._peak_flops(Device()) == 197e12
+    Device.device_kind = "TPU v99 imaginary"
+    with pytest.raises(RuntimeError, match="no bf16 peak recorded"):
+        bench._peak_flops(Device())
+
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--chip", "large"],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode != 0 and "measures the chip" in out.stderr
+
+
 def test_bench_selftest() -> None:
     """bench.py --selftest verifies its own scenario-call signatures without
     touching the chip or spawning training subprocesses."""
@@ -628,7 +653,6 @@ def test_example_emits_committed_line(tmp_path) -> None:
     env = dict(os.environ)
     env.update(
         {
-            "TPUFT_JAX_PLATFORM": "cpu",
             "JAX_PLATFORMS": "cpu",
             "TPUFT_LIGHTHOUSE": lighthouse.address(),
             "REPLICA_GROUP_ID": "0",

@@ -1,0 +1,158 @@
+"""Compiles the main path's pallas kernels, and one whole flagship gradient
+program with them inside, for a DESCRIBED `v5e:2x2` chip — no chip attached,
+no chip time.  What interpret mode cannot show (tiling, fast-memory budget,
+whether the kernel survives inside the jitted step) the TPU compiler
+installed here refuses or accepts exactly as the chip's would.
+
+The topology is described inside a module-scoped fixture of this file and
+nowhere else: only one process may load the TPU's library, so the call must
+not run while any module is imported (every xdist worker imports every test
+file).  Everything compiles in this test's own process, with the persistent
+compile cache off around it (a described-device entry cannot be read back).
+A compile that passes is not a chip run and is never reported as one.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the description skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _models():
+    import bench
+
+    return {"flagship": bench.flagship_config(), "1b": bench.large_config()}
+
+
+def _compile(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _has_kernel(text: str, name: str) -> bool:
+    import chip_smoke
+
+    return chip_smoke.has_kernel(text, name)
+
+
+@pytest.mark.parametrize("width", ["flagship", "1b"])
+@pytest.mark.parametrize("kernel", ["fa_fwd", "fa_bwd", "ce_lse", "ce_dlogits", "rms"])
+def test_kernel_compiles_for_v5e(one_chip, width, kernel) -> None:
+    cfg, batch, seq = _models()[width]
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bh, d = batch * cfg.n_heads, cfg.d_head
+    n, e, v = batch * seq, cfg.d_model, cfg.vocab_size
+    qkv = sds((bh, seq, d))
+    if kernel == "fa_fwd":
+        from torchft_tpu.ops.attention import _fa_pallas_call
+
+        text = _compile(lambda q, k, v_: _fa_pallas_call(q, k, v_, d ** -0.5, True), qkv, qkv, qkv)
+        names = ["tpuft_fa_fwd"]
+    elif kernel == "fa_bwd":
+        from torchft_tpu.ops.attention import _fa_bwd_pallas
+
+        text = _compile(
+            lambda q, k, v_, o, lse, g: _fa_bwd_pallas(q, k, v_, o, lse, g, d ** -0.5, True),
+            qkv, qkv, qkv, qkv, sds((bh, seq), jnp.float32), qkv,
+        )
+        names = ["tpuft_fa_bwd_dkdv"]  # seq/512 <= 4: the merged one-pass form
+    elif kernel == "ce_lse":
+        from torchft_tpu.ops.cross_entropy import _ce_lse_pallas
+
+        text = _compile(_ce_lse_pallas, sds((n, e)), sds((e, v)))
+        names = ["tpuft_ce_lse"]
+    elif kernel == "ce_dlogits":
+        from torchft_tpu.ops.cross_entropy import _ce_dlogits_pallas
+
+        text = _compile(
+            _ce_dlogits_pallas, sds((n, e)), sds((e, v)), sds((n,), jnp.int32),
+            sds((n,), jnp.float32), sds((), jnp.float32),
+        )
+        names = ["tpuft_ce_dlogits"]
+    else:
+        from torchft_tpu.ops.rmsnorm import _rms_pallas
+
+        text = _compile(lambda x, w: _rms_pallas(x, w, 1e-6), sds((n, e)), sds((e,), jnp.float32))
+        names = ["tpuft_rms"]
+    for name in names:
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+
+
+def test_long_context_two_pass_backward_compiles_for_v5e(one_chip) -> None:
+    """seq 4096 -> 8 kv blocks > _DQ_PARTIAL_MAX_K: the dk/dv pass without dq
+    partials plus the separate dq pass (`_fa_bwd_dq_kernel`)."""
+    from torchft_tpu.ops.attention import _DQ_PARTIAL_MAX_K, _fa_bwd_pallas
+
+    bh, seq, d = 12, 4096, 128
+    assert seq // 512 > _DQ_PARTIAL_MAX_K
+    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
+    text = _compile(
+        lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, d ** -0.5, True),
+        qkv, qkv, qkv, qkv, lse, qkv,
+    )
+    assert _has_kernel(text, "tpuft_fa_bwd_dkdv") and _has_kernel(text, "tpuft_fa_bwd_dq")
+
+
+def test_flagship_gradient_program_compiles_with_kernels_for_v5e(
+    topo, one_chip, monkeypatch
+) -> None:
+    """The whole jitted flagship gradient program, kernels inside.  The gate
+    asks `jax.default_backend()`, which is the CPU here, so the test steers
+    it on — in the test, the program has no option for it."""
+    import optax
+
+    import bench
+    import chip_smoke
+    from torchft_tpu.models import init_params, loss_fn
+    from torchft_tpu.ops import _pallas_util
+    from torchft_tpu.parallel import TrainStep, ft_init_mesh
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    cfg, batch, seq = bench.flagship_config()
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes
+    )
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    step = TrainStep(
+        ft_init_mesh({"data": 1}, devices=[topo.devices[0]]),
+        optax.adamw(3e-4),
+        lambda p, b: loss_fn(p, b, cfg),
+    )
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    found = chip_smoke.kernels_in(compiled.as_text())
+    assert all(found.values()), found
+    ma = compiled.memory_analysis()
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
+    assert resident < 16 * 2**30, f"the program needs {resident} bytes, a v5e chip has 16 GiB"

@@ -335,7 +335,7 @@ def test_drain_handoff_zero_dead_time(tmp_path, monkeypatch) -> None:
     host, so scheduling noise can blur a single attempt: the timing bound
     may be met on any of 3 attempts, while the zero-failed-commits
     criterion must hold on EVERY attempt."""
-    monkeypatch.setenv("TPUFT_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     metrics_path = str(tmp_path / "metrics.jsonl")
     best_dead = None
     with Launcher(

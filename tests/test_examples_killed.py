@@ -60,7 +60,7 @@ def _digests(tmp_path):
 
 
 def _drive_kill_and_converge(tmp_path, command, monkeypatch) -> None:
-    monkeypatch.setenv("TPUFT_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     command = list(command) + [
         "--require-merged-final", "2", "--steps-cap", str(_STEPS_CAP),
     ]

@@ -251,3 +251,46 @@ def test_crash_loop_backoff(tmp_path) -> None:
             lambda: (launcher.supervise_once(), launcher.restarts(0) > before)[1],
             timeout=10.0,
         )
+
+
+def test_group_env_and_compile_cache_reach_the_children(tmp_path, monkeypatch) -> None:
+    """`group_env` gives each group what it owns alone (on a TPU host: the
+    runtime's visibility settings that confine it to its own chip), on the
+    first spawn and on every respawn; and every child inherits the one
+    compile-cache location under JAX's own variable — the one the
+    environment already names, else `<repo>/.jax_cache`."""
+    from torchft_tpu.launch import export_compile_cache
+
+    env = {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}
+    assert export_compile_cache(env) == "/somewhere/else" and len(env) == 1
+    env = {}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert export_compile_cache(env) == os.path.join(repo, ".jax_cache")
+    assert env == {"JAX_COMPILATION_CACHE_DIR": os.path.join(repo, ".jax_cache")}
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    show = (
+        "import os,time;"
+        "print('chip', os.environ.get('TPU_VISIBLE_CHIPS'),"
+        " os.environ['JAX_COMPILATION_CACHE_DIR'], flush=True);"
+        "time.sleep(60)"
+    )
+    with Launcher(
+        [sys.executable, "-c", show],
+        num_groups=2,
+        lighthouse="127.0.0.1:1",  # never dialed: the command ignores it
+        max_restarts=1,
+        log_dir=str(tmp_path),
+        group_env={g: {"TPU_VISIBLE_CHIPS": str(g)} for g in (0, 1)},
+    ) as launcher:
+        _wait(lambda: all(
+            (tmp_path / f"g{g}.log").exists()
+            and b"chip" in (tmp_path / f"g{g}.log").read_bytes()
+            for g in (0, 1)
+        ))
+        launcher.kill(1, hold=False)
+        assert launcher.supervise_once() == [1]
+        _wait(lambda: (tmp_path / "g1.log").read_bytes().count(b"chip") >= 2)
+    cache = os.path.join(repo, ".jax_cache")
+    assert (tmp_path / "g0.log").read_text().splitlines() == [f"chip 0 {cache}"]
+    assert (tmp_path / "g1.log").read_text().splitlines() == [f"chip 1 {cache}"] * 2

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from test_manager import make_manager, make_quorum, store  # noqa: F401
-from torchft_tpu._native import StoreServer, ring_engine_available
+from torchft_tpu._native import StoreServer
 from torchft_tpu.collectives import (
     HOP_RECORD_FIELDS,
     HopRecorder,
@@ -69,7 +69,7 @@ def run_ranks(store, world_size, fn, **collective_kw):  # noqa: F811
         return [f.result(timeout=60) for f in futs]
 
 
-ENGINES = ["py"] + (["native"] if ring_engine_available() else [])
+ENGINES = ["py", "native"]
 
 
 # ---------------------------------------------------------------------------

@@ -227,7 +227,11 @@ def test_speculation_fits_budget_arithmetic() -> None:
     peaky = dict(stats, peak_bytes_in_use=12 << 30)
     assert speculation_fits(3 << 30, FakeDevice(peaky)) is True
     assert speculation_fits(8 << 30, FakeDevice(peaky)) is False
-    # No statistics (CPU devices, some TPU tunnels): undecidable.
+    # ... and so does a larger mark the caller knows of (the compiler's
+    # footprint of the step's programs, where the allocator's peak reads low).
+    assert speculation_fits(3 << 30, FakeDevice(peaky), floor=13 << 30) is False
+    assert speculation_fits(2 << 30, FakeDevice(peaky), floor=13 << 30) is True
+    # No statistics (CPU devices): undecidable.
     assert speculation_fits(1, FakeDevice(None)) is None
     assert speculation_fits(1, FakeDevice({})) is None
 
@@ -254,7 +258,7 @@ def test_ft_step_auto_overlap_falls_back_when_memory_tight(monkeypatch) -> None:
     step = TrainStep(ftmesh, optax.sgd(0.1), lambda p, b: loss_fn(p, b, CFG))
     assert step.overlap_commit is None
 
-    monkeypatch.setattr(trainer_mod, "speculation_fits", lambda extra, dev: False)
+    monkeypatch.setattr(trainer_mod, "speculation_fits", lambda extra, dev, floor=0: False)
     opt_state = step.init_opt_state(params)
     params, opt_state, _, committed = step.ft_step(params, opt_state, batch=_batch())
     assert committed is True
@@ -262,7 +266,7 @@ def test_ft_step_auto_overlap_falls_back_when_memory_tight(monkeypatch) -> None:
 
     # Unknown stats (None) keeps the overlap, and the choice is sticky.
     step2 = TrainStep(ftmesh, optax.sgd(0.1), lambda p, b: loss_fn(p, b, CFG))
-    monkeypatch.setattr(trainer_mod, "speculation_fits", lambda extra, dev: None)
+    monkeypatch.setattr(trainer_mod, "speculation_fits", lambda extra, dev, floor=0: None)
     opt_state2 = step2.init_opt_state(params)
     step2.ft_step(params, opt_state2, batch=_batch())
     assert step2._overlap_resolved is True

@@ -28,7 +28,7 @@ def _run_gxx_fallback() -> None:
     build_dir = os.path.join(REPO, "native", "build-g++")
     os.makedirs(build_dir, exist_ok=True)
     binary = os.path.join(build_dir, "tpuft_test")
-    gen_dir = "/tmp/tpuftpb"
+    gen_dir = os.path.join(build_dir, "gen")
     # Same source list the bindings' auto-build compiles (minus capi.cc —
     # the test binary has its own main): one tuple, no recipe drift.
     from torchft_tpu._native import NATIVE_SOURCES
@@ -49,7 +49,7 @@ def _run_gxx_fallback() -> None:
         for src in (proto, generator)
     ):
         subprocess.run(
-            [sys.executable, generator],
+            [sys.executable, generator, gen_dir],
             check=True, capture_output=True, timeout=120,
         )
     # Staleness must see headers too (wire.h etc.) and the generated pb —
@@ -102,3 +102,44 @@ def test_native_core_suite() -> None:
     )
     assert out.returncode == 0, f"ctest failed:\n{out.stdout}\n{out.stderr}"
     assert "100% tests passed" in out.stdout
+
+
+def test_library_is_rebuilt_when_its_sources_change(tmp_path, monkeypatch) -> None:
+    """The build is trusted by a digest of its sources stamped beside the
+    library, not by the library merely existing: the chip tool copies the
+    working tree as it stands, stale build products included."""
+    from torchft_tpu import _native
+
+    # The library this process loaded carries the stamp of the sources on disk.
+    assert _native._built_from(_native.source_digest())
+
+    root = tmp_path / "repo"
+    for rel in ("native/src", "native/tests", "proto"):
+        shutil.copytree(os.path.join(REPO, rel), root / rel)
+    for rel in ("native/CMakeLists.txt", "native/gen_pb_local.py"):
+        shutil.copy(os.path.join(REPO, rel), root / rel)
+    lib = root / "torchft_tpu" / "_lib" / "libtpuft.so"
+    pb2 = root / "torchft_tpu" / "proto" / "tpuft_pb2.py"
+    monkeypatch.setattr(_native, "_REPO_ROOT", str(root))
+    monkeypatch.setattr(_native, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(_native, "_STAMP_PATH", str(lib.with_suffix(".digest")))
+    monkeypatch.setattr(_native, "_PB2_PATH", str(pb2))
+    builds = []
+
+    def fake_build() -> None:
+        builds.append(_native.source_digest())
+        pb2.parent.mkdir(parents=True, exist_ok=True)
+        lib.write_bytes(b"")
+        pb2.write_text("")
+
+    monkeypatch.setattr(_native, "_build_native", fake_build)
+    _native._ensure_built()  # nothing built yet
+    _native._ensure_built()  # stamped: trusted
+    assert len(builds) == 1
+    with open(root / "native" / "src" / "wire.h", "a") as f:
+        f.write("// edited\n")
+    _native._ensure_built()  # a header changed: the library on disk is stale
+    assert len(builds) == 2 and builds[0] != builds[1]
+    lib.with_suffix(".digest").unlink()
+    _native._ensure_built()  # a library without a stamp is not trusted either
+    assert len(builds) == 3

@@ -101,8 +101,13 @@ def _sig(obj) -> str:
     except (TypeError, ValueError):
         return "(...)"
     # Default values whose repr embeds a memory address (dataclass
-    # factories, bound objects) are unstable across runs.
-    return re.sub(r"<[^>]*>", "...", sig)
+    # factories, bound objects) or the state of a stream (sys.stdout, whose
+    # repr nests "<stdout>" and names a mode that differs under pytest) are
+    # unstable across runs: innermost brackets first, until none is left.
+    while True:
+        sig, n = re.subn(r"<[^<>]*>", "...", sig)
+        if not n:
+            return sig
 
 
 def _summary(obj) -> str:

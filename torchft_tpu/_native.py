@@ -18,13 +18,14 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import threading
 from dataclasses import dataclass, field
 from typing import List, Optional
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_lib", "libtpuft.so")
-_BUILD_LOCK = threading.Lock()
+_PKG_DIR = os.path.dirname(os.path.abspath(__file__))
+_LIB_PATH = os.path.join(_PKG_DIR, "_lib", "libtpuft.so")
+_STAMP_PATH = os.path.join(_PKG_DIR, "_lib", "libtpuft.digest")
+_PB2_PATH = os.path.join(_PKG_DIR, "proto", "tpuft_pb2.py")
 
 # Wire status codes (native/src/wire.h).
 _OK = 0
@@ -66,16 +67,16 @@ NATIVE_SOURCES = (
 )
 
 
-def _build_native_gxx() -> None:
+def _build_native_gxx(native_dir: str) -> None:
     """Toolchain-less fallback: gen_pb_local.py + plain g++ -shared (the
     recipe native/gen_pb_local.py documents).  Used when cmake/ninja are
-    absent but g++ exists — the shape of the container this repo's CI
-    runs in."""
+    absent but g++ exists.  The generated header lands in this checkout's
+    own build directory, never in a path other checkouts share."""
     import sys
 
-    native_dir = os.path.join(_REPO_ROOT, "native")
+    gen_dir = os.path.join(native_dir, "build-g++", "gen")
     subprocess.run(
-        [sys.executable, os.path.join(native_dir, "gen_pb_local.py")],
+        [sys.executable, os.path.join(native_dir, "gen_pb_local.py"), gen_dir],
         check=True,
         capture_output=True,
         timeout=120,
@@ -87,7 +88,7 @@ def _build_native_gxx() -> None:
         # engine's f32 combine + wire-codec loops are the data plane's
         # arithmetic hot path.
         ["g++", "-std=c++17", "-O3", "-fPIC", "-shared",
-         "-I", os.path.join(native_dir, "src"), "-I", "/tmp/tpuftpb",
+         "-I", os.path.join(native_dir, "src"), "-I", gen_dir,
          *srcs, "-o", _LIB_PATH, "-lpthread"],
         check=True,
         capture_output=True,
@@ -101,10 +102,10 @@ def _build_native() -> None:
     containers."""
     import shutil
 
-    if shutil.which("cmake") is None or shutil.which("ninja") is None:
-        _build_native_gxx()
-        return
     native_dir = os.path.join(_REPO_ROOT, "native")
+    if shutil.which("cmake") is None or shutil.which("ninja") is None:
+        _build_native_gxx(native_dir)
+        return
     build_dir = os.path.join(native_dir, "build")
     subprocess.run(
         ["cmake", "-B", build_dir, "-G", "Ninja", native_dir],
@@ -117,14 +118,61 @@ def _build_native() -> None:
     subprocess.run(["ninja", "-C", build_dir], check=True, capture_output=True)
 
 
+def source_digest() -> str:
+    """sha256 over everything the native build reads: ``native/src/*``,
+    ``native/tests/*`` (the default target set builds the C++ suite), the
+    two build recipes and the proto.  Stamped beside the library; a
+    differing stamp means the library was built from other sources."""
+    import glob
+    import hashlib
+
+    native_dir = os.path.join(_REPO_ROOT, "native")
+    files = sorted(
+        glob.glob(os.path.join(native_dir, "src", "*"))
+        + glob.glob(os.path.join(native_dir, "tests", "*"))
+    ) + [
+        os.path.join(native_dir, "CMakeLists.txt"),
+        os.path.join(native_dir, "gen_pb_local.py"),
+        os.path.join(_REPO_ROOT, "proto", "tpuft.proto"),
+    ]
+    digest = hashlib.sha256()
+    for path in files:
+        digest.update(os.path.relpath(path, _REPO_ROOT).encode())
+        with open(path, "rb") as f:
+            digest.update(f.read())
+    return digest.hexdigest()
+
+
+def _built_from(digest: str) -> bool:
+    if not (os.path.exists(_LIB_PATH) and os.path.exists(_PB2_PATH)):
+        return False
+    try:
+        with open(_STAMP_PATH, encoding="utf-8") as f:
+            return f.read().strip() == digest
+    except OSError:
+        return False
+
+
 def _ensure_built() -> None:
-    pb2 = os.path.join(os.path.dirname(os.path.abspath(__file__)), "proto", "tpuft_pb2.py")
-    if os.path.exists(_LIB_PATH) and os.path.exists(pb2):
+    """Builds the library unless the one on disk carries the stamp of the
+    sources on disk.  Serialised by a file lock — across processes (launcher
+    children importing for the first time would otherwise race the build)
+    and across threads alike, each call locking its own open file."""
+    import fcntl
+
+    digest = source_digest()
+    if _built_from(digest):
         return
-    with _BUILD_LOCK:
-        if os.path.exists(_LIB_PATH) and os.path.exists(pb2):
+    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    with open(_LIB_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _built_from(digest):
             return
         _build_native()
+        tmp = f"{_STAMP_PATH}.{os.getpid()}"
+        with open(tmp, "w", encoding="utf-8") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, _STAMP_PATH)
 
 
 _ensure_built()
@@ -174,21 +222,15 @@ def _load_lib() -> ctypes.CDLL:
     lib.tf_lighthouse_link_state.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
     lib.tf_lighthouse_flight_json.restype = ctypes.c_void_p
     lib.tf_lighthouse_flight_json.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-    try:
-        # Federation surface (docs/wire.md "Federation").  Declared inside a
-        # probe: a stale .so without the symbols predates the two-tier
-        # topology — LighthouseServer.set_federation raises a clear error
-        # and regions_json degrades to an empty rollup.
-        lib.tf_lighthouse_set_federation.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_char_p,
-            ctypes.c_char_p,
-            ctypes.c_int64,
-        ]
-        lib.tf_lighthouse_regions_json.restype = ctypes.c_void_p
-        lib.tf_lighthouse_regions_json.argtypes = [ctypes.c_void_p]
-    except AttributeError:
-        pass
+    # Federation surface (docs/wire.md "Federation").
+    lib.tf_lighthouse_set_federation.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_char_p,
+        ctypes.c_int64,
+    ]
+    lib.tf_lighthouse_regions_json.restype = ctypes.c_void_p
+    lib.tf_lighthouse_regions_json.argtypes = [ctypes.c_void_p]
     lib.tf_lighthouse_shutdown.argtypes = [ctypes.c_void_p]
     lib.tf_lighthouse_free.argtypes = [ctypes.c_void_p]
     lib.tf_manager_new.restype = ctypes.c_void_p
@@ -220,20 +262,14 @@ def _load_lib() -> ctypes.CDLL:
     ]
     lib.tf_manager_flight_json.restype = ctypes.c_void_p
     lib.tf_manager_flight_json.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-    try:
-        # Goodput-ledger push (heartbeat fields 14-16).  Declared inside a
-        # probe: a stale .so without the symbol degrades to status-only
-        # heartbeats (ManagerServer.set_ledger becomes a no-op) instead of
-        # failing the module import.
-        lib.tf_manager_set_ledger.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_double,
-            ctypes.c_double,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int32,
-        ]
-    except AttributeError:
-        pass
+    # Goodput-ledger push (heartbeat fields 14-16).
+    lib.tf_manager_set_ledger.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_double,
+        ctypes.c_double,
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int32,
+    ]
     lib.tf_manager_shutdown.argtypes = [ctypes.c_void_p]
     lib.tf_manager_free.argtypes = [ctypes.c_void_p]
     lib.tf_store_new.restype = ctypes.c_void_p
@@ -266,169 +302,146 @@ def _load_lib() -> ctypes.CDLL:
 _lib = _load_lib()
 
 
-def _bind_ring(lib: ctypes.CDLL) -> Optional[str]:
-    """Declares the tf_ring_* signatures; returns a human-readable reason
-    when the loaded libtpuft.so predates the ring engine (stale build) —
-    the capability probe TCPCollective's engine selection reads."""
-    try:
-        lib.tf_ring_new.restype = ctypes.c_void_p
-        lib.tf_ring_new.argtypes = [ctypes.c_int32, ctypes.c_double, ctypes.c_double]
-        lib.tf_ring_set_tier.restype = ctypes.c_int
-        lib.tf_ring_set_tier.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_close.argtypes = [ctypes.c_void_p]
-        lib.tf_ring_free.argtypes = [ctypes.c_void_p]
-        lib.tf_ring_detach.restype = ctypes.c_int
-        lib.tf_ring_detach.argtypes = [
-            ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_open_fds.restype = ctypes.c_int
-        lib.tf_ring_open_fds.argtypes = [ctypes.c_void_p]
-        lib.tf_ring_exchange.restype = ctypes.c_int
-        lib.tf_ring_exchange.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_uint32,
-            ctypes.c_char_p,
-            ctypes.c_size_t,
-            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
-            ctypes.POINTER(ctypes.c_size_t),
-            ctypes.c_double,
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_pass.restype = ctypes.c_int
-        lib.tf_ring_pass.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_uint32,
-            ctypes.c_uint32,
-            ctypes.c_uint32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_double,
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_pass_multi.restype = ctypes.c_int
-        lib.tf_ring_pass_multi.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_uint32,
-            ctypes.c_uint32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_double,
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_set_shm.restype = ctypes.c_int
-        lib.tf_ring_set_shm.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_char_p,
-            ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_char_p),
-        ]
-        lib.tf_ring_counters.restype = ctypes.c_int
-        lib.tf_ring_counters.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_int32,
-        ]
-        lib.tf_ring_shaper_counters.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-        ]
-        lib.tf_ring_link_bytes.restype = ctypes.c_uint64
-        lib.tf_ring_link_bytes.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-        ]
-        # Data-plane flight recorder (hop telemetry, PR 14).  Declared with
-        # the base ring symbols: a .so that has tf_ring_new but not these
-        # is a stale build, and a silent half-capability engine would
-        # break the cross-engine telemetry-parity contract.
-        lib.tf_ring_set_hop.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-        ]
-        lib.tf_ring_hop_stats.restype = ctypes.c_int
-        lib.tf_ring_hop_stats.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_double),
-        ]
-        lib.tf_ring_hop_records.restype = ctypes.c_int
-        lib.tf_ring_hop_records.argtypes = [
-            ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_int32,
-        ]
-        lib.tf_ring_shaper_wait_s.restype = ctypes.c_double
-        lib.tf_ring_shaper_wait_s.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-        ]
-        lib.tf_ring_set_shaper.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_double,
-            ctypes.c_double,
-        ]
-    except AttributeError:
-        return (
-            f"libtpuft.so at {_LIB_PATH} lacks the ring-engine symbols "
-            "(stale build predating native/src/ring.cc) — rebuild it: "
-            "python native/gen_pb_local.py && the g++ recipe in that "
-            "file's docstring (or cmake/ninja)"
-        )
-    return None
+def _bind_ring(lib: ctypes.CDLL) -> None:
+    """Declares the tf_ring_* signatures of the GIL-free ring engine
+    (native/src/ring.cc).  A library without them was not built from
+    these sources; the AttributeError is the build error."""
+    lib.tf_ring_new.restype = ctypes.c_void_p
+    lib.tf_ring_new.argtypes = [ctypes.c_int32, ctypes.c_double, ctypes.c_double]
+    lib.tf_ring_set_tier.restype = ctypes.c_int
+    lib.tf_ring_set_tier.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_close.argtypes = [ctypes.c_void_p]
+    lib.tf_ring_free.argtypes = [ctypes.c_void_p]
+    lib.tf_ring_detach.restype = ctypes.c_int
+    lib.tf_ring_detach.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_open_fds.restype = ctypes.c_int
+    lib.tf_ring_open_fds.argtypes = [ctypes.c_void_p]
+    lib.tf_ring_exchange.restype = ctypes.c_int
+    lib.tf_ring_exchange.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_uint32,
+        ctypes.c_char_p,
+        ctypes.c_size_t,
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
+        ctypes.POINTER(ctypes.c_size_t),
+        ctypes.c_double,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_pass.restype = ctypes.c_int
+    lib.tf_ring_pass.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_uint32,
+        ctypes.c_uint32,
+        ctypes.c_uint32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_double,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_pass_multi.restype = ctypes.c_int
+    lib.tf_ring_pass_multi.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_uint32,
+        ctypes.c_uint32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_double,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_set_shm.restype = ctypes.c_int
+    lib.tf_ring_set_shm.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_char_p,
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.tf_ring_counters.restype = ctypes.c_int
+    lib.tf_ring_counters.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_int32,
+    ]
+    lib.tf_ring_shaper_counters.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
+    lib.tf_ring_link_bytes.restype = ctypes.c_uint64
+    lib.tf_ring_link_bytes.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_int32,
+    ]
+    # Data-plane flight recorder (hop telemetry, PR 14).
+    lib.tf_ring_set_hop.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+    ]
+    lib.tf_ring_hop_stats.restype = ctypes.c_int
+    lib.tf_ring_hop_stats.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_double),
+    ]
+    lib.tf_ring_hop_records.restype = ctypes.c_int
+    lib.tf_ring_hop_records.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int32,
+    ]
+    lib.tf_ring_shaper_wait_s.restype = ctypes.c_double
+    lib.tf_ring_shaper_wait_s.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+    ]
+    lib.tf_ring_set_shaper.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_int32,
+        ctypes.c_int32,
+        ctypes.c_double,
+        ctypes.c_double,
+    ]
 
 
-_RING_UNAVAILABLE: Optional[str] = _bind_ring(_lib)
-
-
-def ring_engine_available() -> bool:
-    """True when the loaded native library exports the GIL-free ring
-    engine (tf_ring_*).  False means a stale libtpuft.so; see
-    :func:`ring_engine_unavailable_reason`."""
-    return _RING_UNAVAILABLE is None
-
-
-def ring_engine_unavailable_reason() -> str:
-    return _RING_UNAVAILABLE or ""
+_bind_ring(_lib)
 
 
 def _take_string(ptr: int) -> str:
@@ -664,11 +677,6 @@ class LighthouseServer:
         behave exactly as before."""
         if not self._ptr:
             return
-        if not hasattr(_lib, "tf_lighthouse_set_federation"):
-            raise RuntimeError(
-                "libtpuft.so predates the federation surface "
-                "(tf_lighthouse_set_federation missing) — rebuild native/"
-            )
         _lib.tf_lighthouse_set_federation(
             self._ptr, region.encode(), root_addrs.encode(), int(push_interval_ms)
         )
@@ -680,7 +688,7 @@ class LighthouseServer:
         role is "root"/"child"/"flat".  A root lists one row per region
         with digest freshness and ledger rollups; a child lists its own
         region; a flat instance lists nothing."""
-        if not self._ptr or not hasattr(_lib, "tf_lighthouse_regions_json"):
+        if not self._ptr:
             return '{"role":"flat","region":"","regions":[]}'
         return _take_string(_lib.tf_lighthouse_regions_json(self._ptr))
 
@@ -1057,9 +1065,8 @@ class ManagerServer:
         productive fraction, productive seconds, and per-cause lost
         seconds in the PINNED taxonomy order
         (:data:`torchft_tpu.obs.ledger.LOST_CAUSES`).  Called once per
-        commit vote; counters are monotonic per incarnation.  No-op
-        against a stale libtpuft.so without the symbol."""
-        if not self._ptr or not hasattr(_lib, "tf_manager_set_ledger"):
+        commit vote; counters are monotonic per incarnation."""
+        if not self._ptr:
             return
         arr = (ctypes.c_double * len(lost_seconds))(*lost_seconds)
         _lib.tf_manager_set_ledger(
@@ -1223,8 +1230,6 @@ class RingEngine:
     WIRE_INT4 = 3
 
     def __init__(self, lanes: int, shaper_mbps: float = 0.0, shaper_rtt_ms: float = 0.0) -> None:
-        if _RING_UNAVAILABLE is not None:
-            raise RuntimeError(_RING_UNAVAILABLE)
         self._ptr = _lib.tf_ring_new(int(lanes), float(shaper_mbps), float(shaper_rtt_ms))
         self._lanes = int(lanes)
         # Python→native boundary crossings on the data path (ring_pass +

@@ -1057,9 +1057,9 @@ _native_fallback_warned = False
 
 def _warn_native_fallback(reason: str) -> None:
     """One clear line per process when TPUFT_RING_ENGINE=native was
-    requested but the loaded libtpuft.so predates the ring engine — a
-    silent Python fallback here would report CPU-bound numbers as if they
-    were the native data plane's."""
+    requested but the engine could not be constructed — a silent Python
+    fallback here would report CPU-bound numbers as if they were the native
+    data plane's."""
     global _native_fallback_warned
     if _native_fallback_warned:
         return
@@ -1688,10 +1688,6 @@ class TCPCollective(Collective):
             return None
         from torchft_tpu import _native
 
-        if not _native.ring_engine_available():
-            if self._engine_mode == "native":
-                _warn_native_fallback(_native.ring_engine_unavailable_reason())
-            return None
         mbps = rtt_ms = 0.0
         spec = os.environ.get("TPUFT_SHAPED_LINK")
         if spec:

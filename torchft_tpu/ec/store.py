@@ -360,6 +360,8 @@ def _reconstruct_subset_striped(
 
 
 def fetch_inventory(base_url: str, step: int, timeout: float) -> dict:
+    """One shard holder's inventory for ``step`` (``GET /ec/have/<step>``):
+    which shard indices it can serve — what :func:`reconstruct` probes."""
     with _urlopen(f"{base_url}/ec/have/{step}", timeout) as resp:
         return json.loads(resp.read().decode())
 

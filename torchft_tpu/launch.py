@@ -34,15 +34,35 @@ import subprocess
 import sys
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, MutableMapping, Optional
 
 logger = logging.getLogger(__name__)
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # A group that exits in under this many seconds is treated as crash-looping
 # and restarted with exponential backoff rather than immediately.
 _MIN_UPTIME_S = 5.0
 
-__all__ = ["Launcher", "fetch_alerts", "main"]
+__all__ = ["Launcher", "export_compile_cache", "fetch_alerts", "main"]
+
+
+def export_compile_cache(env: Optional[MutableMapping[str, str]] = None) -> str:
+    """Places JAX's persistent compile cache from OUTSIDE the program and
+    returns the directory: where ``JAX_COMPILATION_CACHE_DIR`` is already
+    set, that holds and nothing is touched; otherwise ``<repo>/.jax_cache``
+    (git-ignored) is exported into ``env`` (default: this process's
+    environment) under the same standard variable, so every child inherits
+    it.  The path is part of the cache's key — a directory that moves never
+    hits — and a restarted group re-JITs from disk instead of recompiling.
+
+    JAX reads the variable when it is imported, so a process that wants the
+    cache for ITSELF calls this before its first ``import jax``; no code of
+    this repo sets the location through ``jax.config``."""
+    env = os.environ if env is None else env
+    return env.setdefault(
+        "JAX_COMPILATION_CACHE_DIR", os.path.join(_REPO_ROOT, ".jax_cache")
+    )
 
 
 def fetch_alerts(http_address: str, timeout: float = 2.0):
@@ -70,9 +90,10 @@ def fetch_alerts(http_address: str, timeout: float = 2.0):
 
 @dataclass
 class _Spare:
-    """A hot-spare process: fully spawned (imports + JAX backend init done
-    while idle), blocked in the example harness's ``replica_env`` until the
-    supervisor writes its go-file with a replica-group id."""
+    """A hot-spare process: spawned and initialized as far as it may be
+    while idle (see ``Launcher``'s ``spares``), blocked in the example
+    harness's ``replica_env`` until the supervisor writes its go-file with
+    a replica-group id."""
 
     proc: subprocess.Popen
     log: Optional[object]
@@ -139,14 +160,20 @@ class Launcher:
         join_timeout_ms: embedded Lighthouse straggler wait.
         log_dir: per-group logs land in ``<log_dir>/g<i>.log`` (append);
             None inherits this process's stdout/stderr.
-        cache_dir: shared persistent XLA compile cache — a restarted group
-            re-JITs from disk instead of recompiling, shrinking recovery.
         env: extra environment for every group (overrides inherited; a None
-            value unsets the variable).
+            value unsets the variable).  The groups also inherit the compile
+            cache location (:func:`export_compile_cache`).
+        group_env: extra environment per group id, applied over ``env`` on
+            every (re)spawn of that group — the place for what one group
+            owns alone, e.g. the TPU runtime's visibility settings that
+            confine a child to its own chip (one process per chip).  A
+            group carrying overrides never adopts a hot spare.
         cwd: working directory for the groups.
         spares: hot-spare pool size.  Spares are spawned WITHOUT a
-            ``REPLICA_GROUP_ID`` and idle fully initialized (imports + JAX
-            backend up) behind ``TPUFT_SPARE_FILE``; when a group dies,
+            ``REPLICA_GROUP_ID`` and idle initialized (imports done; the JAX
+            backend too where it is not exclusive, i.e. under
+            ``JAX_PLATFORMS=cpu`` — a spare never takes a chip its live
+            group needs) behind ``TPUFT_SPARE_FILE``; when a group dies,
             ``spawn`` hands the dead group's id to a ready spare by writing
             that file — adoption skips the process-spawn + runtime-init
             floor that dominates cold-restart downtime (kill-bench
@@ -174,8 +201,8 @@ class Launcher:
         min_replicas: int = 1,
         join_timeout_ms: int = 2000,
         log_dir: Optional[str] = None,
-        cache_dir: Optional[str] = None,
         env: Optional[Dict[str, Optional[str]]] = None,
+        group_env: Optional[Dict[int, Dict[str, str]]] = None,
         cwd: Optional[str] = None,
         spares: int = 0,
         straggler_auto_drain: Optional[bool] = None,
@@ -187,7 +214,10 @@ class Launcher:
         self._max_restarts = max_restarts
         self._log_dir = log_dir
         self._cwd = cwd
-        self._groups: Dict[int, _Group] = {i: _Group() for i in range(num_groups)}
+        self._groups: Dict[int, _Group] = {
+            i: _Group(env=dict((group_env or {}).get(i, {})))
+            for i in range(num_groups)
+        }
         self._embedded = None
         self._spares_target = max(0, spares)
         self._spares: List[_Spare] = []
@@ -249,8 +279,7 @@ class Launcher:
         )
         if lighthouse_addr:
             base["TPUFT_LIGHTHOUSE"] = lighthouse_addr
-        if cache_dir:
-            base["TPUFT_COMPILE_CACHE"] = cache_dir
+        export_compile_cache(base)
         # Cooperative-drain channel: every child (groups AND spares, whose
         # group id resolves at adoption) watches <drain_dir>/drain_<gid>.json
         # through its DrainWatcher; the supervisor's drain() writes it.
@@ -951,9 +980,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     parser.add_argument("--log-dir", default=None)
     parser.add_argument(
-        "--cache-dir", default=None, help="shared persistent XLA compile cache"
-    )
-    parser.add_argument(
         "--incident-watcher", action="store_true",
         help="run the IncidentWatcher against the embedded lighthouse: "
         "auto-capture incident bundles + journal flap-guarded remediation "
@@ -1026,7 +1052,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         min_replicas=args.min_replicas,
         join_timeout_ms=args.join_timeout_ms,
         log_dir=args.log_dir,
-        cache_dir=args.cache_dir,
         spares=args.spares,
         incident_watcher=args.incident_watcher or None,
         watcher_act=args.watcher_act or None,

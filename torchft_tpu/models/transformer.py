@@ -226,7 +226,7 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
             seq_axis="sequence",
             **kwargs,
         )
-    return flash_attention(q, k, v, causal=True)
+    return flash_attention(q, k, v, causal=True, mesh=mesh)
 
 
 def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):

@@ -1,15 +1,10 @@
-"""One shard_map import/compat shim for every sharded op.
-
-JAX moved shard_map from jax.experimental to the top level, and renamed
-its replication-check kwarg (check_rep -> check_vma) along the way; this
-helper resolves whichever this jaxlib has so call sites stay
-version-agnostic.
-"""
+"""The one shard_map call every sharded op goes through."""
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional
+
+import jax
 
 
 def shard_map(
@@ -19,22 +14,12 @@ def shard_map(
     out_specs: Any,
     check: Optional[bool] = None,
 ):
-    """shard_map(f) bound to ``mesh`` with the given specs.
+    """``jax.shard_map(f)`` bound to ``mesh`` with the given specs.
 
-    ``check=None`` keeps the library default replication checking;
-    False/True pins it via whichever kwarg (check_vma / check_rep) this
-    JAX version accepts.
+    ``check=None`` keeps the library's default replication checking;
+    False/True pins ``check_vma``.
     """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-    kwargs = {}
-    if check is not None:
-        params = inspect.signature(_sm).parameters
-        if "check_vma" in params:
-            kwargs["check_vma"] = check
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    kwargs = {} if check is None else {"check_vma": check}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    )

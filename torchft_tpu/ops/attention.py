@@ -20,7 +20,8 @@ Design notes (MXU/HBM-minded):
     reduction axis innermost: TPU executes the innermost grid dimension
     sequentially, which is what makes the VMEM scratch accumulator legal.
 
-Falls back to reference XLA attention off-TPU (CPU test mesh) or for shapes
+The reference XLA attention runs off-TPU (CPU test mesh), under a
+multi-device mesh (a pallas call has no partitioning rule), and for shapes
 the kernel does not tile (seq not divisible by the block size).
 """
 
@@ -32,23 +33,20 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from torchft_tpu.ops import _pallas_util
 from torchft_tpu.ops._pallas_util import row_stat_col
 
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: scratch row-stats are kept (block_q, 128)
 
 
-def _use_pallas(seq_q: int, seq_k: int, head_dim: int) -> bool:
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:  # noqa: BLE001
-        return False
+def _use_pallas(seq_q: int, seq_k: int, head_dim: int, mesh=None) -> bool:
     bq, bk = _block_sizes(seq_q, seq_k)
     return (
         seq_q % bq == 0
         and seq_k % bk == 0
         and head_dim % _LANE == 0
+        and _pallas_util.kernels_apply(mesh)
     )
 
 
@@ -157,6 +155,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
             pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
+        name="tpuft_fa_fwd",
     )(q, k, v)
     return out, lse_padded[:, :, 0]
 
@@ -341,6 +340,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         out_specs=tuple(out_specs),
         scratch_shapes=dkdv_scratch,
         interpret=interpret,
+        name="tpuft_fa_bwd_dkdv",
     )(q, k, v, g, lse, delta)
     if merged:
         dk, dv, dq_part = outs
@@ -367,6 +367,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         out_specs=qo_spec_ij,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="tpuft_fa_bwd_dq",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -387,26 +388,28 @@ def _fa_reference(q, k, v, scale: float, causal: bool):
     return o.astype(q.dtype), lse
 
 
-def _fa_forward(q, k, v, scale: float, causal: bool):
-    if _use_pallas(q.shape[1], k.shape[1], q.shape[2]):
+def _fa_forward(q, k, v, scale: float, causal: bool, kernel: bool):
+    if kernel:
         return _fa_pallas_call(q, k, v, scale, causal)
     return _fa_reference(q, k, v, scale, causal)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, scale: float, causal: bool):
-    o, _ = _fa_forward(q, k, v, scale, causal)
+# `kernel` is decided once, in flash_attention, from the shapes and the mesh
+# of the program being traced, so forward and backward cannot disagree.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, scale: float, causal: bool, kernel: bool):
+    o, _ = _fa_forward(q, k, v, scale, causal, kernel)
     return o
 
 
-def _flash_fwd(q, k, v, scale, causal):
-    o, lse = _fa_forward(q, k, v, scale, causal)
+def _flash_fwd(q, k, v, scale, causal, kernel):
+    o, lse = _fa_forward(q, k, v, scale, causal, kernel)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, res, g):
+def _flash_bwd(scale, causal, kernel, res, g):
     q, k, v, o, lse = res
-    if _use_pallas(q.shape[1], k.shape[1], q.shape[2]):
+    if kernel:
         return _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal)
     return _fa_bwd_xla(q, k, v, o, lse, g, scale, causal)
 
@@ -440,10 +443,14 @@ def flash_attention(
     v: jax.Array,
     causal: bool = True,
     scale: float | None = None,
+    mesh=None,
 ) -> jax.Array:
     """Multi-head attention; q: [B, Hq, S, D], k/v: [B, Hkv, S, D].
 
     GQA: Hkv may divide Hq; kv heads are broadcast to query groups.
+    ``mesh`` is the mesh of the program being traced (None: the ambient
+    abstract mesh); under more than one device the XLA formulation runs,
+    see ``_pallas_util.kernels_apply``.
     """
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
@@ -459,5 +466,6 @@ def flash_attention(
         v.reshape(b * hq, v.shape[2], d),
         scale,
         causal,
+        _use_pallas(sq, k.shape[2], d, mesh),
     )
     return out.reshape(b, hq, sq, d)

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from torchft_tpu.ops._pallas_util import on_tpu, row_stat_col
+from torchft_tpu.ops import _pallas_util
+from torchft_tpu.ops._pallas_util import row_stat_col
 
 _LANE = 128
 
@@ -73,41 +74,18 @@ def _block_rows(n: int, e: int) -> Optional[int]:
 
 
 def fused_ce_applicable(n: int, e: int, v: int, mesh=None) -> bool:
-    """True when the pallas kernels can and should run.
-
-    mesh.size > 1 is excluded: a pallas custom call has no SPMD
-    partitioning rule, so under a real multi-device mesh XLA would
-    all-gather the operands to run it replicated — correct but a perf
-    cliff.  Sharded configurations keep the plain XLA formulation, which
-    propagates shardings (vocab-parallel logsumexp etc.) natively.
-    """
-    if not on_tpu():
-        return False
-    if mesh is None:
-        # Callers that omit mesh (e.g. single-arg loss_fn closures) may
-        # still be tracing under a multi-device GSPMD jit; fall back to
-        # the ambient abstract mesh, then the process device count.  The
-        # device-count check also turns the kernel off for a genuinely
-        # single-device jit on a multi-chip host, which is a deliberate
-        # asymmetric trade: the unfused XLA path is wall-neutral there
-        # (docs/architecture.md — the fusion's win is HBM residency),
-        # while running the pallas custom call replicated under a
-        # sharded jit is a large silent cliff.  Multi-chip callers that
-        # want the kernel single-device pass mesh explicitly.
-        amesh = jax.sharding.get_abstract_mesh()
-        if amesh is not None and not amesh.empty and amesh.size > 1:
-            return False
-        if jax.device_count() > 1:
-            return False
-    elif getattr(mesh, "size", 1) > 1:
-        return False
-    # Blocks are solved against explicit per-operand VMEM budgets, so the
-    # gate is simply "a valid tiling exists" — no separate size check that
-    # could drift from what the kernels actually allocate.
+    """True when the pallas kernels can and should run: a valid tiling
+    exists (blocks are solved against explicit per-operand VMEM budgets, so
+    no separate size check can drift from what the kernels allocate), and
+    the program being traced runs on one TPU device
+    (``_pallas_util.kernels_apply``: ``mesh`` when given, else the ambient
+    abstract mesh).  Sharded configurations keep the plain XLA formulation,
+    which propagates shardings (vocab-parallel logsumexp etc.) natively."""
     return (
         _block_v(v, e) is not None
         and _block_rows(n, e) is not None
         and e % _LANE == 0
+        and _pallas_util.kernels_apply(mesh)
     )
 
 
@@ -187,6 +165,7 @@ def _ce_lse_pallas(x, w, interpret: bool = False):
             pltpu.VMEM((br, _LANE), jnp.float32),   # running sumexp
         ],
         interpret=interpret,
+        name="tpuft_ce_lse",
     )(x, w)
     return lse[0, 0]
 
@@ -218,6 +197,7 @@ def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
         interpret=interpret,
+        name="tpuft_ce_dlogits",
     )(x, w, tgt, lse3, scale2)
 
 
@@ -244,7 +224,7 @@ def fused_linear_cross_entropy(x, w, targets):
 
 
 def _ce_fwd(x, w, targets, interpret: bool = False):
-    if on_tpu() or interpret:
+    if _pallas_util.on_tpu() or interpret:
         lse = _ce_lse_pallas(x, w, interpret=interpret)
         return lse, _target_logit(x, w, targets)
     logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
@@ -262,7 +242,7 @@ def _ce_vjp_bwd(res, g):
     x, w, targets, lse = res
     n = x.shape[0]
     scale = g / n
-    if on_tpu():
+    if _pallas_util.on_tpu():
         # dlogits tile-by-tile in bf16 (pallas) — the f32 logits never
         # exist in HBM.
         dl = _ce_dlogits_pallas(x, w, targets, lse, scale)

@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from torchft_tpu.ops import _pallas_util
+
 __all__ = ["rms_norm", "rms_norm_pallas"]
 
 
@@ -37,13 +39,6 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # -- pallas kernel variant ---------------------------------------------------
-
-
-def _use_pallas() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -73,6 +68,7 @@ def _rms_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="tpuft_rms",
     )(x, w)
 
 
@@ -84,7 +80,7 @@ def rms_norm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def _rms_forward_impl(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    if _use_pallas() and x.ndim >= 2:
+    if _pallas_util.kernels_apply() and x.ndim >= 2:
         flat = x.reshape(-1, x.shape[-1])
         return _rms_pallas(flat, w, eps).reshape(x.shape)
     return rms_norm(x, w, eps)

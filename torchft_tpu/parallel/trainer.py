@@ -60,27 +60,27 @@ def tree_device_bytes(tree: Any) -> int:
     return total
 
 
-def speculation_fits(extra_bytes: int, device: Any) -> Optional[bool]:
+def speculation_fits(extra_bytes: int, device: Any, floor: int = 0) -> Optional[bool]:
     """Whether an extra `extra_bytes` fits the device's free HBM.
 
-    Budgets against the allocator's PEAK (when reported), not the
-    current bytes_in_use: callers decide after a full step has executed,
-    and the peak is what proves the step's activation/workspace
-    footprint coexisted with the resident state.  Returns None when the
-    runtime exposes no memory statistics (CPU devices; some TPU
-    tunnels) — the caller decides the default."""
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001
-        return None
+    Budgets against the step's HIGH-WATER mark, not the current
+    bytes_in_use: under async dispatch the speculative apply is enqueued
+    while the gradient program may still hold its whole footprint, so the
+    two coexist.  The mark is the largest of bytes_in_use, the allocator's
+    peak (when reported) and `floor` — the caller's own lower bound, e.g.
+    the compiler's footprint of the step's programs: on a v5e the
+    allocator's peak read ~1 GB under what the 1B-width gradient program
+    holds (CHANGES.md PR 21), which green-lit a speculative apply that then
+    could not be allocated.  Returns None when the runtime exposes no
+    memory statistics (CPU devices) — the caller decides the default."""
+    stats = device.memory_stats()
     if not stats:
         return None
     limit = stats.get("bytes_limit")
     in_use = stats.get("bytes_in_use")
     if limit is None or in_use is None:
         return None
-    peak = stats.get("peak_bytes_in_use")
-    high_water = max(in_use, peak) if peak is not None else in_use
+    high_water = max(in_use, stats.get("peak_bytes_in_use") or 0, floor)
     return extra_bytes <= (limit - high_water) * _SPECULATION_HEADROOM
 
 
@@ -126,9 +126,14 @@ class TrainStep:
         mesh = self.ftmesh.mesh
 
         def value_and_grad(params, batch):
-            if self.value_and_grad_fn is not None:
-                return self.value_and_grad_fn(params, batch)
-            return jax.value_and_grad(self.loss_fn)(params, batch)
+            # Shardings are explicit NamedShardings; the abstract mesh is
+            # set only so the kernel gate (ops/_pallas_util.kernels_apply)
+            # sees the mesh this program is traced for even when the loss
+            # closure does not pass it down.
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                if self.value_and_grad_fn is not None:
+                    return self.value_and_grad_fn(params, batch)
+                return jax.value_and_grad(self.loss_fn)(params, batch)
 
         def apply(params, opt_state, grads):
             import optax
@@ -141,7 +146,6 @@ class TrainStep:
             params, opt_state = apply(params, opt_state, grads)
             return params, opt_state, loss
 
-        del mesh  # shardings are explicit NamedShardings; no ambient mesh needed
         self._grads_fn = jax.jit(value_and_grad)
         self._apply_fn = jax.jit(apply, donate_argnums=(0, 1))
         # Speculative variant for the overlapped commit path: the old
@@ -165,14 +169,29 @@ class TrainStep:
     def grads(self, params, batch):
         return self._grads_fn(params, batch)
 
+    def lower_grads(self, params, batch):
+        """The gradient program, lowered for these arguments (arrays or
+        ``jax.ShapeDtypeStruct``s with shardings) and not run: ``.compile()``
+        gives ``as_text()`` (is a kernel in it?) and ``memory_analysis()``."""
+        return self._grads_fn.lower(params, batch)
+
     def apply(self, params, opt_state, grads):
         return self._apply_fn(params, opt_state, grads)
 
     # -- fault-tolerant step -------------------------------------------------
 
-    def _resolve_overlap(self, params: Any, opt_state: Any) -> None:
-        """Decide overlap_commit from post-step device memory stats."""
-        extra = tree_device_bytes(params) + tree_device_bytes(opt_state)
+    @property
+    def overlap_resolved(self) -> Optional[bool]:
+        """Which way ``overlap_commit`` went: the forced value, or — for the
+        default None — what the first committed ``ft_step`` decided from the
+        device's memory statistics (None until then)."""
+        return self._overlap_resolved
+
+    def _resolve_overlap(self, params: Any, opt_state: Any, batch: Any) -> None:
+        """Decide overlap_commit from post-step device memory stats and the
+        compiler's footprint of the gradient program that just ran."""
+        opt_bytes = tree_device_bytes(opt_state)
+        extra = tree_device_bytes(params) + opt_bytes
         device = None
         for leaf in jax.tree.leaves(params):
             devs = getattr(leaf, "devices", None)
@@ -182,6 +201,22 @@ class TrainStep:
                     device = next(iter(ds))
                     break
         fits = speculation_fits(extra, device) if device is not None else None
+        if fits:
+            # The program is in the jit cache, so this neither traces nor
+            # compiles again.  Its arguments hold params and batch, its
+            # outputs the gradients; whatever else lives on the device
+            # (another replica's state, say) is in_use minus our own.
+            ma = self._grads_fn.lower(params, batch).compile().memory_analysis()
+            if ma is not None:
+                other = max(0, device.memory_stats()["bytes_in_use"] - extra)
+                floor = (
+                    other
+                    + opt_bytes
+                    + ma.argument_size_in_bytes
+                    + ma.output_size_in_bytes
+                    + ma.temp_size_in_bytes
+                )
+                fits = speculation_fits(extra, device, floor)
         self._overlap_resolved = True if fits is None else fits
         logger.info(
             "overlap_commit auto: %s (extra %.2f GB for the speculative "
@@ -246,5 +281,5 @@ class TrainStep:
         # optimizer-apply footprint the budget must cover.
         if resolve_after and committed:
             jax.block_until_ready(jax.tree.leaves(params))
-            self._resolve_overlap(params, opt_state)
+            self._resolve_overlap(params, opt_state, batch)
         return params, opt_state, loss, committed
