@@ -1,0 +1,7 @@
+"""`ft_step_self_ms` where four groups train together; moves `tokens_per_s.4g`."""
+
+from benchmark.spec import reader_beside
+
+_same = reader_beside(__file__, "ft_step_self_ms")
+LAYER, UNIT, SOURCE, read = _same.LAYER, _same.UNIT, _same.SOURCE, _same.read
+MOVES = "tokens_per_s.4g"

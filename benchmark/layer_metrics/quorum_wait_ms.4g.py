@@ -1,0 +1,8 @@
+"""`quorum_wait_ms` where four groups train together: read as in the one-group
+cells, and what it moves is `tokens_per_s.4g`."""
+
+from benchmark.spec import reader_beside
+
+_same = reader_beside(__file__, "quorum_wait_ms")
+LAYER, UNIT, SOURCE, read = _same.LAYER, _same.UNIT, _same.SOURCE, _same.read
+MOVES = "tokens_per_s.4g"

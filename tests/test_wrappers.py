@@ -29,6 +29,7 @@ def _mock_manager(num_participants: int = 2, commit: bool = True) -> MagicMock:
         should_average: bool = True,
         allow_wire_compression: bool = True,
         donate: bool = False,
+        bucket=None,
     ):
         # Pretend every participant contributed identical values: the average
         # equals the input, so averaging is an identity we can verify around.
@@ -114,6 +115,7 @@ def test_donated_buffer_failure_leaves_grads_intact() -> None:
         should_average: bool = True,
         allow_wire_compression: bool = True,
         donate: bool = False,
+        bucket=None,
     ):
         seen["donate"] = donate
         buf = np.asarray(arr)
@@ -429,7 +431,8 @@ def test_local_sgd_commit_gates_copyback() -> None:
     manager = _mock_manager(commit=False)
 
     def fake_allreduce(
-        arr, should_average=True, allow_wire_compression=True, donate=False
+        arr, should_average=True, allow_wire_compression=True, donate=False,
+        bucket=None,
     ):
         return completed_future(np.zeros_like(np.asarray(arr)))
 

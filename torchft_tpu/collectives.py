@@ -157,10 +157,18 @@ def _is_bf16(dtype) -> bool:
 
 
 class Work:
-    """Handle for an async collective operation (the c10d Work analogue)."""
+    """Handle for an async collective operation (the c10d Work analogue).
 
-    def __init__(self, future: Future) -> None:
+    ``times`` is ``[started_ns, done_ns]`` on ``time.monotonic_ns()``: when
+    one of the engine's workers took the op up and when it finished,
+    stamped by that worker BEFORE the future resolves (so a continuation
+    reads both); 0 where the op never ran on a worker (a world of one, a
+    failure before submission).  The Manager's ``ring_queue`` / ``ring_run``
+    sub-spans read it."""
+
+    def __init__(self, future: Future, times: Optional[List[int]] = None) -> None:
         self._future = future
+        self.times: List[int] = times if times is not None else [0, 0]
 
     def wait(self, timeout: Optional[float] = None):
         return self._future.result(timeout=timeout)
@@ -2283,14 +2291,19 @@ class TCPCollective(Collective):
             err = self._op_error or RuntimeError("collective not configured")
             return Work(failed_future(err))
 
+        times = [0, 0]
+
         def run() -> object:
+            times[0] = time.monotonic_ns()
             try:
                 return fn()
             except Exception as e:  # noqa: BLE001
                 self._latch(e)
                 raise
+            finally:
+                times[1] = time.monotonic_ns()
 
-        return Work(executor.submit(run))
+        return Work(executor.submit(run), times)
 
     def _next_seq(self) -> int:
         """Ring-op sequence number, allocated at call time so identical
@@ -3288,6 +3301,7 @@ class TCPCollective(Collective):
 
         results: List[Optional[object]] = [None] * nstripes
         out: Future = Future()
+        times = [0, 0]  # Work.times: first stripe taken up, op settled
         state_lock = threading.Lock()
         state = {"pending": nstripes, "failed": False}
         with self._lock:
@@ -3302,6 +3316,7 @@ class TCPCollective(Collective):
             self._fail_ring(gen)
             with self._lock:
                 self._inflight.discard(out)
+            times[1] = time.monotonic_ns()
             if not out.done():
                 try:
                     out.set_exception(e)
@@ -3316,6 +3331,7 @@ class TCPCollective(Collective):
                 return
             with self._lock:
                 self._inflight.discard(out)
+            times[1] = time.monotonic_ns()
             if not out.done():
                 try:
                     out.set_result(outs)
@@ -3324,6 +3340,9 @@ class TCPCollective(Collective):
 
         def make_stripe(s: int):
             def run() -> None:
+                with state_lock:
+                    if not times[0]:
+                        times[0] = time.monotonic_ns()
                 try:
                     res = stripe_body(s)
                 except Exception as e:  # noqa: BLE001
@@ -3347,7 +3366,7 @@ class TCPCollective(Collective):
                 lane_exec.submit(make_stripe(s))
         except RuntimeError as e:  # executor shut down by a concurrent abort
             settle_err(e)
-        return Work(out)
+        return Work(out, times)
 
     def _striped_allreduce(
         self,

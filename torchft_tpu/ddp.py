@@ -40,11 +40,12 @@ per-parameter variant (torchft/ddp.py:74-97).
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from concurrent.futures import Future
 from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -756,6 +757,12 @@ class GradientAverager:
                 if dev is not None and not dev.multi_device:
                     flat_devs[k] = dev.prep([leaves[i] for i in bucket.indices])
 
+        def sub_of(k: int) -> Callable[..., Any]:
+            """Bucket k's sub-span factory for ``device_get_into``."""
+            return functools.partial(
+                self._manager.spans.sub, step=step, bucket=k
+            )
+
         hosts: List[Any] = []
         if not self._pipelined:
             # Monolithic reference path: one deadline-guarded fetch of the
@@ -799,10 +806,12 @@ class GradientAverager:
                     for shard, start, stop in parts:
                         view = dev.buffer[start:stop]
                         with self._manager.spans.span(
-                            "allreduce_d2h", step=step, bytes=view.nbytes
+                            "allreduce_d2h", step=step, bytes=view.nbytes, bucket=k
                         ):
                             try:
-                                device_get_into([(shard.data, view)], timeout)
+                                device_get_into(
+                                    [(shard.data, view)], timeout, sub=sub_of(k)
+                                )
                             except TimeoutError as e:
                                 self._manager.report_error(e)
                                 return grads
@@ -814,7 +823,9 @@ class GradientAverager:
                                 start,
                                 stop,
                                 view,
-                                self._manager.allreduce(view, donate=True),
+                                self._manager.allreduce(
+                                    view, donate=True, bucket=k
+                                ),
                             )
                         )
                     stats["slices"] += len(parts)
@@ -822,10 +833,12 @@ class GradientAverager:
                     pending.append(("sharded", bucket, dev, buf, slice_futs))
                 else:
                     with self._manager.spans.span(
-                        "allreduce_d2h", step=step, bytes=dev.buffer.nbytes
+                        "allreduce_d2h", step=step, bytes=dev.buffer.nbytes, bucket=k
                     ):
                         try:
-                            device_get_into([(flat_dev, dev.buffer)], timeout)
+                            device_get_into(
+                                [(flat_dev, dev.buffer)], timeout, sub=sub_of(k)
+                            )
                         except TimeoutError as e:
                             self._manager.report_error(e)
                             return grads
@@ -838,7 +851,9 @@ class GradientAverager:
                             bucket,
                             dev,
                             buf,
-                            self._manager.allreduce(dev.buffer, donate=True),
+                            self._manager.allreduce(
+                                dev.buffer, donate=True, bucket=k
+                            ),
                         )
                     )
                 continue
@@ -849,11 +864,13 @@ class GradientAverager:
                 # allreduce_d2h — this wait blocks the train thread and must
                 # be attributed as FT time, not productive compute.
                 with self._manager.spans.span(
-                    "allreduce_d2h", step=step, bytes=bucket.nbytes
+                    "allreduce_d2h", step=step, bytes=bucket.nbytes, bucket=k
                 ):
                     try:
                         device_get_into(
-                            [(leaves[i], view) for i, view in views], timeout
+                            [(leaves[i], view) for i, view in views],
+                            timeout,
+                            sub=sub_of(k),
                         )
                     except TimeoutError as e:
                         self._manager.report_error(e)
@@ -875,10 +892,10 @@ class GradientAverager:
             # the native engine reduce in place with no working-buffer copy.
             fut = (
                 self._manager.allreduce(
-                    buf, allow_wire_compression=False, donate=True
+                    buf, allow_wire_compression=False, donate=True, bucket=k
                 )
                 if bucket.wire_bypass
-                else self._manager.allreduce(buf, donate=True)
+                else self._manager.allreduce(buf, donate=True, bucket=k)
             )
             pending.append(("host", bucket, dev, buf, fut))
 
@@ -912,56 +929,65 @@ class GradientAverager:
         # untouched — the error is latched and the commit vote fails.
         with self._manager.spans.span("allreduce_h2d", step=step) as sp_h2d:
             h2d_bytes = 0
-            for (kind, bucket, dev, buf, _payload), res in zip(pending, resolved):
-                if kind == "host":
-                    flat = np.asarray(res)
-                    if flat is buf:
-                        # Latched failure resolved to the donated staging
-                        # buffer — with donate the op may have half-reduced
-                        # it, so it must not be republished as gradients.
-                        # Leaves stay untouched; the commit vote fails.
-                        continue
-                    for idx, arr in bucket.unpack(flat):
-                        out[idx] = arr
-                elif kind == "device":
-                    if res is dev.buffer:
-                        continue  # latched failure: leaves stay untouched
-                    flat_host = np.asarray(res)
-                    h2d_bytes += flat_host.nbytes
-                    with _SHARDED_EXEC_LOCK if dev.multi_device else nullcontext():
-                        flat_back = (
-                            jax.device_put(flat_host, dev.last_sharding)
-                            if dev.last_sharding is not None
-                            else jax.device_put(flat_host)
+            for k, ((kind, bucket, dev, buf, _payload), res) in enumerate(
+                zip(pending, resolved)
+            ):
+                # One bucket's way back: unpack, or device_put + the inverse.
+                with self._manager.spans.sub(
+                    "h2d_put", step=step, bucket=k, bytes=bucket.nbytes
+                ):
+                    if kind == "host":
+                        flat = np.asarray(res)
+                        if flat is buf:
+                            # Latched failure resolved to the donated staging
+                            # buffer — with donate the op may have half-reduced
+                            # it, so it must not be republished as gradients.
+                            # Leaves stay untouched; the commit vote fails.
+                            continue
+                        for idx, arr in bucket.unpack(flat):
+                            out[idx] = arr
+                    elif kind == "device":
+                        if res is dev.buffer:
+                            continue  # latched failure: leaves stay untouched
+                        flat_host = np.asarray(res)
+                        h2d_bytes += flat_host.nbytes
+                        with _SHARDED_EXEC_LOCK if dev.multi_device else nullcontext():
+                            flat_back = (
+                                jax.device_put(flat_host, dev.last_sharding)
+                                if dev.last_sharding is not None
+                                else jax.device_put(flat_host)
+                            )
+                            backs = dev.unprep(flat_back)
+                            if dev.multi_device:
+                                jax.block_until_ready(backs)
+                        for idx, arr in zip(bucket.indices, backs):
+                            out[idx] = arr
+                    else:  # sharded
+                        if any(r is view for _, _, _, view, r in res):
+                            continue  # latched failure: leaves stay untouched
+                        flat_host = np.concatenate(
+                            [np.asarray(r).reshape(-1) for _, _, _, _, r in res]
                         )
-                        backs = dev.unprep(flat_back)
-                        if dev.multi_device:
+                        h2d_bytes += flat_host.nbytes
+                        # device_put with the epilogue's sharding performs the
+                        # per-shard H2D placement: each slice lands on its own
+                        # device (each host transfers only its addressable
+                        # slices), and the jitted inverse upcasts in HBM.
+                        with _SHARDED_EXEC_LOCK:
+                            flat_back = jax.device_put(flat_host, dev.last_sharding)
+                            backs = dev.unprep(flat_back)
                             jax.block_until_ready(backs)
-                    for idx, arr in zip(bucket.indices, backs):
-                        out[idx] = arr
-                else:  # sharded
-                    if any(r is view for _, _, _, view, r in res):
-                        continue  # latched failure: leaves stay untouched
-                    flat_host = np.concatenate(
-                        [np.asarray(r).reshape(-1) for _, _, _, _, r in res]
-                    )
-                    h2d_bytes += flat_host.nbytes
-                    # device_put with the epilogue's sharding performs the
-                    # per-shard H2D placement: each slice lands on its own
-                    # device (each host transfers only its addressable
-                    # slices), and the jitted inverse upcasts in HBM.
-                    with _SHARDED_EXEC_LOCK:
-                        flat_back = jax.device_put(flat_host, dev.last_sharding)
-                        backs = dev.unprep(flat_back)
-                        jax.block_until_ready(backs)
-                    for idx, arr in zip(bucket.indices, backs):
-                        out[idx] = arr
+                        for idx, arr in zip(bucket.indices, backs):
+                            out[idx] = arr
 
             serialize = any(
                 d is not None and d.multi_device for d in plan.device
             )
             devices = []
-            with _SHARDED_EXEC_LOCK if serialize else nullcontext():
+            # The per-leaf placement of what came back as host arrays, as one.
+            with self._manager.spans.sub(
+                "h2d_put", step=step
+            ) as sub_put, _SHARDED_EXEC_LOCK if serialize else nullcontext():
                 for i, a in enumerate(out):
                     if is_jax[i]:
                         if not isinstance(a, jax.Array):
@@ -975,6 +1001,7 @@ class GradientAverager:
                     jax.block_until_ready(
                         [d for d in devices if isinstance(d, jax.Array)]
                     )
+                sub_put.fields["bytes"] = h2d_bytes
             sp_h2d.fields["bytes"] = h2d_bytes
         stats["h2d_bytes"] += h2d_bytes
         self._note("h2d", h2d_bytes)

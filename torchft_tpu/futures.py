@@ -286,7 +286,12 @@ def _copy_into(dst, src_host, cast: bool) -> None:
     np.copyto(dst, src_host, casting="unsafe")
 
 
-def device_get_into(pairs: list, timeout: float, cast: bool = False) -> None:
+def device_get_into(
+    pairs: list,
+    timeout: float,
+    cast: bool = False,
+    sub: Optional[Callable[..., Any]] = None,
+) -> None:
     """Materializes ``(src, dst)`` pairs host-side under one shared deadline,
     landing each source directly in its destination view — the bucket-
     pipelined D2H path: every gradient leaf is copied straight into its slot
@@ -299,12 +304,30 @@ def device_get_into(pairs: list, timeout: float, cast: bool = False) -> None:
     opts into value conversion — the device wire-prep path fetches bf16
     bytes into bf16 buffers, and a silent f32<->bf16 convert here would
     hide a mis-planned buffer at half or double the intended D2H bytes.
+
+    ``sub`` (optional: ``sub(name, **fields)`` returning a context manager,
+    the GradientAverager passes its tracker's) takes each pair's fetch apart
+    where it happens, on the materializer thread: ``d2h_ready`` (the wait for
+    the program that produces ``src`` — the one call this adds, same result
+    and order), ``d2h_fetch`` (``np.asarray``: the DMA into PJRT's host
+    buffer) and ``d2h_copy`` (the second pass into ``dst``).
     """
     import numpy as np
 
     def run() -> None:
+        if sub is None:
+            for src, dst in pairs:
+                _copy_into(dst, np.asarray(src), cast)
+            return
+        import jax
+
         for src, dst in pairs:
-            _copy_into(dst, np.asarray(src), cast)
+            with sub("d2h_ready"):
+                jax.block_until_ready(src)
+            with sub("d2h_fetch", bytes=dst.nbytes):
+                host = np.asarray(src)
+            with sub("d2h_copy", bytes=dst.nbytes):
+                _copy_into(dst, host, cast)
 
     _MATERIALIZER.get(run, timeout)
 

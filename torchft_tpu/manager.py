@@ -542,7 +542,14 @@ class Manager:
     def wait_quorum(self) -> None:
         """Blocks until the current quorum completes (torchft/manager.py:440-449)."""
         assert self._quorum_future is not None, "call start_quorum before wait_quorum"
-        self._quorum_future.result()
+        if self._quorum_future.done():
+            self._quorum_future.result()
+            return
+        # What THIS thread waits for the quorum (the ``quorum`` span is the
+        # quorum thread's RPC): every caller passes through here, and a
+        # settled quorum records nothing, so the waits of one step add up.
+        with self._spans.sub("quorum_wait", step=self._step):
+            self._quorum_future.result()
 
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: timedelta
@@ -1028,6 +1035,7 @@ class Manager:
         allow_wire_compression: bool = True,
         wire_codec: Optional[str] = None,
         donate: bool = False,
+        bucket: Optional[int] = None,
     ) -> Future:
         """Fault-tolerant gradient allreduce across replica groups.
 
@@ -1055,6 +1063,10 @@ class Manager:
         op never publishes a half-reduced buffer as the result.  Forwarded
         to the collective only when True, same mock-compat rule as
         wire_codec.
+
+        bucket labels the op's ``ring_queue`` / ``ring_run`` / ``normalize``
+        sub-spans (the GradientAverager passes its bucket index); it is
+        never forwarded and changes nothing the op does.
         """
         if self.errored() is not None:
             return completed_future(tensor)
@@ -1115,18 +1127,36 @@ class Manager:
                 kwargs["wire_codec"] = wire_codec
             if donate:
                 kwargs["donate"] = True
+            step = self._step
+            tags: Dict[str, Any] = {"bytes": int(host.nbytes)}
+            if bucket is not None:
+                tags["bucket"] = bucket
+            t_submit = time.monotonic_ns()
             work = self._collective.allreduce([host], op="sum", **kwargs)
+            # The worker's stamps, filled in place before the future resolves.
+            times = getattr(work, "times", (0, 0))
 
             def normalize(results: List[np.ndarray]):
-                out = results[0]
-                if should_average:
-                    num = max(1, self.num_participants())
-                    out = (out / num).astype(host.dtype, copy=False)
-                if is_jax:
-                    import jax
+                # On the thread that resolved the op's future.
+                started, done = times
+                if started and done:
+                    self._spans.note_sub(
+                        "ring_queue", step, t_submit, started, **tags
+                    )
+                    self._spans.note_sub(
+                        "ring_run", step, started, done,
+                        wire_bytes=ar_nbytes, **tags
+                    )
+                with self._spans.sub("normalize", step=step, **tags):
+                    out = results[0]
+                    if should_average:
+                        num = max(1, self.num_participants())
+                        out = (out / num).astype(host.dtype, copy=False)
+                    if is_jax:
+                        import jax
 
-                    return jax.device_put(out, tensor.sharding)
-                return out
+                        return jax.device_put(out, tensor.sharding)
+                    return out
 
             from torchft_tpu.futures import then
 
@@ -1723,6 +1753,9 @@ class Manager:
         (reference: torchft/manager.py:325-337)."""
         self._errored = e
         self._metrics.emit("error", step=self._step, error=repr(e))
+        # What led up to the error leaves now: a crash after it loses at
+        # most the sub-spans of the step in flight.
+        self._spans.flush_subspans()
 
     def errored(self) -> Optional[Exception]:
         return self._errored
@@ -2151,6 +2184,7 @@ class Manager:
         # data-plane Perfetto track.
         self._dump_hops()
         self._worker_metrics.close()
+        self._spans.flush_subspans()
         self._metrics.close()
         self._executor.shutdown(wait=True)
         if self._checkpoint_transport is not None:

@@ -30,7 +30,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Optional
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 __all__ = ["MetricsLogger", "METRICS_PATH_ENV", "EVENTS", "SCHEMA_VERSION"]
 
@@ -65,6 +65,10 @@ EVENTS = {
     # -- spans (torchft_tpu/obs/spans.py) -----------------------------------
     "span": "begin/end-measured phase of one step (phase, duration_ms)",
     "step_summary": "per-step phase breakdown emitted after the commit vote",
+    "subspan": "the sub-spans buffered since the last flush (spans: name, "
+               "parent, step, t0_ns/t1_ns on time.monotonic_ns, thread, "
+               "bucket/bytes where they apply) — what a phase is made of; "
+               "written with step_summary in one write(), never attributed",
     # -- cooperative drain (torchft_tpu/drain, manager.py, launch.py) -------
     "drain_notice": "drain notice received; finishing the in-flight step",
     "drain_complete": "cooperative departure finished cleanly",
@@ -148,20 +152,29 @@ class MetricsLogger:
         return self._file is not None
 
     def emit(self, event: str, **fields: Any) -> None:
+        if self._file is not None:
+            self.emit_many([(event, fields)])
+
+    def emit_many(self, records: Sequence[Tuple[str, Dict[str, Any]]]) -> None:
+        """Writes ``(event, fields)`` records as consecutive lines in ONE
+        ``write()`` call, under one stamp."""
         if self._file is None:
             return
-        record = {
+        stamp = {
             "schema": SCHEMA_VERSION,
             "ts": time.time(),
             "t_mono": time.monotonic(),
             "replica_id": self._replica_id,
-            "event": event,
         }
-        if event not in EVENTS:
-            record["unregistered"] = True
-        record.update(fields)
         try:
-            line = (json.dumps(record, default=str) + "\n").encode()
+            lines = []
+            for event, fields in records:
+                record = dict(stamp, event=event)
+                if event not in EVENTS:
+                    record["unregistered"] = True
+                record.update(fields)
+                lines.append(json.dumps(record, default=str) + "\n")
+            line = "".join(lines).encode()
             with self._lock:
                 # Raw FileIO.write may return a short count without raising
                 # (signal mid-write, near-full disk).  Finish the line: a

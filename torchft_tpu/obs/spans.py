@@ -17,22 +17,34 @@ One tracker per Manager.  Phases of the same step may run on different
 threads (the quorum thread vs the train loop), so the per-step breakdown
 is lock-guarded; ``step_summary(step, committed=...)`` flushes the
 accumulated phases as one record after the commit vote.
+
+Below the phases sit *sub-spans* (:data:`SUBSPANS`): what a phase is made
+of, measured where the work happens — on the materializer thread, the ring's
+workers, the train thread.  They are kept in memory and leave in one
+``subspan`` record, in the same ``write()`` as the step's ``step_summary``;
+they never enter the phase accumulator, so attribution, the goodput ledger
+and the straggler sentinel do not see them.  Every ``with``-style span and
+sub-span also opens a ``jax.profiler.TraceAnnotation("tpuft:<name>")``, so a
+profile shows them on the thread they ran on, on the device trace's clock.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
 from collections import deque
-from typing import Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from torchft_tpu.metrics import MetricsLogger
 
 __all__ = [
     "PHASES",
     "OVERLAPPED_PHASES",
+    "SUBSPANS",
     "Span",
+    "SubSpan",
     "SpanTracker",
     "StepTimeStats",
 ]
@@ -83,6 +95,51 @@ PHASES = (
 # excludes these from per-step critical-path attribution.
 OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 
+# Sub-spans: name -> the phase (or frame) that caused it.  They take a phase
+# apart and are NEVER attributed themselves: they stay out of the phase
+# accumulator (phases_ms, ft_accounted_ms, the ledger and the sentinel read
+# what they read without them) and leave as one ``subspan`` record a step,
+# not as ``span`` records.  To see them: the ``subspan`` records of the
+# metrics stream, or ``tpuft:<name>`` on the host threads of a profile.
+#   d2h_ready / d2h_fetch / d2h_copy — one bucket's fetch on the
+#     materializer thread: the wait for the gradient program, the DMA into
+#     PJRT's host buffer (np.asarray), the second pass into the flat buffer;
+#     allreduce_d2h minus the three is the hand-off to that thread.
+#   ring_queue / ring_run — one ring op from Manager.allreduce's submission
+#     to the moment a ring worker took it up, and from there to its end
+#     (recorded from the collective's timestamps, so no TraceAnnotation).
+#   normalize — the divide-and-cast continuation on the thread that
+#     resolved the op's future.
+#   h2d_put — one bucket's way back (device_put + unpack), and the final
+#     per-leaf device_put loop as one.
+#   quorum_wait — what the TRAIN thread waits for the quorum (the ``quorum``
+#     phase is the quorum thread's RPC).
+#   ft_step — the frame of one TrainStep.ft_step (speculative = which update
+#     program was dispatched); grads_dispatch / apply_dispatch — the host
+#     time of its two dispatches.
+SUBSPANS = {
+    "d2h_ready": "allreduce_d2h",
+    "d2h_fetch": "allreduce_d2h",
+    "d2h_copy": "allreduce_d2h",
+    "ring_queue": "exchange",
+    "ring_run": "exchange",
+    "normalize": "exchange",
+    "h2d_put": "allreduce_h2d",
+    "quorum_wait": "ft_step",
+    "ft_step": None,
+    "grads_dispatch": "ft_step",
+    "apply_dispatch": "ft_step",
+}
+
+
+@functools.cache
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on first use (a no-op of
+    about a microsecond while no profile is being taken)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
 
 class Span:
     """One in-flight phase measurement; ``duration_ms`` is valid after the
@@ -95,14 +152,45 @@ class Span:
         self.fields = fields
         self.t_start = 0.0
         self.duration_ms: float = 0.0
+        self._annotation: Any = None
 
     def __enter__(self) -> "Span":
+        self._annotation = _annotation_cls()(f"tpuft:{self.phase}", step=self.step)
+        self._annotation.__enter__()
         self.t_start = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration_ms = round((time.monotonic() - self.t_start) * 1e3, 3)
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracker._finish(self, ok=exc_type is None)
+
+
+class SubSpan:
+    """One in-flight sub-span (see :data:`SUBSPANS`).  ``fields`` may be
+    filled inside the ``with`` block; ``t0_ns`` / ``t1_ns`` are
+    ``time.monotonic_ns()`` and valid after it exits."""
+
+    __slots__ = ("_tracker", "name", "step", "fields", "t0_ns", "t1_ns", "_annotation")
+
+    def __init__(self, tracker: "SpanTracker", name: str, step: int, fields: dict):
+        self._tracker = tracker
+        self.name = name
+        self.step = step
+        self.fields = fields
+        self.t0_ns = self.t1_ns = 0
+        self._annotation: Any = None
+
+    def __enter__(self) -> "SubSpan":
+        self._annotation = _annotation_cls()(f"tpuft:{self.name}", step=self.step, **self.fields)
+        self._annotation.__enter__()
+        self.t0_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1_ns = time.monotonic_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._tracker.note_sub(self.name, self.step, self.t0_ns, self.t1_ns, **self.fields)
 
 
 class SpanTracker:
@@ -131,6 +219,9 @@ class SpanTracker:
         # summary flushes everything since the previous vote.  Individual
         # span records still carry their own step.
         self._acc: Dict[str, float] = {}
+        # Sub-spans since the last flush: (name, step, t0_ns, t1_ns, thread,
+        # fields).  Appended from any thread, swapped out under the lock.
+        self._subs: List[Tuple[str, int, int, int, str, dict]] = []
 
     @property
     def enabled(self) -> bool:
@@ -139,6 +230,42 @@ class SpanTracker:
     def span(self, phase: str, step: int, **fields) -> Span:
         """Context manager measuring one phase of one step."""
         return Span(self, phase, step, fields)
+
+    def sub(self, name: str, step: int, **fields) -> SubSpan:
+        """Context manager measuring one sub-span (see :data:`SUBSPANS`) on
+        the calling thread.  It is buffered, never accumulated: with no
+        metrics path it costs its two clock reads and keeps nothing."""
+        return SubSpan(self, name, step, fields)
+
+    def note_sub(self, name: str, step: int, t0_ns: int, t1_ns: int, **fields) -> None:
+        """Records a sub-span from its two ``time.monotonic_ns()`` stamps —
+        for an interval that does not live in one ``with`` block on one
+        thread (a ring op: submitted here, run there)."""
+        if not self._metrics.enabled:
+            return
+        rec = (name, step, t0_ns, t1_ns, threading.current_thread().name, fields)
+        with self._lock:
+            self._subs.append(rec)
+
+    def _take_subspans(self) -> Optional[dict]:
+        """The buffered sub-spans as the fields of one ``subspan`` record
+        (None when there are none); the buffer is left empty."""
+        with self._lock:
+            subs, self._subs = self._subs, []
+        if not subs:
+            return None
+        spans = [
+            dict(f, name=n, parent=SUBSPANS.get(n), step=st, t0_ns=a, t1_ns=b, thread=th)
+            for n, st, a, b, th, f in subs
+        ]
+        return {"slice_gen": self.slice_gen, "spans": spans}
+
+    def flush_subspans(self) -> None:
+        """Writes what is buffered now — at shutdown and when an error is
+        latched, so a crash loses at most the step in flight."""
+        subs = self._take_subspans()
+        if subs is not None:
+            self._metrics.emit("subspan", **subs)
 
     def phases_ms(self) -> Dict[str, float]:
         """Copy of the per-phase accumulation since the last
@@ -169,6 +296,7 @@ class SpanTracker:
             "step": span.step,
             "slice_gen": self.slice_gen,
             "duration_ms": span.duration_ms,
+            "t_start_mono": span.t_start,
         }
         if not ok:
             rec["ok"] = False
@@ -176,8 +304,9 @@ class SpanTracker:
         self._metrics.emit("span", **rec)
 
     def step_summary(self, step: int, committed: bool, **fields) -> None:
-        """Emits the per-step phase breakdown and resets the accumulator.
-        Call once per step, after the commit vote."""
+        """Emits the per-step phase breakdown and resets the accumulator;
+        the sub-spans buffered since the last flush leave in the same
+        ``write()``.  Call once per step, after the commit vote."""
         with self._lock:
             rec = {
                 "step": step,
@@ -188,7 +317,11 @@ class SpanTracker:
             }
             self._acc = {}
         rec.update(fields)
-        self._metrics.emit("step_summary", **rec)
+        records = [("step_summary", rec)]
+        subs = self._take_subspans()
+        if subs is not None:
+            records.append(("subspan", subs))
+        self._metrics.emit_many(records)
 
 
 class StepTimeStats:
@@ -254,15 +387,6 @@ class StepTimeStats:
     def last_ms(self) -> float:
         with self._lock:
             return self._last
-
-    def percentile(self, p: float) -> float:
-        """Nearest-rank percentile over the sliding window (0 when empty)."""
-        with self._lock:
-            if not self._window:
-                return 0.0
-            ordered = sorted(self._window)
-            idx = min(len(ordered) - 1, int(p / 100.0 * len(ordered)))
-            return ordered[idx]
 
     def snapshot(self) -> Dict[str, float]:
         """{ewma, last, p50, p99, max, n} in ms — the step_summary payload."""

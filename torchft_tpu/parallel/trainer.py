@@ -266,20 +266,33 @@ class TrainStep:
         # peak covers compute + resident state.
         resolve_after = self._overlap_resolved is None
 
-        loss, grads = self._grads_fn(params, batch)
-        grads = self._averager.allreduce(grads)
-        if self._overlap_resolved:
-            new_params, new_opt = self._apply_spec_fn(params, opt_state, grads)
-            if manager.should_commit():
-                return new_params, new_opt, loss, True
-            return params, opt_state, loss, False
-        committed = manager.should_commit()
-        if committed:
-            params, opt_state = self._apply_fn(params, opt_state, grads)
-        # Only a COMMITTED step resolves the decision: an aborted vote means
-        # _apply_fn never ran, so the allocator peak would exclude the
-        # optimizer-apply footprint the budget must cover.
-        if resolve_after and committed:
-            jax.block_until_ready(jax.tree.leaves(params))
-            self._resolve_overlap(params, opt_state, batch)
-        return params, opt_state, loss, committed
+        # The frame and the host time of the two dispatches, as sub-spans of
+        # the Manager's tracker (obs/spans.SUBSPANS): `speculative` says
+        # which update program this step dispatched.
+        spans, step = manager.spans, manager.current_step()
+        with spans.sub(
+            "ft_step", step=step, speculative=bool(self._overlap_resolved)
+        ) as frame:
+            with spans.sub("grads_dispatch", step=step):
+                loss, grads = self._grads_fn(params, batch)
+            grads = self._averager.allreduce(grads)
+            if self._overlap_resolved:
+                with spans.sub("apply_dispatch", step=step):
+                    new_params, new_opt = self._apply_spec_fn(
+                        params, opt_state, grads
+                    )
+                committed = frame.fields["committed"] = manager.should_commit()
+                if committed:
+                    return new_params, new_opt, loss, True
+                return params, opt_state, loss, False
+            committed = frame.fields["committed"] = manager.should_commit()
+            if committed:
+                with spans.sub("apply_dispatch", step=step):
+                    params, opt_state = self._apply_fn(params, opt_state, grads)
+            # Only a COMMITTED step resolves the decision: an aborted vote
+            # means _apply_fn never ran, so the allocator peak would exclude
+            # the optimizer-apply footprint the budget must cover.
+            if resolve_after and committed:
+                jax.block_until_ready(jax.tree.leaves(params))
+                self._resolve_overlap(params, opt_state, batch)
+            return params, opt_state, loss, committed
