@@ -34,8 +34,8 @@ def _mock_manager(num_participants: int = 2, commit: bool = True) -> MagicMock:
         # Pretend every participant contributed identical values: the average
         # equals the input, so averaging is an identity we can verify around.
         # Copy on donate: the real manager never returns the donated buffer
-        # itself on success (normalize allocates), and callers use identity
-        # with the input to detect the failure fallback.
+        # itself on success (normalize hands back a view of it at most), and
+        # callers use identity with the input to detect the failure fallback.
         out = np.asarray(arr)
         return completed_future(out.copy() if donate and out is arr else out)
 

@@ -373,6 +373,13 @@ class _DeviceBucket:
         self.unprep = jax.jit(unprep)
 
 
+def _lives_in_host_memory(leaf) -> bool:
+    """Whether ``jax.device_put`` to this jax leaf's devices may hand back an
+    array that aliases its numpy source: the CPU backend does (zero-copy, for
+    a source aligned to 64 bytes); an accelerator copies into its own memory."""
+    return any(d.platform == "cpu" for d in leaf.devices())
+
+
 def _shard_slices(flat_dev) -> Optional[List[Tuple[Any, int, int]]]:
     """``[(shard, start, stop)]`` covering a 1-D device array contiguously,
     one entry per addressable shard — or None when the layout is not a
@@ -944,8 +951,17 @@ class GradientAverager:
                             # it, so it must not be republished as gradients.
                             # Leaves stay untouched; the commit vote fails.
                             continue
+                        # The average was taken in the ring's buffer, which
+                        # with the native engine is ``buf`` itself, and the
+                        # next call rewrites ``buf``: a leaf that would go on
+                        # living in host memory leaves as a copy.
+                        owned = not np.may_share_memory(flat, buf)
                         for idx, arr in bucket.unpack(flat):
-                            out[idx] = arr
+                            out[idx] = (
+                                arr
+                                if owned or (is_jax[idx] and not _lives_in_host_memory(leaves[idx]))
+                                else arr.copy()
+                            )
                     elif kind == "device":
                         if res is dev.buffer:
                             continue  # latched failure: leaves stay untouched
@@ -997,10 +1013,12 @@ class GradientAverager:
                         devices.append(
                             np.asarray(a) if isinstance(a, jax.Array) else a
                         )
-                if serialize:
-                    jax.block_until_ready(
-                        [d for d in devices if isinstance(d, jax.Array)]
-                    )
+                # device_put may read its source after it returns, and the
+                # sources are views of the plan's persistent buffers: no
+                # host view is held past this call.
+                jax.block_until_ready(
+                    [d for d in devices if isinstance(d, jax.Array)]
+                )
                 sub_put.fields["bytes"] = h2d_bytes
             sp_h2d.fields["bytes"] = h2d_bytes
         stats["h2d_bytes"] += h2d_bytes

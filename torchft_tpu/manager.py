@@ -44,7 +44,7 @@ import numpy as np
 
 from torchft_tpu._native import ManagerClient, ManagerServer, StoreClient, StoreServer
 from torchft_tpu.checkpointing.transport import CheckpointTransport
-from torchft_tpu.collectives import Collective
+from torchft_tpu.collectives import Collective, _is_bf16
 from torchft_tpu.futures import completed_future, future_timeout
 
 T = TypeVar("T")
@@ -1062,7 +1062,9 @@ class Manager:
         caller observes today — the collective's contract is that a failed
         op never publishes a half-reduced buffer as the result.  Forwarded
         to the collective only when True, same mock-compat rule as
-        wire_codec.
+        wire_codec.  The average is then taken in that storage too: a
+        successful result may be a view of the donated buffer, and is
+        never, by identity, the input itself.
 
         bucket labels the op's ``ring_queue`` / ``ring_run`` / ``normalize``
         sub-spans (the GradientAverager passes its bucket index); it is
@@ -1147,11 +1149,22 @@ class Manager:
                         "ring_run", step, started, done,
                         wire_bytes=ar_nbytes, **tags
                     )
-                with self._spans.sub("normalize", step=step, **tags):
-                    out = results[0]
+                out = results[0]
+                in_place = should_average and _may_average_in_place(out, host, donate)
+                with self._spans.sub("normalize", step=step, in_place=in_place, **tags):
                     if should_average:
                         num = max(1, self.num_participants())
-                        out = (out / num).astype(host.dtype, copy=False)
+                        if in_place:
+                            # The buffer the ring reduced is already touched
+                            # and the op's own: no fresh pages to fault in on
+                            # the worker that peers and later buckets wait for.
+                            np.divide(out, num, out=out)
+                            if out is host:
+                                # Callers tell a latched failure by identity
+                                # with their input; a success never is it.
+                                out = out.view()
+                        else:
+                            out = (out / num).astype(host.dtype, copy=False)
                     if is_jax:
                         import jax
 
@@ -2217,6 +2230,21 @@ def _max_heal_donors() -> int:
         return int(os.environ.get(TPUFT_MAX_HEAL_DONORS_ENV, "4"))
     except ValueError:
         return 4
+
+
+def _may_average_in_place(out, host: np.ndarray, donate: bool) -> bool:
+    """Whether ``out /= num`` gives bit for bit what
+    ``(out / num).astype(host.dtype, copy=False)`` gives, in a buffer that is
+    the op's to overwrite: a writeable floating array of the caller's dtype
+    (an integer payload divides into float64 and casts back) that was donated
+    or is not the caller's own memory."""
+    return (
+        isinstance(out, np.ndarray)
+        and out.flags.writeable
+        and out.dtype == host.dtype
+        and (np.issubdtype(out.dtype, np.floating) or _is_bf16(out.dtype))
+        and (donate or not np.may_share_memory(out, host))
+    )
 
 
 def _is_jax_array(x) -> bool:

@@ -108,8 +108,9 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 #   ring_queue / ring_run — one ring op from Manager.allreduce's submission
 #     to the moment a ring worker took it up, and from there to its end
 #     (recorded from the collective's timestamps, so no TraceAnnotation).
-#   normalize — the divide-and-cast continuation on the thread that
-#     resolved the op's future.
+#   normalize — the averaging continuation on the thread that resolved the
+#     op's future: it divides in the op's own buffer (``in_place``: true)
+#     where the result shows it may, else into a new array with a cast.
 #   h2d_put — one bucket's way back (device_put + unpack), and the final
 #     per-leaf device_put loop as one.
 #   quorum_wait — what the TRAIN thread waits for the quorum (the ``quorum``
