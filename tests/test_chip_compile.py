@@ -156,3 +156,57 @@ def test_flagship_gradient_program_compiles_with_kernels_for_v5e(
     ma = compiled.memory_analysis()
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
     assert resident < 16 * 2**30, f"the program needs {resident} bytes, a v5e chip has 16 GiB"
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["up_and_gate", "down"])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, k, n) -> None:
+    """The three `tpuft_gmm_*` kernels at OLMoE-1B-7B's widths and the
+    benchmark cell's rows: 8,192 tokens x 8 experts a token over 64 experts,
+    each expert's rows padded to the row tile."""
+    from torchft_tpu.ops import grouped_matmul as gm
+
+    experts, rows = 64, 8192 * 8 + 64 * gm.ROW_TILE
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((experts, k, n), jnp.float32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
+    assert gm._tiles(rows, k, n, gm.ROW_TILE) is not None
+
+    def product_and_gradients(l, r, s):
+        out, vjp = jax.vjp(lambda l_, r_: gm._gmm(l_, r_, s, gm.ROW_TILE, False), l, r)
+        return out, vjp(out)
+
+    text = _compile(product_and_gradients, lhs, rhs, sizes)
+    for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+
+
+def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `olmoe-1b-7b` configuration as `benchmark/programs/moe_lm.py`
+    hands it to `TrainStep`: the whole gradient program at the published
+    widths, all 64 experts, with the grouped-matmul, attention and CE kernels
+    inside, and room for AdamW's moments beside it on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("olmoe-1b-7b"), bench.traffic("steady-1g")
+    shapes = jax.eval_shape(lambda: bench.reference("moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_fa_fwd", "tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    assert n_params == 625_616_896
+    assert resident < 14 * 2**30, f"the step needs {resident} bytes with AdamW's moments, a v5e chip has 16 GiB"

@@ -59,6 +59,7 @@ MODULES = [
     "torchft_tpu.models.convnet",
     "torchft_tpu.ops.attention",
     "torchft_tpu.ops.cross_entropy",
+    "torchft_tpu.ops.grouped_matmul",
     "torchft_tpu.ops.rmsnorm",
     "torchft_tpu.ops.ring_attention",
     "torchft_tpu.ops.ulysses",

@@ -13,12 +13,13 @@ from torchft_tpu.models.convnet import (
     convnet_loss,
     init_convnet_params,
 )
-from torchft_tpu.models.moe import moe_ffn
+from torchft_tpu.models.moe import moe_ffn, moe_layer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     forward,
     forward_with_aux,
     init_params,
+    loss_and_counters,
     loss_fn,
 )
 
@@ -29,6 +30,8 @@ __all__ = [
     "forward",
     "forward_with_aux",
     "moe_ffn",
+    "moe_layer",
+    "loss_and_counters",
     "convnet_forward",
     "convnet_loss",
     "init_convnet_params",

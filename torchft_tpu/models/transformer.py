@@ -63,10 +63,20 @@ class TransformerConfig:
     scan_unroll: int = 1
     # Mixture-of-experts: > 0 replaces the dense MLP with moe_experts
     # experts (stacked, shardable over the "expert" mesh axis).
+    # moe_capacity_factor None = dropless: the sorted path with grouped
+    # matmuls, every expert on one device (models/moe.py); a number = the
+    # capacity-bound dense dispatch an "expert" mesh axis runs.
+    # moe_norm_topk: renormalise the k kept gates (OLMoE does not).
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_aux_coef: float = 0.01
+    moe_capacity_factor: Optional[float] = 1.25
+    moe_norm_topk: bool = True
+    moe_aux_coef: float = 0.01       # load-balance loss
+    moe_z_coef: float = 0.0          # router z-loss
+    # RMSNorm over the whole projected query and key, each with a weight of
+    # its own, before the split into heads and RoPE (OLMoE's attention).
+    qk_norm: bool = False
+    rms_eps: float = 1e-6
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -98,6 +108,8 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if cfg.qk_norm:
+        layer.update({"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv_heads")})
     if cfg.moe_experts > 0:
         layer.update(
             {
@@ -137,6 +149,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         "wo": norm_init(ks[3], (L, H * Dh, E), H * Dh),
         "mlp_norm": jnp.ones((L, E), pd),
     }
+    if cfg.qk_norm:
+        layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
     if cfg.moe_experts > 0:
         X = cfg.moe_experts
         kr, kg, ku, kd = jax.random.split(ks[7], 4)
@@ -234,9 +248,15 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):
     B, S, E = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
 
-    h = rms_norm(x, w["attn_norm"])
-    q = (h @ w["wq"].astype(cfg.dtype)).reshape(B, S, H, Dh)
-    k = (h @ w["wk"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
+    h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
+    q = h @ w["wq"].astype(cfg.dtype)
+    if cfg.qk_norm:
+        q = rms_norm(q, w["q_norm"], cfg.rms_eps)
+    q = q.reshape(B, S, H, Dh)
+    k = h @ w["wk"].astype(cfg.dtype)
+    if cfg.qk_norm:
+        k = rms_norm(k, w["k_norm"], cfg.rms_eps)
+    k = k.reshape(B, S, KV, Dh)
     v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
@@ -248,11 +268,11 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):
     x = x + (attn @ w["wo"].astype(cfg.dtype))
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
-    h = rms_norm(x, w["mlp_norm"])
+    h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
     if cfg.moe_experts > 0:
-        from torchft_tpu.models.moe import moe_ffn
+        from torchft_tpu.models.moe import moe_layer
 
-        y, aux = moe_ffn(
+        y, aux = moe_layer(
             h,
             w["router"],
             w["w_gate"],
@@ -260,6 +280,7 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):
             w["w_down"],
             top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
+            norm_topk=cfg.moe_norm_topk,
             dtype=cfg.dtype,
             mesh=mesh,
             rules=rules,
@@ -281,8 +302,11 @@ def _decoder(
     rules: Optional[ShardingRules] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Embedding + decoder stack (everything before the lm head).
-    tokens: [B, S] int32 -> (hidden [B, S, E], aux scalar f32 — the summed
-    MoE load-balance loss; zero for dense models)."""
+    tokens: [B, S] int32 -> (hidden [B, S, E], aux).  For a dense model aux
+    is a zero scalar; for an MoE model it is the layers' router statistics
+    (models/moe.py ``moe_layer``): ``balance``, ``z`` and ``dropped`` summed
+    over the layers, ``tokens_per_expert`` [n_layers, n_experts] and
+    ``chosen`` [n_layers, B, S, k]."""
     rules = rules or ShardingRules()
     B, S = tokens.shape
     pos = jnp.arange(S, dtype=jnp.int32)
@@ -321,15 +345,32 @@ def _decoder(
         # agree with the scan path to fusion-order rounding, not bitwise
         # (pinned by test_scan_unroll_matches_scan).
         aux_total = jnp.zeros((), jnp.float32)
+        aux_layers = []
         for i in range(cfg.n_layers):
             w_i = jax.tree.map(lambda a, i=i: a[i], params["layers"])
             x, aux = body(x, w_i)
-            aux_total = aux_total + aux
+            if cfg.moe_experts > 0:
+                aux_layers.append(aux)
+            else:
+                aux_total = aux_total + aux
+        if cfg.moe_experts > 0:
+            return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
         return x, aux_total
     x, aux_layers = jax.lax.scan(
         body, x, params["layers"], unroll=cfg.scan_unroll
     )
+    if cfg.moe_experts > 0:
+        return x, _over_layers(aux_layers)
     return x, jnp.sum(aux_layers)
+
+
+def _over_layers(stats: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The MoE layers' statistics stacked on a leading axis -> sums over
+    the layers, the per-expert counts left per layer."""
+    return {
+        name: value if name in ("tokens_per_expert", "chosen") else jnp.sum(value, axis=0)
+        for name, value in stats.items()
+    }
 
 
 def forward_with_aux(
@@ -342,6 +383,8 @@ def forward_with_aux(
     """tokens: [B, S] int32 -> (logits [B, S, vocab] f32, aux scalar f32 —
     the summed MoE load-balance loss; zero for dense models)."""
     x, aux = _decoder(params, tokens, cfg, mesh, rules)
+    if cfg.moe_experts > 0:
+        aux = aux["balance"]
     return head(params, x, cfg, mesh, rules), aux
 
 
@@ -356,7 +399,7 @@ def head(
 
     Shared by the dense path (forward_with_aux) and the pipelined path
     (parallel/pipeline.pipeline_loss_fn) so the two can never diverge."""
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
     # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
     logits = jnp.matmul(
@@ -408,7 +451,7 @@ def lm_head_loss(
 
     B, S, E = x.shape
     if fused_ce_applicable(B * S, E, cfg.vocab_size, mesh):
-        h = rms_norm(x, params["final_norm"])
+        h = rms_norm(x, params["final_norm"], cfg.rms_eps)
         w = params["lm_head"].astype(cfg.dtype)
         return fused_linear_cross_entropy(
             h.reshape(B * S, E), w, targets.reshape(B * S)
@@ -425,10 +468,29 @@ def loss_fn(
 ) -> jax.Array:
     """Next-token cross entropy; batch: {"tokens": [B,S], "targets": [B,S]}.
 
-    MoE configs add moe_aux_coef * load-balance loss (Switch-style).
+    MoE configs add moe_aux_coef * load-balance loss (Switch-style) and
+    moe_z_coef * router z-loss.
     """
+    return loss_and_counters(params, batch, cfg, mesh, rules)[0]
+
+
+def loss_and_counters(
+    params: Dict[str, Any],
+    batch: Dict[str, jax.Array],
+    cfg: TransformerConfig,
+    mesh=None,
+    rules: Optional[ShardingRules] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``loss_fn`` and what the model counted on the way, for a
+    ``TrainStep(loss_has_counters=True)``: for an MoE model
+    ``moe_tokens_per_expert`` ([n_layers, n_experts] int32, assignments sent
+    to each expert) and ``moe_dropped`` (int32, assignments that reached no
+    expert); for a dense model nothing."""
     x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules)
     ce = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
-    if cfg.moe_experts > 0:
-        ce = ce + cfg.moe_aux_coef * aux
-    return ce
+    if cfg.moe_experts == 0:
+        return ce, {}
+    loss = ce + cfg.moe_aux_coef * aux["balance"]
+    if cfg.moe_z_coef:
+        loss = loss + cfg.moe_z_coef * aux["z"]
+    return loss, {"moe_tokens_per_expert": aux["tokens_per_expert"], "moe_dropped": aux["dropped"]}

@@ -117,7 +117,9 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 #     phase is the quorum thread's RPC).
 #   ft_step — the frame of one TrainStep.ft_step (speculative = which update
 #     program was dispatched); grads_dispatch / apply_dispatch — the host
-#     time of its two dispatches.
+#     time of its two dispatches; counters_note — the hand-over of the last
+#     step's loss counters (TrainStep(loss_has_counters=True)) to the
+#     step_summary: a read of arrays already on the host.
 SUBSPANS = {
     "d2h_ready": "allreduce_d2h",
     "d2h_fetch": "allreduce_d2h",
@@ -130,6 +132,7 @@ SUBSPANS = {
     "ft_step": None,
     "grads_dispatch": "ft_step",
     "apply_dispatch": "ft_step",
+    "counters_note": "ft_step",
 }
 
 

@@ -91,7 +91,17 @@ class TrainStep:
     Args:
         ftmesh: mesh + rules (+ optional manager for the replica dim).
         tx: optax GradientTransformation.
-        loss_fn: (params, batch) -> scalar loss (model closure).
+        loss_fn: (params, batch) -> scalar loss (model closure); with
+            ``loss_has_counters`` it returns ``(loss, counters)``, counters
+            a dict of small arrays the model counted inside the gradient
+            program (tokens per expert, say).  They leave the program
+            beside the loss: ``last_counters`` holds the newest (on the
+            device), and ``ft_step`` lands them in the Manager's
+            ``step_summary`` — a scalar under its name, an array as
+            ``<name>_max`` and ``<name>_mean`` — one step late, with
+            ``counters_step`` naming the step they were counted in: by then
+            they are on the host and the hand-over waits for nothing.  A
+            loss without counters compiles to the program it always did.
         bucket_bytes: DCN bucket size for the cross-group averaging path.
         overlap_commit: hide the commit-vote RPC behind a speculatively
             dispatched update (see ft_step).  MEMORY TRADE: the speculative
@@ -117,12 +127,15 @@ class TrainStep:
     bucket_bytes: int = 25 << 20
     overlap_commit: Optional[bool] = None
     value_and_grad_fn: Optional[Callable[[Any, Any], Any]] = None
+    loss_has_counters: bool = False
 
     def __post_init__(self) -> None:
         if (self.loss_fn is None) == (self.value_and_grad_fn is None):
             raise ValueError(
                 "TrainStep needs exactly one of loss_fn / value_and_grad_fn"
             )
+        if self.loss_has_counters and self.loss_fn is None:
+            raise ValueError("loss_has_counters goes with loss_fn")
         mesh = self.ftmesh.mesh
 
         def value_and_grad(params, batch):
@@ -133,6 +146,9 @@ class TrainStep:
             with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
                 if self.value_and_grad_fn is not None:
                     return self.value_and_grad_fn(params, batch)
+                if self.loss_has_counters:
+                    # ((loss, counters), grads)
+                    return jax.value_and_grad(self.loss_fn, has_aux=True)(params, batch)
                 return jax.value_and_grad(self.loss_fn)(params, batch)
 
         def apply(params, opt_state, grads):
@@ -143,6 +159,8 @@ class TrainStep:
 
         def full(params, opt_state, batch):
             loss, grads = value_and_grad(params, batch)
+            if self.loss_has_counters:
+                loss = loss[0]
             params, opt_state = apply(params, opt_state, grads)
             return params, opt_state, loss
 
@@ -156,6 +174,10 @@ class TrainStep:
         self._full_fn = jax.jit(full, donate_argnums=(0, 1))
         self._averager = None  # lazy: the manager may be attached post-init
         self._overlap_resolved: Optional[bool] = self.overlap_commit
+        # The newest gradient program's counters (device arrays), and the
+        # Manager step ft_step dispatched it in (None: the split form).
+        self.last_counters: Any = None
+        self._counters_step: Optional[int] = None
 
     # -- pure compute --------------------------------------------------------
 
@@ -167,7 +189,38 @@ class TrainStep:
         return self._full_fn(params, opt_state, batch)
 
     def grads(self, params, batch):
-        return self._grads_fn(params, batch)
+        """(loss, grads); a loss with counters leaves them in
+        ``last_counters``."""
+        return self._loss_and_grads(params, batch)
+
+    def _loss_and_grads(self, params, batch, step: Optional[int] = None):
+        out, grads = self._grads_fn(params, batch)
+        if not self.loss_has_counters:
+            return out, grads
+        loss, self.last_counters = out
+        self._counters_step = step
+        for leaf in jax.tree.leaves(self.last_counters):
+            leaf.copy_to_host_async()  # on the host by the time the next step asks
+        return loss, grads
+
+    def _note_counters(self, manager, step: int) -> None:
+        """Lands the last ft_step's counters in the step_summary of the step
+        in flight (``Manager.note_summary_fields``)."""
+        if self._counters_step is None:  # no counters, or only the split form ran
+            return
+        import numpy as np
+
+        with manager.spans.sub("counters_note", step=step):
+            fields: dict = {"counters_step": self._counters_step}
+            for name, value in self.last_counters.items():
+                value = np.asarray(value)
+                if value.ndim == 0:
+                    fields[name] = value.item()
+                else:
+                    fields[name + "_max"] = value.max().item()
+                    fields[name + "_mean"] = float(value.mean())
+            manager.note_summary_fields(**fields)
+        self._counters_step = None
 
     def lower_grads(self, params, batch):
         """The gradient program, lowered for these arguments (arrays or
@@ -273,8 +326,9 @@ class TrainStep:
         with spans.sub(
             "ft_step", step=step, speculative=bool(self._overlap_resolved)
         ) as frame:
+            self._note_counters(manager, step)
             with spans.sub("grads_dispatch", step=step):
-                loss, grads = self._grads_fn(params, batch)
+                loss, grads = self._loss_and_grads(params, batch, step)
             grads = self._averager.allreduce(grads)
             if self._overlap_resolved:
                 with spans.sub("apply_dispatch", step=step):
