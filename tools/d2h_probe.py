@@ -19,7 +19,15 @@ Each process makes one float32 array of `--mb` megabytes on its chip and times,
 - `fetch_pinned`: the array moved to the `pinned_host` memory kind, then
   read from there;
 - `h2d_source_reuse`: `jax.device_put` of a host array that is overwritten
-  as soon as the call returns — whether the call has read its source by then.
+  as soon as the call returns — whether the call has read its source by then;
+- `fetch_window_all` / `fetch_window_<W>`: twelve arrays of the four-group
+  cell's leaf sizes (2.52 GB) fetched largest first, with `copy_to_host_async`
+  called on all twelve up front (what the exchange did before PR 28) against a
+  window of `W` arrays hinted beyond the one being fetched (0, 1, 2);
+  `first_share` is the first fetch's part of the whole: near 1 where the
+  first fetch waits for every transfer, 0.30 where each takes its own time;
+- `fetch_turns` (with `--procs 4`): `fetch_new`, one process at a time while
+  the other three idle — the single-stream rate of the four-chip host.
 
 One JSON line per process and phase goes to
 `chiprun_out/d2h_probe/p<procs>.g<i>.jsonl`; the summary (median GB/s per
@@ -40,6 +48,12 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(ROOT, "chiprun_out", "d2h_probe")
 SLICES_MB = (4, 64)
+# Elements of the twelve float32 gradient leaves of `internlm2-1.8b` at 4
+# layers: embedding and head, three feed-forward leaves, wq / wo, wk / wv,
+# three norms.
+CELL_LEAF_ELEMS = (92544 * 2048,) * 2 + (4 * 2048 * 8192,) * 3 + (4 * 2048 * 2048,) * 2 \
+    + (4 * 2048 * 1024,) * 2 + (4 * 2048,) * 2 + (2048,)
+WINDOWS = ("all", 0, 1, 2)
 
 
 def barrier(sync_dir: str, name: str, rank: int, procs: int) -> None:
@@ -64,9 +78,9 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
     os.makedirs(OUT, exist_ok=True)
     log = open(os.path.join(OUT, f"p{procs}.g{rank}.jsonl"), "w", encoding="utf-8")
 
-    def note(phase: str, rep: int, seconds: float, **more) -> None:
+    def note(phase: str, rep: int, seconds: float, moved: int = nbytes, **more) -> None:
         rec = {"phase": phase, "procs": procs, "rank": rank, "rep": rep, "seconds": seconds,
-               "gb_per_s": nbytes / seconds / 1e9, "mb": mb, "device": device.device_kind, **more}
+               "gb_per_s": moved / seconds / 1e9, "mb": moved / 1e6, "device": device.device_kind, **more}
         log.write(json.dumps(rec) + "\n")
         log.flush()
 
@@ -148,6 +162,44 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
         note("h2d_source_reuse", rep, t1 - t0, until_ready_s=t2 - t0,
              put_saw_rewrite=bool(float(jnp.max(y)) != 1.0))
         del y, src
+
+    if procs > 1:
+        for rep in range(reps):
+            for turn in range(procs):
+                k += 1
+                x = new_array(k) if turn == rank else None
+                barrier(sync_dir, f"turn{rep}.{turn}", rank, procs)
+                if turn == rank:
+                    t0 = time.perf_counter()
+                    host = np.asarray(x)
+                    note("fetch_turns", rep, time.perf_counter() - t0, first=float(host[0]))
+                    del host, x
+                barrier(sync_dir, f"turned{rep}.{turn}", rank, procs)
+
+    sizes = sorted((e * mb // 758 for e in CELL_LEAF_ELEMS), reverse=True)
+    make = {e: jax.jit(lambda k, e=e: jnp.arange(e, dtype=jnp.float32) + k) for e in set(sizes)}
+    jax.block_until_ready([make[e](jnp.float32(0)) for e in make])
+    total = 4 * sum(sizes)
+    for window in WINDOWS:
+        for rep in range(reps):
+            k += 1
+            arrays = jax.block_until_ready([make[e](jnp.float32(k)) for e in sizes])
+            barrier(sync_dir, f"window{window}.{rep}", rank, procs)
+            t0 = time.perf_counter()
+            ahead = len(arrays) if window == "all" else window
+            hinted = 1  # a fetch starts its own copy
+            each = []
+            for pos, a in enumerate(arrays):
+                for b in arrays[max(hinted, pos + 1):pos + 1 + ahead]:
+                    b.copy_to_host_async()
+                hinted = max(hinted, pos + 1 + ahead)
+                t1 = time.perf_counter()
+                host = np.asarray(a)
+                each.append(round(time.perf_counter() - t1, 4))
+                del host
+            seconds = time.perf_counter() - t0
+            note(f"fetch_window_{window}", rep, seconds, moved=total, each_s=each, first_share=each[0] / seconds)
+            del arrays
     log.close()
 
 
@@ -184,11 +236,14 @@ def main() -> int:
             pass
     for phase, recs in by_phase.items():
         rates = [r["gb_per_s"] for r in recs]
-        line = {"phase": phase, "procs": args.procs, "mb": args.mb, "n": len(rates),
+        line = {"phase": phase, "procs": args.procs, "mb": recs[0]["mb"], "n": len(rates),
                 "gb_per_s_median": statistics.median(rates), "gb_per_s_min": min(rates), "gb_per_s_max": max(rates)}
         if phase == "h2d_source_reuse":
             line["put_saw_rewrite"] = sum(r["put_saw_rewrite"] for r in recs)
             line["until_ready_s_median"] = statistics.median(r["until_ready_s"] for r in recs)
+        if phase.startswith("fetch_window_"):
+            line["first_share_median"] = statistics.median(r["first_share"] for r in recs)
+            line["each_s_of_rank0_rep0"] = next(r["each_s"] for r in recs if r["rank"] == 0 and r["rep"] == 0)
         if phase == "fetch_pinned":
             errors = sorted({r["error"] for r in recs if "error" in r})
             line.update({"errors": errors} if errors else

@@ -5,12 +5,19 @@ installs a comm hook that routes each gradient bucket through
 ``manager.allreduce`` so reduction overlaps with the rest of backward
 (torchft/ddp.py:47-71).  JAX has no autograd hooks — ``jax.grad`` returns the
 whole gradient pytree at once — so the overlap point moves to the bucket
-pipeline: leaves are coalesced into fixed-size flat buckets **planned once
-per tree shape and packed into persistent preallocated buffers**, and each
-bucket's device->host fetch and cross-group allreduce are issued the moment
-that bucket's leaves land — bucket 0 is on the DCN wire while bucket 2 is
-still leaving the device, and with a multi-lane ring collective
-(``TPUFT_RING_LANES``) the buckets overlap each other on the wire too.
+stream: leaves are coalesced into fixed-size flat buckets **planned once
+per tree shape and packed into persistent preallocated buffers**, and the
+exchange runs over them as ONE STREAM in the plan's fetch order (largest
+bucket first): a bucket's device->host fetch blocks, its cross-group
+allreduce is issued the moment it lands, and every ring op that has resolved
+by then goes straight back to the device (``jax.device_put`` returns at
+once) — so ring and way back run under the fetches of the buckets still on
+the device, and with a multi-lane ring collective (``TPUFT_RING_LANES``) the
+buckets overlap each other on the wire too.  Only a bounded window of
+buckets beyond the one being fetched has its device->host copy started
+(``_FETCH_WINDOW``): the runtime runs started copies side by side, so with
+the whole tree hinted up front the first fetch returned when all had landed
+and nothing could overlap it.
 
 Wire preparation can run ON DEVICE (``device_wire_prep=True`` /
 ``TPUFT_DEVICE_WIRE_PREP=1``): a cached jitted epilogue casts each float
@@ -29,10 +36,14 @@ cross-group allreduce becomes per-slice reduce-scatter + allgather aligned
 with the in-group sharding, ZeRO-style), and scattered back per-shard with
 ``jax.device_put`` under the leaf's original sharding.
 
-The per-bucket D2H wait runs in an ``allreduce_d2h`` span, the result
-scatter-back in ``allreduce_h2d``, and the final drain in
-``allreduce_merge`` (all FT time, never charged as productive compute —
-obs/report.py and the straggler sentinel depend on that).
+The per-bucket D2H wait runs in an ``allreduce_d2h`` span (``pos``: its
+place in the fetch order, ``inflight``: hinted copies beyond it when it
+began), each harvest of resolved buckets in a short ``allreduce_h2d`` span
+between two fetches (so a step holds several), the waits for what is still on
+the ring after the last fetch in ``allreduce_merge``, and the one wait for
+every put in a last ``allreduce_h2d`` (all FT time, never charged as
+productive compute — obs/report.py and the straggler sentinel depend on
+that).  ``step_summary.exchange_stream`` says how far the stream engaged.
 
 ``PerLeafGradientAverager`` mirrors PureDistributedDataParallel's
 per-parameter variant (torchft/ddp.py:74-97).
@@ -43,6 +54,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 from concurrent.futures import Future
 from contextlib import nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -187,6 +199,17 @@ class ElasticBatchScaler:
             "accum_steps": accum_steps,
             "lr_scale": lr_scale,
         }
+
+
+# How many buckets beyond the one being fetched have their device->host copy
+# started (``copy_to_host_async``) in the streamed exchange.  Chosen on the
+# chip (four v5e groups on one host, 2.52 GB of f32 gradients a group; PERF.md
+# section 6, PR 28): a started copy runs side by side with the one being
+# waited for and the two together are SLOWER — twelve arrays of the cell's
+# sizes fetched at 0.42 GB/s a process with no hint, 0.29 / 0.28 with a window
+# of 1 / 2, 0.29 with every array hinted up front (tools/d2h_probe.py), and in
+# the cell a merged step of 7.7–8.2 s at 0, 11.1–11.3 at 1, 11.2–11.4 at 2.
+_FETCH_WINDOW = 0
 
 
 class _Unresolved:
@@ -463,6 +486,15 @@ class _BucketPlan:
                         b.wire_bypass = True
                     split.append(b)
             self.buckets = split
+        # The order the streamed exchange takes the buckets in: largest first
+        # (ties by plan index), so the big ring ops are on the wire while
+        # most of the tree is still to be fetched and the tail after the last
+        # fetch is the smallest bucket's.  A function of the tree's signature
+        # alone: peers pair ring ops by their order of issue, so every group
+        # must take the same order.
+        self.fetch_order: List[int] = sorted(
+            range(len(self.buckets)), key=lambda k: (-self.buckets[k].nbytes, k)
+        )
         self.device: List[Optional[_DeviceBucket]] = []
         for b in self.buckets:
             dev: Optional[_DeviceBucket] = None
@@ -504,12 +536,13 @@ class GradientAverager:
     larger buckets amortize DCN round-trips, smaller ones start the overlap
     earlier.
 
-    ``pipelined=True`` (default) issues each bucket's D2H fetch and its
-    ``manager.allreduce`` as soon as that bucket's leaves land, so early
-    buckets ride the wire while later ones are still leaving the device.
+    ``pipelined=True`` (default) streams the buckets (see the module
+    docstring): largest first, each ring op issued as its bucket lands, each
+    resolved bucket sent home while later ones are still leaving the device.
     ``pipelined=False`` is the monolithic reference path — one blocking
-    ``device_get_tree`` of every leaf, then pack+issue — kept for A/B
-    benchmarking (``bench_allreduce.py``) and debugging.
+    ``device_get_tree`` of every leaf, then pack+issue in plan order, one
+    drain, one scatter-back — kept for A/B benchmarking
+    (``bench_allreduce.py``), debugging, and as the tests' oracle.
 
     ``device_wire_prep`` (default: ``TPUFT_DEVICE_WIRE_PREP``) moves the
     cast to the collective's wire dtype onto the device as a jitted
@@ -517,9 +550,10 @@ class GradientAverager:
     when the collective wires bf16; ``sharded_fetch`` (default:
     ``TPUFT_SHARDED_FETCH``) additionally fetches and ring-reduces each
     bucket per local-device shard slice (see the module docstring).  Both
-    apply to the pipelined path only — the monolithic path stays the
-    untouched host-cast reference for A/B.  Submission order of the
-    per-slice ring ops is part of the cross-rank tag contract: every
+    apply to the streamed path only — the monolithic path stays the
+    untouched host-cast reference for A/B.  Submission order of the ring
+    ops (the plan's fetch order, a function of the tree's signature alone;
+    per slice within a bucket) is part of the cross-rank tag contract: every
     replica group must run the same mode, like every other collective
     knob — and for ``sharded_fetch`` the contract is ENVIRONMENTAL too:
     every group's process must see the SAME local device count (slice
@@ -658,7 +692,7 @@ class GradientAverager:
         """
         import jax
 
-        from torchft_tpu.futures import device_get_into, device_get_tree
+        from torchft_tpu.futures import device_get_tree
 
         leaves, treedef = jax.tree.flatten(grads)
         if not leaves:
@@ -682,8 +716,6 @@ class GradientAverager:
             l if hasattr(l, "shape") else np.asarray(l) for l in leaves
         ]
         plan = self._plan_for(leaves, treedef, is_jax)
-        step = self._manager.current_step()
-        timeout = self._manager.timeout.total_seconds()
         stats = {
             "d2h_bytes": 0,
             "h2d_bytes": 0,
@@ -729,182 +761,36 @@ class GradientAverager:
                 return b.numel * wire_target.itemsize
             return b.nbytes
 
-        # Kick off the device->host DMA for every HOST-path leaf up front
-        # (no-op off accelerator): by the time bucket k's blocking copy
-        # runs, its bytes are already in flight behind buckets 0..k-1's.
-        # Device-prepped buckets fetch the jitted epilogue's output, not
-        # the raw leaves — hinting those would stage the full-width copy
-        # the epilogue exists to avoid.
-        host_leaf_idx = {
-            i
-            for b, d in zip(plan.buckets, plan.device)
-            if d is None
-            for i in b.indices
-        }
-        for i, l in enumerate(leaves):
-            if i not in host_leaf_idx:
-                continue
-            copy_async = getattr(l, "copy_to_host_async", None)
-            if copy_async is not None:
-                try:
-                    copy_async()
-                except Exception:  # noqa: BLE001 — a hint, never load-bearing
-                    pass
-
-        # Dispatch EVERY single-device epilogue before the first blocking
-        # fetch — jit dispatch is async, so bucket k+1's cast runs on
-        # device under bucket k's D2H wait (the device-path analogue of the
-        # copy_to_host_async hint above; without this, later epilogues
-        # would not even be dispatched until the earlier fetch returned).
-        # Multi-device (sharded) programs stay lazy: they serialize behind
-        # _SHARDED_EXEC_LOCK with a blocking wait anyway.
-        flat_devs: Dict[int, Any] = {}
         if self._pipelined:
-            for k, (bucket, dev) in enumerate(zip(plan.buckets, plan.device)):
-                if dev is not None and not dev.multi_device:
-                    flat_devs[k] = dev.prep([leaves[i] for i in bucket.indices])
-
-        def sub_of(k: int) -> Callable[..., Any]:
-            """Bucket k's sub-span factory for ``device_get_into``."""
-            return functools.partial(
-                self._manager.spans.sub, step=step, bucket=k
+            return self._allreduce_streamed(
+                grads, leaves, treedef, is_jax, plan, stats, wire_nbytes
             )
 
-        hosts: List[Any] = []
-        if not self._pipelined:
-            # Monolithic reference path: one deadline-guarded fetch of the
-            # whole tree, then pack+issue every bucket.
-            with self._manager.spans.span("allreduce_d2h", step=step) as sp:
-                try:
-                    hosts = device_get_tree(leaves, timeout)
-                except TimeoutError as e:
-                    self._manager.report_error(e)
-                    return grads
-                d2h = sum(int(getattr(l, "nbytes", 0)) for l in leaves)
-                sp.fields["bytes"] = d2h
-            stats["d2h_bytes"] += d2h
-            self._note("d2h", d2h)
+        # Monolithic reference path: one deadline-guarded fetch of the
+        # whole tree, then pack+issue every bucket in plan order, one drain,
+        # one scatter-back.  Every bucket is a host-path bucket here: device
+        # wire prep and sharded fetch are planned for the streamed path only.
+        step = self._manager.current_step()
+        timeout = self._manager.timeout.total_seconds()
+        with self._manager.spans.span("allreduce_d2h", step=step) as sp:
+            try:
+                hosts = device_get_tree(leaves, timeout)
+            except TimeoutError as e:
+                self._manager.report_error(e)
+                return grads
+            d2h = sum(int(getattr(l, "nbytes", 0)) for l in leaves)
+            sp.fields["bytes"] = d2h
+        stats["d2h_bytes"] += d2h
+        self._note("d2h", d2h)
 
-        # pending: (kind, bucket, dev, buf, payload) where payload is one
-        # future ("host"/"device") or a [(shard, start, stop, view, fut)]
-        # list ("sharded").
-        pending: List[Tuple[str, _Bucket, Any, Any, Any]] = []
-        for k, (bucket, buf, views, dev) in enumerate(
-            zip(plan.buckets, plan.buffers, plan.views, plan.device)
+        pending: List[Tuple[_Bucket, np.ndarray, Future]] = []
+        for k, (bucket, buf, views) in enumerate(
+            zip(plan.buckets, plan.buffers, plan.views)
         ):
-            if dev is not None and self._pipelined:
-                if dev.multi_device:
-                    with _SHARDED_EXEC_LOCK:
-                        flat_dev = dev.prep([leaves[i] for i in bucket.indices])
-                        jax.block_until_ready(flat_dev)
-                else:
-                    flat_dev = flat_devs[k]
-                dev.last_sharding = getattr(flat_dev, "sharding", None)
-                parts = (
-                    _shard_slices(flat_dev) if self._sharded_fetch else None
-                )
-                if parts is not None:
-                    # Sharded fetch: each shard slice comes straight off its
-                    # device and rides the ring as its own tagged op — the
-                    # bucket's cross-group allreduce decomposes into
-                    # per-slice reduce-scatter + allgather, and the slices
-                    # overlap each other on the wire like buckets do.
-                    slice_futs = []
-                    for shard, start, stop in parts:
-                        view = dev.buffer[start:stop]
-                        with self._manager.spans.span(
-                            "allreduce_d2h", step=step, bytes=view.nbytes, bucket=k
-                        ):
-                            try:
-                                device_get_into(
-                                    [(shard.data, view)], timeout, sub=sub_of(k)
-                                )
-                            except TimeoutError as e:
-                                self._manager.report_error(e)
-                                return grads
-                        stats["d2h_bytes"] += view.nbytes
-                        self._note("d2h", view.nbytes)
-                        slice_futs.append(
-                            (
-                                shard,
-                                start,
-                                stop,
-                                view,
-                                self._manager.allreduce(
-                                    view, donate=True, bucket=k
-                                ),
-                            )
-                        )
-                    stats["slices"] += len(parts)
-                    stats["wire_bytes"] += wire_nbytes(bucket)
-                    pending.append(("sharded", bucket, dev, buf, slice_futs))
-                else:
-                    with self._manager.spans.span(
-                        "allreduce_d2h", step=step, bytes=dev.buffer.nbytes, bucket=k
-                    ):
-                        try:
-                            device_get_into(
-                                [(flat_dev, dev.buffer)], timeout, sub=sub_of(k)
-                            )
-                        except TimeoutError as e:
-                            self._manager.report_error(e)
-                            return grads
-                    stats["d2h_bytes"] += dev.buffer.nbytes
-                    self._note("d2h", dev.buffer.nbytes)
-                    stats["wire_bytes"] += wire_nbytes(bucket)
-                    pending.append(
-                        (
-                            "device",
-                            bucket,
-                            dev,
-                            buf,
-                            self._manager.allreduce(
-                                dev.buffer, donate=True, bucket=k
-                            ),
-                        )
-                    )
-                continue
-            if self._pipelined:
-                # Deadline-guarded device->host straight into the persistent
-                # buffer: wedged device work latches an error instead of
-                # hanging the step (stream_timeout analogue).  Spanned as
-                # allreduce_d2h — this wait blocks the train thread and must
-                # be attributed as FT time, not productive compute.
-                with self._manager.spans.span(
-                    "allreduce_d2h", step=step, bytes=bucket.nbytes, bucket=k
-                ):
-                    try:
-                        device_get_into(
-                            [(leaves[i], view) for i, view in views],
-                            timeout,
-                            sub=sub_of(k),
-                        )
-                    except TimeoutError as e:
-                        self._manager.report_error(e)
-                        return grads
-                stats["d2h_bytes"] += bucket.nbytes
-                self._note("d2h", bucket.nbytes)
-            else:
-                for i, view in views:
-                    np.copyto(view, np.asarray(hosts[i]).reshape(view.shape))
-            # Bucket k hits the wire here while bucket k+1 is still copying
-            # off the device (and, with ring lanes, while bucket k-1 is still
-            # mid-flight — the collective overlaps back-to-back calls).
-            # Split-out 0-d/scalar buckets opt OUT of the lossy wire
-            # encoding — full-width is the contract, not just full-width
-            # fetch.
+            for i, view in views:
+                np.copyto(view, np.asarray(hosts[i]).reshape(view.shape))
             stats["wire_bytes"] += wire_nbytes(bucket)
-            # The bucket plan's staging buffer is rewritten from the leaves
-            # every step, so the op may own it for the round: donate lets
-            # the native engine reduce in place with no working-buffer copy.
-            fut = (
-                self._manager.allreduce(
-                    buf, allow_wire_compression=False, donate=True, bucket=k
-                )
-                if bucket.wire_bypass
-                else self._manager.allreduce(buf, donate=True, bucket=k)
-            )
-            pending.append(("host", bucket, dev, buf, fut))
+            pending.append((bucket, buf, self._issue(bucket, buf, k)))
 
         out: List[Any] = list(leaves)
         # The bucket drain blocks this (train) thread on the ring exchange —
@@ -914,96 +800,43 @@ class GradientAverager:
         # as busy for the whole stall — hiding exactly the straggler the
         # step-time telemetry exists to expose (the commit-time drain of
         # what remains keeps the same phase name; the accumulator sums).
-        resolved: List[Any] = []
         with self._manager.spans.span("allreduce_merge", step=step):
-            for kind, bucket, dev, buf, payload in pending:
-                if kind == "sharded":
-                    resolved.append(
-                        [
-                            (shard, start, stop, view, fut.result())
-                            for shard, start, stop, view, fut in payload
-                        ]
-                    )
-                else:
-                    resolved.append(payload.result())
+            resolved = [fut.result() for _bucket, _buf, fut in pending]
 
-        # Scatter-back: device-prepped results go home as wire-dtype bytes
-        # (H2D moves bf16; the upcast to the leaf dtype runs on device in
-        # the jitted inverse).  Spanned as allreduce_h2d — like the fetch,
-        # this is FT time on the train thread, never productive compute.
+        # Scatter-back, spanned as allreduce_h2d — like the fetch, this is
+        # FT time on the train thread, never productive compute.
         # Collective failures resolve a bucket to its own input buffer
         # (wrap_future's default); those buckets keep their ORIGINAL leaves
         # untouched — the error is latched and the commit vote fails.
         with self._manager.spans.span("allreduce_h2d", step=step) as sp_h2d:
             h2d_bytes = 0
-            for k, ((kind, bucket, dev, buf, _payload), res) in enumerate(
-                zip(pending, resolved)
-            ):
-                # One bucket's way back: unpack, or device_put + the inverse.
+            for k, ((bucket, buf, _fut), res) in enumerate(zip(pending, resolved)):
+                # One bucket's way back: unpack.
                 with self._manager.spans.sub(
                     "h2d_put", step=step, bucket=k, bytes=bucket.nbytes
                 ):
-                    if kind == "host":
-                        flat = np.asarray(res)
-                        if flat is buf:
-                            # Latched failure resolved to the donated staging
-                            # buffer — with donate the op may have half-reduced
-                            # it, so it must not be republished as gradients.
-                            # Leaves stay untouched; the commit vote fails.
-                            continue
-                        # The average was taken in the ring's buffer, which
-                        # with the native engine is ``buf`` itself, and the
-                        # next call rewrites ``buf``: a leaf that would go on
-                        # living in host memory leaves as a copy.
-                        owned = not np.may_share_memory(flat, buf)
-                        for idx, arr in bucket.unpack(flat):
-                            out[idx] = (
-                                arr
-                                if owned or (is_jax[idx] and not _lives_in_host_memory(leaves[idx]))
-                                else arr.copy()
-                            )
-                    elif kind == "device":
-                        if res is dev.buffer:
-                            continue  # latched failure: leaves stay untouched
-                        flat_host = np.asarray(res)
-                        h2d_bytes += flat_host.nbytes
-                        with _SHARDED_EXEC_LOCK if dev.multi_device else nullcontext():
-                            flat_back = (
-                                jax.device_put(flat_host, dev.last_sharding)
-                                if dev.last_sharding is not None
-                                else jax.device_put(flat_host)
-                            )
-                            backs = dev.unprep(flat_back)
-                            if dev.multi_device:
-                                jax.block_until_ready(backs)
-                        for idx, arr in zip(bucket.indices, backs):
-                            out[idx] = arr
-                    else:  # sharded
-                        if any(r is view for _, _, _, view, r in res):
-                            continue  # latched failure: leaves stay untouched
-                        flat_host = np.concatenate(
-                            [np.asarray(r).reshape(-1) for _, _, _, _, r in res]
+                    flat = np.asarray(res)
+                    if flat is buf:
+                        # Latched failure resolved to the donated staging
+                        # buffer — with donate the op may have half-reduced
+                        # it, so it must not be republished as gradients.
+                        # Leaves stay untouched; the commit vote fails.
+                        continue
+                    # The average was taken in the ring's buffer, which
+                    # with the native engine is ``buf`` itself, and the
+                    # next call rewrites ``buf``: a leaf that would go on
+                    # living in host memory leaves as a copy.
+                    owned = not np.may_share_memory(flat, buf)
+                    for idx, arr in bucket.unpack(flat):
+                        out[idx] = (
+                            arr
+                            if owned or (is_jax[idx] and not _lives_in_host_memory(leaves[idx]))
+                            else arr.copy()
                         )
-                        h2d_bytes += flat_host.nbytes
-                        # device_put with the epilogue's sharding performs the
-                        # per-shard H2D placement: each slice lands on its own
-                        # device (each host transfers only its addressable
-                        # slices), and the jitted inverse upcasts in HBM.
-                        with _SHARDED_EXEC_LOCK:
-                            flat_back = jax.device_put(flat_host, dev.last_sharding)
-                            backs = dev.unprep(flat_back)
-                            jax.block_until_ready(backs)
-                        for idx, arr in zip(bucket.indices, backs):
-                            out[idx] = arr
 
-            serialize = any(
-                d is not None and d.multi_device for d in plan.device
-            )
             devices = []
             # The per-leaf placement of what came back as host arrays, as one.
-            with self._manager.spans.sub(
-                "h2d_put", step=step
-            ) as sub_put, _SHARDED_EXEC_LOCK if serialize else nullcontext():
+            with self._manager.spans.sub("h2d_put", step=step) as sub_put:
                 for i, a in enumerate(out):
                     if is_jax[i]:
                         if not isinstance(a, jax.Array):
@@ -1024,6 +857,297 @@ class GradientAverager:
         stats["h2d_bytes"] += h2d_bytes
         self._note("h2d", h2d_bytes)
         return jax.tree.unflatten(treedef, devices)
+
+    def _issue(self, bucket: _Bucket, buf: np.ndarray, k: int) -> Future:
+        """Hands one host-path bucket's flat buffer to the ring."""
+        # Split-out 0-d/scalar buckets opt OUT of the lossy wire encoding —
+        # full-width is the contract, not just full-width fetch.  The bucket
+        # plan's staging buffer is rewritten from the leaves every step, so
+        # the op may own it for the round: donate lets the native engine
+        # reduce in place with no working-buffer copy.
+        if bucket.wire_bypass:
+            return self._manager.allreduce(
+                buf, allow_wire_compression=False, donate=True, bucket=k
+            )
+        return self._manager.allreduce(buf, donate=True, bucket=k)
+
+    def _allreduce_streamed(
+        self,
+        grads: Any,
+        leaves: List[Any],
+        treedef: Any,
+        is_jax: List[bool],
+        plan: _BucketPlan,
+        stats: Dict[str, int],
+        wire_nbytes: Callable[[_Bucket], int],
+    ) -> Any:
+        """The exchange as one stream over the buckets in ``plan.fetch_order``:
+        fetch, ring, way back — a bucket's ring op and its return to the
+        device run under the fetches of the buckets after it."""
+        import jax
+
+        from torchft_tpu.futures import device_get_into
+
+        spans = self._manager.spans
+        step = self._manager.current_step()
+        timeout = self._manager.timeout.total_seconds()
+        order = plan.fetch_order
+
+        # Dispatch EVERY single-device epilogue before the first blocking
+        # fetch — jit dispatch is async, so a later bucket's cast runs on
+        # device under an earlier bucket's D2H wait (device programs, not
+        # transfers: the window below does not bound them).  Multi-device
+        # (sharded) programs stay lazy: they serialize behind
+        # _SHARDED_EXEC_LOCK with a blocking wait anyway.
+        flat_devs: Dict[int, Any] = {
+            k: dev.prep([leaves[i] for i in plan.buckets[k].indices])
+            for k, dev in enumerate(plan.device)
+            if dev is not None and not dev.multi_device
+        }
+
+        def hint(k: int) -> bool:
+            """Starts the device->host copy of bucket k's leaves (a no-op off
+            accelerator); False where there was nothing to start.  Device-
+            prepped buckets fetch the jitted epilogue's output, not the raw
+            leaves — hinting those would stage the full-width copy the
+            epilogue exists to avoid."""
+            if plan.device[k] is not None:
+                return False
+            started = False
+            for i in plan.buckets[k].indices:
+                copy_async = getattr(leaves[i], "copy_to_host_async", None)
+                if copy_async is not None:
+                    try:
+                        copy_async()
+                        started = True
+                    except Exception:  # noqa: BLE001 — a hint, never load-bearing
+                        pass
+            return started
+
+        def fetch(k: int, pairs: list, nbytes: int, **where: int) -> bool:
+            """One blocking, deadline-guarded fetch into a persistent buffer:
+            wedged device work latches an error instead of hanging the step
+            (stream_timeout analogue).  Spanned as allreduce_d2h — this wait
+            blocks the train thread and must be attributed as FT time, not
+            productive compute."""
+            with spans.span("allreduce_d2h", step=step, bytes=nbytes, bucket=k, **where):
+                try:
+                    device_get_into(
+                        pairs,
+                        timeout,
+                        sub=functools.partial(spans.sub, step=step, bucket=k),
+                    )
+                except TimeoutError as e:
+                    self._manager.report_error(e)
+                    return False
+            stats["d2h_bytes"] += nbytes
+            self._note("d2h", nbytes)
+            return True
+
+        def fetch_and_issue(k: int, **where: int) -> Optional[Tuple[int, str, Any]]:
+            """Bucket k off the device and onto the ring: (k, "host" or
+            "device", future) or (k, "sharded", [(view, future)]); None where
+            a fetch timed out."""
+            bucket, dev = plan.buckets[k], plan.device[k]
+            if dev is None:
+                pairs = [(leaves[i], view) for i, view in plan.views[k]]
+                if not fetch(k, pairs, bucket.nbytes, **where):
+                    return None
+                stats["wire_bytes"] += wire_nbytes(bucket)
+                return k, "host", self._issue(bucket, plan.buffers[k], k)
+            if dev.multi_device:
+                with _SHARDED_EXEC_LOCK:
+                    flat_dev = dev.prep([leaves[i] for i in bucket.indices])
+                    jax.block_until_ready(flat_dev)
+            else:
+                flat_dev = flat_devs[k]
+            dev.last_sharding = getattr(flat_dev, "sharding", None)
+            parts = _shard_slices(flat_dev) if self._sharded_fetch else None
+            if parts is None:
+                if not fetch(k, [(flat_dev, dev.buffer)], dev.buffer.nbytes, **where):
+                    return None
+                stats["wire_bytes"] += wire_nbytes(bucket)
+                return k, "device", self._manager.allreduce(
+                    dev.buffer, donate=True, bucket=k
+                )
+            # Sharded fetch: each shard slice comes straight off its device
+            # and rides the ring as its own tagged op — the bucket's
+            # cross-group allreduce decomposes into per-slice reduce-scatter
+            # + allgather, and the slices overlap each other on the wire
+            # like buckets do.
+            slice_futs = []
+            for shard, start, stop in parts:
+                view = dev.buffer[start:stop]
+                if not fetch(k, [(shard.data, view)], view.nbytes, **where):
+                    return None
+                slice_futs.append(
+                    (view, self._manager.allreduce(view, donate=True, bucket=k))
+                )
+            stats["slices"] += len(parts)
+            stats["wire_bytes"] += wire_nbytes(bucket)
+            return k, "sharded", slice_futs
+
+        out: List[Any] = list(leaves)
+
+        def put_back(k: int, kind: str, payload: Any) -> int:
+            """Bucket k's way back, its ring op(s) resolved: the averaged
+            leaves go to their devices NOW (``jax.device_put`` returns at
+            once and the copy runs under the fetches still to come).  Returns
+            the bytes handed to the device.  Collective failures resolve a
+            bucket to its own input buffer (wrap_future's default); such a
+            bucket keeps its ORIGINAL leaves untouched — the error is latched
+            and the commit vote fails."""
+            bucket, dev = plan.buckets[k], plan.device[k]
+            if kind == "host":
+                buf = plan.buffers[k]
+                flat = np.asarray(payload.result())
+                if flat is buf:
+                    # With donate the op may have half-reduced the staging
+                    # buffer: it must not be republished as gradients.
+                    return 0
+                # The average was taken in the ring's buffer, which with the
+                # native engine is ``buf`` itself, and the next call rewrites
+                # ``buf``: a leaf that would go on living in host memory
+                # leaves as a copy.
+                owned = not np.may_share_memory(flat, buf)
+                nbytes = 0
+                for idx, arr in bucket.unpack(flat):
+                    if not is_jax[idx]:
+                        out[idx] = arr if owned else arr.copy()
+                        continue
+                    if not owned and _lives_in_host_memory(leaves[idx]):
+                        arr = arr.copy()
+                    nbytes += arr.nbytes
+                    out[idx] = jax.device_put(arr, leaves[idx].sharding)
+                return nbytes
+            # Device-prepped results go home as wire-dtype bytes (H2D moves
+            # bf16; the upcast to the leaf dtype runs on device in the jitted
+            # inverse).
+            if kind == "device":
+                res = payload.result()
+                if res is dev.buffer:
+                    return 0
+                flat_host = np.asarray(res)
+            else:
+                results = [(view, fut.result()) for view, fut in payload]
+                if any(r is view for view, r in results):
+                    return 0
+                flat_host = np.concatenate(
+                    [np.asarray(r).reshape(-1) for _, r in results]
+                )
+            # device_put with the epilogue's sharding performs the per-shard
+            # H2D placement: each slice lands on its own device (each host
+            # transfers only its addressable slices).
+            with _SHARDED_EXEC_LOCK if dev.multi_device else nullcontext():
+                flat_back = (
+                    jax.device_put(flat_host, dev.last_sharding)
+                    if dev.last_sharding is not None
+                    else jax.device_put(flat_host)
+                )
+                backs = dev.unprep(flat_back)
+                if dev.multi_device:
+                    jax.block_until_ready(backs)
+                for idx, arr in zip(bucket.indices, backs):
+                    out[idx] = jax.device_put(arr, leaves[idx].sharding)
+            return flat_host.nbytes
+
+        # On the ring, in fetch order: (k, kind, payload).
+        pending: List[Tuple[int, str, Any]] = []
+        h2d_bytes = 0
+
+        def futures_of(entry: Tuple[int, str, Any]) -> List[Future]:
+            _k, kind, payload = entry
+            return [fut for _view, fut in payload] if kind == "sharded" else [payload]
+
+        def harvest() -> int:
+            """Sends home every bucket whose ring op has resolved, in a short
+            allreduce_h2d span of their own (none where nothing is ready), so
+            between two fetches it lies outside any allreduce_d2h span.
+            Returns how many buckets went."""
+            nonlocal h2d_bytes
+            ready = [e for e in pending if all(f.done() for f in futures_of(e))]
+            if not ready:
+                return 0
+            pending[:] = [e for e in pending if e not in ready]
+            with spans.span("allreduce_h2d", step=step) as sp_h2d:
+                put = 0
+                for entry in ready:
+                    with spans.sub(
+                        "h2d_put",
+                        step=step,
+                        bucket=entry[0],
+                        bytes=plan.buckets[entry[0]].nbytes,
+                    ):
+                        put += put_back(*entry)
+                sp_h2d.fields["bytes"] = put
+            h2d_bytes += put
+            return len(ready)
+
+        # The window.  Before the blocking fetch at position ``pos`` only the
+        # next _FETCH_WINDOW buckets have their copy started — never the
+        # whole tree: the runtime runs hinted transfers side by side, so with
+        # every leaf hinted the FIRST fetch returned when ALL had landed and
+        # ring and way back could only start after the whole copy.  (A fetch
+        # starts its own copy, so position 0 needs no hint, and with a window
+        # of 0 none is made at all.)
+        taken = [False] * len(order)
+        hinted = 1
+        early_puts = 0
+        for pos, k in enumerate(order):
+            hinted = max(hinted, pos + 1)
+            while hinted < min(pos + 1 + _FETCH_WINDOW, len(order)):
+                taken[hinted] = hint(order[hinted])
+                hinted += 1
+            entry = fetch_and_issue(k, pos=pos, inflight=sum(taken[pos + 1 : hinted]))
+            if entry is None:
+                return grads
+            # Bucket k hits the wire here while the buckets after it are
+            # still on the device (and, with ring lanes, while the one before
+            # it is still mid-flight — the collective overlaps back-to-back
+            # calls); whatever the ring has finished meanwhile goes home.
+            pending.append(entry)
+            if pos + 1 < len(order):
+                early_puts += harvest()
+        fetched_at = time.monotonic()
+
+        # What is still on the ring when the last fetch has landed, in fetch
+        # order.  The wait blocks this (train) thread on the ring exchange —
+        # i.e. on the SLOWEST peer's gradients.  Span it as allreduce_merge:
+        # unrecorded, this wait would be charged as productive/busy time,
+        # and on a cluster with one slow host EVERY fast replica would read
+        # as busy for the whole stall — hiding exactly the straggler the
+        # step-time telemetry exists to expose (the commit-time drain of
+        # what remains keeps the same phase name; the accumulator sums).
+        while pending:
+            waiting = [f for f in futures_of(pending[0]) if not f.done()]
+            if waiting:
+                with spans.span("allreduce_merge", step=step):
+                    for fut in waiting:
+                        fut.result()
+            harvest()
+
+        # device_put may read its source after it returns, and the sources
+        # are views of the plan's persistent buffers: no host view is held
+        # past this call.  The one wait for every put of this call.
+        with spans.span("allreduce_h2d", step=step):
+            with spans.sub("h2d_put", step=step, bytes=h2d_bytes):
+                jax.block_until_ready([a for a in out if isinstance(a, jax.Array)])
+        stats["h2d_bytes"] += h2d_bytes
+        stats["early_puts"] = early_puts
+        self._note("h2d", h2d_bytes)
+        note_fields = getattr(self._manager, "note_summary_fields", None)
+        if callable(note_fields):
+            try:
+                note_fields(
+                    exchange_stream={
+                        "early_puts": early_puts,
+                        "buckets": len(order),
+                        "tail_s": round(time.monotonic() - fetched_at, 4),
+                    }
+                )
+            except Exception:  # noqa: BLE001 — telemetry only
+                pass
+        return jax.tree.unflatten(treedef, out)
 
 
 class PerLeafGradientAverager:

@@ -56,11 +56,17 @@ __all__ = [
 # fetch into the persistent flat buffers (blocks the train thread, so it
 # is FT-overhead time, NOT productive compute — report.py charges it to
 # the other-FT bucket and the straggler sentinel subtracts it from busy
-# time); allreduce_h2d = the matching result scatter-back (device_put of
+# time; fields: bucket, bytes, pos = the bucket's place in the plan's
+# fetch order, inflight = hinted copies beyond it when the fetch began);
+# allreduce_h2d = the matching result scatter-back (device_put of
 # reduced buckets onto the leaves' devices/shardings — with device wire
 # prep it moves wire-dtype bytes; charged exactly like allreduce_d2h so
-# the FULL round-trip cost is attributed, not just the fetch);
-# allreduce_merge = drain of pending allreduce futures at commit
+# the FULL round-trip cost is attributed, not just the fetch).  It may
+# occur SEVERAL times a step: the streamed exchange sends every resolved
+# bucket home between two fetches, each harvest in a span of its own,
+# and waits for all puts in a last one; the accumulator sums them;
+# allreduce_merge = the wait for ring ops still in flight after the last
+# fetch, and the drain of pending allreduce futures at commit
 # time; commit_vote = the two-phase commit barrier RPC; snapshot = the
 # donor-side device->host flatten on the HTTP transport's background
 # snapshotter — an OVERLAPPED phase (it runs concurrently with the train
@@ -111,8 +117,10 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 #   normalize — the averaging continuation on the thread that resolved the
 #     op's future: it divides in the op's own buffer (``in_place``: true)
 #     where the result shows it may, else into a new array with a cast.
-#   h2d_put — one bucket's way back (device_put + unpack), and the final
-#     per-leaf device_put loop as one.
+#   h2d_put — one bucket's way back (unpack + device_put, where its ring
+#     op was harvested: possibly between two fetches), and without
+#     ``bucket`` the one wait for every put of the call (``bytes``: all of
+#     them).
 #   quorum_wait — what the TRAIN thread waits for the quorum (the ``quorum``
 #     phase is the quorum thread's RPC).
 #   ft_step — the frame of one TrainStep.ft_step (speculative = which update
