@@ -1,79 +1,41 @@
-"""Elastic quorum spot-market bench: constant global batch across churn.
+"""The elastic-quorum cell, driven by
+tests/test_integration_smokes.py::test_elastic_quick_smoke: constant global
+batch across membership churn.
 
-ISSUE 20's tentpole (c) — the production story for preemptible fleets.  A
-SEEDED arrival/departure trace drives a live cluster of real Manager
+A SEEDED arrival/departure trace drives a live cluster of real Manager
 subprocess groups through membership churn while the elastic batch engine
 (`TPUFT_ELASTIC_GLOBAL_BATCH`, ddp.ElasticBatchScaler) holds the global
 batch constant: survivors take larger per-group shares when the quorum
-shrinks, spares hot-admit and the share relaxes back.  Scored by the
-goodput ledger's commit stream against a FIXED-SIZE ORACLE cell (same
-worker, same step cost, no churn), normalized per group-second of live
-capacity — so the ratio isolates exactly the cost of riding the churn.
+shrinks, joiners hot-admit and the share relaxes back.  What the cell
+returns is counts from the metrics stream: failed commits, step records
+that carry the plan, reconfigure modes, EC re-shard pushes, leaked fds.
 
 Departures take the COOPERATIVE drain path (`lighthouse.drain`): spot
 reclaim gives notice, the lighthouse excludes the leaver from the next
 quorum immediately, the leaver finishes its in-flight step and exits via
-`Manager.complete_drain()` — which is what makes the "zero failed survivor
-commits across every transition" gate honest rather than aspirational
-(SIGKILL mid-allreduce necessarily fails one survivor round; that path is
-bench.py's kill scenario and the churn soak's job, not this trace's).
-Arrivals are freshly spawned groups that pre-warm their runtime BEFORE
-dialing the lighthouse (the launch.py spare-pool shape), then hot-admit at
-the next step boundary.
+`Manager.complete_drain()` -- which is what makes "zero failed survivor
+commits across every transition" honest rather than aspirational (SIGKILL
+mid-allreduce necessarily fails one survivor round; that is the churn
+soak's job, not this trace's).  Arrivals are freshly spawned groups that
+hot-admit at the next step boundary.
 
-What one full trace exercises, per ELASTIC_BENCH.json evidence fields:
-
-  ring2d <-> ring crossover — `TPUFT_RING_TOPOLOGY=auto` with
-      `TPUFT_RING2D_MIN_GROUPS=4`: the 4<->3 transitions cross the
-      hierarchical/flat boundary in both directions (full reconfigure),
-      the 3<->2 transitions stay flat (incremental lane reuse), and the
-      reconfigure-mode counters in the metrics stream prove both paths ran.
-  bucket-plan invalidation — workers run a real GradientAverager over a
-      multi-bucket numpy tree; plans are keyed by participant count
-      (ddp._plan_for), so the summary's bucket_plan_participants shows one
-      plan per membership size with recurring sizes re-hitting their plan.
-  EC re-shard — `TPUFT_EC_K=2` + the Manager's proactive
-      `ECPlane.reshard()` on membership change: `ec_push` events with
-      `reshard=true` land at transitions, not just on the encode path.
-  constant global batch — every committed step_summary record carries
-      `elastic_global_batch` (the Manager stamps the live plan), and the
-      cell asserts it never moves while `elastic_participants` does.
-
-Quick mode (``run_quick()``, tier-1's
-tests/test_bench_contract.py::test_elastic_quick_smoke): a 3-group cell
-with 3 cooperative transitions (leave/join/leave, flat-ring incremental
-path), JAX-free workers (plain Manager.allreduce, no averager) for
-subprocess startup speed, plus a short fixed oracle — full ELASTIC_BENCH
-schema, minutes-not-hours.
+The workers are this file run as a script (``--worker``, see the end):
+nothing a person would run.
 """
 
 from __future__ import annotations
 
-import argparse
 import gc
 import json
 import os
 import random
-import statistics
 import subprocess
 import sys
 import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
-REPO = os.path.dirname(os.path.abspath(__file__))
-
-# The drop-and-respawn baseline this trace's transitions are scored
-# against: BENCH_r05's measured dead time per SIGKILL+respawn cycle.
-DEAD_TIME_BASELINE_S = 12.4
-GOODPUT_GATE = 0.85
-
-
-def _fd_count() -> int:
-    try:
-        return len(os.listdir("/proc/self/fd"))
-    except OSError:  # non-procfs platform: fd accounting unavailable
-        return -1
+from harness import REPO, fd_count, script_env
 
 
 # ---------------------------------------------------------------------------
@@ -322,22 +284,19 @@ def run_trace_cell(
     tail_s: float = 6.0,
     min_groups: int = 2,
     ring2d_min: Optional[int] = None,
-    section: str = "elastic_trace",
     worker_env: Optional[Dict[str, str]] = None,
 ) -> Dict[str, Any]:
     """One churn cell: spawn ``start_groups`` workers, run the trace's
     cooperative leaves (lighthouse drain) and hot-admit joins (fresh
-    spawns), then score the commit stream.  An empty ``trace`` is the
-    fixed-size oracle."""
+    spawns), then count what the metrics stream recorded."""
     from torchft_tpu._native import LighthouseServer
     from torchft_tpu.obs import report as obs_report
 
     os.makedirs(workdir, exist_ok=True)
     metrics_path = os.path.join(workdir, "metrics.jsonl")
     gc.collect()
-    fd_before = _fd_count()
+    fd_before = fd_count()
     result: Dict[str, Any] = {
-        "section": section,
         "groups_start": start_groups,
         "global_batch": global_batch,
         "per_sample_s": per_sample_s,
@@ -361,7 +320,7 @@ def run_trace_cell(
             quorum_tick_ms=50,
             heartbeat_timeout_ms=3000,
         )
-        env = dict(os.environ)
+        env = script_env()
         env["TPUFT_METRICS_PATH"] = metrics_path
         env["TPUFT_ELASTIC_GLOBAL_BATCH"] = str(global_batch)
         # EC plane on: shards of each committed step's state spread across
@@ -394,7 +353,7 @@ def run_trace_cell(
                 obs_report.read_events([metrics_path])
             )
 
-        # Ready/go barrier (bench_scale's lesson): release together so the
+        # Ready/go barrier (fleet_cells' lesson): release together so the
         # first quorum holds the full starting set.
         ready_deadline = time.time() + 90.0 + 2.0 * start_groups
         while time.time() < ready_deadline:
@@ -418,7 +377,6 @@ def run_trace_cell(
         result["warmed_groups"] = sum(
             1 for g in range(start_groups) if len(cs.get(str(g), [])) >= 2
         )
-        t0 = time.time()  # counted window opens here
 
         live = list(range(start_groups))
         transitions: List[Dict[str, Any]] = []
@@ -471,7 +429,6 @@ def run_trace_cell(
                 }
             )
         time.sleep(tail_s)
-        t1 = time.time()  # counted window closes at the stop signal
         with open(os.path.join(workdir, "stop"), "w"):
             pass
         # Linger protocol: every live group checks in, then done_all
@@ -501,74 +458,17 @@ def run_trace_cell(
             1 for t in transitions if t["stabilized"]
         )
 
-        # Committed work in the counted window: committed steps are
-        # cluster-lockstep, so distinct step numbers x the constant global
-        # batch IS the sample count — immune to double-counting per group.
-        committed_steps = {
+        # Committed steps are cluster-lockstep, so distinct step numbers
+        # count the cell's work without double-counting per group.
+        result["committed_steps"] = len({
             int(ev["step"])
             for ev in events
-            if ev.get("event") == "commit"
-            and ev.get("committed")
-            and t0 <= float(ev["ts"]) <= t1
-        }
-        result["committed_steps"] = len(committed_steps)
-        result["committed_samples"] = len(committed_steps) * global_batch
-
-        # Live capacity integral over the counted window: leaves stop
-        # counting at the drain notice; joiners start counting at their
-        # first commit (before that they are healing, not capacity).
-        marks: List[tuple] = []  # (ts, delta)
-        for t in transitions:
-            if t["kind"] == "leave":
-                marks.append((t["ts"], -1))
-            else:
-                first = next(
-                    (x for x in cs.get(str(t["group"]), []) if x > t["ts"]),
-                    None,
-                )
-                marks.append((first if first is not None else t["ts"], +1))
-        marks.sort()
-        capacity = 0.0
-        n = start_groups
-        prev = t0
-        for ts, delta in marks:
-            ts = min(max(ts, t0), t1)
-            capacity += n * (ts - prev)
-            n += delta
-            prev = ts
-        capacity += n * (t1 - prev)
-        result["window_s"] = round(t1 - t0, 2)
-        result["capacity_group_s"] = round(capacity, 2)
-        result["goodput_samples_per_group_s"] = round(
-            result["committed_samples"] / max(1e-9, capacity), 3
-        )
-
-        # Per-transition dead time: the widest survivor commit gap
-        # straddling the event, minus the anchor's steady step interval.
-        anchor_ts = cs.get("0", [])
-        deltas = [b - a for a, b in zip(anchor_ts, anchor_ts[1:])]
-        steady_s = statistics.median(deltas) if deltas else 0.0
-        result["steady_step_s"] = round(steady_s, 3)
-        for t in transitions:
-            worst = 0.0
-            for s in t["survivors"]:
-                ts_list = cs.get(str(s), [])
-                before = [x for x in ts_list if x <= t["ts"]]
-                after = [x for x in ts_list if x > t["ts"]]
-                if before and after:
-                    worst = max(worst, min(after) - max(before))
-                elif not after:
-                    worst = DEAD_TIME_BASELINE_S  # never recovered: fail loud
-            t["dead_s"] = round(worst, 3)
-            t["dead_adj_s"] = round(max(0.0, worst - steady_s), 3)
+            if ev.get("event") == "commit" and ev.get("committed")
+        })
         result["transitions"] = [
-            {k: t[k] for k in ("kind", "group", "n_after", "stabilized",
-                               "dead_s", "dead_adj_s")}
+            {k: t[k] for k in ("kind", "group", "n_after", "stabilized")}
             for t in transitions
         ]
-        result["max_transition_dead_s"] = max(
-            (t["dead_adj_s"] for t in transitions), default=0.0
-        )
 
         # Failed commits, from the stream (authoritative even if a worker
         # summary line is lost): every group in this cell is either a
@@ -626,26 +526,10 @@ def run_trace_cell(
         result["membership_changes"] = sum(
             1 for ev in events if ev.get("event") == "membership_change"
         )
-        result["membership_transition_s"] = [
-            round(float(ev.get("transition_s") or 0.0), 3)
-            for ev in events
-            if ev.get("event") == "membership_change"
-        ]
         result["ec_reshard_pushes"] = sum(
             1 for ev in events
             if ev.get("event") == "ec_push" and ev.get("reshard")
         )
-
-        # Ledger attribution: lost seconds by cause across the cell — the
-        # `resize` row is the transitions' named cost.
-        lost: Dict[str, float] = {}
-        for ev in events:
-            causes = (ev.get("ledger") or {}).get("causes") or {}
-            for cause, seconds in causes.items():
-                lost[cause] = lost.get(cause, 0.0) + float(seconds)
-        result["lost_seconds_by_cause"] = {
-            k: round(v, 3) for k, v in sorted(lost.items())
-        }
 
         summaries = []
         for path in log_paths:
@@ -669,12 +553,12 @@ def run_trace_cell(
             lighthouse.shutdown()
 
     # fd hygiene: everything the cell opened must be closed.
-    fd_after = _fd_count()
+    fd_after = fd_count()
     settle = time.time() + 5.0
     while fd_after > fd_before and time.time() < settle:
         gc.collect()
         time.sleep(0.2)
-        fd_after = _fd_count()
+        fd_after = fd_count()
     result["fd_leaked"] = max(0, fd_after - fd_before) if fd_before >= 0 else None
 
     result["ok"] = bool(
@@ -683,118 +567,24 @@ def run_trace_cell(
         and result.get("committed_steps", 0) > 0
         and result.get("survivor_failed_commits") == 0
         and result.get("elastic_records", {}).get("constant_global_batch")
-        and result.get("max_transition_dead_s", 1e9) < DEAD_TIME_BASELINE_S
         and (result.get("fd_leaked") in (0, None))
     )
     return result
 
 
 # ---------------------------------------------------------------------------
-# Full + quick entry points
+# The smoke's cell
 # ---------------------------------------------------------------------------
 
 
-def _score(payload: Dict[str, Any]) -> Dict[str, Any]:
-    """Folds the elastic + oracle cells into the headline gates."""
-    elastic = payload["elastic"]
-    oracle = payload["oracle"]
-    e_good = elastic.get("goodput_samples_per_group_s") or 0.0
-    o_good = oracle.get("goodput_samples_per_group_s") or 0.0
-    ratio = (e_good / o_good) if o_good else 0.0
-    payload["goodput_ratio_vs_oracle"] = round(ratio, 4)
-    payload["goodput_gate"] = GOODPUT_GATE
-    payload["dead_time_baseline_s"] = DEAD_TIME_BASELINE_S
-    payload["max_transition_dead_s"] = elastic.get("max_transition_dead_s")
-    payload["survivor_failed_commits"] = (
-        elastic.get("survivor_failed_commits", 0)
-        + oracle.get("survivor_failed_commits", 0)
-    )
-    payload["constant_global_batch"] = bool(
-        elastic.get("elastic_records", {}).get("constant_global_batch")
-        and oracle.get("elastic_records", {}).get("constant_global_batch")
-    )
-    payload["fd_leaked_total"] = (
-        (elastic.get("fd_leaked") or 0) + (oracle.get("fd_leaked") or 0)
-    )
-    payload["ok"] = bool(
-        elastic.get("ok")
-        and oracle.get("ok")
-        and ratio >= GOODPUT_GATE
-        and payload["survivor_failed_commits"] == 0
-        and payload["constant_global_batch"]
-        and payload["fd_leaked_total"] == 0
-    )
-    return payload
-
-
-def run_full(
-    workdir: Optional[str] = None,
-    seed: int = 20,
-    global_batch: int = 32,
-    per_sample_s: float = 0.02,
-) -> Dict[str, Any]:
-    """The committed ELASTIC_BENCH.json: a 4-group spot trace with 8
-    seeded transitions crossing the ring2d/ring boundary in both
-    directions (TPUFT_RING2D_MIN_GROUPS=4) and dipping to half capacity,
-    vs a fixed 4-group no-churn oracle at identical worker parameters."""
-    workdir = workdir or tempfile.mkdtemp(prefix="tpuft_bench_elastic_")
-    kinds = ["leave", "join", "leave", "leave", "join", "join", "leave", "join"]
-    trace = make_trace(seed, kinds, start_groups=4, gap_range=(4.0, 7.0))
-    payload: Dict[str, Any] = {
-        "metric": "elastic_goodput_vs_oracle",
-        "quick": False,
-        "seed": seed,
-        "global_batch": global_batch,
-        "workdir": workdir,
-    }
-    payload["elastic"] = run_trace_cell(
-        os.path.join(workdir, "elastic"),
-        start_groups=4,
-        trace=trace,
-        global_batch=global_batch,
-        per_sample_s=per_sample_s,
-        use_averager=True,
-        min_groups=2,
-        ring2d_min=4,
-    )
-    payload["oracle"] = run_trace_cell(
-        os.path.join(workdir, "oracle"),
-        start_groups=4,
-        trace=[],
-        global_batch=global_batch,
-        per_sample_s=per_sample_s,
-        use_averager=True,
-        tail_s=40.0,
-        min_groups=2,
-        ring2d_min=4,
-        section="fixed_oracle",
-    )
-    _score(payload)
-    # Crossover evidence gate (full mode only): both reconfigure paths ran.
-    modes = payload["elastic"].get("reconfigure_modes", {})
-    payload["crossover_exercised"] = bool(
-        modes.get("incremental", 0) > 0 and modes.get("full", 0) > 0
-    )
-    payload["ok"] = bool(payload["ok"] and payload["crossover_exercised"])
-    return payload
-
-
 def run_quick(workdir: Optional[str] = None, seed: int = 7) -> Dict[str, Any]:
-    """Tier-1's 3-transition cell: 3 JAX-free groups, cooperative
-    leave/join/leave on the flat-ring incremental path, plus a short fixed
-    oracle — same schema as the full artifact."""
-    workdir = workdir or tempfile.mkdtemp(prefix="tpuft_bench_elastic_q_")
+    """The 3-transition cell: 3 JAX-free groups, cooperative
+    leave/join/leave on the flat-ring incremental path."""
+    workdir = workdir or tempfile.mkdtemp(prefix="tpuft_elastic_q_")
     trace = make_trace(
         seed, ["leave", "join", "leave"], start_groups=3, gap_range=(1.5, 3.0)
     )
-    payload: Dict[str, Any] = {
-        "metric": "elastic_goodput_vs_oracle",
-        "quick": True,
-        "seed": seed,
-        "global_batch": 24,
-        "workdir": workdir,
-    }
-    payload["elastic"] = run_trace_cell(
+    cell = run_trace_cell(
         os.path.join(workdir, "elastic"),
         start_groups=3,
         trace=trace,
@@ -804,55 +594,10 @@ def run_quick(workdir: Optional[str] = None, seed: int = 7) -> Dict[str, Any]:
         tail_s=3.0,
         min_groups=2,
     )
-    payload["oracle"] = run_trace_cell(
-        os.path.join(workdir, "oracle"),
-        start_groups=3,
-        trace=[],
-        global_batch=24,
-        per_sample_s=0.01,
-        use_averager=False,
-        tail_s=10.0,
-        min_groups=2,
-        section="fixed_oracle",
-    )
-    _score(payload)
-    # Quick mode stays on the flat ring; the crossover is the full trace's
-    # (and the churn soak's) job.
-    payload["crossover_exercised"] = None
-    return payload
-
-
-def main() -> None:
-    parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--worker", type=str, default=None)
-    parser.add_argument("--quick", action="store_true")
-    parser.add_argument("--workdir", type=str, default=None)
-    parser.add_argument("--seed", type=int, default=None)
-    args = parser.parse_args()
-    if args.worker:
-        _worker_main(json.loads(args.worker))
-        return
-    if args.quick:
-        payload = run_quick(args.workdir, **(
-            {"seed": args.seed} if args.seed is not None else {}
-        ))
-    else:
-        payload = run_full(args.workdir, **(
-            {"seed": args.seed} if args.seed is not None else {}
-        ))
-        out = os.path.join(REPO, "ELASTIC_BENCH.json")
-        with open(out, "w", encoding="utf-8") as f:
-            json.dump(payload, f, indent=1)
-            f.write("\n")
-    print(json.dumps({
-        "metric": payload["metric"],
-        "ok": payload["ok"],
-        "goodput_ratio_vs_oracle": payload["goodput_ratio_vs_oracle"],
-        "max_transition_dead_s": payload["max_transition_dead_s"],
-        "survivor_failed_commits": payload["survivor_failed_commits"],
-        "constant_global_batch": payload["constant_global_batch"],
-    }))
+    return {"seed": seed, "global_batch": 24, "workdir": workdir,
+            "elastic": cell, "ok": cell["ok"]}
 
 
 if __name__ == "__main__":
-    main()
+    # Worker entry only: the cell above starts this file as a script.
+    _worker_main(json.loads(sys.argv[2]))

@@ -1,7 +1,6 @@
-"""HA lighthouse failover benchmark: SIGKILL the leader mid-run, measure
-the takeover.
-
-The scenario (``bench.py --scenario lighthouse-failover`` -> HA_BENCH.json):
+"""The HA lighthouse failover cell, driven by
+tests/test_integration_smokes.py::test_ha_quick_smoke: SIGKILL the leader
+mid-run and count what the takeover cost.
 
 - N lighthouse replica processes (``python -m torchft_tpu.lighthouse_cli
   --lease-file ...``) share a lease file; one wins the election and serves,
@@ -9,29 +8,26 @@ The scenario (``bench.py --scenario lighthouse-failover`` -> HA_BENCH.json):
 - G replica-group worker processes run the REAL Manager control loop
   (quorum -> step -> two-phase commit vote) against the full
   comma-separated ``TPUFT_LIGHTHOUSE`` address list.  Workers are
-  JAX-free: the scenario measures the CONTROL plane, so each "step" is a
-  short sleep — hundreds of commits per window instead of a handful;
+  JAX-free: the cell exercises the CONTROL plane, so each "step" is a
+  short sleep -- hundreds of commits per window instead of a handful;
 - mid-window the driver SIGKILLs the current leader (found via the lease
-  file) and records: takeover latency (lease-file epoch bump + the
+  file) and records: the lease-file epoch bump and the
   ``lighthouse_failover`` event the winning standby writes into the obs
-  stream), per-group commit-resume latency, failed commits on the healthy
-  groups (must be ZERO — the managers' failover clients retry inside the
-  quorum deadline instead of failing the step), and state continuity on
-  the new leader (/metrics still shows every replica's step AND the
-  straggler-sentinel step-time gauges that only exist if the health state
-  was replicated, at an epoch exactly one higher).
+  stream, commits of every group after the kill, failed commits on the
+  healthy groups (must be ZERO -- the managers' failover clients retry
+  inside the quorum deadline instead of failing the step), and state
+  continuity on the new leader (/metrics still shows every replica's step
+  AND the straggler-sentinel step-time gauges that only exist if the
+  health state was replicated, at an epoch exactly one higher).
 
-Quick mode (``run_quick()``, wired into tier-1 as
-``tests/test_bench_contract.py::test_ha_quick_smoke``): 2 lighthouses,
-2 groups, one SIGKILL, ~15 s window.
+The workers are this file run as a script (``--worker``, see the end):
+nothing a person would run.
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
-import signal
 import socket
 import subprocess
 import sys
@@ -39,7 +35,7 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
-REPO = os.path.dirname(os.path.abspath(__file__))
+from harness import REPO, script_env
 
 
 def _free_port() -> int:
@@ -210,10 +206,9 @@ def run_failover(
     groups: int = 2,
     lease_ms: int = 1500,
     window_s: float = 30.0,
-    quick: bool = False,
 ) -> Dict:
-    """One failover trial.  Returns the HA_BENCH payload (see module
-    docstring for the criteria each field backs)."""
+    """One failover trial (see the module docstring for what each field
+    backs)."""
     from torchft_tpu.ha.lease import FileLease
     from torchft_tpu.metrics import MetricsLogger
     from torchft_tpu.obs import report as obs_report
@@ -228,10 +223,8 @@ def run_failover(
     http_ports = [_free_port() for _ in range(lighthouses)]
     procs: List[subprocess.Popen] = []
     workers: List[subprocess.Popen] = []
-    lease_s = lease_ms / 1000.0
+    lease_s = lease_ms / 1000.0  # sizes the takeover wait's timeout
     result: Dict = {
-        "metric": "lighthouse_failover",
-        "quick": quick,
         "lighthouses": lighthouses,
         "groups": groups,
         "lease_ms": lease_ms,
@@ -264,8 +257,7 @@ def run_failover(
         # Workers against the FULL address list (leader not first, so the
         # normal path already exercises rotation/redirect).
         addr_list = ",".join(f"127.0.0.1:{p}" for p in ports)
-        worker_env = dict(os.environ)
-        worker_env["TPUFT_METRICS_PATH"] = metrics_path
+        worker_env = script_env({"TPUFT_METRICS_PATH": metrics_path})
         end_ts = time.time() + window_s
         for g in range(groups):
             cfg = {
@@ -324,7 +316,7 @@ def run_failover(
         result["kill_ts"] = kill_ts
 
         # Takeover: lease epoch bump by a different owner.
-        takeover_ts = None
+        took_over = False
         t0 = time.time()
         while time.time() - t0 < max(15.0, 6 * lease_s):
             rec2 = lease_view.read()
@@ -333,15 +325,12 @@ def run_failover(
                 and rec2.epoch > epoch_before
                 and not rec2.expired(int(time.time() * 1000))
             ):
-                takeover_ts = time.time()
+                took_over = True
                 result["leader_epoch_after"] = rec2.epoch
                 new_leader_idx = ports.index(int(rec2.rpc_address.rsplit(":", 1)[1]))
                 break
             time.sleep(0.05)
-        result["takeover_s"] = (
-            round(takeover_ts - kill_ts, 3) if takeover_ts is not None else None
-        )
-        assert takeover_ts is not None, "no standby took over the lease"
+        assert took_over, "no standby took over the lease"
 
         # The lease record is written a settle-delay BEFORE the winner
         # confirms the race and flips its native role (and emits the
@@ -443,33 +432,18 @@ def run_failover(
         result["failed_commits_after_kill"] = failed_after
         result["failed_commits_healthy_groups"] = sum(failed_after.values())
 
-        resume_gaps: Dict[str, float] = {}
-        medians: Dict[str, float] = {}
-        for g in range(groups):
-            ts_list = sorted(commits.get(str(g), []))
-            pre_kill = [t for t in ts_list if t <= kill_ts]
-            post_kill = [t for t in ts_list if t > kill_ts]
-            iv = [b - a for a, b in zip(pre_kill, pre_kill[1:])]
-            med = sorted(iv)[len(iv) // 2] if iv else 0.0
-            medians[str(g)] = round(med, 4)
-            if post_kill:
-                resume_gaps[str(g)] = round(min(post_kill) - kill_ts, 3)
         result["per_group_commits"] = {
             g: len(ts) for g, ts in sorted(commits.items())
         }
-        result["median_step_s"] = medians
-        result["resume_gap_s"] = resume_gaps
-        # The headline criterion: quorum formation (evidenced by the next
-        # committed step, which REQUIRES a formed quorum) resumed within
-        # one lease period of the kill — plus one median step (the step
-        # itself is not failover cost) and a small scheduling slack for
-        # this shared 2-core host.
-        max_gap = max(resume_gaps.values()) if resume_gaps else None
-        slack = 0.5 + 2 * max(medians.values() or [0.0])
-        result["max_resume_gap_s"] = max_gap
-        result["resume_budget_s"] = round(lease_s + slack, 3)
-        result["resumed_within_lease"] = (
-            max_gap is not None and max_gap <= lease_s + slack
+        # The headline criterion: quorum formation resumed under the new
+        # leader -- evidenced by committed steps after the kill (a commit
+        # REQUIRES a formed quorum) in every group.
+        result["commits_after_kill"] = {
+            str(g): sum(1 for t in commits.get(str(g), []) if t > kill_ts)
+            for g in range(groups)
+        }
+        result["resumed_after_kill"] = all(
+            n > 0 for n in result["commits_after_kill"].values()
         )
 
         # The failover must be visible in the obs stream (the standby's
@@ -504,7 +478,7 @@ def run_failover(
             and len(result["replicas_tracked_after"]) == groups
         )
         result["ok"] = bool(
-            result["resumed_within_lease"]
+            result["resumed_after_kill"]
             and result["failed_commits_healthy_groups"] == 0
             and result["metrics_continuity_ok"]
             and result["failover_event_seen"]
@@ -528,46 +502,14 @@ def run_failover(
 
 
 def run_quick() -> Dict:
-    """Tier-1 smoke shape: 2 lighthouses, 2 groups, one leader SIGKILL,
+    """The smoke's shape: 2 lighthouses, 2 groups, one leader SIGKILL,
     short window.  Workdir is kept under a tempdir for post-mortem."""
     workdir = tempfile.mkdtemp(prefix="tpuft_ha_quick_")
     return run_failover(
-        workdir, lighthouses=2, groups=2, lease_ms=1200, window_s=18.0, quick=True
+        workdir, lighthouses=2, groups=2, lease_ms=1200, window_s=18.0
     )
 
 
-def main() -> None:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--worker", default=None, help=argparse.SUPPRESS)
-    parser.add_argument("--quick", action="store_true")
-    parser.add_argument("--lighthouses", type=int, default=3)
-    parser.add_argument("--groups", type=int, default=2)
-    parser.add_argument("--lease-ms", type=int, default=1500)
-    parser.add_argument("--window-s", type=float, default=30.0)
-    parser.add_argument("--out", default=os.path.join(REPO, "HA_BENCH.json"))
-    args = parser.parse_args()
-    if args.worker is not None:
-        _worker_main(json.loads(args.worker))
-        return
-    if args.quick:
-        payload = run_quick()
-    else:
-        workdir = os.environ.get("TPUFT_BENCH_WORKDIR") or tempfile.mkdtemp(
-            prefix="tpuft_bench_ha_"
-        )
-        payload = run_failover(
-            workdir,
-            lighthouses=args.lighthouses,
-            groups=args.groups,
-            lease_ms=args.lease_ms,
-            window_s=args.window_s,
-        )
-        payload["workdir"] = workdir
-        with open(args.out, "w", encoding="utf-8") as f:
-            json.dump(payload, f, indent=1)
-            f.write("\n")
-    print(json.dumps(payload))
-
-
 if __name__ == "__main__":
-    main()
+    # Worker entry only: the cell above starts this file as a script.
+    _worker_main(json.loads(sys.argv[2]))

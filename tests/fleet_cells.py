@@ -1,58 +1,31 @@
-"""O(dozens)-group scale harness: control-plane + data-plane sweeps vs N.
+"""Live control-plane cells with replica groups as JAX-free worker
+subprocesses, driven by tests/test_integration_smokes.py and
+tests/test_federation.py.  What a cell returns is counts and booleans
+(commits per group, quorum transitions reconstructed from the flight
+recorder, observations in the lighthouse's histograms, leaked fds).
 
-ROADMAP item 2's missing half — everything measured before this ran at 2-3
-replica groups on loopback.  Two sweeps, one artifact (``SCALE_BENCH.json``,
-written by ``bench.py --scenario scale`` / ``python bench_scale.py``):
+  control    -- ONE in-process native lighthouse + N worker subprocesses
+                running the REAL Manager control loop (quorum -> sleep-step
+                -> two-phase commit vote).  A cell can inject a CORRELATED
+                PREEMPTION WAVE: several groups SIGKILLed inside one tight
+                window (spot reclaim); the surviving groups must reform a
+                quorum and keep committing, the driver must leak zero fds,
+                and the lighthouse's flight-recorder dump must reconstruct
+                the wave's quorum transitions.
+  federated  -- the two-tier control plane (docs/wire.md "Federation"):
+                child-lighthouse SUBPROCESSES own their region's heartbeats
+                and push digests to an in-driver root, which forms the
+                global quorum from digests alone (the root sees ZERO
+                heartbeats).
+  parity     -- the flat ring and ring2d on the same inputs at 4 in-process
+                ranks.
 
-  control plane  — ONE in-process native lighthouse + N JAX-free worker
-                   subprocesses running the REAL Manager control loop
-                   (quorum -> sleep-step -> two-phase commit vote), N swept
-                   over {4, 8, 16, 32}.  Per cell: per-group commit counts,
-                   quorum-formation latency / heartbeat fan-in cost /
-                   per-method RPC latency / /metrics scrape self-cost, all
-                   read from the PR 7 native histograms on /metrics — the
-                   measurement substrate this sweep exists to exercise.
-                   The largest cell injects a CORRELATED PREEMPTION WAVE:
-                   half the groups SIGKILLed inside one tight window (spot
-                   reclaim).  The cell asserts the surviving half reforms a
-                   quorum and keeps committing, the run leaks zero fds in
-                   the driver, and the lighthouse's flight-recorder dump
-                   reconstructs the wave's quorum transitions (members
-                   N -> N/2 with the victims in ``left``).
-
-  data plane     — flat ring vs hierarchical ring2d allreduce
-                   (TPUFT_RING_TOPOLOGY) at N subprocess ranks on a shaped
-                   link, N swept over the same set.  The flat ring pays
-                   2(N-1) sequential hops of half-RTT each; the 2D
-                   ring-of-rings pays ~4*sqrt(N) — on a 60 ms-RTT link the
-                   crossover shows up well before N=16.  Records reuse
-                   bench_allreduce.bench_lanes (payload/wall GB/s, per-tier
-                   byte counters), reported as paired best-of-N trials with
-                   speedup = ring_wall / ring2d_wall.
-
-  federated      — the two-tier control plane (docs/wire.md "Federation")
-                   at fixed region size and growing N: child-lighthouse
-                   SUBPROCESSES own their region's heartbeats and push
-                   digests to an in-driver root, which forms the global
-                   quorum from digests alone.  Per cell: per-instance
-                   heartbeat fan-in (children bounded by region size, root
-                   ZERO), scrape cost, digest-consistency checks.  The
-                   largest cell SIGKILLs an entire region — child first,
-                   then its workers (correlated cross-region preemption) —
-                   and requires the survivors' global quorum to reform with
-                   zero failed commits and the root's incident bundle
-                   verdict to name the dead REGION.
-
-Quick mode (``run_quick()``, wired into tier-1 as
-``tests/test_bench_contract.py::test_scale_quick_smoke``): a 4-group cell
-with a 2-victim wave under a pinned ring2d topology (the post-wave 2-group
-world crosses the auto crossover back to the flat ring), an in-process
-topology-parity check, and the full SCALE_BENCH schema.
+The workers and the child lighthouses are this file run as a script
+(``--worker`` / ``--child``, see the end): nothing a person would run.
 """
 
 from __future__ import annotations
 
-import argparse
 import gc
 import glob
 import json
@@ -64,14 +37,7 @@ import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
-REPO = os.path.dirname(os.path.abspath(__file__))
-
-
-def _fd_count() -> int:
-    try:
-        return len(os.listdir("/proc/self/fd"))
-    except OSError:  # non-procfs platform: fd accounting unavailable
-        return -1
+from harness import REPO, fd_count, script_env
 
 
 # ---------------------------------------------------------------------------
@@ -87,7 +53,7 @@ def _worker_main(cfg: Dict) -> None:
     group count) the hierarchical topology's tier sockets.  Counted window
     ends when the driver's stop file appears; a bounded linger keeps
     feeding the quorum machine so siblings' last counted quorums can form
-    (see bench_ha.py for the lesson this encodes)."""
+    (see failover_cells.py for the lesson this encodes)."""
     from datetime import timedelta
 
     import numpy as np
@@ -126,7 +92,7 @@ def _worker_main(cfg: Dict) -> None:
         # spreads worker launch over tens of seconds; without the barrier
         # the earliest min_replicas workers form a quorum alone and every
         # late joiner enters through a heal-against-a-moving-cluster (the
-        # bench_ha lesson).  The driver writes "go" once every group is
+        # failover_cells lesson).  The driver writes "go" once every group is
         # constructed, so the FIRST quorum contains all N.
         with open(os.path.join(workdir, f"ready_{cfg['group']}"), "w"):
             pass
@@ -285,7 +251,7 @@ def run_control_cell(
     os.makedirs(workdir, exist_ok=True)
     metrics_path = os.path.join(workdir, "metrics.jsonl")
     gc.collect()
-    fd_before = _fd_count()
+    fd_before = fd_count()
     prior_flight = os.environ.get("TPUFT_FLIGHT_DIR")
     os.environ["TPUFT_FLIGHT_DIR"] = workdir
     survivors = list(range(groups - wave))
@@ -323,10 +289,7 @@ def run_control_cell(
             heartbeat_timeout_ms=heartbeat_timeout_ms,
         )
         http = lighthouse.http_address()
-        env = dict(os.environ)
-        env["TPUFT_METRICS_PATH"] = metrics_path
-        if worker_env:
-            env.update(worker_env)
+        env = script_env({"TPUFT_METRICS_PATH": metrics_path, **(worker_env or {})})
         # Hard ceiling well past the window: worker startup at N=32 on a
         # small host serializes ~0.5 s of interpreter+numpy import each,
         # and a wave cell's counted phase additionally spans the driver's
@@ -572,12 +535,12 @@ def run_control_cell(
     # fd hygiene: everything the cell opened (lighthouse, scrape sockets,
     # worker pipes, log handles) must be closed.  Settle loop because
     # socket close under load is not instantaneous.
-    fd_after = _fd_count()
+    fd_after = fd_count()
     settle = time.time() + 5.0
     while fd_after > fd_before and time.time() < settle:
         gc.collect()
         time.sleep(0.2)
-        fd_after = _fd_count()
+        fd_after = fd_count()
     result["fd_before"] = fd_before
     result["fd_after"] = fd_after
     result["fd_leaked"] = max(0, fd_after - fd_before) if fd_before >= 0 else None
@@ -612,7 +575,6 @@ def run_federated_cell(
     regions: int,
     window_s: float = 8.0,
     step_s: float = 0.1,
-    region_wave: bool = False,
     kill: int = 0,
     push_ms: int = 100,
     heartbeat_timeout_ms: int = 3000,
@@ -624,16 +586,12 @@ def run_federated_cell(
     loop against their region's child — the managers never learn the
     root exists.  Measures per-instance heartbeat fan-in (children see
     only their region; the root sees ZERO heartbeats) and scrape cost vs
-    N.  ``region_wave`` SIGKILLs the last region whole — child first,
-    then its workers, the correlated cross-region preemption shape — and
-    requires: survivors reform the global quorum with ZERO failed
-    commits, the root's incident bundle verdict names the dead REGION,
-    and the root/child digest views stay consistent.  ``kill`` instead
-    SIGKILLs that many individual workers (the quick smoke's 1-victim
-    shape).  Group g lives in region g // (groups // regions)."""
+    N.  ``kill`` SIGKILLs that many individual workers (the highest
+    numbered) mid-window and requires: survivors reform the global quorum
+    with ZERO failed commits, and the root/child digest views stay
+    consistent.  Group g lives in region g // (groups // regions)."""
     from torchft_tpu._native import LighthouseServer
     from torchft_tpu.obs import flight as obs_flight
-    from torchft_tpu.obs import incident as obs_incident
     from torchft_tpu.obs import report as obs_report
 
     assert groups % regions == 0, "groups must divide evenly across regions"
@@ -653,12 +611,7 @@ def run_federated_cell(
     per_region = groups // regions
     region_names = [f"r{i}" for i in range(regions)]
     region_of = lambda g: region_names[g // per_region]  # noqa: E731
-    if region_wave:
-        victims = list(range(groups - per_region, groups))
-        dead_region = region_names[-1]
-    else:
-        victims = list(range(groups - kill, groups)) if kill else []
-        dead_region = None
+    victims = list(range(groups - kill, groups)) if kill else []
     survivors = [g for g in range(groups) if g not in victims]
     surviving_regions = sorted({region_of(g) for g in survivors})
 
@@ -667,7 +620,7 @@ def run_federated_cell(
     os.makedirs(childdir, exist_ok=True)
     metrics_path = os.path.join(workdir, "metrics.jsonl")
     gc.collect()
-    fd_before = _fd_count()
+    fd_before = fd_count()
     prior_flight = os.environ.get("TPUFT_FLIGHT_DIR")
     os.environ["TPUFT_FLIGHT_DIR"] = workdir
     result: Dict[str, Any] = {
@@ -677,8 +630,7 @@ def run_federated_cell(
         "per_region": per_region,
         "window_s": window_s,
         "step_s": step_s,
-        "region_wave": bool(region_wave),
-        "kill": len(victims) if not region_wave else per_region,
+        "kill": len(victims),
         "min_replicas": max(1, len(survivors)),
         "ok": False,
     }
@@ -701,8 +653,7 @@ def run_federated_cell(
         end_cap = time.time() + window_s + 90.0 + 1.5 * groups + (
             240.0 if victims else 0.0
         )
-        child_env = dict(os.environ)
-        child_env["TPUFT_FLIGHT_DIR"] = childdir  # keep root's dump unambiguous
+        child_env = script_env({"TPUFT_FLIGHT_DIR": childdir})  # keep root's dump unambiguous
         for name in region_names:
             ccfg = {
                 "region": name,
@@ -738,8 +689,7 @@ def run_federated_cell(
                 f"only {len(child_info)}/{regions} child lighthouses came up"
             )
 
-        env = dict(os.environ)
-        env["TPUFT_METRICS_PATH"] = metrics_path
+        env = script_env({"TPUFT_METRICS_PATH": metrics_path})
         log_paths = []
         for g in range(groups):
             cfg = {
@@ -855,20 +805,9 @@ def run_federated_cell(
         result["digest_consistency_pre"] = digest_consistent()
 
         wave_ts = None
-        watcher = obs_incident.IncidentWatcher(root_http)
-        watcher.poll()  # baseline: ignore any pre-fault triggers
-        bundle_dir = None
         if victims:
-            # THE FAULT.  Region wave: the child dies FIRST (the region's
-            # control plane goes dark with its capacity block — the root
-            # must infer the loss from digest silence, no goodbye), then
-            # the region's workers.  kill-one: just the worker.
+            # THE FAULT: SIGKILL the victims' worker processes.
             wave_ts = time.time()
-            if region_wave and dead_region is not None:
-                try:
-                    children[dead_region].send_signal(signal.SIGKILL)
-                except OSError:
-                    pass
             for g in victims:
                 try:
                     workers[g].send_signal(signal.SIGKILL)
@@ -876,32 +815,7 @@ def run_federated_cell(
                     pass
             for g in victims:
                 workers[g].wait()
-            if region_wave and dead_region is not None:
-                children[dead_region].wait()
             result["wave_ts"] = wave_ts
-            result["wave_kill_span_s"] = round(time.time() - wave_ts, 3)
-
-            if region_wave:
-                # The root must declare the region dead (digest silence >
-                # heartbeat timeout) and record the region_stale trigger;
-                # capture the bundle LIVE while the survivors reform.
-                stale_deadline = time.time() + 60.0
-                region_incident = None
-                while time.time() < stale_deadline and region_incident is None:
-                    for rec in watcher.poll():
-                        if rec.get("reason") == "region_stale":
-                            region_incident = rec
-                            break
-                    time.sleep(0.25)
-                result["region_stale_incident"] = region_incident
-                if region_incident is not None:
-                    bundle_dir = obs_incident.capture_bundle(
-                        workdir, root_http, region_incident, [metrics_path]
-                    )
-                rollup = root_rollup()
-                result["dead_region_stale_at_root"] = bool(
-                    (rollup.get(dead_region) or {}).get("stale")
-                )
 
             # Reformation: every survivor commits >= 2 AFTER the fault.
             reform_deadline = time.time() + 90.0 + 2 * 30.0
@@ -979,9 +893,7 @@ def run_federated_cell(
                 w.wait()
         with open(os.path.join(workdir, "stop_children"), "w"):
             pass
-        for name, proc in children.items():
-            if region_wave and name == dead_region:
-                continue
+        for proc in children.values():
             try:
                 proc.wait(timeout=30.0)
             except subprocess.TimeoutExpired:
@@ -1009,18 +921,6 @@ def run_federated_cell(
                 str(g): len([t for t in cs.get(str(g), []) if t > wave_ts])
                 for g in survivors
             }
-
-        if bundle_dir is not None:
-            manifest = obs_incident.finalize_bundle(
-                bundle_dir, workdir, events=obs_report.read_events([metrics_path])
-            )
-            v = manifest.get("verdict", {})
-            result["incident_bundle"] = bundle_dir
-            result["verdict"] = v
-            result["verdict_names_dead_region"] = bool(
-                v.get("kind") == "region_loss"
-                and v.get("region") == dead_region
-            )
     finally:
         for w in workers:
             if w.poll() is None:
@@ -1074,12 +974,12 @@ def run_federated_cell(
                 shrunk["ts_ms"] / 1000.0 - wave_ts, 3
             )
 
-    fd_after = _fd_count()
+    fd_after = fd_count()
     settle = time.time() + 5.0
     while fd_after > fd_before and time.time() < settle:
         gc.collect()
         time.sleep(0.2)
-        fd_after = _fd_count()
+        fd_after = fd_count()
     result["fd_before"] = fd_before
     result["fd_after"] = fd_after
     result["fd_leaked"] = (
@@ -1097,12 +997,6 @@ def run_federated_cell(
             and result.get("survivor_failed_commits") == 0
             and result.get("digest_consistency_post", {}).get("ok")
         )
-        if region_wave:
-            fault_ok = fault_ok and bool(
-                result.get("dead_region_stale_at_root")
-                and result.get("verdict_names_dead_region")
-                and result.get("wave_reconstructed")
-            )
     result["ok"] = bool(
         result.get("warmed_groups") == groups
         and all_committed
@@ -1115,76 +1009,8 @@ def run_federated_cell(
     return result
 
 
-def run_federated_sweep(
-    cells: Optional[List[Dict[str, Any]]] = None,
-    window_s: float = 8.0,
-) -> Dict[str, Any]:
-    """The federated half of the scale story: cells with a FIXED region
-    size and growing N, so per-instance fan-in / scrape cost stay flat
-    while the flat cells' grow with N; the largest cell takes the
-    correlated cross-region preemption wave."""
-    cells = cells or [
-        {"groups": 32, "regions": 4, "step_s": 0.25},
-        {"groups": 64, "regions": 8, "step_s": 0.5, "region_wave": True,
-         "heartbeat_timeout_ms": 5000},
-    ]
-    base = os.environ.get("TPUFT_BENCH_WORKDIR") or tempfile.mkdtemp(
-        prefix="tpuft_fed_"
-    )
-    out_cells: List[Dict[str, Any]] = []
-    for spec in cells:
-        spec = dict(spec)
-        n, r = spec.pop("groups"), spec.pop("regions")
-        cell = run_federated_cell(
-            os.path.join(base, f"fed_n{n}_r{r}"),
-            groups=n, regions=r, window_s=window_s, **spec,
-        )
-        out_cells.append(cell)
-        print(json.dumps(cell), flush=True)
-    wave_cell = next(
-        (c for c in out_cells if c.get("region_wave")), None
-    )
-    summary = {
-        "cells": [
-            {
-                "groups": c["groups"],
-                "regions": c["regions"],
-                "per_region": c["per_region"],
-                "max_child_fanin_count": c.get("max_child_fanin_count"),
-                "max_child_fanin_mean_ms": max(
-                    (v["heartbeat_fanin"]["mean_ms"] or 0.0)
-                    for v in c.get("per_instance", {})
-                    .get("children", {"x": {"heartbeat_fanin": {"mean_ms": 0}}})
-                    .values()
-                ),
-                "root_heartbeat_rpcs": c.get("root_heartbeat_rpcs"),
-                "root_scrape_bytes": c.get("per_instance", {})
-                .get("root", {}).get("scrape_bytes"),
-                "ok": c["ok"],
-            }
-            for c in out_cells
-        ],
-        "region_wave": None if wave_cell is None else {
-            "groups": wave_cell["groups"],
-            "regions": wave_cell["regions"],
-            "dead_region_groups": wave_cell["per_region"],
-            "reformed": wave_cell.get("quorum_reformed"),
-            "survivor_failed_commits": wave_cell.get(
-                "survivor_failed_commits"
-            ),
-            "verdict_names_dead_region": wave_cell.get(
-                "verdict_names_dead_region"
-            ),
-            "verdict": wave_cell.get("verdict"),
-            "wave_reform_s": wave_cell.get("wave_reform_s"),
-        },
-        "cells_ok": all(c["ok"] for c in out_cells),
-    }
-    return {"workdir": base, "cells": out_cells, "summary": summary}
-
-
 def run_federated_quick() -> Dict[str, Any]:
-    """Tier-1 federation smoke (tests/test_federation.py::
+    """The federation smoke (tests/test_federation.py::
     test_federation_quick_smoke): 2 regions x 2 groups through real
     child subprocesses, one worker SIGKILLed mid-window; gates on digest
     consistency across the kill, the survivors' reformed global quorum,
@@ -1194,62 +1020,7 @@ def run_federated_quick() -> Dict[str, Any]:
         workdir, groups=4, regions=2, window_s=4.0, step_s=0.1, kill=1,
         push_ms=100,
     )
-    return {
-        "metric": "federation",
-        "quick": True,
-        "workdir": workdir,
-        "cells": [cell],
-        "ok": cell["ok"],
-    }
-
-
-# ---------------------------------------------------------------------------
-# Data-plane sweep (flat ring vs ring2d at N ranks)
-# ---------------------------------------------------------------------------
-
-
-def run_dataplane_sweep(
-    ns: List[int],
-    mbps: float = 200.0,
-    rtt_ms: float = 60.0,
-    payload_mb: float = 2.0,
-    lanes: int = 2,
-    trials: int = 2,
-    timeout: float = 600.0,
-) -> Dict[str, Any]:
-    """Paired flat-vs-ring2d allreduce trials at each N (subprocess ranks,
-    shaped link).  The pinned link models a cross-site hop: at 60 ms RTT
-    the flat ring's 2(N-1) serialized half-RTT hops dominate wall time, so
-    the hierarchical speedup grows with N."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench_allreduce
-    finally:
-        sys.path.pop(0)
-    records: List[Dict[str, Any]] = []
-    speedups: Dict[str, float] = {}
-    for n in ns:
-        walls: Dict[str, float] = {}
-        for topo in ("ring", "ring2d"):
-            rec = bench_allreduce.bench_lanes(
-                payload_mb, lanes, mbps, rtt_ms, n_buckets=2,
-                timeout=timeout, procs=True, trials=trials,
-                world=n, topology=topo,
-            )
-            rec["section"] = "scale_dataplane"
-            walls[rec["topology"]] = rec["wall_s"]
-            records.append(rec)
-            print(json.dumps(rec), flush=True)
-        if "ring" in walls and "ring2d" in walls and walls["ring2d"] > 0:
-            speedups[str(n)] = round(walls["ring"] / walls["ring2d"], 3)
-    return {
-        "records": records,
-        "link": {"mbps": mbps, "rtt_ms": rtt_ms},
-        "payload_mb": payload_mb,
-        "lanes": lanes,
-        "trials": trials,
-        "ring2d_speedup_by_n": speedups,
-    }
+    return {"workdir": workdir, "cells": [cell], "ok": cell["ok"]}
 
 
 # ---------------------------------------------------------------------------
@@ -1329,13 +1100,13 @@ def topology_parity_check(world: int = 4) -> Dict[str, Any]:
 
 
 def run_quick() -> Dict[str, Any]:
-    """Tier-1 smoke shape: topology parity at 4 in-process ranks, then a
+    """The smoke's shape: topology parity at 4 in-process ranks, then a
     4-group control cell with a 2-victim preemption wave under a PINNED
     ring2d topology — the post-wave 2-group world crosses the auto
     crossover back to the flat ring, so the smoke exercises the
     reconfigure-across-topologies path end to end."""
     workdir = tempfile.mkdtemp(prefix="tpuft_scale_quick_")
-    fd_before = _fd_count()
+    fd_before = fd_count()
     parity = topology_parity_check(world=4)
     cell = run_control_cell(
         workdir,
@@ -1346,13 +1117,10 @@ def run_quick() -> Dict[str, Any]:
         worker_env={"TPUFT_RING_TOPOLOGY": "ring2d"},
     )
     gc.collect()
-    fd_after = _fd_count()
+    fd_after = fd_count()
     return {
-        "metric": "scale",
-        "quick": True,
         "parity": parity,
         "cells": [cell],
-        "dataplane": [],
         "workdir": workdir,
         "fd_leaked_total": (
             max(0, fd_after - fd_before) if fd_before >= 0 else None
@@ -1361,138 +1129,7 @@ def run_quick() -> Dict[str, Any]:
     }
 
 
-def run_full(
-    ns: Optional[List[int]] = None,
-    window_s: float = 10.0,
-    mbps: float = 200.0,
-    rtt_ms: float = 60.0,
-    trials: int = 2,
-    wave_n: Optional[int] = None,
-) -> Dict[str, Any]:
-    """The full sweep: control cells at each N (the largest with a half-N
-    preemption wave), plus the flat-vs-ring2d data-plane sweep."""
-    ns = ns or [4, 8, 16, 32]
-    wave_n = wave_n if wave_n is not None else max(ns)
-    base = os.environ.get("TPUFT_BENCH_WORKDIR") or tempfile.mkdtemp(
-        prefix="tpuft_scale_"
-    )
-    cells: List[Dict[str, Any]] = []
-    for n in ns:
-        wave = n // 2 if n == wave_n else 0
-        # Bigger cells slow the step cadence and widen the heartbeat window:
-        # N workers on a 1-2 core host timeshare, and the cell measures
-        # control-plane cost, not the host's scheduler.
-        step_s = 0.1 if n <= 8 else 0.25
-        cell = run_control_cell(
-            os.path.join(base, f"n{n}"),
-            groups=n,
-            window_s=window_s,
-            step_s=step_s,
-            wave=wave,
-            heartbeat_timeout_ms=3000 if n <= 8 else 5000,
-        )
-        cells.append(cell)
-        print(json.dumps(cell), flush=True)
-    dataplane = run_dataplane_sweep(ns, mbps=mbps, rtt_ms=rtt_ms, trials=trials)
-    federation = run_federated_sweep()
-    summary = {
-        "groups_swept": ns,
-        "federation": federation["summary"],
-        "quorum_formation_ms_by_n": {
-            str(c["groups"]): c.get("quorum_formation", {}).get("mean_ms")
-            for c in cells
-        },
-        "heartbeat_fanin_ms_by_n": {
-            str(c["groups"]): c.get("heartbeat_fanin", {}).get("mean_ms")
-            for c in cells
-        },
-        "scrape_ms_by_n": {
-            str(c["groups"]): c.get("scrape", {}).get("mean_ms") for c in cells
-        },
-        "scrape_bytes_by_n": {
-            str(c["groups"]): c.get("scrape_bytes") for c in cells
-        },
-        "ring2d_speedup_by_n": dataplane["ring2d_speedup_by_n"],
-        "wave": {
-            "groups": wave_n,
-            "killed": wave_n // 2,
-            "reform_s": next(
-                (c.get("wave_reform_s") for c in cells if c["groups"] == wave_n),
-                None,
-            ),
-            "reconstructed": next(
-                (c.get("wave_reconstructed") for c in cells
-                 if c["groups"] == wave_n),
-                None,
-            ),
-            "fd_leaked": next(
-                (c.get("fd_leaked") for c in cells if c["groups"] == wave_n),
-                None,
-            ),
-        },
-        "cells_ok": all(c["ok"] for c in cells),
-    }
-    return {
-        "metric": "scale",
-        "quick": False,
-        "workdir": base,
-        "cells": cells,
-        "dataplane": dataplane,
-        "federation": federation,
-        "summary": summary,
-    }
-
-
-def main() -> None:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--worker", default=None, help=argparse.SUPPRESS)
-    parser.add_argument("--child", default=None, help=argparse.SUPPRESS)
-    parser.add_argument("--quick", action="store_true")
-    parser.add_argument(
-        "--federated", action="store_true",
-        help="run only the federated sweep and merge it into an existing "
-        "SCALE_BENCH.json (the flat cells are kept as-is)",
-    )
-    parser.add_argument("--ns", type=int, nargs="*", default=[4, 8, 16, 32])
-    parser.add_argument("--window-s", type=float, default=10.0)
-    parser.add_argument("--mbps", type=float, default=200.0)
-    parser.add_argument("--rtt-ms", type=float, default=60.0)
-    parser.add_argument("--trials", type=int, default=2)
-    parser.add_argument("--out", default=os.path.join(REPO, "SCALE_BENCH.json"))
-    args = parser.parse_args()
-    if args.worker is not None:
-        _worker_main(json.loads(args.worker))
-        return
-    if args.child is not None:
-        _child_main(json.loads(args.child))
-        return
-    if args.federated:
-        federation = run_federated_sweep()
-        try:
-            with open(args.out, "r", encoding="utf-8") as f:
-                payload = json.load(f)
-        except (OSError, ValueError):
-            payload = {"metric": "scale", "quick": False, "cells": [],
-                       "dataplane": {}, "summary": {}}
-        payload["federation"] = federation
-        payload.setdefault("summary", {})["federation"] = federation["summary"]
-        with open(args.out, "w", encoding="utf-8") as f:
-            json.dump(payload, f, indent=1)
-            f.write("\n")
-        print(json.dumps(federation["summary"]), flush=True)
-        return
-    if args.quick:
-        payload = run_quick()
-    else:
-        payload = run_full(
-            ns=args.ns, window_s=args.window_s, mbps=args.mbps,
-            rtt_ms=args.rtt_ms, trials=args.trials,
-        )
-        with open(args.out, "w", encoding="utf-8") as f:
-            json.dump(payload, f, indent=1)
-            f.write("\n")
-    print(json.dumps(payload.get("summary", payload)), flush=True)
-
-
 if __name__ == "__main__":
-    main()
+    # Worker / child entry only: the cells above start this file as a script.
+    role, cfg = sys.argv[1], json.loads(sys.argv[2])
+    {"--worker": _worker_main, "--child": _child_main}[role](cfg)

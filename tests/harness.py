@@ -11,12 +11,35 @@ servers, real TCP collective over localhost.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Set
 
 logger = logging.getLogger(__name__)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def script_env(extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
+    """This process's environment for a child that runs a file of this
+    directory as a script (the cells' workers): its own ``sys.path[0]`` is
+    ``tests/``, so the repo rides in PYTHONPATH."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (REPO, env.get("PYTHONPATH")) if p
+    )
+    env.update(extra or {})
+    return env
+
+
+def fd_count() -> int:
+    """Open file descriptors of this process (-1 where /proc is absent)."""
+    try:
+        return len(os.listdir("/proc/self/fd"))
+    except OSError:
+        return -1
 
 
 class InjectedFailure(Exception):

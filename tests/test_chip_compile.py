@@ -46,9 +46,9 @@ def one_chip(topo):
 
 
 def _models():
-    import bench
+    import chip_smoke
 
-    return {"flagship": bench.flagship_config(), "1b": bench.large_config()}
+    return {"flagship": chip_smoke.flagship_config(), "1b": chip_smoke.large_config()}
 
 
 def _compile(fn, *shapes) -> str:
@@ -132,14 +132,13 @@ def test_flagship_gradient_program_compiles_with_kernels_for_v5e(
     it on — in the test, the program has no option for it."""
     import optax
 
-    import bench
     import chip_smoke
     from torchft_tpu.models import init_params, loss_fn
     from torchft_tpu.ops import _pallas_util
     from torchft_tpu.parallel import TrainStep, ft_init_mesh
 
     monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
-    cfg, batch, seq = bench.flagship_config()
+    cfg, batch, seq = chip_smoke.flagship_config()
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes

@@ -114,6 +114,24 @@ def test_a_phase_that_raises_fails_the_run(capsys, monkeypatch) -> None:
     assert json.loads(last) == {"ok": True, "device": device}
 
 
+def test_an_mfu_is_never_taken_against_a_guessed_peak() -> None:
+    """The smoke's MFU line divides by the benchmark's table
+    (`benchmark/peaks.json`), and a device kind the table does not hold is an
+    error; the operation count is the benchmark's too (the embedding table
+    is a gather and counts nothing)."""
+    import chip_smoke
+
+    assert chip_smoke.bf16_peak("TPU v5 lite") == 197e12
+    with pytest.raises(RuntimeError, match="no peaks recorded"):
+        chip_smoke.bf16_peak("TPU v99 imaginary")
+
+    flagship = chip_smoke.flagship()
+    cfg, tokens = flagship.cfg, flagship.batch_size * flagship.seq
+    matmul = cfg.n_layers * (4 * cfg.d_model**2 + 3 * cfg.d_model * cfg.d_ff) + cfg.d_model * cfg.vocab_size
+    attention = cfg.n_layers * 6 * cfg.d_model * (flagship.seq + 1)
+    assert chip_smoke.flops_per_step(flagship) == (6 * matmul + attention) * tokens
+
+
 @pytest.mark.parametrize("module", ["torchft_tpu", "torchft_tpu.launch", "chip_smoke"])
 def test_import_creates_no_jax_backend(module) -> None:
     """A chip belongs to one process: what a parent imports before it starts

@@ -554,10 +554,13 @@ def test_ec_heal_e2e_donors_unreachable() -> None:
     try:
         failure = FailureInjector().fail_at(0, 3)
 
+        # Read once, before the runners' threads start: a thread that read it
+        # after its sibling had patched would "restore" the patch for good.
+        orig_init = HTTPTransport.__init__
+
         def loop(runner, rank, **kw):
             # Arm the donor-path break for the victim group only: its
             # restarted incarnation must heal via shards.
-            orig_init = HTTPTransport.__init__
             if runner.replica_id == 0:
                 def marked_init(tself, *a, **k):
                     orig_init(tself, *a, **k)

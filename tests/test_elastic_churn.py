@@ -61,7 +61,9 @@ def _fd_count() -> int:
 
 
 def _shm_segments() -> set:
-    return set(glob.glob("/dev/shm/tpuft-*"))
+    # This process's segments only (`_create_shm_segment` names them
+    # tpuft-<pid>-...): other test workers on the machine make their own.
+    return set(glob.glob(f"/dev/shm/tpuft-{os.getpid()}-*"))
 
 
 def _settle_fds(target: int, timeout_s: float = 10.0) -> int:

@@ -9,16 +9,16 @@ contracts:
   three places (native/src, proto, docs/wire.md) with nothing but these
   greps tying them together; a rename in one place would silently strand
   the others, exactly the drift the ledger-taxonomy pins exist for.
-- **Live smoke** — `bench_scale.run_federated_quick()`: 2 regions x 2
+- **Live smoke** — `fleet_cells.run_federated_quick()`: 2 regions x 2
   groups through REAL child-lighthouse subprocesses with one worker
   SIGKILLed mid-window, gated on digest consistency across the kill, a
-  reformed global quorum, and zero failed survivor commits.  This is the
-  tier-1 shape of the SCALE_BENCH.json federated sweep cells.
+  reformed global quorum, and zero failed survivor commits.
 """
 
 import os
 import re
-import sys
+
+import fleet_cells
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -119,13 +119,7 @@ def test_federation_gauges_and_endpoints_pinned() -> None:
 
 
 def test_federation_quick_smoke() -> None:
-    sys.path.insert(0, REPO)
-    try:
-        import bench_scale
-    finally:
-        sys.path.remove(REPO)
-
-    out = bench_scale.run_federated_quick()
+    out = fleet_cells.run_federated_quick()
     cell = out["cells"][0]
     assert cell["digest_consistency_pre"]["ok"] is True, cell
     assert cell["digest_consistency_post"]["ok"] is True, cell

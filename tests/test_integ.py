@@ -296,8 +296,8 @@ def test_ddp_recovery(lighthouse, use_async_quorum, caplog) -> None:
     assert injector.count == 1
     _assert_params_equal(results)
     assert all(r[0]["step"] >= 7 for r in results)
-    # The kill-bench (bench.py) greps subprocess logs for this exact phrase to
-    # verify the heal path ran; a silent rename would zero the headline metric.
+    # Process-level drives (tests/test_examples_killed.py, the verify recipe)
+    # grep a group's log for this exact phrase to see that the heal path ran.
     assert any("healing from replica" in m for m in caplog.messages)
 
 

@@ -96,11 +96,11 @@ def test_phases_registry_is_stable() -> None:
 
 
 def test_every_emit_call_site_is_registered() -> None:
-    """Greps every ``.emit("name", ...)`` call site in the package (and
-    bench.py) against metrics.EVENTS so a new event cannot ship
-    undocumented.  Registered-but-unused names are allowed (consumers may
-    predate their producers during a refactor)."""
-    roots = [os.path.join(REPO, "torchft_tpu"), os.path.join(REPO, "bench.py")]
+    """Greps every ``.emit("name", ...)`` call site in the package against
+    metrics.EVENTS so a new event cannot ship undocumented.
+    Registered-but-unused names are allowed (consumers may predate their
+    producers during a refactor)."""
+    roots = [os.path.join(REPO, "torchft_tpu")]
     pat = re.compile(r"\.emit\(\s*\n?\s*\"([a-zA-Z0-9_]+)\"")
     emitted = {}
     for root in roots:
@@ -252,31 +252,108 @@ def test_attribute_charges_allreduce_d2h_as_ft_not_productive() -> None:
     assert result["totals"]["other_ft_s"] == pytest.approx(1.505, abs=0.01)
 
 
-def test_deadwindow_matches_bench_fixture(tmp_path) -> None:
-    """The report's goodput on a recorded stream (fault records included)
-    equals the arithmetic bench.py charges for the same timeline."""
-    events = []
-    for t in range(1, 41):
-        events.append(
-            {"ts": float(t), "replica_id": "0:a", "event": "commit", "committed": True}
+def _commits(rid: str, times) -> list:
+    return [
+        {"ts": float(t), "replica_id": rid, "event": "commit", "committed": True}
+        for t in times
+    ]
+
+
+def _fault(ts: float, group: str, kind: str = "kill", **extra) -> dict:
+    return {"ts": ts, "replica_id": "driver", "event": "fault", "kind": kind,
+            "group": group, **extra}
+
+
+# Recorded streams and what the dead-window accounting charges for each:
+# (events, {"dead_time_s", "fraction", "victims_recovered"}, commits per group).
+# Group 0 commits every second from 1 to 40 throughout, so the window is
+# [1, 40], span 39 s, and the victim's median step is 1 s.
+_SURVIVOR = _commits("0:a", range(1, 41))
+DEADWINDOW_STREAMS = {
+    # Incarnation A commits 1..10 and is killed at 10.5; B's first event (a
+    # quorum) is at 17.5, its heal lands at 17.9 and it commits 18..40.  The
+    # one kill-containing gap (10, 18) is 8 s, charged less one median step.
+    "single_kill": [(
+        _SURVIVOR + _commits("1:A", range(1, 11))
+        + [{"ts": 17.5, "replica_id": "1:B", "event": "quorum"},
+           {"ts": 17.9, "replica_id": "1:B", "event": "heal_fetched", "heal_ms": 150.0}]
+        + _commits("1:B", range(18, 41)) + [_fault(10.5, "1")],
+        {"dead_time_s": 7.0, "fraction": 1 - 7.0 / 39.0, "victims_recovered": True},
+        {"0": 40, "1": 33},
+    )],
+    # A drain notice at 10.5: the donor COMMITS THROUGH 13 (that is the point
+    # of a drain) and the replacement's first commit is at 15.  The gap that
+    # holds the notice, (10, 11), is one ordinary step: nothing is charged.
+    # A survivor's failed commit (5.5) is no commit and changes nothing.
+    "drain": [(
+        [{"ts": 5.5, "replica_id": "0:a", "event": "commit", "committed": False}]
+        + _SURVIVOR + _commits("1:A", range(1, 14)) + _commits("1:B", range(15, 41))
+        + [_fault(10.5, "1", kind="drain")],
+        {"dead_time_s": 0.0, "fraction": 1.0, "victims_recovered": True},
+        {"0": 40, "1": 39},
+    )],
+    # The stream a kill driver leaves: the fault record rides in it with the
+    # plan's name, and the report needs nothing but the JSONL.
+    "headline": [(
+        _SURVIVOR + _commits("1:A", range(1, 11)) + _commits("1:B", range(18, 41))
+        + [_fault(10.5, "1", plan="single")],
+        {"dead_time_s": 7.0, "fraction": 1 - 7.0 / 39.0, "victims_recovered": True},
+        {"0": 40, "1": 33},
+    )],
+    # Churn: two kills of one victim charge two gaps, (10, 18) and (22, 30),
+    # each less the 1 s median step; a victim that never commits again
+    # invalidates the trial (no fraction).
+    "double_kill_and_unrecovered": [
+        (
+            _SURVIVOR + _commits("1:A", range(1, 11)) + _commits("1:B", range(18, 23))
+            + _commits("1:C", range(30, 41)) + [_fault(10.5, "1"), _fault(22.5, "1")],
+            {"dead_time_s": 14.0, "fraction": 1 - 14.0 / 39.0, "victims_recovered": True},
+            {"0": 40, "1": 26},
+        ),
+        (
+            _SURVIVOR + _commits("1:A", range(1, 11)) + [_fault(10.5, "1")],
+            {"dead_time_s": 0.0, "fraction": None, "victims_recovered": False},
+            {"0": 40, "1": 10},
+        ),
+    ],
+    # One kill, no other record of the victim's second life than its commits.
+    "kill_and_rejoin": [(
+        _SURVIVOR + _commits("1:A", range(1, 11)) + _commits("1:B", range(18, 41))
+        + [_fault(10.5, "1")],
+        {"dead_time_s": 7.0, "fraction": 1 - 7.0 / 39.0, "victims_recovered": True},
+        {"0": 40, "1": 33},
+    )],
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEADWINDOW_STREAMS))
+def test_deadwindow_on_recorded_streams(tmp_path, case) -> None:
+    """The dead-window goodput of a recorded stream, fault records included:
+    `report.deadwindow` on the stream's commit timelines and fault times,
+    and the same figures through `report.attribute` (what the CLI prints)."""
+    for i, (events, want, per_group) in enumerate(DEADWINDOW_STREAMS[case]):
+        path = _write_jsonl(tmp_path / f"m{i}.jsonl", events)
+        events = report.read_events([path])
+        commits = report.commit_timelines(events)
+        assert {g: len(ts) for g, ts in commits.items()} == per_group
+        dw = report.deadwindow(commits, report.fault_times(events))
+        assert (dw["t0"], dw["t_end"], dw["span_s"]) == (1.0, 40.0, 39.0)
+        assert dw["victims_recovered"] is want["victims_recovered"]
+        assert dw["dead_time_s"] == pytest.approx(want["dead_time_s"], abs=1e-6)
+        assert dw["fraction"] == (
+            None if want["fraction"] is None
+            else pytest.approx(want["fraction"], abs=1e-6)
         )
-    for t in list(range(1, 11)) + list(range(18, 41)):
-        rid = "1:A" if t <= 10 else "1:B"
-        events.append(
-            {"ts": float(t), "replica_id": rid, "event": "commit", "committed": True}
+        result = report.attribute(events)
+        goodput = result["goodput"]
+        assert goodput["victims_recovered"] is want["victims_recovered"]
+        assert goodput["dead_time_s"] == pytest.approx(want["dead_time_s"], abs=5e-3)
+        assert goodput["deadwindow_fraction"] == (
+            None if want["fraction"] is None
+            else pytest.approx(want["fraction"], abs=5e-5)
         )
-    events.append(
-        {"ts": 10.5, "replica_id": "bench-driver", "event": "fault",
-         "kind": "kill", "group": "1"}
-    )
-    path = _write_jsonl(tmp_path / "m.jsonl", events)
-    result = report.attribute(report.read_events([path]))
-    # Gap (10, 18) charged minus the 1 s median step over span 39.
-    assert result["goodput"]["dead_time_s"] == pytest.approx(7.0, abs=1e-6)
-    assert result["goodput"]["deadwindow_fraction"] == pytest.approx(
-        1 - 7.0 / 39.0, abs=1e-4
-    )
-    assert result["goodput"]["victims_recovered"] is True
+        # The report also yields a per-step table over the same stream.
+        assert result["steps"], "attribution table empty"
 
 
 def test_report_cli_json_and_table(tmp_path) -> None:

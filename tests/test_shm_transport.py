@@ -54,8 +54,15 @@ def fresh_prefix() -> str:
         return f"shm_transport/{_PREFIX[0]}"
 
 
-def _segments() -> set:
-    return set(glob.glob("/dev/shm/tpuft-*"))
+def _segments(*child_pids: int) -> set:
+    # The segments of this process and of the children it started
+    # (`_create_shm_segment` names them tpuft-<pid>-...): other test workers
+    # on the machine make their own.
+    return {
+        path
+        for pid in (os.getpid(), *child_pids)
+        for path in glob.glob(f"/dev/shm/tpuft-{pid}-*")
+    }
 
 
 def _make_segment(path: str, token: int, cap: int = 4096) -> None:
@@ -202,7 +209,7 @@ def test_shm_peer_sigkill_cleanup_and_heal(store) -> None:
         child.wait(timeout=10)
         child.stdout.close()
     c.abort()
-    assert _segments() == before, "survivor failed to reclaim segments"
+    assert _segments(child.pid) == before, "survivor failed to reclaim segments"
 
     # Heal: a fresh peer process, a fresh prefix, a working shm ring.
     child2 = _spawn_child(store, prefix2, mode="exit")
@@ -221,4 +228,4 @@ def test_shm_peer_sigkill_cleanup_and_heal(store) -> None:
             child2.wait(timeout=10)
         child2.stdout.close()
         c.shutdown()
-    assert _segments() == before, "leaked shm segments after heal"
+    assert _segments(child.pid, child2.pid) == before, "leaked shm segments after heal"

@@ -7,9 +7,8 @@ TensorBoard needed.  This is how the round-3 static-loop win was found
 (the trace fully accounts the device step; look for op classes that are
 overhead rather than matmul FLOPs, e.g. dynamic-update-slice fusions).
 
-Measurement rules (see bench.py module docstring): chain iterations
-through a data dependency and end the timed window with a completion
-barrier.
+Measurement rules: chain iterations through a data dependency and end the
+timed window with a completion barrier.
 
 Usage: python tools/profile_step.py [--steps 3] [--outdir /tmp/jaxprof]
 """
@@ -33,7 +32,7 @@ def capture(outdir: str, steps: int) -> str:
     import jax
     import jax.numpy as jnp
 
-    from bench import flagship_config
+    from chip_smoke import flagship_config
     from torchft_tpu.models import init_params, loss_fn
 
     rng = np.random.default_rng(0)

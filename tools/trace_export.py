@@ -1,15 +1,15 @@
 #!/usr/bin/env python3
 """Export merged multi-replica metrics JSONL as a Chrome/Perfetto trace.
 
-Any bench or kill-run workdir becomes a viewable timeline::
+Any run's metrics stream (``TPUFT_METRICS_PATH``) becomes a viewable
+timeline::
 
-    python bench.py --scenario kill                  # keeps its workdirs
-    python tools/trace_export.py <workdir>/kill_0/metrics.jsonl
-    # -> <workdir>/kill_0/trace.json; open in ui.perfetto.dev
+    python tools/trace_export.py <workdir>/metrics.jsonl
+    # -> <workdir>/trace.json; open in ui.perfetto.dev
 
 or point it at a directory and it collects every ``*.jsonl`` inside::
 
-    python tools/trace_export.py --workdir <workdir>/kill_0
+    python tools/trace_export.py --workdir <workdir>
 
 The output is standard Chrome trace-event JSON: one process per replica
 group, one track per incarnation (background snapshot work on a sub-track),

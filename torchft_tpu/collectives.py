@@ -1213,11 +1213,10 @@ class TCPCollective(Collective):
         so error grows with ring size — at the replica dimension's small
         world sizes (2-8 groups) the rounding is well inside gradient
         noise; (2) it trades host CPU (the casts) for wire bytes, so it
-        wins only when the network is the bottleneck — on a 200 Mbps /
-        20 ms shaped link a 64 MB 2-rank allreduce measured ~1.75x faster
-        with bf16 (see TRANSFER_BENCH.json shaped_link), while on
-        localhost loopback it measured SLOWER (0.57 s vs 0.46 s f32 on a
-        1-core host)."""
+        wins only when the network is the bottleneck — a bandwidth-bound
+        link moves half the bytes, while on localhost loopback the casts
+        cost more than the bytes save (CPU-host observations of earlier
+        rounds; no cell of the benchmark runs the bf16 wire)."""
         if wire_dtype == "auto":
             wire_dtype = (
                 "bf16"
@@ -1537,6 +1536,11 @@ class TCPCollective(Collective):
             self._generation += 1
             self._dialing = set()
             self._accept_cond.notify_all()
+            # The segments of the CLOSING generation, noted at the same
+            # point and for the same reason: a fast new neighbor's lanes —
+            # and the segments their handshakes register — land after this.
+            with self._shm_lock:
+                old_shm_paths = set(self._shm_paths)
         for p in stale:
             p.close()
         # Fresh prev-direction shaper installed before the publish for the
@@ -1606,10 +1610,13 @@ class TCPCollective(Collective):
                     p.close()
         # Reclaim only the segments whose edges died; surviving segments
         # keep their names (the re-built engine re-attaches them by the
-        # unchanged header token).
+        # unchanged header token).  Only the closing generation's: a
+        # segment a new neighbor's handshake registered since the publish
+        # is this generation's, and unlinking it here left that neighbor
+        # holding a name that opens nothing ("shm open: No such file").
         with self._shm_lock:
-            drop = [sp for sp in self._shm_paths if sp not in keep_paths]
-            self._shm_paths = set(keep_paths)
+            drop = [sp for sp in old_shm_paths if sp not in keep_paths]
+            self._shm_paths = (self._shm_paths - set(drop)) | keep_paths
         for sp in drop:
             try:
                 os.unlink(sp)

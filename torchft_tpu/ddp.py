@@ -541,8 +541,7 @@ class GradientAverager:
     resolved bucket sent home while later ones are still leaving the device.
     ``pipelined=False`` is the monolithic reference path — one blocking
     ``device_get_tree`` of every leaf, then pack+issue in plan order, one
-    drain, one scatter-back — kept for A/B benchmarking
-    (``bench_allreduce.py``), debugging, and as the tests' oracle.
+    drain, one scatter-back — kept for debugging and as the tests' oracle.
 
     ``device_wire_prep`` (default: ``TPUFT_DEVICE_WIRE_PREP``) moves the
     cast to the collective's wire dtype onto the device as a jitted
@@ -583,7 +582,7 @@ class GradientAverager:
         self._wire_np: Any = _UNRESOLVED
         self._plans: Dict[Any, _BucketPlan] = {}
         # Transfer accounting for the LAST allreduce call: d2h/h2d/wire
-        # bytes, bucket/slice counts.  bench_allreduce.py reads this per
+        # bytes, bucket/slice counts.  tests/ring_cells.py reads this per
         # step; the same numbers ride the span records (bytes field) and
         # the Manager's step_summary (note_d2h/note_h2d).
         self.last_stats: Dict[str, int] = {}

@@ -22,9 +22,8 @@ notices into a zero-dead-time handoff instead of a post-mortem:
      and it heals live through the existing checkpoint transports.
 
 Observability: ``drain_notice`` / ``drain_handoff`` / ``drain_complete``
-events in the metrics stream (torchft_tpu/metrics.py);
-``bench.py --scenario drain`` measures the drain-path dead time next to
-the SIGKILL numbers.
+events in the metrics stream (torchft_tpu/metrics.py); tests/test_drain.py
+drives the path end to end.
 """
 
 from torchft_tpu.drain.watcher import (

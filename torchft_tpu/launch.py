@@ -15,7 +15,7 @@ CLI::
     python -m torchft_tpu.launch --groups 2 --max-restarts 3 -- \
         python examples/train_ddp.py --steps 20
 
-Programmatic (this is what ``bench.py``'s kill scenario drives)::
+Programmatic (this is what ``benchmark/jobs/steady.py`` drives)::
 
     with Launcher([sys.executable, "train.py"], num_groups=2,
                   lighthouse="embed", log_dir=workdir) as launcher:

@@ -845,8 +845,8 @@ class Manager:
         """The striped multi-donor fetch path: (state, None) on success,
         (None, error) on failure — the caller decides whether an erasure
         reconstruction can still save this quorum round."""
-        # "healing from replica" is a grep contract with bench.py's
-        # log-fallback heal counter (tests/test_bench_contract.py).
+        # "healing from replica" is a grep contract: tests and the verify
+        # recipe read the heal out of a group's log by this phrase.
         self._logger.info(
             f"healing from replica {src_rank} at step {max_step} via "
             f"{len(donor_addrs)} donor(s) {list(zip(donor_ranks, donor_addrs))}"
@@ -1103,7 +1103,7 @@ class Manager:
         # buffer handed in) or inside the ring encode (f32 handed in).
         # Counting the handoff width instead would make the same wire
         # traffic read 2x apart between those two modes, inverting the
-        # device-prep A/B that bench_allreduce draws from this gauge.  The
+        # device-prep comparison drawn from this gauge.  The
         # collective's own wire_nbytes is the single source of truth;
         # collectives without the probe count the handoff width.
         wire_nbytes = getattr(self._collective, "wire_nbytes", None)

@@ -74,7 +74,7 @@ EVENTS = {
     "drain_complete": "cooperative departure finished cleanly",
     "drain_handoff": "launcher handed the draining group's id to a spare",
     "drain_donor_exit": "draining donor process exited",
-    # -- straggler sentinel (native lighthouse + launch.py, bench.py) -------
+    # -- straggler sentinel (native lighthouse + launch.py, test drivers) ---
     "straggler_injected": "bench driver began the per-step sleep injection "
                           "on the victim group (sleep_s, pid-pinned)",
     "alert": "bench driver observed a sentinel alert on the lighthouse's "
@@ -82,7 +82,7 @@ EVENTS = {
              "into the stream so trace export and latency accounting see it",
     "straggler_drain": "launcher sentinel rotated a confirmed straggler out "
                        "through the cooperative-drain path",
-    # -- slow-link sentinel (native lighthouse + bench_allreduce.py) --------
+    # -- slow-link sentinel (native lighthouse + tests/ring_cells.py) -------
     "link_shaped": "bench driver degraded one peer direction's modeled "
                    "link (mbps, rtt_ms, group=victim) — the data-plane "
                    "fault the slow-link sentinel must localize",
@@ -111,7 +111,7 @@ EVENTS = {
                            "(leader_epoch = the new lease epoch); "
                            "obs/report.py charges the election window like "
                            "quorum wait, not like a worker fault",
-    # -- fault injection (bench.py) -----------------------------------------
+    # -- fault injection (whatever driver injects one; obs/report reads it) --
     "fault": "scripted fault fired (kind=kill|drain|straggler|lighthouse, "
              "group=victim) — written by the benchmark driver so "
              "obs/report.py sees the same fault timeline the goodput "

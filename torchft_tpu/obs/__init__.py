@@ -16,8 +16,7 @@ Three halves plus the live exposition:
   replica's JSONL stream into a per-step cluster timeline, classifies wall
   time into productive / quorum-wait / heal / drain / idle, names the
   critical-path phase per step, and computes the dead-window goodput
-  fraction.  ``bench.py`` calls the same functions, so the benchmark
-  headline and the report tool cannot drift apart.  CLI::
+  fraction.  CLI::
 
       python -m torchft_tpu.obs.report metrics.jsonl [...]
 

@@ -16,18 +16,16 @@ have its own).  Output:
   slowest replica;
 - cluster totals: wall time classified productive / quorum-wait / heal /
   drain / idle per group and summed;
-- the dead-window goodput fraction, computed by :func:`deadwindow` — the
-  SAME function ``bench.py`` calls for its headline, so the benchmark
-  number and this report cannot drift apart (pinned by
-  tests/test_bench_contract.py).
+- the dead-window goodput fraction, computed by :func:`deadwindow` from the
+  stream alone (pinned on recorded streams by tests/test_obs.py).
 
 Timing discipline: durations inside one replica's stream use ``t_mono``
 (NTP-step-immune); cross-replica alignment (t0, spans, gaps between
 incarnations — which never share a monotonic origin) uses ``ts``.
 
-Faults are part of the stream: bench.py writes a ``fault`` record (kind
-kill|drain, group=victim) at injection time, so this tool charges the same
-fault timeline the benchmark charged.
+Faults are part of the stream: the driver that injects one writes a
+``fault`` record (kind kill|drain, group=victim) at injection time, so this
+tool charges the fault timeline the run really had.
 """
 
 from __future__ import annotations
@@ -133,12 +131,12 @@ def commit_timelines(events: Sequence[dict]) -> Dict[str, List[float]]:
 
 
 def fault_times(events: Sequence[dict]) -> List[Tuple[float, str]]:
-    """[(ts, victim group)] from ``fault`` records (written by bench.py).
+    """[(ts, victim group)] from ``fault`` records (written by the driver
+    that injected them).
 
     ``straggler`` faults are excluded: an injected slowdown is not a death
     — the victim keeps committing (slowly), so charging its commit gap as
-    a dead window would fabricate downtime.  The straggler scenario's own
-    accounting (detection latency, post-injection rate) lives in bench.py.
+    a dead window would fabricate downtime.
 
     ``lighthouse`` faults are excluded too: a lighthouse kill is a CONTROL
     PLANE fault, not a worker death — no replica group's commit timeline
@@ -382,7 +380,7 @@ def deadwindow(
 # serial with compute), so they fall through the generic branch below into
 # ``other_ft`` — FT overhead, never productive.  Moving either here would
 # inflate productive time by exactly the transfer stall and break the
-# dead-window math bench.py reproduces from these streams.
+# dead-window math over these streams.
 # Aliased from the one registry (obs/spans.py), not duplicated: a phase
 # added to OVERLAPPED_PHASES but missed here would be charged against
 # productive wall time — fabricated FT cost.

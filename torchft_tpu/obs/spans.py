@@ -343,8 +343,7 @@ class StepTimeStats:
     milliseconds (commit-to-commit wall minus the FT wait phases; see
     ``SpanTracker.ft_accounted_ms``).  Maintains an EWMA — the smoothed
     pace the Manager pushes onto its lighthouse heartbeats — plus a sliding
-    window for p50/p99, which ride in the ``step_summary`` record and
-    bench.py's step-time distributions.
+    window for p50/p99, which ride in the ``step_summary`` record.
 
     Knobs: ``TPUFT_STEP_TIME_ALPHA`` (EWMA weight of the newest step,
     default 0.5 — heavy enough that a host going 2x slow crosses a 1.5x
