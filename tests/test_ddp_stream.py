@@ -290,3 +290,53 @@ def test_the_fetch_spans_tie_holds_on_the_recorded_stream(store, tmp_path, monke
         assert any(lo - 2e3 <= s["t0_ns"] and s["t1_ns"] <= hi + 2e3 for lo, hi in map(edges, h2ds))
     for h in h2ds:
         assert not any(edges(f)[0] < edges(h)[1] - 2e3 and edges(h)[0] < edges(f)[1] - 2e3 for f in fetches)
+
+
+def test_the_lease_wait_lies_between_ready_and_fetch_of_its_leaf_and_is_counted(store, tmp_path, monkeypatch) -> None:  # noqa: F811
+    """Every leaf taken for an accelerator's (the CPU backend's own are not:
+    tests/test_d2h_lease.py): each fetch asks the host's D2H lease between its
+    ``d2h_ready`` and its ``d2h_fetch``, holds it for ``np.asarray`` alone,
+    and ``exchange_stream`` carries the four counters."""
+    from torchft_tpu import d2h_lease, futures
+
+    turns = []
+
+    class Lease(d2h_lease.D2HLease):
+        def acquire(self, nbytes, max_wait_s):
+            held = super().acquire(nbytes, max_wait_s)
+            turns.append(("acquire", nbytes, held.outcome))
+            return held
+
+        def release(self, held):
+            turns.append(("release", held.segment[1], held.outcome))
+            super().release(held)
+
+    monkeypatch.setattr(futures, "_fetch_is_d2h", lambda src: True)
+    monkeypatch.setattr(d2h_lease, "_HOST_LEASE", Lease(str(tmp_path / "lease")))
+    tree = TREES["mixed-dtypes"]()
+    manager = a_manager(store, MirrorCollective(), tmp_path, monkeypatch)
+    try:
+        averager = GradientAverager(manager, BUCKET_BYTES)
+        averager.allreduce(tree)
+        assert manager.should_commit()
+    finally:
+        manager.shutdown()
+    fetches, _h2ds, _merges, subs, stream = stream_of(tmp_path / "m.jsonl")
+    n_leaves = len(jax.tree.leaves(tree))
+    names = ["d2h_ready", "d2h_lease_wait", "d2h_fetch", "d2h_copy"]
+    seen = 0
+    for f in fetches:
+        parts = sorted((s for s in subs if s.get("bucket") == f["bucket"] and s["name"] in names), key=lambda s: s["t0_ns"])
+        assert [s["name"] for s in parts] == names * (len(parts) // 4) and parts
+        assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(parts, parts[1:]))
+        assert sum(s["t1_ns"] - s["t0_ns"] for s in parts) <= f["duration_ms"] * 1e6 + 2e3
+        for wait, fetch in zip(parts[1::4], parts[2::4]):
+            assert wait["parent"] == "allreduce_d2h" and wait["thread"].startswith("tpuft_materialize")
+            assert wait["bytes"] == fetch["bytes"] and wait["contended"] is False
+        seen += len(parts) // 4
+    assert seen == n_leaves
+    # One at a time: released before the next is asked for (and before the copy).
+    assert [t[0] for t in turns] == ["acquire", "release"] * n_leaves
+    assert all(t[2] == "free" for t in turns) and sum(t[1] for t in turns[::2]) == averager.last_stats["d2h_bytes"]
+    counters = {"lease_fetches": n_leaves, "lease_contended": 0, "lease_timeouts": 0, "lease_unavailable": 0}
+    assert {k: stream[k] for k in counters} == counters == {k: averager.last_stats[k] for k in counters}

@@ -255,6 +255,10 @@ def test_two_group_ring_yields_the_buckets_sub_spans(store, tmp_path, monkeypatc
         stream = summary["exchange_stream"]
         assert stream["buckets"] == 3 and stream["tail_s"] >= 0
         assert 0 <= stream["early_puts"] == stats[0]["early_puts"] <= 2
+        # The CPU backend's leaves are in host memory: the host's D2H lease is
+        # never asked, its counters are there and read nothing.
+        assert [stream[k] for k in ("lease_fetches", "lease_contended", "lease_timeouts", "lease_unavailable")] == [0] * 4
+        assert not [s for s in subs if s["name"] == "d2h_lease_wait"]
         assert "allreduce_lanes" in summary
         for k in range(n_buckets):
             of = lambda name: [s for s in subs if s["name"] == name and s.get("bucket") == k]  # noqa: E731

@@ -27,7 +27,18 @@ Each process makes one float32 array of `--mb` megabytes on its chip and times,
   `first_share` is the first fetch's part of the whole: near 1 where the
   first fetch waits for every transfer, 0.30 where each takes its own time;
 - `fetch_turns` (with `--procs 4`): `fetch_new`, one process at a time while
-  the other three idle — the single-stream rate of the four-chip host.
+  the other three idle — the single-stream rate of the four-chip host;
+- `fetch_turns_busy` (with `--procs 4`): the same, while the other three run a
+  host load shaped like the four-group cell's between two fetches: a `numpy`
+  add over a buffer of `--mb` megabytes and a `jax.device_put` of 268 MB, in
+  a loop (`load_rounds`: how many each loader finished) — the single-stream
+  rate a group would see inside the cell;
+- `fetch_pairs` / `fetch_pairs_busy` (with `--procs 4`): two processes fetch
+  at once (ranks 0+1, then 2+3) while the other two idle, or run that load:
+  whether two transfers move more bytes a second together than one alone.
+
+`--phases a,b` runs only the named phases (a prefix selects a family:
+`fetch_window`).
 
 One JSON line per process and phase goes to
 `chiprun_out/d2h_probe/p<procs>.g<i>.jsonl`; the summary (median GB/s per
@@ -66,7 +77,7 @@ def barrier(sync_dir: str, name: str, rank: int, procs: int) -> None:
         time.sleep(0.005)
 
 
-def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
+def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str, phases: tuple) -> None:
     import numpy as np
 
     import jax
@@ -84,6 +95,9 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
         log.write(json.dumps(rec) + "\n")
         log.flush()
 
+    def want(phase: str) -> bool:
+        return not phases or any(phase.startswith(p) for p in phases)
+
     fresh = jax.jit(lambda k: jnp.arange(n, dtype=jnp.float32) + k)
 
     def new_array(k: int):
@@ -91,7 +105,7 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
 
     new_array(0)  # compiled before any phase
     k = 0
-    for rep in range(reps):
+    for rep in range(reps if want("host_") else 0):
         barrier(sync_dir, f"touch{rep}", rank, procs)
         t0 = time.perf_counter()
         a = np.empty(n, np.float32)
@@ -103,7 +117,7 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
         note("host_retouch", rep, t2 - t1)
         del a
 
-    for rep in range(reps):
+    for rep in range(reps if want("fetch_new") else 0):
         k += 1
         x = new_array(k)
         barrier(sync_dir, f"new{rep}", rank, procs)
@@ -115,7 +129,7 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
     landing = np.zeros(n, np.float32)  # touched
     for slice_mb in SLICES_MB:
         m = slice_mb * 1_000_000 // 4
-        if m > n:
+        if m > n or not want(f"fetch_slices_{slice_mb}"):
             continue
         cut = jax.jit(lambda x, i: jax.lax.dynamic_slice(x, (i,), (m,)))
         starts = list(range(0, n - m + 1, m))
@@ -134,7 +148,7 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
             note(f"fetch_slices_{slice_mb}", rep, seconds * nbytes / rec_bytes, first=float(landing[0]))
             del parts, x
 
-    for rep in range(reps):
+    for rep in range(reps if want("fetch_pinned") else 0):
         k += 1
         x = new_array(k)
         barrier(sync_dir, f"pinned{rep}", rank, procs)
@@ -150,7 +164,7 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
             note("fetch_pinned", rep, float("inf"), error=repr(e)[:300])
         del x
 
-    for rep in range(reps):
+    for rep in range(reps if want("h2d_source_reuse") else 0):
         src = np.full(n, 1.0, np.float32)
         barrier(sync_dir, f"h2d{rep}", rank, procs)
         t0 = time.perf_counter()
@@ -163,25 +177,55 @@ def child(rank: int, procs: int, mb: int, reps: int, sync_dir: str) -> None:
              put_saw_rewrite=bool(float(jnp.max(y)) != 1.0))
         del y, src
 
-    if procs > 1:
+    def turns(phase: str, at_a_time: int, busy: bool) -> None:
+        """`fetch_new` by `at_a_time` processes at once, group after group;
+        the others idle, or (`busy`) add over a host buffer and put 268 MB to
+        their chip until every fetcher has left its marker."""
+        nonlocal k
+        if busy:
+            load_host = np.ones(n, np.float32)
+            load_src = np.ones(268_000_000 // 4, np.float32)
         for rep in range(reps):
-            for turn in range(procs):
+            for first in range(0, procs, at_a_time):
+                fetchers = range(first, first + at_a_time)
                 k += 1
-                x = new_array(k) if turn == rank else None
-                barrier(sync_dir, f"turn{rep}.{turn}", rank, procs)
-                if turn == rank:
+                x = new_array(k) if rank in fetchers else None
+                done = [os.path.join(sync_dir, f"{phase}.done{rep}.{r}") for r in fetchers]
+                barrier(sync_dir, f"{phase}{rep}.{first}", rank, procs)
+                if rank in fetchers:
+                    if busy:
+                        time.sleep(0.5)  # the loaders are in their loop
                     t0 = time.perf_counter()
                     host = np.asarray(x)
-                    note("fetch_turns", rep, time.perf_counter() - t0, first=float(host[0]))
+                    seconds = time.perf_counter() - t0
+                    open(done[rank - first], "w").close()
+                    note(phase, rep, seconds, first=float(host[0]))
                     del host, x
-                barrier(sync_dir, f"turned{rep}.{turn}", rank, procs)
+                elif busy:
+                    rounds, t0 = 0, time.perf_counter()
+                    while not all(map(os.path.exists, done)):
+                        np.add(load_host, 1.0, out=load_host)
+                        jax.block_until_ready(jax.device_put(load_src, device))
+                        rounds += 1
+                    note(f"{phase}.load", rep, time.perf_counter() - t0, moved=0, load_rounds=rounds)
+                barrier(sync_dir, f"{phase}.end{rep}.{first}", rank, procs)
+
+    # By prefix, `--phases fetch_turns` selects both kinds of turn, `fetch_pairs` both of pair.
+    if procs > 1 and want("fetch_turns"):
+        turns("fetch_turns", 1, busy=False)
+    if procs > 1 and want("fetch_turns_busy"):
+        turns("fetch_turns_busy", 1, busy=True)
+    if procs == 4 and want("fetch_pairs"):
+        turns("fetch_pairs", 2, busy=False)
+    if procs == 4 and want("fetch_pairs_busy"):
+        turns("fetch_pairs_busy", 2, busy=True)
 
     sizes = sorted((e * mb // 758 for e in CELL_LEAF_ELEMS), reverse=True)
     make = {e: jax.jit(lambda k, e=e: jnp.arange(e, dtype=jnp.float32) + k) for e in set(sizes)}
     jax.block_until_ready([make[e](jnp.float32(0)) for e in make])
     total = 4 * sum(sizes)
     for window in WINDOWS:
-        for rep in range(reps):
+        for rep in range(reps if want(f"fetch_window_{window}") else 0):
             k += 1
             arrays = jax.block_until_ready([make[e](jnp.float32(k)) for e in sizes])
             barrier(sync_dir, f"window{window}.{rep}", rank, procs)
@@ -208,11 +252,13 @@ def main() -> int:
     parser.add_argument("--procs", type=int, default=1, choices=(1, 4))
     parser.add_argument("--mb", type=int, default=758)
     parser.add_argument("--reps", type=int, default=3)
+    parser.add_argument("--phases", default="", help="comma-separated phase names or prefixes; default all")
     parser.add_argument("--child", type=int)
     parser.add_argument("--sync-dir")
     args = parser.parse_args()
     if args.child is not None:
-        child(args.child, args.procs, args.mb, args.reps, args.sync_dir)
+        child(args.child, args.procs, args.mb, args.reps, args.sync_dir,
+              tuple(p for p in args.phases.split(",") if p))
         return 0
     # The parent stays off JAX: a chip belongs to one process.
     with tempfile.TemporaryDirectory() as sync_dir:
@@ -224,7 +270,7 @@ def main() -> int:
                            TPU_PROCESS_BOUNDS="1,1,1")
             children.append(subprocess.Popen(
                 [sys.executable, os.path.abspath(__file__), "--child", str(rank), "--procs", str(args.procs),
-                 "--mb", str(args.mb), "--reps", str(args.reps), "--sync-dir", sync_dir], env=env))
+                 "--mb", str(args.mb), "--reps", str(args.reps), "--phases", args.phases, "--sync-dir", sync_dir], env=env))
         codes = [c.wait() for c in children]
     by_phase = {}
     for rank in range(args.procs):
@@ -235,6 +281,10 @@ def main() -> int:
         except OSError:
             pass
     for phase, recs in by_phase.items():
+        if phase.endswith(".load"):
+            print(json.dumps({"phase": phase, "procs": args.procs, "n": len(recs),
+                              "load_rounds": sorted(r["load_rounds"] for r in recs)}), flush=True)
+            continue
         rates = [r["gb_per_s"] for r in recs]
         line = {"phase": phase, "procs": args.procs, "mb": recs[0]["mb"], "n": len(rates),
                 "gb_per_s_median": statistics.median(rates), "gb_per_s_min": min(rates), "gb_per_s_max": max(rates)}
@@ -244,6 +294,8 @@ def main() -> int:
         if phase.startswith("fetch_window_"):
             line["first_share_median"] = statistics.median(r["first_share"] for r in recs)
             line["each_s_of_rank0_rep0"] = next(r["each_s"] for r in recs if r["rank"] == 0 and r["rep"] == 0)
+        if phase.startswith("fetch_pairs"):
+            line["together_gb_per_s_median"] = 2 * line["gb_per_s_median"]
         if phase == "fetch_pinned":
             errors = sorted({r["error"] for r in recs if "error" in r})
             line.update({"errors": errors} if errors else

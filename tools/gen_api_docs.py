@@ -31,6 +31,7 @@ MODULES = [
     "torchft_tpu.collectives",
     "torchft_tpu.baby",
     "torchft_tpu.futures",
+    "torchft_tpu.d2h_lease",
     "torchft_tpu.checkpointing.transport",
     "torchft_tpu.checkpointing.http_transport",
     "torchft_tpu.checkpointing.collective_transport",

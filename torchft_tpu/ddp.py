@@ -45,6 +45,14 @@ every put in a last ``allreduce_h2d`` (all FT time, never charged as
 productive compute — obs/report.py and the straggler sentinel depend on
 that).  ``step_summary.exchange_stream`` says how far the stream engaged.
 
+Where the leaves live on an accelerator, each one's transfer takes its turn
+with those of every other tpu-ft process on the machine (the host's D2H
+lease, ``d2h_lease.py``, taken in ``futures.device_get_into`` around
+``np.asarray`` alone): co-located groups fetch one after the other, first
+come, first served, so all of them land position ``p`` before any fetches
+``p + 1``, and one group's transfer runs under the others' copies, ring ops
+and puts.  ``exchange_stream`` counts the fetches that went through it.
+
 ``PerLeafGradientAverager`` mirrors PureDistributedDataParallel's
 per-parameter variant (torchft/ddp.py:74-97).
 """
@@ -691,7 +699,7 @@ class GradientAverager:
         """
         import jax
 
-        from torchft_tpu.futures import device_get_tree
+        from torchft_tpu.futures import LEASE_COUNTERS, device_get_tree
 
         leaves, treedef = jax.tree.flatten(grads)
         if not leaves:
@@ -722,6 +730,8 @@ class GradientAverager:
             "buckets": len(plan.buckets),
             "device_buckets": sum(1 for d in plan.device if d is not None),
             "slices": 0,
+            # The streamed path's fetches under the host's D2H lease.
+            **dict.fromkeys(LEASE_COUNTERS, 0),
         }
         self.last_stats = stats
         # Per-hop WIRE bytes a bucket's payload travels as — NOT what this
@@ -885,7 +895,7 @@ class GradientAverager:
         device run under the fetches of the buckets after it."""
         import jax
 
-        from torchft_tpu.futures import device_get_into
+        from torchft_tpu.futures import LEASE_COUNTERS, device_get_into
 
         spans = self._manager.spans
         step = self._manager.current_step()
@@ -935,6 +945,7 @@ class GradientAverager:
                         pairs,
                         timeout,
                         sub=functools.partial(spans.sub, step=step, bucket=k),
+                        lease_counts=stats,
                     )
                 except TimeoutError as e:
                     self._manager.report_error(e)
@@ -1142,6 +1153,7 @@ class GradientAverager:
                         "early_puts": early_puts,
                         "buckets": len(order),
                         "tail_s": round(time.monotonic() - fetched_at, 4),
+                        **{name: stats[name] for name in LEASE_COUNTERS},
                     }
                 )
             except Exception:  # noqa: BLE001 — telemetry only

@@ -15,7 +15,7 @@ import heapq
 import itertools
 import threading
 from concurrent.futures import Future
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Generator, Optional, TypeVar
 
 T = TypeVar("T")
@@ -286,11 +286,38 @@ def _copy_into(dst, src_host, cast: bool) -> None:
     np.copyto(dst, src_host, casting="unsafe")
 
 
+def _fetch_is_d2h(src) -> bool:
+    """Whether materializing ``src`` is a device-to-host transfer: a jax
+    array in an accelerator's own memory.  Numpy leaves, CPU-backend arrays
+    and ``pinned_host`` leaves are in host memory already."""
+    devices = getattr(src, "devices", None)
+    if not callable(devices):
+        return False
+    if any(d.platform == "cpu" for d in devices()):
+        return False
+    return getattr(getattr(src, "sharding", None), "memory_kind", None) != "pinned_host"
+
+
+# What device_get_into counts in the ``lease_counts`` dict it is handed, by the
+# outcome of each accelerator-resident fetch: fetches that went through the
+# host's D2H lease, those of them that had to wait, those whose bounded wait
+# expired (fetched without the lease), and fetches on a host where the lease
+# could not be opened.
+LEASE_COUNTERS = ("lease_fetches", "lease_contended", "lease_timeouts", "lease_unavailable")
+_COUNTED_AS = {
+    "free": LEASE_COUNTERS[:1],
+    "waited": LEASE_COUNTERS[:2],
+    "timeout": LEASE_COUNTERS[:3],
+    "unavailable": LEASE_COUNTERS[3:],
+}
+
+
 def device_get_into(
     pairs: list,
     timeout: float,
     cast: bool = False,
     sub: Optional[Callable[..., Any]] = None,
+    lease_counts: Optional[dict] = None,
 ) -> None:
     """Materializes ``(src, dst)`` pairs host-side under one shared deadline,
     landing each source directly in its destination view — the bucket-
@@ -305,27 +332,56 @@ def device_get_into(
     bytes into bf16 buffers, and a silent f32<->bf16 convert here would
     hide a mis-planned buffer at half or double the intended D2H bytes.
 
+    A source in an accelerator's memory is fetched under the host's D2H
+    lease (``torchft_tpu/d2h_lease.py``): every tpu-ft process of the machine
+    takes turns at ``np.asarray``, first come, first served, because
+    transfers side by side are slower together than one after the other.
+    The lease is held for the transfer alone — released before the copy into
+    ``dst``, so another group's transfer runs under this group's copy, ring
+    op and puts — and the wait for it is bounded (a quarter of ``timeout`` at
+    most), after which the fetch runs without it.  ``lease_counts`` (optional:
+    a dict) counts what happened under :data:`LEASE_COUNTERS`.  Sources in
+    host memory never touch it.
+
     ``sub`` (optional: ``sub(name, **fields)`` returning a context manager,
     the GradientAverager passes its tracker's) takes each pair's fetch apart
     where it happens, on the materializer thread: ``d2h_ready`` (the wait for
     the program that produces ``src`` — the one call this adds, same result
-    and order), ``d2h_fetch`` (``np.asarray``: the DMA into PJRT's host
-    buffer) and ``d2h_copy`` (the second pass into ``dst``).
+    and order), ``d2h_lease_wait`` (accelerator sources only; ``contended``:
+    whether it had to wait), ``d2h_fetch`` (``np.asarray``: the DMA into
+    PJRT's host buffer) and ``d2h_copy`` (the second pass into ``dst``).
     """
+    import types
+
     import numpy as np
 
+    from torchft_tpu.d2h_lease import host_lease
+
+    if sub is None:
+        def sub(_name: str, **fields: Any):
+            return nullcontext(types.SimpleNamespace(fields=fields))
+
+    counts = lease_counts if lease_counts is not None else {}
+
     def run() -> None:
-        if sub is None:
-            for src, dst in pairs:
-                _copy_into(dst, np.asarray(src), cast)
-            return
         import jax
 
         for src, dst in pairs:
             with sub("d2h_ready"):
                 jax.block_until_ready(src)
-            with sub("d2h_fetch", bytes=dst.nbytes):
-                host = np.asarray(src)
+            turn = host_lease() if _fetch_is_d2h(src) else None
+            if turn is not None:
+                with sub("d2h_lease_wait", bytes=dst.nbytes) as wait:
+                    held = turn.acquire(dst.nbytes, timeout / 4)
+                    wait.fields["contended"] = held.outcome in ("waited", "timeout")
+                for name in _COUNTED_AS[held.outcome]:
+                    counts[name] = counts.get(name, 0) + 1
+            try:
+                with sub("d2h_fetch", bytes=dst.nbytes):
+                    host = np.asarray(src)
+            finally:
+                if turn is not None:
+                    turn.release(held)
             with sub("d2h_copy", bytes=dst.nbytes):
                 _copy_into(dst, host, cast)
 

@@ -107,10 +107,12 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 # what they read without them) and leave as one ``subspan`` record a step,
 # not as ``span`` records.  To see them: the ``subspan`` records of the
 # metrics stream, or ``tpuft:<name>`` on the host threads of a profile.
-#   d2h_ready / d2h_fetch / d2h_copy — one bucket's fetch on the
-#     materializer thread: the wait for the gradient program, the DMA into
-#     PJRT's host buffer (np.asarray), the second pass into the flat buffer;
-#     allreduce_d2h minus the three is the hand-off to that thread.
+#   d2h_ready / d2h_lease_wait / d2h_fetch / d2h_copy — one leaf's fetch on
+#     the materializer thread: the wait for the gradient program, the wait
+#     for the host's D2H lease (accelerator-resident leaves only; ``bytes``,
+#     ``contended``: whether anyone was ahead), the DMA into PJRT's host
+#     buffer (np.asarray alone), the second pass into the flat buffer;
+#     allreduce_d2h minus the four is the hand-off to that thread.
 #   ring_queue / ring_run — one ring op from Manager.allreduce's submission
 #     to the moment a ring worker took it up, and from there to its end
 #     (recorded from the collective's timestamps, so no TraceAnnotation).
@@ -130,6 +132,7 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 #     step_summary: a read of arrays already on the host.
 SUBSPANS = {
     "d2h_ready": "allreduce_d2h",
+    "d2h_lease_wait": "allreduce_d2h",
     "d2h_fetch": "allreduce_d2h",
     "d2h_copy": "allreduce_d2h",
     "ring_queue": "exchange",
