@@ -157,18 +157,22 @@ def test_flagship_gradient_program_compiles_with_kernels_for_v5e(
     assert resident < 16 * 2**30, f"the program needs {resident} bytes, a v5e chip has 16 GiB"
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)], ids=["up_and_gate", "down"])
-def test_grouped_matmul_kernels_compile_for_v5e(one_chip, k, n) -> None:
-    """The three `tpuft_gmm_*` kernels at OLMoE-1B-7B's widths and the
-    benchmark cell's rows: 8,192 tokens x 8 experts a token over 64 experts,
-    each expert's rows padded to the row tile."""
+@pytest.mark.parametrize("k,n,experts,assignments", [
+    (2048, 1024, 64, 8192 * 8), (1024, 2048, 64, 8192 * 8), (2048, 1408, 8, 24576), (1408, 2048, 8, 24576),
+], ids=["olmoe_up_and_gate", "olmoe_down", "moonlight_up_and_gate", "moonlight_down"])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, k, n, experts, assignments) -> None:
+    """The three `tpuft_gmm_*` kernels at OLMoE-1B-7B's widths and its cell's
+    rows (8,192 tokens x 8 experts a token over 64 experts), and at
+    Moonlight-16B-A3B's over the 8 experts one chip holds (twice their even
+    share of 16,384 x 6 assignments), each expert's rows padded to the row
+    tile.  An expert's whole matrix is one block at both."""
     from torchft_tpu.ops import grouped_matmul as gm
 
-    experts, rows = 64, 8192 * 8 + 64 * gm.ROW_TILE
+    rows = assignments + experts * gm.ROW_TILE
+    assert gm._tiles(rows, k, n, gm.ROW_TILE) == (n, k, n)
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     rhs = jax.ShapeDtypeStruct((experts, k, n), jnp.float32, sharding=one_chip)
     sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
-    assert gm._tiles(rows, k, n, gm.ROW_TILE) is not None
 
     def product_and_gradients(l, r, s):
         out, vjp = jax.vjp(lambda l_, r_: gm._gmm(l_, r_, s, gm.ROW_TILE, False), l, r)
@@ -209,3 +213,59 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mo
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     assert n_params == 625_616_896
     assert resident < 14 * 2**30, f"the step needs {resident} bytes with AdamW's moments, a v5e chip has 16 GiB"
+
+
+def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:
+    """`tpuft_fa_*` at latent attention's widths and the Moonlight cell's
+    shapes: 2 x 16 heads, 8,192 positions, query and key 256 wide (192 padded
+    to a lane multiple), value and output 128; 16 key blocks, so the backward
+    is the two-pass form."""
+    from torchft_tpu.ops.attention import _DQ_PARTIAL_MAX_K, _fa_bwd_pallas, _fa_pallas_call
+
+    bh, seq, d_qk, d_v = 32, 8192, 256, 128
+    assert seq // 512 > _DQ_PARTIAL_MAX_K
+    qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
+    text = _compile(lambda q, k, v_: _fa_pallas_call(q, k, v_, 192 ** -0.5, True), qk, qk, v)
+    assert _has_kernel(text, "tpuft_fa_fwd")
+    text = _compile(lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, 192 ** -0.5, True), qk, qk, v, v, lse, v)
+    assert _has_kernel(text, "tpuft_fa_bwd_dkdv") and _has_kernel(text, "tpuft_fa_bwd_dq")
+
+
+def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `moonlight-16b-a3b` configuration as
+    `benchmark/programs/mla_moe_lm.py` hands it to `TrainStep`: the whole
+    gradient program at the published widths — latent attention through
+    `tpuft_fa_*`, the 8 held experts of each sparse layer through
+    `tpuft_gmm_*`, the leading dense layer, the sliced vocabulary through
+    `tpuft_ce_*` — with room for AdamW's moments beside it on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("moonlight-16b-a3b"), bench.traffic("steady-1g-8k")
+    shapes = jax.eval_shape(lambda: bench.reference("mla_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("mla_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_fa_fwd", "tpuft_fa_bwd_dkdv", "tpuft_fa_bwd_dq", "tpuft_gmm_fwd", "tpuft_gmm_dlhs",
+                 "tpuft_gmm_drhs", "tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    # `remat_keeps_attention`: one forward attention kernel a layer, not a second in the backward pass
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line and "tpuft_fa_fwd" in line]
+    assert len(calls) == config["num_hidden_layers"], len(calls)
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    assert n_params == bench.flops("mla_moe_lm").total_params(config)
+    assert resident < 14.5e9, f"the step needs {resident} bytes with AdamW's moments; the cut's bound is 14.5 GB"

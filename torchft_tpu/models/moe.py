@@ -5,20 +5,41 @@ Capability beyond the reference: torchft has no EP anywhere (SURVEY.md §2.3
 — PP/CP/EP absent); this is part of the TPU build's first-class parallelism
 surface alongside ring/Ulysses sequence parallelism.
 
-Routing is shared (``route``): float32 softmax over the router's logits,
-top-k, the kept gates renormalised or left as they are (OLMoE leaves them).
+Routing is shared (``route``), a float32 product over ALL the router's
+outputs in one of two forms:
+
+  - ``score="softmax"`` (OLMoE): softmax over the logits, the k largest,
+    the kept gates renormalised or left as they are (OLMoE leaves them);
+  - ``score="sigmoid"`` (DeepSeek-V3's ``noaux_tc``, Moonlight): a sigmoid
+    score per expert, the k largest of score + ``bias`` chosen (the bias
+    steers the choice alone: a buffer, not a trained weight, constant under
+    the gradient), the gates the chosen scores WITHOUT the bias,
+    renormalised over the k and times ``scale``
+    (``routed_scaling_factor``).  Its balance loss takes the scores
+    normalised over the experts for P_e and divides by k (DeepSeek-V3's
+    sequence-wise term).
+
 Then one of two ways to the experts, both with STATIC shapes:
 
-  - **dropless, sorted** (``capacity_factor=None``; one device holds every
-    expert): the T * k (token, expert) assignments are ordered by expert —
-    a rank within the expert from a cumulative sum, no sort — into one
-    row buffer in which every expert's rows start on a row-tile boundary
+  - **dropless, sorted** (``capacity_factor=None``; the experts' matrices
+    are the ones THIS device holds, ``held = (first, count)`` of the
+    router's outputs — all of them for OLMoE, one chip's share of an
+    expert-parallel layer for Moonlight): the (token, expert) assignments
+    that fall on held experts are ordered by expert — a rank within the
+    expert from a cumulative sum, no sort — into one row buffer in which
+    every held expert's rows start on a row-tile boundary
     (``ops.padded_group_sizes``), the three expert projections are grouped
     matmuls over that buffer (``ops.grouped_matmul``: the ``tpuft_gmm_*``
     kernels on a TPU), and the rows go back to their tokens weighted by
-    their gates.  No capacity, so no assignment is ever dropped.  Both
-    directions of both moves are gathers (a token has exactly k rows and a
-    row one token), so the backward pass has no scatter-add;
+    their gates.  An assignment to an expert held elsewhere gets no row
+    and adds nothing: the layer's result is this device's part of the sum
+    (its all-to-all partner would add the rest; no code stands in for it).
+    With every expert held the buffer takes all T * k assignments, so none
+    is ever dropped; a share's buffer is ``HELD_ROWS_FACTOR`` times its
+    even share of them, and an assignment to a held expert that finds it
+    full is counted in ``dropped``.  Both directions of both moves are
+    gathers (a token has at most k rows and a row one token), so the
+    backward pass has no scatter-add;
   - **capacity-bound, dense dispatch** (GShard/Switch style,
     arXiv:2006.16668; what a mesh with an "expert" axis runs): dense
     dispatch/combine tensors [T, n_exp, capacity], over-capacity
@@ -33,13 +54,18 @@ Auxiliary losses, per sequence and averaged over the batch (as a
 data-parallel job takes its statistics per device batch): the load-balance
 loss ``n_exp * sum_e f_e * P_e`` with f_e the share of the sequence's
 positions that chose expert e among their k (Switch Transformer,
-arXiv:2101.03961, as OLMoE applies it to top-k) and P_e the mean router
-probability, and the router z-loss ``mean(logsumexp(logits)^2)``
-(arXiv:2202.08906).
+arXiv:2101.03961, as OLMoE applies it to top-k; over k for the sigmoid
+router, arXiv:2412.19437 eq. 17-19) and P_e the mean router probability,
+and the router z-loss ``mean(logsumexp(logits)^2)`` (arXiv:2202.08906).
+
+A **shared expert** (``moe_layer(shared=...)``) is one SwiGLU every token
+passes beside the routed ones; every device of an expert-parallel layer
+computes it alike.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -51,6 +77,10 @@ from torchft_tpu.parallel.sharding import ShardingRules, constrain
 
 Stats = Dict[str, jax.Array]
 
+# A share's dropless row buffer as a multiple of its even share of the
+# assignments: shapes are static, so the buffer needs a bound (`held_rows`).
+HELD_ROWS_FACTOR = 2.0
+
 
 def moe_capacity(tokens: int, n_experts: int, top_k: int, capacity_factor: float) -> int:
     """Static per-expert token capacity, padded to the 8-sublane boundary."""
@@ -58,29 +88,47 @@ def moe_capacity(tokens: int, n_experts: int, top_k: int, capacity_factor: float
     return max(8, -(-cap // 8) * 8)
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool):
+def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool, *,
+          score: str = "softmax", bias: Optional[jax.Array] = None, scale: float = 1.0):
     """x [B, S, E], router [E, n_exp] -> (logits, probs [B, S, n_exp] f32,
     gate_vals [B, S, k] f32, gate_idx [B, S, k]).  The logits are a float32
     product at the highest precision: which experts a token takes hangs on
-    differences far under bf16's rounding."""
+    differences far under bf16's rounding.  ``probs`` is what the balance
+    loss averages: the softmax, or the sigmoid scores normalised over the
+    experts.  ``bias`` [n_exp] (sigmoid only) moves the choice and never a
+    gate."""
     logits = jnp.einsum(
         "bse,ex->bsx", x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
+    if score == "softmax":
+        assert bias is None and scale == 1.0, "the softmax router has no bias and no scale"
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
+        if norm_topk:
+            # Renormalize the kept gates so the combine is a convex mixture.
+            gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+        return logits, probs, gate_vals, gate_idx
+    assert score == "sigmoid", f"unknown router score {score!r}"
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, gate_idx = jax.lax.top_k(choice, top_k)
+    gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
     if norm_topk:
-        # Renormalize the kept gates so the combine is a convex mixture.
-        gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-    return logits, probs, gate_vals, gate_idx
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20)
+    probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return logits, probs, gate_vals * scale, gate_idx
 
 
-def router_stats(logits: jax.Array, probs: jax.Array, gate_idx: jax.Array) -> Stats:
+def router_stats(logits: jax.Array, probs: jax.Array, gate_idx: jax.Array, *, per_choice: bool = False) -> Stats:
     """The two auxiliary losses (module docstring) and how many assignments
-    each expert received, over the whole batch."""
+    each expert received, over the whole batch.  ``per_choice``: f_e counts
+    a position's k choices as one (the balance loss over k)."""
     n_exp = probs.shape[-1]
     chose = jnp.sum(jax.nn.one_hot(gate_idx, n_exp, dtype=jnp.float32), axis=2)  # [B, S, n_exp]
     balance = n_exp * jnp.sum(jnp.mean(chose, axis=1) * jnp.mean(probs, axis=1), axis=-1)
+    if per_choice:
+        balance = balance / gate_idx.shape[-1]
     return {
         "balance": jnp.mean(balance),
         "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
@@ -96,8 +144,17 @@ def _int_zero(x: jax.Array):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
-@jax.custom_vjp
-def _rows_of_tokens(xf, row_token, dest):
+def _take_rows(rows, dest, every_row_exists: bool):
+    """rows[dest]; a `dest` past the end (an assignment without a row) reads
+    zeros.  Where every assignment has a row by construction the check is
+    left out."""
+    if every_row_exists:
+        return jnp.take(rows, dest, axis=0, mode="clip")
+    return jnp.take(rows, dest, axis=0, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_tokens(xf, row_token, dest, every_row_exists: bool):
     """xf [T, E] -> [R, E]: row r is token ``row_token[r]``'s activation.  A
     row no assignment landed in (``row_token[r] == T``) repeats the last
     token's: it only has to be finite, because its cotangent is zero
@@ -106,33 +163,34 @@ def _rows_of_tokens(xf, row_token, dest):
     return jnp.take(xf, row_token, axis=0, mode="clip")
 
 
-def _rows_fwd(xf, row_token, dest):
-    return _rows_of_tokens(xf, row_token, dest), (row_token, dest)
+def _rows_fwd(xf, row_token, dest, every_row_exists):
+    return _rows_of_tokens(xf, row_token, dest, every_row_exists), (row_token, dest)
 
 
-def _rows_bwd(res, drows):
-    # A token's k rows are at dest[t]: a gather and a sum, not a scatter-add.
+def _rows_bwd(every_row_exists, res, drows):
+    # A token's rows are at dest[t]: a gather and a sum, not a scatter-add.
     row_token, dest = res
-    dxf = jnp.sum(jnp.take(drows, dest, axis=0, mode="clip").astype(jnp.float32), axis=1)
+    dxf = jnp.sum(_take_rows(drows, dest, every_row_exists).astype(jnp.float32), axis=1)
     return dxf.astype(drows.dtype), _int_zero(row_token), _int_zero(dest)
 
 
 _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
-@jax.custom_vjp
-def _tokens_of_rows(rows, gates, dest, row_assignment):
-    """rows [R, E], gates [T, k] f32 -> [T, E]: each token the sum of its k
-    rows weighted by their gates."""
-    picked = jnp.take(rows, dest, axis=0, mode="clip").astype(jnp.float32)  # [T, k, E]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists: bool):
+    """rows [R, E], gates [T, k] f32 -> [T, E]: each token the sum of its
+    rows weighted by their gates (an assignment without a row adds zero)."""
+    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)  # [T, k, E]
     return jnp.einsum("tke,tk->te", picked, gates).astype(rows.dtype)
 
 
-def _tokens_fwd(rows, gates, dest, row_assignment):
-    return _tokens_of_rows(rows, gates, dest, row_assignment), (rows, gates, dest, row_assignment)
+def _tokens_fwd(rows, gates, dest, row_assignment, every_row_exists):
+    out = _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists)
+    return out, (rows, gates, dest, row_assignment)
 
 
-def _tokens_bwd(res, dy):
+def _tokens_bwd(every_row_exists, res, dy):
     rows, gates, dest, row_assignment = res
     k = gates.shape[1]
     # Row r belongs to assignment row_assignment[r] = t * k + j (T * k where
@@ -141,7 +199,7 @@ def _tokens_bwd(res, dy):
     row_gate = jnp.take(gates.reshape(-1), row_assignment, mode="fill", fill_value=0)
     drows = jnp.take(dy, row_assignment // k, axis=0, mode="clip")
     drows = (drows.astype(jnp.float32) * row_gate[:, None]).astype(rows.dtype)
-    picked = jnp.take(rows, dest, axis=0, mode="clip").astype(jnp.float32)
+    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
     dgates = jnp.einsum("tke,te->tk", picked, dy.astype(jnp.float32))
     return drows, dgates, _int_zero(dest), _int_zero(row_assignment)
 
@@ -149,35 +207,60 @@ def _tokens_bwd(res, dy):
 _tokens_of_rows.defvjp(_tokens_fwd, _tokens_bwd)
 
 
-def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, mesh):
-    """xf [T, E] in the compute type; gate_vals, gate_idx [T, k].  Returns
-    (y [T, E], assignments that found no row — none, by the buffer's size)."""
+def held_rows(n_assign: int, n_exp: int, count: int, factor: float, row_tile: int = ROW_TILE) -> int:
+    """Rows of the dropless buffer for ``count`` held experts of ``n_exp``:
+    ``factor`` times their even share of the assignments — never more than
+    all of them, which is what every expert held takes — plus a tile an
+    expert for the padding, in whole tiles."""
+    share = n_assign if count == n_exp else min(n_assign, int(-(-n_assign * count * factor // n_exp)))
+    return -(-(share + count * row_tile) // row_tile) * row_tile
+
+
+def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first, rows_factor, mesh):
+    """xf [T, E] in the compute type; gate_vals, gate_idx [T, k] over the
+    router's ``n_exp`` outputs; the matrices those of the held experts
+    ``first ... first + count - 1``.  Returns (y [T, E], assignments that
+    fell on held experts, those of them that found no row — none where
+    every expert is held, by the buffer's size)."""
     tokens, k = gate_idx.shape
-    n_exp = w_gate.shape[0]
+    count = w_gate.shape[0]
     n_assign = tokens * k
     row_tile = ROW_TILE
-    rows = -(-(n_assign + n_exp * row_tile) // row_tile) * row_tile
+    rows = held_rows(n_assign, n_exp, count, rows_factor, row_tile)
+    every_row_exists = count == n_exp  # every assignment is held and the buffer takes them all: `dest` is in bounds
 
     expert = gate_idx.reshape(n_assign)
-    mine = (expert[:, None] == jnp.arange(n_exp, dtype=expert.dtype)[None, :]).astype(jnp.int32)
-    arrived = jnp.cumsum(mine, axis=0)  # [T * k, n_exp]: assignments of each expert up to and with this one
+    held = jnp.arange(first, first + count, dtype=expert.dtype)
+    mine = (expert[:, None] == held[None, :]).astype(jnp.int32)
+    arrived = jnp.cumsum(mine, axis=0)  # [T * k, count]: assignments of each held expert up to and with this one
     # Each assignment's own column, as a masked sum: a gather of T * k
     # scalars costs the v5e 4 ms, this pass over 16 MB a few microseconds.
     rank = jnp.sum(arrived * mine, axis=1) - 1
     sizes = padded_group_sizes(arrived[-1], row_tile)
     starts = jnp.cumsum(sizes) - sizes
-    dest = jnp.sum(starts[None, :] * mine, axis=1) + rank  # the row of each assignment; distinct, all < rows
+    dest = jnp.sum(starts[None, :] * mine, axis=1) + rank  # the row of each assignment; distinct
+    n_held = jnp.sum(arrived[-1])
+    if every_row_exists:
+        dropped = jnp.sum((dest >= rows).astype(jnp.int32))
+    else:
+        # An assignment to an expert held elsewhere, or past the buffer's
+        # end, gets a row past `rows` (each its own, so that the scatter's
+        # indices stay distinct): out of bounds, so the scatter below skips
+        # it and every gather reads zeros for it.
+        here = jnp.sum(mine, axis=1) > 0
+        dropped = jnp.sum((here & (dest >= rows)).astype(jnp.int32))
+        dest = jnp.where(here & (dest < rows), dest, rows + jnp.arange(n_assign, dtype=dest.dtype))
     row_assignment = jnp.full((rows,), n_assign, jnp.int32).at[dest].set(
         jnp.arange(n_assign, dtype=jnp.int32), unique_indices=True
     )
     dest = dest.reshape(tokens, k)
 
-    xs = _rows_of_tokens(xf, row_assignment // k, dest)
+    xs = _rows_of_tokens(xf, row_assignment // k, dest, every_row_exists)
     gate = grouped_matmul(xs, w_gate, sizes, row_tile=row_tile, mesh=mesh)
     up = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
     out = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes, row_tile=row_tile, mesh=mesh)
-    y = _tokens_of_rows(out, gate_vals, dest, row_assignment)
-    return y, jnp.sum((dest >= rows).astype(jnp.int32))
+    y = _tokens_of_rows(out, gate_vals, dest, row_assignment, every_row_exists)
+    return y, n_held.astype(jnp.int32), dropped
 
 
 # -- capacity-bound: dense dispatch/combine tensors ---------------------------
@@ -238,6 +321,12 @@ def moe_layer(
     top_k: int = 2,
     capacity_factor: Optional[float] = 1.25,
     norm_topk: bool = True,
+    score: str = "softmax",
+    route_bias: Optional[jax.Array] = None,
+    route_scale: float = 1.0,
+    held_first: int = 0,
+    held_rows_factor: float = HELD_ROWS_FACTOR,
+    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     dtype: Any = jnp.bfloat16,
     mesh=None,
     rules: Optional[ShardingRules] = None,
@@ -247,42 +336,65 @@ def moe_layer(
     Args:
         x: [B, S, E] activations.
         router: [E, n_exp] routing weights (kept f32 — routing logits are
-            numerically sensitive).
-        w_gate/w_up: [n_exp, E, F]; w_down: [n_exp, F, E] stacked experts.
-        capacity_factor: None = dropless (the sorted path; one device holds
-            every expert); a number = the capacity-bound dense dispatch.
+            numerically sensitive), over ALL the layer's experts.
+        w_gate/w_up: [held, E, F]; w_down: [held, F, E]: the stacked experts
+            this device holds, ``held_first ... held_first + held - 1`` of the
+            router's outputs.  ``held == n_exp`` is every expert; fewer
+            (dropless path only) is one device's share of an expert-parallel
+            layer, and the result then this device's part of the sum.
+        capacity_factor: None = dropless (the sorted path); a number = the
+            capacity-bound dense dispatch (every expert held).
+        score, route_bias, route_scale: the router's form (``route``).
+        held_rows_factor: the dropless row buffer of a share, as a multiple
+            of its even share of the assignments (``held_rows``).
+        shared: (gate [E, Fs], up [E, Fs], down [Fs, E]) of a SwiGLU every
+            token passes beside the routed experts, or None.
 
     Returns:
         (y [B, S, E], stats): ``balance`` and ``z`` (scalar f32 auxiliary
-        losses), ``tokens_per_expert`` ([n_exp] int32, assignments each
-        expert was sent), ``chosen`` ([B, S, k], the experts each position
-        took) and ``dropped`` (int32, assignments that reached no expert: 0
-        on the dropless path by construction).
+        losses), ``tokens_per_expert`` ([n_exp] int32, assignments each of
+        the router's outputs was sent), ``chosen`` ([B, S, k], the experts
+        each position took), ``assignments`` (int32, B * S * k),
+        ``rows_held`` (int32, those that fell on held experts) and
+        ``dropped`` (int32, assignments to HELD experts that reached none: 0
+        on the dropless path with every expert held, by construction).
     """
     rules = rules or ShardingRules()
     B, S, E = x.shape
     n_exp = router.shape[1]
+    held = w_gate.shape[0]
     T = B * S
-    logits, probs, gate_vals, gate_idx = route(x, router, top_k, norm_topk)
-    stats = router_stats(logits, probs, gate_idx)
+    logits, probs, gate_vals, gate_idx = route(
+        x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale)
+    stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
     gate_vals, gate_idx = gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k)
     xf = x.reshape(T, E)
     if capacity_factor is None:
         if mesh is not None and "expert" in mesh.axis_names and mesh.shape["expert"] > 1:
             raise ValueError(
-                "the dropless MoE path holds every expert on one device; a mesh with an "
-                "'expert' axis takes the capacity-bound path (set moe_capacity_factor)"
+                "the dropless MoE path runs on one device (all the experts, or the share it is "
+                "told it holds); a mesh with an 'expert' axis takes the capacity-bound path "
+                "(set moe_capacity_factor)"
             )
-        y, dropped = _dropless_ffn(
-            xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down, mesh=mesh
+        assert 0 <= held_first and held_first + held <= n_exp, (held_first, held, n_exp)
+        y, rows_held, dropped = _dropless_ffn(
+            xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down,
+            n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh,
         )
     else:
+        if held != n_exp:
+            raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis)")
         y, dropped = _capacity_ffn(
             xf, gate_vals, gate_idx, w_gate, w_up, w_down,
             capacity=moe_capacity(T, n_exp, top_k, capacity_factor), dtype=dtype, mesh=mesh, rules=rules,
         )
-    stats["dropped"] = dropped
-    return y.reshape(B, S, E).astype(x.dtype), stats
+        rows_held = jnp.asarray(T * top_k, jnp.int32)
+    stats.update(dropped=dropped, rows_held=rows_held, assignments=jnp.asarray(T * top_k, jnp.int32))
+    y = y.reshape(B, S, E).astype(x.dtype)
+    if shared is not None:
+        s_gate, s_up, s_down = (w.astype(dtype) for w in shared)
+        y = y + ((jax.nn.silu(x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
+    return y, stats
 
 
 def moe_ffn(x, router, w_gate, w_up, w_down, **kwargs) -> Tuple[jax.Array, jax.Array]:

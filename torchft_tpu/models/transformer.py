@@ -43,6 +43,11 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16          # activation/compute dtype (MXU-native)
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # With remat: keep each layer's attention output and row statistics
+    # (ops.attention.SAVED_NAMES; [B, H, S, Dv] in the compute type a layer),
+    # so that the backward pass recomputes the projections and the
+    # feed-forward but does not run the attention forward kernel twice.
+    remat_keeps_attention: bool = False
     # Attention backend: "flash" (pallas kernel / XLA fallback), "ring"
     # (sequence-parallel K/V rotation), or "ulysses" (all-to-all head<->seq
     # resharding) — the latter two engage over the mesh "sequence" axis.
@@ -61,22 +66,52 @@ class TransformerConfig:
     # >= n_layers switches to a static Python loop (constant-folded layer
     # indexing — see forward_with_aux), the fastest measured form.
     scan_unroll: int = 1
-    # Mixture-of-experts: > 0 replaces the dense MLP with moe_experts
-    # experts (stacked, shardable over the "expert" mesh axis).
-    # moe_capacity_factor None = dropless: the sorted path with grouped
-    # matmuls, every expert on one device (models/moe.py); a number = the
-    # capacity-bound dense dispatch an "expert" mesh axis runs.
+    # Mixture-of-experts: > 0 replaces the dense MLP with a router over
+    # moe_experts experts of width d_ff (stacked, shardable over the
+    # "expert" mesh axis).  Two ways to the experts (models/moe.py):
+    # moe_capacity_factor None = dropless, the sorted path with grouped
+    # matmuls on ONE device, which holds every expert or, with moe_held =
+    # (first, count), only that share of them — the router still scores all
+    # moe_experts, the layer's result is this device's part of the sum, and
+    # the parameter tree holds `count` experts a layer; a number = the
+    # capacity-bound dense dispatch an "expert" mesh axis runs (every
+    # expert, over-capacity assignments dropped).
     # moe_norm_topk: renormalise the k kept gates (OLMoE does not).
+    # moe_score "softmax" (OLMoE) or "sigmoid": sigmoid scores, the choice
+    # made on score + a constant bias handed to the loss beside the weights
+    # (`router_bias`: not a trained leaf), the gates the chosen scores
+    # renormalised and times moe_route_scale, the balance loss over k
+    # (DeepSeek-V3 / Moonlight).
+    # moe_shared_experts: a SwiGLU of width moe_shared_experts * d_ff that
+    # every token passes beside the routed experts.
+    # moe_dense_layers: that many LEADING layers keep a dense feed-forward of
+    # width dense_d_ff; they are stacked apart, under params["dense_layers"].
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: Optional[float] = 1.25
     moe_norm_topk: bool = True
     moe_aux_coef: float = 0.01       # load-balance loss
     moe_z_coef: float = 0.0          # router z-loss
+    moe_score: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_experts: int = 0
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_dense_layers: int = 0
+    dense_d_ff: int = 0
     # RMSNorm over the whole projected query and key, each with a weight of
     # its own, before the split into heads and RoPE (OLMoE's attention).
     qk_norm: bool = False
     rms_eps: float = 1e-6
+    # Latent attention (MLA, DeepSeek-V2/V3): mla_kv_rank > 0 replaces wk/wv
+    # by a low-rank path — wkv_a: embed -> mla_kv_rank + mla_rope_dim, an
+    # RMSNorm over the rank, wkv_b: rank -> heads * (mla_nope_dim +
+    # mla_v_dim) — with ONE rotary key of mla_rope_dim columns shared by all
+    # heads.  A query and key head is mla_nope_dim + mla_rope_dim wide, a
+    # value head mla_v_dim, and d_head is not used.
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -86,6 +121,27 @@ class TransformerConfig:
         assert self.ring_layout in ("contiguous", "zigzag"), (
             f"unknown ring_layout {self.ring_layout!r}"
         )
+        assert self.moe_score in ("softmax", "sigmoid"), f"unknown moe_score {self.moe_score!r}"
+        if self.mla_kv_rank:
+            assert self.attention == "flash" and not self.qk_norm, (
+                "latent attention runs the flash backend, without a QK-norm"
+            )
+        if self.moe_dense_layers:
+            assert self.moe_experts > 0 and 0 < self.moe_dense_layers < self.n_layers and self.dense_d_ff > 0
+        if self.moe_held is not None:
+            first, count = self.moe_held
+            assert self.moe_capacity_factor is None and 0 <= first and first + count <= self.moe_experts, (
+                "a share of the experts is held on the dropless path"
+            )
+
+    @property
+    def n_sparse_layers(self) -> int:
+        """Layers under params["layers"] (all of a dense model's)."""
+        return self.n_layers - self.moe_dense_layers
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.moe_held[1] if self.moe_held is not None else self.moe_experts
 
     @property
     def d_head(self) -> int:
@@ -94,23 +150,24 @@ class TransformerConfig:
 
 
 # Logical axis names for every parameter (see parallel/sharding.py).
-def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
-    """Logical axis names for every parameter, keyed like init_params'
-    tree — feed to FTMesh.shard_params to place the model on a mesh."""
+def _layer_axes(cfg: TransformerConfig, sparse: bool) -> Dict[str, Any]:
     layer = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv_heads"),
-        "wv": ("layers", "embed", "kv_heads"),
         "wo": ("layers", "heads", "embed"),
         "mlp_norm": ("layers", "embed"),
         "w_gate": ("layers", "embed", "mlp"),
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if cfg.mla_kv_rank:
+        layer.update({"wkv_a": ("layers", "embed", None), "kv_norm": ("layers", None),
+                      "wkv_b": ("layers", None, "heads")})
+    else:
+        layer.update({"wk": ("layers", "embed", "kv_heads"), "wv": ("layers", "embed", "kv_heads")})
     if cfg.qk_norm:
         layer.update({"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv_heads")})
-    if cfg.moe_experts > 0:
+    if sparse:
         layer.update(
             {
                 "router": ("layers", "embed", "expert"),
@@ -119,50 +176,80 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": ("layers", "expert", "mlp", "embed"),
             }
         )
-    return {
+        if cfg.moe_shared_experts:
+            layer.update({"shared_gate": ("layers", "embed", "mlp"), "shared_up": ("layers", "embed", "mlp"),
+                          "shared_down": ("layers", "mlp", "embed")})
+    return layer
+
+
+def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    """Logical axis names for every parameter, keyed like init_params'
+    tree — feed to FTMesh.shard_params to place the model on a mesh."""
+    axes = {
         "embed": ("vocab", "embed"),
-        "layers": layer,
+        "layers": _layer_axes(cfg, sparse=cfg.moe_experts > 0),
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.moe_dense_layers:
+        axes["dense_layers"] = _layer_axes(cfg, sparse=False)
+    return axes
 
 
-def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
-    """Initializes the transformer parameter pytree (layers stacked on a
-    leading axis for the scan-over-layers; param_dtype precision)."""
-    k_embed, k_layers, k_head = jax.random.split(key, 3)
+def _norm_init(k, shape, fan_in, pd):
+    return (jax.random.normal(k, shape, pd) * (fan_in ** -0.5)).astype(pd)
+
+
+def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -> Dict[str, Any]:
     pd = cfg.param_dtype
-    E, H, KV, Dh, F, L = (
-        cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff,
-        cfg.n_layers,
-    )
+    E, H, KV = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
 
     def norm_init(k, shape, fan_in):
-        return (jax.random.normal(k, shape, pd) * (fan_in ** -0.5)).astype(pd)
+        return _norm_init(k, shape, fan_in, pd)
 
-    ks = jax.random.split(k_layers, 8)
-    layers = {
-        "attn_norm": jnp.ones((L, E), pd),
-        "wq": norm_init(ks[0], (L, E, H * Dh), E),
-        "wk": norm_init(ks[1], (L, E, KV * Dh), E),
-        "wv": norm_init(ks[2], (L, E, KV * Dh), E),
-        "wo": norm_init(ks[3], (L, H * Dh, E), H * Dh),
-        "mlp_norm": jnp.ones((L, E), pd),
-    }
-    if cfg.qk_norm:
-        layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
-    if cfg.moe_experts > 0:
-        X = cfg.moe_experts
+    ks = jax.random.split(key, 8)
+    layers = {"attn_norm": jnp.ones((L, E), pd), "mlp_norm": jnp.ones((L, E), pd)}
+    if cfg.mla_kv_rank:
+        R, Dq = cfg.mla_kv_rank, cfg.mla_nope_dim + cfg.mla_rope_dim
+        layers.update(
+            {
+                "wq": norm_init(ks[0], (L, E, H * Dq), E),
+                "wkv_a": norm_init(ks[1], (L, E, R + cfg.mla_rope_dim), E),
+                "kv_norm": jnp.ones((L, R), pd),
+                "wkv_b": norm_init(ks[2], (L, R, H * (cfg.mla_nope_dim + cfg.mla_v_dim)), R),
+                "wo": norm_init(ks[3], (L, H * cfg.mla_v_dim, E), H * cfg.mla_v_dim),
+            }
+        )
+    else:
+        Dh = cfg.d_head
+        layers.update(
+            {
+                "wq": norm_init(ks[0], (L, E, H * Dh), E),
+                "wk": norm_init(ks[1], (L, E, KV * Dh), E),
+                "wv": norm_init(ks[2], (L, E, KV * Dh), E),
+                "wo": norm_init(ks[3], (L, H * Dh, E), H * Dh),
+            }
+        )
+        if cfg.qk_norm:
+            layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
+    if sparse:
+        F, X, held = cfg.d_ff, cfg.moe_experts, cfg.n_held_experts
         kr, kg, ku, kd = jax.random.split(ks[7], 4)
         layers.update(
             {
                 "router": norm_init(kr, (L, E, X), E),
-                "w_gate": norm_init(kg, (L, X, E, F), E),
-                "w_up": norm_init(ku, (L, X, E, F), E),
-                "w_down": norm_init(kd, (L, X, F, E), F),
+                "w_gate": norm_init(kg, (L, held, E, F), E),
+                "w_up": norm_init(ku, (L, held, E, F), E),
+                "w_down": norm_init(kd, (L, held, F, E), F),
             }
         )
+        if cfg.moe_shared_experts:
+            Fs = cfg.moe_shared_experts * F
+            kg, ku, kd = jax.random.split(jax.random.fold_in(ks[7], 1), 3)
+            layers.update({"shared_gate": norm_init(kg, (L, E, Fs), E), "shared_up": norm_init(ku, (L, E, Fs), E),
+                           "shared_down": norm_init(kd, (L, Fs, E), Fs)})
     else:
+        F = cfg.dense_d_ff if cfg.moe_dense_layers else cfg.d_ff
         layers.update(
             {
                 "w_gate": norm_init(ks[4], (L, E, F), E),
@@ -170,12 +257,26 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": norm_init(ks[6], (L, F, E), F),
             }
         )
-    return {
-        "embed": norm_init(k_embed, (cfg.vocab_size, E), E),
-        "layers": layers,
+    return layers
+
+
+def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
+    """Initializes the transformer parameter pytree (layers stacked on a
+    leading axis for the scan-over-layers; param_dtype precision).  A model
+    with leading dense layers stacks those apart, under "dense_layers"."""
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    pd = cfg.param_dtype
+    E = cfg.d_model
+    params = {
+        "embed": _norm_init(k_embed, (cfg.vocab_size, E), E, pd),
+        "layers": _init_layers(k_layers, cfg, cfg.n_sparse_layers, sparse=cfg.moe_experts > 0),
         "final_norm": jnp.ones((E,), pd),
-        "lm_head": norm_init(k_head, (E, cfg.vocab_size), E),
+        "lm_head": _norm_init(k_head, (E, cfg.vocab_size), E, pd),
     }
+    if cfg.moe_dense_layers:
+        params["dense_layers"] = _init_layers(
+            jax.random.fold_in(k_layers, 1), cfg, cfg.moe_dense_layers, sparse=False)
+    return params
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -243,33 +344,58 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
     return flash_attention(q, k, v, causal=True, mesh=mesh)
 
 
-def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):
-    """One decoder block; x: [B, S, E]."""
+def _mla_qkv(cfg: TransformerConfig, h, w, positions):
+    """Latent attention's q, k [B, S, H, nope + rope] and v [B, S, H, v]
+    from the normed input h [B, S, E]: the keys' and values' content
+    through the low-rank path, one rotary key for all heads."""
+    B, S, _ = h.shape
+    H, Dn, Dr, Dv, R = cfg.n_heads, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim, cfg.mla_kv_rank
+    q = (h @ w["wq"].astype(cfg.dtype)).reshape(B, S, H, Dn + Dr)
+    latent = h @ w["wkv_a"].astype(cfg.dtype)                       # [B, S, R + Dr]
+    kv = rms_norm(latent[..., :R], w["kv_norm"], cfg.rms_eps) @ w["wkv_b"].astype(cfg.dtype)
+    kv = kv.reshape(B, S, H, Dn + Dv)
+    q_rope = _rope(q[..., Dn:], positions, cfg.rope_theta)
+    k_rope = _rope(latent[..., None, R:], positions, cfg.rope_theta)  # [B, S, 1, Dr]
+    q = jnp.concatenate([q[..., :Dn], q_rope], axis=-1)
+    k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(k_rope, (B, S, H, Dr))], axis=-1)
+    return q, k, kv[..., Dn:]
+
+
+def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, sparse=None, router_bias=None):
+    """One decoder block; x: [B, S, E].  `sparse`: whether its feed-forward
+    is the mixture of experts (default: the model has one); `router_bias`
+    [n_exp]: the sigmoid router's choice bias for this layer, or None."""
     B, S, E = x.shape
-    H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    H, KV = cfg.n_heads, cfg.n_kv_heads
+    sparse = cfg.moe_experts > 0 if sparse is None else sparse
 
     h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
-    q = h @ w["wq"].astype(cfg.dtype)
-    if cfg.qk_norm:
-        q = rms_norm(q, w["q_norm"], cfg.rms_eps)
-    q = q.reshape(B, S, H, Dh)
-    k = h @ w["wk"].astype(cfg.dtype)
-    if cfg.qk_norm:
-        k = rms_norm(k, w["k_norm"], cfg.rms_eps)
-    k = k.reshape(B, S, KV, Dh)
-    v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.mla_kv_rank:
+        q, k, v = _mla_qkv(cfg, h, w, positions)
+        KV = H
+    else:
+        Dh = cfg.d_head
+        q = h @ w["wq"].astype(cfg.dtype)
+        if cfg.qk_norm:
+            q = rms_norm(q, w["q_norm"], cfg.rms_eps)
+        q = q.reshape(B, S, H, Dh)
+        k = h @ w["wk"].astype(cfg.dtype)
+        if cfg.qk_norm:
+            k = rms_norm(k, w["k_norm"], cfg.rms_eps)
+        k = k.reshape(B, S, KV, Dh)
+        v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
     k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
     v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
-    attn = _attention(cfg, mesh, q, k, v)            # [B, H, S, Dh]
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
+    attn = _attention(cfg, mesh, q, k, v)            # [B, H, S, Dv]
+    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * attn.shape[-1])
     x = x + (attn @ w["wo"].astype(cfg.dtype))
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
     h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
-    if cfg.moe_experts > 0:
+    if sparse:
         from torchft_tpu.models.moe import moe_layer
 
         y, aux = moe_layer(
@@ -281,6 +407,11 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions):
             top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
             norm_topk=cfg.moe_norm_topk,
+            score=cfg.moe_score,
+            route_bias=router_bias,
+            route_scale=cfg.moe_route_scale,
+            held_first=cfg.moe_held[0] if cfg.moe_held is not None else 0,
+            shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
             dtype=cfg.dtype,
             mesh=mesh,
             rules=rules,
@@ -300,13 +431,18 @@ def _decoder(
     cfg: TransformerConfig,
     mesh=None,
     rules: Optional[ShardingRules] = None,
+    router_bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Embedding + decoder stack (everything before the lm head).
     tokens: [B, S] int32 -> (hidden [B, S, E], aux).  For a dense model aux
-    is a zero scalar; for an MoE model it is the layers' router statistics
-    (models/moe.py ``moe_layer``): ``balance``, ``z`` and ``dropped`` summed
-    over the layers, ``tokens_per_expert`` [n_layers, n_experts] and
-    ``chosen`` [n_layers, B, S, k]."""
+    is a zero scalar; for an MoE model it is the sparse layers' router
+    statistics (models/moe.py ``moe_layer``): ``balance``, ``z``,
+    ``dropped``, ``rows_held`` and ``assignments`` summed over the layers,
+    ``tokens_per_expert`` [n_sparse_layers, n_experts] and ``chosen``
+    [n_sparse_layers, B, S, k].  Leading dense layers
+    (``cfg.moe_dense_layers``, under params["dense_layers"]) run first.
+    ``router_bias`` [n_sparse_layers, n_experts]: the sigmoid router's
+    choice bias, a constant of the loss."""
     rules = rules or ShardingRules()
     B, S = tokens.shape
     pos = jnp.arange(S, dtype=jnp.int32)
@@ -329,12 +465,25 @@ def _decoder(
     x = params["embed"].astype(cfg.dtype)[tokens]
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
+    if cfg.moe_dense_layers:
+        def dense_body(x, w):
+            return _layer(cfg, mesh, rules, x, w, positions, sparse=False)[0]
+
+        if cfg.remat:
+            dense_body = _remat(cfg, dense_body)
+        for i in range(cfg.moe_dense_layers):  # a leading layer or three: always a static loop
+            x = dense_body(x, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]))
+
+    stacked = params["layers"]
+    if router_bias is not None:
+        stacked = dict(stacked, router_bias=router_bias)
+
     def body(x, w):
-        x, aux = _layer(cfg, mesh, rules, x, w, positions)
-        return x, aux
+        w = dict(w)
+        return _layer(cfg, mesh, rules, x, w, positions, router_bias=w.pop("router_bias", None))
 
     if cfg.remat:
-        body = jax.checkpoint(body)
+        body = _remat(cfg, body)
     if cfg.scan_unroll > 1 and cfg.scan_unroll >= cfg.n_layers:
         # Full unroll as a STATIC Python loop rather than lax.scan(unroll=L):
         # scan's internal layer slicing survives as dynamic-update-slice
@@ -346,8 +495,8 @@ def _decoder(
         # (pinned by test_scan_unroll_matches_scan).
         aux_total = jnp.zeros((), jnp.float32)
         aux_layers = []
-        for i in range(cfg.n_layers):
-            w_i = jax.tree.map(lambda a, i=i: a[i], params["layers"])
+        for i in range(cfg.n_sparse_layers):
+            w_i = jax.tree.map(lambda a, i=i: a[i], stacked)
             x, aux = body(x, w_i)
             if cfg.moe_experts > 0:
                 aux_layers.append(aux)
@@ -357,11 +506,19 @@ def _decoder(
             return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
         return x, aux_total
     x, aux_layers = jax.lax.scan(
-        body, x, params["layers"], unroll=cfg.scan_unroll
+        body, x, stacked, unroll=cfg.scan_unroll
     )
     if cfg.moe_experts > 0:
         return x, _over_layers(aux_layers)
     return x, jnp.sum(aux_layers)
+
+
+def _remat(cfg: TransformerConfig, body):
+    if not cfg.remat_keeps_attention:
+        return jax.checkpoint(body)
+    from torchft_tpu.ops.attention import SAVED_NAMES
+
+    return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
 
 
 def _over_layers(stats: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -379,10 +536,11 @@ def forward_with_aux(
     cfg: TransformerConfig,
     mesh=None,
     rules: Optional[ShardingRules] = None,
+    router_bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens: [B, S] int32 -> (logits [B, S, vocab] f32, aux scalar f32 —
     the summed MoE load-balance loss; zero for dense models)."""
-    x, aux = _decoder(params, tokens, cfg, mesh, rules)
+    x, aux = _decoder(params, tokens, cfg, mesh, rules, router_bias)
     if cfg.moe_experts > 0:
         aux = aux["balance"]
     return head(params, x, cfg, mesh, rules), aux
@@ -465,13 +623,14 @@ def loss_fn(
     cfg: TransformerConfig,
     mesh=None,
     rules: Optional[ShardingRules] = None,
+    router_bias: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Next-token cross entropy; batch: {"tokens": [B,S], "targets": [B,S]}.
 
     MoE configs add moe_aux_coef * load-balance loss (Switch-style) and
     moe_z_coef * router z-loss.
     """
-    return loss_and_counters(params, batch, cfg, mesh, rules)[0]
+    return loss_and_counters(params, batch, cfg, mesh, rules, router_bias)[0]
 
 
 def loss_and_counters(
@@ -480,17 +639,27 @@ def loss_and_counters(
     cfg: TransformerConfig,
     mesh=None,
     rules: Optional[ShardingRules] = None,
+    router_bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """``loss_fn`` and what the model counted on the way, for a
     ``TrainStep(loss_has_counters=True)``: for an MoE model
-    ``moe_tokens_per_expert`` ([n_layers, n_experts] int32, assignments sent
-    to each expert) and ``moe_dropped`` (int32, assignments that reached no
-    expert); for a dense model nothing."""
-    x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules)
+    ``moe_tokens_per_expert`` ([n_sparse_layers, n_experts] int32,
+    assignments sent to each of the router's outputs) and ``moe_dropped``
+    (int32, assignments to experts held here that reached none); where the
+    device holds a share of the experts (``cfg.moe_held``) also
+    ``moe_assignments`` (int32, all (token, expert) choices of the sparse
+    layers) and ``moe_rows_held`` (int32, those that fell on held experts);
+    for a dense model nothing.  ``router_bias`` [n_sparse_layers,
+    n_experts] is the sigmoid router's choice bias: a constant, no leaf of
+    ``params``, so neither the gradient nor the optimizer sees it."""
+    x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
     ce = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
     if cfg.moe_experts == 0:
         return ce, {}
     loss = ce + cfg.moe_aux_coef * aux["balance"]
     if cfg.moe_z_coef:
         loss = loss + cfg.moe_z_coef * aux["z"]
-    return loss, {"moe_tokens_per_expert": aux["tokens_per_expert"], "moe_dropped": aux["dropped"]}
+    counters = {"moe_tokens_per_expert": aux["tokens_per_expert"], "moe_dropped": aux["dropped"]}
+    if cfg.moe_held is not None:
+        counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
+    return loss, counters

@@ -20,6 +20,14 @@ Design notes (MXU/HBM-minded):
     reduction axis innermost: TPU executes the innermost grid dimension
     sequentially, which is what makes the VMEM scratch accumulator legal.
 
+Query and key share one head width (``d_qk``), value and output another
+(``d_v``): equal for plain multi-head attention, 192 / 128 for latent
+attention (MLA), whose keys carry 64 rotary columns beside the 128 that the
+values match.  Each kernel's blocks span a whole head width, so both have to
+be lane multiples; ``flash_attention`` pads a ``d_qk`` that is not (MLA's
+192 -> 256) with zero columns, which add nothing to a score, and autodiff
+slices the padding's gradient away again.
+
 The reference XLA attention runs off-TPU (CPU test mesh), under a
 multi-device mesh (a pallas call has no partitioning rule), and for shapes
 the kernel does not tile (seq not divisible by the block size).
@@ -40,12 +48,14 @@ _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: scratch row-stats are kept (block_q, 128)
 
 
-def _use_pallas(seq_q: int, seq_k: int, head_dim: int, mesh=None) -> bool:
+def _use_pallas(seq_q: int, seq_k: int, d_v: int, mesh=None) -> bool:
+    """Whether the kernels run (`flash_attention` pads the query's and key's
+    width to a lane multiple, so only the value's has to be one)."""
     bq, bk = _block_sizes(seq_q, seq_k)
     return (
         seq_q % bq == 0
         and seq_k % bk == 0
-        and head_dim % _LANE == 0
+        and d_v % _LANE == 0
         and _pallas_util.kernels_apply(mesh)
     )
 
@@ -124,8 +134,8 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    bh, seq_q, d = q.shape  # d: query and key; dv: value and output
+    seq_k, dv = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
     num_k = seq_k // block_k
     grid = (bh, seq_q // block_q, num_k)
@@ -136,23 +146,23 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     out, lse_padded = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, _LANE), jnp.float32),
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
         name="tpuft_fa_fwd",
@@ -288,12 +298,13 @@ def _fa_bwd_dq_kernel(
 
 def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
                    interpret: bool = False):
-    """Flash backward on TPU; q/k/v/o/g: [BH, S, D], lse: [BH, S] f32."""
+    """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
+    [BH, S] f32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape
-    seq_k = k.shape[1]
+    seq_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
     num_q, num_k = seq_q // block_q, seq_k // block_k
     # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
@@ -303,21 +314,23 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, None, :]
 
-    qo_spec_ji = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    kv_spec_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    q_spec_ji = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    do_spec_ji = pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0))
+    k_spec_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    v_spec_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0))
     row_spec_ji = pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0))
-    in_specs_ji = [qo_spec_ji, kv_spec_ji, kv_spec_ji, qo_spec_ji,
+    in_specs_ji = [q_spec_ji, k_spec_ji, v_spec_ji, do_spec_ji,
                    row_spec_ji, row_spec_ji]
     dkdv_scratch = [
         pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d_v), jnp.float32),
     ]
     merged = num_k <= _DQ_PARTIAL_MAX_K
     out_shape = [
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     ]
-    out_specs = [kv_spec_ji, kv_spec_ji]
+    out_specs = [k_spec_ji, v_spec_ji]
     if merged:
         # dq as f32 per-kv-block partials: the cross-block sum loses no
         # precision vs the f32 XLA backward this replaced.
@@ -352,8 +365,10 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     dk, dv = outs
 
     # Long-context second pass: dq with the kv axis innermost.
-    qo_spec_ij = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec_ij = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    q_spec_ij = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    do_spec_ij = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
+    k_spec_ij = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    v_spec_ij = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0))
     row_spec_ij = pl.BlockSpec((1, 1, seq_q), lambda b, i, j: (b, 0, 0))
     dq = pl.pallas_call(
         functools.partial(
@@ -362,9 +377,9 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(bh, num_q, num_k),
-        in_specs=[qo_spec_ij, kv_spec_ij, kv_spec_ij, qo_spec_ij,
+        in_specs=[q_spec_ij, k_spec_ij, v_spec_ij, do_spec_ij,
                   row_spec_ij, row_spec_ij],
-        out_specs=qo_spec_ij,
+        out_specs=q_spec_ij,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="tpuft_fa_bwd_dq",
@@ -373,7 +388,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
 
 
 def _fa_reference(q, k, v, scale: float, causal: bool):
-    """Stable XLA attention returning (out, lse); q/k/v: [BH, S, D]."""
+    """Stable XLA attention returning (out, lse); q/k: [BH, S, D], v: [BH, S, Dv]."""
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
     if causal:
         seq_q, seq_k = s.shape[-2], s.shape[-1]
@@ -402,8 +417,18 @@ def _flash(q, k, v, scale: float, causal: bool, kernel: bool):
     return o
 
 
+# What a rematerialised layer has to keep so that its backward pass does not
+# run the forward kernel again: `jax.checkpoint(...,
+# policy=save_only_these_names(*SAVED_NAMES))`.  q, k and v are cheap to make
+# again (projections); the output and the row statistics are the kernel's.
+SAVED_NAMES = ("tpuft_fa_out", "tpuft_fa_lse")
+
+
 def _flash_fwd(q, k, v, scale, causal, kernel):
+    from jax.ad_checkpoint import checkpoint_name
+
     o, lse = _fa_forward(q, k, v, scale, causal, kernel)
+    o, lse = checkpoint_name(o, SAVED_NAMES[0]), checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
@@ -445,7 +470,9 @@ def flash_attention(
     scale: float | None = None,
     mesh=None,
 ) -> jax.Array:
-    """Multi-head attention; q: [B, Hq, S, D], k/v: [B, Hkv, S, D].
+    """Multi-head attention; q: [B, Hq, S, D], k: [B, Hkv, S, D], v:
+    [B, Hkv, S, Dv] -> [B, Hq, S, Dv].  Dv may differ from D (MLA: 192 for
+    query and key, 128 for value); the default scale is D ** -0.5.
 
     GQA: Hkv may divide Hq; kv heads are broadcast to query groups.
     ``mesh`` is the mesh of the program being traced (None: the ambient
@@ -453,19 +480,25 @@ def flash_attention(
     see ``_pallas_util.kernels_apply``.
     """
     b, hq, sq, d = q.shape
-    hkv = k.shape[1]
+    hkv, dv = k.shape[1], v.shape[3]
     if hkv != hq:
         assert hq % hkv == 0, "query heads must be a multiple of kv heads"
         rep = hq // hkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
     scale = scale if scale is not None else d ** -0.5
+    kernel = _use_pallas(sq, k.shape[2], dv, mesh)
+    if kernel and d % _LANE:
+        # Zero columns up to the next lane multiple: nothing in a score.
+        pad = [(0, 0)] * 3 + [(0, -d % _LANE)]
+        q, k = jnp.pad(q, pad), jnp.pad(k, pad)
+        d = q.shape[3]
     out = _flash(
         q.reshape(b * hq, sq, d),
         k.reshape(b * hq, k.shape[2], d),
-        v.reshape(b * hq, v.shape[2], d),
+        v.reshape(b * hq, v.shape[2], dv),
         scale,
         causal,
-        _use_pallas(sq, k.shape[2], d, mesh),
+        kernel,
     )
-    return out.reshape(b, hq, sq, d)
+    return out.reshape(b, hq, sq, dv)

@@ -47,9 +47,12 @@ __all__ = ["ROW_TILE", "grouped_matmul", "padded_group_sizes"]
 ROW_TILE = 128
 # A weight block [K, block_n] in the type the weights are held in and the drhs
 # accumulator [K, block_n] (f32), each double-buffered by the pipeline: what
-# the block solver fits.  OLMoE's [2048, 1024] f32 expert matrix is one block.
-_RHS_BLOCK_BYTES = 8 * 1024 * 1024
-_ACC_BLOCK_BYTES = 8 * 1024 * 1024
+# the block solver fits.  OLMoE's [2048, 1024] f32 expert matrix (8 MiB) is one
+# block, and so is Moonlight's [2048, 1408] (11 MiB): 1408 = 11 * 128 has no
+# divisor between 128 and itself, and at 128 columns a block every row tile is
+# fetched eleven times.
+_RHS_BLOCK_BYTES = 12 * 1024 * 1024
+_ACC_BLOCK_BYTES = 12 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
