@@ -273,7 +273,7 @@ def xla_formulation():
         _pallas_util.on_tpu = saved
 
 
-KERNELS = ("tpuft_fa_fwd", "tpuft_fa_bwd_dkdv", "tpuft_ce_lse", "tpuft_ce_dlogits")
+KERNELS = ("tpuft_fa_fwd", "tpuft_fa_bwd_dkdv_dq", "tpuft_ce_lse", "tpuft_ce_dlogits")
 
 
 def has_kernel(compiled_text: str, name: str) -> bool:

@@ -85,7 +85,7 @@ def test_kernel_compiles_for_v5e(one_chip, width, kernel) -> None:
             lambda q, k, v_, o, lse, g: _fa_bwd_pallas(q, k, v_, o, lse, g, d ** -0.5, True),
             qkv, qkv, qkv, qkv, sds((bh, seq), jnp.float32), qkv,
         )
-        names = ["tpuft_fa_bwd_dkdv"]  # seq/512 <= 4: the merged one-pass form
+        names = ["tpuft_fa_bwd_dkdv_dq"]  # the one-pass form, as at every cell's shape
     elif kernel == "ce_lse":
         from torchft_tpu.ops.cross_entropy import _ce_lse_pallas
 
@@ -108,20 +108,55 @@ def test_kernel_compiles_for_v5e(one_chip, width, kernel) -> None:
         assert _has_kernel(text, name), f"{name} is not in the compiled program"
 
 
-def test_long_context_two_pass_backward_compiles_for_v5e(one_chip) -> None:
-    """seq 4096 -> 8 kv blocks > _DQ_PARTIAL_MAX_K: the dk/dv pass without dq
-    partials plus the separate dq pass (`_fa_bwd_dq_kernel`)."""
-    from torchft_tpu.ops.attention import _DQ_PARTIAL_MAX_K, _fa_bwd_pallas
+def _attention_calls(text: str) -> list:
+    """The names of the compiled program's attention kernels, one entry per
+    `tpu_custom_call` (a pallas kernel's `name=` is in its metadata)."""
+    import re
 
-    bh, seq, d = 12, 4096, 128
-    assert seq // 512 > _DQ_PARTIAL_MAX_K
+    return [m.group(0) for line in text.splitlines() if "tpu_custom_call" in line and "custom-call(" in line
+            for m in [re.search(r"tpuft_fa_[a-z_]*[a-z]", line)] if m]
+
+
+@pytest.mark.parametrize(
+    "bh,seq,d_qk,d_v",
+    [(32, 4096, 128, 128), (16, 32768, 128, 128), (8, 65536, 128, 128)],
+    ids=["dense_cells_4096", "roadmap_w3_32768", "largest_resident_row_65536"],
+)
+def test_one_pass_backward_compiles_for_v5e(one_chip, bh, seq, d_qk, d_v) -> None:
+    """The backward at the dense cells' shape (8 kv blocks; Moonlight's 16 at
+    256 / 128 are `test_latent_attention_kernels_compile_for_v5e`'s), at the
+    32,768 positions ROADMAP W3 asks for (a 16 MiB dq row) and at the
+    longest row the budget admits (32 MiB): ONE attention `tpu_custom_call`,
+    whose dq accumulates in VMEM under a `vmem_limit_bytes` sized from the
+    shapes, and no `tpuft_fa_bwd_dq`."""
+    from torchft_tpu.ops.attention import _dq_row_resident, _fa_bwd_pallas
+
+    assert _dq_row_resident(seq, d_qk)
+    qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
+    text = _compile(
+        lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, d_qk ** -0.5, True),
+        qk, qk, v, v, lse, v,
+    )
+    assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"]
+
+
+def test_long_context_two_pass_backward_compiles_for_v5e(one_chip) -> None:
+    """A dq row over the VMEM budget (one block more than 65,536 positions at
+    128 wide): the dk/dv pass without a row plus the separate dq pass
+    (`_fa_bwd_dq_kernel`)."""
+    from torchft_tpu.ops.attention import _dq_row_resident, _fa_bwd_pallas
+
+    bh, seq, d = 4, 65536 + 512, 128
+    assert not _dq_row_resident(seq, d)
     qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
     text = _compile(
         lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, d ** -0.5, True),
         qkv, qkv, qkv, qkv, lse, qkv,
     )
-    assert _has_kernel(text, "tpuft_fa_bwd_dkdv") and _has_kernel(text, "tpuft_fa_bwd_dq")
+    assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv", "tpuft_fa_bwd_dq"]
 
 
 def test_flagship_gradient_program_compiles_with_kernels_for_v5e(
@@ -218,19 +253,18 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mo
 def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:
     """`tpuft_fa_*` at latent attention's widths and the Moonlight cell's
     shapes: 2 x 16 heads, 8,192 positions, query and key 256 wide (192 padded
-    to a lane multiple), value and output 128; 16 key blocks, so the backward
-    is the two-pass form."""
-    from torchft_tpu.ops.attention import _DQ_PARTIAL_MAX_K, _fa_bwd_pallas, _fa_pallas_call
+    to a lane multiple), value and output 128; 16 key blocks and an 8 MiB dq
+    row, so the backward is the one kernel."""
+    from torchft_tpu.ops.attention import _fa_bwd_pallas, _fa_pallas_call
 
     bh, seq, d_qk, d_v = 32, 8192, 256, 128
-    assert seq // 512 > _DQ_PARTIAL_MAX_K
     qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
     text = _compile(lambda q, k, v_: _fa_pallas_call(q, k, v_, 192 ** -0.5, True), qk, qk, v)
-    assert _has_kernel(text, "tpuft_fa_fwd")
+    assert _attention_calls(text) == ["tpuft_fa_fwd"]
     text = _compile(lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, 192 ** -0.5, True), qk, qk, v, v, lse, v)
-    assert _has_kernel(text, "tpuft_fa_bwd_dkdv") and _has_kernel(text, "tpuft_fa_bwd_dq")
+    assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"]
 
 
 def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
@@ -258,14 +292,19 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     _, step = bench.program("mla_moe_lm").train_step(config, topo.devices[0])
     compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
     text = compiled.as_text()
-    for name in ("tpuft_fa_fwd", "tpuft_fa_bwd_dkdv", "tpuft_fa_bwd_dq", "tpuft_gmm_fwd", "tpuft_gmm_dlhs",
+    for name in ("tpuft_fa_fwd", "tpuft_fa_bwd_dkdv_dq", "tpuft_gmm_fwd", "tpuft_gmm_dlhs",
                  "tpuft_gmm_drhs", "tpuft_ce_lse", "tpuft_ce_dlogits"):
         assert _has_kernel(text, name), f"{name} is not in the compiled program"
-    # `remat_keeps_attention`: one forward attention kernel a layer, not a second in the backward pass
-    calls = [line for line in text.splitlines() if "tpu_custom_call" in line and "tpuft_fa_fwd" in line]
-    assert len(calls) == config["num_hidden_layers"], len(calls)
+    # `remat_keeps_attention`: one forward attention kernel a layer, not a second in the backward
+    # pass, and ONE backward kernel a layer: no `tpuft_fa_bwd_dq` with its recomputed scores
+    calls = _attention_calls(text)
+    layers = config["num_hidden_layers"]
+    assert sorted(calls) == ["tpuft_fa_bwd_dkdv_dq"] * layers + ["tpuft_fa_fwd"] * layers, calls
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     assert n_params == bench.flops("mla_moe_lm").total_params(config)
     assert resident < 14.5e9, f"the step needs {resident} bytes with AdamW's moments; the cut's bound is 14.5 GB"
+    # what the two-kernel backward compiled to (PR 31): dq stays in VMEM until it is bf16, so the
+    # one-pass kernel brings no f32 dq, 268 MB a layer here, into HBM
+    assert resident <= 14.34e9, f"{resident} bytes: the backward's dq has left VMEM in f32"

@@ -13,6 +13,7 @@ two-kind parameter tree through the bucket plan, the disk checkpoint and
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -158,14 +159,20 @@ def test_the_tree_has_leaves_of_two_kinds_and_no_bias() -> None:
 # -- attention at unequal head widths ------------------------------------------
 
 
-@pytest.mark.parametrize("seq,two_pass", [(1024, False), (2560, True)], ids=["merged_backward", "two_pass_backward"])
-def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass) -> None:
+@pytest.mark.parametrize("seq,two_pass", [(1024, False), (2560, False), (1024, True), (2560, True)],
+                         ids=["one_pass_2_blocks", "one_pass_5_blocks", "two_pass_2_blocks", "two_pass_5_blocks"])
+def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass, monkeypatch) -> None:
     """Query and key 256 wide (MLA's 192 padded to a lane multiple with zero
-    columns), value 128: the three kernels against the XLA formulation at the
-    TRUE width of 192, forward and backward."""
+    columns), value 128: the kernels against the XLA formulation at the
+    TRUE width of 192, forward and backward — the one-pass backward that
+    every such row short of 32,768 positions takes, and the two-pass form
+    with the row's VMEM budget cut under it."""
+    from test_ops import ONE_PASS, TWO_PASS, pallas_call_names
     from torchft_tpu.ops import attention as fa
 
-    assert (seq // 512 > fa._DQ_PARTIAL_MAX_K) == two_pass
+    if two_pass:
+        monkeypatch.setattr(fa, "_DQ_ROW_VMEM_BUDGET", seq * 256 * 4 - 1)
+    assert fa._dq_row_resident(seq, 256) != two_pass
     k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seq), 4)
     q, k = (jax.random.normal(kk, (2, seq, 192), jnp.float32) for kk in (k0, k1))
     v, g = (jax.random.normal(kk, (2, seq, 128), jnp.float32) for kk in (k2, k3))
@@ -178,7 +185,9 @@ def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass) ->
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=1e-5, atol=1e-5)
     want = fa._fa_bwd_xla(q, k, v, want_o, want_lse, g, scale, True)
-    got = fa._fa_bwd_pallas(qp, kp, v, got_o, got_lse, g, scale, True, interpret=True)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True)
+    assert pallas_call_names(bwd, qp, kp, v, got_o, got_lse, g) == (TWO_PASS if two_pass else ONE_PASS)
+    got = bwd(qp, kp, v, got_o, got_lse, g)
     assert [a.shape for a in got] == [(2, seq, 256), (2, seq, 256), (2, seq, 128)]
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         a = np.asarray(a)
@@ -186,6 +195,22 @@ def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass) ->
             assert not a[..., 192:].any(), f"{name}: the padding columns carry a gradient"
             a = a[..., :192]
         np.testing.assert_allclose(a, np.asarray(b), rtol=2e-3, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("program", ["dense_lm", "moe_lm", "mla_moe_lm"])
+def test_the_one_pass_backward_is_booked_to_attention_by_its_name(program) -> None:
+    """The benchmark attributes device time to attention by substring and
+    `chip_smoke.has_kernel` by whole word: the one-pass kernel's name has to
+    stay inside the first and is a name of its own to the second, and no
+    `tpuft_fa_bwd_dq` is found in it (its absence from a trace is the
+    evidence that the one-pass form ran)."""
+    import chip_smoke
+
+    op = "%tpuft_fa_bwd_dkdv_dq.7 = (bf16[32,8192,256]) custom-call(...), custom_call_target=\"tpu_custom_call\""
+    assert BENCH.program(program).kernel_names()["attn"](op)
+    assert "tpuft_fa_bwd_dq" not in op
+    assert chip_smoke.has_kernel(op, "tpuft_fa_bwd_dkdv_dq") and "tpuft_fa_bwd_dkdv_dq" in chip_smoke.KERNELS
+    assert not chip_smoke.has_kernel(op, "tpuft_fa_bwd_dkdv") and not chip_smoke.has_kernel(op, "tpuft_fa_bwd_dq")
 
 
 def test_flash_attention_takes_a_value_width_of_its_own() -> None:

@@ -1,6 +1,8 @@
 """Ops correctness: flash attention (reference + pallas-interpret), RMSNorm,
 ring attention vs full attention on the virtual CPU mesh."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -95,41 +97,70 @@ def test_flash_attention_pallas_interpret_matches(causal) -> None:
     np.testing.assert_allclose(np.asarray(lse_pl), np.asarray(lse_ref), rtol=2e-3, atol=2e-3)
 
 
+def pallas_call_names(fn, *args) -> list:
+    """The `name=` of every `pallas_call` in `fn`'s jaxpr, in order."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+
+
+ONE_PASS = ["tpuft_fa_bwd_dkdv_dq"]
+TWO_PASS = ["tpuft_fa_bwd_dkdv", "tpuft_fa_bwd_dq"]
+
+
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("seq", [1024, 4096])
-def test_flash_attention_bwd_pallas_interpret_matches(causal, seq) -> None:
+@pytest.mark.parametrize("over_budget", [False, True], ids=["one_pass", "two_pass_fallback"])
+def test_flash_attention_bwd_pallas_interpret_matches(causal, seq, over_budget, monkeypatch) -> None:
     """The backward pallas kernels vs the XLA flash backward, in interpret
-    mode on CPU — same pattern as the forward kernel test.  seq=1024
-    exercises the merged one-pass kernel (dq via f32 partials); seq=4096
-    has num_k=8 > _DQ_PARTIAL_MAX_K and exercises the two-pass
-    long-context form."""
-    from torchft_tpu.ops.attention import (
-        _DQ_PARTIAL_MAX_K,
-        _block_sizes,
-        _fa_bwd_pallas,
-        _fa_bwd_xla,
-        _fa_reference,
-    )
+    mode on CPU — same pattern as the forward kernel test.  Both lengths
+    (2 and 8 kv blocks) take the one-pass kernel, whose dq accumulates in a
+    VMEM-resident row; with the row's budget cut under them (the only way
+    in: the choice reads shapes alone) they take the two-pass form that a
+    longer row than any here would."""
+    from torchft_tpu.ops import attention as fa
 
-    num_k = seq // _block_sizes(seq, seq)[1]
-    assert (num_k <= _DQ_PARTIAL_MAX_K) == (seq == 1024)
+    bh = 2 if seq == 1024 else 1
+    if over_budget:
+        monkeypatch.setattr(fa, "_DQ_ROW_VMEM_BUDGET", seq * 128 * 4 - 1)
+    assert fa._dq_row_resident(seq, 128) != over_budget
 
     rng = np.random.default_rng(7)
-    bh = 2 if seq == 1024 else 1
     q = jnp.asarray(rng.standard_normal((bh, seq, 128)), dtype=jnp.float32)
     k = jnp.asarray(rng.standard_normal((bh, seq, 128)), dtype=jnp.float32)
     v = jnp.asarray(rng.standard_normal((bh, seq, 128)), dtype=jnp.float32)
     g = jnp.asarray(rng.standard_normal((bh, seq, 128)), dtype=jnp.float32)
     scale = 0.088
-    o, lse = _fa_reference(q, k, v, scale, causal)
+    o, lse = fa._fa_reference(q, k, v, scale, causal)
     # _fa_bwd_xla explicitly, NOT _flash_bwd: on a TPU backend the latter
     # dispatches to the pallas kernels, making the comparison vacuous.
-    d_ref = _fa_bwd_xla(q, k, v, o, lse, g, scale, causal)
-    d_pl = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, interpret=True)
+    d_ref = fa._fa_bwd_xla(q, k, v, o, lse, g, scale, causal)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=causal, interpret=True)
+    assert pallas_call_names(bwd, q, k, v, o, lse, g) == (TWO_PASS if over_budget else ONE_PASS)
+    d_pl = bwd(q, k, v, o, lse, g)
     for a, b, name in zip(d_pl, d_ref, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name
         )
+
+
+def test_flash_attention_bwd_is_one_pallas_call_at_the_cells_shape() -> None:
+    """`[2, 4096, 128]` bf16, the dense cells' sequence: the backward's jaxpr
+    holds exactly one `pallas_call`, whose outputs are dk, dv and a dq of
+    the operand's dtype — no f32 dq, no partials, nothing for XLA to sum."""
+    from torchft_tpu.ops import attention as fa
+
+    qkv = jax.ShapeDtypeStruct((2, 4096, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 4096), jnp.float32)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True)
+    jaxpr = jax.make_jaxpr(bwd)(qkv, qkv, qkv, qkv, lse, qkv)
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert [call.params["name"]] == ONE_PASS
+    assert [(v.aval.shape, v.aval.dtype) for v in call.outvars] == [((2, 4096, 128), jnp.bfloat16)] * 3
+    assert {id(v) for v in jaxpr.jaxpr.outvars} == {id(v) for v in call.outvars}
+    # the row's size is the only thing the choice reads: 65,536 at 128 wide
+    # is the longest resident row, one block more is not
+    assert fa._dq_row_resident(65536, 128) and not fa._dq_row_resident(65536 + 512, 128)
+    assert fa._dq_row_resident(32768, 256) and not fa._dq_row_resident(65536, 256)
 
 
 def test_fused_cross_entropy_matches_and_grads() -> None:

@@ -7,15 +7,23 @@ Design notes (MXU/HBM-minded):
   - the log-sum-exp per query row is saved, and the backward pass recomputes
     scores blockwise from (q, k, lse) — the flash recompute trade: extra
     FLOPs on the MXU instead of an O(S^2) residual in HBM.  On TPU the
-    backward is ONE merged pallas kernel for typical shapes (q axis
-    innermost; dk/dv accumulate in VMEM scratch, dq is emitted as
-    per-kv-block f32 partials in HBM and summed in XLA — the s/p/dp/ds
-    tile work that dominates on the VPU is computed once).  When num_k
-    exceeds _DQ_PARTIAL_MAX_K the partials' (num_k, BH, S, D) transient
-    would dwarf dq itself, so long-context shapes switch to two passes
-    (dk/dv with q innermost, dq with kv innermost, both O(S*D) memory).
-    Off-TPU the same math is expressed in XLA with the scores
-    materialized;
+    backward is ONE pallas kernel, `tpuft_fa_bwd_dkdv_dq` (q axis
+    innermost): dk/dv accumulate in (block_k, d) f32 VMEM scratch, and dq
+    in a third scratch that holds one head's WHOLE (seq_q, d_qk) f32 row
+    while the kv blocks go by — the s/p/dp/ds tile work that these kernels
+    are bound by on the VPU is computed once a tile (5 matrix products
+    where two passes make 7), and the row is cast to the output's dtype
+    inside the kernel, so dq never exists in f32 in HBM.  The row is 2 MiB
+    at seq 4,096 x 128, 8 MiB at 8,192 x 256 (MLA) and 16 MiB at 32,768 x
+    128 of a v5e's 128 MiB of VMEM; `vmem_limit_bytes` is sized from the
+    shapes.  Only a row over `_DQ_ROW_VMEM_BUDGET` takes two passes
+    (`tpuft_fa_bwd_dkdv` with q innermost, then `tpuft_fa_bwd_dq` with kv
+    innermost and the tile work done again): the choice reads the
+    operands' shapes and nothing else.  The one-pass kernel's name
+    contains `tpuft_fa_bwd_dkdv` on purpose: the benchmark books device
+    time to attention by that substring, and a `tpuft_fa_bwd_dq` in a
+    trace says the one-pass form did not engage.  Off-TPU the same math
+    is expressed in XLA with the scores materialized;
   - grid layout (batch*heads, outer_blocks, inner_blocks) with the
     reduction axis innermost: TPU executes the innermost grid dimension
     sequentially, which is what makes the VMEM scratch accumulator legal.
@@ -170,10 +178,24 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     return out, lse_padded[:, :, 0]
 
 
-# Above this many kv blocks the merged backward's per-kv-block dq partials
-# ((num_k, BH, S, D) f32 transient in HBM) cost more than a second
-# recompute pass; long-context shapes switch to the two-kernel form.
-_DQ_PARTIAL_MAX_K = 4
+# The one-pass backward keeps one head's whole dq row, (seq_q, d_qk) f32, in a
+# VMEM scratch.  A row above this many bytes (65,536 positions at 128 wide is
+# the longest that fits) takes the two-pass form instead; the choice reads
+# the operands' shapes and nothing else.  A v5e has 128 MiB of VMEM: the row,
+# its double-buffered bf16 output block (as much again) and the tiles are
+# 80 MiB at the budget, compiled and run at [2, 65536, 128].
+_DQ_ROW_VMEM_BUDGET = 32 * 2**20
+# What the kernels' tiles take beside a resident row: the double-buffered
+# operand blocks, the dk/dv accumulators and the (block_q, block_k) f32
+# temporaries — the compiler's default scoped limit, under which the
+# kernels without a row run.
+_TILE_VMEM_BYTES = 16 * 2**20
+
+
+def _dq_row_resident(seq_q: int, d: int) -> bool:
+    """Whether the backward is the one-pass kernel: the f32 dq row of one
+    head fits the VMEM budget."""
+    return seq_q * d * 4 <= _DQ_ROW_VMEM_BUDGET
 
 
 def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
@@ -205,23 +227,25 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
 def _fa_bwd_dkdv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *rest,
     scale: float, causal: bool, block_q: int, block_k: int, num_q: int,
-    emit_dq: bool,
+    num_k: int, with_dq: bool,
 ):
     """Flash backward with the q axis innermost: dk/dv accumulate in VMEM
-    scratch across the sequential inner q dimension.  With emit_dq (the
-    merged one-pass form for typical shapes) the dq contribution of this
-    kv block is additionally emitted to a per-kv-block f32 partial (one
-    visit per output block, summed in XLA) — the s/p/dp/ds tile work that
-    dominates on the VPU is then computed once instead of twice."""
+    scratch across the sequential inner q dimension.  With ``with_dq`` (the
+    one-pass form) a third scratch holds the head's whole dq row in f32:
+    the tile (ki, qi) adds ``ds @ k`` into its rows qi, and the last kv
+    block's steps cast the row, block by block, into the dq output, whose
+    one block a head stays in VMEM until the head is done — the s/p/dp/ds
+    tile work that dominates on the VPU is computed once, and dq never
+    exists in f32 outside VMEM."""
     from jax.experimental import pallas as pl
-
-    if emit_dq:
-        dqp_ref, dk_scr, dv_scr = rest
-    else:
-        dk_scr, dv_scr = rest
 
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    if with_dq:
+        dq_ref, dk_scr, dv_scr, dq_scr = rest
+        q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+    else:
+        dk_scr, dv_scr = rest
 
     @pl.when(qi == 0)
     def _init():
@@ -229,7 +253,7 @@ def _fa_bwd_dkdv_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     # Causal: a q block strictly above this kv block's diagonal contributes
-    # nothing — but its dq partial (if any) must still be zeroed.
+    # nothing.
     run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
 
     @pl.when(run)
@@ -247,29 +271,42 @@ def _fa_bwd_dkdv_kernel(
             ds, q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                           # ds^T @ q: [block_k, d]
-        if emit_dq:
-            dqp_ref[0, 0] = jax.lax.dot(
+        if with_dq:
+            dq_tile = jax.lax.dot(
                 ds, k_ref[0], preferred_element_type=jnp.float32
-            ).astype(dqp_ref.dtype)                 # ds @ k: [block_q, d]
+            )                                       # ds @ k: [block_q, d]
 
-    if emit_dq and causal:
-        @pl.when(jnp.logical_not(run))
-        def _zero():
-            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+            # Every q block runs against kv block 0, causal or not, so the
+            # first visit assigns and the row is never zeroed.
+            @pl.when(ki == 0)
+            def _first():
+                dq_scr[q_rows, :] = dq_tile
+
+            @pl.when(ki != 0)
+            def _add():
+                dq_scr[q_rows, :] += dq_tile
 
     @pl.when(qi == num_q - 1)
     def _emit():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        # Rows qi are complete once the last kv block has had its turn at
+        # them (a turn the causal rule may have skipped).
+        @pl.when(ki == num_k - 1)
+        def _emit_dq():
+            dq_ref[0, q_rows, :] = dq_scr[q_rows, :].astype(dq_ref.dtype)
+
 
 def _fa_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
     *, scale: float, causal: bool, block_q: int, block_k: int, num_k: int,
 ):
-    """dq-only pass for the long-context form, kv axis innermost: dq
-    accumulates in f32 VMEM scratch, so memory stays O(S*D) regardless of
-    num_k (at the price of recomputing p/ds once more)."""
+    """dq-only second pass, kv axis innermost, for a dq row too long to
+    stay in VMEM: dq accumulates one (block_q, d) f32 block at a time, so
+    memory stays O(block) whatever the length (at the price of recomputing
+    p/ds once more)."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -299,7 +336,9 @@ def _fa_bwd_dq_kernel(
 def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
                    interpret: bool = False):
     """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
-    [BH, S] f32."""
+    [BH, S] f32.  One kernel (`tpuft_fa_bwd_dkdv_dq`) where one head's f32
+    dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
+    `tpuft_fa_bwd_dq`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -319,52 +358,53 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     k_spec_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     v_spec_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0))
     row_spec_ji = pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0))
-    in_specs_ji = [q_spec_ji, k_spec_ji, v_spec_ji, do_spec_ji,
-                   row_spec_ji, row_spec_ji]
-    dkdv_scratch = [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d_v), jnp.float32),
-    ]
-    merged = num_k <= _DQ_PARTIAL_MAX_K
+    one_pass = _dq_row_resident(seq_q, d)
     out_shape = [
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     ]
     out_specs = [k_spec_ji, v_spec_ji]
-    if merged:
-        # dq as f32 per-kv-block partials: the cross-block sum loses no
-        # precision vs the f32 XLA backward this replaced.
-        out_shape.append(
-            jax.ShapeDtypeStruct(
-                (num_k, bh, seq_q, d), q.dtype if num_k == 1 else jnp.float32
-            )
-        )
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q, d), lambda b, j, i: (j, b, i, 0))
+    scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d_v), jnp.float32),
+    ]
+    vmem_limit = None
+    if one_pass:
+        # dq's block is a head's whole row and ignores both inner axes: it
+        # leaves VMEM once, when the head is done.
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        out_specs.append(pl.BlockSpec((1, seq_q, d), lambda b, j, i: (b, 0, 0)))
+        scratch.append(pltpu.VMEM((seq_q, d), jnp.float32))
+        vmem_limit = (
+            seq_q * d * (4 + 2 * q.dtype.itemsize) + _TILE_VMEM_BYTES
         )
     outs = pl.pallas_call(
         functools.partial(
             _fa_bwd_dkdv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_q=num_q, emit_dq=merged,
+            block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
+            with_dq=one_pass,
         ),
         out_shape=tuple(out_shape),
         grid=(bh, num_k, num_q),
-        in_specs=in_specs_ji,
+        in_specs=[q_spec_ji, k_spec_ji, v_spec_ji, do_spec_ji,
+                  row_spec_ji, row_spec_ji],
         out_specs=tuple(out_specs),
-        scratch_shapes=dkdv_scratch,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
+        ),
         interpret=interpret,
-        name="tpuft_fa_bwd_dkdv",
+        # The benchmark books device time to attention by these names'
+        # substrings: the one-pass name has to contain `tpuft_fa_bwd_dkdv`.
+        name="tpuft_fa_bwd_dkdv_dq" if one_pass else "tpuft_fa_bwd_dkdv",
     )(q, k, v, g, lse, delta)
-    if merged:
-        dk, dv, dq_part = outs
-        if num_k == 1:
-            dq = dq_part[0]
-        else:
-            dq = jnp.sum(dq_part, axis=0).astype(q.dtype)
+    if one_pass:
+        dk, dv, dq = outs
         return dq, dk, dv
     dk, dv = outs
 
-    # Long-context second pass: dq with the kv axis innermost.
+    # Second pass for a row over the budget: dq with the kv axis innermost.
     q_spec_ij = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     do_spec_ij = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
     k_spec_ij = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
