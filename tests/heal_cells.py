@@ -346,7 +346,7 @@ def _ec_manager_worker_main(args) -> None:
     )
     commits = failed = 0
     healed_step = None
-    while time.time() < args.end_ts:
+    while time.time() < args.end_ts and not os.path.exists(args.stop):
         manager.start_quorum()
         fut = manager.allreduce(np.ones(64, np.float32))
         fut.result()
@@ -372,12 +372,37 @@ def _ec_manager_worker_main(args) -> None:
     manager.shutdown()
 
 
+def _stream_count(path: str, event: str, **fields: Any) -> int:
+    """How many records of one kind, with these fields, a worker's metrics
+    stream holds so far (it is read while the worker writes it)."""
+    if not os.path.exists(path):
+        return 0
+    n = 0
+    with open(path, "rb") as f:
+        for line in f:
+            try:
+                ev = json.loads(line)
+            except ValueError:
+                continue
+            n += ev.get("event") == event and all(ev.get(key) == v for key, v in fields.items())
+    return n
+
+
+def _wait_for(done, until_ts: float, poll_s: float = 0.1) -> bool:
+    """Poll `done()` until it holds or the wall clock passes `until_ts`."""
+    while not done():
+        if time.time() > until_ts:
+            return False
+        time.sleep(poll_s)
+    return True
+
+
 def bench_ec_manager_wave(
     groups: int = 4,
     k: int = 2,
     m: int = 1,
-    run_s: float = 22.0,
-    kill_at_s: float = 8.0,
+    run_s: float = 90.0,
+    kill_at_s: float = 2.0,
     respawn_after_s: float = 1.5,
     step_s: float = 0.05,
     workdir: Optional[str] = None,
@@ -388,7 +413,15 @@ def bench_ec_manager_wave(
     serving window ever opens on a survivor).  One group is SIGKILLed and
     respawned; its heal must complete via erasure reconstruction from the
     surviving shard holders while every survivor keeps committing with
-    ZERO failed commits."""
+    ZERO failed commits.
+
+    The wave is paced by what the groups have done, not by the clock: the
+    victim is killed once it has committed steps and every survivor has
+    encoded a generation (a kill before the first commit leaves nothing to
+    heal, which is how a loaded machine failed a wave paced by time), and the
+    wave ends once the respawned group has committed after its heal.
+    `kill_at_s` is the earliest kill and `run_s` the longest the wave may
+    take; an idle machine needs about 12 s."""
     import tempfile
 
     from torchft_tpu._native import LighthouseServer
@@ -401,6 +434,7 @@ def bench_ec_manager_wave(
         heartbeat_timeout_ms=1500,
     )
     end_ts = time.time() + run_s
+    stop = os.path.join(workdir, "stop")
     procs: Dict[str, subprocess.Popen] = {}
     metrics_paths: Dict[str, str] = {}
 
@@ -411,7 +445,7 @@ def bench_ec_manager_wave(
         metrics_paths[f"{replica}_{incarnation}"] = metrics
         procs[f"{replica}_{incarnation}"] = _spawn_worker(
             {"wave_role": "manager", "out": out, "replica": replica,
-             "end_ts": end_ts, "step_s": step_s},
+             "end_ts": end_ts, "stop": stop, "step_s": step_s},
             env={
                 "TPUFT_LIGHTHOUSE": lighthouse.address(),
                 "TPUFT_METRICS_PATH": metrics,
@@ -428,10 +462,21 @@ def bench_ec_manager_wave(
             spawn(i, 0)
         time.sleep(kill_at_s)
         victim = f"ecw{groups - 1}"
+        _wait_for(
+            lambda: _stream_count(metrics_paths[f"{victim}_0"], "commit", committed=True) >= 3
+            and all(_stream_count(metrics_paths[f"ecw{i}_0"], "ec_push") for i in range(groups - 1)),
+            end_ts - run_s / 2,
+        )
         procs[f"{victim}_0"].send_signal(signal.SIGKILL)
         procs[f"{victim}_0"].wait(timeout=30)
         time.sleep(respawn_after_s)
         spawn(groups - 1, 1)
+        _wait_for(
+            lambda: _stream_count(metrics_paths[f"{victim}_1"], "commit", committed=True) >= 3,
+            end_ts,
+        )
+        with open(stop, "w"):
+            pass
         deadline = end_ts + 60
         for key, p in procs.items():
             timeout = max(1.0, deadline - time.time())
@@ -508,10 +553,7 @@ def run_ec_quick(gb: float = 0.008, buffers: int = 8, k: int = 2, m: int = 1) ->
             bench_ec_encode(state, k, m),
             bench_ec_reconstruct(state, k, m),
             bench_ec_wave(gb, buffers, k, m, n_donors=2),
-            bench_ec_manager_wave(
-                groups=3, k=k, m=m, run_s=14.0, kill_at_s=5.0, step_s=0.05,
-                survivor_failed_budget=1,
-            ),
+            bench_ec_manager_wave(groups=3, k=k, m=m, step_s=0.05, survivor_failed_budget=1),
         ],
     }
 

@@ -308,3 +308,84 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     # what the two-kernel backward compiled to (PR 31): dq stays in VMEM until it is bf16, so the
     # one-pass kernel brings no f32 dq, 268 MB a layer here, into HBM
     assert resident <= 14.34e9, f"{resident} bytes: the backward's dq has left VMEM in f32"
+
+
+def _kernel_calls(text: str, prefix: str) -> list:
+    """As `_attention_calls`, for the kernels whose names start with `prefix`."""
+    import re
+
+    return [m.group(0) for line in text.splitlines() if "tpu_custom_call" in line and "custom-call(" in line
+            for m in [re.search(prefix + r"[a-z_]*[a-z]", line)] if m]
+
+
+def test_sparse_attention_kernels_compile_for_v5e(one_chip) -> None:
+    """The five `tpuft_dsa_*` kernels at the Keye cell's shapes: one sequence
+    of 32,768 positions, 32 query heads on 4 KV heads of 128, 16 index heads of
+    64, topk 2,048 — the selection's [256, 32,768] int32 keys (32 MiB) and the
+    index loss's resident key-gradient row in VMEM, the mask as the packed lower
+    triangle of int8 tiles, the one-pass backward with a 16 MiB dq row."""
+    from torchft_tpu.ops import sparse_attention as sa
+
+    B, H, KV, S, D, J, Di = 1, 32, 4, 32768, 128, 16, 64
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k, a, bt = sds((B, H, S, D)), sds((B, KV, S, D)), sds((B, J, S, Di)), sds((B, Di, S))
+    w, row, lse = sds((B, S, J), jnp.float32), sds((B, S, 1), jnp.int32), sds((B, H, S), jnp.float32)
+    z, mask = sds((B, S, 1), jnp.float32), sds((B, 64 * 65 // 2, 512, 512), jnp.int8)
+    scale = D ** -0.5
+    for name, fn, args in (
+        ("tpuft_dsa_select", lambda a, bt, w: sa._select_pallas(a, bt, w, 2048), (a, bt, w)),
+        ("tpuft_dsa_mask", sa._mask_pallas, (a, bt, w, row, row)),
+        ("tpuft_dsa_attn_fwd", lambda q, k, v, m: sa._masked_flash_fwd(q, k, v, m, scale), (q, k, k, mask)),
+        ("tpuft_dsa_index_loss", lambda *x: sa._index_loss_pallas(*x, scale), (q, k, lse, a, bt, w, z, mask)),
+        ("tpuft_dsa_attn_bwd_dkdv_dq", lambda q, k, v, o, l, g, m: sa._masked_flash_bwd(q, k, v, o, l, g, m, scale),
+         (q, k, k, q, lse, q, mask)),
+    ):
+        assert _kernel_calls(_compile(fn, *args), "tpuft_dsa_") == [name]
+
+
+def test_keye_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `keye-vl-2.0-30b-a3b` configuration as
+    `benchmark/programs/dsa_moe_lm.py` hands it to `TrainStep`: the whole
+    gradient program at the published widths and the cell's 1 x 32,768 tokens
+    — the indexer, the exact selection and attention over it through
+    `tpuft_dsa_*`, the 16 held experts of each layer through `tpuft_gmm_*`,
+    the sliced vocabulary (18,992 columns, padded for the kernels) through
+    `tpuft_ce_*` — with AdamW's moments beside it on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("keye-vl-2.0-30b-a3b"), bench.traffic("steady-1g-32k")
+    assert (traffic["sequences_per_step"], traffic["seq_len"]) == (1, 32768)
+    shapes = jax.eval_shape(lambda: bench.reference("dsa_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    _, step = bench.program("dsa_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    # `remat_keeps_attention`, extended: a layer selects, attends forward and takes the index loss ONCE;
+    # the backward pass rebuilds the mask from the kept thresholds (the second `tpuft_dsa_mask`) and runs
+    # the one-pass backward kernel; no dense `tpuft_fa_*` kernel is left in the program
+    layers = config["num_hidden_layers"]
+    per_layer = ["tpuft_dsa_attn_bwd_dkdv_dq", "tpuft_dsa_attn_fwd", "tpuft_dsa_index_loss", "tpuft_dsa_mask",
+                 "tpuft_dsa_mask", "tpuft_dsa_select"]
+    assert sorted(_kernel_calls(text, "tpuft_dsa_")) == sorted(per_layer * layers)
+    assert _attention_calls(text) == []
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("dsa_moe_lm").total_params(config)
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # four layers, the floor: 14.82 GB by this count (PR 33); a fifth layer reads 17.29 GB
+    assert resident < 14.9e9, f"the step needs {resident} bytes with AdamW's moments"

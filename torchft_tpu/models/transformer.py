@@ -112,6 +112,26 @@ class TransformerConfig:
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
+    # A head's width where it is not d_model / n_heads (0: it is).
+    head_dim: int = 0
+    # RMSNorm over each head's columns of the projected query and key, one
+    # weight of d_head for all query heads and one for all key heads, before
+    # RoPE (Qwen3's attention).
+    qk_norm_per_head: bool = False
+    # Learned sparse attention (DeepSeek-V3.2's lightning indexer;
+    # ops/sparse_attention.py): dsa_index_heads > 0 gives every layer an
+    # indexer — wi_q: embed -> dsa_index_heads * dsa_index_dim, wi_k: embed
+    # -> ONE key head of dsa_index_dim under a LayerNorm (wi_k_norm,
+    # wi_k_bias), wi_w: embed -> a weight per index head — that scores every
+    # visible (query, key) pair; each query attends to its dsa_topk best
+    # keys alone.  The indexer reads a DETACHED input and learns from a loss
+    # of its own (the KL of its softmax over the selection from the heads'
+    # summed attention probabilities, times dsa_loss_coef, summed over the
+    # layers); the language-model loss sees the selection as a constant.
+    dsa_index_heads: int = 0
+    dsa_index_dim: int = 64
+    dsa_topk: int = 2048
+    dsa_loss_coef: float = 1.0
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -126,6 +146,12 @@ class TransformerConfig:
             assert self.attention == "flash" and not self.qk_norm, (
                 "latent attention runs the flash backend, without a QK-norm"
             )
+        if self.dsa_index_heads:
+            assert self.attention == "flash" and not self.mla_kv_rank, (
+                "the indexer selects keys for the flash backend's plain heads"
+            )
+            assert not self.moe_dense_layers, "the leading dense layers carry no indexer statistics"
+        assert not (self.qk_norm and self.qk_norm_per_head), "one QK-norm or the other"
         if self.moe_dense_layers:
             assert self.moe_experts > 0 and 0 < self.moe_dense_layers < self.n_layers and self.dense_d_ff > 0
         if self.moe_held is not None:
@@ -145,6 +171,8 @@ class TransformerConfig:
 
     @property
     def d_head(self) -> int:
+        if self.head_dim:
+            return self.head_dim
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -167,6 +195,12 @@ def _layer_axes(cfg: TransformerConfig, sparse: bool) -> Dict[str, Any]:
         layer.update({"wk": ("layers", "embed", "kv_heads"), "wv": ("layers", "embed", "kv_heads")})
     if cfg.qk_norm:
         layer.update({"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv_heads")})
+    if cfg.qk_norm_per_head:
+        layer.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
+    if cfg.dsa_index_heads:
+        layer.update({"wi_q": ("layers", "embed", None), "wi_k": ("layers", "embed", None),
+                      "wi_k_norm": ("layers", None), "wi_k_bias": ("layers", None),
+                      "wi_w": ("layers", "embed", None)})
     if sparse:
         layer.update(
             {
@@ -232,6 +266,20 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -
         )
         if cfg.qk_norm:
             layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
+        if cfg.qk_norm_per_head:
+            layers.update({"q_norm": jnp.ones((L, Dh), pd), "k_norm": jnp.ones((L, Dh), pd)})
+    if cfg.dsa_index_heads:
+        J, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
+        kq, kk, kw = jax.random.split(jax.random.fold_in(key, 2), 3)
+        layers.update(
+            {
+                "wi_q": norm_init(kq, (L, E, J * Di), E),
+                "wi_k": norm_init(kk, (L, E, Di), E),
+                "wi_k_norm": jnp.ones((L, Di), pd),
+                "wi_k_bias": jnp.zeros((L, Di), pd),
+                "wi_w": norm_init(kw, (L, E, J), E),
+            }
+        )
     if sparse:
         F, X, held = cfg.d_ff, cfg.moe_experts, cfg.n_held_experts
         kr, kg, ku, kd = jax.random.split(ks[7], 4)
@@ -361,6 +409,39 @@ def _mla_qkv(cfg: TransformerConfig, h, w, positions):
     return q, k, kv[..., Dn:]
 
 
+def _index_operands(cfg: TransformerConfig, h, w, positions):
+    """The indexer's operands from the normed input h [B, S, E], which it
+    reads DETACHED: index queries [B, J, S, Di] and the one index key head
+    [B, S, Di], both after RoPE, and the per-query head weights [B, S, J] f32
+    with the two scale factors (J**-0.5, Di**-0.5) in them."""
+    B, S, _ = h.shape
+    J, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
+    hd = jax.lax.stop_gradient(h)
+    a = _rope((hd @ w["wi_q"].astype(cfg.dtype)).reshape(B, S, J, Di), positions, cfg.rope_theta)
+    b = _layer_norm(hd @ w["wi_k"].astype(cfg.dtype), w["wi_k_norm"], w["wi_k_bias"], cfg.rms_eps)
+    b = _rope(b[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    weights = (hd @ w["wi_w"].astype(cfg.dtype)).astype(jnp.float32) * (J ** -0.5 * Di ** -0.5)
+    return a.transpose(0, 2, 1, 3), b, weights
+
+
+def _sparse_attention(cfg: TransformerConfig, mesh, h, w, positions, q, k, v):
+    """Attention over the keys the layer's indexer selects; q/k/v head-major.
+    Returns (attention [B, H, S, Dh], {"dsa_index_loss", "dsa_selected"})."""
+    from torchft_tpu.ops.sparse_attention import sparse_attention
+
+    a, b, weights = _index_operands(cfg, h, w, positions)
+    attn, index_loss, selected = sparse_attention(q, k, v, a, b, weights, topk=cfg.dsa_topk, mesh=mesh)
+    return attn, {"dsa_index_loss": index_loss, "dsa_selected": selected.astype(jnp.uint32)}
+
+
+def _layer_norm(x, w, b, eps):
+    """LayerNorm over the last axis, f32 statistics."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    return ((xf - mean) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
 def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, sparse=None, router_bias=None):
     """One decoder block; x: [B, S, E].  `sparse`: whether its feed-forward
     is the mixture of experts (default: the model has one); `router_bias`
@@ -383,13 +464,19 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         if cfg.qk_norm:
             k = rms_norm(k, w["k_norm"], cfg.rms_eps)
         k = k.reshape(B, S, KV, Dh)
+        if cfg.qk_norm_per_head:
+            q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
         v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
     q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
     k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
     v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
-    attn = _attention(cfg, mesh, q, k, v)            # [B, H, S, Dv]
+    dsa = None
+    if cfg.dsa_index_heads:
+        attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
+    else:
+        attn = _attention(cfg, mesh, q, k, v)        # [B, H, S, Dv]
     attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * attn.shape[-1])
     x = x + (attn @ w["wo"].astype(cfg.dtype))
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
@@ -421,7 +508,9 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
         up = h @ w["w_up"].astype(cfg.dtype)
         x = x + ((gate * up) @ w["w_down"].astype(cfg.dtype))
-        aux = jnp.zeros((), jnp.float32)
+        aux = {} if dsa is not None else jnp.zeros((), jnp.float32)
+    if dsa is not None:
+        aux = dict(aux, **dsa)
     return constrain(x, ("batch", "seq", "embed"), mesh, rules), aux
 
 
@@ -484,6 +573,7 @@ def _decoder(
 
     if cfg.remat:
         body = _remat(cfg, body)
+    stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
     if cfg.scan_unroll > 1 and cfg.scan_unroll >= cfg.n_layers:
         # Full unroll as a STATIC Python loop rather than lax.scan(unroll=L):
         # scan's internal layer slicing survives as dynamic-update-slice
@@ -498,17 +588,17 @@ def _decoder(
         for i in range(cfg.n_sparse_layers):
             w_i = jax.tree.map(lambda a, i=i: a[i], stacked)
             x, aux = body(x, w_i)
-            if cfg.moe_experts > 0:
+            if stats:
                 aux_layers.append(aux)
             else:
                 aux_total = aux_total + aux
-        if cfg.moe_experts > 0:
+        if stats:
             return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
         return x, aux_total
     x, aux_layers = jax.lax.scan(
         body, x, stacked, unroll=cfg.scan_unroll
     )
-    if cfg.moe_experts > 0:
+    if stats:
         return x, _over_layers(aux_layers)
     return x, jnp.sum(aux_layers)
 
@@ -517,8 +607,10 @@ def _remat(cfg: TransformerConfig, body):
     if not cfg.remat_keeps_attention:
         return jax.checkpoint(body)
     from torchft_tpu.ops.attention import SAVED_NAMES
+    from torchft_tpu.ops.sparse_attention import SAVED_NAMES as DSA_SAVED_NAMES
 
-    return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+    names = SAVED_NAMES + (DSA_SAVED_NAMES if cfg.dsa_index_heads else ())
+    return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def _over_layers(stats: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -541,8 +633,8 @@ def forward_with_aux(
     """tokens: [B, S] int32 -> (logits [B, S, vocab] f32, aux scalar f32 —
     the summed MoE load-balance loss; zero for dense models)."""
     x, aux = _decoder(params, tokens, cfg, mesh, rules, router_bias)
-    if cfg.moe_experts > 0:
-        aux = aux["balance"]
+    if isinstance(aux, dict):
+        aux = aux.get("balance", jnp.zeros((), jnp.float32))
     return head(params, x, cfg, mesh, rules), aux
 
 
@@ -605,15 +697,18 @@ def lm_head_loss(
     from torchft_tpu.ops.cross_entropy import (
         fused_ce_applicable,
         fused_linear_cross_entropy,
+        fused_linear_cross_entropy_padded,
+        padded_vocab,
     )
 
     B, S, E = x.shape
-    if fused_ce_applicable(B * S, E, cfg.vocab_size, mesh):
+    if fused_ce_applicable(B * S, E, padded_vocab(cfg.vocab_size), mesh):
         h = rms_norm(x, params["final_norm"], cfg.rms_eps)
         w = params["lm_head"].astype(cfg.dtype)
-        return fused_linear_cross_entropy(
-            h.reshape(B * S, E), w, targets.reshape(B * S)
-        )
+        # A vocabulary slice that no block divides runs the same kernels over
+        # zero-padded columns whose logits count as -inf.
+        fused = fused_linear_cross_entropy if cfg.vocab_size % 128 == 0 else fused_linear_cross_entropy_padded
+        return fused(h.reshape(B * S, E), w, targets.reshape(B * S))
     return token_cross_entropy(head(params, x, cfg, mesh, rules), targets)
 
 
@@ -653,13 +748,22 @@ def loss_and_counters(
     n_experts] is the sigmoid router's choice bias: a constant, no leaf of
     ``params``, so neither the gradient nor the optimizer sees it."""
     x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
-    ce = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
+    loss = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
+    counters = {}
+    if cfg.dsa_index_heads:
+        # The indexer's own loss: no weight outside the indexer has a gradient from it.
+        loss = loss + cfg.dsa_loss_coef * aux["dsa_index_loss"]
+        B, S = batch["tokens"].shape
+        visible = cfg.n_layers * B * (S * (S + 1) // 2)
+        assert visible < 2 ** 32, "the pair counters are uint32"
+        counters.update(dsa_pairs_selected=aux["dsa_selected"], dsa_pairs_visible=jnp.uint32(visible),
+                        dsa_index_loss=aux["dsa_index_loss"])
     if cfg.moe_experts == 0:
-        return ce, {}
-    loss = ce + cfg.moe_aux_coef * aux["balance"]
+        return loss, counters
+    loss = loss + cfg.moe_aux_coef * aux["balance"]
     if cfg.moe_z_coef:
         loss = loss + cfg.moe_z_coef * aux["z"]
-    counters = {"moe_tokens_per_expert": aux["tokens_per_expert"], "moe_dropped": aux["dropped"]}
+    counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
     if cfg.moe_held is not None:
         counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
     return loss, counters

@@ -78,10 +78,17 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
 
 
 def _fa_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, num_k: int,
+    q_ref, k_ref, v_ref, *rest,
+    scale: float, causal: bool, block_q: int, block_k: int, num_k: int, masked: bool = False,
 ):
+    """``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
+    per-pair mask (ops/sparse_attention.py), decides what a query sees in
+    place of the causal triangle; ``causal`` still says which tiles are
+    empty."""
     from jax.experimental import pallas as pl
+
+    mask_ref = rest[0] if masked else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[1:] if masked else rest
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -106,7 +113,9 @@ def _fa_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [block_q, block_k] f32
-        if causal:
+        if masked:
+            s = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, s, _NEG_INF)
+        elif causal:
             # Unconditional mask: branching per block via lax.cond measured
             # ~3 ms/step SLOWER than these VPU passes (Mosaic conditional
             # overhead exceeds the saved work at flagship shapes).
@@ -138,7 +147,19 @@ def _fa_kernel(
         ).astype(lse_ref.dtype)
 
 
-def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False):
+def _tri(i, j):
+    """Where tile (i, j), j <= i, of a lower triangle lies when the tiles are
+    stored row by row; a j beyond the diagonal is held to it."""
+    return i * (i + 1) // 2 + jnp.minimum(j, i)
+
+
+def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
+                    kv_group: int = 1):
+    """``mask``: int8 [batch, tiles, block_q, block_k], the (block_q,
+    block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`),
+    shared by a batch entry's heads (bh = batch * heads); None for the
+    causal triangle.  ``kv_group``: k and v hold one head for every
+    ``kv_group`` of q's (grouped queries read their head in place)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -149,8 +170,13 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     grid = (bh, seq_q // block_q, num_k)
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_k=num_k,
+        block_q=block_q, block_k=block_k, num_k=num_k, masked=mask is not None,
     )
+    operands, mask_specs = (q, k, v), []
+    if mask is not None:
+        heads = bh // mask.shape[0]
+        operands += (mask,)
+        mask_specs = [pl.BlockSpec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0))]
     out, lse_padded = pl.pallas_call(
         kernel,
         out_shape=(
@@ -160,9 +186,9 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
-        ],
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b // kv_group, j, 0)),
+        ] + mask_specs,
         out_specs=(
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
@@ -173,8 +199,8 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
             pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
-        name="tpuft_fa_fwd",
-    )(q, k, v)
+        name="tpuft_fa_fwd" if mask is None else "tpuft_dsa_attn_fwd",
+    )(*operands)
     return out, lse_padded[:, :, 0]
 
 
@@ -199,7 +225,7 @@ def _dq_row_resident(seq_q: int, d: int) -> bool:
 
 
 def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-               *, scale, causal, block_q, block_k):
+               *, scale, causal, block_q, block_k, mask_ref=None):
     """Shared flash-backward block body: recomputes p and ds for the
     (q-block qi, kv-block ki) tile.  Matmul operands stay in the input
     dtype (bf16 on the model path = full MXU rate); probabilities and
@@ -212,7 +238,9 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                   # [block_q, block_k] f32
     p = jnp.exp(s - row_stat_col(lse_ref, qi, block_q))
-    if causal:
+    if mask_ref is not None:
+        p = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, p, 0.0)
+    elif causal:
         rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         p = jnp.where(rows >= cols, p, 0.0)
@@ -225,9 +253,9 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
 
 
 def _fa_bwd_dkdv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *rest,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     scale: float, causal: bool, block_q: int, block_k: int, num_q: int,
-    num_k: int, with_dq: bool,
+    num_k: int, with_dq: bool, masked: bool = False,
 ):
     """Flash backward with the q axis innermost: dk/dv accumulate in VMEM
     scratch across the sequential inner q dimension.  With ``with_dq`` (the
@@ -241,6 +269,8 @@ def _fa_bwd_dkdv_kernel(
 
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    mask_ref = rest[0] if masked else None  # as `_fa_kernel`'s
+    dk_ref, dv_ref, *rest = rest[1:] if masked else rest
     if with_dq:
         dq_ref, dk_scr, dv_scr, dq_scr = rest
         q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
@@ -260,7 +290,7 @@ def _fa_bwd_dkdv_kernel(
     def _step():
         p, ds = _bwd_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k, mask_ref=mask_ref,
         )
         do = do_ref[0]
         dv_scr[...] += jax.lax.dot_general(
@@ -334,11 +364,14 @@ def _fa_bwd_dq_kernel(
 
 
 def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
-                   interpret: bool = False):
+                   interpret: bool = False, mask=None, kv_group: int = 1):
     """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
     [BH, S] f32.  One kernel (`tpuft_fa_bwd_dkdv_dq`) where one head's f32
     dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
-    `tpuft_fa_bwd_dq`."""
+    `tpuft_fa_bwd_dq`.  ``mask`` as `_fa_pallas_call`'s: the masked backward
+    is the one-pass kernel only, under the name `tpuft_dsa_attn_bwd_dkdv_dq`;
+    with ``kv_group`` k and v are read in place and dk, dv come out a query
+    head each, for the caller to sum over a group."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -357,11 +390,21 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     do_spec_ji = pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0))
     k_spec_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     v_spec_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0))
+    k_in_ji, v_in_ji = k_spec_ji, v_spec_ji
+    if kv_group != 1:
+        k_in_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b // kv_group, j, 0))
+        v_in_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b // kv_group, j, 0))
     row_spec_ji = pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0))
     one_pass = _dq_row_resident(seq_q, d)
+    operands, mask_specs = (q, k, v, g, lse, delta), []
+    if mask is not None:
+        assert one_pass, "the masked backward keeps the dq row in VMEM"
+        heads = bh // mask.shape[0]
+        operands += (mask,)
+        mask_specs = [pl.BlockSpec((1, 1, block_q, block_k), lambda b, j, i: (b // heads, _tri(i, j), 0, 0))]
     out_shape = [
-        jax.ShapeDtypeStruct(k.shape, k.dtype),
-        jax.ShapeDtypeStruct(v.shape, v.dtype),
+        jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype),
+        jax.ShapeDtypeStruct((bh,) + v.shape[1:], v.dtype),
     ]
     out_specs = [k_spec_ji, v_spec_ji]
     scratch = [
@@ -382,12 +425,12 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         functools.partial(
             _fa_bwd_dkdv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-            with_dq=one_pass,
+            with_dq=one_pass, masked=mask is not None,
         ),
         out_shape=tuple(out_shape),
         grid=(bh, num_k, num_q),
-        in_specs=[q_spec_ji, k_spec_ji, v_spec_ji, do_spec_ji,
-                  row_spec_ji, row_spec_ji],
+        in_specs=[q_spec_ji, k_in_ji, v_in_ji, do_spec_ji,
+                  row_spec_ji, row_spec_ji] + mask_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
@@ -397,8 +440,9 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         interpret=interpret,
         # The benchmark books device time to attention by these names'
         # substrings: the one-pass name has to contain `tpuft_fa_bwd_dkdv`.
-        name="tpuft_fa_bwd_dkdv_dq" if one_pass else "tpuft_fa_bwd_dkdv",
-    )(q, k, v, g, lse, delta)
+        name=("tpuft_dsa_attn_bwd_dkdv_dq" if mask is not None
+              else "tpuft_fa_bwd_dkdv_dq" if one_pass else "tpuft_fa_bwd_dkdv"),
+    )(*operands)
     if one_pass:
         dk, dv, dq = outs
         return dq, dk, dv

@@ -90,8 +90,10 @@ def fused_ce_applicable(n: int, e: int, v: int, mesh=None) -> bool:
 
 
 def _ce_lse_kernel(
-    x_ref, w_ref, lse_ref, m_scr, l_scr, *, num_v: int,
+    x_ref, w_ref, lse_ref, m_scr, l_scr, *, num_v: int, valid_v: Optional[int] = None,
 ):
+    """``valid_v``: the head's real width where its columns were padded to
+    a tileable one (`padded_vocab`); the padding's logits count as -inf."""
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
@@ -105,6 +107,9 @@ def _ce_lse_kernel(
     s = jax.lax.dot(
         x_ref[...], w_ref[...], preferred_element_type=jnp.float32
     )                                              # [block_rows, block_v]
+    if valid_v is not None:
+        cols = j * s.shape[1] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < valid_v, s, -1e30)
     m_prev = m_scr[:, :1]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
@@ -124,7 +129,7 @@ def _ce_lse_kernel(
 
 def _ce_dlogits_kernel(
     x_ref, w_ref, tgt_ref, lse_ref, scale_ref, dl_ref,
-    *, block_rows: int, block_v: int,
+    *, block_rows: int, block_v: int, valid_v: Optional[int] = None,
 ):
     from jax.experimental import pallas as pl
 
@@ -138,10 +143,12 @@ def _ce_dlogits_kernel(
     tg = row_stat_col(tgt_ref, i, block_rows)
     cols = j * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     p = jnp.where(cols == tg, p - 1.0, p)          # softmax - onehot
+    if valid_v is not None:
+        p = jnp.where(cols < valid_v, p, 0.0)      # the padding has no logit
     dl_ref[...] = (p * scale_ref[0, 0]).astype(dl_ref.dtype)
 
 
-def _ce_lse_pallas(x, w, interpret: bool = False):
+def _ce_lse_pallas(x, w, interpret: bool = False, valid_v: Optional[int] = None):
     """x: [N, E], w: [E, V] (same dtype as x) -> lse [N] f32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -152,7 +159,7 @@ def _ce_lse_pallas(x, w, interpret: bool = False):
     num_i, num_v = n // br, v // bv
 
     lse = pl.pallas_call(
-        functools.partial(_ce_lse_kernel, num_v=num_v),
+        functools.partial(_ce_lse_kernel, num_v=num_v, valid_v=valid_v),
         out_shape=jax.ShapeDtypeStruct((1, 1, n), jnp.float32),
         grid=(num_i, num_v),
         in_specs=[
@@ -170,7 +177,7 @@ def _ce_lse_pallas(x, w, interpret: bool = False):
     return lse[0, 0]
 
 
-def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False):
+def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid_v: Optional[int] = None):
     """Scaled bf16 dlogits = (softmax(x@w) - onehot(targets)) * scale.
     scale is a traced scalar (folded in here so no extra [N, V] pass)."""
     from jax.experimental import pallas as pl
@@ -185,7 +192,7 @@ def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False):
     scale2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
 
     return pl.pallas_call(
-        functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv),
+        functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv, valid_v=valid_v),
         out_shape=jax.ShapeDtypeStruct((n, v), x.dtype),
         grid=(num_i, num_v),
         in_specs=[
@@ -223,11 +230,13 @@ def fused_linear_cross_entropy(x, w, targets):
     return jnp.mean(lse - tl)
 
 
-def _ce_fwd(x, w, targets, interpret: bool = False):
+def _ce_fwd(x, w, targets, interpret: bool = False, valid_v: Optional[int] = None):
     if _pallas_util.on_tpu() or interpret:
-        lse = _ce_lse_pallas(x, w, interpret=interpret)
+        lse = _ce_lse_pallas(x, w, interpret=interpret, valid_v=valid_v)
         return lse, _target_logit(x, w, targets)
     logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    if valid_v is not None:
+        logits = jnp.where(jnp.arange(w.shape[1]) < valid_v, logits, -1e30)
     lse = jax.nn.logsumexp(logits, axis=-1)
     tl = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
     return lse, tl
@@ -238,16 +247,18 @@ def _ce_vjp_fwd(x, w, targets):
     return jnp.mean(lse - tl), (x, w, targets, lse)
 
 
-def _ce_vjp_bwd(res, g):
+def _ce_vjp_bwd(res, g, valid_v: Optional[int] = None):
     x, w, targets, lse = res
     n = x.shape[0]
     scale = g / n
     if _pallas_util.on_tpu():
         # dlogits tile-by-tile in bf16 (pallas) — the f32 logits never
         # exist in HBM.
-        dl = _ce_dlogits_pallas(x, w, targets, lse, scale)
+        dl = _ce_dlogits_pallas(x, w, targets, lse, scale, valid_v=valid_v)
     else:
         logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+        if valid_v is not None:
+            logits = jnp.where(jnp.arange(w.shape[1]) < valid_v, logits, -1e30)
         p = jnp.exp(logits - lse[:, None])
         p = p - jax.nn.one_hot(targets, w.shape[1], dtype=jnp.float32)
         dl = (p * scale).astype(x.dtype)
@@ -269,3 +280,37 @@ def _ce_vjp_bwd(res, g):
 
 
 fused_linear_cross_entropy.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
+
+
+# -- a head whose width no block divides ------------------------------------------
+
+
+def padded_vocab(v: int) -> int:
+    """The width a head of V columns is padded to where V is no lane
+    multiple (an eighth of a 151,936-row vocabulary is 18,992 = 16 x 1,187):
+    the next multiple of 512, which `_block_v` tiles in blocks of 512."""
+    return v if v % _LANE == 0 else -(-v // 512) * 512
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_ce_valid(x, w, targets, valid_v: int):
+    lse, tl = _ce_fwd(x, w, targets, valid_v=valid_v)
+    return jnp.mean(lse - tl)
+
+
+def _ce_valid_fwd(x, w, targets, valid_v):
+    lse, tl = _ce_fwd(x, w, targets, valid_v=valid_v)
+    return jnp.mean(lse - tl), (x, w, targets, lse)
+
+
+_fused_ce_valid.defvjp(_ce_valid_fwd, lambda valid_v, res, g: _ce_vjp_bwd(res, g, valid_v))
+
+
+def fused_linear_cross_entropy_padded(x, w, targets):
+    """`fused_linear_cross_entropy` for a head whose width V is no lane
+    multiple: zero columns pad w to `padded_vocab(V)`, the kernels treat
+    the padding's logits as -inf (`valid_v`), and autodiff slices the
+    padding's (zero) gradient away.  Callers gate on
+    ``fused_ce_applicable(n, e, padded_vocab(v))``."""
+    v = w.shape[1]
+    return _fused_ce_valid(x, jnp.pad(w, ((0, 0), (0, padded_vocab(v) - v))), targets, v)

@@ -1,0 +1,539 @@
+"""Learned sparse attention (DeepSeek-V3.2's lightning indexer over GQA): an
+indexer scores every visible (query, key) pair, each query keeps its `topk`
+best keys, and attention runs over that selection alone.
+
+For one sequence, with index queries ``a`` [J, S, Di], ONE index key head
+``b`` [S, Di] and per-query head weights ``w`` [S, J] (the two scale factors
+J**-0.5 and Di**-0.5 already in ``w``):
+
+    I[t, s] = sum_j w[t, j] * relu(a[j, t] . b[s])              for s <= t
+    S_t     = the min(t + 1, topk) largest I[t, :t + 1], ties to the lower s
+    out[t, h] = softmax_{s in S_t}(q[t, h] . k[s, g(h)] * scale) v[s, g(h)]
+    loss    = mean_t KL( pbar[t, :] || softmax_{s in S_t} I[t, s] )
+    pbar[t, s] = (1 / H) sum_h  (head h's attention probability of s)
+
+``out`` is differentiated with respect to q, k, v with the selection a
+constant; ``loss`` with respect to a, b, w with ``pbar`` a constant
+(DeepSeek-V3.2's sparse-stage objective).  Nothing else flows: the caller
+hands the indexer a detached input.
+
+On one TPU device five pallas kernels do it, none of which holds an [S, S]
+float32 array in HBM:
+
+  - ``tpuft_dsa_select`` (per block of 256 queries): the block's index scores
+    against every visible key stay in VMEM as order-preserving int32 keys
+    ([256, S]: 32 MiB at 32,768); the EXACT topk-th largest of each row is
+    found by bisection on the key's 32 bits (each pass counts the entries at
+    or above a candidate), ties at the threshold are cut at a position found
+    the same way, and the row's log-sum-exp over the selection is taken while
+    the scores are there.  Out: a threshold, a position cut and a
+    log-sum-exp per query — 12 bytes a query to keep for the backward pass;
+  - ``tpuft_dsa_mask``: the selection as an int8 [S, S] mask (1 GiB at
+    32,768, alive for one layer's attention), rebuilt from the thresholds by
+    scoring the tiles once more — never by selecting again.  The forward and
+    the backward pass both read the mask this kernel makes from the same
+    operands, so the two cannot disagree;
+  - ``tpuft_dsa_attn_fwd`` / ``tpuft_dsa_attn_bwd_dkdv_dq``: the flash
+    kernels of ops/attention.py with the mask tile in place of the causal
+    triangle (they visit every causal tile: skipping tiles the selection
+    leaves empty is the next step);
+  - ``tpuft_dsa_index_loss``: per tile the 32 heads' probabilities again
+    (one QK^T a head, from the saved log-sum-exps) summed into pbar, the KL
+    row sums, and IN THE SAME PASS the loss's gradient with respect to a, b
+    and w — d loss / d I = (softmax(I) - pbar) / (B S) on the selection —
+    which the backward pass only scales by the loss's cotangent.
+
+Off-TPU, under a multi-device mesh and for shapes the kernels do not tile,
+the same mathematics runs in plain XLA with dense [S, S] scores and
+``jax.lax.top_k`` (``_dsa_xla``: also the oracle the kernels are tested
+against).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from torchft_tpu.ops import _pallas_util
+from torchft_tpu.ops import attention as _fa
+
+_INT_MIN = -(2 ** 31)
+BLOCK_Q = 256   # rows of a select / mask / index-loss tile
+BLOCK_K = 512   # key columns of a tile
+_VMEM_LIMIT = 100 * 2 ** 20
+
+# What a rematerialised layer keeps so that its backward pass neither scores,
+# selects nor attends again (see ops.attention.SAVED_NAMES): the attention
+# output and row statistics, the selection's thresholds, and the index
+# loss with its ready gradient.
+SAVED_NAMES = tuple("tpuft_dsa_" + kept for kept in (
+    "out", "lse", "tau", "cut", "loss", "da", "db", "dw", "selected"))
+
+
+def _kept(x, name: str):
+    from jax.ad_checkpoint import checkpoint_name
+
+    assert "tpuft_dsa_" + name in SAVED_NAMES
+    return checkpoint_name(x, "tpuft_dsa_" + name)
+
+
+def kernels_apply(seq: int, d_head: int, d_index: int, mesh=None) -> bool:
+    """Whether the five kernels run: the sequence tiles in blocks of 512, a
+    head is a lane multiple wide, and the program being traced runs on one
+    TPU device (`_pallas_util.kernels_apply`); else the XLA formulation."""
+    return (
+        seq % BLOCK_K == 0
+        and d_head % _pallas_util.LANE == 0
+        and d_index % 8 == 0
+        and _pallas_util.kernels_apply(mesh)
+    )
+
+
+# -- in-tile pieces shared by the kernels --------------------------------------
+
+
+def _sortable(x):
+    """f32 -> int32 with the same order (-0.0 first made +0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _unsortable(key):
+    return jax.lax.bitcast_convert_type(key ^ ((key >> 31) & 0x7FFFFFFF), jnp.float32)
+
+
+def _index_tile(a_ref, bt, w, heads: int):
+    """I of one tile: a_ref (1, J, bq, Di) bf16, bt [Di, bk] bf16 (the key
+    head transposed), w [bq, J] f32 -> [bq, bk] f32.  The one place the
+    kernels score a tile, so that each sees bit for bit the same score."""
+    acc = None
+    for j in range(heads):
+        r = jax.lax.dot(a_ref[0, j], bt, preferred_element_type=jnp.float32)
+        term = w[:, j:j + 1] * jnp.maximum(r, 0.0)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _tile_positions(qi, ki, bq: int, bk: int):
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return rows, cols
+
+
+# -- tpuft_dsa_select ------------------------------------------------------------
+
+
+def _select_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, z_ref, keys_scr,
+                   *, heads: int, topk: int, bq: int, bk: int, seq: int):
+    from jax.experimental import pallas as pl
+
+    qi = pl.program_id(1)
+    tiles = (qi * bq + bq - 1) // bk + 1  # key tiles that hold a visible column
+    w = w_ref[0]
+
+    def fill(kt, _):
+        cols0 = pl.multiple_of(kt * bk, bk)
+        score = _index_tile(a_ref, bt_ref[0, :, pl.ds(cols0, bk)], w, heads)
+        rows, cols = _tile_positions(qi, kt, bq, bk)
+        keys_scr[:, pl.ds(cols0, bk)] = jnp.where(rows >= cols, _sortable(score), _INT_MIN)
+        return 0
+
+    jax.lax.fori_loop(0, tiles, fill, 0)
+
+    def fold(step, init):
+        """step(keys, cols, acc) -> acc over the visible tiles of the block's keys."""
+        def body(kt, acc):
+            cols0 = pl.multiple_of(kt * bk, bk)
+            cols = cols0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            return step(keys_scr[:, pl.ds(cols0, bk)], cols, acc)
+
+        return jax.lax.fori_loop(0, tiles, body, init)
+
+    def count(pred):
+        """Per row, how many entries of the visible tiles satisfy pred(keys, cols)."""
+        part = fold(lambda k, c, acc: acc + pred(k, c).astype(jnp.int32), jnp.zeros((bq, bk), jnp.int32))
+        return jnp.sum(part, axis=1, keepdims=True)
+
+    row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    want = jnp.minimum(row + 1, topk)  # keys this query keeps
+
+    # The want-th largest key: the largest T with count(key >= T) >= want,
+    # built from the sign down (for a negative T setting a lower bit raises it).
+    tau = jnp.where(count(lambda k, _: k >= 0) >= want, 0, _INT_MIN).astype(jnp.int32)
+
+    def bit_step(i, tau):
+        cand = tau | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(lambda k, _: k >= cand) >= want, cand, tau)
+
+    tau = jax.lax.fori_loop(0, 31, bit_step, tau)
+    # Ties at the threshold go to the lower position: of the keys equal to
+    # tau the first `need`, which end at the largest position P with fewer
+    # than `need` equal keys before it.
+    need = want - count(lambda k, _: k > tau)
+
+    def cut_step(i, cut):
+        cand = cut | jnp.left_shift(jnp.int32(1), (seq - 1).bit_length() - 1 - i)
+        return jnp.where(count(lambda k, c: (k == tau) & (c < cand)) < need, cand, cut)
+
+    # Only a block in which some query has more keys tied at its threshold
+    # than it may keep pays for these passes; elsewhere every key equal to
+    # tau is kept, whatever its position.
+    tied = count(lambda k, _: k == tau)
+    cut = jax.lax.cond(
+        jnp.max(tied - need) > 0,
+        lambda: jax.lax.fori_loop(0, (seq - 1).bit_length(), cut_step, jnp.zeros((bq, 1), jnp.int32)),
+        lambda: jnp.full((bq, 1), seq, jnp.int32),
+    )
+
+    # The selection's log-sum-exp of I, while the scores are here.  (An
+    # invisible entry holds INT_MIN, below every tau: it is never selected.)
+    top = _unsortable(jnp.max(
+        fold(lambda k, _, m: jnp.maximum(m, k), jnp.full((bq, bk), _INT_MIN, jnp.int32)), axis=1, keepdims=True))
+    total = jnp.sum(fold(
+        lambda k, c, acc: acc + jnp.where((k > tau) | ((k == tau) & (c <= cut)), jnp.exp(_unsortable(k) - top), 0.0),
+        jnp.zeros((bq, bk), jnp.float32)), axis=1, keepdims=True)
+    tau_ref[0] = jnp.broadcast_to(tau, tau_ref.shape[1:])
+    cut_ref[0] = jnp.broadcast_to(cut, cut_ref.shape[1:])
+    z_ref[0] = jnp.broadcast_to(top + jnp.log(total), z_ref.shape[1:])
+
+
+def _select_pallas(a, bt, w, topk: int, interpret: bool = False):
+    """a [B, J, S, Di], bt [B, Di, S], w [B, S, J] f32 -> (tau int32, cut
+    int32, z f32), each [B, S, 1]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, heads, seq, di = a.shape
+    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
+    lane = _pallas_util.LANE
+    row_spec = pl.BlockSpec((1, bq, lane), lambda b, i: (b, i, 0))
+    tau, cut, z = pl.pallas_call(
+        functools.partial(_select_kernel, heads=heads, topk=topk, bq=bq, bk=bk, seq=seq),
+        out_shape=(
+            jax.ShapeDtypeStruct((batch, seq, lane), jnp.int32),
+            jax.ShapeDtypeStruct((batch, seq, lane), jnp.int32),
+            jax.ShapeDtypeStruct((batch, seq, lane), jnp.float32),
+        ),
+        grid=(batch, seq // bq),
+        in_specs=[
+            pl.BlockSpec((1, heads, bq, di), lambda b, i: (b, 0, i, 0)),
+            pl.BlockSpec((1, di, seq), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, bq, heads), lambda b, i: (b, i, 0)),
+        ],
+        out_specs=(row_spec, row_spec, row_spec),
+        scratch_shapes=[pltpu.VMEM((bq, seq), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="tpuft_dsa_select",
+    )(a, bt, w)
+    return tau[:, :, :1], cut[:, :, :1], z[:, :, :1]
+
+
+# -- tpuft_dsa_mask ---------------------------------------------------------------
+
+
+def _mask_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, mask_ref, *, heads: int, bq: int, bk: int):
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    visible = ki * bk <= qi * bq + bq - 1
+
+    # A tile above the diagonal has no place in the packed triangle: its
+    # steps leave the diagonal tile, where the index map holds them, alone.
+    @pl.when(visible)
+    def _score():
+        keys = _sortable(_index_tile(a_ref, bt_ref[0], w_ref[0], heads))
+        rows, cols = _tile_positions(qi, ki, bq, bk)
+        tau = tau_ref[0]
+        keep = ((keys > tau) | ((keys == tau) & (cols <= cut_ref[0]))) & (rows >= cols)
+        mask_ref[0, 0] = jnp.where(keep, 1, 0).astype(jnp.int8)
+
+
+def _mask_spec(seq: int):
+    """How this file's (bq, bk) tiles read the packed mask, whose tiles are
+    the flash kernels' (tile, bk): a half of a tile where bq is."""
+    from jax.experimental import pallas as pl
+
+    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
+    sub = _fa._block_sizes(seq, seq)[0] // bq
+    return pl.BlockSpec((1, 1, bq, bk), lambda b, i, j: (b, _fa._tri(i // sub, j), i % sub, 0))
+
+
+def _mask_pallas(a, bt, w, tau, cut, interpret: bool = False):
+    """The selection as int8 [B, tiles, tile, bk]: the lower triangle's
+    (tile, bk) tiles row by row (`ops.attention._tri`), 1 where query t
+    keeps key s."""
+    from jax.experimental import pallas as pl
+
+    batch, heads, seq, di = a.shape
+    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
+    tile = _fa._block_sizes(seq, seq)[0]
+    n = seq // tile
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    return pl.pallas_call(
+        functools.partial(_mask_kernel, heads=heads, bq=bq, bk=bk),
+        out_shape=jax.ShapeDtypeStruct((batch, n * (n + 1) // 2, tile, bk), jnp.int8),
+        grid=(batch, seq // bq, seq // bk),
+        in_specs=[
+            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, di, bk), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+            row_spec, row_spec,
+        ],
+        out_specs=_mask_spec(seq),
+        interpret=interpret,
+        name="tpuft_dsa_mask",
+    )(a, bt, w, tau, cut)
+
+
+# -- tpuft_dsa_index_loss -----------------------------------------------------------
+
+
+def _index_loss_kernel(q_ref, k_ref, lse_ref, a_ref, bt_ref, w_ref, z_ref, mask_ref,
+                       kl_ref, da_ref, dbt_ref, dw_ref, kl_scr,
+                       *, q_heads: int, kv_heads: int, heads: int, scale: float, inv_rows: float,
+                       bq: int, bk: int, num_k: int):
+    from jax.experimental import pallas as pl
+
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((qi == 0) & (ki == 0))
+    def _init_keys():
+        dbt_ref[...] = jnp.zeros_like(dbt_ref)
+
+    @pl.when(ki == 0)
+    def _init_rows():
+        kl_scr[...] = jnp.zeros_like(kl_scr)
+        da_ref[...] = jnp.zeros_like(da_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(ki * bk <= qi * bq + bq - 1)
+    def _tile():
+        group = q_heads // kv_heads
+
+        def one_head(h, total):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
+            return total + jnp.exp(s - lse)
+
+        keep = mask_ref[0, 0].astype(jnp.int32) != 0
+        total = jax.lax.fori_loop(0, q_heads, one_head, jnp.zeros((bq, bk), jnp.float32))
+        pbar = jnp.where(keep, total * (1.0 / q_heads), 0.0)
+        bt, w = bt_ref[0], w_ref[0]
+        log_q = _index_tile(a_ref, bt, w, heads) - z_ref[0]
+        kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - log_q), 0.0)
+        kl_scr[:, 0:1] += jnp.sum(kl, axis=1, keepdims=True)
+        # d loss / d I on the selection; the index heads' products once more
+        # for what each passes back
+        g = jnp.where(keep, jnp.exp(jnp.where(keep, log_q, 0.0)) - pbar, 0.0) * inv_rows
+        cols0 = pl.multiple_of(ki * bk, bk)
+        for j in range(heads):
+            a_j = a_ref[0, j]
+            r = jax.lax.dot(a_j, bt, preferred_element_type=jnp.float32)
+            dw_ref[0, :, j:j + 1] += jnp.sum(g * jnp.maximum(r, 0.0), axis=1, keepdims=True)
+            e = jnp.where(r > 0.0, g * w[:, j:j + 1], 0.0).astype(a_j.dtype)
+            da_ref[0, j] += jax.lax.dot_general(
+                e, bt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)      # e @ b: [bq, Di]
+            dbt_ref[0, :, pl.ds(cols0, bk)] += jax.lax.dot_general(
+                a_j, e, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)     # a^T @ e: [Di, bk]
+
+    @pl.when(ki == num_k - 1)
+    def _emit():
+        kl_ref[0] = jnp.broadcast_to(kl_scr[:, 0:1], kl_ref.shape[1:])
+
+
+def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bool = False):
+    """q [B, H, S, D], k [B, KV, S, D], lse [B, H, S] -> (kl rows [B, S],
+    d loss/d a [B, J, S, Di] f32, d loss/d bt [B, Di, S] f32, d loss/d w
+    [B, S, J] f32) of loss = sum(kl rows) / (B * S)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, q_heads, seq, d = q.shape
+    kv_heads, heads, di = k.shape[1], a.shape[1], a.shape[3]
+    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
+    num_k = seq // bk
+    lane = _pallas_util.LANE
+    kl, da, dbt, dw = pl.pallas_call(
+        functools.partial(
+            _index_loss_kernel, q_heads=q_heads, kv_heads=kv_heads, heads=heads, scale=scale,
+            inv_rows=1.0 / (batch * seq), bq=bq, bk=bk, num_k=num_k),
+        out_shape=(
+            jax.ShapeDtypeStruct((batch, seq, lane), jnp.float32),
+            jax.ShapeDtypeStruct(a.shape, jnp.float32),
+            jax.ShapeDtypeStruct(bt.shape, jnp.float32),
+            jax.ShapeDtypeStruct(w.shape, jnp.float32),
+        ),
+        grid=(batch, seq // bq, num_k),
+        in_specs=[
+            pl.BlockSpec((1, q_heads, bq, d), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, kv_heads, bk, d), lambda b, i, j: (b, 0, j, 0)),
+            pl.BlockSpec((1, q_heads, 1, seq), lambda b, i, j: (b, 0, 0, 0)),   # whole rows, as the flash kernels'
+
+            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, di, bk), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            _mask_spec(seq),
+        ],
+        out_specs=(
+            pl.BlockSpec((1, bq, lane), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),   # a sequence's whole row, resident
+            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+        ),
+        scratch_shapes=[pltpu.VMEM((bq, lane), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="tpuft_dsa_index_loss",
+    )(q, k, lse[:, :, None, :], a, bt, w, z, mask)
+    return kl[:, :, 0], da, dbt, dw
+
+
+# -- the plain XLA formulation ---------------------------------------------------------
+
+
+def index_scores(a, bt, w):
+    """Dense I [B, S, S] f32 (every pair, the causal rule not applied)."""
+    r = jnp.einsum("bjtd,bds->bjts", a, bt, preferred_element_type=jnp.float32)
+    return jnp.einsum("btj,bjts->bts", w.astype(jnp.float32), jnp.maximum(r, 0.0))
+
+
+def selection_mask(scores, topk: int):
+    """bool [B, S, S]: the min(t + 1, topk) largest visible scores of each
+    query, ties to the lower position (`jax.lax.top_k`'s rule)."""
+    seq = scores.shape[-1]
+    causal = jnp.tril(jnp.ones((seq, seq), bool))
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), min(topk, seq))
+    return _scatter_rows(idx, seq) & causal
+
+
+def _scatter_rows(idx, seq: int):
+    batch, rows, _ = idx.shape
+    flat = jnp.zeros((batch * rows, seq), bool)
+    flat = flat.at[jnp.arange(batch * rows)[:, None], idx.reshape(batch * rows, -1)].set(True)
+    return flat.reshape(batch, rows, seq)
+
+
+def _dsa_xla(q, k, v, a, bt, w, topk: int, scale: float):
+    """The module docstring's mathematics with dense scores; differentiated
+    by autodiff.  q [B, H, S, D], k/v [B, KV, S, D]."""
+    batch, q_heads, seq, _ = q.shape
+    group = q_heads // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = index_scores(a, bt, w)
+    keep = selection_mask(jax.lax.stop_gradient(scores), topk)[:, None]       # [B, 1, S, S]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v).astype(q.dtype)
+    pbar = jax.lax.stop_gradient(jnp.mean(p, axis=1))                         # [B, S, S]
+    log_q = jax.nn.log_softmax(jnp.where(keep[:, 0], scores, -jnp.inf), axis=-1)
+    kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - jnp.where(keep[:, 0], log_q, 0.0)), 0.0)
+    loss = jnp.sum(kl) / (batch * seq)
+    return out, loss, jnp.sum(keep, dtype=jnp.int32)
+
+
+# -- the kernels' path, one custom_vjp --------------------------------------------------
+
+
+def _masked_flash_fwd(q, k, v, mask, scale, interpret: bool = False):
+    batch, q_heads, seq, d = q.shape
+    flat = lambda t: t.reshape(-1, seq, d)  # noqa: E731
+    o, lse = _fa._fa_pallas_call(flat(q), flat(k), flat(v), scale, True, interpret=interpret, mask=mask,
+                                 kv_group=q_heads // k.shape[1])
+    return o.reshape(q.shape), lse.reshape(batch, q_heads, seq)
+
+
+def _masked_flash_bwd(q, k, v, o, lse, g, mask, scale, interpret: bool = False):
+    batch, q_heads, seq, d = q.shape
+    kv_heads = k.shape[1]
+    group = q_heads // kv_heads
+    flat = lambda t: t.reshape(-1, seq, d)  # noqa: E731
+    dq, dk, dv = _fa._fa_bwd_pallas(
+        flat(q), flat(k), flat(v), flat(o), lse.reshape(batch * q_heads, seq), flat(g), scale, True,
+        interpret=interpret, mask=mask, kv_group=group)
+    fold = lambda t: jnp.sum(  # noqa: E731 — a KV head's gradient: its query heads' summed
+        t.reshape(batch, kv_heads, group, seq, d).astype(jnp.float32), axis=2).astype(k.dtype)
+    return dq.reshape(q.shape), fold(dk), fold(dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _dsa_kernels(q, k, v, a, bt, w, topk: int, scale: float):
+    return _dsa_kernels_fwd(q, k, v, a, bt, w, topk, scale)[0]
+
+
+def _dsa_kernels_fwd(q, k, v, a, bt, w, topk, scale):
+    tau, cut, z = _select_pallas(a, bt, w, topk)
+    # kept as [B, S]: a trailing axis of one would be stored a lane tile wide
+    tau, cut = _kept(tau[..., 0], "tau"), _kept(cut[..., 0], "cut")
+    mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
+    out, lse = _masked_flash_fwd(q, k, v, mask, scale)
+    out, lse = _kept(out, "out"), _kept(lse, "lse")
+    kl, da, dbt, dw = _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale)
+    loss = _kept(jnp.sum(kl) / (kl.shape[0] * kl.shape[1]), "loss")
+    da = _kept(da.astype(a.dtype), "da")
+    dbt, dw = _kept(dbt.astype(bt.dtype), "db"), _kept(dw, "dw")
+    selected = _kept(jnp.sum(mask, dtype=jnp.int32), "selected")
+    return (out, loss, selected), (q, k, v, a, bt, w, tau, cut, out, lse, da, dbt, dw)
+
+
+def _dsa_kernels_bwd(topk, scale, res, cotangents):
+    q, k, v, a, bt, w, tau, cut, out, lse, da, dbt, dw = res
+    g_out, g_loss, _ = cotangents
+    # from the kept thresholds: scored once more, never selected again
+    mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
+    dq, dk, dv = _masked_flash_bwd(q, k, v, out, lse, g_out, mask, scale)
+    g_loss = g_loss.astype(jnp.float32)
+    return (dq, dk, dv, (g_loss * da.astype(jnp.float32)).astype(a.dtype),
+            (g_loss * dbt.astype(jnp.float32)).astype(bt.dtype), (g_loss * dw).astype(w.dtype))
+
+
+_dsa_kernels.defvjp(_dsa_kernels_fwd, _dsa_kernels_bwd)
+
+
+def selection(index_q, index_k, index_w, *, topk: int, mesh=None):
+    """The selection alone, as the packed int8 mask the attention kernels
+    read ([B, tiles, tile, tile]: `ops.attention._tri`) where the kernels run,
+    else as a dense bool [B, S, S].  For tools that count what was selected."""
+    bt, w = index_k.transpose(0, 2, 1), index_w.astype(jnp.float32)
+    if kernels_apply(index_q.shape[2], _pallas_util.LANE, index_q.shape[-1], mesh):
+        tau, cut, _ = _select_pallas(index_q, bt, w, topk)
+        return _mask_pallas(index_q, bt, w, tau, cut)
+    return selection_mask(index_scores(index_q, bt, w), topk)
+
+
+def packed_lower_triangle(dense):
+    """A dense [B, S, S] mask in the kernels' packed layout."""
+    batch, seq, _ = dense.shape
+    tile = _fa._block_sizes(seq, seq)[0]
+    n = seq // tile
+    blocks = dense.reshape(batch, n, tile, n, tile).transpose(0, 1, 3, 2, 4)
+    rows, cols = zip(*[(i, j) for i in range(n) for j in range(i + 1)])
+    return blocks[:, jnp.asarray(rows), jnp.asarray(cols)]
+
+
+def sparse_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, index_q: jax.Array, index_k: jax.Array, index_w: jax.Array,
+    *, topk: int, scale: float | None = None, mesh=None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """q [B, H, S, D], k/v [B, KV, S, D]; index_q [B, J, S, Di] and index_k
+    [B, S, Di] (both after RoPE), index_w [B, S, J] f32 with the indexer's
+    scale factors in it -> (out [B, H, S, D], the index loss (a scalar: the
+    mean over batch and positions of the KL term), the number of selected
+    pairs (int32)).  See the module docstring for what is differentiated
+    with respect to what."""
+    batch, q_heads, seq, d = q.shape
+    assert q_heads % k.shape[1] == 0, "query heads must be a multiple of kv heads"
+    scale = scale if scale is not None else d ** -0.5
+    bt = index_k.transpose(0, 2, 1)  # [B, Di, S]: a key tile is a lane-aligned slice
+    w = index_w.astype(jnp.float32)
+    if kernels_apply(seq, d, index_q.shape[-1], mesh):
+        return _dsa_kernels(q, k, v, index_q, bt, w, topk, scale)
+    return _dsa_xla(q, k, v, index_q, bt, w, topk, scale)
