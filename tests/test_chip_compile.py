@@ -307,7 +307,9 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     assert resident < 14.5e9, f"the step needs {resident} bytes with AdamW's moments; the cut's bound is 14.5 GB"
     # what the two-kernel backward compiled to (PR 31): dq stays in VMEM until it is bf16, so the
     # one-pass kernel brings no f32 dq, 268 MB a layer here, into HBM
-    assert resident <= 14.34e9, f"{resident} bytes: the backward's dq has left VMEM in f32"
+    # (14,339,268,608 then; 14,340,042,752 since PR 34: the kernels alone compile to the same
+    # temporaries, the program's schedule around the copies of the walk's tables holds 0.77 MB more)
+    assert resident <= 14.345e9, f"{resident} bytes: the backward's dq has left VMEM in f32"
 
 
 def _kernel_calls(text: str, prefix: str) -> list:

@@ -318,6 +318,65 @@ def test_index_loss_kernel_gives_the_loss_and_its_gradient_in_one_pass(kernel_ru
         assert np.linalg.norm(got - ref) < 0.01 * np.linalg.norm(ref), name
 
 
+@pytest.mark.parametrize("heads,kv", [(2, 2), (8, 1)], ids=["kv_group_1", "kv_group_8"])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_the_five_kernels_at_n_tiles_a_side_against_the_xla_formulation(n, heads, kv) -> None:
+    """512 n positions: the selection kernels' mask is `lax.top_k`'s, the
+    attention kernels under it (a step for each tile of the lower triangle,
+    `kv_group` query heads reading one KV head in place) give `_dsa_xla`'s
+    out, dq, dk, dv, and the index-loss kernel (256 x 512 tiles, walked the
+    same way) its loss and the loss's gradient."""
+    seq, topk, scale = 512 * n, 200, 128 ** -0.5
+    q, k, v, a, bt, w, g = _kernel_operands(seed=n, heads=heads, kv=kv, seq=seq, j=2, ties=False)
+    tau, cut, z = sa._select_pallas(a, bt, w, topk, interpret=True)
+    mask = sa._mask_pallas(a, bt, w, tau, cut, interpret=True)
+    assert mask.shape == (1, n * (n + 1) // 2, 512, 512)
+    want_mask = sa.selection_mask(sa.index_scores(a, bt, w), topk)
+    assert np.array_equal(_unpacked(mask, seq) != 0, np.asarray(want_mask))
+    out, lse = sa._masked_flash_fwd(q, k, v, mask, scale, interpret=True)
+    dq, dk, dv = sa._masked_flash_bwd(q, k, v, out, lse, g, mask, scale, interpret=True)
+    kl, da, dbt, dw = sa._index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale, interpret=True)
+    gf = g.astype(jnp.float32)
+
+    def both(q, k, v, a, bt, w):
+        xla_out, xla_loss, _ = sa._dsa_xla(q, k, v, a, bt, w, topk, scale)
+        return jnp.sum(xla_out.astype(jnp.float32) * gf), (xla_out, xla_loss)
+
+    (_, (xla_out, xla_loss)), want = jax.value_and_grad(both, argnums=(0, 1, 2), has_aux=True)(q, k, v, a, bt, w)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(xla_out, np.float32), atol=0.03)
+    assert abs(float(jnp.sum(kl) / seq) - float(xla_loss)) < 1e-5
+    want += jax.grad(lambda a, bt, w: sa._dsa_xla(q, k, v, a, bt, w, topk, scale)[1], argnums=(0, 1, 2))(a, bt, w)
+    for name, got, ref in zip(("q", "k", "v", "a", "bt", "w"), (dq, dk, dv, da, dbt, dw), want):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        assert np.linalg.norm(got - ref) < 0.01 * np.linalg.norm(ref), name
+
+
+@pytest.mark.parametrize("seq", [4096, 8192, 32768])
+def test_the_selection_kernels_grids_at_the_cells_lengths(seq) -> None:
+    """`tpuft_dsa_mask` and `tpuft_dsa_index_loss` traced at the cells'
+    lengths (nothing runs): a step for each 256 x 512 tile that holds a
+    visible pair — key tiles 0 .. qi // 2 under query tile qi — and the
+    row's sums are emitted at the last of them."""
+    from test_ops import pallas_call_grids
+
+    n = seq // 512
+    visible = sum(qi // 2 + 1 for qi in range(seq // 256))
+    assert visible == n * (n + 1)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    a, bt, w = (jax.ShapeDtypeStruct(s, t) for s, t in (((1, 16, seq, 64), bf), ((1, 64, seq), bf), ((1, seq, 16), f32)))
+    row = jax.ShapeDtypeStruct((1, seq, 1), jnp.int32)
+    assert pallas_call_grids(sa._mask_pallas, a, bt, w, row, row) == {"tpuft_dsa_mask": (1, visible)}
+    q, k = (jax.ShapeDtypeStruct((1, h, seq, 128), bf) for h in (32, 4))
+    lse, z = jax.ShapeDtypeStruct((1, 32, seq), f32), jax.ShapeDtypeStruct((1, seq, 1), f32)
+    mask = jax.ShapeDtypeStruct((1, n * (n + 1) // 2, 512, 512), jnp.int8)
+    assert pallas_call_grids(lambda *ops: sa._index_loss_pallas(*ops, 0.088), q, k, lse, a, bt, w, z, mask) == {
+        "tpuft_dsa_index_loss": (1, visible)}
+    walk = sa._walk(seq)
+    rows, cols = (np.asarray(t) for t in walk.tables)
+    assert (cols <= rows // 2).all() and (np.diff(rows) >= 0).all() and rows[-1] == seq // 256 - 1
+    assert all(int(walk.last_k(qi)) == qi // 2 for qi in (0, 1, 2, seq // 256 - 1))
+
+
 def test_the_kernels_path_is_one_custom_vjp_with_the_right_partners(monkeypatch) -> None:
     """`sparse_attention` on the kernels' path (interpret mode under the
     gate): out's cotangent reaches q, k, v alone, the loss's a, b, w alone."""

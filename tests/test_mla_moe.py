@@ -197,6 +197,18 @@ def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass, mo
         np.testing.assert_allclose(a, np.asarray(b), rtol=2e-3, atol=2e-4, err_msg=name)
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+@pytest.mark.parametrize("kv_group", [1, 8])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_the_kernels_walk_the_lower_triangle_at_unequal_widths(n, kv_group, masked) -> None:
+    """Query and key 256 wide, value 128, n tiles a side: a grid step for
+    each tile of the lower triangle, out, lse, dq, dk and dv the XLA
+    formulation's (`test_ops.check_the_triangular_walk`)."""
+    from test_ops import check_the_triangular_walk
+
+    check_the_triangular_walk(n, 256, 128, kv_group, masked)
+
+
 @pytest.mark.parametrize("program", ["dense_lm", "moe_lm", "mla_moe_lm"])
 def test_the_one_pass_backward_is_booked_to_attention_by_its_name(program) -> None:
     """The benchmark attributes device time to attention by substring and

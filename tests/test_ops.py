@@ -163,6 +163,129 @@ def test_flash_attention_bwd_is_one_pallas_call_at_the_cells_shape() -> None:
     assert fa._dq_row_resident(32768, 256) and not fa._dq_row_resident(65536, 256)
 
 
+def pallas_call_grids(fn, *args) -> dict:
+    """{name: grid} of every `pallas_call` in `fn`'s jaxpr."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return {e.params["name"]: tuple(e.params["grid_mapping"].grid) for e in eqns if e.primitive.name == "pallas_call"}
+
+
+def _masked_reference(q, k, v, keep, scale):
+    """Dense softmax attention over the pairs `keep` [S, S] allows: (out, lse)."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+    s = jnp.where(keep, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked: bool) -> None:
+    """The flash kernels in interpret mode at n tiles a side, forward (out,
+    lse) and one-pass backward (dq, dk, dv), against the XLA formulations:
+    causal against `_fa_reference` / `_fa_bwd_xla`, under a packed per-pair
+    mask (a seeded third of the visible pairs, the diagonal among them)
+    against dense masked softmax attention and its autodiff.  With
+    `kv_group` the kernels read one KV head for a group of query heads and
+    give dk, dv a query head each.  Each call's grid is (heads, n (n + 1) /
+    2): a step for each tile of the lower triangle and no other."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    seq, heads = 512 * n, max(2, kv_group)
+    ks = jax.random.split(jax.random.PRNGKey(100 * n + kv_group + masked), 5)
+    q = jax.random.normal(ks[0], (heads, seq, d_qk), jnp.float32)
+    k = jax.random.normal(ks[1], (heads // kv_group, seq, d_qk), jnp.float32)
+    v = jax.random.normal(ks[2], (heads // kv_group, seq, d_v), jnp.float32)
+    g = jax.random.normal(ks[3], (heads, seq, d_v), jnp.float32)
+    scale = d_qk ** -0.5
+    k_all, v_all = jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0)
+    more = {"kv_group": kv_group}
+    if masked:
+        keep = (jax.random.bernoulli(ks[4], 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)) & jnp.tril(jnp.ones((seq, seq), bool))
+        more["mask"] = sa.packed_lower_triangle(keep[None]).astype(jnp.int8)
+        (want_o, want_lse), vjp = jax.vjp(lambda *qkv: _masked_reference(*qkv, keep, scale), q, k_all, v_all)
+        want = vjp((g, jnp.zeros_like(want_lse)))
+    else:
+        want_o, want_lse = fa._fa_reference(q, k_all, v_all, scale, True)
+        want = fa._fa_bwd_xla(q, k_all, v_all, want_o, want_lse, g, scale, True)
+    fwd = functools.partial(fa._fa_pallas_call, scale=scale, causal=True, interpret=True, **more)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, **more)
+    got_o, got_lse = fwd(q, k, v)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=2e-3, atol=2e-3)
+    got = bwd(q, k, v, got_o, got_lse, g)
+    assert [a.shape for a in got] == [q.shape, (heads, seq, d_qk), (heads, seq, d_v)]
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+    grids = {**pallas_call_grids(fwd, q, k, v), **pallas_call_grids(bwd, q, k, v, got_o, got_lse, g)}
+    assert set(grids.values()) == {(heads, n * (n + 1) // 2)} and len(grids) == 2, grids
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+@pytest.mark.parametrize("kv_group", [1, 8])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_flash_kernels_walk_the_lower_triangle(n, kv_group, masked) -> None:
+    check_the_triangular_walk(n, 128, 128, kv_group, masked)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+def test_short_rows_of_dq_are_cast_out_at_their_diagonal_step(masked) -> None:
+    """Three tiles a side, one pass: on the square grid every q tile's dq
+    rows left the f32 row at kv tile 2's steps, which a triangular walk
+    visits for q tile 2 alone.  The rows of q tiles 0 and 1 are complete —
+    and have to be cast into the output — at kv tiles 0 and 1."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    seq = 1536
+    q, k, v, g = (jax.random.normal(kk, (2, seq, 128), jnp.bfloat16) for kk in jax.random.split(jax.random.PRNGKey(3), 4))
+    mask = sa.packed_lower_triangle(jnp.tril(jnp.ones((1, seq, seq), jnp.int8))) if masked else None
+    o, lse = fa._fa_pallas_call(q, k, v, 0.088, True, interpret=True, mask=mask)
+    dq, _, _ = fa._fa_bwd_pallas(q, k, v, o, lse, g, 0.088, True, interpret=True, mask=mask)
+    want, _, _ = fa._fa_bwd_xla(q, k, v, o, lse, g, 0.088, True)
+    dq, want = np.asarray(dq, np.float32), np.asarray(want, np.float32)
+    for qi in range(3):
+        rows = slice(512 * qi, 512 * (qi + 1))
+        assert np.abs(dq[:, rows]).max() > 0.01, f"q tile {qi}: nothing was written"
+        assert np.linalg.norm(dq[:, rows] - want[:, rows]) < 0.01 * np.linalg.norm(want[:, rows]), f"q tile {qi}"
+
+
+@pytest.mark.parametrize("seq", [4096, 8192, 32768])
+def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
+    """The traced `pallas_call`s at the cells' three lengths (no kernel
+    runs): causal and masked calls have a step for each of the n (n + 1) / 2
+    tiles of the lower triangle, a non-causal call the whole square."""
+    from torchft_tpu.ops import attention as fa
+
+    bh, n = 8, seq // 512
+    tiles = n * (n + 1) // 2
+    qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((bh // 8, seq, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8)
+
+    def fwd(causal, **more):
+        return functools.partial(fa._fa_pallas_call, scale=0.088, causal=causal, **more)
+
+    def bwd(causal, **more):
+        return functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=causal, **more)
+
+    assert pallas_call_grids(fwd(True), qkv, qkv, qkv) == {"tpuft_fa_fwd": (bh, tiles)}
+    assert pallas_call_grids(bwd(True), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (bh, tiles)}
+    assert pallas_call_grids(lambda q, k, v, m: fwd(True, kv_group=8)(q, k, v, mask=m), qkv, kv, kv, mask) == {
+        "tpuft_dsa_attn_fwd": (bh, tiles)}
+    assert pallas_call_grids(lambda q, k, v, o, l, g, m: bwd(True, kv_group=8)(q, k, v, o, l, g, mask=m),
+                             qkv, kv, kv, qkv, lse, qkv, mask) == {"tpuft_dsa_attn_bwd_dkdv_dq": (bh, tiles)}
+    assert pallas_call_grids(fwd(False), qkv, qkv, qkv) == {"tpuft_fa_fwd": (bh, n, n)}
+    assert pallas_call_grids(bwd(False), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (bh, n, n)}
+    # the walk's tables: the forward's row by row (step t is the packed
+    # mask's tile t), the backward's column by column
+    rows, cols = (np.asarray(t) for t in fa._Walk(True, seq, seq, 512, 512).tables)
+    assert [(int(i), int(j)) for i, j in zip(rows[:4], cols[:4])] == [(0, 0), (1, 0), (1, 1), (2, 0)]
+    assert (np.asarray(fa._tri(rows, cols)) == np.arange(tiles)).all() and (cols <= rows).all()
+    rows, cols = (np.asarray(t) for t in fa._Walk(True, seq, seq, 512, 512, kv_major=True).tables)
+    assert (cols[:n] == 0).all() and (rows[:n] == np.arange(n)).all() and (rows[n], cols[n]) == (1, 1)
+    assert len(rows) == tiles and (cols <= rows).all() and (np.diff(cols) >= 0).all()
+
+
 def test_fused_cross_entropy_matches_and_grads() -> None:
     """The fused lm-head CE op (XLA fallback path) vs the straightforward
     materialized formulation: values and grads."""

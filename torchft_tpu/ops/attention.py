@@ -24,9 +24,22 @@ Design notes (MXU/HBM-minded):
     time to attention by that substring, and a `tpuft_fa_bwd_dq` in a
     trace says the one-pass form did not engage.  Off-TPU the same math
     is expressed in XLA with the scores materialized;
-  - grid layout (batch*heads, outer_blocks, inner_blocks) with the
-    reduction axis innermost: TPU executes the innermost grid dimension
-    sequentially, which is what makes the VMEM scratch accumulator legal.
+  - grid layout: the reduction axis innermost — TPU executes the innermost
+    grid dimension sequentially, which is what makes the VMEM scratch
+    accumulator legal.  A call that is causal over one sequence (``causal``
+    or a mask, ``seq_q == seq_k``) walks the tiles that hold a visible pair
+    and no others: its grid is (batch*heads, T), T = n (n + 1) / 2 for n
+    square tiles a side, and two scalar-prefetched int32 tables give step
+    t's (q tile, kv tile) to the kernel and to every index map (`_Walk`).
+    The forward goes row by row (kv tiles 0 .. qi under q tile qi: start at
+    the first, emit at the diagonal), the backward column by column (q
+    tiles ki .. n - 1 over kv tile ki: dk/dv start at the diagonal and emit
+    at the last row; the dq rows of q tile qi are complete, and cast into
+    the output, at THEIR diagonal step).  A tile above the diagonal has no
+    step, so nothing is issued or fetched for it.  Every other call keeps
+    the rectangle (batch*heads, outer_blocks, inner_blocks): there is
+    nothing to skip.  The choice reads ``causal``, the mask and the
+    operands' shapes, and nothing else.
 
 Query and key share one head width (``d_qk``), value and output another
 (``d_v``): equal for plain multi-head attention, 192 / 128 for latent
@@ -43,6 +56,7 @@ the kernel does not tile (seq not divisible by the block size).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -77,21 +91,114 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     return min(512, seq_q), min(512, seq_k)
 
 
-def _fa_kernel(
-    q_ref, k_ref, v_ref, *rest,
-    scale: float, causal: bool, block_q: int, block_k: int, num_k: int, masked: bool = False,
-):
+@dataclasses.dataclass(frozen=True)
+class _Walk:
+    """The order in which a kernel's grid visits its (q tile, kv tile) pairs,
+    and the one place that decides it: ``triangular`` where the call is
+    causal over one sequence (``causal`` or masked, ``seq_q == seq_k``), so
+    that only the tiles (qi, ki) with a visible pair, ``ki * block_k <= qi *
+    block_q + block_q - 1``, have a grid step; else the whole rectangle.
+    ``kv_major`` walks column by column (the q axis innermost) instead of
+    row by row.  Tiles need not be square (ops/sparse_attention.py's are
+    256 x 512)."""
+
+    causal: bool
+    seq_q: int
+    seq_k: int
+    block_q: int
+    block_k: int
+    kv_major: bool = False
+
+    @property
+    def triangular(self) -> bool:
+        return self.causal and self.seq_q == self.seq_k
+
+    @property
+    def num_q(self) -> int:
+        return self.seq_q // self.block_q
+
+    @property
+    def num_k(self) -> int:
+        return self.seq_k // self.block_k
+
+    @functools.cached_property
+    def tables(self) -> tuple:
+        """() for the rectangle, else (qi, ki): int32 [T], the visible
+        tiles in the walk's order — the kernel's scalar-prefetch operands."""
+        import numpy as np
+
+        if not self.triangular:
+            return ()
+        qi, ki = np.indices((self.num_q, self.num_k), dtype=np.int32)
+        visible = ki * self.block_k <= qi * self.block_q + self.block_q - 1
+        if self.kv_major:
+            return qi.T[visible.T], ki.T[visible.T]
+        return qi[visible], ki[visible]
+
+    def grid(self, outer: int) -> tuple:
+        if self.triangular:
+            return (outer, len(self.tables[0]))
+        return (outer, self.num_k, self.num_q) if self.kv_major else (outer, self.num_q, self.num_k)
+
+    def spec(self, block: tuple, at):
+        """A `BlockSpec` whose block index is ``at(b, qi, ki)``, by tile,
+        whatever this walk's grid."""
+        from jax.experimental import pallas as pl
+
+        if self.triangular:
+            return pl.BlockSpec(block, lambda b, t, qi, ki: at(b, qi[t], ki[t]))
+        return pl.BlockSpec(block, (lambda b, j, i: at(b, i, j)) if self.kv_major else at)
+
+    def grid_spec(self, outer: int, in_specs, out_specs, scratch_shapes=()):
+        from jax.experimental.pallas import tpu as pltpu
+
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(self.tables), grid=self.grid(outer),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+
+    def semantics(self, outer: str) -> tuple:
+        return (outer,) + ("arbitrary",) * (len(self.grid(1)) - 1)
+
+    # inside a kernel, whose leading refs are the tables:
+
+    def tile(self, refs):
+        """(qi, ki) of this grid step, and the kernel's refs without the tables."""
+        from jax.experimental import pallas as pl
+
+        if self.triangular:
+            t = pl.program_id(1)
+            return refs[0][t], refs[1][t], refs[2:]
+        if self.kv_major:
+            return pl.program_id(2), pl.program_id(1), refs
+        return pl.program_id(1), pl.program_id(2), refs
+
+    def visible(self, qi, ki):
+        """Whether the tile holds a visible pair: True, statically, in a
+        triangular walk, which has no other steps."""
+        if self.causal and not self.triangular:
+            return ki * self.block_k <= qi * self.block_q + self.block_q - 1
+        return True
+
+    def last_k(self, qi):
+        """The last kv tile a walk visits under q tile qi."""
+        return (qi * self.block_q + self.block_q - 1) // self.block_k if self.triangular else self.num_k - 1
+
+    def first_q(self, ki):
+        """The first q tile a walk visits over kv tile ki."""
+        return (ki * self.block_k) // self.block_q if self.triangular else 0
+
+
+def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
     """``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
     per-pair mask (ops/sparse_attention.py), decides what a query sees in
     place of the causal triangle; ``causal`` still says which tiles are
     empty."""
     from jax.experimental import pallas as pl
 
+    qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
+    causal, block_q, block_k = walk.causal, walk.block_q, walk.block_k
     mask_ref = rest[0] if masked else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[1:] if masked else rest
-
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -99,8 +206,9 @@ def _fa_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: kv blocks strictly above the diagonal contribute nothing.
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
+    # Causal, on a rectangle: kv blocks strictly above the diagonal
+    # contribute nothing.
+    run = walk.visible(qi, ki)
 
     @pl.when(run)
     def _step():
@@ -136,7 +244,7 @@ def _fa_kernel(
         m_scr[:, 0:1] = m_cur
         l_scr[:, 0:1] = l_new
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(ki == walk.last_k(qi))
     def _emit():
         l = l_scr[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -149,8 +257,8 @@ def _fa_kernel(
 
 def _tri(i, j):
     """Where tile (i, j), j <= i, of a lower triangle lies when the tiles are
-    stored row by row; a j beyond the diagonal is held to it."""
-    return i * (i + 1) // 2 + jnp.minimum(j, i)
+    stored row by row (a triangular walk visits no other tile)."""
+    return i * (i + 1) // 2 + j
 
 
 def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
@@ -166,41 +274,40 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     bh, seq_q, d = q.shape  # d: query and key; dv: value and output
     seq_k, dv = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
-    num_k = seq_k // block_k
-    grid = (bh, seq_q // block_q, num_k)
-    kernel = functools.partial(
-        _fa_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_k=num_k, masked=mask is not None,
-    )
+    assert mask is None or seq_q == seq_k, "a packed mask is one sequence's lower triangle"
+    walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k)
+    spec = walk.spec
     operands, mask_specs = (q, k, v), []
     if mask is not None:
         heads = bh // mask.shape[0]
         operands += (mask,)
-        mask_specs = [pl.BlockSpec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0))]
+        mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0))]
     out, lse_padded = pl.pallas_call(
-        kernel,
+        functools.partial(_fa_kernel, walk=walk, scale=scale, masked=mask is not None),
         out_shape=(
             jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, _LANE), jnp.float32),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b // kv_group, j, 0)),
-        ] + mask_specs,
-        out_specs=(
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+        grid_spec=walk.grid_spec(
+            bh,
+            in_specs=[
+                spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                spec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
+                spec((1, block_k, dv), lambda b, i, j: (b // kv_group, j, 0)),
+            ] + mask_specs,
+            out_specs=(
+                spec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
+                spec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max
+                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum
+                pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
+            ],
         ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
-        ],
         interpret=interpret,
         name="tpuft_fa_fwd" if mask is None else "tpuft_dsa_attn_fwd",
-    )(*operands)
+    )(*walk.tables, *operands)
     return out, lse_padded[:, :, 0]
 
 
@@ -252,23 +359,20 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
     return p, ds
 
 
-def _fa_bwd_dkdv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    scale: float, causal: bool, block_q: int, block_k: int, num_q: int,
-    num_k: int, with_dq: bool, masked: bool = False,
-):
-    """Flash backward with the q axis innermost: dk/dv accumulate in VMEM
-    scratch across the sequential inner q dimension.  With ``with_dq`` (the
-    one-pass form) a third scratch holds the head's whole dq row in f32:
-    the tile (ki, qi) adds ``ds @ k`` into its rows qi, and the last kv
-    block's steps cast the row, block by block, into the dq output, whose
-    one block a head stays in VMEM until the head is done — the s/p/dp/ds
-    tile work that dominates on the VPU is computed once, and dq never
-    exists in f32 outside VMEM."""
+def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked: bool = False):
+    """Flash backward with the q axis innermost (a ``kv_major`` walk): dk/dv
+    accumulate in VMEM scratch across the q tiles over one kv tile.  With
+    ``with_dq`` (the one-pass form) a third scratch holds the head's whole
+    dq row in f32: the tile (ki, qi) adds ``ds @ k`` into its rows qi, and
+    the step of the LAST kv tile that q tile qi sees (its diagonal step in a
+    triangular walk, the last kv block's on a rectangle) casts those rows
+    into the dq output, whose one block a head stays in VMEM until the head
+    is done — the s/p/dp/ds tile work that dominates on the VPU is computed
+    once, and dq never exists in f32 outside VMEM."""
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = walk.tile(refs)
+    causal, block_q, block_k = walk.causal, walk.block_q, walk.block_k
     mask_ref = rest[0] if masked else None  # as `_fa_kernel`'s
     dk_ref, dv_ref, *rest = rest[1:] if masked else rest
     if with_dq:
@@ -277,14 +381,14 @@ def _fa_bwd_dkdv_kernel(
     else:
         dk_scr, dv_scr = rest
 
-    @pl.when(qi == 0)
+    @pl.when(qi == walk.first_q(ki))
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # Causal: a q block strictly above this kv block's diagonal contributes
-    # nothing.
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    # Causal, on a rectangle: a q block strictly above this kv block's
+    # diagonal contributes nothing.
+    run = walk.visible(qi, ki)
 
     @pl.when(run)
     def _step():
@@ -316,49 +420,45 @@ def _fa_bwd_dkdv_kernel(
             def _add():
                 dq_scr[q_rows, :] += dq_tile
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(qi == walk.num_q - 1)
     def _emit():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
     if with_dq:
-        # Rows qi are complete once the last kv block has had its turn at
-        # them (a turn the causal rule may have skipped).
-        @pl.when(ki == num_k - 1)
+        # Rows qi are complete once the last kv tile they see has had its
+        # turn at them: this step, after its own `ds @ k` above, where the
+        # walk is triangular (kv tile qi is the first step of its column);
+        # on a rectangle a turn the causal rule may have skipped.
+        @pl.when(ki == walk.last_k(qi))
         def _emit_dq():
             dq_ref[0, q_rows, :] = dq_scr[q_rows, :].astype(dq_ref.dtype)
 
 
-def _fa_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, scale: float, causal: bool, block_q: int, block_k: int, num_k: int,
-):
+def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     """dq-only second pass, kv axis innermost, for a dq row too long to
     stay in VMEM: dq accumulates one (block_q, d) f32 block at a time, so
     memory stays O(block) whatever the length (at the price of recomputing
     p/ds once more)."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr) = walk.tile(refs)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = (ki * block_k <= qi * block_q + block_q - 1) if causal else True
-
-    @pl.when(run)
+    @pl.when(walk.visible(qi, ki))
     def _step():
         _, ds = _bwd_block(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            scale=scale, causal=walk.causal, block_q=walk.block_q, block_k=walk.block_k,
         )
         dq_scr[...] += jax.lax.dot(
             ds, k_ref[0], preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(ki == walk.last_k(qi))
     def _emit():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -378,7 +478,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     bh, seq_q, d = q.shape
     seq_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
-    num_q, num_k = seq_q // block_q, seq_k // block_k
+    causal = causal or mask is not None
     # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
     # rowsum(do * o) is O(S*D) and computed once here instead of per tile.
     lse = lse[:, None, :]
@@ -386,55 +486,56 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, None, :]
 
-    q_spec_ji = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    do_spec_ji = pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0))
-    k_spec_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    v_spec_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0))
-    k_in_ji, v_in_ji = k_spec_ji, v_spec_ji
-    if kv_group != 1:
-        k_in_ji = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b // kv_group, j, 0))
-        v_in_ji = pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b // kv_group, j, 0))
-    row_spec_ji = pl.BlockSpec((1, 1, seq_q), lambda b, j, i: (b, 0, 0))
+    def specs(walk):
+        """The six operands' specs and dk's and dv's, by tile, for a walk."""
+        spec = walk.spec
+        row = spec((1, 1, seq_q), lambda b, i, j: (b, 0, 0))
+        return [
+            spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            spec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
+            spec((1, block_k, d_v), lambda b, i, j: (b // kv_group, j, 0)),
+            spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+            row, row,
+        ], [
+            spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+        ]
+
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True)
+    in_specs, out_specs = specs(walk)
     one_pass = _dq_row_resident(seq_q, d)
-    operands, mask_specs = (q, k, v, g, lse, delta), []
+    operands = (q, k, v, g, lse, delta)
     if mask is not None:
-        assert one_pass, "the masked backward keeps the dq row in VMEM"
+        assert one_pass and seq_q == seq_k, "the masked backward keeps one sequence's dq row in VMEM"
         heads = bh // mask.shape[0]
         operands += (mask,)
-        mask_specs = [pl.BlockSpec((1, 1, block_q, block_k), lambda b, j, i: (b // heads, _tri(i, j), 0, 0))]
+        in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0)))
     out_shape = [
         jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype),
         jax.ShapeDtypeStruct((bh,) + v.shape[1:], v.dtype),
     ]
-    out_specs = [k_spec_ji, v_spec_ji]
     scratch = [
         pltpu.VMEM((block_k, d), jnp.float32),
         pltpu.VMEM((block_k, d_v), jnp.float32),
     ]
     vmem_limit = None
     if one_pass:
-        # dq's block is a head's whole row and ignores both inner axes: it
-        # leaves VMEM once, when the head is done.
+        # dq's block is a head's whole row and ignores the tile: it leaves
+        # VMEM once, when the head is done.
         out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
-        out_specs.append(pl.BlockSpec((1, seq_q, d), lambda b, j, i: (b, 0, 0)))
+        out_specs.append(walk.spec((1, seq_q, d), lambda b, i, j: (b, 0, 0)))
         scratch.append(pltpu.VMEM((seq_q, d), jnp.float32))
         vmem_limit = (
             seq_q * d * (4 + 2 * q.dtype.itemsize) + _TILE_VMEM_BYTES
         )
     outs = pl.pallas_call(
         functools.partial(
-            _fa_bwd_dkdv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_q=num_q, num_k=num_k,
-            with_dq=one_pass, masked=mask is not None,
+            _fa_bwd_dkdv_kernel, walk=walk, scale=scale, with_dq=one_pass, masked=mask is not None,
         ),
         out_shape=tuple(out_shape),
-        grid=(bh, num_k, num_q),
-        in_specs=[q_spec_ji, k_in_ji, v_in_ji, do_spec_ji,
-                  row_spec_ji, row_spec_ji] + mask_specs,
-        out_specs=tuple(out_specs),
-        scratch_shapes=scratch,
+        grid_spec=walk.grid_spec(bh, in_specs, tuple(out_specs), scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=walk.semantics("parallel"),
             vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
@@ -442,32 +543,23 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         # substrings: the one-pass name has to contain `tpuft_fa_bwd_dkdv`.
         name=("tpuft_dsa_attn_bwd_dkdv_dq" if mask is not None
               else "tpuft_fa_bwd_dkdv_dq" if one_pass else "tpuft_fa_bwd_dkdv"),
-    )(*operands)
+    )(*walk.tables, *operands)
     if one_pass:
         dk, dv, dq = outs
         return dq, dk, dv
     dk, dv = outs
 
     # Second pass for a row over the budget: dq with the kv axis innermost.
-    q_spec_ij = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    do_spec_ij = pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0))
-    k_spec_ij = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    v_spec_ij = pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0))
-    row_spec_ij = pl.BlockSpec((1, 1, seq_q), lambda b, i, j: (b, 0, 0))
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k)
+    in_specs, _ = specs(walk)
     dq = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_k=num_k,
-        ),
+        functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(bh, num_q, num_k),
-        in_specs=[q_spec_ij, k_spec_ij, v_spec_ij, do_spec_ij,
-                  row_spec_ij, row_spec_ij],
-        out_specs=q_spec_ij,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        grid_spec=walk.grid_spec(
+            bh, in_specs, in_specs[0], [pltpu.VMEM((block_q, d), jnp.float32)]),
         interpret=interpret,
         name="tpuft_fa_bwd_dq",
-    )(q, k, v, g, lse, delta)
+    )(*walk.tables, q, k, v, g, lse, delta)
     return dq, dk, dv
 
 

@@ -35,13 +35,19 @@ float32 array in HBM:
     operands, so the two cannot disagree;
   - ``tpuft_dsa_attn_fwd`` / ``tpuft_dsa_attn_bwd_dkdv_dq``: the flash
     kernels of ops/attention.py with the mask tile in place of the causal
-    triangle (they visit every causal tile: skipping tiles the selection
-    leaves empty is the next step);
+    triangle.  They visit every causal tile and no other (a triangular
+    grid walk, `ops.attention._Walk`); at weights drawn from a seed the
+    selection leaves no tile past the dense prefix empty, so there is
+    nothing more for a walk to skip (PERF.md section 7);
   - ``tpuft_dsa_index_loss``: per tile the 32 heads' probabilities again
     (one QK^T a head, from the saved log-sum-exps) summed into pbar, the KL
     row sums, and IN THE SAME PASS the loss's gradient with respect to a, b
     and w — d loss / d I = (softmax(I) - pbar) / (B S) on the selection —
     which the backward pass only scales by the loss's cotangent.
+
+``tpuft_dsa_mask`` and ``tpuft_dsa_index_loss`` walk their 256 x 512 tiles
+the same way: one grid step for each tile that holds a visible pair, row by
+row, none for a tile above the diagonal.
 
 Off-TPU, under a multi-device mesh and for shapes the kernels do not tile,
 the same mathematics runs in plain XLA with dense [S, S] scores and
@@ -236,31 +242,28 @@ def _select_pallas(a, bt, w, topk: int, interpret: bool = False):
 # -- tpuft_dsa_mask ---------------------------------------------------------------
 
 
-def _mask_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, mask_ref, *, heads: int, bq: int, bk: int):
-    from jax.experimental import pallas as pl
-
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    visible = ki * bk <= qi * bq + bq - 1
-
-    # A tile above the diagonal has no place in the packed triangle: its
-    # steps leave the diagonal tile, where the index map holds them, alone.
-    @pl.when(visible)
-    def _score():
-        keys = _sortable(_index_tile(a_ref, bt_ref[0], w_ref[0], heads))
-        rows, cols = _tile_positions(qi, ki, bq, bk)
-        tau = tau_ref[0]
-        keep = ((keys > tau) | ((keys == tau) & (cols <= cut_ref[0]))) & (rows >= cols)
-        mask_ref[0, 0] = jnp.where(keep, 1, 0).astype(jnp.int8)
+def _walk(seq: int) -> "_fa._Walk":
+    """This file's (bq, bk) tiles, causal over one sequence: a step for each
+    tile with a visible pair, row by row (`ops.attention._Walk`)."""
+    return _fa._Walk(True, seq, seq, min(BLOCK_Q, seq), min(BLOCK_K, seq))
 
 
-def _mask_spec(seq: int):
+def _mask_kernel(*refs, walk, heads: int):
+    # A tile above the diagonal has no place in the packed triangle, and no
+    # step in the walk.
+    qi, ki, (a_ref, bt_ref, w_ref, tau_ref, cut_ref, mask_ref) = walk.tile(refs)
+    keys = _sortable(_index_tile(a_ref, bt_ref[0], w_ref[0], heads))
+    rows, cols = _tile_positions(qi, ki, walk.block_q, walk.block_k)
+    tau = tau_ref[0]
+    keep = ((keys > tau) | ((keys == tau) & (cols <= cut_ref[0]))) & (rows >= cols)
+    mask_ref[0, 0] = jnp.where(keep, 1, 0).astype(jnp.int8)
+
+
+def _mask_spec(walk):
     """How this file's (bq, bk) tiles read the packed mask, whose tiles are
     the flash kernels' (tile, bk): a half of a tile where bq is."""
-    from jax.experimental import pallas as pl
-
-    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
-    sub = _fa._block_sizes(seq, seq)[0] // bq
-    return pl.BlockSpec((1, 1, bq, bk), lambda b, i, j: (b, _fa._tri(i // sub, j), i % sub, 0))
+    sub = _fa._block_sizes(walk.seq_q, walk.seq_k)[0] // walk.block_q
+    return walk.spec((1, 1, walk.block_q, walk.block_k), lambda b, i, j: (b, _fa._tri(i // sub, j), i % sub, 0))
 
 
 def _mask_pallas(a, bt, w, tau, cut, interpret: bool = False):
@@ -270,36 +273,38 @@ def _mask_pallas(a, bt, w, tau, cut, interpret: bool = False):
     from jax.experimental import pallas as pl
 
     batch, heads, seq, di = a.shape
-    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
+    walk = _walk(seq)
+    spec, bq, bk = walk.spec, walk.block_q, walk.block_k
     tile = _fa._block_sizes(seq, seq)[0]
     n = seq // tile
-    row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    row_spec = spec((1, bq, 1), lambda b, i, j: (b, i, 0))
     return pl.pallas_call(
-        functools.partial(_mask_kernel, heads=heads, bq=bq, bk=bk),
+        functools.partial(_mask_kernel, walk=walk, heads=heads),
         out_shape=jax.ShapeDtypeStruct((batch, n * (n + 1) // 2, tile, bk), jnp.int8),
-        grid=(batch, seq // bq, seq // bk),
-        in_specs=[
-            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, di, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
-            row_spec, row_spec,
-        ],
-        out_specs=_mask_spec(seq),
+        grid_spec=walk.grid_spec(
+            batch,
+            in_specs=[
+                spec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+                spec((1, di, bk), lambda b, i, j: (b, 0, j)),
+                spec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+                row_spec, row_spec,
+            ],
+            out_specs=_mask_spec(walk),
+        ),
         interpret=interpret,
         name="tpuft_dsa_mask",
-    )(a, bt, w, tau, cut)
+    )(*walk.tables, a, bt, w, tau, cut)
 
 
 # -- tpuft_dsa_index_loss -----------------------------------------------------------
 
 
-def _index_loss_kernel(q_ref, k_ref, lse_ref, a_ref, bt_ref, w_ref, z_ref, mask_ref,
-                       kl_ref, da_ref, dbt_ref, dw_ref, kl_scr,
-                       *, q_heads: int, kv_heads: int, heads: int, scale: float, inv_rows: float,
-                       bq: int, bk: int, num_k: int):
+def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, scale: float, inv_rows: float):
     from jax.experimental import pallas as pl
 
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, ki, (q_ref, k_ref, lse_ref, a_ref, bt_ref, w_ref, z_ref, mask_ref,
+             kl_ref, da_ref, dbt_ref, dw_ref, kl_scr) = walk.tile(refs)
+    bq, bk = walk.block_q, walk.block_k
 
     @pl.when((qi == 0) & (ki == 0))
     def _init_keys():
@@ -311,39 +316,38 @@ def _index_loss_kernel(q_ref, k_ref, lse_ref, a_ref, bt_ref, w_ref, z_ref, mask_
         da_ref[...] = jnp.zeros_like(da_ref)
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    @pl.when(ki * bk <= qi * bq + bq - 1)
-    def _tile():
-        group = q_heads // kv_heads
+    # (every step of the walk is a tile with a visible pair)
+    group = q_heads // kv_heads
 
-        def one_head(h, total):
-            s = jax.lax.dot_general(
-                q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
-            return total + jnp.exp(s - lse)
+    def one_head(h, total):
+        s = jax.lax.dot_general(
+            q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
+        return total + jnp.exp(s - lse)
 
-        keep = mask_ref[0, 0].astype(jnp.int32) != 0
-        total = jax.lax.fori_loop(0, q_heads, one_head, jnp.zeros((bq, bk), jnp.float32))
-        pbar = jnp.where(keep, total * (1.0 / q_heads), 0.0)
-        bt, w = bt_ref[0], w_ref[0]
-        log_q = _index_tile(a_ref, bt, w, heads) - z_ref[0]
-        kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - log_q), 0.0)
-        kl_scr[:, 0:1] += jnp.sum(kl, axis=1, keepdims=True)
-        # d loss / d I on the selection; the index heads' products once more
-        # for what each passes back
-        g = jnp.where(keep, jnp.exp(jnp.where(keep, log_q, 0.0)) - pbar, 0.0) * inv_rows
-        cols0 = pl.multiple_of(ki * bk, bk)
-        for j in range(heads):
-            a_j = a_ref[0, j]
-            r = jax.lax.dot(a_j, bt, preferred_element_type=jnp.float32)
-            dw_ref[0, :, j:j + 1] += jnp.sum(g * jnp.maximum(r, 0.0), axis=1, keepdims=True)
-            e = jnp.where(r > 0.0, g * w[:, j:j + 1], 0.0).astype(a_j.dtype)
-            da_ref[0, j] += jax.lax.dot_general(
-                e, bt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)      # e @ b: [bq, Di]
-            dbt_ref[0, :, pl.ds(cols0, bk)] += jax.lax.dot_general(
-                a_j, e, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)     # a^T @ e: [Di, bk]
+    keep = mask_ref[0, 0].astype(jnp.int32) != 0
+    total = jax.lax.fori_loop(0, q_heads, one_head, jnp.zeros((bq, bk), jnp.float32))
+    pbar = jnp.where(keep, total * (1.0 / q_heads), 0.0)
+    bt, w = bt_ref[0], w_ref[0]
+    log_q = _index_tile(a_ref, bt, w, heads) - z_ref[0]
+    kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - log_q), 0.0)
+    kl_scr[:, 0:1] += jnp.sum(kl, axis=1, keepdims=True)
+    # d loss / d I on the selection; the index heads' products once more
+    # for what each passes back
+    g = jnp.where(keep, jnp.exp(jnp.where(keep, log_q, 0.0)) - pbar, 0.0) * inv_rows
+    cols0 = pl.multiple_of(ki * bk, bk)
+    for j in range(heads):
+        a_j = a_ref[0, j]
+        r = jax.lax.dot(a_j, bt, preferred_element_type=jnp.float32)
+        dw_ref[0, :, j:j + 1] += jnp.sum(g * jnp.maximum(r, 0.0), axis=1, keepdims=True)
+        e = jnp.where(r > 0.0, g * w[:, j:j + 1], 0.0).astype(a_j.dtype)
+        da_ref[0, j] += jax.lax.dot_general(
+            e, bt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)      # e @ b: [bq, Di]
+        dbt_ref[0, :, pl.ds(cols0, bk)] += jax.lax.dot_general(
+            a_j, e, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)     # a^T @ e: [Di, bk]
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(ki == walk.last_k(qi))
     def _emit():
         kl_ref[0] = jnp.broadcast_to(kl_scr[:, 0:1], kl_ref.shape[1:])
 
@@ -357,43 +361,44 @@ def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bo
 
     batch, q_heads, seq, d = q.shape
     kv_heads, heads, di = k.shape[1], a.shape[1], a.shape[3]
-    bq, bk = min(BLOCK_Q, seq), min(BLOCK_K, seq)
-    num_k = seq // bk
+    walk = _walk(seq)
+    spec, bq, bk = walk.spec, walk.block_q, walk.block_k
     lane = _pallas_util.LANE
     kl, da, dbt, dw = pl.pallas_call(
         functools.partial(
-            _index_loss_kernel, q_heads=q_heads, kv_heads=kv_heads, heads=heads, scale=scale,
-            inv_rows=1.0 / (batch * seq), bq=bq, bk=bk, num_k=num_k),
+            _index_loss_kernel, walk=walk, q_heads=q_heads, kv_heads=kv_heads, heads=heads, scale=scale,
+            inv_rows=1.0 / (batch * seq)),
         out_shape=(
             jax.ShapeDtypeStruct((batch, seq, lane), jnp.float32),
             jax.ShapeDtypeStruct(a.shape, jnp.float32),
             jax.ShapeDtypeStruct(bt.shape, jnp.float32),
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
         ),
-        grid=(batch, seq // bq, num_k),
-        in_specs=[
-            pl.BlockSpec((1, q_heads, bq, d), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, kv_heads, bk, d), lambda b, i, j: (b, 0, j, 0)),
-            pl.BlockSpec((1, q_heads, 1, seq), lambda b, i, j: (b, 0, 0, 0)),   # whole rows, as the flash kernels'
-
-            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, di, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            _mask_spec(seq),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bq, lane), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
-            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),   # a sequence's whole row, resident
-            pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+        grid_spec=walk.grid_spec(
+            batch,
+            in_specs=[
+                spec((1, q_heads, bq, d), lambda b, i, j: (b, 0, i, 0)),
+                spec((1, kv_heads, bk, d), lambda b, i, j: (b, 0, j, 0)),
+                spec((1, q_heads, 1, seq), lambda b, i, j: (b, 0, 0, 0)),   # whole rows, as the flash kernels'
+                spec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+                spec((1, di, bk), lambda b, i, j: (b, 0, j)),
+                spec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+                spec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+                _mask_spec(walk),
+            ],
+            out_specs=(
+                spec((1, bq, lane), lambda b, i, j: (b, i, 0)),
+                spec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
+                spec((1, di, seq), lambda b, i, j: (b, 0, 0)),   # a sequence's whole row, resident
+                spec((1, bq, heads), lambda b, i, j: (b, i, 0)),
+            ),
+            scratch_shapes=[pltpu.VMEM((bq, lane), jnp.float32)],
         ),
-        scratch_shapes=[pltpu.VMEM((bq, lane), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=walk.semantics("arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="tpuft_dsa_index_loss",
-    )(q, k, lse[:, :, None, :], a, bt, w, z, mask)
+    )(*walk.tables, q, k, lse[:, :, None, :], a, bt, w, z, mask)
     return kl[:, :, 0], da, dbt, dw
 
 
