@@ -218,11 +218,10 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, k, n, experts, assignm
         assert _has_kernel(text, name), f"{name} is not in the compiled program"
 
 
-def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
-    """The benchmark's `olmoe-1b-7b` configuration as `benchmark/programs/moe_lm.py`
-    hands it to `TrainStep`: the whole gradient program at the published
-    widths, all 64 experts, with the grouped-matmul, attention and CE kernels
-    inside, and room for AdamW's moments beside it on a 16 GiB chip."""
+@pytest.fixture(scope="module")
+def olmoe_program(topo, one_chip):
+    """The benchmark's `olmoe-1b-7b` gradient program at the published widths,
+    compiled once for the tests that read it: (compiled, weight shapes)."""
     import os
     import sys
 
@@ -232,14 +231,23 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mo
     from benchmark.spec import Benchmark
     from torchft_tpu.ops import _pallas_util
 
-    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
     bench = Benchmark(root)
     config, traffic = bench.config("olmoe-1b-7b"), bench.traffic("steady-1g")
     shapes = jax.eval_shape(lambda: bench.reference("moe_lm").make_weights(1, config))
     params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
     tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
-    _, step = bench.program("moe_lm").train_step(config, topo.devices[0])
-    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(_pallas_util, "on_tpu", lambda: True)
+        _, step = bench.program("moe_lm").train_step(config, topo.devices[0])
+        return step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile(), shapes
+
+
+def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(olmoe_program) -> None:
+    """The benchmark's `olmoe-1b-7b` configuration as `benchmark/programs/moe_lm.py`
+    hands it to `TrainStep`: the whole gradient program at the published
+    widths, all 64 experts, with the grouped-matmul, attention and CE kernels
+    inside, and room for AdamW's moments beside it on a 16 GiB chip."""
+    compiled, shapes = olmoe_program
     text = compiled.as_text()
     for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_fa_fwd", "tpuft_ce_lse", "tpuft_ce_dlogits"):
         assert _has_kernel(text, name), f"{name} is not in the compiled program"
@@ -248,6 +256,50 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mo
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     assert n_params == 625_616_896
     assert resident < 14 * 2**30, f"the step needs {resident} bytes with AdamW's moments, a v5e chip has 16 GiB"
+
+
+def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> None:
+    """The op map of that program as the TPU's compiler leaves it
+    (`obs.opmap.op_names` over the compiled text, what `TrainStep.op_map`
+    returns on the chip): it holds every instruction the entry runs; every
+    kernel and every fusion with an op_name path is booked to a part of the
+    vocabulary; what stays unattributed — buffers the compiler allocates or
+    concatenates in place, and `jnp.cumsum`, whose lowering is cached without
+    its name stack (`op_name="reduce_window_sum"`) — holds none of the ten
+    largest results by the compiler's own shapes."""
+    import re
+
+    from torchft_tpu.obs import opmap
+
+    text = olmoe_program[0].as_text()
+    ops = opmap.op_names(text, detail=True)
+    entry = text[text.index("\nENTRY "):]
+    ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
+    assert len(ran) > 500 and set(ran) <= set(ops)
+    booked = {name: opmap.booked(entry) for name, entry in ops.items()}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"dsa_index", "dsa_select", "ffn", "shared_expert"}
+    assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
+    kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
+    assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
+    assert all(booked[name][0] in ("attn", "head_loss", "experts") for name in kernels), kernels
+    working = [n for n, e in ops.items() if e["opcode"] in ("dot", "convolution", "fusion", "custom-call")]
+    nameless = [n for n in working if booked[n][0] is None]
+    assert nameless and len(nameless) <= len(working) // 6
+    for name in nameless:
+        entry = ops[name]
+        assert entry["op_name"] in ("", "reduce_window_sum") and not any(
+            opmap.part_of(path + "/") for path in entry.get("inside", {})), (name, entry)
+
+    def result_bytes(name: str) -> int:
+        kind, dims = re.search(r"%" + re.escape(name) + r" = \(?(\w+)\[([\d,]*)\]", text).groups()
+        count = 1
+        for dim in filter(None, dims.split(",")):
+            count *= int(dim)
+        return count * {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2}.get(kind, 4)
+
+    largest = sorted(working, key=result_bytes, reverse=True)[:10]
+    assert result_bytes(largest[0]) >= 64 * 2048 * 1024 * 4  # an expert matrix's gradient
+    assert not set(largest) & set(nameless), [(n, ops[n]) for n in largest if n in nameless]
 
 
 def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:

@@ -261,12 +261,15 @@ def test_group_env_and_compile_cache_reach_the_children(tmp_path, monkeypatch) -
     environment already names, else `<repo>/.jax_cache`."""
     from torchft_tpu.launch import export_compile_cache
 
-    env = {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}
-    assert export_compile_cache(env) == "/somewhere/else" and len(env) == 1
+    # beside the place: the key holds the programs' metadata (the model's scopes) and no source line
+    key = {"JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY": "1", "JAX_TRACEBACK_IN_LOCATIONS_LIMIT": "0"}
+    env = {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else", "JAX_TRACEBACK_IN_LOCATIONS_LIMIT": "10"}
+    assert export_compile_cache(env) == "/somewhere/else"
+    assert env == dict(key, JAX_COMPILATION_CACHE_DIR="/somewhere/else", JAX_TRACEBACK_IN_LOCATIONS_LIMIT="10")
     env = {}
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert export_compile_cache(env) == os.path.join(repo, ".jax_cache")
-    assert env == {"JAX_COMPILATION_CACHE_DIR": os.path.join(repo, ".jax_cache")}
+    assert env == dict(key, JAX_COMPILATION_CACHE_DIR=os.path.join(repo, ".jax_cache"))
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     show = (

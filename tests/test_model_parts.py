@@ -1,0 +1,271 @@
+"""The parts of the gradient program, by name.
+
+The model writes `jax.named_scope`s (one vocabulary, `obs/spans.PARTS`) around
+its forward computation; JAX's transforms carry them into the backward pass and
+into what `jax.checkpoint` computes again; `TrainStep.op_map` reads them back
+out of the compiled program, instruction by instruction.  Checked here for a
+small model of each of the benchmark's five configurations, on the CPU: every
+instruction that does work maps to a part, the parts and directions are the
+architecture's, and the scopes change no instruction.
+"""
+
+import contextlib
+import dataclasses
+import os
+import sys
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from hlo_text import canonical, without_metadata  # noqa: E402
+from torchft_tpu.models import TransformerConfig, init_params  # noqa: E402
+from torchft_tpu.models.transformer import loss_and_counters  # noqa: E402
+from torchft_tpu.obs import opmap  # noqa: E402
+from torchft_tpu.obs.spans import PARTS  # noqa: E402
+from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
+
+SEQ = 64
+_BASE = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128, max_seq=SEQ,
+             dtype=jnp.float32, remat=False, scan_unroll=4)
+# The five configurations' shapes in small: what each has that the others lack.
+MODELS = {
+    "internlm2": TransformerConfig(**_BASE),
+    "mistral": TransformerConfig(**dict(_BASE, d_model=128, n_heads=8, d_ff=448, vocab_size=128)),
+    "olmoe": TransformerConfig(**dict(_BASE, n_kv_heads=4, qk_norm=True, moe_experts=8, moe_top_k=2, d_ff=64,
+                                      moe_capacity_factor=None, moe_norm_topk=False, moe_z_coef=0.001)),
+    "moonlight": TransformerConfig(**dict(
+        _BASE, n_layers=3, n_kv_heads=4, mla_kv_rank=32, mla_nope_dim=16, mla_rope_dim=8, mla_v_dim=16,
+        moe_experts=8, moe_top_k=2, d_ff=32, moe_capacity_factor=None, moe_held=(2, 2), moe_score="sigmoid",
+        moe_route_scale=2.446, moe_shared_experts=2, moe_aux_coef=0.001, moe_dense_layers=1, dense_d_ff=128,
+        remat=True, remat_keeps_attention=True)),
+    "keye": TransformerConfig(**dict(
+        _BASE, head_dim=32, qk_norm_per_head=True, dsa_index_heads=3, dsa_index_dim=16, dsa_topk=16,
+        moe_experts=8, moe_top_k=2, d_ff=48, moe_capacity_factor=None, moe_held=(2, 2), moe_aux_coef=0.001,
+        remat=True, remat_keeps_attention=True)),
+}
+# Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
+HEAVY = ("dot", "convolution", "fusion", "custom-call")
+
+
+def _step_and_arguments(name: str):
+    cfg = MODELS[name]
+    bias = jnp.zeros((cfg.n_sparse_layers, cfg.moe_experts), jnp.float32) if cfg.moe_score == "sigmoid" else None
+    step = TrainStep(ft_init_mesh({"data": 1}, devices=jax.devices()[:1]), optax.adamw(1e-3),
+                     lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias), loss_has_counters=True)
+    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, size=(2, SEQ)).astype(np.int32)
+    batch = {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
+    return step, init_params(jax.random.PRNGKey(0), cfg), batch
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """Per model, compiled once: the gradient program's detailed op map (after
+    one run of it) and its optimized text."""
+    found = {}
+
+    def get(name: str):
+        if name not in found:
+            step, params, batch = _step_and_arguments(name)
+            loss, _ = step.grads(params, batch)
+            assert np.isfinite(float(loss))
+            found[name] = (step.op_map(detail=True)["jit_value_and_grad"],
+                           step.lower_grads(params, batch).compile().as_text())
+        return found[name]
+
+    return get
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_every_instruction_that_does_work_has_a_part(programs, name) -> None:
+    ops, _ = programs(name)
+    heavy = {k: v for k, v in ops.items() if v["opcode"] in HEAVY}
+    assert len(heavy) > 10, sorted(ops)
+    nameless = {k: v for k, v in heavy.items() if opmap.booked(v)[0] is None}
+    # What is left carries no op_name at all, its own or inside it: XLA:CPU's own making (the first
+    # half of a reduction it split in two, a slice or a broadcast it wrapped), and little of it
+    assert all(v["op_name"] == "" and not v["inside"] for v in nameless.values()), nameless
+    assert len(nameless) <= len(heavy) // 5, (len(nameless), len(heavy))
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_parts_and_directions_are_the_architectures(programs, name) -> None:
+    cfg, (ops, _) = MODELS[name], programs(name)
+    named = [opmap.booked(v) for v in ops.values()]
+    parts = {p for p, _ in named if p is not None}
+    assert parts <= set(PARTS)
+    expected = {"embed", "norm", "attn_proj", "attn", "head_loss", "stack"}
+    if cfg.moe_experts:
+        expected |= {"router", "experts"}
+    if cfg.moe_experts == 0 or cfg.moe_dense_layers:
+        expected |= {"ffn"}
+    if cfg.moe_shared_experts:
+        expected |= {"shared_expert"}
+    if cfg.dsa_index_heads:
+        expected |= {"dsa_index", "dsa_select"}
+    assert parts == expected
+    directions = {d for p, d in named if p is not None}
+    assert directions == ({"fwd", "bwd", "recompute"} if cfg.remat else {"fwd", "bwd"})
+    # the head and the loss are outside the rematerialised layers; a layer's products are inside
+    assert ("head_loss", "recompute") not in named
+    if cfg.remat:
+        assert ("attn_proj", "recompute") in named and ("experts", "recompute") in named
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_the_scopes_change_no_instruction(programs, name) -> None:
+    """The same program built with `jax.named_scope` doing nothing (patched
+    here, in the test: the source has no switch) compiles to the same
+    optimized HLO once the metadata is gone."""
+    _, scoped = programs(name)
+    assert 'op_name="jit(value_and_grad)/jvp(embed)' in scoped
+    with mock.patch.object(jax, "named_scope", lambda _name: contextlib.nullcontext()):
+        step, params, batch = _step_and_arguments(name)
+        plain = step.lower_grads(params, batch).compile().as_text()
+    assert "jvp(embed)" not in plain and "attn_proj" not in plain
+    assert without_metadata(plain) == without_metadata(scoped)
+    assert canonical(plain) == canonical(scoped)
+
+
+def test_the_op_map_names_both_programs_and_costs_nothing_until_asked(monkeypatch) -> None:
+    """Set-up and steps never lower, compile or read a program's text for the
+    map (counted here); asking does, for the programs that last ran."""
+    from jax._src import stages
+
+    calls = {"lower": 0, "compile": 0, "as_text": 0}
+
+    def counting(cls, attr, key):
+        real = getattr(cls, attr)
+
+        def wrapper(self, *a, **k):
+            calls[key] += 1
+            return real(self, *a, **k)
+
+        monkeypatch.setattr(cls, attr, wrapper)
+
+    counting(stages.Lowered, "compile", "compile")
+    counting(stages.Compiled, "as_text", "as_text")
+    lowered = []
+    step, params, batch = _step_and_arguments("internlm2")
+    for fn in ("_grads_fn", "_apply_fn", "_apply_spec_fn"):
+        jitted = getattr(step, fn)
+
+        class Counted:
+            def __init__(self, inner):
+                self.inner, self.__name__ = inner, inner.__name__
+
+            def __call__(self, *a, **k):
+                return self.inner(*a, **k)
+
+            def lower(self, *a, **k):
+                calls["lower"] += 1
+                lowered.append(self.__name__)
+                return self.inner.lower(*a, **k)
+
+        setattr(step, fn, Counted(jitted))
+    opt = step.init_opt_state(params)
+    for _ in range(3):
+        loss, grads = step.grads(params, batch)
+        params, opt = step.apply(params, opt, grads)
+    assert calls == {"lower": 0, "compile": 0, "as_text": 0}
+    assert step in opmap.train_steps()
+    found = step.op_map()
+    assert calls == {"lower": 2, "compile": 2, "as_text": 2} and sorted(lowered) == ["apply", "value_and_grad"]
+    assert set(found) == {"jit_value_and_grad", "jit_apply"}
+    assert all(isinstance(v, str) for program in found.values() for v in program.values())
+    grad_parts = {opmap.part_of(v) for v in found["jit_value_and_grad"].values()}
+    assert {"ffn", "head_loss", "attn"} <= grad_parts
+    assert {opmap.part_of(v) for v in found["jit_apply"].values()} == {None}  # the optimizer is no part of the model
+
+
+def test_a_train_step_that_is_gone_leaves_the_registry() -> None:
+    import gc
+
+    step, _, _ = _step_and_arguments("internlm2")
+    assert step in opmap.train_steps()
+    ident = id(step)
+    del step
+    gc.collect()
+    assert ident not in {id(s) for s in opmap.train_steps()}
+
+
+@pytest.mark.parametrize("op_name,part,direction", [
+    ("jit(value_and_grad)/jvp(ffn)/dot_general", "ffn", "fwd"),
+    ("jit(value_and_grad)/transpose(jvp(attn_proj))/dot_general", "attn_proj", "bwd"),
+    ("jit(value_and_grad)/transpose(jvp(jvp()))/checkpoint/rematted_computation/attn_proj/norm/mul", "norm", "recompute"),
+    ("jit(value_and_grad)/transpose(jvp(jvp()))/checkpoint/experts/tpuft_gmm_dlhs/pallas_call", "experts", "bwd"),
+    ("jit(value_and_grad)/jvp(attn_proj/norm)/jit(norm)/mul", "norm", "fwd"),
+    ("jit(value_and_grad)/jvp(head_loss)/jit(stack)/transpose", "head_loss", "fwd"),
+    ("jit(value_and_grad)/jvp()/transpose", None, "fwd"),
+    ("params['embed']", None, "fwd"),
+    ("", None, "fwd"),
+])
+def test_an_op_name_is_classified_by_its_innermost_scope(op_name, part, direction) -> None:
+    assert (opmap.part_of(op_name), opmap.direction_of(op_name)) == (part, direction)
+
+
+def test_the_text_reader_takes_what_runs_and_leaves_what_is_fused() -> None:
+    text = """HloModule jit_value_and_grad, is_scheduled=true
+
+FileNames
+1 "model.py"
+
+%fused_computation.3 (p.1: f32[8]) -> f32[8] {
+  %p.1 = f32[8]{0} parameter(0)
+  %inner.1 = f32[8]{0} tanh(%p.1), metadata={op_name="jit(f)/jvp(norm)/tanh"}
+  ROOT %inner.2 = f32[8]{0} multiply(%inner.1, %p.1), metadata={op_name="jit(f)/jvp(ffn)/mul"}
+}
+
+%add.red (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %sum.9 = f32[] add(%a, %b)
+}
+
+%body.1 (s: (f32[8], s32[])) -> (f32[8], s32[]) {
+  %s = (f32[8]{0}, s32[]) parameter(0)
+  %in_loop.1 = f32[8]{0} get-tuple-element(%s), index=0
+  ROOT %looped.2 = (f32[8]{0}, s32[]) tuple(%in_loop.1, %in_loop.1), metadata={op_name="jit(f)/jvp(stack)/while/body/add"}
+}
+
+%cond.1 (s.1: (f32[8], s32[])) -> pred[] {
+  %s.1 = (f32[8]{0}, s32[]) parameter(0)
+  ROOT %less.1 = pred[] constant(false)
+}
+
+ENTRY %main.7 (x: f32[8]) -> f32[] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %fusion.3 = f32[8]{0:T(8)S(1)} fusion(%x), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(f)/jvp(ffn)/mul" stack_frame_id=4}
+  %pair.1 = (f32[8]{0}, s32[]) tuple(%fusion.3, %fusion.3)
+  %while.2 = (f32[8]{0}, s32[]) while(%pair.1), condition=%cond.1, body=%body.1
+  %kernel.4 = (f32[8]{0}, f32[8]{0}) custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/jvp(attn)/tpuft_fa_fwd/pallas_call"}
+  ROOT %reduce.5 = f32[] reduce(%fusion.3, %x), dimensions={0}, to_apply=%add.red, metadata={op_name="jit(f)/jvp(head_loss)/reduce_sum"}
+}
+"""
+    assert opmap.module_name(text) == "jit_value_and_grad"
+    flat = opmap.op_names(text)
+    assert set(flat) == {"x", "fusion.3", "pair.1", "while.2", "kernel.4", "reduce.5", "s", "in_loop.1", "looped.2",
+                         "s.1", "less.1"}
+    assert flat["fusion.3"] == "jit(f)/jvp(ffn)/mul" and flat["pair.1"] == ""
+    detail = opmap.op_names(text, detail=True)
+    assert detail["kernel.4"] == {"op_name": "jit(f)/jvp(attn)/tpuft_fa_fwd/pallas_call", "opcode": "custom-call"}
+    assert detail["fusion.3"]["opcode"] == "fusion"
+    assert detail["fusion.3"]["inside"] == {"jit(f)/jvp(ffn)": 1, "jit(f)/jvp(norm)": 1}  # it straddles two parts
+    assert opmap.booked(detail["fusion.3"]) == ("ffn", "fwd")  # and goes by its own name
+    nameless = {"op_name": "", "opcode": "fusion", "inside": {"jit(f)/transpose(jvp(experts))": 2, "jit(f)/jvp(router)": 1}}
+    assert opmap.booked(nameless) == ("experts", "bwd") and opmap.booked({"op_name": "", "opcode": "copy"}) == (None, "fwd")
+    assert detail["while.2"]["opcode"] == "while" and detail["reduce.5"]["opcode"] == "reduce"
+    # what has no op_name anywhere goes where the nearest reader of its result goes, else where its operands do
+    assert detail["pair.1"] == {"op_name": "", "opcode": "tuple", "near": ["ffn", "fwd"]} and "near" not in detail["fusion.3"]
+    assert opmap.booked(detail["pair.1"]) == ("ffn", "fwd") and opmap.booked(detail["while.2"]) == ("ffn", "fwd")
+    assert detail["in_loop.1"]["near"] == ["stack", "fwd"] and "near" not in detail["less.1"]
+    stripped = without_metadata(text)
+    assert "metadata" not in stripped and "model.py" not in stripped and "%fusion.3 = " in stripped
+    assert canonical(text) == canonical(text.replace("kernel.4", "jvp_kernel_.9"))

@@ -68,6 +68,7 @@ MODULES = [
     "torchft_tpu.coordination",
     "torchft_tpu.metrics",
     "torchft_tpu.obs.spans",
+    "torchft_tpu.obs.opmap",
     "torchft_tpu.obs.report",
     "torchft_tpu.obs.trace",
     "torchft_tpu.obs.flight",

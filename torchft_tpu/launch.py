@@ -50,16 +50,29 @@ __all__ = ["Launcher", "export_compile_cache", "fetch_alerts", "main"]
 def export_compile_cache(env: Optional[MutableMapping[str, str]] = None) -> str:
     """Places JAX's persistent compile cache from OUTSIDE the program and
     returns the directory: where ``JAX_COMPILATION_CACHE_DIR`` is already
-    set, that holds and nothing is touched; otherwise ``<repo>/.jax_cache``
-    (git-ignored) is exported into ``env`` (default: this process's
-    environment) under the same standard variable, so every child inherits
-    it.  The path is part of the cache's key — a directory that moves never
-    hits — and a restarted group re-JITs from disk instead of recompiling.
+    set, that holds; otherwise ``<repo>/.jax_cache`` (git-ignored) is
+    exported into ``env`` (default: this process's environment) under the
+    same standard variable, so every child inherits it.  The path is part of
+    the cache's key — a directory that moves never hits — and a restarted
+    group re-JITs from disk instead of recompiling.
 
-    JAX reads the variable when it is imported, so a process that wants the
+    Beside the place go two settings of the key, each unless the environment
+    already has it.  ``JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=1``: a
+    program's metadata — the ``jax.named_scope`` names of the model's parts,
+    which a profile's device time is booked by (``obs/opmap.py``) — is part
+    of what the key hashes; without it a program whose scopes changed is a
+    hit on an executable that carries the old ones.
+    ``JAX_TRACEBACK_IN_LOCATIONS_LIMIT=0``: an operation's location holds its
+    name stack and no source frame, so the key holds no file or line and a
+    change that only moves lines recompiles nothing (a compiled program's
+    metadata then names no source line either).
+
+    JAX reads the variables when it is imported, so a process that wants the
     cache for ITSELF calls this before its first ``import jax``; no code of
-    this repo sets the location through ``jax.config``."""
+    this repo sets them through ``jax.config``."""
     env = os.environ if env is None else env
+    env.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "1")
+    env.setdefault("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", "0")
     return env.setdefault(
         "JAX_COMPILATION_CACHE_DIR", os.path.join(_REPO_ROOT, ".jax_cache")
     )

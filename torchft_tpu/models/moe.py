@@ -364,36 +364,40 @@ def moe_layer(
     n_exp = router.shape[1]
     held = w_gate.shape[0]
     T = B * S
-    logits, probs, gate_vals, gate_idx = route(
-        x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale)
-    stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
-    gate_vals, gate_idx = gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k)
-    xf = x.reshape(T, E)
-    if capacity_factor is None:
-        if mesh is not None and "expert" in mesh.axis_names and mesh.shape["expert"] > 1:
-            raise ValueError(
-                "the dropless MoE path runs on one device (all the experts, or the share it is "
-                "told it holds); a mesh with an 'expert' axis takes the capacity-bound path "
-                "(set moe_capacity_factor)"
+    # The scopes are parts of obs/spans.PARTS: they name the work for a profile.
+    with jax.named_scope("router"):
+        logits, probs, gate_vals, gate_idx = route(
+            x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale)
+        stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
+        gate_vals, gate_idx = gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k)
+    with jax.named_scope("experts"):
+        xf = x.reshape(T, E)
+        if capacity_factor is None:
+            if mesh is not None and "expert" in mesh.axis_names and mesh.shape["expert"] > 1:
+                raise ValueError(
+                    "the dropless MoE path runs on one device (all the experts, or the share it is "
+                    "told it holds); a mesh with an 'expert' axis takes the capacity-bound path "
+                    "(set moe_capacity_factor)"
+                )
+            assert 0 <= held_first and held_first + held <= n_exp, (held_first, held, n_exp)
+            y, rows_held, dropped = _dropless_ffn(
+                xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down,
+                n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh,
             )
-        assert 0 <= held_first and held_first + held <= n_exp, (held_first, held, n_exp)
-        y, rows_held, dropped = _dropless_ffn(
-            xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down,
-            n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh,
-        )
-    else:
-        if held != n_exp:
-            raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis)")
-        y, dropped = _capacity_ffn(
-            xf, gate_vals, gate_idx, w_gate, w_up, w_down,
-            capacity=moe_capacity(T, n_exp, top_k, capacity_factor), dtype=dtype, mesh=mesh, rules=rules,
-        )
-        rows_held = jnp.asarray(T * top_k, jnp.int32)
-    stats.update(dropped=dropped, rows_held=rows_held, assignments=jnp.asarray(T * top_k, jnp.int32))
-    y = y.reshape(B, S, E).astype(x.dtype)
+        else:
+            if held != n_exp:
+                raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis)")
+            y, dropped = _capacity_ffn(
+                xf, gate_vals, gate_idx, w_gate, w_up, w_down,
+                capacity=moe_capacity(T, n_exp, top_k, capacity_factor), dtype=dtype, mesh=mesh, rules=rules,
+            )
+            rows_held = jnp.asarray(T * top_k, jnp.int32)
+        stats.update(dropped=dropped, rows_held=rows_held, assignments=jnp.asarray(T * top_k, jnp.int32))
+        y = y.reshape(B, S, E).astype(x.dtype)
     if shared is not None:
-        s_gate, s_up, s_down = (w.astype(dtype) for w in shared)
-        y = y + ((jax.nn.silu(x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
+        with jax.named_scope("shared_expert"):
+            s_gate, s_up, s_down = (w.astype(dtype) for w in shared)
+            y = y + ((jax.nn.silu(x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
     return y, stats
 
 

@@ -400,7 +400,9 @@ def _mla_qkv(cfg: TransformerConfig, h, w, positions):
     H, Dn, Dr, Dv, R = cfg.n_heads, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim, cfg.mla_kv_rank
     q = (h @ w["wq"].astype(cfg.dtype)).reshape(B, S, H, Dn + Dr)
     latent = h @ w["wkv_a"].astype(cfg.dtype)                       # [B, S, R + Dr]
-    kv = rms_norm(latent[..., :R], w["kv_norm"], cfg.rms_eps) @ w["wkv_b"].astype(cfg.dtype)
+    with jax.named_scope("norm"):
+        kv = rms_norm(latent[..., :R], w["kv_norm"], cfg.rms_eps)
+    kv = kv @ w["wkv_b"].astype(cfg.dtype)
     kv = kv.reshape(B, S, H, Dn + Dv)
     q_rope = _rope(q[..., Dn:], positions, cfg.rope_theta)
     k_rope = _rope(latent[..., None, R:], positions, cfg.rope_theta)  # [B, S, 1, Dr]
@@ -418,7 +420,9 @@ def _index_operands(cfg: TransformerConfig, h, w, positions):
     J, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
     hd = jax.lax.stop_gradient(h)
     a = _rope((hd @ w["wi_q"].astype(cfg.dtype)).reshape(B, S, J, Di), positions, cfg.rope_theta)
-    b = _layer_norm(hd @ w["wi_k"].astype(cfg.dtype), w["wi_k_norm"], w["wi_k_bias"], cfg.rms_eps)
+    b = hd @ w["wi_k"].astype(cfg.dtype)
+    with jax.named_scope("norm"):
+        b = _layer_norm(b, w["wi_k_norm"], w["wi_k_bias"], cfg.rms_eps)
     b = _rope(b[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
     weights = (hd @ w["wi_w"].astype(cfg.dtype)).astype(jnp.float32) * (J ** -0.5 * Di ** -0.5)
     return a.transpose(0, 2, 1, 3), b, weights
@@ -429,7 +433,9 @@ def _sparse_attention(cfg: TransformerConfig, mesh, h, w, positions, q, k, v):
     Returns (attention [B, H, S, Dh], {"dsa_index_loss", "dsa_selected"})."""
     from torchft_tpu.ops.sparse_attention import sparse_attention
 
-    a, b, weights = _index_operands(cfg, h, w, positions)
+    with jax.named_scope("dsa_index"):
+        a, b, weights = _index_operands(cfg, h, w, positions)
+    # `sparse_attention` names its own parts: dsa_select, attn, dsa_index
     attn, index_loss, selected = sparse_attention(q, k, v, a, b, weights, topk=cfg.dsa_topk, mesh=mesh)
     return attn, {"dsa_index_loss": index_loss, "dsa_selected": selected.astype(jnp.uint32)}
 
@@ -450,38 +456,48 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     H, KV = cfg.n_heads, cfg.n_kv_heads
     sparse = cfg.moe_experts > 0 if sparse is None else sparse
 
-    h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
-    if cfg.mla_kv_rank:
-        q, k, v = _mla_qkv(cfg, h, w, positions)
-        KV = H
-    else:
-        Dh = cfg.d_head
-        q = h @ w["wq"].astype(cfg.dtype)
-        if cfg.qk_norm:
-            q = rms_norm(q, w["q_norm"], cfg.rms_eps)
-        q = q.reshape(B, S, H, Dh)
-        k = h @ w["wk"].astype(cfg.dtype)
-        if cfg.qk_norm:
-            k = rms_norm(k, w["k_norm"], cfg.rms_eps)
-        k = k.reshape(B, S, KV, Dh)
-        if cfg.qk_norm_per_head:
-            q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
-        v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
-    k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
-    v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
+    # The scopes are the parts a profile's device time is booked to
+    # (obs/spans.PARTS); they name the work and change no instruction.
+    with jax.named_scope("norm"):
+        h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
+    with jax.named_scope("attn_proj"):
+        if cfg.mla_kv_rank:
+            q, k, v = _mla_qkv(cfg, h, w, positions)
+            KV = H
+        else:
+            Dh = cfg.d_head
+            q = h @ w["wq"].astype(cfg.dtype)
+            if cfg.qk_norm:
+                with jax.named_scope("norm"):
+                    q = rms_norm(q, w["q_norm"], cfg.rms_eps)
+            q = q.reshape(B, S, H, Dh)
+            k = h @ w["wk"].astype(cfg.dtype)
+            if cfg.qk_norm:
+                with jax.named_scope("norm"):
+                    k = rms_norm(k, w["k_norm"], cfg.rms_eps)
+            k = k.reshape(B, S, KV, Dh)
+            if cfg.qk_norm_per_head:
+                with jax.named_scope("norm"):
+                    q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
+            v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
+        k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
+        v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
     dsa = None
     if cfg.dsa_index_heads:
         attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
     else:
-        attn = _attention(cfg, mesh, q, k, v)        # [B, H, S, Dv]
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * attn.shape[-1])
-    x = x + (attn @ w["wo"].astype(cfg.dtype))
-    x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
+        with jax.named_scope("attn"):
+            attn = _attention(cfg, mesh, q, k, v)        # [B, H, S, Dv]
+    with jax.named_scope("attn_proj"):
+        attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * attn.shape[-1])
+        x = x + (attn @ w["wo"].astype(cfg.dtype))
+        x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
-    h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
+    with jax.named_scope("norm"):
+        h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
     if sparse:
         from torchft_tpu.models.moe import moe_layer
 
@@ -503,11 +519,13 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
             mesh=mesh,
             rules=rules,
         )
-        x = x + y
+        with jax.named_scope("experts"):
+            x = x + y
     else:
-        gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
-        up = h @ w["w_up"].astype(cfg.dtype)
-        x = x + ((gate * up) @ w["w_down"].astype(cfg.dtype))
+        with jax.named_scope("ffn"):
+            gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
+            up = h @ w["w_up"].astype(cfg.dtype)
+            x = x + ((gate * up) @ w["w_down"].astype(cfg.dtype))
         aux = {} if dsa is not None else jnp.zeros((), jnp.float32)
     if dsa is not None:
         aux = dict(aux, **dsa)
@@ -549,10 +567,12 @@ def _decoder(
         pos = jnp.asarray(
             zigzag_permutation(S, mesh.shape["sequence"]), dtype=jnp.int32
         )
-    positions = jnp.broadcast_to(pos, (B, S))
+    with jax.named_scope("attn_proj"):  # RoPE's operand
+        positions = jnp.broadcast_to(pos, (B, S))
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
     if cfg.moe_dense_layers:
         def dense_body(x, w):
@@ -561,7 +581,8 @@ def _decoder(
         if cfg.remat:
             dense_body = _remat(cfg, dense_body)
         for i in range(cfg.moe_dense_layers):  # a leading layer or three: always a static loop
-            x = dense_body(x, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]))
+            with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
+                x = dense_body(x, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]))
 
     stacked = params["layers"]
     if router_bias is not None:
@@ -586,21 +607,25 @@ def _decoder(
         aux_total = jnp.zeros((), jnp.float32)
         aux_layers = []
         for i in range(cfg.n_sparse_layers):
-            w_i = jax.tree.map(lambda a, i=i: a[i], stacked)
-            x, aux = body(x, w_i)
+            with jax.named_scope("stack"):
+                w_i = jax.tree.map(lambda a, i=i: a[i], stacked)
+                x, aux = body(x, w_i)
             if stats:
                 aux_layers.append(aux)
             else:
                 aux_total = aux_total + aux
         if stats:
-            return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
+            with jax.named_scope("stack"):
+                return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
         return x, aux_total
-    x, aux_layers = jax.lax.scan(
-        body, x, stacked, unroll=cfg.scan_unroll
-    )
-    if stats:
-        return x, _over_layers(aux_layers)
-    return x, jnp.sum(aux_layers)
+    # The scan's own slicing of the stacked weights is `stack`.
+    with jax.named_scope("stack"):
+        x, aux_layers = jax.lax.scan(
+            body, x, stacked, unroll=cfg.scan_unroll
+        )
+        if stats:
+            return x, _over_layers(aux_layers)
+        return x, jnp.sum(aux_layers)
 
 
 def _remat(cfg: TransformerConfig, body):
@@ -649,22 +674,24 @@ def head(
 
     Shared by the dense path (forward_with_aux) and the pipelined path
     (parallel/pipeline.pipeline_loss_fn) so the two can never diverge."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
-    # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
-    logits = jnp.matmul(
-        x, params["lm_head"].astype(cfg.dtype), preferred_element_type=jnp.float32
-    )
-    return constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
+        # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
+        logits = jnp.matmul(
+            x, params["lm_head"].astype(cfg.dtype), preferred_element_type=jnp.float32
+        )
+        return constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
 
 
 def token_cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Mean next-token CE, computed as logsumexp - target_logit rather than
     materializing the full [B, S, vocab] log-softmax: the logits array is
     the single biggest activation, and one extra copy is pure HBM traffic."""
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    return jnp.mean(lse - tgt)
+    with jax.named_scope("head_loss"):
+        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return jnp.mean(lse - tgt)
 
 
 def forward(
@@ -703,12 +730,13 @@ def lm_head_loss(
 
     B, S, E = x.shape
     if fused_ce_applicable(B * S, E, padded_vocab(cfg.vocab_size), mesh):
-        h = rms_norm(x, params["final_norm"], cfg.rms_eps)
-        w = params["lm_head"].astype(cfg.dtype)
-        # A vocabulary slice that no block divides runs the same kernels over
-        # zero-padded columns whose logits count as -inf.
-        fused = fused_linear_cross_entropy if cfg.vocab_size % 128 == 0 else fused_linear_cross_entropy_padded
-        return fused(h.reshape(B * S, E), w, targets.reshape(B * S))
+        with jax.named_scope("head_loss"):
+            h = rms_norm(x, params["final_norm"], cfg.rms_eps)
+            w = params["lm_head"].astype(cfg.dtype)
+            # A vocabulary slice that no block divides runs the same kernels over
+            # zero-padded columns whose logits count as -inf.
+            fused = fused_linear_cross_entropy if cfg.vocab_size % 128 == 0 else fused_linear_cross_entropy_padded
+            return fused(h.reshape(B * S, E), w, targets.reshape(B * S))
     return token_cross_entropy(head(params, x, cfg, mesh, rules), targets)
 
 
@@ -750,20 +778,21 @@ def loss_and_counters(
     x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
     loss = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
     counters = {}
-    if cfg.dsa_index_heads:
-        # The indexer's own loss: no weight outside the indexer has a gradient from it.
-        loss = loss + cfg.dsa_loss_coef * aux["dsa_index_loss"]
-        B, S = batch["tokens"].shape
-        visible = cfg.n_layers * B * (S * (S + 1) // 2)
-        assert visible < 2 ** 32, "the pair counters are uint32"
-        counters.update(dsa_pairs_selected=aux["dsa_selected"], dsa_pairs_visible=jnp.uint32(visible),
-                        dsa_index_loss=aux["dsa_index_loss"])
-    if cfg.moe_experts == 0:
+    with jax.named_scope("head_loss"):  # the loss's other terms and the counters
+        if cfg.dsa_index_heads:
+            # The indexer's own loss: no weight outside the indexer has a gradient from it.
+            loss = loss + cfg.dsa_loss_coef * aux["dsa_index_loss"]
+            B, S = batch["tokens"].shape
+            visible = cfg.n_layers * B * (S * (S + 1) // 2)
+            assert visible < 2 ** 32, "the pair counters are uint32"
+            counters.update(dsa_pairs_selected=aux["dsa_selected"], dsa_pairs_visible=jnp.uint32(visible),
+                            dsa_index_loss=aux["dsa_index_loss"])
+        if cfg.moe_experts == 0:
+            return loss, counters
+        loss = loss + cfg.moe_aux_coef * aux["balance"]
+        if cfg.moe_z_coef:
+            loss = loss + cfg.moe_z_coef * aux["z"]
+        counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
+        if cfg.moe_held is not None:
+            counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
         return loss, counters
-    loss = loss + cfg.moe_aux_coef * aux["balance"]
-    if cfg.moe_z_coef:
-        loss = loss + cfg.moe_z_coef * aux["z"]
-    counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
-    if cfg.moe_held is not None:
-        counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
-    return loss, counters

@@ -31,7 +31,6 @@ from typing import Callable, List, Optional, Sequence, Tuple
 __all__ = [
     "WorkerMetrics",
     "bucketize",
-    "render_histogram",
     "render_histogram_counts",
     "HOP_LATENCY_BOUNDS",
     "HOP_BYTES_BOUNDS",
@@ -131,24 +130,6 @@ def render_histogram_counts(
         lines.append(f"{name}_sum{suffix} {round(total, 6)}")
         lines.append(f"{name}_count{suffix} {cum}")
     return "\n".join(lines) + "\n"
-
-
-def render_histogram(
-    name: str,
-    help_: str,
-    bounds: Sequence[float],
-    series: Sequence[Tuple[Sequence[Tuple[str, str]], Sequence[float]]],
-) -> str:
-    """One-shot convenience over :func:`bucketize` +
-    :func:`render_histogram_counts` for raw observations.  Only suitable
-    for single renders of a complete value set — repeated scrapes over a
-    SLIDING window must accumulate via ``bucketize`` instead, or the
-    exposed counters go backwards."""
-    folded = []
-    for labels, values in series:
-        counts, total = bucketize(bounds, values)
-        folded.append((labels, counts, total))
-    return render_histogram_counts(name, help_, bounds, folded)
 
 
 class WorkerMetrics:

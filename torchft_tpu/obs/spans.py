@@ -43,6 +43,7 @@ __all__ = [
     "PHASES",
     "OVERLAPPED_PHASES",
     "SUBSPANS",
+    "PARTS",
     "Span",
     "SubSpan",
     "SpanTracker",
@@ -145,6 +146,32 @@ SUBSPANS = {
     "apply_dispatch": "ft_step",
     "counters_note": "ft_step",
 }
+
+
+# Parts of the gradient program: the ``jax.named_scope`` names the model
+# (models/transformer.py, models/moe.py, ops/sparse_attention.py) writes around
+# its forward computation, one vocabulary for every architecture.  Scopes nest,
+# and JAX's transforms carry them into the backward pass
+# (``transpose(jvp(ffn))``) and into what ``jax.checkpoint`` computes again
+# (``checkpoint/rematted_computation/ffn``), so a compiled instruction's part
+# is the innermost of these names on its ``op_name`` path (obs/opmap.py).
+#   embed — the token gather (its backward is the scatter-add);
+#   norm — every RMS / layer norm but the final one; attn_proj — the q/k/v/o
+#     products, latent attention's low-rank path, RoPE, the reshapes and
+#     transposes around the heads; attn — the attention call: kernels and
+#     whatever XLA puts around them; dsa_index — the indexer's operands and
+#     its loss; dsa_select — the selection and the mask built from it;
+#   ffn — the dense gate / up / down; router — scores, top-k and statistics;
+#     experts — row table, row moves, grouped matmuls, gate weighting;
+#     shared_expert — the SwiGLU every token passes beside the routed ones;
+#   head_loss — final norm, head product, cross-entropy, the loss's terms
+#     and counters; stack — a layer's slice of the stacked weights (in the
+#     backward pass: the per-layer gradients padded and summed into the
+#     stacked gradient) and the stacking of the layers' statistics.
+PARTS = (
+    "embed", "norm", "attn_proj", "attn", "dsa_index", "dsa_select", "ffn",
+    "router", "experts", "shared_expert", "head_loss", "stack",
+)
 
 
 @functools.cache

@@ -432,17 +432,23 @@ def _dsa_xla(q, k, v, a, bt, w, topk: int, scale: float):
     by autodiff.  q [B, H, S, D], k/v [B, KV, S, D]."""
     batch, q_heads, seq, _ = q.shape
     group = q_heads // k.shape[1]
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    scores = index_scores(a, bt, w)
-    keep = selection_mask(jax.lax.stop_gradient(scores), topk)[:, None]       # [B, 1, S, S]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v).astype(q.dtype)
-    pbar = jax.lax.stop_gradient(jnp.mean(p, axis=1))                         # [B, S, S]
-    log_q = jax.nn.log_softmax(jnp.where(keep[:, 0], scores, -jnp.inf), axis=-1)
-    kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - jnp.where(keep[:, 0], log_q, 0.0)), 0.0)
-    loss = jnp.sum(kl) / (batch * seq)
-    return out, loss, jnp.sum(keep, dtype=jnp.int32)
+    with jax.named_scope("attn"):
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    with jax.named_scope("dsa_index"):
+        scores = index_scores(a, bt, w)
+    with jax.named_scope("dsa_select"):
+        keep = selection_mask(jax.lax.stop_gradient(scores), topk)[:, None]   # [B, 1, S, S]
+        selected = jnp.sum(keep, dtype=jnp.int32)
+    with jax.named_scope("attn"):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v).astype(q.dtype)
+    with jax.named_scope("dsa_index"):
+        pbar = jax.lax.stop_gradient(jnp.mean(p, axis=1))                     # [B, S, S]
+        log_q = jax.nn.log_softmax(jnp.where(keep[:, 0], scores, -jnp.inf), axis=-1)
+        kl = jnp.where(pbar > 0.0, pbar * (jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)) - jnp.where(keep[:, 0], log_q, 0.0)), 0.0)
+        loss = jnp.sum(kl) / (batch * seq)
+    return out, loss, selected
 
 
 # -- the kernels' path, one custom_vjp --------------------------------------------------
@@ -475,17 +481,22 @@ def _dsa_kernels(q, k, v, a, bt, w, topk: int, scale: float):
 
 
 def _dsa_kernels_fwd(q, k, v, a, bt, w, topk, scale):
-    tau, cut, z = _select_pallas(a, bt, w, topk)
-    # kept as [B, S]: a trailing axis of one would be stored a lane tile wide
-    tau, cut = _kept(tau[..., 0], "tau"), _kept(cut[..., 0], "cut")
-    mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
-    out, lse = _masked_flash_fwd(q, k, v, mask, scale)
-    out, lse = _kept(out, "out"), _kept(lse, "lse")
-    kl, da, dbt, dw = _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale)
-    loss = _kept(jnp.sum(kl) / (kl.shape[0] * kl.shape[1]), "loss")
-    da = _kept(da.astype(a.dtype), "da")
-    dbt, dw = _kept(dbt.astype(bt.dtype), "db"), _kept(dw, "dw")
-    selected = _kept(jnp.sum(mask, dtype=jnp.int32), "selected")
+    # The scopes are parts of obs/spans.PARTS: they name the work for a profile.
+    with jax.named_scope("dsa_select"):
+        tau, cut, z = _select_pallas(a, bt, w, topk)
+        # kept as [B, S]: a trailing axis of one would be stored a lane tile wide
+        tau, cut = _kept(tau[..., 0], "tau"), _kept(cut[..., 0], "cut")
+        mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
+    with jax.named_scope("attn"):
+        out, lse = _masked_flash_fwd(q, k, v, mask, scale)
+        out, lse = _kept(out, "out"), _kept(lse, "lse")
+    with jax.named_scope("dsa_index"):
+        kl, da, dbt, dw = _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale)
+        loss = _kept(jnp.sum(kl) / (kl.shape[0] * kl.shape[1]), "loss")
+        da = _kept(da.astype(a.dtype), "da")
+        dbt, dw = _kept(dbt.astype(bt.dtype), "db"), _kept(dw, "dw")
+    with jax.named_scope("dsa_select"):
+        selected = _kept(jnp.sum(mask, dtype=jnp.int32), "selected")
     return (out, loss, selected), (q, k, v, a, bt, w, tau, cut, out, lse, da, dbt, dw)
 
 
@@ -493,11 +504,14 @@ def _dsa_kernels_bwd(topk, scale, res, cotangents):
     q, k, v, a, bt, w, tau, cut, out, lse, da, dbt, dw = res
     g_out, g_loss, _ = cotangents
     # from the kept thresholds: scored once more, never selected again
-    mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
-    dq, dk, dv = _masked_flash_bwd(q, k, v, out, lse, g_out, mask, scale)
-    g_loss = g_loss.astype(jnp.float32)
-    return (dq, dk, dv, (g_loss * da.astype(jnp.float32)).astype(a.dtype),
-            (g_loss * dbt.astype(jnp.float32)).astype(bt.dtype), (g_loss * dw).astype(w.dtype))
+    with jax.named_scope("dsa_select"):
+        mask = _mask_pallas(a, bt, w, tau[..., None], cut[..., None])
+    with jax.named_scope("attn"):
+        dq, dk, dv = _masked_flash_bwd(q, k, v, out, lse, g_out, mask, scale)
+    with jax.named_scope("dsa_index"):
+        g_loss = g_loss.astype(jnp.float32)
+        return (dq, dk, dv, (g_loss * da.astype(jnp.float32)).astype(a.dtype),
+                (g_loss * dbt.astype(jnp.float32)).astype(bt.dtype), (g_loss * dw).astype(w.dtype))
 
 
 _dsa_kernels.defvjp(_dsa_kernels_fwd, _dsa_kernels_bwd)
@@ -537,8 +551,9 @@ def sparse_attention(
     batch, q_heads, seq, d = q.shape
     assert q_heads % k.shape[1] == 0, "query heads must be a multiple of kv heads"
     scale = scale if scale is not None else d ** -0.5
-    bt = index_k.transpose(0, 2, 1)  # [B, Di, S]: a key tile is a lane-aligned slice
-    w = index_w.astype(jnp.float32)
+    with jax.named_scope("dsa_index"):
+        bt = index_k.transpose(0, 2, 1)  # [B, Di, S]: a key tile is a lane-aligned slice
+        w = index_w.astype(jnp.float32)
     if kernels_apply(seq, d, index_q.shape[-1], mesh):
         return _dsa_kernels(q, k, v, index_q, bt, w, topk, scale)
     return _dsa_xla(q, k, v, index_q, bt, w, topk, scale)

@@ -139,6 +139,7 @@ class TrainStep:
         mesh = self.ftmesh.mesh
 
         def value_and_grad(params, batch):
+            self._retraced.add("value_and_grad")  # runs when JAX traces, never in a step
             # Shardings are explicit NamedShardings; the abstract mesh is
             # set only so the kernel gate (ops/_pallas_util.kernels_apply)
             # sees the mesh this program is traced for even when the loss
@@ -154,6 +155,7 @@ class TrainStep:
         def apply(params, opt_state, grads):
             import optax
 
+            self._retraced.add("apply")
             updates, opt_state = self.tx.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state
 
@@ -178,6 +180,14 @@ class TrainStep:
         # Manager step ft_step dispatched it in (None: the split form).
         self.last_counters: Any = None
         self._counters_step: Optional[int] = None
+        # What `op_map` lowers again: per traced function the jitted program
+        # that ran and its arguments' shapes, types and placements, noted on
+        # the call after a trace (`_retraced`) and on no other.
+        self._retraced: set = set()
+        self._ran: dict = {}
+        from torchft_tpu.obs import opmap
+
+        opmap.register(self)
 
     # -- pure compute --------------------------------------------------------
 
@@ -193,8 +203,40 @@ class TrainStep:
         ``last_counters``."""
         return self._loss_and_grads(params, batch)
 
+    def _note_run(self, fn, *args) -> None:
+        """After a call of the jitted `fn`: where it was traced anew since
+        the last note, keeps what `op_map` needs to lower it again (a donated
+        argument still knows its shape, type and placement)."""
+        if fn.__name__ in self._retraced:
+            self._retraced.discard(fn.__name__)
+            leaves, treedef = jax.tree.flatten(args)
+            self._ran[fn.__name__] = (fn, treedef, [
+                # an uncommitted array compiles as an argument without a placement
+                (x.shape, x.dtype, x.sharding if getattr(x, "committed", False) else None) for x in leaves])
+
+    def op_map(self, detail: bool = False) -> dict:
+        """{program: {instruction name: op_name}} of the gradient and the
+        update program as they last ran (`jit_value_and_grad`, `jit_apply`:
+        the names a profile's `XLA Modules` line gives their executions), read
+        from the compiled executables' text (`obs.opmap.op_names`; `detail`
+        as there).  A device operation of a profile is named by its
+        instruction, and the op_name says which part of the model it came
+        from and in which direction (`obs.opmap.part_of`, `direction_of`).
+        Lowers and compiles again — from the jit and compile caches — so it
+        is for after the steps of interest, never inside one."""
+        from torchft_tpu.obs import opmap
+
+        programs = {}
+        for fn, treedef, leaves in self._ran.values():
+            args = jax.tree.unflatten(
+                treedef, [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype, sharding in leaves])
+            text = fn.lower(*args).compile().as_text()
+            programs[opmap.module_name(text)] = opmap.op_names(text, detail=detail)
+        return programs
+
     def _loss_and_grads(self, params, batch, step: Optional[int] = None):
         out, grads = self._grads_fn(params, batch)
+        self._note_run(self._grads_fn, params, batch)
         if not self.loss_has_counters:
             return out, grads
         loss, self.last_counters = out
@@ -229,7 +271,12 @@ class TrainStep:
         return self._grads_fn.lower(params, batch)
 
     def apply(self, params, opt_state, grads):
-        return self._apply_fn(params, opt_state, grads)
+        return self._apply(self._apply_fn, params, opt_state, grads)
+
+    def _apply(self, fn, params, opt_state, grads):
+        out = fn(params, opt_state, grads)
+        self._note_run(fn, params, opt_state, grads)
+        return out
 
     # -- fault-tolerant step -------------------------------------------------
 
@@ -332,8 +379,8 @@ class TrainStep:
             grads = self._averager.allreduce(grads)
             if self._overlap_resolved:
                 with spans.sub("apply_dispatch", step=step):
-                    new_params, new_opt = self._apply_spec_fn(
-                        params, opt_state, grads
+                    new_params, new_opt = self._apply(
+                        self._apply_spec_fn, params, opt_state, grads
                     )
                 committed = frame.fields["committed"] = manager.should_commit()
                 if committed:
@@ -342,7 +389,7 @@ class TrainStep:
             committed = frame.fields["committed"] = manager.should_commit()
             if committed:
                 with spans.sub("apply_dispatch", step=step):
-                    params, opt_state = self._apply_fn(params, opt_state, grads)
+                    params, opt_state = self._apply(self._apply_fn, params, opt_state, grads)
             # Only a COMMITTED step resolves the decision: an aborted vote
             # means _apply_fn never ran, so the allocator peak would exclude
             # the optimizer-apply footprint the budget must cover.
