@@ -302,6 +302,78 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     assert not set(largest) & set(nameless), [(n, ops[n]) for n in largest if n in nameless]
 
 
+def _instructions(text: str) -> list:
+    """(opcode, elements of the result) of every instruction with one array
+    for a result in a compiled program's entry computation: what runs as an
+    instruction of its own (a `reshape` inside a fusion's body costs what the
+    fusion costs)."""
+    import math
+    import re
+
+    found = []
+    text = text[text.index("ENTRY "):]
+    for m in re.finditer(r"^\s*(?:ROOT )?%?[\w.-]+ = \w+\[([\d,]*)\](?:\{[^}]*\})? ([\w-]+)\(", text, re.M):
+        found.append((m.group(2), math.prod(int(d) for d in m.group(1).split(",") if d)))
+    return found
+
+
+@pytest.mark.parametrize("tokens,k,n_exp,held", [(16384, 6, 64, 8), (32768, 8, 128, 16)],
+                         ids=["moonlight_top6", "keye_top8"])
+def test_expert_row_moves_compile_without_a_relayout_for_v5e(one_chip, tokens, k, n_exp, held) -> None:
+    """`models/moe.py`'s three gathers of a token's k rows at the Moonlight
+    cell's shapes (16,384 tokens, 6 choices, the 8 held experts' buffer of
+    25,600 rows) and at the Keye cell's (32,768 tokens, 8 choices, 67,584
+    rows), 2,048 columns: no `reshape`, `copy` or `transpose` re-tiles the
+    T * k * E gathered elements.  At 6 choices that is so because the k axis
+    leads (six rows on an eight-row tile, where k is the middle axis: the
+    relayout PR 36 took out); at 8 the token-major form is a bitcast."""
+    from torchft_tpu.models import moe
+
+    width = 2048
+    n_rows = moe.held_rows(tokens * k, n_exp, held, 2.0)
+    assert n_rows == {6: 25600, 8: 67584}[k] and moe._k_leads(k) == (k == 6)
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)  # noqa: E731
+    rows, dy = shape((n_rows, width), jnp.bfloat16), shape((tokens, width), jnp.bfloat16)
+    gates, dest = shape((tokens, k), jnp.float32), shape((tokens, k), jnp.int32)
+    row_assignment = shape((n_rows,), jnp.int32)
+
+    def combine_and_its_gradients(rows, gates, dest, row_assignment, dy):
+        out, vjp = jax.vjp(lambda r, g: moe._tokens_of_rows(r, g, dest, row_assignment, False), rows, gates)
+        return out, vjp(dy)
+
+    def dispatch_gradient(drows, dest, row_assignment):
+        return moe._rows_bwd(False, (row_assignment, dest), drows)[0]
+
+    for fn, args in ((combine_and_its_gradients, (rows, gates, dest, row_assignment, dy)),
+                     (dispatch_gradient, (rows, dest, row_assignment))):
+        found = _instructions(_compile(fn, *args))
+        assert ("fusion", tokens * k * width) in found, "the text was not read: no gather of T * k rows found"
+        moved = [(op, n) for op, n in found if op in ("reshape", "copy", "transpose") and n >= tokens * k * width]
+        assert not moved, f"{fn.__name__}: the gathered rows are laid out again: {moved}"
+
+
+def test_sigmoid_router_compiles_without_a_gather_for_v5e(one_chip) -> None:
+    """`route`'s sigmoid branch at the Moonlight cell's shapes (16,384 tokens,
+    a router of 64, 6 chosen) with its gradient: the chosen scores are a
+    masked sum, so nothing gathers T * k scalars along a 6-wide axis or
+    scatter-adds them back."""
+    from torchft_tpu.models import moe
+
+    tokens, k, width, n_exp = 16384, 6, 2048, 64
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)  # noqa: E731
+    x, router = shape((2, tokens // 2, width), jnp.bfloat16), shape((width, n_exp), jnp.float32)
+    bias, ct = shape((n_exp,), jnp.float32), shape((2, tokens // 2, k), jnp.float32)
+
+    def gates_and_their_gradients(x, router, bias, ct):
+        gate_vals, vjp = jax.vjp(lambda x_, r: moe.route(x_, r, k, True, score="sigmoid", bias=bias, scale=2.446)[2],
+                                 x, router)
+        return gate_vals, vjp(ct)
+
+    text = _compile(gates_and_their_gradients, x, router, bias, ct)  # fusions' bodies and all
+    assert " sort(" in text or "topk" in text.lower(), "top_k is not there: the text was not read"
+    assert " gather(" not in text and " scatter(" not in text
+
+
 def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:
     """`tpuft_fa_*` at latent attention's widths and the Moonlight cell's
     shapes: 2 x 16 heads, 8,192 positions, query and key 256 wide (192 padded
@@ -361,7 +433,12 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     # one-pass kernel brings no f32 dq, 268 MB a layer here, into HBM
     # (14,339,268,608 then; 14,340,042,752 since PR 34: the kernels alone compile to the same
     # temporaries, the program's schedule around the copies of the walk's tables holds 0.77 MB more)
-    assert resident <= 14.345e9, f"{resident} bytes: the backward's dq has left VMEM in f32"
+    # 13,910,258,176 since PR 36: the experts' gathered rows are [k, T, E], so no copy of them
+    # re-tiled to [T, 6 -> 8, E] is held (temporaries 3,637,552,640 -> 3,207,768,064)
+    assert resident <= 13.915e9, (
+        f"{resident} bytes: the backward's dq has left VMEM in f32, or the experts' gathered rows are laid out again")
+    gathered = 16384 * 6 * 2048
+    assert not [op for op, n in _instructions(text) if op in ("reshape", "copy") and n == gathered]
 
 
 def _kernel_calls(text: str, prefix: str) -> list:

@@ -33,7 +33,8 @@ if ROOT not in sys.path:
 
 from benchmark.spec import Benchmark  # noqa: E402
 from torchft_tpu.models import init_params  # noqa: E402
-from torchft_tpu.models.moe import held_rows, moe_layer, route  # noqa: E402
+from torchft_tpu.models.moe import _dropless_ffn, _take_rows, held_rows, moe_layer, route  # noqa: E402
+from torchft_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, padded_group_sizes  # noqa: E402
 from torchft_tpu.models.transformer import loss_and_counters, param_axes  # noqa: E402
 from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
 
@@ -288,6 +289,67 @@ def test_route_against_a_hand_written_one_on_ties(with_bias) -> None:
         assert (plain_idx != want_idx).any()
 
 
+def _gathered_route(x, router, k, bias, scale):
+    """`route`'s sigmoid branch as it was before the gates were picked by
+    comparison: the chosen scores by `take_along_axis`."""
+    logits = jnp.einsum("bse,ex->bsx", x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(choice, k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    return gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20) * scale, idx
+
+
+def _primitives(jaxpr, found=None) -> set:
+    """Every primitive of a jaxpr and of the jaxprs inside its equations."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)  # a ClosedJaxpr holds one
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("with_bias", [False, True], ids=["no_bias", "bias"])
+def test_sigmoid_gates_are_picked_by_comparison_and_equal_the_gathered_ones(with_bias) -> None:
+    """The chosen scores as a masked sum over the experts: bit for bit what
+    `take_along_axis` gathers (one term is not zero), the same gradient (the
+    k indices are distinct, so the scatter-add it replaces added nothing
+    twice), and neither direction gathers or scatters a scalar."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    x = jax.random.normal(ks[0], (2, 48, 32), jnp.float32)
+    router = jax.random.normal(ks[1], (32, 64), jnp.float32) * 32 ** -0.5
+    bias = jax.random.normal(ks[2], (64,), jnp.float32) * 0.05 if with_bias else None
+    ct = jax.random.normal(ks[3], (2, 48, 6), jnp.float32)
+
+    def picked(x, router):
+        return route(x, router, 6, True, score="sigmoid", bias=bias, scale=2.446)[2:]
+
+    def gathered(x, router):
+        return _gathered_route(x, router, 6, bias, 2.446)
+
+    (got, got_idx), got_vjp = jax.vjp(picked, x, router)
+    (want, want_idx), want_vjp = jax.vjp(gathered, x, router)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(want_idx))
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    ct = (ct, np.zeros(got_idx.shape, jax.dtypes.float0))
+    for a, b in zip(got_vjp(ct), want_vjp(ct)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7)
+
+    moved = {"gather", "scatter", "scatter-add"}
+    forward = _primitives(jax.make_jaxpr(picked)(x, router).jaxpr)
+    backward = _primitives(jax.make_jaxpr(lambda x, r: jax.vjp(picked, x, r)[1](ct))(x, router).jaxpr)
+    assert "top_k" in forward and not forward & moved, forward & moved
+    assert not backward & moved, backward & moved
+    # the oracle is the form that does: the check can see one
+    assert "gather" in _primitives(jax.make_jaxpr(gathered)(x, router).jaxpr)
+    assert "scatter-add" in _primitives(jax.make_jaxpr(lambda x, r: jax.vjp(gathered, x, r)[1](ct))(x, router).jaxpr)
+
+
 # -- one chip's share of an expert-parallel layer ---------------------------------
 
 
@@ -360,6 +422,75 @@ def test_a_share_whose_buffer_is_full_counts_what_it_drops() -> None:
     assert int(full["dropped"]) == 0 and int(tight["rows_held"]) == int(full["rows_held"]) > rows
     assert 0 < int(tight["dropped"]) <= int(tight["rows_held"])
     assert bool(jnp.all(jnp.isfinite(y)))
+
+
+def _t_major_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first, rows_factor):
+    """`_dropless_ffn` as it was before the k choices led: the same
+    assignment -> row table, a token's rows gathered as [T, k, E] and
+    weighted over the middle axis, and JAX's own derivatives (a scatter-add
+    for each gather) in place of the hand-written ones."""
+    tokens, k = gate_idx.shape
+    count, n_assign = w_gate.shape[0], tokens * k
+    rows = held_rows(n_assign, n_exp, count, rows_factor, ROW_TILE)
+    mine = (gate_idx.reshape(n_assign)[:, None] == jnp.arange(first, first + count)[None, :]).astype(jnp.int32)
+    arrived = jnp.cumsum(mine, axis=0)
+    sizes = padded_group_sizes(arrived[-1], ROW_TILE)
+    dest = jnp.sum((jnp.cumsum(sizes) - sizes)[None, :] * mine, axis=1) + jnp.sum(arrived * mine, axis=1) - 1
+    here = jnp.sum(mine, axis=1) > 0
+    dropped = jnp.sum((here & (dest >= rows)).astype(jnp.int32))
+    dest = jnp.where(here & (dest < rows), dest, rows + jnp.arange(n_assign))
+    row_token = jnp.full((rows,), tokens, jnp.int32).at[dest].set(jnp.arange(n_assign, dtype=jnp.int32) // k)
+    xs = jnp.take(xf, row_token, axis=0, mode="clip")
+    hidden = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, row_tile=ROW_TILE)) * grouped_matmul(
+        xs, w_up, sizes, row_tile=ROW_TILE)
+    out = grouped_matmul(hidden, w_down, sizes, row_tile=ROW_TILE)
+    picked = jnp.take(out, dest.reshape(tokens, k), axis=0, mode="fill", fill_value=0)  # [T, k, E]
+    return jnp.einsum("tke,tk->te", picked, gate_vals), jnp.sum(arrived[-1]), dropped
+
+
+@pytest.mark.parametrize("held", ["every_expert_held", "an_eighth_with_rows_past_the_buffer"])
+@pytest.mark.parametrize("k", [6, 8])
+def test_k_major_row_moves_match_the_t_major_form(k, held) -> None:
+    """The layer's output and its gradients with respect to the
+    activations, the gates and the three expert matrices, against the
+    [T, k, E] form differentiated by JAX: at 6 choices (no multiple of a
+    tile's 8 rows: the k axis leads) and at 8 (it does not), with every
+    assignment in a row and with a share whose buffer is too small for
+    some."""
+    n_exp, tokens, hidden, inner = 16, 512, 64, 32
+    picked = jax.eval_shape(lambda: _take_rows(jnp.zeros((8, hidden)), jnp.zeros((tokens, k), jnp.int32), True))
+    assert picked.shape == ((k, tokens, hidden) if k == 6 else (tokens, k, hidden))
+    first, count, factor = (0, n_exp, 2.0) if held == "every_expert_held" else (4, 2, 0.25)
+    ks = jax.random.split(jax.random.PRNGKey(36 + k), 7)
+    normal = lambda key, shape, fan: jax.random.normal(key, shape, jnp.float32) * fan ** -0.5  # noqa: E731
+    xf = jax.random.normal(ks[0], (tokens, hidden), jnp.float32)
+    w = (normal(ks[1], (count, hidden, inner), hidden), normal(ks[2], (count, hidden, inner), hidden),
+         normal(ks[3], (count, inner, hidden), inner))
+    gate_vals, gate_idx = jax.lax.top_k(jax.nn.sigmoid(jax.random.normal(ks[4], (tokens, n_exp), jnp.float32)), k)
+    ct = jax.random.normal(ks[5], (tokens, hidden), jnp.float32)
+
+    def k_major(xf, gate_vals, *w):
+        return _dropless_ffn(xf, gate_vals, gate_idx, *w, n_exp=n_exp, first=first, rows_factor=factor, mesh=None)
+
+    def t_major(xf, gate_vals, *w):
+        return _t_major_ffn(xf, gate_vals, gate_idx, *w, n_exp=n_exp, first=first, rows_factor=factor)
+
+    with jax.default_matmul_precision("highest"):
+        (got, got_held, got_dropped), got_vjp = jax.vjp(k_major, xf, gate_vals, *w)
+        (want, want_held, want_dropped), want_vjp = jax.vjp(t_major, xf, gate_vals, *w)
+        zero = np.zeros((), jax.dtypes.float0)
+        got_grads, want_grads = got_vjp((ct, zero, zero)), want_vjp((ct, zero, zero))
+    assert int(got_held) == int(want_held) and int(got_dropped) == int(want_dropped)
+    if held == "every_expert_held":
+        assert int(got_held) == tokens * k and int(got_dropped) == 0
+    else:
+        assert 0 < int(got_dropped) < int(got_held) < tokens * k
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    # float32 rounding: the order of a sum's addends is all that differs
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for name, a, b in zip(("xf", "gate_vals", "w_gate", "w_up", "w_down"), got_grads, want_grads):
+        assert float(jnp.max(jnp.abs(b))) > 0.1, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5, err_msg=name)
 
 
 # -- the two-kind tree through the exchange's plan, the checkpoint and TrainStep ---

@@ -39,7 +39,21 @@ Then one of two ways to the experts, both with STATIC shapes:
     even share of them, and an assignment to a held expert that finds it
     full is counted in ``dropped``.  Both directions of both moves are
     gathers (a token has at most k rows and a row one token), so the
-    backward pass has no scatter-add;
+    backward pass has no scatter-add.  Where k is no whole number of the
+    TPU's 8-row tiles (top-6) a token's k rows are gathered **k-major**,
+    ``[k, T, E]``: the TPU tiles an array's two minor axes, so ``[T, 6, E]``
+    lays six rows on an eight-row tile and the compiler re-tiles the
+    gathered rows — a relayout of T * k * E elements after each gather,
+    1.5 ms at 16,384 x 6 x 2,048 on a v5e, 22 ms a step over fifteen
+    gathers — where ``[k, T, E]`` keeps ``[T, E]`` minor, which tiles at
+    every k, and IS the gather's ``[k * T, E]`` result, bit for bit.  At
+    k = 8 ``[T, k, E]`` is that result bit for bit too, and there the rows
+    stay token-major (``_k_leads``): measured on the v5e in the cells that
+    route top-8, the k-major program's gathers of 262,144 rows took 15%
+    longer and a step's expert layers 19 ms more (PERF.md, PR 36).  For
+    the same reason as the k-major rows, a chosen scalar is picked by
+    comparison and never gathered along a k-wide minor axis (``route``'s
+    gates, ``rank`` and ``dest`` below);
   - **capacity-bound, dense dispatch** (GShard/Switch style,
     arXiv:2006.16668; what a mesh with an "expert" axis runs): dense
     dispatch/combine tensors [T, n_exp, capacity], over-capacity
@@ -113,7 +127,13 @@ def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool, *,
     scores = jax.nn.sigmoid(logits)
     choice = scores if bias is None else scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
     _, gate_idx = jax.lax.top_k(choice, top_k)
-    gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    # The chosen scores, as a masked sum over the experts: one term is not
+    # zero, so the values are the gathered ones bit for bit, and the
+    # derivative is a masked sum too (the k indices are distinct) where
+    # `take_along_axis` gathers T * k scalars along a k-wide axis and
+    # scatter-adds them back (0.6 ms a call on the v5e at 16,384 x 6 of 64).
+    chosen = gate_idx[..., None] == jnp.arange(scores.shape[-1], dtype=gate_idx.dtype)
+    gate_vals = jnp.sum(jnp.where(chosen, scores[..., None, :], 0.0), axis=-1)
     if norm_topk:
         gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20)
     probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
@@ -144,10 +164,19 @@ def _int_zero(x: jax.Array):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
+def _k_leads(k: int) -> bool:
+    """Whether a token's k rows are gathered as [k, T, E] (module
+    docstring): where [T, k, E] would lay k rows on tiles of 8."""
+    return k % 8 != 0
+
+
 def _take_rows(rows, dest, every_row_exists: bool):
-    """rows[dest]; a `dest` past the end (an assignment without a row) reads
-    zeros.  Where every assignment has a row by construction the check is
-    left out."""
+    """rows[dest] for ``dest`` [T, k]: [T, k, E], or [k, T, E] where the k
+    choices lead (``_k_leads``).  A `dest` past the end (an assignment
+    without a row) reads zeros.  Where every assignment has a row by
+    construction the check is left out."""
+    if _k_leads(dest.shape[1]):
+        dest = dest.T
     if every_row_exists:
         return jnp.take(rows, dest, axis=0, mode="clip")
     return jnp.take(rows, dest, axis=0, mode="fill", fill_value=0)
@@ -168,9 +197,11 @@ def _rows_fwd(xf, row_token, dest, every_row_exists):
 
 
 def _rows_bwd(every_row_exists, res, drows):
-    # A token's rows are at dest[t]: a gather and a sum, not a scatter-add.
+    # A token's rows are at dest[t]: a gather and a sum, not a scatter-add —
+    # a sum of k slabs of [T, E] where the k choices lead.
     row_token, dest = res
-    dxf = jnp.sum(_take_rows(drows, dest, every_row_exists).astype(jnp.float32), axis=1)
+    k_axis = 0 if _k_leads(dest.shape[1]) else 1
+    dxf = jnp.sum(_take_rows(drows, dest, every_row_exists).astype(jnp.float32), axis=k_axis)
     return dxf.astype(drows.dtype), _int_zero(row_token), _int_zero(dest)
 
 
@@ -179,10 +210,14 @@ _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists: bool):
-    """rows [R, E], gates [T, k] f32 -> [T, E]: each token the sum of its
-    rows weighted by their gates (an assignment without a row adds zero)."""
-    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)  # [T, k, E]
-    return jnp.einsum("tke,tk->te", picked, gates).astype(rows.dtype)
+    """rows [R, E], gates [T, k] f32, dest [T, k] -> [T, E]: each token the
+    sum of its rows weighted by their gates (an assignment without a row
+    adds zero).  Where the rows are picked k-major, so that no 6-wide axis
+    meets an 8-row tile, the gates stay [T, k] as the router gives them
+    (T * k scalars: their transpose is inside the product)."""
+    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
+    product = "kte,tk->te" if _k_leads(gates.shape[1]) else "tke,tk->te"
+    return jnp.einsum(product, picked, gates).astype(rows.dtype)
 
 
 def _tokens_fwd(rows, gates, dest, row_assignment, every_row_exists):
@@ -200,7 +235,10 @@ def _tokens_bwd(every_row_exists, res, dy):
     drows = jnp.take(dy, row_assignment // k, axis=0, mode="clip")
     drows = (drows.astype(jnp.float32) * row_gate[:, None]).astype(rows.dtype)
     picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
-    dgates = jnp.einsum("tke,te->tk", picked, dy.astype(jnp.float32))
+    if _k_leads(k):
+        dgates = jnp.einsum("kte,te->kt", picked, dy.astype(jnp.float32)).T
+    else:
+        dgates = jnp.einsum("tke,te->tk", picked, dy.astype(jnp.float32))
     return drows, dgates, _int_zero(dest), _int_zero(row_assignment)
 
 
