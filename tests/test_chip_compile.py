@@ -277,7 +277,7 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
     assert len(ran) > 500 and set(ran) <= set(ops)
     booked = {name: opmap.booked(entry) for name, entry in ops.items()}
-    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"dsa_index", "dsa_select", "ffn", "shared_expert"}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
     assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
@@ -520,3 +520,68 @@ def test_keye_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     # four layers, the floor: 14.82 GB by this count (PR 33); a fifth layer reads 17.29 GB
     assert resident < 14.9e9, f"the step needs {resident} bytes with AdamW's moments"
+
+
+@pytest.mark.parametrize("window", [512, 1536])
+def test_windowed_attention_kernels_compile_for_v5e(one_chip, window) -> None:
+    """The band-walk kernels at the Laguna cell's window layers (64 heads x
+    16,384 x 128, a window of 512) and at a window of three tiles: one forward
+    and ONE backward `tpu_custom_call` under the `tpuft_swa_*` names — the
+    tile's one unsigned comparison and the walk's traced row and column ends
+    are what interpret mode cannot refuse."""
+    from torchft_tpu.ops.attention import _fa_bwd_pallas, _fa_pallas_call
+
+    qkv = jax.ShapeDtypeStruct((64, 16384, 128), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((64, 16384), jnp.float32, sharding=one_chip)
+    text = _compile(lambda q, k, v: _fa_pallas_call(q, k, v, 128 ** -0.5, True, window=window), qkv, qkv, qkv)
+    assert _kernel_calls(text, "tpuft_swa_") == ["tpuft_swa_fwd"] and not _attention_calls(text)
+    text = _compile(lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, 128 ** -0.5, True, window=window),
+                    qkv, qkv, qkv, qkv, lse, qkv)
+    assert _kernel_calls(text, "tpuft_swa_") == ["tpuft_swa_bwd_dkdv_dq"] and not _attention_calls(text)
+
+
+def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `laguna-xs.2` configuration as
+    `benchmark/programs/swa_moe_lm.py` hands it to `TrainStep`: the whole
+    gradient program at the published widths and 1 x 16,384 tokens — the three
+    window layers through `tpuft_swa_*` at 64 heads, the two full layers
+    through `tpuft_fa_*` at 48, the 32 held experts of each sparse layer
+    through `tpuft_gmm_*`, the sliced vocabulary through `tpuft_ce_*` — with
+    room for AdamW's moments beside it on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("laguna-xs.2"), bench.traffic("steady-1g-16k")
+    shapes = jax.eval_shape(lambda: bench.reference("swa_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("swa_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    # the two kinds of layer read apart: one backward and, attention's output kept under remat, one
+    # forward kernel a layer
+    assert config["program"]["remat_keeps_attention"]
+    assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq"] * 2 + ["tpuft_fa_fwd"] * 2
+    assert sorted(_kernel_calls(text, "tpuft_swa_")) == ["tpuft_swa_bwd_dkdv_dq"] * 3 + ["tpuft_swa_fwd"] * 3
+    # the band's grid, as `swa_pairs_share` reads it out of the compiled calls: a step for each of the
+    # 2n - 1 = 63 tiles with a visible pair a head, where the triangle has 528
+    grids = bench.reader("swa_pairs_share").grids(text)
+    assert sorted(g["name"] for g in grids) == ["tpuft_swa_bwd_dkdv_dq"] * 3 + ["tpuft_swa_fwd"] * 3
+    assert all((g["grid"], g["block_q"], g["seq"]) == ([64, 63], 512, 16_384) for g in grids), grids
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("swa_moe_lm").total_params(config) == 691_623_936
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # 15,451,607,040 (15,167,032,832 with the full layers' attention kept alone; builder's compiles,
+    # PR 37): the chip's allocator has 16.9e9
+    assert resident <= 15.5e9, f"the step needs {resident} bytes with AdamW's moments"

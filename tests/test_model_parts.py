@@ -26,7 +26,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from hlo_text import canonical, without_metadata  # noqa: E402
-from torchft_tpu.models import TransformerConfig, init_params  # noqa: E402
+from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
 from torchft_tpu.models.transformer import loss_and_counters  # noqa: E402
 from torchft_tpu.obs import opmap  # noqa: E402
 from torchft_tpu.obs.spans import PARTS  # noqa: E402
@@ -51,13 +51,25 @@ MODELS = {
         moe_experts=8, moe_top_k=2, d_ff=48, moe_capacity_factor=None, moe_held=(2, 2), moe_aux_coef=0.001,
         remat=True, remat_keeps_attention=True)),
 }
+# The five that the benchmark had before a layer pattern was data: their compiled programs are pinned
+# (`test_the_pattern_left_the_five_programs_as_they_were`).
+BEFORE_THE_PATTERN = tuple(MODELS)
+# The sixth: window and full attention mixed 3:1 at two head counts, YaRN on half a head, a head gate,
+# three kinds of layer under three stacks.
+_FULL = dict(n_heads=6, rope_theta=5e5, rotary_fraction=0.5, yarn=(4.0, 16, 4.0, 1.0, 1.1))
+MODELS["laguna"] = TransformerConfig(**dict(
+    _BASE, n_layers=5, n_heads=6, n_kv_heads=2, head_dim=16, attn_head_gate=True, moe_experts=8, moe_top_k=2, d_ff=32,
+    dense_d_ff=128, moe_capacity_factor=None, moe_held=(2, 2), moe_score="sigmoid", moe_route_scale=2.5,
+    moe_shared_experts=1, moe_aux_coef=0.001, remat=True, remat_keeps_attention=True, scan_unroll=8,
+    pattern=(LayerKind("dense_layers", False, **_FULL),) + (LayerKind("window_layers", True, 8, 1e4, window=16),) * 3 + (LayerKind("layers", True, **_FULL),)))
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
 
 def _step_and_arguments(name: str):
     cfg = MODELS[name]
-    bias = jnp.zeros((cfg.n_sparse_layers, cfg.moe_experts), jnp.float32) if cfg.moe_score == "sigmoid" else None
+    biased = cfg.moe_score == "sigmoid" and not cfg.pattern
+    bias = jnp.zeros((cfg.n_sparse_layers, cfg.moe_experts), jnp.float32) if biased else None
     step = TrainStep(ft_init_mesh({"data": 1}, devices=jax.devices()[:1]), optax.adamw(1e-3),
                      lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias), loss_has_counters=True)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, size=(2, SEQ)).astype(np.int32)
@@ -104,8 +116,10 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
     expected = {"embed", "norm", "attn_proj", "attn", "head_loss", "stack"}
     if cfg.moe_experts:
         expected |= {"router", "experts"}
-    if cfg.moe_experts == 0 or cfg.moe_dense_layers:
+    if cfg.moe_experts == 0 or not all(kind.sparse for kind in cfg.layers):
         expected |= {"ffn"}
+    if any(kind.window for kind in cfg.layers):
+        expected |= {"attn_window"}
     if cfg.moe_shared_experts:
         expected |= {"shared_expert"}
     if cfg.dsa_index_heads:
@@ -132,6 +146,36 @@ def test_the_scopes_change_no_instruction(programs, name) -> None:
     assert "jvp(embed)" not in plain and "attn_proj" not in plain
     assert without_metadata(plain) == without_metadata(scoped)
     assert canonical(plain) == canonical(scoped)
+
+
+@pytest.mark.parametrize("program", ["grads", "update"])
+@pytest.mark.parametrize("name", BEFORE_THE_PATTERN)
+def test_the_pattern_left_the_five_programs_as_they_were(name, program) -> None:
+    """`_decoder` walks a pattern since PR 37, of which "leading dense layers,
+    then the model's own kind" is one instance: for the five configurations the
+    benchmark had, the gradient and the update program compile to the
+    instructions they compiled to before (canonical optimized HLO, by digest;
+    recorded from the parent tree with this JAX).  A PR that changes these
+    programs on purpose records them anew: `tests/data/hlo_before_the_pattern.json`."""
+    import hashlib
+    import json
+
+    with open(os.path.join(ROOT, "tests", "data", "hlo_before_the_pattern.json"), encoding="utf-8") as f:
+        recorded = json.load(f)
+    if recorded["jax"] != jax.__version__:
+        pytest.skip(f"recorded with JAX {recorded['jax']}, this is {jax.__version__}")
+    step, params, batch = _step_and_arguments(name)
+    if program == "grads":
+        text = step.lower_grads(params, batch).compile().as_text()
+    else:
+        _, grads = step.grads(params, batch)
+        text = step._apply_fn.lower(params, step.init_opt_state(params), grads).compile().as_text()
+    digest = hashlib.sha256(canonical(text).encode()).hexdigest()
+    assert digest == recorded["sha256_of_canonical_hlo"][f"{name}.{program}"]
+    # and the tree keeps its leaves' names and shapes: heal and checkpoints read what they wrote
+    cfg = MODELS[name]
+    assert set(params) == {"embed", "final_norm", "lm_head", "layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set())
+    assert {s: n for s, (_, n) in cfg.stacks.items()} == {k: v["attn_norm"].shape[0] for k, v in params.items() if "layers" in k}
 
 
 def test_the_op_map_names_both_programs_and_costs_nothing_until_asked(monkeypatch) -> None:
@@ -183,6 +227,29 @@ def test_the_op_map_names_both_programs_and_costs_nothing_until_asked(monkeypatc
     grad_parts = {opmap.part_of(v) for v in found["jit_value_and_grad"].values()}
     assert {"ffn", "head_loss", "attn"} <= grad_parts
     assert {opmap.part_of(v) for v in found["jit_apply"].values()} == {None}  # the optimizer is no part of the model
+
+
+@pytest.mark.parametrize("then", ["asked_again", "traced_anew"])
+def test_the_compiled_texts_are_read_once_for_the_programs_that_ran(then, monkeypatch) -> None:
+    """`compiled_texts` is what `op_map` reads: the programs' text is made
+    once, handed out again without another compile, and made anew after a
+    program was traced anew (another batch shape)."""
+    from jax._src import stages
+
+    compiles = []
+    real = stages.Lowered.compile
+    monkeypatch.setattr(stages.Lowered, "compile", lambda self, *a, **k: compiles.append(1) or real(self, *a, **k))
+    step, params, batch = _step_and_arguments("internlm2")
+    step.grads(params, batch)
+    texts = step.compiled_texts()
+    assert set(texts) == {"jit_value_and_grad"} and len(compiles) == 1
+    assert step.op_map() == {"jit_value_and_grad": opmap.op_names(texts["jit_value_and_grad"])} and len(compiles) == 1
+    if then == "asked_again":
+        assert step.compiled_texts() is texts and len(compiles) == 1
+    else:
+        step.grads(params, {k: v[:, : SEQ // 2] for k, v in batch.items()})
+        again = step.compiled_texts()
+        assert len(compiles) == 2 and again["jit_value_and_grad"] != texts["jit_value_and_grad"]
 
 
 def test_a_train_step_that_is_gone_leaves_the_registry() -> None:

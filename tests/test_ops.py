@@ -286,6 +286,142 @@ def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
     assert len(rows) == tiles and (cols <= rows).all() and (np.diff(cols) >= 0).all()
 
 
+def _dense_window_attention(q, k, v, g, scale, window):
+    """Windowed causal attention written out with a dense mask built from
+    positions, in plain `jax.numpy`: (o, dq, dk, dv) for the cotangent g."""
+    def out(q, k, v):
+        t = jnp.arange(q.shape[1])
+        d = t[:, None] - t[None, :]
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where((d >= 0) & (d < window), s, -jnp.inf), axis=-1)
+        return jnp.einsum("bqk,bkd->bqd", p, v)
+
+    o, vjp = jax.vjp(out, q, k, v)
+    return (o,) + vjp(g)
+
+
+# seq 2048 under 512 x 512 tiles: a window of one tile, one that divides no tile, one narrower than a
+# tile, one of two tiles and a bit, and the last position short of the sequence
+@pytest.mark.parametrize("window", [512, 300, 37, 1100, 2047])
+def test_windowed_flash_kernels_match_a_dense_mask(window) -> None:
+    """The band-walk kernels in interpret mode, forward and all three
+    gradients, against attention over a dense mask; and the XLA fallback
+    against the same."""
+    from torchft_tpu.ops import attention as fa
+
+    seq, scale = 2048, 0.088
+    rng = np.random.default_rng(window)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((2, seq, 128)), dtype=jnp.float32) for _ in range(4))
+    want = _dense_window_attention(q, k, v, g, scale, window)
+    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv_dq"]
+    got = (o,) + tuple(bwd(q, k, v, o, lse, g))
+    o_x, lse_x = fa._fa_reference(q, k, v, scale, True, window)
+    got_xla = (o_x,) + tuple(fa._fa_bwd_xla(q, k, v, o_x, lse_x, g, scale, True, window))
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_x), rtol=1e-5, atol=1e-5)
+    for a, x, b, name in zip(got, got_xla, want, ("o", "dq", "dk", "dv")):
+        # float32 operands; the kernels accumulate tile by tile, the mask at once
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+        np.testing.assert_allclose(np.asarray(x), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name + " (xla)")
+
+
+def test_windowed_two_pass_backward_matches_a_dense_mask(monkeypatch) -> None:
+    """A dq row over the budget takes the two windowed kernels."""
+    from torchft_tpu.ops import attention as fa
+
+    seq, scale, window = 2048, 0.088, 700
+    monkeypatch.setattr(fa, "_DQ_ROW_VMEM_BUDGET", seq * 128 * 4 - 1)
+    rng = np.random.default_rng(3)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((1, seq, 128)), dtype=jnp.float32) for _ in range(4))
+    want = _dense_window_attention(q, k, v, g, scale, window)
+    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv", "tpuft_swa_bwd_dq"]
+    for a, b, name in zip((o,) + tuple(bwd(q, k, v, o, lse, g)), want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [1024, 5000])
+def test_a_window_that_covers_the_sequence_is_the_causal_call(window) -> None:
+    """`flash_attention(window >= seq)`: the same jaxpr as the causal call
+    (so the same kernel, un-windowed) and the same bits, output and
+    gradients."""
+    from torchft_tpu.ops import flash_attention
+
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((1, 4, 1024, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 1024, 64)), dtype=jnp.float32) for _ in range(2))
+
+    def loss(window):
+        return lambda q, k, v: jnp.sum(jnp.square(flash_attention(q, k, v, causal=True, window=window)))
+
+    assert str(jax.make_jaxpr(jax.grad(loss(window), argnums=(0, 1, 2)))(q, k, v)) == str(
+        jax.make_jaxpr(jax.grad(loss(None), argnums=(0, 1, 2)))(q, k, v))
+    got = jax.value_and_grad(loss(window), argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(None), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def test_windowed_flash_attention_differs_from_causal_and_matches_a_dense_mask() -> None:
+    """The public call with grouped queries and a window under the sequence,
+    through autodiff (the XLA formulation off-TPU)."""
+    from torchft_tpu.ops import flash_attention
+
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)), dtype=jnp.float32) for _ in range(2))
+    g = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
+    o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, window=32), q, k, v)
+    rep = lambda t: jnp.repeat(t, 2, axis=1).reshape(4, 256, 64)  # noqa: E731
+    want = _dense_window_attention(q.reshape(4, 256, 64), rep(k), rep(v), g.reshape(4, 256, 64), 64 ** -0.5, 32)
+    np.testing.assert_allclose(np.asarray(o).reshape(4, 256, 64), np.asarray(want[0]), rtol=1e-4, atol=1e-5)
+    dq, dk, dv = vjp(g)
+    np.testing.assert_allclose(np.asarray(dq).reshape(4, 256, 64), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
+    for got, ref in ((dk, want[2]), (dv, want[3])):  # a kv head's gradient is its two query heads' summed
+        np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref).reshape(2, 2, 256, 64).sum(1), rtol=1e-4, atol=1e-4)
+    assert not np.allclose(np.asarray(o), np.asarray(flash_attention(q, k, v)), atol=1e-3)
+
+
+@pytest.mark.parametrize("seq", [4096, 8192, 16384])
+@pytest.mark.parametrize("block", [512, 256])
+def test_the_band_walk_at_the_window_cells_lengths(seq, block) -> None:
+    """A window of 512: the walk's tables hold every tile with a visible
+    pair and no other, row by row and column by column — 2n - 1 tiles of
+    512 x 512 (two a row but the first), 3n - 3 of 256 x 256 — and the
+    traced `pallas_call`s at the program's blocks have that many steps."""
+    from torchft_tpu.ops import attention as fa
+
+    window, n = 512, seq // block
+    t = np.arange(seq)
+    d = t[:, None] - t[None, :]
+    holds_a_pair = ((d >= 0) & (d < window)).reshape(n, block, n, block).any(axis=(1, 3))
+    tiles = int(holds_a_pair.sum())
+    assert tiles == (2 * n - 1 if block == 512 else 3 * n - 3)
+    for kv_major in (False, True):
+        walk = fa._Walk(True, seq, seq, block, block, kv_major=kv_major, window=window)
+        rows, cols = (np.asarray(x) for x in walk.tables)
+        visited = np.zeros((n, n), bool)
+        visited[rows, cols] = True
+        assert len(rows) == tiles and (visited == holds_a_pair).all()
+        major, minor = (cols, rows) if kv_major else (rows, cols)
+        assert (np.diff(major) >= 0).all() and (np.diff(minor)[np.diff(major) == 0] == 1).all()
+        # the ends the kernels start, assign and emit at are the tables' own
+        for i in range(n):
+            in_row, in_col = cols[rows == i], rows[cols == i]
+            assert (int(walk.first_k(i)), int(walk.last_k(i))) == (in_row.min(), in_row.max())
+            assert (int(walk.first_q(i)), int(walk.last_q(i))) == (in_col.min(), in_col.max())
+    if fa._block_sizes(seq, seq) == (block, block):
+        bh = 8
+        qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+        fwd = functools.partial(fa._fa_pallas_call, scale=0.088, causal=True, window=window)
+        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True, window=window)
+        assert pallas_call_grids(fwd, qkv, qkv, qkv) == {"tpuft_swa_fwd": (bh, tiles)}
+        assert pallas_call_grids(bwd, qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_swa_bwd_dkdv_dq": (bh, tiles)}
+
+
 def test_fused_cross_entropy_matches_and_grads() -> None:
     """The fused lm-head CE op (XLA fallback path) vs the straightforward
     materialized formulation: values and grads."""

@@ -28,8 +28,11 @@ step: `u = T_noncausal / tiles`, `idle = (T_causal - visited * u) /
 int8 mask (`tpuft_dsa_attn_fwd`, `tpuft_dsa_attn_bwd_dkdv_dq`) with
 `--kv-group` query heads a KV head and a mask of the Keye cell's density:
 every earlier key for a query before `--topk`, then `--topk` a query, spread
-evenly over its visible keys.  One JSON line per reading on standard output,
-all of them in `chiprun_out/fa_bwd_probe.json`.
+evenly over its visible keys.  `--windowed` shapes run the band walk
+(`tpuft_swa_fwd`, `tpuft_swa_bwd_dkdv_dq`) under `--window`; their
+`tiles_visited` are the band's tiles and the required products those over the
+band's pairs.  One JSON line per reading on standard output, all of them in
+`chiprun_out/fa_bwd_probe.json`.
 """
 
 from __future__ import annotations
@@ -82,6 +85,8 @@ def main(argv=None) -> int:
     parser.add_argument("--shapes", default="32x4096x128,64x4096x128,32x8192x256/128,32x1024x128,4x32768x128,2x65536x128")
     parser.add_argument("--noncausal", default="4x32768x128", help="shapes read with causal=False too")
     parser.add_argument("--masked", default="32x32768x128", help="shapes read under a packed mask of the Keye cell's density")
+    parser.add_argument("--windowed", default="64x16384x128", help="shapes read under a window (the band walk)")
+    parser.add_argument("--window", type=int, default=512)
     parser.add_argument("--kv-group", type=int, default=8)
     parser.add_argument("--topk", type=int, default=2048)
     parser.add_argument("--reps", type=int, default=10)
@@ -119,19 +124,26 @@ def main(argv=None) -> int:
         g = jax.random.normal(keys[3], (bh, seq, dv), jnp.bfloat16)
         return bh, seq, d, dv, q, k, v, g
 
-    def read(spec, walk, causal=True, mask=None, kv_group=1, two_pass=False, pairs=None, **noted):
+    def read(spec, walk, causal=True, mask=None, kv_group=1, two_pass=False, pairs=None, window=None, **noted):
         """Forward and backward of one shape (`two_pass`: the backward in
         that form too); `walk` names the reading and `noted` goes into each
         of its lines."""
         bh, seq, d, dv, q, k, v, g = operands_of(spec, kv_group)
         scale = d ** -0.5
-        n = seq // fa._block_sizes(seq, seq)[0]
+        side = fa._block_sizes(seq, seq)[0]
+        n = seq // side
         tiles = bh * (n * (n + 1) // 2 if causal else n * n)
+        if window is not None:
+            tiles = bh * len(fa._Walk(True, seq, seq, side, side, window=window).tables[0])
+            pairs = bh * (seq * (seq + 1) / 2.0 - (seq - window) * (seq - window + 1) / 2.0)
         if pairs is None:
             pairs = bh * (seq * (seq + 1) / 2.0 if causal else float(seq) * seq)
         need_fwd = 2.0 * pairs * (d + dv)               # QK^T at d, PV at dv
         need_bwd = 2.0 * pairs * (2 * d + 2 * dv)       # dQ, dK at d; dV, dP at dv
         more_kw = {} if mask is None else {"mask": mask, "kv_group": kv_group}
+        if window is not None:
+            more_kw["window"] = window
+        family = "tpuft_dsa_attn" if mask is not None else "tpuft_fa" if window is None else "tpuft_swa"
 
         def record(what, form, ms, need, steps, passes=1, **more):
             rec = {"shape": spec, "walk": walk, "kv_group": kv_group, "what": what, "form": form, "ms": round(ms, 4),
@@ -143,7 +155,7 @@ def main(argv=None) -> int:
 
         fwd = lambda q_, k_, v_: fa._fa_pallas_call(q_, k_, v_, scale, causal, **more_kw)  # noqa: E731
         ms, (o, lse) = timed(jax.jit(fwd), q, k, v)
-        record("fwd", "tpuft_fa_fwd" if mask is None else "tpuft_dsa_attn_fwd", ms, need_fwd, grid_steps(fwd, q, k, v))
+        record("fwd", family + "_fwd", ms, need_fwd, grid_steps(fwd, q, k, v))
         results = {}
         chosen = "one_pass" if fa._dq_row_resident(seq, d) else "two_pass"
         for form, budget in ((chosen, fa._DQ_ROW_VMEM_BUDGET), ("two_pass", 0)):
@@ -184,6 +196,8 @@ def main(argv=None) -> int:
         selected = int(jnp.sum(mask, dtype=jnp.int32))
         read(spec, "masked", mask=mask, kv_group=args.kv_group, pairs=float(bh) * selected,
              selected_share=selected / (seq * (seq + 1) / 2.0))
+    for spec in filter(None, args.windowed.split(",")):
+        read(spec, "windowed", window=args.window)
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "fa_bwd_probe.json"), "w", encoding="utf-8") as f:

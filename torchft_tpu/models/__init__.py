@@ -15,6 +15,7 @@ from torchft_tpu.models.convnet import (
 )
 from torchft_tpu.models.moe import moe_ffn, moe_layer
 from torchft_tpu.models.transformer import (
+    LayerKind,
     TransformerConfig,
     forward,
     forward_with_aux,
@@ -24,6 +25,7 @@ from torchft_tpu.models.transformer import (
 )
 
 __all__ = [
+    "LayerKind",
     "TransformerConfig",
     "init_params",
     "loss_fn",

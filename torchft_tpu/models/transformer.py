@@ -21,6 +21,7 @@ build's first-party equivalent of that model class.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -28,6 +29,25 @@ import jax.numpy as jnp
 
 from torchft_tpu.ops import flash_attention, rms_norm
 from torchft_tpu.parallel.sharding import ShardingRules, constrain
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One kind of decoder layer: its mixer, its feed-forward, and the subtree
+    of the parameters its layers are stacked under (``params[stack]``, in
+    their order in the model).  A 64-head and a 48-head layer cannot share one
+    stacked array, so a model has one stack a kind."""
+
+    stack: str
+    sparse: bool                       # the feed-forward: mixture of experts, or dense
+    n_heads: int                       # query heads (the KV heads are the model's)
+    rope_theta: float
+    window: Optional[int] = None       # a query at t sees 0 <= t - s < window; None: all of the past
+    rotary_fraction: float = 1.0       # RoPE turns this leading share of a head's columns
+    # YaRN (arXiv:2309.00071): (factor, original length, beta_fast, beta_slow,
+    # attention_factor) — `yarn_frequencies` in place of theta's powers, cos
+    # and sin times the attention factor.
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,6 +152,16 @@ class TransformerConfig:
     dsa_index_dim: int = 64
     dsa_topk: int = 2048
     dsa_loss_coef: float = 1.0
+    # The layer pattern as data: every layer's kind, first to last (window
+    # and full attention mixed, head counts and RoPE by kind, dense and
+    # sparse feed-forwards in any order).  Empty: `moe_dense_layers` leading
+    # layers with a dense feed-forward under params["dense_layers"], then the
+    # model's own kind under params["layers"] — a way of writing a pattern.
+    pattern: Tuple[LayerKind, ...] = ()
+    # A sigmoid gate a head on attention's output, from the layer's normed
+    # input: o_head * sigmoid(h W_g)_head, W_g: embed -> heads ("attn_gate";
+    # arXiv:2505.06708's head-wise form).
+    attn_head_gate: bool = False
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -159,11 +189,39 @@ class TransformerConfig:
             assert self.moe_capacity_factor is None and 0 <= first and first + count <= self.moe_experts, (
                 "a share of the experts is held on the dropless path"
             )
+        if self.pattern:
+            assert len(self.pattern) == self.n_layers and not self.moe_dense_layers, "one kind a layer"
+            assert self.attention == "flash" and not self.mla_kv_rank and not self.dsa_index_heads, (
+                "a pattern's kinds are plain heads on the flash backend"
+            )
+            assert all(a == b for a in self.pattern for b in self.pattern if a.stack == b.stack), "one kind a stack"
+            assert all(self.moe_experts > 0 for kind in self.pattern if kind.sparse)
+
+    @property
+    def layers(self) -> Tuple[LayerKind, ...]:
+        """Every layer's kind, first to last."""
+        if self.pattern:
+            return self.pattern
+        own = LayerKind("layers", self.moe_experts > 0, self.n_heads, self.rope_theta)
+        dense = dataclasses.replace(own, stack="dense_layers", sparse=False)
+        return (dense,) * self.moe_dense_layers + (own,) * (self.n_layers - self.moe_dense_layers)
+
+    @property
+    def stacks(self) -> Dict[str, Tuple[LayerKind, int]]:
+        """stack -> (its kind, how many layers it holds), in order of first appearance."""
+        out: Dict[str, Tuple[LayerKind, int]] = {}
+        for kind in self.layers:
+            out[kind.stack] = (kind, out.get(kind.stack, (kind, 0))[1] + 1)
+        return out
 
     @property
     def n_sparse_layers(self) -> int:
-        """Layers under params["layers"] (all of a dense model's)."""
-        return self.n_layers - self.moe_dense_layers
+        """Layers with experts, in a model that has them (the rows of
+        ``router_bias`` and of the per-layer statistics); all of a dense
+        model's."""
+        if self.moe_experts == 0:
+            return self.n_layers
+        return sum(kind.sparse for kind in self.layers)
 
     @property
     def n_held_experts(self) -> int:
@@ -178,7 +236,8 @@ class TransformerConfig:
 
 
 # Logical axis names for every parameter (see parallel/sharding.py).
-def _layer_axes(cfg: TransformerConfig, sparse: bool) -> Dict[str, Any]:
+def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
+    sparse = kind.sparse
     layer = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
@@ -201,6 +260,8 @@ def _layer_axes(cfg: TransformerConfig, sparse: bool) -> Dict[str, Any]:
         layer.update({"wi_q": ("layers", "embed", None), "wi_k": ("layers", "embed", None),
                       "wi_k_norm": ("layers", None), "wi_k_bias": ("layers", None),
                       "wi_w": ("layers", "embed", None)})
+    if cfg.attn_head_gate:
+        layer["attn_gate"] = ("layers", "embed", "heads")
     if sparse:
         layer.update(
             {
@@ -219,14 +280,9 @@ def _layer_axes(cfg: TransformerConfig, sparse: bool) -> Dict[str, Any]:
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical axis names for every parameter, keyed like init_params'
     tree — feed to FTMesh.shard_params to place the model on a mesh."""
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": _layer_axes(cfg, sparse=cfg.moe_experts > 0),
-        "final_norm": ("embed",),
-        "lm_head": ("embed", "vocab"),
-    }
-    if cfg.moe_dense_layers:
-        axes["dense_layers"] = _layer_axes(cfg, sparse=False)
+    axes = {"embed": ("vocab", "embed"), "final_norm": ("embed",), "lm_head": ("embed", "vocab")}
+    for stack, (kind, _) in cfg.stacks.items():
+        axes[stack] = _layer_axes(cfg, kind)
     return axes
 
 
@@ -234,9 +290,9 @@ def _norm_init(k, shape, fan_in, pd):
     return (jax.random.normal(k, shape, pd) * (fan_in ** -0.5)).astype(pd)
 
 
-def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -> Dict[str, Any]:
+def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind) -> Dict[str, Any]:
     pd = cfg.param_dtype
-    E, H, KV = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
+    E, H, KV, sparse = cfg.d_model, kind.n_heads, cfg.n_kv_heads, kind.sparse
 
     def norm_init(k, shape, fan_in):
         return _norm_init(k, shape, fan_in, pd)
@@ -280,6 +336,8 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -
                 "wi_w": norm_init(kw, (L, E, J), E),
             }
         )
+    if cfg.attn_head_gate:
+        layers["attn_gate"] = norm_init(jax.random.fold_in(key, 3), (L, E, H), E)
     if sparse:
         F, X, held = cfg.d_ff, cfg.moe_experts, cfg.n_held_experts
         kr, kg, ku, kd = jax.random.split(ks[7], 4)
@@ -297,7 +355,7 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -
             layers.update({"shared_gate": norm_init(kg, (L, E, Fs), E), "shared_up": norm_init(ku, (L, E, Fs), E),
                            "shared_down": norm_init(kd, (L, Fs, E), Fs)})
     else:
-        F = cfg.dense_d_ff if cfg.moe_dense_layers else cfg.d_ff
+        F = cfg.dense_d_ff or cfg.d_ff
         layers.update(
             {
                 "w_gate": norm_init(ks[4], (L, E, F), E),
@@ -310,20 +368,22 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, sparse: bool) -
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     """Initializes the transformer parameter pytree (layers stacked on a
-    leading axis for the scan-over-layers; param_dtype precision).  A model
-    with leading dense layers stacks those apart, under "dense_layers"."""
+    leading axis for the scan-over-layers; param_dtype precision): one
+    stacked subtree a kind of layer (``cfg.stacks``) — "layers" for a model
+    of one kind, with its leading dense layers apart under "dense_layers"."""
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     pd = cfg.param_dtype
     E = cfg.d_model
     params = {
         "embed": _norm_init(k_embed, (cfg.vocab_size, E), E, pd),
-        "layers": _init_layers(k_layers, cfg, cfg.n_sparse_layers, sparse=cfg.moe_experts > 0),
         "final_norm": jnp.ones((E,), pd),
         "lm_head": _norm_init(k_head, (E, cfg.vocab_size), E, pd),
     }
-    if cfg.moe_dense_layers:
-        params["dense_layers"] = _init_layers(
-            jax.random.fold_in(k_layers, 1), cfg, cfg.moe_dense_layers, sparse=False)
+    # The last kind's stack draws from `k_layers` itself, each kind before it
+    # from a key folded out of it (a model of one kind with leading dense
+    # layers: "layers", then "dense_layers").
+    for i, (stack, (kind, count)) in enumerate(reversed(cfg.stacks.items())):
+        params[stack] = _init_layers(jax.random.fold_in(k_layers, i) if i else k_layers, cfg, count, kind)
     return params
 
 
@@ -339,7 +399,53 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def _attention(cfg: TransformerConfig, mesh, q, k, v):
+def yarn_frequencies(theta: float, rot_dim: int, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies for the rot_dim / 2 rotary pairs, float64 on
+    the host: pair i turns by theta**(-2i/rot_dim) where it makes more than
+    beta_fast turns over the original length, by that over ``factor`` where it
+    makes fewer than beta_slow, and by their blend along a linear ramp between
+    the two correction dimensions (rounded outward, as the published code)."""
+    import math
+
+    import numpy as np
+
+    def correction_dim(turns: float) -> float:
+        return rot_dim * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rot_dim - 1)
+    pair = np.arange(rot_dim // 2, dtype=np.float64)
+    plain = theta ** (-2.0 * pair / rot_dim)
+    ramp = np.clip((pair - low) / ((high if high != low else high + 0.001) - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def _rotary(x: jax.Array, positions: jax.Array, kind: LayerKind) -> jax.Array:
+    """RoPE as the layer's kind has it: over the leading ``rotary_fraction``
+    of a head's columns (half-split pairs inside that part, the rest passes
+    through), at theta's powers or YaRN's frequencies — a constant of the
+    program — with cos and sin times YaRN's attention factor."""
+    if kind.rotary_fraction == 1.0 and kind.yarn is None:
+        return _rope(x, positions, kind.rope_theta)
+    import numpy as np
+
+    rot = int(x.shape[-1] * kind.rotary_fraction)
+    half = rot // 2
+    if kind.yarn is None:
+        inv_freq, factor = kind.rope_theta ** (-np.arange(half, dtype=np.float64) / half), 1.0
+    else:
+        inv_freq, factor = yarn_frequencies(kind.rope_theta, rot, *kind.yarn[:4]), kind.yarn[4]
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
+    cos = (jnp.cos(angles) * factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * factor)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:rot]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, xf[..., rot:]], axis=-1)
+    return out.astype(x.dtype)
+
+
+def _attention(cfg: TransformerConfig, mesh, q, k, v, kind: LayerKind):
     """q/k/v: [B, H|KV, S, Dh] head-major."""
     seq_parallel = (
         cfg.attention in ("ring", "ulysses")
@@ -362,7 +468,7 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
             from torchft_tpu.ops.ring_attention import ring_attention_sharded as fn
 
             # The ring body assumes equal q/kv head counts.
-            broadcast_gqa = cfg.n_kv_heads != cfg.n_heads
+            broadcast_gqa = cfg.n_kv_heads != kind.n_heads
         else:
             from torchft_tpu.ops.ulysses import ulysses_attention_sharded as fn
 
@@ -372,11 +478,11 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
             # divisibility the local body actually requires.
             tp = mesh.shape.get("tensor", 1) if "tensor" in mesh.axis_names else 1
             broadcast_gqa = (
-                cfg.n_kv_heads != cfg.n_heads
+                cfg.n_kv_heads != kind.n_heads
                 and (cfg.n_kv_heads // tp) % mesh.shape["sequence"] != 0
             )
         if broadcast_gqa:
-            rep = cfg.n_heads // cfg.n_kv_heads
+            rep = kind.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
         kwargs = {}
@@ -389,7 +495,7 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
             seq_axis="sequence",
             **kwargs,
         )
-    return flash_attention(q, k, v, causal=True, mesh=mesh)
+    return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window)
 
 
 def _mla_qkv(cfg: TransformerConfig, h, w, positions):
@@ -448,13 +554,14 @@ def _layer_norm(x, w, b, eps):
     return ((xf - mean) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, sparse=None, router_bias=None):
-    """One decoder block; x: [B, S, E].  `sparse`: whether its feed-forward
-    is the mixture of experts (default: the model has one); `router_bias`
-    [n_exp]: the sigmoid router's choice bias for this layer, or None."""
+def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, kind=None, router_bias=None):
+    """One decoder block; x: [B, S, E].  `kind`: the layer's (default: the
+    model's last layer's, the one kind of a model without a pattern);
+    `router_bias` [n_exp]: the sigmoid router's choice bias for this layer,
+    or None."""
     B, S, E = x.shape
-    H, KV = cfg.n_heads, cfg.n_kv_heads
-    sparse = cfg.moe_experts > 0 if sparse is None else sparse
+    kind = cfg.layers[-1] if kind is None else kind
+    H, KV, sparse = kind.n_heads, cfg.n_kv_heads, kind.sparse
 
     # The scopes are the parts a profile's device time is booked to
     # (obs/spans.PARTS); they name the work and change no instruction.
@@ -480,8 +587,10 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
                 with jax.named_scope("norm"):
                     q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
             v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = _rotary(q, positions, kind)
+            k = _rotary(k, positions, kind)
+        if cfg.attn_head_gate:
+            head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
         q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
         k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
         v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
@@ -489,10 +598,13 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     if cfg.dsa_index_heads:
         attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
     else:
-        with jax.named_scope("attn"):
-            attn = _attention(cfg, mesh, q, k, v)        # [B, H, S, Dv]
+        with jax.named_scope("attn" if kind.window is None else "attn_window"):
+            attn = _attention(cfg, mesh, q, k, v, kind)  # [B, H, S, Dv]
     with jax.named_scope("attn_proj"):
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * attn.shape[-1])
+        attn = attn.transpose(0, 2, 1, 3)
+        if cfg.attn_head_gate:
+            attn = attn * head_gate[..., None]
+        attn = attn.reshape(B, S, H * attn.shape[-1])
         x = x + (attn @ w["wo"].astype(cfg.dtype))
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
@@ -546,8 +658,10 @@ def _decoder(
     statistics (models/moe.py ``moe_layer``): ``balance``, ``z``,
     ``dropped``, ``rows_held`` and ``assignments`` summed over the layers,
     ``tokens_per_expert`` [n_sparse_layers, n_experts] and ``chosen``
-    [n_sparse_layers, B, S, k].  Leading dense layers
-    (``cfg.moe_dense_layers``, under params["dense_layers"]) run first.
+    [n_sparse_layers, B, S, k], the sparse layers in their order in the
+    model.  The layers run as ``cfg.layers`` lists them, each kind's out of
+    its own stack (leading dense layers, ``cfg.moe_dense_layers`` under
+    params["dense_layers"], are the oldest such pattern).
     ``router_bias`` [n_sparse_layers, n_experts]: the sigmoid router's
     choice bias, a constant of the loss."""
     rules = rules or ShardingRules()
@@ -574,58 +688,66 @@ def _decoder(
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
-    if cfg.moe_dense_layers:
-        def dense_body(x, w):
-            return _layer(cfg, mesh, rules, x, w, positions, sparse=False)[0]
+    stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
+    aux_total = jnp.zeros((), jnp.float32)
+    pieces, pending = [], []  # the layers' statistics: stacked runs, and layers still to be stacked
+
+    def flush():
+        if pending:
+            pieces.append(jax.tree.map(lambda *a: jnp.stack(a), *pending))
+            pending.clear()
+
+    if router_bias is not None:
+        assert sum(kind.sparse for kind, _ in cfg.stacks.values()) == 1, "router_bias's rows are one stack's layers"
+    # The walk of the pattern: runs of one kind, each through its own stack.
+    at = {stack: 0 for stack in cfg.stacks}  # the next layer of each stack
+    for kind, run in itertools.groupby(cfg.layers):
+        count, first = len(list(run)), at[kind.stack]
+        at[kind.stack] += count
+        with_stats = stats and (kind.sparse or cfg.dsa_index_heads > 0)
+        stacked = params[kind.stack]
+        if router_bias is not None and kind.sparse:
+            stacked = dict(stacked, router_bias=router_bias)
+
+        def body(x, w, kind=kind):
+            w = dict(w)
+            return _layer(cfg, mesh, rules, x, w, positions, kind=kind, router_bias=w.pop("router_bias", None))
 
         if cfg.remat:
-            dense_body = _remat(cfg, dense_body)
-        for i in range(cfg.moe_dense_layers):  # a leading layer or three: always a static loop
-            with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
-                x = dense_body(x, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]))
-
-    stacked = params["layers"]
-    if router_bias is not None:
-        stacked = dict(stacked, router_bias=router_bias)
-
-    def body(x, w):
-        w = dict(w)
-        return _layer(cfg, mesh, rules, x, w, positions, router_bias=w.pop("router_bias", None))
-
-    if cfg.remat:
-        body = _remat(cfg, body)
-    stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
-    if cfg.scan_unroll > 1 and cfg.scan_unroll >= cfg.n_layers:
-        # Full unroll as a STATIC Python loop rather than lax.scan(unroll=L):
-        # scan's internal layer slicing survives as dynamic-update-slice
-        # fusions in the backward (profiled: ~17 ms/step of DUS on the v5e
-        # flagship config); static integer indexing lets XLA constant-fold
-        # the slices and fold the per-layer grad writes, measured ~4 ms/step
-        # faster end-to-end.  Same math, different op association — results
-        # agree with the scan path to fusion-order rounding, not bitwise
-        # (pinned by test_scan_unroll_matches_scan).
-        aux_total = jnp.zeros((), jnp.float32)
-        aux_layers = []
-        for i in range(cfg.n_sparse_layers):
-            with jax.named_scope("stack"):
-                w_i = jax.tree.map(lambda a, i=i: a[i], stacked)
-                x, aux = body(x, w_i)
-            if stats:
-                aux_layers.append(aux)
-            else:
-                aux_total = aux_total + aux
-        if stats:
-            with jax.named_scope("stack"):
-                return x, _over_layers(jax.tree.map(lambda *a: jnp.stack(a), *aux_layers))
+            body = _remat(cfg, body)
+        # A run that `scan_unroll` covers whole is a STATIC Python loop rather
+        # than lax.scan(unroll=count): scan's internal layer slicing survives
+        # as dynamic-update-slice fusions in the backward (profiled: ~17
+        # ms/step of DUS on the v5e flagship config); static integer indexing
+        # lets XLA constant-fold the slices and fold the per-layer grad
+        # writes, measured ~4 ms/step faster end-to-end.  Same math, different
+        # op association — results agree with the scan path to fusion-order
+        # rounding, not bitwise (pinned by test_scan_unroll_matches_scan).
+        if count <= cfg.scan_unroll:
+            for i in range(first, first + count):
+                with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
+                    x, aux = body(x, jax.tree.map(lambda a, i=i: a[i], stacked))
+                if with_stats:
+                    pending.append(aux)
+                elif not stats:  # beside experts a dense layer has no statistics
+                    aux_total = aux_total + aux
+            continue
+        # The scan's own slicing of the stacked weights is `stack`.
+        with jax.named_scope("stack"):
+            if (first, count) != (0, cfg.stacks[kind.stack][1]):
+                stacked = jax.tree.map(lambda a: a[first:first + count], stacked)
+            x, aux_layers = jax.lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
+        if with_stats:
+            flush()
+            pieces.append(aux_layers)
+        elif not stats:
+            aux_total = aux_total + jnp.sum(aux_layers)
+    if not stats:
         return x, aux_total
-    # The scan's own slicing of the stacked weights is `stack`.
     with jax.named_scope("stack"):
-        x, aux_layers = jax.lax.scan(
-            body, x, stacked, unroll=cfg.scan_unroll
-        )
-        if stats:
-            return x, _over_layers(aux_layers)
-        return x, jnp.sum(aux_layers)
+        flush()
+        whole = pieces[0] if len(pieces) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *pieces)
+        return x, _over_layers(whole)
 
 
 def _remat(cfg: TransformerConfig, body):

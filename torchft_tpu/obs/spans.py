@@ -159,7 +159,9 @@ SUBSPANS = {
 #   norm — every RMS / layer norm but the final one; attn_proj — the q/k/v/o
 #     products, latent attention's low-rank path, RoPE, the reshapes and
 #     transposes around the heads; attn — the attention call: kernels and
-#     whatever XLA puts around them; dsa_index — the indexer's operands and
+#     whatever XLA puts around them; attn_window — the same call in a layer
+#     that attends under a window (the `tpuft_swa_*` kernels), so that a
+#     model of both kinds reads them apart; dsa_index — the indexer's operands and
 #     its loss; dsa_select — the selection and the mask built from it;
 #   ffn — the dense gate / up / down; router — scores, top-k and statistics;
 #     experts — row table, row moves, grouped matmuls, gate weighting;
@@ -169,7 +171,7 @@ SUBSPANS = {
 #     backward pass: the per-layer gradients padded and summed into the
 #     stacked gradient) and the stacking of the layers' statistics.
 PARTS = (
-    "embed", "norm", "attn_proj", "attn", "dsa_index", "dsa_select", "ffn",
+    "embed", "norm", "attn_proj", "attn", "attn_window", "dsa_index", "dsa_select", "ffn",
     "router", "experts", "shared_expert", "head_loss", "stack",
 )
 

@@ -39,7 +39,18 @@ Design notes (MXU/HBM-minded):
     step, so nothing is issued or fetched for it.  Every other call keeps
     the rectangle (batch*heads, outer_blocks, inner_blocks): there is
     nothing to skip.  The choice reads ``causal``, the mask and the
-    operands' shapes, and nothing else.
+    operands' shapes, and nothing else;
+  - a **window** (``flash_attention(..., window=w)``: a query at t sees the
+    keys s with ``0 <= t - s < w``) narrows the same walk to the band: a
+    tile has a step when it holds a pair inside the window, so a row of the
+    walk runs from `first_k` to `last_k` and a column from `first_q` to
+    `last_q` — 2n - 1 tiles at w = the tile's side, where the triangle has
+    n (n + 1) / 2.  Inside a tile ONE comparison keeps the pairs of the
+    band, diagonal and lower edge alike (``t - s`` read as an unsigned
+    number is under w exactly there).  The kernels are the same bodies
+    under names of their own (`tpuft_swa_fwd`, `tpuft_swa_bwd_dkdv_dq`), so
+    a trace tells the two kinds of layer apart.  A window that covers the
+    sequence is no window: the causal call, bit for bit.
 
 Query and key share one head width (``d_qk``), value and output another
 (``d_v``): equal for plain multi-head attention, 192 / 128 for latent
@@ -58,7 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +99,12 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     # measured slower because causal masking can only skip whole blocks
     # (a 1024-row block straddling the diagonal computes 33% more masked
     # elements at the flagship seq=1024 than two 512-row blocks).
+    # The band walk has the same tiles.  Under a window of 512 a 512 x 512 q
+    # tile visits two kv tiles and half of what it computes lies outside the
+    # band; at 256 x 256 it visits three, two thirds of them inside, at three
+    # times the grid steps — and is slower: 64 x 16,384 x 128 on a v5e read
+    # 10.0 ms forward and 12.6 backward at 512, 15.7 and 18.7 at 256
+    # (PERF.md section 6, PR 37).
     return min(512, seq_q), min(512, seq_k)
 
 
@@ -100,7 +117,8 @@ class _Walk:
     block_q + block_q - 1``, have a grid step; else the whole rectangle.
     ``kv_major`` walks column by column (the q axis innermost) instead of
     row by row.  Tiles need not be square (ops/sparse_attention.py's are
-    256 x 512)."""
+    256 x 512).  ``window`` (triangular walks only) keeps of those tiles the
+    ones with a pair ``0 <= t - s < window``: the band."""
 
     causal: bool
     seq_q: int
@@ -108,6 +126,10 @@ class _Walk:
     block_q: int
     block_k: int
     kv_major: bool = False
+    window: Optional[int] = None
+
+    def __post_init__(self) -> None:
+        assert self.window is None or (self.triangular and self.window > 0), "a window is causal over one sequence"
 
     @property
     def triangular(self) -> bool:
@@ -131,6 +153,8 @@ class _Walk:
             return ()
         qi, ki = np.indices((self.num_q, self.num_k), dtype=np.int32)
         visible = ki * self.block_k <= qi * self.block_q + self.block_q - 1
+        if self.window is not None:  # the nearest pair of the tile, (first row, last column), is inside
+            visible &= qi * self.block_q - (ki * self.block_k + self.block_k - 1) < self.window
         if self.kv_major:
             return qi.T[visible.T], ki.T[visible.T]
         return qi[visible], ki[visible]
@@ -187,6 +211,31 @@ class _Walk:
         """The first q tile a walk visits over kv tile ki."""
         return (ki * self.block_k) // self.block_q if self.triangular else 0
 
+    def first_k(self, qi):
+        """The first kv tile a walk visits under q tile qi: the one that
+        holds the oldest key its first row still sees."""
+        if self.window is None:
+            return 0
+        return jnp.maximum(qi * self.block_q - (self.window - 1), 0) // self.block_k
+
+    def last_q(self, ki):
+        """The last q tile a walk visits over kv tile ki: the one that holds
+        the latest query that still sees its last key."""
+        if self.window is None:
+            return self.num_q - 1
+        return jnp.minimum((ki * self.block_k + self.block_k - 1 + self.window - 1) // self.block_q, self.num_q - 1)
+
+    def keep(self, qi, ki, shape):
+        """[block_q, block_k] bool: the pairs of tile (qi, ki) a causal query
+        sees.  Under a window ``t - s`` is read as unsigned, so that one
+        comparison drops what lies above the diagonal (negative: a huge
+        number) and what lies below the band."""
+        rows = qi * self.block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = ki * self.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        if self.window is None:
+            return rows >= cols
+        return jax.lax.bitcast_convert_type(rows - cols, jnp.uint32) < jnp.uint32(self.window)
+
 
 def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
     """``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
@@ -196,11 +245,10 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
-    causal, block_q, block_k = walk.causal, walk.block_q, walk.block_k
     mask_ref = rest[0] if masked else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[1:] if masked else rest
 
-    @pl.when(ki == 0)
+    @pl.when(ki == walk.first_k(qi))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -223,13 +271,11 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
         ) * scale  # [block_q, block_k] f32
         if masked:
             s = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, s, _NEG_INF)
-        elif causal:
+        elif walk.causal:
             # Unconditional mask: branching per block via lax.cond measured
             # ~3 ms/step SLOWER than these VPU passes (Mosaic conditional
             # overhead exceeds the saved work at flagship shapes).
-            rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            s = jnp.where(walk.keep(qi, ki, s.shape), s, _NEG_INF)
 
         m_prev = m_scr[:, :1]                      # [block_q, 1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -262,20 +308,21 @@ def _tri(i, j):
 
 
 def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
-                    kv_group: int = 1):
+                    kv_group: int = 1, window: Optional[int] = None):
     """``mask``: int8 [batch, tiles, block_q, block_k], the (block_q,
     block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`),
     shared by a batch entry's heads (bh = batch * heads); None for the
     causal triangle.  ``kv_group``: k and v hold one head for every
-    ``kv_group`` of q's (grouped queries read their head in place)."""
+    ``kv_group`` of q's (grouped queries read their head in place).
+    ``window``: the band walk, under the name `tpuft_swa_fwd`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_q, d = q.shape  # d: query and key; dv: value and output
     seq_k, dv = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
-    assert mask is None or seq_q == seq_k, "a packed mask is one sequence's lower triangle"
-    walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k)
+    assert mask is None or (seq_q == seq_k and window is None), "a packed mask is one sequence's lower triangle"
+    walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window)
     spec = walk.spec
     operands, mask_specs = (q, k, v), []
     if mask is not None:
@@ -306,7 +353,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
             ],
         ),
         interpret=interpret,
-        name="tpuft_fa_fwd" if mask is None else "tpuft_dsa_attn_fwd",
+        name="tpuft_dsa_attn_fwd" if mask is not None else "tpuft_fa_fwd" if window is None else "tpuft_swa_fwd",
     )(*walk.tables, *operands)
     return out, lse_padded[:, :, 0]
 
@@ -332,7 +379,7 @@ def _dq_row_resident(seq_q: int, d: int) -> bool:
 
 
 def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-               *, scale, causal, block_q, block_k, mask_ref=None):
+               *, scale, walk: _Walk, mask_ref=None):
     """Shared flash-backward block body: recomputes p and ds for the
     (q-block qi, kv-block ki) tile.  Matmul operands stay in the input
     dtype (bf16 on the model path = full MXU rate); probabilities and
@@ -344,18 +391,16 @@ def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                   # [block_q, block_k] f32
-    p = jnp.exp(s - row_stat_col(lse_ref, qi, block_q))
+    p = jnp.exp(s - row_stat_col(lse_ref, qi, walk.block_q))
     if mask_ref is not None:
         p = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, p, 0.0)
-    elif causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        p = jnp.where(rows >= cols, p, 0.0)
+    elif walk.causal:
+        p = jnp.where(walk.keep(qi, ki, s.shape), p, 0.0)
     dp = jax.lax.dot_general(
         do, v_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                           # [block_q, block_k]
-    ds = (p * (dp - row_stat_col(delta_ref, qi, block_q)) * scale).astype(q.dtype)
+    ds = (p * (dp - row_stat_col(delta_ref, qi, walk.block_q)) * scale).astype(q.dtype)
     return p, ds
 
 
@@ -372,7 +417,7 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = walk.tile(refs)
-    causal, block_q, block_k = walk.causal, walk.block_q, walk.block_k
+    block_q = walk.block_q
     mask_ref = rest[0] if masked else None  # as `_fa_kernel`'s
     dk_ref, dv_ref, *rest = rest[1:] if masked else rest
     if with_dq:
@@ -393,8 +438,7 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
     @pl.when(run)
     def _step():
         p, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k, mask_ref=mask_ref,
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, scale=scale, walk=walk, mask_ref=mask_ref,
         )
         do = do_ref[0]
         dv_scr[...] += jax.lax.dot_general(
@@ -410,17 +454,18 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
                 ds, k_ref[0], preferred_element_type=jnp.float32
             )                                       # ds @ k: [block_q, d]
 
-            # Every q block runs against kv block 0, causal or not, so the
-            # first visit assigns and the row is never zeroed.
-            @pl.when(ki == 0)
+            # Every q block runs against its first kv block (block 0 without
+            # a window), causal or not, so the first visit assigns and the row
+            # is never zeroed.
+            @pl.when(ki == walk.first_k(qi))
             def _first():
                 dq_scr[q_rows, :] = dq_tile
 
-            @pl.when(ki != 0)
+            @pl.when(ki != walk.first_k(qi))
             def _add():
                 dq_scr[q_rows, :] += dq_tile
 
-    @pl.when(qi == walk.num_q - 1)
+    @pl.when(qi == walk.last_q(ki))
     def _emit():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -444,15 +489,14 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr) = walk.tile(refs)
 
-    @pl.when(ki == 0)
+    @pl.when(ki == walk.first_k(qi))
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     @pl.when(walk.visible(qi, ki))
     def _step():
         _, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-            scale=scale, causal=walk.causal, block_q=walk.block_q, block_k=walk.block_k,
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, scale=scale, walk=walk,
         )
         dq_scr[...] += jax.lax.dot(
             ds, k_ref[0], preferred_element_type=jnp.float32
@@ -464,14 +508,16 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
 
 
 def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
-                   interpret: bool = False, mask=None, kv_group: int = 1):
+                   interpret: bool = False, mask=None, kv_group: int = 1, window: Optional[int] = None):
     """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
     [BH, S] f32.  One kernel (`tpuft_fa_bwd_dkdv_dq`) where one head's f32
     dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
     `tpuft_fa_bwd_dq`.  ``mask`` as `_fa_pallas_call`'s: the masked backward
     is the one-pass kernel only, under the name `tpuft_dsa_attn_bwd_dkdv_dq`;
     with ``kv_group`` k and v are read in place and dk, dv come out a query
-    head each, for the caller to sum over a group."""
+    head each, for the caller to sum over a group.  ``window``: the band
+    walk, the same kernels as `tpuft_swa_bwd_dkdv_dq` (`tpuft_swa_bwd_dkdv`,
+    `tpuft_swa_bwd_dq`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -479,6 +525,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     seq_k, d_v = k.shape[1], v.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k)
     causal = causal or mask is not None
+    family = "tpuft_fa" if window is None else "tpuft_swa"
     # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
     # rowsum(do * o) is O(S*D) and computed once here instead of per tile.
     lse = lse[:, None, :]
@@ -501,12 +548,12 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
             spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
         ]
 
-    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True)
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True, window=window)
     in_specs, out_specs = specs(walk)
     one_pass = _dq_row_resident(seq_q, d)
     operands = (q, k, v, g, lse, delta)
     if mask is not None:
-        assert one_pass and seq_q == seq_k, "the masked backward keeps one sequence's dq row in VMEM"
+        assert one_pass and seq_q == seq_k and window is None, "the masked backward keeps one sequence's dq row in VMEM"
         heads = bh // mask.shape[0]
         operands += (mask,)
         in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0)))
@@ -542,7 +589,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         # The benchmark books device time to attention by these names'
         # substrings: the one-pass name has to contain `tpuft_fa_bwd_dkdv`.
         name=("tpuft_dsa_attn_bwd_dkdv_dq" if mask is not None
-              else "tpuft_fa_bwd_dkdv_dq" if one_pass else "tpuft_fa_bwd_dkdv"),
+              else family + "_bwd_dkdv_dq" if one_pass else family + "_bwd_dkdv"),
     )(*walk.tables, *operands)
     if one_pass:
         dk, dv, dq = outs
@@ -550,7 +597,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     dk, dv = outs
 
     # Second pass for a row over the budget: dq with the kv axis innermost.
-    walk = _Walk(causal, seq_q, seq_k, block_q, block_k)
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, window=window)
     in_specs, _ = specs(walk)
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale),
@@ -558,19 +605,25 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         grid_spec=walk.grid_spec(
             bh, in_specs, in_specs[0], [pltpu.VMEM((block_q, d), jnp.float32)]),
         interpret=interpret,
-        name="tpuft_fa_bwd_dq",
+        name=family + "_bwd_dq",
     )(*walk.tables, q, k, v, g, lse, delta)
     return dq, dk, dv
 
 
-def _fa_reference(q, k, v, scale: float, causal: bool):
+def _visible(seq_q: int, seq_k: int, window: Optional[int]):
+    """[seq_q, seq_k] bool: what a causal query sees, whole."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 1)
+    if window is None:
+        return rows >= cols
+    return (rows >= cols) & (rows - cols < window)
+
+
+def _fa_reference(q, k, v, scale: float, causal: bool, window: Optional[int] = None):
     """Stable XLA attention returning (out, lse); q/k: [BH, S, D], v: [BH, S, Dv]."""
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
     if causal:
-        seq_q, seq_k = s.shape[-2], s.shape[-1]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window), s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -579,17 +632,17 @@ def _fa_reference(q, k, v, scale: float, causal: bool):
     return o.astype(q.dtype), lse
 
 
-def _fa_forward(q, k, v, scale: float, causal: bool, kernel: bool):
+def _fa_forward(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[int]):
     if kernel:
-        return _fa_pallas_call(q, k, v, scale, causal)
-    return _fa_reference(q, k, v, scale, causal)
+        return _fa_pallas_call(q, k, v, scale, causal, window=window)
+    return _fa_reference(q, k, v, scale, causal, window)
 
 
 # `kernel` is decided once, in flash_attention, from the shapes and the mesh
 # of the program being traced, so forward and backward cannot disagree.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, scale: float, causal: bool, kernel: bool):
-    o, _ = _fa_forward(q, k, v, scale, causal, kernel)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[int]):
+    o, _ = _fa_forward(q, k, v, scale, causal, kernel, window)
     return o
 
 
@@ -600,31 +653,28 @@ def _flash(q, k, v, scale: float, causal: bool, kernel: bool):
 SAVED_NAMES = ("tpuft_fa_out", "tpuft_fa_lse")
 
 
-def _flash_fwd(q, k, v, scale, causal, kernel):
+def _flash_fwd(q, k, v, scale, causal, kernel, window):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fa_forward(q, k, v, scale, causal, kernel)
+    o, lse = _fa_forward(q, k, v, scale, causal, kernel, window)
     o, lse = checkpoint_name(o, SAVED_NAMES[0]), checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, kernel, res, g):
+def _flash_bwd(scale, causal, kernel, window, res, g):
     q, k, v, o, lse = res
     if kernel:
-        return _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal)
-    return _fa_bwd_xla(q, k, v, o, lse, g, scale, causal)
+        return _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window)
+    return _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window)
 
 
-def _fa_bwd_xla(q, k, v, o, lse, g, scale, causal):
+def _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window: Optional[int] = None):
     """Off-TPU backward: same math with the scores materialized in XLA.
     Also the oracle the pallas backward kernels are tested against."""
     qf, kf, vf, gf = (t.astype(jnp.float32) for t in (q, k, v, g))
     s = jnp.einsum("bqd,bkd->bqk", qf, kf) * scale
     if causal:
-        seq_q, seq_k = s.shape[-2], s.shape[-1]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window), s, _NEG_INF)
     p = jnp.exp(s - lse[..., None])                     # recompute softmax
     dv = jnp.einsum("bqk,bqd->bkd", p, gf)
     dp = jnp.einsum("bqd,bkd->bqk", gf, vf)
@@ -645,6 +695,7 @@ def flash_attention(
     causal: bool = True,
     scale: float | None = None,
     mesh=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention; q: [B, Hq, S, D], k: [B, Hkv, S, D], v:
     [B, Hkv, S, Dv] -> [B, Hq, S, Dv].  Dv may differ from D (MLA: 192 for
@@ -654,8 +705,15 @@ def flash_attention(
     ``mesh`` is the mesh of the program being traced (None: the ambient
     abstract mesh); under more than one device the XLA formulation runs,
     see ``_pallas_util.kernels_apply``.
+
+    ``window`` (causal only): a query at t sees the keys s with ``0 <= t - s
+    < window``.  One that covers the sequence is no window.
     """
     b, hq, sq, d = q.shape
+    if window is not None:
+        assert causal and sq == k.shape[2] and window > 0, "a window is causal over one sequence"
+        if window >= sq:
+            window = None
     hkv, dv = k.shape[1], v.shape[3]
     if hkv != hq:
         assert hq % hkv == 0, "query heads must be a multiple of kv heads"
@@ -676,5 +734,6 @@ def flash_attention(
         scale,
         causal,
         kernel,
+        window,
     )
     return out.reshape(b, hq, sq, dv)

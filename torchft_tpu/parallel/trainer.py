@@ -185,6 +185,7 @@ class TrainStep:
         # the call after a trace (`_retraced`) and on no other.
         self._retraced: set = set()
         self._ran: dict = {}
+        self._texts: Optional[dict] = None
         from torchft_tpu.obs import opmap
 
         opmap.register(self)
@@ -209,6 +210,7 @@ class TrainStep:
         argument still knows its shape, type and placement)."""
         if fn.__name__ in self._retraced:
             self._retraced.discard(fn.__name__)
+            self._texts = None
             leaves, treedef = jax.tree.flatten(args)
             self._ran[fn.__name__] = (fn, treedef, [
                 # an uncommitted array compiles as an argument without a placement
@@ -226,13 +228,25 @@ class TrainStep:
         is for after the steps of interest, never inside one."""
         from torchft_tpu.obs import opmap
 
-        programs = {}
-        for fn, treedef, leaves in self._ran.values():
-            args = jax.tree.unflatten(
-                treedef, [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype, sharding in leaves])
-            text = fn.lower(*args).compile().as_text()
-            programs[opmap.module_name(text)] = opmap.op_names(text, detail=detail)
-        return programs
+        return {name: opmap.op_names(text, detail=detail) for name, text in self.compiled_texts().items()}
+
+    def compiled_texts(self) -> dict:
+        """{program: the compiled executable's text} of the programs as they
+        last ran: every instruction with its metadata, and a pallas kernel's
+        custom call with its grid (`iteration_bounds` in the body) and its
+        operands' shapes.  Lowers and compiles again — from the jit and
+        compile caches — once for a set of programs, so it is for after the
+        steps of interest, never inside one."""
+        from torchft_tpu.obs import opmap
+
+        if self._texts is None:
+            self._texts = {}
+            for fn, treedef, leaves in self._ran.values():
+                args = jax.tree.unflatten(treedef, [
+                    jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype, sharding in leaves])
+                text = fn.lower(*args).compile().as_text()
+                self._texts[opmap.module_name(text)] = text
+        return self._texts
 
     def _loss_and_grads(self, params, batch, step: Optional[int] = None):
         out, grads = self._grads_fn(params, batch)
