@@ -413,7 +413,9 @@ def test_rematerialised_layers_give_the_gradients_of_the_stored_ones(keeps_atten
     (loss, _), stored = _value_and_grad(cfg, weights, batch)
     (again_loss, _), again = _value_and_grad(
         dataclasses.replace(cfg, remat=True, remat_keeps_attention=keeps_attention), weights, batch)
-    assert float(again_loss) == float(loss)
+    # the same float32 sums; XLA:CPU contracts one product of RoPE's `x * cos + swapped * sin` into the sum, and
+    # which one it picks differs between a layer under `jax.checkpoint` and one outside: a unit in the last place
+    assert abs(float(again_loss) - float(loss)) <= float(np.spacing(np.float32(loss)))
     leaf, rel = _worst_leaf(again, stored)
     assert rel < 1e-6, (leaf, rel)
 

@@ -148,6 +148,40 @@ def test_the_scopes_change_no_instruction(programs, name) -> None:
     assert canonical(plain) == canonical(scoped)
 
 
+_RECORDED = os.path.join(ROOT, "tests", "data", "hlo_before_the_pattern.json")
+
+
+def _digest(step, params, batch, program: str) -> str:
+    import hashlib
+
+    if program == "grads":
+        text = step.lower_grads(params, batch).compile().as_text()
+    else:
+        _, grads = step.grads(params, batch)
+        text = step._apply_fn.lower(params, step.init_opt_state(params), grads).compile().as_text()
+    return hashlib.sha256(canonical(text).encode()).hexdigest()
+
+
+def record(commit: str) -> None:
+    """Records the ten digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    `JAX_PLATFORMS=cpu`): for a PR that changes the five gradient programs on
+    purpose.  The update programs are no model code's to change, so theirs
+    have to come out as they were."""
+    import json
+
+    with open(_RECORDED, encoding="utf-8") as f:
+        before = json.load(f)
+    digests = {f"{name}.{program}": _digest(*_step_and_arguments(name), program)
+               for name in BEFORE_THE_PATTERN for program in ("grads", "update")}
+    if before["jax"] == jax.__version__:
+        moved = sorted(k for k, v in digests.items() if v != before["sha256_of_canonical_hlo"][k])
+        assert not [k for k in moved if k.endswith(".update")], moved
+        print("differ from the record:", moved or "none")
+    with open(_RECORDED, "w", encoding="utf-8") as f:
+        json.dump({"jax": jax.__version__, "commit": commit, "sha256_of_canonical_hlo": digests}, f, indent=1)
+        f.write("\n")
+
+
 @pytest.mark.parametrize("program", ["grads", "update"])
 @pytest.mark.parametrize("name", BEFORE_THE_PATTERN)
 def test_the_pattern_left_the_five_programs_as_they_were(name, program) -> None:
@@ -157,21 +191,14 @@ def test_the_pattern_left_the_five_programs_as_they_were(name, program) -> None:
     instructions they compiled to before (canonical optimized HLO, by digest;
     recorded from the parent tree with this JAX).  A PR that changes these
     programs on purpose records them anew: `tests/data/hlo_before_the_pattern.json`."""
-    import hashlib
     import json
 
-    with open(os.path.join(ROOT, "tests", "data", "hlo_before_the_pattern.json"), encoding="utf-8") as f:
+    with open(_RECORDED, encoding="utf-8") as f:
         recorded = json.load(f)
     if recorded["jax"] != jax.__version__:
         pytest.skip(f"recorded with JAX {recorded['jax']}, this is {jax.__version__}")
     step, params, batch = _step_and_arguments(name)
-    if program == "grads":
-        text = step.lower_grads(params, batch).compile().as_text()
-    else:
-        _, grads = step.grads(params, batch)
-        text = step._apply_fn.lower(params, step.init_opt_state(params), grads).compile().as_text()
-    digest = hashlib.sha256(canonical(text).encode()).hexdigest()
-    assert digest == recorded["sha256_of_canonical_hlo"][f"{name}.{program}"]
+    assert _digest(step, params, batch, program) == recorded["sha256_of_canonical_hlo"][f"{name}.{program}"]
     # and the tree keeps its leaves' names and shapes: heal and checkpoints read what they wrote
     cfg = MODELS[name]
     assert set(params) == {"embed", "final_norm", "lm_head", "layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set())
@@ -336,3 +363,7 @@ ENTRY %main.7 (x: f32[8]) -> f32[] {
     stripped = without_metadata(text)
     assert "metadata" not in stripped and "model.py" not in stripped and "%fusion.3 = " in stripped
     assert canonical(text) == canonical(text.replace("kernel.4", "jvp_kernel_.9"))
+
+
+if __name__ == "__main__":
+    record(sys.argv[1])

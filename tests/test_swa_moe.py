@@ -34,7 +34,7 @@ if ROOT not in sys.path:
 from benchmark.spec import Benchmark  # noqa: E402
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
 from torchft_tpu.models.moe import moe_layer  # noqa: E402
-from torchft_tpu.models.transformer import _rotary, loss_and_counters, param_axes, yarn_frequencies  # noqa: E402
+from torchft_tpu.models.transformer import _rope, _rotary, loss_and_counters, param_axes, yarn_frequencies  # noqa: E402
 from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
 
 BENCH = Benchmark(ROOT)
@@ -257,6 +257,162 @@ def test_rope_against_a_written_out_rotation(kind) -> None:
     np.testing.assert_allclose(got[0, :3], want[0, :3], atol=1e-5)
     if kind == "full":
         assert (got[..., 64:] == x[..., 64:].astype(np.float32)).all()
+
+
+def _two_halves(x, positions, inv_freq, factor, rot):
+    """The two-halves form `_rope` and `_rotary` had before PR 39, as plain
+    `jax.numpy`: the rotated part split at its middle, turned, joined again."""
+    half = rot // 2
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * factor)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, xf[..., rot:]], axis=-1).astype(x.dtype)
+
+
+_YARN = (64.0, 4096, 64.0, 1.0, 1.4158883083359672)
+# (the call under test, the two-halves form of the same turn, the array's shape): a window layer's 128 columns, a full
+# layer's 64 of 128 under YaRN, latent attention's 64 rotary columns (every head's, and the one key all heads share)
+# and the indexer's 64-wide heads
+_WINDOW_KIND, _FULL_KIND = LayerKind("window_layers", True, 64, 1e4, window=512), LayerKind("layers", True, 48, 5e5, rotary_fraction=0.5, yarn=_YARN)
+_ROPE_CALLS = {
+    "full_128": (lambda x, p: _rotary(x, p, _WINDOW_KIND),
+                 lambda x, p: _two_halves(x, p, 1e4 ** (-jnp.arange(0, 64, dtype=jnp.float32) / 64), 1.0, 128), (2, 24, 3, 128)),
+    "yarn_64_of_128": (lambda x, p: _rotary(x, p, _FULL_KIND),
+                       lambda x, p: _two_halves(x, p, jnp.asarray(yarn_frequencies(5e5, 64, *_YARN[:4]), jnp.float32), _YARN[4], 64), (2, 24, 3, 128)),
+    "latent_64": (lambda x, p: _rope(x, p, 5e4), lambda x, p: _two_halves(x, p, 5e4 ** (-jnp.arange(0, 32, dtype=jnp.float32) / 32), 1.0, 64), (2, 24, 3, 64)),
+    "one_key_64": (lambda x, p: _rope(x, p, 1e4), lambda x, p: _two_halves(x, p, 1e4 ** (-jnp.arange(0, 32, dtype=jnp.float32) / 32), 1.0, 64), (2, 24, 1, 64)),
+}
+
+
+def _bits(a) -> np.ndarray:
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("call", list(_ROPE_CALLS))
+def test_rope_is_the_two_halves_form_bit_for_bit(call, dtype) -> None:
+    """`_rope` / `_rotary` against the two-halves form: positions up to 32,767
+    with an offset a sequence, values and the gradient of a seeded scalar.
+    Operation by operation (no jit: each primitive rounds alone) they agree
+    to the bit, at either width — the same two float32 products and one sum
+    an element.  Under jit XLA:CPU contracts a product into the sum, and which
+    of the two depends on the order they are written in (the two-halves form
+    differs from ITSELF run operation by operation in the same way), so there
+    a rounding of one float32 product is allowed, and a unit in the last
+    place of bf16 where that tips the last rounding.  What is not finite in
+    a column that passes through stays where it was, and reaches no other
+    column."""
+    new, old, shape = _ROPE_CALLS[call]
+    rng = np.random.default_rng(39)
+    x = jnp.asarray(rng.standard_normal(shape), dtype)
+    positions = jnp.asarray(np.arange(shape[1])[None, :] * 1423 + np.asarray([[35], [32_767 - 23 * 1423]]), jnp.int32)
+    assert int(positions.max()) == 32_767 and int(positions[0, 0]) == 35
+    weight = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def scalar(f):
+        return lambda x: (f(x, positions).astype(jnp.float32) * weight).sum()
+
+    with jax.disable_jit():
+        got, want = new(x, positions), old(x, positions)
+        grad_got, grad_want = jax.grad(scalar(new))(x), jax.grad(scalar(old))(x)
+    assert got.dtype == want.dtype == dtype and grad_got.dtype == dtype
+    assert (_bits(got) == _bits(want)).all() and (_bits(grad_got) == _bits(grad_want)).all()
+    assert float(jnp.abs(got.astype(jnp.float32) - x.astype(jnp.float32)).max()) > 0.5  # it turned something
+    jit_got, jit_want = jax.jit(new)(x, positions), jax.jit(old)(x, positions)
+    jit_grad_got, jit_grad_want = jax.jit(jax.grad(scalar(new)))(x), jax.jit(jax.grad(scalar(old)))(x)
+    # a contracted product is not rounded before the sum: the two differ by a rounding of that PRODUCT (the sum may
+    # have cancelled to far less), and then by a unit of the dtype where that moves the last rounding
+    product = np.spacing(np.float32(1.5 * max(float(jnp.abs(x.astype(jnp.float32)).max()), float(jnp.abs(weight).max()))))
+    for a, b in ((jit_got, jit_want), (jit_grad_got, jit_grad_want), (jit_got, got), (jit_grad_got, grad_got)):
+        a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        ulp = np.spacing(np.maximum(np.abs(a32), np.abs(b32))) * 2 ** 16 if dtype == jnp.bfloat16 else 0.0
+        assert (np.abs(a32 - b32) <= ulp + product).all()
+    if call == "yarn_64_of_128":
+        odd = x.at[0, 3, 1, 70].set(jnp.inf).at[1, 5, 0, 127].set(jnp.nan).at[0, 0, 2, 64].set(-jnp.inf).at[1, 1, 1, 100].set(-0.0)
+        with jax.disable_jit():
+            assert (_bits(new(odd, positions)) == _bits(old(odd, positions))).all()
+        for out in (new(odd, positions), jax.jit(new)(odd, positions)):
+            assert (_bits(out[..., 64:]) == _bits(odd[..., 64:])).all()
+            assert np.isfinite(np.asarray(out[..., :64], np.float32)).all()
+        cot = jax.grad(lambda x: (new(x, positions).astype(jnp.float32) * jnp.asarray(odd, jnp.float32)).sum())(x)
+        assert np.isfinite(np.asarray(cot[..., :64], np.float32)).all() and not np.isfinite(np.asarray(cot[..., 64:], np.float32)).all()
+
+
+def _primitives(jaxpr, found):
+    """Every equation of `jaxpr` and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+@pytest.mark.parametrize("call", list(_ROPE_CALLS))
+def test_a_head_is_never_cut_into_halves(call, direction) -> None:
+    """Nothing splits, slices or joins q along its column axis, forward or
+    backward, at 128 columns or at 64: the halves change places by `pad`s
+    that move the whole axis and a `select_n`, inside one `custom_vjp`.
+    Forward the pads move x after its cast to float32 (on the chip the
+    product before them then hands over its float32 result, as it did to the
+    two-halves form); backward they move the cotangent in its own dtype.
+    The two-halves form, walked the same way, shows its `concatenate`."""
+    new, old, shape = _ROPE_CALLS[call]
+    x = jnp.zeros(shape, jnp.bfloat16)
+    positions = jnp.zeros(shape[:2], jnp.int32)
+
+    def walked(f):
+        fn = (lambda x: f(x, positions)) if direction == "forward" else jax.grad(lambda x: f(x, positions).astype(jnp.float32).sum())
+        eqns = _primitives(jax.make_jaxpr(fn)(x).jaxpr, [])
+        on_columns = [e for e in eqns if e.primitive.name in ("concatenate", "split", "slice", "dynamic_slice", "gather")
+                      and any(getattr(v.aval, "ndim", 0) == 4 and v.aval.shape[2] == shape[2] for v in e.invars)]
+        return eqns, [e.primitive.name for e in eqns], on_columns
+
+    eqns, names, on_columns = walked(new)
+    assert not on_columns, [str(e) for e in on_columns]
+    assert "concatenate" not in names and "split" not in names
+    assert "select_n" in names and (direction == "gradient" or any(n.startswith("custom_vjp") for n in names))
+    pads = [e for e in eqns if e.primitive.name == "pad"]
+    # a gradient's jaxpr holds the forward's two pads as well (the residuals are the tables, so XLA drops them)
+    assert {str(e.invars[0].aval.dtype) for e in pads[-2:]} == ({"float32"} if direction == "forward" else {"bfloat16"}) and len(pads) >= 2
+    _, old_names, old_on_columns = walked(old)
+    assert "concatenate" in old_names and old_on_columns
+
+
+def test_a_model_of_whole_heads_is_the_two_halves_model(monkeypatch) -> None:
+    """A small model at the Laguna cell's head width (128 columns: a window
+    layer turned whole, a full layer turned over 64 of 128 under YaRN, K and
+    V repeated to the query heads) gives the loss and the gradients it gives
+    with `_turn` put back to the two-halves form: the same float32 sums, so
+    equal to the rounding of a product XLA:CPU contracts in one and not the
+    other."""
+    from torchft_tpu.models import transformer
+
+    cfg = TransformerConfig(vocab_size=64, d_model=64, n_layers=2, n_heads=2, n_kv_heads=1, head_dim=128, d_ff=64, max_seq=32,
+                            dtype=jnp.float32, attn_head_gate=True, scan_unroll=2,
+                            pattern=(LayerKind("window_layers", False, 2, 1e4, window=8),
+                                     LayerKind("layers", False, 2, 5e5, rotary_fraction=0.5, yarn=(4.0, 16, 4.0, 1.0, 1.1))))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 32)), jnp.int32)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
+
+    def loss_and_grads():
+        return jax.jit(jax.value_and_grad(lambda p: loss_and_counters(p, batch, cfg)[0]))(params)
+
+    loss, grads = loss_and_grads()
+    calls = []
+    monkeypatch.setattr(transformer, "_turn", lambda *a: calls.append(a[0].shape) or _two_halves(*a))
+    old_loss, old_grads = loss_and_grads()
+    assert sorted(set(calls)) == [(2, 32, 1, 128), (2, 32, 2, 128)]
+    np.testing.assert_allclose(float(loss), float(old_loss), rtol=1e-6)
+    for new, old in zip(jax.tree.leaves(grads), jax.tree.leaves(old_grads)):
+        np.testing.assert_allclose(np.asarray(new), np.asarray(old), rtol=1e-4, atol=1e-5 * float(jnp.abs(old).max()))
 
 
 # -- one chip's share of an expert-parallel layer ---------------------------------
