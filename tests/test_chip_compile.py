@@ -277,7 +277,7 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
     assert len(ran) > 500 and set(ran) <= set(ops)
     booked = {name: opmap.booked(entry) for name, entry in ops.items()}
-    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
     assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
@@ -585,3 +585,56 @@ def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, m
     # 15,451,607,040 (15,167,032,832 with the full layers' attention kept alone; builder's compiles,
     # PR 37): the chip's allocator has 16.9e9
     assert resident <= 15.5e9, f"the step needs {resident} bytes with AdamW's moments"
+
+
+def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `zaya1-8b` configuration as `benchmark/programs/cca_moe_lm.py`
+    hands it to `TrainStep`: the whole gradient program at the published widths
+    and 1 x 16,384 tokens — compressed attention through `tpuft_fa_*` at 8 query
+    heads on 2 KV heads in each of four layers, the 8 held experts of each layer
+    through `tpuft_gmm_*`, the tied 131,136-row head through `tpuft_ce_*` over
+    blocks of 1,024 rows — with room for AdamW's moments beside it on a 16 GiB
+    chip, and no array of rows x vocabulary anywhere in it."""
+    import os
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("zaya1-8b"), bench.traffic("steady-1g-16k")
+    shapes = jax.eval_shape(lambda: bench.reference("cca_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("cca_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    # attention's output kept under remat: one forward and one backward kernel a layer
+    assert config["program"]["remat_keeps_attention"]
+    assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq"] * 4 + ["tpuft_fa_fwd"] * 4
+    # three projections a layer: forward, recomputed, and the two gradients
+    gmm = _kernel_calls(text, "tpuft_gmm_")
+    assert sorted(gmm) == ["tpuft_gmm_dlhs"] * 12 + ["tpuft_gmm_drhs"] * 12 + ["tpuft_gmm_fwd"] * 24
+    # the head: each kernel once in the text, inside the loop over the 16 blocks of rows
+    assert sorted(_kernel_calls(text, "tpuft_ce_")) == ["tpuft_ce_dlogits", "tpuft_ce_lse"]
+    rows, vocab = 16_384, 131_136
+    import math
+
+    widest = max(math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(r"(?:bf16|f32|s32)\[([0-9,]+)\]", text))
+    # the largest array is the embedding padded to the kernels' 131,584 columns (the head's weight, and the buffer its
+    # gradient is summed into): an eighth of rows x vocabulary; a block's dlogits [1,024, 131,584] are half of that
+    assert widest == 131_584 * 2_048 <= rows * 131_584 // 8, widest
+    assert "[1024,131584]" in text and f"[{rows},{vocab}]" not in text and f"[{rows},131584]" not in text
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("cca_moe_lm").total_params(config) == 696_250_376
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # 15,076,943,936 (temporaries 3,936,799,744; builder's compile, PR 41): the chip's allocator has 16.9e9.  With
+    # blocks of 2,048 rows 15.88e9, of 4,096 rows 16.99e9
+    assert resident <= 15.1e9, f"the step needs {resident} bytes with AdamW's moments"

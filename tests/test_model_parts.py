@@ -62,14 +62,23 @@ MODELS["laguna"] = TransformerConfig(**dict(
     dense_d_ff=128, moe_capacity_factor=None, moe_held=(2, 2), moe_score="sigmoid", moe_route_scale=2.5,
     moe_shared_experts=1, moe_aux_coef=0.001, remat=True, remat_keeps_attention=True, scan_unroll=8,
     pattern=(LayerKind("dense_layers", False, **_FULL),) + (LayerKind("window_layers", True, 8, 1e4, window=16),) * 3 + (LayerKind("layers", True, **_FULL),)))
+# The six the benchmark had before a mixer was the kind's: PR 41 pins the sixth beside the five.
+PINNED = BEFORE_THE_PATTERN + ("laguna",)
+# The seventh: attention inside a compressed latent, a router with a carried state and a choice that
+# takes no expert, learned merges, the head read off the embedding.
+MODELS["zaya"] = TransformerConfig(**dict(
+    _BASE, n_layers=3, n_heads=8, n_kv_heads=2, head_dim=16, moe_experts=8, moe_top_k=1, moe_norm_topk=False, d_ff=32,
+    moe_capacity_factor=None, moe_held=(0, 4), moe_router_state=16, moe_skip=True, scaled_merge=True, tied_head=True,
+    remat=True, remat_keeps_attention=True, scan_unroll=8,
+    pattern=(LayerKind("layers", True, 8, 5e6, rotary_fraction=0.5, mixer="cca"),) * 3))
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
 
 def _step_and_arguments(name: str):
     cfg = MODELS[name]
-    biased = cfg.moe_score == "sigmoid" and not cfg.pattern
-    bias = jnp.zeros((cfg.n_sparse_layers, cfg.moe_experts), jnp.float32) if biased else None
+    biased = (cfg.moe_score == "sigmoid" and not cfg.pattern) or cfg.moe_skip
+    bias = jnp.zeros((cfg.n_sparse_layers, cfg.n_router_outputs), jnp.float32) if biased else None
     step = TrainStep(ft_init_mesh({"data": 1}, devices=jax.devices()[:1]), optax.adamw(1e-3),
                      lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias), loss_has_counters=True)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, size=(2, SEQ)).astype(np.int32)
@@ -120,6 +129,8 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
         expected |= {"ffn"}
     if any(kind.window for kind in cfg.layers):
         expected |= {"attn_window"}
+    if any(kind.mixer == "cca" for kind in cfg.layers):
+        expected |= {"cca_mix"}
     if cfg.moe_shared_experts:
         expected |= {"shared_expert"}
     if cfg.dsa_index_heads:
@@ -163,8 +174,8 @@ def _digest(step, params, batch, program: str) -> str:
 
 
 def record(commit: str) -> None:
-    """Records the ten digests anew (`python tests/test_model_parts.py "<commit and why>"`,
-    `JAX_PLATFORMS=cpu`): for a PR that changes the five gradient programs on
+    """Records the twelve digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    `JAX_PLATFORMS=cpu`): for a PR that changes the six gradient programs on
     purpose.  The update programs are no model code's to change, so theirs
     have to come out as they were."""
     import json
@@ -172,7 +183,7 @@ def record(commit: str) -> None:
     with open(_RECORDED, encoding="utf-8") as f:
         before = json.load(f)
     digests = {f"{name}.{program}": _digest(*_step_and_arguments(name), program)
-               for name in BEFORE_THE_PATTERN for program in ("grads", "update")}
+               for name in PINNED for program in ("grads", "update")}
     if before["jax"] == jax.__version__:
         moved = sorted(k for k, v in digests.items() if v != before["sha256_of_canonical_hlo"][k])
         assert not [k for k in moved if k.endswith(".update")], moved
@@ -183,13 +194,15 @@ def record(commit: str) -> None:
 
 
 @pytest.mark.parametrize("program", ["grads", "update"])
-@pytest.mark.parametrize("name", BEFORE_THE_PATTERN)
+@pytest.mark.parametrize("name", PINNED)
 def test_the_pattern_left_the_five_programs_as_they_were(name, program) -> None:
     """`_decoder` walks a pattern since PR 37, of which "leading dense layers,
     then the model's own kind" is one instance: for the five configurations the
     benchmark had, the gradient and the update program compile to the
     instructions they compiled to before (canonical optimized HLO, by digest;
-    recorded from the parent tree with this JAX).  A PR that changes these
+    recorded from the parent tree with this JAX) — and since PR 41, which made
+    the mixer the kind's and let the walk carry a second stream, the sixth
+    (window and full attention mixed) with them.  A PR that changes these
     programs on purpose records them anew: `tests/data/hlo_before_the_pattern.json`."""
     import json
 
@@ -201,7 +214,9 @@ def test_the_pattern_left_the_five_programs_as_they_were(name, program) -> None:
     assert _digest(step, params, batch, program) == recorded["sha256_of_canonical_hlo"][f"{name}.{program}"]
     # and the tree keeps its leaves' names and shapes: heal and checkpoints read what they wrote
     cfg = MODELS[name]
-    assert set(params) == {"embed", "final_norm", "lm_head", "layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set())
+    assert set(params) == {"embed", "final_norm", "lm_head"} | set(cfg.stacks)
+    assert set(cfg.stacks) == ({"dense_layers", "window_layers", "layers"} if name == "laguna" else
+                               {"layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set()))
     assert {s: n for s, (_, n) in cfg.stacks.items()} == {k: v["attn_norm"].shape[0] for k, v in params.items() if "layers" in k}
 
 

@@ -488,6 +488,54 @@ def test_fused_cross_entropy_pallas_interpret_matches() -> None:
     )
 
 
+@pytest.mark.parametrize("vocab_major", [False, True], ids=["head_leaf", "tied_embedding"])
+@pytest.mark.parametrize("block", [96, 48, 40], ids=["one_block", "two_blocks", "a_block_that_does_not_divide"])
+def test_a_head_over_blocks_of_rows_is_the_one_block_head(block, vocab_major) -> None:
+    """`fused_linear_cross_entropy_rows` (XLA fallback path) against the
+    padded one-block op at a width no tile divides (200 -> 256 columns): the
+    loss to the rounding of a sum of 96 float32 terms taken in another order,
+    dx and dw to 1e-5 of their largest entry (the blocks' parts of dw are
+    summed in float32 in another order); the weight as the tree holds it,
+    [E, V] or the embedding's [V, E], and its gradient in that layout."""
+    from torchft_tpu.ops.cross_entropy import fused_linear_cross_entropy_padded, fused_linear_cross_entropy_rows
+
+    rng = np.random.default_rng(13)
+    n, e, v = 96, 32, 200
+    x = jnp.asarray(rng.standard_normal((n, e)), dtype=jnp.float32)
+    w = jnp.asarray(rng.standard_normal((e, v)) * 0.1, dtype=jnp.float32)
+    t = jnp.asarray(rng.integers(0, v, n), dtype=jnp.int32)
+    held = w.T if vocab_major else w
+
+    def rows(x, held):
+        return fused_linear_cross_entropy_rows(x, held, t, block, vocab_major)
+
+    want, (dx_want, dw_want) = jax.value_and_grad(fused_linear_cross_entropy_padded, argnums=(0, 1))(x, w, t)
+    got, (dx, dw) = jax.jit(jax.value_and_grad(rows, argnums=(0, 1)))(x, held)
+    assert dw.shape == held.shape and dx.shape == x.shape
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_want), rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(dx_want))))
+    dw = dw.T if vocab_major else dw
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_want), rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(dw_want))))
+
+
+@pytest.mark.parametrize("n,v,block", [
+    (16_384, 131_584, 1024),   # the tied 131,136-row head, padded: 4.3 GB whole, 16 blocks of 0.27 GB
+    (8_192, 92_544, None),     # the widest head the benchmark had: 1.5 GB, whole
+    (16_384, 32_000, None),
+    (32_768, 131_584, 1024),
+    (16_384, 262_656, 512),    # the unsliced vocabulary: half the rows a block
+])
+def test_which_heads_run_over_blocks_of_rows(n, v, block) -> None:
+    from torchft_tpu.ops.cross_entropy import fused_ce_applicable, head_row_block
+
+    assert head_row_block(n, v) == block
+    if block:  # a block is a whole number of the kernels' row tiles at the widths the heads have
+        assert block * v * 2 <= 512 * 1024 * 1024 < 2 * block * v * 2
+        from torchft_tpu.ops.cross_entropy import _block_rows
+
+        assert _block_rows(block, 2048) is not None
+
+
 def test_rms_norm_matches_and_grads() -> None:
     from torchft_tpu.ops import rms_norm
 

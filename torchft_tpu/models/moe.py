@@ -19,6 +19,12 @@ outputs in one of two forms:
     normalised over the experts for P_e and divides by k (DeepSeek-V3's
     sequence-wise term).
 
+The router is one matrix, or a function with a state that the layer loop
+carries (``state_router_logits``); its last output may be a **choice that
+takes no expert** (``moe_layer(skip=True)``): the position gets no row on the
+dropless path, adds nothing, and is counted in ``skipped`` — never in
+``dropped``, which counts assignments to held experts that found no row.
+
 Then one of two ways to the experts, both with STATIC shapes:
 
   - **dropless, sorted** (``capacity_factor=None``; the experts' matrices
@@ -102,23 +108,56 @@ def moe_capacity(tokens: int, n_experts: int, top_k: int, capacity_factor: float
     return max(8, -(-cap // 8) * 8)
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool, *,
-          score: str = "softmax", bias: Optional[jax.Array] = None, scale: float = 1.0):
+def state_router_logits(x: jax.Array, router: Dict[str, jax.Array], state: Optional[jax.Array], rms_eps: float):
+    """The router as a function with a state (ZAYA1's, arXiv:2511.17127):
+    x [B, S, E], ``router`` its subtree of weights, ``state`` [B, S, R] the
+    layer before's (None: there is none) -> (logits [B, S, outputs], this
+    layer's state [B, S, R]), both float32:
+
+        r = x W_down + b_down + carry * state      (what the next layer receives, un-normed)
+        logits = W3 gelu(W2 gelu(W1 RMSNorm(r) + b1) + b2)
+
+    all of it float32 at the highest precision, as a matrix router's product
+    is: which expert a token takes hangs on differences far under bf16's
+    rounding, and here they pass through three more products."""
+    hp = jax.lax.Precision.HIGHEST
+    w = {name: leaf.astype(jnp.float32) for name, leaf in router.items()}
+    r = jnp.einsum("bse,er->bsr", x.astype(jnp.float32), w["down"], precision=hp) + w["down_bias"]
+    if state is not None:
+        r = r + w["carry"] * state
+    h = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + rms_eps) * w["norm"]
+    h = jax.nn.gelu(jnp.einsum("bsr,rq->bsq", h, w["w1"], precision=hp) + w["b1"], approximate=False)
+    h = jax.nn.gelu(jnp.einsum("bsr,rq->bsq", h, w["w2"], precision=hp) + w["b2"], approximate=False)
+    return jnp.einsum("bsr,rx->bsx", h, w["w3"], precision=hp), r
+
+
+def route(x: jax.Array, router: Optional[jax.Array], top_k: int, norm_topk: bool, *,
+          score: str = "softmax", bias: Optional[jax.Array] = None, scale: float = 1.0,
+          logits: Optional[jax.Array] = None):
     """x [B, S, E], router [E, n_exp] -> (logits, probs [B, S, n_exp] f32,
     gate_vals [B, S, k] f32, gate_idx [B, S, k]).  The logits are a float32
     product at the highest precision: which experts a token takes hangs on
-    differences far under bf16's rounding.  ``probs`` is what the balance
-    loss averages: the softmax, or the sigmoid scores normalised over the
-    experts.  ``bias`` [n_exp] (sigmoid only) moves the choice and never a
-    gate."""
-    logits = jnp.einsum(
-        "bse,ex->bsx", x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    )
+    differences far under bf16's rounding — or, where the router is a
+    function (``state_router_logits``), come in as ``logits``.  ``probs`` is
+    what the balance loss averages: the softmax, or the sigmoid scores
+    normalised over the experts.  ``bias`` [n_exp] moves the choice and never
+    a gate."""
+    if logits is None:
+        logits = jnp.einsum(
+            "bse,ex->bsx", x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
     if score == "softmax":
-        assert bias is None and scale == 1.0, "the softmax router has no bias and no scale"
+        assert scale == 1.0, "the softmax router has no scale"
         probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
+        if bias is not None:
+            # The k largest of probability + bias, the gates the probabilities
+            # themselves: picked by comparison as the sigmoid router's are.
+            _, gate_idx = jax.lax.top_k(probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+            chosen = gate_idx[..., None] == jnp.arange(probs.shape[-1], dtype=gate_idx.dtype)
+            gate_vals = jnp.sum(jnp.where(chosen, probs[..., None, :], 0.0), axis=-1)
+        else:
+            gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
         if norm_topk:
             # Renormalize the kept gates so the combine is a convex mixture.
             gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
@@ -254,9 +293,12 @@ def held_rows(n_assign: int, n_exp: int, count: int, factor: float, row_tile: in
     return -(-(share + count * row_tile) // row_tile) * row_tile
 
 
-def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first, rows_factor, mesh):
+def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first, rows_factor, mesh,
+                  skip: bool = False):
     """xf [T, E] in the compute type; gate_vals, gate_idx [T, k] over the
-    router's ``n_exp`` outputs; the matrices those of the held experts
+    router's ``n_exp`` experts — and, with ``skip``, the choice ``n_exp`` that
+    takes none: like an expert held elsewhere it gets no row, and the buffer
+    is sized as if no position took it; the matrices those of the held experts
     ``first ... first + count - 1``.  Returns (y [T, E], assignments that
     fell on held experts, those of them that found no row — none where
     every expert is held, by the buffer's size)."""
@@ -265,7 +307,7 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
     n_assign = tokens * k
     row_tile = ROW_TILE
     rows = held_rows(n_assign, n_exp, count, rows_factor, row_tile)
-    every_row_exists = count == n_exp  # every assignment is held and the buffer takes them all: `dest` is in bounds
+    every_row_exists = count == n_exp and not skip  # every assignment is held and the buffer takes them all: `dest` is in bounds
 
     expert = gate_idx.reshape(n_assign)
     held = jnp.arange(first, first + count, dtype=expert.dtype)
@@ -362,6 +404,9 @@ def moe_layer(
     score: str = "softmax",
     route_bias: Optional[jax.Array] = None,
     route_scale: float = 1.0,
+    router_state: Optional[jax.Array] = None,
+    skip: bool = False,
+    rms_eps: float = 1e-5,
     held_first: int = 0,
     held_rows_factor: float = HELD_ROWS_FACTOR,
     shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
@@ -374,7 +419,11 @@ def moe_layer(
     Args:
         x: [B, S, E] activations.
         router: [E, n_exp] routing weights (kept f32 — routing logits are
-            numerically sensitive), over ALL the layer's experts.
+            numerically sensitive), over ALL the layer's experts; or the
+            subtree of a router with a state (``state_router_logits``), whose
+            input state is ``router_state`` ([B, S, R] float32, ``rms_eps``
+            its norm's) and whose own comes back in ``stats["router_state"]``.
+        skip: the router's LAST output is the choice that takes no expert.
         w_gate/w_up: [held, E, F]; w_down: [held, F, E]: the stacked experts
             this device holds, ``held_first ... held_first + held - 1`` of the
             router's outputs.  ``held == n_exp`` is every expert; fewer
@@ -391,22 +440,31 @@ def moe_layer(
     Returns:
         (y [B, S, E], stats): ``balance`` and ``z`` (scalar f32 auxiliary
         losses), ``tokens_per_expert`` ([n_exp] int32, assignments each of
-        the router's outputs was sent), ``chosen`` ([B, S, k], the experts
-        each position took), ``assignments`` (int32, B * S * k),
+        the router's experts was sent), with ``skip`` also ``skipped`` (int32,
+        assignments to the choice that takes none), ``chosen`` ([B, S, k], the
+        outputs each position took), ``assignments`` (int32, B * S * k),
         ``rows_held`` (int32, those that fell on held experts) and
         ``dropped`` (int32, assignments to HELD experts that reached none: 0
         on the dropless path with every expert held, by construction).
     """
     rules = rules or ShardingRules()
     B, S, E = x.shape
-    n_exp = router.shape[1]
     held = w_gate.shape[0]
     T = B * S
     # The scopes are parts of obs/spans.PARTS: they name the work for a profile.
     with jax.named_scope("router"):
+        logits = new_state = None
+        if isinstance(router, dict):
+            logits, new_state = state_router_logits(x, router, router_state, rms_eps)
         logits, probs, gate_vals, gate_idx = route(
-            x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale)
+            x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale, logits=logits)
+        n_exp = logits.shape[-1] - int(skip)
         stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
+        if skip:
+            sent = stats["tokens_per_expert"]
+            stats.update(tokens_per_expert=sent[:n_exp], skipped=sent[n_exp])
+        if new_state is not None:
+            stats["router_state"] = new_state
         gate_vals, gate_idx = gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k)
     with jax.named_scope("experts"):
         xf = x.reshape(T, E)
@@ -420,11 +478,12 @@ def moe_layer(
             assert 0 <= held_first and held_first + held <= n_exp, (held_first, held, n_exp)
             y, rows_held, dropped = _dropless_ffn(
                 xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down,
-                n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh,
+                n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh, skip=skip,
             )
         else:
-            if held != n_exp:
-                raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis)")
+            if held != n_exp or skip:
+                raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis) "
+                                 "and has no choice that takes none")
             y, dropped = _capacity_ffn(
                 xf, gate_vals, gate_idx, w_gate, w_up, w_down,
                 capacity=moe_capacity(T, n_exp, top_k, capacity_factor), dtype=dtype, mesh=mesh, rules=rules,

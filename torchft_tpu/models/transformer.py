@@ -49,6 +49,14 @@ class LayerKind:
     # attention_factor) — `yarn_frequencies` in place of theta's powers, cos
     # and sin times the attention factor.
     yarn: Optional[Tuple[float, int, float, float, float]] = None
+    # "attention": q, k and v are products of the layer's normed input,
+    # position by position.  "cca": attention inside a compressed latent
+    # (compressed convolutional attention, arXiv:2510.04476; `_cca_qkv`) —
+    # between the projections and the attention call a causal convolution a
+    # channel, one a head over sequence and channels (both of kernel 2), the
+    # mean of the un-convolved q and k added back, half of the KV heads'
+    # values taken from the position before, and an L2 norm a head.
+    mixer: str = "attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,6 +171,22 @@ class TransformerConfig:
     # input: o_head * sigmoid(h W_g)_head, W_g: embed -> heads ("attn_gate";
     # arXiv:2505.06708's head-wise form).
     attn_head_gate: bool = False
+    # The router as a function with a state (ZAYA1's, arXiv:2511.17127;
+    # models/moe.py `state_router_logits`): moe_router_state > 0 is the width
+    # of a state the layer loop carries beside x — layer l's is its input's
+    # down-projection plus a learned vector times layer l - 1's — which an
+    # RMSNorm and a three-layer GELU MLP of that width turn into the scores;
+    # `router` is then a subtree, not a matrix.
+    moe_router_state: int = 0
+    # The router's last output is a choice that takes NO expert: the position
+    # gets no row and adds nothing (counted in `moe_skipped`, never dropped).
+    moe_skip: bool = False
+    # The residual merge with learned vectors, `(x + b_r) * a_r + (y + b_o) *
+    # a_o` in place of `x + y`, four vectors of d_model a sublayer
+    # ("attn_merge", "mlp_merge": rows a_r, b_r, a_o, b_o; 1 and 0 at the start).
+    scaled_merge: bool = False
+    # The head is the embedding itself: no `lm_head` leaf, logits = h embed^T.
+    tied_head: bool = False
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -195,8 +219,18 @@ class TransformerConfig:
             assert self.attention == "flash" and not self.mla_kv_rank and not self.dsa_index_heads, (
                 "a pattern's kinds are plain heads on the flash backend"
             )
+            assert all(kind.mixer in ("attention", "cca") for kind in self.pattern)
+            if any(kind.mixer == "cca" for kind in self.pattern):
+                assert not (self.qk_norm or self.qk_norm_per_head or self.attn_head_gate), (
+                    "compressed attention norms its own heads and has no gate"
+                )
+                assert self.n_kv_heads % 2 == 0 and all(k.n_heads % self.n_kv_heads == 0 for k in self.pattern)
             assert all(a == b for a in self.pattern for b in self.pattern if a.stack == b.stack), "one kind a stack"
             assert all(self.moe_experts > 0 for kind in self.pattern if kind.sparse)
+        if self.moe_router_state or self.moe_skip:
+            assert self.moe_experts > 0 and self.moe_capacity_factor is None, (
+                "a router with a state, or with a choice that takes no expert, routes on the dropless path"
+            )
 
     @property
     def layers(self) -> Tuple[LayerKind, ...]:
@@ -227,6 +261,11 @@ class TransformerConfig:
     @property
     def n_held_experts(self) -> int:
         return self.moe_held[1] if self.moe_held is not None else self.moe_experts
+
+    @property
+    def n_router_outputs(self) -> int:
+        """The router's choices: the experts, and the one that takes none."""
+        return self.moe_experts + int(self.moe_skip)
 
     @property
     def d_head(self) -> int:
@@ -263,10 +302,16 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
                       "wi_w": ("layers", "embed", None)})
     if cfg.attn_head_gate:
         layer["attn_gate"] = ("layers", "embed", "heads")
+    if kind.mixer == "cca":
+        layer.update({"cca_conv0": ("layers", None, None), "cca_bias0": ("layers", None),
+                      "cca_conv1": ("layers", None, None, None, None), "cca_bias1": ("layers", None, None),
+                      "cca_temp": ("layers", None)})
+    if cfg.scaled_merge:
+        layer.update({"attn_merge": ("layers", None, "embed"), "mlp_merge": ("layers", None, "embed")})
     if sparse:
         layer.update(
             {
-                "router": ("layers", "embed", "expert"),
+                "router": _state_router_axes() if cfg.moe_router_state else ("layers", "embed", "expert"),
                 "w_gate": ("layers", "expert", "embed", "mlp"),
                 "w_up": ("layers", "expert", "embed", "mlp"),
                 "w_down": ("layers", "expert", "mlp", "embed"),
@@ -278,10 +323,18 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
     return layer
 
 
+def _state_router_axes() -> Dict[str, Any]:
+    return {"down": ("layers", "embed", None), "down_bias": ("layers", None), "carry": ("layers", None),
+            "norm": ("layers", None), "w1": ("layers", None, None), "b1": ("layers", None),
+            "w2": ("layers", None, None), "b2": ("layers", None), "w3": ("layers", None, "expert")}
+
+
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical axis names for every parameter, keyed like init_params'
     tree — feed to FTMesh.shard_params to place the model on a mesh."""
-    axes = {"embed": ("vocab", "embed"), "final_norm": ("embed",), "lm_head": ("embed", "vocab")}
+    axes = {"embed": ("vocab", "embed"), "final_norm": ("embed",)}
+    if not cfg.tied_head:
+        axes["lm_head"] = ("embed", "vocab")
     for stack, (kind, _) in cfg.stacks.items():
         axes[stack] = _layer_axes(cfg, kind)
     return axes
@@ -339,12 +392,39 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
         )
     if cfg.attn_head_gate:
         layers["attn_gate"] = norm_init(jax.random.fold_in(key, 3), (L, E, H), E)
-    if sparse:
-        F, X, held = cfg.d_ff, cfg.moe_experts, cfg.n_held_experts
-        kr, kg, ku, kd = jax.random.split(ks[7], 4)
+    if kind.mixer == "cca":
+        C, Dh = H + KV, cfg.d_head  # the convolutions run over q's and k's heads side by side
+        k0, k1 = jax.random.split(jax.random.fold_in(key, 4))
         layers.update(
             {
-                "router": norm_init(kr, (L, E, X), E),
+                "cca_conv0": norm_init(k0, (L, 2, C * Dh), 2),         # [tap, channel]: tap 1 the position itself
+                "cca_bias0": jnp.zeros((L, C * Dh), pd),
+                "cca_conv1": norm_init(k1, (L, C, 2, Dh, Dh), 2 * Dh),  # [head, tap, channel in, channel out]
+                "cca_bias1": jnp.zeros((L, C, Dh), pd),
+                "cca_temp": jnp.ones((L, KV), pd),
+            }
+        )
+    if cfg.scaled_merge:
+        merge = jnp.broadcast_to(jnp.asarray([1.0, 0.0, 1.0, 0.0], pd)[None, :, None], (L, 4, E))
+        layers.update({"attn_merge": merge, "mlp_merge": jnp.array(merge)})  # two buffers: a step donates each leaf
+    if sparse:
+        F, X, held = cfg.d_ff, cfg.n_router_outputs, cfg.n_held_experts
+        kr, kg, ku, kd = jax.random.split(ks[7], 4)
+        if cfg.moe_router_state:
+            R = cfg.moe_router_state
+            k_down, k_1, k_2, k_3 = jax.random.split(kr, 4)
+            router = {
+                "down": norm_init(k_down, (L, E, R), E), "down_bias": jnp.zeros((L, R), pd),
+                "carry": jnp.ones((L, R), pd), "norm": jnp.ones((L, R), pd),
+                "w1": norm_init(k_1, (L, R, R), R), "b1": jnp.zeros((L, R), pd),
+                "w2": norm_init(k_2, (L, R, R), R), "b2": jnp.zeros((L, R), pd),
+                "w3": norm_init(k_3, (L, R, X), R),
+            }
+        else:
+            router = norm_init(kr, (L, E, X), E)
+        layers.update(
+            {
+                "router": router,
                 "w_gate": norm_init(kg, (L, held, E, F), E),
                 "w_up": norm_init(ku, (L, held, E, F), E),
                 "w_down": norm_init(kd, (L, held, F, E), F),
@@ -378,8 +458,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     params = {
         "embed": _norm_init(k_embed, (cfg.vocab_size, E), E, pd),
         "final_norm": jnp.ones((E,), pd),
-        "lm_head": _norm_init(k_head, (E, cfg.vocab_size), E, pd),
     }
+    if not cfg.tied_head:
+        params["lm_head"] = _norm_init(k_head, (E, cfg.vocab_size), E, pd)
     # The last kind's stack draws from `k_layers` itself, each kind before it
     # from a key folded out of it (a model of one kind with leading dense
     # layers: "layers", then "dense_layers").
@@ -439,8 +520,10 @@ def _turn_whole_bwd(half: int, rot: int, tables, g: jax.Array):
 _turn_whole.defvjp(lambda x, cos, sin, half, rot: (_turn_whole(x, cos, sin, half, rot), (cos, sin)), _turn_whole_bwd)
 
 
-def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float, rot: int) -> jax.Array:
-    """The leading ``rot`` columns of x [B, S, H, D] turned by positions
+def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float, rot: int,
+          head_major: bool = False) -> jax.Array:
+    """The leading ``rot`` columns of x [B, S, H, D] ([B, H, S, D] where
+    ``head_major``) turned by positions
     [B, S] x inv_freq [rot / 2] in half-split pairs (i, i + rot / 2), cos and
     sin times ``factor``; the other columns pass through.  Float32, two
     products and one sum an element, under tables as wide as the head, so
@@ -452,6 +535,8 @@ def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float
     angles = positions[..., None].astype(jnp.float32) * jnp.take(inv_freq, lane % half)  # [B, S, D]
     cos = jnp.where(lane < rot, jnp.cos(angles) * np.float32(factor), 1.0)
     sin = jnp.where(lane < rot, jnp.sin(angles) * np.where(lane < half, -factor, factor).astype(np.float32), 0.0)
+    if head_major:
+        return _turn_whole(x, cos[:, None], sin[:, None], half, rot)
     return _turn_whole(x, cos[:, :, None, :], sin[:, :, None, :], half, rot)
 
 
@@ -484,12 +569,12 @@ def yarn_frequencies(theta: float, rot_dim: int, factor: float, original: int,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def _rotary(x: jax.Array, positions: jax.Array, kind: LayerKind) -> jax.Array:
+def _rotary(x: jax.Array, positions: jax.Array, kind: LayerKind, head_major: bool = False) -> jax.Array:
     """RoPE as the layer's kind has it: over the leading ``rotary_fraction``
     of a head's columns (half-split pairs inside that part, the rest passes
     through), at theta's powers or YaRN's frequencies — a constant of the
     program — with cos and sin times YaRN's attention factor."""
-    if kind.rotary_fraction == 1.0 and kind.yarn is None:
+    if kind.rotary_fraction == 1.0 and kind.yarn is None and not head_major:
         return _rope(x, positions, kind.rope_theta)
     import numpy as np
 
@@ -499,7 +584,10 @@ def _rotary(x: jax.Array, positions: jax.Array, kind: LayerKind) -> jax.Array:
         inv_freq, factor = kind.rope_theta ** (-np.arange(half, dtype=np.float64) / half), 1.0
     else:
         inv_freq, factor = yarn_frequencies(kind.rope_theta, rot, *kind.yarn[:4]), kind.yarn[4]
-    return _turn(x, positions, jnp.asarray(inv_freq, jnp.float32), factor, rot)
+    inv_freq = jnp.asarray(inv_freq, jnp.float32)
+    if head_major:
+        return _turn(x, positions, inv_freq, factor, rot, head_major=True)
+    return _turn(x, positions, inv_freq, factor, rot)
 
 
 def _attention(cfg: TransformerConfig, mesh, q, k, v, kind: LayerKind):
@@ -611,11 +699,79 @@ def _layer_norm(x, w, b, eps):
     return ((xf - mean) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
+def _before(x: jax.Array) -> jax.Array:
+    """x [B, H, S, D] one position on: ``out[t] = x[t - 1]``, zeros before the
+    first (`lax.pad` with a negative edge; its transpose is the same move the
+    other way)."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), [(0, 0, 0), (0, 0, 0), (1, -1, 0), (0, 0, 0)])
+
+
+def _cca_qkv(cfg: TransformerConfig, kind: LayerKind, h, w, positions):
+    """Compressed convolutional attention's q [B, H, S, D] and k, v
+    [B, G, S, D], head-major, from the normed input h [B, S, E]
+    (arXiv:2510.04476; LayerKind.mixer).  The projections are `attn_proj`'s;
+    what lies between them and RoPE — `cca_mix` — mixes positions and
+    channels, all of it linear but the norm, so its backward pass is the
+    mirrored shifts and the transposed products:
+
+        z = [q~ ; k~], the H + G projected heads side by side
+        z0_t = a1 * z_t + a0 * z_{t-1} + b0                (a weight a channel and tap)
+        z1_{t,h} = z0_{t,h} A_{h,1} + z0_{t-1,h} A_{h,0} + b1_h    (a [D, D] matrix a head and tap)
+        mu_j = (q~_j + k~_{g(j)}) / 2;  q_j = z1_{q,j} + mu_j;  k_g = z1_{k,g} + mean_{j in g} mu_j
+        q^ = sqrt(D) q / |q|;  k^ = tau_g sqrt(D) k / |k|   (float32)
+        v = the first half of the KV heads' values as projected, the second half's from the position before
+
+    Elementwise work is float32 inside its fusion and lands in the compute
+    type; the convolution a head is one batched product over both taps."""
+    B, S, _ = h.shape
+    H, G, D, dt = kind.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.dtype
+    f32 = jnp.float32
+
+    def heads(y, n):  # [B, S, n * D] -> [B, n, S, D]
+        return y.reshape(B, S, n, D).transpose(0, 2, 1, 3)
+
+    q0, k0 = heads(h @ w["wq"].astype(dt), H), heads(h @ w["wk"].astype(dt), G)
+    v = heads(h @ w["wv"].astype(dt), G)
+    with jax.named_scope("cca_mix"):
+        v = jnp.concatenate([v[:, : G // 2], _before(v[:, G // 2:])], axis=1)
+        z = jnp.concatenate([q0, k0], axis=1)                                  # [B, H + G, S, D]
+        taps = w["cca_conv0"].astype(f32).reshape(2, H + G, 1, D)
+        bias0 = w["cca_bias0"].astype(f32).reshape(H + G, 1, D)
+        z0 = (taps[1] * z.astype(f32) + taps[0] * _before(z).astype(f32) + bias0).astype(dt)
+        # both taps in one product a head: [z0_{t-1} ; z0_t] [S, 2D] times [A_0 ; A_1] [2D, D]
+        mats = w["cca_conv1"].astype(dt).reshape(H + G, 2 * D, D)
+        z1 = jnp.einsum("bhsc,hcd->bhsd", jnp.concatenate([_before(z0), z0], axis=-1), mats)
+        z1 = z1.astype(f32) + w["cca_bias1"].astype(f32)[:, None, :]
+        mu = 0.5 * (q0.astype(f32).reshape(B, G, H // G, S, D) + k0.astype(f32)[:, :, None])
+        q = z1[:, :H] + mu.reshape(B, H, S, D)
+        k = z1[:, H:] + jnp.mean(mu, axis=2)
+        q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True)) * D ** 0.5)
+        k = k * (jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True)) * D ** 0.5
+                 * w["cca_temp"].astype(f32)[:, None, None])
+    q, k = (_rotary(a, positions, kind, head_major=True).astype(dt) for a in (q, k))
+    return q, k, v
+
+
+def _merge(x, y, vectors=None):
+    """The residual merge: ``x + y``, or with the sublayer's four learned
+    vectors [4, E] ``(x + b_r) * a_r + (y + b_o) * a_o`` (float32 inside the
+    fusion, the stream's type out)."""
+    if vectors is None:
+        return x + y
+    a_r, b_r, a_o, b_o = vectors.astype(jnp.float32)
+    return ((x.astype(jnp.float32) + b_r) * a_r + (y.astype(jnp.float32) + b_o) * a_o).astype(x.dtype)
+
+
 def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, kind=None, router_bias=None):
-    """One decoder block; x: [B, S, E].  `kind`: the layer's (default: the
-    model's last layer's, the one kind of a model without a pattern);
-    `router_bias` [n_exp]: the sigmoid router's choice bias for this layer,
-    or None."""
+    """One decoder block; x: [B, S, E] — or, where the router carries a state
+    (`cfg.moe_router_state`), the pair (x, r) with r [B, S, state] float32
+    the layer before's, and the same pair comes back.  `kind`: the layer's
+    (default: the model's last layer's, the one kind of a model without a
+    pattern); `router_bias` [router outputs]: the router's choice bias for
+    this layer, or None."""
+    router_state = None
+    if cfg.moe_router_state:
+        x, router_state = x
     B, S, E = x.shape
     kind = cfg.layers[-1] if kind is None else kind
     H, KV, sparse = kind.n_heads, cfg.n_kv_heads, kind.sparse
@@ -625,7 +781,9 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     with jax.named_scope("norm"):
         h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
     with jax.named_scope("attn_proj"):
-        if cfg.mla_kv_rank:
+        if kind.mixer == "cca":
+            q, k, v = _cca_qkv(cfg, kind, h, w, positions)
+        elif cfg.mla_kv_rank:
             q, k, v = _mla_qkv(cfg, h, w, positions)
             KV = H
         else:
@@ -648,9 +806,11 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
             k = _rotary(k, positions, kind)
         if cfg.attn_head_gate:
             head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
-        q = constrain(q.transpose(0, 2, 1, 3), ("batch", "heads", "seq", None), mesh, rules)
-        k = constrain(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
-        v = constrain(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "seq", None), mesh, rules)
+        # compressed attention's heads are head-major already
+        major = (lambda a: a) if kind.mixer == "cca" else (lambda a: a.transpose(0, 2, 1, 3))
+        q = constrain(major(q), ("batch", "heads", "seq", None), mesh, rules)
+        k = constrain(major(k), ("batch", "kv_heads", "seq", None), mesh, rules)
+        v = constrain(major(v), ("batch", "kv_heads", "seq", None), mesh, rules)
     dsa = None
     if cfg.dsa_index_heads:
         attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
@@ -662,7 +822,7 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         if cfg.attn_head_gate:
             attn = attn * head_gate[..., None]
         attn = attn.reshape(B, S, H * attn.shape[-1])
-        x = x + (attn @ w["wo"].astype(cfg.dtype))
+        x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
     with jax.named_scope("norm"):
@@ -682,23 +842,29 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
             score=cfg.moe_score,
             route_bias=router_bias,
             route_scale=cfg.moe_route_scale,
+            router_state=router_state,
+            skip=cfg.moe_skip,
+            rms_eps=cfg.rms_eps,
             held_first=cfg.moe_held[0] if cfg.moe_held is not None else 0,
             shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
             dtype=cfg.dtype,
             mesh=mesh,
             rules=rules,
         )
+        if cfg.moe_router_state:
+            router_state = aux.pop("router_state")
         with jax.named_scope("experts"):
-            x = x + y
+            x = _merge(x, y, w.get("mlp_merge"))
     else:
         with jax.named_scope("ffn"):
             gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
             up = h @ w["w_up"].astype(cfg.dtype)
-            x = x + ((gate * up) @ w["w_down"].astype(cfg.dtype))
+            x = _merge(x, (gate * up) @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
         aux = {} if dsa is not None else jnp.zeros((), jnp.float32)
     if dsa is not None:
         aux = dict(aux, **dsa)
-    return constrain(x, ("batch", "seq", "embed"), mesh, rules), aux
+    x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
+    return ((x, router_state) if cfg.moe_router_state else x), aux
 
 
 def _decoder(
@@ -745,6 +911,9 @@ def _decoder(
         x = params["embed"].astype(cfg.dtype)[tokens]
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
+    if cfg.moe_router_state:
+        # the second stream: the router's state, zeros before the first layer
+        x = (x, jnp.zeros((B, S, cfg.moe_router_state), jnp.float32))
     stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
     aux_total = jnp.zeros((), jnp.float32)
     pieces, pending = [], []  # the layers' statistics: stacked runs, and layers still to be stacked
@@ -799,6 +968,8 @@ def _decoder(
             pieces.append(aux_layers)
         elif not stats:
             aux_total = aux_total + jnp.sum(aux_layers)
+    if cfg.moe_router_state:
+        x, _ = x  # the last layer's state goes nowhere
     if not stats:
         return x, aux_total
     with jax.named_scope("stack"):
@@ -857,9 +1028,12 @@ def head(
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
         # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
-        logits = jnp.matmul(
-            x, params["lm_head"].astype(cfg.dtype), preferred_element_type=jnp.float32
-        )
+        if cfg.tied_head:
+            logits = jnp.einsum("bse,ve->bsv", x, params["embed"].astype(cfg.dtype), preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.matmul(
+                x, params["lm_head"].astype(cfg.dtype), preferred_element_type=jnp.float32
+            )
         return constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
 
 
@@ -904,13 +1078,22 @@ def lm_head_loss(
         fused_ce_applicable,
         fused_linear_cross_entropy,
         fused_linear_cross_entropy_padded,
+        fused_linear_cross_entropy_rows,
+        head_row_block,
         padded_vocab,
     )
 
     B, S, E = x.shape
-    if fused_ce_applicable(B * S, E, padded_vocab(cfg.vocab_size), mesh):
+    block = head_row_block(B * S, padded_vocab(cfg.vocab_size))
+    if fused_ce_applicable(block or B * S, E, padded_vocab(cfg.vocab_size), mesh):
         with jax.named_scope("head_loss"):
             h = rms_norm(x, params["final_norm"], cfg.rms_eps)
+            if cfg.tied_head or block:
+                # The head's weight as the tree holds it, [V, E] where it is the embedding:
+                # cast, padded and laid out for the kernels inside, its gradient float32.
+                w = params["embed"] if cfg.tied_head else params["lm_head"]
+                return fused_linear_cross_entropy_rows(
+                    h.reshape(B * S, E), w, targets.reshape(B * S), block or B * S, cfg.tied_head)
             w = params["lm_head"].astype(cfg.dtype)
             # A vocabulary slice that no block divides runs the same kernels over
             # zero-padded columns whose logits count as -inf.
@@ -972,6 +1155,8 @@ def loss_and_counters(
         if cfg.moe_z_coef:
             loss = loss + cfg.moe_z_coef * aux["z"]
         counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
+        if cfg.moe_skip:
+            counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:
             counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
         return loss, counters

@@ -158,7 +158,11 @@ SUBSPANS = {
 #   embed — the token gather (its backward is the scatter-add);
 #   norm — every RMS / layer norm but the final one; attn_proj — the q/k/v/o
 #     products, latent attention's low-rank path, RoPE, the reshapes and
-#     transposes around the heads; attn — the attention call: kernels and
+#     transposes around the heads; cca_mix — what compressed attention puts
+#     between its projections and RoPE: the value shift, both causal
+#     convolutions, the q-k mean and the norm a head (the first work of a
+#     layer outside the attention call that mixes positions);
+#     attn — the attention call: kernels and
 #     whatever XLA puts around them; attn_window — the same call in a layer
 #     that attends under a window (the `tpuft_swa_*` kernels), so that a
 #     model of both kinds reads them apart; dsa_index — the indexer's operands and
@@ -171,7 +175,7 @@ SUBSPANS = {
 #     backward pass: the per-layer gradients padded and summed into the
 #     stacked gradient) and the stacking of the layers' statistics.
 PARTS = (
-    "embed", "norm", "attn_proj", "attn", "attn_window", "dsa_index", "dsa_select", "ffn",
+    "embed", "norm", "attn_proj", "cca_mix", "attn", "attn_window", "dsa_index", "dsa_select", "ffn",
     "router", "experts", "shared_expert", "head_loss", "stack",
 )
 

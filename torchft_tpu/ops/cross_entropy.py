@@ -49,6 +49,19 @@ _LANE = 128
 # logits tile [block_rows, block_v] is the largest single allocation).
 _X_BLOCK_BYTES = 2 * 1024 * 1024
 _W_BLOCK_BYTES = 3 * 1024 * 1024
+# The most the backward's bf16 dlogits [rows, V] may take whole: a head whose
+# [N, V] would pass it runs over blocks of rows (`head_row_block`).  2 GiB is
+# above every head the kernels were written for (8,192 x 92,544: 1.5 GB) and
+# an eighth of a v5e's memory; at 16,384 x 131,584 the whole is 4.3 GB beside
+# 11 GB of weights, gradient and moments.  A block's dlogits take at most
+# `_DLOGITS_BLOCK_BYTES`: at 512 MiB that head runs in 16 blocks of 1,024 rows
+# (0.27 GB each) and its gradient program compiles to 3.94 GB of temporaries,
+# where blocks of 2,048 rows take 4.74 and of 4,096 rows 5.85 GB, over the chip
+# (compiled for a described v5e, PR 41).  The kernels read the weight once a
+# tile of `_X_BLOCK_BYTES` rows whatever the block, so smaller blocks re-read
+# nothing; below a thousand rows the loop's own steps would begin to show.
+_DLOGITS_BYTES = 2 * 1024 * 1024 * 1024
+_DLOGITS_BLOCK_BYTES = 512 * 1024 * 1024
 
 
 def _block_v(v: int, e: int) -> Optional[int]:
@@ -247,21 +260,24 @@ def _ce_vjp_fwd(x, w, targets):
     return jnp.mean(lse - tl), (x, w, targets, lse)
 
 
-def _ce_vjp_bwd(res, g, valid_v: Optional[int] = None):
-    x, w, targets, lse = res
-    n = x.shape[0]
-    scale = g / n
+def _ce_dlogits(x, w, targets, lse, scale, valid_v: Optional[int] = None):
+    """(softmax(x @ w) - onehot(targets)) * scale in x's dtype, [N, V]."""
     if _pallas_util.on_tpu():
         # dlogits tile-by-tile in bf16 (pallas) — the f32 logits never
         # exist in HBM.
-        dl = _ce_dlogits_pallas(x, w, targets, lse, scale, valid_v=valid_v)
-    else:
-        logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
-        if valid_v is not None:
-            logits = jnp.where(jnp.arange(w.shape[1]) < valid_v, logits, -1e30)
-        p = jnp.exp(logits - lse[:, None])
-        p = p - jax.nn.one_hot(targets, w.shape[1], dtype=jnp.float32)
-        dl = (p * scale).astype(x.dtype)
+        return _ce_dlogits_pallas(x, w, targets, lse, scale, valid_v=valid_v)
+    logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    if valid_v is not None:
+        logits = jnp.where(jnp.arange(w.shape[1]) < valid_v, logits, -1e30)
+    p = jnp.exp(logits - lse[:, None])
+    p = p - jax.nn.one_hot(targets, w.shape[1], dtype=jnp.float32)
+    return (p * scale).astype(x.dtype)
+
+
+def _ce_vjp_bwd(res, g, valid_v: Optional[int] = None):
+    x, w, targets, lse = res
+    n = x.shape[0]
+    dl = _ce_dlogits(x, w, targets, lse, g / n, valid_v)
     # Two plain XLA matmuls — XLA runs these bf16 matmuls near MXU peak,
     # which hand-written scratch-accumulation kernels measured 2x worse at.
     dx = jax.lax.dot_general(
@@ -314,3 +330,91 @@ def fused_linear_cross_entropy_padded(x, w, targets):
     ``fused_ce_applicable(n, e, padded_vocab(v))``."""
     v = w.shape[1]
     return _fused_ce_valid(x, jnp.pad(w, ((0, 0), (0, padded_vocab(v) - v))), targets, v)
+
+
+# -- a head over blocks of rows ---------------------------------------------------
+
+
+def head_row_block(n: int, v: int) -> Optional[int]:
+    """Rows a block where the head of N rows and V (padded) columns runs
+    block by block — the largest power of two whose bf16 dlogits stay within
+    `_DLOGITS_BLOCK_BYTES` — and None where the one [N, V] stays within
+    `_DLOGITS_BYTES`."""
+    if n * v * 2 <= _DLOGITS_BYTES:
+        return None
+    return 1 << ((_DLOGITS_BLOCK_BYTES // (2 * v)).bit_length() - 1)
+
+
+def _kernel_weight(w, dtype, vocab_major: bool):
+    """w as the kernels read it — ``dtype``, [E, padded V] — and its real V."""
+    wk = w.astype(dtype).T if vocab_major else w.astype(dtype)
+    v = wk.shape[1]
+    return jnp.pad(wk, ((0, 0), (0, padded_vocab(v) - v))), v
+
+
+def _row_blocks(a, block: int):
+    """a [N, ...] -> [blocks, block, ...], zero rows after the last: they add
+    nothing to dw (x is zero there), and their loss and dx are cut off."""
+    rows = -(-a.shape[0] // block) * block
+    a = jnp.pad(a, ((0, rows - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
+    return a.reshape(rows // block, block, *a.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_linear_cross_entropy_rows(x, w, targets, block: int, vocab_major: bool = False):
+    """`fused_linear_cross_entropy` over blocks of ``block`` rows, for a head
+    whose bf16 dlogits [N, V] are too large to exist whole: a block's
+    log-sum-exp at a time in the forward pass; in the backward pass a loop
+    over the blocks — the block's dlogits from the kernel, its dx, and its
+    part of dw added in float32 into the one buffer that is w's gradient — so
+    that one block's dlogits exist at a time.  N need be no whole number of
+    blocks.
+
+    ``w`` is the weight as the parameter tree holds it, in the parameters'
+    dtype: [E, V], or with ``vocab_major`` [V, E] — a tied head, the
+    embedding itself, whose transposed copy is made in x's dtype and never in
+    float32, and whose gradient is accumulated [V, E].  V may be any width
+    (`padded_vocab`).  Callers gate on ``fused_ce_applicable(block, e,
+    padded_vocab(v))``."""
+    return _ce_rows_fwd(x, w, targets, block, vocab_major)[0]
+
+
+def _ce_rows_fwd(x, w, targets, block: int, vocab_major: bool):
+    n = x.shape[0]
+    wk, v = _kernel_weight(w, x.dtype, vocab_major)
+    valid_v = None if wk.shape[1] == v else v
+    xs, ts = _row_blocks(x, block), _row_blocks(targets, block)
+    if _pallas_util.on_tpu():
+        lse = jax.lax.map(lambda xb: _ce_lse_pallas(xb, wk, valid_v=valid_v), xs)
+        # the target's logit from the rows of w, outside the loop: no [V, E] copy of a [E, V] head a block
+        rows = w.astype(x.dtype)[targets] if vocab_major else jnp.transpose(wk)[targets]
+        tl = jnp.einsum("ne,ne->n", x, rows, preferred_element_type=jnp.float32)
+        losses = lse.reshape(-1)[:n] - tl
+    else:
+        lse, tl = jax.lax.map(lambda a: _ce_fwd(a[0], wk, a[1], valid_v=valid_v), (xs, ts))
+        losses = (lse - tl).reshape(-1)[:n]
+    return jnp.sum(losses) / n, (x, w, wk, targets, lse)
+
+
+def _ce_rows_bwd(block: int, vocab_major: bool, res, g):
+    x, w, wk, targets, lse = res
+    n = x.shape[0]
+    v = w.shape[0] if vocab_major else w.shape[1]
+    valid_v = None if wk.shape[1] == v else v
+    scale = g / n
+
+    def one(dw, a):
+        xb, tb, lb = a
+        dl = _ce_dlogits(xb, wk, tb, lb, scale, valid_v)
+        dxb = jax.lax.dot_general(dl, wk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        lhs, rhs = (dl, xb) if vocab_major else (xb, dl)
+        dwb = jax.lax.dot_general(lhs, rhs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return dw + dwb, dxb.astype(x.dtype)
+
+    dw = jnp.zeros(wk.shape[::-1] if vocab_major else wk.shape, jnp.float32)
+    dw, dx = jax.lax.scan(one, dw, (_row_blocks(x, block), _row_blocks(targets, block), lse))
+    dw = dw[:v] if vocab_major else dw[:, :v]
+    return dx.reshape(-1, x.shape[1])[:n], dw.astype(w.dtype), np.zeros(targets.shape, jax.dtypes.float0)
+
+
+fused_linear_cross_entropy_rows.defvjp(_ce_rows_fwd, _ce_rows_bwd)

@@ -204,6 +204,7 @@ def pipeline_loss_fn(
     from torchft_tpu.models.transformer import lm_head_loss
 
     assert cfg.moe_experts == 0, "pipeline_loss_fn supports dense configs only"
+    assert not cfg.tied_head, "the pipelined loss keeps embedding and head on different stages: an untied head only"
     tokens = batch["tokens"]
     B, S = tokens.shape
 
@@ -464,6 +465,7 @@ def pipeline_1f1b_value_and_grad(
     from torchft_tpu.ops._shard_map import shard_map
 
     assert cfg.moe_experts == 0, "1F1B pipeline supports dense configs only"
+    assert not cfg.tied_head, "the 1F1B schedule reads other_params['lm_head']: an untied head only"
     if batch_axis is not None and (
         batch_axis not in mesh.axis_names or mesh.shape[batch_axis] == 1
     ):
