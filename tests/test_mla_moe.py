@@ -33,7 +33,7 @@ if ROOT not in sys.path:
 
 from benchmark.spec import Benchmark  # noqa: E402
 from torchft_tpu.models import init_params  # noqa: E402
-from torchft_tpu.models.moe import _dropless_ffn, _take_rows, held_rows, moe_layer, route  # noqa: E402
+from torchft_tpu.models.moe import _dropless_ffn, _take_rows, _tokens_of_rows, held_rows, moe_layer, route  # noqa: E402
 from torchft_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, padded_group_sizes  # noqa: E402
 from torchft_tpu.models.transformer import loss_and_counters, param_axes  # noqa: E402
 from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
@@ -491,6 +491,83 @@ def test_k_major_row_moves_match_the_t_major_form(k, held) -> None:
     for name, a, b in zip(("xf", "gate_vals", "w_gate", "w_up", "w_down"), got_grads, want_grads):
         assert float(jnp.max(jnp.abs(b))) > 0.1, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+def _row_table(gate_idx, first, count, n_rows, row_tile):
+    """The assignment -> row table written out on the host: the assignments
+    to held expert `first`, `first + 1`, ... in turn, each expert's rows from
+    a tile boundary on; an assignment to any other choice, or past the
+    buffer's end, has no row and a `dest` of its own past the end, and a row
+    none landed in carries `T * k`."""
+    n_assign = gate_idx.size
+    expert, assignment = np.asarray(gate_idx).reshape(n_assign), np.arange(n_assign)
+    dest, start = np.full(n_assign, -1), 0
+    for e in range(first, first + count):
+        mine = np.flatnonzero(expert == e)
+        dest[mine] = start + np.arange(mine.size)
+        start += -(-mine.size // row_tile) * row_tile
+    has_row = (dest >= 0) & (dest < n_rows)
+    dest = np.where(has_row, dest, n_rows + assignment)
+    row_assignment = np.full(n_rows, n_assign)
+    row_assignment[dest[has_row]] = assignment[has_row]
+    return dest.reshape(gate_idx.shape).astype(np.int32), row_assignment.astype(np.int32), has_row.reshape(gate_idx.shape)
+
+
+# (choices a token, the router's outputs, first held, held, buffer factor, the last output takes no expert)
+COMBINES = {
+    "top6_of_64_with_8_held_k_major": (6, 64, 0, 8, 2.0, False),
+    "top8_of_128_with_16_held": (8, 128, 16, 16, 2.0, False),
+    "top8_of_16_all_held": (8, 16, 0, 16, 2.0, False),
+    "top1_of_17_with_8_held_and_the_choice_that_takes_none": (1, 17, 0, 8, 2.0, True),
+    "top6_of_64_with_8_held_and_a_buffer_too_small": (6, 64, 8, 8, 0.25, False),
+}
+
+
+@pytest.mark.parametrize("case", list(COMBINES))
+def test_the_combines_gradients_against_a_plain_statement_of_it(case) -> None:
+    """`_tokens_of_rows`' own derivatives — the gates' taken on the row side
+    and scattered through `row_assignment`, the rows' a gather of the
+    cotangent — against JAX's of `y[t] = sum_j gates[t, j] * rows[dest[t, j]]`
+    in float32 (zero where `dest` is past the buffer), on bf16 rows and
+    cotangents as the program has them: a gate's gradient to float32
+    rounding, exactly zero where its assignment has no row; the rows'
+    cotangent bit for bit the float32 product rounded once."""
+    k, outputs, first, count, factor, skip = COMBINES[case]
+    tokens, width, row_tile = 256, 64, 8
+    n_exp, n_assign = outputs - int(skip), tokens * k
+    every_row_exists = count == n_exp and not skip
+    n_rows = held_rows(n_assign, n_exp, count, factor, row_tile)
+    ks = jax.random.split(jax.random.PRNGKey(45 + k + outputs), 4)
+    gates, gate_idx = jax.lax.top_k(jax.nn.sigmoid(jax.random.normal(ks[0], (tokens, outputs), jnp.float32)), k)
+    dest, row_assignment, has_row = _row_table(gate_idx, first, count, n_rows, row_tile)
+    assert np.any(row_assignment == n_assign) and bool(has_row.all()) == every_row_exists  # some rows stay empty
+    if "too_small" in case:  # assignments to held experts that found the buffer full
+        held = (np.asarray(gate_idx) >= first) & (np.asarray(gate_idx) < first + count)
+        assert 0 < int(np.sum(held & ~has_row)) < int(np.sum(held))
+    rows = jax.random.normal(ks[1], (n_rows, width), jnp.float32).astype(jnp.bfloat16)
+    dy = jax.random.normal(ks[2], (tokens, width), jnp.float32).astype(jnp.bfloat16)
+    dest, row_assignment = jnp.asarray(dest), jnp.asarray(row_assignment)
+
+    def plain(rows32, gates):
+        picked = jnp.take(rows32, dest, axis=0, mode="fill", fill_value=0)  # [T, k, E]
+        return jnp.sum(picked * gates[:, :, None], axis=1)
+
+    want, want_vjp = jax.vjp(plain, rows.astype(jnp.float32), gates)
+    want_drows, want_dgates = want_vjp(dy.astype(jnp.float32))
+    got, got_vjp = jax.vjp(lambda r, g: _tokens_of_rows(r, g, dest, row_assignment, every_row_exists), rows, gates)
+    got_drows, got_dgates = got_vjp(dy)
+
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=2 ** -7, atol=2 ** -7)
+    assert got_dgates.shape == (tokens, k) and got_dgates.dtype == jnp.float32
+    scale = float(jnp.max(jnp.abs(want_dgates)))
+    assert scale > 1.0
+    np.testing.assert_allclose(np.asarray(got_dgates), np.asarray(want_dgates), rtol=1e-5, atol=1e-5 * scale)
+    assert np.array_equal(np.asarray(got_dgates) != 0, has_row)  # the same positions, exact zeros elsewhere
+    # A row's cotangent: its token's, weighted by its gate in float32 and rounded once (zero where none landed).
+    row_gate = jnp.take(gates.reshape(-1), row_assignment, mode="fill", fill_value=0)
+    rounded_once = (jnp.take(dy, row_assignment // k, axis=0, mode="clip").astype(jnp.float32) * row_gate[:, None]).astype(rows.dtype)
+    assert got_drows.dtype == rows.dtype and np.array_equal(np.asarray(got_drows, np.float32), np.asarray(rounded_once, np.float32))
+    np.testing.assert_array_equal(np.asarray(want_drows.astype(jnp.bfloat16), np.float32), np.asarray(got_drows, np.float32))
 
 
 # -- the two-kind tree through the exchange's plan, the checkpoint and TrainStep ---

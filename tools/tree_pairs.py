@@ -7,7 +7,8 @@ a parent and a change are read on the same chip; one JSON line a run.
         --trees P=parent_tree C=. --runs P:11 C:11 C:12 P:12 C:13:t --label pairs_laguna
 
 A run is `<tree>:<seed>[:t]` (`t`: `--trace 1`, and the tree's
-`benchmark/tools/parts.py` table of the run kept beside its record); each is
+`benchmark/tools/parts.py` table of the run kept beside its record, and its
+`g0.device_parts.json` in `<nn>_<tree>.run/`, which `parts.py` reads again); each is
 `BENCHMARK.json`'s command as the driver gives it, run from its tree's root
 under that tree's own benchmark files.  A line holds the run's metrics, the
 step's median, set-up phase by phase (seconds each phase TOOK, group 0's:
@@ -25,6 +26,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -83,6 +85,10 @@ def main() -> int:
                                    stderr=subprocess.STDOUT, text=True)
             with open(stem + ".parts.txt", "w", encoding="utf-8") as f:
                 f.write(parts.stdout)
+            every_instruction = os.path.join(run_dir, "g0.device_parts.json")  # for `parts.py <dir>` after the call
+            if os.path.exists(every_instruction):
+                os.makedirs(stem + ".run", exist_ok=True)
+                shutil.copy(every_instruction, stem + ".run")
         with open(stem + ".json", "w", encoding="utf-8") as f:
             json.dump(record, f)
         reference = next(iter(record.get("checks", {}).values()), {})

@@ -45,7 +45,22 @@ Then one of two ways to the experts, both with STATIC shapes:
     even share of them, and an assignment to a held expert that finds it
     full is counted in ``dropped``.  Both directions of both moves are
     gathers (a token has at most k rows and a row one token), so the
-    backward pass has no scatter-add.  Where k is no whole number of the
+    backward pass has no scatter-add.  A layer gathers a token's T * k rows
+    twice and no more: in the combine (``_tokens_of_rows``) and in the
+    dispatch's transpose (``_rows_bwd``, a combine of the same kind).  The
+    combine's own backward gathers none: a gate's gradient is the product
+    of its row with its token's cotangent, and both are already on the ROW
+    side there — the expert's output, and the cotangent's rows gathered for
+    the rows' own gradient — so it is a pass over two [R, E] arrays and a
+    scatter of R float32 scalars through ``row_assignment`` (distinct
+    indices; 0.08-0.34 ms on a v5e for 17,408-73,728 rows) where the
+    token-side form gathered all T * k rows a third time (4.8 ms over
+    131,072 rows of 2,048; PERF.md, PR 45).  A chip of an expert-parallel
+    layer has the same choice: the expert's side returns T * k scalars or
+    the token's side needs the rows again.  Read back by gather
+    (``rowdot[dest]``) the scalars would cost more than they save: 65,536
+    gathered scalars took this chip 4.1 ms (PERF.md, PR 27).  Where k is no
+    whole number of the
     TPU's 8-row tiles (top-6) a token's k rows are gathered **k-major**,
     ``[k, T, E]``: the TPU tiles an array's two minor axes, so ``[T, 6, E]``
     lays six rows on an eight-row tile and the compiler re-tiles the
@@ -266,19 +281,24 @@ def _tokens_fwd(rows, gates, dest, row_assignment, every_row_exists):
 
 def _tokens_bwd(every_row_exists, res, dy):
     rows, gates, dest, row_assignment = res
-    k = gates.shape[1]
+    k, n_assign = gates.shape[1], gates.size
     # Row r belongs to assignment row_assignment[r] = t * k + j (T * k where
     # none landed): its cotangent is token t's, weighted by that gate — by
-    # zero where none landed.
+    # zero where none landed.  (R scalars gathered, 0.48 ms at 67,584: the
+    # T * k gates scattered through `dest` took longer wherever R < T * k.)
     row_gate = jnp.take(gates.reshape(-1), row_assignment, mode="fill", fill_value=0)
-    drows = jnp.take(dy, row_assignment // k, axis=0, mode="clip")
-    drows = (drows.astype(jnp.float32) * row_gate[:, None]).astype(rows.dtype)
-    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
-    if _k_leads(k):
-        dgates = jnp.einsum("kte,te->kt", picked, dy.astype(jnp.float32)).T
-    else:
-        dgates = jnp.einsum("tke,te->tk", picked, dy.astype(jnp.float32))
-    return drows, dgates, _int_zero(dest), _int_zero(row_assignment)
+    dy_rows = jnp.take(dy, row_assignment // k, axis=0, mode="clip").astype(jnp.float32)
+    drows = (dy_rows * row_gate[:, None]).astype(rows.dtype)
+    # The gate's gradient is <row, its token's cotangent>, taken here where
+    # both rows already are and written to the row's assignment: token-major
+    # whichever axis leads the forward's gather.  A row none landed in gets
+    # an index past the end of its own, so the indices stay distinct and the
+    # scatter skips it; an assignment without a row keeps its zero.
+    rowdot = jnp.sum(rows.astype(jnp.float32) * dy_rows, axis=1)
+    empty = row_assignment == n_assign
+    at = row_assignment + jnp.where(empty, jnp.arange(row_assignment.size, dtype=row_assignment.dtype), 0)
+    dgates = jnp.zeros((n_assign,), jnp.float32).at[at].set(rowdot, unique_indices=True, mode="drop")
+    return drows, dgates.reshape(gates.shape), _int_zero(dest), _int_zero(row_assignment)
 
 
 _tokens_of_rows.defvjp(_tokens_fwd, _tokens_bwd)
