@@ -616,8 +616,10 @@ def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     and 1 x 16,384 tokens — compressed attention through `tpuft_fa_*` at 8 query
     heads on 2 KV heads in each of four layers, the 8 held experts of each layer
     through `tpuft_gmm_*`, the tied 131,136-row head through `tpuft_ce_*` over
-    blocks of 1,024 rows — with room for AdamW's moments beside it on a 16 GiB
-    chip, and no array of rows x vocabulary anywhere in it."""
+    blocks of 1,024 rows forward and slabs of 16,384 columns backward, a layer's
+    weight gradients finished inside the layer's backward pass — with room
+    for AdamW's moments beside it on a 16 GiB chip, and no array of rows x
+    vocabulary anywhere in it."""
     import os
     import re
     import sys
@@ -643,24 +645,44 @@ def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # three projections a layer: forward, recomputed, and the two gradients
     gmm = _kernel_calls(text, "tpuft_gmm_")
     assert sorted(gmm) == ["tpuft_gmm_dlhs"] * 12 + ["tpuft_gmm_drhs"] * 12 + ["tpuft_gmm_fwd"] * 24
-    # the head: each kernel once in the text, inside the loop over the 16 blocks of rows
-    assert sorted(_kernel_calls(text, "tpuft_ce_")) == ["tpuft_ce_dlogits", "tpuft_ce_lse"]
+    # a head in pieces puts the program at the memory's edge, and there a layer's weight gradients are finished
+    # inside the layer's backward pass: after each attention backward kernel its own layer's three, not all twelve
+    # after the last (what the compiler chooses alone, holding 21 arrays of [17,408, 2,048] rows till then)
+    late = [c for c in _kernel_calls(text, "tpuft_") if c in ("tpuft_fa_bwd_dkdv_dq", "tpuft_gmm_drhs")]
+    assert late == (["tpuft_fa_bwd_dkdv_dq"] + ["tpuft_gmm_drhs"] * 3) * 4
+    # the head: the forward kernel once in the text, inside the loop over the 16 blocks of rows; the backward one
+    # twice — inside the loop over the 8 slabs of 16,384 columns, and for the last slab of 512 (64 of them the
+    # head's), a call of its own before the loop
+    assert sorted(_kernel_calls(text, "tpuft_ce_")) == ["tpuft_ce_dlogits"] * 2 + ["tpuft_ce_lse"]
     rows, vocab = 16_384, 131_136
     import math
 
     widest = max(math.prod(int(d) for d in dims.split(","))
                  for dims in re.findall(r"(?:bf16|f32|s32)\[([0-9,]+)\]", text))
-    # the largest array is the embedding padded to the kernels' 131,584 columns (the head's weight, and the buffer its
-    # gradient is summed into): an eighth of rows x vocabulary; a block's dlogits [1,024, 131,584] are half of that
+    # the largest array is the embedding padded to the kernels' 131,584 columns (the head's weight): an eighth of
+    # rows x vocabulary; a slab's dlogits [16,384, 16,384] are as large (`_DLOGITS_BLOCK_BYTES` to the byte),
+    # twice a row block's [1,024, 131,584], which is gone
     assert widest == 131_584 * 2_048 <= rows * 131_584 // 8, widest
-    assert "[1024,131584]" in text and f"[{rows},{vocab}]" not in text and f"[{rows},131584]" not in text
+    assert "bf16[16384,16384]" in text and "[1024,131584]" not in text
+    assert f"[{rows},{vocab}]" not in text and f"[{rows},131584]" not in text
+    # the mechanism's witness: inside the head's backward loop the gradient of the table is WRITTEN, a slab's rows
+    # at their place in a buffer of the leaf's own shape, and never summed — no float32 [V, E] is the result of
+    # an add there, padded or not, and none of the padded shape exists at all
+    looped = [line for line in text.splitlines() if "jvp(head_loss))/while/body" in line]
+    table = re.compile(r"= f32\[13(?:1136|1584),2048\]\S* ([a-z-]+)\(")
+    assert "dynamic-update-slice" in {m.group(1) for line in looped for m in [table.search(line)] if m}
+    assert not [line for line in looped for m in [table.search(line)] if m and m.group(1) == "add"]
+    assert "f32[131584,2048]" not in text
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("cca_moe_lm").total_params(config) == 696_250_376
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
-    # 15,177,589,312 (temporaries 4,037,445,120; builder's compile, PR 45: 100.6 MB over PR 41's 15,076,943,936 —
-    # with the gates' gradient taken on the row side the schedule differs, eight asynchronous copies of
-    # [17,408, 2,048] row arrays into the compiler's fast memory space among it; which buffer sets the peak was not
-    # looked for, and the allocator's peak on the chip did not move): the chip's allocator has 16.9e9.  With blocks
-    # of 2,048 rows 15.88e9 at PR 41, of 4,096 rows 16.99e9
+    # 15,039,388,224 (temporaries 3,899,244,032; builder's compile, PR 46), under PR 45's 15,177,589,312 with the
+    # head's backward by rows.  Without the barrier a layer (`_grads_inside`) the same head compiles to
+    # 15,339,575,872 with slabs of 8,192 columns and 15.84e9 with these of 16,384: the table's gradient is written
+    # into the program's output buffer, which the compiler had lent to the layers' backward pass while the padded
+    # accumulator (1.08 GB) sat among the temporaries, and with that room the compiler leaves all twelve
+    # `tpuft_gmm_drhs` calls to the end of the program and peaks in layer 0's backward pass.  With the barrier
+    # the slab's width moves nothing (8,192 and 16,384 compile to the same byte).  The chip's allocator has 16.9e9
+    # (PERF.md section 6, PR 46); by rows, blocks of 2,048 took 15.88e9 at PR 41
     assert resident <= 15.2e9, f"the step needs {resident} bytes with AdamW's moments"

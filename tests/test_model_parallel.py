@@ -72,6 +72,33 @@ def test_scan_unroll_matches_scan() -> None:
         )
 
 
+@pytest.mark.parametrize("unroll", [1, CFG.n_layers], ids=["scan", "static_loop"])
+def test_grads_finished_inside_their_layer_are_the_same_grads(unroll, monkeypatch) -> None:
+    """Where the head runs in pieces (here: a budget of nothing for its
+    dlogits) a layer's cotangents pass one barrier, x's and the weights'
+    together: one barrier more a layer (one in a scan's body), and loss and
+    gradients are those without it to the last bit."""
+    from torchft_tpu.ops import cross_entropy
+
+    cfg = TransformerConfig(**{**CFG.__dict__, "scan_unroll": unroll, "remat": True})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = _batch()
+
+    def run():
+        f = jax.value_and_grad(lambda p: loss_fn(p, batch, cfg))
+        return f(params), jax.jit(f).lower(params).as_text()
+
+    (loss, grads), plain = run()
+    monkeypatch.setattr(cross_entropy, "_DLOGITS_BYTES", 0)
+    (loss_in, grads_in), tied = run()
+    # beside the ones `jax.checkpoint` puts before a layer's recomputed forward pass
+    barriers = tied.count("optimization_barrier") - plain.count("optimization_barrier")
+    assert barriers == (cfg.n_layers if unroll > 1 else 1)
+    assert float(loss) == float(loss_in)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_in)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_sharded_matches_single_device() -> None:
     params = init_params(jax.random.PRNGKey(0), CFG)
     batch = _batch()

@@ -488,22 +488,74 @@ def test_fused_cross_entropy_pallas_interpret_matches() -> None:
     )
 
 
+@pytest.mark.parametrize("j,width,real", [(0, 512, 512), (1, 512, 512), (2, 256, 76)],
+                         ids=["first_slab", "a_slab_with_targets_on_both_sides", "last_slab_partly_padding"])
+def test_a_slab_of_dlogits_is_its_columns_of_the_whole(j, width, real) -> None:
+    """The `tpuft_ce_dlogits` kernel (interpret mode) over one slab of a
+    head's columns, as `_ce_rows_bwd` calls it: the WHOLE weight, read from
+    the slab's first tile on through the index map; the targets and
+    ``valid_v`` in the head's own column numbers, which the kernel's are
+    once it adds that tile; the whole head's log-sum-exp (1,100 columns
+    padded to 1,280, slabs of 512: the last one 256 wide, in tiles of 256).
+    Against the slab's columns of the materialized (softmax - onehot) *
+    scale; the padding's are exact zeros."""
+    from torchft_tpu.ops.cross_entropy import _ce_dlogits_pallas
+
+    rng = np.random.default_rng(15)
+    n, e, v, vp, slab = 256, 128, 1100, 1280, 512
+    col = j * slab
+    x = jnp.asarray(rng.standard_normal((n, e)), dtype=jnp.float32)
+    w = np.zeros((e, vp), np.float32)
+    w[:, :v] = rng.standard_normal((e, v)) * 0.1
+    t = rng.integers(0, v, n)
+    t[:8] = [0, 511, 512, 1023, 1024, 1099, 1050, 600]   # both edges of every slab
+    logits = (np.asarray(x) @ w)[:, :v]
+    lse = np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) + logits.max(-1)
+    want = np.zeros((n, vp), np.float32)
+    want[:, :v] = np.exp(logits - lse[:, None])
+    want[np.arange(n), t] -= 1.0
+    got = jax.jit(lambda j: _ce_dlogits_pallas(  # j traced, as the loop's is
+        x, jnp.asarray(w), jnp.asarray(t, jnp.int32), jnp.asarray(lse, jnp.float32), 0.37,
+        interpret=True, valid_v=None if real == width else v, cols=(j, slab, width)))(j)
+    assert got.shape == (n, width)
+    np.testing.assert_allclose(np.asarray(got), want[:, col:col + width] * 0.37, rtol=1e-4, atol=1e-5)
+    assert not np.any(np.asarray(got)[:, real:]) and ((t >= col) & (t < col + real)).sum() >= 2
+
+
 @pytest.mark.parametrize("vocab_major", [False, True], ids=["head_leaf", "tied_embedding"])
-@pytest.mark.parametrize("block", [96, 48, 40], ids=["one_block", "two_blocks", "a_block_that_does_not_divide"])
-def test_a_head_over_blocks_of_rows_is_the_one_block_head(block, vocab_major) -> None:
+@pytest.mark.parametrize("block,v,slab,tail_targets", [
+    (96, 1000, 1024, False),
+    (48, 1000, 512, False),
+    (40, 2100, 1024, False),
+    (40, 2100, 1024, True),
+], ids=["one_block_one_slab", "two_blocks_two_slabs", "a_block_that_does_not_divide_a_narrower_last_slab",
+        "targets_in_the_partly_padded_last_slab"])
+def test_a_head_over_blocks_of_rows_is_the_one_block_head(block, v, slab, tail_targets, vocab_major, monkeypatch) -> None:
     """`fused_linear_cross_entropy_rows` (XLA fallback path) against the
-    padded one-block op at a width no tile divides (200 -> 256 columns): the
-    loss to the rounding of a sum of 96 float32 terms taken in another order,
-    dx and dw to 1e-5 of their largest entry (the blocks' parts of dw are
-    summed in float32 in another order); the weight as the tree holds it,
-    [E, V] or the embedding's [V, E], and its gradient in that layout."""
-    from torchft_tpu.ops.cross_entropy import fused_linear_cross_entropy_padded, fused_linear_cross_entropy_rows
+    padded one-block op at widths no tile divides (1,000 -> 1,024 columns, one
+    slab or two; 2,100 -> 2,560, two slabs of 1,024 and a last one of 512 with
+    52 real columns; the slab is what `head_slab` makes of a budget of 96 rows
+    of it): the loss to the rounding of a sum of 96 float32 terms taken in
+    another order, dx to 1e-5 of its largest entry (the slabs' parts of dx
+    are summed in float32 in another order), dw to 1e-6 (each element is one
+    product over the same 96 rows, written once); the weight as the tree
+    holds it, [E, V] or the embedding's [V, E], and its gradient in that
+    layout.  With every target in the last slab's real columns a slab that
+    compared its own columns with the head's would subtract no one-hot at all."""
+    from torchft_tpu.ops import cross_entropy
+    from torchft_tpu.ops.cross_entropy import (
+        fused_linear_cross_entropy_padded, fused_linear_cross_entropy_rows, head_slab, padded_vocab)
 
     rng = np.random.default_rng(13)
-    n, e, v = 96, 32, 200
+    n, e = 96, 32
+    monkeypatch.setattr(cross_entropy, "_DLOGITS_BYTES", 0)
+    monkeypatch.setattr(cross_entropy, "_DLOGITS_BLOCK_BYTES", n * slab * 2)
+    assert head_slab(n, padded_vocab(v)) == slab
+    last = (padded_vocab(v) - 1) // slab * slab
+    assert padded_vocab(v) - last in (slab, 512) and (not tail_targets or last < v < padded_vocab(v))
     x = jnp.asarray(rng.standard_normal((n, e)), dtype=jnp.float32)
     w = jnp.asarray(rng.standard_normal((e, v)) * 0.1, dtype=jnp.float32)
-    t = jnp.asarray(rng.integers(0, v, n), dtype=jnp.int32)
+    t = jnp.asarray(rng.integers(last if tail_targets else 0, v, n), dtype=jnp.int32)
     held = w.T if vocab_major else w
 
     def rows(x, held):
@@ -515,25 +567,30 @@ def test_a_head_over_blocks_of_rows_is_the_one_block_head(block, vocab_major) ->
     np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
     np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_want), rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(dx_want))))
     dw = dw.T if vocab_major else dw
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_want), rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(dw_want))))
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_want), rtol=1e-5, atol=1e-6 * float(jnp.max(jnp.abs(dw_want))))
 
 
-@pytest.mark.parametrize("n,v,block", [
-    (16_384, 131_584, 1024),   # the tied 131,136-row head, padded: 4.3 GB whole, 16 blocks of 0.27 GB
-    (8_192, 92_544, None),     # the widest head the benchmark had: 1.5 GB, whole
-    (16_384, 32_000, None),
-    (32_768, 131_584, 1024),
-    (16_384, 262_656, 512),    # the unsliced vocabulary: half the rows a block
+@pytest.mark.parametrize("n,v,block,slab", [
+    # the tied 131,136-row head, padded: 4.3 GB whole; 16 blocks of 0.27 GB, 8 slabs of 0.54 and one of 512 columns
+    (16_384, 131_584, 1024, 16_384),
+    (8_192, 92_544, None, 92_544),    # the widest head the benchmark had: 1.5 GB, whole
+    (16_384, 32_000, None, 32_000),
+    (32_768, 131_584, 1024, 8_192),
+    (16_384, 262_656, 512, 16_384),   # the unsliced vocabulary: half the rows a block, the same slab
 ])
-def test_which_heads_run_over_blocks_of_rows(n, v, block) -> None:
-    from torchft_tpu.ops.cross_entropy import fused_ce_applicable, head_row_block
+def test_which_heads_run_over_blocks_of_rows(n, v, block, slab) -> None:
+    import math
 
-    assert head_row_block(n, v) == block
+    from torchft_tpu.ops.cross_entropy import _DLOGITS_BLOCK_BYTES, _block_rows, _block_v, head_row_block, head_slab
+
+    assert (head_row_block(n, v), head_slab(n, v)) == (block, slab)
     if block:  # a block is a whole number of the kernels' row tiles at the widths the heads have
-        assert block * v * 2 <= 512 * 1024 * 1024 < 2 * block * v * 2
-        from torchft_tpu.ops.cross_entropy import _block_rows
-
+        assert block * v * 2 <= _DLOGITS_BLOCK_BYTES < 2 * block * v * 2
         assert _block_rows(block, 2048) is not None
+        # a slab's dlogits stay within the same budget, one twice as wide would not; the kernels tile it, and the
+        # narrower one after the last in tiles that divide its first column
+        assert n * slab * 2 <= _DLOGITS_BLOCK_BYTES < 2 * n * slab * 2 and slab % 512 == 0
+        assert _block_v(slab, 2048) == 512 and _block_v(math.gcd(v - (v - 1) // slab * slab, slab), 2048) == 512
 
 
 def test_rms_norm_matches_and_grads() -> None:

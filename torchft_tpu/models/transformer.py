@@ -914,6 +914,13 @@ def _decoder(
     if cfg.moe_router_state:
         # the second stream: the router's state, zeros before the first layer
         x = (x, jnp.zeros((B, S, cfg.moe_router_state), jnp.float32))
+    from torchft_tpu.ops.cross_entropy import head_row_block, padded_vocab
+
+    # A head that runs in pieces says the program is at the edge of the chip's memory.  There a layer's weight
+    # gradients are finished inside the layer's backward pass: left alone the compiler puts them all at the end of
+    # the program, where they are stacked, and holds every layer's inputs to them until then (ZAYA's step
+    # compiled to 15.84e9 bytes so and to 15.04e9 with the barriers, PR 46).
+    grads_inside = head_row_block(B * S, padded_vocab(cfg.vocab_size)) is not None
     stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
     aux_total = jnp.zeros((), jnp.float32)
     pieces, pending = [], []  # the layers' statistics: stacked runs, and layers still to be stacked
@@ -936,6 +943,8 @@ def _decoder(
             stacked = dict(stacked, router_bias=router_bias)
 
         def body(x, w, kind=kind):
+            if grads_inside:
+                x, w = _grads_inside(x, w)
             w = dict(w)
             return _layer(cfg, mesh, rules, x, w, positions, kind=kind, router_bias=w.pop("router_bias", None))
 
@@ -976,6 +985,17 @@ def _decoder(
         flush()
         whole = pieces[0] if len(pieces) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *pieces)
         return x, _over_layers(whole)
+
+
+@jax.custom_vjp
+def _grads_inside(x, w):
+    """(x, w) as they are; backward, the two cotangents behind one barrier,
+    so that what follows x's cotangent — the backward pass of the layer
+    before — waits for every gradient of this layer's weights."""
+    return x, w
+
+
+_grads_inside.defvjp(lambda x, w: ((x, w), None), lambda _, ct: jax.lax.optimization_barrier(ct))
 
 
 def _remat(cfg: TransformerConfig, body):

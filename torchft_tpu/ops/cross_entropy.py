@@ -49,17 +49,30 @@ _LANE = 128
 # logits tile [block_rows, block_v] is the largest single allocation).
 _X_BLOCK_BYTES = 2 * 1024 * 1024
 _W_BLOCK_BYTES = 3 * 1024 * 1024
-# The most the backward's bf16 dlogits [rows, V] may take whole: a head whose
-# [N, V] would pass it runs over blocks of rows (`head_row_block`).  2 GiB is
+# The most the backward's bf16 dlogits [N, V] may take whole: a head whose
+# [N, V] would pass it runs in pieces (`head_row_block`, `head_slab`).  2 GiB is
 # above every head the kernels were written for (8,192 x 92,544: 1.5 GB) and
 # an eighth of a v5e's memory; at 16,384 x 131,584 the whole is 4.3 GB beside
-# 11 GB of weights, gradient and moments.  A block's dlogits take at most
-# `_DLOGITS_BLOCK_BYTES`: at 512 MiB that head runs in 16 blocks of 1,024 rows
-# (0.27 GB each) and its gradient program compiles to 3.94 GB of temporaries,
-# where blocks of 2,048 rows take 4.74 and of 4,096 rows 5.85 GB, over the chip
-# (compiled for a described v5e, PR 41).  The kernels read the weight once a
-# tile of `_X_BLOCK_BYTES` rows whatever the block, so smaller blocks re-read
-# nothing; below a thousand rows the loop's own steps would begin to show.
+# 11 GB of weights, gradient and moments.  The pieces are cut along the axis
+# whose sum is the small one, and a piece takes at most `_DLOGITS_BLOCK_BYTES`
+# of bf16.  Forward, by rows: a block's log-sum-exp needs all of its columns
+# and nothing of another block, and no [rows, V] array exists at all; 16 blocks
+# of 1,024 rows at that head ([1,024, 131,584]: 0.27 GB, and 2,048 rows are
+# 2 MB over).  Backward, by columns: a slab's rows of dW are complete after
+# ONE product over all N rows and are written once, and dx [N, E] float32
+# (134 MB) is what is summed over the slabs — cut by rows it was dW [V, E]
+# float32 (1.08 GB) that every block read and wrote, 34.5 GB a step.  9 slabs
+# there: 8 of 16,384 columns ([16,384, 16,384]: 0.54 GB) and the last 512 with
+# the padding in them.  A wider slab sums dx fewer times (8,192 columns
+# measured 2.4 ms a step more in that product and nothing in the kernel,
+# PR 46).  The gradient program compiles to 3.90 GB of
+# temporaries with the layers' weight gradients finished layer by layer
+# (`models/transformer.py` `_grads_inside`; the same with slabs of 8,192), and
+# to 4.20 GB without that at 8,192; by rows it took 4.04 at 1,024, 4.74 at
+# 2,048 and 5.85 at 4,096, over the chip (compiled for a described v5e, PRs
+# 41 and 46).  The kernels read the weight once a tile of `_X_BLOCK_BYTES`
+# rows whatever the piece, so smaller pieces re-read nothing; below a thousand
+# rows or a few thousand columns the loop's own steps would begin to show.
 _DLOGITS_BYTES = 2 * 1024 * 1024 * 1024
 _DLOGITS_BLOCK_BYTES = 512 * 1024 * 1024
 
@@ -142,12 +155,16 @@ def _ce_lse_kernel(
 
 def _ce_dlogits_kernel(
     x_ref, w_ref, tgt_ref, lse_ref, scale_ref, dl_ref,
-    *, block_rows: int, block_v: int, valid_v: Optional[int] = None,
+    *, block_rows: int, block_v: int, valid_v: Optional[int] = None, first_ref=None,
 ):
+    """``first_ref``: where the call covers a slab of w's columns, the slab's
+    first tile of w (SMEM), so that ``cols`` are the head's own columns."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(0)
     j = pl.program_id(1)
+    if first_ref is not None:
+        j = j + first_ref[0]
 
     s = jax.lax.dot(
         x_ref[...], w_ref[...], preferred_element_type=jnp.float32
@@ -190,35 +207,46 @@ def _ce_lse_pallas(x, w, interpret: bool = False, valid_v: Optional[int] = None)
     return lse[0, 0]
 
 
-def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid_v: Optional[int] = None):
+def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid_v: Optional[int] = None,
+                       cols=None):
     """Scaled bf16 dlogits = (softmax(x@w) - onehot(targets)) * scale.
-    scale is a traced scalar (folded in here so no extra [N, V] pass)."""
+    scale is a traced scalar (folded in here so no extra [N, V] pass).
+    ``cols`` = (j, slab, width), j traced: the ``width`` columns from column
+    j * slab alone, [N, width] — the same kernel at the same tiles, reading w
+    in place from the slab's first tile on, a prefetched scalar that the index
+    map of w and the kernel's column numbers add, so that targets and
+    ``valid_v`` stay the head's own column numbers."""
+    import math
+
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, e = x.shape
-    v = w.shape[1]
-    br, bv = _block_rows(n, e), _block_v(v, e)
-    num_i, num_v = n // br, v // bv
+    j, slab, v = cols or (None, None, w.shape[1])
+    br, bv = _block_rows(n, e), _block_v(v if cols is None else math.gcd(v, slab), e)  # a tile divides j * slab
     tgt = targets.astype(jnp.int32)[None, None, :]
     lse3 = lse[None, None, :]
     scale2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-
-    return pl.pallas_call(
-        functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv, valid_v=valid_v),
-        out_shape=jax.ShapeDtypeStruct((n, v), x.dtype),
-        grid=(num_i, num_v),
+    kernel = functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv, valid_v=valid_v)
+    specs = dict(
+        grid=(n // br, v // bv),
         in_specs=[
-            pl.BlockSpec((br, e), lambda i, j: (i, 0)),        # x
-            pl.BlockSpec((e, bv), lambda i, j: (0, j)),        # w
-            pl.BlockSpec((1, 1, n), lambda i, j: (0, 0, 0)),   # targets
-            pl.BlockSpec((1, 1, n), lambda i, j: (0, 0, 0)),   # lse
-            pl.BlockSpec(memory_space=pltpu.SMEM),             # scale
+            pl.BlockSpec((br, e), lambda i, j, *first: (i, 0)),        # x
+            pl.BlockSpec((e, bv), lambda i, j, *first: (0, first[0][0] + j if first else j)),  # w
+            pl.BlockSpec((1, 1, n), lambda i, j, *first: (0, 0, 0)),   # targets
+            pl.BlockSpec((1, 1, n), lambda i, j, *first: (0, 0, 0)),   # lse
+            pl.BlockSpec(memory_space=pltpu.SMEM),                     # scale
         ],
-        out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
-        interpret=interpret,
-        name="tpuft_ce_dlogits",
-    )(x, w, tgt, lse3, scale2)
+        out_specs=pl.BlockSpec((br, bv), lambda i, j, *first: (i, j)),
+    )
+    call = dict(out_shape=jax.ShapeDtypeStruct((n, v), x.dtype), interpret=interpret, name="tpuft_ce_dlogits")
+    if cols is None:
+        return pl.pallas_call(kernel, **specs, **call)(x, w, tgt, lse3, scale2)
+    first = (jnp.asarray(j, jnp.int32) * (slab // bv)).reshape(1)
+    return pl.pallas_call(
+        lambda first_ref, *refs: kernel(*refs, first_ref=first_ref),
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1, **specs), **call,
+    )(first, x, w, tgt, lse3, scale2)
 
 
 def _target_logit(x, w, targets):
@@ -260,17 +288,24 @@ def _ce_vjp_fwd(x, w, targets):
     return jnp.mean(lse - tl), (x, w, targets, lse)
 
 
-def _ce_dlogits(x, w, targets, lse, scale, valid_v: Optional[int] = None):
-    """(softmax(x @ w) - onehot(targets)) * scale in x's dtype, [N, V]."""
+def _ce_dlogits(x, w, targets, lse, scale, valid_v: Optional[int] = None, cols=None):
+    """(softmax(x @ w) - onehot(targets)) * scale in x's dtype, [N, V]; with
+    ``cols`` = (j, slab, width), j traced, its ``width`` columns from column
+    j * slab alone."""
     if _pallas_util.on_tpu():
         # dlogits tile-by-tile in bf16 (pallas) — the f32 logits never
         # exist in HBM.
-        return _ce_dlogits_pallas(x, w, targets, lse, scale, valid_v=valid_v)
+        return _ce_dlogits_pallas(x, w, targets, lse, scale, valid_v=valid_v, cols=cols)
+    first = 0
+    if cols is not None:
+        first = cols[0] * cols[1]
+        w = jax.lax.dynamic_slice_in_dim(w, first, cols[2], 1)
+    at = first + jnp.arange(w.shape[1])
     logits = jax.lax.dot(x, w, preferred_element_type=jnp.float32)
     if valid_v is not None:
-        logits = jnp.where(jnp.arange(w.shape[1]) < valid_v, logits, -1e30)
+        logits = jnp.where(at < valid_v, logits, -1e30)
     p = jnp.exp(logits - lse[:, None])
-    p = p - jax.nn.one_hot(targets, w.shape[1], dtype=jnp.float32)
+    p = p - (targets[:, None] == at)
     return (p * scale).astype(x.dtype)
 
 
@@ -332,17 +367,27 @@ def fused_linear_cross_entropy_padded(x, w, targets):
     return _fused_ce_valid(x, jnp.pad(w, ((0, 0), (0, padded_vocab(v) - v))), targets, v)
 
 
-# -- a head over blocks of rows ---------------------------------------------------
+# -- a head in pieces: blocks of rows forward, slabs of columns backward ------------
 
 
 def head_row_block(n: int, v: int) -> Optional[int]:
-    """Rows a block where the head of N rows and V (padded) columns runs
-    block by block — the largest power of two whose bf16 dlogits stay within
+    """Rows a block where the head of N rows and V (padded) columns runs in
+    pieces — the largest power of two whose bf16 [block, V] stay within
     `_DLOGITS_BLOCK_BYTES` — and None where the one [N, V] stays within
     `_DLOGITS_BYTES`."""
     if n * v * 2 <= _DLOGITS_BYTES:
         return None
     return 1 << ((_DLOGITS_BLOCK_BYTES // (2 * v)).bit_length() - 1)
+
+
+def head_slab(n: int, v: int) -> int:
+    """Columns a slab of that head's backward pass: the largest power of two
+    times 512 (what `padded_vocab` pads to, and `_block_v` tiles) whose bf16
+    dlogits [N, slab] stay within `_DLOGITS_BLOCK_BYTES`, and all V where
+    `head_row_block` is None."""
+    if head_row_block(n, v) is None:
+        return v
+    return 512 << max((_DLOGITS_BLOCK_BYTES // (2 * n * 512)).bit_length() - 1, 0)
 
 
 def _kernel_weight(w, dtype, vocab_major: bool):
@@ -353,8 +398,8 @@ def _kernel_weight(w, dtype, vocab_major: bool):
 
 
 def _row_blocks(a, block: int):
-    """a [N, ...] -> [blocks, block, ...], zero rows after the last: they add
-    nothing to dw (x is zero there), and their loss and dx are cut off."""
+    """a [N, ...] -> [blocks, block, ...], zero rows after the last: their
+    dlogits meet a zero x in dw, and their loss and dx are cut off."""
     rows = -(-a.shape[0] // block) * block
     a = jnp.pad(a, ((0, rows - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
     return a.reshape(rows // block, block, *a.shape[1:])
@@ -362,18 +407,20 @@ def _row_blocks(a, block: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def fused_linear_cross_entropy_rows(x, w, targets, block: int, vocab_major: bool = False):
-    """`fused_linear_cross_entropy` over blocks of ``block`` rows, for a head
-    whose bf16 dlogits [N, V] are too large to exist whole: a block's
-    log-sum-exp at a time in the forward pass; in the backward pass a loop
-    over the blocks — the block's dlogits from the kernel, its dx, and its
-    part of dw added in float32 into the one buffer that is w's gradient — so
-    that one block's dlogits exist at a time.  N need be no whole number of
-    blocks.
+    """`fused_linear_cross_entropy` for a head whose bf16 dlogits [N, V] are
+    too large to exist whole.  Forward over blocks of ``block`` rows: a
+    block's log-sum-exp at a time.  Backward over slabs of `head_slab` columns
+    and all rows: the slab's dlogits from the kernel, its rows of w's
+    gradient from ONE product over the N rows, written once where they belong
+    and never read, and its part of dx added into a float32 [N, E] — so that
+    one slab's dlogits exist at a time and the sum that is carried is the
+    small one.  N need be no whole number of blocks, V none of slabs: the
+    last slab is the narrower one.
 
     ``w`` is the weight as the parameter tree holds it, in the parameters'
     dtype: [E, V], or with ``vocab_major`` [V, E] — a tied head, the
     embedding itself, whose transposed copy is made in x's dtype and never in
-    float32, and whose gradient is accumulated [V, E].  V may be any width
+    float32, and whose gradient is written [V, E].  V may be any width
     (`padded_vocab`).  Callers gate on ``fused_ce_applicable(block, e,
     padded_vocab(v))``."""
     return _ce_rows_fwd(x, w, targets, block, vocab_major)[0]
@@ -398,23 +445,32 @@ def _ce_rows_fwd(x, w, targets, block: int, vocab_major: bool):
 
 def _ce_rows_bwd(block: int, vocab_major: bool, res, g):
     x, w, wk, targets, lse = res
-    n = x.shape[0]
+    n, e = x.shape
+    vp = wk.shape[1]
     v = w.shape[0] if vocab_major else w.shape[1]
-    valid_v = None if wk.shape[1] == v else v
+    slab = head_slab(n, vp)
+    xp = _row_blocks(x, block).reshape(-1, e)
+    tp, lse = _row_blocks(targets, block).reshape(-1), lse.reshape(-1)
     scale = g / n
 
-    def one(dw, a):
-        xb, tb, lb = a
-        dl = _ce_dlogits(xb, wk, tb, lb, scale, valid_v)
-        dxb = jax.lax.dot_general(dl, wk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        lhs, rhs = (dl, xb) if vocab_major else (xb, dl)
-        dwb = jax.lax.dot_general(lhs, rhs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return dw + dwb, dxb.astype(x.dtype)
+    def one(dx, dw, j, width: int, real: int):
+        """The ``width`` columns from the j-th multiple of ``slab``, ``real`` of them the head's own."""
+        dl = _ce_dlogits(xp, wk, tp, lse, scale, None if real == width else v, (j, slab, width))
+        wj = jax.lax.dynamic_slice_in_dim(wk, j * slab, width, 1)
+        dx = dx + jax.lax.dot_general(dl, wj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        lhs, rhs = (dl[:, :real], xp) if vocab_major else (xp, dl[:, :real])
+        dwj = jax.lax.dot_general(lhs, rhs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return dx, jax.lax.dynamic_update_slice_in_dim(dw, dwj, j * slab, 0 if vocab_major else 1)
 
-    dw = jnp.zeros(wk.shape[::-1] if vocab_major else wk.shape, jnp.float32)
-    dw, dx = jax.lax.scan(one, dw, (_row_blocks(x, block), _row_blocks(targets, block), lse))
-    dw = dw[:v] if vocab_major else dw[:, :v]
-    return dx.reshape(-1, x.shape[1])[:n], dw.astype(w.dtype), np.zeros(targets.shape, jax.dtypes.float0)
+    # every slab but the last holds none of the padding (under 512 columns, and a slab is 512 at least):
+    # those run in the loop; the last one, with what it holds of the head as a static width, before it,
+    # or the compiled program holds 135 MB more at its peak (15.17e9 for 15.04e9 at ZAYA's head, PR 46)
+    full = (vp - 1) // slab
+    dx, dw = jnp.zeros(xp.shape, jnp.float32), jnp.zeros(w.shape, jnp.float32)
+    dx, dw = one(dx, dw, full, vp - full * slab, v - full * slab)
+    if full:
+        dx, dw = jax.lax.fori_loop(0, full, lambda j, c: one(*c, j, slab, slab), (dx, dw))
+    return dx[:n].astype(x.dtype), dw.astype(w.dtype), np.zeros(targets.shape, jax.dtypes.float0)
 
 
 fused_linear_cross_entropy_rows.defvjp(_ce_rows_fwd, _ce_rows_bwd)
