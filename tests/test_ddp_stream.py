@@ -1,5 +1,5 @@
 """The streamed exchange (``GradientAverager._allreduce_streamed``): buckets
-fetched largest first with a bounded window of hinted copies, each ring op
+fetched largest first, one at a time and no copy started ahead, each ring op
 issued as its bucket lands, and each resolved bucket sent home at once — bit
 for bit what the monolithic ``pipelined=False`` path gives."""
 
@@ -15,7 +15,6 @@ import pytest
 from test_manager import FakeCollective, make_manager, make_quorum, store  # noqa: F401
 from test_subspans import records
 
-from torchft_tpu import ddp
 from torchft_tpu.collectives import Work
 from torchft_tpu.ddp import GradientAverager, plan_buckets
 
@@ -93,9 +92,7 @@ def n_buckets(tree) -> int:
 
 
 @pytest.mark.parametrize("name", list(TREES))
-@pytest.mark.parametrize("window", [0, 2])
-def test_streamed_equals_the_monolithic_path_bit_for_bit(store, monkeypatch, window, name) -> None:  # noqa: F811
-    monkeypatch.setattr(ddp, "_FETCH_WINDOW", window)
+def test_streamed_equals_the_monolithic_path_bit_for_bit(store, name) -> None:  # noqa: F811
     prep = name == "device-prep"
     manager = a_manager(store, MirrorCollective(wire_dtype="bf16" if prep else None))
     try:
@@ -142,36 +139,26 @@ def test_fetch_order_is_descending_bytes_and_the_same_for_every_group(store, nam
 
 
 class HintedLeaf:
-    """A leaf that counts ``copy_to_host_async`` hints: ``ahead`` holds, for
-    each fetch, how many OTHER leaves were hinted and not yet fetched when it
-    began (the copies in flight beside the one being waited for); ``peak`` the
-    most that were ever outstanding, the one about to be fetched included."""
+    """A leaf that counts ``copy_to_host_async`` hints (``hints``) and records
+    the bytes of each fetch in the order they were made (``fetched``)."""
 
     def __init__(self, value: np.ndarray, state: dict) -> None:
         self.value, self.state = value, state
         self.shape, self.dtype, self.nbytes = value.shape, value.dtype, value.nbytes
-        self.hinted = False
 
     def copy_to_host_async(self) -> None:
-        assert not self.hinted
-        self.hinted = True
-        self.state["outstanding"] += 1
-        self.state["peak"] = max(self.state["peak"], self.state["outstanding"])
+        self.state["hints"] += 1
 
     def __array__(self, dtype=None, copy=None):
-        if self.hinted:
-            self.hinted = False
-            self.state["outstanding"] -= 1
-        self.state["ahead"].append(self.state["outstanding"])
         self.state["fetched"].append(self.nbytes)
         return self.value
 
 
 @pytest.mark.parametrize("n_leaves", [1, 2, 5, 12])
-@pytest.mark.parametrize("window", [0, 1, 2])
-def test_never_more_hints_outstanding_than_the_window(store, tmp_path, monkeypatch, window, n_leaves) -> None:  # noqa: F811
-    monkeypatch.setattr(ddp, "_FETCH_WINDOW", window)
-    state = {"outstanding": 0, "peak": 0, "ahead": [], "fetched": []}
+def test_the_stream_starts_no_copy_ahead(store, tmp_path, monkeypatch, n_leaves) -> None:  # noqa: F811
+    """A second transfer on a host slows both (PERF.md section 6, PRs 28 and
+    30): a fetch starts its own copy and no other is started beside it."""
+    state = {"hints": 0, "fetched": []}
     values = [small((1100 + 10 * i,), i) for i in range(n_leaves)]  # one bucket each
     manager = a_manager(store, MirrorCollective(), tmp_path, monkeypatch)
     try:
@@ -181,13 +168,11 @@ def test_never_more_hints_outstanding_than_the_window(store, tmp_path, monkeypat
         manager.shutdown()
     for v, o in zip(values, out):
         assert o.tobytes() == ((v + v[::-1]) / 2).astype(np.float32).tobytes()
-    assert state["outstanding"] == 0
-    ahead = [min(window, n_leaves - 1 - p) for p in range(n_leaves)]
-    assert state["ahead"] == ahead and state["peak"] <= window + 1
+    assert state["hints"] == 0
     assert state["fetched"] == sorted((v.nbytes for v in values), reverse=True)
     fetches = [r for r in records(tmp_path / "m.jsonl", "span") if r["phase"] == "allreduce_d2h"]
     assert [r["pos"] for r in fetches] == list(range(n_leaves))
-    assert [r["inflight"] for r in fetches] == ahead
+    assert [r["bytes"] for r in fetches] == state["fetched"]
 
 
 def stream_of(path):

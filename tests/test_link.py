@@ -737,22 +737,30 @@ def test_worker_metrics_render_serve_and_sections(monkeypatch) -> None:
         wm.close()
 
 
-def test_worker_metrics_legacy_alias_env(monkeypatch) -> None:
-    """TPUFT_SEMISYNC_METRICS_PORT keeps working as a deprecated alias for
-    the unified endpoint's port."""
+def test_worker_metrics_port_env_serves_the_semisync_section(monkeypatch) -> None:
+    """TPUFT_WORKER_METRICS_PORT is the one name for the worker's endpoint:
+    set, it serves the registered ``tpuft_semisync_*`` section too; unset,
+    nothing is served."""
     from torchft_tpu.obs import prom
+    from torchft_tpu.semisync import SemiSyncMetrics
 
-    monkeypatch.delenv("TPUFT_WORKER_METRICS_PORT", raising=False)
-    monkeypatch.setenv("TPUFT_SEMISYNC_METRICS_PORT", "0")
+    monkeypatch.setenv("TPUFT_WORKER_METRICS_PORT", "0")
+    semisync = SemiSyncMetrics(codec="int8", replica_id="g0")
+    semisync.observe_round(True)
     wm = prom.WorkerMetrics(provider=lambda: [])
     port = wm.serve()
     try:
-        assert port  # alias honored
+        assert port and wm.serving
+        wm.add_section(semisync.render_prometheus)
+        body = urllib.request.urlopen(
+            f"http://[::1]:{port}/metrics", timeout=5
+        ).read().decode()
+        assert 'tpuft_semisync_rounds_total{replica="g0",codec="int8"} 1' in body
     finally:
         wm.close()
-    monkeypatch.delenv("TPUFT_SEMISYNC_METRICS_PORT", raising=False)
+    monkeypatch.delenv("TPUFT_WORKER_METRICS_PORT", raising=False)
     wm2 = prom.WorkerMetrics(provider=lambda: [])
-    assert wm2.serve() is None  # both unset -> disabled
+    assert wm2.serve() is None  # unset -> disabled
 
 
 def test_manager_worker_metrics_endpoint_serves_link_gauges(

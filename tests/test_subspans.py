@@ -99,11 +99,11 @@ def test_subspans_add_no_write_per_span(tmp_path, n_buckets) -> None:
 def test_span_record_gains_its_start_and_keeps_its_keys(tmp_path) -> None:
     path = tmp_path / "m.jsonl"
     tracker = SpanTracker(MetricsLogger(str(path)))
-    with tracker.span("allreduce_d2h", step=1, bytes=8, bucket=2, pos=0, inflight=1):
+    with tracker.span("allreduce_d2h", step=1, bytes=8, bucket=2, pos=0):
         pass
     (rec,) = records(path, "span")
     assert {"phase", "step", "slice_gen", "duration_ms", "bytes", "bucket", "t_start_mono"} <= set(rec)
-    assert (rec["bucket"], rec["pos"], rec["inflight"]) == (2, 0, 1)
+    assert (rec["bucket"], rec["pos"]) == (2, 0)
     assert 0 <= rec["t_mono"] - rec["t_start_mono"] < 1.0
     assert rec["t_start_mono"] + rec["duration_ms"] / 1e3 <= rec["t_mono"] + 1e-6
 
@@ -194,7 +194,7 @@ def test_two_group_ring_yields_the_buckets_sub_spans(store, tmp_path, monkeypatc
     import jax.numpy as jnp
 
     from torchft_tpu.collectives import TCPCollective
-    from torchft_tpu.ddp import _FETCH_WINDOW, GradientAverager
+    from torchft_tpu.ddp import GradientAverager
 
     path = tmp_path / "ring.jsonl"
     monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
@@ -244,13 +244,12 @@ def test_two_group_ring_yields_the_buckets_sub_spans(store, tmp_path, monkeypatc
         fetch_spans = {r["bucket"]: r for r in mine if r["event"] == "span" and r["phase"] == "allreduce_d2h"}
         n_buckets = stats[0]["buckets"]
         assert n_buckets == 3 and sorted(fetch_spans) == [0, 1, 2]
-        # The stream's own fields: a bucket's place in the fetch order
-        # (largest first) and the hinted copies in flight when its fetch began.
+        # The stream's own field: a bucket's place in the fetch order
+        # (largest first).
         by_pos = sorted(fetch_spans.values(), key=lambda r: r["pos"])
         assert [r["pos"] for r in by_pos] == [0, 1, 2]
         assert [r["bytes"] for r in by_pos] == sorted((r["bytes"] for r in by_pos), reverse=True)
         assert [r["t_start_mono"] for r in by_pos] == sorted(r["t_start_mono"] for r in by_pos)
-        assert [r["inflight"] for r in by_pos] == [min(_FETCH_WINDOW, 2 - p) for p in range(3)]
         (summary,) = [r for r in mine if r["event"] == "step_summary"]
         stream = summary["exchange_stream"]
         assert stream["buckets"] == 3 and stream["tail_s"] >= 0

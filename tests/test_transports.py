@@ -159,6 +159,34 @@ def test_http_transport_multi_donor_striped(store) -> None:
         rx.shutdown()
 
 
+def test_http_transport_always_stamps_crcs_and_refuses_a_flipped_byte(monkeypatch) -> None:
+    """There is no way to turn the donor's CRC32C stamping off: with
+    ``TPUFT_HTTP_CRC=0`` in the environment a served snapshot still carries
+    one CRC a buffer, and a byte flipped after they were computed is refused
+    by the healer instead of being installed."""
+    monkeypatch.setenv("TPUFT_HTTP_CRC", "0")
+    state = make_state_dict(seed=4)
+    src = HTTPTransport(timeout=10.0)
+    dst = HTTPTransport(timeout=10.0)
+    try:
+        src.send_checkpoint([1], step=5, state_dict=state, timeout=10.0)
+        assert src.wait_snapshot(10.0)
+        meta, buffers = src._state[0], src._state[1]
+        assert meta.crcs is not None and len(meta.crcs) == len(buffers)
+        assert_state_dicts_equal(
+            dst.recv_checkpoint(1, src.metadata(), step=5, timeout=10.0), state
+        )
+        at = next(i for i, b in enumerate(buffers) if b.size)
+        flipped = np.array(buffers[at])  # a device leaf's buffer is read-only
+        flipped.reshape(-1).view(np.uint8)[0] ^= 0x01
+        buffers[at] = flipped
+        with pytest.raises(Exception, match="checksum mismatch"):
+            dst.recv_checkpoint(1, src.metadata(), step=5, timeout=10.0)
+    finally:
+        src.shutdown()
+        dst.shutdown()
+
+
 def test_http_transport_donor_death_mid_heal_failover(store) -> None:
     """The serving donor dies AFTER the header is fetched (mid-heal): the
     receiver fails its stripes over to the second donor and still

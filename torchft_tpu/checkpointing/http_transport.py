@@ -137,15 +137,6 @@ class HTTPTransport(CheckpointTransport):
         # calls with every flattened snapshot (the EC encode entry point).
         self._shard_store = None
         self._snapshot_hook: Optional[Callable[[int, StateDictMeta, List[np.ndarray]], None]] = None
-        # Per-buffer CRCs on served snapshots (TPUFT_HTTP_CRC=0 disables
-        # computing them; receivers verify whenever the header carries them).
-        self._crc_enabled = os.environ.get("TPUFT_HTTP_CRC", "1") != "0"
-        # Optional serving-side bandwidth cap shared by ALL connections of
-        # this transport (TPUFT_HTTP_SHAPED_MBPS, read at construction):
-        # emulates a donor-NIC link for benchmarking the link-bound regime
-        # where striped multi-donor healing scales (the checkpoint-path
-        # sibling of the collective layer's TPUFT_SHAPED_LINK).
-        self._pacer = _ServerPacer.from_env()
 
         transport = self
 
@@ -226,7 +217,7 @@ class HTTPTransport(CheckpointTransport):
                             write_state_dict(
                                 meta,
                                 buffers,
-                                _paced(self.wfile, transport._pacer),
+                                self.wfile,
                                 prefix=prefix,
                             )
                             return
@@ -247,10 +238,9 @@ class HTTPTransport(CheckpointTransport):
                             )
                             self.send_header("Content-Length", str(total))
                             self.end_headers()
-                            out = _paced(self.wfile, transport._pacer)
-                            out.write(sub_prefix)
+                            self.wfile.write(sub_prefix)
                             for i in sel:
-                                out.write(memoryview(as_u8(buffers[i])))
+                                self.wfile.write(memoryview(as_u8(buffers[i])))
                             return
                         payload = transport._render(meta, buffers, what)
                         if payload is None:
@@ -366,12 +356,11 @@ class HTTPTransport(CheckpointTransport):
         """flatten_state_dict + per-buffer CRCs stamped into the header —
         computed ONCE here on the background thread, verified by every
         receiver (full, striped, shard endpoints)."""
-        meta, buffers = flatten_state_dict(state_dict, step=step)
-        if self._crc_enabled:
-            from torchft_tpu.checkpointing.integrity import checksum_buffers
+        from torchft_tpu.checkpointing.integrity import checksum_buffers
 
-            meta.crc_algo, crcs = checksum_buffers(buffers)
-            meta.crcs = tuple(crcs)
+        meta, buffers = flatten_state_dict(state_dict, step=step)
+        meta.crc_algo, crcs = checksum_buffers(buffers)
+        meta.crcs = tuple(crcs)
         return meta, buffers
 
     def _await_flip(self, step: int) -> None:
@@ -500,9 +489,7 @@ class HTTPTransport(CheckpointTransport):
                 handler.send_header("Content-Type", "application/octet-stream")
                 handler.send_header("Content-Length", str(len(body)))
                 handler.end_headers()
-                # Shares the donor-NIC pacer: shard serving rides the same
-                # physical link as checkpoint serving in the shaped regime.
-                _paced(handler.wfile, self._pacer).write(body)
+                handler.wfile.write(body)
                 return
             if len(parts) == 3 and parts[1] == "have":
                 import json
@@ -831,59 +818,3 @@ def _assign_stripes_by_bytes(sizes: List[int], n_donors: int) -> List[int]:
         assign[idx] = d
         loads[d] += sizes[idx]
     return assign
-
-
-class _ServerPacer:
-    """Virtual-time link shared by every connection of one transport: each
-    write reserves `bytes / rate` seconds of the link and sleeps until its
-    reservation ends, so N parallel stripe readers see ONE donor-NIC's
-    bandwidth, not N connections' worth.  Benchmark-only (enabled by
-    TPUFT_HTTP_SHAPED_MBPS at transport construction)."""
-
-    def __init__(self, mbps: float) -> None:
-        self._rate = mbps * 1e6
-        self._lock = threading.Lock()
-        self._next_free = 0.0
-
-    @classmethod
-    def from_env(cls) -> Optional["_ServerPacer"]:
-        try:
-            mbps = float(os.environ.get("TPUFT_HTTP_SHAPED_MBPS") or 0.0)
-        except ValueError:
-            mbps = 0.0
-        return cls(mbps) if mbps > 0 else None
-
-    def consume(self, n: int) -> None:
-        now = time.monotonic()
-        with self._lock:
-            start = max(now, self._next_free)
-            self._next_free = start + n / self._rate
-            until = self._next_free
-        if until > now:
-            time.sleep(until - now)
-
-
-class _PacedStream:
-    """Write-through wrapper applying a shared _ServerPacer in ~4 MB slices
-    (smooth pacing; a donor killed mid-fetch dies mid-stripe)."""
-
-    _SLICE = 4 << 20
-
-    def __init__(self, raw, pacer: _ServerPacer) -> None:
-        self._raw = raw
-        self._pacer = pacer
-
-    def write(self, data) -> int:
-        mv = memoryview(data)
-        for off in range(0, len(mv), self._SLICE):
-            part = mv[off : off + self._SLICE]
-            # Reserve the link BEFORE writing: the actual socket write then
-            # overlaps the next reservation instead of adding to it, so the
-            # emulated link runs at its nominal rate.
-            self._pacer.consume(len(part))
-            self._raw.write(part)
-        return len(mv)
-
-
-def _paced(raw, pacer: Optional[_ServerPacer]):
-    return raw if pacer is None else _PacedStream(raw, pacer)

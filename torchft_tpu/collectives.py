@@ -1011,7 +1011,6 @@ def _incremental_from_env() -> bool:
 # Per-link SPSC ring capacity (data bytes past the 64-byte header).
 # Frames larger than the capacity flow through in pieces, so this bounds
 # memory, not payload size.
-TPUFT_SHM_RING_BYTES_ENV = "TPUFT_SHM_RING_BYTES"
 _SHM_RING_BYTES_DEFAULT = 1 << 20
 
 # Segment header layout — MUST mirror native/src/ring.cc (kShmMagic,
@@ -1040,14 +1039,6 @@ _SHM_REP = struct.Struct("<BQ64s")
 def _transport_from_env() -> str:
     t = os.environ.get(TPUFT_RING_TRANSPORT_ENV, "tcp")
     return t if t in _TRANSPORTS else "tcp"
-
-
-def _shm_ring_bytes_from_env() -> int:
-    try:
-        return max(4096, int(os.environ.get(
-            TPUFT_SHM_RING_BYTES_ENV, str(_SHM_RING_BYTES_DEFAULT))))
-    except ValueError:
-        return _SHM_RING_BYTES_DEFAULT
 
 
 def _boot_id() -> bytes:
@@ -2000,7 +1991,7 @@ class TCPCollective(Collective):
             os.unlink(path)
         except FileNotFoundError:
             pass
-        cap = _shm_ring_bytes_from_env()
+        cap = _SHM_RING_BYTES_DEFAULT
         fd = os.open(path, os.O_RDWR | os.O_CREAT | os.O_EXCL, 0o600)
         try:
             os.ftruncate(fd, _SHM_HDR + cap)

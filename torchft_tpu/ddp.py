@@ -13,11 +13,10 @@ allreduce is issued the moment it lands, and every ring op that has resolved
 by then goes straight back to the device (``jax.device_put`` returns at
 once) — so ring and way back run under the fetches of the buckets still on
 the device, and with a multi-lane ring collective (``TPUFT_RING_LANES``) the
-buckets overlap each other on the wire too.  Only a bounded window of
-buckets beyond the one being fetched has its device->host copy started
-(``_FETCH_WINDOW``): the runtime runs started copies side by side, so with
-the whole tree hinted up front the first fetch returned when all had landed
-and nothing could overlap it.
+buckets overlap each other on the wire too.  A fetch starts its own
+device->host copy and nothing is started ahead of it: a second transfer on a
+host slows both (0.28–0.29 GB/s a process against 0.42 alone; PERF.md
+section 6, PRs 28 and 30).
 
 Wire preparation can run ON DEVICE (``device_wire_prep=True`` /
 ``TPUFT_DEVICE_WIRE_PREP=1``): a cached jitted epilogue casts each float
@@ -27,21 +26,21 @@ full-width copy through host memory and casting on CPU.  The bf16
 quantization point moves from the host encode to the device epilogue; the
 wire bytes are BITWISE identical (pinned in tests/test_device_prep.py), and
 local ring accumulation stays in float32 (collectives.py treats
-already-wire-dtype payloads as pre-encoded).  ``sharded_fetch=True`` /
-``TPUFT_SHARDED_FETCH=1`` additionally shards the flat bucket across the
-local devices: each shard slice is fetched straight off its device (no XLA
-gather into a replicated host copy — on a multi-host group each host pulls
-only its ``addressable_shards``), ring-reduced as its own tagged op (the
+already-wire-dtype payloads as pre-encoded).  ``sharded_fetch=True``
+additionally shards the flat bucket across the local devices: each shard
+slice is fetched straight off its device (no XLA gather into a replicated
+host copy — on a multi-host group each host pulls only its
+``addressable_shards``), ring-reduced as its own tagged op (the
 cross-group allreduce becomes per-slice reduce-scatter + allgather aligned
 with the in-group sharding, ZeRO-style), and scattered back per-shard with
 ``jax.device_put`` under the leaf's original sharding.
 
 The per-bucket D2H wait runs in an ``allreduce_d2h`` span (``pos``: its
-place in the fetch order, ``inflight``: hinted copies beyond it when it
-began), each harvest of resolved buckets in a short ``allreduce_h2d`` span
-between two fetches (so a step holds several), the waits for what is still on
-the ring after the last fetch in ``allreduce_merge``, and the one wait for
-every put in a last ``allreduce_h2d`` (all FT time, never charged as
+place in the fetch order), each harvest of resolved buckets in a short
+``allreduce_h2d`` span between two fetches (so a step holds several), the
+waits for what is still on the ring after the last fetch in
+``allreduce_merge``, and the one wait for every put in a last
+``allreduce_h2d`` (all FT time, never charged as
 productive compute — obs/report.py and the straggler sentinel depend on
 that).  ``step_summary.exchange_stream`` says how far the stream engaged.
 
@@ -80,7 +79,6 @@ __all__ = [
 ]
 
 TPUFT_DEVICE_WIRE_PREP_ENV = "TPUFT_DEVICE_WIRE_PREP"
-TPUFT_SHARDED_FETCH_ENV = "TPUFT_SHARDED_FETCH"
 
 # Elastic batch engine (docs/architecture.md "Elastic scale").  The fleet's
 # samples-per-step is the training contract (LR schedule, convergence
@@ -90,11 +88,7 @@ TPUFT_SHARDED_FETCH_ENV = "TPUFT_SHARDED_FETCH"
 # committed step record stays pinned.  Enabled by setting
 # TPUFT_ELASTIC_GLOBAL_BATCH; the Manager rebuilds the plan on every
 # quorum transition and hands it to membership callbacks.
-TPUFT_ELASTIC_ENV = "TPUFT_ELASTIC"
 TPUFT_ELASTIC_GLOBAL_BATCH_ENV = "TPUFT_ELASTIC_GLOBAL_BATCH"
-TPUFT_ELASTIC_MICROBATCH_ENV = "TPUFT_ELASTIC_MICROBATCH"
-TPUFT_ELASTIC_SCALE_LR_ENV = "TPUFT_ELASTIC_SCALE_LR"
-TPUFT_ELASTIC_BASE_PARTICIPANTS_ENV = "TPUFT_ELASTIC_BASE_PARTICIPANTS"
 
 
 def _env_flag(name: str, default: bool = False) -> bool:
@@ -123,8 +117,7 @@ class ElasticBatchScaler:
     ``scale_lr="linear"``/``"sqrt"`` support the other elastic policy —
     per-group batch held fixed, global batch breathing with membership —
     where ``lr_scale`` follows participants relative to
-    ``base_participants`` (first membership seen, unless pinned by arg or
-    ``TPUFT_ELASTIC_BASE_PARTICIPANTS``).
+    ``base_participants`` (first membership seen, unless pinned by arg).
     """
 
     def __init__(
@@ -151,32 +144,19 @@ class ElasticBatchScaler:
 
     @classmethod
     def from_env(cls) -> Optional["ElasticBatchScaler"]:
-        """The env-configured scaler, or None when elastic batching is off
-        (no TPUFT_ELASTIC_GLOBAL_BATCH, or TPUFT_ELASTIC=0)."""
+        """The scaler for TPUFT_ELASTIC_GLOBAL_BATCH with the class's own
+        defaults, or None when elastic batching is off (the variable unset,
+        malformed or not positive)."""
         raw = os.environ.get(TPUFT_ELASTIC_GLOBAL_BATCH_ENV)
-        if not raw or not _env_flag(TPUFT_ELASTIC_ENV, True):
+        if not raw:
             return None
         try:
             global_batch = int(raw)
-            microbatch = int(
-                os.environ.get(TPUFT_ELASTIC_MICROBATCH_ENV) or "1"
-            )
-            base = int(
-                os.environ.get(TPUFT_ELASTIC_BASE_PARTICIPANTS_ENV) or "0"
-            )
         except ValueError:
             return None
-        if global_batch <= 0 or microbatch <= 0:
+        if global_batch <= 0:
             return None
-        scale_lr = os.environ.get(TPUFT_ELASTIC_SCALE_LR_ENV, "none")
-        if scale_lr not in ("none", "linear", "sqrt"):
-            scale_lr = "none"
-        return cls(
-            global_batch,
-            microbatch=microbatch,
-            scale_lr=scale_lr,
-            base_participants=base or None,
-        )
+        return cls(global_batch)
 
     def plan(self, participants: int, rank: Optional[int] = None) -> Dict[str, Any]:
         """The batch plan for one membership: exact constant-global-batch
@@ -207,17 +187,6 @@ class ElasticBatchScaler:
             "accum_steps": accum_steps,
             "lr_scale": lr_scale,
         }
-
-
-# How many buckets beyond the one being fetched have their device->host copy
-# started (``copy_to_host_async``) in the streamed exchange.  Chosen on the
-# chip (four v5e groups on one host, 2.52 GB of f32 gradients a group; PERF.md
-# section 6, PR 28): a started copy runs side by side with the one being
-# waited for and the two together are SLOWER — twelve arrays of the cell's
-# sizes fetched at 0.42 GB/s a process with no hint, 0.29 / 0.28 with a window
-# of 1 / 2, 0.29 with every array hinted up front (tools/d2h_probe.py), and in
-# the cell a merged step of 7.7–8.2 s at 0, 11.1–11.3 at 1, 11.2–11.4 at 2.
-_FETCH_WINDOW = 0
 
 
 class _Unresolved:
@@ -554,9 +523,9 @@ class GradientAverager:
     ``device_wire_prep`` (default: ``TPUFT_DEVICE_WIRE_PREP``) moves the
     cast to the collective's wire dtype onto the device as a jitted
     per-bucket epilogue, halving ``allreduce_d2h`` bytes for f32 gradients
-    when the collective wires bf16; ``sharded_fetch`` (default:
-    ``TPUFT_SHARDED_FETCH``) additionally fetches and ring-reduces each
-    bucket per local-device shard slice (see the module docstring).  Both
+    when the collective wires bf16; ``sharded_fetch`` additionally fetches
+    and ring-reduces each bucket per local-device shard slice (see the
+    module docstring).  Both
     apply to the streamed path only — the monolithic path stays the
     untouched host-cast reference for A/B.  Submission order of the ring
     ops (the plan's fetch order, a function of the tree's signature alone;
@@ -576,15 +545,13 @@ class GradientAverager:
         bucket_bytes: int = 25 << 20,
         pipelined: bool = True,
         device_wire_prep: Optional[bool] = None,
-        sharded_fetch: Optional[bool] = None,
+        sharded_fetch: bool = False,
     ) -> None:
         self._manager = manager
         self._bucket_bytes = bucket_bytes
         self._pipelined = pipelined
         if device_wire_prep is None:
             device_wire_prep = _env_flag(TPUFT_DEVICE_WIRE_PREP_ENV)
-        if sharded_fetch is None:
-            sharded_fetch = _env_flag(TPUFT_SHARDED_FETCH_ENV)
         self._device_wire_prep = bool(device_wire_prep)
         self._sharded_fetch = bool(sharded_fetch)
         self._wire_np: Any = _UNRESOLVED
@@ -905,7 +872,7 @@ class GradientAverager:
         # Dispatch EVERY single-device epilogue before the first blocking
         # fetch — jit dispatch is async, so a later bucket's cast runs on
         # device under an earlier bucket's D2H wait (device programs, not
-        # transfers: the window below does not bound them).  Multi-device
+        # transfers).  Multi-device
         # (sharded) programs stay lazy: they serialize behind
         # _SHARDED_EXEC_LOCK with a blocking wait anyway.
         flat_devs: Dict[int, Any] = {
@@ -914,32 +881,13 @@ class GradientAverager:
             if dev is not None and not dev.multi_device
         }
 
-        def hint(k: int) -> bool:
-            """Starts the device->host copy of bucket k's leaves (a no-op off
-            accelerator); False where there was nothing to start.  Device-
-            prepped buckets fetch the jitted epilogue's output, not the raw
-            leaves — hinting those would stage the full-width copy the
-            epilogue exists to avoid."""
-            if plan.device[k] is not None:
-                return False
-            started = False
-            for i in plan.buckets[k].indices:
-                copy_async = getattr(leaves[i], "copy_to_host_async", None)
-                if copy_async is not None:
-                    try:
-                        copy_async()
-                        started = True
-                    except Exception:  # noqa: BLE001 — a hint, never load-bearing
-                        pass
-            return started
-
-        def fetch(k: int, pairs: list, nbytes: int, **where: int) -> bool:
+        def fetch(k: int, pairs: list, nbytes: int, pos: int) -> bool:
             """One blocking, deadline-guarded fetch into a persistent buffer:
             wedged device work latches an error instead of hanging the step
             (stream_timeout analogue).  Spanned as allreduce_d2h — this wait
             blocks the train thread and must be attributed as FT time, not
             productive compute."""
-            with spans.span("allreduce_d2h", step=step, bytes=nbytes, bucket=k, **where):
+            with spans.span("allreduce_d2h", step=step, bytes=nbytes, bucket=k, pos=pos):
                 try:
                     device_get_into(
                         pairs,
@@ -954,14 +902,14 @@ class GradientAverager:
             self._note("d2h", nbytes)
             return True
 
-        def fetch_and_issue(k: int, **where: int) -> Optional[Tuple[int, str, Any]]:
+        def fetch_and_issue(k: int, pos: int) -> Optional[Tuple[int, str, Any]]:
             """Bucket k off the device and onto the ring: (k, "host" or
             "device", future) or (k, "sharded", [(view, future)]); None where
             a fetch timed out."""
             bucket, dev = plan.buckets[k], plan.device[k]
             if dev is None:
                 pairs = [(leaves[i], view) for i, view in plan.views[k]]
-                if not fetch(k, pairs, bucket.nbytes, **where):
+                if not fetch(k, pairs, bucket.nbytes, pos):
                     return None
                 stats["wire_bytes"] += wire_nbytes(bucket)
                 return k, "host", self._issue(bucket, plan.buffers[k], k)
@@ -974,7 +922,7 @@ class GradientAverager:
             dev.last_sharding = getattr(flat_dev, "sharding", None)
             parts = _shard_slices(flat_dev) if self._sharded_fetch else None
             if parts is None:
-                if not fetch(k, [(flat_dev, dev.buffer)], dev.buffer.nbytes, **where):
+                if not fetch(k, [(flat_dev, dev.buffer)], dev.buffer.nbytes, pos):
                     return None
                 stats["wire_bytes"] += wire_nbytes(bucket)
                 return k, "device", self._manager.allreduce(
@@ -988,7 +936,7 @@ class GradientAverager:
             slice_futs = []
             for shard, start, stop in parts:
                 view = dev.buffer[start:stop]
-                if not fetch(k, [(shard.data, view)], view.nbytes, **where):
+                if not fetch(k, [(shard.data, view)], view.nbytes, pos):
                     return None
                 slice_futs.append(
                     (view, self._manager.allreduce(view, donate=True, bucket=k))
@@ -1093,22 +1041,9 @@ class GradientAverager:
             h2d_bytes += put
             return len(ready)
 
-        # The window.  Before the blocking fetch at position ``pos`` only the
-        # next _FETCH_WINDOW buckets have their copy started — never the
-        # whole tree: the runtime runs hinted transfers side by side, so with
-        # every leaf hinted the FIRST fetch returned when ALL had landed and
-        # ring and way back could only start after the whole copy.  (A fetch
-        # starts its own copy, so position 0 needs no hint, and with a window
-        # of 0 none is made at all.)
-        taken = [False] * len(order)
-        hinted = 1
         early_puts = 0
         for pos, k in enumerate(order):
-            hinted = max(hinted, pos + 1)
-            while hinted < min(pos + 1 + _FETCH_WINDOW, len(order)):
-                taken[hinted] = hint(order[hinted])
-                hinted += 1
-            entry = fetch_and_issue(k, pos=pos, inflight=sum(taken[pos + 1 : hinted]))
+            entry = fetch_and_issue(k, pos=pos)
             if entry is None:
                 return grads
             # Bucket k hits the wire here while the buckets after it are

@@ -28,7 +28,6 @@ drives the path end to end.
 
 from torchft_tpu.drain.watcher import (
     DRAIN_DIR_ENV,
-    DRAIN_GRACE_ENV,
     GCE_METADATA_URL_ENV,
     GCE_POLL_ENV,
     DrainNotice,
@@ -37,7 +36,6 @@ from torchft_tpu.drain.watcher import (
 
 __all__ = [
     "DRAIN_DIR_ENV",
-    "DRAIN_GRACE_ENV",
     "GCE_METADATA_URL_ENV",
     "GCE_POLL_ENV",
     "DrainNotice",

@@ -37,7 +37,6 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "DRAIN_DIR_ENV",
-    "DRAIN_GRACE_ENV",
     "GCE_METADATA_URL_ENV",
     "GCE_POLL_ENV",
     "DrainNotice",
@@ -47,15 +46,11 @@ __all__ = [
 # Directory the supervisor and the CLI write per-group notice files into
 # (file name: drain_<REPLICA_GROUP_ID>.json).
 DRAIN_DIR_ENV = "TPUFT_DRAIN_DIR"
-# Default grace period in seconds for sources that carry no deadline of
-# their own (SIGTERM, bare trigger calls).  30 s = the GCE spot notice.
-DRAIN_GRACE_ENV = "TPUFT_DRAIN_GRACE_S"
 # Override of the GCE metadata base URL (tests point this at a local stub).
 GCE_METADATA_URL_ENV = "TPUFT_GCE_METADATA_URL"
 # Opt-in for polling the real metadata server.
 GCE_POLL_ENV = "TPUFT_GCE_DRAIN_POLL"
 
-_DEFAULT_GRACE_S = 30.0
 _GCE_DEFAULT_URL = "http://metadata.google.internal/computeMetadata/v1/instance"
 
 
@@ -84,8 +79,9 @@ class DrainWatcher:
         group_id: replica group id used to derive the notice-file name;
             defaults to ``REPLICA_GROUP_ID`` (resolved at ``start()``, i.e.
             after hot-spare adoption has pinned the id).
-        grace_s: deadline for sources without one (default: 30 s or
-            ``TPUFT_DRAIN_GRACE_S``).
+        grace_s: grace period in seconds for sources that carry no
+            deadline of their own (SIGTERM, bare trigger calls).  30 s =
+            the GCE spot notice.
         sigterm: install the SIGTERM hook (main thread only; silently
             skipped elsewhere).
         drain_dir: notice-file directory (default: ``TPUFT_DRAIN_DIR``;
@@ -101,7 +97,7 @@ class DrainWatcher:
         on_notice: Optional[Callable[[DrainNotice], None]] = None,
         *,
         group_id: Optional[str] = None,
-        grace_s: Optional[float] = None,
+        grace_s: float = 30.0,
         sigterm: bool = True,
         drain_dir: Optional[str] = None,
         gce_url: Optional[str] = None,
@@ -109,11 +105,6 @@ class DrainWatcher:
     ) -> None:
         self._on_notice = on_notice
         self._group_id = group_id
-        if grace_s is None:
-            try:
-                grace_s = float(os.environ.get(DRAIN_GRACE_ENV, _DEFAULT_GRACE_S))
-            except ValueError:
-                grace_s = _DEFAULT_GRACE_S
         self._grace_s = grace_s
         self._sigterm = sigterm
         self._drain_dir = drain_dir if drain_dir is not None else os.environ.get(

@@ -180,8 +180,8 @@ def encode_stream(
 
 def _stream_digest(meta: StateDictMeta, buffers: Sequence[np.ndarray], prefix: bytes) -> int:
     """Content fingerprint of the canonical stream.  When the header
-    already embeds per-buffer CRCs (the transport's default), hashing the
-    prefix alone is content-binding; with TPUFT_HTTP_CRC=0 the prefix is
+    already embeds per-buffer CRCs (the transport always stamps them),
+    hashing the prefix alone is content-binding; a header without them is
     only structural, so the buffers are checksummed here — otherwise two
     divergent same-shape encoders would collide and reconstruction could
     silently combine their shards into garbage."""
@@ -334,13 +334,10 @@ def write_shard_part(shard: Shard, part: int, n: int) -> bytes:
     """Header + one payload byte range — the ``?part=<i>&n=<N>`` response
     body of ``/ec/shard/<step>/<idx>``.  Boundaries are ``i * L // N`` over
     the PAYLOAD (header lengths vary with pickled int widths, so frame
-    offsets would not align across shard indices — payload offsets do,
-    which is what lets the subset-rotation fetch decode each range with a
-    different k-subset of shards).  Every part carries the full
-    self-describing header (tiny next to the payload) so any part alone
-    identifies generation and geometry; there is no per-part CRC —
-    reassemblies verify the whole-payload CRC (single-shard range fetch)
-    or the decoded stream's per-buffer CRCs (subset-rotation fetch)."""
+    offsets would not align across shard indices — payload offsets do).
+    Every part carries the full self-describing header (tiny next to the
+    payload) so any part alone identifies generation and geometry; there
+    is no per-part CRC — the reassembly verifies the whole-payload CRC."""
     header = pickle.dumps(shard.header())
     pl = as_u8(shard.payload)
     lo, hi = part * len(pl) // n, (part + 1) * len(pl) // n

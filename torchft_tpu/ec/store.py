@@ -59,7 +59,6 @@ __all__ = [
 # Environment knobs (docs/api.md "Erasure-coded peer state").
 TPUFT_EC_K_ENV = "TPUFT_EC_K"
 TPUFT_EC_M_ENV = "TPUFT_EC_M"
-TPUFT_EC_RETAIN_ENV = "TPUFT_EC_RETAIN"
 TPUFT_EC_MODE_ENV = "TPUFT_EC_MODE"
 TPUFT_EC_INTERVAL_ENV = "TPUFT_EC_INTERVAL"
 
@@ -119,7 +118,6 @@ class ECConfig:
         return cls(
             k=_env_int(TPUFT_EC_K_ENV, 0),
             m=_env_int(TPUFT_EC_M_ENV, 2),
-            retain=_env_int(TPUFT_EC_RETAIN_ENV, 2),
             mode=os.environ.get(TPUFT_EC_MODE_ENV, "fallback") or "fallback",
             interval=_env_int(TPUFT_EC_INTERVAL_ENV, 1),
         )
@@ -212,33 +210,13 @@ def fetch_shard(base_url: str, step: int, idx: int, timeout: float) -> Shard:
         return read_shard(resp.read())
 
 
-# Range-striped shard fetch parallelism: parts per shard (0 = auto-size at
-# ~one part per MB of shard frame, capped).  The same receiver-chooses
+# Range-striped shard fetch parallelism: parts per shard, auto-sized at
+# ~one part per MB of shard frame, capped.  The same receiver-chooses
 # contract as the checkpoint path's chunk striping.
-TPUFT_EC_FETCH_PARTS_ENV = "TPUFT_EC_FETCH_PARTS"
 _MAX_FETCH_PARTS = 8
-
-# Subset-rotation striping (decode each payload range from its own
-# k-subset so every reachable holder's link serves, parity included).
-# Opt-in: the (k+m)/k fan-out wins only when holder LINKS bind; on a
-# CPU-bound host the per-range GF math for parity rows costs more than the
-# idle links were worth (measured ~25% slower on the 1-core bench host),
-# so operators enable it where reconstruction is genuinely link-bound.
-TPUFT_EC_SUBSET_STRIPE_ENV = "TPUFT_EC_SUBSET_STRIPE"
-
-
-def _subset_stripe_enabled() -> bool:
-    return os.environ.get(TPUFT_EC_SUBSET_STRIPE_ENV, "0") in ("1", "true", "on")
 
 
 def _fetch_parts_for(est_bytes: int) -> int:
-    raw = os.environ.get(TPUFT_EC_FETCH_PARTS_ENV, "0")
-    try:
-        parts = int(raw)
-    except ValueError:
-        parts = 0
-    if parts > 0:
-        return min(parts, _MAX_FETCH_PARTS)
     return max(1, min(_MAX_FETCH_PARTS, est_bytes // (1 << 20)))
 
 
@@ -299,64 +277,6 @@ def fetch_shard_striped(
         f"ec shard {idx} (step {step}, striped reassembly)",
     )
     return whole
-
-
-def _reconstruct_subset_striped(
-    usable: Dict[int, List[str]],
-    k: int,
-    m: int,
-    step: int,
-    deadline: float,
-    stats: dict,
-):
-    """Subset-rotation striped reconstruction: with ``h > k`` distinct
-    reachable shard indices, the payload splits into ``h`` byte ranges and
-    each range decodes from its OWN k-subset (Reed-Solomon is positionwise,
-    so per-range decodes concatenate into the whole-stream decode).  The
-    rotation excludes each index from exactly ``h - k`` ranges, so every
-    holder link serves ``k/h`` of a shard instead of one idle-parity setup
-    serving nothing — in the link-bound regime that is the (k+m)/k fan-out
-    the striped donor fetch gets from extra donors, applied to the shard
-    plane.  Integrity: no whole-shard CRC can apply to ranges; the decoded
-    stream's per-buffer CRCs (read_state_dict) verify end to end instead.
-    Raises on any failure — the caller falls back to whole-shard pulls."""
-    from torchft_tpu.checkpointing.serialization import read_state_dict
-    from torchft_tpu.ec.encoder import _SliceStream, decode_data_slices
-
-    idxs = sorted(usable)
-    h = len(idxs)
-    grid = [
-        (r, idx)
-        for r in range(h)
-        for j, idx in enumerate(idxs)
-        # Range r excludes the h - k indices rotating from position r.
-        if not any((r + t) % h == j for t in range(h - k))
-    ]
-
-    def pull_part(job):
-        r, idx = job
-        url = usable[idx][r % len(usable[idx])]
-        return fetch_shard_part(
-            url, step, idx, r, h, max(1.0, deadline - time.monotonic())
-        )
-
-    with ThreadPoolExecutor(max_workers=min(16, len(grid))) as pool:
-        parts = list(pool.map(pull_part, grid))
-    total_len = parts[0].total_len
-    digest = parts[0].digest
-    by_range: Dict[int, Dict[int, np.ndarray]] = {}
-    for (r, idx), p in zip(grid, parts):
-        if (p.digest, p.k, p.m, p.total_len) != (digest, k, m, total_len):
-            raise IOError(
-                f"ec shard {idx} range {r}: generation/geometry mismatch"
-            )
-        by_range.setdefault(r, {})[idx] = np.asarray(p.payload, dtype=np.uint8)
-    per_range = [decode_data_slices(by_range[r], k, m) for r in range(h)]
-    slices = [
-        np.concatenate([per_range[r][j] for r in range(h)]) for j in range(k)
-    ]
-    stats["subset_striped"] = {"ranges": h, "indices": idxs[: h]}
-    return read_state_dict(_SliceStream(slices, total_len))
 
 
 def fetch_inventory(base_url: str, step: int, timeout: float) -> dict:
@@ -441,29 +361,6 @@ def reconstruct(
             # Shard frame size estimate for the range-striping auto-sizer:
             # total_len / k data bytes plus a small header.
             est_shard_bytes = geo[2] // max(1, k)
-            # Subset-rotation striping (opt-in, link-bound deployments):
-            # more reachable indices than k means idle holder links under
-            # whole-shard pulls; per-range k-subset decode spreads the SAME
-            # k shards' worth of bytes over all of them.  Any failure falls
-            # back to the whole-shard path below.
-            if (
-                len(usable) > k
-                and _subset_stripe_enabled()
-                and _fetch_parts_for(est_shard_bytes) > 1
-            ):
-                try:
-                    meta, buffers = _reconstruct_subset_striped(
-                        usable, k, geo[1], step, deadline, stats
-                    )
-                    idxs = sorted(usable)
-                    stats["shards_used"] = idxs
-                    stats["parity_used"] = sum(1 for i in idxs if i >= k)
-                    return meta, buffers, stats
-                except Exception as e:  # noqa: BLE001 — degrade, don't fail
-                    stats["fetch_errors"] += 1
-                    stats.pop("subset_striped", None)
-                    last_err = e
-
             chosen = sorted(usable)[:k]  # lowest-first: data shards decode by concat
 
             def pull(idx: int):

@@ -219,8 +219,8 @@ class Launcher:
         cwd: Optional[str] = None,
         spares: int = 0,
         straggler_auto_drain: Optional[bool] = None,
-        incident_watcher: Optional[bool] = None,
-        watcher_act: Optional[bool] = None,
+        incident_watcher: bool = False,
+        watcher_act: bool = False,
     ) -> None:
         self._cmd = list(cmd)
         self._num_groups = num_groups
@@ -252,12 +252,8 @@ class Launcher:
         self._handled_alerts: set = set()
         # IncidentWatcher (docs/observability.md "IncidentWatcher"): polls
         # the incident feed, captures bundles, journals flap-guarded
-        # remediation recommendations.  Dry-run unless watcher_act
-        # (TPUFT_WATCHER_ACT=1), which gates the cooperative-drain action.
-        if incident_watcher is None:
-            incident_watcher = os.environ.get("TPUFT_INCIDENT_WATCHER", "") == "1"
-        if watcher_act is None:
-            watcher_act = os.environ.get("TPUFT_WATCHER_ACT", "") == "1"
+        # remediation recommendations.  Dry-run unless watcher_act, which
+        # gates the cooperative-drain action.
         self._incident_watcher_enabled = incident_watcher
         self._watcher_act = watcher_act
         self._watcher = None  # built lazily on the first supervise pass
@@ -997,13 +993,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         help="run the IncidentWatcher against the embedded lighthouse: "
         "auto-capture incident bundles + journal flap-guarded remediation "
         "recommendations (watcher_journal.jsonl in the log dir); dry-run "
-        "unless --watcher-act (also TPUFT_INCIDENT_WATCHER=1)",
+        "unless --watcher-act",
     )
     parser.add_argument(
         "--watcher-act", action="store_true",
         help="let the IncidentWatcher execute its one actionable policy "
-        "(cooperative drain); all other recommendations stay dry-run "
-        "(also TPUFT_WATCHER_ACT=1)",
+        "(cooperative drain); all other recommendations stay dry-run",
     )
     spec = parser.add_argument_group(
         "scheduler spec generation",
@@ -1066,8 +1061,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         join_timeout_ms=args.join_timeout_ms,
         log_dir=args.log_dir,
         spares=args.spares,
-        incident_watcher=args.incident_watcher or None,
-        watcher_act=args.watcher_act or None,
+        incident_watcher=args.incident_watcher,
+        watcher_act=args.watcher_act,
     )
     with launcher:
         print(

@@ -58,8 +58,8 @@ TPUFT_MANAGER_PORT_ENV: str = "TPUFT_MANAGER_PORT"
 # Cap on how many donors one healer stripes a fetch across.  More donors =
 # more aggregate bandwidth (each serves a disjoint byte range) but also more
 # connections per heal; 4 saturates typical host NICs long before the donor
-# pool does.  0 = no cap.
-TPUFT_MAX_HEAL_DONORS_ENV: str = "TPUFT_MAX_HEAL_DONORS"
+# pool does.
+_MAX_HEAL_DONORS = 4
 # Heal-retry pacing (docs/api.md): after a FAILED heal fetch the next
 # quorum's retry waits a decorrelated-jitter backoff (ha/backoff.py) so a
 # flapping donor — or a donor whose serving window is briefly busy — cannot
@@ -406,8 +406,7 @@ class Manager:
         # plane registers its tpuft_semisync_* render here).  Pull-based:
         # the provider snapshot runs at SCRAPE time, so training pays
         # nothing while nobody scrapes.  serve() is a no-op unless
-        # TPUFT_WORKER_METRICS_PORT (or the deprecated
-        # TPUFT_SEMISYNC_METRICS_PORT alias) is set.
+        # TPUFT_WORKER_METRICS_PORT is set.
         from torchft_tpu.obs.prom import WorkerMetrics
 
         self._worker_metrics = WorkerMetrics(
@@ -772,10 +771,8 @@ class Manager:
                     )
                     if a
                 ]
-                max_donors = _max_heal_donors()
-                if max_donors > 0:
-                    donor_ranks = donor_ranks[:max_donors]
-                    donor_addrs = donor_addrs[:max_donors]
+                donor_ranks = donor_ranks[:_MAX_HEAL_DONORS]
+                donor_addrs = donor_addrs[:_MAX_HEAL_DONORS]
                 if not self._checkpoint_transport.serves_all_donors:
                     # Point-to-point transports: only the PRIMARY donor is
                     # sending to us — failing over to another donor would
@@ -2220,16 +2217,6 @@ def _env_float(name: str, default: float) -> float:
             "ignoring malformed %s", name
         )
         return default
-
-
-def _max_heal_donors() -> int:
-    """Donor-count cap for one striped heal (``TPUFT_MAX_HEAL_DONORS``,
-    default 4, 0 = uncapped); malformed values fall back to the default —
-    a bad tuning knob must not abort recovery."""
-    try:
-        return int(os.environ.get(TPUFT_MAX_HEAL_DONORS_ENV, "4"))
-    except ValueError:
-        return 4
 
 
 def _may_average_in_place(out, host: np.ndarray, donate: bool) -> bool:

@@ -13,17 +13,13 @@ callback (the Manager's ``_worker_metrics_snapshot``) is invoked at SCRAPE
 time and returns the series list, so an unscraped endpoint costs the train
 loop nothing.  Subsystems with their own exposition (the semisync plane's
 ``tpuft_semisync_*``) register a render callable via :meth:`add_section`
-instead of opening a second port — the fold that retires the
-semisync-only exporter.
+instead of opening a second port.
 
-Ports: ``TPUFT_WORKER_METRICS_PORT`` (0 = ephemeral).  The pre-unification
-``TPUFT_SEMISYNC_METRICS_PORT`` is honored as a DEPRECATED alias (one
-warning per process) so existing deployments keep scraping.
+Ports: ``TPUFT_WORKER_METRICS_PORT`` (0 = ephemeral).
 """
 
 from __future__ import annotations
 
-import logging
 import os
 import threading
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -40,16 +36,10 @@ __all__ = [
 
 TPUFT_WORKER_METRICS_PORT_ENV = "TPUFT_WORKER_METRICS_PORT"
 TPUFT_WORKER_METRICS_BIND_ENV = "TPUFT_WORKER_METRICS_BIND"
-# Deprecated aliases (the semisync-only exporter this endpoint absorbed).
-_LEGACY_PORT_ENV = "TPUFT_SEMISYNC_METRICS_PORT"
-_LEGACY_BIND_ENV = "TPUFT_SEMISYNC_METRICS_BIND"
 
 # One series: (name, kind, help, labels, value).  ``labels`` is a list of
 # (key, value) pairs; the replica label is added by the renderer.
 Series = Tuple[str, str, str, Sequence[Tuple[str, str]], float]
-
-_alias_warned = False
-
 
 def _prom_escape(v: str) -> str:
     return v.replace("\\", "\\\\").replace('"', '\\"').replace("\n", "\\n")
@@ -198,31 +188,13 @@ class WorkerMetrics:
         self, port: Optional[int] = None, bind: Optional[str] = None
     ) -> Optional[int]:
         """Starts the daemon ``GET /metrics`` server.  ``port=None`` reads
-        ``TPUFT_WORKER_METRICS_PORT``, falling back to the deprecated
-        ``TPUFT_SEMISYNC_METRICS_PORT`` alias (unset/empty = disabled,
-        0 = ephemeral) — when the alias supplies the port, its companion
-        ``TPUFT_SEMISYNC_METRICS_BIND`` supplies the bind too, so an
-        existing non-loopback deployment keeps scraping.  ``bind``
-        defaults to loopback (``::1``) — the endpoint is unauthenticated,
-        so wider binds are an explicit operator choice.  Returns the
-        bound port, or None when disabled.  Never raises."""
-        global _alias_warned
-        legacy = False
+        ``TPUFT_WORKER_METRICS_PORT`` (unset/empty = disabled,
+        0 = ephemeral); ``bind=None`` reads ``TPUFT_WORKER_METRICS_BIND``
+        and defaults to loopback (``::1``) — the endpoint is
+        unauthenticated, so wider binds are an explicit operator choice.
+        Returns the bound port, or None when disabled.  Never raises."""
         if port is None:
             raw = os.environ.get(TPUFT_WORKER_METRICS_PORT_ENV, "")
-            if not raw.strip():
-                raw = os.environ.get(_LEGACY_PORT_ENV, "")
-                if raw.strip():
-                    legacy = True
-                    if not _alias_warned:
-                        _alias_warned = True
-                        logging.getLogger("torchft_tpu.obs.prom").warning(
-                            "%s is deprecated; the worker /metrics endpoint "
-                            "is unified — set %s instead (serving the "
-                            "unified exposition on the legacy port for now)",
-                            _LEGACY_PORT_ENV,
-                            TPUFT_WORKER_METRICS_PORT_ENV,
-                        )
             if not raw.strip():
                 return None
             try:
@@ -230,10 +202,7 @@ class WorkerMetrics:
             except ValueError:
                 return None
         if bind is None:
-            bind = os.environ.get(TPUFT_WORKER_METRICS_BIND_ENV, "").strip()
-            if not bind and legacy:
-                bind = os.environ.get(_LEGACY_BIND_ENV, "").strip()
-            bind = bind or "::1"
+            bind = os.environ.get(TPUFT_WORKER_METRICS_BIND_ENV, "").strip() or "::1"
         from torchft_tpu.http import serve_text_exposition
 
         server = serve_text_exposition(

@@ -58,7 +58,7 @@ __all__ = [
 # is FT-overhead time, NOT productive compute — report.py charges it to
 # the other-FT bucket and the straggler sentinel subtracts it from busy
 # time; fields: bucket, bytes, pos = the bucket's place in the plan's
-# fetch order, inflight = hinted copies beyond it when the fetch began);
+# fetch order);
 # allreduce_h2d = the matching result scatter-back (device_put of
 # reduced buckets onto the leaves' devices/shardings — with device wire
 # prep it moves wire-dtype bytes; charged exactly like allreduce_d2h so

@@ -23,7 +23,7 @@ Journal record::
      "bundle": "incident_<step>", "verdict": {...}}
 
 Flap guard: one journal entry per (policy, target) pair per
-``TPUFT_WATCHER_DEBOUNCE_S`` window (default 30 s) — a goodput_floor and
+``debounce_s`` window (default 30 s) — a goodput_floor and
 its slo_burn alert both naming the same victim within a window record
 ONE recommendation, and a flapping sentinel cannot journal-spam.
 
@@ -76,8 +76,7 @@ class IncidentWatcher:
             against the serving lighthouse).  Everything else is always
             dry-run.
         metrics_paths: span JSONL streams to tail into each bundle.
-        poll_interval_s / debounce_s: poll throttle and flap-guard window
-            (defaults from TPUFT_WATCHER_POLL_S / TPUFT_WATCHER_DEBOUNCE_S).
+        poll_interval_s / debounce_s: poll throttle and flap-guard window.
         drain_cb: ``fn(group) -> None`` used for --act drains (the
             launcher wires its own ``Launcher.drain``).
         fetch / clock: injectables for unit tests — ``fetch(address,
@@ -92,8 +91,8 @@ class IncidentWatcher:
         *,
         act: bool = False,
         metrics_paths: Sequence[str] = (),
-        poll_interval_s: Optional[float] = None,
-        debounce_s: Optional[float] = None,
+        poll_interval_s: float = 2.0,
+        debounce_s: float = 30.0,
         drain_cb: Optional[Callable[[str], None]] = None,
         fetch: Optional[Callable[[str, str], Optional[dict]]] = None,
         clock: Callable[[], float] = time.monotonic,
@@ -104,16 +103,8 @@ class IncidentWatcher:
         self.workdir = workdir
         self.act = act
         self.metrics_paths = list(metrics_paths)
-        self.poll_interval_s = (
-            poll_interval_s
-            if poll_interval_s is not None
-            else _env_float("TPUFT_WATCHER_POLL_S", 2.0)
-        )
-        self.debounce_s = (
-            debounce_s
-            if debounce_s is not None
-            else _env_float("TPUFT_WATCHER_DEBOUNCE_S", 30.0)
-        )
+        self.poll_interval_s = poll_interval_s
+        self.debounce_s = debounce_s
         self._drain_cb = drain_cb
         self._fetch = fetch or fetch_json
         self._clock = clock
@@ -234,14 +225,6 @@ class IncidentWatcher:
                 return 200 <= resp.status < 300
         except Exception:  # noqa: BLE001
             return False
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        v = float(os.environ.get(name, ""))
-        return v if v > 0 else default
-    except ValueError:
-        return default
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

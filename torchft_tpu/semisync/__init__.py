@@ -22,25 +22,15 @@ over this engine; see docs/architecture.md "Streaming semi-sync data
 plane".
 """
 
-from torchft_tpu.semisync.codec import (
-    CODECS,
-    TPUFT_SEMISYNC_CODEC_ENV,
-    FragmentCodec,
-    make_codec,
-)
-from torchft_tpu.semisync.diloco import StreamingDiLoCo, TPUFT_SEMISYNC_STREAM_ENV
+from torchft_tpu.semisync.codec import CODECS, FragmentCodec, make_codec
+from torchft_tpu.semisync.diloco import StreamingDiLoCo
 from torchft_tpu.semisync.engine import SyncEngine
 from torchft_tpu.semisync.fragments import (
     DEFAULT_FRAGMENT_BYTES,
-    TPUFT_SEMISYNC_FRAGMENT_BYTES_ENV,
     Fragment,
     FragmentPlan,
 )
-from torchft_tpu.semisync.metrics import (
-    TPUFT_SEMISYNC_METRICS_BIND_ENV,
-    TPUFT_SEMISYNC_METRICS_PORT_ENV,
-    SemiSyncMetrics,
-)
+from torchft_tpu.semisync.metrics import SemiSyncMetrics
 
 __all__ = [
     "StreamingDiLoCo",
@@ -52,9 +42,4 @@ __all__ = [
     "SemiSyncMetrics",
     "CODECS",
     "DEFAULT_FRAGMENT_BYTES",
-    "TPUFT_SEMISYNC_CODEC_ENV",
-    "TPUFT_SEMISYNC_FRAGMENT_BYTES_ENV",
-    "TPUFT_SEMISYNC_STREAM_ENV",
-    "TPUFT_SEMISYNC_METRICS_PORT_ENV",
-    "TPUFT_SEMISYNC_METRICS_BIND_ENV",
 ]

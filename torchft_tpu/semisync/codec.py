@@ -22,7 +22,7 @@ because error feedback turns per-round quantization error into a
 one-round delay instead of a loss; raw weights do NOT — LocalSGD's
 parameter averaging stays full-width, unchanged.
 
-``bf16`` / ``f32`` — the fallback knob (``TPUFT_SEMISYNC_CODEC``): bf16
+``bf16`` / ``f32`` — the fallback knob (``StreamingDiLoCo(codec=...)``): bf16
 casts the pseudogradient on device and wires bf16 (0.5x); f32 opts the
 sync out of every lossy encoding; ``auto`` defers to the collective's own
 wire policy (the legacy DiLoCo port's behavior — bf16 only when the link
@@ -43,12 +43,10 @@ from torchft_tpu.semisync.fragments import Fragment, pack_flat
 
 __all__ = [
     "CODECS",
-    "TPUFT_SEMISYNC_CODEC_ENV",
     "FragmentCodec",
     "make_codec",
 ]
 
-TPUFT_SEMISYNC_CODEC_ENV = "TPUFT_SEMISYNC_CODEC"
 CODECS = ("int8", "int4", "bf16", "f32", "auto")
 
 

@@ -30,67 +30,23 @@ arXiv:2311.08105; Streaming DiLoCo, arXiv:2501.18512):
 codec="auto"): the old API and blocking semantics, now running on this
 engine.
 
-Knobs (all overridable per-instance):
-  TPUFT_SEMISYNC_CODEC           int8 | int4 | bf16 | f32 | auto  (default int8)
-  TPUFT_SEMISYNC_FRAGMENT_BYTES  fragment size              (default 4 MiB)
-  TPUFT_SEMISYNC_STREAM          1 = background streaming   (default 1)
-  TPUFT_SEMISYNC_METRICS_PORT    serve tpuft_semisync_* /metrics (unset=off)
+The ``tpuft_semisync_*`` series are a section of the worker's ``/metrics``
+(``TPUFT_WORKER_METRICS_PORT``, obs/prom.py).
 """
 
 from __future__ import annotations
 
-import os
 from types import TracebackType
 from typing import Any, Callable, Dict, List, Optional, Type
 
 import numpy as np
 
-from torchft_tpu.ddp import _env_flag
-from torchft_tpu.semisync.codec import (
-    CODECS,
-    TPUFT_SEMISYNC_CODEC_ENV,
-    make_codec,
-)
+from torchft_tpu.semisync.codec import CODECS, make_codec
 from torchft_tpu.semisync.engine import SyncEngine
-from torchft_tpu.semisync.fragments import FragmentPlan
+from torchft_tpu.semisync.fragments import DEFAULT_FRAGMENT_BYTES, FragmentPlan
 from torchft_tpu.semisync.metrics import SemiSyncMetrics
 
-__all__ = [
-    "StreamingDiLoCo",
-    "TPUFT_SEMISYNC_STREAM_ENV",
-    "TPUFT_SEMISYNC_FRAGMENT_COMMIT_ENV",
-]
-
-TPUFT_SEMISYNC_STREAM_ENV = "TPUFT_SEMISYNC_STREAM"
-# Fragment-granular commit (default off): every fragment's pseudogradient
-# round runs under its OWN quorum + commit vote, so a membership change
-# (elastic resize, peer death) mid-round fails only the in-flight
-# fragment's vote — the fragments whose votes already passed keep their
-# outer steps.  The round-level default keeps one vote for the whole round
-# (cheapest; all-or-nothing on churn).
-TPUFT_SEMISYNC_FRAGMENT_COMMIT_ENV = "TPUFT_SEMISYNC_FRAGMENT_COMMIT"
-
-
-def _codec_from_env(explicit: Optional[str]) -> str:
-    if explicit is not None:
-        if explicit not in CODECS:
-            raise ValueError(
-                f"unknown semisync codec {explicit!r}; expected one of {CODECS}"
-            )
-        return explicit
-    raw = os.environ.get(TPUFT_SEMISYNC_CODEC_ENV, "").strip().lower()
-    if not raw:
-        return "int8"
-    if raw not in CODECS:
-        # Unlike a numeric tuning knob, a typo'd codec name must NOT fall
-        # back silently: the default is LOSSY, so "fp32" quietly becoming
-        # int8 would be the exact encoding the user tried to disable.
-        # Construction time, not step time — failing loud here is safe.
-        raise ValueError(
-            f"${TPUFT_SEMISYNC_CODEC_ENV}={raw!r} is not a semisync codec; "
-            f"expected one of {CODECS}"
-        )
-    return raw
+__all__ = ["StreamingDiLoCo"]
 
 
 class StreamingDiLoCo:
@@ -118,15 +74,15 @@ class StreamingDiLoCo:
         set_params: Callable[[Any], None],
         outer_tx: Any,
         sync_every: int,
-        fragment_bytes: Optional[int] = None,
-        codec: Optional[str] = None,
-        stream: Optional[bool] = None,
+        fragment_bytes: int = DEFAULT_FRAGMENT_BYTES,
+        codec: str = "int8",
+        stream: bool = True,
         outer_scope: str = "fragment",
         state_dict_key: str = "diloco",
         set_fragment_params: Optional[
             Callable[[List[int], List[np.ndarray]], None]
         ] = None,
-        fragment_commit: Optional[bool] = None,
+        fragment_commit: bool = False,
     ) -> None:
         """``outer_scope``: "fragment" (default) keeps one optax state per
         fragment and applies the outer update fragment-locally — the
@@ -148,10 +104,9 @@ class StreamingDiLoCo:
         whole-tree ``set_params`` — inner steps moved ALL leaves, and the
         backup they roll back to predates this round's fragments.
 
-        ``fragment_commit`` (env ``TPUFT_SEMISYNC_FRAGMENT_COMMIT``,
-        default off): fragment-granular fault containment for elastic
-        fleets.  Each fragment's pseudogradient round becomes its OWN
-        Manager step — quorum armed at the fragment's issue slot on the
+        ``fragment_commit`` (default off): fragment-granular fault
+        containment for elastic fleets.  Each fragment's pseudogradient
+        round becomes its OWN Manager step — quorum armed at the fragment's issue slot on the
         train thread (heals and elastic reconfiguration stay off the
         worker), the reduce overlaps inner steps as usual, and the vote +
         outer apply land at the NEXT fragment's slot.  A resize or peer
@@ -184,12 +139,15 @@ class StreamingDiLoCo:
         self._voted = False
         self._vote_passed = False
 
-        self._codec_name = _codec_from_env(codec)
-        self._stream = (
-            bool(stream)
-            if stream is not None
-            else _env_flag(TPUFT_SEMISYNC_STREAM_ENV, True)
-        )
+        # A typo'd codec name must NOT fall back silently: the default is
+        # LOSSY, so "fp32" quietly becoming int8 would be the exact encoding
+        # the user tried to disable.
+        if codec not in CODECS:
+            raise ValueError(
+                f"unknown semisync codec {codec!r}; expected one of {CODECS}"
+            )
+        self._codec_name = codec
+        self._stream = bool(stream)
 
         # Host backup of the last-synced params; the flat leaf list is the
         # canonical copy, the tree is derived.  The one jax import here is
@@ -228,11 +186,7 @@ class StreamingDiLoCo:
                 "whole-tree outer update has no per-fragment commit moment"
             )
         self._set_fragment_params = set_fragment_params
-        self._fragment_commit = (
-            bool(fragment_commit)
-            if fragment_commit is not None
-            else _env_flag(TPUFT_SEMISYNC_FRAGMENT_COMMIT_ENV, False)
-        )
+        self._fragment_commit = bool(fragment_commit)
         if self._fragment_commit and set_fragment_params is None:
             raise ValueError(
                 "fragment_commit requires set_fragment_params: a failed "
@@ -263,14 +217,10 @@ class StreamingDiLoCo:
         )
         # Unified worker exposition (obs/prom.py): when the Manager runs
         # the worker /metrics endpoint, the tpuft_semisync_* section folds
-        # into it instead of opening a second port; mocked/legacy managers
-        # fall back to the standalone exporter (the deprecated
-        # TPUFT_SEMISYNC_METRICS_PORT path).
+        # into it instead of opening a second port.
         worker_metrics = getattr(manager, "worker_metrics", None)
         if worker_metrics is not None and getattr(worker_metrics, "serving", False):
             worker_metrics.add_section(self.metrics.render_prometheus)
-        else:
-            self.metrics.serve()
         self._engine = SyncEngine(
             manager, self._codecs, stream=self._stream, metrics=self.metrics
         )
@@ -296,7 +246,6 @@ class StreamingDiLoCo:
         traceback: Optional[TracebackType],
     ) -> bool:
         self._engine.shutdown()
-        self.metrics.close()
         return False
 
     # -- introspection ------------------------------------------------------
@@ -788,13 +737,11 @@ class StreamingDiLoCo:
         manager = self._manager
         residual_l2 = 0.0
         # The residual norm costs a per-fragment device reduction; only
-        # pay it when somebody can actually read it (the JSONL stream or
-        # the Prometheus endpoint).
-        want_residual = self.metrics.serving
+        # pay it when the JSONL stream will carry it.
         try:
-            want_residual = want_residual or bool(manager.metrics.enabled)
+            want_residual = bool(manager.metrics.enabled)
         except Exception:  # noqa: BLE001 — mocked managers
-            pass
+            want_residual = False
         if want_residual:
             for c in self._codecs:
                 fn = getattr(c, "residual_l2", None)

@@ -18,7 +18,6 @@ grouping, oversized-leaf handling — and means a fix there fixes both.
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
@@ -29,7 +28,6 @@ __all__ = [
     "Fragment",
     "FragmentPlan",
     "pack_flat",
-    "TPUFT_SEMISYNC_FRAGMENT_BYTES_ENV",
     "DEFAULT_FRAGMENT_BYTES",
 ]
 
@@ -42,33 +40,12 @@ def pack_flat(arrs: Sequence[Any], dtype: Any) -> np.ndarray:
     flat = parts[0] if len(parts) == 1 else np.concatenate(parts)
     return flat.astype(np.dtype(dtype), copy=False)
 
-TPUFT_SEMISYNC_FRAGMENT_BYTES_ENV = "TPUFT_SEMISYNC_FRAGMENT_BYTES"
 # Default fragment size.  Smaller than DDP's 25 MB gradient buckets: a
 # fragment is the granularity of sync/compute overlap within one outer
 # round, and a round has only ``sync_every`` slots to hide fragments in —
 # 4 MB keeps several fragments per round for typical outer states while
 # staying large enough to amortize ring framing.
 DEFAULT_FRAGMENT_BYTES = 4 << 20
-
-
-def fragment_bytes_from_env(explicit: Any = None) -> int:
-    """Resolves the fragment size: explicit arg, else
-    ``TPUFT_SEMISYNC_FRAGMENT_BYTES``, else the default.  Malformed env
-    values fall back to the default — a bad tuning knob must not abort
-    training."""
-    if explicit is not None:
-        return max(1, int(explicit))
-    try:
-        return max(
-            1,
-            int(
-                os.environ.get(
-                    TPUFT_SEMISYNC_FRAGMENT_BYTES_ENV, str(DEFAULT_FRAGMENT_BYTES)
-                )
-            ),
-        )
-    except ValueError:
-        return DEFAULT_FRAGMENT_BYTES
 
 
 class Fragment:
@@ -119,9 +96,11 @@ class FragmentPlan:
     """
 
     def __init__(
-        self, metas: Sequence[Tuple[tuple, Any]], fragment_bytes: Any = None
+        self,
+        metas: Sequence[Tuple[tuple, Any]],
+        fragment_bytes: int = DEFAULT_FRAGMENT_BYTES,
     ) -> None:
-        self.fragment_bytes = fragment_bytes_from_env(fragment_bytes)
+        self.fragment_bytes = max(1, int(fragment_bytes))
         self.fragments = [
             Fragment(i, b)
             for i, b in enumerate(plan_buckets(metas, self.fragment_bytes))

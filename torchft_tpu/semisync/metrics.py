@@ -3,37 +3,23 @@
 The lighthouse's native ``GET /metrics`` covers the control plane; the
 semi-sync data plane is per-worker and Python-side, so it exposes its own
 gauges the same text-format way: a :class:`SemiSyncMetrics` accumulates
-counters from the engine, ``render_prometheus`` produces the exposition,
-and ``serve`` (opt-in: ``TPUFT_SEMISYNC_METRICS_PORT``) publishes it on a
-tiny stdlib HTTP endpoint at ``/metrics`` for the same scraper that
-already hits the lighthouse.
+counters from the engine and ``render_prometheus`` produces the
+exposition.
 
 Counters are monotonic since construction (restart = reset, standard
 Prometheus counter semantics); gauges are last-observation.
 
-DEPRECATED as a standalone endpoint: the worker-side exposition is unified
-on :class:`torchft_tpu.obs.prom.WorkerMetrics` (one ``/metrics`` per
-worker, ``TPUFT_WORKER_METRICS_PORT``), where the semisync engine now
-registers this exposition as a section when a Manager endpoint is
-serving.  ``TPUFT_SEMISYNC_METRICS_PORT`` keeps working as an alias for
-the unified endpoint's port (one deprecation warning per process), and
-:meth:`SemiSyncMetrics.serve` remains for manager-less embedders.
+It opens no port of its own: the worker-side exposition is
+:class:`torchft_tpu.obs.prom.WorkerMetrics` (one ``/metrics`` per worker,
+``TPUFT_WORKER_METRICS_PORT``), where the semisync engine registers
+``render_prometheus`` as a section when a Manager endpoint is serving.
 """
 
 from __future__ import annotations
 
-import os
 import threading
-from typing import Optional
 
-__all__ = [
-    "SemiSyncMetrics",
-    "TPUFT_SEMISYNC_METRICS_PORT_ENV",
-    "TPUFT_SEMISYNC_METRICS_BIND_ENV",
-]
-
-TPUFT_SEMISYNC_METRICS_PORT_ENV = "TPUFT_SEMISYNC_METRICS_PORT"
-TPUFT_SEMISYNC_METRICS_BIND_ENV = "TPUFT_SEMISYNC_METRICS_BIND"
+__all__ = ["SemiSyncMetrics"]
 
 
 class SemiSyncMetrics:
@@ -51,7 +37,6 @@ class SemiSyncMetrics:
         self.d2h_bytes_total = 0
         self.last_residual_l2 = 0.0
         self.last_round_overlap_ms = 0.0
-        self._server = None
 
     def observe_fragment(self, wire_bytes: int, d2h_bytes: int) -> None:
         with self._lock:
@@ -66,12 +51,6 @@ class SemiSyncMetrics:
                 self.commits_total += 1
             else:
                 self.aborts_total += 1
-
-    @property
-    def serving(self) -> bool:
-        """True while the HTTP exposition is up — consumers can use this
-        to skip gauge computations nobody will scrape."""
-        return self._server is not None
 
     def observe_residual(self, l2: float) -> None:
         with self._lock:
@@ -141,52 +120,3 @@ class SemiSyncMetrics:
                 self.last_round_overlap_ms,
             )
             return "\n".join(lines) + "\n"
-
-    # -- optional HTTP exposition -------------------------------------------
-
-    def serve(
-        self, port: Optional[int] = None, bind: Optional[str] = None
-    ) -> Optional[int]:
-        """Starts a daemon HTTP server answering ``GET /metrics`` with the
-        exposition.  ``port=None`` reads ``TPUFT_SEMISYNC_METRICS_PORT``
-        (unset/empty = disabled, 0 = ephemeral); ``bind=None`` reads
-        ``TPUFT_SEMISYNC_METRICS_BIND`` and defaults to loopback (``::1``
-        — the server is the repo-wide dual-stack v6 class) — the endpoint
-        is unauthenticated, so listening on every interface must be an
-        explicit operator choice (``::``), not the default.  Returns the
-        bound port, or None when disabled.  Never raises — metrics must
-        not be able to fail training."""
-        if port is None:
-            raw = os.environ.get(TPUFT_SEMISYNC_METRICS_PORT_ENV, "")
-            if not raw.strip():
-                return None
-            try:
-                port = int(raw)
-            except ValueError:
-                return None
-        if bind is None:
-            bind = os.environ.get(
-                TPUFT_SEMISYNC_METRICS_BIND_ENV, ""
-            ).strip() or "::1"
-        # The repo's one exposition scaffolding (torchft_tpu/http.py) —
-        # every Python-side metrics endpoint shares it, so v6 handling and
-        # accept-queue fixes apply uniformly.
-        from torchft_tpu.http import serve_text_exposition
-
-        server = serve_text_exposition(
-            self.render_prometheus, port, bind,
-            thread_name="tpuft_semisync_metrics",
-        )
-        if server is None:
-            return None
-        self._server = server
-        return server.server_address[1]
-
-    def close(self) -> None:
-        server, self._server = self._server, None
-        if server is not None:
-            try:
-                server.shutdown()
-                server.server_close()
-            except Exception:  # noqa: BLE001
-                pass
