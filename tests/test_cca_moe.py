@@ -457,4 +457,5 @@ def test_a_configuration_the_benchmark_had_keeps_its_tree(name) -> None:
     assert jax.tree.structure(ours) == jax.tree.structure(theirs) and "lm_head" in ours
     assert [l.shape for l in jax.tree.leaves(ours)] == [l.shape for l in jax.tree.leaves(theirs)]
     assert not (cfg.tied_head or cfg.scaled_merge or cfg.moe_skip or cfg.moe_router_state)
-    assert all(kind.mixer == "attention" for kind in cfg.layers)
+    # latent attention is a kind's since PR 48: the model's `mla_kv_rank` writes it into every layer's kind
+    assert all(kind.mixer == ("mla" if cfg.mla_kv_rank else "attention") for kind in cfg.layers)

@@ -277,7 +277,7 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
     assert len(ran) > 500 and set(ran) <= set(ops)
     booked = {name: opmap.booked(entry) for name, entry in ops.items()}
-    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
     assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
@@ -686,3 +686,74 @@ def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # the slab's width moves nothing (8,192 and 16,384 compile to the same byte).  The chip's allocator has 16.9e9
     # (PERF.md section 6, PR 46); by rows, blocks of 2,048 took 15.88e9 at PR 41
     assert resident <= 15.2e9, f"the step needs {resident} bytes with AdamW's moments"
+
+
+@pytest.mark.parametrize("direction", ["forward", "forward_with_states", "backward"])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, direction) -> None:
+    """`tpuft_kda_fwd` (with and without the chunks' states) and `tpuft_kda_bwd`
+    at the Kimi cell's shape: 32 heads x 16,384 positions x 128 in bfloat16, g
+    float32, chunks of 64 — the level masks and the stacked 0/1 sums resident in
+    VMEM, the [64, 64] products, the transposed-left products and the squarings
+    of the solve as Mosaic takes them."""
+    from torchft_tpu.ops import delta_attention as da
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    bh, seq, d = 32, 16_384, 128
+    rows = [sds((bh, seq, d), bf16)] * 3 + [sds((bh, seq, d), f32), sds((bh, seq), f32)]
+    if direction == "backward":
+        text = _compile(lambda *a: da._bwd_pallas(*a, da.CHUNK), *rows, sds((bh, seq // da.CHUNK, d, d), f32), sds((bh, seq, d), bf16))
+        assert _kernel_calls(text, "tpuft_kda_") == ["tpuft_kda_bwd"]
+    else:
+        text = _compile(lambda *a: da._fwd_pallas(*a, da.CHUNK, direction == "forward_with_states"), *rows)
+        assert _kernel_calls(text, "tpuft_kda_") == ["tpuft_kda_fwd"]
+        assert ("f32[32,256,128,128]" in text) == (direction == "forward_with_states")
+
+
+def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `kimi-linear-48b-a3b` configuration as
+    `benchmark/programs/kda_mla_moe_lm.py` hands it to `TrainStep`: the whole
+    gradient program at the published widths and 1 x 16,384 tokens — four Kimi
+    Delta Attention layers through `tpuft_kda_*`, the one latent layer through
+    `tpuft_fa_*` at 32 heads and 256 / 128 (192 padded), the 8 held experts of
+    each of four sparse layers through `tpuft_gmm_*`, the 20,480-row head
+    through `tpuft_ce_*` — with room for AdamW's moments beside it on a 16 GiB
+    chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("kimi-linear-48b-a3b"), bench.traffic("steady-1g-16k")
+    shapes = jax.eval_shape(lambda: bench.reference("kda_mla_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("kda_mla_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    # what `program.why` states: a KDA layer runs the forward kernel TWICE (the forward pass, and the backward's
+    # pass that makes the chunks' states again: its output is kept under remat, so no third run recomputes it)
+    # and the backward kernel once; the latent layer's attention output is kept too, one kernel each way
+    assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
+    assert sorted(_kernel_calls(text, "tpuft_kda_")) == ["tpuft_kda_bwd"] * 4 + ["tpuft_kda_fwd"] * 8
+    assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
+    # three projections a sparse layer: forward, recomputed, and the two gradients
+    gmm = _kernel_calls(text, "tpuft_gmm_")
+    assert sorted(gmm) == ["tpuft_gmm_dlhs"] * 12 + ["tpuft_gmm_drhs"] * 12 + ["tpuft_gmm_fwd"] * 24
+    assert "tpuft_ce_lse" in _kernel_calls(text, "tpuft_ce_") and "tpuft_ce_dlogits" in _kernel_calls(text, "tpuft_ce_")
+    # the chunks' states exist only inside a layer's backward pass: float32 [32, 256, 128, 128], 537 MB
+    assert "f32[32,256,128,128]" in text
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("kda_mla_moe_lm").total_params(config) == 602_449_792
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # 13,827,982,336 (temporaries 4,188,533,760; builder's compile, PR 48).  Without the two checkpoints inside
+    # `kda_mix` (`_kda_mixer`: each half keeps its inputs and nothing between) the same program compiles to
+    # 15,980,264,960: some twenty float32 [16,384, 4,096] arrays a layer are alive at once
+    assert resident <= 14.0e9, f"the step needs {resident} bytes with AdamW's moments"

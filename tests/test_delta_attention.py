@@ -1,0 +1,139 @@
+"""The gated delta rule's scan (``ops/delta_attention.py``) on the CPU at small
+sizes: the chunk form in XLA against the recurrence position by position —
+output and all five gradients, at chunk sizes that do and do not divide the
+sequence, at one chunk and at many, at decays down to g = -20 a position (no
+inf, no nan) and at g = 0 (the plain delta rule), at beta 0 (the state only
+decays) and 1 — and the kernels in ``interpret`` mode against the XLA form at a
+few tiles.  Float32 on both sides unless a case says bfloat16, so what differs
+is the order of sums."""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from torchft_tpu.ops import delta_attention as da  # noqa: E402
+
+NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
+# (positions, chunk, g's lowest value, how g is drawn, beta)
+CASES = {
+    "chunks_divide": (64, 16, 0.5, "uniform", "sigmoid"),
+    "chunks_do_not_divide": (70, 16, 0.5, "uniform", "sigmoid"),
+    "one_chunk": (16, 16, 0.5, "uniform", "sigmoid"),
+    "one_short_chunk": (9, 16, 1.0, "uniform", "sigmoid"),
+    "many_chunks_of_64": (192, 64, 2.0, "uniform", "sigmoid"),
+    "g_down_to_minus_20": (64, 16, 20.0, "uniform", "sigmoid"),
+    "g_minus_20_everywhere": (48, 16, 20.0, "constant", "sigmoid"),
+    "g_zero_the_plain_delta_rule": (64, 16, 0.0, "constant", "sigmoid"),
+    "beta_zero_the_state_only_decays": (64, 16, 0.5, "uniform", "zero"),
+    "beta_one": (64, 16, 0.5, "uniform", "one"),
+}
+
+
+def _inputs(seq, g_low, g_kind, beta_kind, heads=2, width=16, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (1, heads, seq, width))) * width ** -0.5
+    k = unit(jax.random.normal(ks[1], (1, heads, seq, width)))
+    v = jax.random.normal(ks[2], (1, heads, seq, width))
+    g = -g_low * (jax.random.uniform(ks[3], (1, heads, seq, width)) if g_kind == "uniform" else jnp.ones((1, heads, seq, width)))
+    beta = {"sigmoid": jax.nn.sigmoid(jax.random.normal(ks[4], (1, heads, seq))),
+            "zero": jnp.zeros((1, heads, seq)), "one": jnp.ones((1, heads, seq))}[beta_kind]
+    weight = jax.random.normal(ks[5], (1, heads, seq, width))
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), weight
+
+
+def _both(fn, args, weight):
+    """(o, dq, dk, dv, dg, dbeta) of `fn` under the loss sum(o * weight)."""
+    o = fn(*args)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight), argnums=range(5))(*args)
+    return (o,) + tuple(grads)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunked_and_loop(case):
+    seq, chunk, g_low, g_kind, beta_kind = CASES[case]
+    args, weight = _inputs(seq, g_low, g_kind, beta_kind)
+    got = _both(lambda *a: da.kda(*a, chunk=chunk), args, weight)
+    want = _both(lambda *a: da.kda_loop(*a)[0], args, weight)
+    return got, want
+
+
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_chunk_form_is_the_recurrence(case, name) -> None:
+    """5e-5 of the largest entry: float32 sums in another order (the chunk
+    form re-associates products of up to `chunk` decays and a triangular
+    solve by squarings); nothing else differs."""
+    got, want = _chunked_and_loop(case)
+    a, b = np.asarray(got[NAMES.index(name)], np.float64), np.asarray(want[NAMES.index(name)], np.float64)
+    assert np.all(np.isfinite(a)), "an exponent left its bounds"
+    scale = max(float(np.max(np.abs(b))), 1e-6)
+    assert float(np.max(np.abs(a - b))) <= 5e-5 * scale, (case, name, float(np.max(np.abs(a - b))), scale)
+    if case == "beta_zero_the_state_only_decays" and name == "o":
+        assert float(np.max(np.abs(b))) == 0.0  # nothing is ever written into the state
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted_and_xla(dtype_name):
+    dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[dtype_name]
+    args, weight = _inputs(128, 1.0, "uniform", "sigmoid", heads=2, width=128, seed=3, dtype=dtype)  # 2 heads x 2 chunks of 64
+    got = _both(lambda *a: da.kda(*a, interpret=True), args, weight)
+    want = _both(lambda *a: da.kda(*a), args, weight)
+    return got, want
+
+
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_the_kernels_in_interpret_mode_are_the_xla_form(dtype_name, name) -> None:
+    """The kernels run the XLA form's two chunk functions on their blocks, so
+    the two agree to the last bit or two of float32 (the carried state and the
+    chunks' order are the same); in bfloat16 the outputs are rounded alike."""
+    got, want = _interpreted_and_xla(dtype_name)
+    a, b = np.asarray(got[NAMES.index(name)], np.float64), np.asarray(want[NAMES.index(name)], np.float64)
+    assert a.shape == b.shape and np.all(np.isfinite(a))
+    assert float(np.max(np.abs(a - b))) <= (1e-5 if dtype_name == "float32" else 2e-2) * float(np.max(np.abs(b)))
+
+
+@pytest.mark.parametrize("chunk", [2, 16, 64])
+def test_the_levels_cover_each_pair_once_and_sum_only_what_lies_between(chunk) -> None:
+    """Every pair (r, i), i < r, belongs to exactly one level; a level's row
+    of sums adds g over (b, r] for a row above its boundary b and over (r, b]
+    for a row up to it — never over a range that would make an exponent
+    positive, and the two ranges of a pair join to (i, r]."""
+    c = da._constants(chunk)
+    n = da._levels(chunk)
+    masks = c["masks"].reshape(n, chunk, chunk)
+    assert np.array_equal(masks.sum(axis=0), np.tril(np.ones((chunk, chunk)), -1))
+    sums = c["sums"].reshape(n + 2, chunk, chunk)
+    assert np.array_equal(sums[0], np.tril(np.ones((chunk, chunk)))) and np.array_equal(sums[-1], np.triu(np.ones((chunk, chunk)), 1))
+    for level in range(n):
+        for r, i in zip(*np.nonzero(masks[level])):
+            between = np.zeros(chunk)
+            between[i + 1:r + 1] = 1
+            assert np.array_equal(sums[1 + level][r] + sums[1 + level][i], between)
+
+
+def test_the_benchmark_counts_the_chunk_the_program_runs() -> None:
+    from benchmark.spec import Benchmark
+
+    assert Benchmark(ROOT).flops("tpuft_kda").CHUNK == da.CHUNK == 64
+
+
+def test_a_sequence_is_padded_with_positions_that_write_nothing() -> None:
+    """A chunk that does not divide the sequence: the padded call's outputs up
+    to the sequence's end are the outputs of the longer sequence whose tail
+    writes nothing, and the gradients of what was cut away are not asked for."""
+    (q, k, v, g, beta), _ = _inputs(40, 0.5, "uniform", "sigmoid")
+    short = da.kda(q[:, :, :37], k[:, :, :37], v[:, :, :37], g[:, :, :37], beta[:, :, :37], chunk=8)
+    whole = da.kda(q, k, v, g, beta, chunk=8)
+    assert short.shape == (1, 2, 37, 16)
+    np.testing.assert_allclose(np.asarray(short), np.asarray(whole[:, :, :37]), rtol=1e-6, atol=1e-7)

@@ -583,18 +583,27 @@ def test_leading_dense_layers_are_runs_like_any_other(dense, scan_unroll, scans)
     assert _scans(cfg) == scans
 
 
-def test_a_choice_bias_goes_to_one_sparse_stack() -> None:
-    """`router_bias` has a row a sparse layer of ONE stack: a model of one
-    sparse kind takes it (rows in the stack's order, leading dense layers or
-    not), a pattern with two sparse stacks refuses it."""
+@pytest.mark.parametrize("scan_unroll", [1, 4], ids=["scan", "static_loop"])
+def test_a_choice_bias_goes_by_the_place_among_the_sparse_layers(scan_unroll) -> None:
+    """`router_bias` has a row a sparse layer, in the layers' order in the
+    model WHATEVER their stack (since PR 48; before it a pattern with two
+    sparse stacks refused a bias): a model of one sparse kind takes it in the
+    stack's order, leading dense layers or not, and TINY's three sparse
+    layers in two stacks (`near`, then two of `layers` after a dense one) each
+    take their own row."""
     batch = _batch(0, dict(vocab_size=128), seq_len=32)
-    one_sparse_stack = dataclasses.replace(TINY, pattern=TINY.pattern[1:] + (TINY.pattern[-1],), remat=False)
+    one_sparse_stack = dataclasses.replace(TINY, pattern=TINY.pattern[1:] + (TINY.pattern[-1],), remat=False,
+                                           scan_unroll=scan_unroll)
     params = init_params(jax.random.PRNGKey(1), one_sparse_stack)
     bias = jnp.zeros((3, 4), jnp.float32).at[:, 0].set(100.0)  # every token's first choice is expert 0
     _, counters = loss_and_counters(params, batch, one_sparse_stack, router_bias=bias)
     assert np.asarray(counters["moe_tokens_per_expert"])[:, 0].tolist() == [2 * 32] * 3
-    with pytest.raises(AssertionError, match="one stack"):
-        loss_and_counters(init_params(jax.random.PRNGKey(1), TINY), batch, TINY, router_bias=jnp.zeros((3, 4)))
+    two_sparse_stacks = dataclasses.replace(TINY, scan_unroll=scan_unroll)
+    bias = jnp.zeros((3, 4), jnp.float32).at[jnp.arange(3), jnp.arange(3)].set(100.0)  # sparse layer j's is expert j
+    _, counters = loss_and_counters(init_params(jax.random.PRNGKey(1), two_sparse_stacks), batch, two_sparse_stacks,
+                                    router_bias=bias)
+    per_expert = np.asarray(counters["moe_tokens_per_expert"])
+    assert [per_expert[j, j] for j in range(3)] == [2 * 32] * 3
 
 
 def test_the_stacks_draw_their_weights_as_they_did_before_the_pattern() -> None:

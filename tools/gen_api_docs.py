@@ -63,6 +63,7 @@ MODULES = [
     "torchft_tpu.ops.grouped_matmul",
     "torchft_tpu.ops.rmsnorm",
     "torchft_tpu.ops.sparse_attention",
+    "torchft_tpu.ops.delta_attention",
     "torchft_tpu.ops.ring_attention",
     "torchft_tpu.ops.ulysses",
     "torchft_tpu.coordination",

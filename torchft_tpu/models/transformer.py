@@ -56,6 +56,12 @@ class LayerKind:
     # channel, one a head over sequence and channels (both of kernel 2), the
     # mean of the un-convolved q and k added back, half of the KV heads'
     # values taken from the position before, and an L2 norm a head.
+    # "mla": latent attention (the model's `mla_*` widths; `_mla_qkv`) at this
+    # kind's heads, its `mla_rope_dim` columns rotated where `rotary_fraction`
+    # is not 0 and plain content where it is (NoPE).  "kda": Kimi Delta
+    # Attention (arXiv:2510.26692; `_kda_mixer`) — no softmax and no position
+    # term: a gated delta rule with a decay a channel over `n_heads` heads of
+    # `kda_head_dim`, under short causal convolutions and low-rank gates.
     mixer: str = "attention"
 
 
@@ -187,6 +193,11 @@ class TransformerConfig:
     scaled_merge: bool = False
     # The head is the embedding itself: no `lm_head` leaf, logits = h embed^T.
     tied_head: bool = False
+    # Kimi Delta Attention (LayerKind.mixer "kda"): a head's width, which is
+    # also the rank of the two low-rank gates, and the kernel of the depthwise
+    # causal convolutions on q, k and v.
+    kda_head_dim: int = 128
+    kda_conv: int = 4
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -216,10 +227,15 @@ class TransformerConfig:
             )
         if self.pattern:
             assert len(self.pattern) == self.n_layers and not self.moe_dense_layers, "one kind a layer"
-            assert self.attention == "flash" and not self.mla_kv_rank and not self.dsa_index_heads, (
-                "a pattern's kinds are plain heads on the flash backend"
+            assert self.attention == "flash" and not self.dsa_index_heads, "a pattern's kinds run the flash backend"
+            assert all(kind.mixer in ("attention", "cca", "mla", "kda") for kind in self.pattern)
+            assert bool(self.mla_kv_rank) == any(kind.mixer == "mla" for kind in self.pattern), (
+                "the latent widths are the model's, the layers that use them the pattern's"
             )
-            assert all(kind.mixer in ("attention", "cca") for kind in self.pattern)
+            if any(kind.mixer in ("mla", "kda") for kind in self.pattern):
+                assert not (self.qk_norm or self.qk_norm_per_head or self.attn_head_gate), (
+                    "latent and delta attention have no QK-norm and no head gate of the model's"
+                )
             if any(kind.mixer == "cca" for kind in self.pattern):
                 assert not (self.qk_norm or self.qk_norm_per_head or self.attn_head_gate), (
                     "compressed attention norms its own heads and has no gate"
@@ -237,7 +253,8 @@ class TransformerConfig:
         """Every layer's kind, first to last."""
         if self.pattern:
             return self.pattern
-        own = LayerKind("layers", self.moe_experts > 0, self.n_heads, self.rope_theta)
+        own = LayerKind("layers", self.moe_experts > 0, self.n_heads, self.rope_theta,
+                        mixer="mla" if self.mla_kv_rank else "attention")
         dense = dataclasses.replace(own, stack="dense_layers", sparse=False)
         return (dense,) * self.moe_dense_layers + (own,) * (self.n_layers - self.moe_dense_layers)
 
@@ -287,9 +304,16 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
-    if cfg.mla_kv_rank:
+    if kind.mixer == "mla":
         layer.update({"wkv_a": ("layers", "embed", None), "kv_norm": ("layers", None),
                       "wkv_b": ("layers", None, "heads")})
+    elif kind.mixer == "kda":
+        layer.update({"wk": ("layers", "embed", "heads"), "wv": ("layers", "embed", "heads")})
+        layer.update({name: ("layers", None, "heads") for name in ("kda_conv_q", "kda_conv_k", "kda_conv_v",
+                                                                    "kda_a_up", "kda_g_up")})
+        layer.update({"kda_a_down": ("layers", "embed", None), "kda_g_down": ("layers", "embed", None),
+                      "kda_beta": ("layers", "embed", None), "A_log": ("layers", None), "dt_bias": ("layers", "heads"),
+                      "kda_g_bias": ("layers", "heads"), "kda_norm": ("layers", None)})
     else:
         layer.update({"wk": ("layers", "embed", "kv_heads"), "wv": ("layers", "embed", "kv_heads")})
     if cfg.qk_norm:
@@ -353,7 +377,9 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
 
     ks = jax.random.split(key, 8)
     layers = {"attn_norm": jnp.ones((L, E), pd), "mlp_norm": jnp.ones((L, E), pd)}
-    if cfg.mla_kv_rank:
+    if kind.mixer == "kda":
+        layers.update(_init_kda(jax.random.fold_in(key, 5), cfg, L, H))
+    elif kind.mixer == "mla":
         R, Dq = cfg.mla_kv_rank, cfg.mla_nope_dim + cfg.mla_rope_dim
         layers.update(
             {
@@ -445,6 +471,31 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
             }
         )
     return layers
+
+
+def _init_kda(key: jax.Array, cfg: TransformerConfig, L: int, H: int) -> Dict[str, Any]:
+    """A stack of Kimi Delta Attention mixers: the published layer's
+    initialisation of the decay (A = log U(1, 16) a head, dt_bias the inverse
+    softplus of log-uniform steps in [0.001, 0.1] a channel), both float32."""
+    pd, E, D, T = cfg.param_dtype, cfg.d_model, cfg.kda_head_dim, cfg.kda_conv
+    keys = iter(jax.random.split(key, 14))
+
+    def normal(shape, fan_in):
+        return _norm_init(next(keys), (L,) + shape, fan_in, pd)
+
+    steps = jnp.exp(jax.random.uniform(next(keys), (L, H * D), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "wq": normal((E, H * D), E), "wk": normal((E, H * D), E), "wv": normal((E, H * D), E),
+        "wo": normal((H * D, E), H * D),
+        # [tap, channel]: the last tap the position itself
+        "kda_conv_q": normal((T, H * D), T), "kda_conv_k": normal((T, H * D), T), "kda_conv_v": normal((T, H * D), T),
+        "kda_a_down": normal((E, D), E), "kda_a_up": normal((D, H * D), D),
+        "A_log": jnp.log(jax.random.uniform(next(keys), (L, H), jnp.float32, 1.0, 16.0)),
+        "dt_bias": steps + jnp.log(-jnp.expm1(-steps)),  # the inverse of softplus
+        "kda_beta": normal((E, H), E),
+        "kda_g_down": normal((E, D), E), "kda_g_up": normal((D, H * D), D),
+        "kda_g_bias": jnp.zeros((L, H * D), pd), "kda_norm": jnp.ones((L, D), pd),
+    }
 
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -643,20 +694,25 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v, kind: LayerKind):
     return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window)
 
 
-def _mla_qkv(cfg: TransformerConfig, h, w, positions):
+def _mla_qkv(cfg: TransformerConfig, kind: LayerKind, h, w, positions):
     """Latent attention's q, k [B, S, H, nope + rope] and v [B, S, H, v]
     from the normed input h [B, S, E]: the keys' and values' content
-    through the low-rank path, one rotary key for all heads."""
+    through the low-rank path, one rotary key for all heads — or, where the
+    kind has no rotation (`rotary_fraction` 0), those columns as they are
+    projected: content like the others, the one key still every head's."""
     B, S, _ = h.shape
-    H, Dn, Dr, Dv, R = cfg.n_heads, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim, cfg.mla_kv_rank
+    H, Dn, Dr, Dv, R = kind.n_heads, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim, cfg.mla_kv_rank
     q = (h @ w["wq"].astype(cfg.dtype)).reshape(B, S, H, Dn + Dr)
     latent = h @ w["wkv_a"].astype(cfg.dtype)                       # [B, S, R + Dr]
     with jax.named_scope("norm"):
         kv = rms_norm(latent[..., :R], w["kv_norm"], cfg.rms_eps)
     kv = kv @ w["wkv_b"].astype(cfg.dtype)
     kv = kv.reshape(B, S, H, Dn + Dv)
-    q_rope = _rope(q[..., Dn:], positions, cfg.rope_theta)
-    k_rope = _rope(latent[..., None, R:], positions, cfg.rope_theta)  # [B, S, 1, Dr]
+    if not kind.rotary_fraction:
+        k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(latent[..., None, R:], (B, S, H, Dr))], axis=-1)
+        return q, k, kv[..., Dn:]
+    q_rope = _rope(q[..., Dn:], positions, kind.rope_theta)
+    k_rope = _rope(latent[..., None, R:], positions, kind.rope_theta)  # [B, S, 1, Dr]
     q = jnp.concatenate([q[..., :Dn], q_rope], axis=-1)
     k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(k_rope, (B, S, H, Dr))], axis=-1)
     return q, k, kv[..., Dn:]
@@ -752,6 +808,82 @@ def _cca_qkv(cfg: TransformerConfig, kind: LayerKind, h, w, positions):
     return q, k, v
 
 
+def _causal_conv(z, taps):
+    """A depthwise causal convolution over the sequence: z [B, S, C], taps
+    [T, C] float32 with the LAST tap the position's own, ``out_t = sum_i
+    taps[i] * z_{t - (T - 1) + i}``, zeros before the first position (`lax.pad`
+    with a negative edge; its transpose is the same move the other way)."""
+    n = taps.shape[0]
+    out = taps[n - 1] * z
+    for back in range(1, n):
+        shifted = jax.lax.pad(z, jnp.zeros((), z.dtype), [(0, 0, 0), (back, -back, 0), (0, 0, 0)])
+        out = out + taps[n - 1 - back] * shifted
+    return out
+
+
+def _l2(x):
+    """x over its last axis' L2 norm (float32 in, float32 out)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _kda_mixer(cfg: TransformerConfig, kind: LayerKind, mesh, h, w):
+    """Kimi Delta Attention from the normed input h [B, S, E] to the heads'
+    joined output [B, S, H * D], before `wo` (arXiv:2510.26692;
+    LayerKind.mixer).  The projections are `attn_proj`'s, the recurrence
+    `kda_scan`'s (`ops.delta_attention.kda`), and `kda_mix` is what lies
+    between: a causal convolution of kernel `kda_conv` and SiLU on each of
+    q~, k~, v~; an L2 norm a head on q (times D**-0.5) and k; the decay
+    ``g = -exp(A_log) softplus(a + dt_bias)`` a channel and ``beta =
+    sigmoid(.)`` a head, both float32; after the scan an RMSNorm over each
+    head's columns (one weight of D) under a sigmoid gate.  Also returns the
+    mean of the decay exp(g) over the layer (`kda_alpha_mean`'s term).
+    Elementwise work is float32 inside its fusion and lands in the compute
+    type."""
+    from torchft_tpu.ops.delta_attention import kda
+
+    B, S, _ = h.shape
+    H, D, dt, f32 = kind.n_heads, cfg.kda_head_dim, cfg.dtype, jnp.float32
+    with jax.named_scope("attn_proj"):
+        q0, k0, v0 = (h @ w[name].astype(dt) for name in ("wq", "wk", "wv"))
+        a = (h @ w["kda_a_down"].astype(dt)) @ w["kda_a_up"].astype(dt)
+        gate = (h @ w["kda_g_down"].astype(dt)) @ w["kda_g_up"].astype(dt)
+        b = h @ w["kda_beta"].astype(dt)
+
+    def heads(y):  # [B, S, H * D] -> [B, H, S, D]
+        return y.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+
+    # Both halves of `kda_mix` keep their INPUTS for the backward pass and nothing between (a checkpoint each):
+    # left to autodiff, a layer holds some twenty float32 arrays of [S, H * D] at once (the convolutions' sums,
+    # SiLU's and softplus' arguments, the norms' squares), 268 MB each at the benchmark's size.
+    @jax.checkpoint
+    def before(q0, k0, v0, a, b, w):
+        with jax.named_scope("kda_mix"):
+            q, k, v = (jax.nn.silu(_causal_conv(z.astype(f32), w[name].astype(f32)))
+                       for z, name in ((q0, "kda_conv_q"), (k0, "kda_conv_k"), (v0, "kda_conv_v")))
+            q, k = _l2(q.reshape(B, S, H, D)) * D ** -0.5, _l2(k.reshape(B, S, H, D))
+            rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), D)                    # [H * D]
+            g = -rate * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))    # [B, S, H * D]
+            alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
+            beta = jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)                  # [B, H, S]
+            return heads(q.astype(dt)), heads(k.astype(dt)), heads(v.astype(dt)), heads(g), beta, alpha
+
+    @jax.checkpoint
+    def after(o, gate, w):
+        with jax.named_scope("kda_mix"):
+            o = o.transpose(0, 2, 1, 3).astype(f32)                                  # [B, S, H, D]
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps) * w["kda_norm"].astype(f32)
+            gate = jax.nn.sigmoid(gate.astype(f32) + w["kda_g_bias"].astype(f32))
+            return (o.reshape(B, S, H * D) * gate).astype(dt)
+
+    small = {name: w[name] for name in ("kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_norm",
+                                        "kda_g_bias")}
+    q, k, v, g, beta, alpha = before(q0, k0, v0, a, b, small)
+    with jax.named_scope("kda_scan"):
+        o = kda(q, k, v, g, beta, mesh=mesh)
+    o = after(o, gate, small)
+    return o, alpha
+
+
 def _merge(x, y, vectors=None):
     """The residual merge: ``x + y``, or with the sublayer's four learned
     vectors [4, E] ``(x + b_r) * a_r + (y + b_o) * a_o`` (float32 inside the
@@ -774,17 +906,23 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         x, router_state = x
     B, S, E = x.shape
     kind = cfg.layers[-1] if kind is None else kind
-    H, KV, sparse = kind.n_heads, cfg.n_kv_heads, kind.sparse
+    H, KV = kind.n_heads, cfg.n_kv_heads
 
     # The scopes are the parts a profile's device time is booked to
     # (obs/spans.PARTS); they name the work and change no instruction.
     with jax.named_scope("norm"):
         h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
+    if kind.mixer == "kda":
+        attn, alpha = _kda_mixer(cfg, kind, mesh, h, w)
+        with jax.named_scope("attn_proj"):
+            x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
+            x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
+        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"kda_alpha": alpha})
     with jax.named_scope("attn_proj"):
         if kind.mixer == "cca":
             q, k, v = _cca_qkv(cfg, kind, h, w, positions)
-        elif cfg.mla_kv_rank:
-            q, k, v = _mla_qkv(cfg, h, w, positions)
+        elif kind.mixer == "mla":
+            q, k, v = _mla_qkv(cfg, kind, h, w, positions)
             KV = H
         else:
             Dh = cfg.d_head
@@ -824,10 +962,17 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         attn = attn.reshape(B, S, H * attn.shape[-1])
         x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
+    return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa)
 
+
+def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind: LayerKind, router_bias,
+                  router_state, mixer_stats):
+    """The second half of a decoder block, from the stream x after the mixer:
+    the kind's feed-forward and what `_layer` hands back.  `mixer_stats`: the
+    statistics the mixer counted (a dict), or None."""
     with jax.named_scope("norm"):
         h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
-    if sparse:
+    if kind.sparse:
         from torchft_tpu.models.moe import moe_layer
 
         y, aux = moe_layer(
@@ -860,9 +1005,9 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
             gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
             up = h @ w["w_up"].astype(cfg.dtype)
             x = _merge(x, (gate * up) @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
-        aux = {} if dsa is not None else jnp.zeros((), jnp.float32)
-    if dsa is not None:
-        aux = dict(aux, **dsa)
+        aux = {} if mixer_stats is not None else jnp.zeros((), jnp.float32)
+    if mixer_stats is not None:
+        aux = dict(aux, **mixer_stats)
     x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
     return ((x, router_state) if cfg.moe_router_state else x), aux
 
@@ -930,17 +1075,28 @@ def _decoder(
             pieces.append(jax.tree.map(lambda *a: jnp.stack(a), *pending))
             pending.clear()
 
-    if router_bias is not None:
-        assert sum(kind.sparse for kind, _ in cfg.stacks.values()) == 1, "router_bias's rows are one stack's layers"
-    # The walk of the pattern: runs of one kind, each through its own stack.
+    alpha_total, kda_layers = jnp.zeros((), jnp.float32), sum(kind.mixer == "kda" for kind in cfg.layers)
+
+    def without_alpha(aux):
+        """A KDA layer's (or run's) statistics without its mean decay, which is summed apart."""
+        nonlocal alpha_total
+        if not (isinstance(aux, dict) and "kda_alpha" in aux):
+            return aux
+        aux = dict(aux)
+        alpha_total = alpha_total + jnp.sum(aux.pop("kda_alpha"))
+        return aux or jnp.zeros((), jnp.float32)
+
+    # The walk of the pattern: runs of one kind, each through its own stack;
+    # router_bias's rows by a layer's place among the SPARSE layers, whatever their stack.
     at = {stack: 0 for stack in cfg.stacks}  # the next layer of each stack
+    sparse_at = 0
     for kind, run in itertools.groupby(cfg.layers):
         count, first = len(list(run)), at[kind.stack]
         at[kind.stack] += count
         with_stats = stats and (kind.sparse or cfg.dsa_index_heads > 0)
         stacked = params[kind.stack]
-        if router_bias is not None and kind.sparse:
-            stacked = dict(stacked, router_bias=router_bias)
+        bias, bias_first = (router_bias if kind.sparse else None), sparse_at
+        sparse_at += count * kind.sparse
 
         def body(x, w, kind=kind):
             if grads_inside:
@@ -959,9 +1115,11 @@ def _decoder(
         # op association — results agree with the scan path to fusion-order
         # rounding, not bitwise (pinned by test_scan_unroll_matches_scan).
         if count <= cfg.scan_unroll:
-            for i in range(first, first + count):
+            for n in range(count):
                 with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
-                    x, aux = body(x, jax.tree.map(lambda a, i=i: a[i], stacked))
+                    w = jax.tree.map(lambda a, i=first + n: a[i], stacked)
+                    x, aux = body(x, w if bias is None else dict(w, router_bias=bias[bias_first + n]))
+                aux = without_alpha(aux)
                 if with_stats:
                     pending.append(aux)
                 elif not stats:  # beside experts a dense layer has no statistics
@@ -971,7 +1129,11 @@ def _decoder(
         with jax.named_scope("stack"):
             if (first, count) != (0, cfg.stacks[kind.stack][1]):
                 stacked = jax.tree.map(lambda a: a[first:first + count], stacked)
+            if bias is not None:
+                whole = (bias_first, count) == (0, bias.shape[0])
+                stacked = dict(stacked, router_bias=bias if whole else bias[bias_first:bias_first + count])
             x, aux_layers = jax.lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
+        aux_layers = without_alpha(aux_layers)
         if with_stats:
             flush()
             pieces.append(aux_layers)
@@ -984,7 +1146,10 @@ def _decoder(
     with jax.named_scope("stack"):
         flush()
         whole = pieces[0] if len(pieces) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *pieces)
-        return x, _over_layers(whole)
+        out = _over_layers(whole)
+        if kda_layers:
+            out["kda_alpha"] = alpha_total / kda_layers
+        return x, out
 
 
 @jax.custom_vjp
@@ -1005,6 +1170,10 @@ def _remat(cfg: TransformerConfig, body):
     from torchft_tpu.ops.sparse_attention import SAVED_NAMES as DSA_SAVED_NAMES
 
     names = SAVED_NAMES + (DSA_SAVED_NAMES if cfg.dsa_index_heads else ())
+    if any(kind.mixer == "kda" for kind in cfg.layers):
+        from torchft_tpu.ops.delta_attention import SAVED_NAMES as KDA_SAVED_NAMES
+
+        names += KDA_SAVED_NAMES
     return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
@@ -1175,6 +1344,8 @@ def loss_and_counters(
         if cfg.moe_z_coef:
             loss = loss + cfg.moe_z_coef * aux["z"]
         counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
+        if "kda_alpha" in aux:
+            counters.update(kda_alpha_mean=aux["kda_alpha"])
         if cfg.moe_skip:
             counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:

@@ -161,7 +161,11 @@ SUBSPANS = {
 #     transposes around the heads; cca_mix — what compressed attention puts
 #     between its projections and RoPE: the value shift, both causal
 #     convolutions, the q-k mean and the norm a head (the first work of a
-#     layer outside the attention call that mixes positions);
+#     layer outside the attention call that mixes positions); kda_mix — what
+#     Kimi Delta Attention puts around its scan: the short convolutions with
+#     SiLU, the L2 norms, the decay and beta before it, the gated head norm
+#     after it; kda_scan — the gated delta rule over the sequence (the
+#     `tpuft_kda_*` kernels and whatever XLA puts around them);
 #     attn — the attention call: kernels and
 #     whatever XLA puts around them; attn_window — the same call in a layer
 #     that attends under a window (the `tpuft_swa_*` kernels), so that a
@@ -175,8 +179,8 @@ SUBSPANS = {
 #     backward pass: the per-layer gradients padded and summed into the
 #     stacked gradient) and the stacking of the layers' statistics.
 PARTS = (
-    "embed", "norm", "attn_proj", "cca_mix", "attn", "attn_window", "dsa_index", "dsa_select", "ffn",
-    "router", "experts", "shared_expert", "head_loss", "stack",
+    "embed", "norm", "attn_proj", "cca_mix", "kda_mix", "kda_scan", "attn", "attn_window", "dsa_index", "dsa_select",
+    "ffn", "router", "experts", "shared_expert", "head_loss", "stack",
 )
 
 
