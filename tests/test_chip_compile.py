@@ -710,6 +710,41 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, direction) -> None:
         assert ("f32[32,256,128,128]" in text) == (direction == "forward_with_states")
 
 
+@pytest.mark.parametrize("kernel", ["before_forward", "before_backward", "after_forward", "after_backward"])
+def test_kda_mix_kernels_compile_for_v5e(one_chip, kernel) -> None:
+    """The four `tpuft_kdamix_*` kernels at the Kimi cell's shape: one sequence
+    of 16,384 positions x 32 heads of 128 in bfloat16, tiles of 1,024 rows
+    worked through in blocks of 64 — a head's lane tile read out of
+    [1, 16,384, 4,096] and written head-major, the convolution's shifted reads
+    at unaligned rows of a float32 scratch, the lane reductions of the norms,
+    the partial sums' blocks of one row."""
+    import re
+
+    from torchft_tpu.ops import kda_mix
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    b, seq, h, d = 1, 16_384, 32, 128
+    tile = kda_mix.tile_of(seq)
+    assert tile == 1024
+    joined, major, decay = sds((b, seq, h * d), bf16), sds((b, h, seq, d), bf16), sds((b, h, seq, d), f32)
+    taps, column, norm = sds((3, 4, h * d), f32), sds((1, h * d), f32), sds((1, d), f32)
+    fn, shapes, name = {
+        "before_forward": (lambda *a: kda_mix._before_fwd_pallas(*a, tile), [joined] * 4 + [taps, column, column],
+                           "tpuft_kdamix_fwd"),
+        "before_backward": (lambda *a: kda_mix._before_bwd_pallas(*a, tile),
+                            [joined] * 4 + [taps, column, column] + [major] * 3 + [decay], "tpuft_kdamix_bwd"),
+        "after_forward": (lambda *a: kda_mix._after_fwd_pallas(*a, 1e-5, tile), [major, joined, norm, column],
+                          "tpuft_kdamix_out_fwd"),
+        "after_backward": (lambda *a: kda_mix._after_bwd_pallas(*a, 1e-5, tile), [major, joined, norm, column, joined],
+                           "tpuft_kdamix_out_bwd"),
+    }[kernel]
+    text = _compile(fn, *shapes)
+    assert _kernel_calls(text, "tpuft_kdamix_") == [name] and not _kernel_calls(text, "tpuft_kda_")
+    # nothing between input and output in HBM: no transpose or copy of a [16,384, 4,096] array beside the call
+    assert not re.search(r"= (?:bf16|f32)\[1,(?:16384,4096|32,16384,128)\]\S* (?:copy|transpose)\(", text)
+
+
 def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
     """The benchmark's `kimi-linear-48b-a3b` configuration as
     `benchmark/programs/kda_mla_moe_lm.py` hands it to `TrainStep`: the whole
@@ -742,6 +777,10 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # and the backward kernel once; the latent layer's attention output is kept too, one kernel each way
     assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
     assert sorted(_kernel_calls(text, "tpuft_kda_")) == ["tpuft_kda_bwd"] * 4 + ["tpuft_kda_fwd"] * 8
+    # `kda_mix` around it (since PR 49): each half's forward kernel twice a layer (the forward pass and the layer's
+    # recomputation: a half keeps its inputs, so nothing runs it a third time) and its backward kernel once
+    assert sorted(_kernel_calls(text, "tpuft_kdamix_")) == (
+        ["tpuft_kdamix_bwd"] * 4 + ["tpuft_kdamix_fwd"] * 8 + ["tpuft_kdamix_out_bwd"] * 4 + ["tpuft_kdamix_out_fwd"] * 8)
     assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
     # three projections a sparse layer: forward, recomputed, and the two gradients
     gmm = _kernel_calls(text, "tpuft_gmm_")
@@ -755,5 +794,6 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     # 13,827,982,336 (temporaries 4,188,533,760; builder's compile, PR 48).  Without the two checkpoints inside
     # `kda_mix` (`_kda_mixer`: each half keeps its inputs and nothing between) the same program compiles to
-    # 15,980,264,960: some twenty float32 [16,384, 4,096] arrays a layer are alive at once
-    assert resident <= 14.0e9, f"the step needs {resident} bytes with AdamW's moments"
+    # 15,980,264,960: some twenty float32 [16,384, 4,096] arrays a layer are alive at once.  With the halves as
+    # kernels (PR 49) 13,817,533,952 (temporaries 4,178,085,376; builder's compile, PR 49): not above PR 48's
+    assert resident <= 13_827_982_336, f"the step needs {resident} bytes with AdamW's moments"

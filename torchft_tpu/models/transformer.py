@@ -826,6 +826,41 @@ def _l2(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
+_KDA_SMALL = ("kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_norm", "kda_g_bias")
+
+
+def _kda_before(q0, k0, v0, a, b, w, H):
+    """`kda_mix` before the scan, in XLA: the projections q0, k0, v0, a
+    [B, S, H * D] and b [B, S, H] to the scan's q, k, v [B, H, S, D] in their
+    type, g [B, H, S, D] and beta [B, H, S] float32, and the decay's mean."""
+    B, S, D, dt, f32 = q0.shape[0], q0.shape[1], q0.shape[2] // H, q0.dtype, jnp.float32
+
+    def heads(y):  # [B, S, H * D] -> [B, H, S, D]
+        return y.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("kda_mix"):
+        q, k, v = (jax.nn.silu(_causal_conv(z.astype(f32), w[name].astype(f32)))
+                   for z, name in ((q0, "kda_conv_q"), (k0, "kda_conv_k"), (v0, "kda_conv_v")))
+        q, k = _l2(q.reshape(B, S, H, D)) * D ** -0.5, _l2(k.reshape(B, S, H, D))
+        rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), D)                    # [H * D]
+        g = -rate * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))    # [B, S, H * D]
+        alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
+        beta = jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)                  # [B, H, S]
+        return heads(q.astype(dt)), heads(k.astype(dt)), heads(v.astype(dt)), heads(g), beta, alpha
+
+
+def _kda_after(o, gate, w, eps):
+    """`kda_mix` after the scan, in XLA: o [B, H, S, D] under the head norm
+    and the sigmoid of the gate's projection [B, S, H * D], in the gate's type."""
+    B, H, S, D = o.shape
+    f32 = jnp.float32
+    with jax.named_scope("kda_mix"):
+        o = o.transpose(0, 2, 1, 3).astype(f32)                                  # [B, S, H, D]
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * w["kda_norm"].astype(f32)
+        gate_ = jax.nn.sigmoid(gate.astype(f32) + w["kda_g_bias"].astype(f32))
+        return (o.reshape(B, S, H * D) * gate_).astype(gate.dtype)
+
+
 def _kda_mixer(cfg: TransformerConfig, kind: LayerKind, mesh, h, w):
     """Kimi Delta Attention from the normed input h [B, S, E] to the heads'
     joined output [B, S, H * D], before `wo` (arXiv:2510.26692;
@@ -837,11 +872,14 @@ def _kda_mixer(cfg: TransformerConfig, kind: LayerKind, mesh, h, w):
     sigmoid(.)`` a head, both float32; after the scan an RMSNorm over each
     head's columns (one weight of D) under a sigmoid gate.  Also returns the
     mean of the decay exp(g) over the layer (`kda_alpha_mean`'s term).
-    Elementwise work is float32 inside its fusion and lands in the compute
-    type."""
+    Elementwise work is float32 inside its fusion — or, where
+    `ops.kda_mix.applies` (a TPU's program over one device, heads of 128
+    columns), inside the `tpuft_kdamix_*` kernels' tile — and lands in the
+    compute type."""
+    from torchft_tpu.ops import kda_mix
     from torchft_tpu.ops.delta_attention import kda
 
-    B, S, _ = h.shape
+    S = h.shape[1]
     H, D, dt, f32 = kind.n_heads, cfg.kda_head_dim, cfg.dtype, jnp.float32
     with jax.named_scope("attn_proj"):
         q0, k0, v0 = (h @ w[name].astype(dt) for name in ("wq", "wk", "wv"))
@@ -849,38 +887,25 @@ def _kda_mixer(cfg: TransformerConfig, kind: LayerKind, mesh, h, w):
         gate = (h @ w["kda_g_down"].astype(dt)) @ w["kda_g_up"].astype(dt)
         b = h @ w["kda_beta"].astype(dt)
 
-    def heads(y):  # [B, S, H * D] -> [B, H, S, D]
-        return y.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-
-    # Both halves of `kda_mix` keep their INPUTS for the backward pass and nothing between (a checkpoint each):
-    # left to autodiff, a layer holds some twenty float32 arrays of [S, H * D] at once (the convolutions' sums,
-    # SiLU's and softplus' arguments, the norms' squares), 268 MB each at the benchmark's size.
-    @jax.checkpoint
-    def before(q0, k0, v0, a, b, w):
+    small = {name: w[name] for name in _KDA_SMALL}
+    kernels = cfg.kda_conv == kda_mix.TAPS and kda_mix.applies(S, D, mesh)
+    if kernels:
         with jax.named_scope("kda_mix"):
-            q, k, v = (jax.nn.silu(_causal_conv(z.astype(f32), w[name].astype(f32)))
-                       for z, name in ((q0, "kda_conv_q"), (k0, "kda_conv_k"), (v0, "kda_conv_v")))
-            q, k = _l2(q.reshape(B, S, H, D)) * D ** -0.5, _l2(k.reshape(B, S, H, D))
-            rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), D)                    # [H * D]
-            g = -rate * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))    # [B, S, H * D]
+            q, k, v, g = kda_mix.before(q0, k0, v0, a, *(small[name] for name in _KDA_SMALL[:5]))
             alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
             beta = jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)                  # [B, H, S]
-            return heads(q.astype(dt)), heads(k.astype(dt)), heads(v.astype(dt)), heads(g), beta, alpha
-
-    @jax.checkpoint
-    def after(o, gate, w):
-        with jax.named_scope("kda_mix"):
-            o = o.transpose(0, 2, 1, 3).astype(f32)                                  # [B, S, H, D]
-            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps) * w["kda_norm"].astype(f32)
-            gate = jax.nn.sigmoid(gate.astype(f32) + w["kda_g_bias"].astype(f32))
-            return (o.reshape(B, S, H * D) * gate).astype(dt)
-
-    small = {name: w[name] for name in ("kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_norm",
-                                        "kda_g_bias")}
-    q, k, v, g, beta, alpha = before(q0, k0, v0, a, b, small)
+    else:
+        # The XLA halves keep their INPUTS for the backward pass and nothing between (a checkpoint each): left to
+        # autodiff, a layer holds some twenty float32 arrays of [S, H * D] at once (the convolutions' sums, SiLU's
+        # and softplus' arguments, the norms' squares), 268 MB each at the benchmark's size.
+        q, k, v, g, beta, alpha = jax.checkpoint(lambda *xs: _kda_before(*xs, H))(q0, k0, v0, a, b, small)
     with jax.named_scope("kda_scan"):
         o = kda(q, k, v, g, beta, mesh=mesh)
-    o = after(o, gate, small)
+    if kernels:
+        with jax.named_scope("kda_mix"):
+            o = kda_mix.after(o, gate, small["kda_norm"], small["kda_g_bias"], eps=cfg.rms_eps)
+    else:
+        o = jax.checkpoint(lambda *xs: _kda_after(*xs, cfg.rms_eps))(o, gate, small)
     return o, alpha
 
 

@@ -164,7 +164,9 @@ SUBSPANS = {
 #     layer outside the attention call that mixes positions); kda_mix — what
 #     Kimi Delta Attention puts around its scan: the short convolutions with
 #     SiLU, the L2 norms, the decay and beta before it, the gated head norm
-#     after it; kda_scan — the gated delta rule over the sequence (the
+#     after it (on a TPU's one-device program the `tpuft_kdamix_*` kernels,
+#     one pass a direction, with beta's sigmoid and the decay's mean in XLA
+#     beside them; elsewhere XLA fusions); kda_scan — the gated delta rule over the sequence (the
 #     `tpuft_kda_*` kernels and whatever XLA puts around them);
 #     attn — the attention call: kernels and
 #     whatever XLA puts around them; attn_window — the same call in a layer
