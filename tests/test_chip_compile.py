@@ -472,6 +472,28 @@ def _kernel_calls(text: str, prefix: str) -> list:
             for m in [re.search(prefix + r"[a-z_]*[a-z]", line)] if m]
 
 
+def _kernel_grids(text: str, prefix: str) -> list:
+    """[(name, grid)] of the compiled kernel calls whose names start with
+    `prefix`: the grid is `iteration_bounds` of the kernel's serialised body."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for line in text.splitlines():
+        body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line)
+        name = re.search(prefix + r"[a-z_]*[a-z]", line[:line.find("backend_config=")])
+        if "tpu_custom_call" not in line or not body or not name:
+            continue
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(body.group(1)), context)
+        kernel = next(op for op in module.body.operations if "iteration_bounds" in op.attributes)
+        found.append((name.group(0), tuple(kernel.attributes["iteration_bounds"])))
+    return found
+
+
 def test_sparse_attention_kernels_compile_for_v5e(one_chip) -> None:
     """The five `tpuft_dsa_*` kernels at the Keye cell's shapes: one sequence
     of 32,768 positions, 32 query heads on 4 KV heads of 128, 16 index heads of
@@ -694,7 +716,9 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, direction) -> None:
     at the Kimi cell's shape: 32 heads x 16,384 positions x 128 in bfloat16, g
     float32, chunks of 64 — the level masks and the stacked 0/1 sums resident in
     VMEM, the [64, 64] products, the transposed-left products and the squarings
-    of the solve as Mosaic takes them."""
+    of the solve as Mosaic takes them — and, since PR 50, several heads' chunk
+    a grid step: the compiled call's grid is (32 / H, 256) with the H that
+    `_heads_per_step` reads from the 32 heads, above 1."""
     from torchft_tpu.ops import delta_attention as da
 
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -708,6 +732,8 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, direction) -> None:
         text = _compile(lambda *a: da._fwd_pallas(*a, da.CHUNK, direction == "forward_with_states"), *rows)
         assert _kernel_calls(text, "tpuft_kda_") == ["tpuft_kda_fwd"]
         assert ("f32[32,256,128,128]" in text) == (direction == "forward_with_states")
+    heads = da._heads_per_step(bh)
+    assert heads > 1 and [grid for _, grid in _kernel_grids(text, "tpuft_kda_")] == [(bh // heads, seq // da.CHUNK)]
 
 
 @pytest.mark.parametrize("kernel", ["before_forward", "before_backward", "after_forward", "after_backward"])
@@ -762,6 +788,7 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
         sys.path.insert(0, root)
     from benchmark.spec import Benchmark
     from torchft_tpu.ops import _pallas_util
+    from torchft_tpu.ops import delta_attention as da
 
     monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
     bench = Benchmark(root)
@@ -777,6 +804,9 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # and the backward kernel once; the latent layer's attention output is kept too, one kernel each way
     assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
     assert sorted(_kernel_calls(text, "tpuft_kda_")) == ["tpuft_kda_bwd"] * 4 + ["tpuft_kda_fwd"] * 8
+    # each of the twelve carries H heads' chunk a grid step (PR 50): 32 heads, 256 chunks of 64 positions
+    heads = da._heads_per_step(32)
+    assert heads > 1 and [grid for _, grid in _kernel_grids(text, "tpuft_kda_")] == [(32 // heads, 16_384 // da.CHUNK)] * 12
     # `kda_mix` around it (since PR 49): each half's forward kernel twice a layer (the forward pass and the layer's
     # recomputation: a half keeps its inputs, so nothing runs it a third time) and its backward kernel once
     assert sorted(_kernel_calls(text, "tpuft_kdamix_")) == (

@@ -82,25 +82,59 @@ def test_the_chunk_form_is_the_recurrence(case, name) -> None:
         assert float(np.max(np.abs(b))) == 0.0  # nothing is ever written into the state
 
 
-@functools.lru_cache(maxsize=None)
-def _interpreted_and_xla(dtype_name):
+# (heads, heads a grid step): set through the kernels' private keyword, or None = what `kda` reads from the shape
+# (6 heads -> all 6 in a step, 5 -> 5: the largest divisor not above HEADS_PER_STEP).  Three chunks of 64, so that a
+# chunk's output depends on the state its own head carried through the two before: a head that read its neighbour's row
+# of the scratch would fail from the second chunk on
+KERNEL_CASES = {"1_of_4": (4, 1), "2_of_4": (4, 2), "4_of_4": (4, 4), "6_from_the_shape": (6, None),
+                "5_from_the_shape": (5, None)}
+KERNEL_NAMES = NAMES + ("states",)
+
+
+def _kernel_inputs(dtype_name, heads):
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[dtype_name]
-    args, weight = _inputs(128, 1.0, "uniform", "sigmoid", heads=2, width=128, seed=3, dtype=dtype)  # 2 heads x 2 chunks of 64
-    got = _both(lambda *a: da.kda(*a, interpret=True), args, weight)
-    want = _both(lambda *a: da.kda(*a), args, weight)
-    return got, want
+    args, weight = _inputs(192, 1.0, "uniform", "sigmoid", heads=heads, width=128, seed=3, dtype=dtype)
+    return args, weight, [a.reshape(heads, 192, *a.shape[3:]) for a in args]
 
 
-@pytest.mark.parametrize("name", NAMES)
+@functools.lru_cache(maxsize=None)
+def _xla(dtype_name, heads):
+    args, weight, flat = _kernel_inputs(dtype_name, heads)
+    return _both(lambda *a: da.kda(*a), args, weight) + (da._forward_xla(*flat, da.CHUNK, True)[1],)
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted(dtype_name, case):
+    heads, heads_per_step = KERNEL_CASES[case]
+    args, weight, flat = _kernel_inputs(dtype_name, heads)
+    kw = dict(interpret=True, heads_per_step=heads_per_step)
+    _, states = da._fwd_pallas(*flat, da.CHUNK, True, **kw)
+    if heads_per_step is None:
+        assert da._heads_per_step(heads) == heads
+        return _both(lambda *a: da.kda(*a, interpret=True), args, weight) + (states,)
+    o = da._fwd_pallas(*flat, da.CHUNK, False, **kw)[0]
+    grads = da._bwd_pallas(*flat, states, weight[0].astype(o.dtype), da.CHUNK, **kw)
+    return tuple(a[None] for a in (o,) + tuple(grads)) + (states,)
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
 @pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
-def test_the_kernels_in_interpret_mode_are_the_xla_form(dtype_name, name) -> None:
-    """The kernels run the XLA form's two chunk functions on their blocks, so
-    the two agree to the last bit or two of float32 (the carried state and the
-    chunks' order are the same); in bfloat16 the outputs are rounded alike."""
-    got, want = _interpreted_and_xla(dtype_name)
-    a, b = np.asarray(got[NAMES.index(name)], np.float64), np.asarray(want[NAMES.index(name)], np.float64)
+def test_the_kernels_in_interpret_mode_are_the_xla_form(dtype_name, case, name) -> None:
+    """The kernels run the XLA form's two chunk functions on each head's
+    blocks, however many heads a grid step carries, so the two agree to the
+    last bit or two of float32 (the carried state and the chunks' order are
+    the same); in bfloat16 the outputs are rounded alike."""
+    got, want = _interpreted(dtype_name, case), _xla(dtype_name, KERNEL_CASES[case][0])
+    a, b = np.asarray(got[KERNEL_NAMES.index(name)], np.float64), np.asarray(want[KERNEL_NAMES.index(name)], np.float64)
     assert a.shape == b.shape and np.all(np.isfinite(a))
     assert float(np.max(np.abs(a - b))) <= (1e-5 if dtype_name == "float32" else 2e-2) * float(np.max(np.abs(b)))
+
+
+@pytest.mark.parametrize("bh, most, heads", [(32, 8, 8), (32, 4, 4), (4, 8, 4), (6, 4, 3), (6, 8, 6), (5, 4, 1), (7, 8, 7),
+                                             (1, 8, 1), (48, 32, 24)])
+def test_the_heads_of_a_grid_step_divide_the_heads(bh, most, heads) -> None:
+    assert da._heads_per_step(bh, most) == heads
 
 
 @pytest.mark.parametrize("chunk", [2, 16, 64])

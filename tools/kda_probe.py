@@ -1,14 +1,16 @@
 """Standalone: the `tpuft_kda_*` kernels at the Kimi cell's shape (32 heads x
 16,384 positions x 128), timed forward, forward with the chunks' states and
-backward, and at 4 heads x 2,048 positions compared with the recurrence
-position by position in float32 (output and all five gradients, at decays
-from the seeded gate's range and at g = -20 a position).
+backward at 1, 2, 4 and 8 heads a grid step (the traced grid and µs a head and
+chunk beside each), and at 4 heads x 2,048 positions compared with the
+recurrence position by position in float32 (output and all five gradients, at
+decays from the seeded gate's range and at g = -20 a position).
 
-    chiprun -- python tools/kda_probe.py
+    chiprun -- python tools/kda_probe.py [--heads 1,2,4,8] [--timing]
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -42,13 +44,28 @@ def timed(fn, *args, repeats=5):
     return (time.perf_counter() - t0) / repeats * 1e3
 
 
-def main() -> int:
+def grid_of(fn, *args):
+    """The grid of the one `pallas_call` that ``fn`` traces."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    (grid,) = found
+    return grid
+
+
+def compare(da) -> None:
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.ops import delta_attention as da
-
-    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     for g_scale in (0.1, 2.0, 20.0):
         args = inputs(1, 4, 2048, 128, g_scale, jnp.bfloat16)
         co = jax.random.normal(jax.random.PRNGKey(7), (1, 4, 2048, 128))
@@ -66,14 +83,42 @@ def main() -> int:
     eighths = [float(jnp.linalg.norm(o[:, :, i:i + 2048] - want[:, :, i:i + 2048]) / jnp.linalg.norm(want[:, :, i:i + 2048]))
                for i in range(0, 16384, 2048)]
     print(json.dumps({"error_by_eighth_of_16384_positions": eighths}), flush=True)
-    args = inputs(2, 32, 16384, 128, 0.5, jnp.bfloat16)
-    flat = [a.reshape(32, 16384, *a.shape[3:]) for a in args]
-    fwd = jax.jit(lambda *a: da._fwd_pallas(*a, da.CHUNK, False)[0])
-    fwd_states = jax.jit(lambda *a: da._fwd_pallas(*a, da.CHUNK, True))
-    o, states = fwd_states(*flat)
-    bwd = jax.jit(lambda *a: da._bwd_pallas(*a, da.CHUNK))
-    print(json.dumps({"shape": [32, 16384, 128], "fwd_ms": timed(fwd, *flat), "fwd_states_ms": timed(fwd_states, *flat),
-                      "bwd_ms": timed(bwd, *flat, states, o)}), flush=True)
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--heads", default="1,2,4,8", help="heads a grid step to time, a list")
+    parser.add_argument("--timing", action="store_true", help="the timings alone, no comparison with the recurrence")
+    opts = parser.parse_args()
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops import delta_attention as da
+
+    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if not opts.timing:
+        compare(da)
+    bh, seq = 32, 16384
+    args = inputs(2, bh, seq, 128, 0.5, jnp.bfloat16)
+    flat = [a.reshape(bh, seq, *a.shape[3:]) for a in args]
+    steps = bh * seq // da.CHUNK                                  # heads x chunks
+    first = None
+    for heads in (int(h) for h in opts.heads.split(",")):
+        fwd = jax.jit(lambda *a: da._fwd_pallas(*a, da.CHUNK, False, heads_per_step=heads)[0])
+        fwd_states = jax.jit(lambda *a: da._fwd_pallas(*a, da.CHUNK, True, heads_per_step=heads))
+        bwd = jax.jit(lambda *a: da._bwd_pallas(*a, da.CHUNK, heads_per_step=heads))
+        try:
+            o, states = fwd_states(*flat)
+            ms = {"fwd": timed(fwd, *flat), "fwd_states": timed(fwd_states, *flat), "bwd": timed(bwd, *flat, states, o)}
+            results = (o,) + tuple(bwd(*flat, states, o))
+        except Exception as e:  # noqa: BLE001 — a count the compiler refuses is a line of the table
+            print(json.dumps({"heads_per_step": heads, "refused": str(e)[-300:]}), flush=True)
+            continue
+        first = first or results
+        print(json.dumps({"heads_per_step": heads, "grid": grid_of(fwd, *flat), "grid_bwd": grid_of(bwd, *flat, states, o),
+                          "ms": ms, "us_a_head_and_chunk": {name: 1e3 * t / steps for name, t in ms.items()},
+                          "bitwise_the_first_line": all(bool(jnp.array_equal(a, b)) for a, b in zip(results, first))}),
+              flush=True)
     return 0
 
 

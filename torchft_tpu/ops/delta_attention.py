@@ -43,6 +43,19 @@ writes every chunk's (the backward's only: `kda`'s primal call writes none),
 and everything inside the chunk is recomputed from q, k, v, g, beta.  It gives
 dq, dk, dv, dg, dbeta.  Nothing is kept for the backward beside the inputs.
 
+**The grid.**  A grid step of either kernel carries H heads' chunk j, not one
+head's: grid (heads / H, chunks), blocks [H, CHUNK, width], the carried state a
+scratch [H, V, K].  Why: a head's chunk is a chain of 11 dependent small
+products forward and 18 backward (the sums of g, a level, R, five squarings one
+after another, T, W and U, D, O and the state), each waiting for the one before
+— half of a one-head step's cycles were stalls on results in flight — and the
+heads are independent recurrences.  The two chunk functions run over the H heads
+under `jax.vmap` (`_heads_at_once`), so that every product is one batched
+product and the heads' chains stand side by side in the program's order, which
+the scheduler keeps.  H is read from the shape (`_heads_per_step`: the largest
+divisor of the heads not above ``HEADS_PER_STEP``), so 4 or 5 heads run the same
+code as 32.
+
 The XLA form (`_forward_xla`, `_backward_xla`) runs the same two chunk functions under `lax.scan`:
 off the TPU, under a multi-device mesh and in the tests, which compare it with
 the recurrence position by position and the kernels (``interpret``) with it.
@@ -298,6 +311,27 @@ def _backward_xla(q, k, v, g, beta, states, do, chunk: int):
 # -- the kernels ----------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _heads_at_once(chunk_fn, dtype):
+    """``chunk_fn`` over the leading axis of its blocks, the heads of a grid
+    step, as a function of (the constants, *blocks): each product becomes one
+    batched product, so a head's chain of dependent products stands op by op
+    beside the other heads' and the scheduler fills one's latency with the
+    others' work (unrolled head after head it kept them in sequence: PERF.md
+    section 6, PR 50).  The constants are broadcast to a head each: left
+    unbatched, their products would come out [rows, heads, lanes], the heads
+    on the sublanes.  Jitted, and one object a chunk function and type, so that
+    the batched trace — three times the plain one's seconds — is made once for
+    all the calls of a program and not once for each time JAX traces a kernel
+    (a layer's forward, its recomputation, its transpose)."""
+    def run(consts, *blocks):
+        heads = blocks[0].shape[0]
+        each = {name: jnp.broadcast_to(value, (heads,) + value.shape) for name, value in consts.items()}
+        return jax.vmap(functools.partial(chunk_fn, dtype=dtype))(*blocks, each)
+
+    return jax.jit(run)
+
+
 def _fwd_kernel(sums_ref, masks_ref, q_ref, k_ref, v_ref, g_ref, bc_ref, br_ref, o_ref, *rest, dtype, with_states):
     from jax.experimental import pallas as pl
 
@@ -311,8 +345,8 @@ def _fwd_kernel(sums_ref, masks_ref, q_ref, k_ref, v_ref, g_ref, bc_ref, br_ref,
     if with_states:
         states_ref[...] = state
     consts = {"sums": sums_ref[...], "masks": masks_ref[...]}
-    o, after = _forward_chunk(state, q_ref[...], k_ref[...], v_ref[...], g_ref[...], bc_ref[...], br_ref[...],
-                              consts, dtype)
+    o, after = _heads_at_once(_forward_chunk, dtype)(consts, state, q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+                                                     bc_ref[...], br_ref[...])
     o_ref[...] = o.astype(o_ref.dtype)
     state_scr[...] = after
 
@@ -326,35 +360,48 @@ def _bwd_kernel(sums_ref, masks_ref, back_ref, q_ref, k_ref, v_ref, g_ref, bc_re
         dstate_scr[...] = jnp.zeros_like(dstate_scr)
 
     consts = {"sums": sums_ref[...], "masks": masks_ref[...], "back": back_ref[...]}
-    dq, dk, dv, dg, db_col, db_row, dstate = _backward_chunk(
-        states_ref[...], dstate_scr[...], q_ref[...], k_ref[...], v_ref[...], g_ref[...], bc_ref[...], br_ref[...],
-        do_ref[...], consts, dtype)
+    dq, dk, dv, dg, db_col, db_row, dstate = _heads_at_once(_backward_chunk, dtype)(
+        consts, states_ref[...], dstate_scr[...], q_ref[...], k_ref[...], v_ref[...], g_ref[...], bc_ref[...], br_ref[...],
+        do_ref[...])
     dq_ref[...], dk_ref[...], dv_ref[...] = dq.astype(dq_ref.dtype), dk.astype(dk_ref.dtype), dv.astype(dv_ref.dtype)
     dg_ref[...], dbc_ref[...], dbr_ref[...] = dg, db_col, db_row
     dstate_scr[...] = dstate
 
 
-def _specs(chunk: int, dk: int, dv: int, n_chunks: int, reverse: bool):
-    """Block specs of a (head, chunk) grid step; the backward walks the chunks
-    from the last."""
+# The most heads a grid step carries: at 8 the backward kernel's blocks, twice each, are 5.3 MB and what it keeps
+# between products 7.8 MB more (13.1 MB, the compiler's count at 128 wide); 16 would need 26.7 MB.  The limit leaves
+# a step of 8 twice its need.
+HEADS_PER_STEP = 8
+_VMEM_LIMIT = 32 * 2 ** 20
+
+
+def _heads_per_step(bh: int, most: int = HEADS_PER_STEP) -> int:
+    """The largest divisor of ``bh`` not above ``most``."""
+    return max(h for h in range(1, min(bh, most) + 1) if bh % h == 0)
+
+
+def _specs(heads: int, chunk: int, dk: int, dv: int, n_chunks: int, reverse: bool):
+    """Block specs of a grid step, ``heads`` heads' chunk j; the backward
+    walks the chunks from the last."""
     from jax.experimental import pallas as pl
 
     at = (lambda j: n_chunks - 1 - j) if reverse else (lambda j: j)
-    rows = lambda width: pl.BlockSpec((None, chunk, width), lambda b, j: (b, at(j), 0))    # noqa: E731
-    beta_row = pl.BlockSpec((None, None, 1, chunk), lambda b, j: (b, at(j), 0, 0))
-    state = pl.BlockSpec((None, None, dv, dk), lambda b, j: (b, at(j), 0, 0))
+    rows = lambda width: pl.BlockSpec((heads, chunk, width), lambda b, j: (b, at(j), 0))    # noqa: E731
+    beta_row = pl.BlockSpec((heads, None, 1, chunk), lambda b, j: (b, at(j), 0, 0))
+    state = pl.BlockSpec((heads, None, dv, dk), lambda b, j: (b, at(j), 0, 0))
     whole = lambda shape: pl.BlockSpec(shape, lambda b, j: (0, 0))                          # noqa: E731
     return rows, beta_row, state, whole
 
 
-def _fwd_pallas(q, k, v, g, beta, chunk: int, with_states: bool, interpret: bool = False):
+def _fwd_pallas(q, k, v, g, beta, chunk: int, with_states: bool, interpret: bool = False, heads_per_step=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq, dk = q.shape
     dv, n = v.shape[2], seq // chunk
     consts = _constants(chunk)
-    rows, beta_row, state, whole = _specs(chunk, dk, dv, n, reverse=False)
+    heads = heads_per_step or _heads_per_step(bh)
+    rows, beta_row, state, whole = _specs(heads, chunk, dk, dv, n, reverse=False)
     sums, masks = jnp.asarray(consts["sums"], jnp.bfloat16), jnp.asarray(consts["masks"])
     out_shape = [jax.ShapeDtypeStruct((bh, seq, dv), q.dtype)]
     out_specs = [rows(dv)]
@@ -364,25 +411,27 @@ def _fwd_pallas(q, k, v, g, beta, chunk: int, with_states: bool, interpret: bool
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, dtype=q.dtype, with_states=with_states),
         out_shape=out_shape,
-        grid=(bh, n),
+        grid=(bh // heads, n),
         in_specs=[whole(sums.shape), whole(masks.shape), rows(dk), rows(dk), rows(dv), rows(dk), rows(1), beta_row],
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="tpuft_kda_fwd",
     )(sums, masks, q, k, v, g, beta[..., None], beta.reshape(bh, n, 1, chunk))
     return out[0], (out[1] if with_states else None)
 
 
-def _bwd_pallas(q, k, v, g, beta, states, do, chunk: int, interpret: bool = False):
+def _bwd_pallas(q, k, v, g, beta, states, do, chunk: int, interpret: bool = False, heads_per_step=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq, dk = q.shape
     dv, n = v.shape[2], seq // chunk
     consts = _constants(chunk)
-    rows, beta_row, state, whole = _specs(chunk, dk, dv, n, reverse=True)
+    heads = heads_per_step or _heads_per_step(bh)
+    rows, beta_row, state, whole = _specs(heads, chunk, dk, dv, n, reverse=True)
     sums, back = jnp.asarray(consts["sums"], jnp.bfloat16), jnp.asarray(consts["back"], jnp.bfloat16)
     masks = jnp.asarray(consts["masks"])
     dq, dk_, dv_, dg, db_col, db_row = pl.pallas_call(
@@ -392,12 +441,13 @@ def _bwd_pallas(q, k, v, g, beta, states, do, chunk: int, interpret: bool = Fals
             jax.ShapeDtypeStruct(v.shape, v.dtype), jax.ShapeDtypeStruct(g.shape, _F32),
             jax.ShapeDtypeStruct((bh, seq, 1), _F32), jax.ShapeDtypeStruct((bh, n, 1, chunk), _F32),
         ],
-        grid=(bh, n),
+        grid=(bh // heads, n),
         in_specs=[whole(sums.shape), whole(masks.shape), whole(back.shape), rows(dk), rows(dk), rows(dv), rows(dk),
                   rows(1), beta_row, state, rows(dv)],
         out_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(1), beta_row],
-        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="tpuft_kda_bwd",
     )(sums, masks, back, q, k, v, g, beta[..., None], beta.reshape(bh, n, 1, chunk), states, do)
