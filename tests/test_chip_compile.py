@@ -827,3 +827,54 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # 15,980,264,960: some twenty float32 [16,384, 4,096] arrays a layer are alive at once.  With the halves as
     # kernels (PR 49) 13,817,533,952 (temporaries 4,178,085,376; builder's compile, PR 49): not above PR 48's
     assert resident <= 13_827_982_336, f"the step needs {resident} bytes with AdamW's moments"
+
+
+def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `smallthinker-21b-a3b` configuration as
+    `benchmark/programs/early_router_moe_lm.py` hands it to `TrainStep`: the
+    whole gradient program at the published widths and 1 x 16,384 tokens — the
+    six window-4,096 layers through `tpuft_swa_*` on a band of nine tiles a row
+    (252 of the triangle's 528 a head), the two un-rotated full layers through
+    `tpuft_fa_*`, both at 28 query heads over 4 KV heads (a group of 7), the 8
+    held ReGLU experts of each layer through `tpuft_gmm_*`, the sliced
+    vocabulary through `tpuft_ce_*` — with room for AdamW's moments beside it
+    on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("smallthinker-21b-a3b"), bench.traffic("steady-1g-16k")
+    shapes = jax.eval_shape(lambda: bench.reference("early_router_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("early_router_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    # the two kinds of layer read apart: one backward and, attention's output kept under remat, one
+    # forward kernel a layer
+    assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
+    assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq"] * 2 + ["tpuft_fa_fwd"] * 2
+    assert sorted(_kernel_calls(text, "tpuft_swa_")) == ["tpuft_swa_bwd_dkdv_dq"] * 6 + ["tpuft_swa_fwd"] * 6
+    # the grids, read out of the compiled calls: the band walk a step for each of the 252 tiles with a
+    # visible pair a head (rows of 1 ... 8 tiles, then 24 rows of 9), the full layers the triangle's 528
+    grids = bench.reader("swa_pairs_share").grids(text)
+    assert sorted(g["name"] for g in grids) == ["tpuft_swa_bwd_dkdv_dq"] * 6 + ["tpuft_swa_fwd"] * 6
+    assert all((g["grid"], g["block_q"], g["seq"]) == ([28, 252], 512, 16_384) for g in grids), grids
+    assert sorted(grid for _, grid in _kernel_grids(text, "tpuft_fa_")) == [(28, 528)] * 4
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("early_router_moe_lm").total_params(config) == 643_852_800
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # 15,835,302,912 (arguments 2,575,585,280 + outputs 2,575,461,888 + temporaries 5,533,433,344 + moments
+    # 5,150,822,400; builder's compile, PR 51) and an allocator's peak of 11.70 GB on the chip; with nothing kept
+    # under remat 14,735,490,048, without remat 20,776,999,424
+    assert resident <= 15_835_302_912, f"the step needs {resident} bytes with AdamW's moments"

@@ -261,7 +261,9 @@ def test_the_part_s_bytes_from_shapes_and_its_reader(monkeypatch) -> None:
     assert need["bytes"] / peaks["hbm_bytes_per_s"] == pytest.approx(19.67e-3, rel=1e-3)
     by_name = {m["name"]: m for m in bench.doc["per_layer"]}
     reader, metric = bench.reader("kda_mix_roofline"), by_name["kda_mix_roofline"]
-    assert bench.doc["per_layer"][-1] is metric and metric["workloads"] == ["kimi-linear-48b-a3b.steady-1g-16k"]
+    names = list(by_name)  # appended after PR 48's metrics, and later PRs' after it
+    assert names.index("kda_mix_roofline") == names.index("kda_alpha_mean") + 1
+    assert metric["workloads"] == ["kimi-linear-48b-a3b.steady-1g-16k"]
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
         metric["layer"], metric["unit"], metric["moves"], metric["source"]) == ("kernels", "%", "tokens_per_s", "device_trace")
     ctx = {"peaks": peaks, "bench": bench, "config": config, "traffic": traffic}

@@ -470,7 +470,8 @@ def test_k_major_row_moves_match_the_t_major_form(k, held) -> None:
     ct = jax.random.normal(ks[5], (tokens, hidden), jnp.float32)
 
     def k_major(xf, gate_vals, *w):
-        return _dropless_ffn(xf, gate_vals, gate_idx, *w, n_exp=n_exp, first=first, rows_factor=factor, mesh=None)
+        # the fourth result is ReLU's count of live units: None under SiLU
+        return _dropless_ffn(xf, gate_vals, gate_idx, *w, n_exp=n_exp, first=first, rows_factor=factor, mesh=None)[:3]
 
     def t_major(xf, gate_vals, *w):
         return _t_major_ffn(xf, gate_vals, gate_idx, *w, n_exp=n_exp, first=first, rows_factor=factor)

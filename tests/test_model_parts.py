@@ -84,6 +84,14 @@ MODELS["kimi"] = TransformerConfig(**dict(
     remat_keeps_attention=True, scan_unroll=8,
     pattern=(LayerKind("kda_dense", False, 2, **_KDA),) + (LayerKind("kda_layers", True, 2, **_KDA),) * 2
     + (LayerKind("mla_layers", True, 2, **_NOPE), LayerKind("kda_layers", True, 2, **_KDA))))
+# The ninth: a router that reads the layer's input before attention, ReGLU experts under a softmax over the kept,
+# window layers under RoPE 3 : 1 with un-rotated full layers at seven query heads a KV head, two whole periods.
+MODELS["smallthinker"] = TransformerConfig(**dict(
+    _BASE, n_layers=8, n_heads=7, n_kv_heads=1, head_dim=16, moe_experts=8, moe_top_k=3, d_ff=32,
+    moe_capacity_factor=None, moe_held=(2, 2), moe_aux_coef=0.0, moe_router_early=True, moe_activation="relu",
+    remat=True, remat_keeps_attention=True, scan_unroll=8,
+    pattern=(LayerKind("layers", True, 7, 1.5e6, rotary_fraction=0.0),
+             *(LayerKind("window_layers", True, 7, 1.5e6, window=16),) * 3) * 2))
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
@@ -172,6 +180,30 @@ def test_the_scopes_change_no_instruction(programs, name) -> None:
     assert "jvp(embed)" not in plain and "attn_proj" not in plain
     assert without_metadata(plain) == without_metadata(scoped)
     assert canonical(plain) == canonical(scoped)
+
+
+def _first_of(name: str, part: str) -> int:
+    """The place, among the forward pass's equations in the order they were
+    traced, of the first one whose innermost scope is `part`."""
+    step, params, batch = _step_and_arguments(name)
+    cfg = MODELS[name]
+    jaxpr = jax.make_jaxpr(lambda p, b: loss_and_counters(p, b, dataclasses.replace(cfg, remat=False))[0])(params, batch)
+    for i, eqn in enumerate(jaxpr.jaxpr.eqns):
+        scopes = [word for word in str(eqn.source_info.name_stack).split("/") if word in PARTS]
+        if scopes and scopes[-1] == part:
+            return i
+    raise AssertionError(f"no equation of {part} in {name}")
+
+
+@pytest.mark.parametrize("name,early", [("smallthinker", True), ("laguna", False), ("olmoe", False)])
+def test_an_early_router_stands_before_attention(name, early) -> None:
+    """`moe_router_early`: the part `router` — scores from the layer's input,
+    the choice, the gates — is traced before the layer's attention, its
+    projections included; every other model routes after it."""
+    router = _first_of(name, "router")
+    assert (router < _first_of(name, "norm")) == early  # the layer's first norm: the early router reads the raw stream
+    assert (router < _first_of(name, "attn")) == early
+    assert router < _first_of(name, "experts")
 
 
 _RECORDED = os.path.join(ROOT, "tests", "data", "hlo_before_the_pattern.json")

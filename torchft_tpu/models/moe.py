@@ -96,6 +96,18 @@ and the router z-loss ``mean(logsumexp(logits)^2)`` (arXiv:2202.08906).
 A **shared expert** (``moe_layer(shared=...)``) is one SwiGLU every token
 passes beside the routed ones; every device of an expert-parallel layer
 computes it alike.
+
+The gated product's activation is the caller's (``activation``, ``ACTIVATIONS``):
+SiLU, or ReLU (ReGLU, SmallThinker's experts) — whose derivative is the
+mask ``gate > 0`` and nothing else of the gate, and which leaves a share of
+each row's hidden units at exactly zero: ``stats["active_units"]`` counts the
+others over the rows that hold an assignment.
+
+The scores' input need not be the experts' (``routing``, ``moe_layer(routed=
+...)``): a router that reads the layer's input BEFORE its mixer is routed
+there, by the layer, and the experts take the choice with the normed stream
+after the mixer.  The gates' cotangent then flows into the stream the router
+read.
 """
 
 from __future__ import annotations
@@ -115,6 +127,9 @@ Stats = Dict[str, jax.Array]
 # A share's dropless row buffer as a multiple of its even share of the
 # assignments: shapes are static, so the buffer needs a bound (`held_rows`).
 HELD_ROWS_FACTOR = 2.0
+
+# What a gated feed-forward applies to its gate: SwiGLU's or ReGLU's.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def moe_capacity(tokens: int, n_experts: int, top_k: int, capacity_factor: float) -> int:
@@ -314,14 +329,16 @@ def held_rows(n_assign: int, n_exp: int, count: int, factor: float, row_tile: in
 
 
 def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first, rows_factor, mesh,
-                  skip: bool = False):
+                  skip: bool = False, activation: str = "silu"):
     """xf [T, E] in the compute type; gate_vals, gate_idx [T, k] over the
     router's ``n_exp`` experts — and, with ``skip``, the choice ``n_exp`` that
     takes none: like an expert held elsewhere it gets no row, and the buffer
     is sized as if no position took it; the matrices those of the held experts
     ``first ... first + count - 1``.  Returns (y [T, E], assignments that
     fell on held experts, those of them that found no row — none where
-    every expert is held, by the buffer's size)."""
+    every expert is held, by the buffer's size — and under ReLU the (row,
+    hidden unit) pairs that are not zero over the rows an assignment landed
+    in, else None)."""
     tokens, k = gate_idx.shape
     count = w_gate.shape[0]
     n_assign = tokens * k
@@ -358,15 +375,20 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
     xs = _rows_of_tokens(xf, row_assignment // k, dest, every_row_exists)
     gate = grouped_matmul(xs, w_gate, sizes, row_tile=row_tile, mesh=mesh)
     up = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
-    out = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes, row_tile=row_tile, mesh=mesh)
+    out = grouped_matmul(ACTIVATIONS[activation](gate) * up, w_down, sizes, row_tile=row_tile, mesh=mesh)
     y = _tokens_of_rows(out, gate_vals, dest, row_assignment, every_row_exists)
-    return y, n_held.astype(jnp.int32), dropped
+    active = None
+    if activation == "relu":
+        landed = (row_assignment < n_assign)[:, None]  # a padding row repeats a token's and counts nothing
+        active = jnp.sum(((ACTIVATIONS[activation](gate) != 0) & landed).astype(jnp.int32))
+    return y, n_held.astype(jnp.int32), dropped, active
 
 
 # -- capacity-bound: dense dispatch/combine tensors ---------------------------
 
 
-def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dtype, mesh, rules):
+def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dtype, mesh, rules,
+                  activation: str = "silu"):
     """xf [T, E]; returns (y [T, E], assignments dropped over capacity)."""
     T = xf.shape[0]
     n_exp = w_gate.shape[0]
@@ -399,7 +421,7 @@ def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dt
     # all-to-alls once partitioned.
     xin = jnp.einsum("tec,td->ecd", dispatch.astype(dtype), xf.astype(dtype))
     xin = constrain(xin, ("expert", None, "embed"), mesh, rules)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xin, w_gate.astype(dtype)))
+    h = ACTIVATIONS[activation](jnp.einsum("ecd,edf->ecf", xin, w_gate.astype(dtype)))
     h = h * jnp.einsum("ecd,edf->ecf", xin, w_up.astype(dtype))
     h = constrain(h, ("expert", None, "mlp"), mesh, rules)
     out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(dtype))
@@ -409,6 +431,32 @@ def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dt
 
 
 # -- the layer ------------------------------------------------------------------
+
+
+def routing(x: jax.Array, router, *, top_k: int, norm_topk: bool = True, score: str = "softmax",
+            route_bias: Optional[jax.Array] = None, route_scale: float = 1.0,
+            router_state: Optional[jax.Array] = None, skip: bool = False, rms_eps: float = 1e-5):
+    """The router's part of a layer, from ITS input x [B, S, E] (``moe_layer``
+    says what the arguments are): (gates [T, k] f32, chosen outputs [T, k],
+    the router's statistics).  ``moe_layer`` calls it on the experts' input;
+    a layer whose router reads another tensor calls it there and hands the
+    result on (``moe_layer(routed=...)``)."""
+    # The scopes are parts of obs/spans.PARTS: they name the work for a profile.
+    with jax.named_scope("router"):
+        T = x.shape[0] * x.shape[1]
+        logits = new_state = None
+        if isinstance(router, dict):
+            logits, new_state = state_router_logits(x, router, router_state, rms_eps)
+        logits, probs, gate_vals, gate_idx = route(
+            x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale, logits=logits)
+        n_exp = logits.shape[-1] - int(skip)
+        stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
+        if skip:
+            sent = stats["tokens_per_expert"]
+            stats.update(tokens_per_expert=sent[:n_exp], skipped=sent[n_exp])
+        if new_state is not None:
+            stats["router_state"] = new_state
+        return gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k), stats
 
 
 def moe_layer(
@@ -430,6 +478,8 @@ def moe_layer(
     held_first: int = 0,
     held_rows_factor: float = HELD_ROWS_FACTOR,
     shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    activation: str = "silu",
+    routed: Optional[Tuple[jax.Array, jax.Array, Stats]] = None,
     dtype: Any = jnp.bfloat16,
     mesh=None,
     rules: Optional[ShardingRules] = None,
@@ -456,6 +506,11 @@ def moe_layer(
             of its even share of the assignments (``held_rows``).
         shared: (gate [E, Fs], up [E, Fs], down [Fs, E]) of a SwiGLU every
             token passes beside the routed experts, or None.
+        activation: the gated products' ("silu" | "relu"), the shared
+            expert's too.
+        routed: ``routing``'s result where the scores' input is not x (the
+            router's arguments above are then unused here), or None: x is
+            routed here.
 
     Returns:
         (y [B, S, E], stats): ``balance`` and ``z`` (scalar f32 auxiliary
@@ -465,27 +520,21 @@ def moe_layer(
         outputs each position took), ``assignments`` (int32, B * S * k),
         ``rows_held`` (int32, those that fell on held experts) and
         ``dropped`` (int32, assignments to HELD experts that reached none: 0
-        on the dropless path with every expert held, by construction).
+        on the dropless path with every expert held, by construction); on the
+        dropless path under ReLU also ``active_units`` (int32, the (row,
+        hidden unit) pairs that are not zero, over the rows an assignment
+        landed in — of ``(rows_held - dropped) * F``).
     """
     rules = rules or ShardingRules()
     B, S, E = x.shape
     held = w_gate.shape[0]
     T = B * S
-    # The scopes are parts of obs/spans.PARTS: they name the work for a profile.
-    with jax.named_scope("router"):
-        logits = new_state = None
-        if isinstance(router, dict):
-            logits, new_state = state_router_logits(x, router, router_state, rms_eps)
-        logits, probs, gate_vals, gate_idx = route(
-            x, router, top_k, norm_topk, score=score, bias=route_bias, scale=route_scale, logits=logits)
-        n_exp = logits.shape[-1] - int(skip)
-        stats = router_stats(logits, probs, gate_idx, per_choice=score == "sigmoid")
-        if skip:
-            sent = stats["tokens_per_expert"]
-            stats.update(tokens_per_expert=sent[:n_exp], skipped=sent[n_exp])
-        if new_state is not None:
-            stats["router_state"] = new_state
-        gate_vals, gate_idx = gate_vals.reshape(T, top_k), gate_idx.reshape(T, top_k)
+    if routed is None:
+        routed = routing(x, router, top_k=top_k, norm_topk=norm_topk, score=score, route_bias=route_bias,
+                         route_scale=route_scale, router_state=router_state, skip=skip, rms_eps=rms_eps)
+    gate_vals, gate_idx, stats = routed
+    stats = dict(stats)
+    n_exp = stats["tokens_per_expert"].shape[-1]
     with jax.named_scope("experts"):
         xf = x.reshape(T, E)
         if capacity_factor is None:
@@ -496,10 +545,13 @@ def moe_layer(
                     "(set moe_capacity_factor)"
                 )
             assert 0 <= held_first and held_first + held <= n_exp, (held_first, held, n_exp)
-            y, rows_held, dropped = _dropless_ffn(
+            y, rows_held, dropped, active = _dropless_ffn(
                 xf.astype(dtype), gate_vals, gate_idx, w_gate, w_up, w_down,
                 n_exp=n_exp, first=held_first, rows_factor=held_rows_factor, mesh=mesh, skip=skip,
+                activation=activation,
             )
+            if active is not None:
+                stats["active_units"] = active
         else:
             if held != n_exp or skip:
                 raise ValueError("the capacity-bound path holds every expert (shard them over an 'expert' mesh axis) "
@@ -507,6 +559,7 @@ def moe_layer(
             y, dropped = _capacity_ffn(
                 xf, gate_vals, gate_idx, w_gate, w_up, w_down,
                 capacity=moe_capacity(T, n_exp, top_k, capacity_factor), dtype=dtype, mesh=mesh, rules=rules,
+                activation=activation,
             )
             rows_held = jnp.asarray(T * top_k, jnp.int32)
         stats.update(dropped=dropped, rows_held=rows_held, assignments=jnp.asarray(T * top_k, jnp.int32))
@@ -514,7 +567,7 @@ def moe_layer(
     if shared is not None:
         with jax.named_scope("shared_expert"):
             s_gate, s_up, s_down = (w.astype(dtype) for w in shared)
-            y = y + ((jax.nn.silu(x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
+            y = y + ((ACTIVATIONS[activation](x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
     return y, stats
 
 

@@ -198,6 +198,17 @@ class TransformerConfig:
     # causal convolutions on q, k and v.
     kda_head_dim: int = 128
     kda_conv: int = 4
+    # The router reads the LAYER'S INPUT — the residual stream before the
+    # layer's first norm and its mixer — and not the experts' own input
+    # (SmallThinker's `moe_enable_early_router`): the choice and the gates are
+    # made before attention, the experts take them with the normed stream after
+    # it, and the gates' cotangent flows into the stream the router read.
+    moe_router_early: bool = False
+    # The activation of every gated feed-forward (experts, shared expert,
+    # dense layers): "silu" (SwiGLU) or "relu" (ReGLU).  Under "relu" the held
+    # experts' rows count their hidden units that are not zero
+    # (`moe_active_units` of `moe_units_held`, loss_and_counters).
+    moe_activation: str = "silu"
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -208,6 +219,11 @@ class TransformerConfig:
             f"unknown ring_layout {self.ring_layout!r}"
         )
         assert self.moe_score in ("softmax", "sigmoid"), f"unknown moe_score {self.moe_score!r}"
+        assert self.moe_activation in ("silu", "relu"), f"unknown moe_activation {self.moe_activation!r}"
+        if self.moe_router_early:
+            assert self.moe_experts > 0 and not self.moe_router_state, (
+                "an early router is one matrix over the layer's input: a router with a state reads the experts'"
+            )
         if self.mla_kv_rank:
             assert self.attention == "flash" and not self.qk_norm, (
                 "latent attention runs the flash backend, without a QK-norm"
@@ -933,6 +949,11 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     kind = cfg.layers[-1] if kind is None else kind
     H, KV = kind.n_heads, cfg.n_kv_heads
 
+    routed = None
+    if cfg.moe_router_early and kind.sparse:
+        from torchft_tpu.models.moe import routing
+
+        routed = routing(x, w["router"], **_router_form(cfg, router_bias, router_state))
     # The scopes are the parts a profile's device time is booked to
     # (obs/spans.PARTS); they name the work and change no instruction.
     with jax.named_scope("norm"):
@@ -942,7 +963,7 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         with jax.named_scope("attn_proj"):
             x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
             x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
-        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"kda_alpha": alpha})
+        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"kda_alpha": alpha}, routed)
     with jax.named_scope("attn_proj"):
         if kind.mixer == "cca":
             q, k, v = _cca_qkv(cfg, kind, h, w, positions)
@@ -965,8 +986,9 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
                 with jax.named_scope("norm"):
                     q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
             v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
-            q = _rotary(q, positions, kind)
-            k = _rotary(k, positions, kind)
+            if kind.rotary_fraction:  # 0: no position term, q and k are the projections
+                q = _rotary(q, positions, kind)
+                k = _rotary(k, positions, kind)
         if cfg.attn_head_gate:
             head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
         # compressed attention's heads are head-major already
@@ -987,14 +1009,22 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         attn = attn.reshape(B, S, H * attn.shape[-1])
         x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
-    return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa)
+    return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa, routed)
+
+
+def _router_form(cfg: TransformerConfig, router_bias, router_state) -> Dict[str, Any]:
+    """The router's settings as `moe.routing` and `moe.moe_layer` take them."""
+    return dict(top_k=cfg.moe_top_k, norm_topk=cfg.moe_norm_topk, score=cfg.moe_score, route_bias=router_bias,
+                route_scale=cfg.moe_route_scale, router_state=router_state, skip=cfg.moe_skip, rms_eps=cfg.rms_eps)
 
 
 def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind: LayerKind, router_bias,
-                  router_state, mixer_stats):
+                  router_state, mixer_stats, routed=None):
     """The second half of a decoder block, from the stream x after the mixer:
     the kind's feed-forward and what `_layer` hands back.  `mixer_stats`: the
-    statistics the mixer counted (a dict), or None."""
+    statistics the mixer counted (a dict), or None.  `routed`: the choice an
+    early router made on the layer's input (`moe.routing`'s result), or None:
+    the experts' input is routed."""
     with jax.named_scope("norm"):
         h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
     if kind.sparse:
@@ -1006,20 +1036,15 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
             w["w_gate"],
             w["w_up"],
             w["w_down"],
-            top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
-            norm_topk=cfg.moe_norm_topk,
-            score=cfg.moe_score,
-            route_bias=router_bias,
-            route_scale=cfg.moe_route_scale,
-            router_state=router_state,
-            skip=cfg.moe_skip,
-            rms_eps=cfg.rms_eps,
             held_first=cfg.moe_held[0] if cfg.moe_held is not None else 0,
             shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
+            activation=cfg.moe_activation,
+            routed=routed,
             dtype=cfg.dtype,
             mesh=mesh,
             rules=rules,
+            **_router_form(cfg, router_bias, router_state),
         )
         if cfg.moe_router_state:
             router_state = aux.pop("router_state")
@@ -1027,7 +1052,9 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
             x = _merge(x, y, w.get("mlp_merge"))
     else:
         with jax.named_scope("ffn"):
-            gate = jax.nn.silu(h @ w["w_gate"].astype(cfg.dtype))
+            from torchft_tpu.models.moe import ACTIVATIONS
+
+            gate = ACTIVATIONS[cfg.moe_activation](h @ w["w_gate"].astype(cfg.dtype))
             up = h @ w["w_up"].astype(cfg.dtype)
             x = _merge(x, (gate * up) @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
         aux = {} if mixer_stats is not None else jnp.zeros((), jnp.float32)
@@ -1348,7 +1375,11 @@ def loss_and_counters(
     device holds a share of the experts (``cfg.moe_held``) also
     ``moe_assignments`` (int32, all (token, expert) choices of the sparse
     layers) and ``moe_rows_held`` (int32, those that fell on held experts);
-    for a dense model nothing.  ``router_bias`` [n_sparse_layers,
+    where the experts are ReGLU (``cfg.moe_activation == "relu"``, the dropless
+    path) also ``moe_active_units`` (int32, the (row, hidden unit) pairs of the
+    held experts' rows that ReLU left above zero, over the sparse layers) and
+    ``moe_units_held`` (int32, all such pairs: rows that hold an assignment
+    times ``d_ff``); for a dense model nothing.  ``router_bias`` [n_sparse_layers,
     n_experts] is the sigmoid router's choice bias: a constant, no leaf of
     ``params``, so neither the gradient nor the optimizer sees it."""
     x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
@@ -1375,4 +1406,8 @@ def loss_and_counters(
             counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:
             counters.update(moe_assignments=aux["assignments"], moe_rows_held=aux["rows_held"])
+        if "active_units" in aux:
+            assert cfg.n_sparse_layers * batch["tokens"].size * cfg.moe_top_k * cfg.d_ff < 2 ** 31, "int32 counters"
+            counters.update(moe_active_units=aux["active_units"],
+                            moe_units_held=(aux["rows_held"] - aux["dropped"]) * cfg.d_ff)
         return loss, counters

@@ -173,7 +173,9 @@ SUBSPANS = {
 #     that attends under a window (the `tpuft_swa_*` kernels), so that a
 #     model of both kinds reads them apart; dsa_index — the indexer's operands and
 #     its loss; dsa_select — the selection and the mask built from it;
-#   ffn — the dense gate / up / down; router — scores, top-k and statistics;
+#   ffn — the dense gate / up / down; router — scores, top-k and statistics
+#     (where the router reads the layer's input, `moe_router_early`, it
+#     stands BEFORE the layer's first norm and its attention);
 #     experts — row table, row moves, grouped matmuls, gate weighting;
 #     shared_expert — the SwiGLU every token passes beside the routed ones;
 #   head_loss — final norm, head product, cross-entropy, the loss's terms
