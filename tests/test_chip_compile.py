@@ -140,6 +140,8 @@ def test_one_pass_backward_compiles_for_v5e(one_chip, bh, seq, d_qk, d_v) -> Non
         qk, qk, v, v, lse, v,
     )
     assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"]
+    # four heads a grid step at 4,096 positions, two at 32,768, one at 65,536 (two 64 MiB rows do not fit VMEM)
+    assert _heads_a_step(text, "tpuft_fa_", bh) == {"tpuft_fa_bwd_dkdv_dq": [{4096: 4, 32768: 2, 65536: 1}[seq]]}
 
 
 def test_long_context_two_pass_backward_compiles_for_v5e(one_chip) -> None:
@@ -251,6 +253,7 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(olmoe_program) -> 
     text = compiled.as_text()
     for name in ("tpuft_gmm_fwd", "tpuft_gmm_dlhs", "tpuft_gmm_drhs", "tpuft_fa_fwd", "tpuft_ce_lse", "tpuft_ce_dlogits"):
         assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    assert _heads_a_step(text, "tpuft_fa_", 32) == {"tpuft_fa_fwd": [8], "tpuft_fa_bwd_dkdv_dq": [4]}  # 2 x 16 heads x 4,096
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
@@ -409,9 +412,9 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:
     v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
     text = _compile(lambda q, k, v_: _fa_pallas_call(q, k, v_, 192 ** -0.5, True), qk, qk, v)
-    assert _attention_calls(text) == ["tpuft_fa_fwd"]
+    assert _attention_calls(text) == ["tpuft_fa_fwd"] and _heads_a_step(text, "tpuft_fa_", bh) == {"tpuft_fa_fwd": [8]}
     text = _compile(lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, 192 ** -0.5, True), qk, qk, v, v, lse, v)
-    assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"]
+    assert _attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"] and _heads_a_step(text, "tpuft_fa_", bh) == {"tpuft_fa_bwd_dkdv_dq": [4]}
 
 
 def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
@@ -447,6 +450,8 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     calls = _attention_calls(text)
     layers = config["num_hidden_layers"]
     assert sorted(calls) == ["tpuft_fa_bwd_dkdv_dq"] * layers + ["tpuft_fa_fwd"] * layers, calls
+    # 2 x 16 heads: eight a grid step forward, four backward (8 MiB dq rows)
+    assert _heads_a_step(text, "tpuft_fa_", 32) == {"tpuft_fa_fwd": [8], "tpuft_fa_bwd_dkdv_dq": [4]}
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
@@ -492,6 +497,17 @@ def _kernel_grids(text: str, prefix: str) -> list:
         kernel = next(op for op in module.body.operations if "iteration_bounds" in op.attributes)
         found.append((name.group(0), tuple(kernel.attributes["iteration_bounds"])))
     return found
+
+
+def _heads_a_step(text: str, prefix: str, bh: int) -> dict:
+    """{kernel name: heads a grid step} over the compiled calls whose names
+    start with `prefix`, each at batch * heads = ``bh``: the grid's outer axis
+    is bh / H (since PR 52)."""
+    found = {}
+    for name, grid in _kernel_grids(text, prefix):
+        assert bh % grid[0] == 0, (name, grid)
+        found.setdefault(name, set()).add(bh // grid[0])
+    return {name: sorted(heads) for name, heads in found.items()}
 
 
 def test_sparse_attention_kernels_compile_for_v5e(one_chip) -> None:
@@ -559,6 +575,8 @@ def test_keye_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
                  "tpuft_dsa_mask", "tpuft_dsa_select"]
     assert sorted(_kernel_calls(text, "tpuft_dsa_")) == sorted(per_layer * layers)
     assert _attention_calls(text) == []
+    # a KV head's eight query heads a grid step forward, two of them backward (16 MiB dq rows)
+    assert _heads_a_step(text, "tpuft_dsa_attn_", 32) == {"tpuft_dsa_attn_fwd": [8], "tpuft_dsa_attn_bwd_dkdv_dq": [2]}
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("dsa_moe_lm").total_params(config)
@@ -622,7 +640,10 @@ def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, m
     # 2n - 1 = 63 tiles with a visible pair a head, where the triangle has 528
     grids = bench.reader("swa_pairs_share").grids(text)
     assert sorted(g["name"] for g in grids) == ["tpuft_swa_bwd_dkdv_dq"] * 3 + ["tpuft_swa_fwd"] * 3
-    assert all((g["grid"], g["block_q"], g["seq"]) == ([64, 63], 512, 16_384) for g in grids), grids
+    # ... eight heads a grid step forward and four backward (two 16 MiB dq rows and their tiles a pair of heads)
+    assert all((g["grid"], g["block_q"], g["seq"]) == ([8 if g["name"].endswith("fwd") else 16, 63], 512, 16_384)
+               for g in grids), grids
+    assert sorted(_kernel_grids(text, "tpuft_fa_")) == [("tpuft_fa_bwd_dkdv_dq", (12, 528))] * 2 + [("tpuft_fa_fwd", (6, 528))] * 2
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("swa_moe_lm").total_params(config) == 691_623_936
@@ -664,6 +685,7 @@ def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     # attention's output kept under remat: one forward and one backward kernel a layer
     assert config["program"]["remat_keeps_attention"]
     assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq"] * 4 + ["tpuft_fa_fwd"] * 4
+    assert _heads_a_step(text, "tpuft_fa_", 8) == {"tpuft_fa_fwd": [8], "tpuft_fa_bwd_dkdv_dq": [4]}
     # three projections a layer: forward, recomputed, and the two gradients
     gmm = _kernel_calls(text, "tpuft_gmm_")
     assert sorted(gmm) == ["tpuft_gmm_dlhs"] * 12 + ["tpuft_gmm_drhs"] * 12 + ["tpuft_gmm_fwd"] * 24
@@ -812,6 +834,8 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     assert sorted(_kernel_calls(text, "tpuft_kdamix_")) == (
         ["tpuft_kdamix_bwd"] * 4 + ["tpuft_kdamix_fwd"] * 8 + ["tpuft_kdamix_out_bwd"] * 4 + ["tpuft_kdamix_out_fwd"] * 8)
     assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
+    # the latent layer's 32 heads at 256 / 128: eight a grid step forward, two backward (16 MiB dq rows)
+    assert _heads_a_step(text, "tpuft_fa_", 32) == {"tpuft_fa_fwd": [8], "tpuft_fa_bwd_dkdv_dq": [2]}
     # three projections a sparse layer: forward, recomputed, and the two gradients
     gmm = _kernel_calls(text, "tpuft_gmm_")
     assert sorted(gmm) == ["tpuft_gmm_dlhs"] * 12 + ["tpuft_gmm_drhs"] * 12 + ["tpuft_gmm_fwd"] * 24
@@ -868,13 +892,16 @@ def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_c
     # visible pair a head (rows of 1 ... 8 tiles, then 24 rows of 9), the full layers the triangle's 528
     grids = bench.reader("swa_pairs_share").grids(text)
     assert sorted(g["name"] for g in grids) == ["tpuft_swa_bwd_dkdv_dq"] * 6 + ["tpuft_swa_fwd"] * 6
-    assert all((g["grid"], g["block_q"], g["seq"]) == ([28, 252], 512, 16_384) for g in grids), grids
-    assert sorted(grid for _, grid in _kernel_grids(text, "tpuft_fa_")) == [(28, 528)] * 4
+    # ... 28 heads: seven a grid step forward, four backward
+    assert all((g["grid"], g["block_q"], g["seq"]) == ([4 if g["name"].endswith("fwd") else 7, 252], 512, 16_384)
+               for g in grids), grids
+    assert sorted(_kernel_grids(text, "tpuft_fa_")) == [("tpuft_fa_bwd_dkdv_dq", (7, 528))] * 2 + [("tpuft_fa_fwd", (4, 528))] * 2
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("early_router_moe_lm").total_params(config) == 643_852_800
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     # 15,835,302,912 (arguments 2,575,585,280 + outputs 2,575,461,888 + temporaries 5,533,433,344 + moments
     # 5,150,822,400; builder's compile, PR 51) and an allocator's peak of 11.70 GB on the chip; with nothing kept
-    # under remat 14,735,490,048, without remat 20,776,999,424
-    assert resident <= 15_835_302_912, f"the step needs {resident} bytes with AdamW's moments"
+    # under remat 14,735,490,048, without remat 20,776,999,424; since PR 52, with several heads a grid step in the
+    # attention kernels, the temporaries are 258,048 bytes more (builder's compile): 15,835,560,960
+    assert resident <= 15_835_560_960, f"the step needs {resident} bytes with AdamW's moments"

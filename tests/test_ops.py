@@ -184,8 +184,9 @@ def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked
     mask (a seeded third of the visible pairs, the diagonal among them)
     against dense masked softmax attention and its autodiff.  With
     `kv_group` the kernels read one KV head for a group of query heads and
-    give dk, dv a query head each.  Each call's grid is (heads, n (n + 1) /
-    2): a step for each tile of the lower triangle and no other."""
+    give dk, dv a query head each.  Each call's grid is (heads / H, n (n +
+    1) / 2): a step for each tile of the lower triangle and no other, H heads
+    a step."""
     from torchft_tpu.ops import attention as fa
     from torchft_tpu.ops import sparse_attention as sa
 
@@ -215,8 +216,15 @@ def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked
     assert [a.shape for a in got] == [q.shape, (heads, seq, d_qk), (heads, seq, d_v)]
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
-    grids = {**pallas_call_grids(fwd, q, k, v), **pallas_call_grids(bwd, q, k, v, got_o, got_lse, g)}
-    assert set(grids.values()) == {(heads, n * (n + 1) // 2)} and len(grids) == 2, grids
+    # H heads a grid step, H read from the shapes: all of these heads (one KV head's, or a batch
+    # entry's) forward; backward as many of them as their dq rows leave room for
+    tiles, share = n * (n + 1) // 2, fa._heads_share(heads, more.get("mask"), kv_group)
+    fwd_heads = fa._heads_per_step(share)
+    bwd_heads = fa._bwd_heads_per_step(share, fa._row_vmem_bytes(seq, d_qk, 4))
+    assert fwd_heads == heads and bwd_heads > 1
+    assert pallas_call_grids(fwd, q, k, v) == {("tpuft_dsa_attn_fwd" if masked else "tpuft_fa_fwd"): (heads // fwd_heads, tiles)}
+    assert pallas_call_grids(bwd, q, k, v, got_o, got_lse, g) == {
+        ("tpuft_dsa_attn_bwd_dkdv_dq" if masked else "tpuft_fa_bwd_dkdv_dq"): (heads // bwd_heads, tiles)}
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
@@ -248,6 +256,122 @@ def test_short_rows_of_dq_are_cast_out_at_their_diagonal_step(masked) -> None:
         assert np.linalg.norm(dq[:, rows] - want[:, rows]) < 0.01 * np.linalg.norm(want[:, rows]), f"q tile {qi}"
 
 
+@pytest.mark.parametrize("heads_per_step", [2, 4])
+@pytest.mark.parametrize("kind", ["causal", "rectangle", "window", "masked_kv_group_8", "unequal_widths"])
+def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> None:
+    """out, lse, dq, dk, dv with H heads a grid step — every block and scratch
+    leading with the heads, the tile's arithmetic under `jax.vmap` — are bit
+    for bit those of one head a step: over the triangle, a rectangle (queries
+    against a longer key sequence, not causal), the band, a packed mask whose
+    eight query heads read one KV head in place (and share the mask's tile),
+    and query and key 256 wide beside a value of 128."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    bh, seq_q, seq_k, d, dv, kv_group = 8, 1024, 1024, 128, 128, 1
+    causal, more = True, {}
+    if kind == "rectangle":
+        causal, seq_k = False, 1536
+    elif kind == "window":
+        seq_q = seq_k = 1536
+        more["window"] = 600
+    elif kind == "masked_kv_group_8":
+        kv_group = 8
+        keep = jax.random.bernoulli(jax.random.PRNGKey(5), 0.3, (seq_q, seq_q)) | jnp.eye(seq_q, dtype=bool)
+        more.update(mask=sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8), kv_group=8)
+    elif kind == "unequal_widths":
+        bh, d = 4, 256
+    ks = jax.random.split(jax.random.PRNGKey(len(kind)), 4)
+    q = jax.random.normal(ks[0], (bh, seq_q, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (bh // kv_group, seq_k, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (bh // kv_group, seq_k, dv), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (bh, seq_q, dv), jnp.bfloat16)
+
+    def kernels(heads):
+        fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        o, lse = fwd(q, k, v)
+        grids = {**pallas_call_grids(fwd, q, k, v), **pallas_call_grids(bwd, q, k, v, o, lse, g)}
+        assert len(grids) == 2 and {grid[0] for grid in grids.values()} == {bh // heads}, grids
+        return (o, lse) + tuple(bwd(q, k, v, o, lse, g))
+
+    want = kernels(1)
+    assert all(float(jnp.abs(x.astype(jnp.float32)).max()) > 0.01 for x in want)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), kernels(heads_per_step), want):
+        assert a.dtype == b.dtype and a.shape == b.shape and bool(jnp.array_equal(a, b)), name
+
+
+def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
+    """H is read from the shapes: the largest divisor of the heads not above
+    `HEADS_PER_STEP` whose dq rows and tiles fit VMEM.  At 65,536 x 128 one
+    head's row, its output block and tiles are 80 MiB: two do not fit, the
+    backward stays at one head a step while the forward, which keeps no row,
+    takes both; the two-pass form over a longer row keeps no row either."""
+    from torchft_tpu.ops import attention as fa
+
+    mib = 2 ** 20
+    row = fa._row_vmem_bytes(65536, 128, 2)
+    assert row == 64 * mib and 2 * (row + fa._TILE_VMEM_BYTES) > fa._VMEM_BUDGET
+    assert fa._bwd_heads_per_step(8, row) == 1 and fa._bwd_heads_per_step(8, 0) == fa.HEADS_PER_STEP == 8
+    assert [fa._bwd_heads_per_step(32, fa._row_vmem_bytes(seq, d, 2)) for seq, d in
+            ((4096, 128), (8192, 256), (16384, 128), (16384, 256), (32768, 128))] == [4, 4, 4, 2, 2]
+    assert [fa._heads_per_step(share) for share in (1, 2, 7, 8, 28, 32, 48, 64)] == [1, 2, 7, 8, 7, 8, 8, 8]
+    bh, seq = 2, 65536
+    n = seq // 512
+    qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    assert pallas_call_grids(functools.partial(fa._fa_pallas_call, scale=0.088, causal=True), qkv, qkv, qkv) == {
+        "tpuft_fa_fwd": (1, n * (n + 1) // 2)}
+    assert pallas_call_grids(functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), qkv, qkv, qkv, qkv, lse, qkv) == {
+        "tpuft_fa_bwd_dkdv_dq": (2, n * (n + 1) // 2)}
+    longer = jax.ShapeDtypeStruct((bh, seq + 512, 128), jnp.bfloat16)
+    assert {grid[0] for grid in pallas_call_grids(
+        functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), longer, longer, longer, longer,
+        jax.ShapeDtypeStruct((bh, seq + 512), jnp.float32), longer).values()} == {1}
+
+
+# (batch * heads, positions, query and key width, value width, window, query heads a KV head under a mask): the forward's
+# and the backward's heads a grid step
+CELL_SHAPES = {
+    "dense_16_heads": ((32, 4096, 128, 128, None, None), (8, 4)),
+    "dense_32_heads": ((64, 4096, 128, 128, None, None), (8, 4)),
+    "moonlight": ((32, 8192, 256, 128, None, None), (8, 4)),
+    "keye_masked": ((32, 32768, 128, 128, None, 8), (8, 2)),
+    "laguna_full": ((48, 16384, 128, 128, None, None), (8, 4)),
+    "laguna_window": ((64, 16384, 128, 128, 512, None), (8, 4)),
+    "zaya": ((8, 16384, 128, 128, None, None), (8, 4)),
+    "kimi": ((32, 16384, 256, 128, None, None), (8, 2)),
+    "smallthinker_full": ((28, 16384, 128, 128, None, None), (7, 4)),
+    "smallthinker_window": ((28, 16384, 128, 128, 4096, None), (7, 4)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_heads_a_grid_step_at_the_cells_shapes(cell) -> None:
+    """The traced `pallas_call`s at every cell's attention shape (no kernel
+    runs): grid (batch * heads / H, tiles) with more than one head a step in
+    both directions."""
+    from torchft_tpu.ops import attention as fa
+
+    (bh, seq, d, dv, window, kv_group), (fwd_heads, bwd_heads) = CELL_SHAPES[cell]
+    n = seq // 512
+    tiles = len(fa._Walk(True, seq, seq, 512, 512, window=window).tables[0])
+    assert tiles == (n * (n + 1) // 2 if window is None else {512: 2 * n - 1, 4096: 252}[window])
+    q = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, dv), jnp.bfloat16)
+    o = jax.ShapeDtypeStruct((bh, seq, dv), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8) if kv_group else None
+    more = {"kv_group": kv_group} if kv_group else {"window": window}
+    family = "tpuft_dsa_attn" if kv_group else "tpuft_fa" if window is None else "tpuft_swa"
+    assert min(fwd_heads, bwd_heads) > 1
+    assert pallas_call_grids(lambda q_, k_, v_, m_: fa._fa_pallas_call(q_, k_, v_, 0.088, True, mask=m_, **more),
+                             q, k, v, mask) == {family + "_fwd": (bh // fwd_heads, tiles)}
+    assert pallas_call_grids(lambda q_, k_, v_, o_, l_, g_, m_: fa._fa_bwd_pallas(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more),
+                             q, k, v, o, lse, o, mask) == {family + "_bwd_dkdv_dq": (bh // bwd_heads, tiles)}
+
+
 @pytest.mark.parametrize("seq", [4096, 8192, 32768])
 def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
     """The traced `pallas_call`s at the cells' three lengths (no kernel
@@ -268,14 +392,18 @@ def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
     def bwd(causal, **more):
         return functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=causal, **more)
 
-    assert pallas_call_grids(fwd(True), qkv, qkv, qkv) == {"tpuft_fa_fwd": (bh, tiles)}
-    assert pallas_call_grids(bwd(True), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (bh, tiles)}
+    # eight heads a step forward; backward as many as their dq rows (seq x 128 x 8 bytes a head) and
+    # tiles leave room for in VMEM: four, four and two
+    f, b = bh // fa._heads_per_step(bh), bh // fa._bwd_heads_per_step(bh, fa._row_vmem_bytes(seq, 128, 2))
+    assert (f, b) == (1, {4096: 2, 8192: 2, 32768: 4}[seq])
+    assert pallas_call_grids(fwd(True), qkv, qkv, qkv) == {"tpuft_fa_fwd": (f, tiles)}
+    assert pallas_call_grids(bwd(True), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (b, tiles)}
     assert pallas_call_grids(lambda q, k, v, m: fwd(True, kv_group=8)(q, k, v, mask=m), qkv, kv, kv, mask) == {
-        "tpuft_dsa_attn_fwd": (bh, tiles)}
+        "tpuft_dsa_attn_fwd": (f, tiles)}
     assert pallas_call_grids(lambda q, k, v, o, l, g, m: bwd(True, kv_group=8)(q, k, v, o, l, g, mask=m),
-                             qkv, kv, kv, qkv, lse, qkv, mask) == {"tpuft_dsa_attn_bwd_dkdv_dq": (bh, tiles)}
-    assert pallas_call_grids(fwd(False), qkv, qkv, qkv) == {"tpuft_fa_fwd": (bh, n, n)}
-    assert pallas_call_grids(bwd(False), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (bh, n, n)}
+                             qkv, kv, kv, qkv, lse, qkv, mask) == {"tpuft_dsa_attn_bwd_dkdv_dq": (b, tiles)}
+    assert pallas_call_grids(fwd(False), qkv, qkv, qkv) == {"tpuft_fa_fwd": (f, n, n)}
+    assert pallas_call_grids(bwd(False), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (b, n, n)}
     # the walk's tables: the forward's row by row (step t is the packed
     # mask's tile t), the backward's column by column
     rows, cols = (np.asarray(t) for t in fa._Walk(True, seq, seq, 512, 512).tables)
@@ -418,8 +546,10 @@ def test_the_band_walk_at_the_window_cells_lengths(seq, block) -> None:
         lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
         fwd = functools.partial(fa._fa_pallas_call, scale=0.088, causal=True, window=window)
         bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True, window=window)
-        assert pallas_call_grids(fwd, qkv, qkv, qkv) == {"tpuft_swa_fwd": (bh, tiles)}
-        assert pallas_call_grids(bwd, qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_swa_bwd_dkdv_dq": (bh, tiles)}
+        f, b = bh // fa._heads_per_step(bh), bh // fa._bwd_heads_per_step(bh, fa._row_vmem_bytes(seq, 128, 2))
+        assert f == 1 and b < bh  # the band's tiles, H heads a step
+        assert pallas_call_grids(fwd, qkv, qkv, qkv) == {"tpuft_swa_fwd": (f, tiles)}
+        assert pallas_call_grids(bwd, qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_swa_bwd_dkdv_dq": (b, tiles)}
 
 
 def test_fused_cross_entropy_matches_and_grads() -> None:

@@ -10,9 +10,10 @@ Design notes (MXU/HBM-minded):
     backward is ONE pallas kernel, `tpuft_fa_bwd_dkdv_dq` (q axis
     innermost): dk/dv accumulate in (block_k, d) f32 VMEM scratch, and dq
     in a third scratch that holds one head's WHOLE (seq_q, d_qk) f32 row
-    while the kv blocks go by — the s/p/dp/ds tile work that these kernels
-    are bound by on the VPU is computed once a tile (5 matrix products
-    where two passes make 7), and the row is cast to the output's dtype
+    while the kv blocks go by — the s/p/dp/ds tile work is computed once a
+    tile (5 matrix products where two passes make 7: the one-pass kernel's
+    compiled schedule is those five products' MXU time, its MXUs' slots 83 to
+    86% taken), and the row is cast to the output's dtype
     inside the kernel, so dq never exists in f32 in HBM.  The row is 2 MiB
     at seq 4,096 x 128, 8 MiB at 8,192 x 256 (MLA) and 16 MiB at 32,768 x
     128 of a v5e's 128 MiB of VMEM; `vmem_limit_bytes` is sized from the
@@ -24,11 +25,17 @@ Design notes (MXU/HBM-minded):
     time to attention by that substring, and a `tpuft_fa_bwd_dq` in a
     trace says the one-pass form did not engage.  Off-TPU the same math
     is expressed in XLA with the scores materialized;
+  - a grid step carries H heads' tile, not one (`HEADS_PER_STEP`): the
+    grid's outer axis is batch*heads / H, every block and scratch leads with
+    the heads, and the tile's arithmetic is a function of values run over
+    them under `jax.vmap` (`_fwd_tile`, `_bwd_tile`, `_heads_at_once`); H is
+    read from the shapes.  A head's arithmetic is what one head a step
+    computes, bit for bit;
   - grid layout: the reduction axis innermost — TPU executes the innermost
     grid dimension sequentially, which is what makes the VMEM scratch
     accumulator legal.  A call that is causal over one sequence (``causal``
     or a mask, ``seq_q == seq_k``) walks the tiles that hold a visible pair
-    and no others: its grid is (batch*heads, T), T = n (n + 1) / 2 for n
+    and no others: its grid is (batch*heads / H, T), T = n (n + 1) / 2 for n
     square tiles a side, and two scalar-prefetched int32 tables give step
     t's (q tile, kv tile) to the kernel and to every index map (`_Walk`).
     The forward goes row by row (kv tiles 0 .. qi under q tile qi: start at
@@ -37,7 +44,7 @@ Design notes (MXU/HBM-minded):
     at the last row; the dq rows of q tile qi are complete, and cast into
     the output, at THEIR diagonal step).  A tile above the diagonal has no
     step, so nothing is issued or fetched for it.  Every other call keeps
-    the rectangle (batch*heads, outer_blocks, inner_blocks): there is
+    the rectangle (batch*heads / H, outer_blocks, inner_blocks): there is
     nothing to skip.  The choice reads ``causal``, the mask and the
     operands' shapes, and nothing else;
   - a **window** (``flash_attention(..., window=w)``: a query at t sees the
@@ -75,7 +82,6 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.ops import _pallas_util
-from torchft_tpu.ops._pallas_util import row_stat_col
 
 _NEG_INF = -1e30
 _LANE = 128  # TPU lane width: scratch row-stats are kept (block_q, 128)
@@ -94,11 +100,21 @@ def _use_pallas(seq_q: int, seq_k: int, d_v: int, mesh=None) -> bool:
 
 
 def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
-    # 512x512: these kernels are VPU-bound on the S^2 elementwise tile, so
-    # the finest block that keeps the MXU fed wins — fatter q blocks were
-    # measured slower because causal masking can only skip whole blocks
-    # (a 1024-row block straddling the diagonal computes 33% more masked
-    # elements at the flagship seq=1024 than two 512-row blocks).
+    # 512x512.  Fatter q blocks were measured slower at the flagship's 32
+    # heads x 1,024 positions (PR 2), where causal masking can only skip
+    # whole blocks: a 1024-row block straddling the diagonal computes 33%
+    # more masked elements than two 512-row blocks.  "VPU-bound", as this
+    # comment used to put it, was read off timings; the compiled schedule at
+    # 28 x 16,384 x 128 (`tools/fa_bwd_probe.py --bundles`, PERF.md section
+    # 6, PR 52) says which unit: none.  A forward tile is 1,813 bundles for
+    # 1,024 cycles of MXU work with the VALUs' slots 39% taken, the XLUs'
+    # 37%, the MXUs' 48%: q k^T streams at the MXUs' rate for ~490 bundles,
+    # then p v's matmuls trickle ~40 bundles apart as the softmax tile's row
+    # groups come out of their chain (row max across lanes, a lane
+    # broadcast, exp, row sum across lanes), three quarters of the tile's
+    # stores being spills of the 256-vreg score tile.  The backward tile is
+    # 2,574 bundles for its five products' 2,560 cycles: MXU-bound as
+    # scheduled.
     # The band walk has the same tiles.  Under a window of 512 a 512 x 512 q
     # tile visits two kv tiles and half of what it computes lies outside the
     # band; at 256 x 256 it visits three, two thirds of them inside, at three
@@ -237,16 +253,135 @@ class _Walk:
         return jax.lax.bitcast_convert_type(rows - cols, jnp.uint32) < jnp.uint32(self.window)
 
 
+# The most heads a grid step carries.  A grid step pays, beside its tile, for
+# its blocks' DMAs (started and waited for once a step) and some 270 bundles of
+# the pipeline's bookkeeping: at one head a step a forward tile of 28 x 16,384
+# x 128 read 1.93 us on a v5e for a compiled schedule of 1,813 bundles (1.21 us
+# at 1.5 GHz), the one-pass backward 2.71 for 2,574 (1.72).  The heads of a
+# call are independent, so a step takes H of them: every block and scratch
+# leads with the heads, and the tile's arithmetic runs over them under
+# `jax.vmap` (`_heads_at_once`), each product one batched product.  Read on
+# the chip (`tools/fa_bwd_probe.py`, PERF.md section 6, PR 52), us a tile:
+# forward 1.93 -> 2.02 (H = 2) -> 1.65 (4) -> 1.50 (7) -> 1.43 (14), one-pass
+# backward 2.71 -> 2.34 (2) -> 2.31 (4), results bit for bit those of one head
+# a step.  What the step's cost shared by H tiles does NOT do is put one
+# head's products under another's softmax tile: the schedule stays at 1,850 to
+# 2,020 bundles a head forward and 2,590 to 2,780 backward at every H (the H
+# first products back to back at the MXUs' rate, then each head's softmax tile
+# pacing its own second product; `--bundles` prints where each product
+# starts).  H is read from the shapes (`_heads_per_step`,
+# `_bwd_heads_per_step`) and nothing else.
+HEADS_PER_STEP = 8
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _heads_per_step(share: int, most: int = HEADS_PER_STEP) -> int:
+    """The largest divisor of ``share`` (`_heads_share`) not above ``most``:
+    the heads of a grid step."""
+    return max(h for h in range(1, min(share, most) + 1) if share % h == 0)
+
+
+def _heads_share(bh: int, mask, kv_group: int) -> int:
+    """What the heads of one grid step have to lie inside: a KV head's query
+    heads where k and v are read in place, a batch entry's heads under a
+    packed mask (its tile is one a step), else the call's batch * heads."""
+    if kv_group > 1:
+        return kv_group
+    return bh if mask is None else bh // mask.shape[0]
+
+
+def _fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale: float):
+    """One head's forward tile as a function of values: the online-softmax
+    update ``(m, l, acc) -> (m, l, acc)`` of q's (block_q, d) rows against
+    one (block_k, d) kv tile.  ``keep`` [block_q, block_k] bool, or None
+    where every pair is visible.  Matmuls run in the INPUT dtype with f32
+    accumulation: bf16 model activations hit the MXU at full rate (an f32 x
+    f32 matmul runs at a fraction of it); softmax statistics stay f32."""
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale  # [block_q, block_k] f32
+    if keep is not None:
+        # Unconditional mask.  A `lax.cond` a block in its place read ~3 ms a
+        # step slower at the flagship's 32 heads x 1,024 positions (two tiles
+        # a side, PR 2) and has not been read at a longer shape; PR 34 bounds
+        # what masking only the diagonal's tiles could save at 0.13 us of a
+        # 16,384-position tile's 1.9.
+        s = jnp.where(keep, s, _NEG_INF)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_cur)                     # [block_q, block_k]
+    alpha = jnp.exp(m_prev - m_cur)            # rescale old accumulator
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return m_cur, l_new, acc
+
+
+def _bwd_tile(q, k, v, do, lse, delta, keep, *, scale: float, dkdv: bool, dq: bool):
+    """One head's backward tile as a function of values: recomputes p and ds
+    of the (q block, kv block) tile and returns the products asked for —
+    ``(p^T do, ds^T q)`` for dv and dk, ``ds k`` for dq.  ``lse`` and
+    ``delta`` are the q block's row statistics as (1, block_q) rows.  Matmul
+    operands stay in the input dtype (bf16 on the model path = full MXU
+    rate); probabilities and statistics are f32, ds is cast to the input
+    dtype for the downstream MXU products."""
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale  # [block_q, block_k] f32
+    p = jnp.exp(s - jnp.transpose(lse, (1, 0)))
+    if keep is not None:
+        p = jnp.where(keep, p, 0.0)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)       # [block_q, block_k]
+    ds = (p * (dp - jnp.transpose(delta, (1, 0))) * scale).astype(q.dtype)
+    out = ()
+    if dkdv:
+        out += (jax.lax.dot_general(p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32),  # [block_k, d_v]
+                jax.lax.dot_general(ds, q, _TN, preferred_element_type=jnp.float32))                  # [block_k, d]
+    if dq:
+        out += (jax.lax.dot(ds, k, preferred_element_type=jnp.float32),)                              # [block_q, d]
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _heads_at_once(tile_fn, **static):
+    """``tile_fn`` over the leading axis of its operands, the heads of a grid
+    step, as a function of (keep, *operands): the tile's ``keep`` is one for
+    the step, every other operand is batched, so each product is one batched
+    product with the heads leading.  Jitted, and one object a tile function
+    and its static arguments: the batched trace is made once a process and
+    shape, not once for each time JAX traces a kernel's body (a layer's
+    forward, its recomputation, its transpose — PR 50 read +9 s of set-up
+    without)."""
+    fn = functools.partial(tile_fn, **static)
+    return jax.jit(lambda keep, *operands: jax.vmap(lambda *o: fn(*o, keep))(*operands))
+
+
+def _keep(walk: _Walk, qi, ki, mask_ref):
+    """[block_q, block_k] bool, what the queries of tile (qi, ki) see, for
+    all the heads of a step: the packed mask's tile (one a batch entry, read
+    and converted once), else the causal triangle's or the band's; None where
+    every pair is visible."""
+    if mask_ref is not None:
+        return mask_ref[0, 0].astype(jnp.int32) != 0
+    if walk.causal:
+        return walk.keep(qi, ki, (walk.block_q, walk.block_k))
+    return None
+
+
+def _kv_heads(ref, heads: int):
+    """A step's k or v block with a head each: grouped queries that read their
+    KV head in place have one block for the step."""
+    x = ref[...]
+    return x if x.shape[0] == heads else jnp.broadcast_to(x, (heads,) + x.shape[1:])
+
+
 def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
-    """``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
-    per-pair mask (ops/sparse_attention.py), decides what a query sees in
-    place of the causal triangle; ``causal`` still says which tiles are
-    empty."""
+    """A grid step: H heads' tile (qi, ki); every block and scratch leads
+    with the heads.  ``masked``: a fourth operand, an int8 (block_q, block_k)
+    tile of a per-pair mask (ops/sparse_attention.py), decides what a query
+    sees in place of the causal triangle; ``causal`` still says which tiles
+    are empty."""
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
     mask_ref = rest[0] if masked else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[1:] if masked else rest
+    heads = q_ref.shape[0]
 
     @pl.when(ki == walk.first_k(qi))
     def _init():
@@ -260,44 +395,24 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
 
     @pl.when(run)
     def _step():
-        # Matmuls run in the INPUT dtype with f32 accumulation: bf16 model
-        # activations hit the MXU at full rate (an f32xf32 matmul runs at a
-        # fraction of it); softmax statistics stay f32 throughout.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k] f32
-        if masked:
-            s = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, s, _NEG_INF)
-        elif walk.causal:
-            # Unconditional mask: branching per block via lax.cond measured
-            # ~3 ms/step SLOWER than these VPU passes (Mosaic conditional
-            # overhead exceeds the saved work at flagship shapes).
-            s = jnp.where(walk.keep(qi, ki, s.shape), s, _NEG_INF)
-
-        m_prev = m_scr[:, :1]                      # [block_q, 1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_cur)                     # [block_q, block_k]
-        alpha = jnp.exp(m_prev - m_cur)            # rescale old accumulator
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
+        keep = _keep(walk, qi, ki, mask_ref)
+        m_cur, l_new, acc = _heads_at_once(_fwd_tile, scale=scale)(
+            keep, q_ref[...], _kv_heads(k_ref, heads), _kv_heads(v_ref, heads),
+            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...])
+        acc_scr[...] = acc
         # Partial column stores: broadcasting the stats across the full
         # (block_q, 128) scratch measured ~19% of the kernel.
-        m_scr[:, 0:1] = m_cur
-        l_scr[:, 0:1] = l_new
+        m_scr[:, :, 0:1] = m_cur
+        l_scr[:, :, 0:1] = l_new
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
         # lse output is lane-padded to (block_q, _LANE) to satisfy TPU tiling.
-        lse_ref[0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(safe_l), lse_ref.shape[1:]
+        lse_ref[...] = jnp.broadcast_to(
+            m_scr[:, :, :1] + jnp.log(safe_l), lse_ref.shape
         ).astype(lse_ref.dtype)
 
 
@@ -307,14 +422,28 @@ def _tri(i, j):
     return i * (i + 1) // 2 + j
 
 
+def _kv_spec(spec, heads: int, kv_group: int, block_k: int, width: int):
+    """k's or v's spec for a step of ``heads`` heads: a head each where the
+    array holds one for every query head, the one KV head the step's heads
+    share (``heads`` divides ``kv_group``) where it is read in place."""
+    if kv_group == 1:
+        return spec((heads, block_k, width), lambda b, i, j: (b, j, 0))
+    return spec((1, block_k, width), lambda b, i, j: (b * heads // kv_group, j, 0))
+
+
 def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
-                    kv_group: int = 1, window: Optional[int] = None):
+                    kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
     """``mask``: int8 [batch, tiles, block_q, block_k], the (block_q,
     block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`),
     shared by a batch entry's heads (bh = batch * heads); None for the
     causal triangle.  ``kv_group``: k and v hold one head for every
     ``kv_group`` of q's (grouped queries read their head in place).
-    ``window``: the band walk, under the name `tpuft_swa_fwd`."""
+    ``window``: the band walk, under the name `tpuft_swa_fwd`.
+    ``heads_per_step`` is the probe's and the tests': the program reads H
+    from the shapes, the largest divisor not above `HEADS_PER_STEP` of bh —
+    of ``kv_group`` where k and v are read in place, of a batch entry's
+    heads under a mask — so that the heads of a step share their mask tile
+    and, read in place, their KV head."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -324,11 +453,14 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     assert mask is None or (seq_q == seq_k and window is None), "a packed mask is one sequence's lower triangle"
     walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window)
     spec = walk.spec
+    share = _heads_share(bh, mask, kv_group)
+    heads = heads_per_step or _heads_per_step(share)
+    assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
     operands, mask_specs = (q, k, v), []
     if mask is not None:
-        heads = bh // mask.shape[0]
+        per_entry = bh // mask.shape[0]
         operands += (mask,)
-        mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0))]
+        mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (b * heads // per_entry, _tri(i, j), 0, 0))]
     out, lse_padded = pl.pallas_call(
         functools.partial(_fa_kernel, walk=walk, scale=scale, masked=mask is not None),
         out_shape=(
@@ -336,22 +468,23 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
             jax.ShapeDtypeStruct((bh, seq_q, _LANE), jnp.float32),
         ),
         grid_spec=walk.grid_spec(
-            bh,
+            bh // heads,
             in_specs=[
-                spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                spec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
-                spec((1, block_k, dv), lambda b, i, j: (b // kv_group, j, 0)),
+                spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
+                _kv_spec(spec, heads, kv_group, block_k, d),
+                _kv_spec(spec, heads, kv_group, block_k, dv),
             ] + mask_specs,
             out_specs=(
-                spec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-                spec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+                spec((heads, block_q, dv), lambda b, i, j: (b, i, 0)),
+                spec((heads, block_q, _LANE), lambda b, i, j: (b, i, 0)),
             ),
             scratch_shapes=[
-                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running max
-                pltpu.VMEM((block_q, _LANE), jnp.float32),  # running sum
-                pltpu.VMEM((block_q, dv), jnp.float32),     # output accumulator
+                pltpu.VMEM((heads, block_q, _LANE), jnp.float32),  # running max
+                pltpu.VMEM((heads, block_q, _LANE), jnp.float32),  # running sum
+                pltpu.VMEM((heads, block_q, dv), jnp.float32),     # output accumulator
             ],
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(heads * _TILE_VMEM_BYTES, _VMEM_BUDGET)),
         interpret=interpret,
         name="tpuft_dsa_attn_fwd" if mask is not None else "tpuft_fa_fwd" if window is None else "tpuft_swa_fwd",
     )(*walk.tables, *operands)
@@ -365,11 +498,15 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
 # its double-buffered bf16 output block (as much again) and the tiles are
 # 80 MiB at the budget, compiled and run at [2, 65536, 128].
 _DQ_ROW_VMEM_BUDGET = 32 * 2**20
-# What the kernels' tiles take beside a resident row: the double-buffered
+# What a head's tiles take beside a resident row: the double-buffered
 # operand blocks, the dk/dv accumulators and the (block_q, block_k) f32
 # temporaries — the compiler's default scoped limit, under which the
-# kernels without a row run.
+# kernels of one head a step without a row ran.
 _TILE_VMEM_BYTES = 16 * 2**20
+# What a step's heads may ask for together — their rows, the rows' output
+# blocks and their tiles: a v5e's VMEM.  Compiled and run at the whole of it
+# (PERF.md section 6, PR 52): four heads of 16,384 x 128 and of 8,192 x 256.
+_VMEM_BUDGET = 128 * 2**20
 
 
 def _dq_row_resident(seq_q: int, d: int) -> bool:
@@ -378,48 +515,47 @@ def _dq_row_resident(seq_q: int, d: int) -> bool:
     return seq_q * d * 4 <= _DQ_ROW_VMEM_BUDGET
 
 
-def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-               *, scale, walk: _Walk, mask_ref=None):
-    """Shared flash-backward block body: recomputes p and ds for the
-    (q-block qi, kv-block ki) tile.  Matmul operands stay in the input
-    dtype (bf16 on the model path = full MXU rate); probabilities and
-    statistics are f32.  Returns (p, ds) with ds cast to the input dtype
-    for the downstream MXU products."""
-    q = q_ref[0]
-    k = k_ref[0]
-    do = do_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                   # [block_q, block_k] f32
-    p = jnp.exp(s - row_stat_col(lse_ref, qi, walk.block_q))
-    if mask_ref is not None:
-        p = jnp.where(mask_ref[0, 0].astype(jnp.int32) != 0, p, 0.0)
-    elif walk.causal:
-        p = jnp.where(walk.keep(qi, ki, s.shape), p, 0.0)
-    dp = jax.lax.dot_general(
-        do, v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                           # [block_q, block_k]
-    ds = (p * (dp - row_stat_col(delta_ref, qi, walk.block_q)) * scale).astype(q.dtype)
-    return p, ds
+def _row_vmem_bytes(seq_q: int, d: int, itemsize: int) -> int:
+    """A head's dq row in the one-pass backward: f32 in scratch and the
+    output's block, double-buffered."""
+    return seq_q * d * (4 + 2 * itemsize)
+
+
+def _bwd_heads_per_step(share: int, row_bytes: int) -> int:
+    """The heads of a backward grid step: the largest divisor of ``share``
+    not above `HEADS_PER_STEP` whose rows (``row_bytes`` a head: 0 in the
+    two-pass form) and tiles fit `_VMEM_BUDGET`; one head where two do not."""
+    most = max(1, min(HEADS_PER_STEP, _VMEM_BUDGET // (row_bytes + _TILE_VMEM_BYTES)))
+    return _heads_per_step(share, most)
+
+
+def _row_stats(ref, qi, block_q: int):
+    """The q block's part of a step's row statistics, [H, 1, block_q].  They
+    enter the kernels as compact [.., 1, N] rows (4 KB a head and visit)
+    instead of a lane-padded [.., N, 128] layout (260 KB); the tile turns
+    its part into a column."""
+    from jax.experimental import pallas as pl
+
+    return ref[:, :, pl.ds(qi * block_q, block_q)]
 
 
 def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked: bool = False):
-    """Flash backward with the q axis innermost (a ``kv_major`` walk): dk/dv
-    accumulate in VMEM scratch across the q tiles over one kv tile.  With
-    ``with_dq`` (the one-pass form) a third scratch holds the head's whole
-    dq row in f32: the tile (ki, qi) adds ``ds @ k`` into its rows qi, and
-    the step of the LAST kv tile that q tile qi sees (its diagonal step in a
-    triangular walk, the last kv block's on a rectangle) casts those rows
-    into the dq output, whose one block a head stays in VMEM until the head
-    is done — the s/p/dp/ds tile work that dominates on the VPU is computed
-    once, and dq never exists in f32 outside VMEM."""
+    """Flash backward with the q axis innermost (a ``kv_major`` walk), H
+    heads a grid step: dk/dv accumulate in VMEM scratch across the q tiles
+    over one kv tile.  With ``with_dq`` (the one-pass form) a third scratch
+    holds the heads' whole dq rows in f32: the tile (ki, qi) adds ``ds @ k``
+    into its rows qi, and the step of the LAST kv tile that q tile qi sees
+    (its diagonal step in a triangular walk, the last kv block's on a
+    rectangle) casts those rows into the dq output, whose one block a step's
+    heads stays in VMEM until they are done — the s/p/dp/ds tile work is
+    computed once, and dq never exists in f32 outside VMEM."""
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest) = walk.tile(refs)
     block_q = walk.block_q
     mask_ref = rest[0] if masked else None  # as `_fa_kernel`'s
     dk_ref, dv_ref, *rest = rest[1:] if masked else rest
+    heads = q_ref.shape[0]
     if with_dq:
         dq_ref, dk_scr, dv_scr, dq_scr = rest
         q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
@@ -437,38 +573,27 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
 
     @pl.when(run)
     def _step():
-        p, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, scale=scale, walk=walk, mask_ref=mask_ref,
-        )
-        do = do_ref[0]
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                           # p^T @ do: [block_k, d]
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                           # ds^T @ q: [block_k, d]
+        dv_tile, dk_tile, *dq_tile = _heads_at_once(_bwd_tile, scale=scale, dkdv=True, dq=with_dq)(
+            _keep(walk, qi, ki, mask_ref), q_ref[...], _kv_heads(k_ref, heads), _kv_heads(v_ref, heads), do_ref[...],
+            _row_stats(lse_ref, qi, block_q), _row_stats(delta_ref, qi, block_q))
+        dv_scr[...] += dv_tile                      # p^T @ do: [H, block_k, d_v]
+        dk_scr[...] += dk_tile                      # ds^T @ q: [H, block_k, d]
         if with_dq:
-            dq_tile = jax.lax.dot(
-                ds, k_ref[0], preferred_element_type=jnp.float32
-            )                                       # ds @ k: [block_q, d]
-
             # Every q block runs against its first kv block (block 0 without
             # a window), causal or not, so the first visit assigns and the row
             # is never zeroed.
             @pl.when(ki == walk.first_k(qi))
             def _first():
-                dq_scr[q_rows, :] = dq_tile
+                dq_scr[:, q_rows, :] = dq_tile[0]   # ds @ k: [H, block_q, d]
 
             @pl.when(ki != walk.first_k(qi))
             def _add():
-                dq_scr[q_rows, :] += dq_tile
+                dq_scr[:, q_rows, :] += dq_tile[0]
 
     @pl.when(qi == walk.last_q(ki))
     def _emit():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
     if with_dq:
         # Rows qi are complete once the last kv tile they see has had its
@@ -477,14 +602,14 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
         # on a rectangle a turn the causal rule may have skipped.
         @pl.when(ki == walk.last_k(qi))
         def _emit_dq():
-            dq_ref[0, q_rows, :] = dq_scr[q_rows, :].astype(dq_ref.dtype)
+            dq_ref[:, q_rows, :] = dq_scr[:, q_rows, :].astype(dq_ref.dtype)
 
 
 def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     """dq-only second pass, kv axis innermost, for a dq row too long to
-    stay in VMEM: dq accumulates one (block_q, d) f32 block at a time, so
-    memory stays O(block) whatever the length (at the price of recomputing
-    p/ds once more)."""
+    stay in VMEM: dq accumulates one (block_q, d) f32 block a head at a
+    time, so memory stays O(block) whatever the length (at the price of
+    recomputing p/ds once more)."""
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr) = walk.tile(refs)
@@ -495,20 +620,17 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
 
     @pl.when(walk.visible(qi, ki))
     def _step():
-        _, ds = _bwd_block(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, scale=scale, walk=walk,
-        )
-        dq_scr[...] += jax.lax.dot(
-            ds, k_ref[0], preferred_element_type=jnp.float32
-        )
+        dq_scr[...] += _heads_at_once(_bwd_tile, scale=scale, dkdv=False, dq=True)(
+            _keep(walk, qi, ki, None), q_ref[...], k_ref[...], v_ref[...], do_ref[...],
+            _row_stats(lse_ref, qi, walk.block_q), _row_stats(delta_ref, qi, walk.block_q))[0]
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
-                   interpret: bool = False, mask=None, kv_group: int = 1, window: Optional[int] = None):
+def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bool = False, mask=None,
+                   kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
     """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
     [BH, S] f32.  One kernel (`tpuft_fa_bwd_dkdv_dq`) where one head's f32
     dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
@@ -517,7 +639,15 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     with ``kv_group`` k and v are read in place and dk, dv come out a query
     head each, for the caller to sum over a group.  ``window``: the band
     walk, the same kernels as `tpuft_swa_bwd_dkdv_dq` (`tpuft_swa_bwd_dkdv`,
-    `tpuft_swa_bwd_dq`)."""
+    `tpuft_swa_bwd_dq`).
+
+    A grid step carries H heads (``heads_per_step`` is the probe's and the
+    tests'): the largest divisor of what `_fa_pallas_call` divides, not above
+    `HEADS_PER_STEP`, such that H heads' dq rows — ``seq_q * d * (4 + 2 *
+    itemsize)`` bytes each, the f32 scratch and the double-buffered output
+    block; none in the two-pass form — and H times `_TILE_VMEM_BYTES` fit
+    `_VMEM_BUDGET`: four at 4,096 x 128, two at 16,384 x 128, 8,192 or 16,384
+    x 256 and 32,768 x 128, one where two rows do not fit (65,536 x 128)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -526,6 +656,11 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     block_q, block_k = _block_sizes(seq_q, seq_k)
     causal = causal or mask is not None
     family = "tpuft_fa" if window is None else "tpuft_swa"
+    one_pass = _dq_row_resident(seq_q, d)
+    row_bytes = _row_vmem_bytes(seq_q, d, q.dtype.itemsize) if one_pass else 0
+    share = _heads_share(bh, mask, kv_group)
+    heads = heads_per_step or _bwd_heads_per_step(share, row_bytes)
+    assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
     # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
     # rowsum(do * o) is O(S*D) and computed once here instead of per tile.
     lse = lse[:, None, :]
@@ -536,51 +671,47 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
     def specs(walk):
         """The six operands' specs and dk's and dv's, by tile, for a walk."""
         spec = walk.spec
-        row = spec((1, 1, seq_q), lambda b, i, j: (b, 0, 0))
+        row = spec((heads, 1, seq_q), lambda b, i, j: (b, 0, 0))
         return [
-            spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            spec((1, block_k, d), lambda b, i, j: (b // kv_group, j, 0)),
-            spec((1, block_k, d_v), lambda b, i, j: (b // kv_group, j, 0)),
-            spec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
+            spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
+            _kv_spec(spec, heads, kv_group, block_k, d),
+            _kv_spec(spec, heads, kv_group, block_k, d_v),
+            spec((heads, block_q, d_v), lambda b, i, j: (b, i, 0)),
             row, row,
         ], [
-            spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            spec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
+            spec((heads, block_k, d), lambda b, i, j: (b, j, 0)),
+            spec((heads, block_k, d_v), lambda b, i, j: (b, j, 0)),
         ]
 
     walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True, window=window)
     in_specs, out_specs = specs(walk)
-    one_pass = _dq_row_resident(seq_q, d)
     operands = (q, k, v, g, lse, delta)
     if mask is not None:
         assert one_pass and seq_q == seq_k and window is None, "the masked backward keeps one sequence's dq row in VMEM"
-        heads = bh // mask.shape[0]
+        per_entry = bh // mask.shape[0]
         operands += (mask,)
-        in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (b // heads, _tri(i, j), 0, 0)))
+        in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (b * heads // per_entry, _tri(i, j), 0, 0)))
     out_shape = [
         jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype),
         jax.ShapeDtypeStruct((bh,) + v.shape[1:], v.dtype),
     ]
     scratch = [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d_v), jnp.float32),
+        pltpu.VMEM((heads, block_k, d), jnp.float32),
+        pltpu.VMEM((heads, block_k, d_v), jnp.float32),
     ]
-    vmem_limit = None
     if one_pass:
-        # dq's block is a head's whole row and ignores the tile: it leaves
-        # VMEM once, when the head is done.
+        # dq's block is the heads' whole rows and ignores the tile: it leaves
+        # VMEM once, when the step's heads are done.
         out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
-        out_specs.append(walk.spec((1, seq_q, d), lambda b, i, j: (b, 0, 0)))
-        scratch.append(pltpu.VMEM((seq_q, d), jnp.float32))
-        vmem_limit = (
-            seq_q * d * (4 + 2 * q.dtype.itemsize) + _TILE_VMEM_BYTES
-        )
+        out_specs.append(walk.spec((heads, seq_q, d), lambda b, i, j: (b, 0, 0)))
+        scratch.append(pltpu.VMEM((heads, seq_q, d), jnp.float32))
+    vmem_limit = heads * (row_bytes + _TILE_VMEM_BYTES)
     outs = pl.pallas_call(
         functools.partial(
             _fa_bwd_dkdv_kernel, walk=walk, scale=scale, with_dq=one_pass, masked=mask is not None,
         ),
         out_shape=tuple(out_shape),
-        grid_spec=walk.grid_spec(bh, in_specs, tuple(out_specs), scratch),
+        grid_spec=walk.grid_spec(bh // heads, in_specs, tuple(out_specs), scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=walk.semantics("parallel"),
             vmem_limit_bytes=vmem_limit,
@@ -603,7 +734,8 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool,
         functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=walk.grid_spec(
-            bh, in_specs, in_specs[0], [pltpu.VMEM((block_q, d), jnp.float32)]),
+            bh // heads, in_specs, in_specs[0], [pltpu.VMEM((heads, block_q, d), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=family + "_bwd_dq",
     )(*walk.tables, q, k, v, g, lse, delta)
