@@ -49,6 +49,28 @@ float32 array in HBM:
 the same way: one grid step for each tile that holds a visible pair, row by
 row, none for a tile above the diagonal.
 
+What the two selection-side kernels' loops carry is sized by the vector
+register file (64 registers of 8 x 128; one store slot a bundle, so a carry
+that does not fit is spilled and reloaded whole every iteration, and the
+loop runs at the store slot's pace — PERF.md section 6, PR 55; read the
+schedule with `tools/dsa_probe.py`):
+
+  - ``tpuft_dsa_select`` folds a block's keys 64 rows at a time.  A count
+    (an integer sum: exact in any order) and the maximum add a key tile's
+    four lane blocks into a [64, 128] carry, 8 registers, beside the
+    threshold they compare with (a value a row is a register for every
+    eight rows once it is spread over the lanes) — so ``tau`` and ``cut``
+    are what any order of the additions gives.  The log-sum-exp's float sum
+    keeps a tile's every column apart ([32, 512] carries) and the order in
+    which the tiles are added, so ``z`` keeps its bits;
+  - ``tpuft_dsa_index_loss`` carries the heads' sum [256, 512] float32 — 128
+    registers, twice the file: it cannot be narrowed (products of fewer
+    columns or rows leave the MXU waiting on their latency), so its loop runs
+    several heads a body (`_heads_a_body`: four where the count divides), the
+    next head's product standing under this one's exponential and its
+    load-add-store of the carry; the heads are added in ascending order as in
+    a loop of one.
+
 Off-TPU, under a multi-device mesh and for shapes the kernels do not tile,
 the same mathematics runs in plain XLA with dense [S, S] scores and
 ``jax.lax.top_k`` (``_dsa_xla``: also the oracle the kernels are tested
@@ -69,6 +91,12 @@ from torchft_tpu.ops import attention as _fa
 _INT_MIN = -(2 ** 31)
 BLOCK_Q = 256   # rows of a select / mask / index-loss tile
 BLOCK_K = 512   # key columns of a tile
+# Rows that a fold of `tpuft_dsa_select` carries through the key tiles at a
+# time (the compiler's schedule at the Keye cell's shapes, bundles a key tile
+# of 256 x 512: a count 128 at 64 rows, 176 at 32, 130 with spills at 128;
+# the float sum 464 at 32 rows, 632 at 64 — `tools/dsa_probe.py`).
+COUNT_ROWS = 64  # a count, the maximum: a [64, 128] int32 carry, 8 registers
+SUM_ROWS = 32    # the log-sum-exp's float sum: a [32, 512] f32 carry, 16 registers
 _VMEM_LIMIT = 100 * 2 ** 20
 
 # What a rematerialised layer keeps so that its backward pass neither scores,
@@ -139,6 +167,7 @@ def _select_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, z_ref, keys_scr,
     qi = pl.program_id(1)
     tiles = (qi * bq + bq - 1) // bk + 1  # key tiles that hold a visible column
     w = w_ref[0]
+    lane = _pallas_util.LANE
 
     def fill(kt, _):
         cols0 = pl.multiple_of(kt * bk, bk)
@@ -149,18 +178,36 @@ def _select_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, z_ref, keys_scr,
 
     jax.lax.fori_loop(0, tiles, fill, 0)
 
-    def fold(step, init):
-        """step(keys, cols, acc) -> acc over the visible tiles of the block's keys."""
-        def body(kt, acc):
-            cols0 = pl.multiple_of(kt * bk, bk)
-            cols = cols0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            return step(keys_scr[:, pl.ds(cols0, bk)], cols, acc)
+    # What a fold carries through the key tiles fits the vector registers
+    # (the module docstring): some rows at a time, and where the order of the
+    # additions does not matter a tile's lane blocks folded into one.
+    def fold(step, rows: int, width: int, init, *per_row):
+        """acc = step(acc, keys, cols, *per_row) over the visible tiles of
+        the block's keys, `rows` rows at a time from [rows, width] of `init`
+        -> [bq, width]; `per_row` are [bq, 1] values the step reads.  Rows
+        do not meet in a step."""
+        rows = rows if bq % rows == 0 else bq
+        done = []
+        for r0 in range(0, bq, rows):
+            here = [v[r0:r0 + rows] for v in per_row]
 
-        return jax.lax.fori_loop(0, tiles, body, init)
+            def body(kt, acc):
+                cols0 = pl.multiple_of(kt * bk, bk)
+                cols = cols0 + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+                return step(acc, keys_scr[r0:r0 + rows, pl.ds(cols0, bk)], cols, *here)  # noqa: B023
 
-    def count(pred):
-        """Per row, how many entries of the visible tiles satisfy pred(keys, cols)."""
-        part = fold(lambda k, c, acc: acc + pred(k, c).astype(jnp.int32), jnp.zeros((bq, bk), jnp.int32))
+            done.append(jax.lax.fori_loop(0, tiles, body, jnp.full((rows, width), init)))
+        return jnp.concatenate(done, axis=0)
+
+    width = lane if bk % lane == 0 else bk
+
+    def lanes(x, op):
+        """[rows, bk] -> [rows, width]: the tile's lane blocks folded by op."""
+        return functools.reduce(op, [x[:, i:i + width] for i in range(0, bk, width)])
+
+    def count(pred, *per_row):
+        """Per row, how many entries of the visible tiles satisfy pred(keys, cols, *per_row)."""
+        part = fold(lambda acc, *x: acc + lanes(pred(*x).astype(jnp.int32), jnp.add), COUNT_ROWS, width, jnp.int32(0), *per_row)
         return jnp.sum(part, axis=1, keepdims=True)
 
     row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
@@ -172,22 +219,22 @@ def _select_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, z_ref, keys_scr,
 
     def bit_step(i, tau):
         cand = tau | jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(count(lambda k, _: k >= cand) >= want, cand, tau)
+        return jnp.where(count(lambda k, _, at: k >= at, cand) >= want, cand, tau)
 
     tau = jax.lax.fori_loop(0, 31, bit_step, tau)
     # Ties at the threshold go to the lower position: of the keys equal to
     # tau the first `need`, which end at the largest position P with fewer
     # than `need` equal keys before it.
-    need = want - count(lambda k, _: k > tau)
+    need = want - count(lambda k, _, at: k > at, tau)
 
     def cut_step(i, cut):
         cand = cut | jnp.left_shift(jnp.int32(1), (seq - 1).bit_length() - 1 - i)
-        return jnp.where(count(lambda k, c: (k == tau) & (c < cand)) < need, cand, cut)
+        return jnp.where(count(lambda k, c, at, before: (k == at) & (c < before), tau, cand) < need, cand, cut)
 
     # Only a block in which some query has more keys tied at its threshold
     # than it may keep pays for these passes; elsewhere every key equal to
     # tau is kept, whatever its position.
-    tied = count(lambda k, _: k == tau)
+    tied = count(lambda k, _, at: k == at, tau)
     cut = jax.lax.cond(
         jnp.max(tied - need) > 0,
         lambda: jax.lax.fori_loop(0, (seq - 1).bit_length(), cut_step, jnp.zeros((bq, 1), jnp.int32)),
@@ -196,11 +243,15 @@ def _select_kernel(a_ref, bt_ref, w_ref, tau_ref, cut_ref, z_ref, keys_scr,
 
     # The selection's log-sum-exp of I, while the scores are here.  (An
     # invisible entry holds INT_MIN, below every tau: it is never selected.)
+    # The float sum keeps a tile's every column apart and the order of its
+    # additions, so z keeps its bits.
     top = _unsortable(jnp.max(
-        fold(lambda k, _, m: jnp.maximum(m, k), jnp.full((bq, bk), _INT_MIN, jnp.int32)), axis=1, keepdims=True))
+        fold(lambda acc, k, _: jnp.maximum(acc, lanes(k, jnp.maximum)), COUNT_ROWS, width, jnp.int32(_INT_MIN)),
+        axis=1, keepdims=True))
     total = jnp.sum(fold(
-        lambda k, c, acc: acc + jnp.where((k > tau) | ((k == tau) & (c <= cut)), jnp.exp(_unsortable(k) - top), 0.0),
-        jnp.zeros((bq, bk), jnp.float32)), axis=1, keepdims=True)
+        lambda acc, k, c, tau, cut, top: acc + jnp.where(
+            (k > tau) | ((k == tau) & (c <= cut)), jnp.exp(_unsortable(k) - top), 0.0),
+        SUM_ROWS, bk, jnp.float32(0.0), tau, cut, top), axis=1, keepdims=True)
     tau_ref[0] = jnp.broadcast_to(tau, tau_ref.shape[1:])
     cut_ref[0] = jnp.broadcast_to(cut, cut_ref.shape[1:])
     z_ref[0] = jnp.broadcast_to(top + jnp.log(total), z_ref.shape[1:])
@@ -299,7 +350,8 @@ def _mask_pallas(a, bt, w, tau, cut, interpret: bool = False):
 # -- tpuft_dsa_index_loss -----------------------------------------------------------
 
 
-def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, scale: float, inv_rows: float):
+def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, heads_a_body: int, scale: float,
+                       inv_rows: float):
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, lse_ref, a_ref, bt_ref, w_ref, z_ref, mask_ref,
@@ -319,15 +371,21 @@ def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, sca
     # (every step of the walk is a tile with a visible pair)
     group = q_heads // kv_heads
 
-    def one_head(h, total):
-        s = jax.lax.dot_general(
-            q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
-        return total + jnp.exp(s - lse)
+    def some_heads(i, total):
+        # `heads_a_body` heads a loop body, added in ascending order: head
+        # h + 1's product stands under head h's exponential and its
+        # load-add-store of the carry ([bq, bk] f32, twice the register file)
+        for u in range(heads_a_body):
+            h = i * heads_a_body + u
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
+            total = total + jnp.exp(s - lse)
+        return total
 
     keep = mask_ref[0, 0].astype(jnp.int32) != 0
-    total = jax.lax.fori_loop(0, q_heads, one_head, jnp.zeros((bq, bk), jnp.float32))
+    total = jax.lax.fori_loop(0, q_heads // heads_a_body, some_heads, jnp.zeros((bq, bk), jnp.float32))
     pbar = jnp.where(keep, total * (1.0 / q_heads), 0.0)
     bt, w = bt_ref[0], w_ref[0]
     log_q = _index_tile(a_ref, bt, w, heads) - z_ref[0]
@@ -352,22 +410,36 @@ def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, sca
         kl_ref[0] = jnp.broadcast_to(kl_scr[:, 0:1], kl_ref.shape[1:])
 
 
-def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bool = False):
+def _heads_a_body(q_heads: int) -> int:
+    """Query heads a body of the index loss's loop over them: four where the
+    count divides, else two, else one.  (At the Keye cell's shapes a head is
+    532 bundles of the compiler's schedule in a body of one, 387 of two, 362
+    of four, 357 of eight, and a call 118.4 / 91.2 / 84.3 / 80.9 ms on a
+    v5e: eight doubles the body for the last 4% — PERF.md section 6, PR 55.)"""
+    return next(u for u in (4, 2, 1) if q_heads % u == 0)
+
+
+def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bool = False,
+                       heads_a_body: int | None = None):
     """q [B, H, S, D], k [B, KV, S, D], lse [B, H, S] -> (kl rows [B, S],
     d loss/d a [B, J, S, Di] f32, d loss/d bt [B, Di, S] f32, d loss/d w
-    [B, S, J] f32) of loss = sum(kl rows) / (B * S)."""
+    [B, S, J] f32) of loss = sum(kl rows) / (B * S).  `heads_a_body` is for
+    tests and tools/dsa_probe.py (1 is the loop of one head a body); the
+    program reads it from the shapes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, q_heads, seq, d = q.shape
     kv_heads, heads, di = k.shape[1], a.shape[1], a.shape[3]
+    heads_a_body = heads_a_body or _heads_a_body(q_heads)
+    assert q_heads % heads_a_body == 0, (q_heads, heads_a_body)
     walk = _walk(seq)
     spec, bq, bk = walk.spec, walk.block_q, walk.block_k
     lane = _pallas_util.LANE
     kl, da, dbt, dw = pl.pallas_call(
         functools.partial(
-            _index_loss_kernel, walk=walk, q_heads=q_heads, kv_heads=kv_heads, heads=heads, scale=scale,
-            inv_rows=1.0 / (batch * seq)),
+            _index_loss_kernel, walk=walk, q_heads=q_heads, kv_heads=kv_heads, heads=heads,
+            heads_a_body=heads_a_body, scale=scale, inv_rows=1.0 / (batch * seq)),
         out_shape=(
             jax.ShapeDtypeStruct((batch, seq, lane), jnp.float32),
             jax.ShapeDtypeStruct(a.shape, jnp.float32),
