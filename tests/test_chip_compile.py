@@ -280,7 +280,7 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
     assert len(ran) > 500 and set(ran) <= set(ops)
     booked = {name: opmap.booked(entry) for name, entry in ops.items()}
-    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert"}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert", "ssm_mix", "ssm_scan"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
     assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
@@ -905,3 +905,106 @@ def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_c
     # under remat 14,735,490,048, without remat 20,776,999,424; since PR 52, with several heads a grid step in the
     # attention kernels, the temporaries are 258,048 bytes more (builder's compile): 15,835,560,960
     assert resident <= 15_835_560_960, f"the step needs {resident} bytes with AdamW's moments"
+
+
+@pytest.mark.parametrize("direction", ["forward", "forward_with_states", "backward"])
+def test_state_space_kernels_compile_for_v5e(one_chip, direction) -> None:
+    """`tpuft_ssd_fwd` (with and without the chunks' states) and `tpuft_ssd_bwd`
+    at the Nemotron cell's shape: 64 heads of 64 in 8 groups over a state of 128,
+    16,384 positions in bfloat16, the running sums float32, chunks of 128 — a
+    group's eight heads a grid step, read in place out of [1, 16,384, 4,096]
+    (grid (8, 128): batch * groups, chunks), a head's 64 columns picked by a lane
+    mask inside a 128-lane block, the per-head columns by masked lane sums, the
+    transposed-left products as Mosaic takes them."""
+    from torchft_tpu.ops import ssd
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    seq, heads, p, groups, n = 16_384, 64, 64, 8, 128
+    per_group, chunks = heads // groups, seq // ssd.CHUNK
+    rows = [sds((1, seq, heads * p), bf16), sds((1, seq, groups * n), bf16), sds((1, seq, groups * n), bf16),
+            sds((1, groups, seq, per_group), f32), sds((1, groups, chunks, per_group, ssd.CHUNK), f32)]
+    if direction == "backward":
+        text = _compile(lambda *a: ssd._bwd_pallas(*a, p, ssd.CHUNK), *rows,
+                        sds((chunks, groups, n, per_group * p), f32), sds((1, seq, heads * p), bf16))
+        assert _kernel_calls(text, "tpuft_ssd_") == ["tpuft_ssd_bwd"]
+    else:
+        text = _compile(lambda *a: ssd._fwd_pallas(*a, p, ssd.CHUNK, direction == "forward_with_states"), *rows)
+        assert _kernel_calls(text, "tpuft_ssd_") == ["tpuft_ssd_fwd"]
+        assert ("f32[128,8,128,512]" in text) == (direction == "forward_with_states")
+    assert [grid for _, grid in _kernel_grids(text, "tpuft_ssd_")] == [(groups, chunks)]
+
+
+def test_grouped_matmul_at_a_width_of_1856_compiles_to_the_kernels_for_v5e(one_chip, monkeypatch) -> None:
+    """An expert of 1,856 = 14.5 x 128 columns, up and down, forward and both
+    gradients: `grouped_matmul` pads to 1,920 inside the call and the compiled
+    program holds the three `tpuft_gmm_*` kernels twice each and no
+    `ragged-dot`; the gradients keep the leaves' [8, 2,688, 1,856] and [8, 1,856,
+    2,688]."""
+    from torchft_tpu.ops import _pallas_util, grouped_matmul as gmm
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    rows = 13_312  # the cell's buffer: twice the even share of 98,304 assignments over 8 of 128 experts, a tile an expert
+
+    def loss(xs, w_up, w_down, counts):
+        sizes = gmm.padded_group_sizes(counts, gmm.ROW_TILE)
+        hidden = jnp.square(jax.nn.relu(gmm.grouped_matmul(xs, w_up, sizes, row_tile=gmm.ROW_TILE)))
+        return jnp.sum(gmm.grouped_matmul(hidden, w_down, sizes, row_tile=gmm.ROW_TILE).astype(jnp.float32))
+
+    fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    compiled = fn.lower(sds((rows, 2688), jnp.bfloat16), sds((8, 2688, 1856), jnp.float32), sds((8, 1856, 2688), jnp.float32),
+                        sds((8,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert sorted(_kernel_calls(text, "tpuft_gmm_")) == ["tpuft_gmm_dlhs"] * 2 + ["tpuft_gmm_drhs"] * 2 + ["tpuft_gmm_fwd"] * 2
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)] == [(), (rows, 2688), (8, 2688, 1856), (8, 1856, 2688)]
+
+
+def test_nemotron_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
+    """The benchmark's `nemotron-twotower-30b-a3b` configuration as
+    `benchmark/programs/mamba2_moe_lm.py` hands it to `TrainStep`: the whole
+    gradient program at the published widths and 1 x 16,384 tokens — the four
+    Mamba-2 blocks through `tpuft_ssd_fwd` twice (the forward pass, and the
+    backward's own that makes the chunks' states again: the scan's output is
+    kept under remat) and `tpuft_ssd_bwd` once each, the one attention block at
+    32 query heads over 2 KV heads through one `tpuft_fa_fwd` and one
+    `tpuft_fa_bwd_dkdv_dq`, the 8 held un-gated experts of each of the four
+    expert blocks at 1,856 columns through `tpuft_gmm_*` (two projections:
+    forward, its recomputation, and the two gradients each) with no
+    `ragged-dot` anywhere, the sliced vocabulary through `tpuft_ce_*` — with
+    room for AdamW's moments beside it on a 16 GiB chip."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.spec import Benchmark
+    from torchft_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+    bench = Benchmark(root)
+    config, traffic = bench.config("nemotron-twotower-30b-a3b"), bench.traffic("steady-1g-16k")
+    shapes = jax.eval_shape(lambda: bench.reference("mamba2_moe_lm").make_weights(1, config))
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((traffic["sequences_per_step"], traffic["seq_len"]), jnp.int32, sharding=one_chip)
+    _, step = bench.program("mamba2_moe_lm").train_step(config, topo.devices[0])
+    compiled = step.lower_grads(params, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    for name in ("tpuft_ce_lse", "tpuft_ce_dlogits"):
+        assert _has_kernel(text, name), f"{name} is not in the compiled program"
+    assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
+    assert sorted(_kernel_calls(text, "tpuft_ssd_")) == ["tpuft_ssd_bwd"] * 4 + ["tpuft_ssd_fwd"] * 8
+    assert set(_kernel_grids(text, "tpuft_ssd_")) == {("tpuft_ssd_bwd", (8, 128)), ("tpuft_ssd_fwd", (8, 128))}
+    assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
+    assert sorted(_kernel_calls(text, "tpuft_gmm_")) == (["tpuft_gmm_dlhs"] * 8 + ["tpuft_gmm_drhs"] * 8 + ["tpuft_gmm_fwd"] * 16)
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    ma = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
+    assert n_params == bench.flops("mamba2_moe_lm").total_params(config) == 666_962_944
+    assert shapes["moe"]["w_up"].shape == (4, 8, 2688, 1856)  # no width is cut or grown in the tree
+    resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
+    # 15,317,239,296 (arguments 2,667,987,456 + outputs 2,667,862,528 + temporaries 4,645,685,760 + moments
+    # 5,335,703,552; builder's compile, PR 56)
+    assert resident <= 15_400_000_000, f"the step needs {resident} bytes with AdamW's moments"

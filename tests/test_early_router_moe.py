@@ -365,7 +365,7 @@ def test_relu_counts_and_silu_does_not() -> None:
     y_silu, _ = moe_layer(x, w["router"], w["w_gate"], w["w_up"], w["w_down"], top_k=6, capacity_factor=2.0,
                           dtype=jnp.float32)
     assert float(jnp.max(jnp.abs(y_relu - y_silu))) > 1e-3
-    assert set(moe.ACTIVATIONS) == {"silu", "relu"}
+    assert set(moe.ACTIVATIONS) == {"silu", "relu", "relu2"}  # "relu2": the un-gated experts' (tests/test_mamba2_moe.py)
 
 
 # -- the counter through ft_step ---------------------------------------------------
@@ -455,10 +455,13 @@ def test_the_cell_is_found_and_reports_its_metrics() -> None:
                 "gmm_small_roofline", "attn_roofline"} & reported
     for other in (w["name"] for w in BENCH.doc["workloads"] if w["name"] != CELL):
         assert not set(NEW_METRICS) & {m["name"] for m in BENCH.per_layer(other)}
+    # in their order, wherever later PRs' entries stand (a subset check: PERF.md section 7 lists the tests
+    # that pinned "the last entries" and broke with the next configuration)
     names = [m["name"] for m in BENCH.doc["per_layer"]]
-    assert names[-len(NEW_METRICS):] == list(NEW_METRICS)
-    assert BENCH.doc["workloads"][-1]["name"] == CELL and BENCH.doc["configs"][-1]["name"] == "smallthinker-21b-a3b"
-    assert len(BENCH.doc["workloads"]) == 10 and sum(w["chips"] == 4 for w in BENCH.doc["workloads"]) == 1
+    assert [name for name in names if name in NEW_METRICS] == list(NEW_METRICS)
+    assert CELL in [w["name"] for w in BENCH.doc["workloads"]]
+    assert "smallthinker-21b-a3b" in [c["name"] for c in BENCH.doc["configs"]]
+    assert sum(w["chips"] == 4 for w in BENCH.doc["workloads"]) == 1
     kernels = PROGRAM.kernel_names()
     assert set(kernels) == {"attn", "ce", "gmm", "swa"} and kernels["swa"]("%tpuft_swa_fwd.13")
 

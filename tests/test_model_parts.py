@@ -92,13 +92,23 @@ MODELS["smallthinker"] = TransformerConfig(**dict(
     remat=True, remat_keeps_attention=True, scan_unroll=8,
     pattern=(LayerKind("layers", True, 7, 1.5e6, rotary_fraction=0.0),
              *(LayerKind("window_layers", True, 7, 1.5e6, window=16),) * 3) * 2))
+# The tenth: blocks that are ONE norm and ONE part — a Mamba-2 mixer, un-rotated attention at a group of two, un-gated
+# ReLU^2 experts beside a shared one — three stacks of unequal leaf sets, the published pattern's first nine letters.
+_M = LayerKind("mamba", False, 4, 1e4, rotary_fraction=0.0, mixer="mamba2", feed_forward=False)
+_A = LayerKind("attn", False, 4, 1e4, rotary_fraction=0.0, feed_forward=False)
+_E = LayerKind("moe", True, 4, 1e4, rotary_fraction=0.0, mixer="none")
+MODELS["nemotron"] = TransformerConfig(**dict(
+    _BASE, n_layers=9, n_heads=4, n_kv_heads=2, head_dim=16, moe_experts=8, moe_top_k=2, d_ff=24,
+    moe_capacity_factor=None, moe_held=(2, 2), moe_score="sigmoid", moe_route_scale=2.5, moe_shared_experts=2,
+    moe_aux_coef=0.0, moe_activation="relu2", ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_chunk=16,
+    remat=True, remat_keeps_attention=True, scan_unroll=16, pattern=(_M, _E, _M, _E, _M, _A, _E, _M, _E)))
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
 
 def _step_and_arguments(name: str):
     cfg = MODELS[name]
-    biased = (cfg.moe_score == "sigmoid" and (not cfg.pattern or name == "kimi")) or cfg.moe_skip
+    biased = (cfg.moe_score == "sigmoid" and (not cfg.pattern or name in ("kimi", "nemotron"))) or cfg.moe_skip
     bias = jnp.zeros((cfg.n_sparse_layers, cfg.n_router_outputs), jnp.float32) if biased else None
     step = TrainStep(ft_init_mesh({"data": 1}, devices=jax.devices()[:1]), optax.adamw(1e-3),
                      lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias), loss_has_counters=True)
@@ -146,8 +156,10 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
     expected = {"embed", "norm", "attn_proj", "attn", "head_loss", "stack"}
     if cfg.moe_experts:
         expected |= {"router", "experts"}
-    if cfg.moe_experts == 0 or not all(kind.sparse for kind in cfg.layers):
+    if any(kind.feed_forward and not kind.sparse for kind in cfg.layers):
         expected |= {"ffn"}
+    if any(kind.mixer == "mamba2" for kind in cfg.layers):
+        expected |= {"ssm_mix", "ssm_scan"}
     if any(kind.window for kind in cfg.layers):
         expected |= {"attn_window"}
     if any(kind.mixer == "cca" for kind in cfg.layers):

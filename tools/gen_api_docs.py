@@ -57,6 +57,7 @@ MODULES = [
     "torchft_tpu.parallel.pipeline",
     "torchft_tpu.models.transformer",
     "torchft_tpu.models.moe",
+    "torchft_tpu.models.mamba",
     "torchft_tpu.models.convnet",
     "torchft_tpu.ops.attention",
     "torchft_tpu.ops.cross_entropy",
@@ -64,6 +65,7 @@ MODULES = [
     "torchft_tpu.ops.rmsnorm",
     "torchft_tpu.ops.sparse_attention",
     "torchft_tpu.ops.delta_attention",
+    "torchft_tpu.ops.ssd",
     "torchft_tpu.ops.ring_attention",
     "torchft_tpu.ops.ulysses",
     "torchft_tpu.coordination",

@@ -128,8 +128,19 @@ Stats = Dict[str, jax.Array]
 # assignments: shapes are static, so the buffer needs a bound (`held_rows`).
 HELD_ROWS_FACTOR = 2.0
 
-# What a gated feed-forward applies to its gate: SwiGLU's or ReGLU's.
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# What a gated feed-forward applies to its gate: SwiGLU's or ReGLU's; and what
+# an UN-GATED one ("relu2": no gate matrix, `w_gate` None) applies to its one
+# hidden product: the square of ReLU (Nemotron-H's experts).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu, "relu2": lambda h: jnp.square(jax.nn.relu(h))}
+
+
+def hidden_units(activation: str, w_gate, w_up, product):
+    """A feed-forward's hidden units from ``product(w)``, its input times a
+    matrix: ``act(product(w_gate)) * product(w_up)``, or un-gated (``w_gate``
+    None) ``act(product(w_up))``."""
+    if w_gate is None:
+        return ACTIVATIONS[activation](product(w_up))
+    return ACTIVATIONS[activation](product(w_gate)) * product(w_up)
 
 
 def moe_capacity(tokens: int, n_experts: int, top_k: int, capacity_factor: float) -> int:
@@ -340,7 +351,7 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
     hidden unit) pairs that are not zero over the rows an assignment landed
     in, else None)."""
     tokens, k = gate_idx.shape
-    count = w_gate.shape[0]
+    count = w_up.shape[0]
     n_assign = tokens * k
     row_tile = ROW_TILE
     rows = held_rows(n_assign, n_exp, count, rows_factor, row_tile)
@@ -373,12 +384,17 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
     dest = dest.reshape(tokens, k)
 
     xs = _rows_of_tokens(xf, row_assignment // k, dest, every_row_exists)
-    gate = grouped_matmul(xs, w_gate, sizes, row_tile=row_tile, mesh=mesh)
-    up = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
-    out = grouped_matmul(ACTIVATIONS[activation](gate) * up, w_down, sizes, row_tile=row_tile, mesh=mesh)
+    if w_gate is None:  # un-gated: the activation on the one hidden product
+        gate = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
+        hidden = ACTIVATIONS[activation](gate)
+    else:
+        gate = grouped_matmul(xs, w_gate, sizes, row_tile=row_tile, mesh=mesh)
+        up = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
+        hidden = ACTIVATIONS[activation](gate) * up
+    out = grouped_matmul(hidden, w_down, sizes, row_tile=row_tile, mesh=mesh)
     y = _tokens_of_rows(out, gate_vals, dest, row_assignment, every_row_exists)
     active = None
-    if activation == "relu":
+    if activation in ("relu", "relu2"):
         landed = (row_assignment < n_assign)[:, None]  # a padding row repeats a token's and counts nothing
         active = jnp.sum(((ACTIVATIONS[activation](gate) != 0) & landed).astype(jnp.int32))
     return y, n_held.astype(jnp.int32), dropped, active
@@ -391,7 +407,7 @@ def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dt
                   activation: str = "silu"):
     """xf [T, E]; returns (y [T, E], assignments dropped over capacity)."""
     T = xf.shape[0]
-    n_exp = w_gate.shape[0]
+    n_exp = w_up.shape[0]
     top_k = gate_idx.shape[1]
     C = capacity
 
@@ -421,8 +437,7 @@ def _capacity_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, capacity, dt
     # all-to-alls once partitioned.
     xin = jnp.einsum("tec,td->ecd", dispatch.astype(dtype), xf.astype(dtype))
     xin = constrain(xin, ("expert", None, "embed"), mesh, rules)
-    h = ACTIVATIONS[activation](jnp.einsum("ecd,edf->ecf", xin, w_gate.astype(dtype)))
-    h = h * jnp.einsum("ecd,edf->ecf", xin, w_up.astype(dtype))
+    h = hidden_units(activation, w_gate, w_up, lambda w: jnp.einsum("ecd,edf->ecf", xin, w.astype(dtype)))
     h = constrain(h, ("expert", None, "mlp"), mesh, rules)
     out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(dtype))
     out = constrain(out, ("expert", None, "embed"), mesh, rules)
@@ -494,7 +509,8 @@ def moe_layer(
             input state is ``router_state`` ([B, S, R] float32, ``rms_eps``
             its norm's) and whose own comes back in ``stats["router_state"]``.
         skip: the router's LAST output is the choice that takes no expert.
-        w_gate/w_up: [held, E, F]; w_down: [held, F, E]: the stacked experts
+        w_gate/w_up: [held, E, F] (w_gate None: un-gated experts, `activation`
+            on `x w_up` — "relu2"); w_down: [held, F, E]: the stacked experts
             this device holds, ``held_first ... held_first + held - 1`` of the
             router's outputs.  ``held == n_exp`` is every expert; fewer
             (dropless path only) is one device's share of an expert-parallel
@@ -505,9 +521,10 @@ def moe_layer(
         held_rows_factor: the dropless row buffer of a share, as a multiple
             of its even share of the assignments (``held_rows``).
         shared: (gate [E, Fs], up [E, Fs], down [Fs, E]) of a SwiGLU every
-            token passes beside the routed experts, or None.
-        activation: the gated products' ("silu" | "relu"), the shared
-            expert's too.
+            token passes beside the routed experts (gate None: un-gated, as
+            the experts), or None.
+        activation: the gated products' ("silu" | "relu") or the un-gated
+            one's ("relu2"), the shared expert's too.
         routed: ``routing``'s result where the scores' input is not x (the
             router's arguments above are then unused here), or None: x is
             routed here.
@@ -521,13 +538,13 @@ def moe_layer(
         ``rows_held`` (int32, those that fell on held experts) and
         ``dropped`` (int32, assignments to HELD experts that reached none: 0
         on the dropless path with every expert held, by construction); on the
-        dropless path under ReLU also ``active_units`` (int32, the (row,
+        dropless path under ReLU (or its square) also ``active_units`` (int32, the (row,
         hidden unit) pairs that are not zero, over the rows an assignment
         landed in — of ``(rows_held - dropped) * F``).
     """
     rules = rules or ShardingRules()
     B, S, E = x.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     T = B * S
     if routed is None:
         routed = routing(x, router, top_k=top_k, norm_topk=norm_topk, score=score, route_bias=route_bias,
@@ -566,8 +583,8 @@ def moe_layer(
         y = y.reshape(B, S, E).astype(x.dtype)
     if shared is not None:
         with jax.named_scope("shared_expert"):
-            s_gate, s_up, s_down = (w.astype(dtype) for w in shared)
-            y = y + ((ACTIVATIONS[activation](x @ s_gate) * (x @ s_up)) @ s_down).astype(x.dtype)
+            s_gate, s_up, s_down = (w if w is None else w.astype(dtype) for w in shared)
+            y = y + (hidden_units(activation, s_gate, s_up, lambda w: x @ w) @ s_down).astype(x.dtype)
     return y, stats
 
 

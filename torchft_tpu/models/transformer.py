@@ -62,7 +62,13 @@ class LayerKind:
     # Attention (arXiv:2510.26692; `_kda_mixer`) — no softmax and no position
     # term: a gated delta rule with a decay a channel over `n_heads` heads of
     # `kda_head_dim`, under short causal convolutions and low-rank gates.
+    # "mamba2": a Mamba-2 state-space mixer (arXiv:2405.21060; models/mamba.py)
+    # over `n_heads` heads of the model's `ssm_*` sizes.  "none": the block has
+    # no mixer — it is a feed-forward alone under its one norm (`mlp_norm`).
     mixer: str = "attention"
+    # False: the block is a mixer alone under its one norm (`attn_norm`): no
+    # second norm, no feed-forward, no such leaves in its stack.
+    feed_forward: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,8 +213,18 @@ class TransformerConfig:
     # The activation of every gated feed-forward (experts, shared expert,
     # dense layers): "silu" (SwiGLU) or "relu" (ReGLU).  Under "relu" the held
     # experts' rows count their hidden units that are not zero
-    # (`moe_active_units` of `moe_units_held`, loss_and_counters).
+    # (`moe_active_units` of `moe_units_held`, loss_and_counters).  "relu2":
+    # UN-GATED feed-forwards, `max(h W_up, 0)**2 W_down` — two matrices and no
+    # `w_gate` / `shared_gate` leaf (Nemotron-H's experts); counted as "relu".
     moe_activation: str = "silu"
+    # The Mamba-2 mixer's sizes (LayerKind.mixer "mamba2"; the heads are the
+    # kind's): a head's width, the groups that share B and C, the state's rows
+    # a head, the kernel of the depthwise causal convolution, the scan's chunk.
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -219,7 +235,7 @@ class TransformerConfig:
             f"unknown ring_layout {self.ring_layout!r}"
         )
         assert self.moe_score in ("softmax", "sigmoid"), f"unknown moe_score {self.moe_score!r}"
-        assert self.moe_activation in ("silu", "relu"), f"unknown moe_activation {self.moe_activation!r}"
+        assert self.moe_activation in ("silu", "relu", "relu2"), f"unknown moe_activation {self.moe_activation!r}"
         if self.moe_router_early:
             assert self.moe_experts > 0 and not self.moe_router_state, (
                 "an early router is one matrix over the layer's input: a router with a state reads the experts'"
@@ -244,7 +260,14 @@ class TransformerConfig:
         if self.pattern:
             assert len(self.pattern) == self.n_layers and not self.moe_dense_layers, "one kind a layer"
             assert self.attention == "flash" and not self.dsa_index_heads, "a pattern's kinds run the flash backend"
-            assert all(kind.mixer in ("attention", "cca", "mla", "kda") for kind in self.pattern)
+            assert all(kind.mixer in ("attention", "cca", "mla", "kda", "mamba2", "none") for kind in self.pattern)
+            assert all((kind.feed_forward or not kind.sparse) and (kind.feed_forward or kind.mixer != "none")
+                       for kind in self.pattern), "a block is a mixer, a feed-forward, or both"
+            assert all(kind.n_heads % self.ssm_groups == 0 for kind in self.pattern if kind.mixer == "mamba2")
+            assert len({"kda", "mamba2"} & {kind.mixer for kind in self.pattern}) < 2, "one decay's mean is counted"
+            assert not (self.moe_router_early and any(kind.mixer == "none" for kind in self.pattern)), (
+                "an early router reads the input of a block that has a mixer"
+            )
             assert bool(self.mla_kv_rank) == any(kind.mixer == "mla" for kind in self.pattern), (
                 "the latent widths are the model's, the layers that use them the pattern's"
             )
@@ -330,6 +353,10 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
         layer.update({"kda_a_down": ("layers", "embed", None), "kda_g_down": ("layers", "embed", None),
                       "kda_beta": ("layers", "embed", None), "A_log": ("layers", None), "dt_bias": ("layers", "heads"),
                       "kda_g_bias": ("layers", "heads"), "kda_norm": ("layers", None)})
+    elif kind.mixer == "mamba2":
+        from torchft_tpu.models.mamba import mamba2_axes
+
+        layer.update(mamba2_axes())
     else:
         layer.update({"wk": ("layers", "embed", "kv_heads"), "wv": ("layers", "embed", "kv_heads")})
     if cfg.qk_norm:
@@ -360,7 +387,28 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
         if cfg.moe_shared_experts:
             layer.update({"shared_gate": ("layers", "embed", "mlp"), "shared_up": ("layers", "embed", "mlp"),
                           "shared_down": ("layers", "mlp", "embed")})
-    return layer
+    return _leaves_of_the_kind(cfg, kind, layer)
+
+
+_MIXER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo")
+_FEED_FORWARD_LEAVES = ("mlp_norm", "w_gate", "w_up", "w_down")
+
+
+def _leaves_of_the_kind(cfg: TransformerConfig, kind: LayerKind, layer: Dict[str, Any]) -> Dict[str, Any]:
+    """``layer`` (a stack's leaves, or their axes) without what the kind does
+    not have: the mixer's of a block that is a feed-forward alone, the
+    feed-forward's of a block that is a mixer alone, attention's projections
+    of a Mamba-2 block, the gate matrices of un-gated feed-forwards."""
+    drop = set()
+    if kind.mixer == "none":
+        drop.update(_MIXER_LEAVES)
+    if kind.mixer == "mamba2":
+        drop.update(_MIXER_LEAVES[1:])
+    if not kind.feed_forward:
+        drop.update(_FEED_FORWARD_LEAVES)
+    if cfg.moe_activation == "relu2":
+        drop.update(("w_gate", "shared_gate"))
+    return {name: leaf for name, leaf in layer.items() if name not in drop} if drop else layer
 
 
 def _state_router_axes() -> Dict[str, Any]:
@@ -395,6 +443,10 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
     layers = {"attn_norm": jnp.ones((L, E), pd), "mlp_norm": jnp.ones((L, E), pd)}
     if kind.mixer == "kda":
         layers.update(_init_kda(jax.random.fold_in(key, 5), cfg, L, H))
+    elif kind.mixer == "mamba2":
+        from torchft_tpu.models.mamba import init_mamba2
+
+        layers.update(init_mamba2(jax.random.fold_in(key, 6), cfg, L, H))
     elif kind.mixer == "mla":
         R, Dq = cfg.mla_kv_rank, cfg.mla_nope_dim + cfg.mla_rope_dim
         layers.update(
@@ -486,7 +538,7 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
                 "w_down": norm_init(ks[6], (L, F, E), F),
             }
         )
-    return layers
+    return _leaves_of_the_kind(cfg, kind, layers)
 
 
 def _init_kda(key: jax.Array, cfg: TransformerConfig, L: int, H: int) -> Dict[str, Any]:
@@ -948,6 +1000,8 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     B, S, E = x.shape
     kind = cfg.layers[-1] if kind is None else kind
     H, KV = kind.n_heads, cfg.n_kv_heads
+    if kind.mixer == "none":  # a feed-forward alone: its one norm is `_feed_forward`'s
+        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, None)
 
     routed = None
     if cfg.moe_router_early and kind.sparse:
@@ -964,6 +1018,13 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
             x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
             x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
         return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"kda_alpha": alpha}, routed)
+    if kind.mixer == "mamba2":
+        from torchft_tpu.models.mamba import mamba2_mixer
+
+        out, decay = mamba2_mixer(cfg, kind, mesh, h, w)
+        with jax.named_scope("attn_proj"):
+            x = constrain(_merge(x, out, w.get("attn_merge")), ("batch", "seq", "embed"), mesh, rules)
+        return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"ssm_decay": decay}, routed)
     with jax.named_scope("attn_proj"):
         if kind.mixer == "cca":
             q, k, v = _cca_qkv(cfg, kind, h, w, positions)
@@ -1009,7 +1070,17 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
         attn = attn.reshape(B, S, H * attn.shape[-1])
         x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
         x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
-    return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa, routed)
+    return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa, routed)
+
+
+def _after_the_mixer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind: LayerKind, router_bias,
+                     router_state, mixer_stats, routed):
+    """What follows a block's mixer: the kind's feed-forward, or — a block
+    that is a mixer alone — nothing: the stream and the mixer's statistics."""
+    if kind.feed_forward:
+        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, mixer_stats, routed)
+    aux = mixer_stats if mixer_stats is not None else jnp.zeros((), jnp.float32)
+    return ((x, router_state) if cfg.moe_router_state else x), aux
 
 
 def _router_form(cfg: TransformerConfig, router_bias, router_state) -> Dict[str, Any]:
@@ -1033,12 +1104,12 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
         y, aux = moe_layer(
             h,
             w["router"],
-            w["w_gate"],
+            w.get("w_gate"),  # None: un-gated experts
             w["w_up"],
             w["w_down"],
             capacity_factor=cfg.moe_capacity_factor,
             held_first=cfg.moe_held[0] if cfg.moe_held is not None else 0,
-            shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
+            shared=(w.get("shared_gate"), w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
             activation=cfg.moe_activation,
             routed=routed,
             dtype=cfg.dtype,
@@ -1052,11 +1123,10 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
             x = _merge(x, y, w.get("mlp_merge"))
     else:
         with jax.named_scope("ffn"):
-            from torchft_tpu.models.moe import ACTIVATIONS
+            from torchft_tpu.models.moe import hidden_units
 
-            gate = ACTIVATIONS[cfg.moe_activation](h @ w["w_gate"].astype(cfg.dtype))
-            up = h @ w["w_up"].astype(cfg.dtype)
-            x = _merge(x, (gate * up) @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
+            hidden = hidden_units(cfg.moe_activation, w.get("w_gate"), w["w_up"], lambda m: h @ m.astype(cfg.dtype))
+            x = _merge(x, hidden @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
         aux = {} if mixer_stats is not None else jnp.zeros((), jnp.float32)
     if mixer_stats is not None:
         aux = dict(aux, **mixer_stats)
@@ -1128,14 +1198,16 @@ def _decoder(
             pending.clear()
 
     alpha_total, kda_layers = jnp.zeros((), jnp.float32), sum(kind.mixer == "kda" for kind in cfg.layers)
+    ssm_layers = sum(kind.mixer == "mamba2" for kind in cfg.layers)
 
     def without_alpha(aux):
-        """A KDA layer's (or run's) statistics without its mean decay, which is summed apart."""
+        """A KDA or Mamba-2 layer's (or run's) statistics without its mean decay, which is summed apart."""
         nonlocal alpha_total
-        if not (isinstance(aux, dict) and "kda_alpha" in aux):
+        name = next((n for n in ("kda_alpha", "ssm_decay") if isinstance(aux, dict) and n in aux), None)
+        if name is None:
             return aux
         aux = dict(aux)
-        alpha_total = alpha_total + jnp.sum(aux.pop("kda_alpha"))
+        alpha_total = alpha_total + jnp.sum(aux.pop(name))
         return aux or jnp.zeros((), jnp.float32)
 
     # The walk of the pattern: runs of one kind, each through its own stack;
@@ -1201,6 +1273,8 @@ def _decoder(
         out = _over_layers(whole)
         if kda_layers:
             out["kda_alpha"] = alpha_total / kda_layers
+        if ssm_layers:
+            out["ssm_decay"] = alpha_total / ssm_layers
         return x, out
 
 
@@ -1226,6 +1300,10 @@ def _remat(cfg: TransformerConfig, body):
         from torchft_tpu.ops.delta_attention import SAVED_NAMES as KDA_SAVED_NAMES
 
         names += KDA_SAVED_NAMES
+    if any(kind.mixer == "mamba2" for kind in cfg.layers):
+        from torchft_tpu.ops.ssd import SAVED_NAMES as SSD_SAVED_NAMES
+
+        names += SSD_SAVED_NAMES
     return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
@@ -1402,6 +1480,8 @@ def loss_and_counters(
         counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
         if "kda_alpha" in aux:
             counters.update(kda_alpha_mean=aux["kda_alpha"])
+        if "ssm_decay" in aux:
+            counters.update(ssm_decay_mean=aux["ssm_decay"])
         if cfg.moe_skip:
             counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:

@@ -168,6 +168,13 @@ SUBSPANS = {
 #     one pass a direction, with beta's sigmoid and the decay's mean in XLA
 #     beside them; elsewhere XLA fusions); kda_scan — the gated delta rule over the sequence (the
 #     `tpuft_kda_*` kernels and whatever XLA puts around them);
+#     ssm_mix — what a Mamba-2 block puts around its scan: the kernel-4
+#     convolution with its bias and SiLU, softplus and the decay before it,
+#     the skip D x, the gate SiLU(z) and the norm over groups after it (XLA
+#     fusions; the in- and out-projections are `attn_proj`'s, as KDA's are);
+#     ssm_scan — the state-space recurrence over the sequence (the
+#     `tpuft_ssd_*` kernels, the running sums of the log decay and whatever
+#     else XLA puts around them);
 #     attn — the attention call: kernels and
 #     whatever XLA puts around them; attn_window — the same call in a layer
 #     that attends under a window (the `tpuft_swa_*` kernels), so that a
@@ -184,7 +191,7 @@ SUBSPANS = {
 #     stacked gradient) and the stacking of the layers' statistics.
 PARTS = (
     "embed", "norm", "attn_proj", "cca_mix", "kda_mix", "kda_scan", "attn", "attn_window", "dsa_index", "dsa_select",
-    "ffn", "router", "experts", "shared_expert", "head_loss", "stack",
+    "ffn", "router", "experts", "shared_expert", "head_loss", "stack", "ssm_mix", "ssm_scan",
 )
 
 

@@ -27,13 +27,24 @@ type in VMEM, once a group, so no rounded copy of the matrices is written or
 kept; and its gradient leaves ``tpuft_gmm_drhs``'s float32 accumulator in
 ``rhs``'s own type with no rounding in between.
 
+A width that is no whole number of 128-lane tiles (an expert of 1,856 = 14.5
+x 128 columns) runs the same kernels over operands padded with zeros up to the
+next tile INSIDE the call (``_padded``): zero columns of ``lhs`` against zero
+rows of ``rhs`` add nothing and the padded columns of the result are cut away,
+so the product is exact, and under autodiff the pads' transposes cut the
+gradients back to the leaves' own shapes.  No width changes outside the call.
+
 Off the TPU, under a mesh of several devices, and for shapes the kernels do
-not tile, the same product is ``jax.lax.ragged_dot`` under autodiff.
+not tile, the same product is ``jax.lax.ragged_dot`` under autodiff — and where
+the caller promised tiles (``row_tile > 1``) on one TPU device, the fall is
+said once a shape in the log (``_say_once``), so that an odd width is seen in a
+run and not only in a trace.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional, Tuple
 
 import jax
@@ -42,6 +53,8 @@ import jax.numpy as jnp
 from torchft_tpu.ops import _pallas_util
 
 __all__ = ["ROW_TILE", "grouped_matmul", "padded_group_sizes"]
+
+logger = logging.getLogger(__name__)
 
 # Rows a tile (v5e measurements behind the choice: PERF.md section 6, PR 27).
 ROW_TILE = 128
@@ -263,8 +276,29 @@ def grouped_matmul(
     shapes tile, the ``tpuft_gmm_*`` kernels run; otherwise
     ``jax.lax.ragged_dot``, which asks nothing of the sizes."""
     m, k = lhs.shape
-    if row_tile > 1 and _tiles(m, k, rhs.shape[2], row_tile, rhs.dtype.itemsize) is not None and (
-        interpret or _pallas_util.kernels_apply(mesh)
-    ):
-        return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), row_tile, interpret)
+    n = rhs.shape[2]
+    if row_tile > 1 and (interpret or _pallas_util.kernels_apply(mesh)):
+        lane = _pallas_util.LANE
+        pad_k, pad_n = -k % lane, -n % lane
+        if _tiles(m, k + pad_k, n + pad_n, row_tile, rhs.dtype.itemsize) is not None:
+            if pad_k or pad_n:
+                lhs, rhs = _padded(lhs, rhs, pad_k, pad_n)
+            out = _gmm(lhs, rhs, group_sizes.astype(jnp.int32), row_tile, interpret)
+            return out[:, :n] if pad_n else out
+        _say_once(m, k, n, row_tile)
     return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes.astype(jnp.int32))
+
+
+def _padded(lhs, rhs, pad_k: int, pad_n: int):
+    """lhs [M, K] and rhs [G, K, N] with K and N filled up with zeros."""
+    if pad_k:
+        lhs = jnp.pad(lhs, [(0, 0), (0, pad_k)])
+    return lhs, jnp.pad(rhs, [(0, 0), (0, pad_k), (0, pad_n)])
+
+
+@functools.lru_cache(maxsize=None)
+def _say_once(m: int, k: int, n: int, row_tile: int) -> None:
+    """Trace time, once a shape: the kernels were promised tiles and do not
+    tile this product."""
+    logger.warning("grouped_matmul: [%d, %d] x [G, %d, %d] at row_tile %d does not tile for the tpuft_gmm_* kernels; "
+                   "it runs jax.lax.ragged_dot", m, k, k, n, row_tile)
