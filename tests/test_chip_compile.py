@@ -793,6 +793,43 @@ def test_kda_mix_kernels_compile_for_v5e(one_chip, kernel) -> None:
     assert not re.search(r"= (?:bf16|f32)\[1,(?:16384,4096|32,16384,128)\]\S* (?:copy|transpose)\(", text)
 
 
+@pytest.mark.parametrize("kernel", ["before_forward", "before_backward", "after_forward", "after_backward"])
+def test_ssm_mix_kernels_compile_for_v5e(one_chip, kernel) -> None:
+    """The four `tpuft_ssmmix_*` kernels at the Nemotron cell's shape: one
+    sequence of 16,384 positions, 64 heads of 64 in 8 groups over a state of
+    128 in bfloat16 — u's 6,144 columns read in place in blocks of four lane
+    tiles (x's eight blocks, B's two, C's two: the outputs whose turn it is
+    not stay where they are), dt onto two heads a lane tile as a product with
+    a 0/1 matrix and back as its transpose, the convolution's shifted reads at
+    unaligned rows of a float32 scratch, the group norm's reduction over four
+    lane tiles, the partial sums' blocks of one row."""
+    import re
+
+    from torchft_tpu.ops import ssm_mix
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    b, seq, heads, p, groups, state = 1, 16_384, 64, 64, 8, 128
+    inner, bc = heads * p, groups * state
+    assert ssm_mix.tile_of(seq) == 1024 and ssm_mix._after_tile(seq, inner // groups, None) == 1024
+    assert ssm_mix._lanes(inner, bc) == 4
+    u, wide, narrow, dt = sds((b, seq, inner + 2 * bc), bf16), sds((b, seq, inner), bf16), sds((b, seq, bc), bf16), sds((b, seq, 128), f32)
+    taps, bias, column = sds((4, inner + 2 * bc), f32), sds((1, inner + 2 * bc), f32), sds((1, inner), f32)
+    fn, shapes, name = {
+        "before_forward": (lambda *a: ssm_mix._before_fwd_pallas(*a, p, inner, 1024), [u, dt, taps, bias], "tpuft_ssmmix_fwd"),
+        "before_backward": (lambda *a: ssm_mix._before_bwd_pallas(*a, p, inner, 1024),
+                            [u, dt, taps, bias, wide, wide, narrow, narrow], "tpuft_ssmmix_bwd"),
+        "after_forward": (lambda *a: ssm_mix._after_fwd_pallas(*a, groups, 1e-5, 1024), [wide] * 3 + [column] * 2,
+                          "tpuft_ssmmix_out_fwd"),
+        "after_backward": (lambda *a: ssm_mix._after_bwd_pallas(*a, groups, 1e-5, 1024), [wide] * 3 + [column] * 2 + [wide],
+                           "tpuft_ssmmix_out_bwd"),
+    }[kernel]
+    text = _compile(fn, *shapes)
+    assert _kernel_calls(text, "tpuft_ssmmix_") == [name] and not _kernel_calls(text, "tpuft_ssd_")
+    # nothing between input and output in HBM: no transpose, copy or join of a [16,384, 4,096] or [16,384, 6,144] array
+    assert not re.search(r"= (?:bf16|f32)\[1,16384,(?:4096|6144)\]\S* (?:copy|transpose|concatenate|fusion)\(", text)
+
+
 def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
     """The benchmark's `kimi-linear-48b-a3b` configuration as
     `benchmark/programs/kda_mla_moe_lm.py` hands it to `TrainStep`: the whole
@@ -997,6 +1034,14 @@ def test_nemotron_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip,
     assert config["program"]["remat"] and config["program"]["remat_keeps_attention"]
     assert sorted(_kernel_calls(text, "tpuft_ssd_")) == ["tpuft_ssd_bwd"] * 4 + ["tpuft_ssd_fwd"] * 8
     assert set(_kernel_grids(text, "tpuft_ssd_")) == {("tpuft_ssd_bwd", (8, 128)), ("tpuft_ssd_fwd", (8, 128))}
+    # `ssm_mix` around it (since PR 57): each half's forward kernel twice a block (the forward pass and the block's
+    # recomputation: a half keeps its inputs, so nothing runs it a third time) and its backward kernel once
+    assert sorted(_kernel_calls(text, "tpuft_ssmmix_")) == (
+        ["tpuft_ssmmix_bwd"] * 4 + ["tpuft_ssmmix_fwd"] * 8 + ["tpuft_ssmmix_out_bwd"] * 4 + ["tpuft_ssmmix_out_fwd"] * 8)
+    # before: 16 tiles of 1,024 rows x 12 blocks of 512 columns (8 of x, 2 of B, 2 of C); after: 16 tiles x 8 groups
+    assert set(_kernel_grids(text, "tpuft_ssmmix_")) == {
+        ("tpuft_ssmmix_fwd", (1, 16, 12)), ("tpuft_ssmmix_bwd", (1, 16, 12)),
+        ("tpuft_ssmmix_out_fwd", (1, 16, 8)), ("tpuft_ssmmix_out_bwd", (1, 16, 8))}
     assert sorted(_attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
     assert sorted(_kernel_calls(text, "tpuft_gmm_")) == (["tpuft_gmm_dlhs"] * 8 + ["tpuft_gmm_drhs"] * 8 + ["tpuft_gmm_fwd"] * 16)
     assert "ragged-dot" not in text and "ragged_dot" not in text
@@ -1006,5 +1051,6 @@ def test_nemotron_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip,
     assert shapes["moe"]["w_up"].shape == (4, 8, 2688, 1856)  # no width is cut or grown in the tree
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     # 15,317,239,296 (arguments 2,667,987,456 + outputs 2,667,862,528 + temporaries 4,645,685,760 + moments
-    # 5,335,703,552; builder's compile, PR 56)
+    # 5,335,703,552; builder's compile, PR 56); with `ssm_mix` as kernels 14,471,001,088 (temporaries 3,799,447,552;
+    # builder's compile, PR 57): the XLA halves' float32 [16,384, 6,144] arrays are gone
     assert resident <= 15_400_000_000, f"the step needs {resident} bytes with AdamW's moments"

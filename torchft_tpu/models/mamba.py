@@ -17,10 +17,16 @@ H heads (the kind's ``n_heads``) of P = ``ssm_head_dim`` columns, G =
 
 The products are `attn_proj`'s, the recurrence `ssm_scan`'s, and `ssm_mix` is
 what stands between: the convolution, SiLU, softplus and the decay before the
-scan, the skip, the gate and the group norm after it — XLA fusions, float32
-inside, the compute type out.  Each half keeps its INPUTS for the backward pass
-and nothing between (a checkpoint each, as `kda_mix`'s XLA halves): left to
-autodiff a block holds a dozen float32 arrays of [S, H P + 2 G N] at once.
+scan, the skip, the gate and the group norm after it — float32 inside, the
+compute type out.  Where `ops.ssm_mix.applies` (a TPU's program over one
+device, a convolution of four taps, shapes of whole lane tiles) each half is a
+pallas kernel pair, `tpuft_ssmmix_*`: one pass over HBM a direction, the
+backward recomputing its tile from the half's inputs.  Elsewhere — the CPU, a
+mesh of several devices, shapes the kernels do not tile — the halves are the
+XLA fusions `_before` and `_after`, the kernels' yardstick: each keeps its
+INPUTS for the backward pass and nothing between (a checkpoint each, as
+`kda_mix`'s XLA halves): left to autodiff a block holds a dozen float32 arrays
+of [S, H P + 2 G N] at once.
 """
 
 from __future__ import annotations
@@ -108,6 +114,7 @@ def mamba2_mixer(cfg, kind, mesh, h: jax.Array, w: Dict[str, Any]) -> Tuple[jax.
     (`ssm_decay_mean`'s term).  W_in's three parts are products of their own:
     one product would write z, u and dt side by side and the split would copy
     each out again."""
+    from torchft_tpu.ops import ssm_mix
     from torchft_tpu.ops.ssd import ssd
 
     heads, dt_ = kind.n_heads, cfg.dtype
@@ -117,9 +124,20 @@ def mamba2_mixer(cfg, kind, mesh, h: jax.Array, w: Dict[str, Any]) -> Tuple[jax.
         z, u = h @ w_in[:, :inner], h @ w_in[:, inner:inner + channels]
         dt_raw = h @ w_in[:, inner + channels:]
     small = {name: w[name] for name in _SMALL}
-    x, xdt, bm, cm, la, decay = jax.checkpoint(lambda *a: _before(*a, cfg, heads))(u, dt_raw, small)
+    kernels = ssm_mix.applies(h.shape[1], cfg.ssm_head_dim, heads // cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv, mesh)
+    if kernels:
+        with jax.named_scope("ssm_mix"):
+            x, xdt, bm, cm, la = ssm_mix.before(u, dt_raw, small["ssm_conv"].T, small["ssm_conv_bias"], small["dt_bias"],
+                                                small["A_log"], head_dim=cfg.ssm_head_dim)
+            decay = jnp.mean(jnp.exp(jax.lax.stop_gradient(la)))
+    else:
+        x, xdt, bm, cm, la, decay = jax.checkpoint(lambda *a: _before(*a, cfg, heads))(u, dt_raw, small)
     with jax.named_scope("ssm_scan"):
         y = ssd(xdt, bm, cm, la, head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups, chunk=cfg.ssm_chunk, mesh=mesh)
-    o = jax.checkpoint(lambda *a: _after(*a, cfg, heads))(y, x, z, small)
+    if kernels:
+        with jax.named_scope("ssm_mix"):
+            o = ssm_mix.after(y, x, z, small["ssm_D"], small["ssm_norm"], groups=cfg.ssm_groups, eps=cfg.rms_eps)
+    else:
+        o = jax.checkpoint(lambda *a: _after(*a, cfg, heads))(y, x, z, small)
     with jax.named_scope("attn_proj"):
         return o @ w["ssm_out"].astype(dt_), decay
