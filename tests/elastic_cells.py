@@ -318,7 +318,11 @@ def run_trace_cell(
             min_replicas=max(1, min_groups),
             join_timeout_ms=10000 + 500 * start_groups,
             quorum_tick_ms=50,
-            heartbeat_timeout_ms=3000,
+            # Every departure of a trace is by notice (a drain), so a stale
+            # heartbeat detects nothing here; at 3 s a group that six workers'
+            # load kept off the CPU for 3.4 s was taken for dead, and a
+            # survivor's commit failed that no transition had caused.
+            heartbeat_timeout_ms=15000,
         )
         env = script_env()
         env["TPUFT_METRICS_PATH"] = metrics_path

@@ -1,0 +1,396 @@
+"""The flash-attention kernels' walks, traced or in interpret mode: the lower
+triangle tile by tile, several heads a grid step, the band under a window, the
+grids at the cells' lengths."""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from test_ops import pallas_call_grids, pallas_call_names
+
+
+def _masked_reference(q, k, v, keep, scale):
+    """Dense softmax attention over the pairs `keep` [S, S] allows: (out, lse)."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+    s = jnp.where(keep, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked: bool) -> None:
+    """The flash kernels in interpret mode at n tiles a side, forward (out,
+    lse) and one-pass backward (dq, dk, dv), against the XLA formulations:
+    causal against `_fa_reference` / `_fa_bwd_xla`, under a packed per-pair
+    mask (a seeded third of the visible pairs, the diagonal among them)
+    against dense masked softmax attention and its autodiff.  With
+    `kv_group` the kernels read one KV head for a group of query heads and
+    give dk, dv a query head each.  Each call's grid is (heads / H, n (n +
+    1) / 2): a step for each tile of the lower triangle and no other, H heads
+    a step."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    seq, heads = 512 * n, max(2, kv_group)
+    ks = jax.random.split(jax.random.PRNGKey(100 * n + kv_group + masked), 5)
+    q = jax.random.normal(ks[0], (heads, seq, d_qk), jnp.float32)
+    k = jax.random.normal(ks[1], (heads // kv_group, seq, d_qk), jnp.float32)
+    v = jax.random.normal(ks[2], (heads // kv_group, seq, d_v), jnp.float32)
+    g = jax.random.normal(ks[3], (heads, seq, d_v), jnp.float32)
+    scale = d_qk ** -0.5
+    k_all, v_all = jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0)
+    more = {"kv_group": kv_group}
+    if masked:
+        keep = (jax.random.bernoulli(ks[4], 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)) & jnp.tril(jnp.ones((seq, seq), bool))
+        more["mask"] = sa.packed_lower_triangle(keep[None]).astype(jnp.int8)
+        (want_o, want_lse), vjp = jax.vjp(lambda *qkv: _masked_reference(*qkv, keep, scale), q, k_all, v_all)
+        want = vjp((g, jnp.zeros_like(want_lse)))
+    else:
+        want_o, want_lse = fa._fa_reference(q, k_all, v_all, scale, True)
+        want = fa._fa_bwd_xla(q, k_all, v_all, want_o, want_lse, g, scale, True)
+    fwd = functools.partial(fa._fa_pallas_call, scale=scale, causal=True, interpret=True, **more)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, **more)
+    got_o, got_lse = fwd(q, k, v)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=2e-3, atol=2e-3)
+    got = bwd(q, k, v, got_o, got_lse, g)
+    assert [a.shape for a in got] == [q.shape, (heads, seq, d_qk), (heads, seq, d_v)]
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+    # H heads a grid step, H read from the shapes: all of these heads (one KV head's, or a batch
+    # entry's) forward; backward as many of them as their dq rows leave room for
+    tiles, share = n * (n + 1) // 2, fa._heads_share(heads, more.get("mask"), kv_group)
+    fwd_heads = fa._heads_per_step(share)
+    bwd_heads = fa._bwd_heads_per_step(share, fa._row_vmem_bytes(seq, d_qk, 4))
+    assert fwd_heads == heads and bwd_heads > 1
+    assert pallas_call_grids(fwd, q, k, v) == {("tpuft_dsa_attn_fwd" if masked else "tpuft_fa_fwd"): (heads // fwd_heads, tiles)}
+    assert pallas_call_grids(bwd, q, k, v, got_o, got_lse, g) == {
+        ("tpuft_dsa_attn_bwd_dkdv_dq" if masked else "tpuft_fa_bwd_dkdv_dq"): (heads // bwd_heads, tiles)}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+@pytest.mark.parametrize("kv_group", [1, 8])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_flash_kernels_walk_the_lower_triangle(n, kv_group, masked) -> None:
+    check_the_triangular_walk(n, 128, 128, kv_group, masked)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+def test_short_rows_of_dq_are_cast_out_at_their_diagonal_step(masked) -> None:
+    """Three tiles a side, one pass: on the square grid every q tile's dq
+    rows left the f32 row at kv tile 2's steps, which a triangular walk
+    visits for q tile 2 alone.  The rows of q tiles 0 and 1 are complete —
+    and have to be cast into the output — at kv tiles 0 and 1."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    seq = 1536
+    q, k, v, g = (jax.random.normal(kk, (2, seq, 128), jnp.bfloat16) for kk in jax.random.split(jax.random.PRNGKey(3), 4))
+    mask = sa.packed_lower_triangle(jnp.tril(jnp.ones((1, seq, seq), jnp.int8))) if masked else None
+    o, lse = fa._fa_pallas_call(q, k, v, 0.088, True, interpret=True, mask=mask)
+    dq, _, _ = fa._fa_bwd_pallas(q, k, v, o, lse, g, 0.088, True, interpret=True, mask=mask)
+    want, _, _ = fa._fa_bwd_xla(q, k, v, o, lse, g, 0.088, True)
+    dq, want = np.asarray(dq, np.float32), np.asarray(want, np.float32)
+    for qi in range(3):
+        rows = slice(512 * qi, 512 * (qi + 1))
+        assert np.abs(dq[:, rows]).max() > 0.01, f"q tile {qi}: nothing was written"
+        assert np.linalg.norm(dq[:, rows] - want[:, rows]) < 0.01 * np.linalg.norm(want[:, rows]), f"q tile {qi}"
+
+
+@pytest.mark.parametrize("heads_per_step", [2, 4])
+@pytest.mark.parametrize("kind", ["causal", "rectangle", "window", "masked_kv_group_8", "unequal_widths"])
+def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> None:
+    """out, lse, dq, dk, dv with H heads a grid step — every block and scratch
+    leading with the heads, the tile's arithmetic under `jax.vmap` — are bit
+    for bit those of one head a step: over the triangle, a rectangle (queries
+    against a longer key sequence, not causal), the band, a packed mask whose
+    eight query heads read one KV head in place (and share the mask's tile),
+    and query and key 256 wide beside a value of 128."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    bh, seq_q, seq_k, d, dv, kv_group = 8, 1024, 1024, 128, 128, 1
+    causal, more = True, {}
+    if kind == "rectangle":
+        causal, seq_k = False, 1536
+    elif kind == "window":
+        seq_q = seq_k = 1536
+        more["window"] = 600
+    elif kind == "masked_kv_group_8":
+        kv_group = 8
+        keep = jax.random.bernoulli(jax.random.PRNGKey(5), 0.3, (seq_q, seq_q)) | jnp.eye(seq_q, dtype=bool)
+        more.update(mask=sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8), kv_group=8)
+    elif kind == "unequal_widths":
+        bh, d = 4, 256
+    ks = jax.random.split(jax.random.PRNGKey(len(kind)), 4)
+    q = jax.random.normal(ks[0], (bh, seq_q, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (bh // kv_group, seq_k, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (bh // kv_group, seq_k, dv), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (bh, seq_q, dv), jnp.bfloat16)
+
+    def kernels(heads):
+        fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        o, lse = fwd(q, k, v)
+        grids = {**pallas_call_grids(fwd, q, k, v), **pallas_call_grids(bwd, q, k, v, o, lse, g)}
+        assert len(grids) == 2 and {grid[0] for grid in grids.values()} == {bh // heads}, grids
+        return (o, lse) + tuple(bwd(q, k, v, o, lse, g))
+
+    want = kernels(1)
+    assert all(float(jnp.abs(x.astype(jnp.float32)).max()) > 0.01 for x in want)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), kernels(heads_per_step), want):
+        assert a.dtype == b.dtype and a.shape == b.shape and bool(jnp.array_equal(a, b)), name
+
+
+def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
+    """H is read from the shapes: the largest divisor of the heads not above
+    `HEADS_PER_STEP` whose dq rows and tiles fit VMEM.  At 65,536 x 128 one
+    head's row, its output block and tiles are 80 MiB: two do not fit, the
+    backward stays at one head a step while the forward, which keeps no row,
+    takes both; the two-pass form over a longer row keeps no row either."""
+    from torchft_tpu.ops import attention as fa
+
+    mib = 2 ** 20
+    row = fa._row_vmem_bytes(65536, 128, 2)
+    assert row == 64 * mib and 2 * (row + fa._TILE_VMEM_BYTES) > fa._VMEM_BUDGET
+    assert fa._bwd_heads_per_step(8, row) == 1 and fa._bwd_heads_per_step(8, 0) == fa.HEADS_PER_STEP == 8
+    assert [fa._bwd_heads_per_step(32, fa._row_vmem_bytes(seq, d, 2)) for seq, d in
+            ((4096, 128), (8192, 256), (16384, 128), (16384, 256), (32768, 128))] == [4, 4, 4, 2, 2]
+    assert [fa._heads_per_step(share) for share in (1, 2, 7, 8, 28, 32, 48, 64)] == [1, 2, 7, 8, 7, 8, 8, 8]
+    bh, seq = 2, 65536
+    n = seq // 512
+    qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    assert pallas_call_grids(functools.partial(fa._fa_pallas_call, scale=0.088, causal=True), qkv, qkv, qkv) == {
+        "tpuft_fa_fwd": (1, n * (n + 1) // 2)}
+    assert pallas_call_grids(functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), qkv, qkv, qkv, qkv, lse, qkv) == {
+        "tpuft_fa_bwd_dkdv_dq": (2, n * (n + 1) // 2)}
+    longer = jax.ShapeDtypeStruct((bh, seq + 512, 128), jnp.bfloat16)
+    assert {grid[0] for grid in pallas_call_grids(
+        functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), longer, longer, longer, longer,
+        jax.ShapeDtypeStruct((bh, seq + 512), jnp.float32), longer).values()} == {1}
+
+
+# (batch * heads, positions, query and key width, value width, window, query heads a KV head under a mask): the forward's
+# and the backward's heads a grid step
+CELL_SHAPES = {
+    "dense_16_heads": ((32, 4096, 128, 128, None, None), (8, 4)),
+    "dense_32_heads": ((64, 4096, 128, 128, None, None), (8, 4)),
+    "moonlight": ((32, 8192, 256, 128, None, None), (8, 4)),
+    "keye_masked": ((32, 32768, 128, 128, None, 8), (8, 2)),
+    "laguna_full": ((48, 16384, 128, 128, None, None), (8, 4)),
+    "laguna_window": ((64, 16384, 128, 128, 512, None), (8, 4)),
+    "zaya": ((8, 16384, 128, 128, None, None), (8, 4)),
+    "kimi": ((32, 16384, 256, 128, None, None), (8, 2)),
+    "smallthinker_full": ((28, 16384, 128, 128, None, None), (7, 4)),
+    "smallthinker_window": ((28, 16384, 128, 128, 4096, None), (7, 4)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_heads_a_grid_step_at_the_cells_shapes(cell) -> None:
+    """The traced `pallas_call`s at every cell's attention shape (no kernel
+    runs): grid (batch * heads / H, tiles) with more than one head a step in
+    both directions."""
+    from torchft_tpu.ops import attention as fa
+
+    (bh, seq, d, dv, window, kv_group), (fwd_heads, bwd_heads) = CELL_SHAPES[cell]
+    n = seq // 512
+    tiles = len(fa._Walk(True, seq, seq, 512, 512, window=window).tables[0])
+    assert tiles == (n * (n + 1) // 2 if window is None else {512: 2 * n - 1, 4096: 252}[window])
+    q = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, dv), jnp.bfloat16)
+    o = jax.ShapeDtypeStruct((bh, seq, dv), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8) if kv_group else None
+    more = {"kv_group": kv_group} if kv_group else {"window": window}
+    family = "tpuft_dsa_attn" if kv_group else "tpuft_fa" if window is None else "tpuft_swa"
+    assert min(fwd_heads, bwd_heads) > 1
+    assert pallas_call_grids(lambda q_, k_, v_, m_: fa._fa_pallas_call(q_, k_, v_, 0.088, True, mask=m_, **more),
+                             q, k, v, mask) == {family + "_fwd": (bh // fwd_heads, tiles)}
+    assert pallas_call_grids(lambda q_, k_, v_, o_, l_, g_, m_: fa._fa_bwd_pallas(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more),
+                             q, k, v, o, lse, o, mask) == {family + "_bwd_dkdv_dq": (bh // bwd_heads, tiles)}
+
+
+@pytest.mark.parametrize("seq", [4096, 8192, 32768])
+def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
+    """The traced `pallas_call`s at the cells' three lengths (no kernel
+    runs): causal and masked calls have a step for each of the n (n + 1) / 2
+    tiles of the lower triangle, a non-causal call the whole square."""
+    from torchft_tpu.ops import attention as fa
+
+    bh, n = 8, seq // 512
+    tiles = n * (n + 1) // 2
+    qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((bh // 8, seq, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8)
+
+    def fwd(causal, **more):
+        return functools.partial(fa._fa_pallas_call, scale=0.088, causal=causal, **more)
+
+    def bwd(causal, **more):
+        return functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=causal, **more)
+
+    # eight heads a step forward; backward as many as their dq rows (seq x 128 x 8 bytes a head) and
+    # tiles leave room for in VMEM: four, four and two
+    f, b = bh // fa._heads_per_step(bh), bh // fa._bwd_heads_per_step(bh, fa._row_vmem_bytes(seq, 128, 2))
+    assert (f, b) == (1, {4096: 2, 8192: 2, 32768: 4}[seq])
+    assert pallas_call_grids(fwd(True), qkv, qkv, qkv) == {"tpuft_fa_fwd": (f, tiles)}
+    assert pallas_call_grids(bwd(True), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (b, tiles)}
+    assert pallas_call_grids(lambda q, k, v, m: fwd(True, kv_group=8)(q, k, v, mask=m), qkv, kv, kv, mask) == {
+        "tpuft_dsa_attn_fwd": (f, tiles)}
+    assert pallas_call_grids(lambda q, k, v, o, l, g, m: bwd(True, kv_group=8)(q, k, v, o, l, g, mask=m),
+                             qkv, kv, kv, qkv, lse, qkv, mask) == {"tpuft_dsa_attn_bwd_dkdv_dq": (b, tiles)}
+    assert pallas_call_grids(fwd(False), qkv, qkv, qkv) == {"tpuft_fa_fwd": (f, n, n)}
+    assert pallas_call_grids(bwd(False), qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_fa_bwd_dkdv_dq": (b, n, n)}
+    # the walk's tables: the forward's row by row (step t is the packed
+    # mask's tile t), the backward's column by column
+    rows, cols = (np.asarray(t) for t in fa._Walk(True, seq, seq, 512, 512).tables)
+    assert [(int(i), int(j)) for i, j in zip(rows[:4], cols[:4])] == [(0, 0), (1, 0), (1, 1), (2, 0)]
+    assert (np.asarray(fa._tri(rows, cols)) == np.arange(tiles)).all() and (cols <= rows).all()
+    rows, cols = (np.asarray(t) for t in fa._Walk(True, seq, seq, 512, 512, kv_major=True).tables)
+    assert (cols[:n] == 0).all() and (rows[:n] == np.arange(n)).all() and (rows[n], cols[n]) == (1, 1)
+    assert len(rows) == tiles and (cols <= rows).all() and (np.diff(cols) >= 0).all()
+
+
+def _dense_window_attention(q, k, v, g, scale, window):
+    """Windowed causal attention written out with a dense mask built from
+    positions, in plain `jax.numpy`: (o, dq, dk, dv) for the cotangent g."""
+    def out(q, k, v):
+        t = jnp.arange(q.shape[1])
+        d = t[:, None] - t[None, :]
+        s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where((d >= 0) & (d < window), s, -jnp.inf), axis=-1)
+        return jnp.einsum("bqk,bkd->bqd", p, v)
+
+    o, vjp = jax.vjp(out, q, k, v)
+    return (o,) + vjp(g)
+
+
+# seq 2048 under 512 x 512 tiles: a window of one tile, one that divides no tile, one narrower than a
+# tile, one of two tiles and a bit, and the last position short of the sequence
+@pytest.mark.parametrize("window", [512, 300, 37, 1100, 2047])
+def test_windowed_flash_kernels_match_a_dense_mask(window) -> None:
+    """The band-walk kernels in interpret mode, forward and all three
+    gradients, against attention over a dense mask; and the XLA fallback
+    against the same."""
+    from torchft_tpu.ops import attention as fa
+
+    seq, scale = 2048, 0.088
+    rng = np.random.default_rng(window)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((2, seq, 128)), dtype=jnp.float32) for _ in range(4))
+    want = _dense_window_attention(q, k, v, g, scale, window)
+    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv_dq"]
+    got = (o,) + tuple(bwd(q, k, v, o, lse, g))
+    o_x, lse_x = fa._fa_reference(q, k, v, scale, True, window)
+    got_xla = (o_x,) + tuple(fa._fa_bwd_xla(q, k, v, o_x, lse_x, g, scale, True, window))
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_x), rtol=1e-5, atol=1e-5)
+    for a, x, b, name in zip(got, got_xla, want, ("o", "dq", "dk", "dv")):
+        # float32 operands; the kernels accumulate tile by tile, the mask at once
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+        np.testing.assert_allclose(np.asarray(x), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name + " (xla)")
+
+
+def test_windowed_two_pass_backward_matches_a_dense_mask(monkeypatch) -> None:
+    """A dq row over the budget takes the two windowed kernels."""
+    from torchft_tpu.ops import attention as fa
+
+    seq, scale, window = 2048, 0.088, 700
+    monkeypatch.setattr(fa, "_DQ_ROW_VMEM_BUDGET", seq * 128 * 4 - 1)
+    rng = np.random.default_rng(3)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((1, seq, 128)), dtype=jnp.float32) for _ in range(4))
+    want = _dense_window_attention(q, k, v, g, scale, window)
+    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv", "tpuft_swa_bwd_dq"]
+    for a, b, name in zip((o,) + tuple(bwd(q, k, v, o, lse, g)), want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [1024, 5000])
+def test_a_window_that_covers_the_sequence_is_the_causal_call(window) -> None:
+    """`flash_attention(window >= seq)`: the same jaxpr as the causal call
+    (so the same kernel, un-windowed) and the same bits, output and
+    gradients."""
+    from torchft_tpu.ops import flash_attention
+
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((1, 4, 1024, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 1024, 64)), dtype=jnp.float32) for _ in range(2))
+
+    def loss(window):
+        return lambda q, k, v: jnp.sum(jnp.square(flash_attention(q, k, v, causal=True, window=window)))
+
+    assert str(jax.make_jaxpr(jax.grad(loss(window), argnums=(0, 1, 2)))(q, k, v)) == str(
+        jax.make_jaxpr(jax.grad(loss(None), argnums=(0, 1, 2)))(q, k, v))
+    got = jax.value_and_grad(loss(window), argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(None), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def test_windowed_flash_attention_differs_from_causal_and_matches_a_dense_mask() -> None:
+    """The public call with grouped queries and a window under the sequence,
+    through autodiff (the XLA formulation off-TPU)."""
+    from torchft_tpu.ops import flash_attention
+
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)), dtype=jnp.float32) for _ in range(2))
+    g = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
+    o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, window=32), q, k, v)
+    rep = lambda t: jnp.repeat(t, 2, axis=1).reshape(4, 256, 64)  # noqa: E731
+    want = _dense_window_attention(q.reshape(4, 256, 64), rep(k), rep(v), g.reshape(4, 256, 64), 64 ** -0.5, 32)
+    np.testing.assert_allclose(np.asarray(o).reshape(4, 256, 64), np.asarray(want[0]), rtol=1e-4, atol=1e-5)
+    dq, dk, dv = vjp(g)
+    np.testing.assert_allclose(np.asarray(dq).reshape(4, 256, 64), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
+    for got, ref in ((dk, want[2]), (dv, want[3])):  # a kv head's gradient is its two query heads' summed
+        np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref).reshape(2, 2, 256, 64).sum(1), rtol=1e-4, atol=1e-4)
+    assert not np.allclose(np.asarray(o), np.asarray(flash_attention(q, k, v)), atol=1e-3)
+
+
+@pytest.mark.parametrize("seq", [4096, 8192, 16384])
+@pytest.mark.parametrize("block", [512, 256])
+def test_the_band_walk_at_the_window_cells_lengths(seq, block) -> None:
+    """A window of 512: the walk's tables hold every tile with a visible
+    pair and no other, row by row and column by column — 2n - 1 tiles of
+    512 x 512 (two a row but the first), 3n - 3 of 256 x 256 — and the
+    traced `pallas_call`s at the program's blocks have that many steps."""
+    from torchft_tpu.ops import attention as fa
+
+    window, n = 512, seq // block
+    t = np.arange(seq)
+    d = t[:, None] - t[None, :]
+    holds_a_pair = ((d >= 0) & (d < window)).reshape(n, block, n, block).any(axis=(1, 3))
+    tiles = int(holds_a_pair.sum())
+    assert tiles == (2 * n - 1 if block == 512 else 3 * n - 3)
+    for kv_major in (False, True):
+        walk = fa._Walk(True, seq, seq, block, block, kv_major=kv_major, window=window)
+        rows, cols = (np.asarray(x) for x in walk.tables)
+        visited = np.zeros((n, n), bool)
+        visited[rows, cols] = True
+        assert len(rows) == tiles and (visited == holds_a_pair).all()
+        major, minor = (cols, rows) if kv_major else (rows, cols)
+        assert (np.diff(major) >= 0).all() and (np.diff(minor)[np.diff(major) == 0] == 1).all()
+        # the ends the kernels start, assign and emit at are the tables' own
+        for i in range(n):
+            in_row, in_col = cols[rows == i], rows[cols == i]
+            assert (int(walk.first_k(i)), int(walk.last_k(i))) == (in_row.min(), in_row.max())
+            assert (int(walk.first_q(i)), int(walk.last_q(i))) == (in_col.min(), in_col.max())
+    if fa._block_sizes(seq, seq) == (block, block):
+        bh = 8
+        qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
+        fwd = functools.partial(fa._fa_pallas_call, scale=0.088, causal=True, window=window)
+        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True, window=window)
+        f, b = bh // fa._heads_per_step(bh), bh // fa._bwd_heads_per_step(bh, fa._row_vmem_bytes(seq, 128, 2))
+        assert f == 1 and b < bh  # the band's tiles, H heads a step
+        assert pallas_call_grids(fwd, qkv, qkv, qkv) == {"tpuft_swa_fwd": (f, tiles)}
+        assert pallas_call_grids(bwd, qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_swa_bwd_dkdv_dq": (b, tiles)}

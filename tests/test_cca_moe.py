@@ -3,50 +3,39 @@ an MLP with a state carried from layer to layer, one expert a token or none,
 learned residual merges, a head that is the embedding) through the program, on
 the CPU at small sizes.
 
-The program (``models/transformer.py`` with a ``LayerKind`` whose mixer is
-"cca"; ``models/moe.py``'s ``state_router_logits`` and ``skip``) against the
-benchmark's plain float32 reference (``benchmark/reference/cca_moe_lm.py``,
-which shares no code with it) on seeded random weights; each piece of the
-compressed mixer against a written-out loop; the shares of an expert-parallel
-layer against the uncut layer; the tied head against the untied model's two
-leaves; the counters; the adapter's refusals; and the tree without ``lm_head``
-through ``ft_step``, a heal's transport and the disk checkpoint.
+`ARCH` is the architecture's entry in the suite (`tests/architectures.py`, which
+holds the tests every architecture is held to against the benchmark's plain
+float32 reference, ``benchmark/reference/cca_moe_lm.py``).  What only this
+architecture has is tested here: each piece of the compressed mixer against a
+written-out loop, the top-one gate, the tied head against the untied model's.
 """
 
 import dataclasses
-import json
-import os
-import sys
-from unittest.mock import MagicMock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from test_manager import make_manager, make_quorum, store  # noqa: F401
+from architectures import (  # noqa: F401 — the shared tests this entry has fields for, and their fixture
+    BENCH, Architecture, Case, ExpertLayer, Piece, Tiny, batches, off_start, pytest_generate_tests, store, tiny_of_the_small_model,
+    test_a_model_without_a_piece_is_another_model, test_loss_and_every_gradient_leaf_against_the_plain_reference,
+    test_the_adapter_raises_on_what_it_does_not_honour, test_the_published_configuration_is_handed_over_whole,
+    test_the_shares_add_up_to_the_uncut_layer, test_the_tree_goes_through, test_the_tree_is_the_reference_s)
+from torchft_tpu.models import LayerKind, TransformerConfig, init_params
+from torchft_tpu.models.moe import moe_layer
+from torchft_tpu.models.transformer import _cca_qkv, loss_and_counters, param_axes
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.spec import Benchmark  # noqa: E402
-from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models.moe import moe_layer  # noqa: E402
-from torchft_tpu.models.transformer import _cca_qkv, loss_and_counters, param_axes  # noqa: E402
-from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
-
-BENCH = Benchmark(ROOT)
 REFERENCE = BENCH.reference("cca_moe_lm")
 PROGRAM = BENCH.program("cca_moe_lm")
 PUBLISHED = BENCH.config("zaya1-8b")
 
-SEQ = 64
-# The cut's 4 layers in small, float32 throughout: 8 query heads on 2 KV heads
-# of 16, RoPE on half a head, a router state of 16 over 8 experts and the skip
-# choice, of which this chip holds experts 4-7; the carried state crosses three
-# boundaries.  `layer_types` keeps a longer list: the first four entries count.
+SEQ = 32
+SIZES = """32 positions: the kernel-2 convolutions and the value shift see a first position and 31 others, and 256 positions
+a step leave the biased top-1 choice some that take no expert, some held here and some held elsewhere.  The cut's 4
+layers: the carried state crosses three boundaries (two layers, one boundary, for the pieces).  8 query heads on 2 KV
+heads of 16, RoPE on half a head, a router state of 16 over 8 experts and the skip choice, of which this chip holds
+experts 4-7.  `layer_types` keeps a longer list: the first four entries count.  Float32 throughout."""
 CONFIG = dict(
     architecture="cca_moe_lm", vocab_size=300, hidden_size=64, num_hidden_layers=4, num_attention_heads=8,
     num_key_value_heads=2, head_dim=16, cca_time0=2, cca_time1=2, moe_intermediate_size=32, num_experts=4,
@@ -60,27 +49,6 @@ CONFIG = dict(
     training=dict(compute_dtype="float32", param_dtype="float32", optimizer="adamw", learning_rate=1e-7),
     program=dict(remat=False, remat_keeps_attention=False, scan_unroll=8),
 )
-
-
-def _weights(seed: int, config=CONFIG):
-    """The reference's weights with every leaf moved off its start (a tenth of
-    its spread, or 0.1 where it starts constant): biases, merges, temperature
-    and the carried state's weight then take part in every product."""
-    weights = REFERENCE.make_weights(seed, config)
-    rng = np.random.default_rng(seed)
-    return jax.tree.map(
-        lambda l: l + 0.1 * (float(jnp.std(l)) or 1.0) * jnp.asarray(rng.standard_normal(l.shape), jnp.float32), weights)
-
-
-def _batch(seed: int, vocab: int = 300, seq_len: int = SEQ, sequences: int = 2):
-    tokens = np.random.default_rng(seed).integers(0, vocab, size=(sequences, seq_len)).astype(np.int32)
-    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
-
-
-def _program(config, weights, batch):
-    return jax.jit(jax.value_and_grad(PROGRAM.loss(config), has_aux=True))(weights, batch)
-
-
 WALKS = {
     "static_loop": dict(remat=False, scan_unroll=8),
     "scan": dict(remat=False, scan_unroll=1),
@@ -90,42 +58,125 @@ WALKS = {
 }
 
 
-@pytest.mark.parametrize("walk", list(WALKS))
-def test_loss_and_every_gradient_leaf_against_the_plain_reference(walk) -> None:
-    """Float32 on both sides, so what differs is the order of sums: the loss to
-    1e-6, every leaf's gradient to 2e-5 of its norm (the reference against
-    itself in float64 differs by as much; a piece of the mathematics left out
-    reads 0.5 and more, `test_a_model_without_a_piece_is_another_model`)."""
-    config = dict(CONFIG, program=dict(CONFIG["program"], **WALKS[walk]))
-    weights, batch = _weights(3), _batch(3)
-    (loss, counters), grads = _program(config, weights, batch)
-    want_loss, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], config)
-    assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
-    assert jax.tree.structure(grads) == jax.tree.structure(want)
-    for path, got in jax.tree_util.tree_leaves_with_path(grads):
-        ref = np.asarray(jax.tree_util.tree_reduce(lambda a, k: a[k.key], path, want), np.float64)
-        assert np.linalg.norm(np.asarray(got, np.float64) - ref) <= 2e-5 * np.linalg.norm(ref), jax.tree_util.keystr(path)
+def _weights(seed: int, config=CONFIG):
+    return off_start(REFERENCE.make_weights(seed, config), seed)
+
+
+_batch = batches(300, SEQ)
+
+
+def _counters(counters, config) -> None:
     # a biased choice that takes no expert in some position, an expert held here in some, one held elsewhere in some
-    positions = 4 * 2 * SEQ
+    positions = config["num_hidden_layers"] * 2 * SEQ
     assert 0 < int(counters["moe_skipped"]) < positions and int(counters["moe_dropped"]) == 0
     assert 0 < int(counters["moe_rows_held"]) < positions - int(counters["moe_skipped"])
 
 
-@pytest.mark.parametrize("piece", REFERENCE.LEFT_OUT)
-def test_a_model_without_a_piece_is_another_model(piece) -> None:
-    """The reference computed WITHOUT the value shift, either convolution, the
-    q-k mean or the carried state, in the program's place: every one moves the
-    gradients by a third of their norm or more, so no limit of `correct` that
-    separates rounding from fp8 lets it through."""
-    from benchmark import compare
+# -- the shares of an expert-parallel layer: 2 chips hold 4 of 8 each; the skip choice adds nothing on any ----
 
-    config = dict(CONFIG, num_hidden_layers=2)  # the carried state crosses one boundary
-    weights, batch = _weights(4, config), _batch(4, sequences=1, seq_len=32)
-    _, want = REFERENCE.one_sequence_fn(config)(weights, batch["tokens"][0], batch["targets"][0])
-    _, got = REFERENCE.one_sequence_fn(config, "float32", piece)(weights, batch["tokens"][0], batch["targets"][0])
-    indices = compare.sample_indices(4, weights)
-    rel, _ = compare.grad_rel(compare.sample(got, indices), compare.sample(want, indices))
-    assert rel > 0.3, (piece, rel)
+
+def _expert_layer() -> ExpertLayer:
+    from torchft_tpu.ops.rmsnorm import rms_norm
+
+    seed, positions = 9, 2 * 64
+    weights = _weights(seed, dict(CONFIG, num_experts=8, expert_parallel=None))
+    w = jax.tree.map(lambda leaf: leaf[1], weights["layers"])
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal((2, 64, 64)), jnp.float32) * 0.05
+    state = jnp.asarray(rng.standard_normal((2, 64, 16)), jnp.float32)
+    bias = jnp.asarray(REFERENCE.router_bias(CONFIG)[1])
+    s = REFERENCE.sizes_of(dict(CONFIG, num_experts=8, expert_parallel=None))
+    assert (s["held"], s["experts"], s["first"]) == (8, 8, 0)
+    plain = dict(w, mlp_merge=jnp.asarray([[1.0], [0.0], [1.0], [0.0]]) * jnp.ones((4, 64)))  # x + y: y = merged - x
+
+    def uncut(x):  # the whole sublayer before its merge, and the state it hands on
+        outs = [REFERENCE._experts(seq, plain, st, bias, s, "float32") for seq, st in zip(x, state)]
+        return jnp.stack([y - seq for (y, _), seq in zip(outs, x)]), jnp.stack([carried for _, carried in outs])
+
+    def share(first, count, _, x):  # on its normed input; the skip choice is the ninth output
+        held = slice(first, first + count)
+        return moe_layer(rms_norm(x, w["mlp_norm"], 1e-5), w["router"], w["w_gate"][held], w["w_up"][held], w["w_down"][held],
+                         top_k=1, capacity_factor=None, norm_topk=False, score="softmax", route_bias=bias, router_state=state,
+                         skip=True, rms_eps=1e-5, held_first=first, dtype=jnp.float32)
+
+    def facts(stats, want_state, _) -> None:
+        np.testing.assert_allclose(np.asarray(stats[0]["router_state"]), np.asarray(want_state), rtol=1e-5, atol=1e-6)
+        # skipped + held here + held on the other chips = positions, on every chip
+        skipped = int(stats[0]["skipped"])
+        assert 0 < skipped < positions and all(int(st["skipped"]) == skipped for st in stats)
+        assert all(st["tokens_per_expert"].shape == (8,) for st in stats)
+        assert int(jnp.sum(stats[0]["tokens_per_expert"])) == positions - skipped
+
+    return ExpertLayer((x,), 8, share, uncut, positions, sin=50.0, atol=1e-6, grad_rtol=1e-3, facts=facts)
+
+
+# -- the tree, the configuration, the adapter --------------------------------------------------
+
+
+def _tree_facts(cfg, ours) -> None:
+    """At the published widths, with no `lm_head` and the router a subtree."""
+    assert set(ours) == {"embed", "final_norm", "layers"} and ours["embed"].shape == (131_136, 2048)
+    assert set(ours["layers"]["router"]) == {"down", "down_bias", "carry", "norm", "w1", "b1", "w2", "b2", "w3"}
+    assert ours["layers"]["router"]["w3"].shape == (4, 256, 17) and ours["layers"]["w_gate"].shape == (4, 8, 2048, 2048)
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours))
+    assert n_params == BENCH.flops("cca_moe_lm").total_params(PUBLISHED) == 696_250_376
+
+
+def _published_facts(cfg, _) -> None:
+    kind = cfg.layers[0]
+    assert (cfg.d_model, cfg.n_layers, cfg.n_kv_heads, cfg.d_head, cfg.d_ff) == (2048, 4, 2, 128, 2048)
+    assert all(k == kind for k in cfg.layers) and (kind.mixer, kind.n_heads, kind.rotary_fraction) == ("cca", 8, 0.5)
+    assert kind.rope_theta == 5e6 and kind.sparse and kind.window is None
+    assert (cfg.moe_experts, cfg.n_router_outputs, cfg.moe_held, cfg.moe_top_k) == (16, 17, (0, 8), 1)
+    assert cfg.moe_router_state == 256 and cfg.moe_skip and cfg.scaled_merge and cfg.tied_head
+    assert cfg.moe_score == "softmax" and not cfg.moe_norm_topk and cfg.moe_aux_coef == 0.0
+    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 17)
+    # every number of the catalog's row under the same key, the three cuts listed
+    assert PUBLISHED["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert PUBLISHED["published"] == {"num_hidden_layers": 40, "num_experts": 16, "vocab_size": 262_272}
+    assert len(PUBLISHED["layer_types"]) == 40 and PUBLISHED["router_hidden_size"] == 256
+
+
+REFUSALS = [
+    ("two_kinds_of_layer", dict(layer_types=["hybrid", "hybrid_sliding", "hybrid", "hybrid"]), "one kind"),
+    ("two_experts_a_token", dict(num_experts_per_tok=2), "one expert"),
+    ("sliding_window", dict(sliding_window=4096), "no window"),
+    ("untied_head", dict(tie_word_embeddings=False), "embedding itself"),
+    ("yarn", dict(rope_parameters={"hybrid": dict(partial_rotary_factor=0.5, rope_theta=100.0, rope_type="yarn")}), "rope_type"),
+    ("a_kernel_of_4", dict(cca_time1=4), "kernel 2"),
+    ("attention_bias", dict(attention_bias=True), "no bias"),
+]
+
+
+# -- the tree without a head leaf through ft_step, a heal's transport and the checkpoint ----
+
+
+def _tiny() -> Tiny:
+    def tree_facts(tree) -> None:
+        assert set(tree) == {"embed", "final_norm", "layers"} and isinstance(tree["layers"]["router"], dict)
+
+    def facts(moved, summaries, step, after) -> None:
+        assert {"['embed']", "['layers']['cca_conv1']", "['layers']['router']['carry']", "['layers']['attn_merge']"} <= moved
+        summary = summaries[-1]
+        assert summary["moe_dropped"] == 0 and summary["moe_skipped"] >= 0
+        assert summary["moe_skipped"] + summary["moe_rows_held"] <= summary["moe_assignments"] == 4 * 2 * SEQ
+
+    return tiny_of_the_small_model("cca_moe_lm", CONFIG, _batch(0), tree_facts, facts)
+
+
+ARCH = Architecture(
+    name="cca_moe_lm", configs={"share": CONFIG, "two_layers": dict(CONFIG, num_hidden_layers=2)}, sizes=SIZES, seq=SEQ,
+    variants=dict(WALKS, as_published={}), leaf_cases=[Case(walk, "share", walk, 3) for walk in WALKS],
+    # the loss to 1e-6, every leaf to 2e-5 of its norm (the reference against itself in float64 differs by as much)
+    leaf_tolerance=2e-5, loss_tolerance=1e-6, off_start=True, counters=_counters,
+    # the reference WITHOUT the value shift, either convolution, the q-k mean or the carried state (which crosses one
+    # boundary here): every one moves some leaf by a third of its norm or more, so no limit of `correct` that separates
+    # rounding from fp8 lets it through
+    pieces=[Piece(piece, "reference", piece) for piece in REFERENCE.LEFT_OUT], pieces_at=("two_layers", 4), piece_floor=0.3,
+    chips=[2, 4, 1], expert_layer=_expert_layer,
+    published="zaya1-8b", tree_facts=_tree_facts, published_facts=_published_facts,
+    refusals=REFUSALS, refusal_config="share", through=("ft_step", "heal", "disk_checkpoint"), tiny=_tiny,
+)
 
 
 # -- the compressed mixer, piece by piece, against loops ---------------------------
@@ -191,9 +242,11 @@ def test_a_piece_of_the_compressed_mixer_against_a_written_out_loop(piece) -> No
                             max_seq=S, dtype=jnp.float32, pattern=(kind,), moe_experts=2, moe_capacity_factor=None)
     positions = jnp.zeros((1, S), jnp.int32)  # position zero everywhere: RoPE is the identity
 
+    @jax.jit
     def program(h, w):
         return tuple(a[0] for a in _cca_qkv(cfg, kind, h, w, positions))
 
+    @jax.jit
     def loop(h, w):
         return _loop_qkv(h[0], w, H, G, D)
 
@@ -201,7 +254,7 @@ def test_a_piece_of_the_compressed_mixer_against_a_written_out_loop(piece) -> No
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
     mix = [normal(H, S, D), normal(G, S, D), normal(G, S, D)]
     scalar = lambda f: lambda h, w: sum(jnp.sum(a * m) for a, m in zip(f(h, w), mix))  # noqa: E731
-    got, want = jax.grad(scalar(program), argnums=(0, 1))(h, w), jax.grad(scalar(loop), argnums=(0, 1))(h, w)
+    got, want = jax.jit(jax.grad(scalar(program), argnums=(0, 1)))(h, w), jax.jit(jax.grad(scalar(loop), argnums=(0, 1)))(h, w)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
     if piece == "shift":  # the second half of the KV heads reads the position before, the first the position itself
@@ -210,69 +263,6 @@ def test_a_piece_of_the_compressed_mixer_against_a_written_out_loop(piece) -> No
         np.testing.assert_allclose(np.asarray(v[0]), np.asarray(flat[:, 0]), rtol=1e-6)
         np.testing.assert_allclose(np.asarray(v[1, 1:]), np.asarray(flat[:-1, 1]), rtol=1e-6)
         assert not np.any(np.asarray(v[1, 0]))
-
-
-# -- the shares of an expert-parallel layer ------------------------------------------
-
-
-def _expert_layer(seed: int):
-    weights = _weights(seed, dict(CONFIG, num_experts=8, expert_parallel=None))
-    w = jax.tree.map(lambda leaf: leaf[1], weights["layers"])
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((2, SEQ, 64)), jnp.float32) * 0.05
-    state = jnp.asarray(rng.standard_normal((2, SEQ, 16)), jnp.float32)
-    bias = jnp.asarray(REFERENCE.router_bias(CONFIG)[1])
-    return x, state, w, bias
-
-
-def _share(x, state, w, bias, first, count):
-    """The program's routed part of one expert sublayer for the experts
-    ``first ... first + count - 1`` of 8 (the skip choice is the ninth output),
-    on its normed input."""
-    from torchft_tpu.ops.rmsnorm import rms_norm
-
-    u = rms_norm(x, w["mlp_norm"], 1e-5)
-    held = slice(first, first + count)
-    return moe_layer(u, w["router"], w["w_gate"][held], w["w_up"][held], w["w_down"][held], top_k=1,
-                     capacity_factor=None, norm_topk=False, score="softmax", route_bias=bias, router_state=state,
-                     skip=True, rms_eps=1e-5, held_first=first, dtype=jnp.float32)
-
-
-@pytest.mark.parametrize("chips", [2, 4, 1])
-def test_the_shares_add_up_to_the_uncut_layer(chips) -> None:
-    """What every chip of an expert-parallel layer computes of the routed
-    experts (2 chips: 4 of 8 each; the skip choice adds nothing on any),
-    summed over the chips, is what the uncut plain reference gives for the
-    whole sublayer before its merge — values, the carried state and the
-    gradient of the input — and the counters add up to the positions."""
-    x, state, w, bias = _expert_layer(9)
-    count = 8 // chips
-    s = REFERENCE.sizes_of(dict(CONFIG, num_experts=8, expert_parallel=None))
-    assert (s["held"], s["experts"], s["first"]) == (8, 8, 0)
-    plain = dict(w, mlp_merge=jnp.asarray([[1.0], [0.0], [1.0], [0.0]]) * jnp.ones((4, 64)))  # x + y: y = merged - x
-
-    def uncut(x):
-        return jnp.stack([REFERENCE._experts(seq, plain, st, bias, s, "float32")[0] - seq for seq, st in zip(x, state)])
-
-    def summed(x):
-        return sum(_share(x, state, w, bias, r * count, count)[0] for r in range(chips))
-
-    with jax.default_matmul_precision("highest"):
-        want, got = jax.jit(uncut)(x), jax.jit(summed)(x)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
-        dwant = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(50 * uncut(x)))))(x)
-        dgot = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(50 * summed(x)))))(x)
-        np.testing.assert_allclose(np.asarray(dgot), np.asarray(dwant), rtol=1e-3, atol=1e-6)
-        stats = [_share(x, state, w, bias, r * count, count)[1] for r in range(chips)]
-        want_state = jnp.stack([REFERENCE._experts(seq, plain, st, bias, s, "float32")[1] for seq, st in zip(x, state)])
-    np.testing.assert_allclose(np.asarray(stats[0]["router_state"]), np.asarray(want_state), rtol=1e-5, atol=1e-6)
-    # skipped + held here + held on the other chips = positions, on every chip; nothing is ever dropped
-    positions = 2 * SEQ
-    skipped = int(stats[0]["skipped"])
-    assert 0 < skipped < positions and all(int(st["skipped"]) == skipped for st in stats)
-    assert sum(int(st["rows_held"]) for st in stats) + skipped == positions == int(stats[0]["assignments"])
-    assert all(int(st["dropped"]) == 0 and st["tokens_per_expert"].shape == (8,) for st in stats)
-    assert int(jnp.sum(stats[0]["tokens_per_expert"])) == positions - skipped
 
 
 def test_a_top_one_gate_is_the_probability_and_the_bias_never_enters_it() -> None:
@@ -310,54 +300,6 @@ def test_the_tied_embedding_gradient_is_the_untied_model_s_two_leaves_summed() -
         np.testing.assert_allclose(np.asarray(tied["layers"][name]), np.asarray(both["layers"][name]), rtol=1e-5, atol=1e-9)
 
 
-def test_the_tree_is_the_reference_s() -> None:
-    """Leaf names and shapes of `init_params` are those of the weights the
-    benchmark makes, at the published widths, with no `lm_head` and the
-    router a subtree."""
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    ours = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    theirs = jax.eval_shape(lambda: REFERENCE.make_weights(1, PUBLISHED))
-    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
-    assert [l.shape for l in jax.tree.leaves(ours)] == [l.shape for l in jax.tree.leaves(theirs)]
-    assert set(ours) == {"embed", "final_norm", "layers"} and ours["embed"].shape == (131_136, 2048)
-    assert set(ours["layers"]["router"]) == {"down", "down_bias", "carry", "norm", "w1", "b1", "w2", "b2", "w3"}
-    assert ours["layers"]["router"]["w3"].shape == (4, 256, 17) and ours["layers"]["w_gate"].shape == (4, 8, 2048, 2048)
-    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours))
-    assert n_params == BENCH.flops("cca_moe_lm").total_params(PUBLISHED) == 696_250_376
-    axes = jax.tree.leaves(param_axes(cfg), is_leaf=lambda a: isinstance(a, tuple))
-    assert [len(a) for a in axes] == [l.ndim for l in jax.tree.leaves(ours)]  # dicts flatten by sorted key, both
-
-
-def test_the_published_configuration_is_handed_over_whole() -> None:
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    kind = cfg.layers[0]
-    assert (cfg.d_model, cfg.n_layers, cfg.n_kv_heads, cfg.d_head, cfg.d_ff) == (2048, 4, 2, 128, 2048)
-    assert all(k == kind for k in cfg.layers) and (kind.mixer, kind.n_heads, kind.rotary_fraction) == ("cca", 8, 0.5)
-    assert kind.rope_theta == 5e6 and kind.sparse and kind.window is None
-    assert (cfg.moe_experts, cfg.n_router_outputs, cfg.moe_held, cfg.moe_top_k) == (16, 17, (0, 8), 1)
-    assert cfg.moe_router_state == 256 and cfg.moe_skip and cfg.scaled_merge and cfg.tied_head
-    assert cfg.moe_score == "softmax" and not cfg.moe_norm_topk and cfg.moe_aux_coef == 0.0
-    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 17)
-    # every number of the catalog's row under the same key, the three cuts listed
-    assert PUBLISHED["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
-    assert PUBLISHED["published"] == {"num_hidden_layers": 40, "num_experts": 16, "vocab_size": 262_272}
-    assert len(PUBLISHED["layer_types"]) == 40 and PUBLISHED["router_hidden_size"] == 256
-
-
-@pytest.mark.parametrize("change,message", [
-    (dict(layer_types=["hybrid", "hybrid_sliding", "hybrid", "hybrid"]), "one kind"),
-    (dict(num_experts_per_tok=2), "one expert"),
-    (dict(sliding_window=4096), "no window"),
-    (dict(tie_word_embeddings=False), "embedding itself"),
-    (dict(rope_parameters={"hybrid": dict(partial_rotary_factor=0.5, rope_theta=100.0, rope_type="yarn")}), "rope_type"),
-    (dict(cca_time1=4), "kernel 2"),
-    (dict(attention_bias=True), "no bias"),
-])
-def test_the_adapter_raises_on_what_it_does_not_honour(change, message) -> None:
-    with pytest.raises(ValueError, match=message):
-        PROGRAM.transformer_config(dict(CONFIG, **change))
-
-
 def test_a_pipelined_loss_refuses_a_tied_head() -> None:
     from torchft_tpu.parallel.pipeline import pipeline_loss_fn
 
@@ -366,79 +308,6 @@ def test_a_pipelined_loss_refuses_a_tied_head() -> None:
     with pytest.raises(AssertionError, match="untied"):
         pipeline_loss_fn({}, {"tokens": jnp.zeros((2, 8), jnp.int32)}, cfg, None, num_microbatches=1)
 
-
-# -- the tree without a head leaf through ft_step, a heal's transport and the checkpoint ----
-
-
-def _records(path, event):
-    with open(path, encoding="utf-8") as f:
-        return [r for r in map(json.loads, f) if r.get("event") == event]
-
-
-TINY = dataclasses.replace(PROGRAM.transformer_config(CONFIG), remat=True, remat_keeps_attention=True, scan_unroll=1)
-
-
-@pytest.mark.parametrize("through", ["ft_step", "heal", "disk_checkpoint"])
-def test_a_tied_state_carrying_tree_goes_through(through, store, tmp_path, monkeypatch) -> None:  # noqa: F811
-    params = init_params(jax.random.PRNGKey(5), TINY)
-    assert set(params) == {"embed", "final_norm", "layers"} and isinstance(params["layers"]["router"], dict)
-    leaves = jax.tree.leaves(params)
-    if through == "ft_step":
-        path = tmp_path / "stream.jsonl"
-        monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
-        client = MagicMock()
-        client._quorum.return_value = make_quorum()
-        client.should_commit.return_value = True
-        manager, _, _ = make_manager(store, client_mock=client)
-        ftmesh = ft_init_mesh({"data": 1}, devices=jax.devices()[:1])
-        ftmesh.manager = manager
-        bias = jnp.asarray(PROGRAM.router_bias(CONFIG))
-        step = TrainStep(ftmesh, optax.adamw(1e-3), lambda p, b: loss_and_counters(p, b, TINY, router_bias=bias),
-                         loss_has_counters=True, overlap_commit=False)
-        opt = step.init_opt_state(params)
-        batch = _batch(0)
-        before = jax.tree.map(np.asarray, params)  # `ft_step` donates its arguments
-        try:
-            for _ in range(2):
-                manager.start_quorum()
-                params, opt, loss, committed = step.ft_step(params, opt, batch)
-                assert committed and np.isfinite(float(loss))
-        finally:
-            manager.shutdown()
-        assert jax.tree.structure(params) == jax.tree.structure(before)
-        moved = {jax.tree_util.keystr(p) for (p, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
-                                                              jax.tree.leaves(before)) if not np.array_equal(np.asarray(a), b)}
-        assert {"['embed']", "['layers']['cca_conv1']", "['layers']['router']['carry']", "['layers']['attn_merge']"} <= moved
-        summary = _records(path, "step_summary")[-1]
-        assert summary["moe_dropped"] == 0 and summary["moe_skipped"] >= 0
-        assert summary["moe_skipped"] + summary["moe_rows_held"] <= summary["moe_assignments"] == 4 * 2 * SEQ
-    elif through == "heal":
-        from torchft_tpu.checkpointing.http_transport import HTTPTransport
-
-        donor, healer = HTTPTransport(timeout=30.0), HTTPTransport(timeout=30.0)
-        try:
-            donor.send_checkpoint([1], 7, {"params": params}, 30.0)
-            back = healer.recv_checkpoint(0, donor.metadata(), 7, 30.0)["params"]
-        finally:
-            donor.shutdown()
-            healer.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))
-    else:
-        from torchft_tpu.checkpointing.disk import DiskCheckpointer
-        from torchft_tpu.ddp import plan_buckets
-
-        buckets = plan_buckets([(l.shape, l.dtype) for l in leaves], 1 << 14)
-        assert sorted(i for b in buckets for i in b.indices) == list(range(len(leaves))) and len(buckets) > 2
-        ckpt = DiskCheckpointer(str(tmp_path))
-        try:
-            ckpt.save(4, {"params": params})
-            ckpt.wait()
-            back = ckpt.restore(4)["params"]
-        finally:
-            ckpt.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))
 
 
 # -- the six configurations the benchmark had keep their trees -------------------------

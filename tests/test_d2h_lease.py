@@ -89,7 +89,15 @@ def worker(mode: str, lease_path: str, sync: str, rank: int, nbytes: int) -> int
             # fetching the same leaf at the same time.  (A race starts so
             # too, and is on its own from there.)
             _wait_for(sync, [f"queued.{r}.{turn}" for r in everyone], f"turn {turn}")
+        if mode == "rounds" and turn + 1 < TURNS:
+            # And whoever had this turn before the holder is back in the queue
+            # before the holder joins it: a round's order is then the queue's
+            # doing, not that of the process the host ran first after a release
+            # (six workers' load held one back past its successor's whole turn).
+            had = [r for r in everyone if os.path.exists(os.path.join(sync, f"ended.{r}.{turn}"))]
+            _wait_for(sync, [f"queued.{r}.{turn + 1}" for r in had], f"turn {turn}'s earlier holders")
         _log(log, rank=rank, turn=turn, event="end")
+        _mark(sync, f"ended.{rank}.{turn}")
         lease.release(Held(outcome, segment))
     return 0
 

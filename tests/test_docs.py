@@ -17,6 +17,7 @@ DOCUMENTS = (
     "docs/api.md",
     "docs/getting_started.md",
     "docs/observability.md",
+    "docs/testing.md",
     "PERF.md",
 )
 

@@ -3,45 +3,36 @@ attention, ReGLU experts under a softmax over the kept, window layers under
 RoPE 3:1 with full layers that have no position term, seven query heads a KV
 head) through the program, on the CPU at small sizes.
 
-The program (``models/transformer.py`` with ``moe_router_early`` and
-``moe_activation="relu"`` under a two-kind ``pattern``) against the benchmark's
-plain float32 reference (``benchmark/reference/early_router_moe_lm.py``, which
-shares no code with it) on seeded random weights; the reference without a piece
-and the program with a wrong one against the whole; the un-rotated kind's q and
-k against the bare projections; the shares of an expert-parallel layer against
-the uncut layer; the adapter's refusals; operation counts against hand
-arithmetic; the new counter through ``ft_step``; the new readers on what they
-read and on nothing.
+`ARCH` is the architecture's entry in the suite (`tests/architectures.py`, which
+holds the tests every architecture is held to against the benchmark's plain
+float32 reference, ``benchmark/reference/early_router_moe_lm.py``).  What only
+this architecture has is tested here: the un-rotated kind's q and k against the
+bare projections, the router on the normed input, operation counts against
+hand arithmetic, the new readers on what they read and on nothing.
 """
 
+import contextlib
 import dataclasses
 import json
 import math
-import os
-import sys
-from unittest.mock import MagicMock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from test_manager import make_manager, make_quorum, store  # noqa: F401
+from architectures import (  # noqa: F401 — the shared tests this entry has fields for, and their fixture
+    BENCH, HELD, REMAT, Architecture, Case, ExpertLayer, Piece, Tiny, batches, pytest_generate_tests, store,
+    test_a_model_without_a_piece_is_another_model, test_loss_and_every_gradient_leaf_against_the_plain_reference,
+    test_rematerialised_layers_give_the_gradients_of_the_stored_ones, test_the_adapter_raises_on_what_it_does_not_honour,
+    test_the_published_configuration_is_handed_over_whole, test_the_shares_add_up_to_the_uncut_layer,
+    test_the_tree_goes_through, test_the_tree_is_the_reference_s)
+from benchmark import spec
+from torchft_tpu.models import TransformerConfig
+from torchft_tpu.models import moe, transformer
+from torchft_tpu.models.moe import moe_layer, routing
+from torchft_tpu.models.transformer import loss_and_counters
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark import spec  # noqa: E402
-from benchmark.spec import Benchmark  # noqa: E402
-from torchft_tpu.models import TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models import moe, transformer  # noqa: E402
-from torchft_tpu.models.moe import moe_layer, routing  # noqa: E402
-from torchft_tpu.models.transformer import loss_and_counters, param_axes  # noqa: E402
-from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
-
-BENCH = Benchmark(ROOT)
 REFERENCE = BENCH.reference("early_router_moe_lm")
 PROGRAM = BENCH.program("early_router_moe_lm")
 PUBLISHED = BENCH.config("smallthinker-21b-a3b")
@@ -49,13 +40,14 @@ CELL = "smallthinker-21b-a3b.steady-1g-16k"
 NEW_METRICS = ("swa4k_attn_ms", "swa4k_attn_roofline", "full_nope_attn_ms", "full_nope_attn_roofline",
                "gmm_reglu_roofline", "early_router_ms", "reglu_active_share")
 
-SEQ, WINDOW = 32, 8
-# Two whole periods in small, float32 throughout: 7 query heads over ONE KV head
-# of 16 (the group of 7 is there), a window of 8 under 32 positions, 8 routed
-# experts of width 32, 3 a token.  The layouts keep a published length: the
-# first `num_hidden_layers` entries count.
+SEQ, WINDOW, LAYERS = 32, 8, 5
+SIZES = """32 positions under a window of 8: a window layer's band is a quarter of the triangle, so the band and the
+triangle differ in most pairs.  Five layers — a period (a full layer without a position term, three window layers under
+RoPE) and the next period's full layer: both kinds, the full layers' stack in two runs around the window layers'.  7 query
+heads over ONE KV head of 16 (the group of 7 is there), 8 routed experts of width 32, 3 a token.  The layouts keep a
+published length: the first `num_hidden_layers` entries count.  Float32 throughout."""
 CONFIG = dict(
-    architecture="early_router_moe_lm", vocab_size=256, hidden_size=64, num_hidden_layers=8, num_attention_heads=7,
+    architecture="early_router_moe_lm", vocab_size=256, hidden_size=64, num_hidden_layers=LAYERS, num_attention_heads=7,
     num_key_value_heads=1, head_dim=16, moe_ffn_hidden_size=32, moe_num_primary_experts=8,
     moe_num_active_primary_experts=3, moe_primary_router_apply_softmax=True, norm_topk_prob=True,
     rope_layout=[0, 1, 1, 1] * 3, sliding_window_layout=[0, 1, 1, 1] * 3, sliding_window_size=WINDOW,
@@ -72,43 +64,16 @@ SHARE = dict(CONFIG, moe_num_primary_experts=2,
 # the named omissions moves its leaf by far more, so 3e-5 passes the one and
 # fails the others.
 LEAF_TOLERANCE = 3e-5
-LOSS_TOLERANCE = 1e-6
 
 
-def _batch(seed: int, config=CONFIG, sequences: int = 2, seq_len: int = SEQ):
-    tokens = np.random.default_rng(seed).integers(0, config["vocab_size"], size=(sequences, seq_len)).astype(np.int32)
-    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
+_batch = batches(CONFIG["vocab_size"], SEQ)
 
 
-def _worst_leaf(grads, want):
-    worst = ("", 0.0)
-    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
-        got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
-        rel = float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
-        if rel > worst[1]:
-            worst = (jax.tree_util.keystr(path), rel)
-    return worst
-
-
-def _program_grads(cfg, weights, batch):
-    return jax.jit(jax.value_and_grad(lambda p, b: loss_and_counters(p, b, cfg), has_aux=True))(weights, batch)
-
-
-@pytest.mark.parametrize("config", [CONFIG, SHARE], ids=["every_expert_held", "a_share_of_the_experts"])
-@pytest.mark.parametrize("seed", [11, 2**31 + 29])
-def test_loss_and_every_gradient_leaf_against_the_plain_reference(seed, config) -> None:
-    cfg = PROGRAM.transformer_config(config)
-    weights, batch = REFERENCE.make_weights(seed, config), _batch(seed)
-    (loss, counters), grads = _program_grads(cfg, weights, batch)
-    want_loss, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], config)
-    leaf, rel = _worst_leaf(grads, want)
-    loss_rel = abs(float(loss) - float(want_loss)) / float(want_loss)
-    assert rel < LEAF_TOLERANCE and loss_rel < LOSS_TOLERANCE, (leaf, rel, loss_rel)
-    assert jax.tree.structure(grads) == jax.tree.structure(weights)
+def _counters(counters, config) -> None:
     assert int(counters["moe_dropped"]) == 0
-    assert np.asarray(counters["moe_tokens_per_expert"]).sum(axis=1).tolist() == [2 * SEQ * 3] * 8
+    assert np.asarray(counters["moe_tokens_per_expert"]).sum(axis=1).tolist() == [2 * SEQ * 3] * LAYERS
     # ReLU leaves about half of the held experts' hidden units above zero; the denominator is rows x width
-    held_rows = int(counters["moe_rows_held"]) if "moe_rows_held" in counters else 8 * 2 * SEQ * 3
+    held_rows = int(counters["moe_rows_held"]) if "moe_rows_held" in counters else LAYERS * 2 * SEQ * 3
     assert int(counters["moe_units_held"]) == held_rows * 32
     assert 0.4 < int(counters["moe_active_units"]) / int(counters["moe_units_held"]) < 0.6
 
@@ -116,62 +81,25 @@ def test_loss_and_every_gradient_leaf_against_the_plain_reference(seed, config) 
 # What the reference computes with one piece of the published mathematics left
 # out or put in the wrong layers (`REFERENCE.LEFT_OUT`): the program as
 # published has to fail the comparison with each.
-@pytest.mark.parametrize("piece", [
+LEFT_OUT = (
     "early_router",        # the router fed the experts' input h2
     "relu",                # SiLU for ReLU
     "nope_on_full",        # RoPE on the full layers too
     "rope_on_window",      # no RoPE on the window layers
     "window",              # the window dropped
     "softmax_over_kept",   # the gates a softmax over all the outputs, left as it is
-])
-def test_the_reference_without_a_piece_fails_the_comparison(piece) -> None:
-    assert piece in REFERENCE.LEFT_OUT
-    seed = 5
-    weights, batch = REFERENCE.make_weights(seed, SHARE), _batch(seed)
-    (loss, _), grads = _program_grads(PROGRAM.transformer_config(SHARE), weights, batch)
-    want_loss, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], SHARE, left_out=piece)
-    leaf, rel = _worst_leaf(grads, want)
-    assert rel > 3 * LEAF_TOLERANCE, f"{piece}: the comparison did not see it ({leaf} {rel})"
-
-
+)
+assert set(LEFT_OUT) == set(REFERENCE.LEFT_OUT)
 # The same from the other side: a PROGRAM that runs the wrong mechanism in a
 # kind of layer (what `benchmark/tools/routing_ties_reglu.py --wrong 1` tries on
 # the chip) fails against the reference as published.
 WRONG_PROGRAMS = spec._module("tools", "routing_ties_reglu", BENCH.bench_dir).wrong_programs
-
-
-@pytest.mark.parametrize("wrong", [
-    "window_layers_over_the_whole_triangle", "full_layers_under_the_window", "rope_on_the_full_layers",
-    "router_on_the_experts_input", "silu_for_relu"])
-def test_a_program_with_a_wrong_mechanism_fails_the_comparison(wrong) -> None:
-    seed = 6
-    weights, batch = REFERENCE.make_weights(seed, SHARE), _batch(seed)
-    tried = WRONG_PROGRAMS(PROGRAM.transformer_config(SHARE), WINDOW)
-    assert len(tried) == 5
-    (loss, _), grads = _program_grads(tried[wrong], weights, batch)
-    _, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], SHARE)
-    leaf, rel = _worst_leaf(grads, want)
-    assert rel > 3 * LEAF_TOLERANCE, f"{wrong}: the comparison did not see it ({leaf} {rel})"
-
-
-@pytest.mark.parametrize("keeps", [False, True], ids=["remat", "remat_that_keeps_attention"])
-def test_rematerialised_layers_give_the_gradients_of_the_stored_ones(keeps) -> None:
-    """`remat`, with and without both kinds' attention output kept: what is
-    recomputed — the early choice from the kept layer input, ReLU's mask from
-    the recomputed gate — is not computed differently, and agrees with the
-    reference as the stored program does."""
-    cfg = PROGRAM.transformer_config(SHARE)
-    weights, batch = REFERENCE.make_weights(4, SHARE), _batch(4)
-    (loss, stored_counters), stored = _program_grads(cfg, weights, batch)
-    (again_loss, counters), again = _program_grads(
-        dataclasses.replace(cfg, remat=True, remat_keeps_attention=keeps), weights, batch)
-    assert float(again_loss) == float(loss)
-    assert int(counters["moe_active_units"]) == int(stored_counters["moe_active_units"])
-    leaf, rel = _worst_leaf(again, stored)
-    assert rel < 1e-6, (leaf, rel)
-    _, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], SHARE)
-    leaf, rel = _worst_leaf(again, want)
-    assert rel < LEAF_TOLERANCE, (leaf, rel)
+WRONG = ("window_layers_over_the_whole_triangle", "full_layers_under_the_window", "rope_on_the_full_layers",
+         "router_on_the_experts_input", "silu_for_relu")
+assert set(WRONG_PROGRAMS(PROGRAM.transformer_config(SHARE), WINDOW)) == set(WRONG)
+PIECES = [Piece(piece, "reference", piece) for piece in LEFT_OUT] + [
+    Piece(wrong, "program", lambda cfg, weights, wrong=wrong: (contextlib.nullcontext(), WRONG_PROGRAMS(cfg, WINDOW)[wrong], weights))
+    for wrong in WRONG]
 
 
 def test_the_router_fed_the_normed_input_chooses_the_same_experts() -> None:
@@ -215,23 +143,15 @@ def test_an_unrotated_kinds_q_and_k_are_the_projections_bit_for_bit(monkeypatch)
     assert np.array_equal(np.asarray(q), np.asarray(want_q)) and np.array_equal(np.asarray(k), np.asarray(want_k))
 
 
-def test_the_tree_has_a_stack_a_kind_of_layer() -> None:
-    cfg = PROGRAM.transformer_config(SHARE)
+
+def _tree_facts(cfg, own) -> None:
     assert {s: (k.n_heads, k.window, k.rotary_fraction, k.sparse, n) for s, (k, n) in cfg.stacks.items()} == {
-        "layers": (7, None, 0.0, True, 2), "window_layers": (7, WINDOW, 1.0, True, 6)}
-    own = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    made = jax.eval_shape(lambda: REFERENCE.make_weights(1, SHARE))
-    assert jax.tree.structure(own) == jax.tree.structure(made)
-    assert [a.shape for a in jax.tree.leaves(own)] == [a.shape for a in jax.tree.leaves(made)]
-    assert own["window_layers"]["wq"].shape == (6, 64, 7 * 16) and own["layers"]["wk"].shape == (2, 64, 16)
+        "layers": (7, None, 0.0, True, 2), "window_layers": (7, WINDOW, 1.0, True, 3)}
+    assert own["window_layers"]["wq"].shape == (3, 64, 7 * 16) and own["layers"]["wk"].shape == (2, 64, 16)
     assert own["layers"]["w_gate"].shape == (2, 2, 64, 32) and own["layers"]["router"].shape == (2, 64, 8)
-    axes = param_axes(cfg)
-    assert jax.tree.structure(jax.tree.map(lambda a: 0, axes, is_leaf=lambda a: isinstance(a, tuple))) == \
-        jax.tree.structure(jax.tree.map(lambda a: 0, own))
 
 
-def test_the_published_configuration_is_handed_over_whole() -> None:
-    cfg = PROGRAM.transformer_config(PUBLISHED)
+def _published_facts(cfg, _) -> None:
     assert [(k.stack, k.n_heads, k.window, k.rotary_fraction, k.rope_theta, k.sparse) for k in cfg.layers] == [
         ("layers", 28, None, 0.0, 1.5e6, True)] + [("window_layers", 28, 4096, 1.0, 1.5e6, True)] * 3 + [
         ("layers", 28, None, 0.0, 1.5e6, True)] + [("window_layers", 28, 4096, 1.0, 1.5e6, True)] * 3
@@ -257,23 +177,15 @@ def test_the_published_configuration_is_handed_over_whole() -> None:
                                          "held_rows_factor"}
 
 
-@pytest.mark.parametrize("change,message", [
-    (dict(sliding_window_layout=[1, 1, 1, 1] * 3, rope_layout=[1, 1, 1, 1] * 3), "not the period"),
-    (dict(sliding_window_layout=[1, 0, 1, 1] * 3, rope_layout=[1, 0, 1, 1] * 3), "not the period"),
-    (dict(rope_layout=[1, 1, 1, 1] * 3), "rope_layout is not"),
-    (dict(rope_scaling=dict(type="yarn", factor=4.0)), "no rope_scaling"),
-    (dict(tie_word_embeddings=True), "untied"),
-    (dict(norm_topk_prob=False), "normalised over the kept"),
-    (dict(moe_primary_router_apply_softmax=False), "sigmoid-then-normalise"),
-], ids=["all_window", "period_shifted", "rope_everywhere", "rope_scaling", "tied_head", "gates_not_normalised",
-        "sigmoid_router"])
-def test_the_adapter_raises_on_what_it_does_not_honour(change, message) -> None:
-    with pytest.raises(ValueError, match=message):
-        PROGRAM.transformer_config(dict(CONFIG, **change))
-    if "tie_word_embeddings" not in change and "rope_scaling" not in change:
-        return
-    with pytest.raises(ValueError):
-        REFERENCE.sizes_of(dict(CONFIG, **change))
+REFUSALS = [
+    ("all_window", dict(sliding_window_layout=[1, 1, 1, 1] * 3, rope_layout=[1, 1, 1, 1] * 3), "not the period"),
+    ("period_shifted", dict(sliding_window_layout=[1, 0, 1, 1] * 3, rope_layout=[1, 0, 1, 1] * 3), "not the period"),
+    ("rope_everywhere", dict(rope_layout=[1, 1, 1, 1] * 3), "rope_layout is not"),
+    ("rope_scaling", dict(rope_scaling=dict(type="yarn", factor=4.0)), "no rope_scaling", "and the reference"),
+    ("tied_head", dict(tie_word_embeddings=True), "untied", "and the reference"),
+    ("gates_not_normalised", dict(norm_topk_prob=False), "normalised over the kept"),
+    ("sigmoid_router", dict(moe_primary_router_apply_softmax=False), "sigmoid-then-normalise"),
+]
 
 
 def test_the_new_fields_are_checked_where_the_configuration_is_made() -> None:
@@ -286,7 +198,28 @@ def test_the_new_fields_are_checked_where_the_configuration_is_made() -> None:
     assert not TransformerConfig().moe_router_early and TransformerConfig().moe_activation == "silu"
 
 
-# -- one chip's share of an expert-parallel layer ---------------------------------
+# -- one chip's share of an expert-parallel layer: the router's published 64 outputs and 6 a token at small widths,
+# routed on a tensor that is not the experts' input (8 chips: `moe_held = (8r, 8)`, r = 0..7) ----------------------
+
+
+def _expert_layer() -> ExpertLayer:
+    x, early, w = _layer_inputs()
+    s = REFERENCE.sizes_of(dict(CONFIG, moe_num_primary_experts=64, moe_num_active_primary_experts=6))
+    assert (s["held"], s["experts"], s["first"], s["top_k"]) == (64, 64, 0, 6)
+
+    def share(first, count, _, x, early):
+        return _share(x, early, w, first, count)
+
+    def uncut(x, early):  # and the units ReLU leaves above zero where one chip holds every expert
+        y = jnp.stack([REFERENCE._experts(h2, *REFERENCE._route(e, w, s), w, s, "float32") for h2, e in zip(x, early)])
+        return y, _share(x, early, w, 0, 64)[1]["active_units"]
+
+    def facts(stats, whole_active_units, dwant) -> None:
+        assert float(jnp.max(jnp.abs(dwant[1]))) > 1e-3  # the gates' cotangent reaches the tensor the router read
+        assert sum(int(st["active_units"]) for st in stats) == int(whole_active_units)  # the units add up too
+        assert 0.4 < int(whole_active_units) / (64 * 6 * 16) < 0.6
+
+    return ExpertLayer((x, early), 64, share, uncut, 64 * 6, facts=facts)
 
 
 def _layer_inputs(seed=7, tokens=64, hidden=64, inner=16, n_exp=64):
@@ -306,41 +239,6 @@ def _share(x, early, w, first, count):
         w["w_down"][first:first + count], top_k=6, capacity_factor=None, norm_topk=True, score="softmax",
         held_first=first, dtype=jnp.float32, activation="relu", routed=routed)
 
-
-@pytest.mark.parametrize("chips", [8, 16, 4, 1])
-def test_the_shares_add_up_to_the_uncut_layer(chips) -> None:
-    """The router's published 64 outputs and 6 a token at small widths, routed
-    on a tensor that is not the experts' input: what every chip of an
-    expert-parallel layer computes of the routed experts (8 chips: `moe_held =
-    (8r, 8)`, r = 0..7), summed over the chips, is what the uncut plain
-    reference gives for the whole layer — values and the gradients of both
-    inputs."""
-    x, early, w = _layer_inputs()
-    count = 64 // chips
-    s = REFERENCE.sizes_of(dict(CONFIG, moe_num_primary_experts=64, moe_num_active_primary_experts=6))
-    assert (s["held"], s["experts"], s["first"], s["top_k"]) == (64, 64, 0, 6)
-
-    def uncut(x, early):
-        return jnp.stack([REFERENCE._experts(h2, *REFERENCE._route(e, w, s), w, s, "float32") for h2, e in zip(x, early)])
-
-    def summed(x, early):
-        return sum(_share(x, early, w, r * count, count)[0] for r in range(chips))
-
-    with jax.default_matmul_precision("highest"):
-        want, got = uncut(x, early), summed(x, early)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
-        dwant = jax.grad(lambda x, e: jnp.sum(jnp.sin(uncut(x, e))), argnums=(0, 1))(x, early)
-        dgot = jax.grad(lambda x, e: jnp.sum(jnp.sin(summed(x, e))), argnums=(0, 1))(x, early)
-        for a, b in zip(dgot, dwant):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
-        assert float(jnp.max(jnp.abs(dwant[1]))) > 1e-3  # the gates' cotangent reaches the tensor the router read
-    # the counters: the shares' held rows are all the assignments, none dropped, and the units add up too
-    stats = [_share(x, early, w, r * count, count)[1] for r in range(chips)]
-    assert sum(int(st["rows_held"]) for st in stats) == int(stats[0]["assignments"]) == 64 * 6
-    assert all(int(st["dropped"]) == 0 for st in stats)
-    whole = _share(x, early, w, 0, 64)[1]
-    assert sum(int(st["active_units"]) for st in stats) == int(whole["active_units"])
-    assert 0.4 < int(whole["active_units"]) / (64 * 6 * 16) < 0.6
 
 
 def test_relu_counts_and_silu_does_not() -> None:
@@ -368,41 +266,33 @@ def test_relu_counts_and_silu_does_not() -> None:
     assert set(moe.ACTIVATIONS) == {"silu", "relu", "relu2"}  # "relu2": the un-gated experts' (tests/test_mamba2_moe.py)
 
 
-# -- the counter through ft_step ---------------------------------------------------
+# -- the counter through ft_step: `moe_active_units` and `moe_units_held` ride the next step's summary beside the
+# counters every share has, through the benchmark's own programs file -----------------------------------------------
 
 
-def _records(path, event):
-    with open(path, encoding="utf-8") as f:
-        return [r for r in map(json.loads, f) if r.get("event") == event]
+def _tiny() -> Tiny:
+    def facts(moved, summaries, step, after) -> None:
+        for summary in summaries[1:]:
+            assert summary["moe_assignments"] == LAYERS * 2 * SEQ * 3 and summary["moe_dropped"] == 0
+            assert summary["moe_units_held"] == summary["moe_rows_held"] * 32
+            assert 0.4 < summary["moe_active_units"] / summary["moe_units_held"] < 0.6
+
+    return Tiny(lambda: REFERENCE.make_weights(2, SHARE), PROGRAM.loss(SHARE), _batch, 3, facts)
 
 
-def test_active_units_land_in_the_step_summary(store, tmp_path, monkeypatch) -> None:  # noqa: F811
-    """ft_steps of the share under a real Manager, through the benchmark's own
-    programs file: `moe_active_units` and `moe_units_held` ride the next step's
-    summary beside the counters every share has."""
-    path = tmp_path / "stream.jsonl"
-    monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
-    client = MagicMock()
-    client._quorum.return_value = make_quorum()
-    client.should_commit.return_value = True
-    manager, _, _ = make_manager(store, client_mock=client)
-    ftmesh = ft_init_mesh({"data": 1}, devices=jax.devices()[:1])
-    ftmesh.manager = manager
-    step = TrainStep(ftmesh, optax.adamw(1e-3), PROGRAM.loss(SHARE), loss_has_counters=True, overlap_commit=False)
-    params = REFERENCE.make_weights(2, SHARE)
-    opt = step.init_opt_state(params)
-    try:
-        for i in range(3):
-            manager.start_quorum()
-            params, opt, loss, committed = step.ft_step(params, opt, _batch(i))
-            assert committed and np.isfinite(float(loss))
-    finally:
-        manager.shutdown()
-    _, second, third = _records(path, "step_summary")
-    for summary in (second, third):
-        assert summary["moe_assignments"] == 8 * 2 * SEQ * 3 and summary["moe_dropped"] == 0
-        assert summary["moe_units_held"] == summary["moe_rows_held"] * 32
-        assert 0.4 < summary["moe_active_units"] / summary["moe_units_held"] < 0.6
+ARCH = Architecture(
+    name="early_router_moe_lm", configs=dict(zip(HELD, (CONFIG, SHARE))), sizes=SIZES, seq=SEQ,
+    variants=dict(REMAT, as_published={}),
+    leaf_cases=[Case(f"{seed}-{held}", held, "as_published", seed) for seed in (11, 2**31 + 29) for held in HELD],
+    leaf_tolerance=LEAF_TOLERANCE, loss_tolerance=1e-6, counters=_counters,
+    # what is recomputed — the early choice from the kept layer input, ReLU's mask from the recomputed gate
+    remat=("a_share_of_the_experts", 4, tuple(REMAT)),
+    pieces=PIECES, pieces_at=("a_share_of_the_experts", 5), piece_floor=3 * LEAF_TOLERANCE,
+    chips=[8, 16, 4, 1], expert_layer=_expert_layer,
+    published="smallthinker-21b-a3b", tree_config="a_share_of_the_experts", tree_facts=_tree_facts,
+    published_facts=_published_facts, refusals=REFUSALS, refusal_config="every_expert_held",
+    through=("ft_step",), tiny=_tiny,
+)
 
 
 # -- operation counts from shapes ---------------------------------------------------

@@ -3,53 +3,41 @@ attention, a leading dense layer, bias-corrected sigmoid routing beside a
 shared expert, three layer stacks of which two are sparse) through the program,
 on the CPU at small sizes.
 
-The program (``models/transformer.py`` with ``LayerKind``s whose mixers are
-"kda" and "mla"; ``ops/delta_attention.py``'s chunk form) against the
-benchmark's plain float32 reference (``benchmark/reference/kda_mla_moe_lm.py``,
-which computes the recurrence position by position and shares no code with it)
-on seeded random weights; each piece of ``kda_mix`` against a written-out loop;
-the shares of an expert-parallel layer against the uncut layer; the latent kind
-against the model-level latent attention; the adapter's refusals; and the
-three-stack tree with its float32 leaves of a few elements through ``ft_step``,
-a heal's transport and the disk checkpoint.
+`ARCH` is the architecture's entry in the suite (`tests/architectures.py`, which
+holds the tests every architecture is held to against the benchmark's plain
+float32 reference, ``benchmark/reference/kda_mla_moe_lm.py``).  What only this
+architecture has is tested here: each piece of ``kda_mix`` against a written-out
+loop, and the latent kind against the model-level latent attention.
 """
 
+import contextlib
 import dataclasses
-import functools
-import json
-import os
-import sys
-from unittest.mock import MagicMock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from test_manager import make_manager, make_quorum, store  # noqa: F401
+from architectures import (  # noqa: F401 — the shared tests this entry has fields for, and their fixture
+    BENCH, Architecture, Case, ExpertLayer, Piece, Tiny, batches, patched, pytest_generate_tests, store, tiny_of_the_small_model,
+    test_a_model_without_a_piece_is_another_model, test_loss_and_every_gradient_leaf_against_the_plain_reference,
+    test_the_adapter_raises_on_what_it_does_not_honour, test_the_published_configuration_is_handed_over_whole,
+    test_the_shares_add_up_to_the_uncut_layer, test_the_tree_goes_through, test_the_tree_is_the_reference_s)
+from torchft_tpu.models import LayerKind, TransformerConfig, init_params
+from torchft_tpu.models.moe import moe_layer
+from torchft_tpu.models.transformer import _causal_conv, _kda_mixer, _l2, _mla_qkv, loss_and_counters
+from torchft_tpu.ops import delta_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.spec import Benchmark  # noqa: E402
-from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models.moe import moe_layer  # noqa: E402
-from torchft_tpu.models.transformer import (  # noqa: E402
-    _causal_conv, _kda_mixer, _l2, _mla_qkv, loss_and_counters, param_axes)
-from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
-
-BENCH = Benchmark(ROOT)
 REFERENCE = BENCH.reference("kda_mla_moe_lm")
 PROGRAM = BENCH.program("kda_mla_moe_lm")
 PUBLISHED = BENCH.config("kimi-linear-48b-a3b")
 
-SEQ = 72  # four chunks of 16 and a half: the model's chunk of 64 would be one chunk, see `_small_chunks`
-# The cut's layers 1-5 in small, float32 throughout: KDA at 2 heads of 16 under
-# kernel-4 convolutions, latent attention at 2 heads of 16 + 8 / 16 over rank 32,
-# a dense first layer, then 8 router outputs of which this chip holds experts
-# 2-5, 2 a token, a shared expert.  The lists keep the published 27 entries.
+SEQ = 40
+SIZES = """40 positions under chunks of 16 (`_small_chunks`: the model's chunk of 64 would be one chunk): two chunks and a
+half, so the scan crosses a chunk's edge twice and ends inside one.  The cut's layers 1-5 (KDA dense, KDA, KDA, latent,
+KDA): every kind, a stack of one dense layer, a sparse stack in two runs around the latent layer.  KDA at 2 heads of 16
+under kernel-4 convolutions, latent attention at 2 heads of 16 + 8 / 16 over rank 32, 8 router outputs of which this
+chip holds experts 2-5, 2 a token, a shared expert.  The lists keep the published 27 entries.  Float32 throughout."""
 CONFIG = dict(
     PUBLISHED, vocab_size=300, hidden_size=64, intermediate_size=96, moe_intermediate_size=32, kv_lora_rank=32,
     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, num_attention_heads=2, num_key_value_heads=2,
@@ -62,28 +50,22 @@ CONFIG = dict(
 )
 
 
+@contextlib.contextmanager
+def _chunks_of_16():
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(delta_attention.kda, "__kwdefaults__", dict(delta_attention.kda.__kwdefaults__, chunk=16))
+        yield
+
+
 @pytest.fixture(autouse=True)
-def _small_chunks(monkeypatch):
-    """Chunks of 16 positions, so that a sequence of 72 crosses chunk
+def _small_chunks():
+    """Chunks of 16 positions, so that a sequence of 40 crosses chunk
     boundaries and ends inside one."""
-    from torchft_tpu.ops import delta_attention
-
-    monkeypatch.setattr(delta_attention.kda, "__kwdefaults__", dict(delta_attention.kda.__kwdefaults__, chunk=16))
-
-
-def _weights(seed: int, config=CONFIG):
-    """The reference's weights with every leaf moved off its start (a tenth of
-    its spread, or 0.1 where it starts constant): the gates' biases and the
-    norms' weights then take part in every product."""
-    weights = REFERENCE.make_weights(seed, config)
-    rng = np.random.default_rng(seed)
-    return jax.tree.map(
-        lambda l: l + 0.1 * (float(jnp.std(l)) or 1.0) * jnp.asarray(rng.standard_normal(l.shape), jnp.float32), weights)
+    with _chunks_of_16():
+        yield
 
 
-def _batch(seed: int, vocab: int = 300, seq_len: int = SEQ, sequences: int = 2):
-    tokens = np.random.default_rng(seed).integers(0, vocab, size=(sequences, seq_len)).astype(np.int32)
-    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
+_batch = batches(300, SEQ)
 
 
 WALKS = {
@@ -96,110 +78,158 @@ WALKS = {
 STACKS = ("kda_dense", "kda_layers", "mla_layers", "embed", "final_norm", "lm_head")
 
 
-@functools.lru_cache(maxsize=None)
-def _reference(seed):
-    """The reference's loss and gradients on the seed's weights and batch: the same for every walk and piece."""
-    weights, batch = _weights(seed), _batch(seed)
-    loss, grads = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], CONFIG)
-    return weights, batch, float(loss), grads
-
-
-@functools.lru_cache(maxsize=None)
-def _program_and_reference(walk):
-    config = dict(CONFIG, program=dict(CONFIG["program"], **WALKS[walk]))
-    weights, batch, want_loss, want = _reference(1)
-    from torchft_tpu.ops import delta_attention
-
-    kda = delta_attention.kda
-    with pytest.MonkeyPatch.context() as patch:  # the fixture's patch does not reach a cached call made once
-        patch.setattr(kda, "__kwdefaults__", dict(kda.__kwdefaults__, chunk=16))
-        (loss, counters), grads = jax.jit(jax.value_and_grad(PROGRAM.loss(config), has_aux=True))(weights, batch)
-    return float(loss), counters, grads, want_loss, want
-
-
-@pytest.mark.parametrize("stack", STACKS)
-@pytest.mark.parametrize("walk", list(WALKS))
-def test_loss_and_every_gradient_leaf_against_the_plain_reference(walk, stack) -> None:
-    """Float32 on both sides, so what differs is the order of sums — the chunk
-    form against the recurrence position by position, the grouped experts
-    against the masked loop: the loss to 1e-6, every leaf's gradient to 2e-4 of
-    its largest entry (the deepest leaves sum 144 positions through five
-    layers in another order; a missing term would be 1e-2 or more, as
-    `test_a_model_without_a_piece_is_another_model` shows)."""
-    loss, counters, grads, want_loss, want = _program_and_reference(walk)
-    assert abs(loss - want_loss) <= 1e-6 * abs(want_loss)
-    assert set(grads) == set(want) == set(STACKS)
-    got_leaves = jax.tree_util.tree_leaves_with_path(grads[stack])
-    assert jax.tree.structure(grads[stack]) == jax.tree.structure(want[stack])
-    for (path, got), ref in zip(got_leaves, jax.tree.leaves(want[stack])):
-        scale = float(jnp.max(jnp.abs(ref)))
-        assert scale > 0, f"{stack}{jax.tree_util.keystr(path)} has no gradient in the reference"
-        assert float(jnp.max(jnp.abs(got - ref))) <= 2e-4 * scale, (stack, jax.tree_util.keystr(path))
+def _counters(counters, config) -> None:
     assert int(counters["moe_dropped"]) == 0 and int(counters["moe_assignments"]) == 4 * 2 * 2 * SEQ
     assert 0 < int(counters["moe_rows_held"]) < int(counters["moe_assignments"])
     assert 0.0 < float(counters["kda_alpha_mean"]) < 1.0
 
 
-# -- a model without a piece is another model ----------------------------------------------
+# -- a model without a piece is another model: the program without the decay (alpha = 1), the beta k k^T term, the
+# convolutions' earlier taps, the q / k norm or the output gate, or with its latent layer rotated, is not the reference's
 
 
 def _without(piece):
-    """The program's loss with one piece of the mathematics left out."""
     import torchft_tpu.models.transformer as model
-    from torchft_tpu.ops import delta_attention
 
-    patch = pytest.MonkeyPatch()
-    kda = delta_attention.kda
-    if piece == "decay":
-        patch.setattr(delta_attention, "kda", lambda q, k, v, g, beta, **kw: kda(q, k, v, jnp.zeros_like(g), beta, **kw))
-    elif piece == "delta_term":  # S_t = Diag(alpha) S_{t-1} + beta k v^T: gated linear attention
-        def plain(q, k, v, g, beta, **kw):
-            def step(state, xs):
-                qt, kt, vt, gt, bt = xs
-                state = state * jnp.exp(gt)[..., None] + (bt[..., None] * kt)[..., None] * vt[..., None, :]
-                return state, jnp.einsum("bhk,bhkv->bhv", qt, state)
-            xs = tuple(jnp.moveaxis(a, 2, 0) for a in (q, k, v, g, beta))
-            return jnp.moveaxis(jax.lax.scan(step, jnp.zeros(q.shape[:2] + (q.shape[3], v.shape[3])), xs)[1], 0, 2)
-        patch.setattr(delta_attention, "kda", plain)
-    elif piece == "convolution":
-        patch.setattr(model, "_causal_conv", lambda z, taps: taps[-1] * z)
-    elif piece == "qk_norm":
-        patch.setattr(model, "_l2", lambda x: x)
-    return patch  # the output gate and the rotation are cut in the test itself
+    def how(cfg, weights):
+        changes, kda = [], delta_attention.kda
+        if piece == "decay":
+            changes = [(delta_attention, "kda", lambda q, k, v, g, beta, **kw: kda(q, k, v, jnp.zeros_like(g), beta, **kw))]
+        elif piece == "delta_term":  # S_t = Diag(alpha) S_{t-1} + beta k v^T: gated linear attention
+            def plain(q, k, v, g, beta, **kw):
+                def step(state, xs):
+                    qt, kt, vt, gt, bt = xs
+                    state = state * jnp.exp(gt)[..., None] + (bt[..., None] * kt)[..., None] * vt[..., None, :]
+                    return state, jnp.einsum("bhk,bhkv->bhv", qt, state)
+                xs = tuple(jnp.moveaxis(a, 2, 0) for a in (q, k, v, g, beta))
+                return jnp.moveaxis(jax.lax.scan(step, jnp.zeros(q.shape[:2] + (q.shape[3], v.shape[3])), xs)[1], 0, 2)
+            changes = [(delta_attention, "kda", plain)]
+        elif piece == "convolution":
+            changes = [(model, "_causal_conv", lambda z, taps: taps[-1] * z)]
+        elif piece == "qk_norm":
+            changes = [(model, "_l2", lambda x: x)]
+        elif piece == "output_gate":  # a gate that is always 1/2: the bias pushed far out of the weights' reach is not it
+            weights = dict(weights)
+            for stack in ("kda_dense", "kda_layers"):
+                weights[stack] = dict(weights[stack], kda_g_down=jnp.zeros_like(weights[stack]["kda_g_down"]),
+                                      kda_g_bias=jnp.zeros_like(weights[stack]["kda_g_bias"]))
+        elif piece == "rotated_latent_layer":
+            cfg = dataclasses.replace(cfg, pattern=tuple(
+                dataclasses.replace(kind, rotary_fraction=1.0) if kind.mixer == "mla" else kind for kind in cfg.pattern))
+        return patched(*changes), cfg, weights
+
+    return Piece(piece, "program", how)
 
 
 PIECES = ("decay", "delta_term", "convolution", "qk_norm", "output_gate", "rotated_latent_layer")
 
 
-@pytest.mark.parametrize("piece", PIECES)
-def test_a_model_without_a_piece_is_another_model(piece) -> None:
-    """Each piece of the mathematics moves some leaf's gradient by more than a
-    hundred times the tolerance of the comparison above: the program without
-    the decay (alpha = 1), the beta k k^T term, the convolutions' earlier
-    taps, the q / k norm or the output gate, or with its latent layer rotated,
-    is not the reference's model."""
-    config = dict(CONFIG)
-    weights, batch, _, want = _reference(2)
-    patch = _without(piece)
-    try:
-        cfg = PROGRAM.transformer_config(config)
-        if piece == "rotated_latent_layer":
-            cfg = dataclasses.replace(cfg, pattern=tuple(
-                dataclasses.replace(kind, rotary_fraction=1.0) if kind.mixer == "mla" else kind for kind in cfg.pattern))
-        if piece == "output_gate":  # a gate that is always 1/2: the bias pushed far out of the weights' reach is not it
-            weights_run = jax.tree.map(lambda l: l, weights)
-            for stack in ("kda_dense", "kda_layers"):
-                weights_run[stack] = dict(weights_run[stack], kda_g_down=jnp.zeros_like(weights[stack]["kda_g_down"]),
-                                          kda_g_bias=jnp.zeros_like(weights[stack]["kda_g_bias"]))
-        else:
-            weights_run = weights
-        bias = jnp.asarray(PROGRAM.router_bias(config))
-        _, grads = jax.value_and_grad(lambda p: loss_and_counters(p, batch, cfg, router_bias=bias)[0])(weights_run)
-    finally:
-        patch.undo()
-    worst = max(float(jnp.max(jnp.abs(g - r))) / float(jnp.max(jnp.abs(r)))
-                for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(want)))
-    assert worst > 2e-2, (piece, worst)
+# -- the shares add up: 32 chips hold one of 32 experts each -----------------------------------------
+
+
+def _expert_layer() -> ExpertLayer:
+    hidden, ffn, experts, k = 32, 16, 32, 4
+    rng = np.random.default_rng(4)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)  # noqa: E731
+    w = dict(router=draw(hidden, experts), w_gate=draw(experts, hidden, ffn), w_up=draw(experts, hidden, ffn),
+             w_down=draw(experts, ffn, hidden), shared_gate=draw(hidden, ffn), shared_up=draw(hidden, ffn),
+             shared_down=draw(ffn, hidden))
+    bias = jnp.asarray(rng.standard_normal(experts) * 0.05, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((2, 24, hidden)), jnp.float32)
+    s = dict(experts=experts, held=experts, first=0, top_k=k, route_scale=2.446, aux_coef=0.0)
+
+    def uncut(x):
+        return jnp.stack([REFERENCE._experts(seq, w, bias, s, "float32")[0] for seq in x]), None
+
+    def share(first, count, with_shared, x):
+        held = slice(first, first + count)
+        return moe_layer(x, w["router"], w["w_gate"][held], w["w_up"][held], w["w_down"][held], top_k=k,
+                         capacity_factor=None, norm_topk=True, score="sigmoid", route_bias=bias, route_scale=2.446,
+                         held_first=first, shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if with_shared else None,
+                         dtype=jnp.float32)
+
+    return ExpertLayer((x,), experts, share, uncut, 2 * 24 * k, sin=5.0, grad_rtol=1e-3, shared=True)
+
+
+# -- the tree, the configuration, the adapter --------------------------------------------------
+
+
+def _tree_facts(cfg, ours) -> None:
+    """Three stacks, two of them sparse; `A_log` and `dt_bias` are float32 leaves
+    of 32 and 4,096 elements a layer, and the decay's initialisation is the
+    published one on both sides."""
+    assert [(s, k.mixer, k.sparse, n) for s, (k, n) in cfg.stacks.items()] == [
+        ("kda_dense", "kda", False, 1), ("kda_layers", "kda", True, 3), ("mla_layers", "mla", True, 1)]
+    assert [kind.mixer for kind in cfg.layers] == ["kda", "kda", "kda", "mla", "kda"]
+    theirs = jax.eval_shape(lambda: REFERENCE.make_weights(1, PUBLISHED))
+    assert [l.dtype for l in jax.tree.leaves(ours)] == [l.dtype for l in jax.tree.leaves(theirs)]
+    assert ours["kda_layers"]["A_log"].shape == (3, 32) and ours["kda_layers"]["dt_bias"].shape == (3, 4096)
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours)) == 602_449_792 == BENCH.flops("kda_mla_moe_lm").total_params(PUBLISHED)
+    small = PROGRAM.transformer_config(CONFIG)
+    for tree in (init_params(jax.random.PRNGKey(2), small), REFERENCE.make_weights(2, CONFIG)):
+        rate, steps = np.exp(np.asarray(tree["kda_layers"]["A_log"])), np.asarray(jax.nn.softplus(tree["kda_layers"]["dt_bias"]))
+        assert 1.0 <= rate.min() and rate.max() <= 16.0 and 0.001 * 0.999 <= steps.min() and steps.max() <= 0.1 * 1.001
+
+
+def _published_facts(cfg, _) -> None:
+    assert (cfg.d_model, cfg.dense_d_ff, cfg.d_ff, cfg.vocab_size) == (2304, 9216, 1024, 20480)
+    assert (cfg.kda_head_dim, cfg.kda_conv, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim) == (128, 4, 512, 128, 64, 128)
+    assert all(kind.n_heads == 32 and kind.rotary_fraction == 0.0 for kind in cfg.pattern)
+    assert (cfg.moe_experts, cfg.moe_top_k, cfg.moe_held, cfg.moe_route_scale, cfg.moe_shared_experts) == (256, 8, (0, 8), 2.446, 1)
+    assert cfg.moe_score == "sigmoid" and cfg.moe_norm_topk and cfg.rms_eps == 1e-5 and not cfg.tied_head
+    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 256)
+    # every number of the catalog's row under the same key, the three cuts listed
+    assert PUBLISHED["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert PUBLISHED["published"] == {"num_hidden_layers": 27, "num_experts": 256, "vocab_size": 163_840}
+    linear = PUBLISHED["linear_attn_config"]
+    assert len(linear["kda_layers"]) == 20 and linear["full_attn_layers"] == [4, 8, 12, 16, 20, 24, 27]
+    assert set(PROGRAM.kernel_names()) >= {"attn", "ce", "gmm", "kda"} and PROGRAM.kernel_names()["kda"]("x.tpuft_kda_bwd.3")
+
+
+REFUSALS = [
+    ("q_lora_rank", dict(q_lora_rank=1536), "low-rank query"),
+    ("num_expert_group", dict(num_expert_group=8), "one group"),
+    ("topk_group", dict(topk_group=4), "one group"),
+    ("a_softmax_router", dict(moe_router_activation_func="softmax"), "sigmoid"),
+    ("a_rotated_latent_layer", dict(mla_use_nope=False), "without rotation"),
+    ("extra_prediction_layers", dict(num_nextn_predict_layers=1), "extra prediction"),
+    ("tied_head", dict(tie_word_embeddings=True), "untied"),
+    ("a_layer_in_neither_list", dict(linear_attn_config=dict(CONFIG["linear_attn_config"], kda_layers=[1, 2, 5])), "layer 3 is in neither"),
+    ("a_layer_in_both_lists", dict(linear_attn_config=dict(CONFIG["linear_attn_config"], full_attn_layers=[3, 4])), "layer 3 is in neither or both"),
+]
+
+
+# -- the three-stack tree through ft_step, a heal's transport and the checkpoint -----------------
+
+
+def _tiny() -> Tiny:
+    def tree_facts(tree) -> None:
+        assert set(tree) == {"embed", "final_norm", "lm_head", "kda_dense", "kda_layers", "mla_layers"}
+        assert tree["kda_layers"]["A_log"].shape == (3, 2) and tree["kda_layers"]["A_log"].dtype == jnp.float32
+
+    def facts(moved, summaries, step, after) -> None:
+        assert {"['embed']", "['kda_dense']['A_log']", "['kda_layers']['dt_bias']", "['kda_layers']['kda_conv_k']",
+                "['mla_layers']['wkv_b']", "['kda_layers']['router']", "['mla_layers']['router']"} <= moved
+        summary = summaries[-1]
+        assert summary["moe_dropped"] == 0 and 0 < summary["moe_rows_held"] < summary["moe_assignments"] == 4 * 2 * 2 * SEQ
+        assert 0.0 < summary["kda_alpha_mean"] < 1.0
+
+    return tiny_of_the_small_model("kda_mla_moe_lm", CONFIG, _batch(0), tree_facts, facts)
+
+
+ARCH = Architecture(
+    name="kda_mla_moe_lm", configs={"share": CONFIG}, sizes=SIZES, seq=SEQ, variants=dict(WALKS, as_published={}),
+    leaf_cases=[Case(f"{walk}-{stack}", "share", walk, 1, stack=stack) for walk in WALKS for stack in STACKS],
+    # what differs is the order of sums — the chunk form against the recurrence position by position, the grouped experts
+    # against the masked loop: every leaf to 2e-4 of its largest entry (the deepest leaves sum 80 positions through five
+    # layers in another order); a missing term is 1e-2 or more (the pieces)
+    leaf_error="max", leaf_tolerance=2e-4, loss_tolerance=1e-6, off_start=True, counters=_counters, stacks=STACKS,
+    tracing=_chunks_of_16,  # the fixture's patch does not reach a cached call made once
+    pieces=[_without(piece) for piece in PIECES], pieces_at=("share", 2), piece_floor=2e-2,
+    chips=[32, 8, 1], expert_layer=_expert_layer,
+    published="kimi-linear-48b-a3b", tree_facts=_tree_facts, published_facts=_published_facts,
+    refusals=REFUSALS, refusal_config="share", through=("ft_step", "heal", "disk_checkpoint"), tiny=_tiny,
+)
 
 
 # -- the pieces of kda_mix against written-out loops ----------------------------------------
@@ -269,46 +299,6 @@ def test_the_whole_mixer_against_a_written_out_loop() -> None:
     np.testing.assert_allclose(float(alpha), np.exp(g).mean(), rtol=1e-5)
 
 
-# -- the shares add up -------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("chips", [32, 8, 1])
-def test_the_shares_add_up_to_the_uncut_layer(chips) -> None:
-    """What every chip of an expert-parallel layer computes of the routed
-    experts (32 chips: one of 32 experts each), summed over the chips, plus the
-    shared expert ONCE, is what the uncut plain reference gives for the whole
-    sublayer — values and the gradient of the input."""
-    hidden, ffn, experts, k = 32, 16, 32, 4
-    rng = np.random.default_rng(4)
-    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)  # noqa: E731
-    w = dict(router=draw(hidden, experts), w_gate=draw(experts, hidden, ffn), w_up=draw(experts, hidden, ffn),
-             w_down=draw(experts, ffn, hidden), shared_gate=draw(hidden, ffn), shared_up=draw(hidden, ffn),
-             shared_down=draw(ffn, hidden))
-    bias = jnp.asarray(rng.standard_normal(experts) * 0.05, jnp.float32)
-    x = jnp.asarray(rng.standard_normal((2, 24, hidden)), jnp.float32)
-    s = dict(experts=experts, held=experts, first=0, top_k=k, route_scale=2.446, aux_coef=0.0)
-    count = experts // chips
-
-    def uncut(x):
-        return jnp.stack([REFERENCE._experts(seq, w, bias, s, "float32")[0] for seq in x])
-
-    def share(x, first, with_shared):
-        held = slice(first, first + count)
-        y, _ = moe_layer(x, w["router"], w["w_gate"][held], w["w_up"][held], w["w_down"][held], top_k=k,
-                         capacity_factor=None, norm_topk=True, score="sigmoid", route_bias=bias, route_scale=2.446,
-                         held_first=first, shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if with_shared else None,
-                         dtype=jnp.float32)
-        return y
-
-    def summed(x):  # the shared expert with the first share alone
-        return sum(share(x, r * count, with_shared=r == 0) for r in range(chips))
-
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(np.asarray(jax.jit(summed)(x)), np.asarray(jax.jit(uncut)(x)), rtol=1e-4, atol=1e-5)
-        dwant = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(5 * uncut(x)))))(x)
-        dgot = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(5 * summed(x)))))(x)
-        np.testing.assert_allclose(np.asarray(dgot), np.asarray(dwant), rtol=1e-3, atol=1e-5)
-
 
 # -- the latent kind --------------------------------------------------------------------------
 
@@ -327,7 +317,7 @@ def test_the_latent_kind_with_rotation_is_the_model_level_latent_attention_bit_f
     params = init_params(jax.random.PRNGKey(0), model)
     assert jax.tree.structure(params) == jax.tree.structure(init_params(jax.random.PRNGKey(0), as_kind))
     batch = _batch(0, vocab=64, seq_len=32)
-    losses = [jax.value_and_grad(lambda p, c=c: loss_and_counters(p, batch, c)[0])(params) for c in (model, as_kind, nope)]
+    losses = [jax.jit(jax.value_and_grad(lambda p, c=c: loss_and_counters(p, batch, c)[0]))(params) for c in (model, as_kind, nope)]
     assert float(losses[0][0]) == float(losses[1][0])
     assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(losses[0][1]), jax.tree.leaves(losses[1][1])))
     assert float(losses[2][0]) != float(losses[0][0])
@@ -339,137 +329,3 @@ def test_the_latent_kind_with_rotation_is_the_model_level_latent_attention_bit_f
     latent = h @ w["wkv_a"]
     assert np.array_equal(np.asarray(k[:, :, 0, 8:]), np.asarray(latent[..., 16:])) and np.array_equal(np.asarray(k[:, :, 0, 8:]), np.asarray(k[:, :, 1, 8:]))
     assert np.array_equal(np.asarray(q), np.asarray((h @ w["wq"]).reshape(1, 32, 2, 12)))
-
-
-# -- the tree, the configuration, the adapter --------------------------------------------------
-
-
-def test_the_tree_is_the_reference_s() -> None:
-    """`init_params` and `make_weights` give one tree at the published widths
-    — three stacks, two of them sparse, names and shapes — and `param_axes`
-    names every leaf; `A_log` and `dt_bias` are float32 leaves of 32 and 4,096
-    elements a layer, and the decay's initialisation is the published one on
-    both sides."""
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    assert [(s, k.mixer, k.sparse, n) for s, (k, n) in cfg.stacks.items()] == [
-        ("kda_dense", "kda", False, 1), ("kda_layers", "kda", True, 3), ("mla_layers", "mla", True, 1)]
-    assert [kind.mixer for kind in cfg.layers] == ["kda", "kda", "kda", "mla", "kda"]
-    ours = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    theirs = jax.eval_shape(lambda: REFERENCE.make_weights(1, PUBLISHED))
-    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
-    assert [(l.shape, l.dtype) for l in jax.tree.leaves(ours)] == [(l.shape, l.dtype) for l in jax.tree.leaves(theirs)]
-    assert jax.tree.structure(ours) == jax.tree.structure(param_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    assert ours["kda_layers"]["A_log"].shape == (3, 32) and ours["kda_layers"]["dt_bias"].shape == (3, 4096)
-    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours)) == 602_449_792 == BENCH.flops("kda_mla_moe_lm").total_params(PUBLISHED)
-    small = PROGRAM.transformer_config(CONFIG)
-    for tree in (init_params(jax.random.PRNGKey(2), small), REFERENCE.make_weights(2, CONFIG)):
-        rate, steps = np.exp(np.asarray(tree["kda_layers"]["A_log"])), np.asarray(jax.nn.softplus(tree["kda_layers"]["dt_bias"]))
-        assert 1.0 <= rate.min() and rate.max() <= 16.0 and 0.001 * 0.999 <= steps.min() and steps.max() <= 0.1 * 1.001
-
-
-def test_the_published_configuration_is_handed_over_whole() -> None:
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    assert (cfg.d_model, cfg.dense_d_ff, cfg.d_ff, cfg.vocab_size) == (2304, 9216, 1024, 20480)
-    assert (cfg.kda_head_dim, cfg.kda_conv, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim) == (128, 4, 512, 128, 64, 128)
-    assert all(kind.n_heads == 32 and kind.rotary_fraction == 0.0 for kind in cfg.pattern)
-    assert (cfg.moe_experts, cfg.moe_top_k, cfg.moe_held, cfg.moe_route_scale, cfg.moe_shared_experts) == (256, 8, (0, 8), 2.446, 1)
-    assert cfg.moe_score == "sigmoid" and cfg.moe_norm_topk and cfg.rms_eps == 1e-5 and not cfg.tied_head
-    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 256)
-    # every number of the catalog's row under the same key, the three cuts listed
-    assert PUBLISHED["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
-    assert PUBLISHED["published"] == {"num_hidden_layers": 27, "num_experts": 256, "vocab_size": 163_840}
-    linear = PUBLISHED["linear_attn_config"]
-    assert len(linear["kda_layers"]) == 20 and linear["full_attn_layers"] == [4, 8, 12, 16, 20, 24, 27]
-    assert set(PROGRAM.kernel_names()) >= {"attn", "ce", "gmm", "kda"} and PROGRAM.kernel_names()["kda"]("x.tpuft_kda_bwd.3")
-
-
-@pytest.mark.parametrize("change,message", [
-    (dict(q_lora_rank=1536), "low-rank query"),
-    (dict(num_expert_group=8), "one group"),
-    (dict(topk_group=4), "one group"),
-    (dict(moe_router_activation_func="softmax"), "sigmoid"),
-    (dict(mla_use_nope=False), "without rotation"),
-    (dict(num_nextn_predict_layers=1), "extra prediction"),
-    (dict(tie_word_embeddings=True), "untied"),
-    (dict(linear_attn_config=dict(CONFIG["linear_attn_config"], kda_layers=[1, 2, 5])), "layer 3 is in neither"),
-    (dict(linear_attn_config=dict(CONFIG["linear_attn_config"], full_attn_layers=[3, 4])), "layer 3 is in neither or both"),
-])
-def test_the_adapter_raises_on_what_it_does_not_honour(change, message) -> None:
-    with pytest.raises(ValueError, match=message):
-        PROGRAM.transformer_config(dict(CONFIG, **change))
-
-
-# -- the three-stack tree through ft_step, a heal's transport and the checkpoint -----------------
-
-
-def _records(path, event):
-    with open(path, encoding="utf-8") as f:
-        return [r for r in map(json.loads, f) if r.get("event") == event]
-
-
-TINY = dataclasses.replace(PROGRAM.transformer_config(CONFIG), remat=True, remat_keeps_attention=True, scan_unroll=1)
-
-
-@pytest.mark.parametrize("through", ["ft_step", "heal", "disk_checkpoint"])
-def test_a_three_stack_tree_goes_through(through, store, tmp_path, monkeypatch) -> None:  # noqa: F811
-    params = init_params(jax.random.PRNGKey(5), TINY)
-    assert set(params) == {"embed", "final_norm", "lm_head", "kda_dense", "kda_layers", "mla_layers"}
-    assert params["kda_layers"]["A_log"].shape == (3, 2) and params["kda_layers"]["A_log"].dtype == jnp.float32
-    leaves = jax.tree.leaves(params)
-    if through == "ft_step":
-        path = tmp_path / "stream.jsonl"
-        monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
-        client = MagicMock()
-        client._quorum.return_value = make_quorum()
-        client.should_commit.return_value = True
-        manager, _, _ = make_manager(store, client_mock=client)
-        ftmesh = ft_init_mesh({"data": 1}, devices=jax.devices()[:1])
-        ftmesh.manager = manager
-        bias = jnp.asarray(PROGRAM.router_bias(CONFIG))
-        step = TrainStep(ftmesh, optax.adamw(1e-3), lambda p, b: loss_and_counters(p, b, TINY, router_bias=bias),
-                         loss_has_counters=True, overlap_commit=False)
-        opt = step.init_opt_state(params)
-        batch = _batch(0)
-        before = jax.tree.map(np.asarray, params)  # `ft_step` donates its arguments
-        try:
-            for _ in range(2):
-                manager.start_quorum()
-                params, opt, loss, committed = step.ft_step(params, opt, batch)
-                assert committed and np.isfinite(float(loss))
-        finally:
-            manager.shutdown()
-        assert jax.tree.structure(params) == jax.tree.structure(before)
-        moved = {jax.tree_util.keystr(p) for (p, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
-                                                              jax.tree.leaves(before)) if not np.array_equal(np.asarray(a), b)}
-        assert {"['embed']", "['kda_dense']['A_log']", "['kda_layers']['dt_bias']", "['kda_layers']['kda_conv_k']",
-                "['mla_layers']['wkv_b']", "['kda_layers']['router']", "['mla_layers']['router']"} <= moved
-        summary = _records(path, "step_summary")[-1]
-        assert summary["moe_dropped"] == 0 and 0 < summary["moe_rows_held"] < summary["moe_assignments"] == 4 * 2 * 2 * SEQ
-        assert 0.0 < summary["kda_alpha_mean"] < 1.0
-    elif through == "heal":
-        from torchft_tpu.checkpointing.http_transport import HTTPTransport
-
-        donor, healer = HTTPTransport(timeout=30.0), HTTPTransport(timeout=30.0)
-        try:
-            donor.send_checkpoint([1], 7, {"params": params}, 30.0)
-            back = healer.recv_checkpoint(0, donor.metadata(), 7, 30.0)["params"]
-        finally:
-            donor.shutdown()
-            healer.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))
-    else:
-        from torchft_tpu.checkpointing.disk import DiskCheckpointer
-        from torchft_tpu.ddp import plan_buckets
-
-        buckets = plan_buckets([(l.shape, l.dtype) for l in leaves], 1 << 14)
-        assert sorted(i for b in buckets for i in b.indices) == list(range(len(leaves))) and len(buckets) > 2
-        ckpt = DiskCheckpointer(str(tmp_path))
-        try:
-            ckpt.save(4, {"params": params})
-            ckpt.wait()
-            back = ckpt.restore(4)["params"]
-        finally:
-            ckpt.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))

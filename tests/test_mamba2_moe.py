@@ -1,86 +1,61 @@
 """Nemotron-H-shaped models (blocks that are ONE norm and ONE part: a Mamba-2
-state-space mixer, un-rotated grouped-query attention or un-gated ReLU^2
-experts beside a shared expert, in the order of a pattern string; three stacks
-of unequal leaf sets) through the program, on the CPU at small sizes.
+mixer, un-rotated grouped-query attention or un-gated ReLU^2 experts beside a
+shared expert, in a pattern string's order; three stacks of unequal leaf sets)
+through the program, on the CPU at small sizes.
 
-The program (``models/transformer.py`` with ``LayerKind``s that have no mixer
-or no feed-forward; ``models/mamba.py``; ``ops/ssd.py``'s chunk form) against
-the benchmark's plain float32 reference (``benchmark/reference/mamba2_moe_lm.py``,
-which computes the recurrence position by position and shares no code with it)
-on seeded random weights; the `tpuft_ssd_*` kernels (interpret mode) against the
-XLA chunk form and the loop; each piece of ``ssm_mix`` against a written-out
-loop; ``grouped_matmul`` at a width of 14.5 lane tiles; the shares of an
-expert-parallel block against the uncut block; the adapter's refusals; and the
-three-stack tree with its vectors of a few elements and its tap array through
-``ft_step``, a heal's transport, the bucket plan and the disk checkpoint.
+`ARCH` is the architecture's entry in the suite (`tests/architectures.py`, which
+holds the tests every architecture is held to against the benchmark's plain
+float32 reference, ``benchmark/reference/mamba2_moe_lm.py``).  What only this
+architecture has is tested here: the `tpuft_ssd_*` kernels (interpret mode)
+against the XLA chunk form and the loop, each piece of ``ssm_mix`` against a
+written-out loop, the kinds a pattern may hold (``grouped_matmul`` at its experts'
+14.5 lane tiles: `tests/test_grouped_matmul.py`).
 """
 
 import dataclasses
-import functools
 import json
-import logging
-import os
-import sys
-from unittest.mock import MagicMock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from test_manager import make_manager, make_quorum, store  # noqa: F401
+from architectures import (  # noqa: F401 — the shared tests this entry has fields for, and their fixture
+    BENCH, Architecture, Case, ExpertLayer, Piece, Tiny, batches, patched, pytest_generate_tests, store, tiny_of_the_small_model,
+    test_a_model_without_a_piece_is_another_model, test_loss_and_every_gradient_leaf_against_the_plain_reference,
+    test_the_adapter_raises_on_what_it_does_not_honour, test_the_published_configuration_is_handed_over_whole,
+    test_the_shares_add_up_to_the_uncut_layer, test_the_tree_goes_through, test_the_tree_is_the_reference_s)
+from torchft_tpu.models import LayerKind, TransformerConfig, init_params
+from torchft_tpu.models import mamba
+from torchft_tpu.models.moe import moe_layer
+from torchft_tpu.models.transformer import _causal_conv, loss_and_counters
+from torchft_tpu.ops import ssd
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.spec import Benchmark  # noqa: E402
-from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models import mamba  # noqa: E402
-from torchft_tpu.models.moe import moe_layer  # noqa: E402
-from torchft_tpu.models.transformer import _causal_conv, loss_and_counters, param_axes  # noqa: E402
-from torchft_tpu.ops import grouped_matmul as gmm  # noqa: E402
-from torchft_tpu.ops import ssd  # noqa: E402
-from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
-
-BENCH = Benchmark(ROOT)
 REFERENCE = BENCH.reference("mamba2_moe_lm")
 PROGRAM = BENCH.program("mamba2_moe_lm")
 PUBLISHED = BENCH.config("nemotron-twotower-30b-a3b")
 
-SEQ = 72  # four chunks of 16 and a half
-# The cut's nine blocks `MEMEM*EME` in small, float32 throughout: Mamba-2 at 6
-# heads of 8 in 2 groups over a state of 16, chunks of 16; attention at 4 query
-# heads over 2 KV heads of 16; 8 router outputs of which this chip holds experts
-# 2-5, 2 a token, at a width of 24, beside a shared expert of 48.  The pattern
-# keeps its published 52 letters.
+SEQ = 40
+SIZES = """40 positions: two chunks of 16 and a half, so the scan crosses a chunk's edge twice and ends inside one, and the
+kernel-4 convolution's first three positions are a small share.  Five blocks `ME*ME`: every kind of block, two stacks of
+two and one of one, each kind after each other kind (the published nine letters are `MEMEM*EME`: the same kinds four,
+four and one time; `test_runs_of_one_kind_go_through_the_scan` has runs).  Mamba-2 at 6 heads of 8 in 2 groups over a
+state of 16: three heads a group, a head count that is no multiple of eight.  Attention at 4 query heads over 2 KV heads
+of 16.  8 router outputs of which this chip holds experts 2-5, 2 a token, at a width of 24 (no lane multiple), beside a
+shared expert of 48.  Float32 throughout."""
 CONFIG = dict(
-    PUBLISHED, vocab_size=300, hidden_size=64, mamba_num_heads=6, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
-    chunk_size=16, num_attention_heads=4, num_key_value_heads=2, head_dim=16, moe_intermediate_size=24,
+    PUBLISHED, vocab_size=300, hidden_size=64, num_hidden_layers=5, mamba_num_heads=6, mamba_head_dim=8, n_groups=2,
+    ssm_state_size=16, chunk_size=16, num_attention_heads=4, num_key_value_heads=2, head_dim=16, moe_intermediate_size=24,
     moe_shared_expert_intermediate_size=48, n_routed_experts=4, num_experts_per_tok=2, max_position_embeddings=128,
+    hybrid_override_pattern="ME*ME" + PUBLISHED["hybrid_override_pattern"][5:],
     expert_parallel=dict(chips=2, rank=0, router_outputs=8, first_expert_held=2),
     router_bias=dict(seed=5, scale=0.05),
     training=dict(compute_dtype="float32", param_dtype="float32", optimizer="adamw", learning_rate=1e-7),
     program=dict(remat=False, remat_keeps_attention=False, scan_unroll=16),
 )
-
-
-def _weights(seed: int, config=CONFIG):
-    """The reference's weights with every leaf moved off its start (a tenth of
-    its spread, or 0.1 where it starts constant): the convolution's bias, D and
-    the norms' weights then take part in every product."""
-    weights = REFERENCE.make_weights(seed, config)
-    rng = np.random.default_rng(seed)
-    return jax.tree.map(
-        lambda l: l + 0.1 * (float(jnp.std(l)) or 1.0) * jnp.asarray(rng.standard_normal(l.shape), jnp.float32), weights)
-
-
-def _batch(seed: int, vocab: int = 300, seq_len: int = SEQ, sequences: int = 2):
-    tokens = np.random.default_rng(seed).integers(0, vocab, size=(sequences, seq_len)).astype(np.int32)
-    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
-
-
+# The published pattern's first letters have no two alike in a row, so its walk is a static loop whatever `scan_unroll`;
+# `MMMEE**EM` has runs, and with `scan_unroll` 1 each run is a `lax.scan` over its stack's slice.
+RUNS = dict(CONFIG, num_hidden_layers=9, hybrid_override_pattern="MMMEE**EM" + PUBLISHED["hybrid_override_pattern"][9:])
 WALKS = {
     "static_loop": dict(remat=False, scan_unroll=16),
     "remat": dict(remat=True, scan_unroll=16),
@@ -89,126 +64,199 @@ WALKS = {
 STACKS = ("mamba", "attn", "moe", "embed", "final_norm", "lm_head")
 
 
-@functools.lru_cache(maxsize=None)
-def _reference(seed):
-    """The reference's loss and gradients on the seed's weights and batch: the same for every walk and piece."""
-    weights, batch = _weights(seed), _batch(seed)
-    loss, grads = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], CONFIG)
-    return weights, batch, float(loss), grads
+_batch = batches(300, SEQ)
 
 
-@functools.lru_cache(maxsize=None)
-def _program_and_reference(walk):
-    config = dict(CONFIG, program=dict(CONFIG["program"], **WALKS[walk]))
-    weights, batch, want_loss, want = _reference(1)
-    (loss, counters), grads = jax.jit(jax.value_and_grad(PROGRAM.loss(config), has_aux=True))(weights, batch)
-    return float(loss), counters, grads, want_loss, want
+SCANS_OF_RUNS = {"scan": dict(remat=False, scan_unroll=1),
+                 "remat_in_the_scan": dict(remat=True, remat_keeps_attention=True, scan_unroll=1)}
 
 
-@pytest.mark.parametrize("stack", STACKS)
-@pytest.mark.parametrize("walk", list(WALKS))
-def test_loss_and_every_gradient_leaf_against_the_plain_reference(walk, stack) -> None:
-    """Float32 on both sides, so what differs is the order of sums — the chunk
-    form against the recurrence position by position, the grouped experts
-    against the masked loop: the loss to 1e-6, every leaf's gradient to 2e-4 of
-    its largest entry (a missing term would be 1e-2 or more, as
-    `test_a_model_without_a_piece_is_another_model` shows)."""
-    loss, counters, grads, want_loss, want = _program_and_reference(walk)
-    assert abs(loss - want_loss) <= 1e-6 * abs(want_loss)
-    assert set(grads) == set(want) == set(STACKS)
-    got_leaves = jax.tree_util.tree_leaves_with_path(grads[stack])
-    assert jax.tree.structure(grads[stack]) == jax.tree.structure(want[stack])
-    for (path, got), ref in zip(got_leaves, jax.tree.leaves(want[stack])):
-        scale = float(jnp.max(jnp.abs(ref)))
-        assert scale > 0, f"{stack}{jax.tree_util.keystr(path)} has no gradient in the reference"
-        assert float(jnp.max(jnp.abs(got - ref))) <= 2e-4 * scale, (stack, jax.tree_util.keystr(path))
-    assert int(counters["moe_dropped"]) == 0 and int(counters["moe_assignments"]) == 4 * 2 * 2 * SEQ
+def _counters(counters, config) -> None:
+    expert_blocks = config["hybrid_override_pattern"][:config["num_hidden_layers"]].count("E")
+    assert counters["moe_tokens_per_expert"].shape == (expert_blocks, 8)
+    assert int(counters["moe_dropped"]) == 0 and int(counters["moe_assignments"]) == expert_blocks * 2 * 2 * SEQ
     assert 0 < int(counters["moe_rows_held"]) < int(counters["moe_assignments"])
     assert 0.0 < float(counters["ssm_decay_mean"]) < 1.0
     held_units = int(counters["moe_units_held"])
     assert held_units == int(counters["moe_rows_held"]) * 24 and 0.3 < int(counters["moe_active_units"]) / held_units < 0.7
 
 
-RUNS = dict(CONFIG, hybrid_override_pattern="MMMEE**EM" + PUBLISHED["hybrid_override_pattern"][9:])
+# -- a model without a piece is another model: the program without the decay (a = 1), the D skip, the convolution's
+# earlier taps, the gate SiLU(z), the norm's groups (one norm over all 48 columns), the square, the scale 2.5 or the
+# shared expert, or with its attention block rotated, is not the reference's model
 
 
-@pytest.mark.parametrize("walk", ["scan", "remat_in_the_scan"])
-def test_runs_of_one_kind_go_through_the_scan(walk) -> None:
-    """The published pattern's first nine letters have no two alike in a row,
-    so its walk is a static loop whatever `scan_unroll`; `MMMEE**EM` has runs,
-    and with `scan_unroll` 1 each run is a `lax.scan` over its stack's slice:
-    the loss and every leaf against the reference of that pattern."""
-    remat = walk == "remat_in_the_scan"
-    config = dict(RUNS, program=dict(remat=remat, remat_keeps_attention=remat, scan_unroll=1))
-    weights, batch = _weights(3, RUNS), _batch(3)
-    want_loss, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], RUNS)
-    (loss, counters), grads = jax.jit(jax.value_and_grad(PROGRAM.loss(config), has_aux=True))(weights, batch)
-    assert abs(float(loss) - float(want_loss)) <= 1e-6 * abs(float(want_loss))
-    assert jax.tree.structure(grads) == jax.tree.structure(want)
-    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
-        assert float(jnp.max(jnp.abs(got - ref))) <= 2e-4 * float(jnp.max(jnp.abs(ref))), jax.tree_util.keystr(path)
-    assert counters["moe_tokens_per_expert"].shape == (3, 8) and 0.0 < float(counters["ssm_decay_mean"]) < 1.0
+def _without(piece):
+    import torchft_tpu.models.moe as moe
+    import torchft_tpu.models.transformer as model
 
+    def how(cfg, weights):
+        changes = []
+        if piece == "decay":
+            real = ssd.ssd
+            changes = [(ssd, "ssd", lambda xdt, bm, cm, la, **kw: real(xdt, bm, cm, jnp.zeros_like(la), **kw))]
+        elif piece == "skip":
+            weights = dict(weights, mamba=dict(weights["mamba"], ssm_D=jnp.zeros_like(weights["mamba"]["ssm_D"])))
+        elif piece == "convolution":
+            changes = [(model, "_causal_conv", lambda z, taps: taps[-1] * z)]
+        elif piece == "gate":  # a gate that is always one: SiLU(1.2785) = 1
+            real_after = mamba._after
+            changes = [(mamba, "_after", lambda y, x, z, *a: real_after(y, x, jnp.full_like(z, 1.27846454), *a))]
+        elif piece == "one_norm_over_all_groups":
+            real_after = mamba._after
+            changes = [(mamba, "_after", lambda y, x, z, w, c, heads: real_after(
+                y, x, z, w, dataclasses.replace(c, ssm_groups=1), heads))]
+        elif piece == "square":
+            changes = [(moe.ACTIVATIONS, "relu2", jax.nn.relu)]
+        elif piece == "route_scale":
+            cfg = dataclasses.replace(cfg, moe_route_scale=1.0)
+        elif piece == "shared_expert":
+            cfg = dataclasses.replace(cfg, moe_shared_experts=0)
+        elif piece == "rotated_attention":
+            cfg = dataclasses.replace(cfg, pattern=tuple(
+                dataclasses.replace(kind, rotary_fraction=1.0) if kind.mixer == "attention" else kind for kind in cfg.pattern))
+        return patched(*changes), cfg, weights
 
-# -- a model without a piece is another model ----------------------------------------------
+    return Piece(piece, "program", how)
+
 
 PIECES = ("decay", "skip", "convolution", "gate", "one_norm_over_all_groups", "square", "route_scale", "shared_expert",
           "rotated_attention")
 
 
-def _without(piece, cfg, weights):
-    """(a patch to undo, the program's configuration, its weights) with one
-    piece of the mathematics left out of the PROGRAM."""
-    import torchft_tpu.models.moe as moe
-    from torchft_tpu.ops import ssd as ssd_module
-
-    patch = pytest.MonkeyPatch()
-    if piece == "decay":
-        real = ssd_module.ssd
-        patch.setattr(ssd_module, "ssd", lambda xdt, bm, cm, la, **kw: real(xdt, bm, cm, jnp.zeros_like(la), **kw))
-    elif piece == "skip":
-        weights = dict(weights, mamba=dict(weights["mamba"], ssm_D=jnp.zeros_like(weights["mamba"]["ssm_D"])))
-    elif piece == "convolution":
-        import torchft_tpu.models.transformer as model
-
-        patch.setattr(model, "_causal_conv", lambda z, taps: taps[-1] * z)
-    elif piece == "gate":  # a gate that is always one: SiLU(1.2785) = 1
-        real_after = mamba._after
-        patch.setattr(mamba, "_after", lambda y, x, z, *a: real_after(y, x, jnp.full_like(z, 1.27846454), *a))
-    elif piece == "one_norm_over_all_groups":
-        real_after = mamba._after
-        patch.setattr(mamba, "_after", lambda y, x, z, w, c, heads: real_after(
-            y, x, z, w, dataclasses.replace(c, ssm_groups=1), heads))
-    elif piece == "square":
-        patch.setitem(moe.ACTIVATIONS, "relu2", jax.nn.relu)
-    elif piece == "route_scale":
-        cfg = dataclasses.replace(cfg, moe_route_scale=1.0)
-    elif piece == "shared_expert":
-        cfg = dataclasses.replace(cfg, moe_shared_experts=0)
-    elif piece == "rotated_attention":
-        cfg = dataclasses.replace(cfg, pattern=tuple(
-            dataclasses.replace(kind, rotary_fraction=1.0) if kind.mixer == "attention" else kind for kind in cfg.pattern))
-    return patch, cfg, weights
+# -- the shares add up: 16 chips hold two of 32 experts each ---------------------------------------
 
 
-@pytest.mark.parametrize("piece", PIECES)
-def test_a_model_without_a_piece_is_another_model(piece) -> None:
-    """Each piece of the mathematics moves some leaf's gradient by more than a
-    hundred times the tolerance of the comparison above: the program without
-    the decay (a = 1), the D skip, the convolution's earlier taps, the gate
-    SiLU(z), the norm's groups (one norm over all 48 columns), the square, the
-    scale 2.5 or the shared expert, or with its attention block rotated, is not
-    the reference's model."""
-    weights, batch, _, want = _reference(2)
-    patch, cfg, weights_run = _without(piece, PROGRAM.transformer_config(CONFIG), weights)
-    try:
-        bias = jnp.asarray(PROGRAM.router_bias(CONFIG))
-        _, grads = jax.value_and_grad(lambda p: loss_and_counters(p, batch, cfg, router_bias=bias)[0])(weights_run)
-    finally:
-        patch.undo()
-    worst = max(float(jnp.max(jnp.abs(g - r))) / float(jnp.max(jnp.abs(r)))
-                for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(want)))
-    assert worst > 2e-2, (piece, worst)
+def _expert_layer() -> ExpertLayer:
+    hidden, ffn, experts, k = 32, 24, 32, 6
+    rng = np.random.default_rng(4)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)  # noqa: E731
+    w = dict(mlp_norm=jnp.ones((hidden,)), router=draw(hidden, experts), w_up=draw(experts, hidden, ffn),
+             w_down=draw(experts, ffn, hidden), shared_up=draw(hidden, 2 * ffn), shared_down=draw(2 * ffn, hidden))
+    bias = jnp.asarray(rng.standard_normal(experts) * 0.05, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((2, 24, hidden)), jnp.float32)
+    s = dict(experts=experts, held=experts, first=0, top_k=k, route_scale=2.5, eps=1e-5)
+
+    def normed(x):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-5)
+
+    def uncut(x):  # the reference's block adds the stream; the part is what is compared
+        return jnp.stack([REFERENCE._experts(seq, w, bias, s, "float32") - seq for seq in x]), None
+
+    def share(first, count, with_shared, x):
+        held = slice(first, first + count)
+        return moe_layer(normed(x), w["router"], None, w["w_up"][held], w["w_down"][held], top_k=k,
+                         capacity_factor=None, norm_topk=True, score="sigmoid", route_bias=bias, route_scale=2.5,
+                         held_first=first, shared=(None, w["shared_up"], w["shared_down"]) if with_shared else None,
+                         activation="relu2", dtype=jnp.float32)
+
+    return ExpertLayer((x,), experts, share, uncut, 2 * 24 * k, sin=5.0, grad_rtol=1e-3, shared=True)
+
+
+# -- the tree, the configuration, the adapter --------------------------------------------------
+
+
+def _tree_facts(cfg, ours) -> None:
+    """Three stacks of unequal leaf sets, a block ONE norm; the experts' leaves
+    keep the published 1,856 columns, `A_log`, `ssm_D` and `dt_bias` are float32
+    vectors of 64 a block, the taps [6,144, 4]; and the decay's initialisation is
+    the published one on both sides."""
+    assert [(s, k.mixer, k.feed_forward, k.sparse, n) for s, (k, n) in cfg.stacks.items()] == [
+        ("mamba", "mamba2", False, False, 4), ("moe", "none", True, True, 4), ("attn", "attention", False, False, 1)]
+    assert "".join({"mamba": "M", "moe": "E", "attn": "*"}[kind.stack] for kind in cfg.layers) == "MEMEM*EME"
+    theirs = jax.eval_shape(lambda: REFERENCE.make_weights(1, PUBLISHED))
+    assert [l.dtype for l in jax.tree.leaves(ours)] == [l.dtype for l in jax.tree.leaves(theirs)]
+    assert set(ours["mamba"]) == {"attn_norm", "ssm_in", "ssm_conv", "ssm_conv_bias", "dt_bias", "A_log", "ssm_D",
+                                  "ssm_norm", "ssm_out"}
+    assert set(ours["attn"]) == {"attn_norm", "wq", "wk", "wv", "wo"}
+    assert set(ours["moe"]) == {"mlp_norm", "router", "w_up", "w_down", "shared_up", "shared_down"}
+    assert ours["moe"]["w_up"].shape == (4, 8, 2688, 1856) and ours["moe"]["w_down"].shape == (4, 8, 1856, 2688)
+    assert ours["moe"]["shared_up"].shape == (4, 2688, 3712) and ours["moe"]["router"].shape == (4, 2688, 128)
+    assert ours["mamba"]["ssm_in"].shape == (4, 2688, 10304) and ours["mamba"]["ssm_conv"].shape == (4, 6144, 4)
+    assert all(ours["mamba"][name].shape == (4, 64) for name in ("A_log", "ssm_D", "dt_bias"))
+    assert ours["attn"]["wq"].shape == (1, 2688, 4096) and ours["attn"]["wk"].shape == (1, 2688, 256)
+    flops = BENCH.flops("mamba2_moe_lm")
+    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours)) == 666_962_944 == flops.total_params(PUBLISHED)
+    whole = dict(PUBLISHED, expert_parallel=None, **PUBLISHED["published"])
+    assert flops.total_params(whole) == 31_577_937_344  # the "30B": every weight has a key
+    small = PROGRAM.transformer_config(CONFIG)
+    for tree in (init_params(jax.random.PRNGKey(2), small), REFERENCE.make_weights(2, CONFIG)):
+        rate, steps = np.exp(np.asarray(tree["mamba"]["A_log"])), np.asarray(jax.nn.softplus(tree["mamba"]["dt_bias"]))
+        assert 1.0 <= rate.min() and rate.max() <= 16.0 and 0.001 * 0.999 <= steps.min() and steps.max() <= 0.1 * 1.001
+        assert np.all(np.asarray(tree["mamba"]["ssm_D"]) == 1.0) and not np.any(np.asarray(tree["mamba"]["ssm_conv_bias"]))
+
+
+def _published_facts(cfg, _) -> None:
+    assert (cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_layers, cfg.d_head) == (2688, 1856, 16384, 9, 128)
+    assert (cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_chunk) == (64, 8, 128, 4, 128)
+    assert [kind.n_heads for kind in cfg.pattern if kind.mixer == "mamba2"] == [64] * 4 and cfg.n_kv_heads == 2
+    assert all(kind.rotary_fraction == 0.0 and kind.window is None for kind in cfg.pattern)
+    assert (cfg.moe_experts, cfg.moe_top_k, cfg.moe_held, cfg.moe_route_scale, cfg.moe_shared_experts) == (128, 6, (0, 8), 2.5, 2)
+    assert cfg.moe_score == "sigmoid" and cfg.moe_norm_topk and cfg.moe_activation == "relu2" and cfg.moe_aux_coef == 0.0
+    assert cfg.rms_eps == 1e-5 and not cfg.tied_head and cfg.n_sparse_layers == 4
+    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 128)
+    # every number of the catalog's row under the same key, the three cuts listed, the departure said
+    assert PUBLISHED["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert PUBLISHED["published"] == {"num_hidden_layers": 52, "n_routed_experts": 128, "vocab_size": 131_072}
+    pattern = PUBLISHED["hybrid_override_pattern"]
+    assert len(pattern) == 52 and (pattern.count("M"), pattern.count("E"), pattern.count("*")) == (23, 23, 6)
+    assert "denoiser" in json.dumps(PUBLISHED["departures"]) and "not written" in json.dumps(PUBLISHED["departures"])
+    assert PUBLISHED["expert_parallel"]["chips"] == 16 and PUBLISHED["moe_intermediate_size"] == 1856
+    assert set(PROGRAM.kernel_names()) >= {"attn", "ce", "gmm", "ssd"} and PROGRAM.kernel_names()["ssd"]("x.tpuft_ssd_bwd.3")
+
+
+REFUSALS = [
+    ("n_group", dict(n_group=8), "one group"),
+    ("topk_group", dict(topk_group=4), "one group"),
+    ("tied_head", dict(tie_word_embeddings=True), "untied"),
+    ("sliding_window", dict(sliding_window=4096), "all of the past"),
+    ("residual_in_fp32", dict(residual_in_fp32=True), "compute type"),
+    ("time_step_ceiling", dict(time_step_limit=[0.0, 0.1]), "no clamp"),
+    ("time_step_floor", dict(time_step_limit=[0.001, None]), "no clamp"),
+    ("a_dense_block", dict(hybrid_override_pattern="MEM-M*EME" + "M" * 43), "pattern letter '-'"),
+    ("an_unknown_letter", dict(hybrid_override_pattern="MEMXM*EME" + "M" * 43), "pattern letter 'X'"),
+    ("a_short_pattern", dict(hybrid_override_pattern="MEM"), "3 letters for 5 blocks"),
+    ("no_conv_bias", dict(use_conv_bias=False), "convolution has a bias"),
+    ("a_projection_bias", dict(mamba_proj_bias=True), "no bias"),
+    ("silu_experts", dict(mlp_hidden_act="silu"), "ReLU\\^2"),
+    ("gates_not_renormalised", dict(norm_topk_prob=False), "renormalised"),
+    ("a_shared_width_of_40", dict(moe_shared_expert_intermediate_size=40), "whole number of expert widths"),
+    ("four_groups_for_six_heads", dict(n_groups=4), "whole number of heads"),
+]
+
+
+# -- the three-stack tree through ft_step, a heal's transport and the checkpoint -----------------
+
+
+def _tiny() -> Tiny:
+    def tree_facts(tree) -> None:
+        assert set(tree) == {"embed", "final_norm", "lm_head", "mamba", "attn", "moe"}
+        assert tree["mamba"]["A_log"].shape == (2, 6) and tree["mamba"]["A_log"].dtype == jnp.float32
+        assert tree["mamba"]["ssm_conv"].shape == (2, 112, 4) and "mlp_norm" not in tree["mamba"]
+
+    def facts(moved, summaries, step, after) -> None:
+        assert {"['embed']", "['mamba']['A_log']", "['mamba']['ssm_D']", "['mamba']['dt_bias']", "['mamba']['ssm_conv']",
+                "['mamba']['ssm_conv_bias']", "['attn']['wk']", "['moe']['router']", "['moe']['w_up']"} <= moved
+        summary = summaries[-1]
+        assert summary["moe_dropped"] == 0
+        assert 0 < summary["moe_rows_held"] < summary["moe_assignments"] == 2 * 2 * 2 * SEQ
+        assert 0.0 < summary["ssm_decay_mean"] < 1.0 and 0 < summary["moe_active_units"] < summary["moe_units_held"]
+
+    return tiny_of_the_small_model("mamba2_moe_lm", CONFIG, _batch(0), tree_facts, facts)
+
+
+ARCH = Architecture(
+    name="mamba2_moe_lm", configs={"share": CONFIG, "runs": RUNS}, sizes=SIZES, seq=SEQ, variants=dict(WALKS, as_published={}, **SCANS_OF_RUNS),
+    leaf_cases=[Case(f"{walk}-{stack}", "share", walk, 1, stack=stack) for walk in WALKS for stack in STACKS]
+    + [Case(f"{walk}-a_pattern_with_runs", "runs", walk, 3) for walk in SCANS_OF_RUNS],
+    # what differs is the order of sums — the chunk form against the recurrence position by position, the grouped experts
+    # against the masked loop: every leaf to 2e-4 of its largest entry; a missing term is 1e-2 or more (the pieces)
+    leaf_error="max", leaf_tolerance=2e-4, loss_tolerance=1e-6, off_start=True, counters=_counters, stacks=STACKS,
+    pieces=[_without(piece) for piece in PIECES], pieces_at=("share", 2), piece_floor=2e-2,
+    chips=[16, 4, 1], expert_layer=_expert_layer,
+    published="nemotron-twotower-30b-a3b", tree_facts=_tree_facts, published_facts=_published_facts,
+    refusals=REFUSALS, refusal_config="share", through=("ft_step", "heal", "disk_checkpoint"), tiny=_tiny,
+)
 
 
 # -- the scan: kernels, chunk form, loop -------------------------------------------------------
@@ -368,182 +416,6 @@ def test_the_whole_mixer_against_a_written_out_loop() -> None:
     np.testing.assert_allclose(float(decay), a.mean(), rtol=1e-5)
 
 
-# -- an odd width through the grouped matmuls ---------------------------------------------------
-
-
-@pytest.mark.parametrize("which", ["output", "dlhs", "drhs"])
-@pytest.mark.parametrize("product", ["up_at_14_and_a_half_tiles", "down_at_14_and_a_half_tiles"])
-def test_a_width_of_1856_takes_the_kernel_path_and_equals_ragged_dot(product, which, monkeypatch) -> None:
-    """1,856 = 14.5 x 128 columns: `grouped_matmul` pads both operands with
-    zeros to 1,920 inside the call and runs the `tpuft_gmm_*` kernels (interpret
-    mode here; the call is counted), the result and both gradients equal
-    `jax.lax.ragged_dot`'s on the leaves' own shapes."""
-    k, n = (256, 1856) if product.startswith("up") else (1856, 256)
-    rng = np.random.default_rng(7)
-    sizes = gmm.padded_group_sizes(jnp.asarray([100, 0, 300]), 128)             # 128 + 128 + 384 rows
-    lhs = jnp.asarray(rng.standard_normal((768, k)), jnp.float32)               # a tile past the last group
-    rhs = jnp.asarray(rng.standard_normal((3, k, n)) * k ** -0.5, jnp.float32)
-    calls = []
-    real = gmm._gmm
-    monkeypatch.setattr(gmm, "_gmm", lambda *a: calls.append(a[0].shape + a[1].shape) or real(*a))
-    weight = jnp.asarray(rng.standard_normal((768, n)), jnp.float32).at[640:].set(0.0)
-    with jax.default_matmul_precision("highest"):
-        run = lambda l, r: gmm.grouped_matmul(l, r, sizes, row_tile=128, interpret=True)   # noqa: E731
-        plain = lambda l, r: jax.lax.ragged_dot(l, r, sizes)                               # noqa: E731
-        if which == "output":
-            got, want = run(lhs, rhs), plain(lhs, rhs)
-            assert got.shape == (768, n)
-        else:
-            arg = 0 if which == "dlhs" else 1
-            got = jax.grad(lambda l, r: jnp.sum(run(l, r) * weight), argnums=arg)(lhs, rhs)
-            want = jax.grad(lambda l, r: jnp.sum(plain(l, r) * weight), argnums=arg)(lhs, rhs)
-            assert got.shape == (lhs, rhs)[arg].shape
-    assert calls and calls[0] == (768, -(-k // 128) * 128, 3, -(-k // 128) * 128, -(-n // 128) * 128)
-    np.testing.assert_allclose(np.asarray(got)[:640] if which != "drhs" else np.asarray(got),
-                               np.asarray(want)[:640] if which != "drhs" else np.asarray(want), rtol=2e-5, atol=2e-5)
-
-
-def test_a_shape_that_does_not_tile_is_said_once(monkeypatch, caplog) -> None:
-    """Where the caller promised tiles on one TPU device and the kernels do not
-    tile the product (rows that are no whole tiles), the fall to `ragged_dot`
-    is logged, once a shape."""
-    monkeypatch.setattr(gmm._pallas_util, "kernels_apply", lambda mesh=None: True)
-    gmm._say_once.cache_clear()
-    lhs, rhs = jnp.ones((200, 128)), jnp.ones((2, 128, 128))
-    sizes = jnp.asarray([128, 72], jnp.int32)
-    with caplog.at_level(logging.WARNING, logger=gmm.logger.name):
-        for _ in range(3):
-            out = gmm.grouped_matmul(lhs, rhs, sizes, row_tile=128)
-    assert out.shape == (200, 128)
-    said = [r for r in caplog.records if "does not tile" in r.getMessage()]
-    assert len(said) == 1 and "[200, 128] x [G, 128, 128]" in said[0].getMessage()
-
-
-# -- the shares add up -------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("chips", [16, 4, 1])
-def test_the_shares_add_up_to_the_uncut_block(chips) -> None:
-    """What every chip of an expert-parallel block computes of the routed
-    experts (16 chips: two of 32 experts each), summed over the chips, plus the
-    shared expert ONCE, is what the uncut plain reference gives for the whole
-    `E` block — values and the gradient of the input."""
-    hidden, ffn, experts, k = 32, 24, 32, 6
-    rng = np.random.default_rng(4)
-    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)  # noqa: E731
-    w = dict(mlp_norm=jnp.ones((hidden,)), router=draw(hidden, experts), w_up=draw(experts, hidden, ffn),
-             w_down=draw(experts, ffn, hidden), shared_up=draw(hidden, 2 * ffn), shared_down=draw(2 * ffn, hidden))
-    bias = jnp.asarray(rng.standard_normal(experts) * 0.05, jnp.float32)
-    x = jnp.asarray(rng.standard_normal((2, 24, hidden)), jnp.float32)
-    s = dict(experts=experts, held=experts, first=0, top_k=k, route_scale=2.5, eps=1e-5)
-    count = experts // chips
-
-    def normed(x):
-        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-5)
-
-    def uncut(x):  # the reference's block adds the stream; the part is what is compared
-        return jnp.stack([REFERENCE._experts(seq, w, bias, s, "float32") - seq for seq in x])
-
-    def share(x, first, with_shared):
-        held = slice(first, first + count)
-        y, stats = moe_layer(normed(x), w["router"], None, w["w_up"][held], w["w_down"][held], top_k=k,
-                             capacity_factor=None, norm_topk=True, score="sigmoid", route_bias=bias, route_scale=2.5,
-                             held_first=first, shared=(None, w["shared_up"], w["shared_down"]) if with_shared else None,
-                             activation="relu2", dtype=jnp.float32)
-        return y
-
-    def summed(x):  # the shared expert with the first share alone
-        return sum(share(x, r * count, with_shared=r == 0) for r in range(chips))
-
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(np.asarray(jax.jit(summed)(x)), np.asarray(jax.jit(uncut)(x)), rtol=1e-4, atol=1e-5)
-        dwant = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(5 * uncut(x)))))(x)
-        dgot = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(5 * summed(x)))))(x)
-        np.testing.assert_allclose(np.asarray(dgot), np.asarray(dwant), rtol=1e-3, atol=1e-5)
-
-
-# -- the tree, the configuration, the adapter --------------------------------------------------
-
-
-def test_the_tree_is_the_reference_s() -> None:
-    """`init_params` and `make_weights` give one tree at the published widths
-    — three stacks of unequal leaf sets, names and shapes, a block ONE norm —
-    and `param_axes` names every leaf; the experts' leaves keep the published
-    1,856 columns, `A_log`, `ssm_D` and `dt_bias` are float32 vectors of 64 a
-    block, the taps [6,144, 4]; and the decay's initialisation is the published
-    one on both sides."""
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    assert [(s, k.mixer, k.feed_forward, k.sparse, n) for s, (k, n) in cfg.stacks.items()] == [
-        ("mamba", "mamba2", False, False, 4), ("moe", "none", True, True, 4), ("attn", "attention", False, False, 1)]
-    assert "".join({"mamba": "M", "moe": "E", "attn": "*"}[kind.stack] for kind in cfg.layers) == "MEMEM*EME"
-    ours = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    theirs = jax.eval_shape(lambda: REFERENCE.make_weights(1, PUBLISHED))
-    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
-    assert [(l.shape, l.dtype) for l in jax.tree.leaves(ours)] == [(l.shape, l.dtype) for l in jax.tree.leaves(theirs)]
-    assert jax.tree.structure(ours) == jax.tree.structure(param_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    assert set(ours["mamba"]) == {"attn_norm", "ssm_in", "ssm_conv", "ssm_conv_bias", "dt_bias", "A_log", "ssm_D",
-                                  "ssm_norm", "ssm_out"}
-    assert set(ours["attn"]) == {"attn_norm", "wq", "wk", "wv", "wo"}
-    assert set(ours["moe"]) == {"mlp_norm", "router", "w_up", "w_down", "shared_up", "shared_down"}
-    assert ours["moe"]["w_up"].shape == (4, 8, 2688, 1856) and ours["moe"]["w_down"].shape == (4, 8, 1856, 2688)
-    assert ours["moe"]["shared_up"].shape == (4, 2688, 3712) and ours["moe"]["router"].shape == (4, 2688, 128)
-    assert ours["mamba"]["ssm_in"].shape == (4, 2688, 10304) and ours["mamba"]["ssm_conv"].shape == (4, 6144, 4)
-    assert all(ours["mamba"][name].shape == (4, 64) for name in ("A_log", "ssm_D", "dt_bias"))
-    assert ours["attn"]["wq"].shape == (1, 2688, 4096) and ours["attn"]["wk"].shape == (1, 2688, 256)
-    flops = BENCH.flops("mamba2_moe_lm")
-    assert sum(int(np.prod(l.shape)) for l in jax.tree.leaves(ours)) == 666_962_944 == flops.total_params(PUBLISHED)
-    whole = dict(PUBLISHED, expert_parallel=None, **PUBLISHED["published"])
-    assert flops.total_params(whole) == 31_577_937_344  # the "30B": every weight has a key
-    small = PROGRAM.transformer_config(CONFIG)
-    for tree in (init_params(jax.random.PRNGKey(2), small), REFERENCE.make_weights(2, CONFIG)):
-        rate, steps = np.exp(np.asarray(tree["mamba"]["A_log"])), np.asarray(jax.nn.softplus(tree["mamba"]["dt_bias"]))
-        assert 1.0 <= rate.min() and rate.max() <= 16.0 and 0.001 * 0.999 <= steps.min() and steps.max() <= 0.1 * 1.001
-        assert np.all(np.asarray(tree["mamba"]["ssm_D"]) == 1.0) and not np.any(np.asarray(tree["mamba"]["ssm_conv_bias"]))
-
-
-def test_the_published_configuration_is_handed_over_whole() -> None:
-    cfg = PROGRAM.transformer_config(PUBLISHED)
-    assert (cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_layers, cfg.d_head) == (2688, 1856, 16384, 9, 128)
-    assert (cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv, cfg.ssm_chunk) == (64, 8, 128, 4, 128)
-    assert [kind.n_heads for kind in cfg.pattern if kind.mixer == "mamba2"] == [64] * 4 and cfg.n_kv_heads == 2
-    assert all(kind.rotary_fraction == 0.0 and kind.window is None for kind in cfg.pattern)
-    assert (cfg.moe_experts, cfg.moe_top_k, cfg.moe_held, cfg.moe_route_scale, cfg.moe_shared_experts) == (128, 6, (0, 8), 2.5, 2)
-    assert cfg.moe_score == "sigmoid" and cfg.moe_norm_topk and cfg.moe_activation == "relu2" and cfg.moe_aux_coef == 0.0
-    assert cfg.rms_eps == 1e-5 and not cfg.tied_head and cfg.n_sparse_layers == 4
-    assert PROGRAM.router_bias(PUBLISHED).shape == (4, 128)
-    # every number of the catalog's row under the same key, the three cuts listed, the departure said
-    assert PUBLISHED["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    assert PUBLISHED["published"] == {"num_hidden_layers": 52, "n_routed_experts": 128, "vocab_size": 131_072}
-    pattern = PUBLISHED["hybrid_override_pattern"]
-    assert len(pattern) == 52 and (pattern.count("M"), pattern.count("E"), pattern.count("*")) == (23, 23, 6)
-    assert "denoiser" in json.dumps(PUBLISHED["departures"]) and "not written" in json.dumps(PUBLISHED["departures"])
-    assert PUBLISHED["expert_parallel"]["chips"] == 16 and PUBLISHED["moe_intermediate_size"] == 1856
-    assert set(PROGRAM.kernel_names()) >= {"attn", "ce", "gmm", "ssd"} and PROGRAM.kernel_names()["ssd"]("x.tpuft_ssd_bwd.3")
-
-
-@pytest.mark.parametrize("change,message", [
-    (dict(n_group=8), "one group"),
-    (dict(topk_group=4), "one group"),
-    (dict(tie_word_embeddings=True), "untied"),
-    (dict(sliding_window=4096), "all of the past"),
-    (dict(residual_in_fp32=True), "compute type"),
-    (dict(time_step_limit=[0.0, 0.1]), "no clamp"),
-    (dict(time_step_limit=[0.001, None]), "no clamp"),
-    (dict(hybrid_override_pattern="MEM-M*EME" + "M" * 43), "pattern letter '-'"),
-    (dict(hybrid_override_pattern="MEMXM*EME" + "M" * 43), "pattern letter 'X'"),
-    (dict(hybrid_override_pattern="MEMEM"), "5 letters for 9 blocks"),
-    (dict(use_conv_bias=False), "convolution has a bias"),
-    (dict(mamba_proj_bias=True), "no bias"),
-    (dict(mlp_hidden_act="silu"), "ReLU\\^2"),
-    (dict(norm_topk_prob=False), "renormalised"),
-    (dict(moe_shared_expert_intermediate_size=40), "whole number of expert widths"),
-    (dict(n_groups=4), "whole number of heads"),
-])
-def test_the_adapter_raises_on_what_it_does_not_honour(change, message) -> None:
-    with pytest.raises(ValueError, match=message):
-        PROGRAM.transformer_config(dict(CONFIG, **change))
-
-
 def test_a_block_is_a_mixer_a_feed_forward_or_both() -> None:
     """The kinds the pattern may hold: a kind without a mixer AND without a
     feed-forward is refused, as is a sparse kind without a feed-forward; the
@@ -561,79 +433,3 @@ def test_a_block_is_a_mixer_a_feed_forward_or_both() -> None:
     loss, _ = loss_and_counters(params, _batch(0, vocab=32, seq_len=8), dense)
     assert np.isfinite(float(loss))
 
-
-# -- the three-stack tree through ft_step, a heal's transport and the checkpoint -----------------
-
-
-def _records(path, event):
-    with open(path, encoding="utf-8") as f:
-        return [r for r in map(json.loads, f) if r.get("event") == event]
-
-
-TINY = dataclasses.replace(PROGRAM.transformer_config(CONFIG), remat=True, remat_keeps_attention=True, scan_unroll=1)
-
-
-@pytest.mark.parametrize("through", ["ft_step", "heal", "disk_checkpoint"])
-def test_a_tree_of_three_unequal_stacks_goes_through(through, store, tmp_path, monkeypatch) -> None:  # noqa: F811
-    params = init_params(jax.random.PRNGKey(5), TINY)
-    assert set(params) == {"embed", "final_norm", "lm_head", "mamba", "attn", "moe"}
-    assert params["mamba"]["A_log"].shape == (4, 6) and params["mamba"]["A_log"].dtype == jnp.float32
-    assert params["mamba"]["ssm_conv"].shape == (4, 112, 4) and "mlp_norm" not in params["mamba"]
-    leaves = jax.tree.leaves(params)
-    if through == "ft_step":
-        path = tmp_path / "stream.jsonl"
-        monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
-        client = MagicMock()
-        client._quorum.return_value = make_quorum()
-        client.should_commit.return_value = True
-        manager, _, _ = make_manager(store, client_mock=client)
-        ftmesh = ft_init_mesh({"data": 1}, devices=jax.devices()[:1])
-        ftmesh.manager = manager
-        bias = jnp.asarray(PROGRAM.router_bias(CONFIG))
-        step = TrainStep(ftmesh, optax.adamw(1e-3), lambda p, b: loss_and_counters(p, b, TINY, router_bias=bias),
-                         loss_has_counters=True, overlap_commit=False)
-        opt = step.init_opt_state(params)
-        batch = _batch(0)
-        before = jax.tree.map(np.asarray, params)  # `ft_step` donates its arguments
-        try:
-            for _ in range(2):
-                manager.start_quorum()
-                params, opt, loss, committed = step.ft_step(params, opt, batch)
-                assert committed and np.isfinite(float(loss))
-        finally:
-            manager.shutdown()
-        assert jax.tree.structure(params) == jax.tree.structure(before)
-        moved = {jax.tree_util.keystr(p) for (p, a), b in zip(jax.tree_util.tree_leaves_with_path(params),
-                                                              jax.tree.leaves(before)) if not np.array_equal(np.asarray(a), b)}
-        assert {"['embed']", "['mamba']['A_log']", "['mamba']['ssm_D']", "['mamba']['dt_bias']", "['mamba']['ssm_conv']",
-                "['mamba']['ssm_conv_bias']", "['attn']['wk']", "['moe']['router']", "['moe']['w_up']"} <= moved
-        summary = _records(path, "step_summary")[-1]
-        assert summary["moe_dropped"] == 0 and 0 < summary["moe_rows_held"] < summary["moe_assignments"] == 4 * 2 * 2 * SEQ
-        assert 0.0 < summary["ssm_decay_mean"] < 1.0 and 0 < summary["moe_active_units"] < summary["moe_units_held"]
-    elif through == "heal":
-        from torchft_tpu.checkpointing.http_transport import HTTPTransport
-
-        donor, healer = HTTPTransport(timeout=30.0), HTTPTransport(timeout=30.0)
-        try:
-            donor.send_checkpoint([1], 7, {"params": params}, 30.0)
-            back = healer.recv_checkpoint(0, donor.metadata(), 7, 30.0)["params"]
-        finally:
-            donor.shutdown()
-            healer.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))
-    else:
-        from torchft_tpu.checkpointing.disk import DiskCheckpointer
-        from torchft_tpu.ddp import plan_buckets
-
-        buckets = plan_buckets([(l.shape, l.dtype) for l in leaves], 1 << 14)
-        assert sorted(i for b in buckets for i in b.indices) == list(range(len(leaves))) and len(buckets) > 2
-        ckpt = DiskCheckpointer(str(tmp_path))
-        try:
-            ckpt.save(4, {"params": params})
-            ckpt.wait()
-            back = ckpt.restore(4)["params"]
-        finally:
-            ckpt.shutdown()
-        assert jax.tree.structure(back) == jax.tree.structure(params)
-        assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))

@@ -1,50 +1,36 @@
-"""Moonlight-shaped models (DeepSeek-V3 family) through the program, on the
+"""Moonlight-shaped models (DeepSeek-V3 family: latent attention, a leading
+dense layer, the bias-corrected sigmoid router, a shared expert, one chip's
+share of the routed experts on the dropless path) through the program, on the
 CPU at small sizes.
 
-The program (``models/transformer.py`` with latent attention, a leading dense
-layer, the bias-corrected sigmoid router, a shared expert and one chip's
-share of the routed experts on the dropless path) against the benchmark's
-plain float32 reference (``benchmark/reference/mla_moe_lm.py``, which shares
-no code with it) on seeded random weights; the attention kernels in interpret
-mode at unequal head widths; the shares of an expert-parallel layer against
-the uncut layer; the router against a hand-written one on ties; and the
-two-kind parameter tree through the bucket plan, the disk checkpoint and
-``TrainStep``'s counters.
+`ARCH` is the architecture's entry in the suite (`tests/architectures.py`, which
+holds the tests every architecture is held to against the benchmark's plain
+float32 reference, ``benchmark/reference/mla_moe_lm.py``).  What only this
+architecture has is tested here: the router against a hand-written one on ties,
+the dropless path's row moves against their plain statements (the attention
+kernels at its unequal head widths: `tests/test_attention_unequal_widths.py`).
 """
-
-import dataclasses
-import functools
-import json
-import os
-import sys
-from unittest.mock import MagicMock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from test_manager import make_manager, make_quorum, store  # noqa: F401
+from architectures import (  # noqa: F401 — the shared tests this entry has fields for, and their fixture
+    BENCH, HELD, REMAT, Architecture, ExpertLayer, Tiny, batches, equations, in_the_scan, omission_cases, pytest_generate_tests, store,
+    test_loss_and_every_gradient_leaf_against_the_plain_reference,
+    test_rematerialised_layers_give_the_gradients_of_the_stored_ones, test_the_shares_add_up_to_the_uncut_layer,
+    test_the_tree_goes_through, test_the_tree_is_the_reference_s)
+from torchft_tpu.models.moe import _dropless_ffn, _take_rows, _tokens_of_rows, held_rows, moe_layer, route
+from torchft_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, padded_group_sizes
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.spec import Benchmark  # noqa: E402
-from torchft_tpu.models import init_params  # noqa: E402
-from torchft_tpu.models.moe import _dropless_ffn, _take_rows, _tokens_of_rows, held_rows, moe_layer, route  # noqa: E402
-from torchft_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, padded_group_sizes  # noqa: E402
-from torchft_tpu.models.transformer import loss_and_counters, param_axes  # noqa: E402
-from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
-
-BENCH = Benchmark(ROOT)
 REFERENCE = BENCH.reference("mla_moe_lm")
 PROGRAM = BENCH.program("mla_moe_lm")
 
-# One dense and two sparse layers of Moonlight's shape, float32 throughout:
-# 4 heads of 32 + 16 | 32, a latent of 64, 8 routed experts, 2 a token, one
-# shared expert of twice their width.
+SEQ = 128
+SIZES = """128 positions, the small model's whole `max_position_embeddings`.  One dense and two sparse layers: the
+leading dense stack and a sparse stack of more than one layer, the least with both.  4 heads of 32 + 16 | 32 over a
+latent of 64, 8 routed experts, 2 a token, one shared expert of twice their width.  Float32 throughout."""
 CONFIG = dict(
     architecture="mla_moe_lm", vocab_size=384, hidden_size=128, num_hidden_layers=3, first_k_dense_replace=1,
     num_attention_heads=4, num_key_value_heads=4, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
@@ -59,34 +45,16 @@ CONFIG = dict(
 # The same model as one of the four chips that share each layer holds it:
 # experts 2 and 3 of the router's 8.
 SHARE = dict(CONFIG, n_routed_experts=2, expert_parallel=dict(chips=4, rank=1, router_outputs=8, first_expert_held=2))
-# Both sides compute in float32 on the CPU, so they differ by the order of
-# their sums alone: every leaf agrees to under 1e-5 of its norm.  The least
-# of the named omissions moves its leaf by far more (the balance loss, on the
-# router: 1e-3), so 3e-5 passes the one and fails the others.
-LEAF_TOLERANCE = 3e-5
-LOSS_TOLERANCE = 1e-6
 
 
-def _batch(seed: int, config=CONFIG, sequences: int = 2, seq_len: int = 128):
-    tokens = np.random.default_rng(seed).integers(0, config["vocab_size"], size=(sequences, seq_len)).astype(np.int32)
-    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
-
-
-def _worst_leaf(grads, want):
-    worst = ("", 0.0)
-    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want)):
-        got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
-        rel = float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
-        if rel > worst[1]:
-            worst = (jax.tree_util.keystr(path), rel)
-    return worst
+_batch = batches(CONFIG["vocab_size"], SEQ)
 
 
 # What the program would compute with one part of the published mathematics
 # left out: each has to fail the comparison that the whole passes.
 OMISSIONS = {
     "as_published": {},
-    "without_the_choice_bias": {"bias": None},
+    "without_the_choice_bias": {"router_bias": None},
     "without_the_scaling_factor": {"moe_route_scale": 1.0},
     "top_k_not_renormalised": {"moe_norm_topk": False},
     "without_the_balance_loss": {"moe_aux_coef": 0.0},
@@ -94,161 +62,24 @@ OMISSIONS = {
 }
 
 
-@pytest.mark.parametrize("config", [CONFIG, SHARE], ids=["every_expert_held", "a_share_of_the_experts"])
-@pytest.mark.parametrize("omission", list(OMISSIONS))
-def test_loss_and_every_gradient_leaf_against_the_plain_reference(omission, config) -> None:
-    seed = 11
-    changed = dict(OMISSIONS[omission])
-    bias = changed.pop("bias", jnp.asarray(REFERENCE.router_bias(config)))
-    cfg = dataclasses.replace(PROGRAM.transformer_config(config), **changed)
-    weights = REFERENCE.make_weights(seed, config)
-    if omission == "the_fixed_epsilon":
-        # At unit-scale activations 1e-5 against 1e-6 is 5e-6 relative: seen
-        # only where the norm's input is small, as after a shrunken embedding.
-        weights = dict(weights, embed=weights["embed"] * 0.02)
-    batch = _batch(seed)
-    (loss, counters), grads = jax.jit(jax.value_and_grad(
-        lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias), has_aux=True))(weights, batch)
-    want_loss, want = REFERENCE.loss_and_grads(weights, batch["tokens"], batch["targets"], config)
-    leaf, rel = _worst_leaf(grads, want)
-    loss_rel = abs(float(loss) - float(want_loss)) / float(want_loss)
-    if omission == "as_published":
-        assert rel < LEAF_TOLERANCE and loss_rel < LOSS_TOLERANCE, (leaf, rel, loss_rel)
-        assert jax.tree.structure(grads) == jax.tree.structure(weights)
-        assert int(counters["moe_dropped"]) == 0
-        assert np.asarray(counters["moe_tokens_per_expert"]).sum(axis=1).tolist() == [2 * 128 * 2] * 2
-    else:
-        assert rel > 3 * LEAF_TOLERANCE, f"{omission}: the comparison did not see it ({leaf} {rel}, loss {loss_rel})"
+def _weights(weights, variant):
+    # At unit-scale activations 1e-5 against 1e-6 is 5e-6 relative: seen
+    # only where the norm's input is small, as after a shrunken embedding.
+    return dict(weights, embed=weights["embed"] * 0.02) if variant == "the_fixed_epsilon" else weights
 
 
-@pytest.mark.parametrize("keeps_attention", [False, True], ids=["remat", "remat_that_keeps_attention"])
-def test_rematerialised_layers_give_the_gradients_of_the_stored_ones(keeps_attention) -> None:
-    """`remat`, with and without the policy that keeps each layer's attention
-    output and row statistics: what is recomputed is not computed differently."""
-    cfg = PROGRAM.transformer_config(SHARE)
-    bias = jnp.asarray(REFERENCE.router_bias(SHARE))
-    weights, batch = REFERENCE.make_weights(4, SHARE), _batch(4)
-
-    def grads(cfg):
-        return jax.jit(jax.value_and_grad(lambda p, b: loss_and_counters(p, b, cfg, router_bias=bias)[0]))(weights, batch)
-
-    loss, stored = grads(cfg)
-    again_loss, again = grads(dataclasses.replace(cfg, remat=True, remat_keeps_attention=keeps_attention))
-    assert float(again_loss) == float(loss)
-    leaf, rel = _worst_leaf(again, stored)
-    assert rel < 1e-6, (leaf, rel)
+def _counters(counters, config) -> None:
+    assert int(counters["moe_dropped"]) == 0
+    assert np.asarray(counters["moe_tokens_per_expert"]).sum(axis=1).tolist() == [2 * SEQ * 2] * 2
 
 
-def test_the_tree_has_leaves_of_two_kinds_and_no_bias() -> None:
+def _tree_facts(cfg, own) -> None:
     """The leading dense layer is stacked apart from the sparse ones; the
-    program's own initialiser gives the tree the reference's weights have,
-    shape for shape; the router's bias is a leaf of neither."""
-    cfg = PROGRAM.transformer_config(SHARE)
-    own = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    made = jax.eval_shape(lambda: REFERENCE.make_weights(1, SHARE))
-    assert jax.tree.structure(own) == jax.tree.structure(made)
-    assert [a.shape for a in jax.tree.leaves(own)] == [a.shape for a in jax.tree.leaves(made)]
+    router's bias is a leaf of neither."""
     assert own["dense_layers"]["w_gate"].shape == (1, 128, 256)
     assert own["layers"]["w_gate"].shape == (2, 2, 128, 64) and own["layers"]["router"].shape == (2, 128, 8)
     assert own["layers"]["shared_up"].shape == (2, 128, 128) and own["layers"]["wkv_a"].shape == (2, 128, 64 + 16)
     assert not any("bias" in jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(own))
-    axes = param_axes(cfg)
-    assert jax.tree.structure(jax.tree.map(lambda _: 0, axes, is_leaf=lambda x: isinstance(x, tuple))) == \
-        jax.tree.structure(own)
-
-
-# -- attention at unequal head widths ------------------------------------------
-
-
-@pytest.mark.parametrize("seq,two_pass", [(1024, False), (2560, False), (1024, True), (2560, True)],
-                         ids=["one_pass_2_blocks", "one_pass_5_blocks", "two_pass_2_blocks", "two_pass_5_blocks"])
-def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass, monkeypatch) -> None:
-    """Query and key 256 wide (MLA's 192 padded to a lane multiple with zero
-    columns), value 128: the kernels against the XLA formulation at the
-    TRUE width of 192, forward and backward — the one-pass backward that
-    every such row short of 32,768 positions takes, and the two-pass form
-    with the row's VMEM budget cut under it."""
-    from test_ops import ONE_PASS, TWO_PASS, pallas_call_names
-    from torchft_tpu.ops import attention as fa
-
-    if two_pass:
-        monkeypatch.setattr(fa, "_DQ_ROW_VMEM_BUDGET", seq * 256 * 4 - 1)
-    assert fa._dq_row_resident(seq, 256) != two_pass
-    k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seq), 4)
-    q, k = (jax.random.normal(kk, (2, seq, 192), jnp.float32) for kk in (k0, k1))
-    v, g = (jax.random.normal(kk, (2, seq, 128), jnp.float32) for kk in (k2, k3))
-    scale = 192 ** -0.5
-    pad = [(0, 0), (0, 0), (0, 64)]
-    qp, kp = jnp.pad(q, pad), jnp.pad(k, pad)
-    want_o, want_lse = fa._fa_reference(q, k, v, scale, True)
-    got_o, got_lse = fa._fa_pallas_call(qp, kp, v, scale, True, interpret=True)
-    assert got_o.shape == (2, seq, 128)
-    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=1e-5, atol=1e-5)
-    want = fa._fa_bwd_xla(q, k, v, want_o, want_lse, g, scale, True)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True)
-    assert pallas_call_names(bwd, qp, kp, v, got_o, got_lse, g) == (TWO_PASS if two_pass else ONE_PASS)
-    got = bwd(qp, kp, v, got_o, got_lse, g)
-    assert [a.shape for a in got] == [(2, seq, 256), (2, seq, 256), (2, seq, 128)]
-    for name, a, b in zip(("dq", "dk", "dv"), got, want):
-        a = np.asarray(a)
-        if name != "dv":
-            assert not a[..., 192:].any(), f"{name}: the padding columns carry a gradient"
-            a = a[..., :192]
-        np.testing.assert_allclose(a, np.asarray(b), rtol=2e-3, atol=2e-4, err_msg=name)
-
-
-@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
-@pytest.mark.parametrize("kv_group", [1, 8])
-@pytest.mark.parametrize("n", [1, 2, 3, 5])
-def test_the_kernels_walk_the_lower_triangle_at_unequal_widths(n, kv_group, masked) -> None:
-    """Query and key 256 wide, value 128, n tiles a side: a grid step for
-    each tile of the lower triangle, out, lse, dq, dk and dv the XLA
-    formulation's (`test_ops.check_the_triangular_walk`)."""
-    from test_ops import check_the_triangular_walk
-
-    check_the_triangular_walk(n, 256, 128, kv_group, masked)
-
-
-@pytest.mark.parametrize("program", ["dense_lm", "moe_lm", "mla_moe_lm"])
-def test_the_one_pass_backward_is_booked_to_attention_by_its_name(program) -> None:
-    """The benchmark attributes device time to attention by substring and
-    `chip_smoke.has_kernel` by whole word: the one-pass kernel's name has to
-    stay inside the first and is a name of its own to the second, and no
-    `tpuft_fa_bwd_dq` is found in it (its absence from a trace is the
-    evidence that the one-pass form ran)."""
-    import chip_smoke
-
-    op = "%tpuft_fa_bwd_dkdv_dq.7 = (bf16[32,8192,256]) custom-call(...), custom_call_target=\"tpu_custom_call\""
-    assert BENCH.program(program).kernel_names()["attn"](op)
-    assert "tpuft_fa_bwd_dq" not in op
-    assert chip_smoke.has_kernel(op, "tpuft_fa_bwd_dkdv_dq") and "tpuft_fa_bwd_dkdv_dq" in chip_smoke.KERNELS
-    assert not chip_smoke.has_kernel(op, "tpuft_fa_bwd_dkdv") and not chip_smoke.has_kernel(op, "tpuft_fa_bwd_dq")
-
-
-def test_flash_attention_takes_a_value_width_of_its_own() -> None:
-    """The public entry point off the TPU: [B, H, S, 48] queries and keys,
-    [B, H, S, 32] values, the scale from the query's width, gradients of the
-    operands' own shapes."""
-    from torchft_tpu.ops import flash_attention
-
-    keys = jax.random.split(jax.random.PRNGKey(3), 3)
-    q, k = (jax.random.normal(kk, (2, 4, 64, 48), jnp.float32) for kk in keys[:2])
-    v = jax.random.normal(keys[2], (2, 4, 64, 32), jnp.float32)
-
-    def plain(q, k, v):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 48 ** -0.5
-        s = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), s, -jnp.inf)
-        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
-
-    out = flash_attention(q, k, v)
-    assert out.shape == (2, 4, 64, 32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)), rtol=1e-5, atol=1e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash_attention(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(got, want):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
 
 # -- the router ------------------------------------------------------------------
@@ -301,17 +132,8 @@ def _gathered_route(x, router, k, bias, scale):
     return gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20) * scale, idx
 
 
-def _primitives(jaxpr, found=None) -> set:
-    """Every primitive of a jaxpr and of the jaxprs inside its equations."""
-    found = set() if found is None else found
-    for eqn in jaxpr.eqns:
-        found.add(eqn.primitive.name)
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)  # a ClosedJaxpr holds one
-                if hasattr(inner, "eqns"):
-                    _primitives(inner, found)
-    return found
+def _primitives(jaxpr) -> set:
+    return {eqn.primitive.name for eqn in equations(jaxpr)}
 
 
 @pytest.mark.parametrize("with_bias", [False, True], ids=["no_bias", "bias"])
@@ -352,7 +174,6 @@ def test_sigmoid_gates_are_picked_by_comparison_and_equal_the_gathered_ones(with
 
 # -- one chip's share of an expert-parallel layer ---------------------------------
 
-
 def _layer_inputs(seed=7, tokens=96, hidden=128, inner=64, n_exp=8):
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     normal = lambda k, shape, fan: jax.random.normal(k, shape, jnp.float32) * fan ** -0.5  # noqa: E731
@@ -373,41 +194,18 @@ def _share(x, w, bias, first, count, shared=False, **kwargs):
         shared=(w["shared_gate"], w["shared_up"], w["shared_down"]) if shared else None, **kwargs)
 
 
-@pytest.mark.parametrize("chips", [8, 4, 2, 1])
-def test_the_shares_add_up_to_the_uncut_layer(chips) -> None:
-    """What every chip of an expert-parallel layer computes of the routed
-    experts, summed over the chips, plus the shared expert counted once, is
-    what the uncut plain reference gives for the whole layer — values and
-    the gradient of the input."""
+def _expert_layer() -> ExpertLayer:
     x, w, bias = _layer_inputs()
-    count = 8 // chips
     s = REFERENCE.sizes_of(dict(CONFIG, num_experts_per_tok=3))
     assert (s["held"], s["experts"], s["first"]) == (8, 8, 0)
 
+    def share(first, count, with_shared, x):
+        return _share(x, w, bias, first, count, shared=with_shared)
+
     def uncut(x):
-        ys = [REFERENCE._experts(seq, w, bias, s, "float32")[0] for seq in x]
-        return jnp.stack(ys)
+        return jnp.stack([REFERENCE._experts(seq, w, bias, s, "float32")[0] for seq in x]), None
 
-    def summed(x):
-        routed = sum(_share(x, w, bias, r * count, count)[0] for r in range(chips))
-        shared = (jax.nn.silu(x @ w["shared_gate"]) * (x @ w["shared_up"])) @ w["shared_down"]
-        return routed + shared
-
-    with jax.default_matmul_precision("highest"):
-        want, got = uncut(x), summed(x)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
-        dwant = jax.grad(lambda x: jnp.sum(jnp.sin(uncut(x))))(x)
-        dgot = jax.grad(lambda x: jnp.sum(jnp.sin(summed(x))))(x)
-        np.testing.assert_allclose(np.asarray(dgot), np.asarray(dwant), rtol=1e-4, atol=1e-5)
-    # a share with the shared expert is that share plus the shared expert
-    with_shared = _share(x, w, bias, 0, count, shared=True)[0]
-    alone = _share(x, w, bias, 0, count)[0]
-    assert float(jnp.max(jnp.abs(with_shared - alone))) > 0.1
-    # the counters: the shares' held rows are all the assignments, none dropped
-    stats = [_share(x, w, bias, r * count, count)[1] for r in range(chips)]
-    assert sum(int(st["rows_held"]) for st in stats) == int(stats[0]["assignments"]) == 96 * 3
-    assert all(int(st["dropped"]) == 0 for st in stats)
-    assert all(np.array_equal(st["tokens_per_expert"], stats[0]["tokens_per_expert"]) for st in stats)
+    return ExpertLayer((x,), 8, share, uncut, 96 * 3, shared=True)
 
 
 def test_a_share_whose_buffer_is_full_counts_what_it_drops() -> None:
@@ -415,8 +213,8 @@ def test_a_share_whose_buffer_is_full_counts_what_it_drops() -> None:
     experts beyond it are counted as dropped (those to experts held elsewhere
     never are), and what has a row is computed as before."""
     x, w, bias = _layer_inputs(tokens=1024)
-    _, full = _share(x, w, bias, 2, 2)
-    y, tight = _share(x, w, bias, 2, 2, held_rows_factor=0.1)
+    _, full = jax.jit(lambda x: _share(x, w, bias, 2, 2))(x)
+    y, tight = jax.jit(lambda x: _share(x, w, bias, 2, 2, held_rows_factor=0.1))(x)
     rows = held_rows(1024 * 3, 8, 2, 0.1)
     assert rows == 384 and held_rows(1024 * 3, 8, 8, 0.1) == 1024 * 3 + 8 * 128  # every expert held: no bound
     assert int(full["dropped"]) == 0 and int(tight["rows_held"]) == int(full["rows_held"]) > rows
@@ -571,61 +369,33 @@ def test_the_combines_gradients_against_a_plain_statement_of_it(case) -> None:
     np.testing.assert_array_equal(np.asarray(want_drows.astype(jnp.bfloat16), np.float32), np.asarray(got_drows, np.float32))
 
 
-# -- the two-kind tree through the exchange's plan, the checkpoint and TrainStep ---
+# -- the two-kind tree through TrainStep, the exchange's plan and the checkpoint ---
 
 
-def test_bucket_plan_and_disk_checkpoint_carry_both_kinds_of_layer(tmp_path) -> None:
-    from torchft_tpu.checkpointing.disk import DiskCheckpointer
-    from torchft_tpu.ddp import plan_buckets
+def _tiny() -> Tiny:
+    """`moe_rows_held` and `moe_assignments` ride the next step's summary
+    beside the counters every sparse model has, through the benchmark's own
+    programs file."""
+    def facts(moved, summaries, step, after) -> None:
+        for summary in summaries[1:]:
+            assert summary["moe_assignments"] == 2 * SEQ * 2 * 2 and summary["moe_dropped"] == 0
+            assert 0 < summary["moe_rows_held"] < summary["moe_assignments"]
+            assert summary["moe_tokens_per_expert_mean"] == 2 * SEQ * 2 / 8
+        # every expert held: the two counters of a share are not there
+        _, counters = jax.jit(PROGRAM.loss(CONFIG))(REFERENCE.make_weights(2, CONFIG), _batch(0))
+        assert set(counters) == {"moe_tokens_per_expert", "moe_dropped"}
 
-    weights = REFERENCE.make_weights(3, SHARE)
-    leaves = jax.tree.leaves(weights)
-    buckets = plan_buckets([(l.shape, l.dtype) for l in leaves], 1 << 18)
-    placed = sorted(i for b in buckets for i in b.indices)
-    assert placed == list(range(len(leaves))) and len(buckets) > 2
-    ckpt = DiskCheckpointer(str(tmp_path))
-    try:
-        ckpt.save(4, {"params": weights})
-        ckpt.wait()
-        back = ckpt.restore(4)["params"]
-    finally:
-        ckpt.shutdown()
-    assert jax.tree.structure(back) == jax.tree.structure(weights)
-    assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(jax.tree.leaves(back), leaves))
+    return Tiny(lambda: REFERENCE.make_weights(2, SHARE), PROGRAM.loss(SHARE), _batch, 3, facts)
 
 
-def _records(path, event):
-    with open(path, encoding="utf-8") as f:
-        return [r for r in map(json.loads, f) if r.get("event") == event]
-
-
-def test_held_rows_land_in_the_step_summary(store, tmp_path, monkeypatch) -> None:  # noqa: F811
-    """ft_steps of the share under a real Manager, through the benchmark's own
-    programs file: `moe_rows_held` and `moe_assignments` ride the next step's
-    summary beside the counters every sparse model has."""
-    path = tmp_path / "stream.jsonl"
-    monkeypatch.setenv("TPUFT_METRICS_PATH", str(path))
-    client = MagicMock()
-    client._quorum.return_value = make_quorum()
-    client.should_commit.return_value = True
-    manager, _, _ = make_manager(store, client_mock=client)
-    ftmesh = ft_init_mesh({"data": 1}, devices=jax.devices()[:1])
-    ftmesh.manager = manager
-    step = TrainStep(ftmesh, optax.adamw(1e-3), PROGRAM.loss(SHARE), loss_has_counters=True, overlap_commit=False)
-    params = REFERENCE.make_weights(2, SHARE)
-    opt = step.init_opt_state(params)
-    try:
-        for i in range(3):
-            manager.start_quorum()
-            params, opt, loss, committed = step.ft_step(params, opt, _batch(i))
-            assert committed and np.isfinite(float(loss))
-    finally:
-        manager.shutdown()
-    _, second, third = _records(path, "step_summary")
-    for summary in (second, third):
-        assert summary["moe_assignments"] == 2 * 128 * 2 * 2 and summary["moe_dropped"] == 0
-        assert 0 < summary["moe_rows_held"] < summary["moe_assignments"]
-        assert summary["moe_tokens_per_expert_mean"] == 2 * 128 * 2 / 8
-    # every expert held: the two counters of a share are not there
-    _, counters = jax.jit(PROGRAM.loss(CONFIG))(REFERENCE.make_weights(2, CONFIG), _batch(0))
-    assert set(counters) == {"moe_tokens_per_expert", "moe_dropped"}
+ARCH = Architecture(
+    name="mla_moe_lm", configs=dict(zip(HELD, (CONFIG, SHARE))), sizes=SIZES, seq=SEQ, variants=dict(in_the_scan(OMISSIONS), **REMAT),
+    leaf_cases=omission_cases(OMISSIONS, 11),
+    # Both sides compute in float32 on the CPU, so they differ by the order of their sums alone: every leaf agrees to
+    # under 1e-5 of its norm.  The least of the named omissions moves its leaf by far more (the balance loss, on the
+    # router: 1e-3), so 3e-5 passes the one and fails the others.
+    leaf_tolerance=3e-5, loss_tolerance=1e-6, weights=_weights, weights_vary=("the_fixed_epsilon",), counters=_counters,
+    remat=("a_share_of_the_experts", 4, tuple(REMAT)),
+    chips=[8, 4, 2, 1], expert_layer=_expert_layer, tree_config="a_share_of_the_experts", tree_facts=_tree_facts,
+    through=("ft_step", "disk_checkpoint"), tiny=_tiny,
+)
