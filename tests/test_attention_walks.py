@@ -62,7 +62,7 @@ def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
     # H heads a grid step, H read from the shapes: all of these heads (one KV head's, or a batch
     # entry's) forward; backward as many of them as their dq rows leave room for
-    tiles, share = n * (n + 1) // 2, fa._heads_share(heads, more.get("mask"), kv_group)
+    tiles, share = n * (n + 1) // 2, fa._heads_share(heads, more.get("mask"))
     fwd_heads = fa._heads_per_step(share)
     bwd_heads = fa._bwd_heads_per_step(share, fa._row_vmem_bytes(seq, d_qk, 4))
     assert fwd_heads == heads and bwd_heads > 1
@@ -174,19 +174,33 @@ def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
         jax.ShapeDtypeStruct((bh, seq + 512), jnp.float32), longer).values()} == {1}
 
 
-# (batch * heads, positions, query and key width, value width, window, query heads a KV head under a mask): the forward's
-# and the backward's heads a grid step
+def pallas_call_operands(fn, *args) -> dict:
+    """{name: the operands' shapes, the walk's tables left out} of every `pallas_call` in `fn`'s jaxpr, however deep."""
+    def calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"], [v.aval.shape for v in e.invars[e.params["grid_mapping"].num_index_operands:]]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    return dict(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+# (batch * heads, positions, query and key width, value width, window, query heads a KV head, under a packed mask): the
+# forward's and the backward's heads a grid step
 CELL_SHAPES = {
-    "dense_16_heads": ((32, 4096, 128, 128, None, None), (8, 4)),
-    "dense_32_heads": ((64, 4096, 128, 128, None, None), (8, 4)),
-    "moonlight": ((32, 8192, 256, 128, None, None), (8, 4)),
-    "keye_masked": ((32, 32768, 128, 128, None, 8), (8, 2)),
-    "laguna_full": ((48, 16384, 128, 128, None, None), (8, 4)),
-    "laguna_window": ((64, 16384, 128, 128, 512, None), (8, 4)),
-    "zaya": ((8, 16384, 128, 128, None, None), (8, 4)),
-    "kimi": ((32, 16384, 256, 128, None, None), (8, 2)),
-    "smallthinker_full": ((28, 16384, 128, 128, None, None), (7, 4)),
-    "smallthinker_window": ((28, 16384, 128, 128, 4096, None), (7, 4)),
+    "dense_16_heads": ((32, 4096, 128, 128, None, 2, False), (8, 4)),   # InternLM2: 16 / 8 heads
+    "olmoe": ((32, 4096, 128, 128, None, 1, False), (8, 4)),
+    "dense_32_heads": ((64, 4096, 128, 128, None, 4, False), (8, 4)),   # Mistral: 32 / 8
+    "moonlight": ((32, 8192, 256, 128, None, 1, False), (8, 4)),
+    "keye_masked": ((32, 32768, 128, 128, None, 8, True), (8, 2)),
+    "laguna_full": ((48, 16384, 128, 128, None, 6, False), (8, 4)),
+    "laguna_window": ((64, 16384, 128, 128, 512, 8, False), (8, 4)),
+    "zaya": ((8, 16384, 128, 128, None, 4, False), (8, 4)),
+    "kimi": ((32, 16384, 256, 128, None, 1, False), (8, 2)),
+    "smallthinker_full": ((28, 16384, 128, 128, None, 7, False), (7, 4)),
+    "smallthinker_window": ((28, 16384, 128, 128, 4096, 7, False), (7, 4)),
+    "nemotron": ((32, 16384, 128, 128, None, 16, False), (8, 4)),
 }
 
 
@@ -194,26 +208,102 @@ CELL_SHAPES = {
 def test_the_heads_a_grid_step_at_the_cells_shapes(cell) -> None:
     """The traced `pallas_call`s at every cell's attention shape (no kernel
     runs): grid (batch * heads / H, tiles) with more than one head a step in
-    both directions."""
+    both directions, and k and v given to the kernels with their own KV heads
+    — batch * heads / `kv_group` of them, no repeated copy — while dk and dv
+    leave a query head each."""
     from torchft_tpu.ops import attention as fa
 
-    (bh, seq, d, dv, window, kv_group), (fwd_heads, bwd_heads) = CELL_SHAPES[cell]
+    (bh, seq, d, dv, window, kv_group, masked), (fwd_heads, bwd_heads) = CELL_SHAPES[cell]
     n = seq // 512
     tiles = len(fa._Walk(True, seq, seq, 512, 512, window=window).tables[0])
     assert tiles == (n * (n + 1) // 2 if window is None else {512: 2 * n - 1, 4096: 252}[window])
     q = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16)
-    k = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, d), jnp.bfloat16)
-    v = jax.ShapeDtypeStruct((bh // (kv_group or 1), seq, dv), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((bh // kv_group, seq, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((bh // kv_group, seq, dv), jnp.bfloat16)
     o = jax.ShapeDtypeStruct((bh, seq, dv), jnp.bfloat16)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
-    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8) if kv_group else None
-    more = {"kv_group": kv_group} if kv_group else {"window": window}
-    family = "tpuft_dsa_attn" if kv_group else "tpuft_fa" if window is None else "tpuft_swa"
+    mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8) if masked else None
+    more = {"kv_group": kv_group, "window": window}
+    family = "tpuft_dsa_attn" if masked else "tpuft_fa" if window is None else "tpuft_swa"
     assert min(fwd_heads, bwd_heads) > 1
-    assert pallas_call_grids(lambda q_, k_, v_, m_: fa._fa_pallas_call(q_, k_, v_, 0.088, True, mask=m_, **more),
-                             q, k, v, mask) == {family + "_fwd": (bh // fwd_heads, tiles)}
-    assert pallas_call_grids(lambda q_, k_, v_, o_, l_, g_, m_: fa._fa_bwd_pallas(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more),
-                             q, k, v, o, lse, o, mask) == {family + "_bwd_dkdv_dq": (bh // bwd_heads, tiles)}
+    fwd = lambda q_, k_, v_, m_: fa._fa_pallas_call(q_, k_, v_, 0.088, True, mask=m_, **more)  # noqa: E731
+    bwd = lambda q_, k_, v_, o_, l_, g_, m_: fa._fa_bwd_pallas(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more)  # noqa: E731
+    assert pallas_call_grids(fwd, q, k, v, mask) == {family + "_fwd": (bh // fwd_heads, tiles)}
+    assert pallas_call_grids(bwd, q, k, v, o, lse, o, mask) == {family + "_bwd_dkdv_dq": (bh // bwd_heads, tiles)}
+    (operands,) = pallas_call_operands(fwd, q, k, v, mask).values()
+    assert operands[:3] == [q.shape, k.shape, v.shape]
+    (operands,) = pallas_call_operands(bwd, q, k, v, o, lse, o, mask).values()
+    assert operands[:3] == [q.shape, k.shape, v.shape]
+    dq, dk, dv_ = jax.eval_shape(bwd, q, k, v, o, lse, o, mask)
+    assert (dq.shape, dk.shape, dv_.shape) == (q.shape, (bh, seq, d), (bh, seq, dv))
+
+
+def test_flash_attention_gives_the_kernels_k_and_v_unrepeated(monkeypatch) -> None:
+    """`flash_attention` where the kernels run, through autodiff: no operand
+    of the forward or the backward `pallas_call` has batch * query heads
+    leading rows but q, the output and their gradients' — k and v go in with
+    batch * KV heads — and dk, dv come back in k's and v's shapes."""
+    from torchft_tpu.ops import attention as fa
+
+    monkeypatch.setattr(fa._pallas_util, "kernels_apply", lambda mesh=None: True)
+    b, hq, hkv, seq = 2, 14, 2, 1024
+    q = jax.ShapeDtypeStruct((b, hq, seq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, hkv, seq, 128), jnp.bfloat16)
+    for window, family in ((None, "tpuft_fa"), (600, "tpuft_swa")):
+        loss = lambda q_, k_, v_: jnp.sum(fa.flash_attention(q_, k_, v_, window=window).astype(jnp.float32))  # noqa: E731,B023
+        grads = jax.grad(loss, argnums=(0, 1, 2))
+        assert [a.shape for a in jax.eval_shape(grads, q, kv, kv)] == [q.shape, kv.shape, kv.shape]
+        found = pallas_call_operands(grads, q, kv, kv)
+        assert sorted(found) == [family + "_bwd_dkdv_dq", family + "_fwd"]
+        for operands in found.values():
+            assert operands[:3] == [(b * hq, seq, 128), (b * hkv, seq, 128), (b * hkv, seq, 128)]
+
+
+# query heads a KV head: (batch * heads, heads a step forward, backward) of an interpret-mode case whose steps hold whole
+# groups or lie inside one, and of one whose steps straddle two KV heads' groups
+IN_PLACE_CASES = {
+    "aligned": {2: (8, 8, 4), 4: (8, 8, 4), 6: (12, 6, 3), 7: (14, 7, 7), 16: (16, 8, 4)},
+    "straddling": {2: (6, 3, 3), 4: (12, 6, 3), 6: (24, 8, 4), 7: (28, 4, 4), 16: (48, 6, 6)},
+}
+
+
+@pytest.mark.parametrize("step", ["aligned", "straddling"])
+@pytest.mark.parametrize("kind", ["plain", "window"])
+@pytest.mark.parametrize("kv_group", [2, 4, 6, 7, 16])
+def test_grouped_queries_read_their_kv_head_in_place(kv_group, kind, step) -> None:
+    """The plain and the window kernels in interpret mode with k and v a KV
+    head each, against the same kernels at the same heads a step fed k and v
+    repeated to a head for every query head: out, lse, dq, dk and dv (a query
+    head each) bit for bit; `group_sum` of dk and dv is the float32 sum of a
+    group rounded once.  An aligned step holds whole groups (a group of 2 or
+    4 in 8 heads) or lies inside one; a straddling step's heads belong to two
+    KV heads, as four of smallthinker's group of seven do."""
+    from torchft_tpu.ops import attention as fa
+
+    bh, fwd_heads, bwd_heads = IN_PLACE_CASES[step][kv_group]
+    for heads in (fwd_heads, bwd_heads):
+        assert fa._straddles(heads, kv_group) == (step == "straddling"), heads
+    seq, window = 1024, 600 if kind == "window" else None
+    ks = jax.random.split(jax.random.PRNGKey(16 * kv_group + (window or 0)), 4)
+    q = jax.random.normal(ks[0], (bh, seq, 128), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (bh // kv_group, seq, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (bh // kv_group, seq, 128), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (bh, seq, 128), jnp.bfloat16)
+    k_all, v_all = jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0)
+    kw = dict(scale=0.088, causal=True, interpret=True, window=window)
+    o, lse = fa._fa_pallas_call(q, k, v, kv_group=kv_group, heads_per_step=fwd_heads, **kw)
+    want_o, want_lse = fa._fa_pallas_call(q, k_all, v_all, heads_per_step=fwd_heads, **kw)
+    assert bool(jnp.array_equal(o, want_o)) and bool(jnp.array_equal(lse, want_lse))
+    assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01
+    got = fa._fa_bwd_pallas(q, k, v, o, lse, g, kv_group=kv_group, heads_per_step=bwd_heads, **kw)
+    want = fa._fa_bwd_pallas(q, k_all, v_all, o, lse, g, heads_per_step=bwd_heads, **kw)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == (bh, seq, 128) and bool(jnp.array_equal(a, b)), name
+    for name, a in zip(("dk", "dv"), got[1:]):
+        summed = np.asarray(a, np.float32).reshape(bh // kv_group, kv_group, seq, 128).sum(axis=1)
+        folded = fa.group_sum(a, kv_group)
+        assert folded.shape == k.shape and folded.dtype == k.dtype
+        assert bool(jnp.array_equal(folded, jnp.asarray(summed).astype(jnp.bfloat16))), name
 
 
 @pytest.mark.parametrize("seq", [4096, 8192, 32768])

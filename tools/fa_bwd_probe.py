@@ -24,9 +24,14 @@ heads x n x n where not) and `us_per_tile`.  Where `grid_steps` times
 `heads_per_step` and the tiles differ the kernel issues steps that do
 nothing.  `--noncausal` shapes are read with `causal=False` as well (forward
 and the chosen backward), so that one tree gives the cost of an idle step: `u = T_noncausal / tiles`, `idle = (T_causal - visited * u) /
-(grid_steps - visited)`.  `--masked` shapes run the kernels under a packed
+(grid_steps - visited)`.  A shape's `@G`, else `--kv-group`, gives
+every form G query heads a KV head, k and v read in place (`28x16384x128@7`;
+8 under a mask and 1 elsewhere where neither is given), and such a line says
+in `bitwise_repeated` whether its results are bit for bit those of the same
+kernels fed k and v repeated in HBM (dk and dv a query head each).
+`--masked` shapes run the kernels under a packed
 int8 mask (`tpuft_dsa_attn_fwd`, `tpuft_dsa_attn_bwd_dkdv_dq`) with
-`--kv-group` query heads a KV head and a mask of the Keye cell's density:
+a mask of the Keye cell's density:
 every earlier key for a query before `--topk`, then `--topk` a query, spread
 evenly over its visible keys.  `--windowed` shapes run the band walk
 (`tpuft_swa_fwd`, `tpuft_swa_bwd_dkdv_dq`) under `--window`; their
@@ -46,7 +51,7 @@ refuses is a line with the error.
     python tools/fa_bwd_probe.py --bundles 28x16384x128 --heads-per-step 1,2,4     # no chip
 
 `--bundles` takes shapes (`:w` for the band walk under `--window`, `:m` for
-the packed mask at `--kv-group`), compiles the forward and the backward
+the packed mask; `@G` before either), compiles the forward and the backward
 kernel of each for a described v5e in a child process with
 `LIBTPU_INIT_ARGS=--xla_jf_dump_to`, and reads the compiler's schedule
 (`final_bundles`, one VLIW bundle a line, and its own count of each unit's
@@ -95,10 +100,16 @@ def grids(fn, *operands) -> list:
 
 
 def dims_of(spec: str) -> tuple:
-    """(batch * heads, positions, query and key width, value width) of `BHxSxDqk[/Dv]`."""
-    dims, _, dv = spec.partition("/")
+    """(batch * heads, positions, query and key width, value width) of `BHxSxDqk[/Dv][@G]`."""
+    dims, _, dv = spec.partition("@")[0].partition("/")
     bh, seq, d = (int(x) for x in dims.split("x"))
     return bh, seq, d, int(dv) if dv else d
+
+
+def group_of(spec: str, flag: int, masked: bool = False) -> int:
+    """Query heads a KV head of a shape: its own `@G`, else `--kv-group`,
+    else 8 under a mask (the Keye cell's) and 1 elsewhere."""
+    return int(spec.partition("@")[2] or flag or (8 if masked else 1))
 
 
 def even_mask_tile(i, j, tile: int, topk: int):
@@ -143,7 +154,7 @@ def bundles_child(spec: str, what: str, heads: int, dump: str, window: int, kv_g
 
     spec, _, kind = spec.partition(":")
     bh, seq, d, dv = dims_of(spec)
-    group = kv_group if kind == "m" else 1
+    group = group_of(spec, kv_group, kind == "m")
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     q, k, v, g = shaped((bh, seq, d)), shaped((bh // group, seq, d)), shaped((bh // group, seq, dv)), shaped((bh, seq, dv))
     more = {"heads_per_step": heads or None, "kv_group": group, "window": window if kind == "w" else None}
@@ -236,6 +247,7 @@ def bundles(args) -> int:
                         [sys.executable, os.path.abspath(__file__), "--bundles-child", spec, "--what", what,
                          "--heads-per-step", str(heads), "--dump", dump, "--window", str(args.window),
                          "--kv-group", str(args.kv_group)], capture_output=True, text=True, check=False)
+                    rec["kv_group"] = group_of(spec.partition(":")[0], args.kv_group, spec.endswith(":m"))
                     try:
                         rec.update(read_schedule(dump, kernel))
                     except (ValueError, IndexError, OSError) as e:  # no such file: the compile failed before the kernel
@@ -251,7 +263,8 @@ def main(argv=None) -> int:
     parser.add_argument("--masked", default="32x32768x128", help="shapes read under a packed mask of the Keye cell's density")
     parser.add_argument("--windowed", default="64x16384x128", help="shapes read under a window (the band walk)")
     parser.add_argument("--window", type=int, default=512)
-    parser.add_argument("--kv-group", type=int, default=8)
+    parser.add_argument("--kv-group", type=int, default=0, help="query heads a KV head, read in place, in every form "
+                        "(default: 8 under a mask, the Keye cell's, and 1 elsewhere)")
     parser.add_argument("--topk", type=int, default=2048)
     parser.add_argument("--reps", type=int, default=10)
     parser.add_argument("--seed", type=int, default=1)
@@ -313,7 +326,9 @@ def main(argv=None) -> int:
             pairs = bh * (seq * (seq + 1) / 2.0 if causal else float(seq) * seq)
         need_fwd = 2.0 * pairs * (d + dv)               # QK^T at d, PV at dv
         need_bwd = 2.0 * pairs * (2 * d + 2 * dv)       # dQ, dK at d; dV, dP at dv
-        more_kw = {} if mask is None else {"mask": mask, "kv_group": kv_group}
+        more_kw = {"kv_group": kv_group}
+        if mask is not None:
+            more_kw["mask"] = mask
         if window is not None:
             more_kw["window"] = window
         family = "tpuft_dsa_attn" if mask is not None else "tpuft_fa" if window is None else "tpuft_swa"
@@ -333,6 +348,17 @@ def main(argv=None) -> int:
             line(what, form, heads_per_step_asked=heads, error=f"{type(e).__name__}: {str(e)[:300]}")
 
         same = lambda got, want: all(bool(jnp.array_equal(a, b)) for a, b in zip(got, want))  # noqa: E731
+
+        def repeated(kernel, got, *others):
+            """`bitwise_repeated`: whether `kernel` (this reading's `kw`, but
+            one query head a KV head) given k and v repeated in HBM returns
+            `got`.  Every operand is an argument: one closed over would be a
+            constant of the program, for XLA to fold."""
+            if kv_group == 1:
+                return {}
+            fn = jax.jit(lambda *operands: kernel(*operands, scale, causal, **dict(kw, kv_group=1)))
+            return {"bitwise_repeated": same(fn(q, jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0), *others), got)}
+
         chosen = "one_pass" if fa._dq_row_resident(seq, d) else "two_pass"
         forms = [(chosen, fa._DQ_ROW_VMEM_BUDGET)] + ([("two_pass", 0)] if two_pass and chosen != "two_pass" else [])
         # one head a step first: what every other H's results are compared with, bit for bit
@@ -348,7 +374,8 @@ def main(argv=None) -> int:
                 continue
             one_head.setdefault("fwd", (o, lse))
             if timed_line:
-                record("fwd", family + "_fwd", ms, need_fwd, grids(fwd, q, k, v), bitwise_h1=same((o, lse), one_head["fwd"]))
+                record("fwd", family + "_fwd", ms, need_fwd, grids(fwd, q, k, v), bitwise_h1=same((o, lse), one_head["fwd"]),
+                       **repeated(fa._fa_pallas_call, (o, lse)))
             o, lse = one_head["fwd"]
             results = {}
             for form, budget in forms:
@@ -371,24 +398,26 @@ def main(argv=None) -> int:
                         float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))) / jnp.max(jnp.abs(b.astype(jnp.float32))))
                         for a, b in zip(results[chosen], results[form])
                     ]
+                if timed_line and form == chosen:
+                    more.update(repeated(fa._fa_bwd_pallas, results[form], o, lse, g))
                 if timed_line:
                     record("bwd", form, ms, need_bwd, grid, passes=2 if form == "two_pass" else 1, **more)
 
     for spec in filter(None, args.shapes.split(",")):
-        read(spec, "causal", two_pass=True)
+        read(spec, "causal", two_pass=True, kv_group=group_of(spec, args.kv_group))
     for spec in filter(None, args.noncausal.split(",")):
-        read(spec, "noncausal", causal=False)
+        read(spec, "noncausal", causal=False, kv_group=group_of(spec, args.kv_group))
     for spec in filter(None, args.masked.split(",")):
-        bh, seq = (int(x) for x in spec.split("x")[:2])
+        bh, seq = dims_of(spec)[:2]
         tile = fa._block_sizes(seq, seq)[0]
         rows, cols = zip(*[(i, j) for i in range(seq // tile) for j in range(i + 1)])  # `ops.attention._tri`'s order
         mask = jax.jit(jax.vmap(lambda i, j: even_mask_tile(i, j, tile, args.topk)))(
             jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32))[None]
         selected = int(jnp.sum(mask, dtype=jnp.int32))
-        read(spec, "masked", mask=mask, kv_group=args.kv_group, pairs=float(bh) * selected,
+        read(spec, "masked", mask=mask, kv_group=group_of(spec, args.kv_group, True), pairs=float(bh) * selected,
              selected_share=selected / (seq * (seq + 1) / 2.0))
     for spec in filter(None, args.windowed.split(",")):
-        read(spec, "windowed", window=args.window)
+        read(spec, "windowed", window=args.window, kv_group=group_of(spec, args.kv_group))
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "fa_bwd_probe.json"), "w", encoding="utf-8") as f:

@@ -31,6 +31,16 @@ Design notes (MXU/HBM-minded):
     them under `jax.vmap` (`_fwd_tile`, `_bwd_tile`, `_heads_at_once`); H is
     read from the shapes.  A head's arithmetic is what one head a step
     computes, bit for bit;
+  - grouped queries read their KV heads in place: k and v reach the kernels
+    with their own heads, [batch * kv_heads, S, d], in both directions, and
+    no array of batch * q_heads k or v heads exists in HBM.  A step's k / v
+    block follows its H heads and the group (`_kv_spec`): the one KV head
+    they share, the KV heads of the whole groups they are, or — H neither a
+    divisor nor a multiple of the group — the adjacent KV heads they
+    straddle, in one block placed by element, each head taking its own by a
+    scalar index (`_kv_heads`).  H does not depend on the group.  dk and dv
+    leave the kernel a query head each, and `group_sum` adds a group's in
+    float32;
   - grid layout: the reduction axis innermost — TPU executes the innermost
     grid dimension sequentially, which is what makes the VMEM scratch
     accumulator legal.  A call that is causal over one sequence (``causal``
@@ -276,18 +286,37 @@ _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
+def _straddles(heads: int, kv_group: int) -> bool:
+    """Whether a step of ``heads`` query heads straddles KV heads' groups of
+    ``kv_group``: it neither lies inside one group nor holds whole groups."""
+    return kv_group % heads != 0 and heads % kv_group != 0
+
+
+def _kv_span(heads: int, kv_group: int) -> int:
+    """How many adjacent KV heads the widest of such steps touches: two for
+    four heads of a group of seven, or eight of a group of six."""
+    return 1 + max((b * heads + heads - 1) // kv_group - b * heads // kv_group for b in range(kv_group))
+
+
+def _first_kv_head(b, bh: int, heads: int, kv_group: int, held: int):
+    """The first of the ``held`` adjacent KV heads in a straddling step b's
+    block: its first query head's, and no further than the array's last.
+    (`lax.div` and `lax.min` on the scalars, which are not negative: one
+    operation each where `//` and `jnp.minimum` trace to a dozen.)"""
+    return jax.lax.min(jax.lax.div(b * heads, kv_group), bh // kv_group - held)
+
+
 def _heads_per_step(share: int, most: int = HEADS_PER_STEP) -> int:
     """The largest divisor of ``share`` (`_heads_share`) not above ``most``:
     the heads of a grid step."""
     return max(h for h in range(1, min(share, most) + 1) if share % h == 0)
 
 
-def _heads_share(bh: int, mask, kv_group: int) -> int:
-    """What the heads of one grid step have to lie inside: a KV head's query
-    heads where k and v are read in place, a batch entry's heads under a
-    packed mask (its tile is one a step), else the call's batch * heads."""
-    if kv_group > 1:
-        return kv_group
+def _heads_share(bh: int, mask) -> int:
+    """What the heads of one grid step have to lie inside: a batch entry's
+    heads under a packed mask (its tile is one a step), else the call's
+    batch * heads.  Grouped queries set no limit of their own: a step reads
+    as many KV heads as its query heads belong to (`_kv_spec`)."""
     return bh if mask is None else bh // mask.shape[0]
 
 
@@ -363,19 +392,32 @@ def _keep(walk: _Walk, qi, ki, mask_ref):
     return None
 
 
-def _kv_heads(ref, heads: int):
-    """A step's k or v block with a head each: grouped queries that read their
-    KV head in place have one block for the step."""
+def _kv_heads(ref, heads: int, kv_group: int):
+    """A step's k or v with a head each, from the step's block of it
+    (`_kv_spec`).  Grouped queries read their KV heads in place, so the block
+    holds one head for the whole step, one for each group of the step's
+    heads, or, where the step straddles groups, `_kv_span` adjacent KV heads
+    of which every head takes its own by a scalar index."""
+    from jax.experimental import pallas as pl
+
+    held = ref.shape[0]
+    if _straddles(heads, kv_group):
+        b = pl.program_id(0)
+        first = _first_kv_head(b, pl.num_programs(0) * heads, heads, kv_group, held)
+        return jnp.concatenate([ref[pl.ds(jax.lax.div(b * heads + h, kv_group) - first, 1)] for h in range(heads)], axis=0)
     x = ref[...]
-    return x if x.shape[0] == heads else jnp.broadcast_to(x, (heads,) + x.shape[1:])
+    if held == heads:
+        return x
+    return jnp.broadcast_to(x, (heads,) + x.shape[1:]) if held == 1 else jnp.repeat(x, heads // held, axis=0)
 
 
-def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
+def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group: int = 1):
     """A grid step: H heads' tile (qi, ki); every block and scratch leads
-    with the heads.  ``masked``: a fourth operand, an int8 (block_q, block_k)
-    tile of a per-pair mask (ops/sparse_attention.py), decides what a query
-    sees in place of the causal triangle; ``causal`` still says which tiles
-    are empty."""
+    with the heads (k and v with the step's KV heads, `_kv_spec`).
+    ``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
+    per-pair mask (ops/sparse_attention.py), decides what a query sees in
+    place of the causal triangle; ``causal`` still says which tiles are
+    empty."""
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
@@ -397,7 +439,7 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False):
     def _step():
         keep = _keep(walk, qi, ki, mask_ref)
         m_cur, l_new, acc = _heads_at_once(_fwd_tile, scale=scale)(
-            keep, q_ref[...], _kv_heads(k_ref, heads), _kv_heads(v_ref, heads),
+            keep, q_ref[...], _kv_heads(k_ref, heads, kv_group), _kv_heads(v_ref, heads, kv_group),
             m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...])
         acc_scr[...] = acc
         # Partial column stores: broadcasting the stats across the full
@@ -422,13 +464,22 @@ def _tri(i, j):
     return i * (i + 1) // 2 + j
 
 
-def _kv_spec(spec, heads: int, kv_group: int, block_k: int, width: int):
-    """k's or v's spec for a step of ``heads`` heads: a head each where the
-    array holds one for every query head, the one KV head the step's heads
-    share (``heads`` divides ``kv_group``) where it is read in place."""
-    if kv_group == 1:
-        return spec((heads, block_k, width), lambda b, i, j: (b, j, 0))
-    return spec((1, block_k, width), lambda b, i, j: (b * heads // kv_group, j, 0))
+def _kv_spec(spec, bh: int, heads: int, kv_group: int, block_k: int, width: int):
+    """k's or v's spec for a step of ``heads`` of ``bh`` heads: a head each
+    where the array holds one for every query head; read in place, the KV
+    heads of the whole groups the step holds (block b either way), the one KV
+    head its heads share, or, where the step straddles groups, the `_kv_span`
+    adjacent KV heads from its first head's on, placed by element (and no
+    further than the array's last)."""
+    from jax.experimental import pallas as pl
+
+    if heads % kv_group == 0:
+        return spec((heads // kv_group, block_k, width), lambda b, i, j: (b, j, 0))
+    if kv_group % heads == 0:
+        return spec((1, block_k, width), lambda b, i, j: (b * heads // kv_group, j, 0))
+    held = _kv_span(heads, kv_group)
+    return spec((pl.Element(held), pl.Element(block_k), pl.Element(width)),
+                lambda b, i, j: (_first_kv_head(b, bh, heads, kv_group, held), j * block_k, 0))
 
 
 def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
@@ -441,9 +492,8 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     ``window``: the band walk, under the name `tpuft_swa_fwd`.
     ``heads_per_step`` is the probe's and the tests': the program reads H
     from the shapes, the largest divisor not above `HEADS_PER_STEP` of bh —
-    of ``kv_group`` where k and v are read in place, of a batch entry's
-    heads under a mask — so that the heads of a step share their mask tile
-    and, read in place, their KV head."""
+    of a batch entry's heads under a mask, so that the heads of a step share
+    their mask tile; grouped queries set no limit (`_kv_spec`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -453,7 +503,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     assert mask is None or (seq_q == seq_k and window is None), "a packed mask is one sequence's lower triangle"
     walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window)
     spec = walk.spec
-    share = _heads_share(bh, mask, kv_group)
+    share = _heads_share(bh, mask)
     heads = heads_per_step or _heads_per_step(share)
     assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
     operands, mask_specs = (q, k, v), []
@@ -462,7 +512,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
         operands += (mask,)
         mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (b * heads // per_entry, _tri(i, j), 0, 0))]
     out, lse_padded = pl.pallas_call(
-        functools.partial(_fa_kernel, walk=walk, scale=scale, masked=mask is not None),
+        functools.partial(_fa_kernel, walk=walk, scale=scale, masked=mask is not None, kv_group=kv_group),
         out_shape=(
             jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, _LANE), jnp.float32),
@@ -471,8 +521,8 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
             bh // heads,
             in_specs=[
                 spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
-                _kv_spec(spec, heads, kv_group, block_k, d),
-                _kv_spec(spec, heads, kv_group, block_k, dv),
+                _kv_spec(spec, bh, heads, kv_group, block_k, d),
+                _kv_spec(spec, bh, heads, kv_group, block_k, dv),
             ] + mask_specs,
             out_specs=(
                 spec((heads, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -539,7 +589,7 @@ def _row_stats(ref, qi, block_q: int):
     return ref[:, :, pl.ds(qi * block_q, block_q)]
 
 
-def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked: bool = False):
+def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked: bool = False, kv_group: int = 1):
     """Flash backward with the q axis innermost (a ``kv_major`` walk), H
     heads a grid step: dk/dv accumulate in VMEM scratch across the q tiles
     over one kv tile.  With ``with_dq`` (the one-pass form) a third scratch
@@ -574,7 +624,8 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
     @pl.when(run)
     def _step():
         dv_tile, dk_tile, *dq_tile = _heads_at_once(_bwd_tile, scale=scale, dkdv=True, dq=with_dq)(
-            _keep(walk, qi, ki, mask_ref), q_ref[...], _kv_heads(k_ref, heads), _kv_heads(v_ref, heads), do_ref[...],
+            _keep(walk, qi, ki, mask_ref), q_ref[...], _kv_heads(k_ref, heads, kv_group),
+            _kv_heads(v_ref, heads, kv_group), do_ref[...],
             _row_stats(lse_ref, qi, block_q), _row_stats(delta_ref, qi, block_q))
         dv_scr[...] += dv_tile                      # p^T @ do: [H, block_k, d_v]
         dk_scr[...] += dk_tile                      # ds^T @ q: [H, block_k, d]
@@ -605,7 +656,7 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
             dq_ref[:, q_rows, :] = dq_scr[:, q_rows, :].astype(dq_ref.dtype)
 
 
-def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
+def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, kv_group: int = 1):
     """dq-only second pass, kv axis innermost, for a dq row too long to
     stay in VMEM: dq accumulates one (block_q, d) f32 block a head at a
     time, so memory stays O(block) whatever the length (at the price of
@@ -613,6 +664,7 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr) = walk.tile(refs)
+    heads = q_ref.shape[0]
 
     @pl.when(ki == walk.first_k(qi))
     def _init():
@@ -621,7 +673,8 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     @pl.when(walk.visible(qi, ki))
     def _step():
         dq_scr[...] += _heads_at_once(_bwd_tile, scale=scale, dkdv=False, dq=True)(
-            _keep(walk, qi, ki, None), q_ref[...], k_ref[...], v_ref[...], do_ref[...],
+            _keep(walk, qi, ki, None), q_ref[...], _kv_heads(k_ref, heads, kv_group),
+            _kv_heads(v_ref, heads, kv_group), do_ref[...],
             _row_stats(lse_ref, qi, walk.block_q), _row_stats(delta_ref, qi, walk.block_q))[0]
 
     @pl.when(ki == walk.last_k(qi))
@@ -658,7 +711,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     family = "tpuft_fa" if window is None else "tpuft_swa"
     one_pass = _dq_row_resident(seq_q, d)
     row_bytes = _row_vmem_bytes(seq_q, d, q.dtype.itemsize) if one_pass else 0
-    share = _heads_share(bh, mask, kv_group)
+    share = _heads_share(bh, mask)
     heads = heads_per_step or _bwd_heads_per_step(share, row_bytes)
     assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
     # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
@@ -674,8 +727,8 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
         row = spec((heads, 1, seq_q), lambda b, i, j: (b, 0, 0))
         return [
             spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
-            _kv_spec(spec, heads, kv_group, block_k, d),
-            _kv_spec(spec, heads, kv_group, block_k, d_v),
+            _kv_spec(spec, bh, heads, kv_group, block_k, d),
+            _kv_spec(spec, bh, heads, kv_group, block_k, d_v),
             spec((heads, block_q, d_v), lambda b, i, j: (b, i, 0)),
             row, row,
         ], [
@@ -709,6 +762,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     outs = pl.pallas_call(
         functools.partial(
             _fa_bwd_dkdv_kernel, walk=walk, scale=scale, with_dq=one_pass, masked=mask is not None,
+            kv_group=kv_group,
         ),
         out_shape=tuple(out_shape),
         grid_spec=walk.grid_spec(bh // heads, in_specs, tuple(out_specs), scratch),
@@ -731,7 +785,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     walk = _Walk(causal, seq_q, seq_k, block_q, block_k, window=window)
     in_specs, _ = specs(walk)
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale),
+        functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale, kv_group=kv_group),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=walk.grid_spec(
             bh // heads, in_specs, in_specs[0], [pltpu.VMEM((heads, block_q, d), jnp.float32)]),
@@ -765,9 +819,17 @@ def _fa_reference(q, k, v, scale: float, causal: bool, window: Optional[int] = N
 
 
 def _fa_forward(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[int]):
-    if kernel:
-        return _fa_pallas_call(q, k, v, scale, causal, window=window)
+    if kernel:  # k and v with their own KV heads, read in place
+        return _fa_pallas_call(q, k, v, scale, causal, window=window, kv_group=q.shape[0] // k.shape[0])
     return _fa_reference(q, k, v, scale, causal, window)
+
+
+def group_sum(t, group: int):
+    """[heads * group, ...], a query head each, -> [heads, ...]: a KV head's
+    gradient is its query heads' summed, in float32 and rounded once."""
+    if group == 1:
+        return t
+    return jnp.sum(t.reshape((-1, group) + t.shape[1:]).astype(jnp.float32), axis=1).astype(t.dtype)
 
 
 # `kernel` is decided once, in flash_attention, from the shapes and the mesh
@@ -796,7 +858,9 @@ def _flash_fwd(q, k, v, scale, causal, kernel, window):
 def _flash_bwd(scale, causal, kernel, window, res, g):
     q, k, v, o, lse = res
     if kernel:
-        return _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window)
+        group = q.shape[0] // k.shape[0]
+        dq, dk, dv = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window, kv_group=group)
+        return dq, group_sum(dk, group), group_sum(dv, group)
     return _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window)
 
 
@@ -833,7 +897,9 @@ def flash_attention(
     [B, Hkv, S, Dv] -> [B, Hq, S, Dv].  Dv may differ from D (MLA: 192 for
     query and key, 128 for value); the default scale is D ** -0.5.
 
-    GQA: Hkv may divide Hq; kv heads are broadcast to query groups.
+    GQA: Hkv may divide Hq.  The kernels read a KV head in place for its
+    group of query heads (no repeated copy of k or v in HBM, forward or
+    backward); the XLA formulation broadcasts kv heads to the query groups.
     ``mesh`` is the mesh of the program being traced (None: the ambient
     abstract mesh); under more than one device the XLA formulation runs,
     see ``_pallas_util.kernels_apply``.
@@ -847,13 +913,11 @@ def flash_attention(
         if window >= sq:
             window = None
     hkv, dv = k.shape[1], v.shape[3]
-    if hkv != hq:
-        assert hq % hkv == 0, "query heads must be a multiple of kv heads"
-        rep = hq // hkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
+    assert hq % hkv == 0, "query heads must be a multiple of kv heads"
     scale = scale if scale is not None else d ** -0.5
     kernel = _use_pallas(sq, k.shape[2], dv, mesh)
+    if hkv != hq and not kernel:
+        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
     if kernel and d % _LANE:
         # Zero columns up to the next lane multiple: nothing in a score.
         pad = [(0, 0)] * 3 + [(0, -d % _LANE)]
@@ -861,8 +925,8 @@ def flash_attention(
         d = q.shape[3]
     out = _flash(
         q.reshape(b * hq, sq, d),
-        k.reshape(b * hq, k.shape[2], d),
-        v.reshape(b * hq, v.shape[2], dv),
+        k.reshape(-1, k.shape[2], d),
+        v.reshape(-1, v.shape[2], dv),
         scale,
         causal,
         kernel,

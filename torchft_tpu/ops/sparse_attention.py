@@ -536,15 +536,12 @@ def _masked_flash_fwd(q, k, v, mask, scale, interpret: bool = False):
 
 def _masked_flash_bwd(q, k, v, o, lse, g, mask, scale, interpret: bool = False):
     batch, q_heads, seq, d = q.shape
-    kv_heads = k.shape[1]
-    group = q_heads // kv_heads
+    group = q_heads // k.shape[1]
     flat = lambda t: t.reshape(-1, seq, d)  # noqa: E731
     dq, dk, dv = _fa._fa_bwd_pallas(
         flat(q), flat(k), flat(v), flat(o), lse.reshape(batch * q_heads, seq), flat(g), scale, True,
         interpret=interpret, mask=mask, kv_group=group)
-    fold = lambda t: jnp.sum(  # noqa: E731 — a KV head's gradient: its query heads' summed
-        t.reshape(batch, kv_heads, group, seq, d).astype(jnp.float32), axis=2).astype(k.dtype)
-    return dq.reshape(q.shape), fold(dk), fold(dv)
+    return dq.reshape(q.shape), _fa.group_sum(dk, group).reshape(k.shape), _fa.group_sum(dv, group).reshape(v.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
