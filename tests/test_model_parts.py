@@ -102,6 +102,9 @@ MODELS["nemotron"] = TransformerConfig(**dict(
     moe_capacity_factor=None, moe_held=(2, 2), moe_score="sigmoid", moe_route_scale=2.5, moe_shared_experts=2,
     moe_aux_coef=0.0, moe_activation="relu2", ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_chunk=16,
     remat=True, remat_keeps_attention=True, scan_unroll=16, pattern=(_M, _E, _M, _E, _M, _A, _E, _M, _E)))
+# The ten the benchmark had before a mixer was an entry of a table (`models/mixers.MIXERS`): PR 61 pins the eighth to
+# tenth beside the seven, from its parent tree.
+PINNED = PINNED + ("kimi", "smallthinker", "nemotron")
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
@@ -234,8 +237,8 @@ def _digest(step, params, batch, program: str, grads_text=None) -> str:
 
 
 def record(commit: str) -> None:
-    """Records the fourteen digests anew (`python tests/test_model_parts.py "<commit and why>"`,
-    `JAX_PLATFORMS=cpu`): for a PR that changes the seven gradient programs on
+    """Records the twenty digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    `JAX_PLATFORMS=cpu`): for a PR that changes the ten gradient programs on
     purpose.  The update programs are no model code's to change, so theirs
     have to come out as they were."""
     import json
@@ -245,12 +248,18 @@ def record(commit: str) -> None:
     digests = {f"{name}.{program}": _digest(*_step_and_arguments(name), program)
                for name in PINNED for program in ("grads", "update")}
     if before["jax"] == jax.__version__:
-        moved = sorted(k for k, v in digests.items() if v != before["sha256_of_canonical_hlo"][k])
+        recorded = before["sha256_of_canonical_hlo"]  # a model pinned for the first time has no digest to differ from
+        moved = sorted(k for k, v in digests.items() if k in recorded and v != recorded[k])
         assert not [k for k in moved if k.endswith(".update")], moved
         print("differ from the record:", moved or "none")
     with open(_RECORDED, "w", encoding="utf-8") as f:
         json.dump({"jax": jax.__version__, "commit": commit, "sha256_of_canonical_hlo": digests}, f, indent=1)
         f.write("\n")
+
+
+# The stacks of the models whose pattern names its own (the others: "layers", and "dense_layers" where some lead).
+_STACKS = {"laguna": {"dense_layers", "window_layers", "layers"}, "kimi": {"kda_dense", "kda_layers", "mla_layers"},
+           "smallthinker": {"layers", "window_layers"}, "nemotron": {"mamba", "attn", "moe"}}
 
 
 @pytest.mark.parametrize("program", ["grads", "update"])
@@ -262,8 +271,11 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     instructions they compiled to before (canonical optimized HLO, by digest;
     recorded from the parent tree with this JAX) — and since PR 41, which made
     the mixer the kind's and let the walk carry a second stream, the sixth
-    (window and full attention mixed) with them.  A PR that changes these
-    programs on purpose records them anew: `tests/data/hlo_before_the_pattern.json`."""
+    (window and full attention mixed) with them, since PR 48 the seventh and
+    since PR 61, which made a mixer an entry of `models/mixers.MIXERS`, all ten
+    (each recorded from the tree BEFORE the change it guards).  A PR that
+    changes these programs on purpose records them anew:
+    `tests/data/hlo_before_the_pattern.json`."""
     import json
 
     with open(_RECORDED, encoding="utf-8") as f:
@@ -275,10 +287,13 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     assert _digest(step, params, batch, program, grads_text) == recorded["sha256_of_canonical_hlo"][f"{name}.{program}"]
     # and the tree keeps its leaves' names and shapes: heal and checkpoints read what they wrote
     cfg = MODELS[name]
-    assert set(cfg.stacks) == ({"dense_layers", "window_layers", "layers"} if name == "laguna" else
-                               {"layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set()))
+    assert set(cfg.stacks) == _STACKS.get(name, {"layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set()))
     assert set(params) == {"embed", "final_norm"} | set(cfg.stacks) | (set() if cfg.tied_head else {"lm_head"})
-    assert {s: n for s, (_, n) in cfg.stacks.items()} == {k: v["attn_norm"].shape[0] for k, v in params.items() if "layers" in k}
+    # a stack holds a row a layer of its first norm: the mixer's, or the feed-forward's where the block has no mixer
+    assert [name for name, (kind, _) in cfg.stacks.items() if "attn_norm" not in params[name]] == (
+        ["moe"] if name == "nemotron" else [])
+    assert {s: n for s, (_, n) in cfg.stacks.items()} == {
+        s: params[s]["attn_norm" if "attn_norm" in params[s] else "mlp_norm"].shape[0] for s in cfg.stacks}
 
 
 def test_the_op_map_names_both_programs_and_costs_nothing_until_asked(monkeypatch) -> None:
