@@ -24,7 +24,8 @@ from architectures import (  # noqa: F401 — the shared tests this entry has fi
     test_the_shares_add_up_to_the_uncut_layer, test_the_tree_goes_through, test_the_tree_is_the_reference_s)
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params
 from torchft_tpu.models.moe import moe_layer
-from torchft_tpu.models.transformer import _cca_qkv, loss_and_counters, param_axes
+from torchft_tpu.models.attention import _cca_qkv
+from torchft_tpu.models.transformer import loss_and_counters, param_axes
 
 REFERENCE = BENCH.reference("cca_moe_lm")
 PROGRAM = BENCH.program("cca_moe_lm")

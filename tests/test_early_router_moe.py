@@ -29,7 +29,7 @@ from architectures import (  # noqa: F401 — the shared tests this entry has fi
     test_the_tree_goes_through, test_the_tree_is_the_reference_s)
 from benchmark import spec
 from torchft_tpu.models import TransformerConfig
-from torchft_tpu.models import moe, transformer
+from torchft_tpu.models import attention, moe
 from torchft_tpu.models.moe import moe_layer, routing
 from torchft_tpu.models.transformer import loss_and_counters
 
@@ -127,17 +127,17 @@ def test_an_unrotated_kinds_q_and_k_are_the_projections_bit_for_bit(monkeypatch)
     weights = REFERENCE.make_weights(3, dict(SHARE, num_hidden_layers=2))
     batch = _batch(3)
     seen, turned = [], []
-    real_attention, real_rotary = transformer.flash_attention, transformer._rotary
-    monkeypatch.setattr(transformer, "flash_attention",
+    real_attention, real_rotary = attention.flash_attention, attention._rotary
+    monkeypatch.setattr(attention, "flash_attention",
                         lambda q, k, v, **kw: seen.append((q, k, kw.get("window"))) or real_attention(q, k, v, **kw))
-    monkeypatch.setattr(transformer, "_rotary",
+    monkeypatch.setattr(attention, "_rotary",
                         lambda x, positions, kind, **kw: turned.append(kind.stack) or real_rotary(x, positions, kind, **kw))
     loss_and_counters(weights, batch, cfg)
     assert [window for _, _, window in seen] == [None, WINDOW] and turned == ["window_layers"] * 2
     q, k, _ = seen[0]
     x = weights["embed"][batch["tokens"]]
     w = {name: leaf[0] for name, leaf in weights["layers"].items()}
-    h = transformer.rms_norm(x, w["attn_norm"], cfg.rms_eps)
+    h = attention.rms_norm(x, w["attn_norm"], cfg.rms_eps)
     want_q = (h @ w["wq"]).reshape(2, SEQ, 7, 16).transpose(0, 2, 1, 3)
     want_k = (h @ w["wk"]).reshape(2, SEQ, 1, 16).transpose(0, 2, 1, 3)
     assert np.array_equal(np.asarray(q), np.asarray(want_q)) and np.array_equal(np.asarray(k), np.asarray(want_k))

@@ -1,6 +1,6 @@
 """`ops/kda_mix.py` — the `tpuft_kdamix_*` kernels around Kimi Delta
 Attention's scan — on the CPU (``interpret``), against the XLA halves they
-stand for (`models/transformer.py::_kda_before`, `_kda_after`): forward values
+stand for (`models/kda.py::_kda_before`, `_kda_after`): forward values
 and every gradient, the small leaves' included; the convolution's rows across
 a tile's edge and at the sequence's start, both directions; which path
 `_kda_mixer` takes; and the benchmark's count of the part and its reader
@@ -23,7 +23,7 @@ if ROOT not in sys.path:
 
 from benchmark.spec import Benchmark  # noqa: E402
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models.transformer import _KDA_SMALL, _kda_after, _kda_before, _kda_mixer  # noqa: E402
+from torchft_tpu.models.kda import _KDA_SMALL, _kda_after, _kda_before, _kda_mixer  # noqa: E402
 from torchft_tpu.ops import _pallas_util, kda_mix  # noqa: E402
 
 D = kda_mix.LANE

@@ -25,7 +25,9 @@ from architectures import (  # noqa: F401 — the shared tests this entry has fi
     test_the_shares_add_up_to_the_uncut_layer, test_the_tree_goes_through, test_the_tree_is_the_reference_s)
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params
 from torchft_tpu.models.moe import moe_layer
-from torchft_tpu.models.transformer import _causal_conv, _kda_mixer, _l2, _mla_qkv, loss_and_counters
+from torchft_tpu.models.attention import _mla_qkv
+from torchft_tpu.models.kda import _causal_conv, _kda_mixer, _l2
+from torchft_tpu.models.transformer import loss_and_counters
 from torchft_tpu.ops import delta_attention
 
 REFERENCE = BENCH.reference("kda_mla_moe_lm")
@@ -89,7 +91,7 @@ def _counters(counters, config) -> None:
 
 
 def _without(piece):
-    import torchft_tpu.models.transformer as model
+    import torchft_tpu.models.kda as model
 
     def how(cfg, weights):
         changes, kda = [], delta_attention.kda

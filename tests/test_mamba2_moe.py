@@ -28,7 +28,8 @@ from architectures import (  # noqa: F401 — the shared tests this entry has fi
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params
 from torchft_tpu.models import mamba
 from torchft_tpu.models.moe import moe_layer
-from torchft_tpu.models.transformer import _causal_conv, loss_and_counters
+from torchft_tpu.models.mixer import _causal_conv
+from torchft_tpu.models.transformer import loss_and_counters
 from torchft_tpu.ops import ssd
 
 REFERENCE = BENCH.reference("mamba2_moe_lm")
@@ -88,7 +89,7 @@ def _counters(counters, config) -> None:
 
 def _without(piece):
     import torchft_tpu.models.moe as moe
-    import torchft_tpu.models.transformer as model
+    import torchft_tpu.models.mamba as model
 
     def how(cfg, weights):
         changes = []

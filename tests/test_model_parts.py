@@ -27,7 +27,9 @@ if ROOT not in sys.path:
 
 from hlo_text import canonical, without_metadata  # noqa: E402
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params  # noqa: E402
-from torchft_tpu.models.transformer import loss_and_counters  # noqa: E402
+from torchft_tpu.models.mixer import Mixer  # noqa: E402
+from torchft_tpu.models.mixers import MIXERS  # noqa: E402
+from torchft_tpu.models.transformer import loss_and_counters, param_axes  # noqa: E402
 from torchft_tpu.obs import opmap  # noqa: E402
 from torchft_tpu.obs.spans import PARTS  # noqa: E402
 from torchft_tpu.parallel import TrainStep, ft_init_mesh  # noqa: E402
@@ -294,6 +296,129 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
         ["moe"] if name == "nemotron" else [])
     assert {s: n for s, (_, n) in cfg.stacks.items()} == {
         s: params[s]["attn_norm" if "attn_norm" in params[s] else "mlp_norm"].shape[0] for s in cfg.stacks}
+
+
+# -- the seam: a mixer is an entry of `MIXERS`, and the model asks the entry
+
+
+def _is_axes(x) -> bool:
+    return isinstance(x, tuple)
+
+
+@pytest.mark.parametrize("mixer,model", [("attention", "keye"), ("mla", "moonlight"), ("cca", "zaya"), ("kda", "kimi"),
+                                         ("mamba2", "nemotron")])
+def test_a_mixer_lists_its_own_leaves(mixer, model) -> None:
+    """`Mixer.axes` and `Mixer.init` name exactly the same leaves — the model
+    adds nothing for all and drops nothing for some — and every leaf is a row
+    a layer of as many axes as its axes' names."""
+    assert set(MIXERS) == {"attention", "mla", "cca", "kda", "mamba2"}
+    cfg = MODELS[model]
+    kind = next(kind for kind in cfg.layers if kind.mixer == mixer)
+    entry = MIXERS[mixer]
+    axes = entry.axes(cfg, kind)
+    leaves = jax.eval_shape(lambda: entry.init(jax.random.PRNGKey(0), cfg, 3, kind))
+    assert set(axes) == set(leaves) and "attn_norm" not in leaves and "mlp_norm" not in leaves
+    for name, leaf in leaves.items():
+        assert leaf.shape[0] == 3 and axes[name][0] == "layers" and len(axes[name]) == len(leaf.shape), name
+    assert (entry.check is None) == (mixer == "attention")  # the plain heads refuse nothing
+    if entry.check is not None:
+        entry.check(cfg, kind)
+
+
+@pytest.mark.parametrize("name", list(MODELS))
+def test_the_axes_and_the_leaves_are_one_tree(name) -> None:
+    cfg = MODELS[name]
+    axes, leaves = param_axes(cfg), jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    assert jax.tree.structure(axes, is_leaf=_is_axes) == jax.tree.structure(leaves)
+    assert all(len(a) == len(leaf.shape) for a, leaf in zip(jax.tree.leaves(axes, is_leaf=_is_axes), jax.tree.leaves(leaves)))
+
+
+def _batch(cfg):
+    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, size=(2, SEQ)).astype(np.int32)
+    return {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(np.roll(tokens, -1, axis=1))}
+
+
+def _listening(monkeypatch, name: str, statistic: str) -> list:
+    """`MIXERS[name]` with a forward that also notes down each layer's `statistic`."""
+    entry, heard = MIXERS[name], []
+
+    def forward(*args):
+        y, stats = entry.forward(*args)
+        heard.append(stats[statistic])
+        return y, stats
+
+    monkeypatch.setitem(MIXERS, name, dataclasses.replace(entry, forward=forward))
+    return heard
+
+
+def test_a_sixth_mixer_costs_no_edit_of_the_model(monkeypatch) -> None:
+    """An entry put into `MIXERS` from outside — one projection, one statistic
+    of its own, one kept name — and a pattern that names it: the tree, the
+    gradient, the counter and `remat_keeps_attention` all go by the entry."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def forward(cfg, kind, mesh, rules, h, w, positions):
+        with jax.named_scope("attn_proj"):
+            y = checkpoint_name(jnp.tanh(h @ w["toy_w"].astype(cfg.dtype)), "toy_out")
+        return y, {"toy_gain": jnp.mean(jnp.abs(y))}
+
+    def check(cfg, kind) -> None:
+        assert kind.n_heads == 1, "the toy has one head"
+
+    monkeypatch.setitem(MIXERS, "toy", Mixer(
+        init=lambda key, cfg, L, kind: {"toy_w": jax.random.normal(jax.random.fold_in(key, 9), (L, cfg.d_model, cfg.d_model)) * 0.1},
+        axes=lambda cfg, kind: {"toy_w": ("layers", "embed", None)},
+        forward=forward, saved_names=("toy_out",), mean_statistic=("toy_gain", "toy_gain_mean"), check=check))
+    toy = LayerKind("toy_layers", True, 1, 1e4, mixer="toy")
+    sizes = dict(_BASE, n_layers=3, moe_experts=4, moe_top_k=2, d_ff=32, moe_capacity_factor=None)
+    with pytest.raises(AssertionError, match="the toy has one head"):
+        TransformerConfig(**dict(sizes, pattern=(dataclasses.replace(toy, n_heads=2),) * 3))
+    with pytest.raises(AssertionError, match="a kind's mixer is one of"):
+        TransformerConfig(**dict(sizes, pattern=(dataclasses.replace(toy, mixer="no_such"),) * 3))
+    cfg = TransformerConfig(**dict(sizes, pattern=(toy, LayerKind("layers", True, 4, 1e4), toy)))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    assert set(params["toy_layers"]) == {"attn_norm", "toy_w", "mlp_norm", "router", "w_gate", "w_up", "w_down"}
+    assert params["toy_layers"]["toy_w"].shape == (2, 64, 64) and "toy_w" not in params["layers"]
+    assert jax.tree.structure(param_axes(cfg), is_leaf=_is_axes) == jax.tree.structure(params)
+    batch = _batch(cfg)
+    heard = _listening(monkeypatch, "toy", "toy_gain")
+    _, counters = loss_and_counters(params, batch, cfg)  # eagerly: what is noted down are values
+    heard = [float(a) for a in heard]
+    assert len(heard) == 2 and heard[0] != heard[1]
+    np.testing.assert_allclose(float(counters["toy_gain_mean"]), np.mean(heard), rtol=1e-6)
+    assert "kda_alpha_mean" not in counters and "ssm_decay_mean" not in counters
+    grads = jax.grad(lambda p: loss_and_counters(p, batch, cfg)[0])(params)
+    assert np.isfinite(np.asarray(grads["toy_layers"]["toy_w"])).all()
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads["toy_layers"]["toy_w"])  # each of the two layers learns
+
+    def made(keeps: bool) -> int:
+        """How often the gradient program makes the toy's named output."""
+        remat = dataclasses.replace(cfg, remat=True, remat_keeps_attention=keeps)
+        return str(jax.make_jaxpr(jax.grad(lambda p: loss_and_counters(p, batch, remat)[0]))(params)).count("name=toy_out")
+
+    # kept by the name the entry lists: the backward pass reads each toy layer's output, where without it makes it again
+    assert (made(True), made(False)) == (2, 4)
+
+
+def test_two_recurrent_mixers_in_one_pattern_count_a_mean_each(monkeypatch) -> None:
+    """Kimi Delta Attention and Mamba-2 layers in one pattern: each decay's
+    mean is counted by name, over the layers of its own mixer."""
+    kda = LayerKind("kda_layers", True, 2, **_KDA)
+    ssm = LayerKind("mamba", True, 4, 1e4, rotary_fraction=0.0, mixer="mamba2")
+    cfg = TransformerConfig(**dict(
+        _BASE, n_layers=5, n_heads=2, n_kv_heads=2, kda_head_dim=16, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+        ssm_chunk=16, moe_experts=4, moe_top_k=2, d_ff=32, moe_capacity_factor=None, scan_unroll=8,
+        pattern=(kda, ssm, kda, kda, ssm)))
+    params, batch = init_params(jax.random.PRNGKey(0), cfg), _batch(cfg)
+    alphas, decays = _listening(monkeypatch, "kda", "kda_alpha"), _listening(monkeypatch, "mamba2", "ssm_decay")
+    _, counters = loss_and_counters(params, batch, cfg)  # eagerly: what is noted down are values
+    alphas, decays = [float(a) for a in alphas], [float(a) for a in decays]
+    assert (len(alphas), len(decays)) == (3, 2)
+    np.testing.assert_allclose(float(counters["kda_alpha_mean"]), np.mean(alphas), rtol=1e-6)
+    np.testing.assert_allclose(float(counters["ssm_decay_mean"]), np.mean(decays), rtol=1e-6)
+    assert abs(np.mean(alphas) - np.mean(decays)) > 1e-3  # two means, not one sum shared
+    loss, _ = jax.jit(lambda p: loss_and_counters(p, batch, dataclasses.replace(cfg, scan_unroll=1)))(params)
+    assert np.isfinite(float(loss))
 
 
 def test_the_op_map_names_both_programs_and_costs_nothing_until_asked(monkeypatch) -> None:

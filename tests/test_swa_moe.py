@@ -27,7 +27,8 @@ from architectures import (  # noqa: F401 — the shared tests this entry has fi
     test_the_tree_goes_through, test_the_tree_is_the_reference_s)
 from torchft_tpu.models import LayerKind, TransformerConfig, init_params
 from torchft_tpu.models.moe import moe_layer
-from torchft_tpu.models.transformer import _rope, _rotary, loss_and_counters, yarn_frequencies
+from torchft_tpu.models.rope import _rope, _rotary, yarn_frequencies
+from torchft_tpu.models.transformer import loss_and_counters
 
 REFERENCE = BENCH.reference("swa_moe_lm")
 PROGRAM = BENCH.program("swa_moe_lm")
@@ -308,7 +309,7 @@ def test_a_model_of_whole_heads_is_the_two_halves_model(monkeypatch) -> None:
     with `_turn` put back to the two-halves form: the same float32 sums, so
     equal to the rounding of a product XLA:CPU contracts in one and not the
     other."""
-    from torchft_tpu.models import transformer
+    from torchft_tpu.models import rope
 
     cfg = TransformerConfig(vocab_size=64, d_model=64, n_layers=2, n_heads=2, n_kv_heads=1, head_dim=128, d_ff=64, max_seq=32,
                             dtype=jnp.float32, attn_head_gate=True, scan_unroll=2,
@@ -323,7 +324,7 @@ def test_a_model_of_whole_heads_is_the_two_halves_model(monkeypatch) -> None:
 
     loss, grads = loss_and_grads()
     calls = []
-    monkeypatch.setattr(transformer, "_turn", lambda *a: calls.append(a[0].shape) or _two_halves(*a))
+    monkeypatch.setattr(rope, "_turn", lambda *a: calls.append(a[0].shape) or _two_halves(*a))
     old_loss, old_grads = loss_and_grads()
     assert sorted(set(calls)) == [(2, 32, 1, 128), (2, 32, 2, 128)]
     np.testing.assert_allclose(float(loss), float(old_loss), rtol=1e-6)

@@ -46,7 +46,7 @@ def main() -> int:
     config, traffic = bench.config(cell["config"]), bench.traffic(cell["traffic"])
     reference, program = bench.reference(config["architecture"]), bench.program(config["architecture"])
     job = bench.job(traffic["job"])
-    from torchft_tpu.models.transformer import _index_operands
+    from torchft_tpu.models.attention import _index_operands
     from torchft_tpu.ops import rms_norm
     from torchft_tpu.ops.sparse_attention import packed_lower_triangle, selection
 

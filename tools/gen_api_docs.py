@@ -56,8 +56,13 @@ MODULES = [
     "torchft_tpu.parallel.sharding",
     "torchft_tpu.parallel.pipeline",
     "torchft_tpu.models.transformer",
-    "torchft_tpu.models.moe",
+    "torchft_tpu.models.mixer",
+    "torchft_tpu.models.mixers",
+    "torchft_tpu.models.attention",
+    "torchft_tpu.models.kda",
     "torchft_tpu.models.mamba",
+    "torchft_tpu.models.rope",
+    "torchft_tpu.models.moe",
     "torchft_tpu.models.convnet",
     "torchft_tpu.ops.attention",
     "torchft_tpu.ops.cross_entropy",

@@ -1,7 +1,7 @@
 """Standalone: the `tpuft_kdamix_*` kernels (ops/kda_mix.py) at the Kimi
 cell's shape — one sequence of 16,384 positions x 32 heads of 128 — each
 timed alone, tile height by tile height and block by block, beside the XLA
-halves they stand for (`models/transformer.py::_kda_before`, `_kda_after`,
+halves they stand for (`models/kda.py::_kda_before`, `_kda_after`,
 forward and gradient), with the bytes each must move over the time as GB/s;
 and at 2,048 positions x 4 heads compared with those halves on the chip
 (outputs and every gradient).
@@ -56,7 +56,7 @@ def compare(seed=1, batch=2, seq=2048, heads=4):
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.models.transformer import _kda_after, _kda_before
+    from torchft_tpu.models.kda import _kda_after, _kda_before
     from torchft_tpu.ops import kda_mix
 
     (q0, k0, v0, a, gate, dout), (o, dq, dk, dv), dg, b, w = inputs(seed, batch, seq, heads, jnp.bfloat16)
@@ -100,7 +100,7 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.models.transformer import _kda_after, _kda_before
+    from torchft_tpu.models.kda import _kda_after, _kda_before
     from torchft_tpu.ops import kda_mix
 
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)

@@ -30,7 +30,7 @@ if ROOT not in sys.path:
 
 
 def install(name: str, T, saved: dict) -> None:
-    """Patches `T` (models.transformer) to the form `name`."""
+    """Patches `T` (models.rope) to the form `name`."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -100,7 +100,7 @@ def main() -> None:
     import numpy as np
 
     from torchft_tpu.models import init_params
-    from torchft_tpu.models import transformer as T
+    from torchft_tpu.models import rope as T
     from torchft_tpu.models.transformer import loss_and_counters
 
     os.makedirs(args.out, exist_ok=True)

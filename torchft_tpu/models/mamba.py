@@ -36,6 +36,9 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from torchft_tpu.models.mixer import Mixer, _causal_conv
+from torchft_tpu.ops.ssd import SAVED_NAMES
+
 _SMALL = ("ssm_conv", "ssm_conv_bias", "dt_bias", "A_log", "ssm_D", "ssm_norm")
 
 
@@ -45,21 +48,21 @@ def widths(cfg, heads: int) -> Tuple[int, int]:
     return inner, inner + 2 * cfg.ssm_groups * cfg.ssm_state
 
 
-def mamba2_axes() -> Dict[str, Any]:
+def mamba2_axes(cfg, kind) -> Dict[str, Any]:
     """Logical axis names of the mixer's leaves (``param_axes``)."""
     return {"ssm_in": ("layers", "embed", None), "ssm_conv": ("layers", None, None), "ssm_conv_bias": ("layers", None),
             "dt_bias": ("layers", None), "A_log": ("layers", None), "ssm_D": ("layers", None),
             "ssm_norm": ("layers", None), "ssm_out": ("layers", None, "embed")}
 
 
-def init_mamba2(key: jax.Array, cfg, L: int, heads: int) -> Dict[str, Any]:
-    """A stack of Mamba-2 mixers: the published layer's initialisation of the
-    decay (A = log U(1, 16) a head, dt_bias the inverse softplus of log-uniform
-    steps in [0.001, 0.1] floored at 1e-4), D at one, the taps [channel, tap]
-    normal at kernel**-0.5 with a zero bias."""
-    pd, E, T = cfg.param_dtype, cfg.d_model, cfg.ssm_conv
+def init_mamba2(key: jax.Array, cfg, L: int, kind) -> Dict[str, Any]:
+    """A stack of Mamba-2 mixers, from the stack's key: the published layer's
+    initialisation of the decay (A = log U(1, 16) a head, dt_bias the inverse
+    softplus of log-uniform steps in [0.001, 0.1] floored at 1e-4), D at one,
+    the taps [channel, tap] normal at kernel**-0.5 with a zero bias."""
+    pd, E, T, heads = cfg.param_dtype, cfg.d_model, cfg.ssm_conv, kind.n_heads
     inner, channels = widths(cfg, heads)
-    k_in, k_conv, k_a, k_dt, k_out = jax.random.split(key, 5)
+    k_in, k_conv, k_a, k_dt, k_out = jax.random.split(jax.random.fold_in(key, 6), 5)
     steps = jnp.maximum(jnp.exp(jax.random.uniform(k_dt, (L, heads), jnp.float32, jnp.log(0.001), jnp.log(0.1))), 1e-4)
     normal = lambda k, shape, fan_in: (jax.random.normal(k, (L,) + shape, pd) * fan_in ** -0.5).astype(pd)   # noqa: E731
     return {
@@ -79,8 +82,6 @@ def _before(u, dt_raw, w, cfg, heads: int):
     step's projection dt_raw [B, S, H] to x and dt * x [B, S, H P], B and C
     [B, S, G N] in u's type, the log decay [B, S, H] float32, and the decay's
     mean."""
-    from torchft_tpu.models.transformer import _causal_conv
-
     f32, dt_ = jnp.float32, u.dtype
     B, S, _ = u.shape
     P, inner = cfg.ssm_head_dim, widths(cfg, heads)[0]
@@ -141,3 +142,16 @@ def mamba2_mixer(cfg, kind, mesh, h: jax.Array, w: Dict[str, Any]) -> Tuple[jax.
         o = jax.checkpoint(lambda *a: _after(*a, cfg, heads))(y, x, z, small)
     with jax.named_scope("attn_proj"):
         return o @ w["ssm_out"].astype(dt_), decay
+
+
+def _forward(cfg, kind, mesh, rules, h, w, positions):
+    out, decay = mamba2_mixer(cfg, kind, mesh, h, w)
+    return out, {"ssm_decay": decay}
+
+
+def _check(cfg, kind) -> None:
+    assert kind.n_heads % cfg.ssm_groups == 0, "a group is a whole number of heads"
+
+
+MAMBA2 = Mixer(init_mamba2, mamba2_axes, _forward, SAVED_NAMES, mean_statistic=("ssm_decay", "ssm_decay_mean"),
+               check=_check)

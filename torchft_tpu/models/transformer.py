@@ -8,6 +8,11 @@ TPU-first design choices:
   - hot ops route through torchft_tpu.ops: fused pallas RMSNorm and flash
     attention; ring attention over the "sequence" mesh axis for long
     context;
+  - a block's mixer is an entry of a table (models/mixers.MIXERS, a file a
+    family beside this one): this file holds the configuration, the tree, the
+    walk of the layers, the feed-forward, the head and the losses, and asks
+    the entry for a mixer's leaves, axes, forward pass, kept names and
+    statistic;
   - ``jax.checkpoint`` on the layer body: rematerialize instead of storing
     per-layer activations (HBM is the bottleneck);
   - every array axis has a logical name; sharding is applied by annotation
@@ -21,14 +26,16 @@ build's first-party equivalent of that model class.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.ops import flash_attention, rms_norm
+from torchft_tpu.models.attention import _index_operands  # noqa: F401 — benchmark/tools/selection_ties.py takes it from here
+from torchft_tpu.models.mixer import Mixer, _norm_init
+from torchft_tpu.models.mixers import MIXERS
+from torchft_tpu.ops import rms_norm
 from torchft_tpu.parallel.sharding import ShardingRules, constrain
 
 
@@ -49,22 +56,9 @@ class LayerKind:
     # attention_factor) — `yarn_frequencies` in place of theta's powers, cos
     # and sin times the attention factor.
     yarn: Optional[Tuple[float, int, float, float, float]] = None
-    # "attention": q, k and v are products of the layer's normed input,
-    # position by position.  "cca": attention inside a compressed latent
-    # (compressed convolutional attention, arXiv:2510.04476; `_cca_qkv`) —
-    # between the projections and the attention call a causal convolution a
-    # channel, one a head over sequence and channels (both of kernel 2), the
-    # mean of the un-convolved q and k added back, half of the KV heads'
-    # values taken from the position before, and an L2 norm a head.
-    # "mla": latent attention (the model's `mla_*` widths; `_mla_qkv`) at this
-    # kind's heads, its `mla_rope_dim` columns rotated where `rotary_fraction`
-    # is not 0 and plain content where it is (NoPE).  "kda": Kimi Delta
-    # Attention (arXiv:2510.26692; `_kda_mixer`) — no softmax and no position
-    # term: a gated delta rule with a decay a channel over `n_heads` heads of
-    # `kda_head_dim`, under short causal convolutions and low-rank gates.
-    # "mamba2": a Mamba-2 state-space mixer (arXiv:2405.21060; models/mamba.py)
-    # over `n_heads` heads of the model's `ssm_*` sizes.  "none": the block has
-    # no mixer — it is a feed-forward alone under its one norm (`mlp_norm`).
+    # An entry of `models/mixers.MIXERS` — "attention", "mla", "cca" (models/attention.py), "kda" (models/kda.py),
+    # "mamba2" (models/mamba.py): each file's docstring says what it computes — or "none": the block has no mixer,
+    # it is a feed-forward alone under its one norm (`mlp_norm`).
     mixer: str = "attention"
     # False: the block is a mixer alone under its one norm (`attn_norm`): no
     # second norm, no feed-forward, no such leaves in its stack.
@@ -260,26 +254,16 @@ class TransformerConfig:
         if self.pattern:
             assert len(self.pattern) == self.n_layers and not self.moe_dense_layers, "one kind a layer"
             assert self.attention == "flash" and not self.dsa_index_heads, "a pattern's kinds run the flash backend"
-            assert all(kind.mixer in ("attention", "cca", "mla", "kda", "mamba2", "none") for kind in self.pattern)
-            assert all((kind.feed_forward or not kind.sparse) and (kind.feed_forward or kind.mixer != "none")
-                       for kind in self.pattern), "a block is a mixer, a feed-forward, or both"
-            assert all(kind.n_heads % self.ssm_groups == 0 for kind in self.pattern if kind.mixer == "mamba2")
-            assert len({"kda", "mamba2"} & {kind.mixer for kind in self.pattern}) < 2, "one decay's mean is counted"
-            assert not (self.moe_router_early and any(kind.mixer == "none" for kind in self.pattern)), (
-                "an early router reads the input of a block that has a mixer"
-            )
-            assert bool(self.mla_kv_rank) == any(kind.mixer == "mla" for kind in self.pattern), (
-                "the latent widths are the model's, the layers that use them the pattern's"
-            )
-            if any(kind.mixer in ("mla", "kda") for kind in self.pattern):
-                assert not (self.qk_norm or self.qk_norm_per_head or self.attn_head_gate), (
-                    "latent and delta attention have no QK-norm and no head gate of the model's"
-                )
-            if any(kind.mixer == "cca" for kind in self.pattern):
-                assert not (self.qk_norm or self.qk_norm_per_head or self.attn_head_gate), (
-                    "compressed attention norms its own heads and has no gate"
-                )
-                assert self.n_kv_heads % 2 == 0 and all(k.n_heads % self.n_kv_heads == 0 for k in self.pattern)
+            assert all(kind.mixer == "none" or kind.mixer in MIXERS for kind in self.pattern), (
+                f"a kind's mixer is one of {sorted(MIXERS)}, or 'none'")
+            for kind in self.pattern:
+                mixer = _mixer(kind)
+                assert (kind.feed_forward or not kind.sparse) and (kind.feed_forward or mixer is not None), (
+                    "a block is a mixer, a feed-forward, or both")
+                assert not (self.moe_router_early and mixer is None), (
+                    "an early router reads the input of a block that has a mixer")
+                if mixer is not None and mixer.check is not None:
+                    mixer.check(self, kind)
             assert all(a == b for a in self.pattern for b in self.pattern if a.stack == b.stack), "one kind a stack"
             assert all(self.moe_experts > 0 for kind in self.pattern if kind.sparse)
         if self.moe_router_state or self.moe_skip:
@@ -331,84 +315,40 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
+def _mixer(kind: LayerKind) -> Optional[Mixer]:
+    """The kind's entry of `MIXERS`; None: the block has no mixer ("none")."""
+    return MIXERS.get(kind.mixer)
+
+
 # Logical axis names for every parameter (see parallel/sharding.py).
 def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
-    sparse = kind.sparse
-    layer = {
-        "attn_norm": ("layers", "embed"),
-        "wq": ("layers", "embed", "heads"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", "embed"),
-        "w_gate": ("layers", "embed", "mlp"),
-        "w_up": ("layers", "embed", "mlp"),
-        "w_down": ("layers", "mlp", "embed"),
-    }
-    if kind.mixer == "mla":
-        layer.update({"wkv_a": ("layers", "embed", None), "kv_norm": ("layers", None),
-                      "wkv_b": ("layers", None, "heads")})
-    elif kind.mixer == "kda":
-        layer.update({"wk": ("layers", "embed", "heads"), "wv": ("layers", "embed", "heads")})
-        layer.update({name: ("layers", None, "heads") for name in ("kda_conv_q", "kda_conv_k", "kda_conv_v",
-                                                                    "kda_a_up", "kda_g_up")})
-        layer.update({"kda_a_down": ("layers", "embed", None), "kda_g_down": ("layers", "embed", None),
-                      "kda_beta": ("layers", "embed", None), "A_log": ("layers", None), "dt_bias": ("layers", "heads"),
-                      "kda_g_bias": ("layers", "heads"), "kda_norm": ("layers", None)})
-    elif kind.mixer == "mamba2":
-        from torchft_tpu.models.mamba import mamba2_axes
-
-        layer.update(mamba2_axes())
-    else:
-        layer.update({"wk": ("layers", "embed", "kv_heads"), "wv": ("layers", "embed", "kv_heads")})
-    if cfg.qk_norm:
-        layer.update({"q_norm": ("layers", "heads"), "k_norm": ("layers", "kv_heads")})
-    if cfg.qk_norm_per_head:
-        layer.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
-    if cfg.dsa_index_heads:
-        layer.update({"wi_q": ("layers", "embed", None), "wi_k": ("layers", "embed", None),
-                      "wi_k_norm": ("layers", None), "wi_k_bias": ("layers", None),
-                      "wi_w": ("layers", "embed", None)})
-    if cfg.attn_head_gate:
-        layer["attn_gate"] = ("layers", "embed", "heads")
-    if kind.mixer == "cca":
-        layer.update({"cca_conv0": ("layers", None, None), "cca_bias0": ("layers", None),
-                      "cca_conv1": ("layers", None, None, None, None), "cca_bias1": ("layers", None, None),
-                      "cca_temp": ("layers", None)})
+    """A stack's leaves are its mixer's (`Mixer.axes`) under `attn_norm`, the
+    learned merges', and the feed-forward's under `mlp_norm` — each only where
+    the kind has the part."""
+    layer: Dict[str, Any] = {}
+    mixer = _mixer(kind)
+    if mixer is not None:
+        layer.update({"attn_norm": ("layers", "embed")}, **mixer.axes(cfg, kind))
     if cfg.scaled_merge:
         layer.update({"attn_merge": ("layers", None, "embed"), "mlp_merge": ("layers", None, "embed")})
-    if sparse:
-        layer.update(
-            {
-                "router": _state_router_axes() if cfg.moe_router_state else ("layers", "embed", "expert"),
-                "w_gate": ("layers", "expert", "embed", "mlp"),
-                "w_up": ("layers", "expert", "embed", "mlp"),
-                "w_down": ("layers", "expert", "mlp", "embed"),
-            }
-        )
-        if cfg.moe_shared_experts:
-            layer.update({"shared_gate": ("layers", "embed", "mlp"), "shared_up": ("layers", "embed", "mlp"),
-                          "shared_down": ("layers", "mlp", "embed")})
-    return _leaves_of_the_kind(cfg, kind, layer)
-
-
-_MIXER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo")
-_FEED_FORWARD_LEAVES = ("mlp_norm", "w_gate", "w_up", "w_down")
-
-
-def _leaves_of_the_kind(cfg: TransformerConfig, kind: LayerKind, layer: Dict[str, Any]) -> Dict[str, Any]:
-    """``layer`` (a stack's leaves, or their axes) without what the kind does
-    not have: the mixer's of a block that is a feed-forward alone, the
-    feed-forward's of a block that is a mixer alone, attention's projections
-    of a Mamba-2 block, the gate matrices of un-gated feed-forwards."""
-    drop = set()
-    if kind.mixer == "none":
-        drop.update(_MIXER_LEAVES)
-    if kind.mixer == "mamba2":
-        drop.update(_MIXER_LEAVES[1:])
     if not kind.feed_forward:
-        drop.update(_FEED_FORWARD_LEAVES)
-    if cfg.moe_activation == "relu2":
-        drop.update(("w_gate", "shared_gate"))
-    return {name: leaf for name, leaf in layer.items() if name not in drop} if drop else layer
+        return layer
+    layer["mlp_norm"] = ("layers", "embed")
+    if kind.sparse:
+        ffn = {
+            "router": _state_router_axes() if cfg.moe_router_state else ("layers", "embed", "expert"),
+            "w_gate": ("layers", "expert", "embed", "mlp"),
+            "w_up": ("layers", "expert", "embed", "mlp"),
+            "w_down": ("layers", "expert", "mlp", "embed"),
+        }
+        if cfg.moe_shared_experts:
+            ffn.update({"shared_gate": ("layers", "embed", "mlp"), "shared_up": ("layers", "embed", "mlp"),
+                        "shared_down": ("layers", "mlp", "embed")})
+    else:
+        ffn = {"w_gate": ("layers", "embed", "mlp"), "w_up": ("layers", "embed", "mlp"), "w_down": ("layers", "mlp", "embed")}
+    gated = cfg.moe_activation != "relu2"  # un-gated feed-forwards have no gate matrices
+    layer.update({name: axes for name, axes in ffn.items() if gated or name not in ("w_gate", "shared_gate")})
+    return layer
 
 
 def _state_router_axes() -> Dict[str, Any]:
@@ -428,79 +368,28 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     return axes
 
 
-def _norm_init(k, shape, fan_in, pd):
-    return (jax.random.normal(k, shape, pd) * (fan_in ** -0.5)).astype(pd)
-
-
 def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind) -> Dict[str, Any]:
+    """A stack of L layers of one kind.  The key split in eight: the first
+    four are the mixer's projections' (`Mixer.init` takes the stack's key and
+    splits it so itself), the last four the feed-forward's."""
     pd = cfg.param_dtype
-    E, H, KV, sparse = cfg.d_model, kind.n_heads, cfg.n_kv_heads, kind.sparse
+    E, sparse = cfg.d_model, kind.sparse
 
     def norm_init(k, shape, fan_in):
         return _norm_init(k, shape, fan_in, pd)
 
     ks = jax.random.split(key, 8)
-    layers = {"attn_norm": jnp.ones((L, E), pd), "mlp_norm": jnp.ones((L, E), pd)}
-    if kind.mixer == "kda":
-        layers.update(_init_kda(jax.random.fold_in(key, 5), cfg, L, H))
-    elif kind.mixer == "mamba2":
-        from torchft_tpu.models.mamba import init_mamba2
-
-        layers.update(init_mamba2(jax.random.fold_in(key, 6), cfg, L, H))
-    elif kind.mixer == "mla":
-        R, Dq = cfg.mla_kv_rank, cfg.mla_nope_dim + cfg.mla_rope_dim
-        layers.update(
-            {
-                "wq": norm_init(ks[0], (L, E, H * Dq), E),
-                "wkv_a": norm_init(ks[1], (L, E, R + cfg.mla_rope_dim), E),
-                "kv_norm": jnp.ones((L, R), pd),
-                "wkv_b": norm_init(ks[2], (L, R, H * (cfg.mla_nope_dim + cfg.mla_v_dim)), R),
-                "wo": norm_init(ks[3], (L, H * cfg.mla_v_dim, E), H * cfg.mla_v_dim),
-            }
-        )
-    else:
-        Dh = cfg.d_head
-        layers.update(
-            {
-                "wq": norm_init(ks[0], (L, E, H * Dh), E),
-                "wk": norm_init(ks[1], (L, E, KV * Dh), E),
-                "wv": norm_init(ks[2], (L, E, KV * Dh), E),
-                "wo": norm_init(ks[3], (L, H * Dh, E), H * Dh),
-            }
-        )
-        if cfg.qk_norm:
-            layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
-        if cfg.qk_norm_per_head:
-            layers.update({"q_norm": jnp.ones((L, Dh), pd), "k_norm": jnp.ones((L, Dh), pd)})
-    if cfg.dsa_index_heads:
-        J, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
-        kq, kk, kw = jax.random.split(jax.random.fold_in(key, 2), 3)
-        layers.update(
-            {
-                "wi_q": norm_init(kq, (L, E, J * Di), E),
-                "wi_k": norm_init(kk, (L, E, Di), E),
-                "wi_k_norm": jnp.ones((L, Di), pd),
-                "wi_k_bias": jnp.zeros((L, Di), pd),
-                "wi_w": norm_init(kw, (L, E, J), E),
-            }
-        )
-    if cfg.attn_head_gate:
-        layers["attn_gate"] = norm_init(jax.random.fold_in(key, 3), (L, E, H), E)
-    if kind.mixer == "cca":
-        C, Dh = H + KV, cfg.d_head  # the convolutions run over q's and k's heads side by side
-        k0, k1 = jax.random.split(jax.random.fold_in(key, 4))
-        layers.update(
-            {
-                "cca_conv0": norm_init(k0, (L, 2, C * Dh), 2),         # [tap, channel]: tap 1 the position itself
-                "cca_bias0": jnp.zeros((L, C * Dh), pd),
-                "cca_conv1": norm_init(k1, (L, C, 2, Dh, Dh), 2 * Dh),  # [head, tap, channel in, channel out]
-                "cca_bias1": jnp.zeros((L, C, Dh), pd),
-                "cca_temp": jnp.ones((L, KV), pd),
-            }
-        )
+    layers: Dict[str, Any] = {}
+    mixer = _mixer(kind)
+    if mixer is not None:
+        layers.update({"attn_norm": jnp.ones((L, E), pd)}, **mixer.init(key, cfg, L, kind))
     if cfg.scaled_merge:
         merge = jnp.broadcast_to(jnp.asarray([1.0, 0.0, 1.0, 0.0], pd)[None, :, None], (L, 4, E))
         layers.update({"attn_merge": merge, "mlp_merge": jnp.array(merge)})  # two buffers: a step donates each leaf
+    if not kind.feed_forward:
+        return layers
+    layers["mlp_norm"] = jnp.ones((L, E), pd)
+    gated = cfg.moe_activation != "relu2"  # un-gated feed-forwards have no gate matrices
     if sparse:
         F, X, held = cfg.d_ff, cfg.n_router_outputs, cfg.n_held_experts
         kr, kg, ku, kd = jax.random.split(ks[7], 4)
@@ -516,54 +405,21 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
             }
         else:
             router = norm_init(kr, (L, E, X), E)
-        layers.update(
-            {
-                "router": router,
-                "w_gate": norm_init(kg, (L, held, E, F), E),
-                "w_up": norm_init(ku, (L, held, E, F), E),
-                "w_down": norm_init(kd, (L, held, F, E), F),
-            }
-        )
+        layers.update({"router": router, "w_up": norm_init(ku, (L, held, E, F), E), "w_down": norm_init(kd, (L, held, F, E), F)})
+        if gated:
+            layers["w_gate"] = norm_init(kg, (L, held, E, F), E)
         if cfg.moe_shared_experts:
             Fs = cfg.moe_shared_experts * F
             kg, ku, kd = jax.random.split(jax.random.fold_in(ks[7], 1), 3)
-            layers.update({"shared_gate": norm_init(kg, (L, E, Fs), E), "shared_up": norm_init(ku, (L, E, Fs), E),
-                           "shared_down": norm_init(kd, (L, Fs, E), Fs)})
+            layers.update({"shared_up": norm_init(ku, (L, E, Fs), E), "shared_down": norm_init(kd, (L, Fs, E), Fs)})
+            if gated:
+                layers["shared_gate"] = norm_init(kg, (L, E, Fs), E)
     else:
         F = cfg.dense_d_ff or cfg.d_ff
-        layers.update(
-            {
-                "w_gate": norm_init(ks[4], (L, E, F), E),
-                "w_up": norm_init(ks[5], (L, E, F), E),
-                "w_down": norm_init(ks[6], (L, F, E), F),
-            }
-        )
-    return _leaves_of_the_kind(cfg, kind, layers)
-
-
-def _init_kda(key: jax.Array, cfg: TransformerConfig, L: int, H: int) -> Dict[str, Any]:
-    """A stack of Kimi Delta Attention mixers: the published layer's
-    initialisation of the decay (A = log U(1, 16) a head, dt_bias the inverse
-    softplus of log-uniform steps in [0.001, 0.1] a channel), both float32."""
-    pd, E, D, T = cfg.param_dtype, cfg.d_model, cfg.kda_head_dim, cfg.kda_conv
-    keys = iter(jax.random.split(key, 14))
-
-    def normal(shape, fan_in):
-        return _norm_init(next(keys), (L,) + shape, fan_in, pd)
-
-    steps = jnp.exp(jax.random.uniform(next(keys), (L, H * D), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
-    return {
-        "wq": normal((E, H * D), E), "wk": normal((E, H * D), E), "wv": normal((E, H * D), E),
-        "wo": normal((H * D, E), H * D),
-        # [tap, channel]: the last tap the position itself
-        "kda_conv_q": normal((T, H * D), T), "kda_conv_k": normal((T, H * D), T), "kda_conv_v": normal((T, H * D), T),
-        "kda_a_down": normal((E, D), E), "kda_a_up": normal((D, H * D), D),
-        "A_log": jnp.log(jax.random.uniform(next(keys), (L, H), jnp.float32, 1.0, 16.0)),
-        "dt_bias": steps + jnp.log(-jnp.expm1(-steps)),  # the inverse of softplus
-        "kda_beta": normal((E, H), E),
-        "kda_g_down": normal((E, D), E), "kda_g_up": normal((D, H * D), D),
-        "kda_g_bias": jnp.zeros((L, H * D), pd), "kda_norm": jnp.ones((L, D), pd),
-    }
+        layers.update({"w_up": norm_init(ks[5], (L, E, F), E), "w_down": norm_init(ks[6], (L, F, E), F)})
+        if gated:
+            layers["w_gate"] = norm_init(ks[4], (L, E, F), E)
+    return layers
 
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -586,395 +442,6 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     for i, (stack, (kind, count)) in enumerate(reversed(cfg.stacks.items())):
         params[stack] = _init_layers(jax.random.fold_in(k_layers, i) if i else k_layers, cfg, count, kind)
     return params
-
-
-def _swap_halves(x: jax.Array, half: int) -> jax.Array:
-    """x with the two ``half``-column halves of each block of ``2 * half``
-    columns exchanged: the whole last axis moved ``half`` columns up and down
-    (`lax.pad` with one negative edge: zeros enter, nothing of x is cut out
-    or joined) and one of the two chosen by column.  On the TPU XLA fuses
-    this into its consumer as lane rotations of whole vregs; `jnp.roll`'s
-    slices and `concatenate` leave the fusion as 64-lane arrays in HBM
-    (`tools/rope_probe.py`; PERF.md section 6, PR 39)."""
-    edge, zero = [(0, 0, 0)] * (x.ndim - 1), jnp.zeros((), x.dtype)
-    up = jax.lax.pad(x, zero, edge + [(half, -half, 0)])    # up[..., i] = x[..., i - half]
-    down = jax.lax.pad(x, zero, edge + [(-half, half, 0)])  # down[..., i] = x[..., i + half]
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-    return jnp.where(lane % (2 * half) < half, down, up)
-
-
-def _turned(x: jax.Array, swapped: jax.Array, cos: jax.Array, sin: jax.Array, rot: int) -> jax.Array:
-    """``x * cos + swapped * sin`` in float32, cast back to x's dtype; the
-    columns from ``rot`` on are x's own."""
-    xf = x.astype(jnp.float32)
-    out = xf * cos + swapped * sin
-    if rot < x.shape[-1]:  # chosen, not multiplied by (1, 0): what is not finite there stays where it was
-        out = jnp.where(jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) < rot, out, xf)
-    return out.astype(x.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _turn_whole(x: jax.Array, cos: jax.Array, sin: jax.Array, half: int, rot: int) -> jax.Array:
-    """A head turned as a whole: ``x * cos + swapped(x) * sin`` in float32
-    under tables as wide as the head, cos = [c, c, 1] and sin = [-s, s, 0]
-    over (first half, second half, columns that pass).  x is cast first and
-    swapped in float32: on the TPU XLA hands the product's float32 result to
-    the turn unrounded, as it did to the two-halves form, and a swap of x in
-    its own dtype would make the product round it first."""
-    return _turned(x, _swap_halves(x.astype(jnp.float32), half), cos, sin, rot)
-
-
-def _turn_whole_bwd(half: int, rot: int, tables, g: jax.Array):
-    """The transpose of a turn is the turn by the opposite angle:
-    swapped(g * sin) = swapped(g) * -sin, element by element what
-    differentiating the two halves gives, in one fused pass where autodiff's
-    transposes of the two pads are three.  g arrives in x's dtype from a
-    kernel or a sum, rounded already, so it is swapped as it is and cast
-    after: the same values, and half the bytes read.  The tables are
-    constants of the program (positions and frequencies): no gradient."""
-    cos, sin = tables
-    return _turned(g, _swap_halves(g, half).astype(jnp.float32), cos, -sin, rot), None, None
-
-
-_turn_whole.defvjp(lambda x, cos, sin, half, rot: (_turn_whole(x, cos, sin, half, rot), (cos, sin)), _turn_whole_bwd)
-
-
-def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float, rot: int,
-          head_major: bool = False) -> jax.Array:
-    """The leading ``rot`` columns of x [B, S, H, D] ([B, H, S, D] where
-    ``head_major``) turned by positions
-    [B, S] x inv_freq [rot / 2] in half-split pairs (i, i + rot / 2), cos and
-    sin times ``factor``; the other columns pass through.  Float32, two
-    products and one sum an element, under tables as wide as the head, so
-    that no piece of q or k is narrower than the head is (`_turn_whole`)."""
-    import numpy as np
-
-    D, half = x.shape[-1], rot // 2
-    lane = np.arange(D)
-    angles = positions[..., None].astype(jnp.float32) * jnp.take(inv_freq, lane % half)  # [B, S, D]
-    cos = jnp.where(lane < rot, jnp.cos(angles) * np.float32(factor), 1.0)
-    sin = jnp.where(lane < rot, jnp.sin(angles) * np.where(lane < half, -factor, factor).astype(np.float32), 0.0)
-    if head_major:
-        return _turn_whole(x, cos[:, None], sin[:, None], half, rot)
-    return _turn_whole(x, cos[:, :, None, :], sin[:, :, None, :], half, rot)
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: [B, S, H, Dh], positions: [B, S] (global)."""
-    d_half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, d_half, dtype=jnp.float32) / d_half)
-    return _turn(x, positions, freqs, 1.0, x.shape[-1])
-
-
-def yarn_frequencies(theta: float, rot_dim: int, factor: float, original: int,
-                     beta_fast: float, beta_slow: float):
-    """YaRN's inverse frequencies for the rot_dim / 2 rotary pairs, float64 on
-    the host: pair i turns by theta**(-2i/rot_dim) where it makes more than
-    beta_fast turns over the original length, by that over ``factor`` where it
-    makes fewer than beta_slow, and by their blend along a linear ramp between
-    the two correction dimensions (rounded outward, as the published code)."""
-    import math
-
-    import numpy as np
-
-    def correction_dim(turns: float) -> float:
-        return rot_dim * math.log(original / (turns * 2 * math.pi)) / (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), rot_dim - 1)
-    pair = np.arange(rot_dim // 2, dtype=np.float64)
-    plain = theta ** (-2.0 * pair / rot_dim)
-    ramp = np.clip((pair - low) / ((high if high != low else high + 0.001) - low), 0.0, 1.0)
-    return plain / factor * ramp + plain * (1.0 - ramp)
-
-
-def _rotary(x: jax.Array, positions: jax.Array, kind: LayerKind, head_major: bool = False) -> jax.Array:
-    """RoPE as the layer's kind has it: over the leading ``rotary_fraction``
-    of a head's columns (half-split pairs inside that part, the rest passes
-    through), at theta's powers or YaRN's frequencies — a constant of the
-    program — with cos and sin times YaRN's attention factor."""
-    if kind.rotary_fraction == 1.0 and kind.yarn is None and not head_major:
-        return _rope(x, positions, kind.rope_theta)
-    import numpy as np
-
-    rot = int(x.shape[-1] * kind.rotary_fraction)
-    half = rot // 2
-    if kind.yarn is None:
-        inv_freq, factor = kind.rope_theta ** (-np.arange(half, dtype=np.float64) / half), 1.0
-    else:
-        inv_freq, factor = yarn_frequencies(kind.rope_theta, rot, *kind.yarn[:4]), kind.yarn[4]
-    inv_freq = jnp.asarray(inv_freq, jnp.float32)
-    if head_major:
-        return _turn(x, positions, inv_freq, factor, rot, head_major=True)
-    return _turn(x, positions, inv_freq, factor, rot)
-
-
-def _attention(cfg: TransformerConfig, mesh, q, k, v, kind: LayerKind):
-    """q/k/v: [B, H|KV, S, Dh] head-major."""
-    seq_parallel = (
-        cfg.attention in ("ring", "ulysses")
-        and mesh is not None
-        and "sequence" in mesh.axis_names
-        and mesh.shape["sequence"] > 1
-    )
-    if cfg.attention != "flash" and not seq_parallel:
-        # Trace-time (once per compile), not per step.
-        import warnings
-
-        warnings.warn(
-            f"attention={cfg.attention!r} requested but the mesh has no "
-            ">1-sized 'sequence' axis; falling back to single-shard flash "
-            "attention",
-            stacklevel=2,
-        )
-    if seq_parallel:
-        if cfg.attention == "ring":
-            from torchft_tpu.ops.ring_attention import ring_attention_sharded as fn
-
-            # The ring body assumes equal q/kv head counts.
-            broadcast_gqa = cfg.n_kv_heads != kind.n_heads
-        else:
-            from torchft_tpu.ops.ulysses import ulysses_attention_sharded as fn
-
-            # Ulysses keeps GQA compressed through the all_to_all (the local
-            # flash kernel broadcasts groups afterwards) unless the kv heads
-            # PER TENSOR-PARALLEL SHARD don't tile the sequence axis — the
-            # divisibility the local body actually requires.
-            tp = mesh.shape.get("tensor", 1) if "tensor" in mesh.axis_names else 1
-            broadcast_gqa = (
-                cfg.n_kv_heads != kind.n_heads
-                and (cfg.n_kv_heads // tp) % mesh.shape["sequence"] != 0
-            )
-        if broadcast_gqa:
-            rep = kind.n_heads // cfg.n_kv_heads
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
-        kwargs = {}
-        if cfg.attention == "ring":
-            kwargs["layout"] = cfg.ring_layout
-        return fn(
-            mesh, q, k, v, causal=True,
-            batch_axis="data" if "data" in mesh.axis_names else None,
-            head_axis="tensor" if "tensor" in mesh.axis_names else None,
-            seq_axis="sequence",
-            **kwargs,
-        )
-    return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window)
-
-
-def _mla_qkv(cfg: TransformerConfig, kind: LayerKind, h, w, positions):
-    """Latent attention's q, k [B, S, H, nope + rope] and v [B, S, H, v]
-    from the normed input h [B, S, E]: the keys' and values' content
-    through the low-rank path, one rotary key for all heads — or, where the
-    kind has no rotation (`rotary_fraction` 0), those columns as they are
-    projected: content like the others, the one key still every head's."""
-    B, S, _ = h.shape
-    H, Dn, Dr, Dv, R = kind.n_heads, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim, cfg.mla_kv_rank
-    q = (h @ w["wq"].astype(cfg.dtype)).reshape(B, S, H, Dn + Dr)
-    latent = h @ w["wkv_a"].astype(cfg.dtype)                       # [B, S, R + Dr]
-    with jax.named_scope("norm"):
-        kv = rms_norm(latent[..., :R], w["kv_norm"], cfg.rms_eps)
-    kv = kv @ w["wkv_b"].astype(cfg.dtype)
-    kv = kv.reshape(B, S, H, Dn + Dv)
-    if not kind.rotary_fraction:
-        k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(latent[..., None, R:], (B, S, H, Dr))], axis=-1)
-        return q, k, kv[..., Dn:]
-    q_rope = _rope(q[..., Dn:], positions, kind.rope_theta)
-    k_rope = _rope(latent[..., None, R:], positions, kind.rope_theta)  # [B, S, 1, Dr]
-    q = jnp.concatenate([q[..., :Dn], q_rope], axis=-1)
-    k = jnp.concatenate([kv[..., :Dn], jnp.broadcast_to(k_rope, (B, S, H, Dr))], axis=-1)
-    return q, k, kv[..., Dn:]
-
-
-def _index_operands(cfg: TransformerConfig, h, w, positions):
-    """The indexer's operands from the normed input h [B, S, E], which it
-    reads DETACHED: index queries [B, J, S, Di] and the one index key head
-    [B, S, Di], both after RoPE, and the per-query head weights [B, S, J] f32
-    with the two scale factors (J**-0.5, Di**-0.5) in them."""
-    B, S, _ = h.shape
-    J, Di = cfg.dsa_index_heads, cfg.dsa_index_dim
-    hd = jax.lax.stop_gradient(h)
-    a = _rope((hd @ w["wi_q"].astype(cfg.dtype)).reshape(B, S, J, Di), positions, cfg.rope_theta)
-    b = hd @ w["wi_k"].astype(cfg.dtype)
-    with jax.named_scope("norm"):
-        b = _layer_norm(b, w["wi_k_norm"], w["wi_k_bias"], cfg.rms_eps)
-    b = _rope(b[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
-    weights = (hd @ w["wi_w"].astype(cfg.dtype)).astype(jnp.float32) * (J ** -0.5 * Di ** -0.5)
-    return a.transpose(0, 2, 1, 3), b, weights
-
-
-def _sparse_attention(cfg: TransformerConfig, mesh, h, w, positions, q, k, v):
-    """Attention over the keys the layer's indexer selects; q/k/v head-major.
-    Returns (attention [B, H, S, Dh], {"dsa_index_loss", "dsa_selected"})."""
-    from torchft_tpu.ops.sparse_attention import sparse_attention
-
-    with jax.named_scope("dsa_index"):
-        a, b, weights = _index_operands(cfg, h, w, positions)
-    # `sparse_attention` names its own parts: dsa_select, attn, dsa_index
-    attn, index_loss, selected = sparse_attention(q, k, v, a, b, weights, topk=cfg.dsa_topk, mesh=mesh)
-    return attn, {"dsa_index_loss": index_loss, "dsa_selected": selected.astype(jnp.uint32)}
-
-
-def _layer_norm(x, w, b, eps):
-    """LayerNorm over the last axis, f32 statistics."""
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-    return ((xf - mean) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
-
-
-def _before(x: jax.Array) -> jax.Array:
-    """x [B, H, S, D] one position on: ``out[t] = x[t - 1]``, zeros before the
-    first (`lax.pad` with a negative edge; its transpose is the same move the
-    other way)."""
-    return jax.lax.pad(x, jnp.zeros((), x.dtype), [(0, 0, 0), (0, 0, 0), (1, -1, 0), (0, 0, 0)])
-
-
-def _cca_qkv(cfg: TransformerConfig, kind: LayerKind, h, w, positions):
-    """Compressed convolutional attention's q [B, H, S, D] and k, v
-    [B, G, S, D], head-major, from the normed input h [B, S, E]
-    (arXiv:2510.04476; LayerKind.mixer).  The projections are `attn_proj`'s;
-    what lies between them and RoPE — `cca_mix` — mixes positions and
-    channels, all of it linear but the norm, so its backward pass is the
-    mirrored shifts and the transposed products:
-
-        z = [q~ ; k~], the H + G projected heads side by side
-        z0_t = a1 * z_t + a0 * z_{t-1} + b0                (a weight a channel and tap)
-        z1_{t,h} = z0_{t,h} A_{h,1} + z0_{t-1,h} A_{h,0} + b1_h    (a [D, D] matrix a head and tap)
-        mu_j = (q~_j + k~_{g(j)}) / 2;  q_j = z1_{q,j} + mu_j;  k_g = z1_{k,g} + mean_{j in g} mu_j
-        q^ = sqrt(D) q / |q|;  k^ = tau_g sqrt(D) k / |k|   (float32)
-        v = the first half of the KV heads' values as projected, the second half's from the position before
-
-    Elementwise work is float32 inside its fusion and lands in the compute
-    type; the convolution a head is one batched product over both taps."""
-    B, S, _ = h.shape
-    H, G, D, dt = kind.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.dtype
-    f32 = jnp.float32
-
-    def heads(y, n):  # [B, S, n * D] -> [B, n, S, D]
-        return y.reshape(B, S, n, D).transpose(0, 2, 1, 3)
-
-    q0, k0 = heads(h @ w["wq"].astype(dt), H), heads(h @ w["wk"].astype(dt), G)
-    v = heads(h @ w["wv"].astype(dt), G)
-    with jax.named_scope("cca_mix"):
-        v = jnp.concatenate([v[:, : G // 2], _before(v[:, G // 2:])], axis=1)
-        z = jnp.concatenate([q0, k0], axis=1)                                  # [B, H + G, S, D]
-        taps = w["cca_conv0"].astype(f32).reshape(2, H + G, 1, D)
-        bias0 = w["cca_bias0"].astype(f32).reshape(H + G, 1, D)
-        z0 = (taps[1] * z.astype(f32) + taps[0] * _before(z).astype(f32) + bias0).astype(dt)
-        # both taps in one product a head: [z0_{t-1} ; z0_t] [S, 2D] times [A_0 ; A_1] [2D, D]
-        mats = w["cca_conv1"].astype(dt).reshape(H + G, 2 * D, D)
-        z1 = jnp.einsum("bhsc,hcd->bhsd", jnp.concatenate([_before(z0), z0], axis=-1), mats)
-        z1 = z1.astype(f32) + w["cca_bias1"].astype(f32)[:, None, :]
-        mu = 0.5 * (q0.astype(f32).reshape(B, G, H // G, S, D) + k0.astype(f32)[:, :, None])
-        q = z1[:, :H] + mu.reshape(B, H, S, D)
-        k = z1[:, H:] + jnp.mean(mu, axis=2)
-        q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True)) * D ** 0.5)
-        k = k * (jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True)) * D ** 0.5
-                 * w["cca_temp"].astype(f32)[:, None, None])
-    q, k = (_rotary(a, positions, kind, head_major=True).astype(dt) for a in (q, k))
-    return q, k, v
-
-
-def _causal_conv(z, taps):
-    """A depthwise causal convolution over the sequence: z [B, S, C], taps
-    [T, C] float32 with the LAST tap the position's own, ``out_t = sum_i
-    taps[i] * z_{t - (T - 1) + i}``, zeros before the first position (`lax.pad`
-    with a negative edge; its transpose is the same move the other way)."""
-    n = taps.shape[0]
-    out = taps[n - 1] * z
-    for back in range(1, n):
-        shifted = jax.lax.pad(z, jnp.zeros((), z.dtype), [(0, 0, 0), (back, -back, 0), (0, 0, 0)])
-        out = out + taps[n - 1 - back] * shifted
-    return out
-
-
-def _l2(x):
-    """x over its last axis' L2 norm (float32 in, float32 out)."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
-_KDA_SMALL = ("kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_norm", "kda_g_bias")
-
-
-def _kda_before(q0, k0, v0, a, b, w, H):
-    """`kda_mix` before the scan, in XLA: the projections q0, k0, v0, a
-    [B, S, H * D] and b [B, S, H] to the scan's q, k, v [B, H, S, D] in their
-    type, g [B, H, S, D] and beta [B, H, S] float32, and the decay's mean."""
-    B, S, D, dt, f32 = q0.shape[0], q0.shape[1], q0.shape[2] // H, q0.dtype, jnp.float32
-
-    def heads(y):  # [B, S, H * D] -> [B, H, S, D]
-        return y.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-
-    with jax.named_scope("kda_mix"):
-        q, k, v = (jax.nn.silu(_causal_conv(z.astype(f32), w[name].astype(f32)))
-                   for z, name in ((q0, "kda_conv_q"), (k0, "kda_conv_k"), (v0, "kda_conv_v")))
-        q, k = _l2(q.reshape(B, S, H, D)) * D ** -0.5, _l2(k.reshape(B, S, H, D))
-        rate = jnp.repeat(jnp.exp(w["A_log"].astype(f32)), D)                    # [H * D]
-        g = -rate * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))    # [B, S, H * D]
-        alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
-        beta = jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)                  # [B, H, S]
-        return heads(q.astype(dt)), heads(k.astype(dt)), heads(v.astype(dt)), heads(g), beta, alpha
-
-
-def _kda_after(o, gate, w, eps):
-    """`kda_mix` after the scan, in XLA: o [B, H, S, D] under the head norm
-    and the sigmoid of the gate's projection [B, S, H * D], in the gate's type."""
-    B, H, S, D = o.shape
-    f32 = jnp.float32
-    with jax.named_scope("kda_mix"):
-        o = o.transpose(0, 2, 1, 3).astype(f32)                                  # [B, S, H, D]
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * w["kda_norm"].astype(f32)
-        gate_ = jax.nn.sigmoid(gate.astype(f32) + w["kda_g_bias"].astype(f32))
-        return (o.reshape(B, S, H * D) * gate_).astype(gate.dtype)
-
-
-def _kda_mixer(cfg: TransformerConfig, kind: LayerKind, mesh, h, w):
-    """Kimi Delta Attention from the normed input h [B, S, E] to the heads'
-    joined output [B, S, H * D], before `wo` (arXiv:2510.26692;
-    LayerKind.mixer).  The projections are `attn_proj`'s, the recurrence
-    `kda_scan`'s (`ops.delta_attention.kda`), and `kda_mix` is what lies
-    between: a causal convolution of kernel `kda_conv` and SiLU on each of
-    q~, k~, v~; an L2 norm a head on q (times D**-0.5) and k; the decay
-    ``g = -exp(A_log) softplus(a + dt_bias)`` a channel and ``beta =
-    sigmoid(.)`` a head, both float32; after the scan an RMSNorm over each
-    head's columns (one weight of D) under a sigmoid gate.  Also returns the
-    mean of the decay exp(g) over the layer (`kda_alpha_mean`'s term).
-    Elementwise work is float32 inside its fusion — or, where
-    `ops.kda_mix.applies` (a TPU's program over one device, heads of 128
-    columns), inside the `tpuft_kdamix_*` kernels' tile — and lands in the
-    compute type."""
-    from torchft_tpu.ops import kda_mix
-    from torchft_tpu.ops.delta_attention import kda
-
-    S = h.shape[1]
-    H, D, dt, f32 = kind.n_heads, cfg.kda_head_dim, cfg.dtype, jnp.float32
-    with jax.named_scope("attn_proj"):
-        q0, k0, v0 = (h @ w[name].astype(dt) for name in ("wq", "wk", "wv"))
-        a = (h @ w["kda_a_down"].astype(dt)) @ w["kda_a_up"].astype(dt)
-        gate = (h @ w["kda_g_down"].astype(dt)) @ w["kda_g_up"].astype(dt)
-        b = h @ w["kda_beta"].astype(dt)
-
-    small = {name: w[name] for name in _KDA_SMALL}
-    kernels = cfg.kda_conv == kda_mix.TAPS and kda_mix.applies(S, D, mesh)
-    if kernels:
-        with jax.named_scope("kda_mix"):
-            q, k, v, g = kda_mix.before(q0, k0, v0, a, *(small[name] for name in _KDA_SMALL[:5]))
-            alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
-            beta = jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)                  # [B, H, S]
-    else:
-        # The XLA halves keep their INPUTS for the backward pass and nothing between (a checkpoint each): left to
-        # autodiff, a layer holds some twenty float32 arrays of [S, H * D] at once (the convolutions' sums, SiLU's
-        # and softplus' arguments, the norms' squares), 268 MB each at the benchmark's size.
-        q, k, v, g, beta, alpha = jax.checkpoint(lambda *xs: _kda_before(*xs, H))(q0, k0, v0, a, b, small)
-    with jax.named_scope("kda_scan"):
-        o = kda(q, k, v, g, beta, mesh=mesh)
-    if kernels:
-        with jax.named_scope("kda_mix"):
-            o = kda_mix.after(o, gate, small["kda_norm"], small["kda_g_bias"], eps=cfg.rms_eps)
-    else:
-        o = jax.checkpoint(lambda *xs: _kda_after(*xs, cfg.rms_eps))(o, gate, small)
-    return o, alpha
 
 
 def _merge(x, y, vectors=None):
@@ -1000,7 +467,8 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     B, S, E = x.shape
     kind = cfg.layers[-1] if kind is None else kind
     H, KV = kind.n_heads, cfg.n_kv_heads
-    if kind.mixer == "none":  # a feed-forward alone: its one norm is `_feed_forward`'s
+    mixer = _mixer(kind)
+    if mixer is None:  # a feed-forward alone: its one norm is `_feed_forward`'s
         return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, None)
 
     routed = None
@@ -1012,65 +480,10 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     # (obs/spans.PARTS); they name the work and change no instruction.
     with jax.named_scope("norm"):
         h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
-    if kind.mixer == "kda":
-        attn, alpha = _kda_mixer(cfg, kind, mesh, h, w)
-        with jax.named_scope("attn_proj"):
-            x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
-            x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
-        return _feed_forward(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"kda_alpha": alpha}, routed)
-    if kind.mixer == "mamba2":
-        from torchft_tpu.models.mamba import mamba2_mixer
-
-        out, decay = mamba2_mixer(cfg, kind, mesh, h, w)
-        with jax.named_scope("attn_proj"):
-            x = constrain(_merge(x, out, w.get("attn_merge")), ("batch", "seq", "embed"), mesh, rules)
-        return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, {"ssm_decay": decay}, routed)
+    y, mixer_stats = mixer.forward(cfg, kind, mesh, rules, h, w, positions)
     with jax.named_scope("attn_proj"):
-        if kind.mixer == "cca":
-            q, k, v = _cca_qkv(cfg, kind, h, w, positions)
-        elif kind.mixer == "mla":
-            q, k, v = _mla_qkv(cfg, kind, h, w, positions)
-            KV = H
-        else:
-            Dh = cfg.d_head
-            q = h @ w["wq"].astype(cfg.dtype)
-            if cfg.qk_norm:
-                with jax.named_scope("norm"):
-                    q = rms_norm(q, w["q_norm"], cfg.rms_eps)
-            q = q.reshape(B, S, H, Dh)
-            k = h @ w["wk"].astype(cfg.dtype)
-            if cfg.qk_norm:
-                with jax.named_scope("norm"):
-                    k = rms_norm(k, w["k_norm"], cfg.rms_eps)
-            k = k.reshape(B, S, KV, Dh)
-            if cfg.qk_norm_per_head:
-                with jax.named_scope("norm"):
-                    q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
-            v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
-            if kind.rotary_fraction:  # 0: no position term, q and k are the projections
-                q = _rotary(q, positions, kind)
-                k = _rotary(k, positions, kind)
-        if cfg.attn_head_gate:
-            head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
-        # compressed attention's heads are head-major already
-        major = (lambda a: a) if kind.mixer == "cca" else (lambda a: a.transpose(0, 2, 1, 3))
-        q = constrain(major(q), ("batch", "heads", "seq", None), mesh, rules)
-        k = constrain(major(k), ("batch", "kv_heads", "seq", None), mesh, rules)
-        v = constrain(major(v), ("batch", "kv_heads", "seq", None), mesh, rules)
-    dsa = None
-    if cfg.dsa_index_heads:
-        attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
-    else:
-        with jax.named_scope("attn" if kind.window is None else "attn_window"):
-            attn = _attention(cfg, mesh, q, k, v, kind)  # [B, H, S, Dv]
-    with jax.named_scope("attn_proj"):
-        attn = attn.transpose(0, 2, 1, 3)
-        if cfg.attn_head_gate:
-            attn = attn * head_gate[..., None]
-        attn = attn.reshape(B, S, H * attn.shape[-1])
-        x = _merge(x, attn @ w["wo"].astype(cfg.dtype), w.get("attn_merge"))
-        x = constrain(x, ("batch", "seq", "embed"), mesh, rules)
-    return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, dsa, routed)
+        x = constrain(_merge(x, y, w.get("attn_merge")), ("batch", "seq", "embed"), mesh, rules)
+    return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, mixer_stats, routed)
 
 
 def _after_the_mixer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind: LayerKind, router_bias,
@@ -1197,18 +610,21 @@ def _decoder(
             pieces.append(jax.tree.map(lambda *a: jnp.stack(a), *pending))
             pending.clear()
 
-    alpha_total, kda_layers = jnp.zeros((), jnp.float32), sum(kind.mixer == "kda" for kind in cfg.layers)
-    ssm_layers = sum(kind.mixer == "mamba2" for kind in cfg.layers)
+    # A mixer's mean statistic (a decay's mean) is a scalar a layer of that mixer alone: summed by name, apart from
+    # the statistics every layer of a run has and the runs stack.
+    means = _mean_statistics(cfg)
+    totals = {name: jnp.zeros((), jnp.float32) for name in means}
 
-    def without_alpha(aux):
-        """A KDA or Mamba-2 layer's (or run's) statistics without its mean decay, which is summed apart."""
-        nonlocal alpha_total
-        name = next((n for n in ("kda_alpha", "ssm_decay") if isinstance(aux, dict) and n in aux), None)
-        if name is None:
+    def without_means(aux):
+        """A layer's (or run's) statistics without its mixer's mean statistic, which goes to its total."""
+        counted = means.keys() & aux.keys() if isinstance(aux, dict) else ()
+        if not counted:
             return aux
         aux = dict(aux)
-        alpha_total = alpha_total + jnp.sum(aux.pop(name))
+        for name in counted:
+            totals[name] = totals[name] + jnp.sum(aux.pop(name))
         return aux or jnp.zeros((), jnp.float32)
+
 
     # The walk of the pattern: runs of one kind, each through its own stack;
     # router_bias's rows by a layer's place among the SPARSE layers, whatever their stack.
@@ -1243,7 +659,7 @@ def _decoder(
                 with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
                     w = jax.tree.map(lambda a, i=first + n: a[i], stacked)
                     x, aux = body(x, w if bias is None else dict(w, router_bias=bias[bias_first + n]))
-                aux = without_alpha(aux)
+                aux = without_means(aux)
                 if with_stats:
                     pending.append(aux)
                 elif not stats:  # beside experts a dense layer has no statistics
@@ -1257,7 +673,7 @@ def _decoder(
                 whole = (bias_first, count) == (0, bias.shape[0])
                 stacked = dict(stacked, router_bias=bias if whole else bias[bias_first:bias_first + count])
             x, aux_layers = jax.lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
-        aux_layers = without_alpha(aux_layers)
+        aux_layers = without_means(aux_layers)
         if with_stats:
             flush()
             pieces.append(aux_layers)
@@ -1271,11 +687,21 @@ def _decoder(
         flush()
         whole = pieces[0] if len(pieces) == 1 else jax.tree.map(lambda *a: jnp.concatenate(a), *pieces)
         out = _over_layers(whole)
-        if kda_layers:
-            out["kda_alpha"] = alpha_total / kda_layers
-        if ssm_layers:
-            out["ssm_decay"] = alpha_total / ssm_layers
+        for name, (_, layers) in means.items():
+            out[name] = totals[name] / layers
         return x, out
+
+
+def _mean_statistics(cfg: TransformerConfig) -> Dict[str, Tuple[str, int]]:
+    """{a statistic's name: (its counter's, the layers that count it)} over the
+    mixers of the model's layers (`Mixer.mean_statistic`)."""
+    found: Dict[str, Tuple[str, int]] = {}
+    for kind, count in cfg.stacks.values():
+        mixer = _mixer(kind)
+        if mixer is not None and mixer.mean_statistic is not None:
+            name, counter = mixer.mean_statistic
+            found[name] = (counter, found.get(name, (counter, 0))[1] + count)
+    return found
 
 
 @jax.custom_vjp
@@ -1292,18 +718,9 @@ _grads_inside.defvjp(lambda x, w: ((x, w), None), lambda _, ct: jax.lax.optimiza
 def _remat(cfg: TransformerConfig, body):
     if not cfg.remat_keeps_attention:
         return jax.checkpoint(body)
-    from torchft_tpu.ops.attention import SAVED_NAMES
-    from torchft_tpu.ops.sparse_attention import SAVED_NAMES as DSA_SAVED_NAMES
-
-    names = SAVED_NAMES + (DSA_SAVED_NAMES if cfg.dsa_index_heads else ())
-    if any(kind.mixer == "kda" for kind in cfg.layers):
-        from torchft_tpu.ops.delta_attention import SAVED_NAMES as KDA_SAVED_NAMES
-
-        names += KDA_SAVED_NAMES
-    if any(kind.mixer == "mamba2" for kind in cfg.layers):
-        from torchft_tpu.ops.ssd import SAVED_NAMES as SSD_SAVED_NAMES
-
-        names += SSD_SAVED_NAMES
+    # what each of the model's mixers keeps of a layer (`Mixer.saved_names`)
+    mixers = [_mixer(kind) for kind, _ in cfg.stacks.values()]
+    names = sorted({name for mixer in mixers if mixer is not None for name in mixer.saved_names})
     return jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
@@ -1478,10 +895,7 @@ def loss_and_counters(
         if cfg.moe_z_coef:
             loss = loss + cfg.moe_z_coef * aux["z"]
         counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
-        if "kda_alpha" in aux:
-            counters.update(kda_alpha_mean=aux["kda_alpha"])
-        if "ssm_decay" in aux:
-            counters.update(ssm_decay_mean=aux["ssm_decay"])
+        counters.update({counter: aux[name] for name, (counter, _) in _mean_statistics(cfg).items()})
         if cfg.moe_skip:
             counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:

@@ -149,7 +149,7 @@ SUBSPANS = {
 
 
 # Parts of the gradient program: the ``jax.named_scope`` names the model
-# (models/transformer.py, models/moe.py, ops/sparse_attention.py) writes around
+# (models/transformer.py and the mixers' files beside it, models/moe.py, ops/sparse_attention.py) writes around
 # its forward computation, one vocabulary for every architecture.  Scopes nest,
 # and JAX's transforms carry them into the backward pass
 # (``transpose(jvp(ffn))``) and into what ``jax.checkpoint`` computes again

@@ -28,7 +28,7 @@ weight of D, times ``sigmoid(gate + bias)``.
 
 A tile is worked through in blocks of ``_ROWS`` rows so that a block's
 intermediates stay near the registers; the rounding points are the XLA
-halves' (`models/transformer.py::_kda_mixer`): q, k, v and the output land in
+halves' (`models/kda.py::_kda_mixer`): q, k, v and the output land in
 the compute type, g stays float32.  The names hold no ``tpuft_kda_``: the
 benchmark books every instruction with that in its name to the scan.
 """
