@@ -145,6 +145,74 @@ def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> 
         assert a.dtype == b.dtype and a.shape == b.shape and bool(jnp.array_equal(a, b)), name
 
 
+def _column_stats_fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale):
+    """The forward tile as it stood before PR 62, kept here: the running max
+    and sum ONE COLUMN, [block_q, 1], broadcast along the lanes wherever the
+    scores and the accumulator want them.  It takes the kernel's lane-replicated
+    [block_q, 128] statistics by their first column and hands its own back
+    broadcast, so that `_fa_kernel` runs it in `_fwd_tile`'s place."""
+    from torchft_tpu.ops import attention as fa
+
+    lanes = m_prev.shape
+    m_prev, l_prev = m_prev[:, :1], l_prev[:, :1]
+    s = jax.lax.dot_general(q, k, fa._NT, preferred_element_type=jnp.float32) * scale
+    if keep is not None:
+        s = jnp.where(keep, s, fa._NEG_INF)
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_cur)
+    alpha = jnp.exp(m_prev - m_cur)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return jnp.broadcast_to(m_cur, lanes), jnp.broadcast_to(l_new, lanes), acc
+
+
+# kind: (batch * heads, positions, query and key width, query heads a KV head, H by the module's rule)
+LANE_REPLICATED_CASES = {
+    "causal": (8, 1024, 128, 1, 8),
+    "window_512": (8, 1536, 128, 1, 8),
+    "packed_mask": (8, 1024, 128, 8, 8),
+    "unequal_widths": (4, 1024, 256, 1, 4),
+    "kv_group_4_aligned": (8, 1024, 128, 4, 8),
+    "kv_group_4_straddling": (12, 1024, 128, 4, 6),
+}
+
+
+@pytest.mark.parametrize("heads_per_step", [1, None], ids=["one_head", "the_rule"])
+@pytest.mark.parametrize("kind", sorted(LANE_REPLICATED_CASES))
+def test_lane_replicated_statistics_are_bitwise_one_column(kind, heads_per_step, monkeypatch) -> None:
+    """The forward kernel keeps a row's running max and sum lane-replicated,
+    [block_q, 128] from scratch to scratch (PR 62).  Its out and lse are bit
+    for bit those of the same kernel around the tile of one-column statistics
+    kept above: over the triangle, the band under a window of 512, a packed
+    mask, query and key 256 wide beside a value of 128, and a KV head read in
+    place for four query heads by steps that hold whole groups and by steps
+    that straddle two, at one head a step and at the H the shapes give."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    bh, seq, d, kv_group, rule = LANE_REPLICATED_CASES[kind]
+    more = {"kv_group": kv_group, "heads_per_step": heads_per_step}
+    if kind == "window_512":
+        more["window"] = 512
+    elif kind == "packed_mask":
+        keep = jax.random.bernoulli(jax.random.PRNGKey(62), 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)
+        more["mask"] = sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8)
+    heads = heads_per_step or fa._heads_per_step(fa._heads_share(bh, more.get("mask")))
+    assert heads == (heads_per_step or rule) and fa._straddles(heads, kv_group) == (kind == "kv_group_4_straddling" and heads > 1)
+    ks = jax.random.split(jax.random.PRNGKey(len(kind)), 3)
+    q = jax.random.normal(ks[0], (bh, seq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (bh // kv_group, seq, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (bh // kv_group, seq, 128), jnp.bfloat16)
+    fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=True, interpret=True, **more)
+    assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == bh // heads
+    o, lse = fwd(q, k, v)
+    monkeypatch.setattr(fa, "_fwd_tile", _column_stats_fwd_tile)
+    want_o, want_lse = fwd(q, k, v)
+    assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01 and bool(jnp.all(jnp.isfinite(lse)))
+    assert o.dtype == want_o.dtype and bool(jnp.array_equal(o, want_o)), "out"
+    assert lse.dtype == want_lse.dtype and bool(jnp.array_equal(lse, want_lse)), "lse"
+
+
 def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
     """H is read from the shapes: the largest divisor of the heads not above
     `HEADS_PER_STEP` whose dq rows and tiles fit VMEM.  At 65,536 x 128 one

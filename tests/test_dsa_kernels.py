@@ -213,3 +213,26 @@ def test_the_schedule_reader_counts_a_recorded_dump_loop_by_loop(tmp_path) -> No
     assert (inner["spill_fills"], inner["slots_taken"]["MXU"], inner["slots_taken"]["VALU"]) == (1, 1, 1)
     assert second["slots_taken"]["EUP"] == 1 and second["slots_taken"]["MXU"] == 0
     assert grid["spill_stores"] == 0 and sum(grid["slots_taken"].values()) == 0
+
+
+def test_the_forward_attention_tile_schedules_under_1500_bundles_a_head() -> None:
+    """`tpuft_fa_fwd` at 8 x 4,096 x 128, eight heads a grid step, compiled
+    for a described v5e (no chip: `tools/fa_bwd_probe.py`'s child process,
+    which ends in the compiler's abort after the schedule is written) and the
+    compiler's final schedule counted: a head's tile stands at 1,317 bundles
+    over 1,024 cycles of products since the softmax statistics stay
+    lane-replicated from scratch to scratch (PR 62; 1,860 with one-column
+    statistics narrowed and broadcast again a row group).  A change that puts
+    the broadcasts back fails here and not in a cell."""
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    try:
+        import fa_bwd_probe
+    finally:
+        sys.path.remove(os.path.join(ROOT, "tools"))
+    read = fa_bwd_probe.kernel_schedule("8x4096x128", "fwd")
+    if "libtpu multi-process lockfile" in read.get("error", ""):
+        pytest.skip("another process holds the TPU's library and ALLOW_MULTIPLE_LIBTPU_LOAD is not set")
+    assert "error" not in read, read["error"]
+    heads = len(read["product_starts"]) // 2  # two products a head
+    assert heads == 8 and 1024 < read["tile_bundles"] / heads < 1500, read
+    assert read["mxu_slots_percent"] > 70, read

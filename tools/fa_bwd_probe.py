@@ -45,8 +45,15 @@ outer axis), and `bitwise_h1`: whether the results — out and lse forward; dq,
 dk, dv backward — are bit for bit those of one head a step.
 `--heads-per-step 1,2,4` reads each shape at those H through the kernels'
 private argument (the program has no option: it reads H from the shapes,
-which is what a line reads without the flag); an H whose dq rows the compiler
-refuses is a line with the error.
+which is what a line reads without the flag, and what a 0 in the list asks
+for); an H whose dq rows the compiler refuses is a line with the error.
+
+    chiprun -- python tools/fa_bwd_probe.py --against parent_tree --heads-per-step 1,0
+
+`--against` names another tree of this repo (`git archive <commit> | tar -x -C
+parent_tree`): its forward kernel is timed on the same operands before this
+tree's at every H read (a line with `tree`), and this tree's forward line says
+in `bitwise_other_tree` whether its out and lse are bit for bit the other's.
 
     python tools/fa_bwd_probe.py --bundles 28x16384x128 --heads-per-step 1,2,4     # no chip
 
@@ -232,27 +239,34 @@ def read_schedule(dump: str, kernel: str) -> dict:
     }
 
 
-def bundles(args) -> int:
-    """`--bundles`: a child process a shape and H compiles, this one reads."""
+def kernel_schedule(spec: str, what: str, heads: int = 0, window: int = 512, kv_group: int = 0) -> dict:
+    """One kernel (``what``: fwd or bwd) of a `--bundles` entry compiled in a
+    child process and its schedule counted; `error` where there is none to read."""
     import subprocess
     import tempfile
 
+    family = {"": "tpuft_fa", "w": "tpuft_swa", "m": "tpuft_dsa_attn"}[spec.partition(":")[2]]
+    kernel = family + {"fwd": "_fwd", "bwd": "_bwd_dkdv"}[what]
+    rec = {"shape": spec, "kernel": kernel, "heads_per_step_asked": heads or "the module's rule",
+           "kv_group": group_of(spec.partition(":")[0], kv_group, spec.endswith(":m"))}
+    with tempfile.TemporaryDirectory() as dump:
+        child = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--bundles-child", spec, "--what", what,
+             "--heads-per-step", str(heads), "--dump", dump, "--window", str(window),
+             "--kv-group", str(kv_group)], capture_output=True, text=True, check=False)
+        try:
+            rec.update(read_schedule(dump, kernel))
+        except (ValueError, IndexError, OSError) as e:  # no such file: the compile failed before the kernel
+            rec["error"] = f"{type(e).__name__}: {e}; the child said: {child.stderr[-600:]}"
+    return rec
+
+
+def bundles(args) -> int:
+    """`--bundles`: a child process a shape, H and kernel compiles, this one reads."""
     for spec in filter(None, args.bundles.split(",")):
         for heads in args.heads_per_step or [0]:
-            family = {"": "tpuft_fa", "w": "tpuft_swa", "m": "tpuft_dsa_attn"}[spec.partition(":")[2]]
-            for what, kernel in (("fwd", family + "_fwd"), ("bwd", family + "_bwd_dkdv")):
-                rec = {"shape": spec, "kernel": kernel, "heads_per_step_asked": heads or "the module's rule"}
-                with tempfile.TemporaryDirectory() as dump:
-                    child = subprocess.run(
-                        [sys.executable, os.path.abspath(__file__), "--bundles-child", spec, "--what", what,
-                         "--heads-per-step", str(heads), "--dump", dump, "--window", str(args.window),
-                         "--kv-group", str(args.kv_group)], capture_output=True, text=True, check=False)
-                    rec["kv_group"] = group_of(spec.partition(":")[0], args.kv_group, spec.endswith(":m"))
-                    try:
-                        rec.update(read_schedule(dump, kernel))
-                    except (ValueError, IndexError, OSError) as e:  # no such file: the compile failed before the kernel
-                        rec["error"] = f"{type(e).__name__}: {e}; the child said: {child.stderr[-600:]}"
-                print(json.dumps(rec), flush=True)
+            for what in ("fwd", "bwd"):
+                print(json.dumps(kernel_schedule(spec, what, heads, args.window, args.kv_group)), flush=True)
     return 0
 
 
@@ -270,6 +284,8 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--heads-per-step", type=lambda text: [int(x) for x in text.split(",")], default=None,
                         help="heads a grid step to read each shape at (default: what the module reads from the shape)")
+    parser.add_argument("--against", default="", help="another tree of this repo whose forward kernel is timed beside "
+                        "this tree's on the same operands and compared with it bit for bit")
     parser.add_argument("--bundles", default="", help="shapes (`:w` windowed, `:m` masked) whose kernels are compiled "
                         "for a described v5e and their schedules counted; needs no chip and times nothing")
     parser.add_argument("--bundles-child", default="", help=argparse.SUPPRESS)
@@ -290,6 +306,14 @@ def main(argv=None) -> int:
     if device.platform != "tpu":
         print(f"the probe measures a TPU and JAX found {device.platform!r}", file=sys.stderr)
         return 1
+    other = None
+    if args.against:
+        import importlib.util
+
+        found = importlib.util.spec_from_file_location(
+            "attention_of_the_other_tree", os.path.join(args.against, "torchft_tpu", "ops", "attention.py"))
+        other = sys.modules[found.name] = importlib.util.module_from_spec(found)  # its dataclass looks its module up there
+        found.loader.exec_module(other)
     readings = []
 
     def timed(fn, *operands):
@@ -366,8 +390,12 @@ def main(argv=None) -> int:
         for heads in [1] + [h for h in (args.heads_per_step or [None]) if h != 1]:
             kw = dict(more_kw, heads_per_step=heads)
             timed_line = args.heads_per_step is None or heads in args.heads_per_step
-            fwd = lambda q_, k_, v_: fa._fa_pallas_call(q_, k_, v_, scale, causal, **kw)  # noqa: E731,B023
+            fwd_of = lambda module: lambda q_, k_, v_: module._fa_pallas_call(q_, k_, v_, scale, causal, **kw)  # noqa: E731,B023
+            fwd, theirs = fwd_of(fa), None
             try:
+                if other is not None and timed_line:  # the other tree's first: what this tree's is compared with
+                    ms, theirs = timed(jax.jit(fwd_of(other)), q, k, v)
+                    record("fwd", family + "_fwd", ms, need_fwd, grids(fwd_of(other), q, k, v), tree=args.against)
                 ms, (o, lse) = timed(jax.jit(fwd), q, k, v)
             except Exception as e:  # noqa: BLE001 — an H the compiler refuses is a reading too
                 failed("fwd", family + "_fwd", heads, e)
@@ -375,6 +403,7 @@ def main(argv=None) -> int:
             one_head.setdefault("fwd", (o, lse))
             if timed_line:
                 record("fwd", family + "_fwd", ms, need_fwd, grids(fwd, q, k, v), bitwise_h1=same((o, lse), one_head["fwd"]),
+                       **({} if theirs is None else {"bitwise_other_tree": same((o, lse), theirs)}),
                        **repeated(fa._fa_pallas_call, (o, lse)))
             o, lse = one_head["fwd"]
             results = {}

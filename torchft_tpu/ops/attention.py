@@ -115,16 +115,20 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     # whole blocks: a 1024-row block straddling the diagonal computes 33%
     # more masked elements than two 512-row blocks.  "VPU-bound", as this
     # comment used to put it, was read off timings; the compiled schedule at
-    # 28 x 16,384 x 128 (`tools/fa_bwd_probe.py --bundles`, PERF.md section
-    # 6, PR 52) says which unit: none.  A forward tile is 1,813 bundles for
-    # 1,024 cycles of MXU work with the VALUs' slots 39% taken, the XLUs'
-    # 37%, the MXUs' 48%: q k^T streams at the MXUs' rate for ~490 bundles,
-    # then p v's matmuls trickle ~40 bundles apart as the softmax tile's row
-    # groups come out of their chain (row max across lanes, a lane
-    # broadcast, exp, row sum across lanes), three quarters of the tile's
-    # stores being spills of the 256-vreg score tile.  The backward tile is
-    # 2,574 bundles for its five products' 2,560 cycles: MXU-bound as
-    # scheduled.
+    # 28 x 16,384 x 128, seven heads a step (`tools/fa_bwd_probe.py
+    # --bundles`, PERF.md section 6, PRs 52 and 62) says which unit.  A
+    # forward tile is 1,322 bundles a head for 1,024 cycles of MXU work, the
+    # MXUs' slots 75% taken, the VALUs' 61% (91% of the bundles hold a VALU
+    # operation), the XLUs' 29%: the heads' q k^T go out ~800 bundles apart,
+    # each under the softmax tile of the head before it (row max across
+    # lanes, exp, row sum across lanes: the VALUs' work), and the heads' p v
+    # follow the last exponential back to back at the MXUs' rate, ~480
+    # bundles each with nothing beside them; 55% of the tile's stores are
+    # spills of the score tile.  It was 1,851 a head (PR 52: 1,813 at one
+    # head a step), no unit above 54% of its slots, while the statistics were
+    # one column, narrowed and broadcast along the lanes again a row group.
+    # The backward tile is 2,574 bundles for its five products' 2,560
+    # cycles: MXU-bound as scheduled.
     # The band walk has the same tiles.  Under a window of 512 a 512 x 512 q
     # tile visits two kv tiles and half of what it computes lies outside the
     # band; at 256 x 256 it visits three, two thirds of them inside, at three
@@ -274,11 +278,20 @@ class _Walk:
 # the chip (`tools/fa_bwd_probe.py`, PERF.md section 6, PR 52), us a tile:
 # forward 1.93 -> 2.02 (H = 2) -> 1.65 (4) -> 1.50 (7) -> 1.43 (14), one-pass
 # backward 2.71 -> 2.34 (2) -> 2.31 (4), results bit for bit those of one head
-# a step.  What the step's cost shared by H tiles does NOT do is put one
-# head's products under another's softmax tile: the schedule stays at 1,850 to
-# 2,020 bundles a head forward and 2,590 to 2,780 backward at every H (the H
-# first products back to back at the MXUs' rate, then each head's softmax tile
-# pacing its own second product; `--bundles` prints where each product
+# a step.  With PR 52's one-column statistics the step's cost shared by H
+# tiles was all of it: the schedule stayed at 1,850 to 2,020 bundles a head
+# forward at every H (the H first products back to back at the MXUs' rate,
+# then each head's softmax tile pacing its own second product).  Since the
+# forward's statistics stay lane-replicated (`_fwd_tile`, PR 62) a head's
+# first product does stand under the softmax tile of the head before it, and
+# the forward schedules at 1,310 to 1,320 bundles a head at H = 7 or 8 (1,753
+# at 256 / 128 wide, where it was 2,158): read on the chip against the parent
+# in one call (PERF.md section 6, PR 62), us a tile forward 1.93 -> 1.20 at
+# H = 1 and 1.50 -> 1.05 at H = 7 (28 x 16,384 x 128), 1.43 -> 0.98 under the
+# Keye cell's mask at H = 8, 1.69 -> 1.22 under a window of 4,096, 2.04 ->
+# 1.58 at 32 x 8,192 x 256 / 128, 2.50 -> 1.96 at 32 x 4,096 x 128, bit for
+# bit the parent's.  The backward is untouched: 2,590 to 2,780 bundles a
+# head, its five products' own time (`--bundles` prints where each product
 # starts).  H is read from the shapes (`_heads_per_step`,
 # `_bwd_heads_per_step`) and nothing else.
 HEADS_PER_STEP = 8
@@ -320,13 +333,34 @@ def _heads_share(bh: int, mask) -> int:
     return bh if mask is None else bh // mask.shape[0]
 
 
+def _lanes(stat, width: int):
+    """A lane-replicated row statistic, [.., rows, 128] with every lane of a
+    row the same number, read ``width`` lanes wide: the same array side by
+    side (`pltpu.repeat` has no batching rule under `_heads_at_once`'s
+    `vmap`), cut where ``width`` is no lane multiple."""
+    if width == _LANE:
+        return stat
+    stat = jnp.concatenate([stat] * -(-width // _LANE), axis=-1)
+    return stat if stat.shape[-1] == width else stat[..., :width]
+
+
 def _fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale: float):
     """One head's forward tile as a function of values: the online-softmax
     update ``(m, l, acc) -> (m, l, acc)`` of q's (block_q, d) rows against
     one (block_k, d) kv tile.  ``keep`` [block_q, block_k] bool, or None
     where every pair is visible.  Matmuls run in the INPUT dtype with f32
     accumulation: bf16 model activations hit the MXU at full rate (an f32 x
-    f32 matmul runs at a fraction of it); softmax statistics stay f32."""
+    f32 matmul runs at a fraction of it); softmax statistics stay f32.
+
+    The running max and sum are LANE-REPLICATED, [block_q, 128] in and out as
+    the scratch holds them: a row's max and sum leave their lane reduction as
+    one column and are broadcast once, into the statistic, and nothing is
+    narrowed to a column to be broadcast again where the scores, the
+    accumulator and the scratch want it (`_lanes`).  Every row's max, exp,
+    sum and products are what [block_q, 1] statistics compute, bit for bit
+    (tests/test_attention_walks.py keeps that tile); the schedule is 1,320
+    bundles a head where it was 1,850, 1.05 us a tile on a v5e where it was
+    1.50 (`HEADS_PER_STEP`'s account, PR 62)."""
     s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale  # [block_q, block_k] f32
     if keep is not None:
         # Unconditional mask.  A `lax.cond` a block in its place read ~3 ms a
@@ -335,11 +369,11 @@ def _fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale: float):
         # what masking only the diagonal's tiles could save at 0.13 us of a
         # 16,384-position tile's 1.9.
         s = jnp.where(keep, s, _NEG_INF)
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_cur)                     # [block_q, block_k]
-    alpha = jnp.exp(m_prev - m_cur)            # rescale old accumulator
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))    # [block_q, 128]
+    p = jnp.exp(s - _lanes(m_cur, s.shape[-1]))                        # [block_q, block_k]
+    alpha = jnp.exp(m_prev - m_cur)                                    # rescale old accumulator
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc * alpha + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc = acc * _lanes(alpha, acc.shape[-1]) + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     return m_cur, l_new, acc
 
 
@@ -438,24 +472,22 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group:
     @pl.when(run)
     def _step():
         keep = _keep(walk, qi, ki, mask_ref)
-        m_cur, l_new, acc = _heads_at_once(_fwd_tile, scale=scale)(
+        # The statistics go from scratch to scratch whole, lane-replicated
+        # (`_fwd_tile`): one column of them read or stored has to be broadcast
+        # along the lanes again a row group, which paced the whole tile (a
+        # tile reads 0.70 of the one-column form's time on a v5e, PR 62).
+        m_scr[...], l_scr[...], acc_scr[...] = _heads_at_once(_fwd_tile, scale=scale)(
             keep, q_ref[...], _kv_heads(k_ref, heads, kv_group), _kv_heads(v_ref, heads, kv_group),
-            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...])
-        acc_scr[...] = acc
-        # Partial column stores: broadcasting the stats across the full
-        # (block_q, 128) scratch measured ~19% of the kernel.
-        m_scr[:, :, 0:1] = m_cur
-        l_scr[:, :, 0:1] = l_new
+            m_scr[...], l_scr[...], acc_scr[...])
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
-        l = l_scr[:, :, :1]
+        l = l_scr[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
-        # lse output is lane-padded to (block_q, _LANE) to satisfy TPU tiling.
-        lse_ref[...] = jnp.broadcast_to(
-            m_scr[:, :, :1] + jnp.log(safe_l), lse_ref.shape
-        ).astype(lse_ref.dtype)
+        o_ref[...] = (acc_scr[...] / _lanes(safe_l, acc_scr.shape[-1])).astype(o_ref.dtype)
+        # lse output is lane-padded to (block_q, _LANE) to satisfy TPU tiling:
+        # the statistics' own form.
+        lse_ref[...] = (m_scr[...] + jnp.log(safe_l)).astype(lse_ref.dtype)
 
 
 def _tri(i, j):
