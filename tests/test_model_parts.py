@@ -107,6 +107,13 @@ MODELS["nemotron"] = TransformerConfig(**dict(
 # The ten the benchmark had before a mixer was an entry of a table (`models/mixers.MIXERS`): PR 61 pins the eighth to
 # tenth beside the seven, from its parent tree.
 PINNED = PINNED + ("kimi", "smallthinker", "nemotron")
+# The eleventh: two dense blocks of four norms each run three times over the same weights, a head and an exit gate after
+# every pass, the exit-weighted loss (Ouro's shape).  Pinned by PR 63, which brought it: a later change to the walk, the
+# passes or the per-row head is meant to leave this program alone, or to record it anew.
+MODELS["ouro"] = TransformerConfig(**dict(
+    _BASE, n_heads=4, n_kv_heads=4, head_dim=16, remat=True, scan_unroll=8, loop_steps=3, exit_beta=0.05,
+    pattern=(LayerKind("layers", False, 4, 1e6, post_norms=True),) * 2))
+PINNED = PINNED + ("ouro",)
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
@@ -175,13 +182,15 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
         expected |= {"shared_expert"}
     if cfg.dsa_index_heads:
         expected |= {"dsa_index", "dsa_select"}
+    if cfg.exit_beta is not None:
+        expected |= {"exit_gate"}
     assert parts == expected
     directions = {d for p, d in named if p is not None}
     assert directions == ({"fwd", "bwd", "recompute"} if cfg.remat else {"fwd", "bwd"})
     # the head and the loss are outside the rematerialised layers; a layer's products are inside
     assert ("head_loss", "recompute") not in named
     if cfg.remat:
-        assert ("attn_proj", "recompute") in named and ("experts", "recompute") in named
+        assert ("attn_proj", "recompute") in named and ("experts" if cfg.moe_experts else "ffn", "recompute") in named
 
 
 @pytest.mark.parametrize("name", list(MODELS))
@@ -239,7 +248,7 @@ def _digest(step, params, batch, program: str, grads_text=None) -> str:
 
 
 def record(commit: str) -> None:
-    """Records the twenty digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    """Records the twenty-two digests anew (`python tests/test_model_parts.py "<commit and why>"`,
     `JAX_PLATFORMS=cpu`): for a PR that changes the ten gradient programs on
     purpose.  The update programs are no model code's to change, so theirs
     have to come out as they were."""
@@ -275,7 +284,8 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     the mixer the kind's and let the walk carry a second stream, the sixth
     (window and full attention mixed) with them, since PR 48 the seventh and
     since PR 61, which made a mixer an entry of `models/mixers.MIXERS`, all ten
-    (each recorded from the tree BEFORE the change it guards).  A PR that
+    (each recorded from the tree BEFORE the change it guards); the eleventh, a
+    looped model, from the PR that brought it (63).  A PR that
     changes these programs on purpose records them anew:
     `tests/data/hlo_before_the_pattern.json`."""
     import json
@@ -290,7 +300,8 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     # and the tree keeps its leaves' names and shapes: heal and checkpoints read what they wrote
     cfg = MODELS[name]
     assert set(cfg.stacks) == _STACKS.get(name, {"layers"} | ({"dense_layers"} if cfg.moe_dense_layers else set()))
-    assert set(params) == {"embed", "final_norm"} | set(cfg.stacks) | (set() if cfg.tied_head else {"lm_head"})
+    assert set(params) == ({"embed", "final_norm"} | set(cfg.stacks) | (set() if cfg.tied_head else {"lm_head"})
+                           | ({"exit_gate"} if cfg.exit_beta is not None else set()))
     # a stack holds a row a layer of its first norm: the mixer's, or the feed-forward's where the block has no mixer
     assert [name for name, (kind, _) in cfg.stacks.items() if "attn_norm" not in params[name]] == (
         ["moe"] if name == "nemotron" else [])

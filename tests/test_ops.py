@@ -237,6 +237,50 @@ def test_fused_cross_entropy_pallas_interpret_matches() -> None:
     )
 
 
+@pytest.mark.parametrize("path", ["xla", "kernels_interpreted"])
+def test_the_per_row_cross_entropy_is_the_mean_forms_rows(path, monkeypatch) -> None:
+    """`fused_linear_cross_entropy_per_row` — the XLA form, and the `tpuft_ce_*`
+    kernels in interpret mode with a scale a ROW in `tpuft_ce_dlogits` — against
+    the materialized formulation: the loss of every row, and dx and dw under a
+    random cotangent a row; its mean is `fused_linear_cross_entropy`, and under
+    the cotangent 1 / N a row it has the mean form's gradients."""
+    import functools
+
+    from torchft_tpu.ops import _pallas_util, cross_entropy as ce
+
+    rng = np.random.default_rng(21)
+    n, e, v = 256, 128, 512
+    x = jnp.asarray(rng.standard_normal((n, e)), dtype=jnp.float32)
+    w = jnp.asarray(rng.standard_normal((e, v)) * 0.1, dtype=jnp.float32)
+    t = jnp.asarray(rng.integers(0, v, n), dtype=jnp.int32)
+    g = jnp.asarray(rng.standard_normal(n), dtype=jnp.float32)
+    mean_grads = jax.grad(ce.fused_linear_cross_entropy, argnums=(0, 1))(x, w, t)  # the XLA form, before any patch
+    if path == "kernels_interpreted":
+        monkeypatch.setattr(_pallas_util, "on_tpu", lambda: True)
+        lse_kernel = ce._ce_lse_pallas  # `_ce_fwd` names `interpret` itself
+        monkeypatch.setattr(ce, "_ce_lse_pallas", lambda x, w, interpret=False, valid_v=None: lse_kernel(x, w, True, valid_v))
+        monkeypatch.setattr(ce, "_ce_dlogits_pallas", functools.partial(ce._ce_dlogits_pallas, interpret=True))
+
+    def rows(x, w):
+        logits = x @ w
+        return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+
+    got, vjp = jax.vjp(lambda x, w: ce.fused_linear_cross_entropy_per_row(x, w, t), x, w)
+    want, want_vjp = jax.vjp(rows, x, w)
+    assert got.shape == (n,) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for a, b, name in zip(vjp(g), want_vjp(g), ("dx", "dw")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(float(jnp.mean(got)), float(jnp.mean(want)), rtol=1e-6)
+    for a, b, name in zip(vjp(jnp.full((n,), 1.0 / n, jnp.float32)), mean_grads, ("dx", "dw")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6, err_msg=name)
+    if path == "kernels_interpreted":  # the kernel's own product, a scale a row against one number a row
+        lse = jax.nn.logsumexp(x @ w, axis=-1)
+        a_row = ce._ce_dlogits_pallas(x, w, t, lse, jnp.full((n,), 0.37, jnp.float32))
+        one = ce._ce_dlogits_pallas(x, w, t, lse, 0.37)
+        assert np.array_equal(np.asarray(a_row), np.asarray(one))
+
+
 @pytest.mark.parametrize("j,width,real", [(0, 512, 512), (1, 512, 512), (2, 256, 76)],
                          ids=["first_slab", "a_slab_with_targets_on_both_sides", "last_slab_partly_padding"])
 def test_a_slab_of_dlogits_is_its_columns_of_the_whole(j, width, real) -> None:

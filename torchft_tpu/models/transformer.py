@@ -63,6 +63,9 @@ class LayerKind:
     # False: the block is a mixer alone under its one norm (`attn_norm`): no
     # second norm, no feed-forward, no such leaves in its stack.
     feed_forward: bool = True
+    # A norm AFTER each sublayer too, on what the residual takes (`x + RMS(mixer(RMS(x)))`: four norms a block,
+    # Ouro's; leaves `attn_post_norm`, `mlp_post_norm`).  A block of a mixer and a dense feed-forward.
+    post_norms: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,6 +222,17 @@ class TransformerConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # A looped model (Ouro, arXiv:2510.25741): the layers run `loop_steps` times over the SAME weights, the final
+    # norm after every pass, its output the next pass's input; `_decoder` hands back every pass's normed state
+    # [loop_steps, B, S, E].  `loop_scan`: the passes are a `lax.scan` of the program (the layers traced once, one
+    # running gradient a weight in the scan's backward carry) and not `loop_steps` copies of the walk.
+    loop_steps: int = 1
+    loop_scan: bool = False
+    # The exit-weighted loss of such a model: every pass's state goes through the head and — all but the last —
+    # through a gate (`exit_gate`: a vector of d_model and a bias, float32; lambda = sigmoid(h w + b)); a token
+    # leaves after pass t with p_t = lambda_t prod_{j<t} (1 - lambda_j), the last pass takes what is left, and the
+    # loss is mean_i [sum_t p_t loss_t - exit_beta H(p)].  None: no gate, the (last) state's mean cross-entropy.
+    exit_beta: Optional[float] = None
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -266,6 +280,12 @@ class TransformerConfig:
                     mixer.check(self, kind)
             assert all(a == b for a in self.pattern for b in self.pattern if a.stack == b.stack), "one kind a stack"
             assert all(self.moe_experts > 0 for kind in self.pattern if kind.sparse)
+            assert all(kind.feed_forward and not kind.sparse and kind.mixer != "none"
+                       for kind in self.pattern if kind.post_norms), "post-norms: a mixer and a dense feed-forward"
+        assert self.loop_steps >= 1 and (self.exit_beta is None or self.loop_steps > 1), "the exit gate is a looped model's"
+        if self.loop_steps > 1:
+            assert not (self.moe_experts or self.dsa_index_heads or self.moe_router_state or self.tied_head), (
+                "the layers that run several times are dense ones under an untied head: no statistics a layer")
         if self.moe_router_state or self.moe_skip:
             assert self.moe_experts > 0 and self.moe_capacity_factor is None, (
                 "a router with a state, or with a choice that takes no expert, routes on the dropless path"
@@ -331,6 +351,8 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
         layer.update({"attn_norm": ("layers", "embed")}, **mixer.axes(cfg, kind))
     if cfg.scaled_merge:
         layer.update({"attn_merge": ("layers", None, "embed"), "mlp_merge": ("layers", None, "embed")})
+    if kind.post_norms:
+        layer.update({"attn_post_norm": ("layers", "embed"), "mlp_post_norm": ("layers", "embed")})
     if not kind.feed_forward:
         return layer
     layer["mlp_norm"] = ("layers", "embed")
@@ -363,6 +385,8 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     axes = {"embed": ("vocab", "embed"), "final_norm": ("embed",)}
     if not cfg.tied_head:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.exit_beta is not None:
+        axes["exit_gate"] = {"w": ("embed",), "b": (None,)}
     for stack, (kind, _) in cfg.stacks.items():
         axes[stack] = _layer_axes(cfg, kind)
     return axes
@@ -386,6 +410,8 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
     if cfg.scaled_merge:
         merge = jnp.broadcast_to(jnp.asarray([1.0, 0.0, 1.0, 0.0], pd)[None, :, None], (L, 4, E))
         layers.update({"attn_merge": merge, "mlp_merge": jnp.array(merge)})  # two buffers: a step donates each leaf
+    if kind.post_norms:
+        layers.update({"attn_post_norm": jnp.ones((L, E), pd), "mlp_post_norm": jnp.ones((L, E), pd)})
     if not kind.feed_forward:
         return layers
     layers["mlp_norm"] = jnp.ones((L, E), pd)
@@ -436,6 +462,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     }
     if not cfg.tied_head:
         params["lm_head"] = _norm_init(k_head, (E, cfg.vocab_size), E, pd)
+    if cfg.exit_beta is not None:  # lambda starts near 1/2: p near (1/2, 1/4, 1/8, 1/8) over four passes
+        params["exit_gate"] = {"w": _norm_init(jax.random.fold_in(k_head, 1), (E,), E, pd), "b": jnp.zeros((1,), pd)}
     # The last kind's stack draws from `k_layers` itself, each kind before it
     # from a key folded out of it (a model of one kind with leading dense
     # layers: "layers", then "dense_layers").
@@ -481,6 +509,9 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     with jax.named_scope("norm"):
         h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
     y, mixer_stats = mixer.forward(cfg, kind, mesh, rules, h, w, positions)
+    if kind.post_norms:
+        with jax.named_scope("norm"):
+            y = rms_norm(y, w["attn_post_norm"], cfg.rms_eps)
     with jax.named_scope("attn_proj"):
         x = constrain(_merge(x, y, w.get("attn_merge")), ("batch", "seq", "embed"), mesh, rules)
     return _after_the_mixer(cfg, mesh, rules, x, w, kind, router_bias, router_state, mixer_stats, routed)
@@ -539,7 +570,11 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
             from torchft_tpu.models.moe import hidden_units
 
             hidden = hidden_units(cfg.moe_activation, w.get("w_gate"), w["w_up"], lambda m: h @ m.astype(cfg.dtype))
-            x = _merge(x, hidden @ w["w_down"].astype(cfg.dtype), w.get("mlp_merge"))
+            y = hidden @ w["w_down"].astype(cfg.dtype)
+            if kind.post_norms:
+                with jax.named_scope("norm"):
+                    y = rms_norm(y, w["mlp_post_norm"], cfg.rms_eps)
+            x = _merge(x, y, w.get("mlp_merge"))
         aux = {} if mixer_stats is not None else jnp.zeros((), jnp.float32)
     if mixer_stats is not None:
         aux = dict(aux, **mixer_stats)
@@ -600,7 +635,9 @@ def _decoder(
     # gradients are finished inside the layer's backward pass: left alone the compiler puts them all at the end of
     # the program, where they are stacked, and holds every layer's inputs to them until then (ZAYA's step
     # compiled to 15.84e9 bytes so and to 15.04e9 with the barriers, PR 46).
-    grads_inside = head_row_block(B * S, padded_vocab(cfg.vocab_size)) is not None
+    # The same where the layers run several times: a weight's gradient is a sum over the passes, and each term is
+    # added to the running sum where its layer's backward pass ends.
+    grads_inside = head_row_block(B * S, padded_vocab(cfg.vocab_size)) is not None or cfg.loop_steps > 1
     stats = cfg.moe_experts > 0 or cfg.dsa_index_heads > 0  # a layer's aux is a dict of statistics
     aux_total = jnp.zeros((), jnp.float32)
     pieces, pending = [], []  # the layers' statistics: stacked runs, and layers still to be stacked
@@ -626,59 +663,95 @@ def _decoder(
         return aux or jnp.zeros((), jnp.float32)
 
 
-    # The walk of the pattern: runs of one kind, each through its own stack;
-    # router_bias's rows by a layer's place among the SPARSE layers, whatever their stack.
-    at = {stack: 0 for stack in cfg.stacks}  # the next layer of each stack
-    sparse_at = 0
-    for kind, run in itertools.groupby(cfg.layers):
-        count, first = len(list(run)), at[kind.stack]
-        at[kind.stack] += count
-        with_stats = stats and (kind.sparse or cfg.dsa_index_heads > 0)
-        stacked = params[kind.stack]
-        bias, bias_first = (router_bias if kind.sparse else None), sparse_at
-        sparse_at += count * kind.sparse
+    # A looped model's passes read each layer's slice of the stacked weights ONCE, and a pass hands the slice on to
+    # the next THROUGH the layer's barrier (`_grads_inside` returns the weights it was given): backward, the
+    # cotangent that arrives there is the later passes' sum plus this pass's term, and the barrier holds the layer
+    # before's backward pass until that sum exists.  One running float32 gradient a weight — left to itself the
+    # compiler keeps every pass's term until the end of the program (three more copies of the layers' gradient at
+    # four passes: 4.9 GB at Ouro's 8 layers, compiled for a described v5e, PR 63) — stacked once at the end.
+    sliced: Dict[Any, Any] = {}
 
-        def body(x, w, kind=kind):
-            if grads_inside:
-                x, w = _grads_inside(x, w)
-            w = dict(w)
-            return _layer(cfg, mesh, rules, x, w, positions, kind=kind, router_bias=w.pop("router_bias", None))
+    def layer_weights(stack: str, i: int):
+        if (stack, i) in sliced:
+            return sliced[stack, i]
+        w = jax.tree.map(lambda a: a[i], params[stack])
+        if cfg.loop_steps > 1:
+            sliced[stack, i] = w
+        return w
 
-        if cfg.remat:
-            body = _remat(cfg, body)
-        # A run that `scan_unroll` covers whole is a STATIC Python loop rather
-        # than lax.scan(unroll=count): scan's internal layer slicing survives
-        # as dynamic-update-slice fusions in the backward (profiled: ~17
-        # ms/step of DUS on the v5e flagship config); static integer indexing
-        # lets XLA constant-fold the slices and fold the per-layer grad
-        # writes, measured ~4 ms/step faster end-to-end.  Same math, different
-        # op association — results agree with the scan path to fusion-order
-        # rounding, not bitwise (pinned by test_scan_unroll_matches_scan).
-        if count <= cfg.scan_unroll:
-            for n in range(count):
-                with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
-                    w = jax.tree.map(lambda a, i=first + n: a[i], stacked)
-                    x, aux = body(x, w if bias is None else dict(w, router_bias=bias[bias_first + n]))
-                aux = without_means(aux)
-                if with_stats:
-                    pending.append(aux)
-                elif not stats:  # beside experts a dense layer has no statistics
-                    aux_total = aux_total + aux
-            continue
-        # The scan's own slicing of the stacked weights is `stack`.
+    if cfg.loop_steps > 1:  # outside the passes (a scan's body closes over the slices)
         with jax.named_scope("stack"):
-            if (first, count) != (0, cfg.stacks[kind.stack][1]):
-                stacked = jax.tree.map(lambda a: a[first:first + count], stacked)
-            if bias is not None:
-                whole = (bias_first, count) == (0, bias.shape[0])
-                stacked = dict(stacked, router_bias=bias if whole else bias[bias_first:bias_first + count])
-            x, aux_layers = jax.lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
-        aux_layers = without_means(aux_layers)
-        if with_stats:
-            flush()
-            pieces.append(aux_layers)
-        elif not stats:
-            aux_total = aux_total + jnp.sum(aux_layers)
+            for stack, (kind, count) in cfg.stacks.items():
+                if count <= cfg.scan_unroll:
+                    for i in range(count):
+                        layer_weights(stack, i)
+
+    def walk(x, aux_total):
+        """The walk of the pattern, once: runs of one kind, each through its own stack;
+        router_bias's rows by a layer's place among the SPARSE layers, whatever their stack."""
+        at = {stack: 0 for stack in cfg.stacks}  # the next layer of each stack
+        sparse_at = 0
+        for kind, run in itertools.groupby(cfg.layers):
+            count, first = len(list(run)), at[kind.stack]
+            at[kind.stack] += count
+            with_stats = stats and (kind.sparse or cfg.dsa_index_heads > 0)
+            stacked = params[kind.stack]
+            bias, bias_first = (router_bias if kind.sparse else None), sparse_at
+            sparse_at += count * kind.sparse
+
+            # a static run of a looped model's static passes: the layer hands its weights on (above); a scan
+            # over the passes sums a weight's terms in its backward carry
+            chained = cfg.loop_steps > 1 and not cfg.loop_scan and count <= cfg.scan_unroll
+
+            def body(x, w, kind=kind, chained=chained):
+                if grads_inside:
+                    x, w = _grads_inside(x, w)
+                taken = dict(w)
+                x, aux = _layer(cfg, mesh, rules, x, taken, positions, kind=kind, router_bias=taken.pop("router_bias", None))
+                return x, ((aux, w) if chained else aux)
+
+            if cfg.remat:
+                body = _remat(cfg, body)
+            # A run that `scan_unroll` covers whole is a STATIC Python loop rather
+            # than lax.scan(unroll=count): scan's internal layer slicing survives
+            # as dynamic-update-slice fusions in the backward (profiled: ~17
+            # ms/step of DUS on the v5e flagship config); static integer indexing
+            # lets XLA constant-fold the slices and fold the per-layer grad
+            # writes, measured ~4 ms/step faster end-to-end.  Same math, different
+            # op association — results agree with the scan path to fusion-order
+            # rounding, not bitwise (pinned by test_scan_unroll_matches_scan).
+            if count <= cfg.scan_unroll:
+                for n in range(count):
+                    with jax.named_scope("stack"):  # the layer's parts are the innermost scopes and name their work
+                        w = layer_weights(kind.stack, first + n)
+                        x, aux = body(x, w if bias is None else dict(w, router_bias=bias[bias_first + n]))
+                        if chained:
+                            aux, sliced[kind.stack, first + n] = aux
+                    aux = without_means(aux)
+                    if with_stats:
+                        pending.append(aux)
+                    elif not stats:  # beside experts a dense layer has no statistics
+                        aux_total = aux_total + aux
+                continue
+            # The scan's own slicing of the stacked weights is `stack`.
+            with jax.named_scope("stack"):
+                if (first, count) != (0, cfg.stacks[kind.stack][1]):
+                    stacked = jax.tree.map(lambda a: a[first:first + count], stacked)
+                if bias is not None:
+                    whole = (bias_first, count) == (0, bias.shape[0])
+                    stacked = dict(stacked, router_bias=bias if whole else bias[bias_first:bias_first + count])
+                x, aux_layers = jax.lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
+            aux_layers = without_means(aux_layers)
+            if with_stats:
+                flush()
+                pieces.append(aux_layers)
+            elif not stats:
+                aux_total = aux_total + jnp.sum(aux_layers)
+        return x, aux_total
+
+    if cfg.loop_steps > 1:
+        return _passes(cfg, lambda x: walk(x, jnp.zeros((), jnp.float32))[0], x, params["final_norm"]), aux_total
+    x, aux_total = walk(x, aux_total)
     if cfg.moe_router_state:
         x, _ = x  # the last layer's state goes nowhere
     if not stats:
@@ -690,6 +763,26 @@ def _decoder(
         for name, (_, layers) in means.items():
             out[name] = totals[name] / layers
         return x, out
+
+
+def _passes(cfg: TransformerConfig, walk, x, final_norm):
+    """A looped model's passes: `walk` (the layers, first to last) `loop_steps`
+    times over the same weights, the final norm after every pass and its output
+    the next pass's input.  x [B, S, E] -> every pass's normed state
+    [loop_steps, B, S, E].  The final norm is `head_loss`'s, as a plain model's."""
+    def one(x, _=None):
+        x = walk(x)
+        with jax.named_scope("head_loss"):
+            x = rms_norm(x, final_norm, cfg.rms_eps)
+        return x, x
+
+    if cfg.loop_scan:
+        return jax.lax.scan(one, x, None, length=cfg.loop_steps)[1]
+    states = []
+    for _ in range(cfg.loop_steps):
+        x = one(x)[0]
+        states.append(x)
+    return jnp.stack(states)
 
 
 def _mean_statistics(cfg: TransformerConfig) -> Dict[str, Tuple[str, int]]:
@@ -746,6 +839,8 @@ def forward_with_aux(
     x, aux = _decoder(params, tokens, cfg, mesh, rules, router_bias)
     if isinstance(aux, dict):
         aux = aux.get("balance", jnp.zeros((), jnp.float32))
+    if cfg.loop_steps > 1:  # the last pass's state, which left the passes normed
+        return head(params, x[-1], cfg, mesh, rules, normed=True), aux
     return head(params, x, cfg, mesh, rules), aux
 
 
@@ -755,13 +850,16 @@ def head(
     cfg: TransformerConfig,
     mesh=None,
     rules: Optional[ShardingRules] = None,
+    normed: bool = False,
 ) -> jax.Array:
     """Final norm + lm head: decoder output [B, S, E] -> logits [B, S, V].
+    ``normed``: x is a looped model's state, which the passes left normed.
 
     Shared by the dense path (forward_with_aux) and the pipelined path
     (parallel/pipeline.pipeline_loss_fn) so the two can never diverge."""
     with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        if not normed:
+            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
         # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
         if cfg.tied_head:
@@ -838,6 +936,57 @@ def lm_head_loss(
     return token_cross_entropy(head(params, x, cfg, mesh, rules), targets)
 
 
+def lm_head_losses(params: Dict[str, Any], h: jax.Array, cfg: TransformerConfig, targets: jax.Array, mesh=None,
+                   rules: Optional[ShardingRules] = None) -> jax.Array:
+    """The next-token loss of every row, [B * S] float32, from a looped
+    model's NORMED state h [B, S, E] (`_passes`): `lm_head_loss` without its
+    norm and its mean, for a loss that weighs the rows itself.  On one TPU
+    device the `tpuft_ce_*` kernels (`fused_linear_cross_entropy_per_row`:
+    the backward reads a cotangent a row); elsewhere the plain XLA form."""
+    from torchft_tpu.ops.cross_entropy import fused_ce_applicable, fused_linear_cross_entropy_per_row, head_row_block
+
+    B, S, E = h.shape
+    whole = head_row_block(B * S, cfg.vocab_size) is None and cfg.vocab_size % 128 == 0
+    with jax.named_scope("head_loss"):
+        if whole and fused_ce_applicable(B * S, E, cfg.vocab_size, mesh):
+            return fused_linear_cross_entropy_per_row(
+                h.reshape(B * S, E), params["lm_head"].astype(cfg.dtype), targets.reshape(B * S))
+        logits = head(params, h, cfg, mesh, rules, normed=True)
+        picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return (jax.nn.logsumexp(logits, axis=-1) - picked).reshape(B * S)
+
+
+def _looped_loss(params: Dict[str, Any], states: jax.Array, cfg: TransformerConfig, targets: jax.Array, mesh=None,
+                 rules: Optional[ShardingRules] = None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """A looped model's loss from every pass's normed state [T, B, S, E], and
+    its counters.  Without a gate (`exit_beta` None): the last pass's mean
+    next-token loss, and nothing counted.  With it, every pass's head (the one
+    untied head, a pass after another) and the exit distribution of every
+    token — p_t = lambda_t prod_{j<t} (1 - lambda_j) with lambda_t =
+    sigmoid(h_t w + b) for t < T, and p_T what is left — in float32 and in
+    logarithms (log p_t is a sum of log-sigmoids):
+    ``mean_i [sum_t p_t loss_t - exit_beta H(p)]``.  Counters:
+    `loop_exit_mass` [T] (sum_i p_t), `loop_pass_loss` [T] (mean_i loss_t),
+    `loop_exit_entropy` (mean_i H(p))."""
+    T, B, S, E = states.shape
+    if cfg.exit_beta is None:
+        return jnp.mean(lm_head_losses(params, states[-1], cfg, targets, mesh, rules)), {}
+    losses = jnp.stack([lm_head_losses(params, states[t], cfg, targets, mesh, rules) for t in range(T)])  # [T, N]
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        h = states[:-1].reshape(T - 1, B * S, E).astype(jnp.float32)
+        logit = jnp.einsum("tne,e->tn", h, gate["w"].astype(jnp.float32)) + gate["b"].astype(jnp.float32)
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-logit), axis=0)  # log prod_{j<=t} (1 - lambda_j)
+        before = jnp.concatenate([jnp.zeros((1, B * S), jnp.float32), stay[:-1]])
+        log_p = jnp.concatenate([jax.nn.log_sigmoid(logit) + before, stay[-1:]])  # [T, N]
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * losses, axis=0) - cfg.exit_beta * entropy)
+        counters = {"loop_exit_mass": jnp.sum(p, axis=1), "loop_pass_loss": jnp.mean(losses, axis=1),
+                    "loop_exit_entropy": jnp.mean(entropy)}
+    return loss, counters
+
+
 def loss_fn(
     params: Dict[str, Any],
     batch: Dict[str, jax.Array],
@@ -874,10 +1023,13 @@ def loss_and_counters(
     path) also ``moe_active_units`` (int32, the (row, hidden unit) pairs of the
     held experts' rows that ReLU left above zero, over the sparse layers) and
     ``moe_units_held`` (int32, all such pairs: rows that hold an assignment
-    times ``d_ff``); for a dense model nothing.  ``router_bias`` [n_sparse_layers,
+    times ``d_ff``); for a looped model under the exit-weighted loss what
+    `_looped_loss` lists; for a dense model nothing.  ``router_bias`` [n_sparse_layers,
     n_experts] is the sigmoid router's choice bias: a constant, no leaf of
     ``params``, so neither the gradient nor the optimizer sees it."""
     x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
+    if cfg.loop_steps > 1:
+        return _looped_loss(params, x, cfg, batch["targets"], mesh, rules)
     loss = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
     counters = {}
     with jax.named_scope("head_loss"):  # the loss's other terms and the counters

@@ -188,10 +188,14 @@ SUBSPANS = {
 #   head_loss — final norm, head product, cross-entropy, the loss's terms
 #     and counters; stack — a layer's slice of the stacked weights (in the
 #     backward pass: the per-layer gradients padded and summed into the
-#     stacked gradient) and the stacking of the layers' statistics.
+#     stacked gradient) and the stacking of the layers' statistics;
+#   exit_gate — a looped model's exit-weighted loss: the gate's product on
+#     every pass's state, the exit distribution, the combination of the
+#     passes' losses, the entropy term and the three counters (the passes'
+#     heads and the final norm between passes stay head_loss).
 PARTS = (
     "embed", "norm", "attn_proj", "cca_mix", "kda_mix", "kda_scan", "attn", "attn_window", "dsa_index", "dsa_select",
-    "ffn", "router", "experts", "shared_expert", "head_loss", "stack", "ssm_mix", "ssm_scan",
+    "ffn", "router", "experts", "shared_expert", "head_loss", "stack", "ssm_mix", "ssm_scan", "exit_gate",
 )
 
 

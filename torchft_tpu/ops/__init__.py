@@ -11,6 +11,7 @@ from torchft_tpu.ops.attention import flash_attention
 from torchft_tpu.ops.cross_entropy import (
     fused_ce_applicable,
     fused_linear_cross_entropy,
+    fused_linear_cross_entropy_per_row,
 )
 from torchft_tpu.ops.ring_attention import ring_attention
 from torchft_tpu.ops.rmsnorm import rms_norm, rms_norm_pallas
@@ -20,6 +21,7 @@ __all__ = [
     "flash_attention",
     "fused_ce_applicable",
     "fused_linear_cross_entropy",
+    "fused_linear_cross_entropy_per_row",
     "ring_attention",
     "rms_norm",
     "rms_norm_pallas",

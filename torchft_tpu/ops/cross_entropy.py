@@ -155,10 +155,12 @@ def _ce_lse_kernel(
 
 def _ce_dlogits_kernel(
     x_ref, w_ref, tgt_ref, lse_ref, scale_ref, dl_ref,
-    *, block_rows: int, block_v: int, valid_v: Optional[int] = None, first_ref=None,
+    *, block_rows: int, block_v: int, valid_v: Optional[int] = None, first_ref=None, row_scale: bool = False,
 ):
     """``first_ref``: where the call covers a slab of w's columns, the slab's
-    first tile of w (SMEM), so that ``cols`` are the head's own columns."""
+    first tile of w (SMEM), so that ``cols`` are the head's own columns.
+    ``row_scale``: ``scale_ref`` holds a scale a row, laid out as the
+    log-sum-exp is ([1, 1, N]), and not the one number in SMEM."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(0)
@@ -175,7 +177,8 @@ def _ce_dlogits_kernel(
     p = jnp.where(cols == tg, p - 1.0, p)          # softmax - onehot
     if valid_v is not None:
         p = jnp.where(cols < valid_v, p, 0.0)      # the padding has no logit
-    dl_ref[...] = (p * scale_ref[0, 0]).astype(dl_ref.dtype)
+    scale = row_stat_col(scale_ref, i, block_rows) if row_scale else scale_ref[0, 0]
+    dl_ref[...] = (p * scale).astype(dl_ref.dtype)
 
 
 def _ce_lse_pallas(x, w, interpret: bool = False, valid_v: Optional[int] = None):
@@ -210,7 +213,8 @@ def _ce_lse_pallas(x, w, interpret: bool = False, valid_v: Optional[int] = None)
 def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid_v: Optional[int] = None,
                        cols=None):
     """Scaled bf16 dlogits = (softmax(x@w) - onehot(targets)) * scale.
-    scale is a traced scalar (folded in here so no extra [N, V] pass).
+    scale is a traced scalar (folded in here so no extra [N, V] pass), or
+    [N]: a scale a row, which the kernel reads as it reads the log-sum-exp.
     ``cols`` = (j, slab, width), j traced: the ``width`` columns from column
     j * slab alone, [N, width] — the same kernel at the same tiles, reading w
     in place from the slab's first tile on, a prefetched scalar that the index
@@ -226,8 +230,9 @@ def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid
     br, bv = _block_rows(n, e), _block_v(v if cols is None else math.gcd(v, slab), e)  # a tile divides j * slab
     tgt = targets.astype(jnp.int32)[None, None, :]
     lse3 = lse[None, None, :]
-    scale2 = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    kernel = functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv, valid_v=valid_v)
+    row_scale = jnp.ndim(scale) == 1
+    scale2 = jnp.asarray(scale, jnp.float32).reshape((1, 1, n) if row_scale else (1, 1))
+    kernel = functools.partial(_ce_dlogits_kernel, block_rows=br, block_v=bv, valid_v=valid_v, row_scale=row_scale)
     specs = dict(
         grid=(n // br, v // bv),
         in_specs=[
@@ -235,7 +240,8 @@ def _ce_dlogits_pallas(x, w, targets, lse, scale, interpret: bool = False, valid
             pl.BlockSpec((e, bv), lambda i, j, *first: (0, first[0][0] + j if first else j)),  # w
             pl.BlockSpec((1, 1, n), lambda i, j, *first: (0, 0, 0)),   # targets
             pl.BlockSpec((1, 1, n), lambda i, j, *first: (0, 0, 0)),   # lse
-            pl.BlockSpec(memory_space=pltpu.SMEM),                     # scale
+            pl.BlockSpec((1, 1, n), lambda i, j, *first: (0, 0, 0)) if row_scale
+            else pl.BlockSpec(memory_space=pltpu.SMEM),                # scale
         ],
         out_specs=pl.BlockSpec((br, bv), lambda i, j, *first: (i, j)),
     )
@@ -289,9 +295,9 @@ def _ce_vjp_fwd(x, w, targets):
 
 
 def _ce_dlogits(x, w, targets, lse, scale, valid_v: Optional[int] = None, cols=None):
-    """(softmax(x @ w) - onehot(targets)) * scale in x's dtype, [N, V]; with
-    ``cols`` = (j, slab, width), j traced, its ``width`` columns from column
-    j * slab alone."""
+    """(softmax(x @ w) - onehot(targets)) * scale in x's dtype, [N, V] — scale
+    one number, or [N]: one a row; with ``cols`` = (j, slab, width), j traced,
+    its ``width`` columns from column j * slab alone."""
     if _pallas_util.on_tpu():
         # dlogits tile-by-tile in bf16 (pallas) — the f32 logits never
         # exist in HBM.
@@ -306,13 +312,18 @@ def _ce_dlogits(x, w, targets, lse, scale, valid_v: Optional[int] = None, cols=N
         logits = jnp.where(at < valid_v, logits, -1e30)
     p = jnp.exp(logits - lse[:, None])
     p = p - (targets[:, None] == at)
-    return (p * scale).astype(x.dtype)
+    return (p * (scale[:, None] if jnp.ndim(scale) == 1 else scale)).astype(x.dtype)
 
 
 def _ce_vjp_bwd(res, g, valid_v: Optional[int] = None):
+    return _ce_grads(res, g / res[0].shape[0], valid_v)
+
+
+def _ce_grads(res, scale, valid_v: Optional[int] = None):
+    """(dx, dw, no gradient of the targets) from the dlogits times ``scale``:
+    the mean's one number g / N, or the per-row form's cotangent a row [N]."""
     x, w, targets, lse = res
-    n = x.shape[0]
-    dl = _ce_dlogits(x, w, targets, lse, g / n, valid_v)
+    dl = _ce_dlogits(x, w, targets, lse, scale, valid_v)
     # Two plain XLA matmuls — XLA runs these bf16 matmuls near MXU peak,
     # which hand-written scratch-accumulation kernels measured 2x worse at.
     dx = jax.lax.dot_general(
@@ -331,6 +342,28 @@ def _ce_vjp_bwd(res, g, valid_v: Optional[int] = None):
 
 
 fused_linear_cross_entropy.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
+
+
+# -- the loss of every row ------------------------------------------------------------
+
+
+@jax.custom_vjp
+def fused_linear_cross_entropy_per_row(x, w, targets):
+    """`fused_linear_cross_entropy` before its mean: the loss of every row,
+    [N] float32, for a loss that weighs the rows itself (a looped model's
+    exit-weighted loss, `models/transformer.py`).  Its backward takes a
+    cotangent a row, which `tpuft_ce_dlogits` reads as a scale a row, so the
+    bf16 dlogits are still written once and nothing [N, V] is scaled after.
+    x, w, targets and the gate (`fused_ce_applicable`) as there."""
+    return _ce_per_row_fwd(x, w, targets)[0]
+
+
+def _ce_per_row_fwd(x, w, targets):
+    lse, tl = _ce_fwd(x, w, targets)
+    return lse - tl, (x, w, targets, lse)
+
+
+fused_linear_cross_entropy_per_row.defvjp(_ce_per_row_fwd, lambda res, g: _ce_grads(res, g))
 
 
 # -- a head whose width no block divides ------------------------------------------

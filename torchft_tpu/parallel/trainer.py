@@ -98,7 +98,8 @@ class TrainStep:
             beside the loss: ``last_counters`` holds the newest (on the
             device), and ``ft_step`` lands them in the Manager's
             ``step_summary`` — a scalar under its name, an array as
-            ``<name>_max`` and ``<name>_mean`` — one step late, with
+            ``<name>_max`` and ``<name>_mean``, a vector of at most 16
+            entries whole under its name too — one step late, with
             ``counters_step`` naming the step they were counted in: by then
             they are on the host and the hand-over waits for nothing.  A
             loss without counters compiles to the program it always did.
@@ -275,6 +276,8 @@ class TrainStep:
                 else:
                     fields[name + "_max"] = value.max().item()
                     fields[name + "_mean"] = float(value.mean())
+                    if value.ndim == 1 and value.size <= 16:  # a number a pass of a looped model: whole, too
+                        fields[name] = value.tolist()
             manager.note_summary_fields(**fields)
         self._counters_step = None
 
