@@ -145,12 +145,13 @@ def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> 
         assert a.dtype == b.dtype and a.shape == b.shape and bool(jnp.array_equal(a, b)), name
 
 
-def _column_stats_fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale):
-    """The forward tile as it stood before PR 62, kept here: the running max
-    and sum ONE COLUMN, [block_q, 1], broadcast along the lanes wherever the
-    scores and the accumulator want them.  It takes the kernel's lane-replicated
+def _column_stats_fwd_scores(q, k, m_prev, l_prev, keep, *, scale):
+    """The forward tile's first half as it stood before PR 62, kept here: the
+    running max and sum ONE COLUMN, [block_q, 1], broadcast along the lanes
+    wherever the scores want them.  It takes the kernel's lane-replicated
     [block_q, 128] statistics by their first column and hands its own back
-    broadcast, so that `_fa_kernel` runs it in `_fwd_tile`'s place."""
+    broadcast, so that `_fa_kernel` runs it in `_fwd_scores`' place; the
+    rescale goes on to the second half as the column it is."""
     from torchft_tpu.ops import attention as fa
 
     lanes = m_prev.shape
@@ -162,8 +163,13 @@ def _column_stats_fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale):
     p = jnp.exp(s - m_cur)
     alpha = jnp.exp(m_prev - m_cur)
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc * alpha + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return jnp.broadcast_to(m_cur, lanes), jnp.broadcast_to(l_new, lanes), acc
+    return jnp.broadcast_to(m_cur, lanes), alpha, jnp.broadcast_to(l_new, lanes), p
+
+
+def _column_stats_fwd_accumulate(acc, alpha, p, v):
+    """The second half of that tile, in `_fwd_accumulate`'s place: the
+    accumulator rescaled by the one column."""
+    return acc * alpha + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
 # kind: (batch * heads, positions, query and key width, query heads a KV head, H by the module's rule)
@@ -206,7 +212,71 @@ def test_lane_replicated_statistics_are_bitwise_one_column(kind, heads_per_step,
     fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=True, interpret=True, **more)
     assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == bh // heads
     o, lse = fwd(q, k, v)
-    monkeypatch.setattr(fa, "_fwd_tile", _column_stats_fwd_tile)
+    monkeypatch.setattr(fa, "_fwd_scores", _column_stats_fwd_scores)
+    monkeypatch.setattr(fa, "_fwd_accumulate", _column_stats_fwd_accumulate)
+    want_o, want_lse = fwd(q, k, v)
+    assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01 and bool(jnp.all(jnp.isfinite(lse)))
+    assert o.dtype == want_o.dtype and bool(jnp.array_equal(o, want_o)), "out"
+    assert lse.dtype == want_lse.dtype and bool(jnp.array_equal(lse, want_lse)), "lse"
+
+
+def _vmapped_fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale, kv_group):
+    """The forward step as it stood before PR 64, kept here: all the step's
+    heads at once, the tile's two halves one function of values under
+    `jax.vmap`, every block and scratch read and stored whole."""
+    from torchft_tpu.ops import attention as fa
+
+    heads = q_ref.shape[0]
+
+    def tile(q, k, v, m_prev, l_prev, acc):
+        m_cur, alpha, l_new, p = fa._fwd_scores(q, k, m_prev, l_prev, keep, scale=scale)
+        return m_cur, l_new, fa._fwd_accumulate(acc, alpha, p, v)
+
+    m_scr[...], l_scr[...], acc_scr[...] = jax.vmap(tile)(
+        q_ref[...], fa._kv_heads(k_ref, heads, kv_group), fa._kv_heads(v_ref, heads, kv_group),
+        m_scr[...], l_scr[...], acc_scr[...])
+
+
+# At seven heads a step the cases run 28 heads (smallthinker's 7 of 28), their KV heads a group of seven where a step
+# holds whole groups and of four where it straddles two; at one head and at the rule's H, `LANE_REPLICATED_CASES`' shapes.
+SEVEN_OF_28 = {"packed_mask": 7, "kv_group_4_aligned": 7}
+
+
+@pytest.mark.parametrize("heads_per_step", [1, 7, None], ids=["one_head", "seven_of_28", "the_rule"])
+@pytest.mark.parametrize("kind", sorted(LANE_REPLICATED_CASES))
+def test_the_skewed_heads_of_a_step_are_bitwise_the_heads_at_once(kind, heads_per_step, monkeypatch) -> None:
+    """The forward step walks its heads with a skew of one (PR 64: head h's
+    scores, max, exp and sum, then head h - 1's rescale and p v).  Out and lse
+    are bit for bit those of the same kernel around the step kept above, all
+    heads under one `jax.vmap`: over the triangle, the band under a window of
+    512, a packed mask (its tile read once for the step's heads), query and key
+    256 wide beside a value of 128, and KV heads read in place by steps that
+    hold whole groups and by steps that straddle two — at one head a step
+    (first half, then second: no skew to speak of), at seven of 28 and at the H
+    the shapes give."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    bh, seq, d, kv_group, rule = LANE_REPLICATED_CASES[kind]
+    if heads_per_step == 7:
+        bh, kv_group = 28, SEVEN_OF_28.get(kind, kv_group)
+    more = {"kv_group": kv_group, "heads_per_step": heads_per_step}
+    if kind == "window_512":
+        more["window"] = 512
+    elif kind == "packed_mask":
+        keep = jax.random.bernoulli(jax.random.PRNGKey(64), 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)
+        more["mask"] = sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8)
+    heads = heads_per_step or fa._heads_per_step(fa._heads_share(bh, more.get("mask")))
+    assert heads == (heads_per_step or rule)
+    assert fa._straddles(heads, kv_group) == (kind == "kv_group_4_straddling" and heads > 1)
+    ks = jax.random.split(jax.random.PRNGKey(len(kind) + heads), 3)
+    q = jax.random.normal(ks[0], (bh, seq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (bh // kv_group, seq, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (bh // kv_group, seq, 128), jnp.bfloat16)
+    fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=True, interpret=True, **more)
+    assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == bh // heads
+    o, lse = fwd(q, k, v)
+    monkeypatch.setattr(fa, "_fwd_step", _vmapped_fwd_step)
     want_o, want_lse = fwd(q, k, v)
     assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01 and bool(jnp.all(jnp.isfinite(lse)))
     assert o.dtype == want_o.dtype and bool(jnp.array_equal(o, want_o)), "out"

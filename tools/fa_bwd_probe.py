@@ -68,7 +68,12 @@ VALUs', XLUs' and store slots taken there and of the bundles that hold one, the
 stores that are spills, and where
 in the tile each product starts (`product_starts`: a product's first matmul
 latches new weights) beside the first and the last exponential — whether one
-head's products stand under another's softmax tile.  A schedule is static:
+head's products stand under another's softmax tile.  For a forward kernel the
+starts are told apart by how their weights were pushed (`qk_starts`: k
+transposed, two loads a head where query and key are 256 wide; `pv_starts`: v
+as it lies), and `pv_before_last_exp` counts the p v that start before the
+step's last exponential: all but the last head's since the step walks its heads
+with a skew of one (PR 64), none before.  A schedule is static:
 stalls on results in flight are not in it, so bundles at the clock (1.5 GHz)
 are a floor for the measured tile, not its time.
 """
@@ -228,13 +233,24 @@ def read_schedule(dump: str, kernel: str) -> dict:
     holding = lambda unit: round(100.0 * sum(1 for t in taken if t[unit]) / len(taken), 1)  # noqa: E731
     at = lambda wanted: [number - first for number, ops in bundles if first <= number <= last and any(wanted(op) for op in ops)]  # noqa: E731
     exps = at(lambda op: op.startswith("vpow2"))
+    on_mxu0 = [(number - first, op) for number, ops in bundles if first <= number <= last for op in ops if op.endswith("mxu0")]
+    products = [(start, op) for start, op in on_mxu0 if op.startswith("vmatmul") and ".vlgmr." in op]
+    forward = {}
+    if kernel.endswith("_fwd"):
+        # a product starts on the weights last pushed into the staging register it names (`msra`, `msrb`: the
+        # next product's go in while this one streams): k transposed for q k^T, v as it lies for p v
+        staged = lambda op: re.search(r"\.(msr\w)\.", op).group(1)  # noqa: E731
+        pushes = [(start, staged(op), ".xpose." in op) for start, op in on_mxu0 if op.startswith("vmatpush")]
+        is_qk = {start: [xpose for pushed, reg, xpose in pushes if pushed < start and reg == staged(op)][-1] for start, op in products}
+        pv = [start for start, _ in products if not is_qk[start]]
+        forward = {"qk_starts": [start for start, _ in products if is_qk[start]], "pv_starts": pv,
+                   "pv_before_last_exp": sum(1 for start in pv if start < exps[-1])}
     return {
         "bundles": len(bundles), "tile_bundles": last - first + 1,
         "mxu_slots_percent": share("MXU"), "valu_slots_percent": share("VALU"), "xlu_slots_percent": share("XLU"),
         "vstore_slots_percent": share("VSTORE"), "spill_stores": sum(t["VSTORE:SPILL"] for t in taken),
         "bundles_with_mxu_percent": holding("MXU"), "bundles_with_valu_percent": holding("VALU"),
-        "product_starts": at(lambda op: op.startswith("vmatmul") and ".vlgmr." in op and op.endswith("mxu0")),
-        "first_exp": exps[0], "last_exp": exps[-1],
+        "product_starts": [start for start, _ in products], **forward, "first_exp": exps[0], "last_exp": exps[-1],
         "last_pop": at(lambda op: ".mrf." in op)[-1],
     }
 

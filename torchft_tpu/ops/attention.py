@@ -27,10 +27,13 @@ Design notes (MXU/HBM-minded):
     is expressed in XLA with the scores materialized;
   - a grid step carries H heads' tile, not one (`HEADS_PER_STEP`): the
     grid's outer axis is batch*heads / H, every block and scratch leads with
-    the heads, and the tile's arithmetic is a function of values run over
-    them under `jax.vmap` (`_fwd_tile`, `_bwd_tile`, `_heads_at_once`); H is
-    read from the shapes.  A head's arithmetic is what one head a step
-    computes, bit for bit;
+    the heads, and the tile's arithmetic is a function of values: the
+    backward's run over the heads under `jax.vmap` (`_bwd_tile`,
+    `_heads_at_once`), the forward's in two halves a head, walked with a skew
+    of one so that a head's p v stands beside the next head's softmax tile
+    (`_fwd_scores`, `_fwd_accumulate`, `_fwd_step`); H is read from the
+    shapes.  A head's arithmetic is what one head a step computes, bit for
+    bit;
   - grouped queries read their KV heads in place: k and v reach the kernels
     with their own heads, [batch * kv_heads, S, d], in both directions, and
     no array of batch * q_heads k or v heads exists in HBM.  A step's k / v
@@ -116,17 +119,20 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     # more masked elements than two 512-row blocks.  "VPU-bound", as this
     # comment used to put it, was read off timings; the compiled schedule at
     # 28 x 16,384 x 128, seven heads a step (`tools/fa_bwd_probe.py
-    # --bundles`, PERF.md section 6, PRs 52 and 62) says which unit.  A
-    # forward tile is 1,322 bundles a head for 1,024 cycles of MXU work, the
-    # MXUs' slots 75% taken, the VALUs' 61% (91% of the bundles hold a VALU
-    # operation), the XLUs' 29%: the heads' q k^T go out ~800 bundles apart,
-    # each under the softmax tile of the head before it (row max across
-    # lanes, exp, row sum across lanes: the VALUs' work), and the heads' p v
-    # follow the last exponential back to back at the MXUs' rate, ~480
-    # bundles each with nothing beside them; 55% of the tile's stores are
-    # spills of the score tile.  It was 1,851 a head (PR 52: 1,813 at one
-    # head a step), no unit above 54% of its slots, while the statistics were
-    # one column, narrowed and broadcast along the lanes again a row group.
+    # --bundles`, PERF.md section 6, PRs 52, 62 and 64) says which unit.  A
+    # forward tile is 1,139 bundles a head for 1,024 cycles of MXU work, the
+    # MXUs' slots 88% taken, the VALUs' 62% (90% of the bundles hold a VALU
+    # operation), the XLUs' 33%: a head's q k^T and the p v of the head
+    # before it (`_fwd_step`'s skew) go out ~520 bundles apart under one
+    # softmax tile (row max across lanes, exp, row sum across lanes: the
+    # VALUs' work), and only the last head's p v follows the last
+    # exponential; 4,575 of the tile's 5,917 stores are spills of the score
+    # tile (5,045 of 6,387 before).  It was 1,322 a head at 75% while the
+    # heads ran under one `jax.vmap` and all their p v stood after the last
+    # exponential, ~480 bundles each with nothing beside them (PR 62), and
+    # 1,851 (PR 52: 1,813 at one head a step), no unit above 54% of its
+    # slots, while the statistics were one column, narrowed and broadcast
+    # along the lanes again a row group.
     # The backward tile is 2,574 bundles for its five products' 2,560
     # cycles: MXU-bound as scheduled.
     # The band walk has the same tiles.  Under a window of 512 a 512 x 512 q
@@ -273,8 +279,9 @@ class _Walk:
 # x 128 read 1.93 us on a v5e for a compiled schedule of 1,813 bundles (1.21 us
 # at 1.5 GHz), the one-pass backward 2.71 for 2,574 (1.72).  The heads of a
 # call are independent, so a step takes H of them: every block and scratch
-# leads with the heads, and the tile's arithmetic runs over them under
-# `jax.vmap` (`_heads_at_once`), each product one batched product.  Read on
+# leads with the heads, and the tile's arithmetic runs over them — the
+# backward's under `jax.vmap` (`_heads_at_once`), each product one batched
+# product; the forward's a head at a time since PR 64 (`_fwd_step`).  Read on
 # the chip (`tools/fa_bwd_probe.py`, PERF.md section 6, PR 52), us a tile:
 # forward 1.93 -> 2.02 (H = 2) -> 1.65 (4) -> 1.50 (7) -> 1.43 (14), one-pass
 # backward 2.71 -> 2.34 (2) -> 2.31 (4), results bit for bit those of one head
@@ -290,10 +297,26 @@ class _Walk:
 # H = 1 and 1.50 -> 1.05 at H = 7 (28 x 16,384 x 128), 1.43 -> 0.98 under the
 # Keye cell's mask at H = 8, 1.69 -> 1.22 under a window of 4,096, 2.04 ->
 # 1.58 at 32 x 8,192 x 256 / 128, 2.50 -> 1.96 at 32 x 4,096 x 128, bit for
-# bit the parent's.  The backward is untouched: 2,590 to 2,780 bundles a
-# head, its five products' own time (`--bundles` prints where each product
-# starts).  H is read from the shapes (`_heads_per_step`,
-# `_bwd_heads_per_step`) and nothing else.
+# bit the parent's.  That schedule still left every head's p v after the
+# step's last exponential, alone in the MXUs; since the step walks its heads
+# with a skew of one (`_fwd_step`, PR 64) a head's p v stands beside the next
+# head's softmax tile and the forward schedules at 1,126 to 1,139 bundles a
+# head at H = 7 or 8, the MXUs' slots 88 to 89% taken (1,653 at 256 / 128
+# wide): read on the chip against the parent in one call (PERF.md section 6,
+# PR 64), us a tile forward 1.05 -> 0.92 at H = 7 (28 x 16,384 x 128), 0.98
+# -> 0.84 under the Keye cell's mask at H = 8, 1.16 -> 1.04 under a window of
+# 4,096, 1.57 -> 1.44 at 64 heads under a window of 512, 1.56 -> 1.50 at 32 x
+# 8,192 x 256 / 128, 1.90 -> 1.84 at 32 x 4,096 x 128, bit for bit the
+# parent's.  The same loop WITHOUT the skew (a head's two halves one after the
+# other) schedules at 1,115 to 1,131 (1,581 at 256 / 128) and read 0.5 to
+# 3.5% under the skewed one at six of those seven shapes, the parent's own
+# readings 1.3% apart from call to call: not kept (ISSUE 64 keeps the first
+# form under 1,200), left as the next reading.  A `fori_loop` over the heads,
+# p handed on through a VMEM scratch of two slots, schedules at ~1,250 a head
+# (a body of ~1,230 and ~1,400 of prologue and epilogue a step): struck.  The
+# backward is untouched: 2,590 to 2,780 bundles a head, its five products' own
+# time (`--bundles` prints where each product starts).  H is read from the
+# shapes (`_heads_per_step`, `_bwd_heads_per_step`) and nothing else.
 HEADS_PER_STEP = 8
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
@@ -336,31 +359,34 @@ def _heads_share(bh: int, mask) -> int:
 def _lanes(stat, width: int):
     """A lane-replicated row statistic, [.., rows, 128] with every lane of a
     row the same number, read ``width`` lanes wide: the same array side by
-    side (`pltpu.repeat` has no batching rule under `_heads_at_once`'s
-    `vmap`), cut where ``width`` is no lane multiple."""
+    side (a `concatenate`, as it has been since the tile ran under `jax.vmap`,
+    for which `pltpu.repeat` has no batching rule), cut where ``width`` is no
+    lane multiple."""
     if width == _LANE:
         return stat
     stat = jnp.concatenate([stat] * -(-width // _LANE), axis=-1)
     return stat if stat.shape[-1] == width else stat[..., :width]
 
 
-def _fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale: float):
-    """One head's forward tile as a function of values: the online-softmax
-    update ``(m, l, acc) -> (m, l, acc)`` of q's (block_q, d) rows against
-    one (block_k, d) kv tile.  ``keep`` [block_q, block_k] bool, or None
-    where every pair is visible.  Matmuls run in the INPUT dtype with f32
-    accumulation: bf16 model activations hit the MXU at full rate (an f32 x
-    f32 matmul runs at a fraction of it); softmax statistics stay f32.
+def _fwd_scores(q, k, m_prev, l_prev, keep, *, scale: float):
+    """The first half of one head's forward tile as a function of values: the
+    scores of q's (block_q, d) rows against one (block_k, d) k tile, and the
+    online softmax's statistics moved on, ``(m, l) -> (m, alpha, l, p)`` with
+    ``alpha`` the old accumulator's rescale and ``p`` [block_q, block_k] the
+    unnormalised probabilities, float32.  ``keep`` [block_q, block_k] bool,
+    or None where every pair is visible.  Matmuls run in the INPUT dtype with
+    f32 accumulation: bf16 model activations hit the MXU at full rate (an f32
+    x f32 matmul runs at a fraction of it); softmax statistics stay f32.
 
     The running max and sum are LANE-REPLICATED, [block_q, 128] in and out as
-    the scratch holds them: a row's max and sum leave their lane reduction as
-    one column and are broadcast once, into the statistic, and nothing is
-    narrowed to a column to be broadcast again where the scores, the
-    accumulator and the scratch want it (`_lanes`).  Every row's max, exp,
-    sum and products are what [block_q, 1] statistics compute, bit for bit
-    (tests/test_attention_walks.py keeps that tile); the schedule is 1,320
-    bundles a head where it was 1,850, 1.05 us a tile on a v5e where it was
-    1.50 (`HEADS_PER_STEP`'s account, PR 62)."""
+    the scratch holds them, and so is ``alpha``: a row's max and sum leave
+    their lane reduction as one column and are broadcast once, into the
+    statistic, and nothing is narrowed to a column to be broadcast again where
+    the scores, the accumulator and the scratch want it (`_lanes`).  Every
+    row's max, exp, sum and products are what [block_q, 1] statistics
+    compute, bit for bit (tests/test_attention_walks.py keeps that tile);
+    the schedule was 1,320 bundles a head where it had been 1,850, 1.05 us a
+    tile on a v5e where it had been 1.50 (`HEADS_PER_STEP`'s account, PR 62)."""
     s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale  # [block_q, block_k] f32
     if keep is not None:
         # Unconditional mask.  A `lax.cond` a block in its place read ~3 ms a
@@ -373,8 +399,15 @@ def _fwd_tile(q, k, v, m_prev, l_prev, acc, keep, *, scale: float):
     p = jnp.exp(s - _lanes(m_cur, s.shape[-1]))                        # [block_q, block_k]
     alpha = jnp.exp(m_prev - m_cur)                                    # rescale old accumulator
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc * _lanes(alpha, acc.shape[-1]) + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return m_cur, l_new, acc
+    return m_cur, alpha, l_new, p
+
+
+def _fwd_accumulate(acc, alpha, p, v):
+    """The second half of that tile: the (block_q, d_v) float32 accumulator
+    rescaled and ``p``, cast to v's dtype, times the (block_k, d_v) v tile
+    added.  Nothing hangs from it but the accumulator's store, so a scheduler
+    that sees it alone lets it sink: `_fwd_step` says what it stands beside."""
+    return acc * _lanes(alpha, acc.shape[-1]) + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
 def _bwd_tile(q, k, v, do, lse, delta, keep, *, scale: float, dkdv: bool, dq: bool):
@@ -426,19 +459,74 @@ def _keep(walk: _Walk, qi, ki, mask_ref):
     return None
 
 
-def _kv_heads(ref, heads: int, kv_group: int):
-    """A step's k or v with a head each, from the step's block of it
-    (`_kv_spec`).  Grouped queries read their KV heads in place, so the block
-    holds one head for the whole step, one for each group of the step's
-    heads, or, where the step straddles groups, `_kv_span` adjacent KV heads
-    of which every head takes its own by a scalar index."""
+@functools.lru_cache(maxsize=None)
+def _traced_once(fn, **static):
+    """``fn`` with its static arguments, jitted, and one object for them: a
+    kernel's body that calls it once a head holds one trace of it a shape, and
+    so does every later trace of the body (`_heads_at_once`'s reason)."""
+    return jax.jit(functools.partial(fn, **static))
+
+
+def _kv_head(ref, h: int, heads: int, kv_group: int):
+    """Head h's k or v tile, of a step's ``heads``, read from the step's block
+    of it (`_kv_spec`): the block's own head h, the head of h's group, the one
+    head the step shares, or, where the step straddles groups, the KV head of
+    h's group counted from the block's first, a scalar index."""
     from jax.experimental import pallas as pl
 
     held = ref.shape[0]
     if _straddles(heads, kv_group):
         b = pl.program_id(0)
-        first = _first_kv_head(b, pl.num_programs(0) * heads, heads, kv_group, held)
-        return jnp.concatenate([ref[pl.ds(jax.lax.div(b * heads + h, kv_group) - first, 1)] for h in range(heads)], axis=0)
+        return ref[jax.lax.div(b * heads + h, kv_group) - _first_kv_head(b, pl.num_programs(0) * heads, heads, kv_group, held)]
+    return ref[h * held // heads]
+
+
+def _fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale: float, kv_group: int):
+    """A grid step's forward tiles, the heads walked with a SKEW OF ONE: head
+    0's scores half; then, for h = 1 .. H - 1, head h's scores half and head
+    h - 1's accumulate half; then head H - 1's accumulate half.  A head reads
+    its own slice of every block and scratch and stores its statistics and
+    accumulator where they are made; ``keep`` is the step's.  The heads share
+    nothing else, so every head's max, exp, sum, rescale and both products
+    are what all heads under one `jax.vmap` computed (and one head a step
+    computes), bit for bit: only their order across heads is written down
+    (tests/test_attention_walks.py keeps the step under `jax.vmap`).
+
+    Why: a head's p v has nothing hanging from it but the accumulator's
+    store.  While the H heads were one batched function the compiler's
+    scheduler let every p v sink to the end of the step: at 28 x 16,384 x 128,
+    seven heads a step, the seven p v stood after the last exponential, back
+    to back at the MXUs' rate with nothing beside them, 3,000 of the tile's
+    9,270 bundles, while the softmax tiles before them kept the VALUs busy
+    and the MXUs at 75% of their slots.  Written a head at a time, a head's
+    p v (MXU) stands beside the next head's softmax tile (VALU, XLU, EUP):
+    7,975 bundles, 1,139 a head for 1,024 cycles of products, the MXUs' slots
+    88% taken, every p v but the last started before the last exponential
+    (`tools/fa_bwd_probe.py --bundles`: `pv_starts`, `last_exp`), and on a
+    v5e 0.92 us a tile where it read 1.05, 0.84 from 0.98 under the Keye
+    cell's mask, 1.04 from 1.16 under a window of 4,096, 1.50 from 1.56 at
+    256 / 128 wide (`HEADS_PER_STEP`'s account, PR 64).  K and V change
+    places in the MXUs once a head, 512 streamed rows a weight load."""
+    heads = q_ref.shape[0]
+    scores, accumulate = _traced_once(_fwd_scores, scale=scale), _traced_once(_fwd_accumulate)
+    before = None  # the head before's rescale and probabilities, on their way to its p v
+    for h in range(heads):
+        m_scr[h], alpha, l_scr[h], p = scores(q_ref[h], _kv_head(k_ref, h, heads, kv_group), m_scr[h], l_scr[h], keep)
+        if before is not None:
+            acc_scr[h - 1] = accumulate(acc_scr[h - 1], *before, _kv_head(v_ref, h - 1, heads, kv_group))
+        before = alpha, p
+    acc_scr[heads - 1] = accumulate(acc_scr[heads - 1], *before, _kv_head(v_ref, heads - 1, heads, kv_group))
+
+
+def _kv_heads(ref, heads: int, kv_group: int):
+    """A step's k or v with a head each, from the step's block of it
+    (`_kv_spec`).  Grouped queries read their KV heads in place, so the block
+    holds one head for the whole step, one for each group of the step's
+    heads, or, where the step straddles groups, `_kv_span` adjacent KV heads
+    of which every head takes its own by a scalar index (`_kv_head`)."""
+    held = ref.shape[0]
+    if _straddles(heads, kv_group):
+        return jnp.stack([_kv_head(ref, h, heads, kv_group) for h in range(heads)])
     x = ref[...]
     if held == heads:
         return x
@@ -457,7 +545,6 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group:
     qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
     mask_ref = rest[0] if masked else None
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[1:] if masked else rest
-    heads = q_ref.shape[0]
 
     @pl.when(ki == walk.first_k(qi))
     def _init():
@@ -471,14 +558,7 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group:
 
     @pl.when(run)
     def _step():
-        keep = _keep(walk, qi, ki, mask_ref)
-        # The statistics go from scratch to scratch whole, lane-replicated
-        # (`_fwd_tile`): one column of them read or stored has to be broadcast
-        # along the lanes again a row group, which paced the whole tile (a
-        # tile reads 0.70 of the one-column form's time on a v5e, PR 62).
-        m_scr[...], l_scr[...], acc_scr[...] = _heads_at_once(_fwd_tile, scale=scale)(
-            keep, q_ref[...], _kv_heads(k_ref, heads, kv_group), _kv_heads(v_ref, heads, kv_group),
-            m_scr[...], l_scr[...], acc_scr[...])
+        _fwd_step(_keep(walk, qi, ki, mask_ref), q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale, kv_group=kv_group)
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
