@@ -132,3 +132,32 @@ def heads_a_step(text: str, prefix: str, bh: int) -> dict:
         assert bh % grid[0] == 0, (name, grid)
         found.setdefault(name, set()).add(bh // grid[0])
     return {name: sorted(heads) for name, heads in found.items()}
+
+
+def relayouts(text: str, scopes=("attn_proj", "attn", "attn_window"), at_least: int = 1 << 20) -> list:
+    """[(name, dtype, dims, op_name)] of the instructions of a compiled
+    program's entry computation that only move an array — a `copy`, a
+    `transpose`, or a fusion whose root is one — inside the model's ``scopes``
+    (a component of the instruction's `op_name`), results of ``at_least``
+    elements or more: what stands between a projection and an attention
+    kernel when the two disagree about where a head lies."""
+    import re
+
+    roots = {}
+    for m in re.finditer(r"^%([\w.-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S):
+        root = re.search(r"ROOT %?[\w.-]+ = [^ ]+ ([\w-]+)\(", m.group(2))
+        roots[m.group(1)] = root.group(1) if root else ""
+    found = []
+    for line in text[text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (\w+)\[([\d,]*)\][^ ]* ([\w-]+)\(", line)
+        if not m or elements(m.group(3)) < at_least:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        called = re.search(r"calls=%([\w.-]+)", line)
+        if opcode == "fusion" and called:
+            opcode = roots.get(called.group(1), "")
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        if opcode in ("copy", "transpose") and set(op_name.split("/")) & set(scopes):
+            found.append((name, dtype, tuple(int(d) for d in dims.split(",") if d), op_name))
+    return found

@@ -20,6 +20,7 @@ def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass, mo
     TRUE width of 192, forward and backward — the one-pass backward that
     every such row short of 32,768 positions takes, and the two-pass form
     with the row's VMEM budget cut under it."""
+    import attention_forms as forms
     from test_ops import ONE_PASS, TWO_PASS, pallas_call_names
     from torchft_tpu.ops import attention as fa
 
@@ -33,12 +34,12 @@ def test_attention_kernels_at_unequal_widths_in_interpret_mode(seq, two_pass, mo
     pad = [(0, 0), (0, 0), (0, 64)]
     qp, kp = jnp.pad(q, pad), jnp.pad(k, pad)
     want_o, want_lse = fa._fa_reference(q, k, v, scale, True)
-    got_o, got_lse = fa._fa_pallas_call(qp, kp, v, scale, True, interpret=True)
+    got_o, got_lse = forms.fwd(qp, kp, v, scale, True, interpret=True)
     assert got_o.shape == (2, seq, 128)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=1e-5, atol=1e-5)
     want = fa._fa_bwd_xla(q, k, v, want_o, want_lse, g, scale, True)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True)
+    bwd = functools.partial(forms.bwd, scale=scale, causal=True, interpret=True)
     assert pallas_call_names(bwd, qp, kp, v, got_o, got_lse, g) == (TWO_PASS if two_pass else ONE_PASS)
     got = bwd(qp, kp, v, got_o, got_lse, g)
     assert [a.shape for a in got] == [(2, seq, 256), (2, seq, 256), (2, seq, 128)]
@@ -79,22 +80,22 @@ def test_the_one_pass_backward_is_booked_to_attention_by_its_name(program) -> No
 
 
 def test_flash_attention_takes_a_value_width_of_its_own() -> None:
-    """The public entry point off the TPU: [B, H, S, 48] queries and keys,
-    [B, H, S, 32] values, the scale from the query's width, gradients of the
+    """The public entry point off the TPU: [B, S, H, 48] queries and keys,
+    [B, S, H, 32] values, the scale from the query's width, gradients of the
     operands' own shapes."""
     from torchft_tpu.ops import flash_attention
 
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
-    q, k = (jax.random.normal(kk, (2, 4, 64, 48), jnp.float32) for kk in keys[:2])
-    v = jax.random.normal(keys[2], (2, 4, 64, 32), jnp.float32)
+    q, k = (jax.random.normal(kk, (2, 64, 4, 48), jnp.float32) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (2, 64, 4, 32), jnp.float32)
 
     def plain(q, k, v):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 48 ** -0.5
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 48 ** -0.5
         s = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), s, -jnp.inf)
-        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
     out = flash_attention(q, k, v)
-    assert out.shape == (2, 4, 64, 32)
+    assert out.shape == (2, 64, 4, 32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)), rtol=1e-5, atol=1e-5)
     got = jax.grad(lambda *a: jnp.sum(flash_attention(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), argnums=(0, 1, 2))(q, k, v)

@@ -10,6 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import attention_forms as forms
 from test_ops import pallas_call_grids, pallas_call_names
 
 
@@ -51,8 +52,8 @@ def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked
     else:
         want_o, want_lse = fa._fa_reference(q, k_all, v_all, scale, True)
         want = fa._fa_bwd_xla(q, k_all, v_all, want_o, want_lse, g, scale, True)
-    fwd = functools.partial(fa._fa_pallas_call, scale=scale, causal=True, interpret=True, **more)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, **more)
+    fwd = functools.partial(forms.fwd, scale=scale, causal=True, interpret=True, **more)
+    bwd = functools.partial(forms.bwd, scale=scale, causal=True, interpret=True, **more)
     got_o, got_lse = fwd(q, k, v)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(got_lse), np.asarray(want_lse), rtol=2e-3, atol=2e-3)
@@ -62,7 +63,7 @@ def check_the_triangular_walk(n: int, d_qk: int, d_v: int, kv_group: int, masked
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
     # H heads a grid step, H read from the shapes: all of these heads (one KV head's, or a batch
     # entry's) forward; backward as many of them as their dq rows leave room for
-    tiles, share = n * (n + 1) // 2, fa._heads_share(heads, more.get("mask"))
+    tiles, share = n * (n + 1) // 2, heads
     fwd_heads = fa._heads_per_step(share)
     bwd_heads = fa._bwd_heads_per_step(share, fa._row_vmem_bytes(seq, d_qk, 4))
     assert fwd_heads == heads and bwd_heads > 1
@@ -90,8 +91,8 @@ def test_short_rows_of_dq_are_cast_out_at_their_diagonal_step(masked) -> None:
     seq = 1536
     q, k, v, g = (jax.random.normal(kk, (2, seq, 128), jnp.bfloat16) for kk in jax.random.split(jax.random.PRNGKey(3), 4))
     mask = sa.packed_lower_triangle(jnp.tril(jnp.ones((1, seq, seq), jnp.int8))) if masked else None
-    o, lse = fa._fa_pallas_call(q, k, v, 0.088, True, interpret=True, mask=mask)
-    dq, _, _ = fa._fa_bwd_pallas(q, k, v, o, lse, g, 0.088, True, interpret=True, mask=mask)
+    o, lse = forms.fwd(q, k, v, 0.088, True, interpret=True, mask=mask)
+    dq, _, _ = forms.bwd(q, k, v, o, lse, g, 0.088, True, interpret=True, mask=mask)
     want, _, _ = fa._fa_bwd_xla(q, k, v, o, lse, g, 0.088, True)
     dq, want = np.asarray(dq, np.float32), np.asarray(want, np.float32)
     for qi in range(3):
@@ -103,9 +104,9 @@ def test_short_rows_of_dq_are_cast_out_at_their_diagonal_step(masked) -> None:
 @pytest.mark.parametrize("heads_per_step", [2, 4])
 @pytest.mark.parametrize("kind", ["causal", "rectangle", "window", "masked_kv_group_8", "unequal_widths"])
 def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> None:
-    """out, lse, dq, dk, dv with H heads a grid step — every block and scratch
-    leading with the heads, the tile's arithmetic under `jax.vmap` — are bit
-    for bit those of one head a step: over the triangle, a rectangle (queries
+    """out, lse, dq, dk, dv with H heads a grid step — a head a column block of
+    its operands' blocks, every scratch leading with the heads, the tile's
+    arithmetic a head at a time — are bit for bit those of one head a step: over the triangle, a rectangle (queries
     against a longer key sequence, not causal), the band, a packed mask whose
     eight query heads read one KV head in place (and share the mask's tile),
     and query and key 256 wide beside a value of 128."""
@@ -132,8 +133,8 @@ def test_heads_a_grid_step_are_bitwise_one_head_a_step(kind, heads_per_step) -> 
     g = jax.random.normal(ks[3], (bh, seq_q, dv), jnp.bfloat16)
 
     def kernels(heads):
-        fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
-        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        fwd = functools.partial(forms.fwd, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
+        bwd = functools.partial(forms.bwd, scale=0.07, causal=causal, interpret=True, heads_per_step=heads, **more)
         o, lse = fwd(q, k, v)
         grids = {**pallas_call_grids(fwd, q, k, v), **pallas_call_grids(bwd, q, k, v, o, lse, g)}
         assert len(grids) == 2 and {grid[0] for grid in grids.values()} == {bh // heads}, grids
@@ -203,13 +204,13 @@ def test_lane_replicated_statistics_are_bitwise_one_column(kind, heads_per_step,
     elif kind == "packed_mask":
         keep = jax.random.bernoulli(jax.random.PRNGKey(62), 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)
         more["mask"] = sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8)
-    heads = heads_per_step or fa._heads_per_step(fa._heads_share(bh, more.get("mask")))
+    heads = heads_per_step or fa._heads_per_step(bh)
     assert heads == (heads_per_step or rule) and fa._straddles(heads, kv_group) == (kind == "kv_group_4_straddling" and heads > 1)
     ks = jax.random.split(jax.random.PRNGKey(len(kind)), 3)
     q = jax.random.normal(ks[0], (bh, seq, d), jnp.bfloat16)
     k = jax.random.normal(ks[1], (bh // kv_group, seq, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (bh // kv_group, seq, 128), jnp.bfloat16)
-    fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=True, interpret=True, **more)
+    fwd = functools.partial(forms.fwd, scale=0.07, causal=True, interpret=True, **more)
     assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == bh // heads
     o, lse = fwd(q, k, v)
     monkeypatch.setattr(fa, "_fwd_scores", _column_stats_fwd_scores)
@@ -220,21 +221,23 @@ def test_lane_replicated_statistics_are_bitwise_one_column(kind, heads_per_step,
     assert lse.dtype == want_lse.dtype and bool(jnp.array_equal(lse, want_lse)), "lse"
 
 
-def _vmapped_fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale, kv_group):
+def _vmapped_fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale, kv_group, q_heads):
     """The forward step as it stood before PR 64, kept here: all the step's
     heads at once, the tile's two halves one function of values under
-    `jax.vmap`, every block and scratch read and stored whole."""
+    `jax.vmap`, every block (its heads side by side since PR 65, stacked
+    here) and scratch read and stored whole."""
     from torchft_tpu.ops import attention as fa
 
-    heads = q_ref.shape[0]
+    heads = m_scr.shape[0]
 
     def tile(q, k, v, m_prev, l_prev, acc):
         m_cur, alpha, l_new, p = fa._fwd_scores(q, k, m_prev, l_prev, keep, scale=scale)
         return m_cur, l_new, fa._fwd_accumulate(acc, alpha, p, v)
 
+    stacked = lambda head: jnp.stack([head(h) for h in range(heads)])  # noqa: E731
     m_scr[...], l_scr[...], acc_scr[...] = jax.vmap(tile)(
-        q_ref[...], fa._kv_heads(k_ref, heads, kv_group), fa._kv_heads(v_ref, heads, kv_group),
-        m_scr[...], l_scr[...], acc_scr[...])
+        stacked(lambda h: fa._head(q_ref, h, heads)), stacked(lambda h: fa._kv_head(k_ref, h, heads, kv_group, q_heads)),
+        stacked(lambda h: fa._kv_head(v_ref, h, heads, kv_group, q_heads)), m_scr[...], l_scr[...], acc_scr[...])
 
 
 # At seven heads a step the cases run 28 heads (smallthinker's 7 of 28), their KV heads a group of seven where a step
@@ -253,7 +256,9 @@ def test_the_skewed_heads_of_a_step_are_bitwise_the_heads_at_once(kind, heads_pe
     256 wide beside a value of 128, and KV heads read in place by steps that
     hold whole groups and by steps that straddle two — at one head a step
     (first half, then second: no skew to speak of), at seven of 28 and at the H
-    the shapes give."""
+    the shapes give.  The backward step runs `_bwd_tile` a head at a time (PR
+    65): dq, dk and dv bit for bit those of the step kept beside the forward's,
+    the heads' slices stacked under one `jax.vmap`."""
     from torchft_tpu.ops import attention as fa
     from torchft_tpu.ops import sparse_attention as sa
 
@@ -266,21 +271,27 @@ def test_the_skewed_heads_of_a_step_are_bitwise_the_heads_at_once(kind, heads_pe
     elif kind == "packed_mask":
         keep = jax.random.bernoulli(jax.random.PRNGKey(64), 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)
         more["mask"] = sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8)
-    heads = heads_per_step or fa._heads_per_step(fa._heads_share(bh, more.get("mask")))
+    heads = heads_per_step or fa._heads_per_step(bh)
     assert heads == (heads_per_step or rule)
     assert fa._straddles(heads, kv_group) == (kind == "kv_group_4_straddling" and heads > 1)
-    ks = jax.random.split(jax.random.PRNGKey(len(kind) + heads), 3)
+    ks = jax.random.split(jax.random.PRNGKey(len(kind) + heads), 4)
     q = jax.random.normal(ks[0], (bh, seq, d), jnp.bfloat16)
     k = jax.random.normal(ks[1], (bh // kv_group, seq, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (bh // kv_group, seq, 128), jnp.bfloat16)
-    fwd = functools.partial(fa._fa_pallas_call, scale=0.07, causal=True, interpret=True, **more)
+    g = jax.random.normal(ks[3], (bh, seq, 128), jnp.bfloat16)
+    fwd = functools.partial(forms.fwd, scale=0.07, causal=True, interpret=True, **more)
+    bwd = functools.partial(forms.bwd, scale=0.07, causal=True, interpret=True, **more)
     assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == bh // heads
     o, lse = fwd(q, k, v)
+    grads = bwd(q, k, v, o, lse, g)
     monkeypatch.setattr(fa, "_fwd_step", _vmapped_fwd_step)
+    monkeypatch.setattr(fa, "_bwd_step", _vmapped_bwd_step)  # the backward's heads, one at a time since PR 65
     want_o, want_lse = fwd(q, k, v)
     assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01 and bool(jnp.all(jnp.isfinite(lse)))
     assert o.dtype == want_o.dtype and bool(jnp.array_equal(o, want_o)), "out"
     assert lse.dtype == want_lse.dtype and bool(jnp.array_equal(lse, want_lse)), "lse"
+    for name, a, b in zip(("dq", "dk", "dv"), grads, bwd(q, k, v, o, lse, g)):
+        assert float(jnp.abs(a.astype(jnp.float32)).max()) > 0.01 and a.dtype == b.dtype and bool(jnp.array_equal(a, b)), name
 
 
 def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
@@ -302,13 +313,13 @@ def test_two_dq_rows_over_the_vmem_budget_run_one_head_a_step() -> None:
     n = seq // 512
     qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
-    assert pallas_call_grids(functools.partial(fa._fa_pallas_call, scale=0.088, causal=True), qkv, qkv, qkv) == {
+    assert pallas_call_grids(functools.partial(forms.fwd, scale=0.088, causal=True), qkv, qkv, qkv) == {
         "tpuft_fa_fwd": (1, n * (n + 1) // 2)}
-    assert pallas_call_grids(functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), qkv, qkv, qkv, qkv, lse, qkv) == {
+    assert pallas_call_grids(functools.partial(forms.bwd, scale=0.088, causal=True), qkv, qkv, qkv, qkv, lse, qkv) == {
         "tpuft_fa_bwd_dkdv_dq": (2, n * (n + 1) // 2)}
     longer = jax.ShapeDtypeStruct((bh, seq + 512, 128), jnp.bfloat16)
     assert {grid[0] for grid in pallas_call_grids(
-        functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True), longer, longer, longer, longer,
+        functools.partial(forms.bwd, scale=0.088, causal=True), longer, longer, longer, longer,
         jax.ShapeDtypeStruct((bh, seq + 512), jnp.float32), longer).values()} == {1}
 
 
@@ -346,9 +357,9 @@ CELL_SHAPES = {
 def test_the_heads_a_grid_step_at_the_cells_shapes(cell) -> None:
     """The traced `pallas_call`s at every cell's attention shape (no kernel
     runs): grid (batch * heads / H, tiles) with more than one head a step in
-    both directions, and k and v given to the kernels with their own KV heads
-    — batch * heads / `kv_group` of them, no repeated copy — while dk and dv
-    leave a query head each."""
+    both directions, every operand position-major — q [1, S, heads * d], k and
+    v given to the kernels with their own KV heads, heads / `kv_group` column
+    blocks, no repeated copy — while dk and dv leave a query head each."""
     from torchft_tpu.ops import attention as fa
 
     (bh, seq, d, dv, window, kv_group, masked), (fwd_heads, bwd_heads) = CELL_SHAPES[cell]
@@ -364,29 +375,30 @@ def test_the_heads_a_grid_step_at_the_cells_shapes(cell) -> None:
     more = {"kv_group": kv_group, "window": window}
     family = "tpuft_dsa_attn" if masked else "tpuft_fa" if window is None else "tpuft_swa"
     assert min(fwd_heads, bwd_heads) > 1
-    fwd = lambda q_, k_, v_, m_: fa._fa_pallas_call(q_, k_, v_, 0.088, True, mask=m_, **more)  # noqa: E731
-    bwd = lambda q_, k_, v_, o_, l_, g_, m_: fa._fa_bwd_pallas(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more)  # noqa: E731
+    fwd = lambda q_, k_, v_, m_: forms.fwd(q_, k_, v_, 0.088, True, mask=m_, **more)  # noqa: E731
+    bwd = lambda q_, k_, v_, o_, l_, g_, m_: forms.bwd(q_, k_, v_, o_, l_, g_, 0.088, True, mask=m_, **more)  # noqa: E731
     assert pallas_call_grids(fwd, q, k, v, mask) == {family + "_fwd": (bh // fwd_heads, tiles)}
     assert pallas_call_grids(bwd, q, k, v, o, lse, o, mask) == {family + "_bwd_dkdv_dq": (bh // bwd_heads, tiles)}
+    wide = [(1, seq, bh * d), (1, seq, bh // kv_group * d), (1, seq, bh // kv_group * dv)]
     (operands,) = pallas_call_operands(fwd, q, k, v, mask).values()
-    assert operands[:3] == [q.shape, k.shape, v.shape]
+    assert operands[:3] == wide
     (operands,) = pallas_call_operands(bwd, q, k, v, o, lse, o, mask).values()
-    assert operands[:3] == [q.shape, k.shape, v.shape]
+    assert operands[:3] == wide and operands[3] == (1, seq, bh * dv)
     dq, dk, dv_ = jax.eval_shape(bwd, q, k, v, o, lse, o, mask)
     assert (dq.shape, dk.shape, dv_.shape) == (q.shape, (bh, seq, d), (bh, seq, dv))
 
 
 def test_flash_attention_gives_the_kernels_k_and_v_unrepeated(monkeypatch) -> None:
-    """`flash_attention` where the kernels run, through autodiff: no operand
-    of the forward or the backward `pallas_call` has batch * query heads
-    leading rows but q, the output and their gradients' — k and v go in with
-    batch * KV heads — and dk, dv come back in k's and v's shapes."""
+    """`flash_attention` where the kernels run, through autodiff: every
+    operand of the forward and the backward `pallas_call` is position-major,
+    [B, S, heads * d], q with its query heads' columns and k and v with their
+    KV heads' — no repeated copy — and dk, dv come back in k's and v's shapes."""
     from torchft_tpu.ops import attention as fa
 
     monkeypatch.setattr(fa._pallas_util, "kernels_apply", lambda mesh=None: True)
     b, hq, hkv, seq = 2, 14, 2, 1024
-    q = jax.ShapeDtypeStruct((b, hq, seq, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, hkv, seq, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((b, seq, hq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, seq, hkv, 128), jnp.bfloat16)
     for window, family in ((None, "tpuft_fa"), (600, "tpuft_swa")):
         loss = lambda q_, k_, v_: jnp.sum(fa.flash_attention(q_, k_, v_, window=window).astype(jnp.float32))  # noqa: E731,B023
         grads = jax.grad(loss, argnums=(0, 1, 2))
@@ -394,7 +406,7 @@ def test_flash_attention_gives_the_kernels_k_and_v_unrepeated(monkeypatch) -> No
         found = pallas_call_operands(grads, q, kv, kv)
         assert sorted(found) == [family + "_bwd_dkdv_dq", family + "_fwd"]
         for operands in found.values():
-            assert operands[:3] == [(b * hq, seq, 128), (b * hkv, seq, 128), (b * hkv, seq, 128)]
+            assert operands[:3] == [(b, seq, hq * 128), (b, seq, hkv * 128), (b, seq, hkv * 128)]
 
 
 # query heads a KV head: (batch * heads, heads a step forward, backward) of an interpret-mode case whose steps hold whole
@@ -429,17 +441,17 @@ def test_grouped_queries_read_their_kv_head_in_place(kv_group, kind, step) -> No
     g = jax.random.normal(ks[3], (bh, seq, 128), jnp.bfloat16)
     k_all, v_all = jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0)
     kw = dict(scale=0.088, causal=True, interpret=True, window=window)
-    o, lse = fa._fa_pallas_call(q, k, v, kv_group=kv_group, heads_per_step=fwd_heads, **kw)
-    want_o, want_lse = fa._fa_pallas_call(q, k_all, v_all, heads_per_step=fwd_heads, **kw)
+    o, lse = forms.fwd(q, k, v, kv_group=kv_group, heads_per_step=fwd_heads, **kw)
+    want_o, want_lse = forms.fwd(q, k_all, v_all, heads_per_step=fwd_heads, **kw)
     assert bool(jnp.array_equal(o, want_o)) and bool(jnp.array_equal(lse, want_lse))
     assert float(jnp.abs(o.astype(jnp.float32)).max()) > 0.01
-    got = fa._fa_bwd_pallas(q, k, v, o, lse, g, kv_group=kv_group, heads_per_step=bwd_heads, **kw)
-    want = fa._fa_bwd_pallas(q, k_all, v_all, o, lse, g, heads_per_step=bwd_heads, **kw)
+    got = forms.bwd(q, k, v, o, lse, g, kv_group=kv_group, heads_per_step=bwd_heads, **kw)
+    want = forms.bwd(q, k_all, v_all, o, lse, g, heads_per_step=bwd_heads, **kw)
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         assert a.shape == (bh, seq, 128) and bool(jnp.array_equal(a, b)), name
     for name, a in zip(("dk", "dv"), got[1:]):
         summed = np.asarray(a, np.float32).reshape(bh // kv_group, kv_group, seq, 128).sum(axis=1)
-        folded = fa.group_sum(a, kv_group)
+        folded = forms.heads(fa.group_sum(forms.rows(a), bh // kv_group, kv_group), bh // kv_group)
         assert folded.shape == k.shape and folded.dtype == k.dtype
         assert bool(jnp.array_equal(folded, jnp.asarray(summed).astype(jnp.bfloat16))), name
 
@@ -459,10 +471,10 @@ def test_the_attention_grids_at_the_cells_lengths(seq) -> None:
     mask = jax.ShapeDtypeStruct((1, tiles, 512, 512), jnp.int8)
 
     def fwd(causal, **more):
-        return functools.partial(fa._fa_pallas_call, scale=0.088, causal=causal, **more)
+        return functools.partial(forms.fwd, scale=0.088, causal=causal, **more)
 
     def bwd(causal, **more):
-        return functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=causal, **more)
+        return functools.partial(forms.bwd, scale=0.088, causal=causal, **more)
 
     # eight heads a step forward; backward as many as their dq rows (seq x 128 x 8 bytes a head) and
     # tiles leave room for in VMEM: four, four and two
@@ -513,8 +525,8 @@ def test_windowed_flash_kernels_match_a_dense_mask(window) -> None:
     rng = np.random.default_rng(window)
     q, k, v, g = (jnp.asarray(rng.standard_normal((2, seq, 128)), dtype=jnp.float32) for _ in range(4))
     want = _dense_window_attention(q, k, v, g, scale, window)
-    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    o, lse = forms.fwd(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(forms.bwd, scale=scale, causal=True, interpret=True, window=window)
     assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv_dq"]
     got = (o,) + tuple(bwd(q, k, v, o, lse, g))
     o_x, lse_x = fa._fa_reference(q, k, v, scale, True, window)
@@ -535,8 +547,8 @@ def test_windowed_two_pass_backward_matches_a_dense_mask(monkeypatch) -> None:
     rng = np.random.default_rng(3)
     q, k, v, g = (jnp.asarray(rng.standard_normal((1, seq, 128)), dtype=jnp.float32) for _ in range(4))
     want = _dense_window_attention(q, k, v, g, scale, window)
-    o, lse = fa._fa_pallas_call(q, k, v, scale, True, interpret=True, window=window)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=True, interpret=True, window=window)
+    o, lse = forms.fwd(q, k, v, scale, True, interpret=True, window=window)
+    bwd = functools.partial(forms.bwd, scale=scale, causal=True, interpret=True, window=window)
     assert pallas_call_names(bwd, q, k, v, o, lse, g) == ["tpuft_swa_bwd_dkdv", "tpuft_swa_bwd_dq"]
     for a, b, name in zip((o,) + tuple(bwd(q, k, v, o, lse, g)), want, ("o", "dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
@@ -550,8 +562,8 @@ def test_a_window_that_covers_the_sequence_is_the_causal_call(window) -> None:
     from torchft_tpu.ops import flash_attention
 
     rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.standard_normal((1, 4, 1024, 64)), dtype=jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((1, 2, 1024, 64)), dtype=jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((1, 1024, 4, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 1024, 2, 64)), dtype=jnp.float32) for _ in range(2))
 
     def loss(window):
         return lambda q, k, v: jnp.sum(jnp.square(flash_attention(q, k, v, causal=True, window=window)))
@@ -570,17 +582,18 @@ def test_windowed_flash_attention_differs_from_causal_and_matches_a_dense_mask()
     from torchft_tpu.ops import flash_attention
 
     rng = np.random.default_rng(9)
-    q = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)), dtype=jnp.float32) for _ in range(2))
-    g = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), dtype=jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, 256, 4, 64)), dtype=jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 256, 2, 64)), dtype=jnp.float32) for _ in range(2))
+    g = jnp.asarray(rng.standard_normal((1, 256, 4, 64)), dtype=jnp.float32)
     o, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, window=32), q, k, v)
-    rep = lambda t: jnp.repeat(t, 2, axis=1).reshape(4, 256, 64)  # noqa: E731
-    want = _dense_window_attention(q.reshape(4, 256, 64), rep(k), rep(v), g.reshape(4, 256, 64), 64 ** -0.5, 32)
-    np.testing.assert_allclose(np.asarray(o).reshape(4, 256, 64), np.asarray(want[0]), rtol=1e-4, atol=1e-5)
+    major = lambda t: t[0].transpose(1, 0, 2)  # noqa: E731 — [1, S, heads, d] -> [heads, S, d]
+    rep = lambda t: jnp.repeat(major(t), 2, axis=0)  # noqa: E731
+    want = _dense_window_attention(major(q), rep(k), rep(v), major(g), 64 ** -0.5, 32)
+    np.testing.assert_allclose(np.asarray(major(o)), np.asarray(want[0]), rtol=1e-4, atol=1e-5)
     dq, dk, dv = vjp(g)
-    np.testing.assert_allclose(np.asarray(dq).reshape(4, 256, 64), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(major(dq)), np.asarray(want[1]), rtol=1e-4, atol=1e-5)
     for got, ref in ((dk, want[2]), (dv, want[3])):  # a kv head's gradient is its two query heads' summed
-        np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref).reshape(2, 2, 256, 64).sum(1), rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(major(got)), np.asarray(ref).reshape(2, 2, 256, 64).sum(1), rtol=1e-4, atol=1e-4)
     assert not np.allclose(np.asarray(o), np.asarray(flash_attention(q, k, v)), atol=1e-3)
 
 
@@ -616,9 +629,166 @@ def test_the_band_walk_at_the_window_cells_lengths(seq, block) -> None:
         bh = 8
         qkv = jax.ShapeDtypeStruct((bh, seq, 128), jnp.bfloat16)
         lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32)
-        fwd = functools.partial(fa._fa_pallas_call, scale=0.088, causal=True, window=window)
-        bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True, window=window)
+        fwd = functools.partial(forms.fwd, scale=0.088, causal=True, window=window)
+        bwd = functools.partial(forms.bwd, scale=0.088, causal=True, window=window)
         f, b = bh // fa._heads_per_step(bh), bh // fa._bwd_heads_per_step(bh, fa._row_vmem_bytes(seq, 128, 2))
         assert f == 1 and b < bh  # the band's tiles, H heads a step
         assert pallas_call_grids(fwd, qkv, qkv, qkv) == {"tpuft_swa_fwd": (f, tiles)}
         assert pallas_call_grids(bwd, qkv, qkv, qkv, qkv, lse, qkv) == {"tpuft_swa_bwd_dkdv_dq": (b, tiles)}
+
+
+def _vmapped_bwd_step(keep, qi, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, walk, scale, kv_group, q_heads, dkdv, dq):
+    """The backward step as it stood before PR 65, kept here as the forward's
+    above: the step's heads' slices stacked and `_bwd_tile` over them under
+    one `jax.vmap`, every product one batched product with the heads leading."""
+    from jax.experimental import pallas as pl
+    from torchft_tpu.ops import attention as fa
+
+    heads = lse_ref.shape[0]
+    rows = pl.ds(qi * walk.block_q, walk.block_q)
+    stacked = lambda head: jnp.stack([head(h) for h in range(heads)])  # noqa: E731
+    tiles = jax.vmap(lambda *o: fa._bwd_tile(*o, keep, scale=scale, dkdv=dkdv, dq=dq))(
+        stacked(lambda h: fa._head(q_ref, h, heads)), stacked(lambda h: fa._kv_head(k_ref, h, heads, kv_group, q_heads)),
+        stacked(lambda h: fa._kv_head(v_ref, h, heads, kv_group, q_heads)), stacked(lambda h: fa._head(do_ref, h, heads)),
+        lse_ref[:, :, rows], delta_ref[:, :, rows])
+    return [tuple(t[h] for t in tiles) for h in range(heads)]
+
+
+def _head_major_oracle(monkeypatch, q, k, v, g, *, scale, kv_group=1, window=None, mask=None):
+    """out, lse, dq, dk, dv of head-major operands [heads, S, d] as the
+    kernels of before PR 65 computed them: every head an entry of ONE head
+    ([heads, S, d]: a block is a head's (rows, d) whole and nothing is sliced
+    out of a row of heads), one entry a grid step, and the steps the ones kept
+    above — the tile functions under `jax.vmap`.  k and v are repeated to a
+    head a query head, the values a KV head read in place gives every head of
+    its group, and a mask's tiles to a copy an entry."""
+    from torchft_tpu.ops import attention as fa
+
+    k, v = jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0)
+    more = {"q_heads": 1, "window": window, "interpret": True, "heads_per_step": 1,
+            "mask": None if mask is None else jnp.broadcast_to(mask, q.shape[:1] + mask.shape[1:])}
+    with monkeypatch.context() as before:
+        before.setattr(fa, "_fwd_step", _vmapped_fwd_step)
+        before.setattr(fa, "_bwd_step", _vmapped_bwd_step)
+        out, lse = fa._fa_pallas_call(q, k, v, scale, True, **more)
+        return (out, lse) + tuple(fa._fa_bwd_pallas(q, k, v, out, lse, g, scale, True, **more))
+
+
+# kind: (heads, positions, query and key width, query heads a KV head, window, heads a step forward and backward)
+POSITION_MAJOR_CASES = {
+    "plain": (8, 1024, 128, 1, None, None, None),
+    "kv_group_7_straddling": (28, 1024, 128, 7, None, 4, 4),
+    "window_1024": (8, 2048, 128, 1, 1024, None, None),
+    "masked_kv_group_8": (8, 1024, 128, 8, None, None, None),
+    "unequal_widths": (4, 1024, 256, 1, None, None, None),
+}
+
+
+def _rowsum_delta(g, o, q_heads):
+    """`ops.attention._row_delta` as a sum over a head's columns: the same
+    float32 additions whatever the heads of a batch entry, which the product
+    with the heads' indicator (its place in the program: the sum would have
+    XLA re-tile g * o whole on the TPU) does not promise."""
+    b, seq, width = g.shape
+    prod = (g.astype(jnp.float32) * o.astype(jnp.float32)).reshape(b, seq, q_heads, width // q_heads)
+    return jnp.sum(prod, axis=-1).transpose(0, 2, 1).reshape(b * q_heads, 1, seq)
+
+
+@pytest.mark.parametrize("kind", sorted(POSITION_MAJOR_CASES))
+def test_position_major_kernels_are_bitwise_the_head_major_tiles(kind, monkeypatch) -> None:
+    """The kernels read q, k, v and g and write out, dq, dk and dv where the
+    projections leave them, [1, S, heads * d] with a head a lane-aligned column
+    block (PR 65).  In interpret mode their out, lse, dq, dk and dv are bit for
+    bit the head-major oracle's above, turned: over the triangle, with a group
+    of seven query heads a KV head read in place by steps of four heads that
+    straddle two KV heads (an element-placed block along the columns, a head's
+    own by a scalar index times the width), over the band under a window of
+    1,024, under a packed mask whose eight query heads read one KV head, and
+    with query and key 256 wide beside a value of 128.  The backward's delta
+    = rowsum(g * o) is given to both as the same sum (`_rowsum_delta`); the
+    program's own, a product with the heads' indicator, is that sum to
+    float32's rounding."""
+    import attention_forms as forms
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    heads, seq, d, kv_group, window, fwd_heads, bwd_heads = POSITION_MAJOR_CASES[kind]
+    more = {"kv_group": kv_group, "window": window}
+    if kind.startswith("masked"):
+        keep = jax.random.bernoulli(jax.random.PRNGKey(65), 0.3, (seq, seq)) | jnp.eye(seq, dtype=bool)
+        more["mask"] = sa.packed_lower_triangle((keep & jnp.tril(jnp.ones_like(keep)))[None]).astype(jnp.int8)
+    assert fa._straddles(fwd_heads or fa._heads_per_step(heads), kv_group) == (kind == "kv_group_7_straddling")
+    ks = jax.random.split(jax.random.PRNGKey(len(kind)), 4)
+    q = jax.random.normal(ks[0], (heads, seq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (heads // kv_group, seq, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (heads // kv_group, seq, 128), jnp.bfloat16)
+    g = jax.random.normal(ks[3], (heads, seq, 128), jnp.bfloat16)
+    rows = forms.rows
+    np.testing.assert_allclose(np.asarray(fa._row_delta(rows(g), rows(q[..., :128]), heads)),
+                               np.asarray(_rowsum_delta(rows(g), rows(q[..., :128]), heads)), rtol=1e-5, atol=1e-5)
+    monkeypatch.setattr(fa, "_row_delta", _rowsum_delta)
+    want = _head_major_oracle(monkeypatch, q, k, v, g, scale=0.07, **more)
+    o, lse = forms.fwd(q, k, v, 0.07, True, interpret=True, heads_per_step=fwd_heads, **more)
+    got = (o, lse) + forms.bwd(q, k, v, o, lse, g, 0.07, True, interpret=True, heads_per_step=bwd_heads, **more)
+    assert all(float(jnp.abs(x.astype(jnp.float32)).max()) > 0.01 for x in want)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape and bool(jnp.array_equal(a, b)), name
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "masked"])
+def test_entries_of_one_head_share_a_step_and_a_packed_mask_keeps_its_entry(masked) -> None:
+    """Entries of ONE head ([B, S, 1 * d]: `flash_attention` folds heads that
+    are no lane multiple wide into the batch) have no second column block, so
+    a grid step takes adjacent entries — all four here forward, the backward's
+    rule's — but under a packed mask, whose tile is one entry's: then a step
+    is one entry and every entry is held to its OWN mask (four different
+    ones), forward and backward, against dense masked attention and its
+    autodiff."""
+    from torchft_tpu.ops import attention as fa
+    from torchft_tpu.ops import sparse_attention as sa
+
+    batch, seq, d = 4, 1024, 128
+    ks = jax.random.split(jax.random.PRNGKey(65 + masked), 5)
+    q, k, v, g = (jax.random.normal(key, (batch, seq, d), jnp.float32) for key in ks[:4])
+    keep = jnp.broadcast_to(jnp.tril(jnp.ones((seq, seq), bool)), (batch, seq, seq))
+    more = {"q_heads": 1, "interpret": True}
+    if masked:
+        keep = (jax.random.bernoulli(ks[4], 0.3, (batch, seq, seq)) | jnp.eye(seq, dtype=bool)) & keep
+        more["mask"] = sa.packed_lower_triangle(keep).astype(jnp.int8)
+        assert not bool(jnp.array_equal(keep[0], keep[1]))
+    (want_o, want_lse), vjp = jax.vjp(lambda *qkv: _masked_reference(*qkv, keep, d ** -0.5), q, k, v)
+    fwd = functools.partial(fa._fa_pallas_call, scale=d ** -0.5, causal=True, **more)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=d ** -0.5, causal=True, **more)
+    o, lse = fwd(q, k, v)
+    steps = batch if masked else 1
+    assert pallas_call_grids(fwd, q, k, v).popitem()[1][0] == steps
+    assert pallas_call_grids(bwd, q, k, v, o, lse, g).popitem()[1][0] == (batch if masked else batch // fa._bwd_heads_per_step(
+        batch, fa._row_vmem_bytes(seq, d, 4)))
+    got = (o, lse) + tuple(bwd(q, k, v, o, lse, g))
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, (want_o, want_lse) + vjp((g, jnp.zeros_like(want_lse)))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3, err_msg=name)
+    # entries that share a step keep the kernels' form of before PR 65 — lse lane-padded out of the forward kernel, the
+    # backward's heads one batched product: bit for bit one entry a step (lse a row, `_bwd_tile` a head)
+    one = fwd(q, k, v, heads_per_step=1) + tuple(bwd(q, k, v, o, lse, g, heads_per_step=1))
+    assert all(bool(jnp.array_equal(a, b)) for a, b in zip(got, one))
+
+
+def test_flash_attention_folds_padded_heads_into_the_batch_with_their_kv_heads_repeated(monkeypatch) -> None:
+    """Heads 192 wide reach the kernels padded to 256 and folded into the
+    batch, [B * heads, S, 256] — with grouped queries too (no cell: latent
+    attention has a KV head a head), k and v repeated to a head a query head
+    first, and dk, dv come back in k's and v's shapes."""
+    from torchft_tpu.ops import attention as fa
+
+    monkeypatch.setattr(fa._pallas_util, "kernels_apply", lambda mesh=None: True)
+    b, seq = 2, 1024
+    for hq, hkv in ((4, 4), (4, 2)):
+        q = jax.ShapeDtypeStruct((b, seq, hq, 192), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((b, seq, hkv, 192), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((b, seq, hkv, 128), jnp.bfloat16)
+        grads = jax.grad(lambda q_, k_, v_: jnp.sum(fa.flash_attention(q_, k_, v_).astype(jnp.float32)), argnums=(0, 1, 2))
+        assert [a.shape for a in jax.eval_shape(grads, q, k, v)] == [q.shape, k.shape, v.shape]
+        found = pallas_call_operands(grads, q, k, v)
+        assert sorted(found) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
+        for operands in found.values():
+            assert operands[:3] == [(b * hq, seq, 256), (b * hq, seq, 256), (b * hq, seq, 128)]

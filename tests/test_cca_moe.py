@@ -245,7 +245,7 @@ def test_a_piece_of_the_compressed_mixer_against_a_written_out_loop(piece) -> No
 
     @jax.jit
     def program(h, w):
-        return tuple(a[0] for a in _cca_qkv(cfg, kind, h, w, positions))
+        return tuple(a[0].transpose(1, 0, 2) for a in _cca_qkv(cfg, kind, h, w, positions))  # [S, heads, D] -> the loop's head-major
 
     @jax.jit
     def loop(h, w):

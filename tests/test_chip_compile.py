@@ -26,17 +26,17 @@ def test_kernel_compiles_for_v5e(one_chip, width, kernel) -> None:
 
     bh, d = batch * cfg.n_heads, cfg.d_head
     n, e, v = batch * seq, cfg.d_model, cfg.vocab_size
-    qkv = sds((bh, seq, d))
+    qkv = sds((batch, seq, cfg.n_heads * d))  # position-major: a position's heads side by side
     if kernel == "fa_fwd":
         from torchft_tpu.ops.attention import _fa_pallas_call
 
-        text = compile_text(lambda q, k, v_: _fa_pallas_call(q, k, v_, d ** -0.5, True), qkv, qkv, qkv)
+        text = compile_text(lambda q, k, v_: _fa_pallas_call(q, k, v_, d ** -0.5, True, q_heads=cfg.n_heads), qkv, qkv, qkv)
         names = ["tpuft_fa_fwd"]
     elif kernel == "fa_bwd":
         from torchft_tpu.ops.attention import _fa_bwd_pallas
 
         text = compile_text(
-            lambda q, k, v_, o, lse, g: _fa_bwd_pallas(q, k, v_, o, lse, g, d ** -0.5, True),
+            lambda q, k, v_, o, lse, g: _fa_bwd_pallas(q, k, v_, o, lse, g, d ** -0.5, True, q_heads=cfg.n_heads),
             qkv, qkv, qkv, qkv, sds((bh, seq), jnp.float32), qkv,
         )
         names = ["tpuft_fa_bwd_dkdv_dq"]  # the one-pass form, as at every cell's shape
@@ -77,11 +77,11 @@ def test_one_pass_backward_compiles_for_v5e(one_chip, bh, seq, d_qk, d_v) -> Non
     from torchft_tpu.ops.attention import _dq_row_resident, _fa_bwd_pallas
 
     assert _dq_row_resident(seq, d_qk)
-    qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
+    qk = jax.ShapeDtypeStruct((1, seq, bh * d_qk), jnp.bfloat16, sharding=one_chip)  # position-major, one batch entry
+    v = jax.ShapeDtypeStruct((1, seq, bh * d_v), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
     text = compile_text(
-        lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, d_qk ** -0.5, True),
+        lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, d_qk ** -0.5, True, q_heads=bh),
         qk, qk, v, v, lse, v,
     )
     assert attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"]
@@ -97,10 +97,10 @@ def test_long_context_two_pass_backward_compiles_for_v5e(one_chip) -> None:
 
     bh, seq, d = 4, 65536 + 512, 128
     assert not _dq_row_resident(seq, d)
-    qkv = jax.ShapeDtypeStruct((bh, seq, d), jnp.bfloat16, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct((1, seq, bh * d), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
     text = compile_text(
-        lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, d ** -0.5, True),
+        lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, d ** -0.5, True, q_heads=bh),
         qkv, qkv, qkv, qkv, lse, qkv,
     )
     assert attention_calls(text) == ["tpuft_fa_bwd_dkdv", "tpuft_fa_bwd_dq"]

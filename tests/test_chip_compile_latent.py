@@ -43,12 +43,12 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip) -> None:
     from torchft_tpu.ops.attention import _fa_bwd_pallas, _fa_pallas_call
 
     bh, seq, d_qk, d_v = 32, 8192, 256, 128
-    qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)
+    qk = jax.ShapeDtypeStruct((bh, seq, d_qk), jnp.bfloat16, sharding=one_chip)  # an entry a head, as `flash_attention` hands padded heads over
     v = jax.ShapeDtypeStruct((bh, seq, d_v), jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((bh, seq), jnp.float32, sharding=one_chip)
-    text = compile_text(lambda q, k, v_: _fa_pallas_call(q, k, v_, 192 ** -0.5, True), qk, qk, v)
+    text = compile_text(lambda q, k, v_: _fa_pallas_call(q, k, v_, 192 ** -0.5, True, q_heads=1), qk, qk, v)
     assert attention_calls(text) == ["tpuft_fa_fwd"] and heads_a_step(text, "tpuft_fa_", bh) == {"tpuft_fa_fwd": [8]}
-    text = compile_text(lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, 192 ** -0.5, True), qk, qk, v, v, lse, v)
+    text = compile_text(lambda q, k, v_, o, l, g: _fa_bwd_pallas(q, k, v_, o, l, g, 192 ** -0.5, True, q_heads=1), qk, qk, v, v, lse, v)
     assert attention_calls(text) == ["tpuft_fa_bwd_dkdv_dq"] and heads_a_step(text, "tpuft_fa_", bh) == {"tpuft_fa_bwd_dkdv_dq": [4]}
 
 
@@ -98,6 +98,7 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
     # temporaries, the program's schedule around the copies of the walk's tables holds 0.77 MB more)
     # 13,910,258,176 since PR 36: the experts' gathered rows are [k, T, E], so no copy of them
     # re-tiled to [T, 6 -> 8, E] is held (temporaries 3,637,552,640 -> 3,207,768,064)
+    # (PR 65: heads of 192 columns reach the kernels folded into the batch, in the form of before it: the same bytes)
     assert resident <= 13.915e9, (
         f"{resident} bytes: the backward's dq has left VMEM in f32, or the experts' gathered rows are laid out again")
     gathered = 16384 * 6 * 2048

@@ -24,7 +24,7 @@ def test_sparse_attention_kernels_compile_for_v5e(one_chip) -> None:
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    q, k, a, bt = sds((B, H, S, D)), sds((B, KV, S, D)), sds((B, J, S, Di)), sds((B, Di, S))
+    q, k, a, bt = sds((B, S, H, D)), sds((B, S, KV, D)), sds((B, J, S, Di)), sds((B, Di, S))  # q, k position-major
     w, row, lse = sds((B, S, J), jnp.float32), sds((B, S, 1), jnp.int32), sds((B, H, S), jnp.float32)
     z, mask = sds((B, S, 1), jnp.float32), sds((B, 64 * 65 // 2, 512, 512), jnp.int8)
     scale = D ** -0.5

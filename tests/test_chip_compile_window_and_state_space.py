@@ -23,11 +23,11 @@ def test_windowed_attention_kernels_compile_for_v5e(one_chip, window) -> None:
     are what interpret mode cannot refuse."""
     from torchft_tpu.ops.attention import _fa_bwd_pallas, _fa_pallas_call
 
-    qkv = jax.ShapeDtypeStruct((64, 16384, 128), jnp.bfloat16, sharding=one_chip)
+    qkv = jax.ShapeDtypeStruct((1, 16384, 64 * 128), jnp.bfloat16, sharding=one_chip)  # position-major
     lse = jax.ShapeDtypeStruct((64, 16384), jnp.float32, sharding=one_chip)
-    text = compile_text(lambda q, k, v: _fa_pallas_call(q, k, v, 128 ** -0.5, True, window=window), qkv, qkv, qkv)
+    text = compile_text(lambda q, k, v: _fa_pallas_call(q, k, v, 128 ** -0.5, True, window=window, q_heads=64), qkv, qkv, qkv)
     assert kernel_calls(text, "tpuft_swa_") == ["tpuft_swa_fwd"] and not attention_calls(text)
-    text = compile_text(lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, 128 ** -0.5, True, window=window),
+    text = compile_text(lambda q, k, v, o, l, g: _fa_bwd_pallas(q, k, v, o, l, g, 128 ** -0.5, True, window=window, q_heads=64),
                     qkv, qkv, qkv, qkv, lse, qkv)
     assert kernel_calls(text, "tpuft_swa_") == ["tpuft_swa_bwd_dkdv_dq"] and not attention_calls(text)
 
@@ -79,8 +79,12 @@ def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, m
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     # 15,451,607,040 (15,167,032,832 with the full layers' attention kept alone; builder's compiles,
     # PR 37), 15,272,240,128 since PR 39, 14,923,113,472 since PR 45, 14,568,567,808 since PR 60 (k and v reach the
-    # attention kernels with their 8 KV heads, not repeated to 64 and 48): the chip's allocator has 16.9e9
-    assert resident <= 14.6e9, f"the step needs {resident} bytes with AdamW's moments"
+    # attention kernels with their 8 KV heads, not repeated to 64 and 48), 14,653,631,488 since PR 65 (the kernels read
+    # q, k, v position-major).  Nothing is held longer: the buffer assignment's peak of LIVE bytes fell, 6,686,688,738 ->
+    # 6,661,719,522 (`--xla_dump_to`'s `*buffer-assignment.txt`, "peak usage"), and the heap it packs the temporaries
+    # into came out 95.5 MB larger, 3,464,856,064 -> 3,560,391,168: the packing's, PERF.md section 6, PR 65 (7).  The
+    # chip's allocator has 16.9e9
+    assert resident <= 14.66e9, f"the step needs {resident} bytes with AdamW's moments"
 
 
 def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
@@ -284,5 +288,12 @@ def test_nemotron_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip,
     # 15,317,239,296 (arguments 2,667,987,456 + outputs 2,667,862,528 + temporaries 4,645,685,760 + moments
     # 5,335,703,552; builder's compile, PR 56); with `ssm_mix` as kernels 14,471,001,088 (temporaries 3,799,447,552;
     # builder's compile, PR 57): the XLA halves' float32 [16,384, 6,144] arrays are gone; 14,471,033,344 since PR 60
-    # (the one GQA block's k and v un-repeated: 32,256 bytes MORE, the repeated copies were never at the peak)
-    assert resident <= 14_500_000_000, f"the step needs {resident} bytes with AdamW's moments"
+    # (the one GQA block's k and v un-repeated: 32,256 bytes MORE, the repeated copies were never at the peak);
+    # 14,695,830,528 since PR 65 (temporaries 4,024,276,992 from 3,799,479,808).  Nothing is held longer: the buffer
+    # assignment's peak of LIVE bytes is the parent's to the byte, 6,121,351,714, at a `tpuft_ssd_bwd` (the attention
+    # block is not at the peak), and the values of 8 MB and more in the temporaries' heap are FEWER (two [32, 16384, 128]
+    # and two [16384, 3712] go, one [1, 16384, 4096] comes); the heap packs them into 3,769,811,456 bytes where the
+    # parent's pack into 3,657,155,072 — and into 3,636,953,600 (temporaries 3,759,086,080, under the parent's) with
+    # the backward's delta written as a row sum, the same large values to the last: the packing's, not an array's
+    # (PERF.md section 6, PR 65 (7)); the cell's allocator peaks at 12.9 GB of 16.9
+    assert resident <= 14_700_000_000, f"the step needs {resident} bytes with AdamW's moments"

@@ -112,14 +112,14 @@ def test_the_index_loss_at_several_heads_a_loop_body_is_bit_for_bit_the_loop_of_
     assert sa._heads_a_body(q_heads) == heads_a_body
     seq, d, topk = 512 * n, 128, 200
     ks = jax.random.split(jax.random.PRNGKey(q_heads + n), 2)
-    q = jax.random.normal(ks[0], (1, q_heads, seq, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (1, kv, seq, d), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (1, seq, q_heads, d), jnp.bfloat16)  # position-major, as the kernel reads them
+    k = jax.random.normal(ks[1], (1, seq, kv, d), jnp.bfloat16)
     a, bt, w = _index_operands(n, seq)
     scores = sa.index_scores(a, bt, w)
     keep = sa.selection_mask(scores, topk)
     mask = sa.packed_lower_triangle(keep.astype(jnp.int8))
     z = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1)[..., None]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, q_heads // kv, axis=1), preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, q_heads // kv, axis=2), preferred_element_type=jnp.float32)
     lse = jax.nn.logsumexp(jnp.where(keep[:, None], s * d ** -0.5, -jnp.inf), axis=-1)
     one = sa._index_loss_pallas(q, k, lse, a, bt, w, z, mask, d ** -0.5, interpret=True, heads_a_body=1)
     got = sa._index_loss_pallas(q, k, lse, a, bt, w, z, mask, d ** -0.5, interpret=True)

@@ -15,15 +15,15 @@ from torchft_tpu.ops import sparse_attention as sa
 def _kernel_operands(seed=0, batch=1, heads=4, kv=2, seq=1024, d=128, j=3, di=64, ties=True):
     ks = jax.random.split(jax.random.PRNGKey(seed), 7)
     bf = jnp.bfloat16
-    q = jax.random.normal(ks[0], (batch, heads, seq, d), bf)
-    k = jax.random.normal(ks[1], (batch, kv, seq, d), bf)
-    v = jax.random.normal(ks[2], (batch, kv, seq, d), bf)
+    q = jax.random.normal(ks[0], (batch, seq, heads, d), bf)  # position-major, as the kernels read them
+    k = jax.random.normal(ks[1], (batch, seq, kv, d), bf)
+    v = jax.random.normal(ks[2], (batch, seq, kv, d), bf)
     a = jax.random.normal(ks[3], (batch, j, seq, di), bf)
     b = jax.random.normal(ks[4], (batch, seq, di), bf)
     if ties:
         b = b.at[:, 100:140].set(b[:, 100:101])
     w = jax.random.normal(ks[5], (batch, seq, j), jnp.float32) * (j * di) ** -0.5
-    g = jax.random.normal(ks[6], (batch, heads, seq, d), bf)
+    g = jax.random.normal(ks[6], (batch, seq, heads, d), bf)
     return q, k, v, a, b.transpose(0, 2, 1), w, g
 
 
@@ -134,7 +134,7 @@ def test_the_selection_kernels_grids_at_the_cells_lengths(seq) -> None:
     a, bt, w = (jax.ShapeDtypeStruct(s, t) for s, t in (((1, 16, seq, 64), bf), ((1, 64, seq), bf), ((1, seq, 16), f32)))
     row = jax.ShapeDtypeStruct((1, seq, 1), jnp.int32)
     assert pallas_call_grids(sa._mask_pallas, a, bt, w, row, row) == {"tpuft_dsa_mask": (1, visible)}
-    q, k = (jax.ShapeDtypeStruct((1, h, seq, 128), bf) for h in (32, 4))
+    q, k = (jax.ShapeDtypeStruct((1, seq, h, 128), bf) for h in (32, 4))
     lse, z = jax.ShapeDtypeStruct((1, 32, seq), f32), jax.ShapeDtypeStruct((1, seq, 1), f32)
     mask = jax.ShapeDtypeStruct((1, n * (n + 1) // 2, 512, 512), jnp.int8)
     assert pallas_call_grids(lambda *ops: sa._index_loss_pallas(*ops, 0.088), q, k, lse, a, bt, w, z, mask) == {

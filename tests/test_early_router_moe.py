@@ -138,8 +138,8 @@ def test_an_unrotated_kinds_q_and_k_are_the_projections_bit_for_bit(monkeypatch)
     x = weights["embed"][batch["tokens"]]
     w = {name: leaf[0] for name, leaf in weights["layers"].items()}
     h = attention.rms_norm(x, w["attn_norm"], cfg.rms_eps)
-    want_q = (h @ w["wq"]).reshape(2, SEQ, 7, 16).transpose(0, 2, 1, 3)
-    want_k = (h @ w["wk"]).reshape(2, SEQ, 1, 16).transpose(0, 2, 1, 3)
+    want_q = (h @ w["wq"]).reshape(2, SEQ, 7, 16)
+    want_k = (h @ w["wk"]).reshape(2, SEQ, 1, 16)
     assert np.array_equal(np.asarray(q), np.asarray(want_q)) and np.array_equal(np.asarray(k), np.asarray(want_k))
 
 

@@ -138,7 +138,7 @@ def test_ulysses_matches_flash_attention() -> None:
         jax.random.normal(kk, (B, H, S, D), jnp.float32)
         for kk in jax.random.split(key, 3)
     )
-    ref = flash_attention(q, k, v, causal=True)
+    ref = flash_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True).transpose(0, 2, 1, 3)  # position-major
 
     ftmesh = ft_init_mesh({"data": 2, "sequence": 4})
     spec = ftmesh.rules.sharding(("batch", "heads", "seq", None), ftmesh.mesh)
@@ -187,7 +187,7 @@ def test_ulysses_gqa_compressed_kv() -> None:
     q = jax.random.normal(kq, (B, Hq, S, D), jnp.float32)
     k = jax.random.normal(kk, (B, Hkv, S, D), jnp.float32)
     v = jax.random.normal(kv_, (B, Hkv, S, D), jnp.float32)
-    ref = flash_attention(q, k, v, causal=True)
+    ref = flash_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True).transpose(0, 2, 1, 3)  # position-major
 
     ftmesh = ft_init_mesh({"data": 2, "sequence": 4})
     qspec = ftmesh.rules.sharding(("batch", "heads", "seq", None), ftmesh.mesh)

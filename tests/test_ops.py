@@ -12,6 +12,12 @@ import jax
 import jax.numpy as jnp
 
 
+def _major(x):
+    """[B, heads, S, d] <-> [B, S, heads, d]: the oracles here are written
+    head-major, `flash_attention` takes and gives position-major."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def _naive_attention(q, k, v, causal):
     # Straightforward softmax attention in f64 for a trustworthy oracle.
     qf, kf, vf = (np.asarray(t, dtype=np.float64) for t in (q, k, v))
@@ -37,7 +43,7 @@ def test_flash_attention_reference_path(causal) -> None:
     q = jnp.asarray(rng.standard_normal((2, 3, 64, 32)), dtype=jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, 3, 64, 32)), dtype=jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, 3, 64, 32)), dtype=jnp.float32)
-    out = flash_attention(q, k, v, causal=causal)
+    out = _major(flash_attention(_major(q), _major(k), _major(v), causal=causal))
     np.testing.assert_allclose(
         np.asarray(out), _naive_attention(q, k, v, causal), rtol=1e-4, atol=1e-4
     )
@@ -50,7 +56,7 @@ def test_flash_attention_gqa_broadcast() -> None:
     q = jnp.asarray(rng.standard_normal((1, 4, 32, 16)), dtype=jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 2, 32, 16)), dtype=jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 2, 32, 16)), dtype=jnp.float32)
-    out = flash_attention(q, k, v, causal=True)
+    out = _major(flash_attention(_major(q), _major(k), _major(v), causal=True))
     kr = jnp.repeat(k, 2, axis=1)
     vr = jnp.repeat(v, 2, axis=1)
     np.testing.assert_allclose(
@@ -67,7 +73,7 @@ def test_flash_attention_grads_match_reference() -> None:
     v = jnp.asarray(rng.standard_normal((1, 2, 32, 16)), dtype=jnp.float32)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(flash_attention(_major(q), _major(k), _major(v), causal=True) ** 2)
 
     def loss_naive(q, k, v):
         d = q.shape[-1]
@@ -86,7 +92,8 @@ def test_flash_attention_grads_match_reference() -> None:
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_pallas_interpret_matches(causal) -> None:
     """Runs the actual TPU kernel in pallas interpret mode on CPU."""
-    from torchft_tpu.ops.attention import _fa_pallas_call, _fa_reference
+    from attention_forms import fwd as _fa_pallas_call
+    from torchft_tpu.ops.attention import _fa_reference
 
     rng = np.random.default_rng(3)
     # seq 1024 -> two 512-blocks in both q and kv; d=128 lane-aligned.
@@ -119,6 +126,7 @@ def test_flash_attention_bwd_pallas_interpret_matches(causal, seq, over_budget, 
     VMEM-resident row; with the row's budget cut under them (the only way
     in: the choice reads shapes alone) they take the two-pass form that a
     longer row than any here would."""
+    import attention_forms as forms
     from torchft_tpu.ops import attention as fa
 
     bh = 2 if seq == 1024 else 1
@@ -136,7 +144,7 @@ def test_flash_attention_bwd_pallas_interpret_matches(causal, seq, over_budget, 
     # _fa_bwd_xla explicitly, NOT _flash_bwd: on a TPU backend the latter
     # dispatches to the pallas kernels, making the comparison vacuous.
     d_ref = fa._fa_bwd_xla(q, k, v, o, lse, g, scale, causal)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=scale, causal=causal, interpret=True)
+    bwd = functools.partial(forms.bwd, scale=scale, causal=causal, interpret=True)
     assert pallas_call_names(bwd, q, k, v, o, lse, g) == (TWO_PASS if over_budget else ONE_PASS)
     d_pl = bwd(q, k, v, o, lse, g)
     for a, b, name in zip(d_pl, d_ref, ("dq", "dk", "dv")):
@@ -146,18 +154,19 @@ def test_flash_attention_bwd_pallas_interpret_matches(causal, seq, over_budget, 
 
 
 def test_flash_attention_bwd_is_one_pallas_call_at_the_cells_shape() -> None:
-    """`[2, 4096, 128]` bf16, the dense cells' sequence: the backward's jaxpr
+    """Two heads of 128 at 4,096 positions in bf16, the dense cells' sequence
+    ([1, 4096, 2 * 128] as the kernels take them): the backward's jaxpr
     holds exactly one `pallas_call`, whose outputs are dk, dv and a dq of
     the operand's dtype — no f32 dq, no partials, nothing for XLA to sum."""
     from torchft_tpu.ops import attention as fa
 
-    qkv = jax.ShapeDtypeStruct((2, 4096, 128), jnp.bfloat16)
+    qkv = jax.ShapeDtypeStruct((1, 4096, 256), jnp.bfloat16)
     lse = jax.ShapeDtypeStruct((2, 4096), jnp.float32)
-    bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True)
+    bwd = functools.partial(fa._fa_bwd_pallas, scale=0.088, causal=True, q_heads=2)
     jaxpr = jax.make_jaxpr(bwd)(qkv, qkv, qkv, qkv, lse, qkv)
     (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert [call.params["name"]] == ONE_PASS
-    assert [(v.aval.shape, v.aval.dtype) for v in call.outvars] == [((2, 4096, 128), jnp.bfloat16)] * 3
+    assert [(v.aval.shape, v.aval.dtype) for v in call.outvars] == [((1, 4096, 256), jnp.bfloat16)] * 3
     assert {id(v) for v in jaxpr.jaxpr.outvars} == {id(v) for v in call.outvars}
     # the row's size is the only thing the choice reads: 65,536 at 128 wide
     # is the longest resident row, one block more is not

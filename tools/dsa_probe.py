@@ -110,7 +110,7 @@ def child(args) -> int:
         return 0
     tile = fa._block_sizes(seq, seq)[0]
     n = seq // tile
-    operands = (shaped((1, heads, seq, d)), shaped((1, args.kv_heads, seq, d)), shaped((1, heads, seq), jnp.float32),
+    operands = (shaped((1, seq, heads, d)), shaped((1, seq, args.kv_heads, d)), shaped((1, heads, seq), jnp.float32),
                 a, bt, w, shaped((1, seq, 1), jnp.float32), shaped((1, n * (n + 1) // 2, tile, sa.BLOCK_K), jnp.int8))
     more = {"heads_a_body": args.heads_a_body[0]} if args.heads_a_body else {}
     jax.jit(lambda *x: sa._index_loss_pallas(*x, d ** -0.5, **more)).lower(*operands).compile()
@@ -140,7 +140,7 @@ def on_chip(args) -> int:
     j, di = (int(x) for x in args.index.split("x"))
     ks = jax.random.split(jax.random.PRNGKey(args.seed), 6)
     bf = jnp.bfloat16
-    q, k, v = (jax.random.normal(key, (1, n, seq, d), bf) for key, n in zip(ks, (heads, args.kv_heads, args.kv_heads)))
+    q, k, v = (jax.random.normal(key, (1, seq, n, d), bf) for key, n in zip(ks, (heads, args.kv_heads, args.kv_heads)))  # position-major
     a, bt = jax.random.normal(ks[3], (1, j, seq, di), bf), jax.random.normal(ks[4], (1, di, seq), bf)
     w = jax.random.normal(ks[5], (1, seq, j), jnp.float32) * (j * di) ** -0.5
     scale = d ** -0.5
@@ -170,7 +170,9 @@ def on_chip(args) -> int:
     mask = jax.jit(sa._mask_pallas)(a, bt, w, tau, cut)
     _, lse = jax.jit(lambda *x: sa._masked_flash_fwd(*x, scale))(q, k, v, mask)
     operands = (q, k, lse, a, bt, w, z, mask)
-    ms, first = timed(jax.jit(lambda *x: other._index_loss_pallas(*x, scale)), *operands)
+    # a tree from before PR 65 takes q and k head-major
+    theirs = operands if hasattr(other._fa, "_entry_and_block") else tuple(x.transpose(0, 2, 1, 3) for x in (q, k)) + operands[2:]
+    ms, first = timed(jax.jit(lambda *x: other._index_loss_pallas(*x, scale)), *theirs)
     line("tpuft_dsa_index_loss", args.against, ms, first, first)
     for u in args.heads_a_body or [None]:
         ms, out = timed(jax.jit(lambda *x, u=u: sa._index_loss_pallas(*x, scale, heads_a_body=u)), *operands)

@@ -51,9 +51,19 @@ for); an H whose dq rows the compiler refuses is a line with the error.
     chiprun -- python tools/fa_bwd_probe.py --against parent_tree --heads-per-step 1,0
 
 `--against` names another tree of this repo (`git archive <commit> | tar -x -C
-parent_tree`): its forward kernel is timed on the same operands before this
-tree's at every H read (a line with `tree`), and this tree's forward line says
-in `bitwise_other_tree` whether its out and lse are bit for bit the other's.
+parent_tree`): its forward kernel and its chosen backward are timed on the
+same operands beside this tree's at every H read (a line with `tree`), and
+this tree's lines say in `bitwise_other_tree` whether out and lse, and dq, dk,
+dv, are bit for bit the other's (`max_diff_other_tree` where they are not).
+The operands are drawn head-major, [heads, S, d], and handed to a tree in the
+form its kernels take — since PR 65 position-major, [1, S, heads * d], a head
+a lane-aligned column block; a tree from before it head-major — turned
+outside what is timed, as the results are for the comparison.  A shape with
+an `f` (`32x8192x256/128f`) is fed as `flash_attention` feeds heads it has
+padded: folded into the batch, [heads, S, d] entries of one head.  `--delta`
+shapes read the backward's delta, rowsum(g * o), as the program makes it (a
+product with the heads' indicator) beside the float32 sum, each against
+float64 on the host.
 
     python tools/fa_bwd_probe.py --bundles 28x16384x128 --heads-per-step 1,2,4     # no chip
 
@@ -81,6 +91,7 @@ are a floor for the measured tile, not its time.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -112,8 +123,8 @@ def grids(fn, *operands) -> list:
 
 
 def dims_of(spec: str) -> tuple:
-    """(batch * heads, positions, query and key width, value width) of `BHxSxDqk[/Dv][@G]`."""
-    dims, _, dv = spec.partition("@")[0].partition("/")
+    """(batch * heads, positions, query and key width, value width) of `BHxSxDqk[/Dv][f][@G]`."""
+    dims, _, dv = spec.replace("f", "").partition("@")[0].partition("/")
     bh, seq, d = (int(x) for x in dims.split("x"))
     return bh, seq, d, int(dv) if dv else d
 
@@ -168,8 +179,10 @@ def bundles_child(spec: str, what: str, heads: int, dump: str, window: int, kv_g
     bh, seq, d, dv = dims_of(spec)
     group = group_of(spec, kv_group, kind == "m")
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
-    q, k, v, g = shaped((bh, seq, d)), shaped((bh // group, seq, d)), shaped((bh // group, seq, dv)), shaped((bh, seq, dv))
-    more = {"heads_per_step": heads or None, "kv_group": group, "window": window if kind == "w" else None}
+    # one batch entry's bh heads side by side, as the kernels read them (`f`: bh entries of one head, as padded heads go)
+    entries, cols = (bh, 1) if "f" in spec else (1, bh)
+    q, k, v, g = (shaped((entries, seq, cols // n * width)) for n, width in ((1, d), (group, d), (group, dv), (1, dv)))
+    more = {"q_heads": cols, "heads_per_step": heads or None, "kv_group": group, "window": window if kind == "w" else None}
     mask = None
     if kind == "m":
         tile = fa._block_sizes(seq, seq)[0]
@@ -288,11 +301,13 @@ def bundles(args) -> int:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--shapes", default="32x4096x128,64x4096x128,32x8192x256/128,32x1024x128,4x32768x128,2x65536x128")
+    parser.add_argument("--shapes", default="32x4096x128,64x4096x128,32x8192x256/128f,32x1024x128,4x32768x128,2x65536x128",
+                        help="`BHxSxDqk[/Dv][f][@G]`; `f`: fed as `flash_attention` feeds padded heads, folded into the batch")
     parser.add_argument("--noncausal", default="4x32768x128", help="shapes read with causal=False too")
     parser.add_argument("--masked", default="32x32768x128", help="shapes read under a packed mask of the Keye cell's density")
     parser.add_argument("--windowed", default="64x16384x128", help="shapes read under a window (the band walk)")
     parser.add_argument("--window", type=int, default=512)
+    parser.add_argument("--delta", default="28x16384x128", help="shapes at which the backward's delta, rowsum(g * o), is read both ways")
     parser.add_argument("--kv-group", type=int, default=0, help="query heads a KV head, read in place, in every form "
                         "(default: 8 under a mask, the Keye cell's, and 1 elsewhere)")
     parser.add_argument("--topk", type=int, default=2048)
@@ -315,6 +330,7 @@ def main(argv=None) -> int:
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from torchft_tpu.ops import attention as fa
 
@@ -342,6 +358,7 @@ def main(argv=None) -> int:
         return statistics.median(times) * 1e3, out
 
     def operands_of(spec, kv_group=1):
+        """Drawn head-major, [heads, S, d], as every tree's probe has drawn them from the seed."""
         bh, seq, d, dv = dims_of(spec)
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
         q = jax.random.normal(keys[0], (bh, seq, d), jnp.bfloat16)
@@ -350,11 +367,25 @@ def main(argv=None) -> int:
         g = jax.random.normal(keys[3], (bh, seq, dv), jnp.bfloat16)
         return bh, seq, d, dv, q, k, v, g
 
+    def position_major(module) -> bool:
+        """Whether a tree's kernels take position-major operands, [1, S, heads * d] (since PR 65)."""
+        return hasattr(module, "_entry_and_block")
+
+    def given_as(module, *operands, folded=False):
+        """Head-major operands [heads, S, d] in the form ``module``'s kernels take, turned outside what is timed
+        (``folded``: as they are, entries of one head)."""
+        return tuple(fa._from_heads(x, x.shape[0]) if position_major(module) and not folded and x.ndim == 3 else x for x in operands)
+
+    def turned_as(module, results, bh, folded=False):
+        """A tree's results head-major, for the comparisons (lse, [heads, S], is that in every tree)."""
+        return tuple(fa._to_heads(x, bh) if position_major(module) and not folded and x.ndim == 3 else x for x in results)
+
     def read(spec, walk, causal=True, mask=None, kv_group=1, two_pass=False, pairs=None, window=None, **noted):
         """Forward and backward of one shape (`two_pass`: the backward in
         that form too); `walk` names the reading and `noted` goes into each
         of its lines."""
         bh, seq, d, dv, q, k, v, g = operands_of(spec, kv_group)
+        given, turned = (functools.partial(f, folded="f" in spec) for f in (given_as, turned_as))
         scale = d ** -0.5
         side = fa._block_sizes(seq, seq)[0]
         n = seq // side
@@ -396,8 +427,9 @@ def main(argv=None) -> int:
             constant of the program, for XLA to fold."""
             if kv_group == 1:
                 return {}
-            fn = jax.jit(lambda *operands: kernel(*operands, scale, causal, **dict(kw, kv_group=1)))
-            return {"bitwise_repeated": same(fn(q, jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0), *others), got)}
+            fn = jax.jit(lambda *operands: kernel(*operands, scale, causal, **dict(kw, kv_group=1, q_heads=bh)))
+            every = given(fa, q, jnp.repeat(k, kv_group, axis=0), jnp.repeat(v, kv_group, axis=0), *others)
+            return {"bitwise_repeated": same(turned(fa, fn(*every), bh), got)}
 
         chosen = "one_pass" if fa._dq_row_resident(seq, d) else "two_pass"
         forms = [(chosen, fa._DQ_ROW_VMEM_BUDGET)] + ([("two_pass", 0)] if two_pass and chosen != "two_pass" else [])
@@ -406,19 +438,23 @@ def main(argv=None) -> int:
         for heads in [1] + [h for h in (args.heads_per_step or [None]) if h != 1]:
             kw = dict(more_kw, heads_per_step=heads)
             timed_line = args.heads_per_step is None or heads in args.heads_per_step
-            fwd_of = lambda module: lambda q_, k_, v_: module._fa_pallas_call(q_, k_, v_, scale, causal, **kw)  # noqa: E731,B023
+            its = lambda module: dict(kw, q_heads=1 if "f" in spec else bh) if position_major(module) else kw  # noqa: E731,B023
+            fwd_of = lambda module: lambda q_, k_, v_: module._fa_pallas_call(q_, k_, v_, scale, causal, **its(module))  # noqa: E731,B023
+            bwd_of = lambda module: lambda *x: module._fa_bwd_pallas(*x, scale, causal, **its(module))  # noqa: E731,B023
             fwd, theirs = fwd_of(fa), None
             try:
                 if other is not None and timed_line:  # the other tree's first: what this tree's is compared with
-                    ms, theirs = timed(jax.jit(fwd_of(other)), q, k, v)
-                    record("fwd", family + "_fwd", ms, need_fwd, grids(fwd_of(other), q, k, v), tree=args.against)
-                ms, (o, lse) = timed(jax.jit(fwd), q, k, v)
+                    ms, theirs = timed(jax.jit(fwd_of(other)), *given(other, q, k, v))
+                    record("fwd", family + "_fwd", ms, need_fwd, grids(fwd_of(other), *given(other, q, k, v)), tree=args.against)
+                    theirs = turned(other, theirs, bh)
+                ms, (o, lse) = timed(jax.jit(fwd), *given(fa, q, k, v))
+                o, lse = turned(fa, (o, lse), bh)
             except Exception as e:  # noqa: BLE001 — an H the compiler refuses is a reading too
                 failed("fwd", family + "_fwd", heads, e)
                 continue
             one_head.setdefault("fwd", (o, lse))
             if timed_line:
-                record("fwd", family + "_fwd", ms, need_fwd, grids(fwd, q, k, v), bitwise_h1=same((o, lse), one_head["fwd"]),
+                record("fwd", family + "_fwd", ms, need_fwd, grids(fwd, *given(fa, q, k, v)), bitwise_h1=same((o, lse), one_head["fwd"]),
                        **({} if theirs is None else {"bitwise_other_tree": same((o, lse), theirs)}),
                        **repeated(fa._fa_pallas_call, (o, lse)))
             o, lse = one_head["fwd"]
@@ -426,11 +462,12 @@ def main(argv=None) -> int:
             for form, budget in forms:
                 # The backward traced with `budget` bytes for the dq row: a function of
                 # its own a form, or the second would be the first's cached trace.
-                bwd = lambda q_, k_, v_, o_, lse_, g_: fa._fa_bwd_pallas(q_, k_, v_, o_, lse_, g_, scale, causal, **kw)  # noqa: E731,B023
+                bwd, ours = bwd_of(fa), given(fa, q, k, v, o, lse, g)
                 kept, fa._DQ_ROW_VMEM_BUDGET = fa._DQ_ROW_VMEM_BUDGET, budget
                 try:
-                    grid = grids(bwd, q, k, v, o, lse, g)
-                    ms, results[form] = timed(jax.jit(bwd).lower(q, k, v, o, lse, g).compile(), q, k, v, o, lse, g)
+                    grid = grids(bwd, *ours)
+                    ms, results[form] = timed(jax.jit(bwd).lower(*ours).compile(), *ours)
+                    results[form] = turned(fa, results[form], bh)
                 except Exception as e:  # noqa: BLE001 — a form the compiler refuses is a reading too
                     failed("bwd", form, heads, e)
                     continue
@@ -438,6 +475,14 @@ def main(argv=None) -> int:
                     fa._DQ_ROW_VMEM_BUDGET = kept
                 one_head.setdefault(form, results[form])
                 more = {"bitwise_h1": same(results[form], one_head[form])}
+                if other is not None and timed_line and form == chosen:  # the other tree's backward on the same operands
+                    theirs = given(other, q, k, v, o, lse, g)
+                    their_ms, their_grads = timed(jax.jit(bwd_of(other)), *theirs)
+                    record("bwd", form, their_ms, need_bwd, grids(bwd_of(other), *theirs), tree=args.against)
+                    their_grads = turned(other, their_grads, bh)
+                    more["bitwise_other_tree"] = same(results[form], their_grads)
+                    more["max_diff_other_tree"] = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+                                                   for a, b in zip(results[form], their_grads)]
                 if form == "two_pass" and chosen in results and chosen != form:
                     more["max_diff_over_max"] = [
                         float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))) / jnp.max(jnp.abs(b.astype(jnp.float32))))
@@ -463,6 +508,19 @@ def main(argv=None) -> int:
              selected_share=selected / (seq * (seq + 1) / 2.0))
     for spec in filter(None, args.windowed.split(",")):
         read(spec, "windowed", window=args.window, kv_group=group_of(spec, args.kv_group))
+    for spec in filter(None, args.delta.split(",")):
+        # the backward's delta as the program makes it (a product with the heads' indicator at `Precision.HIGH`) beside
+        # the float32 sum over a head's columns, each against float64 on the host
+        bh, seq, _, dv, _, _, _, g = operands_of(spec)
+        g, o = given_as(fa, g, jax.random.normal(jax.random.PRNGKey(args.seed + 1), g.shape, g.dtype))
+        exact = (np.asarray(g, np.float64) * np.asarray(o, np.float64)).reshape(seq, bh, dv).sum(-1).T
+        summed = jax.jit(lambda g_, o_: jnp.sum((g_.astype(jnp.float32) * o_.astype(jnp.float32)).reshape(seq, bh, dv), -1).T)(g, o)
+        product = jax.jit(functools.partial(fa._row_delta, q_heads=bh))(g, o)[:, 0]
+        off = lambda x: float(np.max(np.abs(np.asarray(x, np.float64) - exact)) / np.max(np.abs(exact)))  # noqa: E731
+        rec = {"shape": spec, "what": "row_delta", "product_off_float64": off(product), "float32_sum_off_float64": off(summed),
+               "product_off_float32_sum": float(jnp.max(jnp.abs(product - summed)) / jnp.max(jnp.abs(summed)))}
+        readings.append(rec)
+        print(json.dumps(rec), flush=True)
     out = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "fa_bwd_probe.json"), "w", encoding="utf-8") as f:

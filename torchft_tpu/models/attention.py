@@ -1,6 +1,8 @@
 """The softmax-attention mixers (`LayerKind.mixer`), three entries that share
-a tail — head-major q, k, v -> the attention call (or, where the model has an
-indexer, the keys it selects) -> the head gate -> `wo`:
+a tail — q, k, v position-major as their projections leave them, [B, S, heads,
+D] -> the attention call (or, where the model has an indexer, the keys it
+selects), which reads and writes a head as a column block of a position's row
+-> the head gate -> `wo`:
 
 "attention": q, k and v are products of the layer's normed input, position by
 position, under the model's QK-norm and the kind's RoPE.
@@ -31,13 +33,14 @@ import jax.numpy as jnp
 from torchft_tpu.models.mixer import Mixer, _norm_init
 from torchft_tpu.models.rope import _rope, _rotary
 from torchft_tpu.ops import flash_attention, rms_norm
-from torchft_tpu.ops.attention import SAVED_NAMES
+from torchft_tpu.ops.attention import SAVED_NAMES, heads_indicator
 from torchft_tpu.ops.sparse_attention import SAVED_NAMES as DSA_SAVED_NAMES
 from torchft_tpu.parallel.sharding import constrain
 
 
 def _attention(cfg, mesh, q, k, v, kind):
-    """q/k/v: [B, H|KV, S, Dh] head-major."""
+    """q/k/v: [B, S, H|KV, Dh] -> [B, S, H, Dv].  The sequence-parallel forms
+    (no cell runs them) take and give head-major, and are turned to here."""
     seq_parallel = (
         cfg.attention in ("ring", "ulysses")
         and mesh is not None
@@ -72,6 +75,7 @@ def _attention(cfg, mesh, q, k, v, kind):
                 cfg.n_kv_heads != kind.n_heads
                 and (cfg.n_kv_heads // tp) % mesh.shape["sequence"] != 0
             )
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         if broadcast_gqa:
             rep = kind.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=1)
@@ -85,7 +89,7 @@ def _attention(cfg, mesh, q, k, v, kind):
             head_axis="tensor" if "tensor" in mesh.axis_names else None,
             seq_axis="sequence",
             **kwargs,
-        )
+        ).transpose(0, 2, 1, 3)
     return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window)
 
 
@@ -115,7 +119,7 @@ def _mla_qkv(cfg, kind, h, w, positions):
 
 def _index_operands(cfg, h, w, positions):
     """The indexer's operands from the normed input h [B, S, E], which it
-    reads DETACHED: index queries [B, J, S, Di] and the one index key head
+    reads DETACHED: index queries [B, J, S, Di] (64 columns, no lane multiple: head-major) and the one index key head
     [B, S, Di], both after RoPE, and the per-query head weights [B, S, J] f32
     with the two scale factors (J**-0.5, Di**-0.5) in them."""
     B, S, _ = h.shape
@@ -131,8 +135,8 @@ def _index_operands(cfg, h, w, positions):
 
 
 def _sparse_attention(cfg, mesh, h, w, positions, q, k, v):
-    """Attention over the keys the layer's indexer selects; q/k/v head-major.
-    Returns (attention [B, H, S, Dh], {"dsa_index_loss", "dsa_selected"})."""
+    """Attention over the keys the layer's indexer selects; q/k/v [B, S, H|KV, Dh].
+    Returns (attention [B, S, H, Dh], {"dsa_index_loss", "dsa_selected"})."""
     from torchft_tpu.ops.sparse_attention import sparse_attention
 
     with jax.named_scope("dsa_index"):
@@ -158,9 +162,10 @@ def _before(x: jax.Array) -> jax.Array:
 
 
 def _cca_qkv(cfg, kind, h, w, positions):
-    """Compressed convolutional attention's q [B, H, S, D] and k, v
-    [B, G, S, D], head-major, from the normed input h [B, S, E]
-    (arXiv:2510.04476).  The projections are `attn_proj`'s;
+    """Compressed convolutional attention's q [B, S, H, D] and k, v
+    [B, S, G, D] from the normed input h [B, S, E] (arXiv:2510.04476), worked
+    head-major (its convolutions are batched products with the heads leading)
+    and turned once where it hands them over.  The projections are `attn_proj`'s;
     what lies between them and RoPE — `cca_mix` — mixes positions and
     channels, all of it linear but the norm, so its backward pass is the
     mirrored shifts and the transposed products:
@@ -199,8 +204,8 @@ def _cca_qkv(cfg, kind, h, w, positions):
         q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True)) * D ** 0.5)
         k = k * (jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True)) * D ** 0.5
                  * w["cca_temp"].astype(f32)[:, None, None])
-    q, k = (_rotary(a, positions, kind, head_major=True).astype(dt) for a in (q, k))
-    return q, k, v
+    q, k = (_rotary(a.transpose(0, 2, 1, 3), positions, kind).astype(dt) for a in (q, k))
+    return q, k, v.transpose(0, 2, 1, 3)
 
 
 def _plain_qkv(cfg, kind, h, w, positions):
@@ -228,9 +233,9 @@ def _plain_qkv(cfg, kind, h, w, positions):
     return q, k, v
 
 
-def _forward(qkv, head_major: bool = False):
+def _forward(qkv):
     """A `Mixer.forward` around ``qkv(cfg, kind, h, w, positions)``, which
-    gives q, k, v [B, S, heads, D] — or [B, heads, S, D] where ``head_major``."""
+    gives q, k, v [B, S, heads, D]."""
 
     def forward(cfg, kind, mesh, rules, h, w, positions):
         B, S, _ = h.shape
@@ -238,21 +243,21 @@ def _forward(qkv, head_major: bool = False):
             q, k, v = qkv(cfg, kind, h, w, positions)
             if cfg.attn_head_gate:
                 head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
-            major = (lambda a: a) if head_major else (lambda a: a.transpose(0, 2, 1, 3))
-            q = constrain(major(q), ("batch", "heads", "seq", None), mesh, rules)
-            k = constrain(major(k), ("batch", "kv_heads", "seq", None), mesh, rules)
-            v = constrain(major(v), ("batch", "kv_heads", "seq", None), mesh, rules)
+            q = constrain(q, ("batch", "seq", "heads", None), mesh, rules)
+            k = constrain(k, ("batch", "seq", "kv_heads", None), mesh, rules)
+            v = constrain(v, ("batch", "seq", "kv_heads", None), mesh, rules)
         dsa = None
         if cfg.dsa_index_heads:
             attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
         else:
             with jax.named_scope("attn" if kind.window is None else "attn_window"):
-                attn = _attention(cfg, mesh, q, k, v, kind)  # [B, H, S, Dv]
+                attn = _attention(cfg, mesh, q, k, v, kind)  # [B, S, H, Dv]
         with jax.named_scope("attn_proj"):
-            attn = attn.transpose(0, 2, 1, 3)
-            if cfg.attn_head_gate:
-                attn = attn * head_gate[..., None]
-            attn = attn.reshape(B, S, kind.n_heads * attn.shape[-1])
+            d_v = attn.shape[-1]
+            attn = attn.reshape(B, S, kind.n_heads * d_v)
+            if cfg.attn_head_gate:  # a head's gate over its columns: a product with 0 / 1, exact, and no [B, S, H, Dv] array
+                attn = attn * jnp.einsum("bsh,hc->bsc", head_gate, heads_indicator(kind.n_heads, d_v).astype(cfg.dtype),
+                                         precision=jax.lax.Precision.HIGHEST)
             return attn @ w["wo"].astype(cfg.dtype), dsa
 
     return forward
@@ -376,4 +381,4 @@ def _check_cca(cfg, kind) -> None:
 _KEPT = SAVED_NAMES + DSA_SAVED_NAMES
 ATTENTION = Mixer(_init_plain, _plain_axes, _forward(_plain_qkv), _KEPT)
 MLA = Mixer(_init_mla, _mla_axes, _forward(_mla_qkv), _KEPT, check=_check_mla)
-CCA = Mixer(_init_cca, _cca_axes, _forward(_cca_qkv, head_major=True), _KEPT, check=_check_cca)
+CCA = Mixer(_init_cca, _cca_axes, _forward(_cca_qkv), _KEPT, check=_check_cca)

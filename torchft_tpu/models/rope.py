@@ -62,10 +62,8 @@ def _turn_whole_bwd(half: int, rot: int, tables, g: jax.Array):
 _turn_whole.defvjp(lambda x, cos, sin, half, rot: (_turn_whole(x, cos, sin, half, rot), (cos, sin)), _turn_whole_bwd)
 
 
-def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float, rot: int,
-          head_major: bool = False) -> jax.Array:
-    """The leading ``rot`` columns of x [B, S, H, D] ([B, H, S, D] where
-    ``head_major``) turned by positions
+def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float, rot: int) -> jax.Array:
+    """The leading ``rot`` columns of x [B, S, H, D] turned by positions
     [B, S] x inv_freq [rot / 2] in half-split pairs (i, i + rot / 2), cos and
     sin times ``factor``; the other columns pass through.  Float32, two
     products and one sum an element, under tables as wide as the head, so
@@ -77,8 +75,6 @@ def _turn(x: jax.Array, positions: jax.Array, inv_freq: jax.Array, factor: float
     angles = positions[..., None].astype(jnp.float32) * jnp.take(inv_freq, lane % half)  # [B, S, D]
     cos = jnp.where(lane < rot, jnp.cos(angles) * np.float32(factor), 1.0)
     sin = jnp.where(lane < rot, jnp.sin(angles) * np.where(lane < half, -factor, factor).astype(np.float32), 0.0)
-    if head_major:
-        return _turn_whole(x, cos[:, None], sin[:, None], half, rot)
     return _turn_whole(x, cos[:, :, None, :], sin[:, :, None, :], half, rot)
 
 
@@ -111,12 +107,12 @@ def yarn_frequencies(theta: float, rot_dim: int, factor: float, original: int,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def _rotary(x: jax.Array, positions: jax.Array, kind, head_major: bool = False) -> jax.Array:
+def _rotary(x: jax.Array, positions: jax.Array, kind) -> jax.Array:
     """RoPE as the layer's kind has it: over the leading ``rotary_fraction``
     of a head's columns (half-split pairs inside that part, the rest passes
     through), at theta's powers or YaRN's frequencies — a constant of the
     program — with cos and sin times YaRN's attention factor."""
-    if kind.rotary_fraction == 1.0 and kind.yarn is None and not head_major:
+    if kind.rotary_fraction == 1.0 and kind.yarn is None:
         return _rope(x, positions, kind.rope_theta)
     import numpy as np
 
@@ -126,7 +122,4 @@ def _rotary(x: jax.Array, positions: jax.Array, kind, head_major: bool = False) 
         inv_freq, factor = kind.rope_theta ** (-np.arange(half, dtype=np.float64) / half), 1.0
     else:
         inv_freq, factor = yarn_frequencies(kind.rope_theta, rot, *kind.yarn[:4]), kind.yarn[4]
-    inv_freq = jnp.asarray(inv_freq, jnp.float32)
-    if head_major:
-        return _turn(x, positions, inv_freq, factor, rot, head_major=True)
-    return _turn(x, positions, inv_freq, factor, rot)
+    return _turn(x, positions, jnp.asarray(inv_freq, jnp.float32), factor, rot)

@@ -1,4 +1,13 @@
-"""Flash attention: pallas TPU forward + backward kernels.
+"""Flash attention: pallas TPU forward + backward kernels, position-major.
+
+The kernels read q, k, v and the output's cotangent and write the output, dq,
+dk and dv WHERE THE PROJECTIONS LEAVE THEM: [B, S, heads * d], head h the
+lane-aligned column block [:, h * d:(h + 1) * d] — whole vector registers, and
+in HBM's tiled layout the whole tiles that `heads` blocks of (rows, d) are.
+Nothing turns to head-major and back around a call (117 MB a turn at 28 x
+16,384 x 128); only the row statistics (lse, delta: 4 bytes a position and
+head) and the VMEM scratch lead with the heads, and the XLA formulation turns
+inside itself.
 
 Design notes (MXU/HBM-minded):
   - forward streams K/V blocks through VMEM with the classic online-softmax
@@ -11,39 +20,36 @@ Design notes (MXU/HBM-minded):
     innermost): dk/dv accumulate in (block_k, d) f32 VMEM scratch, and dq
     in a third scratch that holds one head's WHOLE (seq_q, d_qk) f32 row
     while the kv blocks go by — the s/p/dp/ds tile work is computed once a
-    tile (5 matrix products where two passes make 7: the one-pass kernel's
-    compiled schedule is those five products' MXU time, its MXUs' slots 83 to
-    86% taken), and the row is cast to the output's dtype
-    inside the kernel, so dq never exists in f32 in HBM.  The row is 2 MiB
-    at seq 4,096 x 128, 8 MiB at 8,192 x 256 (MLA) and 16 MiB at 32,768 x
-    128 of a v5e's 128 MiB of VMEM; `vmem_limit_bytes` is sized from the
-    shapes.  Only a row over `_DQ_ROW_VMEM_BUDGET` takes two passes
-    (`tpuft_fa_bwd_dkdv` with q innermost, then `tpuft_fa_bwd_dq` with kv
-    innermost and the tile work done again): the choice reads the
-    operands' shapes and nothing else.  The one-pass kernel's name
-    contains `tpuft_fa_bwd_dkdv` on purpose: the benchmark books device
-    time to attention by that substring, and a `tpuft_fa_bwd_dq` in a
-    trace says the one-pass form did not engage.  Off-TPU the same math
-    is expressed in XLA with the scores materialized;
+    tile (5 matrix products where two passes make 7), and the row is cast to
+    the output's dtype inside the kernel, so dq never exists in f32 in HBM
+    (2 MiB at seq 4,096 x 128, 16 MiB at 32,768 x 128 of a v5e's 128 MiB of
+    VMEM; `vmem_limit_bytes` is sized from the shapes).  Only a row over
+    `_DQ_ROW_VMEM_BUDGET` takes two passes (`tpuft_fa_bwd_dkdv`, then
+    `tpuft_fa_bwd_dq` with kv innermost and the tile work done again): the
+    choice reads the operands' shapes and nothing else.  The one-pass
+    kernel's name contains `tpuft_fa_bwd_dkdv` on purpose: the benchmark
+    books device time to attention by that substring, and a
+    `tpuft_fa_bwd_dq` in a trace says the one-pass form did not engage;
   - a grid step carries H heads' tile, not one (`HEADS_PER_STEP`): the
-    grid's outer axis is batch*heads / H, every block and scratch leads with
-    the heads, and the tile's arithmetic is a function of values: the
-    backward's run over the heads under `jax.vmap` (`_bwd_tile`,
-    `_heads_at_once`), the forward's in two halves a head, walked with a skew
-    of one so that a head's p v stands beside the next head's softmax tile
-    (`_fwd_scores`, `_fwd_accumulate`, `_fwd_step`); H is read from the
-    shapes.  A head's arithmetic is what one head a step computes, bit for
-    bit;
+    grid's outer axis is batch*heads / H — a batch entry's heads / H column
+    blocks of H * d lanes one after the other (`_entry_and_block`) — a head
+    is a static slice of its block (`_head`), every scratch leads with the
+    heads, and the tile's arithmetic is a function of values, a head at a
+    time: the backward's whole (`_bwd_tile`, `_bwd_step`), the forward's in
+    two halves a head, walked with a skew of one so that a head's p v stands
+    beside the next head's softmax tile (`_fwd_scores`, `_fwd_accumulate`,
+    `_fwd_step`); H is read from the shapes.  A head's arithmetic is what
+    one head a step computes, bit for bit;
   - grouped queries read their KV heads in place: k and v reach the kernels
-    with their own heads, [batch * kv_heads, S, d], in both directions, and
-    no array of batch * q_heads k or v heads exists in HBM.  A step's k / v
-    block follows its H heads and the group (`_kv_spec`): the one KV head
-    they share, the KV heads of the whole groups they are, or — H neither a
-    divisor nor a multiple of the group — the adjacent KV heads they
-    straddle, in one block placed by element, each head taking its own by a
-    scalar index (`_kv_heads`).  H does not depend on the group.  dk and dv
-    leave the kernel a query head each, and `group_sum` adds a group's in
-    float32;
+    with their own heads, [B, S, kv_heads * d], in both directions, and
+    no array of q_heads k or v heads exists in HBM.  A step's k / v
+    block follows its H heads and the group along the columns (`_kv_spec`):
+    the one KV head they share, the KV heads of the whole groups they are,
+    or — H neither a divisor nor a multiple of the group — the adjacent KV
+    heads they straddle, in one block placed by element, each head taking
+    its own by a scalar index times the width (`_kv_head`).  H does not
+    depend on the group.  dk and dv leave the kernel a query head each, and
+    `group_sum` adds a group's column blocks in float32;
   - grid layout: the reduction axis innermost — TPU executes the innermost
     grid dimension sequentially, which is what makes the VMEM scratch
     accumulator legal.  A call that is causal over one sequence (``causal``
@@ -74,15 +80,15 @@ Design notes (MXU/HBM-minded):
 
 Query and key share one head width (``d_qk``), value and output another
 (``d_v``): equal for plain multi-head attention, 192 / 128 for latent
-attention (MLA), whose keys carry 64 rotary columns beside the 128 that the
-values match.  Each kernel's blocks span a whole head width, so both have to
-be lane multiples; ``flash_attention`` pads a ``d_qk`` that is not (MLA's
-192 -> 256) with zero columns, which add nothing to a score, and autodiff
-slices the padding's gradient away again.
+attention (MLA).  Both have to be lane multiples; ``flash_attention`` pads a
+``d_qk`` that is not (192 -> 256) with zero columns, which add nothing to a
+score, and folds such heads into the batch — B * heads entries of ONE head, a
+step adjacent entries (`_step_share`), the kernels' form of before PR 65: they
+are assembled as [B, S, heads, d] arrays, which XLA lays S innermost, so a
+head's [S, d] is a view and a row of heads 8 copies a layer (`flash_attention`).
 
-The reference XLA attention runs off-TPU (CPU test mesh), under a
-multi-device mesh (a pallas call has no partitioning rule), and for shapes
-the kernel does not tile (seq not divisible by the block size).
+The reference XLA attention runs off-TPU (CPU test mesh), under a multi-device mesh (a pallas call
+has no partitioning rule), and for shapes the kernel does not tile (seq not divisible by the block size).
 """
 
 from __future__ import annotations
@@ -116,31 +122,14 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     # 512x512.  Fatter q blocks were measured slower at the flagship's 32
     # heads x 1,024 positions (PR 2), where causal masking can only skip
     # whole blocks: a 1024-row block straddling the diagonal computes 33%
-    # more masked elements than two 512-row blocks.  "VPU-bound", as this
-    # comment used to put it, was read off timings; the compiled schedule at
-    # 28 x 16,384 x 128, seven heads a step (`tools/fa_bwd_probe.py
-    # --bundles`, PERF.md section 6, PRs 52, 62 and 64) says which unit.  A
-    # forward tile is 1,139 bundles a head for 1,024 cycles of MXU work, the
-    # MXUs' slots 88% taken, the VALUs' 62% (90% of the bundles hold a VALU
-    # operation), the XLUs' 33%: a head's q k^T and the p v of the head
-    # before it (`_fwd_step`'s skew) go out ~520 bundles apart under one
-    # softmax tile (row max across lanes, exp, row sum across lanes: the
-    # VALUs' work), and only the last head's p v follows the last
-    # exponential; 4,575 of the tile's 5,917 stores are spills of the score
-    # tile (5,045 of 6,387 before).  It was 1,322 a head at 75% while the
-    # heads ran under one `jax.vmap` and all their p v stood after the last
-    # exponential, ~480 bundles each with nothing beside them (PR 62), and
-    # 1,851 (PR 52: 1,813 at one head a step), no unit above 54% of its
-    # slots, while the statistics were one column, narrowed and broadcast
-    # along the lanes again a row group.
-    # The backward tile is 2,574 bundles for its five products' 2,560
-    # cycles: MXU-bound as scheduled.
-    # The band walk has the same tiles.  Under a window of 512 a 512 x 512 q
-    # tile visits two kv tiles and half of what it computes lies outside the
-    # band; at 256 x 256 it visits three, two thirds of them inside, at three
-    # times the grid steps — and is slower: 64 x 16,384 x 128 on a v5e read
-    # 10.0 ms forward and 12.6 backward at 512, 15.7 and 18.7 at 256
-    # (PERF.md section 6, PR 37).
+    # more masked elements than two 512-row blocks.  As compiled at 28 x
+    # 16,384 x 128 (`tools/fa_bwd_probe.py --bundles`; PERF.md section 6), a
+    # forward tile is 1,139 bundles a head for 1,024 cycles of MXU work and the
+    # backward tile ~2,500 for its five products' 2,560.  The band walk has the same tiles: under a window of 512, 256
+    # x 256 tiles visit three kv tiles a row, two thirds of them inside the
+    # band, at three times the grid steps — and are slower (64 x 16,384 x 128
+    # on a v5e: 10.0 ms forward and 12.6 backward at 512, 15.7 and 18.7 at
+    # 256; PERF.md section 6, PR 37).
     return min(512, seq_q), min(512, seq_k)
 
 
@@ -275,93 +264,78 @@ class _Walk:
 
 # The most heads a grid step carries.  A grid step pays, beside its tile, for
 # its blocks' DMAs (started and waited for once a step) and some 270 bundles of
-# the pipeline's bookkeeping: at one head a step a forward tile of 28 x 16,384
-# x 128 read 1.93 us on a v5e for a compiled schedule of 1,813 bundles (1.21 us
-# at 1.5 GHz), the one-pass backward 2.71 for 2,574 (1.72).  The heads of a
-# call are independent, so a step takes H of them: every block and scratch
-# leads with the heads, and the tile's arithmetic runs over them — the
-# backward's under `jax.vmap` (`_heads_at_once`), each product one batched
-# product; the forward's a head at a time since PR 64 (`_fwd_step`).  Read on
-# the chip (`tools/fa_bwd_probe.py`, PERF.md section 6, PR 52), us a tile:
-# forward 1.93 -> 2.02 (H = 2) -> 1.65 (4) -> 1.50 (7) -> 1.43 (14), one-pass
-# backward 2.71 -> 2.34 (2) -> 2.31 (4), results bit for bit those of one head
-# a step.  With PR 52's one-column statistics the step's cost shared by H
-# tiles was all of it: the schedule stayed at 1,850 to 2,020 bundles a head
-# forward at every H (the H first products back to back at the MXUs' rate,
-# then each head's softmax tile pacing its own second product).  Since the
-# forward's statistics stay lane-replicated (`_fwd_tile`, PR 62) a head's
-# first product does stand under the softmax tile of the head before it, and
-# the forward schedules at 1,310 to 1,320 bundles a head at H = 7 or 8 (1,753
-# at 256 / 128 wide, where it was 2,158): read on the chip against the parent
-# in one call (PERF.md section 6, PR 62), us a tile forward 1.93 -> 1.20 at
-# H = 1 and 1.50 -> 1.05 at H = 7 (28 x 16,384 x 128), 1.43 -> 0.98 under the
-# Keye cell's mask at H = 8, 1.69 -> 1.22 under a window of 4,096, 2.04 ->
-# 1.58 at 32 x 8,192 x 256 / 128, 2.50 -> 1.96 at 32 x 4,096 x 128, bit for
-# bit the parent's.  That schedule still left every head's p v after the
-# step's last exponential, alone in the MXUs; since the step walks its heads
-# with a skew of one (`_fwd_step`, PR 64) a head's p v stands beside the next
-# head's softmax tile and the forward schedules at 1,126 to 1,139 bundles a
-# head at H = 7 or 8, the MXUs' slots 88 to 89% taken (1,653 at 256 / 128
-# wide): read on the chip against the parent in one call (PERF.md section 6,
-# PR 64), us a tile forward 1.05 -> 0.92 at H = 7 (28 x 16,384 x 128), 0.98
-# -> 0.84 under the Keye cell's mask at H = 8, 1.16 -> 1.04 under a window of
-# 4,096, 1.57 -> 1.44 at 64 heads under a window of 512, 1.56 -> 1.50 at 32 x
-# 8,192 x 256 / 128, 1.90 -> 1.84 at 32 x 4,096 x 128, bit for bit the
-# parent's.  The same loop WITHOUT the skew (a head's two halves one after the
-# other) schedules at 1,115 to 1,131 (1,581 at 256 / 128) and read 0.5 to
-# 3.5% under the skewed one at six of those seven shapes, the parent's own
-# readings 1.3% apart from call to call: not kept (ISSUE 64 keeps the first
-# form under 1,200), left as the next reading.  A `fori_loop` over the heads,
-# p handed on through a VMEM scratch of two slots, schedules at ~1,250 a head
-# (a body of ~1,230 and ~1,400 of prologue and epilogue a step): struck.  The
-# backward is untouched: 2,590 to 2,780 bundles a head, its five products' own
-# time (`--bundles` prints where each product starts).  H is read from the
-# shapes (`_heads_per_step`, `_bwd_heads_per_step`) and nothing else.
+# the pipeline's bookkeeping (at one head a step a forward tile of 28 x 16,384
+# x 128 read 1.93 us on a v5e, the one-pass backward 2.71).  The heads of a
+# call are independent, so a step takes H of them (`_step_share`), every scratch
+# leading with the heads, and the tile's arithmetic runs over them a head at a
+# time: the backward's whole (`_bwd_step`), the forward's skewed by one
+# (`_fwd_step`).  On the chip (`tools/fa_bwd_probe.py`; PERF.md section 6, PRs
+# 52, 62, 64, 65), us a tile at H = 7: forward 1.93 -> 0.92, backward 2.71 ->
+# 2.31 at H = 4 under one `jax.vmap`, results bit for bit one head a step's.
+# H is read from the shapes (`_heads_per_step`, `_bwd_heads_per_step`) alone.
 HEADS_PER_STEP = 8
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
 def _straddles(heads: int, kv_group: int) -> bool:
-    """Whether a step of ``heads`` query heads straddles KV heads' groups of
-    ``kv_group``: it neither lies inside one group nor holds whole groups."""
+    """Whether a step of ``heads`` query heads neither lies inside one KV head's group of ``kv_group`` nor holds whole groups."""
     return kv_group % heads != 0 and heads % kv_group != 0
 
 
 def _kv_span(heads: int, kv_group: int) -> int:
-    """How many adjacent KV heads the widest of such steps touches: two for
-    four heads of a group of seven, or eight of a group of six."""
+    """How many adjacent KV heads the widest of such steps touches: two for four heads of a group of seven."""
     return 1 + max((b * heads + heads - 1) // kv_group - b * heads // kv_group for b in range(kv_group))
 
 
-def _first_kv_head(b, bh: int, heads: int, kv_group: int, held: int):
-    """The first of the ``held`` adjacent KV heads in a straddling step b's
-    block: its first query head's, and no further than the array's last.
-    (`lax.div` and `lax.min` on the scalars, which are not negative: one
-    operation each where `//` and `jnp.minimum` trace to a dozen.)"""
-    return jax.lax.min(jax.lax.div(b * heads, kv_group), bh // kv_group - held)
+def _kv_held(heads: int, kv_group: int) -> int:
+    """How many KV heads a step's k or v block holds (`_kv_spec`)."""
+    if heads % kv_group == 0:
+        return heads // kv_group
+    return 1 if kv_group % heads == 0 else _kv_span(heads, kv_group)
+
+
+def _first_kv_head(c, q_heads: int, heads: int, kv_group: int, held: int):
+    """The first of the ``held`` adjacent KV heads in the block of a
+    straddling step, the c-th of its batch entry's: its first query head's,
+    and no further than the entry's last.  (`lax.div` and `lax.min` on the
+    scalars, which are not negative: one operation each where `//` and
+    `jnp.minimum` trace to a dozen.)"""
+    return jax.lax.min(jax.lax.div(c * heads, kv_group), q_heads // kv_group - held)
+
+
+def _step_share(batch: int, q_heads: int, masked: bool) -> int:
+    """What the heads of a grid step divide: a batch entry's heads, adjacent column blocks of its rows — or, where an
+    entry has ONE head and so no second column block, the entries (a packed mask's tile is one entry's: one a step)."""
+    return batch if q_heads == 1 and not masked else q_heads
+
+
+def _step_heads(batch: int, q_heads: int, heads: int):
+    """(entries a step's blocks span, the grid's outer steps, step -> (entry block, column block)) for ``heads`` heads
+    a step: ``heads`` column blocks of one entry's rows, or ``heads`` adjacent entries of one head (`_step_share`)."""
+    assert (batch if q_heads == 1 else q_heads) % heads == 0, f"{heads} heads a grid step do not divide {q_heads} (x {batch})"
+    where = functools.partial(_entry_and_block, batch=batch, blocks=max(q_heads // heads, 1))
+    return (heads if q_heads == 1 else 1), batch * q_heads // heads, where
+
+
+def _entry_and_block(b, batch: int, blocks: int):
+    """Grid step b of the outer axis as (batch entry, column block): an entry's ``blocks`` steps follow one another."""
+    return (0, b) if batch == 1 else (jax.lax.div(b, blocks), jax.lax.rem(b, blocks))
 
 
 def _heads_per_step(share: int, most: int = HEADS_PER_STEP) -> int:
-    """The largest divisor of ``share`` (`_heads_share`) not above ``most``:
-    the heads of a grid step."""
+    """The largest divisor of ``share`` not above ``most``: the heads of a
+    grid step, of the batch entry's they have to lie inside (adjacent columns
+    of its rows; under a packed mask its tile is one a step).  Grouped queries
+    set no limit of their own: a step reads as many KV heads as its query
+    heads belong to (`_kv_spec`)."""
     return max(h for h in range(1, min(share, most) + 1) if share % h == 0)
-
-
-def _heads_share(bh: int, mask) -> int:
-    """What the heads of one grid step have to lie inside: a batch entry's
-    heads under a packed mask (its tile is one a step), else the call's
-    batch * heads.  Grouped queries set no limit of their own: a step reads
-    as many KV heads as its query heads belong to (`_kv_spec`)."""
-    return bh if mask is None else bh // mask.shape[0]
 
 
 def _lanes(stat, width: int):
     """A lane-replicated row statistic, [.., rows, 128] with every lane of a
     row the same number, read ``width`` lanes wide: the same array side by
-    side (a `concatenate`, as it has been since the tile ran under `jax.vmap`,
-    for which `pltpu.repeat` has no batching rule), cut where ``width`` is no
-    lane multiple."""
+    side (a `concatenate`), cut where ``width`` is no lane multiple."""
     if width == _LANE:
         return stat
     stat = jnp.concatenate([stat] * -(-width // _LANE), axis=-1)
@@ -384,9 +358,7 @@ def _fwd_scores(q, k, m_prev, l_prev, keep, *, scale: float):
     statistic, and nothing is narrowed to a column to be broadcast again where
     the scores, the accumulator and the scratch want it (`_lanes`).  Every
     row's max, exp, sum and products are what [block_q, 1] statistics
-    compute, bit for bit (tests/test_attention_walks.py keeps that tile);
-    the schedule was 1,320 bundles a head where it had been 1,850, 1.05 us a
-    tile on a v5e where it had been 1.50 (`HEADS_PER_STEP`'s account, PR 62)."""
+    compute, bit for bit (tests/test_attention_walks.py keeps that tile; PR 62)."""
     s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale  # [block_q, block_k] f32
     if keep is not None:
         # Unconditional mask.  A `lax.cond` a block in its place read ~3 ms a
@@ -433,20 +405,6 @@ def _bwd_tile(q, k, v, do, lse, delta, keep, *, scale: float, dkdv: bool, dq: bo
     return out
 
 
-@functools.lru_cache(maxsize=None)
-def _heads_at_once(tile_fn, **static):
-    """``tile_fn`` over the leading axis of its operands, the heads of a grid
-    step, as a function of (keep, *operands): the tile's ``keep`` is one for
-    the step, every other operand is batched, so each product is one batched
-    product with the heads leading.  Jitted, and one object a tile function
-    and its static arguments: the batched trace is made once a process and
-    shape, not once for each time JAX traces a kernel's body (a layer's
-    forward, its recomputation, its transpose — PR 50 read +9 s of set-up
-    without)."""
-    fn = functools.partial(tile_fn, **static)
-    return jax.jit(lambda keep, *operands: jax.vmap(lambda *o: fn(*o, keep))(*operands))
-
-
 def _keep(walk: _Walk, qi, ki, mask_ref):
     """[block_q, block_k] bool, what the queries of tile (qi, ki) see, for
     all the heads of a step: the packed mask's tile (one a batch entry, read
@@ -463,83 +421,97 @@ def _keep(walk: _Walk, qi, ki, mask_ref):
 def _traced_once(fn, **static):
     """``fn`` with its static arguments, jitted, and one object for them: a
     kernel's body that calls it once a head holds one trace of it a shape, and
-    so does every later trace of the body (`_heads_at_once`'s reason)."""
+    so does every later trace of the body (a layer's forward, its
+    recomputation, its transpose: PR 50 read +9 s of set-up without)."""
     return jax.jit(functools.partial(fn, **static))
 
 
-def _kv_head(ref, h: int, heads: int, kv_group: int):
-    """Head h's k or v tile, of a step's ``heads``, read from the step's block
-    of it (`_kv_spec`): the block's own head h, the head of h's group, the one
-    head the step shares, or, where the step straddles groups, the KV head of
-    h's group counted from the block's first, a scalar index."""
+def _head(ref, h: int, heads: int):
+    """Head h of a step's block [1, rows, heads * width]: its column block, a static lane-aligned
+    slice (where an entry is one head, the block is [heads, rows, width] and the head its entry)."""
+    cols = heads // ref.shape[0]
+    width = ref.shape[2] // cols
+    return ref[h // cols, :, h % cols * width:(h % cols + 1) * width]
+
+
+def _store_heads(ref, x, rows=slice(None)) -> None:
+    """x [heads, rows, width] into the block ref, cast to its type, a head where `_head` reads it."""
+    cols, width = x.shape[0] // ref.shape[0], x.shape[2]
+    for h in range(x.shape[0]):
+        ref[h // cols, rows, h % cols * width:(h % cols + 1) * width] = x[h].astype(ref.dtype)
+
+
+def _kv_head(ref, h: int, heads: int, kv_group: int, q_heads: int):
+    """Head h's k or v tile, of a step's ``heads``, out of the step's block (`_kv_spec`: [1, block_k, held * width]):
+    the block's own head h, the head of h's group, the one head the step shares, or, where the step straddles
+    groups, the KV head of h's group counted from the block's first along the lanes."""
     from jax.experimental import pallas as pl
 
-    held = ref.shape[0]
-    if _straddles(heads, kv_group):
-        b = pl.program_id(0)
-        return ref[jax.lax.div(b * heads + h, kv_group) - _first_kv_head(b, pl.num_programs(0) * heads, heads, kv_group, held)]
-    return ref[h * held // heads]
+    held = _kv_held(heads, kv_group)
+    if not _straddles(heads, kv_group):
+        return _head(ref, h * held // heads, held)
+    width = ref.shape[2] // held
+    c = jax.lax.rem(pl.program_id(0), q_heads // heads)
+    at = jax.lax.div(c * heads + h, kv_group) - _first_kv_head(c, q_heads, heads, kv_group, held)
+    return ref[0, :, pl.ds(pl.multiple_of(at * width, width), width)]
 
 
-def _fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale: float, kv_group: int):
+def _fwd_step(keep, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *, scale: float, kv_group: int, q_heads: int):
     """A grid step's forward tiles, the heads walked with a SKEW OF ONE: head
     0's scores half; then, for h = 1 .. H - 1, head h's scores half and head
     h - 1's accumulate half; then head H - 1's accumulate half.  A head reads
-    its own slice of every block and scratch and stores its statistics and
-    accumulator where they are made; ``keep`` is the step's.  The heads share
-    nothing else, so every head's max, exp, sum, rescale and both products
-    are what all heads under one `jax.vmap` computed (and one head a step
-    computes), bit for bit: only their order across heads is written down
+    its own column block of every block and its slice of every scratch;
+    ``keep`` is the step's.  The heads share nothing else, so every head's
+    arithmetic is what all heads under one `jax.vmap` computed (and one head a
+    step computes), bit for bit: only their order across heads is written down
     (tests/test_attention_walks.py keeps the step under `jax.vmap`).
 
-    Why: a head's p v has nothing hanging from it but the accumulator's
-    store.  While the H heads were one batched function the compiler's
-    scheduler let every p v sink to the end of the step: at 28 x 16,384 x 128,
-    seven heads a step, the seven p v stood after the last exponential, back
-    to back at the MXUs' rate with nothing beside them, 3,000 of the tile's
-    9,270 bundles, while the softmax tiles before them kept the VALUs busy
-    and the MXUs at 75% of their slots.  Written a head at a time, a head's
-    p v (MXU) stands beside the next head's softmax tile (VALU, XLU, EUP):
-    7,975 bundles, 1,139 a head for 1,024 cycles of products, the MXUs' slots
-    88% taken, every p v but the last started before the last exponential
-    (`tools/fa_bwd_probe.py --bundles`: `pv_starts`, `last_exp`), and on a
-    v5e 0.92 us a tile where it read 1.05, 0.84 from 0.98 under the Keye
-    cell's mask, 1.04 from 1.16 under a window of 4,096, 1.50 from 1.56 at
-    256 / 128 wide (`HEADS_PER_STEP`'s account, PR 64).  K and V change
-    places in the MXUs once a head, 512 streamed rows a weight load."""
-    heads = q_ref.shape[0]
+    Why: nothing hangs from a head's p v but the accumulator's store, so as
+    one batched function every p v sank to the end of the step, alone in the
+    MXUs after the last exponential.  A head at a time, a head's p v stands
+    beside the next head's softmax tile: 1,139 bundles a head at 28 x 16,384 x
+    128 where all heads at once were 1,322 (PERF.md section 6, PR 64)."""
+    heads = m_scr.shape[0]
     scores, accumulate = _traced_once(_fwd_scores, scale=scale), _traced_once(_fwd_accumulate)
+    kv_head = functools.partial(_kv_head, heads=heads, kv_group=kv_group, q_heads=q_heads)
     before = None  # the head before's rescale and probabilities, on their way to its p v
     for h in range(heads):
-        m_scr[h], alpha, l_scr[h], p = scores(q_ref[h], _kv_head(k_ref, h, heads, kv_group), m_scr[h], l_scr[h], keep)
+        m_scr[h], alpha, l_scr[h], p = scores(_head(q_ref, h, heads), kv_head(k_ref, h), m_scr[h], l_scr[h], keep)
         if before is not None:
-            acc_scr[h - 1] = accumulate(acc_scr[h - 1], *before, _kv_head(v_ref, h - 1, heads, kv_group))
+            acc_scr[h - 1] = accumulate(acc_scr[h - 1], *before, kv_head(v_ref, h - 1))
         before = alpha, p
-    acc_scr[heads - 1] = accumulate(acc_scr[heads - 1], *before, _kv_head(v_ref, heads - 1, heads, kv_group))
+    acc_scr[heads - 1] = accumulate(acc_scr[heads - 1], *before, kv_head(v_ref, heads - 1))
 
 
-def _kv_heads(ref, heads: int, kv_group: int):
-    """A step's k or v with a head each, from the step's block of it
-    (`_kv_spec`).  Grouped queries read their KV heads in place, so the block
-    holds one head for the whole step, one for each group of the step's
-    heads, or, where the step straddles groups, `_kv_span` adjacent KV heads
-    of which every head takes its own by a scalar index (`_kv_head`)."""
-    held = ref.shape[0]
-    if _straddles(heads, kv_group):
-        return jnp.stack([_kv_head(ref, h, heads, kv_group) for h in range(heads)])
-    x = ref[...]
-    if held == heads:
-        return x
-    return jnp.broadcast_to(x, (heads,) + x.shape[1:]) if held == 1 else jnp.repeat(x, heads // held, axis=0)
+def _bwd_step(keep, qi, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, walk: _Walk, scale: float, kv_group: int,
+              q_heads: int, dkdv: bool, dq: bool) -> list:
+    """A grid step's backward tiles, `_bwd_tile`'s products a head: a head reads its own column block of q's and
+    do's blocks, its KV head's of k's and v's (`_kv_head`) and its row of the statistics; ``keep`` is the step's.  A
+    head at a time, as the forward's: under one `jax.vmap` the heads' slices were stacked first, a copy; written out,
+    32 x 4,096 x 128 schedules at 2,500 bundles a head, the MXUs' slots 94% taken, where the batched form's was 2,780
+    at 81% (PERF.md section 6, PR 65).  Bit for bit what one head a step computes."""
+    from jax.experimental import pallas as pl
+
+    heads = lse_ref.shape[0]
+    tile = _traced_once(_bwd_tile, scale=scale, dkdv=dkdv, dq=dq)
+    # The q block's part of the row statistics: they enter the kernels as compact [.., 1, N] rows (4 KB a head
+    # and visit) instead of a lane-padded [.., N, 128] layout (260 KB); the tile turns its part into a column.
+    rows = pl.ds(qi * walk.block_q, walk.block_q)
+    if q_ref.shape[0] == heads > 1:  # entries of one head: the blocks ARE the heads stacked, so one batched product a
+        # step's heads, no copy — at 256 / 128 wide its MXU-bound schedule is the shorter (14,326 bundles against 14,872)
+        tiles = jax.vmap(tile, in_axes=(0,) * 6 + (None,))(q_ref[...], k_ref[...], v_ref[...], do_ref[...], lse_ref[:, :, rows],
+                                                           delta_ref[:, :, rows], keep)
+        return [tuple(t[h] for t in tiles) for h in range(heads)]
+    return [tile(_head(q_ref, h, heads), _kv_head(k_ref, h, heads, kv_group, q_heads), _kv_head(v_ref, h, heads, kv_group, q_heads),
+                 _head(do_ref, h, heads), lse_ref[h, :, rows], delta_ref[h, :, rows], keep) for h in range(heads)]
 
 
-def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group: int = 1):
-    """A grid step: H heads' tile (qi, ki); every block and scratch leads
-    with the heads (k and v with the step's KV heads, `_kv_spec`).
-    ``masked``: a fourth operand, an int8 (block_q, block_k) tile of a
-    per-pair mask (ops/sparse_attention.py), decides what a query sees in
-    place of the causal triangle; ``causal`` still says which tiles are
-    empty."""
+def _fa_kernel(*refs, walk: _Walk, scale: float, q_heads: int, masked: bool = False, kv_group: int = 1):
+    """A grid step: H heads' tile (qi, ki).  q's, k's, v's and the output's blocks are position-major, [1, rows, H *
+    width] with a head a lane-aligned column block (k and v the step's KV heads, `_kv_spec`); the statistics' block and
+    every scratch lead with the heads.  ``masked``: a fourth operand, an int8 (block_q, block_k) tile of a per-pair mask
+    (ops/sparse_attention.py), decides what a query sees in place of the causal triangle; ``causal`` still says which
+    tiles are empty."""
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, *rest) = walk.tile(refs)
@@ -558,16 +530,22 @@ def _fa_kernel(*refs, walk: _Walk, scale: float, masked: bool = False, kv_group:
 
     @pl.when(run)
     def _step():
-        _fwd_step(_keep(walk, qi, ki, mask_ref), q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale, kv_group=kv_group)
+        _fwd_step(_keep(walk, qi, ki, mask_ref), q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale, kv_group=kv_group,
+                  q_heads=q_heads)
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
         l = l_scr[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / _lanes(safe_l, acc_scr.shape[-1])).astype(o_ref.dtype)
-        # lse output is lane-padded to (block_q, _LANE) to satisfy TPU tiling:
-        # the statistics' own form.
-        lse_ref[...] = (m_scr[...] + jnp.log(safe_l)).astype(lse_ref.dtype)
+        _store_heads(o_ref, acc_scr[...] / _lanes(safe_l, acc_scr.shape[-1]))
+        # lse leaves as the backward reads it, a [1, block_q] row a head (lane-padded: 128 times the bytes, and a copy
+        # after) — lane-padded only where `_fa_pallas_call` asks for that
+        lse = m_scr[...] + jnp.log(safe_l)
+        if lse_ref.shape[1] > 1:
+            lse_ref[...] = lse
+        else:
+            for h in range(lse.shape[0]):
+                lse_ref[h] = jnp.transpose(lse[h])[:1]
 
 
 def _tri(i, j):
@@ -576,69 +554,66 @@ def _tri(i, j):
     return i * (i + 1) // 2 + j
 
 
-def _kv_spec(spec, bh: int, heads: int, kv_group: int, block_k: int, width: int):
-    """k's or v's spec for a step of ``heads`` of ``bh`` heads: a head each
-    where the array holds one for every query head; read in place, the KV
-    heads of the whole groups the step holds (block b either way), the one KV
-    head its heads share, or, where the step straddles groups, the `_kv_span`
-    adjacent KV heads from its first head's on, placed by element (and no
-    further than the array's last)."""
+def _kv_spec(spec, where, q_heads: int, heads: int, kv_group: int, block_k: int, width: int):
+    """k's or v's spec, out of [B, S, kv_heads * width], for a step of ``heads`` of a batch entry's ``q_heads``: along
+    the columns the KV heads of the whole groups the step holds, the one KV head its heads share, or, where the step
+    straddles groups, the `_kv_span` adjacent KV heads from its first head's on, placed by element (and no further than
+    the entry's last).  ``where(b)`` is the step's (batch entry, column block)."""
     from jax.experimental import pallas as pl
 
+    held = _kv_held(heads, kv_group)
+    if q_heads == 1:  # an entry a head (and a KV head a head): the step's adjacent entries
+        return spec((heads, block_k, width), lambda b, i, j: (b, j, 0))
     if heads % kv_group == 0:
-        return spec((heads // kv_group, block_k, width), lambda b, i, j: (b, j, 0))
+        return spec((1, block_k, held * width), lambda b, i, j: (where(b)[0], j, where(b)[1]))
     if kv_group % heads == 0:
-        return spec((1, block_k, width), lambda b, i, j: (b * heads // kv_group, j, 0))
-    held = _kv_span(heads, kv_group)
-    return spec((pl.Element(held), pl.Element(block_k), pl.Element(width)),
-                lambda b, i, j: (_first_kv_head(b, bh, heads, kv_group, held), j * block_k, 0))
+        return spec((1, block_k, width), lambda b, i, j: (where(b)[0], j, jax.lax.div(where(b)[1] * heads, kv_group)))
+    return spec((pl.Element(1), pl.Element(block_k), pl.Element(held * width)),
+                lambda b, i, j: (where(b)[0], j * block_k, _first_kv_head(where(b)[1], q_heads, heads, kv_group, held) * width))
 
 
-def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None,
+def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None, *, q_heads: int,
                     kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
-    """``mask``: int8 [batch, tiles, block_q, block_k], the (block_q,
-    block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`),
-    shared by a batch entry's heads (bh = batch * heads); None for the
-    causal triangle.  ``kv_group``: k and v hold one head for every
-    ``kv_group`` of q's (grouped queries read their head in place).
-    ``window``: the band walk, under the name `tpuft_swa_fwd`.
-    ``heads_per_step`` is the probe's and the tests': the program reads H
-    from the shapes, the largest divisor not above `HEADS_PER_STEP` of bh —
-    of a batch entry's heads under a mask, so that the heads of a step share
-    their mask tile; grouped queries set no limit (`_kv_spec`)."""
+    """q [B, S, q_heads * d], k [B, S, kv_heads * d], v [B, S, kv_heads * dv], as the projections leave them ->
+    (out [B, S, q_heads * dv], lse [B * q_heads, S] float32).  ``mask``: int8 [B, tiles, block_q, block_k], the
+    (block_q, block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`), shared by a batch entry's heads;
+    None for the causal triangle.  ``kv_group``: k and v hold one head for every ``kv_group`` of q's (read in place).
+    ``window``: the band walk, under the name `tpuft_swa_fwd`.  ``heads_per_step`` is the probe's and the tests': the
+    program reads H from the shapes, the largest divisor of `_step_share` not above `HEADS_PER_STEP`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, seq_q, d = q.shape  # d: query and key; dv: value and output
-    seq_k, dv = k.shape[1], v.shape[2]
+    batch, seq_q, seq_k = q.shape[0], q.shape[1], k.shape[1]
+    d, dv = q.shape[2] // q_heads, v.shape[2] * kv_group // q_heads  # d: query and key; dv: value and output
     block_q, block_k = _block_sizes(seq_q, seq_k)
-    assert mask is None or (seq_q == seq_k and window is None), "a packed mask is one sequence's lower triangle"
+    assert mask is None or (seq_q == seq_k and window is None and mask.shape[0] == batch), "a packed mask is one sequence's lower triangle"
     walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window)
     spec = walk.spec
-    share = _heads_share(bh, mask)
-    heads = heads_per_step or _heads_per_step(share)
-    assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
+    heads = heads_per_step or _heads_per_step(_step_share(batch, q_heads, mask is not None))
+    across, steps, where = _step_heads(batch, q_heads, heads)
+    by_q = lambda b, i, j: (where(b)[0], i, where(b)[1])  # noqa: E731 — a step's heads' columns of q tile i
     operands, mask_specs = (q, k, v), []
     if mask is not None:
-        per_entry = bh // mask.shape[0]
+        assert across == 1, "a packed mask's tile is one batch entry's"
         operands += (mask,)
-        mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (b * heads // per_entry, _tri(i, j), 0, 0))]
-    out, lse_padded = pl.pallas_call(
-        functools.partial(_fa_kernel, walk=walk, scale=scale, masked=mask is not None, kv_group=kv_group),
+        mask_specs = [spec((1, 1, block_q, block_k), lambda b, i, j: (where(b)[0], _tri(i, j), 0, 0))]
+    out, lse = pl.pallas_call(
+        functools.partial(_fa_kernel, walk=walk, scale=scale, q_heads=q_heads, masked=mask is not None, kv_group=kv_group),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq_q, _LANE), jnp.float32),
+            jax.ShapeDtypeStruct((batch, seq_q, q_heads * dv), q.dtype),
+            jax.ShapeDtypeStruct((batch * q_heads,) + ((1, seq_q) if across == 1 else (seq_q, _LANE)), jnp.float32),
         ),
         grid_spec=walk.grid_spec(
-            bh // heads,
+            steps,
             in_specs=[
-                spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
-                _kv_spec(spec, bh, heads, kv_group, block_k, d),
-                _kv_spec(spec, bh, heads, kv_group, block_k, dv),
+                spec((across, block_q, heads // across * d), by_q),
+                _kv_spec(spec, where, q_heads, heads, kv_group, block_k, d),
+                _kv_spec(spec, where, q_heads, heads, kv_group, block_k, dv),
             ] + mask_specs,
             out_specs=(
-                spec((heads, block_q, dv), lambda b, i, j: (b, i, 0)),
-                spec((heads, block_q, _LANE), lambda b, i, j: (b, i, 0)),
+                spec((across, block_q, heads // across * dv), by_q),
+                spec((heads, 1, block_q), lambda b, i, j: (b, 0, i)) if across == 1 else
+                spec((heads, block_q, _LANE), lambda b, i, j: (b, i, 0)),  # `flash_attention` says why
             ),
             scratch_shapes=[
                 pltpu.VMEM((heads, block_q, _LANE), jnp.float32),  # running max
@@ -650,7 +625,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
         interpret=interpret,
         name="tpuft_dsa_attn_fwd" if mask is not None else "tpuft_fa_fwd" if window is None else "tpuft_swa_fwd",
     )(*walk.tables, *operands)
-    return out, lse_padded[:, :, 0]
+    return out, lse[:, 0] if across == 1 else lse[:, :, 0]
 
 
 # The one-pass backward keeps one head's whole dq row, (seq_q, d_qk) f32, in a
@@ -691,17 +666,28 @@ def _bwd_heads_per_step(share: int, row_bytes: int) -> int:
     return _heads_per_step(share, most)
 
 
-def _row_stats(ref, qi, block_q: int):
-    """The q block's part of a step's row statistics, [H, 1, block_q].  They
-    enter the kernels as compact [.., 1, N] rows (4 KB a head and visit)
-    instead of a lane-padded [.., N, 128] layout (260 KB); the tile turns
-    its part into a column."""
-    from jax.experimental import pallas as pl
-
-    return ref[:, :, pl.ds(qi * block_q, block_q)]
+def heads_indicator(heads: int, width: int):
+    """float32 [heads, heads * width], 1 where a column is the head's: a product with it sums a head's
+    columns, or spreads a number a head over them, in [.., heads * width] as it lies (with the heads
+    an axis of their own, [.., heads, width], XLA re-tiles the array whole, S innermost)."""
+    return jnp.repeat(jnp.eye(heads, dtype=jnp.float32), width, axis=1)  # a constant of the program
 
 
-def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked: bool = False, kv_group: int = 1):
+def _row_delta(g, o, q_heads: int):
+    """delta = rowsum(g * o) a head, [B, S, q_heads * dv] twice -> [B *
+    q_heads, 1, S] float32, as a product with `heads_indicator`, which gives
+    its result S innermost (a sum over [.., q_heads, dv] has g * o re-tiled
+    whole in float32 first: 234 MB a layer at 28 x 16,384 x 128).  Exact term
+    by term: two bfloat16s' product has 16 significant bits, which the
+    three-pass form's two pieces hold."""
+    precision = jax.lax.Precision.HIGH if g.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    delta = jnp.einsum("hc,bsc->bhs", heads_indicator(q_heads, g.shape[2] // q_heads),
+                       g.astype(jnp.float32) * o.astype(jnp.float32), precision=precision)
+    return delta.reshape(-1, 1, g.shape[1])
+
+
+def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, q_heads: int, masked: bool = False,
+                        kv_group: int = 1):
     """Flash backward with the q axis innermost (a ``kv_major`` walk), H
     heads a grid step: dk/dv accumulate in VMEM scratch across the q tiles
     over one kv tile.  With ``with_dq`` (the one-pass form) a third scratch
@@ -717,7 +703,6 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
     block_q = walk.block_q
     mask_ref = rest[0] if masked else None  # as `_fa_kernel`'s
     dk_ref, dv_ref, *rest = rest[1:] if masked else rest
-    heads = q_ref.shape[0]
     if with_dq:
         dq_ref, dk_scr, dv_scr, dq_scr = rest
         q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
@@ -735,28 +720,29 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
 
     @pl.when(run)
     def _step():
-        dv_tile, dk_tile, *dq_tile = _heads_at_once(_bwd_tile, scale=scale, dkdv=True, dq=with_dq)(
-            _keep(walk, qi, ki, mask_ref), q_ref[...], _kv_heads(k_ref, heads, kv_group),
-            _kv_heads(v_ref, heads, kv_group), do_ref[...],
-            _row_stats(lse_ref, qi, block_q), _row_stats(delta_ref, qi, block_q))
-        dv_scr[...] += dv_tile                      # p^T @ do: [H, block_k, d_v]
-        dk_scr[...] += dk_tile                      # ds^T @ q: [H, block_k, d]
+        tiles = _bwd_step(_keep(walk, qi, ki, mask_ref), qi, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, walk=walk, scale=scale,
+                          kv_group=kv_group, q_heads=q_heads, dkdv=True, dq=with_dq)
+        for h, (dv_tile, dk_tile, *_) in enumerate(tiles):
+            dv_scr[h] += dv_tile                    # p^T @ do: [block_k, d_v]
+            dk_scr[h] += dk_tile                    # ds^T @ q: [block_k, d]
         if with_dq:
             # Every q block runs against its first kv block (block 0 without
             # a window), causal or not, so the first visit assigns and the row
             # is never zeroed.
             @pl.when(ki == walk.first_k(qi))
             def _first():
-                dq_scr[:, q_rows, :] = dq_tile[0]   # ds @ k: [H, block_q, d]
+                for h, tile in enumerate(tiles):
+                    dq_scr[h, q_rows, :] = tile[2]  # ds @ k: [block_q, d]
 
             @pl.when(ki != walk.first_k(qi))
             def _add():
-                dq_scr[:, q_rows, :] += dq_tile[0]
+                for h, tile in enumerate(tiles):
+                    dq_scr[h, q_rows, :] += tile[2]
 
     @pl.when(qi == walk.last_q(ki))
     def _emit():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        _store_heads(dk_ref, dk_scr[...])
+        _store_heads(dv_ref, dv_scr[...])
 
     if with_dq:
         # Rows qi are complete once the last kv tile they see has had its
@@ -765,10 +751,10 @@ def _fa_bwd_dkdv_kernel(*refs, walk: _Walk, scale: float, with_dq: bool, masked:
         # on a rectangle a turn the causal rule may have skipped.
         @pl.when(ki == walk.last_k(qi))
         def _emit_dq():
-            dq_ref[:, q_rows, :] = dq_scr[:, q_rows, :].astype(dq_ref.dtype)
+            _store_heads(dq_ref, dq_scr[:, q_rows, :], q_rows)
 
 
-def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, kv_group: int = 1):
+def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, q_heads: int, kv_group: int = 1):
     """dq-only second pass, kv axis innermost, for a dq row too long to
     stay in VMEM: dq accumulates one (block_q, d) f32 block a head at a
     time, so memory stays O(block) whatever the length (at the price of
@@ -776,7 +762,6 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, kv_group: int = 1):
     from jax.experimental import pallas as pl
 
     qi, ki, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr) = walk.tile(refs)
-    heads = q_ref.shape[0]
 
     @pl.when(ki == walk.first_k(qi))
     def _init():
@@ -784,81 +769,74 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, kv_group: int = 1):
 
     @pl.when(walk.visible(qi, ki))
     def _step():
-        dq_scr[...] += _heads_at_once(_bwd_tile, scale=scale, dkdv=False, dq=True)(
-            _keep(walk, qi, ki, None), q_ref[...], _kv_heads(k_ref, heads, kv_group),
-            _kv_heads(v_ref, heads, kv_group), do_ref[...],
-            _row_stats(lse_ref, qi, walk.block_q), _row_stats(delta_ref, qi, walk.block_q))[0]
+        tiles = _bwd_step(_keep(walk, qi, ki, None), qi, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, walk=walk, scale=scale,
+                          kv_group=kv_group, q_heads=q_heads, dkdv=False, dq=True)
+        for h, (dq_tile,) in enumerate(tiles):
+            dq_scr[h] += dq_tile
 
     @pl.when(ki == walk.last_k(qi))
     def _emit():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+        _store_heads(dq_ref, dq_scr[...])
 
 
-def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bool = False, mask=None,
-                   kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
-    """Flash backward on TPU; q/k: [BH, S, D], v/o/g: [BH, S, Dv], lse:
-    [BH, S] f32.  One kernel (`tpuft_fa_bwd_dkdv_dq`) where one head's f32
-    dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
-    `tpuft_fa_bwd_dq`.  ``mask`` as `_fa_pallas_call`'s: the masked backward
-    is the one-pass kernel only, under the name `tpuft_dsa_attn_bwd_dkdv_dq`;
-    with ``kv_group`` k and v are read in place and dk, dv come out a query
-    head each, for the caller to sum over a group.  ``window``: the band
-    walk, the same kernels as `tpuft_swa_bwd_dkdv_dq` (`tpuft_swa_bwd_dkdv`,
-    `tpuft_swa_bwd_dq`).
+def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bool = False, mask=None, *,
+                   q_heads: int, kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
+    """Flash backward on TPU; q, k, v as `_fa_pallas_call`'s and o, g [B, S, q_heads * dv] as it gives them, lse
+    [B * q_heads, S] f32 -> dq in q's form, dk [B, S, q_heads * d] and dv [B, S, q_heads * dv].  One kernel
+    (`tpuft_fa_bwd_dkdv_dq`) where one head's f32 dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
+    `tpuft_fa_bwd_dq`.  ``mask``: the masked backward is the one-pass kernel only, under the name
+    `tpuft_dsa_attn_bwd_dkdv_dq`; with ``kv_group`` k and v are read in place and dk, dv come out a query head each,
+    for the caller to sum over a group (`group_sum`).  ``window``: the band walk, the same kernels as
+    `tpuft_swa_bwd_dkdv_dq` (`_bwd_dkdv`, `_bwd_dq`).
 
     A grid step carries H heads (``heads_per_step`` is the probe's and the
     tests'): the largest divisor of what `_fa_pallas_call` divides, not above
-    `HEADS_PER_STEP`, such that H heads' dq rows — ``seq_q * d * (4 + 2 *
-    itemsize)`` bytes each, the f32 scratch and the double-buffered output
-    block; none in the two-pass form — and H times `_TILE_VMEM_BYTES` fit
-    `_VMEM_BUDGET`: four at 4,096 x 128, two at 16,384 x 128, 8,192 or 16,384
-    x 256 and 32,768 x 128, one where two rows do not fit (65,536 x 128)."""
+    `HEADS_PER_STEP`, such that H heads' dq rows (`_row_vmem_bytes`; none in the
+    two-pass form) and H times `_TILE_VMEM_BYTES` fit `_VMEM_BUDGET`: four at
+    4,096 x 128, two at 32,768 x 128, one at 65,536 x 128."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, seq_q, d = q.shape
-    seq_k, d_v = k.shape[1], v.shape[2]
+    batch, seq_q, seq_k = q.shape[0], q.shape[1], k.shape[1]
+    d, d_v = q.shape[2] // q_heads, g.shape[2] // q_heads
     block_q, block_k = _block_sizes(seq_q, seq_k)
     causal = causal or mask is not None
     family = "tpuft_fa" if window is None else "tpuft_swa"
     one_pass = _dq_row_resident(seq_q, d)
     row_bytes = _row_vmem_bytes(seq_q, d, q.dtype.itemsize) if one_pass else 0
-    share = _heads_share(bh, mask)
-    heads = heads_per_step or _bwd_heads_per_step(share, row_bytes)
-    assert share % heads == 0, f"{heads} heads a grid step do not divide {share}"
-    # Row stats as [BH, 1, S]: whole row per visit (4 KB).  delta_i =
+    heads = heads_per_step or _bwd_heads_per_step(_step_share(batch, q_heads, mask is not None), row_bytes)
+    across, steps, where = _step_heads(batch, q_heads, heads)
+    cols = heads // across
+    # Row stats as [B * H, 1, S]: whole row per visit (4 KB).  delta_i =
     # rowsum(do * o) is O(S*D) and computed once here instead of per tile.
     lse = lse[:, None, :]
-    delta = jnp.sum(
-        g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )[:, None, :]
+    delta = _row_delta(g, o, q_heads)
 
     def specs(walk):
         """The six operands' specs and dk's and dv's, by tile, for a walk."""
         spec = walk.spec
         row = spec((heads, 1, seq_q), lambda b, i, j: (b, 0, 0))
+        by_q = lambda b, i, j: (where(b)[0], i, where(b)[1])  # noqa: E731 — a step's heads' columns of q tile i
+        by_k = lambda b, i, j: (where(b)[0], j, where(b)[1])  # noqa: E731
         return [
-            spec((heads, block_q, d), lambda b, i, j: (b, i, 0)),
-            _kv_spec(spec, bh, heads, kv_group, block_k, d),
-            _kv_spec(spec, bh, heads, kv_group, block_k, d_v),
-            spec((heads, block_q, d_v), lambda b, i, j: (b, i, 0)),
+            spec((across, block_q, cols * d), by_q),
+            _kv_spec(spec, where, q_heads, heads, kv_group, block_k, d),
+            _kv_spec(spec, where, q_heads, heads, kv_group, block_k, d_v),
+            spec((across, block_q, cols * d_v), by_q),
             row, row,
-        ], [
-            spec((heads, block_k, d), lambda b, i, j: (b, j, 0)),
-            spec((heads, block_k, d_v), lambda b, i, j: (b, j, 0)),
-        ]
+        ], [spec((across, block_k, cols * d), by_k), spec((across, block_k, cols * d_v), by_k)]
 
     walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True, window=window)
     in_specs, out_specs = specs(walk)
     operands = (q, k, v, g, lse, delta)
     if mask is not None:
-        assert one_pass and seq_q == seq_k and window is None, "the masked backward keeps one sequence's dq row in VMEM"
-        per_entry = bh // mask.shape[0]
+        assert one_pass and seq_q == seq_k and window is None and mask.shape[0] == batch and across == 1, \
+            "the masked backward keeps one sequence's dq row in VMEM, its mask's tile one batch entry's"
         operands += (mask,)
-        in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (b * heads // per_entry, _tri(i, j), 0, 0)))
+        in_specs.append(walk.spec((1, 1, block_q, block_k), lambda b, i, j: (where(b)[0], _tri(i, j), 0, 0)))
     out_shape = [
-        jax.ShapeDtypeStruct((bh,) + k.shape[1:], k.dtype),
-        jax.ShapeDtypeStruct((bh,) + v.shape[1:], v.dtype),
+        jax.ShapeDtypeStruct((batch, seq_k, q_heads * d), k.dtype),
+        jax.ShapeDtypeStruct((batch, seq_k, q_heads * d_v), v.dtype),
     ]
     scratch = [
         pltpu.VMEM((heads, block_k, d), jnp.float32),
@@ -868,16 +846,16 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
         # dq's block is the heads' whole rows and ignores the tile: it leaves
         # VMEM once, when the step's heads are done.
         out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
-        out_specs.append(walk.spec((heads, seq_q, d), lambda b, i, j: (b, 0, 0)))
+        out_specs.append(walk.spec((across, seq_q, cols * d), lambda b, i, j: (where(b)[0], 0, where(b)[1])))
         scratch.append(pltpu.VMEM((heads, seq_q, d), jnp.float32))
     vmem_limit = heads * (row_bytes + _TILE_VMEM_BYTES)
     outs = pl.pallas_call(
         functools.partial(
-            _fa_bwd_dkdv_kernel, walk=walk, scale=scale, with_dq=one_pass, masked=mask is not None,
+            _fa_bwd_dkdv_kernel, walk=walk, scale=scale, with_dq=one_pass, q_heads=q_heads, masked=mask is not None,
             kv_group=kv_group,
         ),
         out_shape=tuple(out_shape),
-        grid_spec=walk.grid_spec(bh // heads, in_specs, tuple(out_specs), scratch),
+        grid_spec=walk.grid_spec(steps, in_specs, tuple(out_specs), scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=walk.semantics("parallel"),
             vmem_limit_bytes=vmem_limit,
@@ -897,10 +875,10 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     walk = _Walk(causal, seq_q, seq_k, block_q, block_k, window=window)
     in_specs, _ = specs(walk)
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale, kv_group=kv_group),
+        functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale, q_heads=q_heads, kv_group=kv_group),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=walk.grid_spec(
-            bh // heads, in_specs, in_specs[0], [pltpu.VMEM((heads, block_q, d), jnp.float32)]),
+            steps, in_specs, in_specs[0], [pltpu.VMEM((heads, block_q, d), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=family + "_bwd_dq",
@@ -930,25 +908,43 @@ def _fa_reference(q, k, v, scale: float, causal: bool, window: Optional[int] = N
     return o.astype(q.dtype), lse
 
 
-def _fa_forward(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[int]):
-    if kernel:  # k and v with their own KV heads, read in place
-        return _fa_pallas_call(q, k, v, scale, causal, window=window, kv_group=q.shape[0] // k.shape[0])
-    return _fa_reference(q, k, v, scale, causal, window)
+def _to_heads(x, heads: int):
+    """[B, S, heads * d] -> [B * heads, S, d]: the XLA formulation's form."""
+    return x.reshape(x.shape[:2] + (heads, -1)).transpose(0, 2, 1, 3).reshape(x.shape[0] * heads, x.shape[1], -1)
 
 
-def group_sum(t, group: int):
-    """[heads * group, ...], a query head each, -> [heads, ...]: a KV head's
-    gradient is its query heads' summed, in float32 and rounded once."""
+def _from_heads(x, heads: int):
+    """[B * heads, S, d] -> [B, S, heads * d]."""
+    return x.reshape((-1, heads) + x.shape[1:]).transpose(0, 2, 1, 3).reshape(x.shape[0] // heads, x.shape[1], -1)
+
+
+def _fa_forward(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int]):
+    """(out, lse [B * q_heads, S]) of q, k, v [B, S, heads * width], ``heads``
+    the (query, KV) heads: the kernels read them as they are, the XLA
+    formulation turns to head-major and back inside itself."""
+    if kernel:
+        return _fa_pallas_call(q, k, v, scale, causal, window=window, q_heads=heads[0], kv_group=heads[0] // heads[1])
+    o, lse = _fa_reference(_to_heads(q, heads[0]), _to_heads(k, heads[1]), _to_heads(v, heads[1]), scale, causal, window)
+    return _from_heads(o, heads[0]), lse
+
+
+def group_sum(t, heads: int, group: int):
+    """[B, S, heads * group * width], a query head each, -> [B, S, heads *
+    width]: a KV head's gradient is its query heads' summed, in float32 and
+    rounded once."""
     if group == 1:
         return t
-    return jnp.sum(t.reshape((-1, group) + t.shape[1:]).astype(jnp.float32), axis=1).astype(t.dtype)
+    width = t.shape[2] // (heads * group)  # column blocks added as they lie: [.., heads, group, width] is re-tiled whole
+    blocks = [t[:, :, h * width:(h + 1) * width].astype(jnp.float32) for h in range(heads * group)]
+    return jnp.concatenate([sum(blocks[kv * group + 1:(kv + 1) * group], blocks[kv * group]) for kv in range(heads)],
+                           axis=2).astype(t.dtype)
 
 
 # `kernel` is decided once, in flash_attention, from the shapes and the mesh
 # of the program being traced, so forward and backward cannot disagree.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[int]):
-    o, _ = _fa_forward(q, k, v, scale, causal, kernel, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int]):
+    o, _ = _fa_forward(q, k, v, heads, scale, causal, kernel, window)
     return o
 
 
@@ -959,21 +955,24 @@ def _flash(q, k, v, scale: float, causal: bool, kernel: bool, window: Optional[i
 SAVED_NAMES = ("tpuft_fa_out", "tpuft_fa_lse")
 
 
-def _flash_fwd(q, k, v, scale, causal, kernel, window):
+def _flash_fwd(q, k, v, heads, scale, causal, kernel, window):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fa_forward(q, k, v, scale, causal, kernel, window)
+    o, lse = _fa_forward(q, k, v, heads, scale, causal, kernel, window)
     o, lse = checkpoint_name(o, SAVED_NAMES[0]), checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, kernel, window, res, g):
+def _flash_bwd(heads, scale, causal, kernel, window, res, g):
     q, k, v, o, lse = res
+    q_heads, kv_heads = heads
     if kernel:
-        group = q.shape[0] // k.shape[0]
-        dq, dk, dv = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window, kv_group=group)
-        return dq, group_sum(dk, group), group_sum(dv, group)
-    return _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window)
+        group = q_heads // kv_heads
+        dq, dk, dv = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window, q_heads=q_heads, kv_group=group)
+        return dq, group_sum(dk, kv_heads, group), group_sum(dv, kv_heads, group)
+    dq, dk, dv = _fa_bwd_xla(_to_heads(q, q_heads), _to_heads(k, kv_heads), _to_heads(v, kv_heads), _to_heads(o, q_heads),
+                             lse, _to_heads(g, q_heads), scale, causal, window)
+    return _from_heads(dq, q_heads), _from_heads(dk, kv_heads), _from_heads(dv, kv_heads)
 
 
 def _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window: Optional[int] = None):
@@ -1005,9 +1004,9 @@ def flash_attention(
     mesh=None,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Multi-head attention; q: [B, Hq, S, D], k: [B, Hkv, S, D], v:
-    [B, Hkv, S, Dv] -> [B, Hq, S, Dv].  Dv may differ from D (MLA: 192 for
-    query and key, 128 for value); the default scale is D ** -0.5.
+    """Multi-head attention, position-major as the projections leave it; q:
+    [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] -> [B, S, Hq, Dv].
+    Dv may differ from D (MLA: 192 / 128); the default scale is D ** -0.5.
 
     GQA: Hkv may divide Hq.  The kernels read a KV head in place for its
     group of query heads (no repeated copy of k or v in HBM, forward or
@@ -1019,29 +1018,25 @@ def flash_attention(
     ``window`` (causal only): a query at t sees the keys s with ``0 <= t - s
     < window``.  One that covers the sequence is no window.
     """
-    b, hq, sq, d = q.shape
+    b, sq, hq, d = q.shape
     if window is not None:
-        assert causal and sq == k.shape[2] and window > 0, "a window is causal over one sequence"
+        assert causal and sq == k.shape[1] and window > 0, "a window is causal over one sequence"
         if window >= sq:
             window = None
-    hkv, dv = k.shape[1], v.shape[3]
+    hkv, dv = k.shape[2], v.shape[3]
     assert hq % hkv == 0, "query heads must be a multiple of kv heads"
     scale = scale if scale is not None else d ** -0.5
-    kernel = _use_pallas(sq, k.shape[2], dv, mesh)
-    if hkv != hq and not kernel:
-        k, v = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
+    kernel = _use_pallas(sq, k.shape[1], dv, mesh)
+    if hkv != hq and (not kernel or d % _LANE):  # the XLA formulation, and heads folded into the batch: a KV head a head
+        k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
     if kernel and d % _LANE:
-        # Zero columns up to the next lane multiple: nothing in a score.
+        # padded, and the heads folded into the batch: the kernels' form of before PR 65 whole — blocks that lead with the
+        # heads, lse lane-padded, the backward one batched product.  Moonlight read -2.5% with its heads side by side (30
+        # copies of 134 MB) and -1.8% folded but with lse as rows and the backward a head at a time: XLA had hidden the
+        # prefetch of the experts' 100 MiB of rows into fast memory behind the lse copy (PERF.md section 6, PR 65 (8))
         pad = [(0, 0)] * 3 + [(0, -d % _LANE)]
-        q, k = jnp.pad(q, pad), jnp.pad(k, pad)
-        d = q.shape[3]
-    out = _flash(
-        q.reshape(b * hq, sq, d),
-        k.reshape(-1, k.shape[2], d),
-        v.reshape(-1, v.shape[2], dv),
-        scale,
-        causal,
-        kernel,
-        window,
-    )
-    return out.reshape(b, hq, sq, dv)
+        q, k, v = (t.reshape((b * hq,) + t.shape[2:]) for t in (jnp.pad(q.transpose(0, 2, 1, 3), pad), jnp.pad(k.transpose(0, 2, 1, 3), pad),
+                                                                 v.transpose(0, 2, 1, 3)))
+        return _flash(q, k, v, (1, 1), scale, causal, kernel, window).reshape(b, hq, sq, dv).transpose(0, 2, 1, 3)
+    out = _flash(*(t.reshape(t.shape[:2] + (-1,)) for t in (q, k, v)), (hq, k.shape[2]), scale, causal, kernel, window)
+    return out.reshape(b, sq, hq, dv)

@@ -370,6 +370,8 @@ def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, hea
 
     # (every step of the walk is a tile with a visible pair)
     group = q_heads // kv_heads
+    d = q_ref.shape[2] // q_heads  # q's and k's blocks are [1, rows, heads * d]: head h the lane-aligned column block at h * d
+    head = lambda ref, h: ref[0, :, pl.ds(pl.multiple_of(h * d, d), d)]  # noqa: E731
 
     def some_heads(i, total):
         # `heads_a_body` heads a loop body, added in ascending order: head
@@ -378,7 +380,7 @@ def _index_loss_kernel(*refs, walk, q_heads: int, kv_heads: int, heads: int, hea
         for u in range(heads_a_body):
             h = i * heads_a_body + u
             s = jax.lax.dot_general(
-                q_ref[0, h], k_ref[0, h // group], (((1,), (1,)), ((), ())),
+                head(q_ref, h), head(k_ref, jax.lax.div(h, group)), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             lse = jnp.transpose(lse_ref[0, h, 0:1, pl.ds(pl.multiple_of(qi * bq, bq), bq)], (1, 0))  # [bq, 1]
             total = total + jnp.exp(s - lse)
@@ -421,7 +423,7 @@ def _heads_a_body(q_heads: int) -> int:
 
 def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bool = False,
                        heads_a_body: int | None = None):
-    """q [B, H, S, D], k [B, KV, S, D], lse [B, H, S] -> (kl rows [B, S],
+    """q [B, S, H, D], k [B, S, KV, D], lse [B, H, S] -> (kl rows [B, S],
     d loss/d a [B, J, S, Di] f32, d loss/d bt [B, Di, S] f32, d loss/d w
     [B, S, J] f32) of loss = sum(kl rows) / (B * S).  `heads_a_body` is for
     tests and tools/dsa_probe.py (1 is the loop of one head a body); the
@@ -429,8 +431,8 @@ def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bo
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    batch, q_heads, seq, d = q.shape
-    kv_heads, heads, di = k.shape[1], a.shape[1], a.shape[3]
+    batch, seq, q_heads, d = q.shape
+    kv_heads, heads, di = k.shape[2], a.shape[1], a.shape[3]
     heads_a_body = heads_a_body or _heads_a_body(q_heads)
     assert q_heads % heads_a_body == 0, (q_heads, heads_a_body)
     walk = _walk(seq)
@@ -449,8 +451,8 @@ def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bo
         grid_spec=walk.grid_spec(
             batch,
             in_specs=[
-                spec((1, q_heads, bq, d), lambda b, i, j: (b, 0, i, 0)),
-                spec((1, kv_heads, bk, d), lambda b, i, j: (b, 0, j, 0)),
+                spec((1, bq, q_heads * d), lambda b, i, j: (b, i, 0)),
+                spec((1, bk, kv_heads * d), lambda b, i, j: (b, j, 0)),
                 spec((1, q_heads, 1, seq), lambda b, i, j: (b, 0, 0, 0)),   # whole rows, as the flash kernels'
                 spec((1, heads, bq, di), lambda b, i, j: (b, 0, i, 0)),
                 spec((1, di, bk), lambda b, i, j: (b, 0, j)),
@@ -470,7 +472,7 @@ def _index_loss_pallas(q, k, lse, a, bt, w, z, mask, scale: float, interpret: bo
             dimension_semantics=walk.semantics("arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="tpuft_dsa_index_loss",
-    )(*walk.tables, q, k, lse[:, :, None, :], a, bt, w, z, mask)
+    )(*walk.tables, q.reshape(batch, seq, -1), k.reshape(batch, seq, -1), lse[:, :, None, :], a, bt, w, z, mask)
     return kl[:, :, 0], da, dbt, dw
 
 
@@ -501,10 +503,12 @@ def _scatter_rows(idx, seq: int):
 
 def _dsa_xla(q, k, v, a, bt, w, topk: int, scale: float):
     """The module docstring's mathematics with dense scores; differentiated
-    by autodiff.  q [B, H, S, D], k/v [B, KV, S, D]."""
-    batch, q_heads, seq, _ = q.shape
-    group = q_heads // k.shape[1]
+    by autodiff.  q [B, S, H, D], k/v [B, S, KV, D], turned to head-major in
+    here, and the output back."""
+    batch, seq, q_heads, _ = q.shape
+    group = q_heads // k.shape[2]
     with jax.named_scope("attn"):
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     with jax.named_scope("dsa_index"):
         scores = index_scores(a, bt, w)
@@ -514,7 +518,7 @@ def _dsa_xla(q, k, v, a, bt, w, topk: int, scale: float):
     with jax.named_scope("attn"):
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
         p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v).astype(q.dtype)
+        out = jnp.einsum("bhqk,bhkd->bqhd", p.astype(v.dtype), v).astype(q.dtype)
     with jax.named_scope("dsa_index"):
         pbar = jax.lax.stop_gradient(jnp.mean(p, axis=1))                     # [B, S, S]
         log_q = jax.nn.log_softmax(jnp.where(keep[:, 0], scores, -jnp.inf), axis=-1)
@@ -527,21 +531,23 @@ def _dsa_xla(q, k, v, a, bt, w, topk: int, scale: float):
 
 
 def _masked_flash_fwd(q, k, v, mask, scale, interpret: bool = False):
-    batch, q_heads, seq, d = q.shape
-    flat = lambda t: t.reshape(-1, seq, d)  # noqa: E731
+    """q [B, S, H, D], k/v [B, S, KV, D] -> (out [B, S, H, D], lse [B, H, S])."""
+    batch, seq, q_heads, _ = q.shape
+    flat = lambda t: t.reshape(batch, seq, -1)  # noqa: E731 — a head a column block, the kernels' form
     o, lse = _fa._fa_pallas_call(flat(q), flat(k), flat(v), scale, True, interpret=interpret, mask=mask,
-                                 kv_group=q_heads // k.shape[1])
+                                 q_heads=q_heads, kv_group=q_heads // k.shape[2])
     return o.reshape(q.shape), lse.reshape(batch, q_heads, seq)
 
 
 def _masked_flash_bwd(q, k, v, o, lse, g, mask, scale, interpret: bool = False):
-    batch, q_heads, seq, d = q.shape
-    group = q_heads // k.shape[1]
-    flat = lambda t: t.reshape(-1, seq, d)  # noqa: E731
+    (batch, seq, q_heads, _), kv_heads = q.shape, k.shape[2]
+    group = q_heads // kv_heads
+    flat = lambda t: t.reshape(batch, seq, -1)  # noqa: E731
     dq, dk, dv = _fa._fa_bwd_pallas(
         flat(q), flat(k), flat(v), flat(o), lse.reshape(batch * q_heads, seq), flat(g), scale, True,
-        interpret=interpret, mask=mask, kv_group=group)
-    return dq.reshape(q.shape), _fa.group_sum(dk, group).reshape(k.shape), _fa.group_sum(dv, group).reshape(v.shape)
+        interpret=interpret, mask=mask, q_heads=q_heads, kv_group=group)
+    return (dq.reshape(q.shape), _fa.group_sum(dk, kv_heads, group).reshape(k.shape),
+            _fa.group_sum(dv, kv_heads, group).reshape(v.shape))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -611,14 +617,14 @@ def sparse_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, index_q: jax.Array, index_k: jax.Array, index_w: jax.Array,
     *, topk: int, scale: float | None = None, mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """q [B, H, S, D], k/v [B, KV, S, D]; index_q [B, J, S, Di] and index_k
-    [B, S, Di] (both after RoPE), index_w [B, S, J] f32 with the indexer's
-    scale factors in it -> (out [B, H, S, D], the index loss (a scalar: the
+    """q [B, S, H, D], k/v [B, S, KV, D] as `flash_attention`'s; index_q
+    [B, J, S, Di] and index_k [B, S, Di] (both after RoPE), index_w [B, S, J] f32 with the
+    indexer's scale factors in it -> (out [B, S, H, D], the index loss (a scalar: the
     mean over batch and positions of the KL term), the number of selected
     pairs (int32)).  See the module docstring for what is differentiated
     with respect to what."""
-    batch, q_heads, seq, d = q.shape
-    assert q_heads % k.shape[1] == 0, "query heads must be a multiple of kv heads"
+    batch, seq, q_heads, d = q.shape
+    assert q_heads % k.shape[2] == 0, "query heads must be a multiple of kv heads"
     scale = scale if scale is not None else d ** -0.5
     with jax.named_scope("dsa_index"):
         bt = index_k.transpose(0, 2, 1)  # [B, Di, S]: a key tile is a lane-aligned slice

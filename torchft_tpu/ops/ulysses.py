@@ -44,7 +44,8 @@ def ulysses_attention(
     q = a2a(q, split_axis=1, concat_axis=2)
     k = a2a(k, split_axis=1, concat_axis=2)
     v = a2a(v, split_axis=1, concat_axis=2)
-    out = flash_attention(q, k, v, causal=causal, scale=scale)
+    # `flash_attention` is position-major: turned to here, at this form's own boundary
+    out = flash_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=causal, scale=scale).transpose(0, 2, 1, 3)
     # [B, H/n, S, D] -> [B, H, S_local, D]
     return a2a(out, split_axis=2, concat_axis=1)
 
