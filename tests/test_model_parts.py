@@ -114,6 +114,14 @@ MODELS["ouro"] = TransformerConfig(**dict(
     _BASE, n_heads=4, n_kv_heads=4, head_dim=16, remat=True, scan_unroll=8, loop_steps=3, exit_beta=0.05,
     pattern=(LayerKind("layers", False, 4, 1e6, post_norms=True),) * 2))
 PINNED = PINNED + ("ouro",)
+# The twelfth: Keye's body without its indexer under the block-diffusion objective — a noised and a clean copy of every
+# sequence in one stream of 2 x 64 positions in blocks of 4, the three-part mask, the head over the noised half under a
+# weight a row (SDAR's shape).  Pinned by PR 66, which brought it.
+MODELS["sdar"] = TransformerConfig(**dict(
+    _BASE, head_dim=32, qk_norm_per_head=True, moe_experts=8, moe_top_k=2, d_ff=48, moe_capacity_factor=None,
+    moe_held=(2, 2), moe_aux_coef=0.001, remat=True, remat_keeps_attention=True, rope_theta=1e6, bd_block_length=4,
+    bd_noise_seed=66))
+PINNED = PINNED + ("sdar",)
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
@@ -184,6 +192,8 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
         expected |= {"dsa_index", "dsa_select"}
     if cfg.exit_beta is not None:
         expected |= {"exit_gate"}
+    if cfg.bd_block_length is not None:  # the attention call over the doubled stream has a name of its own
+        expected = expected - {"attn"} | {"bd_noise", "bd_attn"}
     assert parts == expected
     directions = {d for p, d in named if p is not None}
     assert directions == ({"fwd", "bwd", "recompute"} if cfg.remat else {"fwd", "bwd"})
@@ -248,7 +258,7 @@ def _digest(step, params, batch, program: str, grads_text=None) -> str:
 
 
 def record(commit: str) -> None:
-    """Records the twenty-two digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    """Records the twenty-four digests anew (`python tests/test_model_parts.py "<commit and why>"`,
     `JAX_PLATFORMS=cpu`): for a PR that changes the ten gradient programs on
     purpose.  The update programs are no model code's to change, so theirs
     have to come out as they were."""
@@ -285,7 +295,8 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     (window and full attention mixed) with them, since PR 48 the seventh and
     since PR 61, which made a mixer an entry of `models/mixers.MIXERS`, all ten
     (each recorded from the tree BEFORE the change it guards); the eleventh, a
-    looped model, from the PR that brought it (63).  A PR that
+    looped model, and the twelfth, one trained by block diffusion, from the PRs
+    that brought them (63, 66).  A PR that
     changes these programs on purpose records them anew:
     `tests/data/hlo_before_the_pattern.json`."""
     import json

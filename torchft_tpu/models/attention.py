@@ -90,7 +90,7 @@ def _attention(cfg, mesh, q, k, v, kind):
             seq_axis="sequence",
             **kwargs,
         ).transpose(0, 2, 1, 3)
-    return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window)
+    return flash_attention(q, k, v, causal=True, mesh=mesh, window=kind.window, block_length=cfg.bd_block_length)
 
 
 def _mla_qkv(cfg, kind, h, w, positions):
@@ -250,7 +250,8 @@ def _forward(qkv):
         if cfg.dsa_index_heads:
             attn, dsa = _sparse_attention(cfg, mesh, h, w, positions, q, k, v)
         else:
-            with jax.named_scope("attn" if kind.window is None else "attn_window"):
+            scope = "bd_attn" if cfg.bd_block_length is not None else "attn" if kind.window is None else "attn_window"
+            with jax.named_scope(scope):
                 attn = _attention(cfg, mesh, q, k, v, kind)  # [B, S, H, Dv]
         with jax.named_scope("attn_proj"):
             d_v = attn.shape[-1]

@@ -36,6 +36,7 @@ from torchft_tpu.models.attention import _index_operands  # noqa: F401 — bench
 from torchft_tpu.models.mixer import Mixer, _norm_init
 from torchft_tpu.models.mixers import MIXERS
 from torchft_tpu.ops import rms_norm
+from torchft_tpu.ops.attention import bd_pairs_walked
 from torchft_tpu.parallel.sharding import ShardingRules, constrain
 
 
@@ -233,6 +234,13 @@ class TransformerConfig:
     # leaves after pass t with p_t = lambda_t prod_{j<t} (1 - lambda_j), the last pass takes what is left, and the
     # loss is mean_i [sum_t p_t loss_t - exit_beta H(p)].  None: no gate, the (last) state's mean cross-entropy.
     exit_beta: Optional[float] = None
+    # Block-diffusion training (BD3-LM, arXiv:2503.09573, as SDAR, arXiv:2510.06303, adapts it): the objective is
+    # `_block_diffusion_loss` — a noised and a clean copy of every sequence in one stream of 2 x S positions under a
+    # three-part block mask (ops/attention.py `_bd_visible`), a 1/t-weighted cross-entropy over the masked tokens —
+    # with blocks of `bd_block_length` tokens; None: next-token cross-entropy over one causal stream.  The noise
+    # is a function of the batch's ids and `bd_noise_seed` alone (`block_diffusion_noise`).
+    bd_block_length: Optional[int] = None
+    bd_noise_seed: int = 0
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -283,6 +291,12 @@ class TransformerConfig:
             assert all(kind.feed_forward and not kind.sparse and kind.mixer != "none"
                        for kind in self.pattern if kind.post_norms), "post-norms: a mixer and a dense feed-forward"
         assert self.loop_steps >= 1 and (self.exit_beta is None or self.loop_steps > 1), "the exit gate is a looped model's"
+        if self.bd_block_length is not None:
+            assert self.bd_block_length > 0 and self.attention == "flash" and not self.dsa_index_heads and self.loop_steps == 1, (
+                "block diffusion runs the flash backend's mask over a plain stack, without an indexer")
+            assert all(kind.window is None and kind.mixer in ("attention", "mla", "cca", "none") for kind in self.layers), (
+                "a doubled stream's mixers are softmax attention over all of the past: no window, no recurrence")
+            assert not self.moe_router_state, "a router's state is carried along one causal stream"
         if self.loop_steps > 1:
             assert not (self.moe_experts or self.dsa_index_heads or self.moe_router_state or self.tied_head), (
                 "the layers that run several times are dense ones under an untied head: no statistics a layer")
@@ -619,6 +633,8 @@ def _decoder(
         pos = jnp.asarray(
             zigzag_permutation(S, mesh.shape["sequence"]), dtype=jnp.int32
         )
+    if cfg.bd_block_length is not None:  # the stream is [noised copy | clean copy]: a token's place in its sequence, twice
+        pos = pos % (S // 2)
     with jax.named_scope("attn_proj"):  # RoPE's operand
         positions = jnp.broadcast_to(pos, (B, S))
 
@@ -943,14 +959,17 @@ def lm_head_losses(params: Dict[str, Any], h: jax.Array, cfg: TransformerConfig,
     norm and its mean, for a loss that weighs the rows itself.  On one TPU
     device the `tpuft_ce_*` kernels (`fused_linear_cross_entropy_per_row`:
     the backward reads a cotangent a row); elsewhere the plain XLA form."""
-    from torchft_tpu.ops.cross_entropy import fused_ce_applicable, fused_linear_cross_entropy_per_row, head_row_block
+    from torchft_tpu.ops.cross_entropy import (
+        fused_ce_applicable, fused_linear_cross_entropy_per_row, fused_linear_cross_entropy_per_row_padded, head_row_block,
+        padded_vocab)
 
     B, S, E = h.shape
-    whole = head_row_block(B * S, cfg.vocab_size) is None and cfg.vocab_size % 128 == 0
+    whole = head_row_block(B * S, padded_vocab(cfg.vocab_size)) is None and not cfg.tied_head
     with jax.named_scope("head_loss"):
-        if whole and fused_ce_applicable(B * S, E, cfg.vocab_size, mesh):
-            return fused_linear_cross_entropy_per_row(
-                h.reshape(B * S, E), params["lm_head"].astype(cfg.dtype), targets.reshape(B * S))
+        if whole and fused_ce_applicable(B * S, E, padded_vocab(cfg.vocab_size), mesh):
+            # a vocabulary slice that no block divides: the same kernels over zero-padded columns (`lm_head_loss`)
+            fused = fused_linear_cross_entropy_per_row if cfg.vocab_size % 128 == 0 else fused_linear_cross_entropy_per_row_padded
+            return fused(h.reshape(B * S, E), params["lm_head"].astype(cfg.dtype), targets.reshape(B * S))
         logits = head(params, h, cfg, mesh, rules, normed=True)
         picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         return (jax.nn.logsumexp(logits, axis=-1) - picked).reshape(B * S)
@@ -985,6 +1004,70 @@ def _looped_loss(params: Dict[str, Any], states: jax.Array, cfg: TransformerConf
         counters = {"loop_exit_mass": jnp.sum(p, axis=1), "loop_pass_loss": jnp.mean(losses, axis=1),
                     "loop_exit_entropy": jnp.mean(entropy)}
     return loss, counters
+
+
+_BD_GRID = 23  # levels and draws live on float32's uniform grid, the multiples of 2**-23
+_BD_EPS = round(1e-3 * 2 ** _BD_GRID)  # the linear schedule's first thousandth (LLaDA's and BD3-LM's eps), on the grid
+
+
+def block_diffusion_noise(tokens: jax.Array, block_length: int, noise_seed: int) -> Tuple[jax.Array, jax.Array]:
+    """(masked [B, S] bool, level [B, S] float32) of a batch of token ids [B, S]: every block of ``block_length``
+    consecutive tokens of a sequence draws a level ``t = eps + (1 - eps) u``, u ~ U[0, 1), eps = 1e-3, and each of
+    its tokens is masked independently with probability t (``v < t``, v ~ U[0, 1)): a masked token's weight 1 / t
+    is at most 1,000.  u and v are 23 random bits over 2**23 (what `jax.random.uniform` draws in float32) under a
+    key that is a function of the SEQUENCE'S ids and ``noise_seed`` alone — the seed's key folded with a 32-bit sum
+    of the ids weighted by position — so a step that runs again (a failed commit vote, a heal's replay) and the
+    plain reference see the same masked batch, whatever else the batch holds.  The level is worked out in INTEGERS,
+    rounded down to the grid: a product and a sum in float32 are one rounding or two as a backend fuses them, an
+    ulp apart inside a jitted step and outside one."""
+    B, S = tokens.shape
+    assert S % block_length == 0, "a sequence is whole blocks"
+
+    def one(ids):
+        place = jnp.arange(S, dtype=jnp.uint32) * jnp.uint32(2654435761) + jnp.uint32(1)
+        k_level, k_token = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(noise_seed), jnp.sum(ids.astype(jnp.uint32) * place)))
+        u = jax.random.bits(k_level, (S // block_length,), jnp.uint32) >> (32 - _BD_GRID)
+        # T = EPS + u - ceil(u EPS / 2**23), from EPS to 2**23 - 1.  u EPS passes 32 bits, so u goes in as its
+        # high 11 and low 12 bits: ceil((u1 2**12 + u0) EPS / 2**23) = (u1 EPS + (u0 EPS + 2**23 - 1 >> 12)) >> 11.
+        up = ((u >> 12) * _BD_EPS + (((u & jnp.uint32(0xFFF)) * _BD_EPS + (2 ** _BD_GRID - 1)) >> 12)) >> 11
+        level = jnp.repeat(_BD_EPS + u - up, block_length)
+        draw = jax.random.bits(k_token, (S,), jnp.uint32) >> (32 - _BD_GRID)
+        return draw < level, level.astype(jnp.float32) * 2.0 ** -_BD_GRID  # both exact: a level is under 2**24
+
+    return jax.vmap(one)(tokens)
+
+
+def block_diffusion_stream(tokens: jax.Array, cfg: TransformerConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(stream [B, 2 S] int32, masked [B, S] bool, weight [B, S] float32) of a batch of token ids [B, S]: the
+    noised copy — a masked token is the vocabulary's LAST id, which stands for [MASK]; the mask itself is carried as
+    booleans, so a data token with that id is an ordinary token — then the clean copy; and the loss's weight a
+    token, 1 / t where it is masked and 0 where it is not."""
+    with jax.named_scope("bd_noise"):
+        masked, level = block_diffusion_noise(tokens, cfg.bd_block_length, cfg.bd_noise_seed)
+        weight = jnp.where(masked, 1.0 / level, 0.0)
+        return jnp.concatenate([jnp.where(masked, cfg.vocab_size - 1, tokens), tokens], axis=1), masked, weight
+
+
+def _block_diffusion_loss(params: Dict[str, Any], tokens: jax.Array, cfg: TransformerConfig, mesh=None,
+                          rules: Optional[ShardingRules] = None, router_bias: Optional[jax.Array] = None):
+    """The block-diffusion objective of a batch of token ids [B, S], and the decoder's statistics: the stream
+    ``[noised copy | clean copy]`` of 2 S positions (`block_diffusion_stream`) through the decoder once, positions
+    ``0 .. S - 1`` twice, then the head over the NOISED half alone, position i predicting token i (no shift), each row
+    under its weight: ``sum_i m_i / t_i * CE_i / (B S)``.  Counters: `bd_masked_share` (masked tokens over data tokens:
+    near 1/2), `bd_weight_mean` (the mean of m / t: near 1, so a schedule that drifts shows) and `bd_live_pairs_share`
+    (the visible (query, key) pairs under the attention walk's steps, `ops/attention.py` `bd_pairs_walked`, over all
+    (2 S)**2: (S**2 + S b) / (2 S)**2 where the walk has every live tile)."""
+    B, S = tokens.shape
+    stream, masked, weight = block_diffusion_stream(tokens, cfg)
+    x, aux = _decoder(params, stream, cfg, mesh, rules, router_bias)
+    with jax.named_scope("head_loss"):
+        h = rms_norm(x[:, :S], params["final_norm"], cfg.rms_eps)
+    losses = lm_head_losses(params, h, cfg, tokens, mesh, rules)
+    with jax.named_scope("head_loss"):
+        loss = jnp.sum(weight.reshape(B * S) * losses) / (B * S)
+        counters = {"bd_masked_share": jnp.mean(masked.astype(jnp.float32)), "bd_weight_mean": jnp.mean(weight),
+                    "bd_live_pairs_share": jnp.float32(bd_pairs_walked(2 * S, cfg.bd_block_length) / (2 * S) ** 2)}
+    return loss, aux, counters
 
 
 def loss_fn(
@@ -1027,11 +1110,14 @@ def loss_and_counters(
     `_looped_loss` lists; for a dense model nothing.  ``router_bias`` [n_sparse_layers,
     n_experts] is the sigmoid router's choice bias: a constant, no leaf of
     ``params``, so neither the gradient nor the optimizer sees it."""
-    x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
-    if cfg.loop_steps > 1:
-        return _looped_loss(params, x, cfg, batch["targets"], mesh, rules)
-    loss = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
-    counters = {}
+    if cfg.bd_block_length is not None:  # `batch["targets"]`, the tokens one place on, is not read
+        loss, aux, counters = _block_diffusion_loss(params, batch["tokens"], cfg, mesh, rules, router_bias)
+    else:
+        x, aux = _decoder(params, batch["tokens"], cfg, mesh, rules, router_bias)
+        if cfg.loop_steps > 1:
+            return _looped_loss(params, x, cfg, batch["targets"], mesh, rules)
+        loss = lm_head_loss(params, x, cfg, batch["targets"], mesh, rules)
+        counters = {}
     with jax.named_scope("head_loss"):  # the loss's other terms and the counters
         if cfg.dsa_index_heads:
             # The indexer's own loss: no weight outside the indexer has a gradient from it.

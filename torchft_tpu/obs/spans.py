@@ -192,10 +192,16 @@ SUBSPANS = {
 #   exit_gate — a looped model's exit-weighted loss: the gate's product on
 #     every pass's state, the exit distribution, the combination of the
 #     passes' losses, the entropy term and the three counters (the passes'
-#     heads and the final norm between passes stay head_loss).
+#     heads and the final norm between passes stay head_loss);
+#   bd_noise — block-diffusion training's noise: the sequences' keys, the
+#     blocks' levels, the tokens' mask, the weights 1 / t and the doubled
+#     stream of ids (`_block_diffusion_loss`); bd_attn — the attention call
+#     over that stream (the `tpuft_bd_*` kernels: the live tiles of the
+#     three-part block mask), so a trace tells it from `attn`.
 PARTS = (
     "embed", "norm", "attn_proj", "cca_mix", "kda_mix", "kda_scan", "attn", "attn_window", "dsa_index", "dsa_select",
     "ffn", "router", "experts", "shared_expert", "head_loss", "stack", "ssm_mix", "ssm_scan", "exit_gate",
+    "bd_noise", "bd_attn",
 )
 
 

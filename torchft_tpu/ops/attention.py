@@ -133,6 +133,59 @@ def _block_sizes(seq_q: int, seq_k: int) -> Tuple[int, int]:
     return min(512, seq_q), min(512, seq_k)
 
 
+def _bd_visible(rows, cols, half: int, block: int):
+    """What a query at ``rows`` sees of the keys at ``cols`` (int32, shapes that
+    broadcast) in a block-diffusion stream of ``2 * half`` positions — the
+    noised copy of ``half`` data tokens, then the clean copy — with blocks of
+    ``block`` tokens, B(p) = (p mod half) // block: a noised query sees the
+    noised keys of its own block and the clean keys of the blocks before it, a
+    clean query the clean keys of its own block and before, and no noised key.
+    Inside a block attention runs both ways.  As two comparisons: a clean key
+    counts as its block, a noised one as ``half // block`` more; a query sees
+    the keys up to ``reach`` (its block, less one where it is noised: clean
+    keys alone lie there) and the one number ``own`` (its own noised block)."""
+    shift = block.bit_length() - 1 if block & (block - 1) == 0 else None
+
+    def block_of(p):
+        return jax.lax.shift_right_logical(p, shift) if shift is not None else jax.lax.div(p, block)
+
+    blocks = half // block
+    row_noised, col_noised = rows < half, cols < half
+    row_block = block_of(jnp.where(row_noised, rows, rows - half))
+    key = block_of(jnp.where(col_noised, cols, cols - half)) + jnp.where(col_noised, blocks, 0)
+    reach = row_block - row_noised.astype(jnp.int32)
+    own = jnp.where(row_noised, row_block + blocks, -1)
+    return (key <= reach) | (key == own)
+
+
+def bd_pairs_walked(seq: int, block_length: int) -> int:
+    """The visible pairs under the steps of the walk `flash_attention(..., block_length=block_length)` takes over a
+    stream of ``seq`` positions (the tiles the kernels use; one tile where they do not apply, as the XLA form)."""
+    bq, bk = _block_sizes(seq, seq)
+    if seq % bq or seq % bk:
+        bq = bk = seq
+    return _Walk(True, seq, seq, bq, bk, block_length=block_length).pairs_seen()
+
+
+def _bd_tiles(half: int, block: int, r0, rows: int, c0, cols: int):
+    """bool, r0's shape: whether the tile of ``rows`` queries from r0 and
+    ``cols`` keys from c0 (numpy int arrays) holds a pair `_bd_visible` keeps.
+    A tile may straddle the halves: its noised and its clean rows and columns
+    are taken apart, and a part's blocks are a range."""
+    import numpy as np
+
+    r1, c1 = r0 + rows - 1, c0 + cols - 1
+    rows_noised, rows_clean, cols_noised, cols_clean = r0 < half, r1 >= half, c0 < half, c1 >= half
+    noised_rows = (r0 // block, np.minimum(r1, half - 1) // block)         # first and last block, where there are any
+    noised_cols = (c0 // block, np.minimum(c1, half - 1) // block)
+    last_clean_row = (r1 - half) // block
+    first_clean_col = (np.maximum(c0, half) - half) // block
+    own = rows_noised & cols_noised & (noised_rows[0] <= noised_cols[1]) & (noised_cols[0] <= noised_rows[1])
+    before = rows_noised & cols_clean & (first_clean_col < noised_rows[1])
+    causal = rows_clean & cols_clean & (first_clean_col <= last_clean_row)
+    return own | before | causal
+
+
 @dataclasses.dataclass(frozen=True)
 class _Walk:
     """The order in which a kernel's grid visits its (q tile, kv tile) pairs,
@@ -143,7 +196,11 @@ class _Walk:
     ``kv_major`` walks column by column (the q axis innermost) instead of
     row by row.  Tiles need not be square (ops/sparse_attention.py's are
     256 x 512).  ``window`` (triangular walks only) keeps of those tiles the
-    ones with a pair ``0 <= t - s < window``: the band."""
+    ones with a pair ``0 <= t - s < window``: the band.  ``block_length``: the
+    sequence is a block-diffusion stream (`_bd_visible`) and the tiles are the
+    ones that hold a pair visible under ITS rule — no longer a triangle: the
+    clean queries' tiles over the noised keys have no step, nor have the
+    noised-noised tiles off the block diagonal."""
 
     causal: bool
     seq_q: int
@@ -152,9 +209,18 @@ class _Walk:
     block_k: int
     kv_major: bool = False
     window: Optional[int] = None
+    block_length: Optional[int] = None
 
     def __post_init__(self) -> None:
         assert self.window is None or (self.triangular and self.window > 0), "a window is causal over one sequence"
+        if self.block_length is not None:
+            assert self.triangular and self.window is None and self.seq_q % (2 * self.block_length) == 0, (
+                "a block-diffusion stream is one sequence of two halves of whole blocks")
+
+    @property
+    def half(self) -> int:
+        """The data tokens of a block-diffusion stream: its noised copy's positions, and its clean copy's."""
+        return self.seq_q // 2
 
     @property
     def triangular(self) -> bool:
@@ -177,12 +243,33 @@ class _Walk:
         if not self.triangular:
             return ()
         qi, ki = np.indices((self.num_q, self.num_k), dtype=np.int32)
-        visible = ki * self.block_k <= qi * self.block_q + self.block_q - 1
+        if self.block_length is not None:
+            visible = _bd_tiles(self.half, self.block_length, qi * self.block_q, self.block_q, ki * self.block_k, self.block_k)
+        else:
+            visible = ki * self.block_k <= qi * self.block_q + self.block_q - 1
         if self.window is not None:  # the nearest pair of the tile, (first row, last column), is inside
             visible &= qi * self.block_q - (ki * self.block_k + self.block_k - 1) < self.window
         if self.kv_major:
             return qi.T[visible.T], ki.T[visible.T]
         return qi[visible], ki[visible]
+
+    def pairs_seen(self) -> int:
+        """The (query, key) pairs a block-diffusion walk's steps cover that the rule keeps: over the tables' tiles,
+        row by row, the tile's columns among the row's clean keys (the clean half up to its block's start, or its
+        block's end for a clean row) and among its own block's noised keys.  The whole rule has
+        ``half ** 2 + half * block_length`` of them: a walk short of a live tile reads less."""
+        import numpy as np
+
+        qi, ki = (np.asarray(t, np.int64) for t in self.tables)
+        half, b = self.half, self.block_length
+        rows = qi[:, None] * self.block_q + np.arange(self.block_q)
+        c0 = ki[:, None] * self.block_k
+        c1 = c0 + self.block_k
+        noised = rows < half
+        start = (rows - np.where(noised, 0, half)) // b * b  # the row's block's first token
+        clean = np.minimum(c1, half + start + np.where(noised, 0, b)) - np.maximum(c0, half)
+        own = np.where(noised, np.minimum(c1, start + b) - np.maximum(c0, start), 0)
+        return int(np.maximum(clean, 0).sum() + np.maximum(own, 0).sum())
 
     def grid(self, outer: int) -> tuple:
         if self.triangular:
@@ -230,15 +317,36 @@ class _Walk:
 
     def last_k(self, qi):
         """The last kv tile a walk visits under q tile qi."""
+        if self.block_length is not None:
+            # the tile's last row's last key: a clean row's own block's end in the clean half, a noised row's
+            # block's start there — or, in the stream's first block, which sees no clean key, its own block's end
+            # (a tile that straddles the halves has both kinds of row: its last noised row may see further)
+            b, half, r1 = self.block_length, self.half, qi * self.block_q + self.block_q - 1
+            last_noised = jnp.minimum(r1, half - 1)
+            noised = jnp.where(last_noised >= b, half + last_noised // b * b - 1, b - 1)
+            clean = half + ((r1 - half) // b + 1) * b - 1
+            straddles = qi * self.block_q < half
+            return jnp.where(r1 >= half, jnp.where(straddles, jnp.maximum(noised, clean), clean), noised) // self.block_k
         return (qi * self.block_q + self.block_q - 1) // self.block_k if self.triangular else self.num_k - 1
 
     def first_q(self, ki):
         """The first q tile a walk visits over kv tile ki."""
+        if self.block_length is not None:
+            # a noised key's first query is its own block's first; a clean key's the noised block after its own
+            # or, for the last block, its own block's first clean query
+            b, half, c0 = self.block_length, self.half, ki * self.block_k
+            after = (jnp.maximum(c0, half) - half) // b * b + b
+            clean = jnp.where(after < half, after, after - b + half)
+            straddles = c0 + self.block_k > half
+            return jnp.where(c0 < half, jnp.where(straddles, jnp.minimum(c0 // b * b, clean), c0 // b * b), clean) // self.block_q
         return (ki * self.block_k) // self.block_q if self.triangular else 0
 
     def first_k(self, qi):
         """The first kv tile a walk visits under q tile qi: the one that
         holds the oldest key its first row still sees."""
+        if self.block_length is not None:  # a noised row's own block's first noised key; a clean row's, the clean half's first
+            r0 = qi * self.block_q
+            return jnp.where(r0 < self.half, r0 // self.block_length * self.block_length, self.half) // self.block_k
         if self.window is None:
             return 0
         return jnp.maximum(qi * self.block_q - (self.window - 1), 0) // self.block_k
@@ -246,6 +354,9 @@ class _Walk:
     def last_q(self, ki):
         """The last q tile a walk visits over kv tile ki: the one that holds
         the latest query that still sees its last key."""
+        if self.block_length is not None:  # a clean key's: the stream's last; a noised key's: its own block's last
+            c1 = ki * self.block_k + self.block_k - 1
+            return jnp.where(c1 >= self.half, self.seq_q - 1, (c1 // self.block_length + 1) * self.block_length - 1) // self.block_q
         if self.window is None:
             return self.num_q - 1
         return jnp.minimum((ki * self.block_k + self.block_k - 1 + self.window - 1) // self.block_q, self.num_q - 1)
@@ -254,7 +365,13 @@ class _Walk:
         """[block_q, block_k] bool: the pairs of tile (qi, ki) a causal query
         sees.  Under a window ``t - s`` is read as unsigned, so that one
         comparison drops what lies above the diagonal (negative: a huge
-        number) and what lies below the band."""
+        number) and what lies below the band.  A block-diffusion stream's
+        rule (`_bd_visible`) is worked on one column of rows and one row of
+        columns, and broadcast by the last two comparisons."""
+        if self.block_length is not None:
+            rows = qi * self.block_q + jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+            cols = ki * self.block_k + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+            return _bd_visible(rows, cols, self.half, self.block_length)
         rows = qi * self.block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         cols = ki * self.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         if self.window is None:
@@ -573,13 +690,15 @@ def _kv_spec(spec, where, q_heads: int, heads: int, kv_group: int, block_k: int,
 
 
 def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False, mask=None, *, q_heads: int,
-                    kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
+                    kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None,
+                    block_length: Optional[int] = None):
     """q [B, S, q_heads * d], k [B, S, kv_heads * d], v [B, S, kv_heads * dv], as the projections leave them ->
     (out [B, S, q_heads * dv], lse [B * q_heads, S] float32).  ``mask``: int8 [B, tiles, block_q, block_k], the
     (block_q, block_k) tiles of a per-pair mask's lower triangle row by row (`_tri`), shared by a batch entry's heads;
     None for the causal triangle.  ``kv_group``: k and v hold one head for every ``kv_group`` of q's (read in place).
-    ``window``: the band walk, under the name `tpuft_swa_fwd`.  ``heads_per_step`` is the probe's and the tests': the
-    program reads H from the shapes, the largest divisor of `_step_share` not above `HEADS_PER_STEP`."""
+    ``window``: the band walk, under the name `tpuft_swa_fwd`.  ``block_length``: the sequence is a block-diffusion
+    stream and the walk its live tiles' (`_Walk`), under the name `tpuft_bd_fwd`.  ``heads_per_step`` is the probe's and
+    the tests': the program reads H from the shapes, the largest divisor of `_step_share` not above `HEADS_PER_STEP`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -587,7 +706,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
     d, dv = q.shape[2] // q_heads, v.shape[2] * kv_group // q_heads  # d: query and key; dv: value and output
     block_q, block_k = _block_sizes(seq_q, seq_k)
     assert mask is None or (seq_q == seq_k and window is None and mask.shape[0] == batch), "a packed mask is one sequence's lower triangle"
-    walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window)
+    walk = _Walk(causal or mask is not None, seq_q, seq_k, block_q, block_k, window=window, block_length=block_length)
     spec = walk.spec
     heads = heads_per_step or _heads_per_step(_step_share(batch, q_heads, mask is not None))
     across, steps, where = _step_heads(batch, q_heads, heads)
@@ -623,7 +742,7 @@ def _fa_pallas_call(q, k, v, scale: float, causal: bool, interpret: bool = False
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(heads * _TILE_VMEM_BYTES, _VMEM_BUDGET)),
         interpret=interpret,
-        name="tpuft_dsa_attn_fwd" if mask is not None else "tpuft_fa_fwd" if window is None else "tpuft_swa_fwd",
+        name="tpuft_dsa_attn_fwd" if mask is not None else _family(window, block_length) + "_fwd",
     )(*walk.tables, *operands)
     return out, lse[:, 0] if across == 1 else lse[:, :, 0]
 
@@ -644,6 +763,11 @@ _TILE_VMEM_BYTES = 16 * 2**20
 # blocks and their tiles: a v5e's VMEM.  Compiled and run at the whole of it
 # (PERF.md section 6, PR 52): four heads of 16,384 x 128 and of 8,192 x 256.
 _VMEM_BUDGET = 128 * 2**20
+
+
+def _family(window: Optional[int], block_length: Optional[int]) -> str:
+    """The kernels' name by the walk, so that a trace tells the layers' kinds apart."""
+    return "tpuft_bd" if block_length is not None else "tpuft_fa" if window is None else "tpuft_swa"
 
 
 def _dq_row_resident(seq_q: int, d: int) -> bool:
@@ -780,14 +904,15 @@ def _fa_bwd_dq_kernel(*refs, walk: _Walk, scale: float, q_heads: int, kv_group: 
 
 
 def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bool = False, mask=None, *,
-                   q_heads: int, kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None):
+                   q_heads: int, kv_group: int = 1, window: Optional[int] = None, heads_per_step: Optional[int] = None,
+                   block_length: Optional[int] = None):
     """Flash backward on TPU; q, k, v as `_fa_pallas_call`'s and o, g [B, S, q_heads * dv] as it gives them, lse
     [B * q_heads, S] f32 -> dq in q's form, dk [B, S, q_heads * d] and dv [B, S, q_heads * dv].  One kernel
     (`tpuft_fa_bwd_dkdv_dq`) where one head's f32 dq row fits `_DQ_ROW_VMEM_BUDGET`, else `tpuft_fa_bwd_dkdv` and then
     `tpuft_fa_bwd_dq`.  ``mask``: the masked backward is the one-pass kernel only, under the name
     `tpuft_dsa_attn_bwd_dkdv_dq`; with ``kv_group`` k and v are read in place and dk, dv come out a query head each,
     for the caller to sum over a group (`group_sum`).  ``window``: the band walk, the same kernels as
-    `tpuft_swa_bwd_dkdv_dq` (`_bwd_dkdv`, `_bwd_dq`).
+    `tpuft_swa_bwd_dkdv_dq` (`_bwd_dkdv`, `_bwd_dq`); ``block_length``: a block-diffusion stream's, as `tpuft_bd_bwd_*`.
 
     A grid step carries H heads (``heads_per_step`` is the probe's and the
     tests'): the largest divisor of what `_fa_pallas_call` divides, not above
@@ -801,7 +926,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     d, d_v = q.shape[2] // q_heads, g.shape[2] // q_heads
     block_q, block_k = _block_sizes(seq_q, seq_k)
     causal = causal or mask is not None
-    family = "tpuft_fa" if window is None else "tpuft_swa"
+    family = _family(window, block_length)
     one_pass = _dq_row_resident(seq_q, d)
     row_bytes = _row_vmem_bytes(seq_q, d, q.dtype.itemsize) if one_pass else 0
     heads = heads_per_step or _bwd_heads_per_step(_step_share(batch, q_heads, mask is not None), row_bytes)
@@ -826,7 +951,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
             row, row,
         ], [spec((across, block_k, cols * d), by_k), spec((across, block_k, cols * d_v), by_k)]
 
-    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True, window=window)
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, kv_major=True, window=window, block_length=block_length)
     in_specs, out_specs = specs(walk)
     operands = (q, k, v, g, lse, delta)
     if mask is not None:
@@ -872,7 +997,7 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     dk, dv = outs
 
     # Second pass for a row over the budget: dq with the kv axis innermost.
-    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, window=window)
+    walk = _Walk(causal, seq_q, seq_k, block_q, block_k, window=window, block_length=block_length)
     in_specs, _ = specs(walk)
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, walk=walk, scale=scale, q_heads=q_heads, kv_group=kv_group),
@@ -886,20 +1011,22 @@ def _fa_bwd_pallas(q, k, v, o, lse, g, scale: float, causal: bool, interpret: bo
     return dq, dk, dv
 
 
-def _visible(seq_q: int, seq_k: int, window: Optional[int]):
-    """[seq_q, seq_k] bool: what a causal query sees, whole."""
+def _visible(seq_q: int, seq_k: int, window: Optional[int], block_length: Optional[int] = None):
+    """[seq_q, seq_k] bool: what a causal query sees, whole — or a block-diffusion stream's (`_bd_visible`)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (seq_q, seq_k), 1)
+    if block_length is not None:
+        return _bd_visible(rows, cols, seq_q // 2, block_length)
     if window is None:
         return rows >= cols
     return (rows >= cols) & (rows - cols < window)
 
 
-def _fa_reference(q, k, v, scale: float, causal: bool, window: Optional[int] = None):
+def _fa_reference(q, k, v, scale: float, causal: bool, window: Optional[int] = None, block_length: Optional[int] = None):
     """Stable XLA attention returning (out, lse); q/k: [BH, S, D], v: [BH, S, Dv]."""
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
     if causal:
-        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window), s, _NEG_INF)
+        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window, block_length), s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -918,13 +1045,16 @@ def _from_heads(x, heads: int):
     return x.reshape((-1, heads) + x.shape[1:]).transpose(0, 2, 1, 3).reshape(x.shape[0] // heads, x.shape[1], -1)
 
 
-def _fa_forward(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int]):
+def _fa_forward(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int],
+                block_length: Optional[int] = None):
     """(out, lse [B * q_heads, S]) of q, k, v [B, S, heads * width], ``heads``
     the (query, KV) heads: the kernels read them as they are, the XLA
     formulation turns to head-major and back inside itself."""
     if kernel:
-        return _fa_pallas_call(q, k, v, scale, causal, window=window, q_heads=heads[0], kv_group=heads[0] // heads[1])
-    o, lse = _fa_reference(_to_heads(q, heads[0]), _to_heads(k, heads[1]), _to_heads(v, heads[1]), scale, causal, window)
+        return _fa_pallas_call(q, k, v, scale, causal, window=window, q_heads=heads[0], kv_group=heads[0] // heads[1],
+                               block_length=block_length)
+    o, lse = _fa_reference(_to_heads(q, heads[0]), _to_heads(k, heads[1]), _to_heads(v, heads[1]), scale, causal, window,
+                           block_length)
     return _from_heads(o, heads[0]), lse
 
 
@@ -942,9 +1072,10 @@ def group_sum(t, heads: int, group: int):
 
 # `kernel` is decided once, in flash_attention, from the shapes and the mesh
 # of the program being traced, so forward and backward cannot disagree.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int]):
-    o, _ = _fa_forward(q, k, v, heads, scale, causal, kernel, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: bool, window: Optional[int],
+           block_length: Optional[int] = None):
+    o, _ = _fa_forward(q, k, v, heads, scale, causal, kernel, window, block_length)
     return o
 
 
@@ -955,33 +1086,34 @@ def _flash(q, k, v, heads: Tuple[int, int], scale: float, causal: bool, kernel: 
 SAVED_NAMES = ("tpuft_fa_out", "tpuft_fa_lse")
 
 
-def _flash_fwd(q, k, v, heads, scale, causal, kernel, window):
+def _flash_fwd(q, k, v, heads, scale, causal, kernel, window, block_length):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _fa_forward(q, k, v, heads, scale, causal, kernel, window)
+    o, lse = _fa_forward(q, k, v, heads, scale, causal, kernel, window, block_length)
     o, lse = checkpoint_name(o, SAVED_NAMES[0]), checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(heads, scale, causal, kernel, window, res, g):
+def _flash_bwd(heads, scale, causal, kernel, window, block_length, res, g):
     q, k, v, o, lse = res
     q_heads, kv_heads = heads
     if kernel:
         group = q_heads // kv_heads
-        dq, dk, dv = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window, q_heads=q_heads, kv_group=group)
+        dq, dk, dv = _fa_bwd_pallas(q, k, v, o, lse, g, scale, causal, window=window, q_heads=q_heads, kv_group=group,
+                                    block_length=block_length)
         return dq, group_sum(dk, kv_heads, group), group_sum(dv, kv_heads, group)
     dq, dk, dv = _fa_bwd_xla(_to_heads(q, q_heads), _to_heads(k, kv_heads), _to_heads(v, kv_heads), _to_heads(o, q_heads),
-                             lse, _to_heads(g, q_heads), scale, causal, window)
+                             lse, _to_heads(g, q_heads), scale, causal, window, block_length)
     return _from_heads(dq, q_heads), _from_heads(dk, kv_heads), _from_heads(dv, kv_heads)
 
 
-def _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window: Optional[int] = None):
+def _fa_bwd_xla(q, k, v, o, lse, g, scale, causal, window: Optional[int] = None, block_length: Optional[int] = None):
     """Off-TPU backward: same math with the scores materialized in XLA.
     Also the oracle the pallas backward kernels are tested against."""
     qf, kf, vf, gf = (t.astype(jnp.float32) for t in (q, k, v, g))
     s = jnp.einsum("bqd,bkd->bqk", qf, kf) * scale
     if causal:
-        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window), s, _NEG_INF)
+        s = jnp.where(_visible(s.shape[-2], s.shape[-1], window, block_length), s, _NEG_INF)
     p = jnp.exp(s - lse[..., None])                     # recompute softmax
     dv = jnp.einsum("bqk,bqd->bkd", p, gf)
     dp = jnp.einsum("bqd,bkd->bqk", gf, vf)
@@ -1003,6 +1135,7 @@ def flash_attention(
     scale: float | None = None,
     mesh=None,
     window: Optional[int] = None,
+    block_length: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention, position-major as the projections leave it; q:
     [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] -> [B, S, Hq, Dv].
@@ -1017,8 +1150,17 @@ def flash_attention(
 
     ``window`` (causal only): a query at t sees the keys s with ``0 <= t - s
     < window``.  One that covers the sequence is no window.
+
+    ``block_length`` (causal only, no window): the sequence is a
+    block-diffusion stream — a noised copy of S / 2 data tokens, then their
+    clean copy, in blocks of ``block_length`` — and a query sees what
+    `_bd_visible` says: the `tpuft_bd_*` kernels walk the tiles that hold such a
+    pair and no others.
     """
     b, sq, hq, d = q.shape
+    if block_length is not None:
+        assert causal and window is None and sq == k.shape[1] and sq % (2 * block_length) == 0, (
+            "a block-diffusion stream is one sequence of two halves of whole blocks")
     if window is not None:
         assert causal and sq == k.shape[1] and window > 0, "a window is causal over one sequence"
         if window >= sq:
@@ -1030,6 +1172,7 @@ def flash_attention(
     if hkv != hq and (not kernel or d % _LANE):  # the XLA formulation, and heads folded into the batch: a KV head a head
         k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
     if kernel and d % _LANE:
+        assert block_length is None, "the block-diffusion kernels take heads that are lane multiples"
         # padded, and the heads folded into the batch: the kernels' form of before PR 65 whole — blocks that lead with the
         # heads, lse lane-padded, the backward one batched product.  Moonlight read -2.5% with its heads side by side (30
         # copies of 134 MB) and -1.8% folded but with lse as rows and the backward a head at a time: XLA had hidden the
@@ -1038,5 +1181,6 @@ def flash_attention(
         q, k, v = (t.reshape((b * hq,) + t.shape[2:]) for t in (jnp.pad(q.transpose(0, 2, 1, 3), pad), jnp.pad(k.transpose(0, 2, 1, 3), pad),
                                                                  v.transpose(0, 2, 1, 3)))
         return _flash(q, k, v, (1, 1), scale, causal, kernel, window).reshape(b, hq, sq, dv).transpose(0, 2, 1, 3)
-    out = _flash(*(t.reshape(t.shape[:2] + (-1,)) for t in (q, k, v)), (hq, k.shape[2]), scale, causal, kernel, window)
+    out = _flash(*(t.reshape(t.shape[:2] + (-1,)) for t in (q, k, v)), (hq, k.shape[2]), scale, causal, kernel, window,
+                 block_length)
     return out.reshape(b, sq, hq, dv)

@@ -347,23 +347,35 @@ fused_linear_cross_entropy.defvjp(_ce_vjp_fwd, _ce_vjp_bwd)
 # -- the loss of every row ------------------------------------------------------------
 
 
-@jax.custom_vjp
 def fused_linear_cross_entropy_per_row(x, w, targets):
     """`fused_linear_cross_entropy` before its mean: the loss of every row,
     [N] float32, for a loss that weighs the rows itself (a looped model's
-    exit-weighted loss, `models/transformer.py`).  Its backward takes a
-    cotangent a row, which `tpuft_ce_dlogits` reads as a scale a row, so the
-    bf16 dlogits are still written once and nothing [N, V] is scaled after.
-    x, w, targets and the gate (`fused_ce_applicable`) as there."""
-    return _ce_per_row_fwd(x, w, targets)[0]
+    exit-weighted loss, block diffusion's 1 / t, `models/transformer.py`).  Its
+    backward takes a cotangent a row, which `tpuft_ce_dlogits` reads as a scale
+    a row, so the bf16 dlogits are still written once and nothing [N, V] is
+    scaled after.  x, w, targets and the gate (`fused_ce_applicable`) as there."""
+    return _ce_per_row(x, w, targets, None)
 
 
-def _ce_per_row_fwd(x, w, targets):
-    lse, tl = _ce_fwd(x, w, targets)
+def fused_linear_cross_entropy_per_row_padded(x, w, targets):
+    """The same for a head whose width V is no lane multiple, as
+    `fused_linear_cross_entropy_padded` is the mean's: zero columns pad w to
+    `padded_vocab(V)` and their logits count as -inf (`valid_v`)."""
+    v = w.shape[1]
+    return _ce_per_row(x, jnp.pad(w, ((0, 0), (0, padded_vocab(v) - v))), targets, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _ce_per_row(x, w, targets, valid_v: Optional[int]):
+    return _ce_per_row_fwd(x, w, targets, valid_v)[0]
+
+
+def _ce_per_row_fwd(x, w, targets, valid_v):
+    lse, tl = _ce_fwd(x, w, targets, valid_v=valid_v)
     return lse - tl, (x, w, targets, lse)
 
 
-fused_linear_cross_entropy_per_row.defvjp(_ce_per_row_fwd, lambda res, g: _ce_grads(res, g))
+_ce_per_row.defvjp(_ce_per_row_fwd, lambda valid_v, res, g: _ce_grads(res, g, valid_v))
 
 
 # -- a head whose width no block divides ------------------------------------------
