@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from chip_compile import (  # noqa: F401 — `topo` and `one_chip` are the fixtures
-    ROOT, attention_calls, compile_text, elements, has_kernel, heads_a_step, instructions, models, one_chip, topo)
+    ROOT, attention_calls, compile_text, elements, has_kernel, heads_a_step, instructions, kernel_calls, kernel_grids, models, one_chip,
+    topo)
 
 
 @pytest.mark.parametrize("width", ["flagship", "1b"])
@@ -204,6 +205,11 @@ def test_olmoe_gradient_program_compiles_with_kernels_for_v5e(olmoe_program) -> 
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     assert n_params == 625_616_896
     assert resident < 14 * 2**30, f"the step needs {resident} bytes with AdamW's moments, a v5e chip has 16 GiB"
+    # the one sparse layer's row buffer, bf16[73728,2048] = 288 MiB: its two T * k-row gathers are `tpuft_moe_rows`
+    # calls since PR 67 (no remat here: XLA shared the forward's gather with the backward, and still shares the call);
+    # 11,733,697,536 bytes where PR 66's tree compiled to 11,733,536,256 (temporaries 1,723,598,336 -> 1,723,759,616)
+    assert kernel_calls(text, "tpuft_moe_") == ["tpuft_moe_rows"] * 2
+    assert resident <= 11_733_697_536
 
 
 def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> None:
@@ -228,7 +234,10 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert", "ssm_mix", "ssm_scan", "exit_gate", "bd_noise", "bd_attn"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
-    assert len(kernels) == 13  # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls
+    # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls and, since PR 67, the expert
+    # layer's two `tpuft_moe_rows` calls — booked to `experts` like the grouped matmuls
+    assert len(kernels) == 15 and sum("tpuft_moe_rows" in e["op_name"] for e in kernels.values()) == 2
+    assert all(booked[n][0] == "experts" for n, e in kernels.items() if "tpuft_moe_rows" in e["op_name"])
     assert all(booked[name][0] in ("attn", "head_loss", "experts") for name in kernels), kernels
     working = [n for n, e in ops.items() if e["opcode"] in ("dot", "convolution", "fusion", "custom-call")]
     nameless = [n for n in working if booked[n][0] is None]
@@ -248,6 +257,28 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     largest = sorted(working, key=result_bytes, reverse=True)[:10]
     assert result_bytes(largest[0]) >= 64 * 2048 * 1024 * 4  # an expert matrix's gradient
     assert not set(largest) & set(nameless), [(n, ops[n]) for n in largest if n in nameless]
+
+
+@pytest.mark.parametrize("tokens,k,width,n_rows", [(16384, 6, 2560, 25600), (32768, 8, 2048, 67584), (8192, 8, 2048, 73728)],
+                         ids=["smallthinker", "keye_and_sdar", "olmoe"])
+@pytest.mark.parametrize("weighted", [True, False], ids=["combine", "dispatch_transpose"])
+def test_moe_rows_kernel_compiles_for_v5e(one_chip, tokens, k, width, n_rows, weighted) -> None:
+    """`ops/moe_rows.py` at three cells' shapes, as `models/moe.py` calls it
+    for the combine (gates) and for the dispatch's transpose (a plain sum):
+    one `tpuft_moe_rows` call on a grid of T / 32 steps, a row whole tiles of
+    its own (2,560 columns padded to 24 pieces of 128), no gather left and no
+    array of T * k * E elements — what stands around the kernel is the
+    source's turn (R rows) and the result's (T rows)."""
+    from torchft_tpu.ops import moe_rows as mr
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)  # noqa: E731
+    rows, dest = shape((n_rows, width), jnp.bfloat16), shape((tokens, k), jnp.int32)
+    gates = shape((tokens, k), jnp.float32) if weighted else None
+    assert mr._tiles(rows.shape, rows.dtype, dest.shape) and n_rows * width * 2 > mr.FAST_SOURCE_BYTES
+    text = compile_text(mr.moe_rows, rows, dest, gates)
+    assert kernel_grids(text, "tpuft_moe_") == [("tpuft_moe_rows", (tokens // 32,))]
+    assert f"bf16[{n_rows},{-(-width // 1024) * 8},128]" in text and " gather(" not in text
+    assert not [(op, n) for op, n in instructions(text) if n == tokens * k * width]  # the gathered rows are nowhere
 
 
 @pytest.mark.parametrize("tokens,k,n_exp,held", [(16384, 6, 64, 8), (32768, 8, 128, 16), (16384, 8, 256, 32)],
@@ -278,14 +309,14 @@ def test_expert_row_moves_compile_without_a_relayout_for_v5e(one_chip, tokens, k
     row_assignment = shape((n_rows,), jnp.int32)
 
     def combine_and_its_gradients(rows, gates, dest, row_assignment, dy):
-        out, vjp = jax.vjp(lambda r, g: moe._tokens_of_rows(r, g, dest, row_assignment, False), rows, gates)
+        out, vjp = jax.vjp(lambda r, g: moe._tokens_of_rows(r, g, dest, row_assignment, False, False), rows, gates)
         return out, vjp(dy)
 
     def combine_gradients_alone(rows, gates, dest, row_assignment, dy):  # as the backward pass runs them: no forward beside
-        return moe._tokens_bwd(False, (rows, gates, dest, row_assignment), dy)[:2]
+        return moe._tokens_bwd(False, False, (rows, gates, dest, row_assignment), dy)[:2]
 
     def dispatch_gradient(drows, dest, row_assignment):
-        return moe._rows_bwd(False, (row_assignment, dest), drows)[0]
+        return moe._rows_bwd(False, False, (row_assignment, dest), drows)[0]
 
     for fn, args, gathers in ((combine_and_its_gradients, (rows, gates, dest, row_assignment, dy), 1),
                               (combine_gradients_alone, (rows, gates, dest, row_assignment, dy), 0),

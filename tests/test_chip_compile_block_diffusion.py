@@ -83,7 +83,12 @@ def test_sdar_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip) -> 
     assert n_params == bench.flops("bd_moe_lm").total_params(config) == 550_984_960
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
     quoted = [int(n.replace(",", "")) for n in re.findall(r"\d{1,3}(?:,\d{3}){3,}", config["reduced_why"])]
-    for name, size in (("arguments", ma.argument_size_in_bytes), ("outputs", ma.output_size_in_bytes),
-                       ("temporaries", ma.temp_size_in_bytes), ("the step", resident)):
+    for name, size in (("arguments", ma.argument_size_in_bytes), ("outputs", ma.output_size_in_bytes)):
         assert size in quoted, f"{name}: {size} bytes compiled, `reduced_why` quotes {quoted}"
-    assert resident < 14_500_000_000
+    # the row buffer, bf16[67584,2048] = 264 MiB: a layer's two T * k-row gathers are `tpuft_moe_rows` calls since PR 67,
+    # and the gathered [32768, 8, 2048] rows are not written: the temporaries `reduced_why` quotes (PR 66's compile,
+    # 5,610,596,352, and the step's 14,426,533,888 with them) are 4,990,429,696 now and the step 13,806,367,232 — the
+    # file is the benchmark's and keeps PR 66's figures until a `benchmark` PR quotes these
+    assert kernel_calls(text, "tpuft_moe_") == ["tpuft_moe_rows"] * 2 * layers == ["tpuft_moe_rows"] * 10
+    assert {5_610_596_352, 14_426_533_888} <= set(quoted) and ma.temp_size_in_bytes <= 4_990_429_696
+    assert resident <= 13_806_367_232

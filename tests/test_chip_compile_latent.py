@@ -103,6 +103,14 @@ def test_moonlight_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip
         f"{resident} bytes: the backward's dq has left VMEM in f32, or the experts' gathered rows are laid out again")
     gathered = 16384 * 6 * 2048
     assert not [op for op, n in instructions(text) if op in ("reshape", "copy") and n == gathered]
+    # the row buffer, bf16[25600,2048] = 100 MiB, fits the fast memory: the gathers stay XLA's (no `tpuft_moe_rows`,
+    # PR 67) and XLA still prefetches four of the layers' buffers there behind `tpuft_gmm_fwd` (PR 65 (8))
+    assert kernel_calls(text, "tpuft_moe_") == []
+    import re
+
+    prefetched = [line for line in text.splitlines() if " copy-start(%tpuft_gmm_fwd" in line
+                  and re.search(r"= \(bf16\[25600,2048\]\{[^}]*S\(1\)\}", line)]
+    assert len(prefetched) == 4, f"{len(prefetched)} row buffers are prefetched into the fast memory"
 
 
 @pytest.mark.parametrize("direction", ["forward", "forward_with_states", "backward"])
@@ -215,6 +223,7 @@ def test_kimi_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     assert "tpuft_ce_lse" in kernel_calls(text, "tpuft_ce_") and "tpuft_ce_dlogits" in kernel_calls(text, "tpuft_ce_")
     # the chunks' states exist only inside a layer's backward pass: float32 [32, 256, 128, 128], 537 MB
     assert "f32[32,256,128,128]" in text
+    assert kernel_calls(text, "tpuft_moe_") == []  # row buffers under the rule's size: XLA's gathers (PR 67)
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("kda_mla_moe_lm").total_params(config) == 602_449_792

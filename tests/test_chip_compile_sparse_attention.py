@@ -82,8 +82,12 @@ def test_keye_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("dsa_moe_lm").total_params(config)
     resident = ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes + 8 * n_params
-    # four layers, the floor: 14.82 GB by this count (PR 33); a fifth layer reads 17.29 GB
-    assert resident < 14.9e9, f"the step needs {resident} bytes with AdamW's moments"
+    # four layers, the floor: 14.82 GB by this count (PR 33); a fifth layer reads 17.29 GB; 13,303,942,144 at PR 66 and,
+    # the gathered [32768, 8, 2048] rows no longer written, 12,736,049,152 since PR 67 (temporaries 5,857,412,096 ->
+    # 5,289,519,104)
+    assert resident <= 12_736_049_152, f"the step needs {resident} bytes with AdamW's moments"
+    # the row buffer, bf16[67584,2048] = 264 MiB: a layer's two T * k-row gathers are `tpuft_moe_rows` calls (PR 67)
+    assert kernel_calls(text, "tpuft_moe_") == ["tpuft_moe_rows"] * 2 * layers == ["tpuft_moe_rows"] * 8
 
 
 def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
@@ -150,6 +154,8 @@ def test_zaya_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, mon
     assert "dynamic-update-slice" in {m.group(1) for line in looped for m in [table.search(line)] if m}
     assert not [line for line in looped for m in [table.search(line)] if m and m.group(1) == "add"]
     assert "f32[131584,2048]" not in text
+    # a 68 MiB row buffer: under the rule's size, the gathers stay XLA's (PR 67)
+    assert kernel_calls(text, "tpuft_moe_") == []
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("cca_moe_lm").total_params(config) == 696_250_376

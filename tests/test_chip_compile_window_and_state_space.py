@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from chip_compile import (  # noqa: F401 — `topo` and `one_chip` are the fixtures
-    ROOT, attention_calls, compile_text, has_kernel, kernel_calls, kernel_grids, one_chip, topo)
+    ROOT, attention_calls, compile_text, has_kernel, instructions, kernel_calls, kernel_grids, one_chip, topo)
 
 
 @pytest.mark.parametrize("window", [512, 1536])
@@ -73,6 +73,8 @@ def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, m
     assert all((g["grid"], g["block_q"], g["seq"]) == ([8 if g["name"].endswith("fwd") else 16, 63], 512, 16_384)
                for g in grids), grids
     assert sorted(kernel_grids(text, "tpuft_fa_")) == [("tpuft_fa_bwd_dkdv_dq", (12, 528))] * 2 + [("tpuft_fa_fwd", (6, 528))] * 2
+    # four sparse layers' row buffers of bf16[36864,2048] = 144 MiB: two `tpuft_moe_rows` calls a layer since PR 67
+    assert kernel_calls(text, "tpuft_moe_") == ["tpuft_moe_rows"] * 8
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("swa_moe_lm").total_params(config) == 691_623_936
@@ -83,8 +85,9 @@ def test_laguna_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, m
     # q, k, v position-major).  Nothing is held longer: the buffer assignment's peak of LIVE bytes fell, 6,686,688,738 ->
     # 6,661,719,522 (`--xla_dump_to`'s `*buffer-assignment.txt`, "peak usage"), and the heap it packs the temporaries
     # into came out 95.5 MB larger, 3,464,856,064 -> 3,560,391,168: the packing's, PERF.md section 6, PR 65 (7).  The
-    # chip's allocator has 16.9e9
-    assert resident <= 14.66e9, f"the step needs {resident} bytes with AdamW's moments"
+    # chip's allocator has 16.9e9.  14,278,144,512 since PR 67 (temporaries 3,587,478,016 -> 3,211,991,040: the gathered
+    # [16384, 8, 2048] rows are not written)
+    assert resident <= 14_278_144_512, f"the step needs {resident} bytes with AdamW's moments"
 
 
 def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip, monkeypatch) -> None:
@@ -130,6 +133,10 @@ def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_c
     assert all((g["grid"], g["block_q"], g["seq"]) == ([4 if g["name"].endswith("fwd") else 7, 252], 512, 16_384)
                for g in grids), grids
     assert sorted(kernel_grids(text, "tpuft_fa_")) == [("tpuft_fa_bwd_dkdv_dq", (7, 528))] * 2 + [("tpuft_fa_fwd", (4, 528))] * 2
+    # the row buffer, bf16[25600,2560] = 125 MiB, is past what XLA keeps in the fast memory: a layer's two T * k-row
+    # gathers (the combine, the dispatch's transpose) are `tpuft_moe_rows` calls since PR 67, no [6, 16384, 2560] array
+    assert kernel_calls(text, "tpuft_moe_") == ["tpuft_moe_rows"] * 2 * config["num_hidden_layers"] == ["tpuft_moe_rows"] * 16
+    assert not [op for op, n in instructions(text) if n == 16_384 * 6 * 2_560]
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("early_router_moe_lm").total_params(config) == 643_852_800
@@ -138,8 +145,10 @@ def test_smallthinker_gradient_program_compiles_with_kernels_for_v5e(topo, one_c
     # 5,150,822,400; builder's compile, PR 51) and an allocator's peak of 11.70 GB on the chip; with nothing kept
     # under remat 14,735,490,048, without remat 20,776,999,424; since PR 52, with several heads a grid step in the
     # attention kernels, the temporaries are 258,048 bytes more (builder's compile): 15,835,560,960; since PR 60, k
-    # and v given to the kernels with their 4 KV heads and not repeated to 28, 282,052,608 fewer: 15,553,508,352
-    assert resident <= 15_553_508_352, f"the step needs {resident} bytes with AdamW's moments"
+    # and v given to the kernels with their 4 KV heads and not repeated to 28, 282,052,608 fewer: 15,553,508,352; since
+    # PR 65 15,422,781,440 (temporaries 5,120,911,872); since PR 67, the gathered [6, 16384, 2560] rows gone and a padded
+    # copy of the row buffer come, temporaries 4,377,747,968: 14,679,617,536
+    assert resident <= 14_679_617_536, f"the step needs {resident} bytes with AdamW's moments"
 
 
 @pytest.mark.parametrize("kernel", ["before_forward", "before_backward", "after_forward", "after_backward"])
@@ -280,6 +289,8 @@ def test_nemotron_gradient_program_compiles_with_kernels_for_v5e(topo, one_chip,
     assert sorted(attention_calls(text)) == ["tpuft_fa_bwd_dkdv_dq", "tpuft_fa_fwd"]
     assert sorted(kernel_calls(text, "tpuft_gmm_")) == (["tpuft_gmm_dlhs"] * 8 + ["tpuft_gmm_drhs"] * 8 + ["tpuft_gmm_fwd"] * 16)
     assert "ragged-dot" not in text and "ragged_dot" not in text
+    # the row buffers here are under what XLA keeps in the fast memory: the gathers stay XLA's (PR 67)
+    assert kernel_calls(text, "tpuft_moe_") == []
     ma = compiled.memory_analysis()
     n_params = sum(int(x.size) for x in jax.tree.leaves(shapes))
     assert n_params == bench.flops("mamba2_moe_lm").total_params(config) == 666_962_944

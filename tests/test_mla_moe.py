@@ -353,7 +353,7 @@ def test_the_combines_gradients_against_a_plain_statement_of_it(case) -> None:
 
     want, want_vjp = jax.vjp(plain, rows.astype(jnp.float32), gates)
     want_drows, want_dgates = want_vjp(dy.astype(jnp.float32))
-    got, got_vjp = jax.vjp(lambda r, g: _tokens_of_rows(r, g, dest, row_assignment, every_row_exists), rows, gates)
+    got, got_vjp = jax.vjp(lambda r, g: _tokens_of_rows(r, g, dest, row_assignment, every_row_exists, False), rows, gates)
     got_drows, got_dgates = got_vjp(dy)
 
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), rtol=2 ** -7, atol=2 ** -7)

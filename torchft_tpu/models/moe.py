@@ -71,7 +71,13 @@ Then one of two ways to the experts, both with STATIC shapes:
     k = 8 ``[T, k, E]`` is that result bit for bit too, and there the rows
     stay token-major (``_k_leads``): measured on the v5e in the cells that
     route top-8, the k-major program's gathers of 262,144 rows took 15%
-    longer and a step's expert layers 19 ms more (PERF.md, PR 36).  For
+    longer and a step's expert layers 19 ms more (PERF.md, PR 36).  Where
+    the row buffer is too large for XLA to keep it in the fast memory (on
+    one TPU device: ``ops/moe_rows.applies``, a rule over static shapes)
+    those two T * k-row gathers and their sums are one `tpuft_moe_rows` call
+    each (``_rows_summed``): the rows fetched a DMA each and summed where
+    they land, no ``[T, k, E]`` array — 3.5 ms a call where the gather and
+    its weighting pass took 11.3 (262,144 rows; PERF.md, PR 67).  For
     the same reason as the k-major rows, a chosen scalar is picked by
     comparison and never gathered along a k-wide minor axis (``route``'s
     gates, ``rank`` and ``dest`` below);
@@ -119,6 +125,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from torchft_tpu.ops import moe_rows
 from torchft_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, padded_group_sizes
 from torchft_tpu.parallel.sharding import ShardingRules, constrain
 
@@ -262,8 +269,26 @@ def _take_rows(rows, dest, every_row_exists: bool):
     return jnp.take(rows, dest, axis=0, mode="fill", fill_value=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of_tokens(xf, row_token, dest, every_row_exists: bool):
+def _rows_summed(rows, dest, gates, every_row_exists: bool, fused: bool):
+    """rows [R, E], dest [T, k], gates [T, k] f32 or None (ones) -> [T, E] in
+    ``rows``'s type: each token the float32 sum of its rows ``rows[dest[t]]``
+    weighted by its gates, an assignment without a row adding zero.
+    ``fused``: one `tpuft_moe_rows` call (``ops/moe_rows.py``: the rows
+    fetched a DMA each and summed where they land, no [T, k, E] array); else
+    XLA's gather and a pass over what it gathered — k-major where the k
+    choices lead, the gates staying [T, k] as the router gives them (T * k
+    scalars: their transpose is inside the product)."""
+    if fused:
+        return moe_rows.moe_rows(rows, dest, gates)
+    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
+    k_leads = _k_leads(dest.shape[1])
+    if gates is None:
+        return jnp.sum(picked, axis=0 if k_leads else 1).astype(rows.dtype)
+    return jnp.einsum("kte,tk->te" if k_leads else "tke,tk->te", picked, gates).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rows_of_tokens(xf, row_token, dest, every_row_exists: bool, fused: bool):
     """xf [T, E] -> [R, E]: row r is token ``row_token[r]``'s activation.  A
     row no assignment landed in (``row_token[r] == T``) repeats the last
     token's: it only has to be finite, because its cotangent is zero
@@ -272,40 +297,32 @@ def _rows_of_tokens(xf, row_token, dest, every_row_exists: bool):
     return jnp.take(xf, row_token, axis=0, mode="clip")
 
 
-def _rows_fwd(xf, row_token, dest, every_row_exists):
-    return _rows_of_tokens(xf, row_token, dest, every_row_exists), (row_token, dest)
+def _rows_fwd(xf, row_token, dest, every_row_exists, fused):
+    return _rows_of_tokens(xf, row_token, dest, every_row_exists, fused), (row_token, dest)
 
 
-def _rows_bwd(every_row_exists, res, drows):
-    # A token's rows are at dest[t]: a gather and a sum, not a scatter-add —
-    # a sum of k slabs of [T, E] where the k choices lead.
+def _rows_bwd(every_row_exists, fused, res, drows):
+    # A token's rows are at dest[t]: a gather and a sum, not a scatter-add.
     row_token, dest = res
-    k_axis = 0 if _k_leads(dest.shape[1]) else 1
-    dxf = jnp.sum(_take_rows(drows, dest, every_row_exists).astype(jnp.float32), axis=k_axis)
-    return dxf.astype(drows.dtype), _int_zero(row_token), _int_zero(dest)
+    return _rows_summed(drows, dest, None, every_row_exists, fused), _int_zero(row_token), _int_zero(dest)
 
 
 _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists: bool, fused: bool):
     """rows [R, E], gates [T, k] f32, dest [T, k] -> [T, E]: each token the
-    sum of its rows weighted by their gates (an assignment without a row
-    adds zero).  Where the rows are picked k-major, so that no 6-wide axis
-    meets an 8-row tile, the gates stay [T, k] as the router gives them
-    (T * k scalars: their transpose is inside the product)."""
-    picked = _take_rows(rows, dest, every_row_exists).astype(jnp.float32)
-    product = "kte,tk->te" if _k_leads(gates.shape[1]) else "tke,tk->te"
-    return jnp.einsum(product, picked, gates).astype(rows.dtype)
+    sum of its rows weighted by their gates (``_rows_summed``)."""
+    return _rows_summed(rows, dest, gates, every_row_exists, fused)
 
 
-def _tokens_fwd(rows, gates, dest, row_assignment, every_row_exists):
-    out = _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists)
+def _tokens_fwd(rows, gates, dest, row_assignment, every_row_exists, fused):
+    out = _tokens_of_rows(rows, gates, dest, row_assignment, every_row_exists, fused)
     return out, (rows, gates, dest, row_assignment)
 
 
-def _tokens_bwd(every_row_exists, res, dy):
+def _tokens_bwd(every_row_exists, fused, res, dy):
     rows, gates, dest, row_assignment = res
     k, n_assign = gates.shape[1], gates.size
     # Row r belongs to assignment row_assignment[r] = t * k + j (T * k where
@@ -382,8 +399,12 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
         jnp.arange(n_assign, dtype=jnp.int32), unique_indices=True
     )
     dest = dest.reshape(tokens, k)
+    # The layer's two T * k-row gathers (the combine, the dispatch's transpose:
+    # the same shapes) through `tpuft_moe_rows` where the row buffer is too
+    # large for XLA to read it out of the fast memory: a static shape decides.
+    fused = moe_rows.applies((rows, xf.shape[1]), xf.dtype, dest.shape, mesh)
 
-    xs = _rows_of_tokens(xf, row_assignment // k, dest, every_row_exists)
+    xs = _rows_of_tokens(xf, row_assignment // k, dest, every_row_exists, fused)
     if w_gate is None:  # un-gated: the activation on the one hidden product
         gate = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
         hidden = ACTIVATIONS[activation](gate)
@@ -392,7 +413,7 @@ def _dropless_ffn(xf, gate_vals, gate_idx, w_gate, w_up, w_down, *, n_exp, first
         up = grouped_matmul(xs, w_up, sizes, row_tile=row_tile, mesh=mesh)
         hidden = ACTIVATIONS[activation](gate) * up
     out = grouped_matmul(hidden, w_down, sizes, row_tile=row_tile, mesh=mesh)
-    y = _tokens_of_rows(out, gate_vals, dest, row_assignment, every_row_exists)
+    y = _tokens_of_rows(out, gate_vals, dest, row_assignment, every_row_exists, fused)
     active = None
     if activation in ("relu", "relu2"):
         landed = (row_assignment < n_assign)[:, None]  # a padding row repeats a token's and counts nothing
