@@ -231,7 +231,7 @@ def test_olmoe_gradient_program_is_named_part_by_part_for_v5e(olmoe_program) -> 
     ran = re.findall(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ", entry[:entry.index("\n}")], flags=re.M)
     assert len(ran) > 500 and set(ran) <= set(ops)
     booked = {name: opmap.booked(entry) for name, entry in ops.items()}
-    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert", "ssm_mix", "ssm_scan", "exit_gate", "bd_noise", "bd_attn"}
+    assert {part for part, _ in booked.values()} - {None} == set(opmap.PARTS) - {"cca_mix", "kda_mix", "kda_scan", "attn_window", "dsa_index", "dsa_select", "ffn", "shared_expert", "ssm_mix", "ssm_scan", "exit_gate", "bd_noise", "bd_attn", "gdn_mix", "gdn_scan"}
     assert {direction for part, direction in booked.values() if part} == {"fwd", "bwd"}  # the cell does not rematerialise
     kernels = {name: entry for name, entry in ops.items() if "tpuft_" in entry["op_name"] and entry["opcode"] == "custom-call"}
     # attention forward and backward, `tpuft_ce_lse` and `_dlogits`, nine grouped matmuls and, since PR 67, the expert

@@ -4,8 +4,11 @@ output and all five gradients, at chunk sizes that do and do not divide the
 sequence, at one chunk and at many, at decays down to g = -20 a position (no
 inf, no nan) and at g = 0 (the plain delta rule), at beta 0 (the state only
 decays) and 1 — and the kernels in ``interpret`` mode against the XLA form at a
-few tiles.  Float32 on both sides unless a case says bfloat16, so what differs
-is the order of sums."""
+few tiles — each for the rule's two shapes of the decay (a number a channel of
+the key: Kimi Delta Attention; ONE number a head: Gated DeltaNet) and its two
+counts of key heads (as many as value heads; half of them, value head j on key
+head j // 2), which `kda` reads off its operands (`RULES`).  Float32 on both
+sides unless a case says bfloat16, so what differs is the order of sums."""
 
 import functools
 import os
@@ -38,13 +41,27 @@ CASES = {
 }
 
 
-def _inputs(seq, g_low, g_kind, beta_kind, heads=2, width=16, seed=0, dtype=jnp.float32):
+# the rule by its operands' shapes: (the decay: a number a "channel" of the key | ONE a "head", the key heads: "all" the
+# value heads' | "half" of them)
+RULES = {"a_decay_a_channel": ("channel", "all"), "a_decay_a_head": ("head", "all"), "half_the_key_heads": ("channel", "half"),
+         "a_decay_a_head_and_half_the_key_heads": ("head", "half")}
+OWN = "a_decay_a_channel"
+# every case under the channel rule; under the other three the cases that cross a chunk's edge with a ragged end, that
+# reach g = -20 and that run at the model's chunk
+RULE_CASES = [(case, OWN) for case in CASES] + [
+    (case, rule) for rule in RULES if rule != OWN for case in ("chunks_do_not_divide", "many_chunks_of_64", "g_down_to_minus_20")]
+
+
+def _inputs(seq, g_low, g_kind, beta_kind, heads=2, width=16, seed=0, dtype=jnp.float32, rule=OWN):
+    decay, keys = RULES[rule]
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (1, heads, seq, width))) * width ** -0.5
-    k = unit(jax.random.normal(ks[1], (1, heads, seq, width)))
+    key_heads = heads if keys == "all" else heads // 2
+    q = unit(jax.random.normal(ks[0], (1, key_heads, seq, width))) * width ** -0.5
+    k = unit(jax.random.normal(ks[1], (1, key_heads, seq, width)))
     v = jax.random.normal(ks[2], (1, heads, seq, width))
-    g = -g_low * (jax.random.uniform(ks[3], (1, heads, seq, width)) if g_kind == "uniform" else jnp.ones((1, heads, seq, width)))
+    of_g = (1, heads, seq, width) if decay == "channel" else (1, heads, seq)
+    g = -g_low * (jax.random.uniform(ks[3], of_g) if g_kind == "uniform" else jnp.ones(of_g))
     beta = {"sigmoid": jax.nn.sigmoid(jax.random.normal(ks[4], (1, heads, seq))),
             "zero": jnp.zeros((1, heads, seq)), "one": jnp.ones((1, heads, seq))}[beta_kind]
     weight = jax.random.normal(ks[5], (1, heads, seq, width))
@@ -59,21 +76,25 @@ def _both(fn, args, weight):
 
 
 @functools.lru_cache(maxsize=None)
-def _chunked_and_loop(case):
+def _chunked_and_loop(case, rule):
     seq, chunk, g_low, g_kind, beta_kind = CASES[case]
-    args, weight = _inputs(seq, g_low, g_kind, beta_kind)
+    args, weight = _inputs(seq, g_low, g_kind, beta_kind, rule=rule)
     got = _both(lambda *a: da.kda(*a, chunk=chunk), args, weight)
     want = _both(lambda *a: da.kda_loop(*a)[0], args, weight)
     return got, want
 
 
 @pytest.mark.parametrize("name", NAMES)
-@pytest.mark.parametrize("case", list(CASES))
-def test_the_chunk_form_is_the_recurrence(case, name) -> None:
+@pytest.mark.parametrize("case,rule", RULE_CASES, ids=[f"{case}-{rule}" for case, rule in RULE_CASES])
+def test_the_chunk_form_is_the_recurrence(case, rule, name) -> None:
     """5e-5 of the largest entry: float32 sums in another order (the chunk
     form re-associates products of up to `chunk` decays and a triangular
-    solve by squarings); nothing else differs."""
-    got, want = _chunked_and_loop(case)
+    solve by squarings); nothing else differs — for a decay a head as for one
+    a channel (dg then one number a head and position), for shared key heads as
+    for a key head a value head (dq and dk then the sums over a key head's
+    value heads)."""
+    got, want = _chunked_and_loop(case, rule)
+    assert [a.shape for a in got] == [a.shape for a in want]
     a, b = np.asarray(got[NAMES.index(name)], np.float64), np.asarray(want[NAMES.index(name)], np.float64)
     assert np.all(np.isfinite(a)), "an exponent left its bounds"
     scale = max(float(np.max(np.abs(b))), 1e-6)
@@ -89,12 +110,13 @@ def test_the_chunk_form_is_the_recurrence(case, name) -> None:
 KERNEL_CASES = {"1_of_4": (4, 1), "2_of_4": (4, 2), "4_of_4": (4, 4), "6_from_the_shape": (6, None),
                 "5_from_the_shape": (5, None)}
 KERNEL_NAMES = NAMES + ("states",)
+GATED_DELTA_NET = "a_decay_a_head_and_half_the_key_heads"  # Gated DeltaNet's rule, through `kda` itself: 4 value heads on 2 key heads
 
 
-def _kernel_inputs(dtype_name, heads):
+def _kernel_inputs(dtype_name, heads, rule=OWN):
     dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[dtype_name]
-    args, weight = _inputs(192, 1.0, "uniform", "sigmoid", heads=heads, width=128, seed=3, dtype=dtype)
-    return args, weight, [a.reshape(heads, 192, *a.shape[3:]) for a in args]
+    args, weight = _inputs(192, 1.0, "uniform", "sigmoid", heads=heads, width=128, seed=3, dtype=dtype, rule=rule)
+    return args, weight, [a.reshape(a.shape[1], 192, *a.shape[3:]) for a in args]
 
 
 @functools.lru_cache(maxsize=None)
@@ -131,6 +153,25 @@ def test_the_kernels_in_interpret_mode_are_the_xla_form(dtype_name, case, name) 
     assert float(np.max(np.abs(a - b))) <= (1e-5 if dtype_name == "float32" else 2e-2) * float(np.max(np.abs(b)))
 
 
+@functools.lru_cache(maxsize=None)
+def _gated_delta_net(interpret: bool):
+    args, weight, _ = _kernel_inputs("float32", 4, GATED_DELTA_NET)
+    return _both(lambda *a: da.kda(*a, interpret=interpret), args, weight)
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_the_kernels_in_interpret_mode_are_the_xla_form_for_a_decay_a_head_under_shared_key_heads(name) -> None:
+    """Gated DeltaNet's operands — g [B, H, S], q and k at half the value
+    heads — through `kda` with the kernels (``interpret``) and with the XLA
+    form: the output and all five gradients, dq and dk a KEY head's and dg one
+    number a head and position, agree as the channel rule's do."""
+    got, want = _gated_delta_net(True), _gated_delta_net(False)
+    a, b = np.asarray(got[NAMES.index(name)], np.float64), np.asarray(want[NAMES.index(name)], np.float64)
+    assert a.shape == b.shape == {"o": (1, 4, 192, 128), "dq": (1, 2, 192, 128), "dk": (1, 2, 192, 128), "dv": (1, 4, 192, 128),
+                                  "dg": (1, 4, 192), "dbeta": (1, 4, 192)}[name]
+    assert np.all(np.isfinite(a)) and float(np.max(np.abs(a - b))) <= 1e-5 * float(np.max(np.abs(b)))
+
+
 @pytest.mark.parametrize("bh, most, heads", [(32, 8, 8), (32, 4, 4), (4, 8, 4), (6, 4, 3), (6, 8, 6), (5, 4, 1), (7, 8, 7),
                                              (1, 8, 1), (48, 32, 24)])
 def test_the_heads_of_a_grid_step_divide_the_heads(bh, most, heads) -> None:
@@ -159,14 +200,15 @@ def test_the_levels_cover_each_pair_once_and_sum_only_what_lies_between(chunk) -
 def test_the_benchmark_counts_the_chunk_the_program_runs() -> None:
     from benchmark.spec import Benchmark
 
-    assert Benchmark(ROOT).flops("tpuft_kda").CHUNK == da.CHUNK == 64
+    assert Benchmark(ROOT).flops("tpuft_kda").CHUNK == Benchmark(ROOT).flops("tpuft_gdn").CHUNK == da.CHUNK == 64
 
 
-def test_a_sequence_is_padded_with_positions_that_write_nothing() -> None:
+@pytest.mark.parametrize("rule", list(RULES))
+def test_a_sequence_is_padded_with_positions_that_write_nothing(rule) -> None:
     """A chunk that does not divide the sequence: the padded call's outputs up
     to the sequence's end are the outputs of the longer sequence whose tail
     writes nothing, and the gradients of what was cut away are not asked for."""
-    (q, k, v, g, beta), _ = _inputs(40, 0.5, "uniform", "sigmoid")
+    (q, k, v, g, beta), _ = _inputs(40, 0.5, "uniform", "sigmoid", rule=rule)
     short = da.kda(q[:, :, :37], k[:, :, :37], v[:, :, :37], g[:, :, :37], beta[:, :, :37], chunk=8)
     whole = da.kda(q, k, v, g, beta, chunk=8)
     assert short.shape == (1, 2, 37, 16)

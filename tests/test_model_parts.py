@@ -122,6 +122,16 @@ MODELS["sdar"] = TransformerConfig(**dict(
     moe_held=(2, 2), moe_aux_coef=0.001, remat=True, remat_keeps_attention=True, rope_theta=1e6, bd_block_length=4,
     bd_noise_seed=66))
 PINNED = PINNED + ("sdar",)
+# The thirteenth: Gated DeltaNet — a decay a head, two key heads under four value heads — 3 : 1 with attention under a
+# gate a column, a quarter of a head rotated, zero-centred norms, a shared expert under its own gate (Qwen3-Next's
+# shape: one whole period).  Pinned by PR 68, which brought it.
+MODELS["qwen3next"] = TransformerConfig(**dict(
+    _BASE, n_layers=4, head_dim=32, qk_norm_per_head=True, attn_out_gate=True, norm_unit_offset=True, gdn_key_heads=2,
+    gdn_key_dim=16, gdn_value_dim=16, moe_experts=8, moe_top_k=3, d_ff=32, moe_capacity_factor=None, moe_held=(2, 2),
+    moe_shared_experts=1, moe_shared_gate=True, moe_aux_coef=0.001, remat=True, remat_keeps_attention=True, scan_unroll=8,
+    pattern=(LayerKind("gdn_layers", True, 4, 1e7, rotary_fraction=0.25, mixer="gdn"),) * 3
+    + (LayerKind("attn_layers", True, 4, 1e7, rotary_fraction=0.25),)))
+PINNED = PINNED + ("qwen3next",)
 # Instructions that do the device's work (a copy, a bitcast or a tuple moves or names data).
 HEAVY = ("dot", "convolution", "fusion", "custom-call")
 
@@ -186,6 +196,8 @@ def test_parts_and_directions_are_the_architectures(programs, name) -> None:
         expected |= {"cca_mix"}
     if any(kind.mixer == "kda" for kind in cfg.layers):
         expected |= {"kda_mix", "kda_scan"}
+    if any(kind.mixer == "gdn" for kind in cfg.layers):
+        expected |= {"gdn_mix", "gdn_scan"}
     if cfg.moe_shared_experts:
         expected |= {"shared_expert"}
     if cfg.dsa_index_heads:
@@ -258,7 +270,7 @@ def _digest(step, params, batch, program: str, grads_text=None) -> str:
 
 
 def record(commit: str) -> None:
-    """Records the twenty-four digests anew (`python tests/test_model_parts.py "<commit and why>"`,
+    """Records the twenty-six digests anew (`python tests/test_model_parts.py "<commit and why>"`,
     `JAX_PLATFORMS=cpu`): for a PR that changes the ten gradient programs on
     purpose.  The update programs are no model code's to change, so theirs
     have to come out as they were."""
@@ -280,7 +292,8 @@ def record(commit: str) -> None:
 
 # The stacks of the models whose pattern names its own (the others: "layers", and "dense_layers" where some lead).
 _STACKS = {"laguna": {"dense_layers", "window_layers", "layers"}, "kimi": {"kda_dense", "kda_layers", "mla_layers"},
-           "smallthinker": {"layers", "window_layers"}, "nemotron": {"mamba", "attn", "moe"}}
+           "smallthinker": {"layers", "window_layers"}, "nemotron": {"mamba", "attn", "moe"},
+           "qwen3next": {"gdn_layers", "attn_layers"}}
 
 
 @pytest.mark.parametrize("program", ["grads", "update"])
@@ -295,8 +308,9 @@ def test_the_pattern_left_the_five_programs_as_they_were(programs, name, program
     (window and full attention mixed) with them, since PR 48 the seventh and
     since PR 61, which made a mixer an entry of `models/mixers.MIXERS`, all ten
     (each recorded from the tree BEFORE the change it guards); the eleventh, a
-    looped model, and the twelfth, one trained by block diffusion, from the PRs
-    that brought them (63, 66).  A PR that
+    looped model, the twelfth, one trained by block diffusion, and the
+    thirteenth, Gated DeltaNet with gated attention, from the PRs that brought
+    them (63, 66, 68).  A PR that
     changes these programs on purpose records them anew:
     `tests/data/hlo_before_the_pattern.json`."""
     import json
@@ -328,12 +342,12 @@ def _is_axes(x) -> bool:
 
 
 @pytest.mark.parametrize("mixer,model", [("attention", "keye"), ("mla", "moonlight"), ("cca", "zaya"), ("kda", "kimi"),
-                                         ("mamba2", "nemotron")])
+                                         ("mamba2", "nemotron"), ("gdn", "qwen3next")])
 def test_a_mixer_lists_its_own_leaves(mixer, model) -> None:
     """`Mixer.axes` and `Mixer.init` name exactly the same leaves — the model
     adds nothing for all and drops nothing for some — and every leaf is a row
     a layer of as many axes as its axes' names."""
-    assert set(MIXERS) == {"attention", "mla", "cca", "kda", "mamba2"}
+    assert set(MIXERS) == {"attention", "mla", "cca", "kda", "gdn", "mamba2"}
     cfg = MODELS[model]
     kind = next(kind for kind in cfg.layers if kind.mixer == mixer)
     entry = MIXERS[mixer]

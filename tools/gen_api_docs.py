@@ -60,6 +60,7 @@ MODULES = [
     "torchft_tpu.models.mixers",
     "torchft_tpu.models.attention",
     "torchft_tpu.models.kda",
+    "torchft_tpu.models.gdn",
     "torchft_tpu.models.mamba",
     "torchft_tpu.models.rope",
     "torchft_tpu.models.moe",

@@ -2,7 +2,7 @@
 a tail — q, k, v position-major as their projections leave them, [B, S, heads,
 D] -> the attention call (or, where the model has an indexer, the keys it
 selects), which reads and writes a head as a column block of a position's row
--> the head gate -> `wo`:
+-> the gate (a head's, or a column's) -> `wo`:
 
 "attention": q, k and v are products of the layer's normed input, position by
 position, under the model's QK-norm and the kind's RoPE.
@@ -30,7 +30,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.mixer import Mixer, _norm_init
+from torchft_tpu.models.mixer import Mixer, _norm_init, _norm_start, _unit
 from torchft_tpu.models.rope import _rope, _rotary
 from torchft_tpu.ops import flash_attention, rms_norm
 from torchft_tpu.ops.attention import SAVED_NAMES, heads_indicator
@@ -225,7 +225,7 @@ def _plain_qkv(cfg, kind, h, w, positions):
     k = k.reshape(B, S, KV, Dh)
     if cfg.qk_norm_per_head:
         with jax.named_scope("norm"):
-            q, k = rms_norm(q, w["q_norm"], cfg.rms_eps), rms_norm(k, w["k_norm"], cfg.rms_eps)
+            q, k = rms_norm(q, _unit(cfg, w["q_norm"]), cfg.rms_eps), rms_norm(k, _unit(cfg, w["k_norm"]), cfg.rms_eps)
     v = (h @ w["wv"].astype(cfg.dtype)).reshape(B, S, KV, Dh)
     if kind.rotary_fraction:  # 0: no position term, q and k are the projections
         q = _rotary(q, positions, kind)
@@ -243,6 +243,8 @@ def _forward(qkv):
             q, k, v = qkv(cfg, kind, h, w, positions)
             if cfg.attn_head_gate:
                 head_gate = jax.nn.sigmoid((h @ w["attn_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
+            if cfg.attn_out_gate:  # a gate a column, [B, S, H * Dv] as the attention call leaves its output
+                out_gate = jax.nn.sigmoid((h @ w["attn_out_gate"].astype(cfg.dtype)).astype(jnp.float32)).astype(cfg.dtype)
             q = constrain(q, ("batch", "seq", "heads", None), mesh, rules)
             k = constrain(k, ("batch", "seq", "kv_heads", None), mesh, rules)
             v = constrain(v, ("batch", "seq", "kv_heads", None), mesh, rules)
@@ -259,6 +261,8 @@ def _forward(qkv):
             if cfg.attn_head_gate:  # a head's gate over its columns: a product with 0 / 1, exact, and no [B, S, H, Dv] array
                 attn = attn * jnp.einsum("bsh,hc->bsc", head_gate, heads_indicator(kind.n_heads, d_v).astype(cfg.dtype),
                                          precision=jax.lax.Precision.HIGHEST)
+            if cfg.attn_out_gate:
+                attn = attn * out_gate
             return attn @ w["wo"].astype(cfg.dtype), dsa
 
     return forward
@@ -276,6 +280,8 @@ def _shared_axes(cfg) -> Dict[str, Any]:
                      "wi_w": ("layers", "embed", None)})
     if cfg.attn_head_gate:
         axes["attn_gate"] = ("layers", "embed", "heads")
+    if cfg.attn_out_gate:
+        axes["attn_out_gate"] = ("layers", "embed", "heads")
     return axes
 
 
@@ -296,6 +302,8 @@ def _init_shared(key, cfg, L: int, kind) -> Dict[str, Any]:
         )
     if cfg.attn_head_gate:
         layers["attn_gate"] = _norm_init(jax.random.fold_in(key, 3), (L, E, kind.n_heads), E, pd)
+    if cfg.attn_out_gate:  # as wide as the heads' joined VALUES: the plain kind's d_head, what its `wo` takes
+        layers["attn_out_gate"] = _norm_init(jax.random.fold_in(key, 7), (L, E, kind.n_heads * cfg.d_head), E, pd)
     return layers
 
 
@@ -321,7 +329,7 @@ def _init_plain(key, cfg, L: int, kind) -> Dict[str, Any]:
     if cfg.qk_norm:
         layers.update({"q_norm": jnp.ones((L, H * Dh), pd), "k_norm": jnp.ones((L, KV * Dh), pd)})
     if cfg.qk_norm_per_head:
-        layers.update({"q_norm": jnp.ones((L, Dh), pd), "k_norm": jnp.ones((L, Dh), pd)})
+        layers.update({"q_norm": _norm_start(cfg)((L, Dh), pd), "k_norm": _norm_start(cfg)((L, Dh), pd)})
     return dict(layers, **_init_shared(key, cfg, L, kind))
 
 
@@ -365,7 +373,7 @@ def _init_cca(key, cfg, L: int, kind) -> Dict[str, Any]:
 
 
 def _no_norm_no_gate(cfg, why: str) -> None:
-    assert not (cfg.qk_norm or cfg.qk_norm_per_head or cfg.attn_head_gate), why
+    assert not (cfg.qk_norm or cfg.qk_norm_per_head or cfg.attn_head_gate or cfg.attn_out_gate), why
 
 
 def _check_mla(cfg, kind) -> None:

@@ -11,7 +11,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.mixer import Mixer, _causal_conv, _norm_init
+from torchft_tpu.models.mixer import Mixer, _causal_conv, _l2, _norm_init
 from torchft_tpu.ops.delta_attention import SAVED_NAMES
 
 
@@ -50,11 +50,6 @@ def _axes(cfg, kind) -> Dict[str, Any]:
                  "kda_beta": ("layers", "embed", None), "A_log": ("layers", None), "dt_bias": ("layers", "heads"),
                  "kda_g_bias": ("layers", "heads"), "kda_norm": ("layers", None)})
     return axes
-
-
-def _l2(x):
-    """x over its last axis' L2 norm (float32 in, float32 out)."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
 _KDA_SMALL = ("kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_norm", "kda_g_bias")
@@ -146,7 +141,7 @@ def _forward(cfg, kind, mesh, rules, h, w, positions):
 
 
 def _check(cfg, kind) -> None:
-    assert not (cfg.qk_norm or cfg.qk_norm_per_head or cfg.attn_head_gate), (
+    assert not (cfg.qk_norm or cfg.qk_norm_per_head or cfg.attn_head_gate or cfg.attn_out_gate), (
         "delta attention has no QK-norm and no head gate of the model's")
 
 

@@ -43,6 +43,24 @@ def _norm_init(k, shape, fan_in, pd):
     return (jax.random.normal(k, shape, pd) * (fan_in ** -0.5)).astype(pd)
 
 
+def _unit(cfg, w):
+    """A norm's weight as `rms_norm` takes it: w, or `1 + w` (float32) where the
+    model's norm weights are zero-centred (`cfg.norm_unit_offset`: a block's two
+    norms, the final norm and the per-head QK-norm's two — never a mixer's own
+    head norm)."""
+    return w.astype(jnp.float32) + 1.0 if cfg.norm_unit_offset else w
+
+
+def _norm_start(cfg):
+    """What such a norm's weight starts at: `jnp.zeros` where the norm is `1 + w`, else `jnp.ones`."""
+    return jnp.zeros if cfg.norm_unit_offset else jnp.ones
+
+
+def _l2(x):
+    """x over its last axis' L2 norm (float32 in, float32 out): the delta-rule mixers' q and k, a head."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
 def _causal_conv(z, taps):
     """A depthwise causal convolution over the sequence: z [B, S, C], taps
     [T, C] float32 with the LAST tap the position's own, ``out_t = sum_i

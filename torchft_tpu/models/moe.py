@@ -101,7 +101,10 @@ and the router z-loss ``mean(logsumexp(logits)^2)`` (arXiv:2202.08906).
 
 A **shared expert** (``moe_layer(shared=...)``) is one SwiGLU every token
 passes beside the routed ones; every device of an expert-parallel layer
-computes it alike.
+computes it alike.  Under a gate of its own (``shared_scale`` [E, 1], Qwen3-
+Next's and Qwen2-MoE's) it adds ``sigmoid(x w_s) * Shared(x)``: the gate a
+number a token, a float32 product and sigmoid, its mean over the positions in
+``stats["shared_gate"]``.
 
 The gated product's activation is the caller's (``activation``, ``ACTIVATIONS``):
 SiLU, or ReLU (ReGLU, SmallThinker's experts) — whose derivative is the
@@ -514,6 +517,7 @@ def moe_layer(
     held_first: int = 0,
     held_rows_factor: float = HELD_ROWS_FACTOR,
     shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    shared_scale: Optional[jax.Array] = None,
     activation: str = "silu",
     routed: Optional[Tuple[jax.Array, jax.Array, Stats]] = None,
     dtype: Any = jnp.bfloat16,
@@ -544,6 +548,8 @@ def moe_layer(
         shared: (gate [E, Fs], up [E, Fs], down [Fs, E]) of a SwiGLU every
             token passes beside the routed experts (gate None: un-gated, as
             the experts), or None.
+        shared_scale: [E, 1], the shared expert's own gate — it adds
+            ``sigmoid(x shared_scale) * Shared(x)`` — or None: it adds Shared(x).
         activation: the gated products' ("silu" | "relu") or the un-gated
             one's ("relu2"), the shared expert's too.
         routed: ``routing``'s result where the scores' input is not x (the
@@ -561,7 +567,8 @@ def moe_layer(
         on the dropless path with every expert held, by construction); on the
         dropless path under ReLU (or its square) also ``active_units`` (int32, the (row,
         hidden unit) pairs that are not zero, over the rows an assignment
-        landed in — of ``(rows_held - dropped) * F``).
+        landed in — of ``(rows_held - dropped) * F``); under ``shared_scale``
+        also ``shared_gate`` (f32, the gate's mean over the positions).
     """
     rules = rules or ShardingRules()
     B, S, E = x.shape
@@ -605,7 +612,13 @@ def moe_layer(
     if shared is not None:
         with jax.named_scope("shared_expert"):
             s_gate, s_up, s_down = (w if w is None else w.astype(dtype) for w in shared)
-            y = y + (hidden_units(activation, s_gate, s_up, lambda w: x @ w) @ s_down).astype(x.dtype)
+            out = hidden_units(activation, s_gate, s_up, lambda w: x @ w) @ s_down
+            if shared_scale is not None:
+                scale = jax.nn.sigmoid(jnp.einsum("bse,eo->bso", x.astype(jnp.float32), shared_scale.astype(jnp.float32),
+                                                  precision=jax.lax.Precision.HIGHEST))
+                stats["shared_gate"] = jnp.mean(jax.lax.stop_gradient(scale))
+                out = out * scale.astype(out.dtype)
+            y = y + out.astype(x.dtype)
     return y, stats
 
 

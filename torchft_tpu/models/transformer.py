@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.models.attention import _index_operands  # noqa: F401 — benchmark/tools/selection_ties.py takes it from here
-from torchft_tpu.models.mixer import Mixer, _norm_init
+from torchft_tpu.models.mixer import Mixer, _norm_init, _norm_start, _unit
 from torchft_tpu.models.mixers import MIXERS
 from torchft_tpu.ops import rms_norm
 from torchft_tpu.ops.attention import bd_pairs_walked
@@ -58,7 +58,7 @@ class LayerKind:
     # and sin times the attention factor.
     yarn: Optional[Tuple[float, int, float, float, float]] = None
     # An entry of `models/mixers.MIXERS` — "attention", "mla", "cca" (models/attention.py), "kda" (models/kda.py),
-    # "mamba2" (models/mamba.py): each file's docstring says what it computes — or "none": the block has no mixer,
+    # "gdn" (models/gdn.py), "mamba2" (models/mamba.py): each file's docstring says what it computes — or "none": the block has no mixer,
     # it is a feed-forward alone under its one norm (`mlp_norm`).
     mixer: str = "attention"
     # False: the block is a mixer alone under its one norm (`attn_norm`): no
@@ -241,6 +241,22 @@ class TransformerConfig:
     # is a function of the batch's ids and `bd_noise_seed` alone (`block_diffusion_noise`).
     bd_block_length: Optional[int] = None
     bd_noise_seed: int = 0
+    # Gated DeltaNet (LayerKind.mixer "gdn"; the kind's heads are its VALUE heads): the key heads they share, a key
+    # and a value head's widths, the kernel of the depthwise causal convolution on q, k and v.
+    gdn_key_heads: int = 16
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    # Zero-centred norm weights (Qwen3-Next's): `rms_norm(x, 1 + w)` at a block's two norms, the final norm and the
+    # per-head QK-norm's two — a weight starts at 0 — and NOT at a mixer's own head norm (models/mixer.py `_unit`).
+    norm_unit_offset: bool = False
+    # A sigmoid gate a COLUMN on attention's output, from the layer's normed input: `attn * sigmoid(h W_g)`, W_g:
+    # embed -> heads * d_head ("attn_out_gate"; arXiv:2505.06708's elementwise form, where `attn_head_gate` is a
+    # number a head).
+    attn_out_gate: bool = False
+    # The shared expert under a sigmoid gate a token: `sigmoid(h w_s) * Shared(h)`, w_s: embed -> 1 ("shared_scale",
+    # float32; models/moe.py).
+    moe_shared_gate: bool = False
 
     def __post_init__(self) -> None:
         assert self.attention in ("flash", "ring", "ulysses"), (
@@ -266,6 +282,12 @@ class TransformerConfig:
             )
             assert not self.moe_dense_layers, "the leading dense layers carry no indexer statistics"
         assert not (self.qk_norm and self.qk_norm_per_head), "one QK-norm or the other"
+        assert not (self.attn_head_gate and self.attn_out_gate), "a gate a head or a gate a column"
+        assert not self.moe_shared_gate or self.moe_shared_experts, "the gate is a shared expert's"
+        if self.norm_unit_offset:
+            assert not (self.qk_norm or self.mla_kv_rank or self.loop_steps > 1 or self.bd_block_length is not None
+                        or self.moe_router_state or any(kind.post_norms for kind in self.pattern)), (
+                "the unit offset is written for a block's two norms, the final norm and the per-head QK-norm")
         if self.moe_dense_layers:
             assert self.moe_experts > 0 and 0 < self.moe_dense_layers < self.n_layers and self.dense_d_ff > 0
         if self.moe_held is not None:
@@ -380,6 +402,8 @@ def _layer_axes(cfg: TransformerConfig, kind: LayerKind) -> Dict[str, Any]:
         if cfg.moe_shared_experts:
             ffn.update({"shared_gate": ("layers", "embed", "mlp"), "shared_up": ("layers", "embed", "mlp"),
                         "shared_down": ("layers", "mlp", "embed")})
+        if cfg.moe_shared_gate:
+            ffn["shared_scale"] = ("layers", "embed", None)
     else:
         ffn = {"w_gate": ("layers", "embed", "mlp"), "w_up": ("layers", "embed", "mlp"), "w_down": ("layers", "mlp", "embed")}
     gated = cfg.moe_activation != "relu2"  # un-gated feed-forwards have no gate matrices
@@ -419,8 +443,9 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
     ks = jax.random.split(key, 8)
     layers: Dict[str, Any] = {}
     mixer = _mixer(kind)
+    one = _norm_start(cfg)
     if mixer is not None:
-        layers.update({"attn_norm": jnp.ones((L, E), pd)}, **mixer.init(key, cfg, L, kind))
+        layers.update({"attn_norm": one((L, E), pd)}, **mixer.init(key, cfg, L, kind))
     if cfg.scaled_merge:
         merge = jnp.broadcast_to(jnp.asarray([1.0, 0.0, 1.0, 0.0], pd)[None, :, None], (L, 4, E))
         layers.update({"attn_merge": merge, "mlp_merge": jnp.array(merge)})  # two buffers: a step donates each leaf
@@ -428,7 +453,7 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
         layers.update({"attn_post_norm": jnp.ones((L, E), pd), "mlp_post_norm": jnp.ones((L, E), pd)})
     if not kind.feed_forward:
         return layers
-    layers["mlp_norm"] = jnp.ones((L, E), pd)
+    layers["mlp_norm"] = one((L, E), pd)
     gated = cfg.moe_activation != "relu2"  # un-gated feed-forwards have no gate matrices
     if sparse:
         F, X, held = cfg.d_ff, cfg.n_router_outputs, cfg.n_held_experts
@@ -454,6 +479,8 @@ def _init_layers(key: jax.Array, cfg: TransformerConfig, L: int, kind: LayerKind
             layers.update({"shared_up": norm_init(ku, (L, E, Fs), E), "shared_down": norm_init(kd, (L, Fs, E), Fs)})
             if gated:
                 layers["shared_gate"] = norm_init(kg, (L, E, Fs), E)
+            if cfg.moe_shared_gate:
+                layers["shared_scale"] = norm_init(jax.random.fold_in(ks[7], 2), (L, E, 1), E)
     else:
         F = cfg.dense_d_ff or cfg.d_ff
         layers.update({"w_up": norm_init(ks[5], (L, E, F), E), "w_down": norm_init(ks[6], (L, F, E), F)})
@@ -472,7 +499,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     E = cfg.d_model
     params = {
         "embed": _norm_init(k_embed, (cfg.vocab_size, E), E, pd),
-        "final_norm": jnp.ones((E,), pd),
+        "final_norm": _norm_start(cfg)((E,), pd),
     }
     if not cfg.tied_head:
         params["lm_head"] = _norm_init(k_head, (E, cfg.vocab_size), E, pd)
@@ -521,7 +548,7 @@ def _layer(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, positions, 
     # The scopes are the parts a profile's device time is booked to
     # (obs/spans.PARTS); they name the work and change no instruction.
     with jax.named_scope("norm"):
-        h = rms_norm(x, w["attn_norm"], cfg.rms_eps)
+        h = rms_norm(x, _unit(cfg, w["attn_norm"]), cfg.rms_eps)
     y, mixer_stats = mixer.forward(cfg, kind, mesh, rules, h, w, positions)
     if kind.post_norms:
         with jax.named_scope("norm"):
@@ -555,7 +582,7 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
     early router made on the layer's input (`moe.routing`'s result), or None:
     the experts' input is routed."""
     with jax.named_scope("norm"):
-        h = rms_norm(x, w["mlp_norm"], cfg.rms_eps)
+        h = rms_norm(x, _unit(cfg, w["mlp_norm"]), cfg.rms_eps)
     if kind.sparse:
         from torchft_tpu.models.moe import moe_layer
 
@@ -568,6 +595,7 @@ def _feed_forward(cfg: TransformerConfig, mesh, rules: ShardingRules, x, w, kind
             capacity_factor=cfg.moe_capacity_factor,
             held_first=cfg.moe_held[0] if cfg.moe_held is not None else 0,
             shared=(w.get("shared_gate"), w["shared_up"], w["shared_down"]) if cfg.moe_shared_experts else None,
+            shared_scale=w["shared_scale"] if cfg.moe_shared_gate else None,
             activation=cfg.moe_activation,
             routed=routed,
             dtype=cfg.dtype,
@@ -875,7 +903,7 @@ def head(
     (parallel/pipeline.pipeline_loss_fn) so the two can never diverge."""
     with jax.named_scope("head_loss"):
         if not normed:
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+            x = rms_norm(x, _unit(cfg, params["final_norm"]), cfg.rms_eps)
         # bf16 operands on the MXU, f32 accumulation/output: full systolic-array
         # rate with f32 logits (an f32xf32 matmul runs at a fraction of MXU peak).
         if cfg.tied_head:
@@ -937,7 +965,7 @@ def lm_head_loss(
     block = head_row_block(B * S, padded_vocab(cfg.vocab_size))
     if fused_ce_applicable(block or B * S, E, padded_vocab(cfg.vocab_size), mesh):
         with jax.named_scope("head_loss"):
-            h = rms_norm(x, params["final_norm"], cfg.rms_eps)
+            h = rms_norm(x, _unit(cfg, params["final_norm"]), cfg.rms_eps)
             if cfg.tied_head or block:
                 # The head's weight as the tree holds it, [V, E] where it is the embedding:
                 # cast, padded and laid out for the kernels inside, its gradient float32.
@@ -1134,6 +1162,8 @@ def loss_and_counters(
             loss = loss + cfg.moe_z_coef * aux["z"]
         counters.update(moe_tokens_per_expert=aux["tokens_per_expert"], moe_dropped=aux["dropped"])
         counters.update({counter: aux[name] for name, (counter, _) in _mean_statistics(cfg).items()})
+        if cfg.moe_shared_gate:  # the mean of the shared expert's gate over positions and sparse layers
+            counters.update(moe_shared_gate_mean=aux["shared_gate"] / cfg.n_sparse_layers)
         if cfg.moe_skip:
             counters.update(moe_skipped=aux["skipped"])
         if cfg.moe_held is not None:

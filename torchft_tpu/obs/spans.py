@@ -198,10 +198,18 @@ SUBSPANS = {
 #     stream of ids (`_block_diffusion_loss`); bd_attn — the attention call
 #     over that stream (the `tpuft_bd_*` kernels: the live tiles of the
 #     three-part block mask), so a trace tells it from `attn`.
+#   gdn_mix — what Gated DeltaNet puts around its scan: the kernel-4
+#     convolution with SiLU over q, k and v, the L2 norm a key head, the
+#     decay's softplus and beta (a number a value head) before it, the head
+#     norm times SiLU(z) after it (XLA fusions under a checkpoint a half; the
+#     projections are `attn_proj`'s, as KDA's are); gdn_scan — the gated delta
+#     rule with a decay a head over the sequence (`ops.delta_attention.kda`:
+#     the `tpuft_kda_*` kernels and what XLA puts around them — today the
+#     decay's broadcast over the key's channels and the key heads' repeat).
 PARTS = (
     "embed", "norm", "attn_proj", "cca_mix", "kda_mix", "kda_scan", "attn", "attn_window", "dsa_index", "dsa_select",
     "ffn", "router", "experts", "shared_expert", "head_loss", "stack", "ssm_mix", "ssm_scan", "exit_gate",
-    "bd_noise", "bd_attn",
+    "bd_noise", "bd_attn", "gdn_mix", "gdn_scan",
 )
 
 

@@ -1,5 +1,6 @@
-"""Kimi Delta Attention's scan: the gated delta rule with a decay a channel
-(arXiv:2510.26692), chunk by chunk, as pallas TPU kernels forward and backward
+"""The gated delta rule's scan — Kimi Delta Attention's, a decay a channel
+(arXiv:2510.26692), and Gated DeltaNet's, a decay a head under shared key
+heads (arXiv:2412.06464) — chunk by chunk, as pallas TPU kernels forward and backward
 (`tpuft_kda_fwd`, `tpuft_kda_bwd`) and as the same chunk algebra in XLA.
 
 The recurrence, a head, with a state S [keys, values] in float32 and zero
@@ -26,6 +27,22 @@ of (K * u_level)(K * w_level)^T, all on the MXU, and g = -20 a position
 underflows to the zero it means instead of overflowing.  The sums of g (G, the
 levels' exponents, the sum to the chunk's end) are products of constant 0/1
 matrices with g, exact in float32 by three bfloat16 passes (`_sum01`).
+
+**A decay a head.**  Gated DeltaNet's decay is ONE number a head and position
+(`kda`'s g of rank 3), and its q and k are shared by the value heads of a key
+head.  With a scalar, `exp(G_r - G_i)` is one [C, C] matrix a head and chunk:
+R = (K K^T) o exp(G_r - G_i) and Rq are ONE masked product each where the
+channel form takes log2(CHUNK) = 6, the difference is <= 0 wherever the mask
+is 1 (masked BEFORE the `exp`, so nothing overflows and no level is needed),
+the level sums of `_sum01` fall away, `W = T (K e^G)`, `S' = e^{G_last} S +
+(K e^{G_last - G})^T D`, and dg is a [C] vector a head.  That form is NOT what
+runs yet: `kda` broadcasts the head's decay over the key's 128 channels and
+repeats a key head for its value heads (float32 [H, S, K] of g, and of dg, a
+layer), and runs the channel form below — correct by construction, and paying
+for what the channel decay alone needs.  What is left — the chunk functions
+reading the decay's rank off their operand, and the q / k blocks' index map
+reading key head j // 2 in place — is PERF.md section 7's first item for the
+cell that runs it.
 
 **Types.**  The state, g, G, every exponent, R, M and all accumulation are
 float32.  The operands of the products with a head-wide side (R's, W, U, D, O
@@ -498,14 +515,31 @@ _kda.defvjp(_kda_fwd, _kda_bwd)
 def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *, chunk: int = CHUNK,
         mesh=None, interpret: bool = False) -> jax.Array:
     """The gated delta rule over a sequence, head-major like the flash call:
-    q, k [B, H, S, K] and v [B, H, S, V] in the compute type (q carries its
-    scale), g [B, H, S, K] float32 <= 0 the log of the decay a channel, beta
-    [B, H, S] float32 in [0, 1] -> o [B, H, S, V] in q's type.  The state
-    before the first position is zero.  A sequence that ``chunk`` does not
-    divide is padded at its end with positions that write nothing (k = 0,
+    q, k [B, Hk, S, K] and v [B, H, S, V] in the compute type (q carries its
+    scale), g float32 <= 0 the log of the decay, beta [B, H, S] float32 in
+    [0, 1] -> o [B, H, S, V] in q's type.  The operands' shapes say which rule:
+
+    - the decay: g [B, H, S, K] a number a channel of the key (Kimi Delta
+      Attention), or g [B, H, S] ONE number a head and position (Gated
+      DeltaNet);
+    - the key heads: Hk = H, or Hk a divisor of H, value head j then reading
+      key head ``j // (H // Hk)`` (Gated DeltaNet at 16 key heads under 32
+      value heads), dq and dk the sums over the value heads of a key head.
+
+    Today a head's decay is broadcast over the key's channels and a shared key
+    head repeated for its value heads before the one scan that runs (module
+    docstring, "A decay a head"); the results are the rule's either way.  The
+    state before the first position is zero.  A sequence that ``chunk`` does
+    not divide is padded at its end with positions that write nothing (k = 0,
     beta = 0, g = 0) and whose outputs are cut away."""
-    b, h, seq, dk = q.shape
-    dv = v.shape[3]
+    b, h, seq, dv = v.shape
+    dk, shared = q.shape[3], h // q.shape[1]
+    assert q.shape == k.shape and h % q.shape[1] == 0 and g.shape in ((b, h, seq, dk), (b, h, seq)), (
+        q.shape, k.shape, v.shape, g.shape)
+    if shared > 1:
+        q, k = jnp.repeat(q, shared, axis=1), jnp.repeat(k, shared, axis=1)
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], (b, h, seq, dk))
     pad = -seq % chunk
     flat = [a.reshape(b * h, seq, *a.shape[3:]) for a in (q, k, v, g.astype(_F32), beta.astype(_F32))]
     if pad:
@@ -517,17 +551,20 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
 
 
 def kda_loop(q, k, v, g, beta) -> Tuple[jax.Array, jax.Array]:
-    """The recurrence position by position, float32: (o, the last state
+    """The recurrence position by position, float32, for either shape of the
+    decay and either count of key heads (`kda`): (o, the last state
     [B, H, K, V]).  The tests' yardstick for the chunk form; no program runs it."""
+    shared = v.shape[1] // q.shape[1]
     qf, kf, vf, gf, bf = (jnp.moveaxis(a.astype(_F32), 2, 0) for a in (q, k, v, g, beta))
 
     def step(state, xs):
-        qt, kt, vt, gt, bt = xs                                   # [B, H, .]
-        state = state * jnp.exp(gt)[..., None]
+        qt, kt, vt, gt, bt = xs                                   # [B, H, .]; qt, kt [B, Hk, K]
+        qt, kt = jnp.repeat(qt, shared, axis=1), jnp.repeat(kt, shared, axis=1)   # value head j reads key head j // shared
+        state = state * (jnp.exp(gt)[..., None] if gt.ndim == 3 else jnp.exp(gt)[..., None, None])
         state = state + (bt[..., None] * kt)[..., None] * (vt - jnp.einsum("bhk,bhkv->bhv", kt, state))[..., None, :]
         return state, jnp.einsum("bhk,bhkv->bhv", qt, state)
 
-    start = jnp.zeros(q.shape[:2] + (k.shape[3], v.shape[3]), _F32)
+    start = jnp.zeros(v.shape[:2] + (k.shape[3], v.shape[3]), _F32)
     with jax.default_matmul_precision("highest"):
         last, o = jax.lax.scan(step, start, (qf, kf, vf, gf, bf))
     return jnp.moveaxis(o, 0, 2), last
