@@ -11,7 +11,16 @@ The published layer fuses its projections (`in_proj_qkvz`: hidden -> q | k | v
 | z interleaved a key-head group; `in_proj_ba`: hidden -> b | a) and runs ONE
 convolution over the 8,192 channels of (q, k, v).  Here they are four matrices
 and two, and three tap arrays: a permutation of columns, a departure of layout
-and not of mathematics."""
+and not of mathematics.
+
+What stands around the scan (`gdn_mix`) runs in one of two places.  On a TPU's
+program over one device, with key and value heads of 128 columns and the
+kernel-4 convolution, both halves are `ops/kda_mix.py`'s pallas kernels (the
+ones Kimi Delta Attention's layer runs, under these operands' shapes) and only
+the decay a head — `_gdn_decay`, [B, S, 32] — stays XLA's.  Everywhere else
+(the CPU, a mesh of several devices, another kernel size or head width) they
+are the XLA halves `_gdn_before` and `_gdn_after` under a checkpoint each,
+which are also what the tests hold the kernels to."""
 
 from __future__ import annotations
 
@@ -61,6 +70,17 @@ def _axes(cfg, kind) -> Dict[str, Any]:
 _GDN_SMALL = ("gdn_conv_q", "gdn_conv_k", "gdn_conv_v", "A_log", "dt_bias", "gdn_norm")
 
 
+def _gdn_decay(a, b, w):
+    """g and beta [B, H, S] float32 — ONE number each a value head and
+    position — from a, b [B, S, H], and the decay's mean: two small fusions
+    that stay XLA's on either path."""
+    f32 = jnp.float32
+    g = -jnp.exp(w["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))  # [B, S, H]
+    alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
+    beta = jax.nn.sigmoid(b.astype(f32))
+    return g.transpose(0, 2, 1), beta.transpose(0, 2, 1), alpha
+
+
 def _gdn_before(q0, k0, v0, a, b, w, Hk, H):
     """`gdn_mix` before the scan, in XLA: the projections q0, k0 [B, S, Hk *
     Dk], v0 [B, S, H * Dv] and a, b [B, S, H] to the scan's q, k [B, Hk, S, Dk]
@@ -76,10 +96,7 @@ def _gdn_before(q0, k0, v0, a, b, w, Hk, H):
                    for z, name in ((q0, "gdn_conv_q"), (k0, "gdn_conv_k"), (v0, "gdn_conv_v")))
         Dk = q.shape[-1] // Hk
         q, k = _l2(q.reshape(B, S, Hk, Dk)) * Dk ** -0.5, _l2(k.reshape(B, S, Hk, Dk))
-        g = -jnp.exp(w["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + w["dt_bias"].astype(f32))  # [B, S, H]
-        alpha = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
-        beta = jax.nn.sigmoid(b.astype(f32))
-        return major(q), major(k), major(v.reshape(B, S, H, -1)), g.transpose(0, 2, 1), beta.transpose(0, 2, 1), alpha
+        return major(q), major(k), major(v.reshape(B, S, H, -1)), *_gdn_decay(a, b, w)
 
 
 def _gdn_after(o, z, w, eps):
@@ -105,18 +122,36 @@ def _gdn_mixer(cfg, kind, mesh, h, w):
     sigmoid(b)``, ONE number each a value head and position, float32; after
     the scan an RMSNorm over each head's columns (one plain weight of Dv)
     times SiLU(z).  Also returns the mean of the decay exp(g) over the layer
-    (`gdn_alpha_mean`'s term).  The two halves around the scan are checkpoints
-    that keep their INPUTS and nothing between (models/kda.py says what a
-    layer holds otherwise)."""
+    (`gdn_alpha_mean`'s term).  Where `ops.kda_mix.applies` (a TPU's program
+    over one device, key and value heads of 128 columns, a sequence with a
+    tile) and the convolution has its four taps, the two halves around the
+    scan are `ops/kda_mix.py`'s kernels — `tpuft_kdamix_fwd` / `_bwd` before
+    it, v's two lane tiles riding with the key head that reads them, and
+    `tpuft_kdamix_out_fwd` / `_bwd` after it with SiLU for the gate — and the
+    decay's two small fusions stay XLA's.  Everywhere else (the CPU, a mesh of
+    several devices, another kernel size or head width) they are the XLA
+    halves `_gdn_before` and `_gdn_after`, checkpoints that keep their INPUTS
+    and nothing between (models/kda.py says what a layer holds otherwise)."""
+    from torchft_tpu.ops import kda_mix
     from torchft_tpu.ops.delta_attention import kda
 
     H, Hk, dt = kind.n_heads, cfg.gdn_key_heads, cfg.dtype
     with jax.named_scope("attn_proj"):
         q0, k0, v0, z, a, b = (h @ w[name].astype(dt) for name in ("wq", "wk", "wv", "wz", "gdn_a", "gdn_b"))
     small = {name: w[name] for name in _GDN_SMALL}
-    q, k, v, g, beta, alpha = jax.checkpoint(lambda *xs: _gdn_before(*xs, Hk, H))(q0, k0, v0, a, b, small)
+    kernels = (cfg.gdn_conv == kda_mix.TAPS and cfg.gdn_key_dim == cfg.gdn_value_dim
+               and kda_mix.applies(h.shape[1], cfg.gdn_value_dim, mesh))
+    if kernels:
+        with jax.named_scope("gdn_mix"):
+            q, k, v = kda_mix.before(q0, k0, v0, None, *(small[name] for name in _GDN_SMALL[:3]))
+            g, beta, alpha = _gdn_decay(a, b, small)
+    else:
+        q, k, v, g, beta, alpha = jax.checkpoint(lambda *xs: _gdn_before(*xs, Hk, H))(q0, k0, v0, a, b, small)
     with jax.named_scope("gdn_scan"):
         o = kda(q, k, v, g, beta, mesh=mesh)
+    if kernels:
+        with jax.named_scope("gdn_mix"):
+            return kda_mix.after(o, z, small["gdn_norm"], None, eps=cfg.rms_eps), alpha
     return jax.checkpoint(lambda *xs: _gdn_after(*xs, cfg.rms_eps))(o, z, small), alpha
 
 
