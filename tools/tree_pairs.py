@@ -19,6 +19,12 @@ the whole record, per-layer metrics included, goes to
 library in its first run (about 15 s of that run's `imports` phase) unless
 `torchft_tpu/_lib/` was copied into it.  No cell runs this; PERF.md's sections
 2 and 6 cite its readings since PR 39 (step 0: `--seconds 3`).
+
+Since PR 70 every run's group-0 stream and step dump are kept too
+(`<nn>_<tree>.run/g0.metrics.jsonl`, `steps.jsonl`: the `program_build` records
+and the `manager_start` sub-span are read from there), and `--fresh-cache`
+gives the call's runs one new, empty compile cache: the first run is then a
+checkout's first, the later ones warm from it.
 """
 
 from __future__ import annotations
@@ -49,6 +55,7 @@ def main() -> int:
     parser.add_argument("--runs", nargs="+", required=True, help="NAME:seed[:t]")
     parser.add_argument("--seconds", type=float)
     parser.add_argument("--label", required=True)
+    parser.add_argument("--fresh-cache", action="store_true", help="one new, empty compile cache for this call's runs")
     args = parser.parse_args()
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         doc = json.load(f)
@@ -56,12 +63,15 @@ def main() -> int:
     trees = {name: os.path.join(ROOT, path) for name, _, path in (t.partition("=") for t in args.trees)}
     out_dir = os.path.join(ROOT, "chiprun_out", args.label)
     os.makedirs(out_dir, exist_ok=True)
+    env = dict(os.environ)
+    if args.fresh_cache:
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(ROOT, "benchmark", "out", f"fresh_cache.{args.label}.{os.getpid()}")
     bad = 0
     for i, run in enumerate(args.runs):
         tree, seed, *traced = run.split(":")
         cmd = [*doc["command"], "--workload", args.workload, "--seed", seed, "--seconds", str(seconds), "--trace", str(int(bool(traced)))]
         t0 = time.time()
-        proc = subprocess.run(cmd, cwd=trees[tree], stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        proc = subprocess.run(cmd, cwd=trees[tree], env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
         record = {"i": i, "tree": tree, "seed": int(seed), "traced": bool(traced), "rc": proc.returncode, "took_s": round(time.time() - t0, 1)}
         for text in proc.stdout.splitlines():
             try:
@@ -79,15 +89,20 @@ def main() -> int:
             bad += 1
             record["stderr_tail"] = proc.stderr[-6000:]
         stem = os.path.join(out_dir, f"{i:02d}_{tree}")
+        tag = f"{args.workload}.{seed}" + (".trace" if traced else "")
+        run_dir = os.path.join(trees[tree], "benchmark", "out", tag + ".run")
+        os.makedirs(stem + ".run", exist_ok=True)
+        for kept, name in ((os.path.join(run_dir, "g0.metrics.jsonl"), "g0.metrics.jsonl"),
+                           (os.path.join(trees[tree], "benchmark", "out", tag + ".steps.jsonl"), "steps.jsonl")):
+            if os.path.exists(kept):
+                shutil.copy(kept, os.path.join(stem + ".run", name))
         if traced and proc.returncode == 0:
-            run_dir = os.path.join(trees[tree], "benchmark", "out", f"{args.workload}.{seed}.trace.run")
             parts = subprocess.run([sys.executable, "benchmark/tools/parts.py", run_dir], cwd=trees[tree], stdout=subprocess.PIPE,
                                    stderr=subprocess.STDOUT, text=True)
             with open(stem + ".parts.txt", "w", encoding="utf-8") as f:
                 f.write(parts.stdout)
             every_instruction = os.path.join(run_dir, "g0.device_parts.json")  # for `parts.py <dir>` after the call
             if os.path.exists(every_instruction):
-                os.makedirs(stem + ".run", exist_ok=True)
                 shutil.copy(every_instruction, stem + ".run")
         with open(stem + ".json", "w", encoding="utf-8") as f:
             json.dump(record, f)

@@ -133,6 +133,7 @@ class Manager:
             init_sync: sync weights from replica 0 at step 0.
             max_retries: consecutive failed commits before giving up.
         """
+        start_ns = time.monotonic_ns()  # the `manager_start` sub-span opens here
         self._load_state_dict_fns: Dict[str, Callable] = {}
         self._user_state_dicts: Dict[str, Callable] = {}
         if load_state_dict is not None:
@@ -273,6 +274,11 @@ class Manager:
         # phase below runs inside a span, and the span's single monotonic
         # measurement also feeds the legacy *_ms fields.
         self._spans = SpanTracker(self._metrics)
+        # The process's program builds (obs/builds.py) carry this Manager's
+        # step from here on, and leave through this tracker's stream.
+        from torchft_tpu.obs import builds
+
+        builds.register(step_of=self.current_step)
         # Goodput ledger (obs/ledger.py): every committed step's wall time
         # classified into the pinned cause taxonomy at the commit vote —
         # the per-step vector rides step_summary, the cumulative counters
@@ -425,6 +431,9 @@ class Manager:
         self._worker_metrics.serve()
 
         self._wire_transport_spans()
+        # The control plane's share of a start (obs/spans.SUBSPANS): a
+        # sub-span, so it enters no step's phases, ledger or busy time.
+        self._spans.note_sub("manager_start", self._step, start_ns, time.monotonic_ns())
 
     def _wire_transport_spans(self) -> None:
         """Hands the span tracker to transports that emit their own spans —

@@ -69,6 +69,18 @@ EVENTS = {
                "parent, step, t0_ns/t1_ns on time.monotonic_ns, thread, "
                "bucket/bytes where they apply) — what a phase is made of; "
                "written with step_summary in one write(), never attributed",
+    # -- program builds (torchft_tpu/obs/builds.py) -------------------------
+    "program_build": "one stage of one program JAX built (fun_name, stage="
+                     "trace|lower|backend, program = jit_value_and_grad | "
+                     "jit_apply | jit_full for TrainStep's own else null, "
+                     "outer = the outermost stage it fell in, t0_ns/t1_ns on "
+                     "time.monotonic_ns, step = the Manager step in flight or "
+                     "null before a Manager; on a backend stage cache="
+                     "hit|miss|off, retrieval_s, saved_s; nested_short[_s] = "
+                     "the traces under a millisecond inside it, counted and "
+                     "not kept) — written with the "
+                     "next step_summary in one write(); one with a step "
+                     "number after warm-up names the step that recompiled",
     # -- cooperative drain (torchft_tpu/drain, manager.py, launch.py) -------
     "drain_notice": "drain notice received; finishing the in-flight step",
     "drain_complete": "cooperative departure finished cleanly",

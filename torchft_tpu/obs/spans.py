@@ -26,6 +26,10 @@ they never enter the phase accumulator, so attribution, the goodput ledger
 and the straggler sentinel do not see them.  Every ``with``-style span and
 sub-span also opens a ``jax.profiler.TraceAnnotation("tpuft:<name>")``, so a
 profile shows them on the thread they ran on, on the device trace's clock.
+
+The process's program builds (obs/builds.py: one record a stage of every
+program JAX builds) leave the same way: a tracker with a stream takes what has
+gathered and writes it as ``program_build`` records in that ``write()`` too.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from torchft_tpu.metrics import MetricsLogger
+from torchft_tpu.obs import builds
 
 __all__ = [
     "PHASES",
@@ -131,6 +136,10 @@ OVERLAPPED_PHASES = ("snapshot", "ec_encode", "outer_sync")
 #     time of its two dispatches; counters_note — the hand-over of the last
 #     step's loss counters (TrainStep(loss_has_counters=True)) to the
 #     step_summary: a read of arrays already on the host.
+#   manager_start — Manager.__init__ from its first line to its return: the
+#     store's server and client, the native ManagerServer's bind and its
+#     first word with the lighthouse, the Manager's client.  A sub-span and
+#     not a span, so a start-up enters no step's phases or ledger.
 SUBSPANS = {
     "d2h_ready": "allreduce_d2h",
     "d2h_lease_wait": "allreduce_d2h",
@@ -145,6 +154,7 @@ SUBSPANS = {
     "grads_dispatch": "ft_step",
     "apply_dispatch": "ft_step",
     "counters_note": "ft_step",
+    "manager_start": None,
 }
 
 
@@ -341,12 +351,22 @@ class SpanTracker:
         ]
         return {"slice_gen": self.slice_gen, "spans": spans}
 
+    def _take_builds(self) -> List[dict]:
+        """The process's program builds gathered since anyone took them
+        (obs/builds.py), as the fields of one ``program_build`` record each.
+        Without a stream they stay in their ring."""
+        if not self._metrics.enabled:
+            return []
+        return [dict(b, slice_gen=self.slice_gen) for b in builds.take()]
+
     def flush_subspans(self) -> None:
         """Writes what is buffered now — at shutdown and when an error is
         latched, so a crash loses at most the step in flight."""
         subs = self._take_subspans()
         if subs is not None:
             self._metrics.emit("subspan", **subs)
+        for build in self._take_builds():
+            self._metrics.emit("program_build", **build)
 
     def phases_ms(self) -> Dict[str, float]:
         """Copy of the per-phase accumulation since the last
@@ -386,8 +406,8 @@ class SpanTracker:
 
     def step_summary(self, step: int, committed: bool, **fields) -> None:
         """Emits the per-step phase breakdown and resets the accumulator;
-        the sub-spans buffered since the last flush leave in the same
-        ``write()``.  Call once per step, after the commit vote."""
+        the sub-spans and program builds buffered since the last flush leave
+        in the same ``write()``.  Call once per step, after the commit vote."""
         with self._lock:
             rec = {
                 "step": step,
@@ -402,6 +422,7 @@ class SpanTracker:
         subs = self._take_subspans()
         if subs is not None:
             records.append(("subspan", subs))
+        records += [("program_build", build) for build in self._take_builds()]
         self._metrics.emit_many(records)
 
 

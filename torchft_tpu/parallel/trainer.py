@@ -140,7 +140,7 @@ class TrainStep:
         mesh = self.ftmesh.mesh
 
         def value_and_grad(params, batch):
-            self._retraced.add("value_and_grad")  # runs when JAX traces, never in a step
+            self._traced("value_and_grad")
             # Shardings are explicit NamedShardings; the abstract mesh is
             # set only so the kernel gate (ops/_pallas_util.kernels_apply)
             # sees the mesh this program is traced for even when the loss
@@ -156,11 +156,12 @@ class TrainStep:
         def apply(params, opt_state, grads):
             import optax
 
-            self._retraced.add("apply")
+            self._traced("apply")
             updates, opt_state = self.tx.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state
 
         def full(params, opt_state, batch):
+            self._traced("full")  # first: the stage keeps the name the outermost function gives it
             loss, grads = value_and_grad(params, batch)
             if self.loss_has_counters:
                 loss = loss[0]
@@ -187,9 +188,10 @@ class TrainStep:
         self._retraced: set = set()
         self._ran: dict = {}
         self._texts: Optional[dict] = None
-        from torchft_tpu.obs import opmap
+        from torchft_tpu.obs import builds, opmap
 
         opmap.register(self)
+        builds.register()
 
     # -- pure compute --------------------------------------------------------
 
@@ -204,6 +206,16 @@ class TrainStep:
         """(loss, grads); a loss with counters leaves them in
         ``last_counters``."""
         return self._loss_and_grads(params, batch)
+
+    def _traced(self, name: str) -> None:
+        """Called by the function `name` as its first line, so it runs when
+        JAX traces it and never in a step: the next `_note_run` keeps the
+        call's arguments, and the build's records (obs/builds.py) carry the
+        program's name — `jit_<name>`, as `op_map` and a profile have it."""
+        from torchft_tpu.obs import builds
+
+        self._retraced.add(name)
+        builds.tag("jit_" + name)
 
     def _note_run(self, fn, *args) -> None:
         """After a call of the jitted `fn`: where it was traced anew since
